@@ -74,6 +74,8 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv: Optional[Sequence[str]] = None) -> int:
     args = build_parser().parse_args(argv)
     logging.basicConfig(level=args.log_level)
+    from dalle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.platform:
         import jax
         jax.config.update("jax_platforms", args.platform)

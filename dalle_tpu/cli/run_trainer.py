@@ -154,6 +154,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     logging.basicConfig(
         level=args.log_level,
         format="%(asctime)s %(name)s %(levelname)s %(message)s")
+    from dalle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.platform:
         import jax
         jax.config.update("jax_platforms", args.platform)

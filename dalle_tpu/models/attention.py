@@ -30,6 +30,7 @@ runs in float32, with activations in bfloat16 for the MXU.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional, Tuple
 
 import jax
@@ -43,6 +44,9 @@ from dalle_tpu.config import (
     ATTN_CONV_LIKE,
     ATTN_FULL,
 )
+from dalle_tpu.parallel.mesh import HEADS_SPEC, per_shard
+
+logger = logging.getLogger(__name__)
 
 NEG_INF = -1e9  # softmax mask fill; safe in fp32 accumulation
 
@@ -54,6 +58,15 @@ _PALLAS_INTERPRET = False
 
 def _pallas_by_default() -> bool:
     return jax.default_backend() == "tpu" or _PALLAS_INTERPRET
+
+
+@functools.lru_cache(maxsize=None)
+def log_kernel_choice(site: str, kernel: bool, why: str) -> None:
+    """Say which lowering a shape predicate picked — once per distinct
+    (site, choice, reason), at trace time — so a run that quietly gave a
+    fused kernel up for the XLA lowering shows it in its log."""
+    logger.info("%s: %s (%s)", site,
+                "Pallas kernel" if kernel else "XLA lowering", why)
 
 
 # ---------------------------------------------------------------------------
@@ -339,14 +352,36 @@ def window_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def zoo_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   attn_type: str, text_len: int, grid: int,
-                  conv_kernel: int = 11) -> jax.Array:
-    """Train-time attention dispatch: fast paths where available."""
+                  conv_kernel: int = 11, mesh=None) -> jax.Array:
+    """Train-time attention dispatch: fast paths where available. With a
+    ``mesh`` of more than one device the fused kernels run per shard
+    (batch over dp/fsdp, heads over tp; parallel/mesh.per_shard)."""
+    if not _pallas_by_default():
+        if attn_type in (ATTN_AXIAL_ROW, ATTN_AXIAL_COL):
+            return axial_attention(q, k, v, attn_type, text_len, grid,
+                                   use_pallas=False)
+        return dense_zoo_attention(q, k, v, attn_type, text_len, grid,
+                                   conv_kernel)
+    fused = functools.partial(_fused_zoo_attention, attn_type=attn_type,
+                              text_len=text_len, grid=grid,
+                              conv_kernel=conv_kernel)
+    return per_shard(fused, mesh, (HEADS_SPEC,) * 3, HEADS_SPEC)(q, k, v)
+
+
+def _fused_zoo_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                         attn_type: str, text_len: int, grid: int,
+                         conv_kernel: int) -> jax.Array:
+    """The kernel dispatch on one shard's (local) shapes."""
     if attn_type in (ATTN_AXIAL_ROW, ATTN_AXIAL_COL):
-        return axial_attention(q, k, v, attn_type, text_len, grid)
-    if _pallas_by_default() and _window_fits_vmem(q.shape, text_len, grid):
+        return axial_attention_fused(q, k, v, attn_type, text_len, grid,
+                                     interpret=_PALLAS_INTERPRET)
+    fits = _window_fits_vmem(q.shape, text_len, grid)
+    log_kernel_choice(
+        f"{attn_type} attention", fits,
+        f"_window_fits_vmem(local q{tuple(q.shape)}, text {text_len}, "
+        f"grid {grid}) is {fits}")
+    if fits:
         return window_attention_fused(q, k, v, attn_type, text_len, grid,
                                       conv_kernel,
                                       interpret=_PALLAS_INTERPRET)
     return dense_zoo_attention(q, k, v, attn_type, text_len, grid, conv_kernel)
-
-

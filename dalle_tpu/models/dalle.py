@@ -86,9 +86,12 @@ def _segment_nll(h: jax.Array, table: jax.Array, targets: jax.Array,
 
 class DALLE(nn.Module):
     cfg: ModelConfig
-    # Device mesh, needed only when cfg.sequence_parallel != "none": the
-    # attention ops become explicit shard_map programs over the mesh's sp
-    # axis (parallel/sequence.py). Parameter shapes do not depend on it.
+    # Device mesh the model is trained on. The fused Pallas kernels run
+    # per shard of it (parallel/mesh.per_shard — GSPMD cannot partition a
+    # Mosaic kernel), and with cfg.sequence_parallel != "none" the
+    # attention ops become shard_map programs over its sp axis
+    # (parallel/sequence.py). None = one device. Parameter shapes do not
+    # depend on it.
     mesh: Any = None
 
     def setup(self):
@@ -227,6 +230,11 @@ class DALLE(nn.Module):
 def init_params(model: DALLE, rng: jax.Array,
                 batch: int = 2) -> "flax.core.FrozenDict":
     cfg = model.cfg
+    if model.mesh is not None:
+        # the per-shard kernels split the batch over dp x fsdp, so even
+        # the dummy init batch must divide over the data shards
+        shards = model.mesh.shape["dp"] * model.mesh.shape["fsdp"]
+        batch = -(-batch // shards) * shards
     text = jnp.zeros((batch, cfg.text_seq_len), jnp.int32)
     image = jnp.zeros((batch, cfg.image_seq_len), jnp.int32)
     return model.init(rng, text, image)

@@ -15,12 +15,14 @@ rematerialisation — each block is wrapped in ``jax.checkpoint`` via
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
 
 from dalle_tpu.config import ModelConfig
 from dalle_tpu.models.attention import (
@@ -28,6 +30,7 @@ from dalle_tpu.models.attention import (
     rotary_cos_sin,
     zoo_attention,
 )
+from dalle_tpu.parallel.mesh import TOKENS_SPEC, per_shard
 
 
 def _dtype(cfg: ModelConfig):
@@ -97,7 +100,8 @@ class ZooAttention(nn.Module):
         else:
             out = zoo_attention(
                 q, k, v, attn_type=self.attn_type, text_len=cfg.text_seq_len,
-                grid=cfg.image_grid, conv_kernel=cfg.conv_kernel)
+                grid=cfg.image_grid, conv_kernel=cfg.conv_kernel,
+                mesh=self.mesh)
         # (the attention output is named for the remat save-policies at
         # its source: "attn_out"/"attn_stats" inside the Pallas kernels'
         # custom_vjp fwd rules, "attn_ctx" on the dense/axial XLA paths —
@@ -114,9 +118,11 @@ class FusedLayerNorm(nn.Module):
     single-pass Pallas kernel (ops/pallas/ln_kernels.py) when the shape
     supports it. The fallback is the flax lowering written out inline
     (f32 stats, fast variance, f32 affine) so both paths share one
-    parameter tree and one numerical contract."""
+    parameter tree and one numerical contract. With a ``mesh`` of more
+    than one device the kernel runs per shard of the token rows."""
 
     cfg: ModelConfig
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -127,12 +133,6 @@ class FusedLayerNorm(nn.Module):
         bias = self.param("bias", nn.initializers.zeros_init(), (d,),
                           _param_dtype(cfg))
         from dalle_tpu.models import attention as attn_mod
-        from dalle_tpu.ops.pallas.ln_kernels import (_stats, layer_norm,
-                                                     ln_supported)
-        shape = x.shape
-        m = 1
-        for s in shape[:-1]:
-            m *= s
         # one numerical contract (flax's): statistics are formed in f32
         # from the ORIGINAL input. The kernel reads activation-dtype
         # tiles, so it is used only when the input is ALREADY in
@@ -140,23 +140,44 @@ class FusedLayerNorm(nn.Module):
         # then a no-op); a wider input (f32 into a bf16 model) takes the
         # inline fallback, whose f32 stats match nn.LayerNorm exactly
         # (ADVICE r4: the two paths previously diverged on such inputs)
-        if (attn_mod._pallas_by_default() and ln_supported(m, d)
+        if (attn_mod._pallas_by_default() and x.ndim == 3
                 and x.dtype == jnp.dtype(_dtype(cfg))):
-            y = layer_norm(x.reshape(m, d), scale,
-                           bias, 1e-6, 256, attn_mod._PALLAS_INTERPRET)
-            return y.reshape(shape)
-        xf = x.astype(jnp.float32)
-        mean, rstd = _stats(xf, 1e-6)
-        y = ((xf - mean) * rstd
-             * scale.astype(jnp.float32) + bias.astype(jnp.float32))
-        return y.astype(_dtype(cfg))
+            return per_shard(
+                functools.partial(_layer_norm_shard, out_dtype=_dtype(cfg)),
+                self.mesh, (TOKENS_SPEC, P(), P()), TOKENS_SPEC)(
+                    x, scale, bias)
+        return _layer_norm_xla(x, scale, bias, _dtype(cfg))
 
 
-def _norm(cfg: ModelConfig, name: str):
+def _layer_norm_xla(x, scale, bias, out_dtype):
+    from dalle_tpu.ops.pallas.ln_kernels import _stats
+    xf = x.astype(jnp.float32)
+    mean, rstd = _stats(xf, 1e-6)
+    y = ((xf - mean) * rstd
+         * scale.astype(jnp.float32) + bias.astype(jnp.float32))
+    return y.astype(out_dtype)
+
+
+def _layer_norm_shard(x, scale, bias, *, out_dtype):
+    """One shard's LayerNorm: the kernel where its LOCAL rows tile."""
+    from dalle_tpu.models import attention as attn_mod
+    from dalle_tpu.ops.pallas.ln_kernels import layer_norm, ln_supported
+    m, d = x.shape[0] * x.shape[1], x.shape[-1]
+    ok = ln_supported(m, d)
+    attn_mod.log_kernel_choice(
+        "LayerNorm", ok, f"ln_supported({m} local rows, {d}) is {ok}")
+    if not ok:
+        return _layer_norm_xla(x, scale, bias, out_dtype)
+    y = layer_norm(x.reshape(m, d), scale, bias, 1e-6, 256,
+                   attn_mod._PALLAS_INTERPRET)
+    return y.reshape(x.shape)
+
+
+def _norm(cfg: ModelConfig, name: str, mesh=None):
     """The block norm: fused Pallas LN when ``cfg.ln_fusion``, else flax's
     ``nn.LayerNorm`` — identical {scale, bias} param tree either way."""
     if cfg.ln_fusion:
-        return FusedLayerNorm(cfg, name=name)
+        return FusedLayerNorm(cfg, mesh=mesh, name=name)
     return nn.LayerNorm(dtype=_dtype(cfg), param_dtype=_param_dtype(cfg),
                         name=name)
 
@@ -188,11 +209,15 @@ class GEGLUFeedForward(nn.Module):
     (ops/pallas/geglu_kernels.py): the (B*T, inner) intermediates stay in
     VMEM tiles and backward saves only ``x`` — on a NON-rematted block
     that removes the dominant autodiff residual (PERF.md r3 headroom #1).
-    Shapes the kernel cannot tile fall back to the unfused path.
+    Shapes the kernel cannot tile fall back to the unfused path. With a
+    ``mesh`` of more than one device the kernel runs per shard: token
+    rows over dp/fsdp/sp, the inner dimension over tp with one psum of
+    the partial products.
     """
 
     cfg: ModelConfig
     fuse: bool = False
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -208,22 +233,48 @@ class GEGLUFeedForward(nn.Module):
         wi, wg, wo = wi.astype(cd), wg.astype(cd), wo.astype(cd)
         bi, bg, bo = bi.astype(cd), bg.astype(cd), bo.astype(cd)
         x = x.astype(cd)
-        if self.fuse:
-            # same kernel gating as the attention zoo: real TPU backend,
-            # or interpret mode when tests opt in (models/attention.py)
-            from dalle_tpu.models import attention as attn_mod
-            from dalle_tpu.ops.pallas.geglu_kernels import (geglu_ff,
-                                                            geglu_supported)
-            b, t, _ = x.shape
-            if (attn_mod._pallas_by_default()
-                    and geglu_supported(b * t, d, inner, cd)):
-                out = geglu_ff(x.reshape(b * t, d), wi, wg, wo,
-                               bi, bg, bo,
-                               256, 512, attn_mod._PALLAS_INTERPRET)
-                return out.reshape(b, t, cfg.dim)
-        h = jnp.dot(x, wi) + bi
-        gate = jnp.dot(x, wg) + bg
-        return jnp.dot(h * nn.gelu(gate), wo) + bo
+        # same kernel gating as the attention zoo: real TPU backend, or
+        # interpret mode when tests opt in (models/attention.py)
+        from dalle_tpu.models import attention as attn_mod
+        if self.fuse and attn_mod._pallas_by_default():
+            tp = self.mesh.shape["tp"] if self.mesh is not None else 1
+            return per_shard(
+                functools.partial(_geglu_shard, tp=tp), self.mesh,
+                (TOKENS_SPEC, P(None, "tp"), P(None, "tp"), P("tp", None),
+                 P("tp"), P("tp"), P()), TOKENS_SPEC)(
+                     x, wi, wg, wo, bi, bg, bo)
+        return _geglu_xla(x, wi, wg, wo, bi, bg, bo)
+
+
+def _geglu_xla(x, wi, wg, wo, bi, bg, bo):
+    h = jnp.dot(x, wi) + bi
+    gate = jnp.dot(x, wg) + bg
+    return jnp.dot(h * nn.gelu(gate), wo) + bo
+
+
+def _geglu_shard(x, wi, wg, wo, bi, bg, bo, *, tp: int):
+    """One shard's GEGLU FF: the fused kernel where the LOCAL shapes tile.
+    Under tp each shard holds a slice of the inner dimension, so its
+    output is a partial product: the output bias joins on one shard only
+    and the partials are summed over ``tp``."""
+    from dalle_tpu.models import attention as attn_mod
+    from dalle_tpu.ops.pallas.geglu_kernels import geglu_ff, geglu_supported
+    b, t, d = x.shape
+    inner = wi.shape[1]
+    if tp > 1:
+        bo = jnp.where(jax.lax.axis_index("tp") == 0, bo, jnp.zeros_like(bo))
+    ok = geglu_supported(b * t, d, inner, x.dtype)
+    attn_mod.log_kernel_choice(
+        "GEGLU feed-forward", ok,
+        f"geglu_supported({b * t} local rows, {d}, {inner}, {x.dtype}) "
+        f"is {ok}")
+    if ok:
+        out = geglu_ff(x.reshape(b * t, d), wi, wg, wo, bi, bg, bo,
+                       256, 512, attn_mod._PALLAS_INTERPRET
+                       ).reshape(b, t, d)
+    else:
+        out = _geglu_xla(x, wi, wg, wo, bi, bg, bo)
+    return jax.lax.psum(out, "tp") if tp > 1 else out
 
 
 class TransformerBlock(nn.Module):
@@ -242,11 +293,12 @@ class TransformerBlock(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, rot=None) -> jax.Array:
         cfg = self.cfg
-        h = _norm(cfg, "attn_norm")(x)
+        h = _norm(cfg, "attn_norm", self.mesh)(x)
         x = x + ZooAttention(cfg, self.attn_type, mesh=self.mesh,
                              name="attn")(h, rot)
-        h = _norm(cfg, "ff_norm")(x)
-        x = x + GEGLUFeedForward(cfg, fuse=self.fuse_ff, name="ff")(h)
+        h = _norm(cfg, "ff_norm", self.mesh)(x)
+        x = x + GEGLUFeedForward(cfg, fuse=self.fuse_ff, mesh=self.mesh,
+                                 name="ff")(h)
         return x
 
 
@@ -407,4 +459,4 @@ class Transformer(nn.Module):
                                   name=name)
             x = blocks[uid](x, rot)
 
-        return _norm(cfg, "final_norm")(x)
+        return _norm(cfg, "final_norm", self.mesh)(x)

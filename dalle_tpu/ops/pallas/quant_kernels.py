@@ -30,7 +30,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dalle_tpu.ops.quant import codebook_midpoints, to_blocks
+from dalle_tpu.ops.quant import codebook_midpoints
 
 ROWS_PER_TILE = 8
 
@@ -61,21 +61,20 @@ def _quant_kernel(x_ref, thr_ref, codes_ref, absmax_ref):
     absmax_ref[:] = absmax
 
 
-def quantize_blockwise_pallas(x: jax.Array, block_size: int = 4096,
-                              signed: bool = True,
-                              interpret: bool = False):
-    """(codes uint8 (n_blocks, block), absmax f32 (n_blocks, 1)).
-
-    Same contract as ops.quant.quantize_blockwise's internals; the caller
-    wraps the result in a Quantized. block_size must be a multiple of 128.
-    """
+def quantize_blocks_pallas(blocks: jax.Array, signed: bool = True,
+                           interpret: bool = False):
+    """(codes uint8 (n_blocks, block), absmax f32 (n_blocks, 1)) of rows
+    that are already blocked (``ops.quant.to_blocks``) — the kernel half
+    of ops.quant.quantize_blockwise, which wraps the result in a
+    Quantized. Taking blocked rows lets a sharded caller run it on each
+    device's own rows. The block must be a multiple of 128."""
+    n_blocks, block_size = blocks.shape
     if block_size % 128:
         raise ValueError("block_size must be a multiple of 128")
-    tail = to_blocks(x, block_size)                # shared prologue
-    n_blocks = tail.shape[0]
     # pad rows up to a tile multiple
     rows = -(-n_blocks // ROWS_PER_TILE) * ROWS_PER_TILE
-    blocks = jnp.zeros((rows, block_size), jnp.float32).at[:n_blocks].set(tail)
+    blocks = jnp.pad(blocks.astype(jnp.float32),
+                     ((0, rows - n_blocks), (0, 0)))
 
     thr = jnp.asarray(_thresholds(signed))
     grid = (rows // ROWS_PER_TILE,)
@@ -104,6 +103,12 @@ def quantize_blockwise_pallas(x: jax.Array, block_size: int = 4096,
 WIRE_QBLOCK = 256  # the wire codec's block (compression._QBLOCK) = 2 lanes
 
 
+def _to_u8(q):
+    """Exact small non-negative integers in f32 -> uint8, by way of int32:
+    Mosaic has no direct f32 -> u8 cast ("Unsupported cast")."""
+    return q.astype(jnp.int32).astype(jnp.uint8)
+
+
 def _wire_quant_kernel(x_ref, d_ref, codes_ref, scale_ref):
     """Blockwise symmetric uniform u8 (the swarm wire codec): per 256-elem
     block, scale = absmax/127, code = clip(rint(x/scale), -128, 127)+128.
@@ -119,7 +124,7 @@ def _wire_quant_kernel(x_ref, d_ref, codes_ref, scale_ref):
     scale = absmax / d_ref[0]
     safe = jnp.where(scale > 0, scale, 1.0)
     q = jnp.clip(jnp.rint(x / safe), -128.0, 127.0) + 128.0
-    codes_ref[:] = q.astype(jnp.uint8)
+    codes_ref[:] = _to_u8(q)
     scale_ref[:] = scale
 
 
@@ -148,7 +153,7 @@ def _wire_quant4_kernel(x_ref, d_ref, codes_ref, scale_ref):
     scale = absmax / d_ref[0]
     safe = jnp.where(scale > 0, scale, 1.0)
     q = jnp.clip(jnp.rint(x / safe), -8.0, 7.0) + 8.0
-    codes_ref[:] = q.astype(jnp.uint8)
+    codes_ref[:] = _to_u8(q)
     scale_ref[:] = scale
 
 
@@ -172,8 +177,7 @@ def _wire_quantize_pallas(x: jax.Array, block: int, divisor: float,
     n = flat.shape[0]
     n_blocks = -(-n // block)
     rows = -(-n_blocks // ROWS_PER_TILE) * ROWS_PER_TILE
-    blocks = jnp.zeros((rows, block), jnp.float32).at[:n_blocks].set(
-        jnp.pad(flat, (0, n_blocks * block - n)).reshape(n_blocks, block))
+    blocks = jnp.pad(flat, (0, rows * block - n)).reshape(rows, block)
     codes, scales = pl.pallas_call(
         kernel,
         out_shape=(

@@ -33,6 +33,7 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 DEFAULT_BLOCK = 4096
 
@@ -122,22 +123,38 @@ def _nearest_code(normed: jax.Array, signed: bool) -> jax.Array:
     return jnp.searchsorted(mids, normed, side="left").astype(jnp.uint8)
 
 
+def blocks_spec(mesh, n_blocks: int) -> P:
+    """How a leaf's (n_blocks, ...) quantized codes/absmax lie on the mesh:
+    rows over fsdp when they divide, else replicated. ONE rule for the
+    optimizer-state placement (parallel/sharding.py) and the per-shard
+    quantize kernel below, which must agree."""
+    fsdp = mesh.shape.get("fsdp", 1) if mesh is not None else 1
+    return P("fsdp") if fsdp > 1 and n_blocks % fsdp == 0 else P()
+
+
 def quantize_blockwise(x: jax.Array, block_size: int = DEFAULT_BLOCK,
                        signed: bool = True,
-                       use_pallas: Optional[bool] = None) -> Quantized:
+                       use_pallas: Optional[bool] = None,
+                       mesh=None, interpret: bool = False) -> Quantized:
     """Block-quantize ``x``. ``use_pallas=None`` auto-selects the Pallas VPU
-    kernel on TPU when the block size tiles lanes (multiple of 128)."""
+    kernel on TPU when the block size tiles lanes (multiple of 128). With a
+    ``mesh`` of more than one device the kernel runs per shard of the
+    block rows (:func:`blocks_spec`); the blocking prologue stays in XLA."""
     shape = tuple(x.shape)
     if use_pallas is None:
         use_pallas = (jax.default_backend() == "tpu"
                       and block_size % 128 == 0)
+    blocks = to_blocks(x, block_size)
     if use_pallas:
-        from dalle_tpu.ops.pallas.quant_kernels import quantize_blockwise_pallas
-        codes, absmax = quantize_blockwise_pallas(
-            x, block_size, signed=signed)
+        from dalle_tpu.ops.pallas.quant_kernels import quantize_blocks_pallas
+        from dalle_tpu.parallel.mesh import per_shard
+        spec = blocks_spec(mesh, blocks.shape[0])
+        codes, absmax = per_shard(
+            functools.partial(quantize_blocks_pallas, signed=signed,
+                              interpret=interpret),
+            mesh, (spec,), (spec, spec))(blocks)
         return Quantized(codes=codes, absmax=absmax, shape=shape,
                          signed=signed)
-    blocks = to_blocks(x, block_size)
     absmax = jnp.max(jnp.abs(blocks), axis=1, keepdims=True)
     scale = jnp.where(absmax > 0, absmax, 1.0)
     normed = blocks / scale

@@ -24,9 +24,12 @@ from dalle_tpu.optim.lamb8bit import (  # noqa: F401
 )
 
 
-def make_optimizer(cfg: OptimizerConfig) -> optax.GradientTransformation:
+def make_optimizer(cfg: OptimizerConfig,
+                   mesh=None) -> optax.GradientTransformation:
+    """``mesh``: the device mesh the train state lives on (None = one
+    device); the 8-bit state's quantize kernel runs per shard of it."""
     if cfg.state_bits == 8:
-        return make_optimizer_8bit(cfg)
+        return make_optimizer_8bit(cfg, mesh=mesh)
     if cfg.state_bits == 32:
         return make_optimizer_fp32(cfg)
     raise ValueError(f"unsupported state_bits={cfg.state_bits}")

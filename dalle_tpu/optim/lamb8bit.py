@@ -63,11 +63,15 @@ def lamb8bit(learning_rate: ScalarOrSchedule,
              min_8bit_size: int = 65536,
              wd_mask_fn: Callable[[Any], Any] = default_wd_mask,
              stacked_reps: Optional[int] = None,
+             mesh=None,
              ) -> optax.GradientTransformation:
+    """``mesh``: the device mesh the train state is sharded over; the
+    quantize kernel then runs per shard of it (ops/quant.py)."""
 
     def _quantize_moment(x: jax.Array, signed: bool):
         if x.size >= min_8bit_size:
-            return quantize_blockwise(x, block_size, signed=signed)
+            return quantize_blockwise(x, block_size, signed=signed,
+                                      mesh=mesh)
         return x
 
     def _dequantize_moment(m) -> jax.Array:
@@ -124,13 +128,15 @@ def lamb8bit(learning_rate: ScalarOrSchedule,
     return optax.GradientTransformation(init_fn, update_fn)
 
 
-def make_optimizer_8bit(cfg: OptimizerConfig) -> optax.GradientTransformation:
+def make_optimizer_8bit(cfg: OptimizerConfig,
+                        mesh=None) -> optax.GradientTransformation:
     return lamb8bit(
         learning_rate=make_lr_schedule(cfg),
         b1=cfg.beta1, b2=cfg.beta2, eps=cfg.eps,
         weight_decay=cfg.weight_decay, clamp_value=cfg.clamp_value,
         max_grad_norm=cfg.max_grad_norm, block_size=cfg.block_size,
-        min_8bit_size=cfg.min_8bit_size, stacked_reps=cfg.stacked_reps)
+        min_8bit_size=cfg.min_8bit_size, stacked_reps=cfg.stacked_reps,
+        mesh=mesh)
 
 
 def optimizer_state_bytes(state) -> int:

@@ -21,6 +21,11 @@ AXES = ("dp", "fsdp", "tp", "sp")
 # Batch is sharded over every data-like axis; dp and fsdp both consume
 # examples, so the global batch must divide dp*fsdp.
 BATCH_SPEC = P(("dp", "fsdp"))
+# Per-shard layouts of the operands the Mosaic kernels see (per_shard):
+# (B, T, H, d) attention heads split over tp; (B, T, dim) block activations
+# are row-wise work, so the token axis may stay split over sp.
+HEADS_SPEC = P(("dp", "fsdp"), None, "tp", None)
+TOKENS_SPEC = P(("dp", "fsdp"), "sp", None)
 
 
 def make_mesh(dp: int = -1, fsdp: int = 1, tp: int = 1, sp: int = 1,
@@ -41,6 +46,24 @@ def make_mesh(dp: int = -1, fsdp: int = 1, tp: int = 1, sp: int = 1,
             f"mesh {dp}x{fsdp}x{tp}x{sp} != device count {n}")
     arr = np.asarray(devices).reshape(dp, fsdp, tp, sp)
     return Mesh(arr, AXES)
+
+
+def per_shard(fn, mesh: Optional[Mesh], in_specs, out_specs):
+    """``fn`` run once per device on its own shard of the operands.
+
+    GSPMD cannot partition a Mosaic kernel (the lowering raises "Mosaic
+    kernels cannot be automatically partitioned" for any jit that spans
+    more than one device), so every Pallas call site goes through here:
+    ``shard_map`` manual over ALL mesh axes, which is the one context the
+    lowering accepts. On one device (or with no mesh) it is ``fn`` itself.
+    Replication is not type-checked (``check_vma=False``: ``pallas_call``
+    carries no varying-axes annotation); the per-kernel parity tests on
+    the 8-device mesh are the check.
+    """
+    if mesh is None or mesh.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def batch_sharding(mesh: Mesh) -> NamedSharding:

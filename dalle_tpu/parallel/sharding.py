@@ -108,20 +108,17 @@ def opt_state_shardings(mesh: Mesh, opt_state, params) -> Any:
     Replicating fp32 moments — the largest tensors in training — on every
     chip would defeat FSDP and negate the memory point of 8-bit state.
     """
-    from dalle_tpu.ops.quant import Quantized
+    from dalle_tpu.ops.quant import Quantized, blocks_spec
 
     rep = NamedSharding(mesh, P())
     pshards = param_shardings(mesh, params)
     ptreedef = jax.tree.structure(params)
-    fsdp = mesh.shape.get("fsdp", 1)
 
     def _is_q(x) -> bool:
         return isinstance(x, Quantized)
 
     def _quantized_shardings(q: Quantized) -> Quantized:
-        blocks = NamedSharding(
-            mesh,
-            P("fsdp") if fsdp > 1 and q.codes.shape[0] % fsdp == 0 else P())
+        blocks = NamedSharding(mesh, blocks_spec(mesh, q.codes.shape[0]))
         return Quantized(codes=blocks, absmax=blocks,
                          shape=q.shape, signed=q.signed)
 

@@ -228,12 +228,23 @@ def _encode_u4(flat: jax.Array):
 
 def flatten_device(tensors: Sequence) -> jax.Array:
     """Device-side flatten_tensors: one jitted concat, no host pull.
-    Accepts a mix of device and host arrays (host leaves are pushed)."""
+    Accepts a mix of device and host arrays (host leaves are pushed).
+
+    The flat vector lands on ONE device. A peer that trains over several
+    chips hands over gradients replicated or sharded across its mesh, but
+    the wire codec feeds one host NIC: every codec program downstream
+    (slice, quantize kernel, dequantize, accumulate) is a single-device
+    program, which is also the only way the Mosaic quantizers can run
+    outside shard_map."""
     leaves = [jnp.asarray(np.asarray(t)) if not isinstance(t, jax.Array)
               else t for t in tensors]
     if not leaves:
         return jnp.zeros((0,), jnp.float32)
-    return _concat_f32(leaves)
+    flat = _concat_f32(leaves)
+    if len(flat.sharding.device_set) > 1:
+        flat = jax.device_put(flat, min(flat.sharding.device_set,
+                                        key=lambda d: d.id))
+    return flat
 
 
 # -- single-buffer wire codec (registry entries) -------------------------

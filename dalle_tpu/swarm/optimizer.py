@@ -768,6 +768,7 @@ class CollaborativeOptimizer:
         pending.timings.setdefault("allreduce_s", 0.0)
         self.last_timings = {
             **pending.timings, **self._apply_timings,
+            "group_size": pending.group_size,
             "overlapped_steps": pending.overlapped_steps,
             "hidden_s": round(pending.hidden_s, 4),
             "round_hops": pending.hop_progress(),
@@ -954,6 +955,7 @@ class CollaborativeOptimizer:
             "allreduce_s": round(t_reduce - t_match - max(
                 0.0, pull_s - (t_pull - t0)), 4),
             **self._apply_timings,
+            "group_size": group.size if group else 1,
             "robust": self.robustness_snapshot(),
         }
         logger.info("global step -> epoch %d (%.2fs, group=%s, %s)",

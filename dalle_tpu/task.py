@@ -153,9 +153,7 @@ class TrainingTask:
     @functools.cached_property
     def model(self):
         from dalle_tpu.models.dalle import DALLE
-        mesh = (self.mesh
-                if self.model_cfg.sequence_parallel != "none" else None)
-        return DALLE(self.model_cfg, mesh=mesh)
+        return DALLE(self.model_cfg, mesh=self.mesh)
 
     @functools.cached_property
     def tx(self):
@@ -168,7 +166,7 @@ class TrainingTask:
         if cfg.stacked_reps is None:
             cfg = dataclasses.replace(
                 cfg, stacked_reps=self.model_cfg.dense_scan_reps())
-        return make_optimizer(cfg)
+        return make_optimizer(cfg, mesh=self.mesh)
 
     @functools.cached_property
     def train_state(self):

@@ -24,11 +24,9 @@ import traceback
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-import jax  # noqa: E402
+from dalle_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-jax.config.update("jax_use_direct_linearize", False)
+enable_compile_cache()
 
 from bench import _bench, _is_oom  # noqa: E402
 from dalle_tpu.config import flagship_model_config  # noqa: E402

@@ -132,3 +132,43 @@ class TestModelIntegration:
         assert ff["wi"]["bias"].shape == (inner,)
         assert ff["gate"]["bias"].shape == (inner,)
         assert ff["wo"]["bias"].shape == (cfg.dim,)
+
+
+def test_per_shard_kernel_matches_single_device(monkeypatch):
+    """On a dp=2 x fsdp=2 x tp=2 mesh the fused FF runs per shard — token
+    rows over the batch axes, the inner dimension over tp with one psum
+    of the partial products and the output bias added once: values and
+    gradients must equal the unwrapped one-device kernel."""
+    from dalle_tpu.config import flagship_model_config
+    from dalle_tpu.models import attention
+    from dalle_tpu.models.transformer import GEGLUFeedForward
+    from dalle_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    cfg = flagship_model_config(dim=128, heads=2, head_dim=64,
+                                dtype="float32")
+    mesh = make_mesh(dp=2, fsdp=2, tp=2)
+    x = jax.random.normal(jax.random.PRNGKey(0), (4, 128, 128)) * 0.5
+    w = jax.random.normal(jax.random.PRNGKey(1), x.shape)
+    params = GEGLUFeedForward(cfg, fuse=True).init(jax.random.PRNGKey(2), x)
+    # zero-init biases would hide a bias added tp times (or never)
+    params = jax.tree.map(
+        lambda p: p + 0.1 * jax.random.normal(jax.random.PRNGKey(3),
+                                              p.shape), params)
+
+    def loss(mesh_):
+        ff = GEGLUFeedForward(cfg, fuse=True, mesh=mesh_)
+
+        def f(p, x):
+            out = ff.apply(p, x)
+            return jnp.sum(out * w), out
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
+
+    (_, out_m), g_m = loss(mesh)(params, x)
+    (_, out_1), g_1 = loss(None)(params, x)
+    assert len(out_m.sharding.device_set) == 8
+    np.testing.assert_allclose(np.asarray(out_m), np.asarray(out_1),
+                               rtol=1e-4, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(g_m), jax.tree.leaves(g_1)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)

@@ -150,3 +150,41 @@ class TestModelIntegration:
         np.testing.assert_allclose(np.asarray(y, np.float32),
                                    np.asarray(ref, np.float32),
                                    rtol=1e-6, atol=1e-6)
+
+
+def test_per_shard_kernel_matches_single_device(monkeypatch):
+    """On a dp=2 x fsdp=2 x tp=2 mesh the LayerNorm kernel runs per shard
+    of the token rows; the scale/bias gradients are sums over shards.
+    Values and gradients must equal the unwrapped one-device kernel."""
+    from dalle_tpu.config import flagship_model_config
+    from dalle_tpu.models import attention
+    from dalle_tpu.models.transformer import FusedLayerNorm
+    from dalle_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    cfg = flagship_model_config(dim=128, heads=2, head_dim=64,
+                                dtype="float32")
+    mesh = make_mesh(dp=2, fsdp=2, tp=2)
+    x = jax.random.normal(jax.random.PRNGKey(0), (4, 128, 128)) * 2.0
+    w = jax.random.normal(jax.random.PRNGKey(1), x.shape)
+    params = {"params": {
+        "scale": 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(2),
+                                               (128,)),
+        "bias": 0.1 * jax.random.normal(jax.random.PRNGKey(3), (128,))}}
+
+    def loss(mesh_):
+        ln = FusedLayerNorm(cfg, mesh=mesh_)
+
+        def f(p, x):
+            out = ln.apply(p, x)
+            return jnp.sum(out * w), out
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
+
+    (_, out_m), g_m = loss(mesh)(params, x)
+    (_, out_1), g_1 = loss(None)(params, x)
+    assert len(out_m.sharding.device_set) == 8
+    np.testing.assert_allclose(np.asarray(out_m), np.asarray(out_1),
+                               rtol=1e-5, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(g_m), jax.tree.leaves(g_1)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)

@@ -170,3 +170,38 @@ class TestRematPolicyPinsKernelReplay:
         # save_ctx: 2 per call site (fwd, bwd) -> ratio exactly 2/3
         assert pruned < base, (base, pruned)
         assert pruned * 3 == base * 2, (base, pruned)
+
+
+# one line kernel (with the in-XLA column reorder) and one window kernel:
+# the wrapper is the same for every zoo type
+@pytest.mark.parametrize("attn_type", [ATTN_AXIAL_COL, "conv_like"])
+def test_per_shard_kernels_match_single_device(attn_type, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel, so on a mesh the dispatcher
+    runs the fused kernels per shard (batch over dp x fsdp, heads over
+    tp): values and gradients must equal the unwrapped one-device call."""
+    from dalle_tpu.models import attention
+    from dalle_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    mesh = make_mesh(dp=2, fsdp=2, tp=2)
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    shape = (4, TEXT + GRID * GRID, 4, D)
+    q, k, v, w = (jax.random.normal(kk, shape, jnp.float32) for kk in ks)
+
+    def loss(mesh_):
+        def f(q, k, v):
+            out = attention.zoo_attention(
+                q, k, v, attn_type=attn_type, text_len=TEXT, grid=GRID,
+                conv_kernel=3, mesh=mesh_)
+            return jnp.sum(out * w), out
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    (_, out_m), g_m = loss(mesh)(q, k, v)
+    (_, out_1), g_1 = loss(None)(q, k, v)
+    assert len(out_m.sharding.device_set) == 8
+    np.testing.assert_allclose(np.asarray(out_m), np.asarray(out_1),
+                               rtol=1e-5, atol=1e-6)
+    for a, b in zip(g_m, g_1):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)

@@ -154,19 +154,40 @@ class TestLamb8bit:
 
 class TestPallasKernel:
     def test_matches_pure_jax_exactly(self):
-        from dalle_tpu.ops.pallas.quant_kernels import quantize_blockwise_pallas
         x = jax.random.normal(jax.random.PRNGKey(0), (10_000,))
         for signed in (True, False):
             data = x if signed else jnp.abs(x)
-            ref = quantize_blockwise(data, 4096, signed=signed)
-            codes, absmax = quantize_blockwise_pallas(
-                data, 4096, signed=signed, interpret=True)
-            np.testing.assert_array_equal(np.asarray(codes),
+            ref = quantize_blockwise(data, 4096, signed=signed,
+                                     use_pallas=False)
+            got = quantize_blockwise(data, 4096, signed=signed,
+                                     use_pallas=True, interpret=True)
+            np.testing.assert_array_equal(np.asarray(got.codes),
                                           np.asarray(ref.codes))
-            np.testing.assert_allclose(np.asarray(absmax),
+            np.testing.assert_allclose(np.asarray(got.absmax),
                                        np.asarray(ref.absmax))
 
     def test_rejects_bad_block(self):
-        from dalle_tpu.ops.pallas.quant_kernels import quantize_blockwise_pallas
+        from dalle_tpu.ops.pallas.quant_kernels import quantize_blocks_pallas
         with pytest.raises(ValueError):
-            quantize_blockwise_pallas(jnp.zeros(100), block_size=100)
+            quantize_blocks_pallas(jnp.zeros((1, 100)))
+
+    @pytest.mark.parametrize("n_blocks", [6, 5])
+    def test_per_shard_kernel_matches_single_device(self, n_blocks):
+        """On a dp=2 x fsdp=2 x tp=2 mesh the quantize kernel runs per
+        shard of the block rows — over fsdp when the rows divide (6),
+        replicated when they do not (5) — and lands exactly where
+        opt_state_shardings places the codes. Byte-equal to one device."""
+        from dalle_tpu.ops.quant import blocks_spec
+        from dalle_tpu.parallel.mesh import make_mesh
+
+        mesh = make_mesh(dp=2, fsdp=2, tp=2)
+        x = jax.random.normal(jax.random.PRNGKey(1), (n_blocks * 512 - 7,))
+        quant = jax.jit(lambda x, mesh_: quantize_blockwise(
+            x, 512, use_pallas=True, interpret=True, mesh=mesh_),
+            static_argnums=1)
+        got, ref = quant(x, mesh), quant(x, None)
+        assert got.codes.sharding.spec == blocks_spec(mesh, n_blocks)
+        np.testing.assert_array_equal(np.asarray(got.codes),
+                                      np.asarray(ref.codes))
+        np.testing.assert_array_equal(np.asarray(got.absmax),
+                                      np.asarray(ref.absmax))
