@@ -1,0 +1,81 @@
+"""BENCHMARK.json and the data files it names.
+
+Everything that belongs to one configuration, one traffic mix or one
+per-layer metric is a file of its own, found by the name in
+``BENCHMARK.json``:
+
+- configuration ``<c>``  -> the entry's ``file`` (``benchmark/configs/<c>.json``)
+- traffic mix ``<t>``    -> ``benchmark/traffic/<t>.json``
+- per-layer metric ``<m>`` -> ``benchmark/layer_metrics/<m>.json``, which
+  names a reducer ``<r>`` -> ``benchmark/reducers/<r>.py`` (``read(ctx, **params)``)
+
+so a later PR adds a cell by adding files and one entry, and edits nothing.
+"""
+
+from __future__ import annotations
+
+import importlib
+import json
+import re
+from pathlib import Path
+from typing import Any, Callable, Dict, List, Optional
+
+ROOT = Path(__file__).resolve().parent.parent
+HERE = Path(__file__).resolve().parent
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+
+
+def _load(path: Path) -> Dict[str, Any]:
+    with open(path) as f:
+        return json.load(f)
+
+
+class Cell:
+    """One entry of ``workloads`` with its files resolved."""
+
+    def __init__(self, manifest: "Manifest", entry: Dict[str, Any]):
+        self.name: str = entry["name"]
+        self.chips: int = entry["chips"]
+        self.config_name: str = entry["config"]
+        self.traffic_name: str = entry["traffic"]
+        cfg_entry = manifest.configs[self.config_name]
+        self.config = _load(manifest.root / cfg_entry["file"])
+        self.traffic = _load(manifest.traffic_file(self.traffic_name))
+        self.end_to_end: List[Dict[str, Any]] = [
+            m for m in manifest.data["end_to_end"] if self._has(m)]
+        self.per_layer: List[Dict[str, Any]] = [
+            dict(m, **_load(manifest.metric_file(m["name"])))
+            for m in manifest.data["per_layer"] if self._has(m)]
+
+    def _has(self, metric: Dict[str, Any]) -> bool:
+        return "workloads" not in metric or self.name in metric["workloads"]
+
+
+class Manifest:
+    def __init__(self, root: Path = ROOT):
+        self.root = Path(root)
+        self.data = _load(self.root / "BENCHMARK.json")
+        self.configs = {c["name"]: c for c in self.data["configs"]}
+        self.cells = {w["name"]: w for w in self.data["workloads"]}
+        self.dir = self.root / self.data["paths"][0]
+
+    def traffic_file(self, name: str) -> Path:
+        return self.dir / "traffic" / f"{name}.json"
+
+    def metric_file(self, name: str) -> Path:
+        return self.dir / "layer_metrics" / f"{name}.json"
+
+    def cell(self, name: str) -> Cell:
+        if name not in self.cells:
+            raise KeyError(f"no workload {name!r} in BENCHMARK.json; "
+                           f"have {sorted(self.cells)}")
+        return Cell(self, self.cells[name])
+
+
+def reducer(name: str) -> Callable[..., Optional[float]]:
+    """``benchmark/reducers/<name>.py``'s ``read``."""
+    if not NAME.match(name):
+        raise ValueError(f"bad reducer name {name!r}")
+    return importlib.import_module(f"benchmark.reducers.{name}").read
