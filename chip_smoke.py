@@ -154,25 +154,6 @@ class _Records(logging.Handler):
         self.records.append(record)
 
 
-class _CompileLog:
-    """jax.monitoring sink: backend-compile seconds per jitted program and
-    persistent-cache hits. Listeners cannot be unregistered one by one, so
-    it is switched off instead."""
-
-    def __init__(self):
-        self.active = True
-        self.compile_s = collections.defaultdict(float)
-        self.cache_hits = 0
-
-    def on_duration(self, event: str, seconds: float, **kw) -> None:
-        if self.active and event.endswith("backend_compile_duration"):
-            self.compile_s[kw.get("fun_name", "?")] += seconds
-
-    def on_event(self, event: str, **kw) -> None:
-        if self.active and event.endswith("compilation_cache/cache_hits"):
-            self.cache_hits += 1
-
-
 def _free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
@@ -296,11 +277,6 @@ def run_smoke(out_dir: Path, *, preset: str = "flagship",
         print(f"jax {jax.__version__} device {device} "
               f"compile cache {cache_dir}", flush=True)
 
-        compiles = _CompileLog()
-        jax.monitoring.register_event_duration_secs_listener(
-            compiles.on_duration)
-        jax.monitoring.register_event_listener(compiles.on_event)
-        cleanup.callback(setattr, compiles, "active", False)
         warnings = _Records(logging.WARNING)
         loop_log = _Records(logging.INFO)
         for name, handler in (("dalle_tpu", warnings),
@@ -354,11 +330,17 @@ def run_smoke(out_dir: Path, *, preset: str = "flagship",
                  if str(r.msg).startswith("warmup %d/%d")]
         print(f"warmup grad steps (first compiles) {steps} s; "
               f"whole run {run_s:.1f} s")
-        for name, secs in sorted(compiles.compile_s.items(),
-                                 key=lambda kv: -kv[1]):
-            if secs >= 1.0:
-                print(f"compile {name}: {secs:.1f} s")
-        print(f"persistent compile cache hits: {compiles.cache_hits}")
+        # the trainer's own compile counter (dalle_tpu/obs/compiles.py),
+        # counting since run_trainer built its task
+        from dalle_tpu.obs import compiles
+        counted = compiles.installed().snapshot()
+        for name, row in sorted(counted["by_program"].items(),
+                                key=lambda kv: -kv[1]["compile_s"]):
+            if row["compile_s"] >= 1.0:
+                print(f"compile {name}: {row['compile_s']:.1f} s")
+        print("persistent compile cache hits: "
+              f"{counted['total']['cache_hits']}; compiles after the first "
+              f"step: {[c[0] for c in counted['after_first_step']]}")
         peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
                  for d in devices]
         print(f"peak_bytes_in_use per device: {peaks}")

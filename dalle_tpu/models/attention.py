@@ -352,10 +352,12 @@ def window_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def zoo_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   attn_type: str, text_len: int, grid: int,
-                  conv_kernel: int = 11, mesh=None) -> jax.Array:
+                  conv_kernel: int = 11, mesh=None,
+                  scope: Optional[str] = None) -> jax.Array:
     """Train-time attention dispatch: fast paths where available. With a
     ``mesh`` of more than one device the fused kernels run per shard
-    (batch over dp/fsdp, heads over tp; parallel/mesh.per_shard)."""
+    (batch over dp/fsdp, heads over tp; parallel/mesh.per_shard), under
+    the caller's ``scope`` so that they keep its name there."""
     if not _pallas_by_default():
         if attn_type in (ATTN_AXIAL_ROW, ATTN_AXIAL_COL):
             return axial_attention(q, k, v, attn_type, text_len, grid,
@@ -365,7 +367,8 @@ def zoo_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     fused = functools.partial(_fused_zoo_attention, attn_type=attn_type,
                               text_len=text_len, grid=grid,
                               conv_kernel=conv_kernel)
-    return per_shard(fused, mesh, (HEADS_SPEC,) * 3, HEADS_SPEC)(q, k, v)
+    return per_shard(fused, mesh, (HEADS_SPEC,) * 3, HEADS_SPEC,
+                     scope=scope)(q, k, v)
 
 
 def _fused_zoo_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

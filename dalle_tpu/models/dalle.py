@@ -32,6 +32,14 @@ from dalle_tpu.config import ModelConfig
 from dalle_tpu.models.transformer import Transformer
 
 
+# Device scopes (``jax.named_scope``) for what no flax module names: they
+# put ``embed`` / ``head`` / ``ce`` into the scope path of the operations
+# they enclose, in the lowered program and in a profiler trace
+# (``run_trainer --profile-dir``). Metadata only: the compiled program is
+# the same.
+EMBED_SCOPE, HEAD_SCOPE, CE_SCOPE = "embed", "head", "ce"
+
+
 def _segment_nll(h: jax.Array, table: jax.Array, targets: jax.Array,
                  head_chunk: int = 0) -> jax.Array:
     """Per-token negative log-likelihood of ``targets`` under the tied-head
@@ -44,17 +52,20 @@ def _segment_nll(h: jax.Array, table: jax.Array, targets: jax.Array,
     """
     v = table.shape[0]
     if head_chunk <= 0 or v <= head_chunk:
-        logits = jnp.einsum("btd,vd->btv", h, table.astype(h.dtype),
-                            preferred_element_type=jnp.float32)
-        return -jnp.take_along_axis(
-            jax.nn.log_softmax(logits, axis=-1),
-            targets[..., None], axis=-1)[..., 0]
+        with jax.named_scope(HEAD_SCOPE):
+            logits = jnp.einsum("btd,vd->btv", h, table.astype(h.dtype),
+                                preferred_element_type=jnp.float32)
+        with jax.named_scope(CE_SCOPE):
+            return -jnp.take_along_axis(
+                jax.nn.log_softmax(logits, axis=-1),
+                targets[..., None], axis=-1)[..., 0]
 
     # the target logit, without the full logits tensor: gather the target
     # rows of the table and contract against h
-    tgt_rows = jnp.take(table, targets, axis=0).astype(h.dtype)  # (B,T,D)
-    target_logit = jnp.einsum("btd,btd->bt", h, tgt_rows,
-                              preferred_element_type=jnp.float32)
+    with jax.named_scope(HEAD_SCOPE):
+        tgt_rows = jnp.take(table, targets, axis=0).astype(h.dtype)  # B,T,D
+        target_logit = jnp.einsum("btd,btd->bt", h, tgt_rows,
+                                  preferred_element_type=jnp.float32)
 
     pad = (-v) % head_chunk
     tbl = jnp.pad(table, ((0, pad), (0, 0))) if pad else table
@@ -68,20 +79,23 @@ def _segment_nll(h: jax.Array, table: jax.Array, targets: jax.Array,
     def body(carry, xs):
         m, l = carry
         chunk, valid = xs
-        s = jnp.einsum("btd,vd->btv", h, chunk,
-                       preferred_element_type=jnp.float32)
-        s = jnp.where(valid[None, None], s, -jnp.inf)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        l = l * jnp.exp(m - m_new) + jnp.sum(
-            jnp.exp(s - m_new[..., None]), axis=-1)
+        with jax.named_scope(HEAD_SCOPE):
+            s = jnp.einsum("btd,vd->btv", h, chunk,
+                           preferred_element_type=jnp.float32)
+        with jax.named_scope(CE_SCOPE):
+            s = jnp.where(valid[None, None], s, -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            l = l * jnp.exp(m - m_new) + jnp.sum(
+                jnp.exp(s - m_new[..., None]), axis=-1)
         return (m_new, l), None
 
     b, t = h.shape[0], h.shape[1]
     m0 = jnp.full((b, t), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b, t), jnp.float32)
     (m, l), _ = jax.lax.scan(body, (m0, l0), (chunks, valid0))
-    lse = m + jnp.log(l)
-    return lse - target_logit
+    with jax.named_scope(CE_SCOPE):
+        lse = m + jnp.log(l)
+        return lse - target_logit
 
 
 class DALLE(nn.Module):
@@ -143,27 +157,30 @@ class DALLE(nn.Module):
         shifted so position p holds the token preceding S_p.
         """
         cfg = self.cfg
-        x = jnp.take(self.token_emb, input_ids, axis=0)
-        x = x + self.positional()[None]
-        x = x.astype(jnp.dtype(cfg.dtype))
+        with jax.named_scope(EMBED_SCOPE):
+            x = jnp.take(self.token_emb, input_ids, axis=0)
+            x = x + self.positional()[None]
+            x = x.astype(jnp.dtype(cfg.dtype))
         return self.transformer(x)
 
     def logits_from_hidden(self, h: jax.Array) -> jax.Array:
         """Tied-embedding head + segment masking, in float32."""
         cfg = self.cfg
-        if cfg.tied_embeddings:
-            table = self.token_emb[: cfg.vocab_total].astype(h.dtype)
-            logits = jnp.einsum("btd,vd->btv", h, table,
-                                preferred_element_type=jnp.float32)
-        else:
-            logits = self.lm_head(h).astype(jnp.float32)
-        # Text positions predict text ids; image positions image ids.
-        t = h.shape[1]
-        is_text_pos = (jnp.arange(t) < cfg.text_seq_len)[None, :, None]
-        is_text_vocab = (jnp.arange(cfg.vocab_total) < cfg.vocab_text)[
-            None, None, :]
-        valid = jnp.logical_not(jnp.logical_xor(is_text_pos, is_text_vocab))
-        return jnp.where(valid, logits, -1e9)
+        with jax.named_scope(HEAD_SCOPE):
+            if cfg.tied_embeddings:
+                table = self.token_emb[: cfg.vocab_total].astype(h.dtype)
+                logits = jnp.einsum("btd,vd->btv", h, table,
+                                    preferred_element_type=jnp.float32)
+            else:
+                logits = self.lm_head(h).astype(jnp.float32)
+            # Text positions predict text ids; image positions image ids.
+            t = h.shape[1]
+            is_text_pos = (jnp.arange(t) < cfg.text_seq_len)[None, :, None]
+            is_text_vocab = (jnp.arange(cfg.vocab_total) < cfg.vocab_text)[
+                None, None, :]
+            valid = jnp.logical_not(
+                jnp.logical_xor(is_text_pos, is_text_vocab))
+            return jnp.where(valid, logits, -1e9)
 
     def __call__(self, text_tokens: jax.Array, image_tokens: jax.Array,
                  loss_mask: Optional[jax.Array] = None,
@@ -185,9 +202,10 @@ class DALLE(nn.Module):
             # the untied head must be trained through the same lm_head the
             # eval/decode path reads, so it takes the full-vocab route
             logits = self.logits_from_hidden(h)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            token_ll = jnp.take_along_axis(
-                logp, labels[..., None], axis=-1)[..., 0]
+            with jax.named_scope(CE_SCOPE):
+                logp = jax.nn.log_softmax(logits, axis=-1)
+                token_ll = jnp.take_along_axis(
+                    logp, labels[..., None], axis=-1)[..., 0]
             nll = -token_ll
             nll_text = nll[:, : cfg.text_seq_len]
             nll_img = nll[:, cfg.text_seq_len:]

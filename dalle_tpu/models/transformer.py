@@ -101,7 +101,7 @@ class ZooAttention(nn.Module):
             out = zoo_attention(
                 q, k, v, attn_type=self.attn_type, text_len=cfg.text_seq_len,
                 grid=cfg.image_grid, conv_kernel=cfg.conv_kernel,
-                mesh=self.mesh)
+                mesh=self.mesh, scope=self.name)
         # (the attention output is named for the remat save-policies at
         # its source: "attn_out"/"attn_stats" inside the Pallas kernels'
         # custom_vjp fwd rules, "attn_ctx" on the dense/axial XLA paths —
@@ -144,8 +144,8 @@ class FusedLayerNorm(nn.Module):
                 and x.dtype == jnp.dtype(_dtype(cfg))):
             return per_shard(
                 functools.partial(_layer_norm_shard, out_dtype=_dtype(cfg)),
-                self.mesh, (TOKENS_SPEC, P(), P()), TOKENS_SPEC)(
-                    x, scale, bias)
+                self.mesh, (TOKENS_SPEC, P(), P()), TOKENS_SPEC,
+                scope=self.name)(x, scale, bias)
         return _layer_norm_xla(x, scale, bias, _dtype(cfg))
 
 
@@ -241,7 +241,7 @@ class GEGLUFeedForward(nn.Module):
             return per_shard(
                 functools.partial(_geglu_shard, tp=tp), self.mesh,
                 (TOKENS_SPEC, P(None, "tp"), P(None, "tp"), P("tp", None),
-                 P("tp"), P("tp"), P()), TOKENS_SPEC)(
+                 P("tp"), P("tp"), P()), TOKENS_SPEC, scope=self.name)(
                      x, wi, wg, wo, bi, bg, bo)
         return _geglu_xla(x, wi, wg, wo, bi, bg, bo)
 

@@ -1,15 +1,24 @@
-"""Swarm flight recorder: protocol-id span tracing for both planes.
+"""Swarm flight recorder: protocol-id span tracing for every plane.
 
 The soak gates can tell you *that* a run went red; until now nothing
 could tell you which phase of which round on which peer stalled or
 diverged first — the only evidence was counters and interleaved log
 lines. This module is the missing layer: monotonic-clock spans whose
 trace ids are **protocol ids** (swarm ``{prefix}:{epoch}`` round ids,
-state-transfer nonces, serving request ids), so per-peer span files
+state-transfer nonces, serving request ids, the trainer's ``setup`` and
+``step:<n>``), so per-peer span files
 merge into one cross-peer round timeline with no clock synchronization
 at all. Wall clocks never enter a trace id; within one peer the
 monotonic ``t0`` orders spans, across peers the protocol id does — the
 same shared-round-id determinism the r14 audit challenge exploits.
+
+A live span (``with tracer.span(...)``) is one flight-ring row AND, when
+the tracer was given an annotation factory (``jax.profiler.
+TraceAnnotation``, injected by the entry point: this module never imports
+JAX), one ``<plane>/<phase>`` host event in whatever profiler session is
+running. The row's clock reads enclose the annotation, so the row mapped
+onto the profiler's clock holds its event. Rows recorded from
+pre-measured walls (:meth:`Tracer.add`) have no event.
 
 Three consumers share one :class:`Tracer`:
 
@@ -36,12 +45,13 @@ enforces; a hot-path JSONL sink is the pattern that rule exists for).
 
 Span row schema (one JSON object per line; OBSERVABILITY.md):
 
-``{"v": 1, "peer": str, "plane": "swarm"|"serving", "phase": str,
-"trace": str, "t0": float, "dur_s": float, "a": {...}}``
+``{"v": 1, "peer": str, "plane": "swarm"|"serving"|"train", "phase": str,
+"trace": str, "t0": float, "dur_s": float, "parent": str, "a": {...}}``
 
-``t0`` is this peer's ``time.monotonic()`` at span start — meaningful
+``t0`` is this peer's ``time.perf_counter()`` at span start — meaningful
 only relative to other spans from the SAME peer. Events are spans with
-``dur_s == 0``.
+``dur_s == 0``. ``parent`` (optional) is the phase of the span that was
+open on the recording thread: the span that caused this one.
 """
 
 from __future__ import annotations
@@ -50,9 +60,12 @@ import json
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 SCHEMA_VERSION = 1
+
+#: the ``trace`` of a row recorded with none given and no span open
+NO_TRACE = "-"
 
 #: log-spaced latency buckets (seconds) for the per-phase histograms —
 #: the Prometheus ``le`` edges; one implicit +Inf bucket follows.
@@ -88,21 +101,33 @@ NULL_SPAN = _NullSpan()
 
 class _Span:
     """A live span: records on ``__exit__`` (errors annotate, never
-    swallow). ``set(**attrs)`` attaches attributes mid-flight."""
+    swallow). ``set(**attrs)`` attaches attributes mid-flight. While it
+    is open it is the innermost entry of its thread's span stack (the
+    ``parent`` of whatever that thread records meanwhile) and, if the
+    tracer has an annotation factory, a host event of the profiler."""
 
-    __slots__ = ("_tracer", "plane", "phase", "trace", "attrs", "_t0")
+    __slots__ = ("_tracer", "plane", "phase", "trace", "attrs", "_t0",
+                 "_stack", "_parent", "_note")
 
     def __init__(self, tracer: "Tracer", plane: str, phase: str,
-                 trace: str, attrs: Dict[str, Any]):
+                 trace: Optional[str], attrs: Dict[str, Any]):
         self._tracer = tracer
         self.plane = plane
         self.phase = phase
         self.trace = trace
         self.attrs = attrs
         self._t0 = 0.0
+        self._note = None
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._tracer._clock()
+        tracer = self._tracer
+        self._parent, self.trace = tracer._context(self.trace)
+        self._stack = tracer.open_spans()
+        self._stack.append(self)
+        self._t0 = tracer._clock()
+        if tracer._annotate is not None:
+            self._note = tracer._annotate(f"{self.plane}/{self.phase}")
+            self._note.__enter__()
         return self
 
     def set(self, **attrs) -> "_Span":
@@ -110,16 +135,23 @@ class _Span:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
         t1 = self._tracer._clock()
+        if self._stack[-1] is self:
+            self._stack.pop()
+        else:                      # misnested exits: still leave the stack
+            self._stack.remove(self)
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         self._tracer.add(self.plane, self.phase, self.trace,
-                         self._t0, t1 - self._t0, **self.attrs)
+                         self._t0, t1 - self._t0, parent=self._parent,
+                         **self.attrs)
         return False
 
 
 def span(tracer: Optional["Tracer"], plane: str, phase: str,
-         trace: str, **attrs):
+         trace: Optional[str] = None, **attrs):
     """``with span(maybe_tracer, ...)`` — the guarded call-site helper.
     With ``tracer=None`` this returns the shared :data:`NULL_SPAN`
     (zero allocation, zero clock reads): disabled tracing costs one
@@ -137,12 +169,18 @@ class Tracer:
     def __init__(self, peer: str = "", sink_path: Optional[str] = None,
                  ring_bytes: int = 256 * 1024,
                  flush_interval_s: float = 2.0,
-                 clock=time.monotonic):
+                 clock=time.perf_counter,
+                 annotate: Optional[Callable[[str], Any]] = None):
         self.peer = peer
         self.sink_path = sink_path
         self.ring_bytes = int(ring_bytes)
         self.flush_interval_s = flush_interval_s
         self._clock = clock
+        # name -> context manager that writes a host event into the
+        # running profiler session (jax.profiler.TraceAnnotation); the
+        # entry point injects it, so this module needs no JAX
+        self._annotate = annotate
+        self._open = threading.local()   # .stack: this thread's open spans
         self._lock = threading.Lock()
         self._ring: deque = deque()      # (est_bytes, row)
         self._ring_used = 0
@@ -155,26 +193,53 @@ class Tracer:
 
     # -- recording -------------------------------------------------------
 
-    def span(self, plane: str, phase: str, trace: str, **attrs) -> _Span:
+    def span(self, plane: str, phase: str, trace: Optional[str] = None,
+             **attrs) -> _Span:
+        """A live span. With ``trace=None`` it belongs to whatever the
+        span open on this thread belongs to (a ``collab/step`` inside
+        ``loop/step`` is that step's)."""
         return _Span(self, plane, phase, trace, attrs)
 
-    def event(self, plane: str, phase: str, trace: str, **attrs) -> None:
+    def open_spans(self) -> List[_Span]:
+        """The calling thread's open spans, outermost first (live list)."""
+        try:
+            return self._open.stack
+        except AttributeError:
+            stack = self._open.stack = []
+            return stack
+
+    def _context(self, trace: Optional[str]) -> Tuple[Optional[str], str]:
+        """(parent phase, trace) of what this thread records now: the
+        innermost open span causes it and, unless a trace is given,
+        lends its trace."""
+        stack = self.open_spans()
+        if not stack:
+            return None, NO_TRACE if trace is None else trace
+        return stack[-1].phase, stack[-1].trace if trace is None else trace
+
+    def event(self, plane: str, phase: str, trace: Optional[str] = None,
+              **attrs) -> None:
         """A zero-duration span (lifecycle marker: submit, admit,
-        fault_injected, ...)."""
-        self.add(plane, phase, trace, self._clock(), 0.0, **attrs)
+        fault_injected, ...), the child of the span open on this
+        thread; with ``trace=None`` it shares that span's trace."""
+        parent, trace = self._context(trace)
+        self.add(plane, phase, trace, self._clock(), 0.0, parent=parent,
+                 **attrs)
 
     def add(self, plane: str, phase: str, trace: str, t0: float,
-            dur_s: float, **attrs) -> None:
+            dur_s: float, parent: Optional[str] = None, **attrs) -> None:
         """Record one span from pre-measured times — how the optimizer
         converts its existing ``last_timings`` seams into spans without
         re-timing anything. Memory-only: never touches the sink file."""
         row = {"v": SCHEMA_VERSION, "peer": self.peer, "plane": plane,
                "phase": phase, "trace": trace,
                "t0": round(t0, 6), "dur_s": round(dur_s, 6)}
+        if parent is not None:
+            row["parent"] = parent
         if attrs:
             row["a"] = attrs
         est = (_ROW_BASE_BYTES + len(phase) + len(trace)
-               + _ATTR_EST_BYTES * len(attrs))
+               + len(parent or "") + _ATTR_EST_BYTES * len(attrs))
         hkey = (plane, phase)
         with self._lock:
             self.spans_recorded += 1
@@ -203,6 +268,12 @@ class Tracer:
             counts[i] += 1
             h[1] += dur_s
             h[2] += 1
+
+    def closed(self, plane: str, phase: str) -> int:
+        """How many spans of this phase have closed (events excluded)."""
+        with self._lock:
+            h = self._hist.get((plane, phase))
+            return h[2] if h is not None else 0
 
     # -- the JSONL sink --------------------------------------------------
 
@@ -323,13 +394,15 @@ _default: Optional[Tracer] = None
 
 
 def configure(peer: str = "", sink_path: Optional[str] = None,
-              ring_bytes: int = 256 * 1024) -> Tracer:
+              ring_bytes: int = 256 * 1024,
+              annotate: Optional[Callable[[str], Any]] = None) -> Tracer:
     """Install (and return) the process-default tracer. Library code
-    takes tracers as explicit parameters — this default exists for CLI
-    entry points and tools that want one shared recorder."""
+    takes tracers as explicit parameters — this default exists for the
+    entry points (``TrainingTask`` installs one, ring only unless a
+    sink is given) and for tools that read what they recorded."""
     global _default
     _default = Tracer(peer=peer, sink_path=sink_path,
-                      ring_bytes=ring_bytes)
+                      ring_bytes=ring_bytes, annotate=annotate)
     return _default
 
 

@@ -48,7 +48,8 @@ def make_mesh(dp: int = -1, fsdp: int = 1, tp: int = 1, sp: int = 1,
     return Mesh(arr, AXES)
 
 
-def per_shard(fn, mesh: Optional[Mesh], in_specs, out_specs):
+def per_shard(fn, mesh: Optional[Mesh], in_specs, out_specs,
+              scope: Optional[str] = None):
     """``fn`` run once per device on its own shard of the operands.
 
     GSPMD cannot partition a Mosaic kernel (the lowering raises "Mosaic
@@ -59,10 +60,22 @@ def per_shard(fn, mesh: Optional[Mesh], in_specs, out_specs):
     Replication is not type-checked (``check_vma=False``: ``pallas_call``
     carries no varying-axes annotation); the per-kernel parity tests on
     the 8-device mesh are the check.
+
+    XLA names a Mosaic custom call after the innermost name-stack
+    component at the ``pallas_call``: the calling flax module's name on one
+    device (``attn``, ``ff``, ``attn_norm``), but ``shard_map`` once this
+    wrapper sits between. ``scope`` — the call site's own name — is opened
+    again inside the body, so a kernel keeps its name on a mesh and the
+    trace reduction finds it on four chips as on one.
     """
     if mesh is None or mesh.size == 1:
         return fn
-    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+    body = fn
+    if scope is not None:
+        def body(*args):
+            with jax.named_scope(scope):
+                return fn(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
 
 

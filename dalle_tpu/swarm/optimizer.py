@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dalle_tpu.config import CollabConfig
+from dalle_tpu.obs.trace import span as obs_span
 from dalle_tpu.swarm import compression
 from dalle_tpu.swarm.allreduce import run_allreduce
 from dalle_tpu.swarm.dht import DHT
@@ -138,7 +139,8 @@ class CollaborativeOptimizer:
                  serve_state: bool = True,
                  matchmaking_min_group: int = 2,
                  authorizer=None,
-                 role=None):
+                 role=None,
+                 tracer=None):
         from dalle_tpu.parallel.multihost import SliceRole
         self.role = role or SliceRole()
         if self.role.swarm_enabled and dht is None:
@@ -157,11 +159,14 @@ class CollaborativeOptimizer:
         # lifecycle's existing timing seams become spans whose trace id
         # is the PROTOCOL round id ({run_id}:grads:{epoch}), so several
         # peers' JSONL files merge into one cross-peer round timeline
-        # with no clock sync. None (the default) records nothing and
-        # every round path stays byte-identical — each seam pays one
-        # `is None` test (transparency pinned by tests/test_obs.py).
-        self.tracer = None
-        if getattr(cfg, "trace_file", None):
+        # with no clock sync. What runs every step() is spans of plane
+        # "train" on the same recorder. A trainer passes its always-on
+        # ring (TrainingTask); a library caller's None (the default)
+        # records nothing and every round path stays byte-identical —
+        # each seam pays one `is None` test (transparency pinned by
+        # tests/test_obs.py).
+        self.tracer = tracer
+        if tracer is None and getattr(cfg, "trace_file", None):
             from dalle_tpu.obs.trace import Tracer
             self.tracer = Tracer(
                 peer=(dht.peer_id[:12] if dht is not None else "local"),
@@ -485,80 +490,94 @@ class CollaborativeOptimizer:
         Overlap is disabled there: followers cannot join broadcasts from a
         background thread, so slices run the synchronous path.
         """
+        with obs_span(self.tracer, "train", "collab/step"):
+            return self._step(grads, batch_size)
+
+    def _step(self, grads: Any, batch_size: int) -> bool:
+        """:meth:`step` proper. Its parts are spans of plane ``train``
+        (OBSERVABILITY.md), children of ``collab/step`` and, through it,
+        of the loop's step: recorded at dispatch, never by waiting for
+        the device, so the recorder does not change what it times."""
         from dalle_tpu.parallel.multihost import broadcast_decision
+        tracer = self.tracer
 
         did_global = False
         if self._pending is not None and self._pending.done.is_set():
-            self._finish_pending()
+            with obs_span(tracer, "train", "collab/reconcile"):
+                self._finish_pending()
             did_global = True
 
-        if self._grad_acc is None:
-            self._grad_acc = jax.tree.map(
-                lambda g: jnp.zeros(g.shape, jnp.float32), grads)
-        if self.tracer is not None and self._pending is not None:
-            # overlap proof (r19): while a round is in flight, the
-            # accumulate becomes a span on the ROUND's trace id, so the
-            # merged cross-peer timeline shows compute strictly
-            # concurrent with in-round hop spans. The block_until_ready
-            # pins the span's wall to the device work — values are
-            # untouched, and recorder-off rounds skip all of it.
-            t_acc = time.monotonic()
-            self._grad_acc = self._accumulate(
-                self._grad_acc, grads, float(batch_size))
-            jax.block_until_ready(self._grad_acc)
-            self.tracer.add(
-                "swarm", "accumulate",
-                self._round_trace(self._pending.epoch), t_acc,
-                time.monotonic() - t_acc, samples=int(batch_size))
-        else:
+        # while a round is in flight the span names it, so the merged
+        # timeline shows the accumulates between that round's hop spans
+        in_round = ({"round": self._round_trace(self._pending.epoch)}
+                    if tracer is not None and self._pending is not None
+                    else {})
+        with obs_span(tracer, "train", "collab/accumulate",
+                      samples=int(batch_size), **in_round):
+            if self._grad_acc is None:
+                # placed like the gradients: the accumulate then sees the
+                # operands of every later call and compiles once (the
+                # compile counter found a second compile at step 2)
+                self._grad_acc = jax.tree.map(
+                    lambda g: jnp.zeros(g.shape, jnp.float32,
+                                        device=g.sharding), grads)
             self._grad_acc = self._accumulate(
                 self._grad_acc, grads, float(batch_size))
         self.local_samples += int(batch_size)
-        if self._pending is not None:
-            # round in flight: report the FROZEN pre-round progress (pure
-            # liveness — publishing the restarted counter would deflate the
-            # swarm's sample total and flip ready_to_update off for peers
-            # still deciding to join); decisions wait for the reconcile
-            self._pending.overlapped_steps += 1
+        with obs_span(tracer, "train", "collab/progress"):
+            if self._pending is not None:
+                # round in flight: report the FROZEN pre-round progress
+                # (pure liveness — publishing the restarted counter would
+                # deflate the swarm's sample total and flip
+                # ready_to_update off for peers still deciding to join);
+                # decisions wait for the reconcile
+                self._pending.overlapped_steps += 1
+                self.tracker.report_local_progress(
+                    self.local_epoch, self._pending.weight_int)
+                return did_global
+            # after a reconcile the tracker just force-published the
+            # epoch reset (samples=0) milliseconds ago: an unforced
+            # report here would be THROTTLED, the swarm would see 0
+            # samples, and this call's ready check would miss — costing a
+            # whole grad step of epoch latency every round (measured:
+            # 44 s epochs vs 22 s)
             self.tracker.report_local_progress(
-                self.local_epoch, self._pending.weight_int)
-            return did_global
-        # after a reconcile the tracker just force-published the epoch
-        # reset (samples=0) milliseconds ago: an unforced report here
-        # would be THROTTLED, the swarm would see 0 samples, and this
-        # call's ready check would miss — costing a whole grad step of
-        # epoch latency every round (measured: 44 s epochs vs 22 s)
-        self.tracker.report_local_progress(
-            self.local_epoch, self.local_samples, force=did_global)
+                self.local_epoch, self.local_samples, force=did_global)
 
         decision = self._CONTINUE
         min_epoch = 0
-        if self.role.swarm_enabled:
-            progress = self.tracker.global_progress()
-            if progress.epoch > self.local_epoch:
-                # keep accumulating between throttled attempts: hammering
-                # load_state_from_peers starves the host (and the swarm's
-                # state servers) without helping us catch up any faster
-                if time.monotonic() >= self._next_resync:
-                    decision = self._RESYNC
-                    min_epoch = progress.epoch
-                    self._next_resync = time.monotonic() + 1.0
-            elif progress.ready_to_update:
-                decision = self._GLOBAL_STEP
-        decision = broadcast_decision(decision)
+        with obs_span(tracer, "train", "collab/decide") as deciding:
+            if self.role.swarm_enabled:
+                progress = self.tracker.global_progress()
+                if progress.epoch > self.local_epoch:
+                    # keep accumulating between throttled attempts:
+                    # hammering load_state_from_peers starves the host
+                    # (and the swarm's state servers) without helping us
+                    # catch up any faster
+                    if time.monotonic() >= self._next_resync:
+                        decision = self._RESYNC
+                        min_epoch = progress.epoch
+                        self._next_resync = time.monotonic() + 1.0
+                elif progress.ready_to_update:
+                    decision = self._GLOBAL_STEP
+            decision = broadcast_decision(decision)
+            deciding.set(decision=decision)
 
         if decision == self._RESYNC:
             if self.role.swarm_enabled:
                 logger.info(
                     "behind the swarm (local %d < global %d): resyncing",
                     self.local_epoch, min_epoch)
-            self.load_state_from_peers(min_epoch=min_epoch)
+            with obs_span(tracer, "train", "collab/resync"):
+                self.load_state_from_peers(min_epoch=min_epoch)
             return did_global
         if decision == self._GLOBAL_STEP:
             if self._delay_rounds:
-                self._launch_round()
+                with obs_span(tracer, "train", "collab/launch_round"):
+                    self._launch_round()
                 return did_global  # the apply lands at a later reconcile
-            self._run_global_step()
+            with obs_span(tracer, "train", "collab/global_step"):
+                self._run_global_step()
             return True
         return did_global
 
@@ -644,7 +663,7 @@ class CollaborativeOptimizer:
         """Wire half of an overlapped round: matchmaking + all-reduce.
         Touches the DHT and host copies of the handed-off gradients only —
         never ``self.state`` (the training thread owns it)."""
-        t0 = time.monotonic()
+        t0 = time.perf_counter()
         try:
             group = make_group(
                 self.dht, f"{self.cfg.run_id}_grads", pending.epoch,
@@ -653,7 +672,7 @@ class CollaborativeOptimizer:
                 min_group_size=self.matchmaking_min_group,
                 client_mode=self.client_mode, authorizer=self.authorizer,
                 encrypt=self.cfg.encrypt_data_plane, ledger=self.ledger)
-            t_match = time.monotonic()
+            t_match = time.perf_counter()
             pending.timings["matchmaking_s"] = round(t_match - t0, 4)
             if self.tracer is not None:
                 self.tracer.add(
@@ -675,7 +694,7 @@ class CollaborativeOptimizer:
                                                  budget, sharded=False),
                         epoch=pending.epoch)
                 else:
-                    t_pull = time.monotonic()
+                    t_pull = time.perf_counter()
                     if self._device_grad_handoff:
                         # hand device arrays to the codec: the divide,
                         # flatten and quantize all run on device; the
@@ -688,7 +707,7 @@ class CollaborativeOptimizer:
                         grads_local = [np.asarray(g) / pending.weight
                                        for g in pending.leaves]
                     pending.timings["grad_pull_s"] = round(
-                        time.monotonic() - t_pull, 4)
+                        time.perf_counter() - t_pull, 4)
                     ra = self._new_round_audit(pending.epoch)
                     # the report dict is write-only wire telemetry;
                     # requested only when the tracer consumes it so the
@@ -715,10 +734,10 @@ class CollaborativeOptimizer:
                         self._auditor.submit(ra)
                     self._trace_allreduce(
                         self._round_trace(pending.epoch), t_match,
-                        time.monotonic(), rep, group.size)
+                        time.perf_counter(), rep, group.size)
                 pending.result = averaged
                 pending.timings["allreduce_s"] = round(
-                    time.monotonic() - t_match, 4)
+                    time.perf_counter() - t_match, 4)
             if group is not None:
                 pending.group_size = group.size
         # not silent, deferred: the error crosses threads on the round
@@ -728,7 +747,7 @@ class CollaborativeOptimizer:
         except BaseException as e:  # noqa: BLE001 - reported at reconcile
             pending.error = e
         finally:
-            pending.hidden_s = time.monotonic() - t0
+            pending.hidden_s = time.perf_counter() - t0
             pending.done.set()
 
     def _finish_pending(self, block: bool = False,
@@ -826,7 +845,7 @@ class CollaborativeOptimizer:
                                                   host_global,
                                                   is_fully_addressable)
 
-        t0 = time.monotonic()
+        t0 = time.perf_counter()
         treedef = jax.tree_util.tree_structure(self._grad_acc)
         leaves = jax.tree_util.tree_leaves(self._grad_acc)
         # Gradients sharded ACROSS processes (fsdp/tp/sp slices): pulling
@@ -857,7 +876,7 @@ class CollaborativeOptimizer:
             grads_local = None  # pulled below iff the epoch exchanges
         else:
             grads_local = [a / weight for a in host_global(leaves)]
-        t_pull = time.monotonic()
+        t_pull = time.perf_counter()
 
         if not self.role.swarm_enabled:
             self._follower_exchange(treedef, leaves, grads_local, sharded)
@@ -869,7 +888,7 @@ class CollaborativeOptimizer:
             min_group_size=self.matchmaking_min_group,
             client_mode=self.client_mode, authorizer=self.authorizer,
             encrypt=self.cfg.encrypt_data_plane, ledger=self.ledger)
-        t_match = time.monotonic()
+        t_match = time.perf_counter()
         if self.tracer is not None:
             self.tracer.add(
                 "swarm", "matchmaking", self._round_trace(
@@ -883,7 +902,7 @@ class CollaborativeOptimizer:
         pull_s = t_pull - t0
         if exchanging:
             if grads_local is None:  # deferred pull: the wire needs the
-                t_lazy = time.monotonic()  # grads outside the accumulator
+                t_lazy = time.perf_counter()  # grads outside the accumulator
                 if self._device_grad_handoff:
                     # device codec: the grads stay device arrays — the
                     # round flattens and quantizes them there (its one
@@ -891,10 +910,10 @@ class CollaborativeOptimizer:
                     grads_local = [g / weight for g in leaves]
                 else:
                     grads_local = [a / weight for a in host_global(leaves)]
-                pull_s += time.monotonic() - t_lazy  # keep attribution
+                pull_s += time.perf_counter() - t_lazy  # keep attribution
             budget = min(self.cfg.allreduce_timeout,
                          max(1.0, self.cfg.averaging_timeout
-                             - (time.monotonic() - t0)))
+                             - (time.perf_counter() - t0)))
             if mode == self._X_POWERSGD:
                 from dalle_tpu.swarm.powersgd import average_with_powersgd
                 averaged = average_with_powersgd(
@@ -905,7 +924,7 @@ class CollaborativeOptimizer:
             else:
                 ra = self._new_round_audit(self.local_epoch)
                 rep = {} if self.tracer is not None else None
-                t_ar = time.monotonic()
+                t_ar = time.perf_counter()
                 averaged = run_allreduce(
                     self.dht, group, f"{self.cfg.run_id}_grads",
                     self.local_epoch, grads_local, weight=weight,
@@ -926,7 +945,7 @@ class CollaborativeOptimizer:
                     self._auditor.submit(ra)
                 self._trace_allreduce(
                     self._round_trace(self.local_epoch), t_ar,
-                    time.monotonic(), rep, group.size)
+                    time.perf_counter(), rep, group.size)
         else:
             # alone this epoch: with a deferred pull the grads never left
             # the device — they flow straight into the jitted apply
@@ -942,7 +961,7 @@ class CollaborativeOptimizer:
                 averaged = broadcast_arrays(averaged, like=grads_local)
         else:
             averaged = broadcast_arrays(averaged, like=grads_local)
-        t_reduce = time.monotonic()
+        t_reduce = time.perf_counter()
 
         self._apply_averaged(treedef, averaged)
         # per-phase timing of the collective path (SURVEY.md §5 calls for
@@ -959,7 +978,7 @@ class CollaborativeOptimizer:
             "robust": self.robustness_snapshot(),
         }
         logger.info("global step -> epoch %d (%.2fs, group=%s, %s)",
-                    self.local_epoch, time.monotonic() - t0,
+                    self.local_epoch, time.perf_counter() - t0,
                     group.size if group else 1, self.last_timings)
 
     def _follower_exchange(self, treedef, leaves, grads_local,
@@ -1074,7 +1093,7 @@ class CollaborativeOptimizer:
         split. ``preserve_accumulator`` (overlapped rounds): the live
         accumulator holds the NEXT epoch's gradients collected during the
         round — it must survive the reconcile."""
-        t0 = time.monotonic()
+        t0 = time.perf_counter()
         from dalle_tpu.parallel.multihost import process_count
         grads_prefix = f"{self.cfg.run_id}_grads"
         if (self._repair is not None
@@ -1098,7 +1117,7 @@ class CollaborativeOptimizer:
             treedef, [jnp.asarray(a) for a in averaged])
         self.state = self.apply_step(self.state, grads_tree)
         jax.block_until_ready(jax.tree_util.tree_leaves(self.state.params)[0])
-        t_applied = time.monotonic()
+        t_applied = time.perf_counter()
 
         epoch0 = self.local_epoch
         self.local_epoch += 1
@@ -1113,7 +1132,7 @@ class CollaborativeOptimizer:
             self._average_state()
         self._apply_timings = {
             "apply_s": round(t_applied - t0, 4),
-            "state_avg_s": round(time.monotonic() - t_applied, 4),
+            "state_avg_s": round(time.perf_counter() - t_applied, 4),
         }
         if self.tracer is not None:
             trace = self._round_trace(epoch0)

@@ -46,6 +46,20 @@ class TrainingTask:
         self.peer_cfg = peer
         self.data_path = data_path
         self.tokenizer_path = tokenizer_path
+        # The trainer times itself (OBSERVABILITY.md, plane ``train``): one
+        # process-default flight ring, always on, whose live spans are also
+        # host events of any profiler session; ``--trace-file`` adds the
+        # JSONL sink. The compile counter records into the same ring.
+        from dalle_tpu.obs import compiles
+        from dalle_tpu.obs.trace import configure
+        self.tracer = configure(
+            peer="trainer", sink_path=collab.trace_file,
+            ring_bytes=collab.trace_ring_kb * 1024,
+            annotate=jax.profiler.TraceAnnotation)
+        self.compiles = compiles.install(self.tracer)
+
+    def _setup_span(self, what: str):
+        return self.tracer.span("train", f"setup/{what}", "setup")
 
     # -- identity / swarm -------------------------------------------------
 
@@ -57,6 +71,10 @@ class TrainingTask:
     @functools.cached_property
     def dht(self):
         """This peer's swarm node (reference ``task.py:101-119``)."""
+        with self._setup_span("dht"):
+            return self._open_dht()
+
+    def _open_dht(self):
         from dalle_tpu.swarm.dht import DHT
         initial_peers = list(self.peer_cfg.initial_peers)
         rdv = None
@@ -108,6 +126,9 @@ class TrainingTask:
                 dht.bootstrap(addr)
         logger.info("swarm node up: peer_id=%s addr=%s",
                     dht.peer_id[:16], dht.visible_address)
+        # rows recorded from here on carry the swarm's name for this peer:
+        # the key trace_report merges several peers' files by
+        self.tracer.peer = dht.peer_id[:12]
         return dht
 
     @functools.cached_property
@@ -134,13 +155,14 @@ class TrainingTask:
         a DHT — the coordinator's averaged results reach them via
         broadcasts."""
         from dalle_tpu.swarm.optimizer import CollaborativeOptimizer
-        dht = self.dht if self.slice_role.swarm_enabled else None
-        return CollaborativeOptimizer(
-            dht, self.collab_cfg, self.train_state, self.apply_step,
-            client_mode=self.peer_cfg.client_mode,
-            authorizer=self.authorizer if self.slice_role.swarm_enabled
-            else None,
-            role=self.slice_role)
+        with self._setup_span("collab_optimizer"):
+            dht = self.dht if self.slice_role.swarm_enabled else None
+            return CollaborativeOptimizer(
+                dht, self.collab_cfg, self.train_state, self.apply_step,
+                client_mode=self.peer_cfg.client_mode,
+                authorizer=self.authorizer if self.slice_role.swarm_enabled
+                else None,
+                role=self.slice_role, tracer=self.tracer)
 
     # -- mesh / compute ---------------------------------------------------
 
@@ -177,13 +199,14 @@ class TrainingTask:
         from dalle_tpu.models.dalle import init_params
         from dalle_tpu.parallel.sharding import shard_train_state
         from dalle_tpu.training.steps import TrainState
-        params = init_params(self.model,
-                             jax.random.PRNGKey(self.trainer_cfg.seed))
-        state = TrainState.create(params, self.tx)
-        if self.opt_cfg.offload:
-            from dalle_tpu.training.offload import offload_train_state
-            return offload_train_state(self.mesh, state)
-        return shard_train_state(self.mesh, state)
+        with self._setup_span("train_state"):
+            params = init_params(self.model,
+                                 jax.random.PRNGKey(self.trainer_cfg.seed))
+            state = TrainState.create(params, self.tx)
+            if self.opt_cfg.offload:
+                from dalle_tpu.training.offload import offload_train_state
+                return offload_train_state(self.mesh, state)
+            return shard_train_state(self.mesh, state)
 
     @functools.cached_property
     def grad_step(self):

@@ -13,6 +13,7 @@ round needs them on the host.
 
 from __future__ import annotations
 
+import functools
 import logging
 import time
 from typing import Callable, List, Optional
@@ -44,13 +45,14 @@ def warmup(task: TrainingTask, steps: int = 3) -> float:
     batches = task.batches()
     params = task.collab_optimizer.state.params
     loss = float("nan")
-    for i in range(steps):
-        t0 = time.monotonic()
-        grads, metrics = task.grad_step(params, next(batches))
-        jax.block_until_ready(grads)
-        loss = float(metrics["loss"])
-        logger.info("warmup %d/%d: loss=%.4f (%.2fs)",
-                    i + 1, steps, loss, time.monotonic() - t0)
+    with task.tracer.span("train", "setup/warmup", "setup", steps=steps):
+        for i in range(steps):
+            t0 = time.monotonic()
+            grads, metrics = task.grad_step(params, next(batches))
+            jax.block_until_ready(grads)
+            loss = float(metrics["loss"])
+            logger.info("warmup %d/%d: loss=%.4f (%.2fs)",
+                        i + 1, steps, loss, time.monotonic() - t0)
     if not np.isfinite(loss):
         raise RuntimeError(f"warmup produced non-finite loss {loss}")
     # warmup gradients are discarded; the tracker timer starts fresh
@@ -127,128 +129,119 @@ def train_loop(task: TrainingTask,
     loss_sum, mini_steps, local_steps = 0.0, 0, 0
     profiler = _StepProfiler(profile_dir, profile_steps)
     batches = task.batches()
+    # the loop times itself (OBSERVABILITY.md, plane "train"): one
+    # loop/step span a step, its parts as children; what the children do
+    # not cover is the loop's own time
+    span = functools.partial(task.tracer.span, "train")
     try:
         while ((max_epochs is None or collab.local_epoch < max_epochs)
                and (max_steps is None or local_steps < max_steps)):
             profiler.tick(local_steps)
-            batch = next(batches)
-            grads, metrics = task.grad_step(collab.state.params, batch)
-            loss = float(metrics["loss"])
-            loss_sum += loss
-            mini_steps += 1
-            local_steps += 1
-            if on_step is not None:
-                on_step(local_steps, loss)
+            with span("loop/step", f"step:{local_steps + 1}"):
+                with span("loop/batch_fetch"):
+                    batch = next(batches)
+                with span("loop/grad_dispatch"):
+                    grads, metrics = task.grad_step(collab.state.params,
+                                                    batch)
+                with span("loop/loss_wait"):
+                    loss = float(metrics["loss"])
+                loss_sum += loss
+                mini_steps += 1
+                local_steps += 1
+                if on_step is not None:
+                    with span("loop/hook"):
+                        on_step(local_steps, loss)
 
-            epoch_before = collab.local_epoch
-            did_global = collab.step(grads,
-                                     batch_size=task.local_batch_size)
-            # hop-granular round visibility (r19): while an overlapped
-            # round is in flight the loop keeps accumulating — surface
-            # which parts have already landed instead of one opaque
-            # "round pending" wall (debug level: this fires every step)
-            if logger.isEnabledFor(logging.DEBUG):
-                prog = collab.round_progress()
-                if prog is not None:
-                    logger.debug(
-                        "round in flight (epoch %d): scatter=%d "
-                        "reduce=%d gather=%d parts done, %d grad steps "
-                        "overlapped", prog["epoch"], prog["scatter"],
-                        prog["reduce"], prog["gather"],
-                        prog["overlapped_steps"])
-            rolled_back = False
-            if did_global and ckpt is not None:
-                epoch = collab.local_epoch
-                try:
-                    if not params_are_finite(collab.state.params):
-                        logger.warning(
-                            "non-finite params after epoch %d: rolling "
-                            "back to the local backup", epoch)
-                        # a round launched in the same step() that
-                        # reconciled the NaN-producing apply carries the
-                        # divergent trajectory's gradients: discard it
-                        # before restoring (never apply it post-rollback)
-                        collab.drop_pending_round()
-                        restored = ckpt.restore_backup(collab.state)
-                        if restored is None:
-                            restored = ckpt.restore_latest(collab.state)
-                        if restored is None:
-                            raise RuntimeError(
-                                "params corrupted and no backup to restore")
-                        collab.state, backup_epoch = restored
-                        collab.local_epoch = backup_epoch
-                        collab.tracker.reset_epoch(backup_epoch)
-                        rolled_back = True
-                    else:
-                        do_backup = (backup_every
-                                     and epoch % backup_every == 0)
-                        if save_every and epoch % save_every == 0:
-                            ckpt.save(collab.state, epoch, backup=do_backup)
-                        elif do_backup:
-                            ckpt.save_backup(collab.state, epoch)
-                except BaseException:
-                    # a coordinator dying between the global step and the
-                    # rollback broadcast would wedge every follower inside
-                    # broadcast_decision forever: send the abort code
-                    # first, then re-raise
-                    if multihost.process_count() > 1:
-                        multihost.broadcast_decision(2)
-                    raise
-            if did_global and multihost.process_count() > 1:
-                # a coordinator-side NaN rollback must re-align followers;
-                # code 2 = the coordinator failed and is going down
-                rb = multihost.broadcast_decision(1 if rolled_back else 0)
-                if rb == 2:
-                    raise RuntimeError(
-                        "slice coordinator failed during checkpoint "
-                        "handling")
-                if rb == 1:
-                    leaves = collab._state_leaves()
-                    leaves = multihost.broadcast_arrays(
-                        leaves if coordinator else None, like=leaves)
-                    collab._replace_state_leaves(leaves)
-                    collab.local_epoch = multihost.broadcast_decision(
-                        collab.local_epoch)
-                    collab.tracker.reset_epoch(collab.local_epoch)
-            if collab.local_epoch != epoch_before:
-                # global step OR resync-from-peers: either way a new epoch
-                report = EpochReport(
-                    epoch=collab.local_epoch,
-                    loss=loss_sum / max(mini_steps, 1),
-                    mini_steps=mini_steps,
-                    samples_per_second=(
-                        collab.tracker.performance_ema.samples_per_second))
-                reports.append(report)
-                if did_global and publish_metrics_records and coordinator:
-                    robust = collab.robustness_snapshot()
-                    publish_metrics(
-                        task.dht, task.peer_cfg.experiment_prefix,
-                        LocalMetrics(
-                            peer_id=task.dht.peer_id,
-                            epoch=report.epoch,
-                            samples_per_second=report.samples_per_second,
-                            samples_accumulated=0,
-                            loss=report.loss,
-                            mini_steps=report.mini_steps,
-                            parts_audited=robust["parts_audited"],
-                            audit_convictions=(robust["audit_fail"]
-                                               + robust["audit_omit"]),
-                            repairs_applied=robust["repairs_applied"],
-                            repair_ring_evictions=robust["ring_evictions"],
-                            ef_lost_rounds=robust["ef_lost_rounds"],
-                            proofs_published=robust["proofs_published"],
-                            proofs_convicted=robust["proofs_convicted"],
-                            proofs_rejected=robust["proofs_rejected"]),
-                        expiration=task.collab_cfg.metrics_expiration)
-                logger.info(
-                    "epoch %d: mean_loss=%.4f mini_steps=%d sps=%.1f%s",
-                    report.epoch, report.loss, report.mini_steps,
-                    report.samples_per_second,
-                    (" hops=%s" % (collab.last_timings["round_hops"],)
-                     if "round_hops" in collab.last_timings else ""))
-                if on_epoch is not None:
-                    on_epoch(report)
-                loss_sum, mini_steps = 0.0, 0
+                epoch_before = collab.local_epoch
+                did_global = collab.step(grads,
+                                         batch_size=task.local_batch_size)
+                # hop-granular round visibility (r19): while an overlapped
+                # round is in flight the loop keeps accumulating — surface
+                # which parts have already landed instead of one opaque
+                # "round pending" wall (debug level: this fires every step)
+                if logger.isEnabledFor(logging.DEBUG):
+                    prog = collab.round_progress()
+                    if prog is not None:
+                        logger.debug(
+                            "round in flight (epoch %d): scatter=%d "
+                            "reduce=%d gather=%d parts done, %d grad steps "
+                            "overlapped", prog["epoch"], prog["scatter"],
+                            prog["reduce"], prog["gather"],
+                            prog["overlapped_steps"])
+                rolled_back = False
+                if did_global and ckpt is not None:
+                    epoch = collab.local_epoch
+                    try:
+                        if not params_are_finite(collab.state.params):
+                            logger.warning(
+                                "non-finite params after epoch %d: rolling "
+                                "back to the local backup", epoch)
+                            # a round launched in the same step() that
+                            # reconciled the NaN-producing apply carries the
+                            # divergent trajectory's gradients: discard it
+                            # before restoring (never apply it post-rollback)
+                            collab.drop_pending_round()
+                            restored = ckpt.restore_backup(collab.state)
+                            if restored is None:
+                                restored = ckpt.restore_latest(collab.state)
+                            if restored is None:
+                                raise RuntimeError(
+                                    "params corrupted and no backup to "
+                                    "restore")
+                            collab.state, backup_epoch = restored
+                            collab.local_epoch = backup_epoch
+                            collab.tracker.reset_epoch(backup_epoch)
+                            rolled_back = True
+                        else:
+                            do_backup = (backup_every
+                                         and epoch % backup_every == 0)
+                            if save_every and epoch % save_every == 0:
+                                ckpt.save(collab.state, epoch,
+                                          backup=do_backup)
+                            elif do_backup:
+                                ckpt.save_backup(collab.state, epoch)
+                    except BaseException:
+                        # a coordinator dying between the global step and the
+                        # rollback broadcast would wedge every follower inside
+                        # broadcast_decision forever: send the abort code
+                        # first, then re-raise
+                        if multihost.process_count() > 1:
+                            multihost.broadcast_decision(2)
+                        raise
+                if did_global and multihost.process_count() > 1:
+                    # a coordinator-side NaN rollback must re-align followers;
+                    # code 2 = the coordinator failed and is going down
+                    rb = multihost.broadcast_decision(1 if rolled_back else 0)
+                    if rb == 2:
+                        raise RuntimeError(
+                            "slice coordinator failed during checkpoint "
+                            "handling")
+                    if rb == 1:
+                        leaves = collab._state_leaves()
+                        leaves = multihost.broadcast_arrays(
+                            leaves if coordinator else None, like=leaves)
+                        collab._replace_state_leaves(leaves)
+                        collab.local_epoch = multihost.broadcast_decision(
+                            collab.local_epoch)
+                        collab.tracker.reset_epoch(collab.local_epoch)
+                if collab.local_epoch != epoch_before:
+                    # global step OR resync-from-peers: either way a new
+                    # epoch
+                    with span("loop/epoch_report"):
+                        report = EpochReport(
+                            epoch=collab.local_epoch,
+                            loss=loss_sum / max(mini_steps, 1),
+                            mini_steps=mini_steps,
+                            samples_per_second=collab.tracker
+                            .performance_ema.samples_per_second)
+                        reports.append(report)
+                        _announce_epoch(
+                            task, report,
+                            publish=(did_global and publish_metrics_records
+                                     and coordinator))
+                        if on_epoch is not None:
+                            on_epoch(report)
+                    loss_sum, mini_steps = 0.0, 0
         # an overlapped round (delay_optimizer_step) may still be in
         # flight when the loop exits: apply it rather than lose the
         # epoch's averaging (shutdown() would discard it) — EXCEPT when
@@ -278,6 +271,40 @@ def train_loop(task: TrainingTask,
         if ckpt is not None:
             ckpt.close()  # drain async checkpoint writes before returning
     return reports
+
+
+def _announce_epoch(task: TrainingTask, report: EpochReport,
+                    publish: bool) -> None:
+    """Publish this peer's signed metrics record for the epoch (the
+    coordinator, after a global step) and log the epoch line."""
+    collab = task.collab_optimizer
+    if publish:
+        robust = collab.robustness_snapshot()
+        publish_metrics(
+            task.dht, task.peer_cfg.experiment_prefix,
+            LocalMetrics(
+                peer_id=task.dht.peer_id,
+                epoch=report.epoch,
+                samples_per_second=report.samples_per_second,
+                samples_accumulated=0,
+                loss=report.loss,
+                mini_steps=report.mini_steps,
+                parts_audited=robust["parts_audited"],
+                audit_convictions=(robust["audit_fail"]
+                                   + robust["audit_omit"]),
+                repairs_applied=robust["repairs_applied"],
+                repair_ring_evictions=robust["ring_evictions"],
+                ef_lost_rounds=robust["ef_lost_rounds"],
+                proofs_published=robust["proofs_published"],
+                proofs_convicted=robust["proofs_convicted"],
+                proofs_rejected=robust["proofs_rejected"]),
+            expiration=task.collab_cfg.metrics_expiration)
+    logger.info(
+        "epoch %d: mean_loss=%.4f mini_steps=%d sps=%.1f%s",
+        report.epoch, report.loss, report.mini_steps,
+        report.samples_per_second,
+        (" hops=%s" % (collab.last_timings["round_hops"],)
+         if "round_hops" in collab.last_timings else ""))
 
 
 class _StepProfiler:

@@ -62,6 +62,9 @@ def _loss_fn(model, params, batch):
     return loss, aux
 
 
+GRAD_ACCUMULATE_SCOPE = "grad_accumulate"
+
+
 def _accumulate_grads(model, params, batch, accum_steps: int):
     """Mean loss/grads over ``accum_steps`` microbatches via lax.scan."""
     if accum_steps <= 1:
@@ -79,7 +82,10 @@ def _accumulate_grads(model, params, batch, accum_steps: int):
     def body(carry, mb):
         g_acc, loss_acc, aux_acc = carry
         (loss, aux), g = grad_fn(params, mb)
-        g_acc = jax.tree.map(jnp.add, g_acc, g)
+        # a device scope of its own (models/dalle.py names the others):
+        # the accumulation's add shows apart from the backward pass
+        with jax.named_scope(GRAD_ACCUMULATE_SCOPE):
+            g_acc = jax.tree.map(jnp.add, g_acc, g)
         aux_acc = jax.tree.map(jnp.add, aux_acc, aux)
         return (g_acc, loss_acc + loss, aux_acc), None
 
