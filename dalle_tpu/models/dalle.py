@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from dalle_tpu.config import ModelConfig
 from dalle_tpu.models.transformer import Transformer
+from dalle_tpu.parallel.mesh import sum_over_manual_data_axes
 
 
 # Device scopes (``jax.named_scope``) for what no flax module names: they
@@ -230,11 +231,20 @@ class DALLE(nn.Module):
             mask_img = loss_mask[:, cfg.text_seq_len:]
             nll_text = nll_text * mask_text
             nll_img = nll_img * mask_img
-            denom_text = jnp.maximum(mask_text.sum(), 1.0)
-            denom_img = jnp.maximum(mask_img.sum(), 1.0)
+            denom_text, denom_img = mask_text.sum(), mask_img.sum()
         else:
             denom_text = nll_text.shape[0] * cfg.text_seq_len
             denom_img = nll_img.shape[0] * cfg.image_seq_len
+        # The loss is normalised over the WHOLE (micro)batch. Where this
+        # trace holds one data shard of it (training/steps.py accumulates
+        # under a shard_map manual over dp) the denominators are summed
+        # over the shards, two scalars, and the loss returned is this
+        # shard's share: the shares add up to the batch's loss.
+        denom_text, denom_img = sum_over_manual_data_axes(
+            (denom_text, denom_img))
+        if loss_mask is not None:
+            denom_text = jnp.maximum(denom_text, 1.0)
+            denom_img = jnp.maximum(denom_img, 1.0)
         loss_text = nll_text.sum() / denom_text
         loss_img = nll_img.sum() / denom_img
         w = cfg.loss_img_weight

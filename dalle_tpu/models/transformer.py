@@ -241,8 +241,9 @@ class GEGLUFeedForward(nn.Module):
             return per_shard(
                 functools.partial(_geglu_shard, tp=tp), self.mesh,
                 (TOKENS_SPEC, P(None, "tp"), P(None, "tp"), P("tp", None),
-                 P("tp"), P("tp"), P()), TOKENS_SPEC, scope=self.name)(
-                     x, wi, wg, wo, bi, bg, bo)
+                 P("tp"), P("tp"), P(), P("tp")), TOKENS_SPEC,
+                scope=self.name)(
+                    x, wi, wg, wo, bi, bg, bo, jnp.arange(tp))
         return _geglu_xla(x, wi, wg, wo, bi, bg, bo)
 
 
@@ -252,17 +253,19 @@ def _geglu_xla(x, wi, wg, wo, bi, bg, bo):
     return jnp.dot(h * nn.gelu(gate), wo) + bo
 
 
-def _geglu_shard(x, wi, wg, wo, bi, bg, bo, *, tp: int):
+def _geglu_shard(x, wi, wg, wo, bi, bg, bo, tp_index, *, tp: int):
     """One shard's GEGLU FF: the fused kernel where the LOCAL shapes tile.
     Under tp each shard holds a slice of the inner dimension, so its
     output is a partial product: the output bias joins on one shard only
-    and the partials are summed over ``tp``."""
+    (``tp_index``: (1,), the shard's slice of ``arange(tp)``; see
+    parallel/mesh.shard_map_unbound) and the partials are summed over
+    ``tp``."""
     from dalle_tpu.models import attention as attn_mod
     from dalle_tpu.ops.pallas.geglu_kernels import geglu_ff, geglu_supported
     b, t, d = x.shape
     inner = wi.shape[1]
     if tp > 1:
-        bo = jnp.where(jax.lax.axis_index("tp") == 0, bo, jnp.zeros_like(bo))
+        bo = jnp.where(tp_index[0] == 0, bo, jnp.zeros_like(bo))
     ok = geglu_supported(b * t, d, inner, x.dtype)
     attn_mod.log_kernel_choice(
         "GEGLU feed-forward", ok,

@@ -48,6 +48,65 @@ def make_mesh(dp: int = -1, fsdp: int = 1, tp: int = 1, sp: int = 1,
     return Mesh(arr, AXES)
 
 
+def unbound_axes(mesh: Mesh):
+    """``(mesh, axes)`` for a ``shard_map`` opened at this point of a trace:
+    the axes of ``mesh`` that the tracing context has not made manual yet.
+
+    ``training/steps._accumulate_grads`` runs the model inside a
+    ``shard_map`` manual over ``dp``; a ``shard_map`` nested in it can split
+    its operands only over the other axes, and has to name the context's
+    own (abstract) mesh. Outside any manual context this is the mesh
+    itself and all of its axes.
+    """
+    ctx = jax.sharding.get_abstract_mesh()
+    manual = ctx.manual_axes
+    if not manual:
+        return mesh, tuple(mesh.axis_names)
+    return ctx, tuple(a for a in mesh.axis_names if a not in manual)
+
+
+def _spec_over(spec: P, axes) -> P:
+    """``spec`` with every mesh axis outside ``axes`` dropped."""
+    def keep(entry):
+        names = entry if isinstance(entry, tuple) else (entry,)
+        names = tuple(a for a in names if a in axes)
+        return names if len(names) > 1 else (names[0] if names else None)
+    return P(*(keep(e) for e in spec))
+
+
+def shard_map_unbound(body, mesh: Mesh, in_specs, out_specs, check_vma):
+    """``jax.shard_map`` manual over :func:`unbound_axes` of ``mesh``: the
+    specs, written for the whole mesh, lose the axes that are manual
+    already (along those an operand is this shard's block as it stands).
+
+    Nested like that, a body cannot call ``lax.axis_index``: jax 0.9.0
+    lowers it as if the outer axes were not manual (the verifier refuses
+    "axis already bound by a parent"). A body that needs its position
+    takes it as an operand, a slice of ``arange`` split over that axis.
+    """
+    mesh, axes = unbound_axes(mesh)
+    if not axes:
+        # every axis is manual already (a pure-dp mesh inside the gradient
+        # accumulation): the operands are this device's own as they stand
+        return body
+
+    def over(specs):
+        return jax.tree.map(lambda s: _spec_over(s, axes), specs,
+                            is_leaf=lambda s: isinstance(s, P))
+    return jax.shard_map(body, mesh=mesh, in_specs=over(in_specs),
+                         out_specs=over(out_specs), axis_names=set(axes),
+                         check_vma=check_vma)
+
+
+def sum_over_manual_data_axes(x):
+    """``x`` summed over the data axes that are manual in the tracing
+    context: what one shard of a batch contributes, made the whole
+    batch's. Outside such a context (one device, GSPMD) it is ``x``."""
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    axes = tuple(a for a in ("dp", "fsdp") if a in manual)
+    return jax.lax.psum(x, axes) if axes else x
+
+
 def per_shard(fn, mesh: Optional[Mesh], in_specs, out_specs,
               scope: Optional[str] = None):
     """``fn`` run once per device on its own shard of the operands.
@@ -56,7 +115,11 @@ def per_shard(fn, mesh: Optional[Mesh], in_specs, out_specs,
     kernels cannot be automatically partitioned" for any jit that spans
     more than one device), so every Pallas call site goes through here:
     ``shard_map`` manual over ALL mesh axes, which is the one context the
-    lowering accepts. On one device (or with no mesh) it is ``fn`` itself.
+    lowering accepts; where the call sits inside a ``shard_map`` already
+    (the gradient accumulation, manual over ``dp``), over all that are
+    left (:func:`shard_map_unbound`; the context is read here, so build the
+    wrapper where it is called). On one device (or with no mesh) it is
+    ``fn`` itself.
     Replication is not type-checked (``check_vma=False``: ``pallas_call``
     carries no varying-axes annotation); the per-kernel parity tests on
     the 8-device mesh are the check.
@@ -75,8 +138,8 @@ def per_shard(fn, mesh: Optional[Mesh], in_specs, out_specs,
         def body(*args):
             with jax.named_scope(scope):
                 return fn(*args)
-    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+    return shard_map_unbound(body, mesh, in_specs, out_specs,
+                             check_vma=False)
 
 
 def batch_sharding(mesh: Mesh) -> NamedSharding:

@@ -39,17 +39,21 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from dalle_tpu.config import ATTN_FULL, SP_RING, SP_ULYSSES
 from dalle_tpu.models.attention import zoo_attention
+from dalle_tpu.parallel.mesh import shard_map_unbound, unbound_axes
 
 BATCH_AXES: Tuple[str, ...] = ("dp", "fsdp")
 
 
-def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                   axis_name: str, n_shards: int,
+def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                   index: jax.Array, *, axis_name: str, n_shards: int,
                    vary_axes: Tuple[str, ...] = ()) -> jax.Array:
     """Per-shard ZIGZAG ring attention body (call inside ``shard_map``).
 
     q/k/v: (B, T/sp, H, d) local sequence shards, contiguous layout in and
-    out (shard i holds global positions [i*T/sp, (i+1)*T/sp)). Global
+    out (shard i holds global positions [i*T/sp, (i+1)*T/sp)). ``index``:
+    (1,) int32, this shard's position i, handed in as a slice of
+    ``arange(sp)`` (``axis_index`` does not lower where this ``shard_map``
+    is nested in another: parallel/mesh.shard_map_unbound). Global
     semantics: plain causal attention over the full sequence — exactly the
     zoo's ``full`` type.
 
@@ -70,7 +74,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     The zigzag re-deal in/out costs two half-chunk ppermutes each way —
     ~2 extra ring-hop-equivalents against halving the attention matmuls.
     """
-    idx = jax.lax.axis_index(axis_name)
+    idx = index[0]
     b, tl, h, d = q.shape
     n = n_shards
     scale = d ** -0.5
@@ -219,11 +223,16 @@ def sp_zoo_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              grid=grid, conv_kernel=conv_kernel, mesh=mesh)
     b, t, h, d = q.shape
     tp = mesh.shape[tp_axis]
+    # inside the gradient accumulation's shard_map (manual over dp) the
+    # batch here is one dp shard's, and only the other axes are bound below
+    _, axes = unbound_axes(mesh)
     dbatch = 1
     for ax in BATCH_AXES:
-        dbatch *= mesh.shape[ax]
+        if ax in axes:
+            dbatch *= mesh.shape[ax]
     if b % dbatch:
-        raise ValueError(f"batch {b} not divisible by dp*fsdp={dbatch}")
+        raise ValueError(
+            f"batch {b} not divisible by its data shards ({dbatch})")
     if t % sp:
         raise ValueError(f"sequence {t} not divisible by sp={sp}")
     if mode == SP_RING and t % (2 * sp):
@@ -240,7 +249,7 @@ def sp_zoo_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 f"ring sequence parallelism requires 'full' attention "
                 f"layers, got {attn_type!r} (use mode='ulysses')")
         body = functools.partial(ring_attention, axis_name=sp_axis,
-                                 n_shards=sp, vary_axes=mesh.axis_names)
+                                 n_shards=sp, vary_axes=axes)
     elif mode == SP_ULYSSES:
         if (h // tp) % sp:
             raise ValueError(
@@ -252,8 +261,12 @@ def sp_zoo_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     else:
         raise ValueError(f"unknown sequence-parallel mode {mode!r}")
 
+    operands, specs = (q, k, v), (spec, spec, spec)
+    if mode == SP_RING:
+        operands += (jnp.arange(sp, dtype=jnp.int32),)
+        specs += (P(sp_axis),)
     # the Ulysses body runs the zoo's Pallas kernels on TPU, and
     # pallas_call carries no varying-axes annotation for the checker
-    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_vma=mode == SP_RING)
-    return fn(q, k, v)
+    fn = shard_map_unbound(body, mesh, in_specs=specs, out_specs=spec,
+                           check_vma=mode == SP_RING)
+    return fn(*operands)

@@ -23,6 +23,7 @@ import numpy as np
 
 from dalle_tpu.swarm.metrics import LocalMetrics, publish_metrics
 from dalle_tpu.task import TrainingTask
+from dalle_tpu.training.steps import grad_reduction_plan
 
 logger = logging.getLogger(__name__)
 
@@ -45,7 +46,8 @@ def warmup(task: TrainingTask, steps: int = 3) -> float:
     batches = task.batches()
     params = task.collab_optimizer.state.params
     loss = float("nan")
-    with task.tracer.span("train", "setup/warmup", "setup", steps=steps):
+    with task.tracer.span("train", "setup/warmup", "setup", steps=steps,
+                          grad_reduction=grad_reduction_plan(task.mesh)):
         for i in range(steps):
             t0 = time.monotonic()
             grads, metrics = task.grad_step(params, next(batches))

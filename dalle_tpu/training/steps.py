@@ -22,12 +22,16 @@ their state buffers so XLA updates parameters in place.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Any, Callable, Dict, Optional
 
 import flax.struct
 import jax
 import jax.numpy as jnp
 import optax
+from jax.sharding import PartitionSpec as P
+
+logger = logging.getLogger(__name__)
 
 
 class TrainState(flax.struct.PyTreeNode):
@@ -65,8 +69,93 @@ def _loss_fn(model, params, batch):
 GRAD_ACCUMULATE_SCOPE = "grad_accumulate"
 
 
+def _reduces_once(mesh) -> bool:
+    """Whether :func:`_accumulate_grads` takes the ``dp`` reduction out of
+    the scans: ``dp > 1``, and at most one other mesh axis larger than 1.
+
+    With two more (dp2 x fsdp2 x tp2) XLA's SPMD partitioner ABORTS the
+    process on some programs once ``dp`` is manual: a gather whose
+    operand is partly replicated over the automatic axes (the embedding
+    lookup of a tp-sharded table with the hidden axis over fsdp) fails a
+    ``Check`` in ``spmd_partitioner_util.cc`` (``ExpandDeviceGroupsWithIota``;
+    jaxlib 0.9.0 on the CPU and libtpu 0.0.34 compiling for a v5e alike).
+    One automatic axis cannot be partly replicated, so there the path is
+    safe; such a mesh keeps the partitioner's in-loop reduction.
+    """
+    if mesh is None or mesh.shape["dp"] == 1:
+        return False
+    return sum(n > 1 for a, n in mesh.shape.items() if a != "dp") <= 1
+
+
+def grad_reduction_plan(mesh) -> str:
+    """Where the gradient is summed over the mesh's ``dp`` axis, in words:
+    what :func:`_accumulate_grads` logs once when a step is traced and
+    the ``setup/warmup`` row of the ``train`` plane carries."""
+    if mesh is None or mesh.size == 1:
+        return "single device: none"
+    dp = mesh.shape["dp"]
+    if dp == 1:
+        return "dp=1: none"
+    if _reduces_once(mesh):
+        return f"over dp={dp}: once per step"
+    return f"over dp={dp}: inside the scans, where the partitioner puts it"
+
+
+@functools.lru_cache(maxsize=None)
+def _log_reduction_plan(plan: str) -> None:
+    logger.info("gradient reduction %s", plan)
+
+
 def _accumulate_grads(model, params, batch, accum_steps: int):
-    """Mean loss/grads over ``accum_steps`` microbatches via lax.scan."""
+    """Mean loss/grads over the batch, ``accum_steps`` microbatches at a
+    time, reduced over the mesh's ``dp`` axis ONCE.
+
+    Left to GSPMD, every weight-gradient matmul contracts over the
+    dp-sharded batch axis, its result is a partial sum, and the
+    partitioner reduces it where it is made: inside the backward body of
+    the weight-shared layer scan, every iteration of every microbatch
+    (the flagship on four chips: ~270 all-reduces and 18 GB a chip a step
+    for a 0.5 GB gradient). So on a mesh with ``dp > 1``
+    (:func:`_reduces_once`) the whole accumulation runs under a
+    ``shard_map`` manual over ``dp``: each shard accumulates the gradient
+    of its own samples in f32, and one ``psum`` follows the scan. An
+    ``fsdp``/``tp``/``sp`` axis larger than 1 stays the partitioner's.
+    """
+    mesh = getattr(model, "mesh", None)
+    _log_reduction_plan(grad_reduction_plan(mesh))
+    if not _reduces_once(mesh):
+        return _shard_grads(model, params, batch, accum_steps)
+    dp = mesh.shape["dp"]
+    if accum_steps > 1 and "mask" in batch:
+        # a masked loss is normalised per microbatch, so which samples
+        # share one matters: deal the batch so that the shards' i-th
+        # microbatches together are the batch's i-th microbatch, as on
+        # one device (tokens only, once a step, outside the scan)
+        def deal(x):
+            n = x.shape[0] // (accum_steps * dp)
+            x = x.reshape(accum_steps, dp, n, *x.shape[1:])
+            return x.swapaxes(0, 1).reshape(-1, *x.shape[3:])
+        batch = jax.tree.map(deal, batch)
+
+    def shard(params, batch):
+        # DALLE normalises by the whole microbatch (its denominators are
+        # summed over dp), so a shard's loss and gradient are its share
+        # of the mean and the shares add up
+        return jax.lax.psum(
+            _shard_grads(model, params, batch, accum_steps), "dp")
+
+    # axes of size 1 split nothing: made manual with dp, they leave a
+    # pure-dp mesh nothing to nest a shard_map for (parallel/mesh.per_shard
+    # then calls its kernel as on one device: 92 fewer shard_maps to trace,
+    # differentiate and lower in the flagship's step)
+    manual = {a for a, n in mesh.shape.items() if a == "dp" or n == 1}
+    return jax.shard_map(shard, mesh=mesh, in_specs=(P(), P("dp")),
+                         out_specs=P(), axis_names=manual,
+                         check_vma=False)(params, batch)
+
+
+def _shard_grads(model, params, batch, accum_steps: int):
+    """Mean loss/grads of the batch in hand via lax.scan over microbatches."""
     if accum_steps <= 1:
         (loss, aux), grads = jax.value_and_grad(
             functools.partial(_loss_fn, model), has_aux=True)(params, batch)

@@ -158,6 +158,19 @@ def round_table(rows: List[dict]) -> List[dict]:
     return out
 
 
+def setup_facts(rows: List[dict]) -> Dict[str, dict]:
+    """What the trainer's set-up rows say beside their duration (plane
+    ``train``, trace ``setup``): e.g. ``setup/warmup``'s ``grad_reduction``,
+    the plan the gradient step took for its sum over ``dp``."""
+    facts: Dict[str, dict] = {}
+    for r in rows:
+        if r["plane"] == "train" and r["trace"] == "setup" and r.get("a") \
+                and r.get("dur_s", 0) > 0:
+            facts.setdefault(f"{r.get('peer', '')} {r['phase']}".strip(),
+                             {}).update(r["a"])
+    return facts
+
+
 def build_report(files: List[str], gap_s: float = 1.0,
                  rounds: bool = False) -> dict:
     per_peer = [load_jsonl(f) for f in files]
@@ -168,6 +181,7 @@ def build_report(files: List[str], gap_s: float = 1.0,
         "traces": len({r["trace"] for r in rows}),
         "peers": sorted({str(r.get("peer", "")) for r in rows}),
         "phases": phase_table(rows),
+        "setup": setup_facts(rows),
         "stragglers": straggler_attribution(rows),
         "gaps": detect_gaps(rows, gap_s=gap_s),
     }
@@ -203,6 +217,9 @@ def main(argv=None) -> int:
     for phase, st in report["phases"].items():
         print(f"{phase:<28}{st['n']:>6}{st['p50_s']:>10.4f}"
               f"{st['p95_s']:>10.4f}{st['max_s']:>10.4f}")
+    for phase, attrs in report["setup"].items():
+        print(f"  {phase}: " + ", ".join(
+            f"{k}={v}" for k, v in sorted(attrs.items())))
     strag = report["stragglers"]
     if strag["straggles_by_peer"]:
         print(f"stragglers ({strag['cells_examined']} multi-peer "

@@ -21,3 +21,33 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_threefry_partitionable", True)
 assert jax.default_backend() == "cpu" and jax.device_count() >= 8
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def inside_manual_dp():
+    """``wrap(vg, mesh, batched, argnums)``: the value-and-grad function
+    ``vg(*args) -> ((loss, out), grads)`` run as the gradient accumulation
+    runs the model (training/steps.py): under a ``shard_map`` manual over
+    ``dp`` alone. Arguments flagged in ``batched`` are split over ``dp``
+    on their first axis (as ``out`` and their gradients are); the others
+    are replicated, and their gradients summed over ``dp``."""
+    from jax.sharding import PartitionSpec as P
+
+    def wrap(vg, mesh, batched, argnums):
+        def spec(i):
+            return P("dp") if batched[i] else P()
+
+        def shard(*args):
+            (loss, out), grads = vg(*args)
+            grads = tuple(g if batched[i] else jax.lax.psum(g, "dp")
+                          for i, g in zip(argnums, grads))
+            return (jax.lax.psum(loss, "dp"), out), grads
+
+        return jax.shard_map(
+            shard, mesh=mesh, in_specs=tuple(map(spec, range(len(batched)))),
+            out_specs=((P(), P("dp")), tuple(map(spec, argnums))),
+            axis_names={"dp"}, check_vma=False)
+    return wrap

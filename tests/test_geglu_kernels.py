@@ -134,11 +134,16 @@ class TestModelIntegration:
         assert ff["wo"]["bias"].shape == (cfg.dim,)
 
 
-def test_per_shard_kernel_matches_single_device(monkeypatch):
+@pytest.mark.parametrize("nested", [False, True],
+                         ids=["whole_mesh", "inside_manual_dp"])
+def test_per_shard_kernel_matches_single_device(nested, monkeypatch,
+                                                inside_manual_dp):
     """On a dp=2 x fsdp=2 x tp=2 mesh the fused FF runs per shard — token
     rows over the batch axes, the inner dimension over tp with one psum
     of the partial products and the output bias added once: values and
-    gradients must equal the unwrapped one-device kernel."""
+    gradients must equal the unwrapped one-device kernel. ``nested``: the
+    call sits where the gradient accumulation puts it, inside a
+    ``shard_map`` manual over ``dp``, and binds the other axes only."""
     from dalle_tpu.config import flagship_model_config
     from dalle_tpu.models import attention
     from dalle_tpu.models.transformer import GEGLUFeedForward
@@ -156,16 +161,19 @@ def test_per_shard_kernel_matches_single_device(monkeypatch):
         lambda p: p + 0.1 * jax.random.normal(jax.random.PRNGKey(3),
                                               p.shape), params)
 
-    def loss(mesh_):
+    def loss(mesh_, nested=False):
         ff = GEGLUFeedForward(cfg, fuse=True, mesh=mesh_)
 
-        def f(p, x):
+        def f(p, x, w):
             out = ff.apply(p, x)
             return jnp.sum(out * w), out
-        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
+        vg = jax.value_and_grad(f, argnums=(0, 1), has_aux=True)
+        if nested:
+            vg = inside_manual_dp(vg, mesh_, (False, True, True), (0, 1))
+        return jax.jit(vg)
 
-    (_, out_m), g_m = loss(mesh)(params, x)
-    (_, out_1), g_1 = loss(None)(params, x)
+    (_, out_m), g_m = loss(mesh, nested)(params, x, w)
+    (_, out_1), g_1 = loss(None)(params, x, w)
     assert len(out_m.sharding.device_set) == 8
     np.testing.assert_allclose(np.asarray(out_m), np.asarray(out_1),
                                rtol=1e-4, atol=1e-5)

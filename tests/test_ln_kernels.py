@@ -5,6 +5,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dalle_tpu.ops.pallas.ln_kernels import layer_norm, ln_supported
 
@@ -152,10 +153,15 @@ class TestModelIntegration:
                                    rtol=1e-6, atol=1e-6)
 
 
-def test_per_shard_kernel_matches_single_device(monkeypatch):
+@pytest.mark.parametrize("nested", [False, True],
+                         ids=["whole_mesh", "inside_manual_dp"])
+def test_per_shard_kernel_matches_single_device(nested, monkeypatch,
+                                                inside_manual_dp):
     """On a dp=2 x fsdp=2 x tp=2 mesh the LayerNorm kernel runs per shard
     of the token rows; the scale/bias gradients are sums over shards.
-    Values and gradients must equal the unwrapped one-device kernel."""
+    Values and gradients must equal the unwrapped one-device kernel.
+    ``nested``: called inside a ``shard_map`` manual over ``dp`` (the
+    gradient accumulation's), it binds the other axes only."""
     from dalle_tpu.config import flagship_model_config
     from dalle_tpu.models import attention
     from dalle_tpu.models.transformer import FusedLayerNorm
@@ -172,16 +178,19 @@ def test_per_shard_kernel_matches_single_device(monkeypatch):
                                                (128,)),
         "bias": 0.1 * jax.random.normal(jax.random.PRNGKey(3), (128,))}}
 
-    def loss(mesh_):
+    def loss(mesh_, nested=False):
         ln = FusedLayerNorm(cfg, mesh=mesh_)
 
-        def f(p, x):
+        def f(p, x, w):
             out = ln.apply(p, x)
             return jnp.sum(out * w), out
-        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
+        vg = jax.value_and_grad(f, argnums=(0, 1), has_aux=True)
+        if nested:
+            vg = inside_manual_dp(vg, mesh_, (False, True, True), (0, 1))
+        return jax.jit(vg)
 
-    (_, out_m), g_m = loss(mesh)(params, x)
-    (_, out_1), g_1 = loss(None)(params, x)
+    (_, out_m), g_m = loss(mesh, nested)(params, x, w)
+    (_, out_1), g_1 = loss(None)(params, x, w)
     assert len(out_m.sharding.device_set) == 8
     np.testing.assert_allclose(np.asarray(out_m), np.asarray(out_1),
                                rtol=1e-5, atol=1e-6)

@@ -174,11 +174,17 @@ class TestRematPolicyPinsKernelReplay:
 
 # one line kernel (with the in-XLA column reorder) and one window kernel:
 # the wrapper is the same for every zoo type
+@pytest.mark.parametrize("nested", [False, True],
+                         ids=["whole_mesh", "inside_manual_dp"])
 @pytest.mark.parametrize("attn_type", [ATTN_AXIAL_COL, "conv_like"])
-def test_per_shard_kernels_match_single_device(attn_type, monkeypatch):
+def test_per_shard_kernels_match_single_device(attn_type, nested,
+                                               monkeypatch,
+                                               inside_manual_dp):
     """GSPMD cannot partition a Mosaic kernel, so on a mesh the dispatcher
     runs the fused kernels per shard (batch over dp x fsdp, heads over
-    tp): values and gradients must equal the unwrapped one-device call."""
+    tp): values and gradients must equal the unwrapped one-device call.
+    ``nested``: called inside a ``shard_map`` manual over ``dp`` (the
+    gradient accumulation's), the wrapper binds the other axes only."""
     from dalle_tpu.models import attention
     from dalle_tpu.parallel.mesh import make_mesh
 
@@ -188,17 +194,19 @@ def test_per_shard_kernels_match_single_device(attn_type, monkeypatch):
     shape = (4, TEXT + GRID * GRID, 4, D)
     q, k, v, w = (jax.random.normal(kk, shape, jnp.float32) for kk in ks)
 
-    def loss(mesh_):
-        def f(q, k, v):
+    def loss(mesh_, nested=False):
+        def f(q, k, v, w):
             out = attention.zoo_attention(
                 q, k, v, attn_type=attn_type, text_len=TEXT, grid=GRID,
                 conv_kernel=3, mesh=mesh_)
             return jnp.sum(out * w), out
-        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
-                                          has_aux=True))
+        vg = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+        if nested:
+            vg = inside_manual_dp(vg, mesh_, (True,) * 4, (0, 1, 2))
+        return jax.jit(vg)
 
-    (_, out_m), g_m = loss(mesh)(q, k, v)
-    (_, out_1), g_1 = loss(None)(q, k, v)
+    (_, out_m), g_m = loss(mesh, nested)(q, k, v, w)
+    (_, out_1), g_1 = loss(None)(q, k, v, w)
     assert len(out_m.sharding.device_set) == 8
     np.testing.assert_allclose(np.asarray(out_m), np.asarray(out_1),
                                rtol=1e-5, atol=1e-6)

@@ -124,3 +124,33 @@ def test_model_loss_and_grads_match_single_device(mode, attn_types,
     for a, b in zip(flat_sp, flat_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-4, atol=5e-5)
+
+
+@pytest.mark.parametrize("mode,attn_types", [
+    ("ring", (ATTN_FULL,)),
+    ("ulysses", (ATTN_AXIAL_ROW, ATTN_AXIAL_COL)),
+])
+def test_grad_step_on_a_dp_mesh_matches_single_device(mode, attn_types):
+    """With dp > 1 the gradient step runs the model inside a ``shard_map``
+    manual over ``dp`` (training/steps.py): the sp programs nest in it,
+    bind the other axes only, and take their ring position as an operand
+    (``axis_index`` does not lower there). One microbatch, so the
+    attention sits in no accumulation scan."""
+    from dalle_tpu.training.steps import make_grad_step
+    cfg = tiny_model_config(attn_types=attn_types, sequence_parallel=mode,
+                            shared_block_cycle=2, depth=4, remat=True)
+    mesh = make_mesh(dp=2, sp=2, devices=jax.devices()[:4])
+    model_ref = DALLE(cfg.__class__(**{
+        **cfg.__dict__, "sequence_parallel": "none"}))
+    params = init_params(model_ref, jax.random.PRNGKey(0))
+    text, image = _batch(cfg)
+    batch = {"text": text, "image": image}
+
+    grads_ref, aux_ref = jax.jit(make_grad_step(model_ref))(params, batch)
+    grads_sp, aux_sp = jax.jit(make_grad_step(DALLE(cfg, mesh=mesh)))(
+        params, batch)
+    np.testing.assert_allclose(float(aux_sp["loss"]), float(aux_ref["loss"]),
+                               rtol=1e-5, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(grads_sp), jax.tree.leaves(grads_ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-5)

@@ -64,6 +64,19 @@ class TestTrainLoopRing:
                  if r["phase"].startswith("setup/")}
         assert setup == {"setup"}
 
+    def test_warmup_row_says_where_the_gradient_is_reduced(self, rows):
+        """The engagement record of training/steps._accumulate_grads: the
+        plan the step took for its sum over dp, on the row of the span in
+        which the step is traced, and in trace_report's output."""
+        from scripts.trace_report import setup_facts
+        ring, _ = rows
+        warm, = [r for r in ring if r["phase"] == "setup/warmup"]
+        dp = jax.device_count()
+        assert warm["a"]["grad_reduction"] == f"over dp={dp}: once per step"
+        shown, = [a for k, a in setup_facts(ring).items()
+                  if k.endswith("setup/warmup")]
+        assert shown["grad_reduction"] == warm["a"]["grad_reduction"]
+
     def test_rows_carry_their_step_and_the_span_that_caused_them(self, rows):
         ring, _ = rows
         steps = [r for r in ring if r["phase"] == "loop/step"]
