@@ -16,8 +16,9 @@ Phases, all but the last counted as set-up:
 3. Mosaic census of the lowered grad step (a dispatcher that gave way to
    XLA is ``correct: false``);
 4. reference check: the system's real grad step on a batch that tiles two
-   seeded sequences, against ``benchmark/reference.py`` on those two
-   (``reference_check_s``);
+   seeded sequences, against the reference of the configuration's
+   yardstick (``cell.yardstick``, ``benchmark/yardsticks/<name>.py``) on
+   those two (``reference_check_s``);
 5. ``train_loop``: one warm-up batch, then the loop's first steps until the
    window is armed;
 6. the window: from one ``on_step`` boundary to the first boundary at or
@@ -48,15 +49,22 @@ import traceback
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
-from benchmark import counts, intervals
-from benchmark.manifest import Cell, reducer
+from benchmark import intervals
+from benchmark.manifest import BenchFailure, Cell, reducer
 
 TRACED_STEPS = 3
 SEED_MODULUS = 2 ** 31 - 1       # the driver's seeds pass 32 signed bits
+PEAKS_FILE = Path(__file__).with_name("peaks.json")
 
 
-class BenchFailure(RuntimeError):
-    """The run cannot give a result (no TPU, a phase of set-up failed)."""
+def peaks_for(device_kind: str) -> Dict[str, float]:
+    """The peaks of one chip of this kind; a kind not in the table is an
+    error, never a default."""
+    table = json.loads(PEAKS_FILE.read_text())
+    if device_kind not in table or device_kind.startswith("_"):
+        raise KeyError(f"no peaks for device kind {device_kind!r} in "
+                       f"{PEAKS_FILE.name}: add a row with its source")
+    return table[device_kind]
 
 
 class _WindowOver(Exception):
@@ -377,13 +385,13 @@ def census_check(task, cell: Cell, batch) -> Dict[str, Any]:
 def reference_check(task, cell: Cell, seed: int) -> Dict[str, Any]:
     """The system's loss and gradients, through its real jitted grad step
     on a batch that tiles two seeded sequences, against the plain
-    reference on those two sequences. The mean over the tiled batch is the
-    mean over the two, so no second program is compiled for the check."""
+    reference of the configuration's yardstick on those two sequences. The
+    mean over the tiled batch is the mean over the two, so no second
+    program is compiled for the check."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark import reference
     from dalle_tpu.parallel.mesh import batch_sharding
 
     model = cell.config["model"]
@@ -405,7 +413,7 @@ def reference_check(task, cell: Cell, seed: int) -> Dict[str, Any]:
     on_dev = lambda tree: jax.tree.map(
         lambda a: jax.device_put(np.asarray(a) if len(a.devices()) > 1
                                  else a, dev), tree)
-    ref_loss, ref_grads = reference.loss_and_grads(
+    ref_loss, ref_grads = cell.yardstick.loss_and_grads(
         on_dev(params), jnp.asarray(text2), jnp.asarray(image2), model,
         checkpoint_blocks=True)
     ref_loss = float(ref_loss)
@@ -478,7 +486,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
             raise BenchFailure(f"{cell.name} asks for {cell.chips} chip(s), "
                                f"JAX finds {len(devices)}")
         kind = devices[0].device_kind
-        peaks = counts.peaks_for(kind) if require_backend else \
+        peaks = peaks_for(kind) if require_backend else \
             {"bf16_flops_per_s": float("nan"),
              "hbm_bytes_per_s": float("nan")}
         compiles = CompileLog()
@@ -502,7 +510,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         jax.block_until_ready(task.train_state)
         t = mark("init_s", t)
         local_batch = task.local_batch_size
-        tokens_per_step = local_batch * counts.tokens_per_sample(
+        tokens_per_step = local_batch * cell.yardstick.tokens_per_sample(
             cell.config["model"])
 
         # -- 4 (and 3): reference check, then the census -------------------
@@ -591,8 +599,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
                   program_reserved_bytes=reserved_peak,
                   grad_step_plan_bytes=census["grad_step_plan_bytes"])
     ctx = RunContext(
-        model=cell.config["model"], chips=cell.chips, peaks=peaks,
-        values=values,
+        model=cell.config["model"], yardstick=cell.yardstick,
+        chips=cell.chips, peaks=peaks, values=values,
         spans=spans.inside(last_stamps[0], last_stamps[-1]) if spans else {},
         trace=reduced, traced_steps=window.traced_steps,
         samples_per_step=local_batch)

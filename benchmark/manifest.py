@@ -8,16 +8,22 @@ per-layer metric is a file of its own, found by the name in
 - traffic mix ``<t>``    -> ``benchmark/traffic/<t>.json``
 - per-layer metric ``<m>`` -> ``benchmark/layer_metrics/<m>.json``, which
   names a reducer ``<r>`` -> ``benchmark/reducers/<r>.py`` (``read(ctx, **params)``)
+- the configuration file's ``yardstick`` ``<y>`` (absent: ``dalle``) ->
+  ``benchmark/yardsticks/<y>.py`` under the manifest's own root: the
+  architecture's plain reference and counts (contract: ``yardsticks/dalle.py``)
 
-so a later PR adds a cell by adding files and one entry, and edits nothing.
+so a later PR adds a cell, or a configuration of another architecture, by
+adding files and entries, and edits nothing.
 """
 
 from __future__ import annotations
 
 import importlib
+import importlib.util
 import json
 import re
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -25,6 +31,11 @@ HERE = Path(__file__).resolve().parent
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+
+
+class BenchFailure(RuntimeError):
+    """The run cannot give a result (no TPU, a phase of set-up failed, a
+    file the manifest names is not there)."""
 
 
 def _load(path: Path) -> Dict[str, Any]:
@@ -42,6 +53,8 @@ class Cell:
         self.traffic_name: str = entry["traffic"]
         cfg_entry = manifest.configs[self.config_name]
         self.config = _load(manifest.root / cfg_entry["file"])
+        self.yardstick = manifest.yardstick(
+            self.config.get("yardstick", "dalle"))
         self.traffic = _load(manifest.traffic_file(self.traffic_name))
         self.end_to_end: List[Dict[str, Any]] = [
             m for m in manifest.data["end_to_end"] if self._has(m)]
@@ -66,6 +79,22 @@ class Manifest:
 
     def metric_file(self, name: str) -> Path:
         return self.dir / "layer_metrics" / f"{name}.json"
+
+    def yardstick(self, name: str) -> ModuleType:
+        """``<benchmark dir>/yardsticks/<name>.py``, loaded by its path: a
+        file a later PR (or a test's throw-away root) adds is found with no
+        edit to the package. An unknown name is an error, never ``dalle``."""
+        path = self.dir / "yardsticks" / f"{name}.py"
+        if not (isinstance(name, str) and NAME.match(name)
+                and path.is_file()):
+            have = sorted(p.stem for p in path.parent.glob("*.py"))
+            raise BenchFailure(f"no yardstick {name!r} under {path.parent}; "
+                               f"have {have}")
+        spec = importlib.util.spec_from_file_location(
+            f"benchmark_yardstick_{name}", path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        return module
 
     def cell(self, name: str) -> Cell:
         if name not in self.cells:

@@ -38,19 +38,18 @@ def main(argv=None) -> int:
                              "trace land (default .benchmark_out/<run>)")
     args = parser.parse_args(argv)
 
-    from benchmark.manifest import ROOT, Manifest
-    cell = Manifest().cell(args.workload)
-    out_dir = args.out_dir or (
-        ROOT / ".benchmark_out"
-        / f"{cell.name}-seed{args.seed}-trace{args.trace}")
-
     from benchmark import harness, intervals
+    from benchmark.manifest import ROOT, BenchFailure, Manifest
     try:
+        cell = Manifest().cell(args.workload)
+        out_dir = args.out_dir or (
+            ROOT / ".benchmark_out"
+            / f"{cell.name}-seed{args.seed}-trace{args.trace}")
         result = harness.run_cell(
             cell, seed=args.seed, seconds=args.seconds,
             trace=bool(args.trace), out_dir=out_dir, t_start=T_START,
             repeat=args.repeat)
-    except (harness.BenchFailure, intervals.TooFewIntervals) as e:
+    except (BenchFailure, intervals.TooFewIntervals) as e:
         print(f"benchmark: no result: {e}", file=sys.stderr, flush=True)
         return 3
     print(json.dumps(result), flush=True)

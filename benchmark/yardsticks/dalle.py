@@ -1,14 +1,44 @@
-"""Plain reference of the DALL-E forward pass and loss: the yardstick that
-decides ``correct``.
+"""The ``dalle`` yardstick: what the benchmark knows about the DALL-E
+architecture of learning-at-home/dalle (dalle-pytorch's DALLE as ``task.py:62-83``
+configures it) — the plain reference that decides ``correct``, and the
+counts that turn a rate into ``mfu_pct`` and a kernel's device time into
+its roofline share.
 
-Written from the model's equations (dalle-pytorch's DALLE as
-learning-at-home/dalle ``task.py:62-83`` configures it), not by calling
-``dalle_tpu.models``: ``jax.numpy`` in float32 under
-``default_matmul_precision("highest")``, dense masked attention, no flax
-module, no kernel, no cache. It takes
-only the numbers of a configuration file (``model`` group) and the
-parameter tree (the names flax gives the system's parameters are the one
-thing shared with the program).
+**The contract of a yardstick** (every ``benchmark/yardsticks/<name>.py``;
+``tests/benchmark_tests/test_benchmark_yardstick.py`` holds each to it). A
+configuration file names its yardstick (``"yardstick": "<name>"``, absent:
+``dalle``); ``Manifest.yardstick`` loads the file by its path under the
+manifest's root, and harness and reducers reach it only as
+``cell.yardstick`` / ``ctx.yardstick``. ``model`` is the ``model`` group
+of the configuration file. The module holds:
+
+- ``loss_and_grads(params, text, image, model, checkpoint_blocks=False)
+  -> (loss, grads)``: the architecture's loss and every parameter's
+  gradient, written from its equations in ``jax.numpy``, float32 under
+  ``jax.default_matmul_precision("highest")``: no flax module, no kernel,
+  no cache, nothing imported from the program. ``grads`` has the
+  structure of ``params`` (the harness zips their leaves);
+- ``tokens_per_sample(model)`` and ``train_flops_per_sample(model)``: the
+  operations the forward and backward passes *require* of one sample —
+  matmuls at 2 flops a multiply-add, attention over the allowed (query,
+  key) pairs only, backward at twice the forward. Replays under
+  rematerialisation, an overhanging scan block and masked-out score tiles
+  are work the program chose, not work the model needs, and are not
+  counted. ``train_tokens_per_s`` and ``mfu_pct`` read them;
+- any number of *least seconds* functions ``f(model, peaks) ->
+  {"seconds": ...}``: the least time one chip can spend in one kernel
+  family over one sample's forward and backward pass, per call the larger
+  of flops / peak and bytes / bandwidth. A ``layer_metrics/<m>.json`` of
+  the ``kernel_roofline`` reducer names one under ``least``. Here:
+  ``attention_min_seconds_per_sample``.
+
+The chip's peaks (``peaks.json``, ``harness.peaks_for``) belong to no
+architecture and stay common.
+
+**The reference.** It takes only the numbers of a configuration file and
+the parameter tree (the names flax gives the system's parameters are the
+one thing shared with the program): dense masked attention, pre-norm
+LayerNorm, rotate-half rotary, GEGLU, the tied table as the head.
 
 Two departures from "plain", noted where they are made (``_run_layers``):
 the layers run as a ``lax.scan`` that picks each layer's parameters and
@@ -227,3 +257,74 @@ def loss_and_grads(params, text, image, model: Mapping[str, Any],
         loss = loss + loss_i
         grads = jax.tree.map(jnp.add, grads, grads_i)
     return loss / n, jax.tree.map(lambda g: g / n, grads)
+
+
+# -- the counts: operations and bytes from shapes alone ----------------------
+
+def tokens_per_sample(model: Mapping[str, Any]) -> int:
+    return model["text_seq_len"] + model["image_grid"] ** 2
+
+
+def attention_pairs(model: Mapping[str, Any], attn_type: str) -> int:
+    """Allowed (query, key) pairs of one head of one sequence."""
+    return int(attention_mask(attn_type, model["text_seq_len"],
+                              model["image_grid"],
+                              model["conv_kernel"]).sum())
+
+
+def block_matmul_params(model: Mapping[str, Any]) -> int:
+    """Weights one token is multiplied by in one block: q, k, v, out
+    projections and the GEGLU feed-forward's value, gate and output."""
+    d, inner = model["dim"], model["ff_mult"] * model["dim"]
+    return 4 * d * d + 3 * d * inner
+
+
+def head_params_per_token(model: Mapping[str, Any]) -> float:
+    """Rows of the tied table a position is scored against, times dim,
+    averaged over the sequence: text positions see the text rows only and
+    image positions the image rows."""
+    tl, il = model["text_seq_len"], model["image_grid"] ** 2
+    rows = (tl * model["vocab_text"] + il * model["vocab_image"]) / (tl + il)
+    return rows * model["dim"]
+
+
+def effective_params(model: Mapping[str, Any]) -> float:
+    """Weights a token meets on its way through the model (shared blocks
+    count once per layer that applies them)."""
+    return (model["depth"] * block_matmul_params(model)
+            + head_params_per_token(model))
+
+
+def attention_flops_forward(model: Mapping[str, Any], attn_type: str) -> int:
+    """QK^T and PV of one sequence, all heads, allowed pairs only."""
+    return (4 * attention_pairs(model, attn_type) * model["head_dim"]
+            * model["heads"])
+
+
+def train_flops_per_sample(model: Mapping[str, Any]) -> float:
+    """Forward plus backward (2x forward) of one sample."""
+    fwd = 2 * effective_params(model) * tokens_per_sample(model)
+    fwd += sum(attention_flops_forward(model, kind)
+               for _, kind in layer_schedule(model))
+    return 3.0 * fwd
+
+
+def attention_min_seconds_per_sample(model: Mapping[str, Any],
+                                     peaks: Mapping[str, float],
+                                     act_bytes: int = 2) -> Dict[str, float]:
+    """The least time one chip can spend in the attention of one sample's
+    forward and backward pass: per layer and direction the larger of
+    flops / peak and bytes / bandwidth. Forward reads q, k, v and writes
+    the context (4 tensors of T x dim); backward reads q, k, v, context and
+    its cotangent and writes dq, dk, dv (8 tensors) at twice the flops.
+    Returns the seconds and how much of them is bound by bandwidth."""
+    tensor = tokens_per_sample(model) * model["dim"] * act_bytes
+    total = by_bytes = 0.0
+    for _, kind in layer_schedule(model):
+        flops = attention_flops_forward(model, kind)
+        for n_tensors, mult in ((4, 1.0), (8, 2.0)):
+            t_flops = mult * flops / peaks["bf16_flops_per_s"]
+            t_bytes = n_tensors * tensor / peaks["hbm_bytes_per_s"]
+            total += max(t_flops, t_bytes)
+            by_bytes += t_bytes if t_bytes >= t_flops else 0.0
+    return {"seconds": total, "bandwidth_bound_share": by_bytes / total}

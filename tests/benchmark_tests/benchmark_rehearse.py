@@ -1,10 +1,12 @@
 """CPU rehearsal of the benchmark command at a tiny size (test-only hook).
 
-    python tests/benchmark_tests/benchmark_rehearse.py <chips> <trace 0|1> <out dir> [dtype]
+    python tests/benchmark_tests/benchmark_rehearse.py <chips> <trace 0|1> <out dir> [dtype [yardstick]]
 
 Builds a throw-away manifest root with one tiny cell (the flagship's layer
-pattern at the tiny preset's widths: shared axial blocks, scan, conv block),
-then calls ``harness.run_cell`` with ``require_backend=None`` and interpreted
+pattern at the tiny preset's widths: shared axial blocks, scan, conv block;
+the ``dalle`` yardstick unless one is named, which may be a file the caller
+has put under ``<out dir>/root/benchmark/yardsticks/`` beforehand), then
+calls ``harness.run_cell`` with ``require_backend=None`` and interpreted
 kernels. What it prints is a rehearsal, never a result: its last line starts
 with ``REHEARSAL``. Run it from the root of the repo with ``JAX_PLATFORMS=cpu``
 and, for ``chips`` > 1, ``XLA_FLAGS=--xla_force_host_platform_device_count=<chips>``.
@@ -26,7 +28,7 @@ from dalle_tpu.config import tiny_model_config  # noqa: E402
 PATTERN = ("axial_row", "axial_col", "axial_row", "axial_row")
 
 
-def tiny_root(tmp: Path, chips=1, dtype="float32"):
+def tiny_root(tmp: Path, chips=1, dtype="float32", yardstick=None):
     tmp.mkdir(parents=True, exist_ok=True)
     b = json.loads((ROOT / "BENCHMARK.json").read_text())
     over = dict(shared_block_cycle=4, attn_types=PATTERN,
@@ -37,14 +39,18 @@ def tiny_root(tmp: Path, chips=1, dtype="float32"):
     d = tmp / "benchmark"
     for sub in ("configs", "traffic"):
         (d / sub).mkdir(parents=True, exist_ok=True)
-    shutil.copytree(ROOT / "benchmark" / "layer_metrics",
-                    d / "layer_metrics", dirs_exist_ok=True)
+    for sub in ("layer_metrics", "yardsticks"):
+        shutil.copytree(ROOT / "benchmark" / sub, d / sub,
+                        dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     exact = dtype == "float32"
     cfg = {"name": "tiny", "preset": "tiny", "model": m, "reduced": [],
            "source": "test", "mosaic_kernels": [],
            "tolerance": {"loss_rel": 1e-4 if exact else 3e-2,
                          "grad_rel_l2": 1e-3 if exact else 0.2,
                          "reason": "test"}}
+    if yardstick is not None:
+        cfg["yardstick"] = yardstick
     (d / "configs" / "tiny.json").write_text(json.dumps(cfg))
     traffic = {"per_device_batch": 2, "grad_accum_steps": 2,
                "target_batch_size": 1 << 30, "setup_steps": 2,
@@ -69,7 +75,7 @@ if __name__ == "__main__":
     chips, trace = (int(a) for a in sys.argv[1:3])
     out = Path(sys.argv[3])
     dtype = sys.argv[4] if len(sys.argv) > 4 else "float32"
-    cell = tiny_root(out / "root", chips, dtype)
+    cell = tiny_root(out / "root", chips, dtype, *sys.argv[5:6])
     res = harness.run_cell(
         cell, seed=2**31 + 12345, seconds=float(os.environ.get("SECS", "4")),
         trace=bool(trace), out_dir=out / "run", t_start=T0,

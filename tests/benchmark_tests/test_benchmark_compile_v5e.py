@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from benchmark import reference
 from benchmark.manifest import Manifest
 
 
@@ -50,6 +49,7 @@ def test_reference_check_program_fits_one_v5e(config, one_chip,
     from dalle_tpu.cli.run_trainer import MODEL_PRESETS
     from dalle_tpu.models.dalle import DALLE, init_params
     man = Manifest()
+    reference = man.yardstick("dalle")
     model = json.loads(
         (man.root / man.configs[config]["file"]).read_text())["model"]
     shapes = jax.eval_shape(lambda: init_params(

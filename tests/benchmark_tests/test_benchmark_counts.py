@@ -1,7 +1,11 @@
-"""The FLOP and byte counters against counts made by hand at a tiny shape."""
+"""The ``dalle`` yardstick's FLOP and byte counters against counts made by
+hand at a tiny shape, and the chip's table of peaks."""
 import pytest
 
-from benchmark import counts
+from benchmark import harness
+from benchmark.manifest import Manifest
+
+counts = Manifest().yardstick("dalle")
 
 TINY = {"vocab_text": 128, "vocab_image": 64, "text_seq_len": 4,
         "image_grid": 2, "dim": 8, "depth": 3, "heads": 2, "head_dim": 4,
@@ -49,8 +53,8 @@ def test_attention_roofline_time_by_hand():
 
 
 def test_unknown_device_kind_is_an_error():
-    assert counts.peaks_for("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+    assert harness.peaks_for("TPU v5 lite")["bf16_flops_per_s"] == 197e12
     with pytest.raises(KeyError):
-        counts.peaks_for("TPU v9")
+        harness.peaks_for("TPU v9")
     with pytest.raises(KeyError):
-        counts.peaks_for("_source")
+        harness.peaks_for("_source")

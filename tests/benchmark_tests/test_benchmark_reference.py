@@ -1,6 +1,7 @@
-"""The plain reference against the system at the tiny preset's widths on
-the CPU, in the flagship's layer pattern (shared axial blocks in a scan
-with an overhanging iteration, a final conv block, rotary, tied head)."""
+"""The ``dalle`` yardstick's plain reference against the system at the tiny
+preset's widths on the CPU, in the flagship's layer pattern (shared axial
+blocks in a scan with an overhanging iteration, a final conv block, rotary,
+tied head)."""
 import dataclasses
 
 import jax
@@ -8,9 +9,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import reference
+from benchmark.manifest import Manifest
 from dalle_tpu.config import tiny_model_config
 from dalle_tpu.models.dalle import DALLE, init_params
+
+reference = Manifest().yardstick("dalle")
 
 PATTERN = dict(shared_block_cycle=4, final_conv_block=True, depth=10,
                scan_unroll=2, conv_kernel=3,
