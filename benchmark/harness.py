@@ -565,6 +565,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         compile_by_program[program] += secs
 
     reduced = None
+    t_trace = time.perf_counter()
     if trace:
         from benchmark import trace as trace_mod
         raw = trace_mod.load_xplane(trace_mod.find_xplane(trace_dir))
@@ -576,6 +577,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
             if require_backend is not None:
                 raise BenchFailure(str(e)) from e
         shutil.rmtree(trace_dir, ignore_errors=True)   # the digest stays
+    trace_read_s = time.perf_counter() - t_trace
 
     results = []
     for w in window.windows:
@@ -636,7 +638,9 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
                     "cache_misses": compiles.cache_misses,
                     "cache_dir": cache_dir,
                     "memory_stats_after_window": fullest,
-                    "shutdown_s": round(t_end - last_stamps[-1], 3)}))
+                    "shutdown_s": round(t_end - last_stamps[-1], 3),
+                    # reading and reducing the trace file, after the window
+                    "trace_read_s": round(trace_read_s, 3)}))
     say(json.dumps({"reference_check": ref, "census": census}))
     for i, r in enumerate(results):
         say(json.dumps({"window": i, **r}))
@@ -653,4 +657,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         (out_dir / "device_ops.json").write_text(json.dumps(
             sorted(reduced.seconds_by_name().items(),
                    key=lambda kv: -kv[1]), indent=0))
+        # the same by scope path: what a ``scope`` expression is written from
+        (out_dir / "device_scopes.json").write_text(json.dumps(
+            reduced.seconds_by_name_and_scope()[:400], indent=0))
     return result

@@ -14,6 +14,36 @@ per-layer metric is a file of its own, found by the name in
 
 so a later PR adds a cell, or a configuration of another architecture, by
 adding files and entries, and edits nothing.
+
+**What a configuration's file holds** (``tests/benchmark_tests/
+benchmark_checks.py:configuration_file`` holds every configuration of
+``BENCHMARK.json`` to it, and a stand-in of another architecture in a
+throw-away root besides):
+
+- ``name``, ``preset``, ``source`` (equal to the entry's), ``model``,
+  ``reduced`` (equal to the entry's), ``assumed``, ``mosaic_kernels``,
+  ``tolerance``; optionally ``yardstick``, ``deployment``, notes.
+- ``model`` is ``dataclasses.asdict(MODEL_PRESETS[preset]())`` of
+  ``cli/run_trainer``, tuples as lists: the preset **as it is run**, cut
+  included. The dataclass may be of any class; the harness itself reads
+  five of its fields to draw the check's batch and judge the parameters
+  (``vocab_text``, ``text_seq_len``, ``vocab_image``, ``image_grid``,
+  ``param_dtype``), the yardstick whatever it needs.
+- every name in ``reduced`` (keys changed from the source: depth, the
+  experts or heads held here, a slice of the vocabulary; never a width)
+  and in ``assumed`` (sizes no public source gives, e.g. the split of a
+  sequence into the trainer's ``text`` / ``image`` fields) is a key of
+  ``model``.
+- a **cut** configuration (``reduced`` not empty) also holds
+  ``published``: the source's value for exactly the keys in ``reduced``,
+  each different from the value held; ``layer_shared_by``: the number
+  (>= 1) of chips that share each layer in the deployment the cut stands
+  for (guide ``model-configs`` section 4: not all the chips the model
+  needs); and ``deployment``, that deployment in words. An uncut one
+  (``reduced`` empty) has neither ``published`` nor ``layer_shared_by``.
+- ``flagship`` and ``xl`` are pinned besides to what they are:
+  ``reduced`` ``[]`` in both, ``assumed`` ``[]`` and ``dim``, ``heads``,
+  ``vocab_image``.
 """
 
 from __future__ import annotations

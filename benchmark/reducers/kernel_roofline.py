@@ -5,14 +5,15 @@ the configuration's yardstick that gives the least seconds of one sample
 (``f(model, peaks) -> {"seconds": ...}``: per call the larger of
 flops/peak and bytes/bandwidth; ``yardsticks/dalle.py`` has
 ``attention_min_seconds_per_sample``). A new kernel's share is one
-``layer_metrics/<m>.json`` naming this reducer and one such function."""
+``layer_metrics/<m>.json`` naming this reducer and one such function; with
+``scope`` only the kernels issued under a matching scope path count."""
 
 
-def read(ctx, pattern, least):
+def read(ctx, pattern, least, scope=None):
     tr = ctx.trace
     if tr is None or not ctx.traced_steps:
         return None
-    spent = tr.seconds_matching(pattern)
+    spent = tr.seconds_matching(pattern, scope)
     if not spent:
         return None
     seconds = getattr(ctx.yardstick, least)(ctx.model, ctx.peaks)["seconds"]

@@ -8,8 +8,29 @@ as a JSON fixture and every PR computes the same numbers the same way::
                  "lines": [{"name": "XLA Ops",
                             "events": [[name, start_ns, duration_ns], ...]}]}]}
 
+A device event may carry a fourth element, the **scope path** under which
+the program issued the operation (``[name, start_ns, duration_ns, scope]``,
+``""`` where there is none; an event of three elements has none).
 ``load_xplane`` turns the ``.xplane.pb`` file JAX's profiler writes into
-that form with nothing but JAX.
+that form with ``benchmark/xspace.py``, a reader of the file's wire format:
+the path is the ``tf_op`` stat of the event's *metadata*, which
+``jax.profiler.ProfileData`` does not hand out.
+The path is JAX's name stack, as the flagship's grad step wrote it on the
+chip (PR 27; a trailing ``:`` is the empty type of ``name:type``)::
+
+    jit(grad_step)/while/body/closed_call/jvp(DALLE)/DALLE.backbone/transformer/while/body/closed_call/cycle/block_0/ff/dot_general:
+    jit(grad_step)/while/body/closed_call/transpose(jvp(DALLE))/DALLE.backbone/transformer/while/body/closed_call/cycle/cycle/checkpoint/rematted_computation/block_0/attn/v/dot_general:
+    jit(grad_step)/while/body/closed_call/grad_accumulate/add:
+
+The first ``while`` is the accumulation scan, ``jvp(DALLE)`` the forward
+and ``transpose(jvp(DALLE))`` the backward pass, ``transformer/while`` the
+layer scan, ``checkpoint/rematted_computation`` a block computed again;
+``block_0/ff``, ``attn/v``, ``head``, ``ce``, ``embed``, ``grad_accumulate``
+are the program's flax modules and ``jax.named_scope``s. A fusion carries
+the path of the one operation XLA named it after, so what was fused into a
+matmul's fusion (a residual's store, a LayerNorm reduction) counts with
+that matmul's module. ``Reduced.seconds_matching`` and its siblings take a
+``scope`` expression, searched in the path (``""`` where there is none).
 
 Device operations are the events of the ``XLA Ops`` line of each
 ``/device:TPU:<n>`` plane. The profiler names an event by the whole HLO
@@ -29,7 +50,8 @@ from __future__ import annotations
 
 import re
 from pathlib import Path
-from typing import Any, Dict, Iterable, List, Mapping, Sequence, Tuple
+from typing import (Any, Callable, Dict, Iterable, List, Mapping, Optional,
+                    Sequence, Tuple)
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OP_LINE = "XLA Ops"
@@ -39,7 +61,9 @@ WINDOW_SPAN = "bench/traced_window"
 MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
 _INSTRUCTION = re.compile(r"^%?([^\s=(]+?)(?:\.\d+)*(?=\s|=|$)")
 
-Event = Tuple[str, int, int]          # name, start_ns, duration_ns
+SCOPE_STAT = "tf_op"
+
+Event = Tuple[Any, ...]               # name, start_ns, duration_ns[, scope]
 Interval = Tuple[int, int]            # start_ns, end_ns
 
 
@@ -51,20 +75,32 @@ def op_key(name: str) -> str:
 
 
 def load_xplane(path: Path) -> Dict[str, Any]:
-    from jax.profiler import ProfileData
-    data = ProfileData.from_file(str(path))
+    from benchmark import xspace
+
+    def want_line(plane: str, line: str) -> bool:
+        # of a device plane only its operations
+        return line == OP_LINE or not DEVICE_PLANE.match(plane)
+
     planes = []
-    for plane in data.planes:
-        on_device = bool(DEVICE_PLANE.match(plane.name))
+    for plane in xspace.read(Path(path).read_bytes(), want_line):
+        on_device = bool(DEVICE_PLANE.match(plane["name"]))
+        meta = plane["metadata"]
+        # a device operation: its short name and its path, worked out once
+        # per metadata and not per event
+        short = {mid: (op_key(m["name"]), m["stats"].get(SCOPE_STAT) or "")
+                 for mid, m in meta.items()} if on_device else {}
         lines = []
-        for line in plane.lines:
-            if on_device and line.name != OP_LINE:
-                continue
-            lines.append({"name": line.name, "events": [
-                [op_key(e.name) if on_device else e.name,
-                 int(e.start_ns), int(e.duration_ns)]
-                for e in line.events]})
-        planes.append({"name": plane.name, "lines": lines})
+        for line in plane["lines"]:
+            events = []
+            for mid, start, dur in line["events"]:
+                if on_device:
+                    name, scope = short.get(mid, ("", ""))
+                    events.append([name, start, dur, scope])
+                else:
+                    events.append([meta[mid]["name"] if mid in meta else "",
+                                   start, dur])
+            lines.append({"name": line["name"], "events": events})
+        planes.append({"name": plane["name"], "lines": lines})
     return {"planes": planes}
 
 
@@ -98,7 +134,7 @@ def device_ops(trace: Mapping[str, Any]) -> Dict[int, List[Event]]:
 
 
 def host_spans(trace: Mapping[str, Any]) -> List[Event]:
-    return sorted((tuple(e) for plane in trace["planes"]
+    return sorted((tuple(e[:3]) for plane in trace["planes"]
                    if not DEVICE_PLANE.match(plane["name"])
                    for line in plane["lines"] for e in line["events"]
                    if e[0].startswith(SPAN_PREFIX)), key=lambda e: e[1])
@@ -117,14 +153,14 @@ def traced_window(trace: Mapping[str, Any]) -> Interval:
 
 
 def self_times(events: Sequence[Event], window: Interval
-               ) -> List[Tuple[str, int, int, int, bool]]:
-    """(name, start, end, self_ns, is_leaf) of each event clipped to the
-    window; ``events`` sorted by (start, -duration). An event that lies
+               ) -> List[Tuple[str, int, int, int, bool, str]]:
+    """(name, start, end, self_ns, is_leaf, scope) of each event clipped to
+    the window; ``events`` sorted by (start, -duration). An event that lies
     inside an earlier, still open one is its child."""
     lo, hi = window
     out: List[List[Any]] = []
     stack: List[int] = []
-    for name, start, dur in events:
+    for name, start, dur, *scope in events:
         s, e = max(start, lo), min(start + dur, hi)
         if e <= s:
             continue
@@ -136,7 +172,7 @@ def self_times(events: Sequence[Event], window: Interval
             parent = out[stack[-1]]
             parent[3] -= min(e, parent[2]) - s
             parent[4] = False
-        out.append([name, s, e, e - s, True])
+        out.append([name, s, e, e - s, True, scope[0] if scope else ""])
         stack.append(len(out) - 1)
     return [tuple(o) for o in out]
 
@@ -174,6 +210,17 @@ def total(intervals: Iterable[Interval]) -> int:
     return sum(e - s for s, e in intervals)
 
 
+def _selector(pattern: str, scope: Optional[str]
+              ) -> Callable[[str, str], bool]:
+    """Whether an operation's name matches ``pattern`` and, where ``scope``
+    is given, its path matches that."""
+    rx = re.compile(pattern)
+    if scope is None:
+        return lambda name, path: bool(rx.search(name))
+    sx = re.compile(scope)
+    return lambda name, path: bool(rx.search(name) and sx.search(path))
+
+
 class Reduced:
     """One trace reduced: what every trace-fed per-layer metric reads."""
 
@@ -184,6 +231,8 @@ class Reduced:
             dev: self_times(evs, self.window)
             for dev, evs in device_ops(trace).items()}
         self.devices = sorted(self.per_device)
+        self._ns: Optional[Dict[Tuple[str, str], int]] = None
+        self._busy: Dict[int, List[Interval]] = {}
         if not self.devices:
             raise ValueError("the trace holds no device operation")
 
@@ -192,8 +241,11 @@ class Reduced:
         return (self.window[1] - self.window[0]) / 1e9
 
     def busy(self, dev: int) -> List[Interval]:
-        return union((s, e) for _, s, e, _, leaf in self.per_device[dev]
-                     if leaf)
+        if dev not in self._busy:      # every share of busy time asks
+            self._busy[dev] = union(
+                (s, e) for _, s, e, _, leaf, _ in self.per_device[dev]
+                if leaf)
+        return self._busy[dev]
 
     @property
     def busy_s(self) -> float:
@@ -201,35 +253,66 @@ class Reduced:
         return sum(total(self.busy(d)) for d in self.devices) \
             / len(self.devices) / 1e9
 
+    def ns_by_name_and_scope(self) -> Dict[Tuple[str, str], int]:
+        """Self nanoseconds by (operation name, scope path), summed over
+        the devices, in the order first met: the one pass over the events
+        that every share is then read from."""
+        if self._ns is None:
+            acc: Dict[Tuple[str, str], int] = {}
+            for dev in self.devices:
+                for name, _, _, self_ns, _, scope in self.per_device[dev]:
+                    key = (name, scope)
+                    acc[key] = acc.get(key, 0) + self_ns
+            self._ns = acc
+        return self._ns
+
     def seconds_by_name(self) -> Dict[str, float]:
         """Self seconds by operation name, averaged over the devices."""
         acc: Dict[str, float] = {}
-        for dev in self.devices:
-            for name, _, _, self_ns, _ in self.per_device[dev]:
-                acc[name] = acc.get(name, 0.0) + self_ns
+        for (name, _), self_ns in self.ns_by_name_and_scope().items():
+            acc[name] = acc.get(name, 0.0) + self_ns
         return {k: v / len(self.devices) / 1e9 for k, v in acc.items()}
 
-    def seconds_matching(self, pattern: str) -> float:
-        rx = re.compile(pattern)
-        return sum(v for k, v in self.seconds_by_name().items()
-                   if rx.search(k))
+    def seconds_by_name_and_scope(self) -> List[Tuple[str, str, float]]:
+        """(name, scope, self seconds averaged over the devices), largest
+        first: what to read before writing a ``scope`` expression."""
+        return sorted(((n, p, v / len(self.devices) / 1e9) for (n, p), v
+                       in self.ns_by_name_and_scope().items()),
+                      key=lambda row: -row[2])
 
-    def matching_intervals(self, dev: int, pattern: str) -> List[Interval]:
+    def seconds_matching(self, pattern: str,
+                         scope: Optional[str] = None) -> float:
+        """Self seconds of the operations whose name matches ``pattern``
+        and, where ``scope`` is given, whose path matches that too (both
+        searched, not anchored; an event with no path has the path "")."""
         rx = re.compile(pattern)
-        return union((s, e) for n, s, e, _, leaf in self.per_device[dev]
-                     if leaf and rx.search(n))
+        if scope is None:   # summed name by name, as before there were paths
+            return sum(v for k, v in self.seconds_by_name().items()
+                       if rx.search(k))
+        sx = re.compile(scope)
+        return sum(v for (n, p), v in self.ns_by_name_and_scope().items()
+                   if rx.search(n) and sx.search(p)) \
+            / len(self.devices) / 1e9
 
-    def exposed_seconds(self, pattern: str) -> float:
+    def matching_intervals(self, dev: int, pattern: str,
+                           scope: Optional[str] = None) -> List[Interval]:
+        mine = _selector(pattern, scope)
+        return union((s, e) for n, s, e, _, leaf, p in self.per_device[dev]
+                     if leaf and mine(n, p))
+
+    def exposed_seconds(self, pattern: str,
+                        scope: Optional[str] = None) -> float:
         """Seconds (mean over devices) in which an operation matching
-        ``pattern`` ran and no other operation did on that device."""
-        rx = re.compile(pattern)
+        ``pattern`` (and ``scope``) ran and no other operation did on that
+        device."""
+        mine = _selector(pattern, scope)
         acc = 0
         for dev in self.devices:
-            mine = self.matching_intervals(dev, pattern)
-            others = union((s, e) for n, s, e, _, leaf
+            others = union((s, e) for n, s, e, _, leaf, p
                            in self.per_device[dev]
-                           if leaf and not rx.search(n))
-            acc += total(subtract(mine, others))
+                           if leaf and not mine(n, p))
+            acc += total(subtract(
+                self.matching_intervals(dev, pattern, scope), others))
         return acc / len(self.devices) / 1e9
 
     def idle_gaps_by_span(self) -> Dict[str, float]:

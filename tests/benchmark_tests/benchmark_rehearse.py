@@ -11,7 +11,6 @@ kernels. What it prints is a rehearsal, never a result: its last line starts
 with ``REHEARSAL``. Run it from the root of the repo with ``JAX_PLATFORMS=cpu``
 and, for ``chips`` > 1, ``XLA_FLAGS=--xla_force_host_platform_device_count=<chips>``.
 """
-import dataclasses
 import json
 import os
 import shutil
@@ -21,21 +20,44 @@ from pathlib import Path
 
 T0 = time.perf_counter()
 
+from benchmark_checks import as_run  # noqa: E402
+
 from benchmark import harness  # noqa: E402
 from benchmark.manifest import ROOT, Manifest  # noqa: E402
-from dalle_tpu.config import tiny_model_config  # noqa: E402
 
 PATTERN = ("axial_row", "axial_col", "axial_row", "axial_row")
 
 
-def tiny_root(tmp: Path, chips=1, dtype="float32", yardstick=None):
-    tmp.mkdir(parents=True, exist_ok=True)
-    b = json.loads((ROOT / "BENCHMARK.json").read_text())
+def tiny_dalle(dtype="float32"):
+    """Today's tiny cell: the overrides of preset ``tiny`` and the same as
+    ``run_trainer`` flags."""
     over = dict(shared_block_cycle=4, attn_types=PATTERN,
                 final_conv_block=True, depth=10, scan_unroll=2,
                 conv_kernel=3, dtype=dtype)
-    m = dataclasses.asdict(tiny_model_config(**over))
-    m = {k: (list(v) if isinstance(v, tuple) else v) for k, v in m.items()}
+    args = ["--shared-block-cycle", 4, "--attn-types", *PATTERN,
+            "--final-conv-block", "--depth", 10, "--scan-unroll", 2,
+            "--conv-kernel", 3, "--dtype", dtype]
+    return over, args
+
+
+def tiny_root(tmp: Path, chips=1, dtype="float32", yardstick=None, *,
+              preset="tiny", overrides=None, trainer_args=None):
+    """A throw-away manifest root with one cell ``tiny-cell``. ``preset``
+    names an entry of ``run_trainer.MODEL_PRESETS`` (a dataclass of any
+    class), ``overrides`` the fields set on it and ``trainer_args`` the
+    same as ``run_trainer`` flags: the two have to describe one model, or
+    ``harness.check_model`` refuses the run. Left out, they are today's
+    tiny DALL-E at ``dtype``. So a later PR rehearses a tiny preset of
+    another architecture through ``harness.run_cell`` from a test file of
+    its own, with its yardstick written under ``tmp`` beforehand."""
+    from dalle_tpu.cli.run_trainer import MODEL_PRESETS
+    tmp.mkdir(parents=True, exist_ok=True)
+    b = json.loads((ROOT / "BENCHMARK.json").read_text())
+    if overrides is None or trainer_args is None:
+        default_over, default_args = tiny_dalle(dtype)
+        overrides = default_over if overrides is None else overrides
+        trainer_args = default_args if trainer_args is None else trainer_args
+    m = as_run(MODEL_PRESETS[preset](**overrides))
     d = tmp / "benchmark"
     for sub in ("configs", "traffic"):
         (d / sub).mkdir(parents=True, exist_ok=True)
@@ -44,8 +66,8 @@ def tiny_root(tmp: Path, chips=1, dtype="float32", yardstick=None):
                         dirs_exist_ok=True,
                         ignore=shutil.ignore_patterns("__pycache__"))
     exact = dtype == "float32"
-    cfg = {"name": "tiny", "preset": "tiny", "model": m, "reduced": [],
-           "source": "test", "mosaic_kernels": [],
+    cfg = {"name": "tiny", "preset": preset, "model": m, "reduced": [],
+           "assumed": [], "source": "test", "mosaic_kernels": [],
            "tolerance": {"loss_rel": 1e-4 if exact else 3e-2,
                          "grad_rel_l2": 1e-3 if exact else 0.2,
                          "reason": "test"}}
@@ -54,10 +76,7 @@ def tiny_root(tmp: Path, chips=1, dtype="float32", yardstick=None):
     (d / "configs" / "tiny.json").write_text(json.dumps(cfg))
     traffic = {"per_device_batch": 2, "grad_accum_steps": 2,
                "target_batch_size": 1 << 30, "setup_steps": 2,
-               "trainer_args": [
-                   "--shared-block-cycle", 4, "--attn-types", *PATTERN,
-                   "--final-conv-block", "--depth", 10, "--scan-unroll", 2,
-                   "--conv-kernel", 3, "--dtype", dtype]}
+               "trainer_args": list(trainer_args)}
     (d / "traffic" / "t.json").write_text(json.dumps(traffic))
     b["configs"] = [{"name": "tiny", "source": "test", "reduced": [],
                      "file": "benchmark/configs/tiny.json", "why": "t"}]

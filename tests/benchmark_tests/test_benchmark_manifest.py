@@ -6,9 +6,12 @@ entries is found."""
 import dataclasses
 import json
 import shutil
+import types
 
+import benchmark_checks as checks
 import pytest
 
+from benchmark import harness
 from benchmark import manifest as M
 from benchmark.harness import RunContext
 from dalle_tpu.cli.run_trainer import MODEL_PRESETS
@@ -18,83 +21,29 @@ CELLS = sorted(MAN.cells)
 
 
 def test_manifest_shape():
-    d = MAN.data
-    assert set(d) == {"command", "paths", "run_seconds", "configs",
-                      "workloads", "end_to_end", "per_layer"}
-    assert 1 <= d["run_seconds"] <= 51 and isinstance(d["run_seconds"], int)
-    assert "setup_s" in {m["name"] for m in d["end_to_end"]}
-    four = [w for w in d["workloads"] if w["chips"] == 4]
-    assert len(four) <= max(1, len(d["workloads"]) // 4)
-    names = [m["name"] for m in d["end_to_end"] + d["per_layer"]]
-    assert len(names) == len(set(names))
-    assert len(json.dumps(d)) < 64 * 1024
-    assert d["paths"] == ["benchmark", "tests/benchmark_tests"]
-    assert not any(w.startswith("/") or ".." in w for w in d["command"])
-    used = {w["config"] for w in d["workloads"]}
-    assert used == set(MAN.configs)
+    checks.manifest_shape(MAN)
 
 
 @pytest.mark.parametrize("cell_name", CELLS)
 def test_cell_resolves_its_files(cell_name):
-    cell = MAN.cell(cell_name)
-    assert cell.chips in (1, 4)
-    assert cell.config["name"] == cell.config_name
-    assert cell.traffic["per_device_batch"] >= 1
-    assert {m["name"] for m in cell.end_to_end} >= {"setup_s",
-                                                    "train_tokens_per_s"}
-    assert cell.per_layer
-    e2e = {m["name"] for m in cell.end_to_end}
-    for m in cell.per_layer:
-        assert m["moves"] in e2e
-        assert callable(M.reducer(m["reducer"]))
+    checks.cell_resolves_its_files(MAN, cell_name)
 
 
 def test_names_units_and_whys_keep_to_the_contract():
-    d = MAN.data
-    for entry in d["configs"] + d["workloads"] + d["end_to_end"] \
-            + d["per_layer"]:
-        assert M.NAME.match(entry["name"]), entry["name"]
-    for w in d["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert M.NAME.match(w["config"]) and M.NAME.match(w["traffic"])
-        assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
-    for c in d["configs"]:
-        assert 1 <= len(c["why"]) <= 200 and 1 <= len(c["source"]) <= 200
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-    for m in d["end_to_end"]:
-        assert set(m) == {"name", "unit", "better", "bound", "source"}
-        assert 0 < m["bound"] <= 0.1
-        assert m["source"] in ("host_clock", "device_trace")
-    for m in d["per_layer"]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better",
-                                          "source", "layer", "moves"}
-        assert m["source"] in M.SOURCES
-        assert M.UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
-        if m["name"].endswith("_roofline"):
-            assert m["unit"] == "%"
+    checks.names_units_and_whys(MAN)
 
 
 @pytest.mark.parametrize("metric", [m["name"]
                                     for m in MAN.data["per_layer"]])
 def test_metric_file_agrees_with_the_manifest(metric):
-    entry = next(m for m in MAN.data["per_layer"] if m["name"] == metric)
-    on_file = json.loads(MAN.metric_file(metric).read_text())
-    for key in ("name", "layer", "unit", "better", "source", "moves"):
-        assert on_file[key] == entry[key], key
+    checks.metric_file_agrees(MAN, metric)
 
 
 @pytest.mark.parametrize("config", sorted(MAN.configs))
 def test_configuration_file_holds_the_preset_as_run(config):
-    entry = MAN.configs[config]
-    on_file = json.loads((MAN.root / entry["file"]).read_text())
-    ran = dataclasses.asdict(MODEL_PRESETS[on_file["preset"]]())
-    ran = {k: list(v) if isinstance(v, tuple) else v for k, v in ran.items()}
-    assert on_file["model"] == ran
-    assert on_file["reduced"] == entry["reduced"] == []
-    assert on_file["source"] == entry["source"]
-    # a size no public source gives is listed as assumed
-    assert set(on_file["assumed"]) == (
-        {"dim", "heads", "vocab_image"} if config == "xl" else set())
+    """The rule of ``benchmark/manifest.py``'s docstring; ``flagship`` and
+    ``xl`` are pinned besides to exactly what they are."""
+    checks.configuration_file(MAN, config, MODEL_PRESETS)
 
 
 def _copy_of_the_benchmark(tmp_path):
@@ -150,53 +99,126 @@ def loss_and_grads(params, text, image, model, checkpoint_blocks=False):
 
 
 def tokens_per_sample(model):
-    return model["seq_len"]
+    return model["text_seq_len"] + model["image_grid"] ** 2
 
 
 def train_flops_per_sample(model):
-    return 6.0 * model["weights"] * model["seq_len"]
+    active = model["layers"] * model["experts_routed"] * 3 * model[
+        "hidden"] * model["expert_width"]
+    return 6.0 * active * tokens_per_sample(model)
 
 
 def router_min_seconds_per_sample(model, peaks):
-    flops = 2.0 * model["seq_len"] * model["hidden"] * model["experts"]
+    flops = 2.0 * tokens_per_sample(model) * model["hidden"] * model[
+        "experts_published"] * model["layers"]
     return {"seconds": 3.0 * flops / peaks["bf16_flops_per_s"]}
 '''
 
 
-def test_a_configuration_added_as_files_and_entries_is_found(tmp_path):
-    """What a ``model_config`` PR brings: a configuration file, its
-    yardstick file, a roofline metric as one ``layer_metrics`` file naming
-    the common reducer and the yardstick's function, and the entries that
-    name them. No file the benchmark had is edited, and the new cell reads
-    its kernel's share with no reducer code."""
+@dataclasses.dataclass(frozen=True)
+class StandInConfig:
+    """A preset of another class than the program's ``ModelConfig``, cut to
+    one chip's share: the five fields the harness reads of every model, and
+    a few of its own."""
+    vocab_text: int = 37984                 # a quarter of 151 936 rows
+    vocab_image: int = 8192
+    text_seq_len: int = 1024
+    image_grid: int = 32
+    param_dtype: str = "float32"
+    hidden: int = 2560
+    expert_width: int = 768
+    layers: int = 4                         # one period of 52
+    layer_pattern: tuple = ("global", "window", "window", "window")
+    experts_published: int = 64             # the router's width, never cut
+    experts_held: int = 16
+    experts_routed: int = 6
+    norm_eps: float = 1e-6
+
+
+STANDIN = "standin"
+STANDIN_FILE = {
+    "name": STANDIN, "preset": STANDIN, "yardstick": "other",
+    "source": "test", "reduced": ["layers", "experts_held", "vocab_text"],
+    "assumed": ["text_seq_len", "image_grid"],
+    "published": {"layers": 52, "experts_held": 64, "vocab_text": 151936},
+    "layer_shared_by": 4,
+    "deployment": "each layer shared by the four chips of one host; this "
+                  "chip holds 16 of 64 experts and a quarter of the "
+                  "vocabulary, and one period of the layer pattern",
+    "mosaic_kernels": [],
+    "tolerance": {"loss_rel": 1e-4, "grad_rel_l2": 1e-3, "reason": "test"}}
+
+
+def _standin_root(tmp_path, presets, mutate=None):
+    """A throw-away root with the stand-in added as files and entries.
+    ``mutate(data, on_file)`` spoils the manifest's data or the stand-in's
+    file before they are written."""
     data = _copy_of_the_benchmark(tmp_path)
-    before = {p: p.read_bytes() for p in (tmp_path / "benchmark").rglob("*")
-              if p.is_file()}
     d = tmp_path / "benchmark"
     (d / "yardsticks/other.py").write_text(OTHER_YARDSTICK)
-    model = {"seq_len": 2048, "hidden": 2048, "experts": 64, "weights": 1e9}
-    (d / "configs/other.json").write_text(json.dumps(
-        {"name": "other", "yardstick": "other", "model": model}))
+    on_file = dict(json.loads(json.dumps(STANDIN_FILE)),
+                   model=checks.as_run(presets[STANDIN]()))
     roofline = dict(json.loads(MAN.metric_file("attn_roofline").read_text()),
                     name="router_roofline",
                     params={"pattern": r"^router\[mosaic\]$",
                             "least": "router_min_seconds_per_sample"})
     (d / "layer_metrics/router_roofline.json").write_text(
         json.dumps(roofline))
-    data["configs"].append({"name": "other", "source": "test", "reduced": [],
-                            "file": "benchmark/configs/other.json",
+    data["configs"].append({"name": STANDIN, "source": "test",
+                            "reduced": list(on_file["reduced"]),
+                            "file": f"benchmark/configs/{STANDIN}.json",
                             "why": "test"})
-    data["workloads"].append({"name": "other-train-solo", "config": "other",
+    data["workloads"].append({"name": "standin-train-solo",
+                              "config": STANDIN,
                               "traffic": MAN.cell(CELLS[0]).traffic_name,
                               "chips": 1, "why": "test"})
     data["per_layer"].append(
-        _per_layer_entry(roofline, ["other-train-solo"]))
+        _per_layer_entry(roofline, ["standin-train-solo"]))
+    if mutate is not None:
+        mutate(data, on_file)
+    (d / f"configs/{STANDIN}.json").write_text(json.dumps(on_file))
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(data))
+    return M.Manifest(tmp_path), roofline
 
-    man = M.Manifest(tmp_path)
-    cell = man.cell("other-train-solo")
+
+@pytest.fixture()
+def presets(monkeypatch):
+    """``MODEL_PRESETS`` with the stand-in's preset registered, as the
+    ``model_config`` PR's program would have it."""
+    monkeypatch.setitem(MODEL_PRESETS, STANDIN, StandInConfig)
+    return MODEL_PRESETS
+
+
+def test_a_configuration_added_as_files_and_entries_is_found(tmp_path,
+                                                             presets):
+    """What a ``model_config`` PR brings: a configuration file (here one
+    **cut to a chip's share**, of a dataclass that is not the program's
+    ``ModelConfig``), its yardstick file, a roofline metric as one
+    ``layer_metrics`` file naming the common reducer and the yardstick's
+    function, and the entries that name them. It then meets **every** check
+    the suite applies per configuration, cell, metric and yardstick — the
+    same functions the parametrised tests call — and ``check_model``. No
+    file the benchmark had is edited, and the new cell reads its kernel's
+    share with no reducer code."""
+    _copy_of_the_benchmark(tmp_path / "before")
+    before = {p.relative_to(tmp_path / "before"): p.read_bytes()
+              for p in (tmp_path / "before/benchmark").rglob("*")
+              if p.is_file()}
+    man, roofline = _standin_root(tmp_path / "root", presets)
+    d = man.dir
+
+    checks.every_check(man, presets)
+    cell = man.cell("standin-train-solo")
+    model = cell.config["model"]
+    assert model["layer_pattern"] == ["global", "window", "window", "window"]
+    stub = types.SimpleNamespace(model_cfg=StandInConfig())
+    harness.check_model(stub, cell)
+    with pytest.raises(M.BenchFailure, match="standin: experts_held is 8"):
+        harness.check_model(types.SimpleNamespace(
+            model_cfg=StandInConfig(experts_held=8)), cell)
+
     assert cell.yardstick.__file__ == str(d / "yardsticks/other.py")
-    assert cell.yardstick.tokens_per_sample(cell.config["model"]) == 2048
+    assert cell.yardstick.tokens_per_sample(model) == 2048
     read = {m["name"]: m for m in cell.per_layer}
     assert "router_roofline" in read and "attn_roofline" not in read
     assert "mfu_pct" in read          # a metric of every cell comes along
@@ -207,7 +229,7 @@ def test_a_configuration_added_as_files_and_entries_is_found(tmp_path):
 
     class Trace:
         @staticmethod
-        def seconds_matching(pattern):
+        def seconds_matching(pattern, scope=None):
             return 0.5 if pattern == roofline["params"]["pattern"] else 0.0
 
     peaks = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
@@ -216,9 +238,113 @@ def test_a_configuration_added_as_files_and_entries_is_found(tmp_path):
                      samples_per_step=8,
                      values={"train_tokens_per_s": 5000.0})
     m = read["router_roofline"]
-    least = 3.0 * 2.0 * 2048 * 2048 * 64 / 1e12
+    least = 3.0 * 2.0 * 2048 * 2560 * 64 * 4 / 1e12
     assert M.reducer(m["reducer"])(ctx, **m["params"]) == pytest.approx(
         100 * least * 3 * 8 / 0.5)
+    flops_per_token = 6.0 * 4 * 6 * 3 * 2560 * 768
     assert M.reducer(read["mfu_pct"]["reducer"])(ctx) == pytest.approx(
-        100 * 6.0 * 1e9 * 5000.0 / 1e12)
-    assert all(p.read_bytes() == raw for p, raw in before.items())
+        100 * flops_per_token * 5000.0 / 1e12)
+    after = {p.relative_to(tmp_path / "root"): p.read_bytes()
+             for p in d.rglob("*")
+             if p.is_file() and "__pycache__" not in p.parts}
+    assert {p: raw for p, raw in after.items() if p in before} == before
+    assert sorted(str(p) for p in set(after) - set(before)) == [
+        "benchmark/configs/standin.json",
+        "benchmark/layer_metrics/router_roofline.json",
+        "benchmark/yardsticks/other.py"]
+
+
+def _no_such_key(data, on_file):
+    on_file["reduced"].append("no_such_key")
+    on_file["published"]["no_such_key"] = 1
+    data["configs"][-1]["reduced"].append("no_such_key")
+
+
+def _no_published(data, on_file):
+    del on_file["published"]
+
+
+def _entry_disagrees(data, on_file):
+    data["configs"][-1]["reduced"] = ["layers"]
+
+
+def _cut_flagship(root):
+    """``flagship`` given a cut that the general rule would admit: only
+    its pin refuses it. (A throw-away copy: no PR may edit the real one.)"""
+    def mutate(data, on_file):
+        path = root / "benchmark/configs/flagship.json"
+        flagship = json.loads(path.read_text())
+        flagship.update(reduced=["depth"], published={"depth": 128},
+                        layer_shared_by=1)
+        path.write_text(json.dumps(flagship))
+        data["configs"][0]["reduced"] = ["depth"]
+    return mutate
+
+
+@pytest.mark.parametrize("case, config, message", [
+    ("no_such_key", STANDIN,
+     r"configuration standin: reduced names 'no_such_key'.*no key of model"),
+    ("no_published", STANDIN,
+     r"configuration standin: reduced is \[.*\] and the file has no "
+     r"published"),
+    ("entry_disagrees", STANDIN,
+     r"configuration standin: reduced is \['layers', 'experts_held', "
+     r"'vocab_text'\] in the file and \['layers'\] in BENCHMARK.json"),
+    ("cut_flagship", "flagship",
+     r"configuration flagship: reduced is pinned to \[\], not \['depth'\]"),
+])
+def test_a_configuration_that_breaks_the_rule_is_refused(
+        tmp_path, presets, case, config, message):
+    mutate = {"no_such_key": _no_such_key, "no_published": _no_published,
+              "entry_disagrees": _entry_disagrees,
+              "cut_flagship": _cut_flagship(tmp_path)}[case]
+    man, _ = _standin_root(tmp_path, presets, mutate)
+    with pytest.raises(AssertionError, match=message):
+        checks.configuration_file(man, config, presets)
+    # the other configurations of that root still pass
+    for other in sorted(set(man.configs) - {config}):
+        checks.configuration_file(man, other, presets)
+
+
+@pytest.mark.parametrize("config, key, value", [
+    ("flagship", "assumed", ["dim"]), ("xl", "assumed", []),
+    ("xl", "assumed", ["dim", "heads"])])
+def test_the_two_pinned_configurations_keep_their_assumed(
+        tmp_path, presets, config, key, value):
+    def mutate(data, on_file):
+        path = tmp_path / f"benchmark/configs/{config}.json"
+        path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                        **{key: value})))
+    man, _ = _standin_root(tmp_path, presets, mutate)
+    with pytest.raises(AssertionError,
+                       match=f"configuration {config}: {key} is pinned"):
+        checks.configuration_file(man, config, presets)
+
+
+def test_the_rehearsal_root_takes_a_preset_of_another_class(tmp_path,
+                                                            presets):
+    """``benchmark_rehearse.tiny_root`` builds its one cell from any preset,
+    its overrides and its ``run_trainer`` flags; left out, today's tiny
+    DALL-E."""
+    import benchmark_rehearse
+    cell = benchmark_rehearse.tiny_root(
+        tmp_path / "other", yardstick="dalle", preset=STANDIN,
+        overrides={"experts_held": 8, "layers": 2},
+        trainer_args=["--experts-held", 8, "--layers", 2])
+    assert cell.config["preset"] == STANDIN
+    assert cell.config["model"] == checks.as_run(
+        StandInConfig(experts_held=8, layers=2))
+    assert cell.traffic["trainer_args"] == ["--experts-held", 8,
+                                            "--layers", 2]
+    harness.check_model(types.SimpleNamespace(
+        model_cfg=StandInConfig(experts_held=8, layers=2)), cell)
+    argv = harness.trainer_argv(cell, seed=5)
+    assert argv[:2] == ["--preset", STANDIN] and argv[-2:] == ["--layers",
+                                                               "2"]
+    plain = benchmark_rehearse.tiny_root(tmp_path / "plain")
+    over, args = benchmark_rehearse.tiny_dalle("float32")
+    assert plain.config["preset"] == "tiny"
+    assert plain.traffic["trainer_args"] == [
+        list(a) if isinstance(a, tuple) else a for a in args]
+    assert plain.config["model"]["depth"] == over["depth"] == 10
+    assert plain.config["model"]["attn_types"] == list(over["attn_types"])

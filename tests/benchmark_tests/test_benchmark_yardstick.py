@@ -4,14 +4,13 @@ unknown one is an error, and harness and reducers really read through it —
 a second yardstick, written here into a throw-away root, changes
 ``correct``, the reference loss and the FLOPs behind ``mfu_pct`` with not
 one edit to a file of the benchmark."""
-import ast
-import inspect
 import json
 import os
 import subprocess
 import sys
 from pathlib import Path
 
+import benchmark_checks as checks
 import pytest
 
 from benchmark import manifest as M
@@ -43,58 +42,14 @@ def train_flops_per_sample(model):
 '''
 
 
-def _cells_of(config):
-    return [MAN.cell(w["name"]) for w in MAN.data["workloads"]
-            if w["config"] == config]
-
-
 @pytest.mark.parametrize("config", sorted(MAN.configs))
 def test_configuration_resolves_a_yardstick_that_counts(config):
-    on_file = json.loads((MAN.root / MAN.configs[config]["file"]).read_text())
-    cells = _cells_of(config)
-    assert cells
-    peaks = peaks_for("TPU v5 lite")
-    for cell in cells:
-        y, model = cell.yardstick, cell.config["model"]
-        assert Path(y.__file__) == MAN.dir / "yardsticks" / (
-            on_file.get("yardstick", "dalle") + ".py")
-        assert callable(y.loss_and_grads)
-        tokens = y.tokens_per_sample(model)
-        assert isinstance(tokens, int) and tokens > 0
-        assert 0 < y.train_flops_per_sample(model) < float("inf")
-        # every roofline share this cell reads names a function that is there
-        for m in cell.per_layer:
-            if m["reducer"] == "kernel_roofline":
-                least = getattr(y, m["params"]["least"])(model, peaks)
-                assert 0 < least["seconds"] < float("inf")
+    checks.configuration_resolves_a_yardstick_that_counts(MAN, config)
 
 
 @pytest.mark.parametrize("name", YARDSTICKS)
 def test_yardstick_module_keeps_the_contract(name):
-    y = MAN.yardstick(name)
-
-    def names(f):
-        return list(inspect.signature(f).parameters)
-
-    assert names(y.loss_and_grads) == ["params", "text", "image", "model",
-                                       "checkpoint_blocks"]
-    assert inspect.signature(y.loss_and_grads).parameters[
-        "checkpoint_blocks"].default is False
-    assert names(y.tokens_per_sample) == ["model"]
-    assert names(y.train_flops_per_sample) == ["model"]
-    used = {json.loads(p.read_text()).get("params", {}).get("least")
-            for p in (MAN.dir / "layer_metrics").glob("*.json")} - {None}
-    for least in used & set(dir(y)):
-        assert names(getattr(y, least))[:2] == ["model", "peaks"]
-    # the reference takes nothing from the program
-    tree = ast.parse(Path(y.__file__).read_text())
-    imported = {a.name for n in ast.walk(tree) if isinstance(n, ast.Import)
-                for a in n.names} | {
-        n.module or "" for n in ast.walk(tree)
-        if isinstance(n, ast.ImportFrom)}
-    assert not [m for m in imported
-                if m.split(".")[0] in ("dalle_tpu", "flax")], imported
-    assert (y.__doc__ or "").strip()
+    checks.yardstick_module_keeps_the_contract(MAN, name)
 
 
 def test_absent_key_is_dalle_and_an_unknown_name_is_an_error(tmp_path):
@@ -114,7 +69,7 @@ class _Trace:
     """Stands for a reduced trace in which the matched kernels ran 2 s."""
 
     @staticmethod
-    def seconds_matching(pattern):
+    def seconds_matching(pattern, scope=None):
         return 2.0
 
 
