@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import functools
 import logging
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,8 +43,9 @@ from dalle_tpu.config import (
     ATTN_AXIAL_ROW,
     ATTN_CONV_LIKE,
     ATTN_FULL,
+    SP_ULYSSES,
 )
-from dalle_tpu.parallel.mesh import HEADS_SPEC, per_shard
+from dalle_tpu.parallel.mesh import LANES_SPEC, per_shard
 
 logger = logging.getLogger(__name__)
 
@@ -69,48 +70,75 @@ def log_kernel_choice(site: str, kernel: bool, why: str) -> None:
                 "Pallas kernel" if kernel else "XLA lowering", why)
 
 
+# (attn_type, head_dim, local width, tokens, text_len) -> whether the
+# dispatcher gave a traced call of those local shapes the Pallas kernel.
+# Keyed by everything the choice is made from, so that another model or
+# shape traced in the same process (eval, a second config) neither
+# vouches for this one nor taints it: what attn_layout_record reads.
+_KERNEL_CHOICES: Dict[Tuple[str, int, int, int, int], bool] = {}
+
+
+def attn_layout_record(cfg, mesh=None) -> str:
+    """The attention layers whose traced calls took the lane-dense kernel,
+    in words — the ``attn_layout`` attribute of the ``train`` plane's
+    ``setup/warmup`` row. Looked up, for this model's own local shapes
+    (``mesh``'s ``tp`` splits the heads' lanes; Ulysses its ``sp`` too),
+    in what the dispatcher did while tracing, not worked out from the
+    shapes again: a layer never traced, one that fell back, or a run with
+    no Mosaic backend at all counts as not on the kernel."""
+    from dalle_tpu.ops.pallas.attention_kernels import LANES
+
+    shards = 1
+    if mesh is not None:
+        shards = mesh.shape.get("tp", 1)
+        if cfg.sequence_parallel == SP_ULYSSES:
+            shards *= mesh.shape.get("sp", 1)
+    width = cfg.heads * cfg.head_dim // shards
+    sched = cfg.layer_schedule()
+    on = sum(_KERNEL_CHOICES.get(
+        (t, cfg.head_dim, width, cfg.total_seq_len, cfg.text_seq_len), False)
+        for _, t in sched)
+    return f"lane-dense {LANES}: {on} of {len(sched)} layers"
+
+
 # ---------------------------------------------------------------------------
 # Rotary position embeddings (reference: rotary_emb=True, task.py:80)
 # ---------------------------------------------------------------------------
 
 def rotary_cos_sin(positions: jax.Array, head_dim: int,
-                   base: float = 10000.0) -> Tuple[jax.Array, jax.Array]:
-    """cos/sin tables for the given absolute positions, shape (..., head_dim)."""
+                   base: float = 10000.0,
+                   heads: int = 1) -> Tuple[jax.Array, jax.Array]:
+    """cos/sin tables for the given absolute positions, shape
+    (..., heads * head_dim): one head's table, repeated for ``heads`` heads
+    side by side (the (B, T, H*d) layout of :func:`apply_rotary_lanes`)."""
     half = head_dim // 2
     freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
-    angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., half)
-    angles = jnp.concatenate([angles, angles], axis=-1)        # (..., head_dim)
+    # tiled while still one row: no (T, head_dim) table is ever built
+    angles = (positions.astype(jnp.float32)[..., None]
+              * jnp.tile(freqs, 2 * heads))
     return jnp.cos(angles), jnp.sin(angles)
 
 
-@functools.lru_cache(maxsize=8)
-def _rotation_matrix(head_dim: int) -> np.ndarray:
-    """(d, d) matrix R with x @ R == rotate_half(x) == concat(-x2, x1).
-
-    The concat/slice lowering of rotate_half costs two HBM copies per q/k
-    per layer (it was the largest single line in the step profile); as a
-    tiny matmul it rides the MXU and fuses with the surrounding elementwise
-    multiply-adds.
-    """
+def apply_rotary_lanes(x: jax.Array, cos: jax.Array, sin: jax.Array,
+                       head_dim: int) -> jax.Array:
+    """Rotary on the projections' own (..., H*d) array; cos/sin
+    broadcastable to it (:func:`rotary_cos_sin` with ``heads``):
+    ``x * cos + rotate_half(x) * sin`` in f32 a head, with no array of
+    minor dimension ``head_dim`` in between. ``rotate_half`` (a head's
+    ``concat(-x2, x1)``) is a shift by half a head along the lanes, up
+    for a head's first half and down for its second, which fuses into the
+    multiply-adds around it. (As a matmul, ``x @ kron(I, R)`` on a
+    (..., H*d/128, 128) view, XLA lays the result out tokens-minor, at
+    one transposing copy an operand and direction.)"""
     half = head_dim // 2
-    r = np.zeros((head_dim, head_dim), dtype=np.float32)
-    for i in range(half):
-        r[half + i, i] = -1.0   # out[..., :half] = -x2
-        r[i, half + i] = 1.0    # out[..., half:] = x1
-    return r
-
-
-def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Apply rotary embedding. x: (..., T, H, d); cos/sin: (T, d) or (..., T, d)."""
-    if cos.ndim < x.ndim:  # insert the heads axis for broadcasting
-        cos = cos[..., :, None, :]
-        sin = sin[..., :, None, :]
-    xf = x.astype(jnp.float32)
-    rot = jnp.einsum("...d,de->...e", xf,
-                     jnp.asarray(_rotation_matrix(x.shape[-1])),
-                     preferred_element_type=jnp.float32)
-    out = xf * cos + rot * sin
-    return out.astype(x.dtype)
+    rest = [(0, 0)] * (x.ndim - 1)
+    # shifted in x's own dtype, widened after: the projection then hands
+    # this fusion its bf16 result, not an f32 copy of it
+    up = jnp.pad(x[..., half:], rest + [(0, half)])       # x[lane + half]
+    down = jnp.pad(x[..., :-half], rest + [(half, 0)])    # x[lane - half]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (x.shape[-1],), 0)
+    rot = jnp.where(lane % head_dim < half, -up, down).astype(jnp.float32)
+    return (x.astype(jnp.float32) * cos + rot * sin).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -240,6 +268,28 @@ def _axial_lines(q_g: jax.Array, k_g: jax.Array, v_g: jax.Array,
     return out.astype(q_g.dtype)
 
 
+def _as_lanes(fn, q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """``fn`` on the (B, T, H*d) view of (B, T, H, d) operands: the
+    kernels' own layout (the reshape of a row-major array is a bitcast)."""
+    b, t, h, d = q.shape
+    return fn(*(x.reshape(b, t, h * d) for x in (q, k, v))).reshape(q.shape)
+
+
+def _fused_lanes(q: jax.Array, k: jax.Array, v: jax.Array, head_dim: int,
+                 attn_type: str, text_len: int, grid: int, conv_kernel: int,
+                 interpret: bool) -> jax.Array:
+    """A zoo layer's Pallas kernel on (B, T, H*d) operands: the line
+    kernel for the axial types, the window kernel for conv_like / full."""
+    from dalle_tpu.ops.pallas.attention_kernels import (line_attention,
+                                                        window_attention)
+
+    if attn_type in (ATTN_AXIAL_ROW, ATTN_AXIAL_COL):
+        return line_attention(q, k, v, head_dim, text_len, grid,
+                              attn_type == ATTN_AXIAL_COL, interpret)
+    hw = conv_kernel // 2 if attn_type == ATTN_CONV_LIKE else None
+    return window_attention(q, k, v, head_dim, text_len, grid, hw, interpret)
+
+
 def axial_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
                           attn_type: str, text_len: int, grid: int,
                           interpret: bool = False) -> jax.Array:
@@ -247,23 +297,14 @@ def axial_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
     only (flash-attention style, with a custom backward); the XLA lowering
     of the same math materialized them in HBM at ~31% of the train step.
 
-    Operands are (B, T, H, d); the kernels want heads-major (B, H, T, d),
-    so each call pays explicit swapaxes relayouts. A variant emitting
-    heads-major straight from the q/k/v projections measured ~12% slower
-    overall (XLA's transposed-epilogue matmuls cost more than these
-    transposes), so the copies stay. ``interpret=True`` runs the kernels
-    on CPU for tests."""
-    from dalle_tpu.ops.pallas.attention_kernels import line_attention
-
-    q, k, v = (x.swapaxes(1, 2) for x in (q, k, v))
-    q_t, k_t, v_t = (x[:, :, :text_len] for x in (q, k, v))
-    q_i, k_i, v_i = (x[:, :, text_len:] for x in (q, k, v))
-    out_t = line_attention(q_t, k_t, v_t, None, None,
-                           text_len, 0, False, interpret)
-    out_i = line_attention(q_i, k_i, v_i, k_t, v_t,
-                           grid, grid, attn_type == ATTN_AXIAL_COL,
-                           interpret)
-    return jnp.concatenate([out_t, out_i], axis=2).swapaxes(1, 2)
+    Operands are (B, T, H, d) here for the tests' and the XLA lowering's
+    sake; the kernel reads them as the (B, T, H*d) array they are in
+    memory (ops/pallas/attention_kernels.py: no heads-major copy, text and
+    image rows in one call). ``interpret=True`` runs the kernel on CPU
+    for tests."""
+    return _as_lanes(
+        lambda *qkv: _fused_lanes(*qkv, q.shape[-1], attn_type, text_len,
+                                  grid, 0, interpret), q, k, v)
 
 
 def axial_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -304,46 +345,18 @@ def axial_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                            "attn_ctx")
 
 
-def _window_fits_vmem(qshape, text_len: int, grid: int,
-                      budget_bytes: int = 12 * 2 ** 20) -> bool:
-    """Whether the window kernel's per-grid-step VMEM footprint fits.
-
-    The backward kernel holds ~11 whole-(T, D) refs (q/k/v, o/do, dq/dk/dv,
-    prefix pairs) at 2 heads per step plus two (T, D) f32 scratch
-    accumulators; past ~2k image tokens (e.g. the long-context 64x64 grid)
-    that exceeds the ~16 MB VMEM budget and the dense XLA path — or, for
-    long contexts, ring/Ulysses sequence parallelism — is the right
-    lowering."""
-    from dalle_tpu.ops.pallas.attention_kernels import _heads_per_step
-
-    _, t, h, d = qshape
-    img = grid * grid
-    hps = _heads_per_step(h)
-    per_step = (11 * hps * img * d + 2 * text_len * d * hps) * 2 \
-        + 2 * img * d * 4  # bf16 refs + f32 scratch
-    return per_step <= budget_bytes
-
-
 def window_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
                            attn_type: str, text_len: int, grid: int,
                            conv_kernel: int = 11,
                            interpret: bool = False) -> jax.Array:
     """Pallas fused conv_like/full attention (see axial_attention_fused for
-    the layout rationale): image queries attend to the text prefix plus the
-    exact conv window (or, for 'full', every earlier token) with scores in
-    VMEM only — the dense lowering materialized (B, H, T, T) f32 scores in
-    HBM for the flagship's final 'w_conv' layer (reference task.py:63-65)."""
-    from dalle_tpu.ops.pallas.attention_kernels import (line_attention,
-                                                        window_attention)
-
-    hw = conv_kernel // 2 if attn_type == ATTN_CONV_LIKE else None
-    q, k, v = (x.swapaxes(1, 2) for x in (q, k, v))
-    q_t, k_t, v_t = (x[:, :, :text_len] for x in (q, k, v))
-    q_i, k_i, v_i = (x[:, :, text_len:] for x in (q, k, v))
-    out_t = line_attention(q_t, k_t, v_t, None, None,
-                           text_len, 0, False, interpret)
-    out_i = window_attention(q_i, k_i, v_i, k_t, v_t, grid, hw, interpret)
-    return jnp.concatenate([out_t, out_i], axis=2).swapaxes(1, 2)
+    the layout): image queries attend to the text prefix plus the exact
+    conv window (or, for 'full', every earlier token) with scores in VMEM
+    only — the dense lowering materialized (B, H, T, T) f32 scores in HBM
+    for the flagship's final 'w_conv' layer (reference task.py:63-65)."""
+    return _as_lanes(
+        lambda *qkv: _fused_lanes(*qkv, q.shape[-1], attn_type, text_len,
+                                  grid, conv_kernel, interpret), q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -354,37 +367,67 @@ def zoo_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   attn_type: str, text_len: int, grid: int,
                   conv_kernel: int = 11, mesh=None,
                   scope: Optional[str] = None) -> jax.Array:
-    """Train-time attention dispatch: fast paths where available. With a
-    ``mesh`` of more than one device the fused kernels run per shard
-    (batch over dp/fsdp, heads over tp; parallel/mesh.per_shard), under
-    the caller's ``scope`` so that they keep its name there."""
+    """:func:`zoo_attention_lanes` for (B, T, H, d) operands."""
+    return _as_lanes(
+        functools.partial(zoo_attention_lanes, head_dim=q.shape[-1],
+                          attn_type=attn_type, text_len=text_len, grid=grid,
+                          conv_kernel=conv_kernel, mesh=mesh, scope=scope),
+        q, k, v)
+
+
+def zoo_attention_lanes(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                        head_dim: int, attn_type: str, text_len: int,
+                        grid: int, conv_kernel: int = 11, mesh=None,
+                        scope: Optional[str] = None) -> jax.Array:
+    """Train-time attention dispatch on the projections' (B, T, H*d)
+    arrays: fast paths where available. With a ``mesh`` of more than one
+    device the fused kernels run per shard (batch over dp/fsdp, lanes —
+    whole heads — over tp; parallel/mesh.per_shard), under the caller's
+    ``scope`` so that they keep its name there."""
+    kw = dict(head_dim=head_dim, attn_type=attn_type, text_len=text_len,
+              grid=grid, conv_kernel=conv_kernel)
     if not _pallas_by_default():
-        if attn_type in (ATTN_AXIAL_ROW, ATTN_AXIAL_COL):
-            return axial_attention(q, k, v, attn_type, text_len, grid,
-                                   use_pallas=False)
-        return dense_zoo_attention(q, k, v, attn_type, text_len, grid,
-                                   conv_kernel)
-    fused = functools.partial(_fused_zoo_attention, attn_type=attn_type,
-                              text_len=text_len, grid=grid,
-                              conv_kernel=conv_kernel)
-    return per_shard(fused, mesh, (HEADS_SPEC,) * 3, HEADS_SPEC,
-                     scope=scope)(q, k, v)
+        return _xla_on_lanes(q, k, v, **kw)
+    return per_shard(functools.partial(_fused_zoo_attention, **kw), mesh,
+                     (LANES_SPEC,) * 3, LANES_SPEC, scope=scope)(q, k, v)
+
+
+def _xla_on_lanes(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                  head_dim: int, attn_type: str, text_len: int, grid: int,
+                  conv_kernel: int) -> jax.Array:
+    """The XLA lowering of a zoo layer, on its (B, T, H, d) view."""
+    b, t, width = q.shape
+    q, k, v = (x.reshape(b, t, width // head_dim, head_dim)
+               for x in (q, k, v))
+    if attn_type in (ATTN_AXIAL_ROW, ATTN_AXIAL_COL):
+        out = axial_attention(q, k, v, attn_type, text_len, grid,
+                              use_pallas=False)
+    else:
+        out = dense_zoo_attention(q, k, v, attn_type, text_len, grid,
+                                  conv_kernel)
+    return out.reshape(b, t, width)
 
 
 def _fused_zoo_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                         attn_type: str, text_len: int, grid: int,
-                         conv_kernel: int) -> jax.Array:
-    """The kernel dispatch on one shard's (local) shapes."""
-    if attn_type in (ATTN_AXIAL_ROW, ATTN_AXIAL_COL):
-        return axial_attention_fused(q, k, v, attn_type, text_len, grid,
-                                     interpret=_PALLAS_INTERPRET)
-    fits = _window_fits_vmem(q.shape, text_len, grid)
+                         head_dim: int, attn_type: str, text_len: int,
+                         grid: int, conv_kernel: int) -> jax.Array:
+    """The kernel dispatch on one shard's (local) shapes: the lane-dense
+    kernel where ``attention_kernels.lane_dense_fits``, else the XLA
+    lowering."""
+    from dalle_tpu.ops.pallas.attention_kernels import (LANES,
+                                                        lane_dense_fits)
+
+    _, t, width = q.shape
+    why_not = lane_dense_fits(width, head_dim, t, text_len,
+                              q.dtype.itemsize)
+    _KERNEL_CHOICES[attn_type, head_dim, width, t, text_len] = why_not is None
     log_kernel_choice(
-        f"{attn_type} attention", fits,
-        f"_window_fits_vmem(local q{tuple(q.shape)}, text {text_len}, "
-        f"grid {grid}) is {fits}")
-    if fits:
-        return window_attention_fused(q, k, v, attn_type, text_len, grid,
-                                      conv_kernel,
-                                      interpret=_PALLAS_INTERPRET)
-    return dense_zoo_attention(q, k, v, attn_type, text_len, grid, conv_kernel)
+        f"{attn_type} attention", why_not is None,
+        why_not or f"local q{tuple(q.shape)}: {LANES // head_dim} heads to "
+        f"a {LANES}-lane tile, text {text_len} + grid {grid} in one call")
+    if why_not is not None:
+        return _xla_on_lanes(q, k, v, head_dim=head_dim, attn_type=attn_type,
+                             text_len=text_len, grid=grid,
+                             conv_kernel=conv_kernel)
+    return _fused_lanes(q, k, v, head_dim, attn_type, text_len, grid,
+                        conv_kernel, _PALLAS_INTERPRET)

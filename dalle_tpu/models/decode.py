@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dalle_tpu.config import ModelConfig
-from dalle_tpu.models.attention import (NEG_INF, apply_rotary,
+from dalle_tpu.models.attention import (NEG_INF, apply_rotary_lanes,
                                         rotary_cos_sin, zoo_attention_mask)
 
 LN_EPS = 1e-6  # flax nn.LayerNorm default
@@ -160,24 +160,17 @@ def _positional_table(params: Dict, cfg: ModelConfig) -> jax.Array:
 def _qkv_rows(x, lp, cos_p, sin_p, cfg: ModelConfig, dtype):
     """The block's q/k/v rows for the current position: (B, H, d) each.
 
-    ``cos_p``/``sin_p`` are (d,) when every row shares one position, or
-    (B, d) when each batch row sits at its own position (the serving
+    ``cos_p``/``sin_p`` are (H*d,) when every row shares one position, or
+    (B, H*d) when each batch row sits at its own position (the serving
     engine's per-slot decode)."""
     b = x.shape[0]
     h = _ln(x, lp["attn_norm"], dtype)
-    q = (h @ lp["attn"]["q"]["kernel"].astype(dtype)).reshape(
-        b, cfg.heads, cfg.head_dim)
-    k = (h @ lp["attn"]["k"]["kernel"].astype(dtype)).reshape(
-        b, cfg.heads, cfg.head_dim)
-    v = (h @ lp["attn"]["v"]["kernel"].astype(dtype)).reshape(
-        b, cfg.heads, cfg.head_dim)
+    q, k, v = (h @ lp["attn"][name]["kernel"].astype(dtype)
+               for name in ("q", "k", "v"))
     if cfg.rotary:
-        if cos_p.ndim == 1:
-            cos_b, sin_b = cos_p[None, None, :], sin_p[None, None, :]
-        else:                      # per-slot positions: (B, d) -> (B, 1, d)
-            cos_b, sin_b = cos_p[:, None, :], sin_p[:, None, :]
-        q = apply_rotary(q, cos_b, sin_b)
-        k = apply_rotary(k, cos_b, sin_b)
+        q = apply_rotary_lanes(q, cos_p, sin_p, cfg.head_dim)
+        k = apply_rotary_lanes(k, cos_p, sin_p, cfg.head_dim)
+    q, k, v = (a.reshape(b, cfg.heads, cfg.head_dim) for a in (q, k, v))
     return q, k, v
 
 
@@ -294,8 +287,9 @@ def decode_step(params: Dict, cfg: ModelConfig, cache: Dict,
     x = x + _positional_table(params, cfg)[pos]
     x = x.astype(dtype)                      # (B, dim)
 
-    cos_t, sin_t = rotary_cos_sin(jnp.arange(t_total), cfg.head_dim)
-    cos_p, sin_p = cos_t[pos], sin_t[pos]    # (d,)
+    cos_t, sin_t = rotary_cos_sin(jnp.arange(t_total), cfg.head_dim,
+                                  heads=cfg.heads)
+    cos_p, sin_p = cos_t[pos], sin_t[pos]    # (H*d,)
 
     reps = _cycle_reps(cfg)
     if reps:

@@ -26,9 +26,9 @@ from jax.sharding import PartitionSpec as P
 
 from dalle_tpu.config import ModelConfig
 from dalle_tpu.models.attention import (
-    apply_rotary,
+    apply_rotary_lanes,
     rotary_cos_sin,
-    zoo_attention,
+    zoo_attention_lanes,
 )
 from dalle_tpu.parallel.mesh import TOKENS_SPEC, per_shard
 
@@ -60,37 +60,38 @@ class ZooAttention(nn.Module):
         # Separate q/k/v projections: a fused qkv matmul needs three strided
         # slices of its output, which XLA materializes as HBM copies per
         # layer; three matmuls of the same total FLOPs fuse cleanly instead.
-        # (A heads-major nn.Einsum variant emitting (B, H, T, d) directly
-        # measured ~12% slower: XLA's transposed-epilogue matmuls cost more
-        # than the explicit operand transposes they replaced.)
+        # q/k/v stay the (B, T, H*d) arrays the projections emit, through
+        # rotary and the attention kernels to the out projection: an array
+        # with a minor dimension of head_dim (64) is tiled half empty.
         proj = dict(use_bias=False, dtype=_dtype(cfg),
                     param_dtype=_param_dtype(cfg))
         q = nn.Dense(cfg.dim, **proj, name="q")(x)
         k = nn.Dense(cfg.dim, **proj, name="k")(x)
         v = nn.Dense(cfg.dim, **proj, name="v")(x)
-        q = q.reshape(b, t, cfg.heads, cfg.head_dim)
-        k = k.reshape(b, t, cfg.heads, cfg.head_dim)
-        v = v.reshape(b, t, cfg.heads, cfg.head_dim)
         if rot is not None:
             cos, sin = rot
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
+            q = apply_rotary_lanes(q, cos, sin, cfg.head_dim)
+            k = apply_rotary_lanes(k, cos, sin, cfg.head_dim)
         # names for the optional remat save-policy (config.remat_policy):
         # saving rotated q/k/v lets the backward pass skip recomputing the
-        # projections; the attention kernel's own outputs are named
-        # "attn_out"/"attn_stats" inside its custom_vjp fwd rule
+        # projections. They go on the (B, T, H*d) arrays, so what
+        # save_attn keeps is unpadded; the attention kernel's own outputs
+        # are named "attn_out"/"attn_stats" inside its custom_vjp fwd rule
         # (ops/pallas/attention_kernels.py) so policies can prune the
         # kernel replay too
         q = checkpoint_name(q, "attn_q")
         k = checkpoint_name(k, "attn_k")
         v = checkpoint_name(v, "attn_v")
+        zoo = dict(attn_type=self.attn_type, text_len=cfg.text_seq_len,
+                   grid=cfg.image_grid, conv_kernel=cfg.conv_kernel,
+                   mesh=self.mesh)
         if (cfg.sequence_parallel != "none" and self.mesh is not None
                 and self.mesh.shape.get("sp", 1) > 1):
             from dalle_tpu.parallel.sequence import sp_zoo_attention
             out = sp_zoo_attention(
-                q, k, v, mesh=self.mesh, mode=cfg.sequence_parallel,
-                attn_type=self.attn_type, text_len=cfg.text_seq_len,
-                grid=cfg.image_grid, conv_kernel=cfg.conv_kernel)
+                *(a.reshape(b, t, cfg.heads, cfg.head_dim)
+                  for a in (q, k, v)),
+                mode=cfg.sequence_parallel, **zoo)
             # names emitted inside the shard_map body don't surface to
             # the outer remat policy: name the sp output here so
             # save_ctx/save_attn at least save the attention RESULT
@@ -98,10 +99,8 @@ class ZooAttention(nn.Module):
             # replay for their own residuals)
             out = checkpoint_name(out, "attn_ctx")
         else:
-            out = zoo_attention(
-                q, k, v, attn_type=self.attn_type, text_len=cfg.text_seq_len,
-                grid=cfg.image_grid, conv_kernel=cfg.conv_kernel,
-                mesh=self.mesh, scope=self.name)
+            out = zoo_attention_lanes(q, k, v, head_dim=cfg.head_dim,
+                                      scope=self.name, **zoo)
         # (the attention output is named for the remat save-policies at
         # its source: "attn_out"/"attn_stats" inside the Pallas kernels'
         # custom_vjp fwd rules, "attn_ctx" on the dense/axial XLA paths —
@@ -366,7 +365,7 @@ def _make_rot(cfg: ModelConfig):
     if not cfg.rotary:
         return None
     positions = jnp.arange(cfg.total_seq_len)
-    return rotary_cos_sin(positions, cfg.head_dim)
+    return rotary_cos_sin(positions, cfg.head_dim, heads=cfg.heads)
 
 
 class Transformer(nn.Module):

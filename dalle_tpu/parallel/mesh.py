@@ -22,9 +22,10 @@ AXES = ("dp", "fsdp", "tp", "sp")
 # examples, so the global batch must divide dp*fsdp.
 BATCH_SPEC = P(("dp", "fsdp"))
 # Per-shard layouts of the operands the Mosaic kernels see (per_shard):
-# (B, T, H, d) attention heads split over tp; (B, T, dim) block activations
-# are row-wise work, so the token axis may stay split over sp.
-HEADS_SPEC = P(("dp", "fsdp"), None, "tp", None)
+# the (B, T, H*d) q/k/v/context of attention, whole heads' lanes split over
+# tp; (B, T, dim) block activations are row-wise work, so the token axis
+# may stay split over sp.
+LANES_SPEC = P(("dp", "fsdp"), None, "tp")
 TOKENS_SPEC = P(("dp", "fsdp"), "sp", None)
 
 

@@ -21,6 +21,7 @@ from typing import Callable, List, Optional
 import jax
 import numpy as np
 
+from dalle_tpu.models.attention import attn_layout_record
 from dalle_tpu.swarm.metrics import LocalMetrics, publish_metrics
 from dalle_tpu.task import TrainingTask
 from dalle_tpu.training.steps import grad_reduction_plan
@@ -47,7 +48,8 @@ def warmup(task: TrainingTask, steps: int = 3) -> float:
     params = task.collab_optimizer.state.params
     loss = float("nan")
     with task.tracer.span("train", "setup/warmup", "setup", steps=steps,
-                          grad_reduction=grad_reduction_plan(task.mesh)):
+                          grad_reduction=grad_reduction_plan(task.mesh)
+                          ) as span:
         for i in range(steps):
             t0 = time.monotonic()
             grads, metrics = task.grad_step(params, next(batches))
@@ -55,6 +57,8 @@ def warmup(task: TrainingTask, steps: int = 3) -> float:
             loss = float(metrics["loss"])
             logger.info("warmup %d/%d: loss=%.4f (%.2fs)",
                         i + 1, steps, loss, time.monotonic() - t0)
+        # the step is traced by now: what its attention layers lowered to
+        span.set(attn_layout=attn_layout_record(task.model_cfg, task.mesh))
     if not np.isfinite(loss):
         raise RuntimeError(f"warmup produced non-finite loss {loss}")
     # warmup gradients are discarded; the tracker timer starts fresh

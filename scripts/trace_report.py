@@ -161,7 +161,8 @@ def round_table(rows: List[dict]) -> List[dict]:
 def setup_facts(rows: List[dict]) -> Dict[str, dict]:
     """What the trainer's set-up rows say beside their duration (plane
     ``train``, trace ``setup``): e.g. ``setup/warmup``'s ``grad_reduction``,
-    the plan the gradient step took for its sum over ``dp``."""
+    the plan the gradient step took for its sum over ``dp``, and its
+    ``attn_layout``, how many attention layers took the Mosaic kernel."""
     facts: Dict[str, dict] = {}
     for r in rows:
         if r["plane"] == "train" and r["trace"] == "setup" and r.get("a") \

@@ -1,0 +1,119 @@
+"""One attention layer's gradient compiled for the real chip from the
+sandbox (no chip attached), at the benchmark cells' shapes: between the
+q/k/v projections and the out projection no array with a minor dimension
+of ``head_dim`` may exist — a 64-minor bf16 array is tiled half empty, and
+used to cost a heads-major transpose, six slices, six pads and three f32
+adds a layer beside. All in one file and behind fixtures, so that only the
+worker given this file loads the TPU compiler."""
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dalle_tpu.config import (ATTN_AXIAL_COL, ATTN_AXIAL_ROW,
+                              ATTN_CONV_LIKE, ATTN_FULL,
+                              flagship_model_config, xl_model_config)
+
+MICRO = 4            # the flagship cells' microbatch
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    # a compile for a described chip is written to the cache but cannot be
+    # read back without one; keep the test silent and the cache clean
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _layer_gradient_hlo(cfg, attn_type, one_chip, monkeypatch):
+    """d(sum(attn(x)^2))/d(params, x) for one ``ZooAttention`` layer,
+    compiled for one v5e: the Mosaic kernels' names in the lowered module
+    (where the benchmark's census reads them) and the optimised HLO."""
+    from dalle_tpu.models import attention
+    from dalle_tpu.models.transformer import ZooAttention, _make_rot
+
+    # the dispatcher asks the backend whether Mosaic is there: here it is
+    # the described chip's compiler, whatever the process runs on
+    monkeypatch.setattr(attention, "_pallas_by_default", lambda: True)
+    mod = ZooAttention(cfg, attn_type, name="attn")
+    t = cfg.total_seq_len
+    params = jax.eval_shape(lambda: mod.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, t, cfg.dim), jnp.bfloat16),
+        _make_rot(cfg)))
+    params = jax.tree.map(lambda p: jax.ShapeDtypeStruct(
+        p.shape, p.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((MICRO, t, cfg.dim), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        y = mod.apply(p, x, _make_rot(cfg))
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x)
+    names = sorted(re.findall(r'kernel_name = "([^"]+)"', lowered.as_text()))
+    return names, lowered.compile().as_text()
+
+
+_ARRAY = re.compile(r"\b(?:pred|[suf]\d+|bf16)\[([\d,]+)\]")
+_PRODUCED = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (\S+) (copy|pad|slice|transpose)\(")
+
+
+def _minor(shape: str) -> int:
+    return int(shape.rsplit(",", 1)[-1])
+
+
+@pytest.mark.parametrize("preset,attn_type", [
+    ("flagship", ATTN_AXIAL_ROW), ("flagship", ATTN_AXIAL_COL),
+    ("flagship", ATTN_CONV_LIKE), ("flagship", ATTN_FULL),
+    ("xl", ATTN_AXIAL_ROW)])
+def test_no_head_dim_minor_array_around_the_kernels(
+        preset, attn_type, one_chip, no_persistent_cache, monkeypatch):
+    cfg = {"flagship": flagship_model_config, "xl": xl_model_config}[preset]()
+    d = cfg.head_dim
+    names, text = _layer_gradient_hlo(cfg, attn_type, one_chip, monkeypatch)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # text and image rows in ONE call a direction (it was two)
+    assert len(calls) == 2, len(calls)
+    assert names == (["_win_bwd_kernel", "_win_fwd_kernel"]
+                     if attn_type in (ATTN_CONV_LIKE, ATTN_FULL)
+                     else ["_bwd_kernel", "_fwd_kernel"])
+    for call in calls:
+        head = call.split("backend_config")[0]   # results, operand layouts
+        arrays = [s for s in _ARRAY.findall(head) if "," in s]
+        assert len(arrays) >= 5, call[:300]     # results and operands
+        assert not [s for s in arrays if _minor(s) == d], call[:300]
+        # q/k/v, the context and the gradients are the projections' own
+        # (B, T, H*d) arrays
+        assert f"{MICRO},{cfg.total_seq_len},{cfg.dim}" in arrays
+    # and nothing around them moves a head_dim-minor array either
+    narrow = [m.group(0)[:160] for m in map(_PRODUCED.match,
+                                            text.splitlines())
+              if m and any("," in s and _minor(s) == d
+                           for s in _ARRAY.findall(m.group(1)))]
+    assert not narrow, narrow

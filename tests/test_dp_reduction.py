@@ -140,13 +140,13 @@ class TestDpReduction:
         operands over those alone; outside, over the whole mesh."""
         from jax.sharding import PartitionSpec as P
 
-        from dalle_tpu.parallel.mesh import HEADS_SPEC, per_shard
+        from dalle_tpu.parallel.mesh import LANES_SPEC, per_shard
         mesh = make_mesh(dp=2, fsdp=2, tp=2)
 
         def call(x):
-            return per_shard(lambda x: x * 2, mesh, (HEADS_SPEC,),
-                             HEADS_SPEC)(x)
-        x = jnp.ones((8, 4, 4, 2))
+            return per_shard(lambda x: x * 2, mesh, (LANES_SPEC,),
+                             LANES_SPEC)(x)
+        x = jnp.ones((8, 4, 8))
 
         def inner_eqn(jaxpr):
             eqns = [e for e in jaxpr.eqns if e.primitive.name == "shard_map"]
@@ -155,7 +155,7 @@ class TestDpReduction:
 
         flat = inner_eqn(jax.make_jaxpr(call)(x).jaxpr)
         assert flat.params["manual_axes"] == frozenset(mesh.axis_names)
-        assert flat.params["in_specs"] == (HEADS_SPEC,)
+        assert flat.params["in_specs"] == (LANES_SPEC,)
 
         nested = jax.shard_map(call, mesh=mesh, in_specs=P("dp"),
                                out_specs=P("dp"), axis_names={"dp"},
@@ -163,8 +163,8 @@ class TestDpReduction:
         outer = inner_eqn(jax.make_jaxpr(nested)(x).jaxpr)
         inner = inner_eqn(outer.params["jaxpr"])
         assert inner.params["manual_axes"] == frozenset({"fsdp", "tp", "sp"})
-        assert inner.params["in_specs"] == (P("fsdp", None, "tp", None),)
-        assert inner.params["out_specs"] == (P("fsdp", None, "tp", None),)
+        assert inner.params["in_specs"] == (P("fsdp", None, "tp"),)
+        assert inner.params["out_specs"] == (P("fsdp", None, "tp"),)
         assert inner.params["mesh"].manual_axes == ("dp",)
         np.testing.assert_array_equal(np.asarray(jax.jit(nested)(x)),
                                       2 * np.ones(x.shape))

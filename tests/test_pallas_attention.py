@@ -16,7 +16,8 @@ from dalle_tpu.models.attention import (axial_attention,
                                         dense_zoo_attention,
                                         window_attention_fused)
 
-TEXT, GRID, H, D = 16, 4, 2, 8
+# 4 heads of 32: one 128-lane tile, the narrowest width the kernels take
+TEXT, GRID, H, D = 16, 4, 4, 32
 
 
 def _qkv(key, b=2, t=TEXT + GRID * GRID):
@@ -149,7 +150,7 @@ class TestRematPolicyPinsKernelReplay:
         # + the w_conv layer; tiny dims keep tracing fast while keeping
         # the flagship's structure (scan + remat + custom_vjp kernels)
         cfg = flagship_model_config(
-            depth=9, dim=64, heads=2, head_dim=32, text_seq_len=16,
+            depth=9, dim=128, heads=4, head_dim=32, text_seq_len=16,
             image_grid=4, vocab_text=64, vocab_image=32,
             remat_skip_blocks=0, head_chunk=0, remat_policy=policy)
         model = DALLE(cfg)
@@ -181,8 +182,9 @@ def test_per_shard_kernels_match_single_device(attn_type, nested,
                                                monkeypatch,
                                                inside_manual_dp):
     """GSPMD cannot partition a Mosaic kernel, so on a mesh the dispatcher
-    runs the fused kernels per shard (batch over dp x fsdp, heads over
-    tp): values and gradients must equal the unwrapped one-device call.
+    runs the fused kernels per shard (batch over dp x fsdp, whole heads'
+    lanes over tp): values and gradients must equal the unwrapped
+    one-device call.
     ``nested``: called inside a ``shard_map`` manual over ``dp`` (the
     gradient accumulation's), the wrapper binds the other axes only."""
     from dalle_tpu.models import attention
@@ -191,7 +193,8 @@ def test_per_shard_kernels_match_single_device(attn_type, nested,
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
     mesh = make_mesh(dp=2, fsdp=2, tp=2)
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
-    shape = (4, TEXT + GRID * GRID, 4, D)
+    # 8 heads of 32: tp=2 leaves each shard one 128-lane tile of 4 heads
+    shape = (4, TEXT + GRID * GRID, 8, 32)
     q, k, v, w = (jax.random.normal(kk, shape, jnp.float32) for kk in ks)
 
     def loss(mesh_, nested=False):
@@ -205,7 +208,11 @@ def test_per_shard_kernels_match_single_device(attn_type, nested,
             vg = inside_manual_dp(vg, mesh_, (True,) * 4, (0, 1, 2))
         return jax.jit(vg)
 
+    monkeypatch.setattr(attention, "_KERNEL_CHOICES", {})
     (_, out_m), g_m = loss(mesh, nested)(q, k, v, w)
+    # the kernel, not the XLA lowering, on the shards' local shapes
+    assert attention._KERNEL_CHOICES == {
+        (attn_type, 32, 128, TEXT + GRID * GRID, TEXT): True}
     (_, out_1), g_1 = loss(None)(q, k, v, w)
     assert len(out_m.sharding.device_set) == 8
     np.testing.assert_allclose(np.asarray(out_m), np.asarray(out_1),
@@ -213,3 +220,204 @@ def test_per_shard_kernels_match_single_device(attn_type, nested,
     for a, b in zip(g_m, g_1):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
+
+
+ZOO = [ATTN_AXIAL_ROW, ATTN_AXIAL_COL, "conv_like", "full"]
+
+
+def _fused(q, k, v, attn_type, grid=GRID):
+    if attn_type in (ATTN_AXIAL_ROW, ATTN_AXIAL_COL):
+        return axial_attention_fused(q, k, v, attn_type, TEXT, grid,
+                                     interpret=True)
+    return window_attention_fused(q, k, v, attn_type, TEXT, grid,
+                                  conv_kernel=3, interpret=True)
+
+
+@pytest.mark.parametrize("head_dim", [32, 64, 128])
+@pytest.mark.parametrize("attn_type", ZOO)
+class TestLanePacking:
+    """128 // head_dim heads share a 128-lane tile (4 / 2 / 1), separated
+    by lane masks; 256 lanes = two tiles a sample, so the grid's second
+    axis and the statistics' per-tile heads are walked too."""
+
+    @staticmethod
+    def _qkv(head_dim, seed):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        shape = (2, TEXT + GRID * GRID, 256 // head_dim, head_dim)
+        return [jax.random.normal(kk, shape, jnp.float32) for kk in ks]
+
+    def test_forward_matches_dense_oracle(self, attn_type, head_dim):
+        q, k, v, _ = self._qkv(head_dim, 11)
+        want = dense_zoo_attention(q, k, v, attn_type, TEXT, GRID,
+                                   conv_kernel=3)
+        np.testing.assert_allclose(np.asarray(_fused(q, k, v, attn_type)),
+                                   np.asarray(want), rtol=2e-4, atol=2e-5)
+
+    def test_backward_matches_xla_autodiff(self, attn_type, head_dim):
+        q, k, v, w = self._qkv(head_dim, 12)
+        g_fused = jax.grad(
+            lambda *qkv: jnp.sum(_fused(*qkv, attn_type) * w),
+            argnums=(0, 1, 2))(q, k, v)
+        g_ref = jax.grad(
+            lambda *qkv: jnp.sum(dense_zoo_attention(
+                *qkv, attn_type, TEXT, GRID, conv_kernel=3) * w),
+            argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g_fused, g_ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=5e-4, atol=5e-5)
+
+
+@pytest.mark.parametrize("attn_type", ZOO)
+def test_text_rows_dk_dv_are_the_two_parts_summed(attn_type):
+    """The text rows' keys and values serve their own causal line and
+    every image row's prefix. Before, two kernels gave the two parts and
+    XLA added them; now one kernel sums them in VMEM: the gradient of the
+    whole must be the sum of the gradients through the text rows' and
+    through the image rows' outputs, each of which the dense oracle
+    confirms."""
+    ks = jax.random.split(jax.random.PRNGKey(21), 4)
+    shape = (2, TEXT + GRID * GRID, 4, 32)
+    q, k, v, w = (jax.random.normal(kk, shape, jnp.float32) for kk in ks)
+    is_text = (jnp.arange(shape[1]) < TEXT)[None, :, None, None]
+
+    def grads(fn, weight):
+        return jax.grad(lambda k, v: jnp.sum(fn(q, k, v) * weight),
+                        argnums=(0, 1))(k, v)
+
+    fused = lambda q, k, v: _fused(q, k, v, attn_type)  # noqa: E731
+    dense = lambda q, k, v: dense_zoo_attention(  # noqa: E731
+        q, k, v, attn_type, TEXT, GRID, conv_kernel=3)
+    whole = grads(fused, w)
+    own = grads(fused, jnp.where(is_text, w, 0.0))
+    prefix = grads(fused, jnp.where(is_text, 0.0, w))
+    for got, a, b, a_ref, b_ref in zip(
+            whole, own, prefix, grads(dense, jnp.where(is_text, w, 0.0)),
+            grads(dense, jnp.where(is_text, 0.0, w))):
+        assert float(jnp.abs(a[:, :TEXT]).max()) > 0
+        assert float(jnp.abs(b[:, :TEXT]).max()) > 0
+        np.testing.assert_allclose(np.asarray(a), np.asarray(a_ref),
+                                   rtol=5e-4, atol=5e-5)
+        np.testing.assert_allclose(np.asarray(b), np.asarray(b_ref),
+                                   rtol=5e-4, atol=5e-5)
+        np.testing.assert_allclose(np.asarray(got[:, :TEXT]),
+                                   np.asarray((a + b)[:, :TEXT]),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("attn_type", [ATTN_AXIAL_ROW, "conv_like"])
+def test_odd_head_count_takes_the_xla_lowering_and_says_so(
+        attn_type, monkeypatch, caplog):
+    """Three heads of 64 do not fill 128-lane tiles: the dispatcher takes
+    the XLA lowering of the same attention and logs the choice once."""
+    import logging
+
+    from dalle_tpu.models import attention
+
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    monkeypatch.setattr(attention, "_KERNEL_CHOICES", {})
+    attention.log_kernel_choice.cache_clear()     # it says a thing once
+    ks = jax.random.split(jax.random.PRNGKey(31), 3)
+    q, k, v = (jax.random.normal(kk, (2, TEXT + GRID * GRID, 3, 64),
+                                 jnp.float32) for kk in ks)
+
+    def run(q, k, v):
+        return attention.zoo_attention(q, k, v, attn_type=attn_type,
+                                       text_len=TEXT, grid=GRID,
+                                       conv_kernel=3)
+    with caplog.at_level(logging.INFO, logger=attention.logger.name):
+        jaxpr = jax.make_jaxpr(run)(q, k, v)
+        got = run(q, k, v)
+    assert "pallas_call" not in str(jaxpr)
+    said = [r.getMessage() for r in caplog.records
+            if r.getMessage().startswith(f"{attn_type} attention")]
+    assert said == [f"{attn_type} attention: XLA lowering (3 heads of 64 "
+                    f"do not fill 128-lane tiles)"]
+    assert attention._KERNEL_CHOICES == {
+        (attn_type, 64, 192, TEXT + GRID * GRID, TEXT): False}
+    want = dense_zoo_attention(q, k, v, attn_type, TEXT, GRID, conv_kernel=3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("heads,on", [(4, 9), (3, 0)],
+                         ids=["fills_tiles", "falls_back"])
+def test_attn_layout_record_counts_the_layers_that_took_the_kernel(
+        heads, on, monkeypatch):
+    """The ``attn_layout`` attribute of the ``setup/warmup`` row: looked
+    up in what the dispatcher did while the step was traced, at this
+    model's own shapes — another model traced in the same process
+    neither vouches for this one nor taints it."""
+    from dalle_tpu.config import flagship_model_config
+    from dalle_tpu.models import attention
+    from dalle_tpu.models.dalle import DALLE, init_params
+
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    monkeypatch.setattr(attention, "_KERNEL_CHOICES", {})
+
+    def trace(cfg):
+        model = DALLE(cfg)
+        params = jax.eval_shape(
+            lambda: init_params(model, jax.random.PRNGKey(0)))
+        jax.eval_shape(lambda p: model.apply(
+            p, jnp.zeros((1, cfg.text_seq_len), jnp.int32),
+            jnp.zeros((1, cfg.image_seq_len), jnp.int32))[0], params)
+
+    small = dict(depth=9, head_dim=32, text_seq_len=16, image_grid=4,
+                 vocab_text=64, vocab_image=32, head_chunk=0)
+    cfg = flagship_model_config(dim=heads * 32, heads=heads, **small)
+    # the other outcome, at another width, traced first
+    trace(flagship_model_config(dim=(7 - heads) * 32, heads=7 - heads,
+                                **small))
+    assert attention.attn_layout_record(cfg) == \
+        "lane-dense 128: 0 of 9 layers"       # this model: not traced yet
+    trace(cfg)
+    assert attention.attn_layout_record(cfg) == \
+        f"lane-dense 128: {on} of 9 layers"
+    # a tp that splits the heads' lanes reads its own local width
+    from dalle_tpu.parallel.mesh import make_mesh
+    assert attention.attn_layout_record(cfg, make_mesh(dp=4, tp=2)) == \
+        "lane-dense 128: 0 of 9 layers"
+
+
+def test_rotary_on_lanes_equals_rotary_per_head():
+    """``apply_rotary_lanes`` on (B, T, H*d) is rotate-half rotary on each
+    head of (B, T, H, d) — ``x * cos + concat(-x2, x1) * sin`` in f32 —
+    bit for bit; decode's single rows take the same function."""
+    from dalle_tpu.models.attention import (apply_rotary_lanes,
+                                            rotary_cos_sin)
+    b, t, h, d = 2, 24, 6, 32
+    x = jax.random.normal(jax.random.PRNGKey(41), (b, t, h, d),
+                          jnp.float32).astype(jnp.bfloat16)
+    pos = jnp.arange(t)
+    cos, sin = (a[:, None, :] for a in rotary_cos_sin(pos, d))
+    xf = x.astype(jnp.float32)
+    rot = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], axis=-1)
+    want = (xf * cos + rot * sin).astype(x.dtype)
+    cos_l, sin_l = rotary_cos_sin(pos, d, heads=h)
+    got = apply_rotary_lanes(x.reshape(b, t, h * d), cos_l, sin_l, d)
+    np.testing.assert_array_equal(
+        np.asarray(got.reshape(b, t, h, d), np.float32),
+        np.asarray(want, np.float32))
+    # one row a sample, each at its own position (per-slot decode)
+    row = apply_rotary_lanes(x[:, 5].reshape(b, h * d), cos_l[jnp.array(
+        [5, 5])], sin_l[jnp.array([5, 5])], d)
+    np.testing.assert_array_equal(np.asarray(row, np.float32),
+                                  np.asarray(got[:, 5], np.float32))
+
+
+@pytest.mark.parametrize("width,head_dim,t,text,fits", [
+    (1024, 64, 1280, 256, True),         # the flagship: 8 tiles of 2 heads
+    (1792, 64, 1280, 256, True),         # XL: 28 heads = 14 tiles
+    (512, 128, 1280, 256, True),         # one head a tile
+    (128, 32, 32, 16, True),             # four heads a tile
+    (1024, 32, 1280, 256, False),        # ... whose prefix scores all live
+    (192, 64, 1280, 256, False),         # 3 heads of 64: a half-empty tile
+    (192, 96, 1280, 256, False),         # 128 % head_dim
+    (1024, 64, 256 + 64 * 64, 256, False),   # long context: past VMEM
+], ids=["flagship", "xl", "d128", "d32", "d32_flagship_length", "odd_heads",
+        "d96", "longctx"])
+def test_one_predicate_says_which_shapes_take_the_kernel(
+        width, head_dim, t, text, fits):
+    from dalle_tpu.ops.pallas.attention_kernels import lane_dense_fits
+    why_not = lane_dense_fits(width, head_dim, t, text)
+    assert (why_not is None) == fits, why_not

@@ -77,6 +77,21 @@ class TestTrainLoopRing:
                   if k.endswith("setup/warmup")]
         assert shown["grad_reduction"] == warm["a"]["grad_reduction"]
 
+    def test_warmup_row_says_what_the_attention_layers_lowered_to(self,
+                                                                  rows):
+        """The engagement record of the lane-dense attention kernels
+        (models/attention.attn_layout_record), set on the warm-up row once
+        the step is traced: on the CPU no layer takes a Mosaic kernel, and
+        the row says so."""
+        from scripts.trace_report import setup_facts
+        ring, _ = rows
+        warm, = [r for r in ring if r["phase"] == "setup/warmup"]
+        assert re.fullmatch(r"lane-dense 128: 0 of \d+ layers",
+                            warm["a"]["attn_layout"])
+        shown, = [a for k, a in setup_facts(ring).items()
+                  if k.endswith("setup/warmup")]
+        assert shown["attn_layout"] == warm["a"]["attn_layout"]
+
     def test_rows_carry_their_step_and_the_span_that_caused_them(self, rows):
         ring, _ = rows
         steps = [r for r in ring if r["phase"] == "loop/step"]
