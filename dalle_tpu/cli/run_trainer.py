@@ -32,11 +32,11 @@ from dalle_tpu.cli._args import (add_dataclass_args, check_no_collisions,
 logger = logging.getLogger("dalle_tpu.trainer")
 
 MODEL_PRESETS = {
-    # the 1.3B (task.py:62-83) WITH the measured-best v5e training knobs —
-    # the same object bench.py measures (config.FLAGSHIP_TUNED)
+    # the 1.3B (task.py:62-83) with config.FLAGSHIP_TUNED: what the
+    # cells flagship-train-solo and flagship-train-dp4 run
     "flagship": flagship_model_config,
     "tiny": tiny_model_config,                # CPU smoke shape
-    # DALL-E-XL ~3B for pod-slice peers (BASELINE.json config 5)
+    # DALL-E-XL ~3B for pod-slice peers: the cell xl-train-solo
     "xl": xl_model_config,
 }
 

@@ -79,6 +79,10 @@ class ModelConfig:
     # Memory saving: jax.checkpoint (remat) replaces the reference's
     # reversible layers (task.py:81) with the XLA-idiomatic equivalent.
     remat: bool = True
+    # The knobs from here to ln_fusion are set by the presets below.
+    # What a preset costs as a whole is the ledger's (PERF_LEDGER.jsonl;
+    # PERF.md §5); what each knob is worth alone is not measured on
+    # today's stack (ROADMAP Design 5).
     # None = blanket remat (save only block boundaries); "save_ctx" saves
     # the attention kernel's outputs (context + softmax row stats) so
     # backward never re-runs the forward attention kernel; "save_attn"
@@ -93,33 +97,37 @@ class ModelConfig:
     # Streaming cross-entropy: compute the image-segment head loss as a
     # chunked logsumexp over the vocabulary (chunks of this many ids)
     # instead of materializing the full (B, T, vocab) logits in HBM.
-    # 0 = off (dense head). Identical losses either way.
+    # 0 = off (dense head). Identical losses either way. Head +
+    # cross-entropy at 2048 are 1.19% of the flagship's busy time
+    # (`head_ce_share_pct`, ledger, PR 28).
     head_chunk: int = 0
     # Cycle passes unrolled inside ONE scan iteration of the weight-shared
     # body. Backward accumulates the shared weights' f32 gradients into
-    # the scan carry once per iteration — at unroll 1 that read-modify-
-    # write of every unique weight 16x per microbatch was ~17% of the
-    # flagship step (profiled r3); unroll N divides it by N at the cost
-    # of an N-times-larger compiled body.
+    # the scan carry once per iteration; unroll N divides that
+    # read-modify-write of every unique weight by N at the cost of an
+    # N-times-larger compiled body. At unroll 2 the layer scan's own
+    # traffic is 6.68% of the flagship's busy time
+    # (`layer_scan_share_pct`, ledger, PR 28).
     scan_unroll: int = 1
     # Hoist the f32->bf16 parameter casts OUT of the weight-shared scan
     # (and its remat region): the scan body then reads pre-cast bf16
     # weights — the per-iteration casts and their remat replays disappear
-    # (4.1% of the r3 flagship profile) and the shared-grad scan carry
-    # accumulates in BF16, halving the carry read-modify-write bytes
-    # (the remaining ~9% after scan_unroll=2). The cost is bf16
-    # round-nearest gradient accumulation across the cycle repetitions
-    # (master params/LAMB stay f32) — measure trajectory drift before
-    # enabling for a long run (PERF.md r5 records both).
+    # and the shared-grad scan carry accumulates in BF16, halving the
+    # carry read-modify-write bytes. The cost is bf16 round-nearest
+    # gradient accumulation across the cycle repetitions (master
+    # params/LAMB stay f32); the flagship's worst gradient leaf then
+    # reads 0.040–0.046 relative L2 against the f32 reference (PERF.md
+    # §4, chip runs of PRs 23–28).
     param_cast_hoist: bool = False
     # Fused Pallas GEGLU feed-forward (ops/pallas/geglu_kernels.py): the
     # (B*T, ff_mult*dim) intermediates stay in VMEM tiles and backward
     # saves only the FF input. "plain" fuses the non-rematted blocks
-    # (remat_skip_blocks), where it cuts the FF autodiff residual from
-    # ~84 MB to ~10 MB per flagship apply at strictly fewer FLOPs than
-    # remat; "all" also fuses rematted blocks (their replay already
-    # avoids the residual, so this mostly trades FLOPs for HBM traffic);
-    # "none" keeps the unfused XLA lowering everywhere.
+    # (remat_skip_blocks), whose FF autodiff residual it replaces at
+    # fewer FLOPs than remat would; "all" also fuses rematted blocks
+    # (their replay already avoids the residual, so this mostly trades
+    # FLOPs for HBM traffic); "none" keeps the unfused XLA lowering
+    # everywhere. On the flagship `ff[mosaic]` is 8.0% of busy time
+    # beside 48.3% of feed-forward left to XLA (PERF.md §5, PR 28).
     ff_fusion: str = "plain"
     # Single-pass Pallas LayerNorm with fused backward
     # (ops/pallas/ln_kernels.py): forward reads/writes each row once with
@@ -127,6 +135,9 @@ class ModelConfig:
     # dscale/dbias partials in ONE pass instead of XLA's separate
     # reduction fusions. flax-parity numerics; unsupported shapes (tiny
     # test models, single-token decode) fall back to the plain lowering.
+    # On for the flagship (`ff_norm[mosaic]` 0.024 s a step), off for
+    # xl, whose LayerNorm runs as two XLA reductions of 0.260 + 0.246 s
+    # a step (PERF.md §5, PR 28).
     ln_fusion: bool = False
     dtype: str = "bfloat16"          # activation dtype on TPU (MXU-native)
     param_dtype: str = "float32"
@@ -255,7 +266,6 @@ class TrainerConfig:
     per_device_batch: int = 2         # arguments.py:12-14
     grad_accum_steps: int = 1
     seed: int = 0
-    text_pad_id: int = 1              # T5 pad token (=eos in reference, task.py:58-59)
     # Mesh axis sizes; -1 means "use all remaining devices" on the dp axis.
     dp: int = -1
     fsdp: int = 1
@@ -289,12 +299,12 @@ class CollabConfig:
     # feedback (swarm/powersgd.py; hivemind carries PowerSGD upstream,
     # SURVEY.md §2 component 15).
     size_adaptive_threshold: int = 2 ** 16 + 1
-    # NOTE: the tuned flagship operating point (FLAGSHIP_TUNED, PERF.md)
-    # was measured against the HBM wall with size_adaptive compression.
-    # power_sgd keeps device-resident f32 error-feedback + in-flight M
-    # caches at gradient size (~500 MB persistent + ~2x transient for the
-    # flagship's 125.6M unique params) — see PERF.md's PowerSGD footprint
-    # note before combining it with the tuned micro/accum point.
+    # NOTE: the benchmark's cells run size_adaptive. power_sgd keeps
+    # device-resident f32 error-feedback + in-flight M caches at
+    # gradient size (~500 MB persistent + ~2x transient for the
+    # flagship's 125.6M unique params); its footprint beside the
+    # flagship's micro 4 x accum 8 step (9.44 GB peak; PERF.md §5,
+    # PR 28) is not measured.
     grad_compression: str = "size_adaptive"
     state_compression: str = "size_adaptive"
     # Where the u8/u4/f16 wire codec EXECUTES (never what it emits —
@@ -360,19 +370,20 @@ class CollabConfig:
     # libp2p's security handshake; ours is framing-level.
     encrypt_data_plane: bool = True
     delay_optimizer_step: bool = True  # task.py:129
-    reuse_grad_buffers: bool = True    # task.py:133
     metrics_expiration: float = 600.0  # statistics_expiration, arguments.py:129-131
     # --- Byzantine defense (swarm/screening.py + swarm/health.py;
     # CHAOS.md "Defense in depth"). Signatures and strict parsing stop
-    # forged/malformed traffic; these knobs govern the CONTENT layer:
-    # screening of valid-but-wrong gradients, the sender-weight clamp,
-    # and gossiped signed strike receipts.
+    # forged/malformed traffic; the CONTENT layer — screening of
+    # valid-but-wrong gradients, the sender-weight clamp, gossiped
+    # signed strike receipts, verified aggregation, round repair and
+    # evidence by reference — is armed on every swarm-speaking peer
+    # (CollaborativeOptimizer.__init__); what follows are its
+    # thresholds, not switches.
     # Norm/cosine outlier screening of scatter contributions at each
     # part owner (drop/keep, never reweight — surviving rounds stay
     # bit-identical to an honest-only round). Auto-skipped below
     # screen_min_senders weighted contributors (small swarms keep the
     # pre-screening semantics byte-for-byte).
-    screen_gradients: bool = True
     screen_min_senders: int = 4
     # never drop a majority (see screening.ScreenPolicy for the
     # calibration rationale on every threshold)
@@ -386,11 +397,10 @@ class CollabConfig:
     # peer can legitimately carry more than the whole swarm's target);
     # 0 disables the clamp.
     max_peer_weight: "float | None" = None
-    # Gossip attributable strikes as Ed25519-signed receipts under
-    # {run_id}_strikes and fold verified remote receipts into the local
+    # Attributable strikes gossip as Ed25519-signed receipts under
+    # {run_id}_strikes, and verified remote receipts fold into the local
     # ledger (bounded influence: no issuer veto, and remote evidence
-    # alone can never convict — health.py). Off = ledger stays local.
-    gossip_strikes: bool = True
+    # alone can never convict — health.py), this often (seconds).
     strike_gossip_period: float = 5.0
     # Verified aggregation (swarm/audit.py; CHAOS.md "Defense in
     # depth" row 7): each round a deterministic challenge derived from
@@ -408,53 +418,29 @@ class CollabConfig:
     # persistent cheat within a few epochs at a quarter of the
     # bandwidth/CPU tax (the soaks and gates run frac=1.0 for
     # deterministic conviction-latency oracles). audit_ttl bounds how
-    # long a transcript stays fetchable in the owner's mailbox. Off =
-    # zero retention, rounds byte-identical to the pre-audit protocol.
-    audit_gather: bool = True
+    # long a transcript stays fetchable in the owner's mailbox. The
+    # PowerSGD factor rounds ({run}_grads_p/_q) and periodic state
+    # averaging ({run}_state) ride the same butterfly and the same
+    # challenge/transcript/replay machinery, each under its own prefix.
+    # A replayed-bytes-mismatch conviction in any phase has recomputed
+    # the honest part bit-exactly, so the optimizer applies the
+    # correction honest - served at that phase's application site
+    # (swarm/repair.py; CHAOS.md "Round repair").
     audit_frac: float = 0.25
     audit_ttl: float = 120.0
-    # Round repair (swarm/repair.py; CHAOS.md "Round repair"): an
-    # owner-audit-fail conviction whose replay SUCCEEDED (the
-    # replayed-bytes-mismatch class — the wrong_gather_part attack
-    # shape) has recomputed the honest part bytes bit-exactly, so the
-    # optimizer applies the compensating correction honest - served:
-    # assigned over the averaged gradients when the conviction beats
-    # the apply (bit-exact), added into the next applied gradient
-    # vector after the LAMB step fired (bounded-staleness
-    # compensation — one step of preconditioner staleness). False
-    # keeps the r15 detection-only behavior byte-for-byte.
-    repair_convicted: bool = True
     # BYTE bound on the audit worker's retained-round ring (the
     # pending RoundAudits hold signed frames + gathered part copies
     # that late repair/proofs need): oldest-first eviction with a
     # counted eviction, so flagship-size parts cannot balloon host
     # RAM under a slow audit. The round-count bound (8) still applies.
     audit_ring_bytes: int = 256 << 20
-    # Audit the two auxiliary averaging phases too — PowerSGD factor
-    # rounds ({run}_grads_p/_q) and periodic state averaging
-    # ({run}_state) ride the same butterfly and, with this on, the
-    # same challenge/transcript/replay machinery (each phase under its
-    # own prefix). Convictions there strike + gossip proof-carrying
-    # receipts.
-    audit_aux_phases: bool = True
-    # r20 aux-phase REPAIR: a replayed-bytes-mismatch conviction in a
-    # PowerSGD factor round or in state averaging queues its
-    # honest - served correction into the factor buffers / the
-    # averaged-state application (same pre-step-exact /
-    # bounded-staleness split as gradient repair, each phase drained
-    # at its own application site). Requires repair_convicted and
-    # audit_aux_phases; False keeps factor/state convictions
-    # detection + proof, byte-identical to r19.
-    repair_aux_phases: bool = True
-    # r20 evidence by reference (swarm/audit.EvidencePlane): evidence
+    # Evidence by reference (swarm/audit.EvidencePlane): evidence
     # bundles too large to embed inline in a proof receipt
     # (PROOF_MAX_BYTES) are parked chunked in the issuer's mailbox and
     # the receipt carries a sha256 digest + mailbox descriptor;
     # verifiers fetch under the hard byte/time budgets below
     # (hash-check before any sized allocation), replay, and re-serve
-    # verified bundles for failover. Off: over-budget convictions
-    # degrade to the capped r13 accusation exactly as in r19.
-    proof_by_reference: bool = True
+    # verified bundles for failover.
     # hard per-bundle byte budget a verifier will fetch (an oversize
     # descriptor claim is rejected before any allocation or I/O); the
     # flagship 502 MB part's bundle (~2x part bytes: transcript
@@ -677,7 +663,6 @@ class AuxConfig:
 
     refresh_period: float = 10.0       # arguments.py:146
     checkpoint_dir: Optional[str] = None
-    upload_interval: Optional[float] = None
     store_checkpoints: bool = True
     # Beyond-the-stub: the reference DECLARES this mode but its
     # implementation raises NotImplementedError (run_aux_peer.py:99-104).
@@ -690,7 +675,8 @@ class AuxConfig:
 
 
 def tiny_model_config(**overrides: Any) -> ModelConfig:
-    """CPU-smoke configuration (BASELINE.json config 1: 12L d512 full attn)."""
+    """CPU-smoke configuration (preset ``tiny``): 4 full-attention layers
+    of width 64."""
     base = dict(
         vocab_text=128, vocab_image=64, text_seq_len=16, image_grid=4,
         dim=64, depth=4, heads=4, head_dim=16, shared_block_cycle=0,
@@ -701,42 +687,44 @@ def tiny_model_config(**overrides: Any) -> ModelConfig:
     return ModelConfig(**base)
 
 
-# Measured-best v5e training knobs (PERF.md): partial remat leaves 1 of
-# the 4 weight-shared blocks un-rematerialized; streaming cross-entropy
-# chunks the image head's logsumexp at 2048 vocabulary ids; two cycle
-# passes per scan iteration halve the shared-weight f32 grad-carry
-# traffic (unroll 4 regressed: measured 10.72 / 10.85 / 10.45 img/s for
-# unroll 1/2/4). These ship as the flagship defaults so `--preset
-# flagship` trains the same config bench.py measures (one source of
-# truth; VERDICT r2 weak #6).
-# r5 grid (PERF_GRID.json): save_attn remat (backward replays neither
-# projections nor attention; the GEGLU fusion freed the memory it needs)
-# + the hoisted bf16 parameter cast = 11.599 img/s/chip, the round-5
-# record (r4 shipped 11.311; the full grid is in PERF.md).
+# The flagship's v5e training knobs: partial remat leaves 1 of the 4
+# weight-shared blocks un-rematerialized; streaming cross-entropy chunks
+# the image head's logsumexp at 2048 vocabulary ids; two cycle passes
+# per scan iteration halve the shared-weight grad-carry traffic;
+# save_attn remat (backward replays neither projections nor attention);
+# the fused LayerNorm; the hoisted bf16 parameter cast. `--preset
+# flagship` trains exactly what the benchmark's `flagship` configuration
+# holds (benchmark/configs/flagship.json equals asdict of the preset,
+# held by tests/benchmark_tests): 16 817 tokens/s/chip in
+# `flagship-train-solo`, 16 718 in `flagship-train-dp4` (ledger, PR 28;
+# PERF.md §4–§5). Each knob's own worth: not measured on today's stack
+# (ROADMAP Design 5).
 FLAGSHIP_TUNED = dict(remat_skip_blocks=1, head_chunk=2048, scan_unroll=2,
                       ln_fusion=True, remat_policy="save_attn",
                       param_cast_hoist=True)
 
 
 def flagship_model_config(**overrides: Any) -> ModelConfig:
-    """The 1.3B flagship (reference task.py:62-83 shape) with the
-    bench-winning v5e training knobs (``FLAGSHIP_TUNED``) applied."""
+    """The 1.3B flagship (reference task.py:62-83 shape) with its v5e
+    training knobs (``FLAGSHIP_TUNED``) applied."""
     base = dict(FLAGSHIP_TUNED)
     base.update(overrides)
     return dataclasses.replace(ModelConfig(), **base)
 
 
 def xl_model_config(**overrides: Any) -> ModelConfig:
-    """DALL-E-XL ~3B (BASELINE.json config 5): dim 1792, depth 64 with the
+    """DALL-E-XL ~3B (preset ``xl``; this repo's own widening of the
+    flagship, not a published architecture): dim 1792, depth 64 with the
     same 4-block weight sharing, 28 heads x 64, VQGAN-f16 tokens (16384-code
     codebook; 512px images -> 32x32 codes). Sized for pod-slice peers
-    (v5p-64 in the north star) — one v5e chip cannot hold its state; train
-    it with fsdp/tp over a mesh (``parallel/sharding.py``).
+    (v5p-64 in the north star); on one v5e chip it runs at micro 2 x
+    accum 8, 10.91 GB peak and 5 778 tokens/s/chip (`xl-train-solo`;
+    PERF.md §5 and ledger, PR 28).
     """
-    # ln_fusion measured SLOWER on this shape (3.84 vs 4.12 img/s at
-    # micro 2 — XL_STEP.json; identical losses): under blanket remat at
-    # depth 64 the kernel's replay beats XLA's LN-into-neighbor fusion
-    # on the flagship but not at dim 1792. Keep the XLA lowering here.
+    # Blanket remat and the XLA LayerNorm (ln_fusion off): a pre-round
+    # reading had the fused kernel slower at this width, where XLA fuses
+    # the norm into its neighbours (BENCHMARK.json's xl.why keeps that
+    # claim); not measured on today's stack (ROADMAP Design 5).
     base = dict(dim=1792, heads=28, head_dim=64,
                 vocab_image=16384, image_grid=32,
                 remat_skip_blocks=0, head_chunk=2048, scan_unroll=2)

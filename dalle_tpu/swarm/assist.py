@@ -117,8 +117,7 @@ def assist_one_round(dht: DHT, cfg: CollabConfig, epoch: int,
                   pin_codec=pin_codec,
                   adaptive_threshold=cfg.size_adaptive_threshold,
                   report=report, audit=ra,
-                  codec_backend=resolve_backend(
-                      getattr(cfg, "wire_codec_backend", "auto")))
+                  codec_backend=resolve_backend(cfg.wire_codec_backend))
     return "assisted" if report.get("reduced_senders", 0) > 0 else "empty"
 
 
@@ -175,8 +174,8 @@ class AveragingAssistant(threading.Thread):
         # the knobs exactly as CollaborativeOptimizer maps them.
         from dalle_tpu.swarm.compression import codec_for_bits
         from dalle_tpu.swarm.optimizer import _CODECS
-        wb_r = getattr(self.cfg, "wire_bits_reduce", None)
-        wb_g = getattr(self.cfg, "wire_bits_gather", None)
+        wb_r = self.cfg.wire_bits_reduce
+        wb_g = self.cfg.wire_bits_gather
         codec = (codec_for_bits(wb_r) if wb_r is not None
                  else _CODECS[self.cfg.grad_compression])
         gather_codec = codec_for_bits(wb_g)
@@ -184,11 +183,9 @@ class AveragingAssistant(threading.Thread):
         # owner-side audit duty (see assist_one_round): the assistant
         # must answer challenges on the part it owns, or every trainer
         # down-ranks it with audit-timeout strikes
-        audit_policy = None
-        if getattr(self.cfg, "audit_gather", False):
-            from dalle_tpu.swarm.audit import AuditPolicy
-            audit_policy = AuditPolicy(frac=self.cfg.audit_frac,
-                                       ttl=self.cfg.audit_ttl)
+        from dalle_tpu.swarm.audit import AuditPolicy
+        audit_policy = AuditPolicy(frac=self.cfg.audit_frac,
+                                   ttl=self.cfg.audit_ttl)
         template = np.zeros(self._n_elements, np.float32)
         tracker = ProgressTracker(self.dht, self.cfg.run_id,
                                   self.cfg.target_batch_size)

@@ -166,12 +166,12 @@ class CollaborativeOptimizer:
         # each seam pays one `is None` test (transparency pinned by
         # tests/test_obs.py).
         self.tracer = tracer
-        if tracer is None and getattr(cfg, "trace_file", None):
+        if tracer is None and cfg.trace_file:
             from dalle_tpu.obs.trace import Tracer
             self.tracer = Tracer(
                 peer=(dht.peer_id[:12] if dht is not None else "local"),
                 sink_path=cfg.trace_file,
-                ring_bytes=getattr(cfg, "trace_ring_kb", 256) * 1024)
+                ring_bytes=cfg.trace_ring_kb * 1024)
         self.local_epoch = 0
         self.local_samples = 0
         # Multi-host slices (parallel/multihost.py): exactly one process —
@@ -181,40 +181,36 @@ class CollaborativeOptimizer:
         # Peer-health ledger (swarm/health.py): allreduce bans feed
         # strikes; matchmaking and progress aggregation down-rank repeat
         # offenders until the strikes decay. Local knowledge only.
-        # Byzantine defense wiring (CHAOS.md "Defense in depth"):
+        # Byzantine defense wiring (CHAOS.md "Defense in depth"): a
+        # swarm-speaking peer always arms the whole trust plane —
         # content screening + the frame-weight clamp ride every
         # allreduce call below; the gossip worker publishes/folds
         # signed strike receipts until shutdown() reaps it.
         self._gossip = None
         if self.role.swarm_enabled:
+            from dalle_tpu.swarm.audit import (AuditPolicy, AuditWorker,
+                                               EvidencePlane)
             from dalle_tpu.swarm.health import PeerHealthLedger, StrikeGossip
+            from dalle_tpu.swarm.screening import GradientScreen, ScreenPolicy
             self.ledger = PeerHealthLedger()
             self.tracker = ProgressTracker(
                 dht, cfg.run_id, cfg.target_batch_size,
                 client_mode=client_mode, ledger=self.ledger,
-                max_epoch_lead=getattr(cfg, "progress_max_epoch_lead",
-                                       2))
-            if getattr(cfg, "screen_gradients", False):
-                from dalle_tpu.swarm.screening import (GradientScreen,
-                                                       ScreenPolicy)
-                self._screen = GradientScreen(ScreenPolicy(
-                    min_senders=cfg.screen_min_senders,
-                    max_drop_frac=cfg.screen_max_drop_frac,
-                    norm_tolerance=cfg.screen_norm_tolerance,
-                    cosine_floor=cfg.screen_cosine_floor,
-                    abs_norm_ceiling=getattr(
-                        cfg, "screen_abs_norm_ceiling", 0.0)))
-            else:
-                self._screen = None
-            mpw = getattr(cfg, "max_peer_weight", None)
+                max_epoch_lead=cfg.progress_max_epoch_lead)
+            self._screen = GradientScreen(ScreenPolicy(
+                min_senders=cfg.screen_min_senders,
+                max_drop_frac=cfg.screen_max_drop_frac,
+                norm_tolerance=cfg.screen_norm_tolerance,
+                cosine_floor=cfg.screen_cosine_floor,
+                abs_norm_ceiling=cfg.screen_abs_norm_ceiling))
+            mpw = cfg.max_peer_weight
             if mpw is None:
                 mpw = float(cfg.target_batch_size)
             self._max_peer_weight = mpw if mpw > 0 else None
-            if getattr(cfg, "gossip_strikes", False):
-                self._gossip = StrikeGossip(
-                    dht, self.ledger, cfg.run_id,
-                    period=cfg.strike_gossip_period)
-                self._gossip.start()
+            self._gossip = StrikeGossip(
+                dht, self.ledger, cfg.run_id,
+                period=cfg.strike_gossip_period)
+            self._gossip.start()
             # Verified aggregation (swarm/audit.py): the worker drains
             # completed rounds' RoundAudit retention off the training
             # thread — fetches challenged owners' transcripts, replays
@@ -234,62 +230,45 @@ class CollaborativeOptimizer:
             # graftlint: handoff=init-then-joined-teardown
             self._auditor = None
             # graftlint: handoff=init-then-joined-teardown
-            self._audit_policy = None
+            self._audit_policy = AuditPolicy(
+                frac=cfg.audit_frac, ttl=cfg.audit_ttl)
             self._repair = None
-            self._evidence = None
-            if getattr(cfg, "audit_gather", False):
-                from dalle_tpu.swarm.audit import (AuditPolicy, AuditWorker,
-                                                   EvidencePlane)
-                self._audit_policy = AuditPolicy(
-                    frac=cfg.audit_frac, ttl=cfg.audit_ttl)
-                if getattr(cfg, "repair_convicted", False) \
-                        and jax.process_count() == 1:
-                    # single-process peers only: a multi-host slice
-                    # would need every correction broadcast to stay in
-                    # lockstep (followers run no auditor to agree
-                    # with), and a plane nothing drains would just
-                    # retain part-sized copies — don't create one
-                    from dalle_tpu.swarm.repair import RepairPlane
-                    prefixes = [f"{cfg.run_id}_grads"]
-                    if getattr(cfg, "repair_aux_phases", False):
-                        # r20: factor and state convictions queue
-                        # corrections too, drained at their own phase's
-                        # application site (prefix-scoped — a factor
-                        # correction never lands in a gradient vector)
-                        prefixes += [f"{cfg.run_id}_grads_p",
-                                     f"{cfg.run_id}_grads_q",
-                                     f"{cfg.run_id}_state"]
-                    self._repair = RepairPlane(
-                        accept_prefix=tuple(prefixes))
-                if getattr(cfg, "proof_by_reference", False) \
-                        and self._gossip is not None:
-                    # Evidence-by-reference plane (r20): bundles past
-                    # PROOF_MAX_BYTES ride the receipt as digest +
-                    # mailbox reference; this plane serves ours and
-                    # fetches theirs (budgeted, hash-checked,
-                    # failover-capable). Without gossip nothing ever
-                    # publishes or resolves a reference — skip it.
-                    self._evidence = EvidencePlane(
-                        dht, cfg.run_id,
-                        max_bytes=getattr(cfg, "proof_fetch_max_bytes",
-                                          2 << 30),
-                        budget_s=getattr(cfg, "proof_fetch_budget_s",
-                                         30.0),
-                        retries=getattr(cfg, "proof_fetch_retries", 3),
-                        tracer=self.tracer)
-                    # bind-once wiring before the gossip worker's first
-                    # over-budget publish can look at it
-                    self._gossip.evidence_store = self._evidence
-                self._auditor = AuditWorker(
-                    dht, self.ledger, repair=self._repair,
-                    max_bytes=getattr(cfg, "audit_ring_bytes",
-                                      AuditWorker.MAX_BYTES),
-                    # with the by-reference plane armed, evidence has no
-                    # inline size cap — oversized bundles publish by
-                    # reference instead of degrading to capped accusation
-                    evidence_limit=0 if self._evidence is not None
-                    else None)
-                self._auditor.start()
+            if jax.process_count() == 1:
+                # single-process peers only: a multi-host slice
+                # would need every correction broadcast to stay in
+                # lockstep (followers run no auditor to agree
+                # with), and a plane nothing drains would just
+                # retain part-sized copies — don't create one.
+                # Factor and state convictions queue corrections
+                # too, drained at their own phase's application site
+                # (prefix-scoped — a factor correction never lands in
+                # a gradient vector)
+                from dalle_tpu.swarm.repair import RepairPlane
+                self._repair = RepairPlane(accept_prefix=(
+                    f"{cfg.run_id}_grads", f"{cfg.run_id}_grads_p",
+                    f"{cfg.run_id}_grads_q", f"{cfg.run_id}_state"))
+            # Evidence-by-reference plane: bundles past
+            # PROOF_MAX_BYTES ride the receipt as digest +
+            # mailbox reference; this plane serves ours and
+            # fetches theirs (budgeted, hash-checked,
+            # failover-capable).
+            self._evidence = EvidencePlane(
+                dht, cfg.run_id,
+                max_bytes=cfg.proof_fetch_max_bytes,
+                budget_s=cfg.proof_fetch_budget_s,
+                retries=cfg.proof_fetch_retries,
+                tracer=self.tracer)
+            # bind-once wiring before the gossip worker's first
+            # over-budget publish can look at it
+            self._gossip.evidence_store = self._evidence
+            self._auditor = AuditWorker(
+                dht, self.ledger, repair=self._repair,
+                max_bytes=cfg.audit_ring_bytes,
+                # with the by-reference plane armed, evidence has no
+                # inline size cap — oversized bundles publish by
+                # reference instead of degrading to capped accusation
+                evidence_limit=0)
+            self._auditor.start()
         else:
             self.ledger = None
             self.tracker = _FollowerTracker()
@@ -307,8 +286,7 @@ class CollaborativeOptimizer:
         # are identical either way. Resolved once — the backend is a
         # property of this process's hardware, not of the round.
         from dalle_tpu.swarm.device_codec import resolve_backend
-        self._codec_backend = resolve_backend(
-            getattr(cfg, "wire_codec_backend", "auto"))
+        self._codec_backend = resolve_backend(cfg.wire_codec_backend)
         # device-array handoff is only valid when every leaf lives whole
         # on this process (multi-process slices pull via the collective
         # host_global path regardless of codec backend)
@@ -335,9 +313,9 @@ class CollaborativeOptimizer:
         # second stage (swarm/error_feedback.py). Grad rounds only:
         # state averaging keeps its own codec, PowerSGD factor rounds
         # are a different compression family entirely.
-        wb_r = getattr(cfg, "wire_bits_reduce", None)
-        wb_g = getattr(cfg, "wire_bits_gather", None)
-        ef_on = getattr(cfg, "ef_residuals", False)
+        wb_r = cfg.wire_bits_reduce
+        wb_g = cfg.wire_bits_gather
+        ef_on = cfg.ef_residuals
         # the shared knob mapping (compression.codec_for_bits) raises
         # on anything outside {None, 4, 8}
         reduce_codec = compression.codec_for_bits(wb_r)
@@ -364,8 +342,8 @@ class CollaborativeOptimizer:
         # pipeline_hops contract). Grad rounds only — PowerSGD factor
         # rounds and state averaging keep the sequential protocol (they
         # are latency-insensitive and run rarely).
-        self._pipeline_hops = bool(getattr(cfg, "pipeline_hops", False))
-        self._pipeline_depth = int(getattr(cfg, "pipeline_depth", 2))
+        self._pipeline_hops = bool(cfg.pipeline_hops)
+        self._pipeline_depth = int(cfg.pipeline_depth)
         if ef_on:
             from dalle_tpu.swarm.error_feedback import ErrorFeedback
             self._ef_scatter = ErrorFeedback()
@@ -382,7 +360,7 @@ class CollaborativeOptimizer:
         # resolution: the verifier judges by the same codec/pin/screen/
         # clamp this peer's own rounds run under (the run-config-
         # homogeneity contract the r14 audit already documents).
-        if self._gossip is not None and self._audit_policy is not None:
+        if self.role.swarm_enabled:
             from dalle_tpu.swarm.allreduce import CHUNK_ELEMS
             from dalle_tpu.swarm.audit import ProofVerifier
             self._gossip.verifier = ProofVerifier(
@@ -400,9 +378,8 @@ class CollaborativeOptimizer:
                     "state": {"codec": self._state_codec,
                               "gather_codec": None, "pinned": None},
                 },
-                # r20: receipts whose evidence rides by reference are
-                # resolved through the fetch plane before replay; with
-                # no plane armed they are dropped without ledger effect
+                # receipts whose evidence rides by reference are
+                # resolved through the fetch plane before replay
                 fetcher=self._evidence)
         self._grad_acc = None
         self._accumulate = jax.jit(
@@ -594,18 +571,15 @@ class CollaborativeOptimizer:
                 and process_count() == 1)
 
     def _new_round_audit(self, epoch: int, phase_suffix: str = "grads"):
-        """A fresh per-round audit container, or None when auditing is
-        off. ``phase_suffix`` names the averaging phase's prefix leg:
-        the main gradient rounds ("grads"), the PowerSGD factor rounds
+        """A fresh per-round audit container, or None on a peer that
+        runs no auditor (a follower of a multi-host slice).
+        ``phase_suffix`` names the averaging phase's prefix leg: the
+        main gradient rounds ("grads"), the PowerSGD factor rounds
         ("grads_p"/"grads_q") and the periodic state averaging
-        ("state") each ride the same butterfly and, since r16, the
-        same challenge/transcript/replay machinery under their own
-        prefix (the r14 per-phase gap CHAOS.md documented). Aux-phase
-        auditing is gated by ``cfg.audit_aux_phases``."""
+        ("state") each ride the same butterfly and the same
+        challenge/transcript/replay machinery under their own
+        prefix."""
         if self._auditor is None:
-            return None
-        if phase_suffix != "grads" and not getattr(
-                self.cfg, "audit_aux_phases", False):
             return None
         from dalle_tpu.swarm.audit import RoundAudit
         return RoundAudit(f"{self.cfg.run_id}_{phase_suffix}", epoch,
@@ -1031,14 +1005,14 @@ class CollaborativeOptimizer:
                 # the factor rounds are audited like any butterfly
                 # round (r16): a challenged factor-part owner serves a
                 # transcript under the phase prefix, and a conviction
-                # gossips a proof-carrying receipt. Since r20 they are
-                # REPAIRED too (cfg.repair_aux_phases): a replayed-
-                # bytes-mismatch conviction queues its honest-minus-
-                # served correction under this phase's prefix, and the
-                # drain below patches the averaged factor bytes before
-                # the compressor reconstructs from them — the same
-                # pre-step-exact / bounded-staleness split as gradient
-                # repair, confined to projection space.
+                # gossips a proof-carrying receipt. They are REPAIRED
+                # too: a replayed-bytes-mismatch conviction queues its
+                # honest-minus-served correction under this phase's
+                # prefix, and the drain below patches the averaged
+                # factor bytes before the compressor reconstructs from
+                # them — the same pre-step-exact / bounded-staleness
+                # split as gradient repair, confined to projection
+                # space.
                 prefix = f"{self.cfg.run_id}_grads_{phase}"
                 ra = self._new_round_audit(self.local_epoch,
                                            f"grads_{phase}")

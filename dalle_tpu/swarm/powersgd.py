@@ -19,8 +19,8 @@ Algorithm, per 2D-reshapable gradient M (m x n), rank r:
 
 **Where the work happens.** All O(m*n*r) math — the P/Q projections, the
 reconstruction, and the error-feedback update — runs as jitted device ops
-(the BASELINE.json north star names PowerSGD "reimplemented as XLA/Pallas
-kernels"); the error-feedback and M caches are device arrays, not host
+(the north star names PowerSGD "reimplemented as XLA/Pallas kernels");
+the error-feedback and M caches are device arrays, not host
 RAM. Only the rank-r factors (r*(m+n) floats per tensor, ~128x smaller
 than the gradients at the flagship's 1024x4096 blocks) cross to the host
 for the wire. Gram-Schmidt is the one exception, and it runs on the HOST
@@ -58,21 +58,20 @@ Tensors too small to win from rank-r factorization travel uncompressed
 through the same all-reduce rounds (appended to the Q phase).
 
 Trust (r16): the factor rounds ride the same butterfly as the gradient
-rounds and, with ``CollabConfig.audit_aux_phases``, the same verified-
-aggregation machinery under their own prefixes (``{run}_grads_p`` /
-``_q``) — a hostile factor-part owner serving wrong averaged-P bytes
+rounds and the same verified-aggregation machinery under their own
+prefixes (``{run}_grads_p`` / ``_q``) — a hostile factor-part owner serving wrong averaged-P bytes
 (which every peer would then orthogonalize into a corrupted shared
 basis) is convicted by transcript replay exactly like a gradient-part
 owner, and the conviction gossips as a proof-carrying receipt
-(swarm/audit.py, CHAOS.md "Round repair"). Since r20 factor rounds are
-REPAIRED as well (``CollabConfig.repair_aux_phases``): the conviction's
-``honest - served`` correction is queued under the phase's own prefix
-and the optimizer's reduce callback drains it into the averaged factor
-bytes before reconstruction — in projection space, where the correction
-actually lives, never scattered into the gradient accumulator. With aux
-repair off the blast radius of one wrong factor round stays this
-epoch's reconstruction — the same bound the :class:`IncompleteRound`
-fallback already accepts.
+(swarm/audit.py, CHAOS.md "Round repair"). Factor rounds are REPAIRED
+as well: the conviction's ``honest - served`` correction is queued
+under the phase's own prefix and the optimizer's reduce callback drains
+it into the averaged factor bytes before reconstruction — in projection
+space, where the correction actually lives, never scattered into the
+gradient accumulator. Where no
+repair plane is wired (a multi-host slice) the blast radius of one wrong
+factor round stays this epoch's reconstruction — the same bound the
+:class:`IncompleteRound` fallback already accepts.
 
 Compression: a (m x n) tensor costs r*(m+n) floats on the wire instead of
 m*n — at the flagship's 1024x1024 blocks and rank 4 that is 128x less

@@ -44,9 +44,9 @@ replay *succeeded* (the transcript is internally consistent — the
 shape) yield an honest reconstruction; a transcript that is itself the
 lie proves the owner dishonest without revealing what the honest part
 was, so those convictions stay detection-only (the round degrades
-exactly as in r15). Repair OFF (``CollabConfig.repair_convicted``
-False, or no plane wired) leaves every byte identical to the r15
-protocol — the plane is pull-only and nothing consults it.
+exactly as in r15). With no plane wired (a multi-host slice, or a bare
+``run_allreduce`` call) every byte is identical to the r15 protocol —
+the plane is pull-only and nothing consults it.
 
 The retention that makes late repair possible — the per-round
 :class:`~dalle_tpu.swarm.audit.RoundAudit` objects queued at the

@@ -1,7 +1,7 @@
 """Overlap bench: how much of the collective does the r19 pipeline hide?
 
-The r18 overlap demo (OVERLAP_DEMO.json) proved the ROUND can run
-behind grad steps; this bench measures what r19's per-part pipeline
+An overlapped round runs behind grad steps (``delay_optimizer_step``,
+tests/test_collab.py); this bench measures what r19's per-part pipeline
 does to the round itself at the flagship payload (~125.6M unique
 params, ~502 MB f32 per peer, the SWARM_SCALE.md regime): N loopback
 peers run ONE honest grad round per mode — sequential protocol vs

@@ -538,6 +538,14 @@ class TestCollaborativeOptimizer:
         try:
             import jax.numpy as jnp
 
+            # a swarm-speaking peer arms the whole trust plane: no
+            # configuration switches any of it off
+            for opt in opts:
+                for plane in ("_screen", "_gossip", "_auditor",
+                              "_audit_policy", "_repair", "_evidence"):
+                    assert getattr(opt, plane) is not None, plane
+                assert opt._gossip.verifier is not None
+
             def run_peer(i):
                 opt = opts[i]
                 grads = {"w": jnp.full((16,), float(i + 1)),
