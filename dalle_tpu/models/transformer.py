@@ -11,17 +11,28 @@ Memory: the reference uses reversible residual layers (``reversible=True``,
 ``task.py:81``) to get O(1) activation memory; the XLA-idiomatic equivalent is
 rematerialisation — each block is wrapped in ``jax.checkpoint`` via
 ``nn.remat`` so backward recomputes activations block by block.
+
+The layer scan: the body's layers run as one ``nn.scan`` over
+:class:`BlockCycle`, whose body holds ``shared_block_cycle x scan_unroll``
+block slots. Where the body is not a whole number of iterations (the
+flagship's 63 layers in 8 x 8 slots) the last iteration has no layer for
+its final slots: such a slot runs under a conditional on the scan index
+(:func:`_run_if`), the slots that are never empty call their block
+outright, and the chip computes the 63 layers the model has.
+:func:`layer_loop_record` says which case a configuration is.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.extend import core as jex_core
 from jax.sharding import PartitionSpec as P
 
 from dalle_tpu.config import ModelConfig
@@ -31,6 +42,8 @@ from dalle_tpu.models.attention import (
     zoo_attention_lanes,
 )
 from dalle_tpu.parallel.mesh import TOKENS_SPEC, per_shard
+
+logger = logging.getLogger(__name__)
 
 
 def _dtype(cfg: ModelConfig):
@@ -304,40 +317,162 @@ class TransformerBlock(nn.Module):
         return x
 
 
+def _scan_plan(cfg: ModelConfig):
+    """How the body of ``cfg``'s schedule is scanned: ``(n_body, cycle,
+    per_iter, reps)``. The scan body holds ``per_iter`` block slots, which
+    cycle ``cycle`` unique blocks, and runs ``reps`` times; ``reps`` is 0
+    where the stack unrolls instead (no cycle, or a body no longer than
+    one iteration)."""
+    n_body = len(cfg.layer_schedule()) - (1 if cfg.final_conv_block else 0)
+    # dense (cycle=0) with dense_scan: scan one attn-type group with
+    # STACKED per-iteration params — the compiled body stays one group
+    # while every iteration reads its own weights (a 64-block dense
+    # flagship otherwise unrolls to an XLA program ~16x the shared
+    # model's, past the compile service's budget). Each iteration's param
+    # slice is one group of layers, so in-iteration unrolling would REUSE
+    # that slice — and the unroll lever only exists to amortize the
+    # shared-weight grad accumulation dense models don't have: unroll 1.
+    dense_scan = cfg.dense_scan_reps() > 0
+    cycle = len(cfg.attn_types) if dense_scan else cfg.shared_block_cycle
+    per_iter = cycle * (1 if dense_scan else max(1, cfg.scan_unroll))
+    reps = -(-n_body // per_iter) if cycle else 0
+    return n_body, cycle, per_iter, reps if reps > 1 else 0
+
+
+def layer_loop_record(cfg: ModelConfig) -> str:
+    """How the layer scan runs ``cfg``'s body, in words — the
+    ``layer_loop`` attribute of the ``train`` plane's ``setup/warmup``
+    row: how many slots :class:`BlockCycle` always runs and how many sit
+    under a conditional because the last iteration has no layer for
+    them."""
+    n_body, _, per_iter, reps = _scan_plan(cfg)
+    if not reps:
+        return "unrolled"
+    always = n_body - (reps - 1) * per_iter
+    head = f"{n_body} layers in {reps} x {per_iter} slots: "
+    if always == per_iter:
+        return head + "all always run"
+    return head + (f"{always} always run, {per_iter - always} conditional "
+                   f"(runs {reps - 1} of {reps})")
+
+
+@functools.lru_cache(maxsize=None)
+def _log_layer_loop(record: str) -> None:
+    logger.info("layer loop: %s", record)
+
+
+def _run_if(active, fn, consts, x):
+    """``fn(consts, x)`` where ``active`` (a traced scalar), else ``x``: a
+    conditional that costs the empty turn nothing, forward or backward.
+
+    Differentiated by its own rule, because ``lax.cond``'s returns every
+    value its backward reads as an output of the forward conditional, the
+    branch's own inputs among them: inside the layer scan each iteration
+    would copy the block's weights and rotary tables out of the
+    conditional and stack them with the residuals (the XL step planned
+    2.9 GiB more that way). Here the forward conditional returns the
+    result and those residuals only that the live branch computes: one
+    that IS an input of the branch (a weight, the block's input) or
+    another output (the result itself) is read where it already lives. A
+    rematted block, whose residuals are its inputs, so crosses the
+    conditional with nothing but its result; a plain one with what it
+    saves. The backward conditional runs the branch's pullback or passes
+    the cotangent through beside zero weight gradients.
+    """
+    zeros = functools.partial(jax.tree.map,
+                              lambda a: jnp.zeros(a.shape, a.dtype))
+    args, in_tree = jax.tree.flatten((consts, x))
+    # ``fn`` is traced here, once, as the always-live slots' blocks are;
+    # both directions below work on its jaxpr
+    traced = jax.make_jaxpr(
+        lambda *args: fn(*jax.tree.unflatten(in_tree, args)))(*args)
+
+    def live(*args):
+        return jex_core.jaxpr_as_fun(traced)(*args)[0]
+
+    @jax.custom_vjp
+    def run(active, *args):
+        return jax.lax.cond(active, live, lambda *args: args[-1], *args)
+
+    def fwd(active, *args):
+        closed, out_shape = jax.make_jaxpr(
+            lambda *args: jax.vjp(live, *args), return_shape=True)(*args)
+        # where each output of the live branch comes from: an input or a
+        # constant of the branch, or the first output that holds the
+        # same value
+        source = {id(v): ("in", i) for i, v in enumerate(closed.jaxpr.invars)}
+        source.update({id(v): ("const", i)
+                       for i, v in enumerate(closed.jaxpr.constvars)})
+        kept = []
+        for i, v in enumerate(closed.jaxpr.outvars):
+            if id(v) not in source:
+                source[id(v)] = ("kept", len(kept))
+                kept.append(i)
+        assert kept[0] == 0, "the branch's result is its own output"
+
+        def branch(*args):
+            outs = jex_core.jaxpr_as_fun(closed)(*args)
+            return [outs[i] for i in kept]
+
+        def empty(*args):
+            return [args[-1]] + zeros([closed.out_avals[i] for i in kept[1:]])
+
+        held = {"in": args, "const": closed.consts,
+                "kept": jax.lax.cond(active, branch, empty, *args)}
+        y, pullback = jax.tree.unflatten(
+            jax.tree.structure(out_shape),
+            [held[kind][i] for kind, i in
+             (source[id(v)] for v in closed.jaxpr.outvars)])
+        return y, (active, pullback)
+
+    def bwd(residuals, ct):
+        active, pullback = residuals
+        return (None,) + tuple(jax.lax.cond(
+            active, lambda pullback, ct: pullback(ct),
+            lambda pullback, ct: (*zeros(traced.in_avals[:-1]), ct),
+            pullback, ct))
+
+    run.defvjp(fwd, bwd)
+    return run(active, *args)
+
+
+def _as_function(block: nn.Module):
+    """A bound block as a function of ``((variables, rot), x)``: what
+    :func:`_run_if` takes (its conditional is jax's, not a lifted one)."""
+    call = nn.apply(lambda blk, x, rot: blk(x, rot), block)
+    return lambda consts, x: call(consts[0], x, consts[1])
+
+
 class BlockCycle(nn.Module):
     """One pass over the unique weight-shared blocks (the scan body).
 
-    ``n_body`` bounds the global layer index: when the body depth is not a
-    clean multiple of the cycle (the flagship's 63 = 15x4 + 3), the final
-    iteration's overhanging blocks still execute (scan bodies are uniform)
-    but their outputs are discarded by a ``where`` — one wasted block
-    evaluation per step buys compiling the cycle once instead of unrolling
-    64 layers.
+    The body's depth bounds the global layer index: when it is not a
+    clean multiple of the slots an iteration holds (the flagship's 63 in
+    8 x 8), the last of the ``reps`` iterations has no layer for its final
+    slots. A slot the last iteration still fills is always live: it calls
+    its block and takes the result. A slot that can be empty runs under a
+    conditional on the scan index — the block in one branch, the identity
+    in the other — so the empty turn costs no forward, no replay and no
+    backward, and the cycle is still compiled once.
     """
 
     cfg: ModelConfig
     block_cls: Any
-    n_body: int
     mesh: Any = None
     # blocks with uid >= cycle - remat_skip_blocks use this class instead
     # (plain, no remat) — partial remat, cfg.remat_skip_blocks
     plain_cls: Any = None
-    # body size override: the weight-shared path cycles
-    # cfg.shared_block_cycle unique blocks; the dense_scan path (stacked
-    # per-iteration params) cycles one attn-type group instead
-    cycle_override: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array, it: jax.Array) -> jax.Array:
         cfg = self.cfg
         rot = _make_rot(cfg)
-        cycle = self.cycle_override or cfg.shared_block_cycle
-        # dense_scan (cycle_override set): each iteration's param slice is
-        # one group of layers, so in-iteration unrolling would REUSE that
-        # slice — and the unroll lever only exists to amortize the shared-
-        # weight grad accumulation dense models don't have. Force 1.
-        unroll = 1 if self.cycle_override else max(1, cfg.scan_unroll)
-        exact = self.n_body % (cycle * unroll) == 0
+        # the weight-shared path cycles cfg.shared_block_cycle unique
+        # blocks; the dense_scan path (stacked per-iteration params) one
+        # attn-type group
+        n_body, cycle, per_iter, reps = _scan_plan(cfg)
+        # the slots the last iteration still fills: those never empty
+        filled = n_body - (reps - 1) * per_iter
         first_plain = cycle - cfg.remat_skip_blocks
         blocks = {}
         for uid in range(cycle):
@@ -347,17 +482,18 @@ class BlockCycle(nn.Module):
             blocks[uid] = cls(cfg, attn_type, mesh=self.mesh,
                               fuse_ff=cfg.fuse_ff(is_plain),
                               name=f"block_{uid}")
-        for u in range(unroll):
-            for uid in range(cycle):
-                # one module instance per uid, called ``unroll`` times:
-                # Flax shares the parameters across the calls
-                y = blocks[uid](x, rot)
-                if exact:
-                    x = y
-                else:
-                    active = ((it * unroll + u) * cycle + uid
-                              < self.n_body)
-                    x = jnp.where(active, y, x)
+        for slot in range(per_iter):
+            # one module instance per uid, called ``per_iter / cycle`` times:
+            # Flax shares the parameters across the calls
+            block = blocks[slot % cycle]
+            # (while initializing, the plain call: both branches of a
+            # conditional must make the same variables, and with unroll 1
+            # the conditional slot is its block's first call)
+            if slot < filled or self.is_initializing():
+                x = block(x, rot)
+            else:
+                x = _run_if(it * per_iter + slot < n_body,
+                            _as_function(block), (block.variables, rot), x)
         return x, None
 
 
@@ -377,7 +513,9 @@ class Transformer(nn.Module):
     repetitions run as one ``nn.scan`` with broadcast parameters — XLA
     compiles the cycle once instead of unrolling 64 layers (SURVEY.md §2:
     "lax.scan over a stack of 4 unique blocks repeated 16x"), and the
-    shared weights' gradients accumulate through the scan.
+    shared weights' gradients accumulate through the scan. A body that is
+    not a whole number of iterations is scanned all the same: the slots
+    its last iteration leaves empty are conditional (:class:`BlockCycle`).
     """
 
     cfg: ModelConfig
@@ -413,31 +551,19 @@ class Transformer(nn.Module):
                 policy = None  # blanket remat: save only block boundaries
             block_cls = nn.remat(TransformerBlock, policy=policy)
 
-        cycle = cfg.shared_block_cycle
-        body = len(sched) - (1 if cfg.final_conv_block else 0)
-        # dense (cycle=0) with dense_scan: scan one attn-type group with
-        # STACKED per-iteration params — the compiled body stays one
-        # group while every iteration reads its own weights (a 64-block
-        # dense flagship otherwise unrolls to an XLA program ~16x the
-        # shared model's, past the compile service's budget)
-        dense_scan = cfg.dense_scan_reps() > 0
-        group = len(cfg.attn_types) if dense_scan else cycle
-        # dense_scan forces unroll 1 (see BlockCycle): per_iter = group
-        unroll = 1 if dense_scan else max(1, cfg.scan_unroll)
-        per_iter = group * unroll if group else 0
-        reps = (cfg.dense_scan_reps() if dense_scan
-                else -(-body // per_iter) if group else 0)
-        if group and reps > 1:
+        body, _, _, reps = _scan_plan(cfg)
+        _log_layer_loop(layer_loop_record(cfg))
+        if reps:
+            dense_scan = cfg.dense_scan_reps() > 0
             scan = nn.scan(
                 BlockCycle,
                 variable_broadcast=() if dense_scan else "params",
                 variable_axes={"params": 0} if dense_scan else {},
                 split_rngs={"params": dense_scan})
-            x, _ = scan(cfg, block_cls, body, mesh=self.mesh,
+            x, _ = scan(cfg, block_cls, mesh=self.mesh,
                         plain_cls=(TransformerBlock if cfg.remat
                                    and cfg.remat_skip_blocks
                                    and not dense_scan else None),
-                        cycle_override=group if dense_scan else 0,
                         name="cycle")(x, jnp.arange(reps))
             rest = sched[body:]
         else:

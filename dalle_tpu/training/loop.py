@@ -22,6 +22,7 @@ import jax
 import numpy as np
 
 from dalle_tpu.models.attention import attn_layout_record
+from dalle_tpu.models.transformer import layer_loop_record
 from dalle_tpu.swarm.metrics import LocalMetrics, publish_metrics
 from dalle_tpu.task import TrainingTask
 from dalle_tpu.training.steps import grad_reduction_plan
@@ -48,7 +49,8 @@ def warmup(task: TrainingTask, steps: int = 3) -> float:
     params = task.collab_optimizer.state.params
     loss = float("nan")
     with task.tracer.span("train", "setup/warmup", "setup", steps=steps,
-                          grad_reduction=grad_reduction_plan(task.mesh)
+                          grad_reduction=grad_reduction_plan(task.mesh),
+                          layer_loop=layer_loop_record(task.model_cfg)
                           ) as span:
         for i in range(steps):
             t0 = time.monotonic()

@@ -161,8 +161,10 @@ def round_table(rows: List[dict]) -> List[dict]:
 def setup_facts(rows: List[dict]) -> Dict[str, dict]:
     """What the trainer's set-up rows say beside their duration (plane
     ``train``, trace ``setup``): e.g. ``setup/warmup``'s ``grad_reduction``,
-    the plan the gradient step took for its sum over ``dp``, and its
-    ``attn_layout``, how many attention layers took the Mosaic kernel."""
+    the plan the gradient step took for its sum over ``dp``, its
+    ``attn_layout``, how many attention layers took the Mosaic kernel, and
+    its ``layer_loop``, which slots of the layer scan run under a
+    conditional."""
     facts: Dict[str, dict] = {}
     for r in rows:
         if r["plane"] == "train" and r["trace"] == "setup" and r.get("a") \

@@ -21,12 +21,14 @@ from dalle_tpu.training.steps import (
 )
 
 
-def _flagship_shaped():
+def _flagship_shaped(depth=9):
     """The flagship's structure at toy widths: four weight-shared blocks
-    cycled by a layer scan two passes at a time, a conv block after the
-    scan, rematerialised, parameters cast once at the top of the loss."""
+    cycled two passes at a time, a conv block after them, rematerialised,
+    parameters cast once at the top of the loss. Depth 9 is one pass of
+    the eight slots (no scan is built); depth 16 is the flagship's case,
+    15 layers in 2 x 8 slots, the last of them conditional."""
     return tiny_model_config(
-        dim=64, heads=4, head_dim=16, depth=9, shared_block_cycle=4,
+        dim=64, heads=4, head_dim=16, depth=depth, shared_block_cycle=4,
         scan_unroll=2, attn_types=("axial_row", "axial_col", "axial_row",
                                    "axial_row"),
         final_conv_block=True, conv_kernel=3, remat=True, ln_fusion=True,
@@ -104,7 +106,22 @@ class TestDpReduction:
         masked loss is normalised per microbatch, so this also holds the
         microbatches' make-up and the cross-shard denominators. (XLA
         lowerings: the kernels' own nesting parity is in their files.)"""
-        cfg = _flagship_shaped()
+        self._dp4_equals_one_device(_flagship_shaped(), masked, step_kind)
+
+    def test_dp4_step_equals_the_one_device_step_with_a_conditional_slot(
+            self):
+        """The layer scan's conditional slot under the ``shard_map`` over
+        ``dp``: its predicate is the scan index, the same on every
+        shard."""
+        from dalle_tpu.models.transformer import layer_loop_record
+        cfg = _flagship_shaped(depth=16)
+        assert layer_loop_record(cfg) == (
+            "15 layers in 2 x 8 slots: 7 always run, 1 conditional "
+            "(runs 1 of 2)")
+        self._dp4_equals_one_device(cfg, False, "grad_step")
+
+    @staticmethod
+    def _dp4_equals_one_device(cfg, masked, step_kind):
         mesh = make_mesh(dp=4, devices=jax.devices()[:4])
         params = init_params(DALLE(cfg), jax.random.PRNGKey(0))
         batch = _batch16(cfg, masked)

@@ -2,6 +2,7 @@
 causality, weight sharing, loss behavior."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -180,67 +181,6 @@ class TestModel:
         base_u = n_unshared - blocks_params_unshared * per_block
         assert abs(base_s - base_u) < 1e-6
 
-    def test_scan_cycle_matches_unrolled(self):
-        """The nn.scan BlockCycle path (the flagship's forward, including
-        the 63 = 15x4 + 3 overhang discard) must match the unrolled
-        schedule exactly, given the same parameters."""
-        import flax
-        import jax.numpy as jnp
-
-        from dalle_tpu.models.transformer import Transformer
-
-        # depth 10 with final conv: body 9 = 2 full cycles + 1 overhang
-        cfg = tiny_model_config(
-            dim=32, heads=2, head_dim=16, depth=10, shared_block_cycle=4,
-            final_conv_block=True,
-            attn_types=("axial_row", "axial_col", "axial_row", "full"),
-            conv_kernel=3)
-        assert cfg.layer_schedule()[:4] == tuple(
-            (i, cfg.attn_types[i]) for i in range(4))
-        model = Transformer(cfg)
-        x = jax.random.normal(jax.random.PRNGKey(0), (2, cfg.total_seq_len,
-                                                      cfg.dim))
-        params = model.init(jax.random.PRNGKey(1), x)
-        out_scan = model.apply(params, x)
-
-        # rebuild the same computation unrolled, reusing the scan's params
-        flat = flax.traverse_util.flatten_dict(params["params"])
-        renamed = {}
-        for path, leaf in flat.items():
-            if path[0] == "cycle":
-                renamed[path[1:]] = leaf
-            else:
-                renamed[path] = leaf
-        unrolled_params = {"params": flax.traverse_util.unflatten_dict(
-            renamed)}
-
-        from dalle_tpu.models.transformer import (TransformerBlock,
-                                                  _make_rot)
-        import flax.linen as nn
-
-        from dalle_tpu.config import ModelConfig
-
-        class Unrolled(nn.Module):
-            cfg: ModelConfig
-
-            @nn.compact
-            def __call__(self, x):
-                rot = _make_rot(self.cfg)
-                blocks = {}
-                for uid, at in self.cfg.layer_schedule():
-                    if uid not in blocks:
-                        name = ("block_wconv" if uid == -1
-                                else f"block_{uid}")
-                        blocks[uid] = TransformerBlock(self.cfg, at,
-                                                       name=name)
-                    x = blocks[uid](x, rot)
-                return nn.LayerNorm(name="final_norm")(x)
-
-        out_unrolled = Unrolled(cfg).apply(unrolled_params, x)
-        np.testing.assert_allclose(np.asarray(out_scan),
-                                   np.asarray(out_unrolled),
-                                   rtol=2e-5, atol=2e-5)
-
     def test_loss_decreases_under_overfit_signal(self):
         """Sanity: loss on an all-constant batch is lower than on random
         tokens after a few SGD steps (full training-loop test lives in
@@ -284,6 +224,176 @@ class TestModel:
         loss_f, _ = model.apply(params, text, img)
         assert np.isfinite(float(loss_m))
         assert float(loss_m) != pytest.approx(float(loss_f))
+
+
+# The layer scan against the unrolled schedule. Each case is a schedule
+# shaped like one the presets run (dim 32, cycle 4, a final conv block):
+#   overhang_unroll1  body 9 in 3 x 4 slots: slots 1-3 conditional, and
+#                     slot 3 is block_3's first call
+#   plain_slot        body 15 in 2 x 8, remat_skip_blocks 1 + save_attn:
+#                     the one conditional slot is the plain block (flagship)
+#   rematted_slot     the same under blanket remat (XL)
+#   even              body 16 in 2 x 8: no slot can be empty
+SCAN_CASES = {
+    "overhang_unroll1": dict(depth=10),
+    "plain_slot": dict(depth=16, scan_unroll=2, remat=True,
+                       remat_skip_blocks=1, remat_policy="save_attn"),
+    "rematted_slot": dict(depth=16, scan_unroll=2, remat=True,
+                          remat_skip_blocks=0),
+    "even": dict(depth=17, scan_unroll=2, remat=True,
+                 remat_skip_blocks=1, remat_policy="save_attn"),
+}
+SCAN_CONDITIONAL_SLOTS = {"overhang_unroll1": 3, "plain_slot": 1,
+                          "rematted_slot": 1, "even": 0}
+
+# the parameter tree of every case: what checkpoints and the benchmark's
+# yardstick read (the parent's paths, written out)
+_BLOCK_LEAVES = (
+    "attn/k/kernel", "attn/out/bias", "attn/out/kernel", "attn/q/kernel",
+    "attn/v/kernel", "attn_norm/bias", "attn_norm/scale", "ff/gate/bias",
+    "ff/gate/kernel", "ff/wi/bias", "ff/wi/kernel", "ff/wo/bias",
+    "ff/wo/kernel", "ff_norm/bias", "ff_norm/scale")
+SCAN_PARAM_PATHS = sorted(
+    [f"{block}/{leaf}" for block in (
+        "block_wconv", "cycle/block_0", "cycle/block_1", "cycle/block_2",
+        "cycle/block_3") for leaf in _BLOCK_LEAVES]
+    + ["final_norm/bias", "final_norm/scale"])
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_case(case):
+    from dalle_tpu.models.transformer import Transformer
+    cfg = tiny_model_config(
+        dim=32, heads=2, head_dim=16, shared_block_cycle=4,
+        final_conv_block=True,
+        attn_types=("axial_row", "axial_col", "axial_row", "full"),
+        conv_kernel=3, **SCAN_CASES[case])
+    model = Transformer(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(0),
+                          (2, cfg.total_seq_len, cfg.dim))
+    return cfg, model, x, model.init(jax.random.PRNGKey(1), x)
+
+
+def _scan_loss(model, x):
+    target = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+    return lambda params: jnp.mean((model.apply(params, x) - target) ** 2)
+
+
+@pytest.mark.parametrize("case", sorted(SCAN_CASES))
+def test_scan_cycle_matches_unrolled(case):
+    """The nn.scan BlockCycle path (the flagship's, with the slot its last
+    iteration leaves empty under a conditional) matches the unrolled
+    schedule on the loss and on every gradient leaf, given the same
+    parameters."""
+    import flax
+    import flax.linen as nn
+
+    from dalle_tpu.models.transformer import TransformerBlock, _make_rot
+
+    cfg, model, x, params = _scan_case(case)
+
+    class Unrolled(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            rot = _make_rot(cfg)
+            blocks = {}
+            for uid, at in cfg.layer_schedule():
+                if uid not in blocks:
+                    blocks[uid] = TransformerBlock(
+                        cfg, at,
+                        name="block_wconv" if uid == -1 else f"block_{uid}")
+                x = blocks[uid](x, rot)
+            return nn.LayerNorm(name="final_norm")(x)
+
+    def without_cycle(tree):
+        flat = flax.traverse_util.flatten_dict(tree["params"])
+        return {"params": flax.traverse_util.unflatten_dict(
+            {p[1:] if p[0] == "cycle" else p: v for p, v in flat.items()})}
+
+    loss, grads = jax.jit(jax.value_and_grad(_scan_loss(model, x)))(params)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+        _scan_loss(Unrolled(), x)))(without_cycle(params))
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
+    got = flax.traverse_util.flatten_dict(without_cycle(grads))
+    want = flax.traverse_util.flatten_dict(ref_grads)
+    assert sorted(got) == sorted(want)
+    for path, g in want.items():
+        scale = float(jnp.abs(g).max())
+        assert scale > 0, path
+        np.testing.assert_allclose(
+            np.asarray(got[path]), np.asarray(g), rtol=1e-4,
+            atol=2e-5 * scale, err_msg="/".join(path))
+
+
+@pytest.mark.parametrize("case", sorted(SCAN_CASES))
+def test_scan_cycle_parameter_tree(case):
+    """The conditional changes no parameter's path, also where the
+    conditional slot is its block's first call (overhang_unroll1)."""
+    import flax
+    _, _, _, params = _scan_case(case)
+    assert sorted("/".join(p) for p in flax.traverse_util.flatten_dict(
+        params["params"])) == SCAN_PARAM_PATHS
+
+
+def _walk_jaxpr(jaxpr, stack=(), in_cond=False):
+    """(primitive, name-stack path, inside a cond, equation) of every
+    equation, the nested jaxprs' included (an inner name stack is relative
+    to its holder's)."""
+    for eqn in jaxpr.eqns:
+        path = stack + (str(eqn.source_info.name_stack),)
+        yield eqn.primitive.name, "/".join(path), in_cond, eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_jaxpr(
+                        sub, path, in_cond or eqn.primitive.name == "cond")
+
+
+@pytest.mark.parametrize("case", sorted(SCAN_CASES))
+def test_scan_cycle_conditionals(case):
+    """One conditional a direction for each slot that can be empty, none
+    on an even schedule, and no select left on the cycle's own path (the
+    ``where`` that used to discard every slot's result): the selects that
+    remain belong to a block (attention masks) or sit inside a
+    conditional's branch."""
+    _, model, x, params = _scan_case(case)
+    eqns = list(_walk_jaxpr(
+        jax.make_jaxpr(jax.grad(_scan_loss(model, x)))(params).jaxpr))
+    conds = [(path, eqn) for name, path, inside, eqn in eqns
+             if name == "cond" and not inside]
+    assert len(conds) == 2 * SCAN_CONDITIONAL_SLOTS[case], conds
+    assert all("cycle" in path for path, _ in conds)
+    assert sum(name == "scan" for name, *_ in eqns) == 2
+    assert not [path for name, path, inside, _ in eqns
+                if name == "select_n" and not inside and "cycle" in path
+                and "block_" not in path]
+    if case == "rematted_slot":
+        # a rematted block's residuals are its inputs: nothing but the
+        # result leaves the forward conditional (lax.cond's own derivative
+        # returns the weights too, and the scan stacks them)
+        forward, = [eqn for path, eqn in conds if "transpose" not in path]
+        assert len(forward.outvars) == 1
+
+
+def test_layer_loop_record_of_the_presets():
+    """The ``layer_loop`` attribute of the ``setup/warmup`` row, a pure
+    function of the configuration (nothing is traced or compiled)."""
+    from dalle_tpu.config import (flagship_model_config,
+                                  long_context_model_config, xl_model_config)
+    from dalle_tpu.models.transformer import layer_loop_record
+    overhang = ("63 layers in 8 x 8 slots: 7 always run, 1 conditional "
+                "(runs 7 of 8)")
+    assert layer_loop_record(flagship_model_config()) == overhang
+    assert layer_loop_record(xl_model_config()) == overhang
+    assert layer_loop_record(long_context_model_config()) == \
+        "64 layers in 16 x 4 slots: all always run"
+    assert layer_loop_record(flagship_model_config(scan_unroll=1)) == \
+        "63 layers in 16 x 4 slots: 3 always run, 1 conditional (runs 15 of 16)"
+    assert layer_loop_record(tiny_model_config(
+        depth=4, shared_block_cycle=4)) == "unrolled"
+    assert layer_loop_record(tiny_model_config(
+        depth=6, shared_block_cycle=0)) == "unrolled"
 
 
 def test_partial_remat_matches_full_remat():

@@ -92,6 +92,19 @@ class TestTrainLoopRing:
                   if k.endswith("setup/warmup")]
         assert shown["attn_layout"] == warm["a"]["attn_layout"]
 
+    def test_warmup_row_says_how_the_layer_scan_runs(self, rows):
+        """The engagement record of the layer scan's conditional slots
+        (models/transformer.layer_loop_record): a pure function of the
+        model's configuration, on the warm-up row and in trace_report's
+        output. The tiny preset's four dense layers build no scan."""
+        from scripts.trace_report import setup_facts
+        ring, _ = rows
+        warm, = [r for r in ring if r["phase"] == "setup/warmup"]
+        assert warm["a"]["layer_loop"] == "unrolled"
+        shown, = [a for k, a in setup_facts(ring).items()
+                  if k.endswith("setup/warmup")]
+        assert shown["layer_loop"] == warm["a"]["layer_loop"]
+
     def test_rows_carry_their_step_and_the_span_that_caused_them(self, rows):
         ring, _ = rows
         steps = [r for r in ring if r["phase"] == "loop/step"]
