@@ -30,6 +30,7 @@ from dalle_tpu.cli._args import (add_dataclass_args, check_no_collisions,
 from dalle_tpu.config import (AuxConfig, CollabConfig, ModelConfig,
                               OptimizerConfig, PeerConfig)
 from dalle_tpu.cli.run_trainer import (MODEL_PRESETS, banner,
+                                       decodable_model_from_args,
                                        maybe_wandb_run)
 
 logger = logging.getLogger("dalle_tpu.aux")
@@ -128,6 +129,8 @@ def aggregate(metrics):
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    # refused here, before anything is built, if nothing decodes the preset
+    model = decodable_model_from_args(args, "dalle-tpu-aux-peer")
     logging.basicConfig(
         level=args.log_level,
         format="%(asctime)s %(name)s %(levelname)s %(message)s")
@@ -143,8 +146,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                                                 load_state_from_peers)
     from dalle_tpu.task import TrainingTask
 
-    model = dataclass_from_args(ModelConfig, args,
-                                base=MODEL_PRESETS[args.preset]())
     opt = dataclass_from_args(OptimizerConfig, args)
     collab = dataclass_from_args(CollabConfig, args)
     peer = dataclass_from_args(PeerConfig, args)

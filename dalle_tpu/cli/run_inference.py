@@ -25,7 +25,8 @@ import sys
 from typing import Optional, Sequence
 
 from dalle_tpu.cli._args import add_dataclass_args, dataclass_from_args
-from dalle_tpu.cli.run_trainer import MODEL_PRESETS
+from dalle_tpu.cli.run_trainer import (MODEL_PRESETS,
+                                       decodable_model_from_args)
 from dalle_tpu.config import ModelConfig
 
 logger = logging.getLogger("dalle_tpu.inference")
@@ -73,6 +74,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    # refused here, before anything is built, if nothing decodes the preset
+    cfg = decodable_model_from_args(args, "dalle-tpu-inference")
     logging.basicConfig(level=args.log_level)
     from dalle_tpu.utils.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -88,8 +91,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     from dalle_tpu.models.decode import SamplingConfig, generate_images
     from dalle_tpu.training.checkpoint import CheckpointManager
 
-    cfg = dataclass_from_args(ModelConfig, args,
-                              base=MODEL_PRESETS[args.preset]())
     tokenizer = CaptionTokenizer.load(args.tokenizer_path)
 
     # params-only restore: inference needs no optimizer state, and this

@@ -37,7 +37,8 @@ from typing import Optional, Sequence
 
 from dalle_tpu.cli._args import (add_dataclass_args, check_no_collisions,
                                  dataclass_from_args)
-from dalle_tpu.cli.run_trainer import MODEL_PRESETS
+from dalle_tpu.cli.run_trainer import (MODEL_PRESETS,
+                                       decodable_model_from_args)
 from dalle_tpu.config import ModelConfig, PeerConfig, ServingConfig
 
 logger = logging.getLogger("dalle_tpu.server")
@@ -187,6 +188,8 @@ def _build_pixel_fn(args, cfg):
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    # refused here, before anything is built, if nothing decodes the preset
+    cfg = decodable_model_from_args(args, "dalle-tpu-server")
     logging.basicConfig(
         level=args.log_level,
         format="%(asctime)s %(name)s %(levelname)s %(message)s")
@@ -202,8 +205,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     from dalle_tpu.serving.pixels import PixelPipeline
     from dalle_tpu.serving.server import ServingHTTPServer
 
-    cfg = dataclass_from_args(ModelConfig, args,
-                              base=MODEL_PRESETS[args.preset]())
     serving = dataclass_from_args(ServingConfig, args)
     serving.validate()
 

@@ -17,15 +17,17 @@ Usage::
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import logging
 import sys
 from typing import Optional, Sequence
 
 from dalle_tpu.config import (CollabConfig, ModelConfig, OptimizerConfig,
-                              PeerConfig, TrainerConfig,
-                              flagship_model_config, tiny_model_config,
-                              xl_model_config)
+                              PeerConfig, SparseLMConfig, TrainerConfig,
+                              flagship_model_config,
+                              smallthinker21b_model_config,
+                              tiny_model_config, xl_model_config)
 from dalle_tpu.cli._args import (add_dataclass_args, check_no_collisions,
                                  dataclass_from_args)
 
@@ -38,10 +40,16 @@ MODEL_PRESETS = {
     "tiny": tiny_model_config,                # CPU smoke shape
     # DALL-E-XL ~3B for pod-slice peers: the cell xl-train-solo
     "xl": xl_model_config,
+    # SmallThinker-21BA3B-Instruct cut to one chip's share of a layer
+    # (a dataclass of its own): the cell smallthinker21b-train-solo
+    "smallthinker21b": smallthinker21b_model_config,
 }
 
 CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
                   PeerConfig)
+# Every architecture's configuration class. A preset builds one of them;
+# a field two of them share (vocab_text, dtype, ...) is one flag.
+MODEL_CLASSES = (ModelConfig, SparseLMConfig)
 
 
 def maybe_wandb_run(project: Optional[str], name: str):
@@ -88,8 +96,38 @@ def make_epoch_sink(metrics_file: Optional[str], wandb_run,
     return on_epoch
 
 
+def add_model_args(parser: argparse.ArgumentParser) -> None:
+    """One flag a field of every model class, a shared field once; none
+    for a field its class lists in ``no_flag``."""
+    seen: set = set()
+    for cls in MODEL_CLASSES:
+        check_no_collisions(cls, *CONFIG_CLASSES[1:])
+        add_dataclass_args(parser, cls, skip=(
+            *seen, *getattr(cls, "no_flag", ())))
+        seen |= {f.name for f in dataclasses.fields(cls)}
+
+
+def model_from_args(args: argparse.Namespace):
+    """The preset's configuration, of whichever class the preset builds,
+    with the field flags the user passed laid over it."""
+    base = MODEL_PRESETS[args.preset]()
+    return dataclass_from_args(type(base), args, base=base)
+
+
+def decodable_model_from_args(args: argparse.Namespace, prog: str):
+    """:func:`model_from_args` for the entry points that decode, serve or
+    rebuild the DALL-E's gradient layout: a preset whose architecture the
+    decode path cannot run is refused here, at start, in one sentence."""
+    model = model_from_args(args)
+    if model.decode_missing:
+        raise SystemExit(
+            f"{prog}: preset {args.preset!r} trains (run_trainer) but "
+            f"cannot be decoded, served or assisted yet: "
+            f"{model.decode_missing}.")
+    return model
+
+
 def build_parser() -> argparse.ArgumentParser:
-    check_no_collisions(*CONFIG_CLASSES)
     parser = argparse.ArgumentParser(
         prog="dalle-tpu-trainer", description=__doc__.splitlines()[0])
     parser.add_argument("--preset", choices=sorted(MODEL_PRESETS),
@@ -122,15 +160,14 @@ def build_parser() -> argparse.ArgumentParser:
                         help="capture a JAX profiler trace of a few early "
                              "steps into this directory")
     parser.add_argument("--log-level", type=str, default="INFO")
-    for cls in CONFIG_CLASSES:
+    add_model_args(parser)
+    for cls in CONFIG_CLASSES[1:]:
         add_dataclass_args(parser, cls)
     return parser
 
 
 def configs_from_args(args: argparse.Namespace):
-    model = dataclass_from_args(ModelConfig, args,
-                                base=MODEL_PRESETS[args.preset]())
-    return (model,
+    return (model_from_args(args),
             dataclass_from_args(OptimizerConfig, args),
             dataclass_from_args(TrainerConfig, args),
             dataclass_from_args(CollabConfig, args),

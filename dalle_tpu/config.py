@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, ClassVar, Dict, Optional, Sequence, Tuple
 
 # Attention layer kinds supported by the attention zoo (reference
 # ``task.py:63-64`` selects from dalle-pytorch's attn_types).
@@ -147,6 +147,13 @@ class ModelConfig:
     # mesh whose sp axis is > 1 (parallel/sequence.py).
     sequence_parallel: str = SP_NONE
 
+    # Where this architecture lives: the module that builds the model, its
+    # parameters and its engagement records from a configuration of this
+    # class (``models/__init__.py:family``). Not a field.
+    model_module: ClassVar[str] = "dalle_tpu.models.dalle"
+    # what the decode path lacks for this architecture (None: it decodes)
+    decode_missing: ClassVar[Optional[str]] = None
+
     @property
     def image_seq_len(self) -> int:
         return self.image_grid * self.image_grid
@@ -158,6 +165,12 @@ class ModelConfig:
     @property
     def vocab_total(self) -> int:
         return self.vocab_text + self.vocab_image
+
+    def optimizer_stacking(self) -> Dict[str, int]:
+        """The leading axes of this model's leaves that hold independent
+        weights, as ``OptimizerConfig`` fields: LAMB takes one trust ratio
+        a slice there."""
+        return {"stacked_reps": self.dense_scan_reps(), "stacked_experts": 0}
 
     def fuse_ff(self, is_plain: bool) -> bool:
         """Whether a block routes its FF through the fused Pallas GEGLU
@@ -257,6 +270,10 @@ class OptimizerConfig:
     # parameter names (ADVICE r4). 0 = the model has no stacked leaves;
     # None = infer by path heuristic (standalone optimizer construction).
     stacked_reps: "int | None" = None
+    # The same for an expert layer's leaves (``.../experts/<name>``, the
+    # experts held on the leading axis): that many experts a leaf, one
+    # trust ratio each. 0 = none; None = infer from the path.
+    stacked_experts: "int | None" = None
 
 
 @dataclass(frozen=True)
@@ -672,6 +689,124 @@ class AuxConfig:
     # refused loudly) with grad_compression="power_sgd", whose wire
     # shapes an aux peer without a model cannot reproduce.
     assist_in_averaging: bool = False
+
+
+LAYER_FULL_NOPE = "full_nope"      # causal over the whole sequence, no positions
+LAYER_WINDOW_ROPE = "window_rope"  # causal inside ``window``, rotary
+
+VALID_LAYER_KINDS = (LAYER_FULL_NOPE, LAYER_WINDOW_ROPE)
+
+
+@dataclass(frozen=True)
+class SparseLMConfig:
+    """A decoder-only language model whose every layer is a mixture of
+    experts: RMSNorm, grouped key-value heads, per-layer attention kind
+    (``layer_kinds``, cycled over the depth), a router that reads the
+    normed layer input, gated-ReLU experts, an untied head
+    (``models/sparse_lm.py``). Defaults are SmallThinker-21BA3B-Instruct
+    (PowerInfer, config.json) cut to the share one of the 8 chips of a
+    layer holds: one period of the layer pattern, experts 0-7 of 64, an
+    eighth of the vocabulary; every width as published.
+
+    The trainer's batches are ``text`` and ``image`` id fields: here the
+    two halves of one token sequence, image ids offset by ``vocab_text``
+    into the one vocabulary (``vocab_text + vocab_image == vocab_size``).
+    """
+
+    hidden_size: int = 2560
+    num_hidden_layers: int = 4       # published 52: 13 periods of layer_kinds
+    num_heads: int = 28
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    expert_width: int = 768
+    num_experts: int = 64            # what the router scores
+    experts_per_token: int = 6
+    # The experts this peer holds: ``experts_held`` consecutive ones from
+    # ``expert_offset``. The layer routes over all ``num_experts`` and
+    # computes the held experts' part of the result (published: all 64).
+    experts_held: int = 8
+    expert_offset: int = 0
+    vocab_size: int = 18992          # published 151936: the slice's rows
+    window: int = 4096
+    layer_kinds: Tuple[str, ...] = (
+        LAYER_FULL_NOPE, LAYER_WINDOW_ROPE, LAYER_WINDOW_ROPE,
+        LAYER_WINDOW_ROPE)
+    rope_theta: float = 1.5e6
+    rms_eps: float = 1e-6
+    router_softmax_over_chosen: bool = True
+    tied_embeddings: bool = False
+    router_input: str = "input_norm"
+    attention_bias: bool = False
+    # rows of the (tokens, vocab_size) logits alive at once in the head's
+    # streaming cross-entropy
+    head_chunk: int = 2048
+    # the embedding's scale at init: no source pins it (models/sparse_lm.py
+    # says what it decides: whether an untrained router reads tokens)
+    embed_init_std: float = 1.0
+    dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+    text_seq_len: int = 4096
+    image_grid: int = 64
+    vocab_text: int = 9496
+    vocab_image: int = 9496
+
+    model_module: ClassVar[str] = "dalle_tpu.models.sparse_lm"
+    # fields a configuration's file states and no entry point's flag sets:
+    # what the source fixes and models/sparse_lm.py is written for
+    # (``validate`` holds each to its one value), and the one assumption
+    # about initialisation
+    no_flag: ClassVar[Tuple[str, ...]] = (
+        "router_softmax_over_chosen", "tied_embeddings", "router_input",
+        "attention_bias", "embed_init_std")
+    decode_missing: ClassVar[Optional[str]] = (
+        "models/decode.py has no grouped key-value heads, no cache per "
+        "layer kind (full / window) and no expert layer")
+
+    @property
+    def image_seq_len(self) -> int:
+        return self.image_grid * self.image_grid
+
+    @property
+    def total_seq_len(self) -> int:
+        return self.text_seq_len + self.image_seq_len
+
+    def kind_of_layer(self, layer: int) -> str:
+        return self.layer_kinds[layer % len(self.layer_kinds)]
+
+    def optimizer_stacking(self) -> Dict[str, int]:
+        return {"stacked_reps": 0, "stacked_experts": self.experts_held}
+
+    def validate(self) -> None:
+        for kind in self.layer_kinds:
+            if kind not in VALID_LAYER_KINDS:
+                raise ValueError(f"unknown layer kind {kind!r}")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError("num_heads must be a multiple of num_kv_heads")
+        if self.vocab_text + self.vocab_image != self.vocab_size:
+            raise ValueError("vocab_text + vocab_image must equal vocab_size")
+        if not (0 <= self.expert_offset
+                and self.expert_offset + self.experts_held
+                <= self.num_experts):
+            raise ValueError(
+                f"experts {self.expert_offset}..+{self.experts_held} are "
+                f"not among the router's {self.num_experts}")
+        if self.experts_per_token > self.num_experts:
+            raise ValueError("experts_per_token exceeds num_experts")
+        if self.router_input != "input_norm":
+            raise ValueError(
+                f"router_input {self.router_input!r}: the router reads the "
+                "normed layer input ('input_norm')")
+        if (self.tied_embeddings or self.attention_bias
+                or not self.router_softmax_over_chosen):
+            raise ValueError(
+                "models/sparse_lm.py has an untied head, no attention bias "
+                "and a softmax over the chosen experts only")
+
+
+def smallthinker21b_model_config(**overrides: Any) -> SparseLMConfig:
+    """Preset ``smallthinker21b``: the cell ``smallthinker21b-train-solo``
+    (benchmark/configs/smallthinker21b.json holds ``asdict`` of it)."""
+    return dataclasses.replace(SparseLMConfig(), **overrides)
 
 
 def tiny_model_config(**overrides: Any) -> ModelConfig:

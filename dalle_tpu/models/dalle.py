@@ -268,5 +268,22 @@ def init_params(model: DALLE, rng: jax.Array,
     return model.init(rng, text, image)
 
 
+def build(cfg: ModelConfig, mesh=None) -> DALLE:
+    return DALLE(cfg, mesh=mesh)
+
+
+def engagement_records(cfg: ModelConfig, mesh=None) -> dict:
+    """The ``setup/warmup`` row's attributes: how the layer scan runs the
+    body, and which attention layers' traced calls took the kernel."""
+    from dalle_tpu.models.attention import attn_layout_record
+    from dalle_tpu.models.transformer import layer_loop_record
+    return {"layer_loop": layer_loop_record(cfg),
+            "attn_layout": attn_layout_record(cfg, mesh)}
+
+
+# entries of the step's aux that go onto every loop/step row: none
+STEP_ATTRIBUTES = ()
+
+
 def param_count(params) -> int:
     return sum(int(p.size) for p in jax.tree.leaves(params))

@@ -1,0 +1,658 @@
+"""A sparse decoder-only language model on the trainer's normal path.
+
+What ``SparseLMConfig`` describes (its defaults: SmallThinker-21BA3B-
+Instruct, PowerInfer): every layer is
+
+    a   = rmsnorm(x)
+    r   = a . W_r                        the router, in f32, BEFORE attention
+    h   = x + attention(a) . W_o         grouped key-value heads; per layer
+                                         either full causal with no
+                                         positions, or a causal window with
+                                         rotary (``cfg.layer_kinds``)
+    m   = rmsnorm(h)
+    S   = the k largest of r;  p = softmax(r_S)
+    out = h + sum_{e in S, e held here} p_e . W_down,e(relu(W_gate,e m) * W_up,e m)
+
+then a final RMSNorm, an untied head and next-token cross-entropy.
+
+**The expert layer is told which experts it holds** (``experts_held``
+consecutive ones from ``expert_offset``): it routes over all
+``num_experts``, computes the part of the result its own experts give for
+the tokens routed to them, and passes that partial sum on. With every
+expert held that is the whole layer; with a share of them it is what one
+expert-parallel rank computes before the exchange, and nothing here stands
+in for the other ranks or their traffic. No token is dropped and there is
+no capacity factor: the assignments that fall on held experts are sorted
+by expert into a buffer of ``dispatch_rows`` rows (twice what a uniform
+router sends here, plus a tile's rounding an expert) and multiplied group
+by group (ops/pallas/grouped_matmul_kernels.py); a step whose router sends
+more than that takes the dense lowering of the same sum (every held expert
+on every token, times its routing weight) instead, chosen on the device by
+the count (:func:`held_experts`). Where the grouped kernels cannot run (no
+Mosaic backend, sizes that are not lane tiles) the dense lowering is the
+layer.
+
+The model takes the trainer's batches as they are: ``text`` and ``image``
+are the two halves of one token sequence, image ids offset by
+``vocab_text``. The loss is the mean next-token cross-entropy over the
+T - 1 predicted positions; ``loss_text`` / ``loss_img`` are its means over
+the targets of the two fields. Router product and softmax, attention
+softmax and cross-entropy are f32; the rest runs in ``cfg.dtype`` from f32
+parameters.
+
+Device scopes (``jax.named_scope`` and module names; the benchmark's
+``*_share_pct`` metrics read them): ``embed``, ``attn`` (projections,
+rotary, kernel), ``rms_norm``, ``ff/router``, ``ff/dispatch``,
+``ff/experts``, ``ff/combine``, ``head``, ``ce``.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Dict, NamedTuple, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from dalle_tpu.config import LAYER_WINDOW_ROPE, SparseLMConfig
+from dalle_tpu.models import attention as attn_mod
+from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
+from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
+from dalle_tpu.parallel.mesh import (LANES_SPEC, per_shard,
+                                     sum_over_manual_data_axes)
+
+# rows of the dispatch buffer over what a uniform router sends to the
+# held experts (tests shrink it to reach the dense lowering)
+ROWS_OVER_EXPECTED = 2.0
+
+# (kind, tokens, query lanes, key-value lanes) -> whether a traced call of
+# those local shapes took the blockwise kernel: what attn_layout reads
+_KERNEL_CHOICES: Dict[Tuple[str, int, int, int], bool] = {}
+
+
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    with jax.named_scope("rms_norm"):
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+        return (y * scale).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Attention
+# ---------------------------------------------------------------------------
+
+def dense_causal_attention(q, k, v, window: Optional[int],
+                           head_dim: int) -> jax.Array:
+    """The XLA lowering (no Mosaic backend, or a head size that is not a
+    lane tile: CPU runs at test sizes): dense masked scores, query head
+    ``h`` reading key-value head ``h // group``. q: (B, T, H*d); k, v:
+    (B, T, G*d)."""
+    b, t, _ = q.shape
+    g = k.shape[2] // head_dim
+    qh = q.reshape(b, t, g, -1, head_dim)
+    kh, vh = k.reshape(b, t, g, head_dim), v.reshape(b, t, g, head_dim)
+    s = jnp.einsum("bqgnd,bkgd->bgnqk", qh, kh,
+                   preferred_element_type=jnp.float32) * head_dim ** -0.5
+    i = np.arange(t)
+    allowed = i[None, :] <= i[:, None]
+    if window is not None:
+        allowed &= i[:, None] - i[None, :] < window
+    w = jax.nn.softmax(jnp.where(allowed, s, attn_mod.NEG_INF), axis=-1)
+    out = jnp.einsum("bgnqk,bkgd->bqgnd", w.astype(v.dtype), vh,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(q.shape).astype(q.dtype)
+
+
+def _attend_shard(q, k, v, *, kind: str, window: Optional[int],
+                  head_dim: int):
+    """One shard's attention: the blockwise kernel where it fits."""
+    why_not = kernels.blockwise_fits(q.shape[2], k.shape[2], head_dim)
+    _KERNEL_CHOICES[kind, q.shape[1], q.shape[2], k.shape[2]] = \
+        why_not is None
+    attn_mod.log_kernel_choice(
+        f"{kind} attention", why_not is None,
+        why_not or f"local q{tuple(q.shape)} over k{tuple(k.shape)}: "
+        f"blocks of {kernels.BLOCK}, "
+        f"{q.shape[2] // k.shape[2]} query heads a key-value tile")
+    if why_not is not None:
+        return dense_causal_attention(q, k, v, window, head_dim)
+    return kernels.causal_attention(q, k, v, window, kernels.BLOCK,
+                                    attn_mod._PALLAS_INTERPRET)
+
+
+class Attention(nn.Module):
+    cfg: SparseLMConfig
+    kind: str
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, a: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        dt, pdt = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=dt,
+                                  param_dtype=pdt)
+        q = dense(cfg.num_heads * cfg.head_dim, name="q")(a)
+        k = dense(cfg.num_kv_heads * cfg.head_dim, name="k")(a)
+        v = dense(cfg.num_kv_heads * cfg.head_dim, name="v")(a)
+        window = None
+        if self.kind == LAYER_WINDOW_ROPE:
+            window = cfg.window
+            pos = jnp.arange(a.shape[1])
+            q, k = (attn_mod.apply_rotary_lanes(
+                x, *attn_mod.rotary_cos_sin(pos, cfg.head_dim,
+                                            cfg.rope_theta, heads),
+                cfg.head_dim)
+                for x, heads in ((q, cfg.num_heads), (k, cfg.num_kv_heads)))
+        if attn_mod._pallas_by_default():
+            attend = functools.partial(_attend_shard, kind=self.kind,
+                                       window=window, head_dim=cfg.head_dim)
+            ctx = per_shard(attend, self.mesh, (LANES_SPEC,) * 3,
+                            LANES_SPEC, scope=self.name)(q, k, v)
+        else:
+            ctx = dense_causal_attention(q, k, v, window, cfg.head_dim)
+        return dense(cfg.hidden_size, name="out")(ctx)
+
+
+# ---------------------------------------------------------------------------
+# The expert layer
+# ---------------------------------------------------------------------------
+
+def dispatch_rows(tokens: int, cfg: SparseLMConfig) -> int:
+    """Rows of the dispatch buffer for ``tokens`` tokens: what a uniform
+    router sends to the held experts times ``ROWS_OVER_EXPECTED``, in
+    whole row tiles, and never more than every assignment a token can make
+    to held experts."""
+    worst = tokens * min(cfg.experts_per_token, cfg.experts_held)
+    expected = tokens * cfg.experts_per_token * cfg.experts_held \
+        / cfg.num_experts
+    tile = grouped.TILE
+    return min(worst, -(-int(ROWS_OVER_EXPECTED * expected) // tile) * tile)
+
+
+def held_key(idx: jax.Array, offset: int, held: int) -> jax.Array:
+    """(tokens * k,) the held expert's local index of every assignment,
+    ``held`` for an assignment to an expert that lives elsewhere."""
+    local = idx.reshape(-1) - offset
+    return jnp.where((local >= 0) & (local < held), local, held)
+
+
+def group_sizes(key: jax.Array, held: int) -> jax.Array:
+    return jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                   dtype=jnp.int32)
+
+
+class _Plan(NamedTuple):
+    """Where every assignment goes, as integer arrays (no gradient). The
+    buffer's rows are laid out by ``grouped_matmul_kernels.tile_plan``:
+    a held expert's assignments are contiguous and start at a multiple of
+    the row tile. Slots are a token's k assignments."""
+    token: jax.Array      # (rows,) the token a row computes
+    slot: jax.Array       # (rows,) which of the token's k assignments
+    valid: jax.Array      # (rows,) the row holds an assignment
+    row: jax.Array        # (N, k) the row of a token's assignment
+    here: jax.Array       # (N, k) that row exists (a held expert's)
+    sizes: jax.Array      # (held,) assignments to each held expert
+    tiles: grouped.Tiles  # the row tiles' experts
+
+
+def dispatch_plan(idx: jax.Array, offset: int, held: int,
+                  rows: int) -> _Plan:
+    """``rows``: assignments the buffer has to hold; it gets a tile more
+    an expert for the rounding."""
+    n, k = idx.shape
+    tile = grouped.TILE
+    n_tiles = -(-rows // tile) + held
+    key = held_key(idx, offset, held)
+    sizes = group_sizes(key, held)
+    order = jnp.argsort(key, stable=True)     # held experts' first, by expert
+    rank = jnp.argsort(order)                 # the inverse permutation
+    first_sorted = jnp.cumsum(sizes) - sizes
+    first_row, tiles = grouped.tile_plan(sizes, n_tiles, tile)
+    # assignment -> row
+    e = jnp.minimum(key, held - 1)
+    row = (first_row[e] + rank - first_sorted[e]).reshape(n, k)
+    here = (key.reshape(n, k) < held) & (row < n_tiles * tile)
+    # row -> assignment
+    r = jnp.arange(n_tiles * tile)
+    e = tiles.expert[r // tile]
+    within = r - first_row[e]
+    valid = (tiles.active[r // tile] == 1) & (within < sizes[e])
+    a = order[jnp.clip(first_sorted[e] + within, 0, n * k - 1)]
+    # a slot with no row here is gathered all the same and masked after:
+    # it reads a row of its own (one row for all of them is a hot spot:
+    # 0.73 against 0.59 ms a gather on the v5e)
+    elsewhere = (jnp.arange(n) % (n_tiles * tile))[:, None]
+    return _Plan(a // k, a % k, valid, jnp.where(here, row, elsewhere), here,
+                 sizes, tiles)
+
+
+def _sum_over_slots(rows_of, plan: _Plan, weight=None):
+    """(N, D) f32: every token's sum over its assignments computed here of
+    [weight x] the row that computed it — one gather of N rows a slot, so
+    that neither direction of dispatch or combine is a scatter. Rows that
+    hold no assignment are never read (the grouped products leave rows
+    outside every group unwritten)."""
+    total = 0.0
+    for j in range(plan.row.shape[1]):
+        picked = jnp.where(plan.here[:, j, None], rows_of[plan.row[:, j]],
+                           0).astype(jnp.float32)
+        total = total + (picked if weight is None
+                         else picked * weight[:, j, None])
+    return total
+
+
+def _rows_of(source, plan: _Plan):
+    """(rows, D): ``source[plan.token]`` where the row holds an assignment,
+    zero elsewhere."""
+    return jnp.where(plan.valid[:, None], source[plan.token], 0)
+
+
+def grouped_kernels_why_not(dim: int, width: int) -> Optional[str]:
+    """Why the grouped Pallas products cannot take experts of ``dim`` x
+    ``width`` here; None where they can (interpreted, any size)."""
+    if not attn_mod._pallas_by_default():
+        return "no Mosaic backend"
+    if (dim % 128 or width % 128) and not attn_mod._PALLAS_INTERPRET:
+        return f"{dim} x {width} are not lane tiles"
+    return None
+
+
+def _grouped_dots(plan: _Plan):
+    """``dot(x, w)``: row tile t of x times its expert's weights, and
+    ``grads(x, w, dy) -> (dx, dw)``."""
+    kw = dict(tiles=plan.tiles, tile=grouped.TILE,
+              interpret=attn_mod._PALLAS_INTERPRET)
+    return (functools.partial(grouped.grouped_matmul, **kw),
+            functools.partial(grouped.grouped_matmul_grads, **kw))
+
+
+class _Kept(NamedTuple):
+    """What the sorted lowering keeps for its backward pass."""
+    plan: _Plan
+    xs: jax.Array         # (rows, D) the rows' tokens
+    gate: jax.Array       # (rows, F) xs . W_gate
+    up: jax.Array         # (rows, F) xs . W_up
+    ys: jax.Array         # (rows, D) the experts' outputs
+
+
+def _sorted_forward(m, idx, p, gate, up, down, *, offset: int, rows: int):
+    """The held experts' part of the layer for assignments sorted by
+    expert. m: (N, D); idx, p: (N, k); gate, up: (E_h, D, F); down:
+    (E_h, F, D). Returns (((N, D) f32, assignments computed), _Kept)."""
+    with jax.named_scope("dispatch"):
+        plan = dispatch_plan(idx, offset, gate.shape[0], rows)
+        xs = _rows_of(m, plan)
+    with jax.named_scope("experts"):
+        dot, _ = _grouped_dots(plan)
+        g, u = dot(xs, gate), dot(xs, up)
+        ys = dot(jax.nn.relu(g) * u, down)
+    with jax.named_scope("combine"):
+        y = _sum_over_slots(ys, plan, p)
+    return ((y, jnp.sum(plan.valid, dtype=jnp.float32)),
+            _Kept(plan, xs, g, u, ys))
+
+
+def _sorted_backward(kept: _Kept, p, gate, up, down, dy):
+    """Cotangents of (m, p, gate, up, down) from what the forward kept:
+    no product and no gather of the forward pass is run again."""
+    plan, xs, g, u, ys = kept
+    with jax.named_scope("combine"):
+        weight = jnp.where(plan.valid, p[plan.token, plan.slot], 0.0)
+        dy_rows = _rows_of(dy, plan)
+        dys = (dy_rows * weight[:, None]).astype(ys.dtype)
+        # a routing weight's cotangent is its row's <dy, ys>: taken on the
+        # rows, then one scalar a slot (not one row a slot)
+        score = jnp.sum(jnp.where(plan.valid[:, None],
+                                  dy_rows * ys.astype(jnp.float32), 0.0),
+                        axis=-1)
+        dp = jnp.where(plan.here, score[plan.row], 0.0)
+    with jax.named_scope("experts"):
+        _, grads = _grouped_dots(plan)
+        act = jax.nn.relu(g)
+        dhidden, ddown = grads(act * u, down, dys)
+        dxs_gate, dgate = grads(xs, gate, jnp.where(g > 0, dhidden * u, 0))
+        dxs_up, dup = grads(xs, up, dhidden * act)
+    with jax.named_scope("dispatch"):
+        dm = _sum_over_slots(dxs_gate + dxs_up, plan).astype(xs.dtype)
+    return dm, dp, dgate, dup, ddown
+
+
+def _every_expert(m, idx, p, gate, up, down, *, offset: int):
+    """The same sum with no dispatch: every held expert on every token,
+    times its routing weight (0 for a token not routed to it)."""
+    held = gate.shape[0]
+
+    def one(y, xs):
+        e, w_gate, w_up, w_down = xs
+        with jax.named_scope("combine"):
+            weight = jnp.sum(jnp.where(idx == e + offset, p, 0.0), axis=1)
+        with jax.named_scope("experts"):
+            out = jnp.dot(jax.nn.relu(jnp.dot(m, w_gate))
+                          * jnp.dot(m, w_up), w_down)
+        with jax.named_scope("combine"):
+            return y + out.astype(jnp.float32) * weight[:, None], None
+
+    y, _ = jax.lax.scan(one, jnp.zeros(m.shape, jnp.float32),
+                        (jnp.arange(held), gate, up, down))
+    here = jnp.sum(held_key(idx, offset, held) < held, dtype=jnp.float32)
+    return y, here
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(offset, rows, m, idx, p, gate, up, down):
+    return _held_experts_fwd(offset, rows, m, idx, p, gate, up, down)[0]
+
+
+def _held_experts_fwd(offset, rows, m, idx, p, gate, up, down):
+    # One conditional a direction, written out: lax.cond's own derivative
+    # would return both lowerings' residuals from the forward conditional
+    # and run neither backward without them. Here the sorted lowering
+    # returns what its backward reads (_Kept); the dense one, taken when a
+    # step's assignments do not fit, hands back zeros of those shapes and
+    # is computed again in the backward pass.
+    operands = (m, idx, p, gate, up, down)
+    sorted_ = functools.partial(_sorted_forward, offset=offset, rows=rows)
+    like = jax.eval_shape(sorted_, *operands)[1]
+
+    def dense(*operands):
+        return (_every_expert(*operands, offset=offset),
+                jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), like))
+
+    fits = jnp.sum(held_key(idx, offset, gate.shape[0])
+                   < gate.shape[0]) <= rows
+    out, kept = jax.lax.cond(fits, sorted_, dense, *operands)
+    return out, (fits, kept, operands)
+
+
+def _held_experts_bwd(offset, rows, res, cotangent):
+    fits, kept, (m, idx, p, gate, up, down) = res
+    dy = cotangent[0]
+
+    def dense(kept, m, p, gate, up, down, dy):
+        _, vjp = jax.vjp(lambda m, p, *w: _every_expert(
+            m, idx, p, *w, offset=offset)[0], m, p, gate, up, down)
+        return vjp(dy)
+
+    def sorted_(kept, m, p, gate, up, down, dy):
+        return _sorted_backward(kept, p, gate, up, down, dy)
+
+    dm, dp, *dw = jax.lax.cond(fits, sorted_, dense, kept, m, p, gate, up,
+                               down, dy)
+    return (dm, np.zeros(idx.shape, jax.dtypes.float0), dp, *dw)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def held_experts(m, idx, p, gate, up, down, *, offset: int, rows: int):
+    """The held experts' part of the layer: the sorted lowering where the
+    step's assignments to held experts fit ``rows``, the dense one where
+    they do not, chosen on the device by the count; the dense one alone
+    where the grouped kernels cannot run. Returns ((N, D) f32, the
+    assignments computed)."""
+    why_not = grouped_kernels_why_not(m.shape[1], gate.shape[2])
+    attn_mod.log_kernel_choice(
+        "expert products", why_not is None,
+        why_not or f"{rows} rows in tiles of {grouped.TILE}, "
+        f"{gate.shape[0]} experts of {m.shape[1]} x {gate.shape[2]}")
+    if why_not is not None:
+        return _every_expert(m, idx, p, gate, up, down, offset=offset)
+    return _held_experts(offset, rows, m, idx, p, gate, up, down)
+
+
+class ExpertWeights(nn.Module):
+    """The held experts' weights, stacked on a leading axis: leaves
+    ``.../experts/{gate,up,down}`` (LAMB: one trust ratio an expert)."""
+    cfg: SparseLMConfig
+
+    @nn.compact
+    def __call__(self):
+        cfg = self.cfg
+        init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
+            batch_axis=(0,))
+        pdt, dt = jnp.dtype(cfg.param_dtype), jnp.dtype(cfg.dtype)
+        d, f, e = cfg.hidden_size, cfg.expert_width, cfg.experts_held
+        return tuple(self.param(name, init, shape, pdt).astype(dt)
+                     for name, shape in (("gate", (e, d, f)),
+                                         ("up", (e, d, f)),
+                                         ("down", (e, f, d))))
+
+
+class ExpertLayer(nn.Module):
+    cfg: SparseLMConfig
+
+    def setup(self):
+        cfg = self.cfg
+        self.router = self.param(
+            "router", nn.initializers.lecun_normal(),
+            (cfg.hidden_size, cfg.num_experts), jnp.dtype(cfg.param_dtype))
+        self.experts = ExpertWeights(cfg)
+
+    def route(self, a: jax.Array):
+        """Top-k of the router's f32 scores of the normed layer input:
+        (B, T, k) expert ids and softmax weights over the chosen."""
+        # flax names this call's scope ``ff.route``: the router's
+        # operations are put under ``ff/router`` by hand, beside the rest
+        # of the layer's
+        with jax.named_scope("ff"), jax.named_scope("router"):
+            scores = jnp.einsum(
+                "btd,de->bte", a.astype(jnp.float32),
+                self.router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+            top, idx = jax.lax.top_k(scores, self.cfg.experts_per_token)
+            return idx, jax.nn.softmax(top, axis=-1)
+
+    def __call__(self, m: jax.Array, idx: jax.Array, p: jax.Array):
+        cfg = self.cfg
+        b, t, d = m.shape
+        rows = dispatch_rows(b * t, cfg)
+        y, computed = held_experts(
+            m.reshape(b * t, d), idx.reshape(b * t, -1),
+            p.reshape(b * t, -1), *self.experts(),
+            offset=cfg.expert_offset, rows=rows)
+        with jax.named_scope("router"):
+            sizes = group_sizes(
+                held_key(idx.reshape(b * t, -1), cfg.expert_offset,
+                         cfg.experts_held), cfg.experts_held
+            ).astype(jnp.float32)
+            here = jnp.sum(sizes)
+            sorted_ = grouped_kernels_why_not(d, cfg.expert_width) is None
+            counters = {
+                "here": here / (b * t * cfg.experts_per_token),
+                "load": jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9),
+                "dense": ((here > rows) | (not sorted_)).astype(jnp.float32),
+                "dropped": here - computed}
+        return y.reshape(b, t, d).astype(m.dtype), counters
+
+
+class Layer(nn.Module):
+    cfg: SparseLMConfig
+    kind: str
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        cfg = self.cfg
+        norm = lambda name, v: rms_norm(
+            v, self.param(name, nn.initializers.ones, (cfg.hidden_size,),
+                          jnp.dtype(cfg.param_dtype)), cfg.rms_eps)
+        ff = ExpertLayer(cfg, name="ff")
+        a = norm("attn_norm", x)
+        idx, p = ff.route(a)
+        # for whoever asks (apply(..., mutable=["intermediates"])): the
+        # experts every token chose; nothing is kept otherwise
+        self.sow("intermediates", "chosen", idx)
+        h = x + Attention(cfg, self.kind, self.mesh, name="attn")(a)
+        y, counters = ff(norm("ff_norm", h), idx, p)
+        return h + y, counters
+
+
+# ---------------------------------------------------------------------------
+# The model
+# ---------------------------------------------------------------------------
+
+def _streamed_nll(h, kernel, targets, weights, chunk: int):
+    """Sums of ``weights`` x next-token negative log-likelihood over the
+    rows of ``h`` (N, D), ``chunk`` rows of the (N, V) logits alive at a
+    time and none kept for the backward pass. weights: (N, n_sums)."""
+    n = h.shape[0]
+    pad = -n % chunk
+    if pad:
+        h, targets, weights = (jnp.pad(x, ((0, pad),) + ((0, 0),)
+                                       * (x.ndim - 1))
+                               for x in (h, targets, weights))
+
+    @jax.checkpoint
+    def body(sums, xs):
+        hc, tc, wc = xs
+        with jax.named_scope("head"):
+            logits = jnp.dot(hc, kernel, preferred_element_type=jnp.float32)
+        with jax.named_scope("ce"):
+            nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+                logits, tc[:, None], axis=-1)[:, 0]
+            return sums + jnp.sum(nll[:, None] * wc, axis=0), None
+
+    split = lambda x: x.reshape(-1, chunk, *x.shape[1:])
+    sums, _ = jax.lax.scan(body, jnp.zeros(weights.shape[1:], jnp.float32),
+                           (split(h), split(targets), split(weights)))
+    return sums
+
+
+class SparseLM(nn.Module):
+    cfg: SparseLMConfig
+    # as DALLE.mesh: the Mosaic kernels run per shard of it
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, text_tokens: jax.Array, image_tokens: jax.Array,
+                 loss_mask: Optional[jax.Array] = None):
+        cfg = self.cfg
+        dt, pdt = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
+        # cfg.embed_init_std: an assumption of the configuration (no
+        # source pins an embedding's scale). At unit variance a token's
+        # own embedding outweighs the running mean of its prefix that
+        # attention adds to the residual stream, and an untrained router
+        # reads tokens; at 0.02 (the head's scale) it reads sequences
+        # and sends a whole sequence's assignments to this chip's experts
+        # or to none (PERF.md section 6, PR 31).
+        table = self.param(
+            "token_emb", nn.initializers.normal(stddev=cfg.embed_init_std),
+            (cfg.vocab_size, cfg.hidden_size), pdt)
+        ids = jnp.concatenate(
+            [text_tokens, image_tokens + cfg.vocab_text], axis=1)
+        with jax.named_scope("embed"):
+            x = jnp.take(table, ids, axis=0).astype(dt)
+
+        # a layer keeps its input and its attention's output and row
+        # statistics: the backward pass replays the projections and the
+        # experts, not the attention kernel
+        layer_cls = nn.remat(
+            Layer, policy=jax.checkpoint_policies.save_only_these_names(
+                "attn_out", "attn_stats"))
+        counters = []
+        for i in range(cfg.num_hidden_layers):
+            x, c = layer_cls(cfg, cfg.kind_of_layer(i), self.mesh,
+                             name=f"layer_{i}")(x)
+            counters.append(c)
+        x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
+                                   (cfg.hidden_size,), pdt), cfg.rms_eps)
+        head = self.param("lm_head", nn.initializers.normal(stddev=0.02),
+                          (cfg.hidden_size, cfg.vocab_size), pdt)
+
+        # position t predicts token t + 1; the last has nothing to predict
+        b, t = ids.shape
+        targets = jnp.concatenate(
+            [ids[:, 1:], jnp.zeros((b, 1), ids.dtype)], axis=1)
+        scored = (jnp.arange(t) < t - 1).astype(jnp.float32)
+        in_text = (jnp.arange(t) + 1 < text_tokens.shape[1]).astype(
+            jnp.float32)
+        weights = jnp.broadcast_to(
+            jnp.stack([scored * in_text, scored * (1.0 - in_text)], -1),
+            (b, t, 2))
+        if loss_mask is not None:
+            shifted = jnp.concatenate(
+                [loss_mask[:, 1:], jnp.zeros((b, 1), loss_mask.dtype)], 1)
+            weights = weights * shifted[..., None].astype(jnp.float32)
+        sums = _streamed_nll(
+            x.reshape(b * t, -1), head.astype(dt), targets.reshape(-1),
+            weights.reshape(b * t, 2), min(cfg.head_chunk, b * t))
+        # normalised over the WHOLE (micro)batch: under the accumulation's
+        # shard_map over dp the denominators are summed over the shards
+        # and this shard returns its share (as DALLE)
+        denoms = sum_over_manual_data_axes(jnp.sum(weights, axis=(0, 1)))
+        shards = sum_over_manual_data_axes(1)
+        if loss_mask is not None:
+            denoms = jnp.maximum(denoms, 1.0)
+        stack = lambda key: jnp.stack([c[key] for c in counters])
+        aux = {
+            "loss": jnp.sum(sums) / jnp.sum(denoms),
+            "loss_text": sums[0] / jnp.maximum(denoms[0], 1.0),
+            "loss_img": sums[1] / jnp.maximum(denoms[1], 1.0),
+            # the expert layers' counters: share of the tokens x k
+            # assignments that fell on held experts (mean over layers),
+            # the busiest held expert's tokens over the mean (worst
+            # layer), assignments to held experts left uncomputed
+            "moe_assignments_here_pct":
+                100.0 * jnp.mean(stack("here")) / shards,
+            "moe_load_max_over_mean": jnp.max(stack("load")) / shards,
+            "moe_dropped": jnp.sum(stack("dropped")),
+            # expert layers of this micro-batch whose assignments did not
+            # fit the dispatch buffer and took the dense lowering
+            "moe_dense_calls": jnp.sum(stack("dense")),
+        }
+        return aux["loss"], aux
+
+
+# ---------------------------------------------------------------------------
+# What task.py and training/loop.py ask of a model's module
+# ---------------------------------------------------------------------------
+
+def build(cfg: SparseLMConfig, mesh=None) -> SparseLM:
+    return SparseLM(cfg, mesh=mesh)
+
+
+def init_params(model: SparseLM, rng: jax.Array, batch: int = 2):
+    """Parameter shapes depend on no length: initialised on a one-block
+    sequence, as one jitted program."""
+    if model.mesh is not None:
+        shards = model.mesh.shape["dp"] * model.mesh.shape["fsdp"]
+        batch = -(-batch // shards) * shards
+    tokens = jnp.zeros((batch, 8), jnp.int32)
+    return jax.jit(model.init)(rng, tokens, tokens)
+
+
+def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
+    """The ``setup/warmup`` row's attributes: which layers' traced calls
+    took the blockwise kernel (looked up in what the dispatcher did), how
+    the layers run, and what the expert layer holds."""
+    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+    widths = (cfg.num_heads * cfg.head_dim // tp,
+              cfg.num_kv_heads * cfg.head_dim // tp)
+    kinds = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
+    on = sum(_KERNEL_CHOICES.get((k, cfg.total_seq_len, *widths), False)
+             for k in kinds)
+    windows = sum(k == LAYER_WINDOW_ROPE for k in kinds)
+    first, last = cfg.expert_offset, cfg.expert_offset + cfg.experts_held - 1
+    devices = mesh.size if mesh is not None else 1
+    return {
+        "attn_layout": (
+            f"blockwise {kernels.BLOCK}: {on} of {len(kinds)} layers, "
+            f"{len(kinds) - windows} full no-rope + {windows} window "
+            f"{cfg.window} rope, {cfg.num_heads // cfg.num_kv_heads} query "
+            f"heads a key-value head"),
+        "layer_loop": (f"unrolled: {len(kinds)} layers, each "
+                       "rematerialised but its attention"),
+        "moe_layout": (
+            f"{cfg.experts_held} of {cfg.num_experts} experts held "
+            f"({first}-{last}), top {cfg.experts_per_token} of "
+            f"{cfg.num_experts}, softmax over the chosen, no exchange: "
+            f"{'one device' if devices == 1 else f'{devices} devices, data parallel'}"),
+    }
+
+
+STEP_ATTRIBUTES = ("moe_assignments_here_pct", "moe_load_max_over_mean",
+                   "moe_dropped", "moe_dense_calls")

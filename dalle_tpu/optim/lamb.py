@@ -53,25 +53,32 @@ def default_wd_mask(params) -> Any:
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-def default_stacked_mask(params, reps: Optional[int] = None) -> Any:
-    """True for dense_scan's STACKED per-iteration leaves (transformer.py:
-    scan with ``variable_axes={"params": 0}``): leaves under the scanned
-    ``cycle`` whose rank exceeds their kind's canonical rank (kernel 2;
-    bias/scale 1) carry a leading scan-reps axis of independent layers.
-    LAMB's per-tensor trust ratio must then be computed PER SLICE so the
-    stacked model optimizes identically to its unrolled equivalent —
-    one shared ratio across 16 independent layers would silently change
-    convergence dynamics vs the model dense_scan merely re-stages.
+def default_stacked_mask(params, reps: Optional[int] = None,
+                         experts: Optional[int] = None) -> Any:
+    """How many LEADING axes of each leaf hold independent weights (0 for
+    an ordinary leaf). LAMB's per-tensor trust ratio must then be computed
+    PER SLICE so a stacked model optimizes identically to its unrolled
+    equivalent — one shared ratio across 16 independent layers, or 8
+    independent experts, would silently change convergence dynamics vs
+    the model the stacking merely re-stages. Two kinds of stacking:
 
-    ``reps`` is the config-derived stacked-axis size
-    (``ModelConfig.dense_scan_reps()``, threaded through
-    ``OptimizerConfig.stacked_reps`` by the task wiring): 0 means the
-    model has NO stacked leaves (every leaf gets the ordinary per-tensor
-    ratio regardless of its name), and a positive value additionally
-    requires the leading axis to equal it — so a future rank-3 kernel or
-    odd-rank param under the cycle scope cannot silently opt into
-    per-slice ratios (ADVICE r4). ``reps=None`` keeps the name+rank
-    inference for callers without model context."""
+    - dense_scan's per-iteration leaves (transformer.py: scan with
+      ``variable_axes={"params": 0}``): leaves under the scanned ``cycle``
+      whose rank exceeds their kind's canonical rank (kernel 2; bias/scale
+      1) carry a leading scan-reps axis of independent layers;
+    - an expert layer's leaves (models/sparse_lm.py: ``.../experts/<name>``
+      of rank 3): the experts held on the leading axis, one trust ratio
+      per expert (per layer: each layer's leaf is its own).
+
+    ``reps`` / ``experts`` are the config-derived stacked-axis sizes
+    (``<model config>.optimizer_stacking()``, threaded through
+    ``OptimizerConfig.stacked_reps`` / ``stacked_experts`` by the task
+    wiring): 0 means the model has NO such leaves (every leaf gets the
+    ordinary per-tensor ratio regardless of its name), and a positive
+    value additionally requires the leading axis to equal it — so a
+    future rank-3 kernel or odd-rank param under those scopes cannot
+    silently opt into per-slice ratios (ADVICE r4). ``None`` keeps the
+    name+rank inference for callers without model context."""
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
     treedef = jax.tree_util.tree_structure(params)
     out = []
@@ -83,32 +90,36 @@ def default_stacked_mask(params, reps: Optional[int] = None) -> Any:
             stacked = (stacked and reps > 0
                        and leaf.ndim == canonical + 1
                        and leaf.shape[0] == reps)
-        out.append(stacked)
+        by_expert = "experts" in keys[:-1] and leaf.ndim == 3
+        if experts is not None:
+            by_expert = (by_expert and experts > 0
+                         and leaf.shape[0] == experts)
+        out.append(int(stacked) + int(by_expert))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
 def lamb_leaf_update(p: jax.Array, m: jax.Array, v: jax.Array,
                      decay, lr, *, eps: float, weight_decay: float,
-                     clamp_value: float, stacked: bool = False) -> jax.Array:
+                     clamp_value: float, stacked: int = 0) -> jax.Array:
     """The shared per-tensor LAMB update (used by both the fp32 and 8-bit
     optimizers so their trajectories agree up to moment quantization):
     adam_step = m/(sqrt(v)+eps) + wd*p; trust = clamp(||p||, clamp_value) /
     ||adam_step|| (1.0 where either norm is 0); update = -lr*trust*adam_step.
     Matches reference lamb_8bit.py:135-158 (debias=False).
 
-    ``stacked`` (dense_scan leaves, see default_stacked_mask): the leading
-    axis holds independent layers' weights — norms and trust ratios are
+    ``stacked`` (see default_stacked_mask): that many leading axes hold
+    independent layers' or experts' weights — norms and trust ratios are
     computed per slice so the update equals the unrolled model's."""
     p32 = p.astype(jnp.float32)
     adam_step = m / (jnp.sqrt(v) + eps)
     if weight_decay:
         adam_step = adam_step + jnp.where(decay, weight_decay, 0.0) * p32
-    axes = tuple(range(1, p32.ndim)) if stacked else None
+    axes = tuple(range(int(stacked), p32.ndim)) if stacked else None
     wnorm = jnp.minimum(
-        jnp.sqrt(jnp.sum(p32 * p32, axis=axes, keepdims=stacked)),
+        jnp.sqrt(jnp.sum(p32 * p32, axis=axes, keepdims=bool(stacked))),
         clamp_value)
     anorm = jnp.sqrt(jnp.sum(adam_step * adam_step, axis=axes,
-                             keepdims=stacked))
+                             keepdims=bool(stacked)))
     trust = jnp.where((wnorm > 0) & (anorm > 0),
                       wnorm / (anorm + 1e-12), 1.0)
     return (-lr * trust * adam_step).astype(p.dtype)
@@ -123,6 +134,7 @@ def lamb(learning_rate: ScalarOrSchedule,
          max_grad_norm: Optional[float] = 4.0,
          wd_mask_fn: Callable[[Any], Any] = default_wd_mask,
          stacked_reps: Optional[int] = None,
+         stacked_experts: Optional[int] = None,
          ) -> optax.GradientTransformation:
 
     def init_fn(params):
@@ -150,7 +162,8 @@ def lamb(learning_rate: ScalarOrSchedule,
         lr = learning_rate(state.count) if callable(learning_rate) \
             else learning_rate
         wd_mask = wd_mask_fn(params)
-        stacked_mask = default_stacked_mask(params, stacked_reps)
+        stacked_mask = default_stacked_mask(params, stacked_reps,
+                                            stacked_experts)
 
         def leaf_update(p, m, v, decay, stacked):
             return lamb_leaf_update(
@@ -185,4 +198,5 @@ def make_optimizer_fp32(cfg: OptimizerConfig) -> optax.GradientTransformation:
         learning_rate=make_lr_schedule(cfg),
         b1=cfg.beta1, b2=cfg.beta2, eps=cfg.eps,
         weight_decay=cfg.weight_decay, clamp_value=cfg.clamp_value,
-        max_grad_norm=cfg.max_grad_norm, stacked_reps=cfg.stacked_reps)
+        max_grad_norm=cfg.max_grad_norm, stacked_reps=cfg.stacked_reps,
+        stacked_experts=cfg.stacked_experts)

@@ -63,6 +63,7 @@ def lamb8bit(learning_rate: ScalarOrSchedule,
              min_8bit_size: int = 65536,
              wd_mask_fn: Callable[[Any], Any] = default_wd_mask,
              stacked_reps: Optional[int] = None,
+             stacked_experts: Optional[int] = None,
              mesh=None,
              ) -> optax.GradientTransformation:
     """``mesh``: the device mesh the train state is sharded over; the
@@ -98,7 +99,7 @@ def lamb8bit(learning_rate: ScalarOrSchedule,
         v_leaves = treedef.flatten_up_to(state.nu)
         d_leaves = treedef.flatten_up_to(wd_mask_fn(params))
         s_leaves = treedef.flatten_up_to(
-            default_stacked_mask(params, stacked_reps))
+            default_stacked_mask(params, stacked_reps, stacked_experts))
 
         g_leaves = [g.astype(jnp.float32) for g in g_leaves]
         if max_grad_norm is not None:
@@ -136,7 +137,7 @@ def make_optimizer_8bit(cfg: OptimizerConfig,
         weight_decay=cfg.weight_decay, clamp_value=cfg.clamp_value,
         max_grad_norm=cfg.max_grad_norm, block_size=cfg.block_size,
         min_8bit_size=cfg.min_8bit_size, stacked_reps=cfg.stacked_reps,
-        mesh=mesh)
+        stacked_experts=cfg.stacked_experts, mesh=mesh)
 
 
 def optimizer_state_bytes(state) -> int:

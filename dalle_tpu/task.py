@@ -21,7 +21,7 @@ import jax
 import numpy as np
 
 from dalle_tpu.config import (CollabConfig, ModelConfig, OptimizerConfig,
-                              PeerConfig, TrainerConfig)
+                              PeerConfig, SparseLMConfig, TrainerConfig)
 from dalle_tpu.swarm.metrics import make_validators, peer_data_seed
 
 logger = logging.getLogger(__name__)
@@ -31,7 +31,7 @@ class TrainingTask:
     """Lazy container: each property builds its subsystem on first use."""
 
     def __init__(self,
-                 model: ModelConfig,
+                 model: "ModelConfig | SparseLMConfig",
                  optimizer: OptimizerConfig,
                  trainer: TrainerConfig,
                  collab: CollabConfig,
@@ -173,22 +173,30 @@ class TrainingTask:
         return make_mesh(dp=t.dp, fsdp=t.fsdp, tp=t.tp, sp=t.sp)
 
     @functools.cached_property
+    def family(self):
+        """The module of the configuration's architecture: it builds the
+        model and its parameters and says its own records
+        (``models.family``)."""
+        from dalle_tpu.models import family
+        return family(self.model_cfg)
+
+    @functools.cached_property
     def model(self):
-        from dalle_tpu.models.dalle import DALLE
-        return DALLE(self.model_cfg, mesh=self.mesh)
+        return self.family.build(self.model_cfg, mesh=self.mesh)
 
     @functools.cached_property
     def tx(self):
         import dataclasses
 
         from dalle_tpu.optim import make_optimizer
-        # thread the model's stacked-axis size so the per-slice trust
+        # thread the model's stacked-axis sizes so the per-slice trust
         # ratio mask is config-derived, not name-inferred (ADVICE r4)
         cfg = self.opt_cfg
-        if cfg.stacked_reps is None:
-            cfg = dataclasses.replace(
-                cfg, stacked_reps=self.model_cfg.dense_scan_reps())
-        return make_optimizer(cfg, mesh=self.mesh)
+        stacking = {k: v for k, v in
+                    self.model_cfg.optimizer_stacking().items()
+                    if getattr(cfg, k) is None}
+        return make_optimizer(dataclasses.replace(cfg, **stacking),
+                              mesh=self.mesh)
 
     @functools.cached_property
     def train_state(self):
@@ -196,12 +204,11 @@ class TrainingTask:
         the trainer loop's job, reference ``task.py:88-93``). With
         ``optimizer.offload`` the optimizer state is placed in host RAM
         instead of on the mesh (reference ``offload.py``/``task.py:130``)."""
-        from dalle_tpu.models.dalle import init_params
         from dalle_tpu.parallel.sharding import shard_train_state
         from dalle_tpu.training.steps import TrainState
         with self._setup_span("train_state"):
-            params = init_params(self.model,
-                                 jax.random.PRNGKey(self.trainer_cfg.seed))
+            params = self.family.init_params(
+                self.model, jax.random.PRNGKey(self.trainer_cfg.seed))
             state = TrainState.create(params, self.tx)
             if self.opt_cfg.offload:
                 from dalle_tpu.training.offload import offload_train_state

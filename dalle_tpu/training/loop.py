@@ -21,8 +21,6 @@ from typing import Callable, List, Optional
 import jax
 import numpy as np
 
-from dalle_tpu.models.attention import attn_layout_record
-from dalle_tpu.models.transformer import layer_loop_record
 from dalle_tpu.swarm.metrics import LocalMetrics, publish_metrics
 from dalle_tpu.task import TrainingTask
 from dalle_tpu.training.steps import grad_reduction_plan
@@ -49,8 +47,7 @@ def warmup(task: TrainingTask, steps: int = 3) -> float:
     params = task.collab_optimizer.state.params
     loss = float("nan")
     with task.tracer.span("train", "setup/warmup", "setup", steps=steps,
-                          grad_reduction=grad_reduction_plan(task.mesh),
-                          layer_loop=layer_loop_record(task.model_cfg)
+                          grad_reduction=grad_reduction_plan(task.mesh)
                           ) as span:
         for i in range(steps):
             t0 = time.monotonic()
@@ -59,8 +56,10 @@ def warmup(task: TrainingTask, steps: int = 3) -> float:
             loss = float(metrics["loss"])
             logger.info("warmup %d/%d: loss=%.4f (%.2fs)",
                         i + 1, steps, loss, time.monotonic() - t0)
-        # the step is traced by now: what its attention layers lowered to
-        span.set(attn_layout=attn_layout_record(task.model_cfg, task.mesh))
+        # the step is traced by now: the model says what its layers
+        # lowered to (attn_layout, layer_loop, ...)
+        span.set(**task.family.engagement_records(task.model_cfg,
+                                                  task.mesh))
     if not np.isfinite(loss):
         raise RuntimeError(f"warmup produced non-finite loss {loss}")
     # warmup gradients are discarded; the tracker timer starts fresh
@@ -141,11 +140,12 @@ def train_loop(task: TrainingTask,
     # loop/step span a step, its parts as children; what the children do
     # not cover is the loop's own time
     span = functools.partial(task.tracer.span, "train")
+    step_attributes = task.family.STEP_ATTRIBUTES
     try:
         while ((max_epochs is None or collab.local_epoch < max_epochs)
                and (max_steps is None or local_steps < max_steps)):
             profiler.tick(local_steps)
-            with span("loop/step", f"step:{local_steps + 1}"):
+            with span("loop/step", f"step:{local_steps + 1}") as step_row:
                 with span("loop/batch_fetch"):
                     batch = next(batches)
                 with span("loop/grad_dispatch"):
@@ -153,6 +153,10 @@ def train_loop(task: TrainingTask,
                                                     batch)
                 with span("loop/loss_wait"):
                     loss = float(metrics["loss"])
+                    # what the model counts a step (an expert layer's
+                    # load), read with the loss from the step's aux
+                    step_row.set(**{k: float(metrics[k])
+                                    for k in step_attributes})
                 loss_sum += loss
                 mini_steps += 1
                 local_steps += 1

@@ -169,21 +169,21 @@ def _shard_grads(model, params, batch, accum_steps: int):
         functools.partial(_loss_fn, model), has_aux=True)
 
     def body(carry, mb):
-        g_acc, loss_acc, aux_acc = carry
+        g_acc, loss_acc = carry
         (loss, aux), g = grad_fn(params, mb)
         # a device scope of its own (models/dalle.py names the others):
         # the accumulation's add shows apart from the backward pass
         with jax.named_scope(GRAD_ACCUMULATE_SCOPE):
             g_acc = jax.tree.map(jnp.add, g_acc, g)
-        aux_acc = jax.tree.map(jnp.add, aux_acc, aux)
-        return (g_acc, loss_acc + loss, aux_acc), None
+        # the model's own aux (its losses, and whatever else it counts a
+        # step) leaves the scan a microbatch at a time: the accumulation
+        # names none of its entries
+        return (g_acc, loss_acc + loss), aux
 
     g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    aux0 = {"loss": jnp.zeros([], jnp.float32),
-            "loss_text": jnp.zeros([], jnp.float32),
-            "loss_img": jnp.zeros([], jnp.float32)}
-    (grads, loss, aux), _ = jax.lax.scan(
-        body, (g0, jnp.zeros([], jnp.float32), aux0), micro)
+    (grads, loss), aux = jax.lax.scan(
+        body, (g0, jnp.zeros([], jnp.float32)), micro)
+    aux = jax.tree.map(lambda a: jnp.sum(a, axis=0), aux)
     inv = 1.0 / accum_steps
     grads = jax.tree.map(lambda g: g * inv, grads)
     aux = jax.tree.map(lambda a: a * inv, aux)

@@ -1,0 +1,294 @@
+"""The sparse language model (models/sparse_lm.py) at a tiny size, seeded
+random weights, f32: against the plain reference of its yardstick; the
+shares of the expert layer add up to the uncut layer; no token is dropped
+whatever the router does; and the model trains through the peer's normal
+path (run_trainer's parser, TrainingTask, train_loop)."""
+import dataclasses
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.manifest import Manifest
+from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
+from dalle_tpu.config import ModelConfig, SparseLMConfig
+from dalle_tpu.models import attention, family, sparse_lm
+
+Y = Manifest().yardstick("smallthinker")
+
+TINY = dict(hidden_size=64, num_hidden_layers=4, num_heads=4, num_kv_heads=2,
+            head_dim=16, expert_width=32, num_experts=8, experts_per_token=2,
+            experts_held=4, expert_offset=2, vocab_size=96, window=8,
+            text_seq_len=12, image_grid=4, vocab_text=48, vocab_image=48,
+            dtype="float32", head_chunk=16)
+
+
+def as_file(cfg):
+    return json.loads(json.dumps(dataclasses.asdict(cfg)))
+
+
+def _batch(cfg, seed=0, n=2):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.integers(2, cfg.vocab_text,
+                                     (n, cfg.text_seq_len)), jnp.int32),
+            jnp.asarray(rng.integers(0, cfg.vocab_image,
+                                     (n, cfg.image_seq_len)), jnp.int32))
+
+
+def rel_l2(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b),
+                                                      1e-30))
+
+
+def _system(cfg, params, text, image):
+    model = sparse_lm.build(cfg)
+    return jax.jit(jax.value_and_grad(
+        lambda p: model.apply(p, text, image), has_aux=True))(params)
+
+
+@pytest.mark.parametrize("kernels", [False, True])
+def test_loss_and_every_gradient_leaf_against_the_yardstick(kernels,
+                                                            monkeypatch):
+    """Whole tiny model, both layer kinds, a sequence (28) longer than the
+    window (8), half of the router's experts held; with ``kernels`` the
+    attention runs the blockwise Pallas kernels, interpreted."""
+    cfg = SparseLMConfig(**dict(TINY, head_dim=128 if kernels else 16))
+    assert {cfg.kind_of_layer(i) for i in range(4)} == {"full_nope",
+                                                       "window_rope"}
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", kernels)
+    params = sparse_lm.init_params(sparse_lm.build(cfg),
+                                   jax.random.PRNGKey(1))
+    text, image = _batch(cfg)
+    (loss, aux), grads = _system(cfg, params, text, image)
+    ref_loss, ref_grads = Y.loss_and_grads(params, text, image, as_file(cfg))
+    assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree.leaves(ref_grads)):
+        assert rel_l2(g, r) < 2e-5, jax.tree_util.keystr(path)
+    # the two fields' means weigh back to the loss: 11 and 16 targets
+    assert float(aux["loss"]) == pytest.approx(
+        (11 * float(aux["loss_text"]) + 16 * float(aux["loss_img"])) / 27,
+        rel=1e-6)
+    assert 0 < float(aux["moe_assignments_here_pct"]) < 100
+    assert float(aux["moe_dropped"]) == 0.0
+    taken = sparse_lm._KERNEL_CHOICES.get(
+        ("window_rope", 28, 4 * 128, 2 * 128), False)
+    assert taken == kernels
+
+
+def test_the_reference_at_the_sets_the_program_chose():
+    """``loss_and_grads_at``: the reference routed by given sets. At the
+    program's own (sown by every layer; in f32 they are the reference's)
+    it is ``loss_and_grads``; at other sets it is another function, whose
+    routing weights are the softmax of its own scores at those experts."""
+    cfg = SparseLMConfig(**TINY)
+    model = sparse_lm.build(cfg)
+    params = sparse_lm.init_params(model, jax.random.PRNGKey(2))
+    text, image = _batch(cfg)
+    _, kept = model.apply(params, text, image, mutable=["intermediates"])
+    ours = np.stack([np.asarray(kept["intermediates"][f"layer_{i}"]
+                                ["chosen"][0]) for i in range(4)])
+    assert ours.shape == (4, 2, 28, cfg.experts_per_token)
+    theirs = np.asarray(Y.chosen_experts(params, text, image, as_file(cfg)))
+    np.testing.assert_array_equal(np.sort(ours, -1), np.sort(theirs, -1))
+    own_loss, own = Y.loss_and_grads(params, text, image, as_file(cfg))
+    at_loss, at = Y.loss_and_grads_at(ours, params, text, image, as_file(cfg))
+    assert float(at_loss) == pytest.approx(float(own_loss), rel=1e-6)
+    for g, r in zip(jax.tree.leaves(at), jax.tree.leaves(own)):
+        assert rel_l2(g, r) < 1e-5
+    other = (ours + 1) % cfg.num_experts
+    other_loss, _ = Y.loss_and_grads_at(other, params, text, image,
+                                        as_file(cfg))
+    assert abs(float(other_loss) - float(own_loss)) > 1e-6
+    a = jax.random.normal(jax.random.PRNGKey(4), (5, cfg.hidden_size))
+    router = jax.random.normal(jax.random.PRNGKey(5), (cfg.hidden_size, 8))
+    given = jnp.asarray([[7, 0]] * 5)
+    idx, p = Y.route(a, router, 2, given)
+    np.testing.assert_array_equal(idx, given)
+    np.testing.assert_allclose(
+        p, jax.nn.softmax((a @ router)[:, [7, 0]], -1), rtol=1e-6)
+
+
+@pytest.mark.parametrize("kernels", [False, True])
+def test_the_shares_add_up_to_the_uncut_expert_layer(kernels, monkeypatch):
+    """8 experts over 4 shares of 2: the four partial results, summed,
+    equal the reference's whole expert layer; what every share computes
+    alike (the router here; attention likewise) is counted once, and every
+    assignment is computed by exactly one share. With ``kernels`` the
+    sorted lowering and its grouped Pallas products, interpreted; without,
+    the dense lowering a backend with no Mosaic kernels takes."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", kernels)
+    cfg = SparseLMConfig(**TINY)
+    rng = jax.random.split(jax.random.PRNGKey(3), 6)
+    n, d, f = 56, cfg.hidden_size, cfg.expert_width
+    a = jax.random.normal(rng[0], (n, d))
+    m = jax.random.normal(rng[1], (n, d))
+    router = jax.random.normal(rng[2], (d, 8))
+    whole = {"gate": jax.random.normal(rng[3], (8, d, f)) * 0.2,
+             "up": jax.random.normal(rng[4], (8, d, f)) * 0.2,
+             "down": jax.random.normal(rng[5], (8, f, d)) * 0.2}
+    want = Y.whole_layer_experts(m, a, router, whole, cfg.experts_per_token)
+    idx, p = Y.route(a, router, cfg.experts_per_token)   # computed once
+
+    total, here = jnp.zeros((n, d)), 0.0
+    for share in range(4):
+        mine = {k: w[2 * share: 2 * share + 2] for k, w in whole.items()}
+        y, computed = sparse_lm.held_experts(
+            m, idx, p, mine["gate"], mine["up"], mine["down"],
+            offset=2 * share, rows=n * 2)
+        total, here = total + y, here + float(computed)
+        # and the reference, given the same share, gives the same part
+        np.testing.assert_allclose(
+            y, Y.expert_sum(m, idx, p, mine, 2 * share), atol=2e-5)
+    np.testing.assert_allclose(total, want, atol=5e-5)
+    assert here == n * cfg.experts_per_token
+
+
+@pytest.mark.parametrize("rows_over_expected, fits", [(2.0, True),
+                                                      (0.5, False)])
+def test_no_token_is_dropped_when_the_router_sends_everything_to_one_expert(
+        rows_over_expected, fits, monkeypatch):
+    """A router forced onto one held expert sends it every token: twice
+    what a uniform router sends to the two held experts together. The
+    usual buffer (2 x expected) just holds that and the sorted lowering
+    computes it; a smaller one does not, and the dense lowering takes the
+    step. Either way every assignment to a held expert is computed,
+    forward and backward."""
+    monkeypatch.setattr(sparse_lm, "ROWS_OVER_EXPECTED", rows_over_expected)
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    cfg = SparseLMConfig(**dict(TINY, experts_held=2, expert_offset=4))
+    n = 1024
+    rows = sparse_lm.dispatch_rows(n, cfg)
+    assert (rows >= n) == fits and rows < n * 2   # never the worst case
+    rng = jax.random.split(jax.random.PRNGKey(5), 5)
+    d, f = cfg.hidden_size, cfg.expert_width
+    m = jax.random.normal(rng[0], (n, d))
+    experts = {"gate": jax.random.normal(rng[1], (2, d, f)) * 0.2,
+               "up": jax.random.normal(rng[2], (2, d, f)) * 0.2,
+               "down": jax.random.normal(rng[3], (2, f, d)) * 0.2}
+    # every token: expert 5 (held) first, expert 0 (elsewhere) second
+    idx = jnp.tile(jnp.asarray([[5, 0]], jnp.int32), (n, 1))
+    p = jax.nn.softmax(jax.random.normal(rng[4], (n, 2)), -1)
+
+    def system(m, p, experts):
+        y, computed = sparse_lm.held_experts(
+            m, idx, p, experts["gate"], experts["up"], experts["down"],
+            offset=4, rows=rows)
+        return jnp.sum(y * jnp.cos(y)), computed
+
+    def reference(m, p, experts):
+        y = Y.expert_sum(m, idx, p, experts, 4)
+        return jnp.sum(y * jnp.cos(y))
+
+    (value, computed), grads = jax.jit(jax.value_and_grad(
+        system, (0, 1, 2), has_aux=True))(m, p, experts)
+    want, ref_grads = jax.value_and_grad(reference, (0, 1, 2))(m, p, experts)
+    assert float(computed) == n             # every token, none dropped
+    assert float(value) == pytest.approx(float(want), rel=1e-5)
+    for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
+        assert rel_l2(g, r) < 2e-5
+    # no row of the result is zero: every token went through expert 5
+    y, _ = sparse_lm.held_experts(m, idx, p, experts["gate"], experts["up"],
+                                  experts["down"], offset=4, rows=rows)
+    assert float(jnp.min(jnp.linalg.norm(y, axis=1))) > 0
+
+
+TINY_FLAGS = [
+    "--hidden-size", "64", "--num-hidden-layers", "4", "--num-heads", "4",
+    "--num-kv-heads", "2", "--head-dim", "16", "--expert-width", "32",
+    "--num-experts", "8", "--experts-per-token", "2", "--experts-held", "4",
+    "--expert-offset", "2", "--vocab-size", "96", "--window", "8",
+    "--text-seq-len", "12", "--image-grid", "4", "--vocab-text", "48",
+    "--vocab-image", "48", "--dtype", "float32", "--head-chunk", "16"]
+
+
+def test_the_preset_trains_through_the_peers_normal_path():
+    """``run_trainer --preset smallthinker21b`` (+ tiny field flags):
+    the parser builds the preset's own class, TrainingTask builds the
+    model its configuration names, and train_loop runs it with the swarm
+    optimizer; the rows of the trainer's ring carry the model's records."""
+    from dalle_tpu.obs.trace import default_tracer
+    from dalle_tpu.task import TrainingTask
+    from dalle_tpu.training.loop import train_loop
+
+    args = run_trainer.build_parser().parse_args(
+        ["--preset", "smallthinker21b", *TINY_FLAGS,
+         "--per-device-batch", "1", "--grad-accum-steps", "2",
+         "--target-batch-size", str(1 << 30), "--seed", "7"])
+    configs = run_trainer.configs_from_args(args)
+    assert configs[0] == SparseLMConfig(**TINY)
+    assert type(run_trainer.configs_from_args(
+        run_trainer.build_parser().parse_args(["--preset", "tiny"]))[0]) \
+        is ModelConfig
+    task = TrainingTask(*configs)
+    assert family(task.model_cfg) is sparse_lm
+    assert isinstance(task.model, sparse_lm.SparseLM)
+    losses = []
+    with task:
+        train_loop(task, max_steps=3, warmup_steps=1,
+                   on_step=lambda n, loss: losses.append(loss))
+    assert len(losses) == 3 and all(np.isfinite(losses))
+    rows = [r for r in default_tracer().dump() if r.get("plane") == "train"]
+    warm = [r for r in rows if r["phase"] == "setup/warmup"][-1]["a"]
+    assert warm["moe_layout"].startswith("4 of 8 experts held (2-5), top 2")
+    assert warm["attn_layout"].startswith("blockwise 512: 0 of 4 layers")
+    assert warm["layer_loop"] == ("unrolled: 4 layers, each rematerialised "
+                                  "but its attention")
+    steps = [r for r in rows if r["phase"] == "loop/step"][-3:]
+    for row in (r["a"] for r in steps):
+        assert 0 < row["moe_assignments_here_pct"] < 100
+        assert row["moe_load_max_over_mean"] >= 1.0
+        assert row["moe_dropped"] == 0.0
+        # no Mosaic backend here: the dense lowering in every layer of
+        # every shard, and said so
+        assert row["moe_dense_calls"] == 4.0 * task.mesh.size
+    # the optimizer was told the expert axis by the configuration
+    assert task.model_cfg.optimizer_stacking()["stacked_experts"] == 4
+
+
+def test_what_a_configuration_states_is_a_field_and_not_a_flag():
+    """The four facts of the source that ``validate`` holds to one value,
+    and the embedding's scale at init, are fields (the configuration file
+    states them) and no entry point's flags; layers are always
+    rematerialised: no field for it."""
+    parser = run_trainer.build_parser()
+    flags = {action.dest for action in parser._actions}
+    fields = {f.name for f in dataclasses.fields(SparseLMConfig)}
+    stated = set(SparseLMConfig.no_flag)
+    assert stated == {"router_softmax_over_chosen", "tied_embeddings",
+                      "router_input", "attention_bias",
+                      "embed_init_std"} <= fields
+    assert flags & stated == {"tied_embeddings"}     # the DALL-E's own flag
+    assert "remat" not in fields
+    with pytest.raises(SystemExit):
+        parser.parse_args(["--preset", "smallthinker21b",
+                           "--router-input", "layer_input"])
+
+
+def test_the_dalle_keeps_its_records_and_its_step_rows():
+    from dalle_tpu.config import tiny_model_config
+    from dalle_tpu.models import dalle
+    cfg = tiny_model_config()
+    assert family(cfg) is dalle and dalle.STEP_ATTRIBUTES == ()
+    records = dalle.engagement_records(cfg)
+    assert set(records) == {"layer_loop", "attn_layout"}
+    assert cfg.optimizer_stacking() == {"stacked_reps": 0,
+                                        "stacked_experts": 0}
+    assert len(dataclasses.fields(ModelConfig)) == 29
+
+
+@pytest.mark.parametrize("cli, argv", [
+    (run_inference, ["--checkpoint-dir", "x", "--tokenizer-path", "y",
+                     "--query", "a cat"]),
+    (run_server, ["--random-init"]),
+    (run_aux_peer, []),
+])
+def test_entry_points_that_decode_refuse_the_preset_at_start(cli, argv):
+    with pytest.raises(SystemExit) as refused:
+        cli.main(["--preset", "smallthinker21b", *argv])
+    message = str(refused.value)
+    assert "smallthinker21b" in message and "models/decode.py" in message
+    assert "grouped key-value heads" in message and "expert layer" in message
+    assert message.count(".") <= 3 and "\n" not in message   # one sentence
