@@ -32,6 +32,22 @@ the count (:func:`held_experts`). Where the grouped kernels cannot run (no
 Mosaic backend, sizes that are not lane tiles) the dense lowering is the
 layer.
 
+**The sorted lowering moves rows four times**: tokens to rows and the
+output's cotangent to rows (one gather of the buffer's rows each,
+:func:`_rows_of`), and two token-major sums, combine's forward ``y[n] =
+sum_j p[n, j] ys[row[n, j]]`` and dispatch's backward ``dm[n] = sum_j
+dxs[row[n, j]]`` (:func:`_sum_to_tokens`). The plan sorts the flattened
+(token, slot) assignments by held expert with a *stable* sort and
+``top_k`` names an expert once a token, so **a group's rows ascend by
+token and a tile of consecutive tokens owns one contiguous run of rows in
+every group**: a sum is, a token tile, one copy of a window of rows a held
+expert and a placement on the MXU (ops/pallas/token_sum_kernels.py: only
+rows that hold an assignment are read, each once, nothing is rounded).
+Where that kernel's cost, which grows with the experts held, passes the
+cost of one gather of all N rows a slot, or its windows do not fit VMEM,
+the sums are those gathers (a slot with no row here reads a row of its
+own and is masked): :func:`runs_why_not` is the rule, read off shapes.
+
 The model takes the trainer's batches as they are: ``text`` and ``image``
 are the two halves of one token sequence, image ids offset by
 ``vocab_text``. The loss is the mean next-token cross-entropy over the
@@ -43,7 +59,10 @@ parameters.
 Device scopes (``jax.named_scope`` and module names; the benchmark's
 ``*_share_pct`` metrics read them): ``embed``, ``attn`` (projections,
 rotary, kernel), ``rms_norm``, ``ff/router``, ``ff/dispatch``,
-``ff/experts``, ``ff/combine``, ``head``, ``ce``.
+``ff/experts``, ``ff/combine``, ``head``, ``ce``. The token-major kernel
+(``token_major_sum[mosaic]`` in a trace) runs under the scope of its sum,
+``ff/combine`` or ``ff/dispatch``; the grouped products under
+``ff/experts``.
 """
 
 from __future__ import annotations
@@ -60,6 +79,7 @@ from dalle_tpu.config import LAYER_WINDOW_ROPE, SparseLMConfig
 from dalle_tpu.models import attention as attn_mod
 from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
 from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
+from dalle_tpu.ops.pallas import token_sum_kernels as token_sum
 from dalle_tpu.parallel.mesh import (LANES_SPEC, per_shard,
                                      sum_over_manual_data_axes)
 
@@ -171,6 +191,41 @@ def dispatch_rows(tokens: int, cfg: SparseLMConfig) -> int:
     return min(worst, -(-int(ROWS_OVER_EXPECTED * expected) // tile) * tile)
 
 
+# What the two lowerings of a token-major sum cost on the v5e (my chip runs,
+# PR 32: 16 384 tokens of 2 560 in bf16, 6 slots, 8 and 16 of 64 experts
+# held): a gathered slot 49 ns a token, whatever holds a row there (0.8 ms
+# a slot; six and their select-adds 4.8 ms a sum); a window of
+# token_sum.WINDOW rows copied and placed 1.2 us (0.39 ms a sum without
+# weights, 0.82 with, for 64 tiles x 8 experts).
+SLOT_NS_A_TOKEN = 49.0
+WINDOW_NS = 1200.0
+
+# (slots, held, width, dtype) -> (why not the kernel, tokens a tile) of the
+# last traced token-major sum of those shapes: what moe_layout reads
+_SUM_LOWERINGS: Dict[Tuple[int, int, int, str],
+                     Tuple[Optional[str], int]] = {}
+
+
+def runs_why_not(tokens: int, slots: int, held: int, dim: int,
+                 dtype) -> Optional[str]:
+    """Why a token-major sum of these shapes takes one gather a slot and
+    not the kernel over runs (token_sum_kernels.py); None where it takes
+    the kernel. The kernel reads a window for every held expert and token
+    tile, the gathers a row for every slot and token, held here or not:
+    the kernel wins while ``held`` stays under about ten experts a slot
+    (``TOKENS * SLOT_NS_A_TOKEN / WINDOW_NS`` = 10.4), if its windows fit
+    VMEM. With 8 of 64 experts held and 6 slots it costs an eighth; the
+    crossing, 62 experts for 6 slots, lies past the 16 whose windows fit
+    at a width of 2 560."""
+    why_not = token_sum.fits(held, dim, dtype)
+    if why_not is None:
+        windows = -(-tokens // token_sum.tokens_tile(tokens)) * held
+        if windows * WINDOW_NS > tokens * slots * SLOT_NS_A_TOKEN:
+            why_not = (f"{windows} windows cost more than {slots} gathers "
+                       f"of {tokens} rows")
+    return why_not
+
+
 def held_key(idx: jax.Array, offset: int, held: int) -> jax.Array:
     """(tokens * k,) the held expert's local index of every assignment,
     ``held`` for an assignment to an expert that lives elsewhere."""
@@ -193,17 +248,27 @@ class _Plan(NamedTuple):
     valid: jax.Array      # (rows,) the row holds an assignment
     row: jax.Array        # (N, k) the row of a token's assignment
     here: jax.Array       # (N, k) that row exists (a held expert's)
+    key: jax.Array        # (N, k) the assignment's held expert, or held
     sizes: jax.Array      # (held,) assignments to each held expert
     tiles: grouped.Tiles  # the row tiles' experts
+    # what the token-major kernel reads (token_sum_kernels.py)
+    row_of: jax.Array     # (N, held) a token's row in an expert's group, -1
+    start: jax.Array      # (token tiles + 1, held) the tiles' runs of rows
+    written: jax.Array    # () rows from here on are in no active tile
+
+
+def _buffer_tiles(rows: int, held: int) -> int:
+    """Row tiles of a buffer that holds ``rows`` assignments: a tile more
+    an expert for the rounding."""
+    return -(-rows // grouped.TILE) + held
 
 
 def dispatch_plan(idx: jax.Array, offset: int, held: int,
                   rows: int) -> _Plan:
-    """``rows``: assignments the buffer has to hold; it gets a tile more
-    an expert for the rounding."""
+    """``rows``: assignments the buffer has to hold."""
     n, k = idx.shape
     tile = grouped.TILE
-    n_tiles = -(-rows // tile) + held
+    n_tiles = _buffer_tiles(rows, held)
     key = held_key(idx, offset, held)
     sizes = group_sizes(key, held)
     order = jnp.argsort(key, stable=True)     # held experts' first, by expert
@@ -224,14 +289,29 @@ def dispatch_plan(idx: jax.Array, offset: int, held: int,
     # it reads a row of its own (one row for all of them is a hot spot:
     # 0.73 against 0.59 ms a gather on the v5e)
     elsewhere = (jnp.arange(n) % (n_tiles * tile))[:, None]
+    # token -> its row in every held expert's group, and the token tiles'
+    # runs: a group's rows ascend by token (the sort is stable, a token
+    # names an expert once), so a tile of tokens owns a contiguous run
+    key = key.reshape(n, k)
+    mine = (key[:, :, None] == jnp.arange(held)) & here[:, :, None]
+    row_of = jnp.max(jnp.where(mine, row[:, :, None], -1), axis=1)
     return _Plan(a // k, a % k, valid, jnp.where(here, row, elsewhere), here,
-                 sizes, tiles)
+                 key, sizes, tiles, row_of, _run_starts(key, first_row),
+                 jnp.sum(tiles.active) * tile)
+
+
+def _run_starts(key: jax.Array, first_row: jax.Array) -> jax.Array:
+    """(token tiles + 1, held): where every token tile's run of rows
+    starts in every held expert's group. key: (N, k) from ``held_key``."""
+    held = first_row.shape[0]
+    return token_sum.run_starts(
+        jnp.any(key[:, :, None] == jnp.arange(held), axis=1), first_row)
 
 
 def _sum_over_slots(rows_of, plan: _Plan, weight=None):
     """(N, D) f32: every token's sum over its assignments computed here of
-    [weight x] the row that computed it — one gather of N rows a slot, so
-    that neither direction of dispatch or combine is a scatter. Rows that
+    [weight x] the row that computed it, one gather of N rows a slot (a
+    slot with no row here reads a row of its own and is masked). Rows that
     hold no assignment are never read (the grouped products leave rows
     outside every group unwritten)."""
     total = 0.0
@@ -241,6 +321,25 @@ def _sum_over_slots(rows_of, plan: _Plan, weight=None):
         total = total + (picked if weight is None
                          else picked * weight[:, j, None])
     return total
+
+
+def _sum_to_tokens(rows_of, plan: _Plan, weight=None, dtype=jnp.float32):
+    """(N, D) ``dtype``: the same sum by the lowering its shapes choose
+    (:func:`runs_why_not`): where the kernel over runs, a tile of tokens
+    reads its run of rows in every held expert's group, each row once."""
+    (n, k), held = plan.row.shape, plan.row_of.shape[1]
+    why_not = runs_why_not(n, k, held, rows_of.shape[1], rows_of.dtype)
+    _SUM_LOWERINGS[k, held, rows_of.shape[1], rows_of.dtype.name] = (
+        why_not, token_sum.tokens_tile(n))
+    if why_not is not None:
+        return _sum_over_slots(rows_of, plan, weight).astype(dtype)
+    if weight is not None:
+        weight = jnp.sum(jnp.where(
+            plan.key[:, :, None] == jnp.arange(held), weight[:, :, None],
+            0.0), axis=1)
+    return token_sum.token_major_sum(
+        rows_of, plan.row_of, plan.start, plan.written, weight,
+        out_dtype=dtype, interpret=attn_mod._PALLAS_INTERPRET)
 
 
 def _rows_of(source, plan: _Plan):
@@ -289,7 +388,7 @@ def _sorted_forward(m, idx, p, gate, up, down, *, offset: int, rows: int):
         g, u = dot(xs, gate), dot(xs, up)
         ys = dot(jax.nn.relu(g) * u, down)
     with jax.named_scope("combine"):
-        y = _sum_over_slots(ys, plan, p)
+        y = _sum_to_tokens(ys, plan, p)
     return ((y, jnp.sum(plan.valid, dtype=jnp.float32)),
             _Kept(plan, xs, g, u, ys))
 
@@ -315,7 +414,7 @@ def _sorted_backward(kept: _Kept, p, gate, up, down, dy):
         dxs_gate, dgate = grads(xs, gate, jnp.where(g > 0, dhidden * u, 0))
         dxs_up, dup = grads(xs, up, dhidden * act)
     with jax.named_scope("dispatch"):
-        dm = _sum_over_slots(dxs_gate + dxs_up, plan).astype(xs.dtype)
+        dm = _sum_to_tokens(dxs_gate + dxs_up, plan, dtype=xs.dtype)
     return dm, dp, dgate, dup, ddown
 
 
@@ -455,17 +554,30 @@ class ExpertLayer(nn.Module):
             p.reshape(b * t, -1), *self.experts(),
             offset=cfg.expert_offset, rows=rows)
         with jax.named_scope("router"):
-            sizes = group_sizes(
-                held_key(idx.reshape(b * t, -1), cfg.expert_offset,
-                         cfg.experts_held), cfg.experts_held
-            ).astype(jnp.float32)
+            key = held_key(idx.reshape(b * t, -1), cfg.expert_offset,
+                           cfg.experts_held)
+            counts = group_sizes(key, cfg.experts_held)
+            sizes = counts.astype(jnp.float32)
             here = jnp.sum(sizes)
             sorted_ = grouped_kernels_why_not(d, cfg.expert_width) is None
+            dense = (here > rows) | (not sorted_)
+            # runs of the sorted lowering's token-major kernel that pass
+            # their first window (none where the sums are gathered or
+            # the call is dense)
+            spills = 0.0
+            if sorted_ and runs_why_not(b * t, cfg.experts_per_token,
+                                        cfg.experts_held, d,
+                                        m.dtype) is None:
+                first_row, _ = grouped.tile_plan(
+                    counts, _buffer_tiles(rows, cfg.experts_held))
+                spills = jnp.where(dense, 0, token_sum.spills(_run_starts(
+                    key.reshape(b * t, -1), first_row))).astype(jnp.float32)
             counters = {
                 "here": here / (b * t * cfg.experts_per_token),
                 "load": jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9),
-                "dense": ((here > rows) | (not sorted_)).astype(jnp.float32),
-                "dropped": here - computed}
+                "dense": dense.astype(jnp.float32),
+                "dropped": here - computed,
+                "spills": spills}
         return y.reshape(b, t, d).astype(m.dtype), counters
 
 
@@ -603,6 +715,10 @@ class SparseLM(nn.Module):
             # expert layers of this micro-batch whose assignments did not
             # fit the dispatch buffer and took the dense lowering
             "moe_dense_calls": jnp.sum(stack("dense")),
+            # runs of rows (a token tile's in a held expert's group) that
+            # passed the token-major kernel's first window and cost their
+            # tile a further round, both sums of a layer alike
+            "moe_sum_spills": jnp.sum(stack("spills")),
         }
         return aux["loss"], aux
 
@@ -638,6 +754,13 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     windows = sum(k == LAYER_WINDOW_ROPE for k in kinds)
     first, last = cfg.expert_offset, cfg.expert_offset + cfg.experts_held - 1
     devices = mesh.size if mesh is not None else 1
+    why_not, tile = _SUM_LOWERINGS.get(
+        (cfg.experts_per_token, cfg.experts_held, cfg.hidden_size,
+         jnp.dtype(cfg.dtype).name), ("", 0))
+    sums = ("none traced (the dense lowering)" if not tile else
+            f"one gather a slot ({why_not})" if why_not else
+            f"runs of rows, {tile} tokens a tile, windows of "
+            f"{token_sum.WINDOW} rows")
     return {
         "attn_layout": (
             f"blockwise {kernels.BLOCK}: {on} of {len(kinds)} layers, "
@@ -650,9 +773,10 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
             f"{cfg.experts_held} of {cfg.num_experts} experts held "
             f"({first}-{last}), top {cfg.experts_per_token} of "
             f"{cfg.num_experts}, softmax over the chosen, no exchange: "
-            f"{'one device' if devices == 1 else f'{devices} devices, data parallel'}"),
+            f"{'one device' if devices == 1 else f'{devices} devices, data parallel'}"
+            f"; token-major sums: {sums}"),
     }
 
 
 STEP_ATTRIBUTES = ("moe_assignments_here_pct", "moe_load_max_over_mean",
-                   "moe_dropped", "moe_dense_calls")
+                   "moe_dropped", "moe_dense_calls", "moe_sum_spills")

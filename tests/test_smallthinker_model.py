@@ -15,6 +15,7 @@ from benchmark.manifest import Manifest
 from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
 from dalle_tpu.config import ModelConfig, SparseLMConfig
 from dalle_tpu.models import attention, family, sparse_lm
+from dalle_tpu.ops.pallas import token_sum_kernels as token_sum
 
 Y = Manifest().yardstick("smallthinker")
 
@@ -195,6 +196,146 @@ def test_no_token_is_dropped_when_the_router_sends_everything_to_one_expert(
     assert float(jnp.min(jnp.linalg.norm(y, axis=1))) > 0
 
 
+def _uniform(rng, n, k, experts):
+    return np.argsort(rng.normal(size=(n, experts)), axis=1)[:, :k]
+
+
+def _one_expert_a_tile(rng, n, k, experts):
+    """Every token's first choice is held expert 3: each tile's run in
+    its group is the whole tile."""
+    idx = _uniform(rng, n, k, experts)
+    return np.concatenate([np.full((n, 1), 3), np.where(
+        idx[:, 1:] == 3, 7, idx[:, 1:])], axis=1)
+
+
+def _a_tile_and_an_expert_with_none(rng, n, k, experts):
+    """Held expert 4 is never chosen; tokens 256..511 choose only experts
+    that live elsewhere (0, 1, 6, 7 of 8 with 2..5 held)."""
+    idx = _uniform(rng, n, k, experts)
+    idx = np.where(idx == 4, (idx + 1 + np.arange(k)) % experts, idx)
+    idx[256:512] = np.asarray([0, 1, 6, 7])[_uniform(rng, 256, k, 4)]
+    # the repair may have named an expert twice: a token names one once
+    twice = np.asarray([len(set(row)) < k for row in idx])
+    idx[twice] = np.asarray([2, 3, 5, 0])[:k]
+    return idx
+
+
+SUMS = {
+    # name: (tokens, router, dtype, NaN in the rows no tile wrote)
+    "uniform": (1024, _uniform, "float32", False),
+    "a_whole_tile_on_one_expert": (512, _one_expert_a_tile, "float32",
+                                   False),
+    "an_expert_and_a_tile_with_none": (768, _a_tile_and_an_expert_with_none,
+                                       "float32", False),
+    "tokens_no_multiple_of_the_tile": (1000, _uniform, "float32", False),
+    "unwritten_rows_hold_nan": (700, _uniform, "bfloat16", True),
+}
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["dispatch", "combine"])
+@pytest.mark.parametrize("case", list(SUMS))
+def test_the_kernel_over_runs_against_one_gather_a_slot(case, weighted,
+                                                        monkeypatch):
+    """The token-major sums of the sorted lowering, on one plan by both
+    lowerings: the Pallas kernel over the runs a token tile owns in every
+    held expert's group (interpreted) and one gather of N rows a slot.
+    Without weights (dispatch's backward) and with (combine's forward);
+    only the order of a token's additions may differ."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    n, router, dtype, nan = SUMS[case]
+    k, experts, held, offset, d = 2, 8, 4, 2, 64
+    rng = np.random.default_rng(11)
+    idx = jnp.asarray(router(rng, n, k, experts), jnp.int32)
+    assert all(len(set(row)) == k for row in np.asarray(idx))
+    p = jax.nn.softmax(jnp.asarray(rng.normal(size=(n, k)), jnp.float32), -1)
+    plan = sparse_lm.dispatch_plan(idx, offset, held, n * k)
+    rows = jnp.asarray(rng.normal(size=(plan.token.shape[0], d)), dtype)
+    written = int(plan.written)
+    assert written < rows.shape[0]       # some tile holds no group
+    if nan:
+        rows = rows.at[written:].set(jnp.nan)
+    assert sparse_lm.runs_why_not(n, k, held, d, dtype) is None
+    weight = p if weighted else None
+    want = sparse_lm._sum_over_slots(rows, plan, weight)
+    got = jax.jit(sparse_lm._sum_to_tokens)(rows, plan, weight)
+    assert got.shape == (n, d) and got.dtype == jnp.float32
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    if not weighted and dtype == "float32":
+        np.testing.assert_array_equal(got, want)    # two addends: no order
+    # what the cases are for
+    runs = np.diff(np.asarray(plan.start), axis=0)
+    spills = int(token_sum.spills(plan.start))
+    if case == "a_whole_tile_on_one_expert":
+        assert (runs[:, 1] == 256).all() and spills >= 2
+        assert float(jnp.min(jnp.linalg.norm(got, axis=1))) > 0
+    if case == "an_expert_and_a_tile_with_none":
+        assert (runs[:, 2] == 0).all() and (runs[1] == 0).all()
+        assert float(jnp.max(jnp.abs(got[256:512]))) == 0.0
+    if case == "tokens_no_multiple_of_the_tile":
+        assert plan.start.shape == (5, held)
+    # and in the rows' own dtype, as dispatch's backward asks for it
+    low = jax.jit(lambda *a: sparse_lm._sum_to_tokens(*a, jnp.bfloat16))(
+        rows, plan, weight)
+    np.testing.assert_array_equal(low, got.astype(jnp.bfloat16))
+
+
+def test_a_weights_pieces_add_up_to_it_exactly():
+    """Three bf16 numbers for an f32 routing weight (under jit, where a
+    rounding that is converted back may be dropped); the weight itself for
+    f32 rows."""
+    w = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(0), (64, 6))
+                       * 4, -1)
+    w = jnp.concatenate([w, jnp.asarray([[1.0, 0.0, 1e-30, 1 / 3, 0.999999,
+                                          2.0 ** -100]])])
+    pieces = jax.jit(lambda w: token_sum.weight_pieces(w, jnp.bfloat16))(w)
+    assert pieces.shape == (65, 18) and pieces.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        pieces.astype(jnp.bfloat16).astype(jnp.float32), pieces)
+    np.testing.assert_array_equal(
+        pieces[:, :6] + pieces[:, 6:12] + pieces[:, 12:], w)
+    assert token_sum.weight_pieces(w, jnp.float32) is w
+
+
+def test_which_lowering_a_token_major_sum_takes_is_read_off_its_shapes(
+        monkeypatch):
+    """The kernel over runs while the held experts stay under about ten a
+    slot and their windows fit VMEM; one gather a slot otherwise. The
+    cell's layout takes the kernel; the crossing lies past what fits."""
+    why_not = sparse_lm.runs_why_not
+    assert why_not(16384, 6, 8, 2560, "bfloat16") is None     # the cell
+    assert why_not(16384, 6, 16, 2560, "bfloat16") is None
+    assert "VMEM" in why_not(16384, 6, 32, 2560, "bfloat16")
+    assert "VMEM" in why_not(16384, 6, 64, 2560, "bfloat16")  # all held
+    assert why_not(16384, 6, 64, 256, "bfloat16") == (
+        "4096 windows cost more than 6 gathers of 16384 rows")
+    assert why_not(16384, 6, 60, 256, "bfloat16") is None     # the crossing
+    assert why_not(16384, 1, 16, 2560, "bfloat16") == (
+        "1024 windows cost more than 1 gathers of 16384 rows")
+    assert why_not(16384, 1, 8, 2560, "bfloat16") is None
+    assert "lane tiles" in why_not(16384, 6, 7, 2560, "bfloat16")
+    assert why_not(16384, 6, 8, 2560, "float16") == "rows in float16"
+    # the setup/warmup row says what the last traced sum of the
+    # configuration's shapes took
+    cfg = SparseLMConfig(**dict(TINY, hidden_size=192, dtype="bfloat16"))
+    layout = lambda: sparse_lm.engagement_records(cfg)["moe_layout"]
+    assert layout().endswith("token-major sums: none traced (the dense "
+                             "lowering)")
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    idx = jnp.asarray(np.argsort(np.random.default_rng(0).normal(
+        size=(56, 8)), axis=1)[:, :2], jnp.int32)
+    plan = sparse_lm.dispatch_plan(idx, 2, 4, 112)
+    rows = jnp.ones((plan.token.shape[0], 192), jnp.bfloat16)
+    sparse_lm._sum_to_tokens(rows, plan)
+    assert layout().endswith("one device; token-major sums: runs of rows, "
+                             "56 tokens a tile, windows of 64 rows")
+    monkeypatch.setattr(sparse_lm, "WINDOW_NS", 1e6)
+    sparse_lm._sum_to_tokens(rows, plan)
+    assert layout().endswith("token-major sums: one gather a slot (4 windows "
+                             "cost more than 2 gathers of 56 rows)")
+    sparse_lm._SUM_LOWERINGS.pop((2, 4, 192, "bfloat16"))
+
 TINY_FLAGS = [
     "--hidden-size", "64", "--num-hidden-layers", "4", "--num-heads", "4",
     "--num-kv-heads", "2", "--head-dim", "16", "--expert-width", "32",
@@ -244,6 +385,7 @@ def test_the_preset_trains_through_the_peers_normal_path():
         # no Mosaic backend here: the dense lowering in every layer of
         # every shard, and said so
         assert row["moe_dense_calls"] == 4.0 * task.mesh.size
+        assert row["moe_sum_spills"] == 0.0      # a dense call has no runs
     # the optimizer was told the expert axis by the configuration
     assert task.model_cfg.optimizer_stacking()["stacked_experts"] == 4
 
