@@ -23,11 +23,12 @@ import logging
 import sys
 from typing import Optional, Sequence
 
-from dalle_tpu.config import (CollabConfig, ModelConfig, OptimizerConfig,
-                              PeerConfig, SparseLMConfig, TrainerConfig,
-                              flagship_model_config,
+from dalle_tpu.config import (AfmoeLMConfig, CollabConfig, ModelConfig,
+                              OptimizerConfig, PeerConfig, SparseLMConfig,
+                              TrainerConfig, flagship_model_config,
                               smallthinker21b_model_config,
-                              tiny_model_config, xl_model_config)
+                              tiny_model_config, trinitymini_model_config,
+                              xl_model_config)
 from dalle_tpu.cli._args import (add_dataclass_args, check_no_collisions,
                                  dataclass_from_args)
 
@@ -43,13 +44,16 @@ MODEL_PRESETS = {
     # SmallThinker-21BA3B-Instruct cut to one chip's share of a layer
     # (a dataclass of its own): the cell smallthinker21b-train-solo
     "smallthinker21b": smallthinker21b_model_config,
+    # Trinity-Mini cut to one of 16 chips' share of a layer (a subclass
+    # that states its mechanisms as fields): trinitymini-train-solo
+    "trinitymini": trinitymini_model_config,
 }
 
 CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
                   PeerConfig)
 # Every architecture's configuration class. A preset builds one of them;
 # a field two of them share (vocab_text, dtype, ...) is one flag.
-MODEL_CLASSES = (ModelConfig, SparseLMConfig)
+MODEL_CLASSES = (ModelConfig, SparseLMConfig, AfmoeLMConfig)
 
 
 def maybe_wandb_run(project: Optional[str], name: str):

@@ -751,6 +751,22 @@ class SparseLMConfig:
     vocab_image: int = 9496
 
     model_module: ClassVar[str] = "dalle_tpu.models.sparse_lm"
+    # What models/sparse_lm.py reads besides, fixed for this class and
+    # fields of ``AfmoeLMConfig``: class attributes here, so that
+    # ``asdict`` of this class (benchmark/configs/smallthinker21b.json
+    # holds it) keeps its keys.
+    num_dense_layers: ClassVar[int] = 0
+    dense_width: ClassVar[int] = 0
+    num_shared_experts: ClassVar[int] = 0
+    hidden_act: ClassVar[str] = "relu"
+    score_func: ClassVar[str] = "softmax"
+    selection_bias: ClassVar[bool] = False
+    route_norm: ClassVar[bool] = False
+    route_scale: ClassVar[float] = 1.0
+    attention_gate: ClassVar[bool] = False
+    qk_norm: ClassVar[bool] = False
+    sandwich_norms: ClassVar[bool] = False
+    mup_enabled: ClassVar[bool] = False
     # fields a configuration's file states and no entry point's flag sets:
     # what the source fixes and models/sparse_lm.py is written for
     # (``validate`` holds each to its one value), and the one assumption
@@ -776,7 +792,12 @@ class SparseLMConfig:
     def optimizer_stacking(self) -> Dict[str, int]:
         return {"stacked_reps": 0, "stacked_experts": self.experts_held}
 
-    def validate(self) -> None:
+    def layer_is_dense(self, layer: int) -> bool:
+        """Whether ``layer``'s feed-forward is the dense gated block and
+        not the expert layer (the leading ``num_dense_layers``)."""
+        return layer < self.num_dense_layers
+
+    def validate_shapes(self) -> None:
         for kind in self.layer_kinds:
             if kind not in VALID_LAYER_KINDS:
                 raise ValueError(f"unknown layer kind {kind!r}")
@@ -792,6 +813,9 @@ class SparseLMConfig:
                 f"not among the router's {self.num_experts}")
         if self.experts_per_token > self.num_experts:
             raise ValueError("experts_per_token exceeds num_experts")
+
+    def validate(self) -> None:
+        self.validate_shapes()
         if self.router_input != "input_norm":
             raise ValueError(
                 f"router_input {self.router_input!r}: the router reads the "
@@ -807,6 +831,107 @@ def smallthinker21b_model_config(**overrides: Any) -> SparseLMConfig:
     """Preset ``smallthinker21b``: the cell ``smallthinker21b-train-solo``
     (benchmark/configs/smallthinker21b.json holds ``asdict`` of it)."""
     return dataclasses.replace(SparseLMConfig(), **overrides)
+
+
+@dataclass(frozen=True)
+class AfmoeLMConfig(SparseLMConfig):
+    """``SparseLMConfig`` with the mechanisms of ``model_type`` ``afmoe``
+    as fields (the same ``models/sparse_lm.py`` reads each): leading dense
+    gated layers before the expert layers, a shared expert every token
+    takes beside the routed ones, gated-SiLU blocks, a router that reads
+    the post-attention norm, scores by sigmoid, selects on score + a bias
+    that is no trained parameter and weighs by the unbiased scores of the
+    chosen (normalised, scaled), attention's output gated by
+    ``sigmoid(W_g a)`` over RMS-normed queries and keys, four norms a
+    layer, the embedding's output times sqrt(hidden_size). Defaults are
+    Trinity-Mini (arcee-ai, config.json) cut to the share one of the 16
+    chips of a layer holds: the first dense layer and one period of expert
+    layers (``layer_kinds`` names all five: published layers 0 and 4-7),
+    experts 0-7 of 128, an eighth of the vocabulary; every width as
+    published."""
+
+    hidden_size: int = 2048
+    num_hidden_layers: int = 5       # published 32: 2 dense + 30 expert
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    expert_width: int = 1024
+    num_experts: int = 128
+    experts_per_token: int = 8
+    vocab_size: int = 25024          # published 200192
+    window: int = 2048
+    layer_kinds: Tuple[str, ...] = (
+        LAYER_WINDOW_ROPE, LAYER_WINDOW_ROPE, LAYER_WINDOW_ROPE,
+        LAYER_WINDOW_ROPE, LAYER_FULL_NOPE)
+    rope_theta: float = 1e4
+    rms_eps: float = 1e-5
+    router_softmax_over_chosen: bool = False
+    router_input: str = "post_attention_norm"
+    # assumed, as the parent class's and for its reason: at 0.02 (the
+    # family's initializer range; 0.9 after ``mup_enabled``) the normed
+    # attention output, a prefix mean alike for a sequence's tokens,
+    # weighs as much as the token and an untrained router sends a whole
+    # sequence to few experts (PERF.md section 6, PR 33)
+    embed_init_std: float = 1.0
+    vocab_text: int = 12512
+    vocab_image: int = 12512
+    num_dense_layers: int = 1        # published 2
+    dense_width: int = 6144
+    num_shared_experts: int = 1      # of expert_width each
+    hidden_act: str = "silu"
+    score_func: str = "sigmoid"
+    selection_bias: bool = True
+    route_norm: bool = True
+    route_scale: float = 2.826
+    attention_gate: bool = True
+    qk_norm: bool = True
+    sandwich_norms: bool = True
+    mup_enabled: bool = True
+
+    no_flag: ClassVar[Tuple[str, ...]] = SparseLMConfig.no_flag + (
+        "num_shared_experts", "hidden_act", "score_func", "selection_bias",
+        "route_norm", "route_scale", "attention_gate", "qk_norm",
+        "sandwich_norms", "mup_enabled")
+    decode_missing: ClassVar[Optional[str]] = (
+        "models/decode.py has no grouped key-value heads, no cache per "
+        "layer kind (full / window), no gated attention over normed "
+        "queries and keys, no dense gated block and no expert layer with "
+        "a shared expert")
+
+    def validate(self) -> None:
+        self.validate_shapes()
+        if not 0 <= self.num_dense_layers < self.num_hidden_layers:
+            raise ValueError(
+                "num_dense_layers must leave an expert layer (the step's "
+                "counters are the expert layers')")
+        if self.num_dense_layers and self.dense_width <= 0:
+            raise ValueError("dense layers need a dense_width")
+        if self.hidden_act not in ("relu", "silu"):
+            raise ValueError(f"unknown hidden_act {self.hidden_act!r}")
+        if self.router_input != "post_attention_norm":
+            raise ValueError(
+                f"router_input {self.router_input!r}: the router reads the "
+                "norm the experts read ('post_attention_norm')")
+        if self.tied_embeddings or self.attention_bias:
+            raise ValueError(
+                "models/sparse_lm.py has an untied head and no attention "
+                "bias")
+        softmax = self.score_func == "softmax"
+        if self.score_func not in ("softmax", "sigmoid") \
+                or softmax != self.router_softmax_over_chosen:
+            raise ValueError(
+                "score_func is 'softmax' (over the chosen: "
+                "router_softmax_over_chosen) or 'sigmoid' (not)")
+        if softmax and (self.selection_bias or self.route_norm
+                        or self.route_scale != 1.0):
+            raise ValueError(
+                "a selection bias, route_norm and route_scale belong to "
+                "the sigmoid router")
+
+
+def trinitymini_model_config(**overrides: Any) -> AfmoeLMConfig:
+    """Preset ``trinitymini``: the cell ``trinitymini-train-solo``
+    (benchmark/configs/trinitymini.json holds ``asdict`` of it)."""
+    return dataclasses.replace(AfmoeLMConfig(), **overrides)
 
 
 def tiny_model_config(**overrides: Any) -> ModelConfig:

@@ -1,7 +1,9 @@
 """A sparse decoder-only language model on the trainer's normal path.
 
-What ``SparseLMConfig`` describes (its defaults: SmallThinker-21BA3B-
-Instruct, PowerInfer): every layer is
+Two configurations' equations, each mechanism read from a field of the
+configuration and none from a preset's name. What ``SparseLMConfig``
+describes (its defaults: SmallThinker-21BA3B-Instruct, PowerInfer): every
+layer is
 
     a   = rmsnorm(x)
     r   = a . W_r                        the router, in f32, BEFORE attention
@@ -14,6 +16,29 @@ Instruct, PowerInfer): every layer is
     out = h + sum_{e in S, e held here} p_e . W_down,e(relu(W_gate,e m) * W_up,e m)
 
 then a final RMSNorm, an untied head and next-token cross-entropy.
+
+What ``AfmoeLMConfig`` describes (its defaults: Trinity-Mini, arcee-ai,
+``model_type`` ``afmoe``), with x0 = E[ids] * sqrt(hidden) (``mup_enabled``):
+
+    a     = rmsnorm(x)
+    q,k,v,g = a.W_q, a.W_k, a.W_v, a.W_g   ``attention_gate``: no bias
+    q,k   = rmsnorm(q), rmsnorm(k)         ``qk_norm``: over each head's
+                                           head_dim, before rotary
+    h     = x + rmsnorm((attention(q,k,v) * sigmoid(g)) . W_o)
+    m     = rmsnorm(h)                     ``sandwich_norms``: both results
+                                           are normed before they join x
+    dense layer (the leading ``num_dense_layers``):
+      f   = W_down(silu(W_gate m) * W_up m)            width ``dense_width``
+    expert layer:
+      s   = sigmoid(m . W_r)               f32; the router reads m
+      S   = the k largest of s + b         b: ``router_bias``, zeros, a leaf
+                                           no gradient reaches
+      p_e = route_scale * s_e / (sum_S s + 1e-20)
+      f   = shared(m) + sum_{e in S, e held here} p_e . expert_e(m)
+    out   = h + rmsnorm(f)
+
+(:class:`GatedBlock` is the dense layer's block and the shared expert, which
+every token takes and every rank computes alike.)
 
 **The expert layer is told which experts it holds** (``experts_held``
 consecutive ones from ``expert_offset``): it routes over all
@@ -59,7 +84,10 @@ parameters.
 Device scopes (``jax.named_scope`` and module names; the benchmark's
 ``*_share_pct`` metrics read them): ``embed``, ``attn`` (projections,
 rotary, kernel), ``rms_norm``, ``ff/router``, ``ff/dispatch``,
-``ff/experts``, ``ff/combine``, ``head``, ``ce``. The token-major kernel
+``ff/experts``, ``ff/combine``, ``head``, ``ce``; where the configuration
+has them ``ff/shared`` (the shared expert), ``ff/dense`` (a dense layer's
+block), ``attn/gate`` (the ``W_g`` product, the sigmoid and the multiply),
+``attn/qk_norm``. The token-major kernel
 (``token_major_sum[mosaic]`` in a trace) runs under the scope of its sum,
 ``ff/combine`` or ``ff/dispatch``; the grouped products under
 ``ff/experts``.
@@ -156,6 +184,14 @@ class Attention(nn.Module):
         q = dense(cfg.num_heads * cfg.head_dim, name="q")(a)
         k = dense(cfg.num_kv_heads * cfg.head_dim, name="k")(a)
         v = dense(cfg.num_kv_heads * cfg.head_dim, name="v")(a)
+        if cfg.qk_norm:
+            # over each head's head_dim, one scale vector for all heads
+            with jax.named_scope("qk_norm"):
+                q, k = (rms_norm(
+                    x.reshape(*x.shape[:2], -1, cfg.head_dim),
+                    self.param(name, nn.initializers.ones, (cfg.head_dim,),
+                               pdt), cfg.rms_eps).reshape(x.shape)
+                    for x, name in ((q, "q_norm"), (k, "k_norm")))
         window = None
         if self.kind == LAYER_WINDOW_ROPE:
             window = cfg.window
@@ -172,6 +208,10 @@ class Attention(nn.Module):
                             LANES_SPEC, scope=self.name)(q, k, v)
         else:
             ctx = dense_causal_attention(q, k, v, window, cfg.head_dim)
+        if cfg.attention_gate:
+            g = dense(cfg.num_heads * cfg.head_dim, name="gate")(a)
+            with jax.named_scope("gate"):
+                ctx = ctx * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dt)
         return dense(cfg.hidden_size, name="out")(ctx)
 
 
@@ -367,6 +407,23 @@ def _grouped_dots(plan: _Plan):
             functools.partial(grouped.grouped_matmul_grads, **kw))
 
 
+def _act(name: str, g: jax.Array) -> jax.Array:
+    """The gate's activation (``cfg.hidden_act``), in g's dtype."""
+    if name == "relu":
+        return jax.nn.relu(g)
+    return jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype)
+
+
+def _gate_cotangent(name: str, g: jax.Array, u: jax.Array,
+                    dhidden: jax.Array) -> jax.Array:
+    """The cotangent of ``g`` in ``hidden = act(g) * u``."""
+    if name == "relu":
+        return jnp.where(g > 0, dhidden * u, 0)
+    g32 = g.astype(jnp.float32)
+    sig = jax.nn.sigmoid(g32)
+    return (dhidden * u * (sig * (1.0 + g32 * (1.0 - sig)))).astype(g.dtype)
+
+
 class _Kept(NamedTuple):
     """What the sorted lowering keeps for its backward pass."""
     plan: _Plan
@@ -376,7 +433,8 @@ class _Kept(NamedTuple):
     ys: jax.Array         # (rows, D) the experts' outputs
 
 
-def _sorted_forward(m, idx, p, gate, up, down, *, offset: int, rows: int):
+def _sorted_forward(m, idx, p, gate, up, down, *, offset: int, rows: int,
+                    act: str):
     """The held experts' part of the layer for assignments sorted by
     expert. m: (N, D); idx, p: (N, k); gate, up: (E_h, D, F); down:
     (E_h, F, D). Returns (((N, D) f32, assignments computed), _Kept)."""
@@ -386,14 +444,14 @@ def _sorted_forward(m, idx, p, gate, up, down, *, offset: int, rows: int):
     with jax.named_scope("experts"):
         dot, _ = _grouped_dots(plan)
         g, u = dot(xs, gate), dot(xs, up)
-        ys = dot(jax.nn.relu(g) * u, down)
+        ys = dot(_act(act, g) * u, down)
     with jax.named_scope("combine"):
         y = _sum_to_tokens(ys, plan, p)
     return ((y, jnp.sum(plan.valid, dtype=jnp.float32)),
             _Kept(plan, xs, g, u, ys))
 
 
-def _sorted_backward(kept: _Kept, p, gate, up, down, dy):
+def _sorted_backward(kept: _Kept, p, gate, up, down, dy, act: str):
     """Cotangents of (m, p, gate, up, down) from what the forward kept:
     no product and no gather of the forward pass is run again."""
     plan, xs, g, u, ys = kept
@@ -409,16 +467,17 @@ def _sorted_backward(kept: _Kept, p, gate, up, down, dy):
         dp = jnp.where(plan.here, score[plan.row], 0.0)
     with jax.named_scope("experts"):
         _, grads = _grouped_dots(plan)
-        act = jax.nn.relu(g)
-        dhidden, ddown = grads(act * u, down, dys)
-        dxs_gate, dgate = grads(xs, gate, jnp.where(g > 0, dhidden * u, 0))
-        dxs_up, dup = grads(xs, up, dhidden * act)
+        hidden = _act(act, g)
+        dhidden, ddown = grads(hidden * u, down, dys)
+        dxs_gate, dgate = grads(xs, gate,
+                                _gate_cotangent(act, g, u, dhidden))
+        dxs_up, dup = grads(xs, up, dhidden * hidden)
     with jax.named_scope("dispatch"):
         dm = _sum_to_tokens(dxs_gate + dxs_up, plan, dtype=xs.dtype)
     return dm, dp, dgate, dup, ddown
 
 
-def _every_expert(m, idx, p, gate, up, down, *, offset: int):
+def _every_expert(m, idx, p, gate, up, down, *, offset: int, act: str):
     """The same sum with no dispatch: every held expert on every token,
     times its routing weight (0 for a token not routed to it)."""
     held = gate.shape[0]
@@ -428,7 +487,7 @@ def _every_expert(m, idx, p, gate, up, down, *, offset: int):
         with jax.named_scope("combine"):
             weight = jnp.sum(jnp.where(idx == e + offset, p, 0.0), axis=1)
         with jax.named_scope("experts"):
-            out = jnp.dot(jax.nn.relu(jnp.dot(m, w_gate))
+            out = jnp.dot(_act(act, jnp.dot(m, w_gate))
                           * jnp.dot(m, w_up), w_down)
         with jax.named_scope("combine"):
             return y + out.astype(jnp.float32) * weight[:, None], None
@@ -439,12 +498,13 @@ def _every_expert(m, idx, p, gate, up, down, *, offset: int):
     return y, here
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_experts(offset, rows, m, idx, p, gate, up, down):
-    return _held_experts_fwd(offset, rows, m, idx, p, gate, up, down)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_experts(offset, rows, act, m, idx, p, gate, up, down):
+    return _held_experts_fwd(offset, rows, act, m, idx, p, gate, up,
+                             down)[0]
 
 
-def _held_experts_fwd(offset, rows, m, idx, p, gate, up, down):
+def _held_experts_fwd(offset, rows, act, m, idx, p, gate, up, down):
     # One conditional a direction, written out: lax.cond's own derivative
     # would return both lowerings' residuals from the forward conditional
     # and run neither backward without them. Here the sorted lowering
@@ -452,11 +512,12 @@ def _held_experts_fwd(offset, rows, m, idx, p, gate, up, down):
     # step's assignments do not fit, hands back zeros of those shapes and
     # is computed again in the backward pass.
     operands = (m, idx, p, gate, up, down)
-    sorted_ = functools.partial(_sorted_forward, offset=offset, rows=rows)
+    sorted_ = functools.partial(_sorted_forward, offset=offset, rows=rows,
+                                act=act)
     like = jax.eval_shape(sorted_, *operands)[1]
 
     def dense(*operands):
-        return (_every_expert(*operands, offset=offset),
+        return (_every_expert(*operands, offset=offset, act=act),
                 jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), like))
 
     fits = jnp.sum(held_key(idx, offset, gate.shape[0])
@@ -465,17 +526,18 @@ def _held_experts_fwd(offset, rows, m, idx, p, gate, up, down):
     return out, (fits, kept, operands)
 
 
-def _held_experts_bwd(offset, rows, res, cotangent):
+def _held_experts_bwd(offset, rows, act, res, cotangent):
     fits, kept, (m, idx, p, gate, up, down) = res
     dy = cotangent[0]
 
     def dense(kept, m, p, gate, up, down, dy):
         _, vjp = jax.vjp(lambda m, p, *w: _every_expert(
-            m, idx, p, *w, offset=offset)[0], m, p, gate, up, down)
+            m, idx, p, *w, offset=offset, act=act)[0],
+            m, p, gate, up, down)
         return vjp(dy)
 
     def sorted_(kept, m, p, gate, up, down, dy):
-        return _sorted_backward(kept, p, gate, up, down, dy)
+        return _sorted_backward(kept, p, gate, up, down, dy, act)
 
     dm, dp, *dw = jax.lax.cond(fits, sorted_, dense, kept, m, p, gate, up,
                                down, dy)
@@ -485,7 +547,8 @@ def _held_experts_bwd(offset, rows, res, cotangent):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def held_experts(m, idx, p, gate, up, down, *, offset: int, rows: int):
+def held_experts(m, idx, p, gate, up, down, *, offset: int, rows: int,
+                 act: str = "relu"):
     """The held experts' part of the layer: the sorted lowering where the
     step's assignments to held experts fit ``rows``, the dense one where
     they do not, chosen on the device by the count; the dense one alone
@@ -497,8 +560,9 @@ def held_experts(m, idx, p, gate, up, down, *, offset: int, rows: int):
         why_not or f"{rows} rows in tiles of {grouped.TILE}, "
         f"{gate.shape[0]} experts of {m.shape[1]} x {gate.shape[2]}")
     if why_not is not None:
-        return _every_expert(m, idx, p, gate, up, down, offset=offset)
-    return _held_experts(offset, rows, m, idx, p, gate, up, down)
+        return _every_expert(m, idx, p, gate, up, down, offset=offset,
+                             act=act)
+    return _held_experts(offset, rows, act, m, idx, p, gate, up, down)
 
 
 class ExpertWeights(nn.Module):
@@ -520,19 +584,61 @@ class ExpertWeights(nn.Module):
                                          ("down", (e, f, d))))
 
 
+class GatedBlock(nn.Module):
+    """``W_down(act(W_gate m) * W_up m)`` of one width on every token: a
+    dense layer's feed-forward and an expert layer's shared expert.
+    Ordinary leaves ``.../{gate,up,down}/kernel``."""
+    cfg: SparseLMConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, m: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=jnp.dtype(cfg.dtype),
+            param_dtype=jnp.dtype(cfg.param_dtype))
+        hidden = _act(cfg.hidden_act, dense(self.width, name="gate")(m)) \
+            * dense(self.width, name="up")(m)
+        return dense(cfg.hidden_size, name="down")(hidden)
+
+
+class DenseFF(nn.Module):
+    """A dense layer's feed-forward, under the scope ``ff/dense``."""
+    cfg: SparseLMConfig
+
+    @nn.compact
+    def __call__(self, m: jax.Array) -> jax.Array:
+        return GatedBlock(self.cfg, self.cfg.dense_width, name="dense")(m)
+
+
 class ExpertLayer(nn.Module):
     cfg: SparseLMConfig
 
     def setup(self):
         cfg = self.cfg
+        pdt = jnp.dtype(cfg.param_dtype)
         self.router = self.param(
             "router", nn.initializers.lecun_normal(),
-            (cfg.hidden_size, cfg.num_experts), jnp.dtype(cfg.param_dtype))
+            (cfg.hidden_size, cfg.num_experts), pdt)
+        if cfg.selection_bias:
+            # moves which experts are chosen, not their weights; no
+            # gradient reaches it (the source steps it by the sign of each
+            # expert's token count: not in this program yet)
+            self.router_bias = self.param(
+                "router_bias", nn.initializers.zeros, (cfg.num_experts,),
+                pdt)
         self.experts = ExpertWeights(cfg)
+        if cfg.num_shared_experts:
+            self.shared = GatedBlock(
+                cfg, cfg.num_shared_experts * cfg.expert_width)
 
     def route(self, a: jax.Array):
-        """Top-k of the router's f32 scores of the normed layer input:
-        (B, T, k) expert ids and softmax weights over the chosen."""
+        """Top-k of the router's f32 scores of its normed input (the
+        layer's, or the post-attention one: ``cfg.router_input``): (B, T,
+        k) expert ids and weights. ``softmax``: the largest scores and
+        their softmax; ``sigmoid``: the largest of sigmoid(score) + bias,
+        weighted by their sigmoids alone, normalised (``route_norm``) and
+        times ``route_scale``."""
         # flax names this call's scope ``ff.route``: the router's
         # operations are put under ``ff/router`` by hand, beside the rest
         # of the layer's
@@ -542,8 +648,20 @@ class ExpertLayer(nn.Module):
                 self.router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32)
-            top, idx = jax.lax.top_k(scores, self.cfg.experts_per_token)
-            return idx, jax.nn.softmax(top, axis=-1)
+            cfg = self.cfg
+            if cfg.score_func == "softmax":
+                top, idx = jax.lax.top_k(scores, cfg.experts_per_token)
+                return idx, jax.nn.softmax(top, axis=-1)
+            scores = jax.nn.sigmoid(scores)
+            select = scores
+            if cfg.selection_bias:
+                select = scores + jax.lax.stop_gradient(
+                    self.router_bias.astype(jnp.float32))
+            _, idx = jax.lax.top_k(select, cfg.experts_per_token)
+            top = jnp.take_along_axis(scores, idx, axis=-1)
+            if cfg.route_norm:
+                top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+            return idx, top * cfg.route_scale
 
     def __call__(self, m: jax.Array, idx: jax.Array, p: jax.Array):
         cfg = self.cfg
@@ -552,7 +670,7 @@ class ExpertLayer(nn.Module):
         y, computed = held_experts(
             m.reshape(b * t, d), idx.reshape(b * t, -1),
             p.reshape(b * t, -1), *self.experts(),
-            offset=cfg.expert_offset, rows=rows)
+            offset=cfg.expert_offset, rows=rows, act=cfg.hidden_act)
         with jax.named_scope("router"):
             key = held_key(idx.reshape(b * t, -1), cfg.expert_offset,
                            cfg.experts_held)
@@ -578,13 +696,20 @@ class ExpertLayer(nn.Module):
                 "dense": dense.astype(jnp.float32),
                 "dropped": here - computed,
                 "spills": spills}
+        if cfg.num_shared_experts:
+            y = y + self.shared(m).reshape(b * t, d)
         return y.reshape(b, t, d).astype(m.dtype), counters
 
 
 class Layer(nn.Module):
+    """One layer; ``dense``: its feed-forward is the dense gated block
+    (no router, no counters). With ``cfg.sandwich_norms`` the attention's
+    and the feed-forward's results are normed before they join the
+    residual: four norms a layer."""
     cfg: SparseLMConfig
     kind: str
     mesh: Any = None
+    dense: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array):
@@ -592,14 +717,27 @@ class Layer(nn.Module):
         norm = lambda name, v: rms_norm(
             v, self.param(name, nn.initializers.ones, (cfg.hidden_size,),
                           jnp.dtype(cfg.param_dtype)), cfg.rms_eps)
-        ff = ExpertLayer(cfg, name="ff")
+        ff = (DenseFF if self.dense else ExpertLayer)(cfg, name="ff")
+        early = not self.dense and cfg.router_input == "input_norm"
         a = norm("attn_norm", x)
-        idx, p = ff.route(a)
-        # for whoever asks (apply(..., mutable=["intermediates"])): the
-        # experts every token chose; nothing is kept otherwise
-        self.sow("intermediates", "chosen", idx)
-        h = x + Attention(cfg, self.kind, self.mesh, name="attn")(a)
-        y, counters = ff(norm("ff_norm", h), idx, p)
+        if early:
+            idx, p = ff.route(a)
+        y = Attention(cfg, self.kind, self.mesh, name="attn")(a)
+        if cfg.sandwich_norms:
+            y = norm("post_attn_norm", y)
+        h = x + y
+        m = norm("ff_norm", h)
+        if self.dense:
+            y, counters = ff(m), None
+        else:
+            if not early:
+                idx, p = ff.route(m)
+            # for whoever asks (apply(..., mutable=["intermediates"])):
+            # the experts every token chose; nothing is kept otherwise
+            self.sow("intermediates", "chosen", idx)
+            y, counters = ff(m, idx, p)
+        if cfg.sandwich_norms:
+            y = norm("post_ff_norm", y)
         return h + y, counters
 
 
@@ -657,7 +795,10 @@ class SparseLM(nn.Module):
         ids = jnp.concatenate(
             [text_tokens, image_tokens + cfg.vocab_text], axis=1)
         with jax.named_scope("embed"):
-            x = jnp.take(table, ids, axis=0).astype(dt)
+            x = jnp.take(table, ids, axis=0)
+            if cfg.mup_enabled:
+                x = x * cfg.hidden_size ** 0.5
+            x = x.astype(dt)
 
         # a layer keeps its input and its attention's output and row
         # statistics: the backward pass replays the projections and the
@@ -668,8 +809,9 @@ class SparseLM(nn.Module):
         counters = []
         for i in range(cfg.num_hidden_layers):
             x, c = layer_cls(cfg, cfg.kind_of_layer(i), self.mesh,
-                             name=f"layer_{i}")(x)
-            counters.append(c)
+                             cfg.layer_is_dense(i), name=f"layer_{i}")(x)
+            if c is not None:
+                counters.append(c)
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
                                    (cfg.hidden_size,), pdt), cfg.rms_eps)
         head = self.param("lm_head", nn.initializers.normal(stddev=0.02),
@@ -761,18 +903,32 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
             f"one gather a slot ({why_not})" if why_not else
             f"runs of rows, {tile} tokens a tile, windows of "
             f"{token_sum.WINDOW} rows")
+    # the router's kind, and what stands beside the routed experts
+    router = "softmax over the chosen"
+    if cfg.score_func == "sigmoid":
+        router = ("sigmoid" + ", bias" * cfg.selection_bias
+                  + ", norm" * cfg.route_norm + f", x{cfg.route_scale:g}")
+    beside = ""
+    if cfg.num_shared_experts:
+        beside += (f", a shared expert of "
+                   f"{cfg.num_shared_experts * cfg.expert_width}")
+    if cfg.num_dense_layers:
+        beside += (f", layers 0-{cfg.num_dense_layers - 1} dense "
+                   f"{cfg.dense_width}")
     return {
         "attn_layout": (
             f"blockwise {kernels.BLOCK}: {on} of {len(kinds)} layers, "
             f"{len(kinds) - windows} full no-rope + {windows} window "
             f"{cfg.window} rope, {cfg.num_heads // cfg.num_kv_heads} query "
-            f"heads a key-value head"),
+            f"heads a key-value head"
+            + ", normed queries and keys" * cfg.qk_norm
+            + ", gated output" * cfg.attention_gate),
         "layer_loop": (f"unrolled: {len(kinds)} layers, each "
                        "rematerialised but its attention"),
         "moe_layout": (
             f"{cfg.experts_held} of {cfg.num_experts} experts held "
             f"({first}-{last}), top {cfg.experts_per_token} of "
-            f"{cfg.num_experts}, softmax over the chosen, no exchange: "
+            f"{cfg.num_experts}, {router}{beside}, no exchange: "
             f"{'one device' if devices == 1 else f'{devices} devices, data parallel'}"
             f"; token-major sums: {sums}"),
     }

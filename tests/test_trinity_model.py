@@ -1,0 +1,377 @@
+"""``AfmoeLMConfig`` (preset ``trinitymini``) through models/sparse_lm.py at
+a tiny size, seeded random weights, f32: against the plain reference of its
+yardstick; one test a mechanism, which fails if the mechanism is left out;
+the shares of the expert layer with the shared expert counted once add up
+to the uncut layer; and the preset trains through the peer's normal path
+(run_trainer's parser, TrainingTask, train_loop)."""
+import dataclasses
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.manifest import Manifest
+from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
+from dalle_tpu.config import (AfmoeLMConfig, SparseLMConfig,
+                              trinitymini_model_config)
+from dalle_tpu.models import attention, family, sparse_lm
+
+Y = Manifest().yardstick("trinity")
+
+# a dense layer and one period: four window layers and a full one, a
+# sequence (32: no other test file's, the dispatchers' records are a
+# process's) longer than the window, half of the router's experts held
+TINY = dict(hidden_size=64, num_hidden_layers=5, num_heads=4, num_kv_heads=2,
+            head_dim=16, expert_width=32, num_experts=8, experts_per_token=2,
+            experts_held=4, expert_offset=2, vocab_size=96, window=8,
+            text_seq_len=16, image_grid=4, vocab_text=48, vocab_image=48,
+            dtype="float32", head_chunk=16, dense_width=96)
+
+
+def as_file(cfg):
+    return json.loads(json.dumps(dataclasses.asdict(cfg)))
+
+
+def _batch(cfg, seed=0, n=2):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.integers(2, cfg.vocab_text,
+                                     (n, cfg.text_seq_len)), jnp.int32),
+            jnp.asarray(rng.integers(0, cfg.vocab_image,
+                                     (n, cfg.image_seq_len)), jnp.int32))
+
+
+def rel_l2(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b),
+                                                      1e-30))
+
+
+def _params(cfg, seed=1):
+    """Seeded weights with every vector leaf (norm scales, the router's
+    bias) moved off its initial ones and zeros, so that each counts."""
+    params = sparse_lm.init_params(sparse_lm.build(cfg),
+                                   jax.random.PRNGKey(seed))
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 100), len(leaves))
+    return jax.tree.unflatten(tree, [
+        a + 0.1 * jax.random.normal(k, a.shape) if a.ndim == 1 else a
+        for a, k in zip(leaves, keys)])
+
+
+def _system(cfg, params, text, image):
+    model = sparse_lm.build(cfg)
+    return jax.jit(jax.value_and_grad(
+        lambda p: model.apply(p, text, image), has_aux=True))(params)
+
+
+@pytest.mark.parametrize("kernels", [False, True])
+def test_loss_and_every_gradient_leaf_against_the_yardstick(kernels,
+                                                            monkeypatch):
+    """The whole tiny model with every mechanism on; with ``kernels`` the
+    attention, the grouped products and the token-major sums run their
+    Pallas kernels, interpreted."""
+    cfg = AfmoeLMConfig(**dict(TINY, head_dim=128 if kernels else 16))
+    cfg.validate()
+    kinds = [cfg.kind_of_layer(i) for i in range(5)]
+    assert kinds == ["window_rope"] * 4 + ["full_nope"]
+    assert [cfg.layer_is_dense(i) for i in range(5)] == [True] + [False] * 4
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", kernels)
+    params = _params(cfg)
+    text, image = _batch(cfg)
+    (loss, aux), grads = _system(cfg, params, text, image)
+    ref_loss, ref_grads = Y.loss_and_grads(params, text, image, as_file(cfg))
+    assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree.leaves(ref_grads)):
+        assert rel_l2(g, r) < 2e-5, jax.tree_util.keystr(path)
+    layer = params["params"]["layer_1"]
+    assert set(layer) == {"attn", "attn_norm", "post_attn_norm", "ff",
+                          "ff_norm", "post_ff_norm"}          # four norms
+    assert set(layer["attn"]) == {"q", "k", "v", "gate", "out", "q_norm",
+                                  "k_norm"}
+    assert layer["attn"]["q_norm"].shape == (cfg.head_dim,)
+    assert set(layer["ff"]) == {"router", "router_bias", "experts",
+                                "shared"}
+    assert set(params["params"]["layer_0"]["ff"]) == {"dense"}
+    # no gradient reaches the router's bias, on either side: exact zeros
+    for i in range(1, 5):
+        for tree in (grads, ref_grads):
+            bias = tree["params"][f"layer_{i}"]["ff"]["router_bias"]
+            assert bias.shape == (8,) and not np.asarray(bias).any()
+    # counters of the four expert layers only
+    assert 0 < float(aux["moe_assignments_here_pct"]) < 100
+    assert float(aux["moe_dropped"]) == 0.0
+    assert float(aux["moe_dense_calls"]) == (0.0 if kernels else 4.0)
+
+
+# what each mechanism is when it is left out; for every one the reference
+# reads the same key of ``model``. (The results' norms take a token's scale
+# out again, so leaving ``route_norm`` out moves the loss by 2e-6 of it:
+# the router's own test below tells it, by the weights' sum.)
+LEFT_OUT = {
+    "the shared expert": dict(num_shared_experts=0),
+    "the attention's output gate": dict(attention_gate=False),
+    "the head norms of queries and keys": dict(qk_norm=False),
+    "the two norms of the results (four a layer)":
+        dict(sandwich_norms=False),
+    "the embedding's scale": dict(mup_enabled=False),
+    "the weights' normalisation": dict(route_norm=False),
+    "the weights' scale": dict(route_scale=1.0),
+    "rotary on sliding layers only":
+        dict(layer_kinds=("window_rope",) * 2),
+    "the gated SiLU": dict(hidden_act="relu"),
+}
+
+
+# a dense layer, then a full expert layer: every mechanism in two layers
+SMALL = dict(TINY, num_hidden_layers=2,
+             layer_kinds=("window_rope", "full_nope"))
+
+
+@pytest.fixture(scope="module")
+def with_everything():
+    cfg = AfmoeLMConfig(**SMALL)
+    params = _params(cfg)
+    text, image = _batch(cfg)
+    (loss, _), _ = _system(cfg, params, text, image)
+    return cfg, params, text, image, float(loss)
+
+
+@pytest.mark.parametrize("mechanism", list(LEFT_OUT))
+def test_a_mechanism_left_out_is_told(mechanism, with_everything):
+    """The system with every mechanism against the reference without this
+    one: they disagree. The system without it against the reference
+    without it: they agree, so both read the same key."""
+    cfg, params, text, image, loss = with_everything
+    without = dataclasses.replace(cfg, **LEFT_OUT[mechanism])
+    without.validate()
+    if mechanism == "the gated SiLU":
+        # the reference is written for SiLU only: the system's ReLU differs
+        (other, _), _ = _system(without, params, text, image)
+        assert abs(float(other) - loss) > 1e-5 * loss
+        return
+    lacking, _ = jax.jit(lambda p: Y.loss_fn(p, text, image,
+                                             as_file(without)))(params)
+    if mechanism != "the weights' normalisation":
+        assert abs(float(lacking) - loss) > 4e-6 * loss
+    params = _params(without)
+    (loss, _), grads = _system(without, params, text, image)
+    ref_loss, ref_grads = Y.loss_and_grads(params, text, image,
+                                           as_file(without))
+    assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
+    for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
+        assert rel_l2(g, r) < 2e-5
+
+
+def test_the_leading_dense_layer():
+    """With no dense layer, layer 0 is an expert layer with a router of
+    its own; the dense block is what the reference's dense layer is."""
+    cfg = AfmoeLMConfig(**TINY)
+    without = dataclasses.replace(cfg, num_dense_layers=0)
+    params = _params(without)
+    assert "router" in params["params"]["layer_0"]["ff"]
+    text, image = _batch(cfg)
+    (loss, aux), _ = _system(without, params, text, image)
+    ref_loss, _ = Y.loss_and_grads(params, text, image, as_file(without))
+    assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
+    assert float(aux["moe_dense_calls"]) == 5.0      # five expert layers
+    m = jax.random.normal(jax.random.PRNGKey(0), (2, 7, cfg.hidden_size))
+    block = sparse_lm.DenseFF(cfg)
+    w = block.init(jax.random.PRNGKey(1), m)
+    assert w["params"]["dense"]["gate"]["kernel"].shape == (64, 96)
+    np.testing.assert_allclose(
+        block.apply(w, m), Y.gated_block(m, w["params"]["dense"]),
+        atol=1e-6)
+    with pytest.raises(ValueError, match="leave an expert layer"):
+        dataclasses.replace(cfg, num_dense_layers=5).validate()
+
+
+def _router(cfg, m, bias=None):
+    layer = sparse_lm.ExpertLayer(cfg)
+    w = layer.init(jax.random.PRNGKey(7), m, method="route")
+    assert set(w["params"]) == {"router", "router_bias"}
+    if bias is not None:
+        w = {"params": dict(w["params"], router_bias=bias)}
+    idx, p = layer.apply(w, m, method="route")
+    return w["params"], np.asarray(idx), np.asarray(p)
+
+
+def test_a_bias_changes_the_chosen_set_and_not_the_weights():
+    """Selection is on sigmoid(score) + bias; the weights are the chosen
+    experts' sigmoids alone over their sum, times ``route_scale``: they
+    sum to ``route_scale`` whatever the bias, and a token whose set a bias
+    leaves unchanged keeps its weights."""
+    cfg = AfmoeLMConfig(**TINY)
+    m = jax.random.normal(jax.random.PRNGKey(3), (2, 64, cfg.hidden_size))
+    w, idx0, p0 = _router(cfg, m)
+    bias = 0.08 * jax.random.normal(jax.random.PRNGKey(4), (8,))
+    _, idx1, p1 = _router(cfg, m, bias)
+    np.testing.assert_allclose(p0.sum(-1), cfg.route_scale, rtol=1e-6)
+    np.testing.assert_allclose(p1.sum(-1), cfg.route_scale, rtol=1e-6)
+    by_expert = lambda idx, p: np.take_along_axis(p, np.argsort(idx, -1), -1)
+    same = (np.sort(idx0, -1) == np.sort(idx1, -1)).all(-1)
+    assert same.any() and (~same).any()     # some sets change, some stay
+    np.testing.assert_allclose(by_expert(idx0, p0)[same],
+                               by_expert(idx1, p1)[same], rtol=1e-6)
+    # the sets are the largest of score + bias, the weights unbiased
+    s = np.asarray(jax.nn.sigmoid(m @ w["router"]))
+    want = np.argsort(-(s + np.asarray(bias)), -1)[..., :2]
+    np.testing.assert_array_equal(np.sort(idx1, -1), np.sort(want, -1))
+    chosen = np.take_along_axis(s, idx1, -1)
+    np.testing.assert_allclose(
+        p1, cfg.route_scale * chosen / chosen.sum(-1, keepdims=True),
+        rtol=1e-5)
+    # and the reference's router is the same function
+    ref_idx, ref_p = Y.route(m, dict(w, router_bias=bias), as_file(cfg))
+    np.testing.assert_array_equal(np.sort(ref_idx, -1), np.sort(idx1, -1))
+    np.testing.assert_allclose(by_expert(np.asarray(ref_idx),
+                                         np.asarray(ref_p)),
+                               by_expert(idx1, p1), rtol=1e-5)
+    # a bias large enough puts its expert into every token's set
+    _, idx2, _ = _router(cfg, m, jnp.zeros((8,)).at[5].set(2.0))
+    assert (idx2 == 5).any(-1).all()
+
+
+@pytest.mark.parametrize("kernels", [False, True])
+def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
+        kernels, monkeypatch):
+    """8 experts over 4 shares of 2 (``expert_offset`` 0, 2, 4, 6): every
+    share's layer returns its routed part plus the shared expert, which
+    all compute alike; the routed parts summed plus the shared expert
+    counted once equal the reference's uncut layer."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", kernels)
+    base = AfmoeLMConfig(**dict(TINY, experts_held=2))
+    rng = jax.random.split(jax.random.PRNGKey(3), 9)
+    d, f = base.hidden_size, base.expert_width
+    m = jax.random.normal(rng[0], (2, 28, d))
+    kernel = lambda key, shape: {"kernel": jax.random.normal(key, shape)
+                                 * 0.2}
+    whole = {"router": jax.random.normal(rng[1], (d, 8)),
+             "router_bias": 0.05 * jax.random.normal(rng[2], (8,)),
+             "experts": {"gate": jax.random.normal(rng[3], (8, d, f)) * 0.2,
+                         "up": jax.random.normal(rng[4], (8, d, f)) * 0.2,
+                         "down": jax.random.normal(rng[5], (8, f, d)) * 0.2},
+             "shared": {"gate": kernel(rng[6], (d, f)),
+                        "up": kernel(rng[7], (d, f)),
+                        "down": kernel(rng[8], (f, d))}}
+    want = Y.whole_layer_experts(m, whole, as_file(base))
+    shared = Y.gated_block(m, whole["shared"])
+    assert float(jnp.abs(shared).max()) > 0.01
+
+    routed, here = jnp.zeros_like(m), 0.0
+    for share in range(4):
+        cfg = dataclasses.replace(base, expert_offset=2 * share)
+        layer = sparse_lm.ExpertLayer(cfg)
+        mine = {"params": dict(whole, experts={
+            k: w[2 * share: 2 * share + 2]
+            for k, w in whole["experts"].items()})}
+        idx, p = layer.apply(mine, m, method="route")    # alike on all
+        y, counters = layer.apply(mine, m, idx, p)
+        routed = routed + (y - shared)
+        here += float(counters["here"])
+    np.testing.assert_allclose(routed + shared, want, atol=5e-5)
+    assert here == pytest.approx(1.0)     # every assignment, by one share
+    # summing the shares' results as they come counts the shared expert
+    # four times: that is not the layer
+    assert float(jnp.abs(routed + 4 * shared - want).max()) > 0.01
+
+
+TINY_FLAGS = [
+    "--hidden-size", "64", "--num-hidden-layers", "5", "--num-heads", "4",
+    "--num-kv-heads", "2", "--head-dim", "16", "--expert-width", "32",
+    "--num-experts", "8", "--experts-per-token", "2", "--experts-held", "4",
+    "--expert-offset", "2", "--vocab-size", "96", "--window", "8",
+    "--text-seq-len", "16", "--image-grid", "4", "--vocab-text", "48",
+    "--vocab-image", "48", "--dtype", "float32", "--head-chunk", "16",
+    "--dense-width", "96"]
+
+
+def test_the_preset_trains_through_the_peers_normal_path():
+    """``run_trainer --preset trinitymini`` (+ tiny field flags): the
+    parser builds the preset's own class, TrainingTask the model its
+    configuration names, and train_loop runs it with the swarm optimizer;
+    the rows of the trainer's ring carry the model's records."""
+    from dalle_tpu.obs.trace import default_tracer
+    from dalle_tpu.task import TrainingTask
+    from dalle_tpu.training.loop import train_loop
+
+    args = run_trainer.build_parser().parse_args(
+        ["--preset", "trinitymini", *TINY_FLAGS,
+         "--per-device-batch", "1", "--grad-accum-steps", "2",
+         "--target-batch-size", str(1 << 30), "--seed", "7"])
+    configs = run_trainer.configs_from_args(args)
+    assert configs[0] == AfmoeLMConfig(**TINY)
+    task = TrainingTask(*configs)
+    assert family(task.model_cfg) is sparse_lm
+    assert isinstance(task.model, sparse_lm.SparseLM)
+    losses = []
+    with task:
+        train_loop(task, max_steps=3, warmup_steps=1,
+                   on_step=lambda n, loss: losses.append(loss))
+    assert len(losses) == 3 and all(np.isfinite(losses))
+    rows = [r for r in default_tracer().dump() if r.get("plane") == "train"]
+    warm = [r for r in rows if r["phase"] == "setup/warmup"][-1]["a"]
+    assert warm["moe_layout"].startswith(
+        "4 of 8 experts held (2-5), top 2 of 8, sigmoid, bias, norm, "
+        "x2.826, a shared expert of 32, layers 0-0 dense 96, no exchange")
+    assert warm["attn_layout"].startswith("blockwise 512: 0 of 5 layers, 1 "
+                                          "full no-rope + 4 window 8 rope")
+    assert warm["attn_layout"].endswith("normed queries and keys, gated "
+                                        "output")
+    steps = [r for r in rows if r["phase"] == "loop/step"][-3:]
+    for row in (r["a"] for r in steps):
+        assert 0 < row["moe_assignments_here_pct"] < 100
+        assert row["moe_dropped"] == 0.0
+        # no Mosaic backend here: the dense lowering in each of the four
+        # expert layers of every shard
+        assert row["moe_dense_calls"] == 4.0 * task.mesh.size
+    assert task.model_cfg.optimizer_stacking()["stacked_experts"] == 4
+
+
+def test_the_preset_is_a_class_of_its_own_and_the_sparse_class_keeps_its():
+    """``benchmark/configs/smallthinker21b.json`` holds ``asdict`` of
+    ``SparseLMConfig``: what the new class states as fields are class
+    attributes there, and no key is new. The mechanism switches are fields
+    a configuration's file states and no entry point's flags."""
+    sparse = {f.name for f in dataclasses.fields(SparseLMConfig)}
+    afmoe = {f.name for f in dataclasses.fields(AfmoeLMConfig)}
+    assert len(sparse) == 27 and set(dataclasses.asdict(SparseLMConfig())) \
+        == sparse
+    added = afmoe - sparse
+    assert added == {"num_dense_layers", "dense_width", "num_shared_experts",
+                     "hidden_act", "score_func", "selection_bias",
+                     "route_norm", "route_scale", "attention_gate",
+                     "qk_norm", "sandwich_norms", "mup_enabled"}
+    for name in added:        # fixed for the parent class, off
+        assert not getattr(SparseLMConfig(), name) or name in (
+            "hidden_act", "score_func", "route_scale")
+    assert SparseLMConfig().hidden_act == "relu"
+    assert SparseLMConfig().score_func == "softmax"
+    assert SparseLMConfig().route_scale == 1.0
+    cfg = trinitymini_model_config()
+    assert type(cfg) is AfmoeLMConfig and isinstance(cfg, SparseLMConfig)
+    cfg.validate()
+    flags = {a.dest for a in run_trainer.build_parser()._actions}
+    assert {"num_dense_layers", "dense_width"} <= flags
+    assert not flags & (set(AfmoeLMConfig.no_flag) - {"tied_embeddings"})
+    with pytest.raises(ValueError, match="sigmoid"):
+        dataclasses.replace(cfg, score_func="softmax").validate()
+    with pytest.raises(ValueError, match="softmax over the chosen"):
+        SparseLMConfig(router_softmax_over_chosen=False).validate()
+
+
+@pytest.mark.parametrize("cli, argv", [
+    (run_inference, ["--checkpoint-dir", "x", "--tokenizer-path", "y",
+                     "--query", "a cat"]),
+    (run_server, ["--random-init"]),
+    (run_aux_peer, []),
+])
+def test_entry_points_that_decode_refuse_the_preset_at_start(cli, argv):
+    with pytest.raises(SystemExit) as refused:
+        cli.main(["--preset", "trinitymini", *argv])
+    message = str(refused.value)
+    assert "trinitymini" in message and "models/decode.py" in message
+    assert "gated attention" in message and "shared expert" in message
+    assert "dense gated block" in message
+    assert message.count(".") <= 3 and "\n" not in message   # one sentence
