@@ -90,7 +90,10 @@ block), ``attn/gate`` (the ``W_g`` product, the sigmoid and the multiply),
 ``attn/qk_norm``. The token-major kernel
 (``token_major_sum[mosaic]`` in a trace) runs under the scope of its sum,
 ``ff/combine`` or ``ff/dispatch``; the grouped products under
-``ff/experts``.
+``ff/experts``; the head norms' one pass on the lanes
+(``qk_norm[mosaic]``, ops/pallas/head_norm_kernels.py: no (B, T, H, d)
+array exists where it runs, :func:`head_norm_why_not` is the rule) under
+``attn/qk_norm``.
 """
 
 from __future__ import annotations
@@ -102,11 +105,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from dalle_tpu.config import LAYER_WINDOW_ROPE, SparseLMConfig
 from dalle_tpu.models import attention as attn_mod
 from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
 from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
+from dalle_tpu.ops.pallas import head_norm_kernels as head_norm
 from dalle_tpu.ops.pallas import token_sum_kernels as token_sum
 from dalle_tpu.parallel.mesh import (LANES_SPEC, per_shard,
                                      sum_over_manual_data_axes)
@@ -120,11 +125,46 @@ ROWS_OVER_EXPECTED = 2.0
 _KERNEL_CHOICES: Dict[Tuple[str, int, int, int], bool] = {}
 
 
+# (tokens, lanes of the whole array, head_dim) -> why the last traced head
+# norm of such an array did not take the one-pass kernel on its local
+# shapes, None where it did: what attn_layout reads
+_HEAD_NORMS: Dict[Tuple[int, int, int], Optional[str]] = {}
+
+
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     with jax.named_scope("rms_norm"):
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
         return (y * scale).astype(x.dtype)
+
+
+def head_norm_why_not(tokens: int, width: int,
+                      head_dim: int) -> Optional[str]:
+    """Why the RMS norm over each head's ``head_dim`` lanes of (tokens,
+    width) samples is the reshape to heads and :func:`rms_norm`, and not
+    one pass on the lanes (ops/pallas/head_norm_kernels.py); None where it
+    is the pass."""
+    if not attn_mod._pallas_by_default():
+        return "no Mosaic backend"
+    return head_norm.fits(tokens, width, head_dim)
+
+
+def _norm_heads_shard(x, scale, *, eps: float, head_dim: int, lanes: int):
+    """One shard's norm of x (B, T, H*d) over each head's d lanes, one
+    scale vector for all heads: the one-pass kernel where it fits.
+    ``lanes``: the whole array's, of which a ``tp`` shard holds a part."""
+    b, t, width = x.shape
+    why_not = head_norm_why_not(t, width, head_dim)
+    _HEAD_NORMS[t, lanes, head_dim] = why_not
+    attn_mod.log_kernel_choice(
+        "head norm", why_not is None,
+        why_not or f"local {tuple(x.shape)}: heads of {head_dim} lanes, "
+        f"{head_norm.rows_tile(t, width)} rows a tile")
+    if why_not is not None:
+        return rms_norm(x.reshape(b, t, -1, head_dim), scale,
+                        eps).reshape(x.shape)
+    return head_norm.head_rms_norm(x, scale, eps, head_dim,
+                                   attn_mod._PALLAS_INTERPRET)
 
 
 # ---------------------------------------------------------------------------
@@ -185,13 +225,18 @@ class Attention(nn.Module):
         k = dense(cfg.num_kv_heads * cfg.head_dim, name="k")(a)
         v = dense(cfg.num_kv_heads * cfg.head_dim, name="v")(a)
         if cfg.qk_norm:
-            # over each head's head_dim, one scale vector for all heads
+            def normed(x, name):
+                norm = functools.partial(
+                    _norm_heads_shard, eps=cfg.rms_eps,
+                    head_dim=cfg.head_dim, lanes=x.shape[2])
+                if attn_mod._pallas_by_default():
+                    norm = per_shard(norm, self.mesh, (LANES_SPEC, P()),
+                                     LANES_SPEC, scope="qk_norm")
+                return norm(x, self.param(name, nn.initializers.ones,
+                                          (cfg.head_dim,), pdt))
+
             with jax.named_scope("qk_norm"):
-                q, k = (rms_norm(
-                    x.reshape(*x.shape[:2], -1, cfg.head_dim),
-                    self.param(name, nn.initializers.ones, (cfg.head_dim,),
-                               pdt), cfg.rms_eps).reshape(x.shape)
-                    for x, name in ((q, "q_norm"), (k, "k_norm")))
+                q, k = normed(q, "q_norm"), normed(k, "k_norm")
         window = None
         if self.kind == LAYER_WINDOW_ROPE:
             window = cfg.window
@@ -894,6 +939,12 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     on = sum(_KERNEL_CHOICES.get((k, cfg.total_seq_len, *widths), False)
              for k in kinds)
     windows = sum(k == LAYER_WINDOW_ROPE for k in kinds)
+    # every layer's head norms are the same two shapes: all took the pass
+    # on the lanes, or the first refusal says why none did
+    norm_why_not = next(filter(None, (
+        _HEAD_NORMS.get((cfg.total_seq_len, h * cfg.head_dim, cfg.head_dim),
+                        "none traced")
+        for h in (cfg.num_heads, cfg.num_kv_heads))), None)
     first, last = cfg.expert_offset, cfg.expert_offset + cfg.experts_held - 1
     devices = mesh.size if mesh is not None else 1
     why_not, tile = _SUM_LOWERINGS.get(
@@ -921,7 +972,10 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
             f"{len(kinds) - windows} full no-rope + {windows} window "
             f"{cfg.window} rope, {cfg.num_heads // cfg.num_kv_heads} query "
             f"heads a key-value head"
-            + ", normed queries and keys" * cfg.qk_norm
+            + (", normed queries and keys ("
+               + (f"XLA: {norm_why_not}" if norm_why_not else
+                  f"one pass on the lanes: {len(kinds)} of {len(kinds)} "
+                  "layers") + ")") * cfg.qk_norm
             + ", gated output" * cfg.attention_gate),
         "layer_loop": (f"unrolled: {len(kinds)} layers, each "
                        "rematerialised but its attention"),

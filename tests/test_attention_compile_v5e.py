@@ -117,3 +117,39 @@ def test_no_head_dim_minor_array_around_the_kernels(
               if m and any("," in s and _minor(s) == d
                            for s in _ARRAY.findall(m.group(1)))]
     assert not narrow, narrow
+
+
+def test_the_head_norms_of_queries_and_keys_stay_on_the_lanes(
+        one_chip, no_persistent_cache, monkeypatch):
+    """One attention layer of ``trinitymini`` (a window layer: norm, then
+    rotary) at the cell's shapes: the head norms are two Mosaic calls a
+    direction named ``qk_norm`` (not ``attn``: the attention roofline must
+    not count them), and no (B, T, heads, 128) array exists."""
+    from dalle_tpu.config import LAYER_WINDOW_ROPE, trinitymini_model_config
+    from dalle_tpu.models import attention, sparse_lm
+
+    monkeypatch.setattr(attention, "_pallas_by_default", lambda: True)
+    cfg = trinitymini_model_config()
+    mod = sparse_lm.Attention(cfg, LAYER_WINDOW_ROPE, name="attn")
+    a = jax.ShapeDtypeStruct((1, cfg.total_seq_len, cfg.hidden_size),
+                             jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: mod.init(jax.random.PRNGKey(0),
+                                        jnp.zeros(a.shape, a.dtype))))
+
+    def loss(p, a):
+        return jnp.sum(mod.apply(p, a).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, a).compile().as_text()
+    names = sorted(
+        re.match(r"\s*(?:ROOT )?%?([\w\-]+?)[.\d]* =", line).group(1)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+    assert names.count("qk_norm") == 4 and len(names) == 7, names
+    assert all("attn" in n for n in names if n != "qk_norm"), names
+    for heads in (cfg.num_heads, cfg.num_kv_heads):
+        assert f"{cfg.total_seq_len},{heads},{cfg.head_dim}]" not in text
+    assert sparse_lm._HEAD_NORMS[
+        cfg.total_seq_len, cfg.num_heads * cfg.head_dim, cfg.head_dim] is None

@@ -85,6 +85,10 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(kernels,
     for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
                             jax.tree.leaves(ref_grads)):
         assert rel_l2(g, r) < 2e-5, jax.tree_util.keystr(path)
+    # ... through the one-pass head norm, where the kernels run
+    assert ("normed queries and keys (one pass on the lanes: 5 of 5 layers)"
+            if kernels else "normed queries and keys (XLA: no Mosaic "
+            "backend)") in sparse_lm.engagement_records(cfg)["attn_layout"]
     layer = params["params"]["layer_1"]
     assert set(layer) == {"attn", "attn_norm", "post_attn_norm", "ff",
                           "ff_norm", "post_ff_norm"}          # four norms
@@ -162,6 +166,26 @@ def test_a_mechanism_left_out_is_told(mechanism, with_everything):
     assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
     for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
         assert rel_l2(g, r) < 2e-5
+
+
+@pytest.mark.parametrize("interpret, head_dim, words", [
+    (True, 128, "(one pass on the lanes: 2 of 2 layers)"),
+    (True, 64, "(XLA: head_dim 64 is not whole 128-lane tiles)"),
+    (False, 128, "(XLA: no Mosaic backend)"),
+])
+def test_attn_layout_says_which_lowering_the_head_norms_took(
+        interpret, head_dim, words, monkeypatch):
+    """Read from what the traced calls did, as the blockwise count is."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", interpret)
+    cfg = AfmoeLMConfig(**dict(SMALL, head_dim=head_dim))
+    text, image = _batch(cfg)
+    jax.eval_shape(lambda p: sparse_lm.build(cfg).apply(p, text, image),
+                   _params(cfg))
+    layout = sparse_lm.engagement_records(cfg)["attn_layout"]
+    assert layout.endswith(f"normed queries and keys {words}, gated output")
+    without = dataclasses.replace(cfg, qk_norm=False)
+    assert "normed" not in sparse_lm.engagement_records(without)[
+        "attn_layout"]
 
 
 def test_the_leading_dense_layer():
@@ -317,8 +341,8 @@ def test_the_preset_trains_through_the_peers_normal_path():
         "x2.826, a shared expert of 32, layers 0-0 dense 96, no exchange")
     assert warm["attn_layout"].startswith("blockwise 512: 0 of 5 layers, 1 "
                                           "full no-rope + 4 window 8 rope")
-    assert warm["attn_layout"].endswith("normed queries and keys, gated "
-                                        "output")
+    assert warm["attn_layout"].endswith(
+        "normed queries and keys (XLA: no Mosaic backend), gated output")
     steps = [r for r in rows if r["phase"] == "loop/step"][-3:]
     for row in (r["a"] for r in steps):
         assert 0 < row["moe_assignments_here_pct"] < 100
