@@ -281,8 +281,10 @@ def engagement_records(cfg: ModelConfig, mesh=None) -> dict:
             "attn_layout": attn_layout_record(cfg, mesh)}
 
 
-# entries of the step's aux that go onto every loop/step row: none
+# entries of the step's aux that go onto every loop/step row, and those
+# of them that count a slower lowering (obs/late.py's cause "model"): none
 STEP_ATTRIBUTES = ()
+SLOW_STEP_ATTRIBUTES = ()
 
 
 def param_count(params) -> int:

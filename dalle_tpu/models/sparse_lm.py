@@ -990,3 +990,7 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
 
 STEP_ATTRIBUTES = ("moe_assignments_here_pct", "moe_load_max_over_mean",
                    "moe_dropped", "moe_dense_calls", "moe_sum_spills")
+# those of them that count a slower lowering, so that a step above its
+# median in one is late because of the model (obs/late.py's cause "model");
+# a spill costs its tile microseconds and is reported without being blamed
+SLOW_STEP_ATTRIBUTES = ("moe_dense_calls",)

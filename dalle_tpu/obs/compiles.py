@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import logging
 import threading
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from dalle_tpu.obs.trace import Tracer
 
@@ -126,6 +126,14 @@ class CompileCounter:
         tracer = self.tracer
         self._count(kind, None, None,
                     tracer.open_spans() if tracer is not None else [])
+
+    def cost(self) -> Tuple[int, float]:
+        """(backend compiles, seconds tracing + lowering + compiling) so
+        far: what the late-step recorder reads at every step's edge."""
+        with self._lock:
+            total = self.total
+            return int(total["compile_n"]), (
+                total["trace_s"] + total["lower_s"] + total["compile_s"])
 
     def snapshot(self) -> Dict[str, object]:
         with self._lock:

@@ -56,6 +56,7 @@ open on the recording thread: the span that caused this one.
 
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
@@ -310,6 +311,22 @@ class Tracer:
         dicts' references — rows are write-once after ``add``)."""
         with self._lock:
             return [row for _est, row in self._ring]
+
+    def mark(self) -> int:
+        """How many rows have been recorded: hand it to :meth:`since`."""
+        with self._lock:
+            return self.spans_recorded
+
+    def since(self, mark: int) -> List[dict]:
+        """The rows recorded after :meth:`mark` returned ``mark`` (those
+        the ring still holds), oldest first: one step's rows cost that
+        step's reader a dozen references, not a copy of the ring."""
+        with self._lock:
+            n = min(self.spans_recorded - mark, len(self._ring))
+            rows = [row for _est, row in
+                    itertools.islice(reversed(self._ring), n)]
+        rows.reverse()
+        return rows
 
     def last_rounds(self, n: int = 3) -> List[dict]:
         """Rows belonging to the last ``n`` distinct trace ids seen —

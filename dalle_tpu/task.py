@@ -61,6 +61,30 @@ class TrainingTask:
     def _setup_span(self, what: str):
         return self.tracer.span("train", f"setup/{what}", "setup")
 
+    @functools.cached_property
+    def late_steps(self):
+        """The late-step recorder ``train_loop`` opens its steps through
+        (``obs/late.py``; always on, like the ring it records into). What
+        needs JAX is handed in: the compile counter and the fullest local
+        device's allocator statistics (the device is chosen at the first
+        reading, the recorder's start: a step's edge reads one device,
+        not every chip of the host)."""
+        from dalle_tpu.obs.late import LateSteps
+        fullest = []
+
+        def device_memory():
+            if not fullest:
+                fullest.append(max(
+                    jax.local_devices(), key=lambda d: (
+                        d.memory_stats() or {}).get("bytes_in_use", 0)))
+            return fullest[0].memory_stats()
+
+        trace_file = self.collab_cfg.trace_file
+        return LateSteps(
+            self.tracer, compiles=self.compiles, device_memory=device_memory,
+            slow_attributes=self.family.SLOW_STEP_ATTRIBUTES,
+            stacks_path=f"{trace_file}.stacks" if trace_file else None)
+
     # -- identity / swarm -------------------------------------------------
 
     @functools.cached_property

@@ -138,14 +138,17 @@ def train_loop(task: TrainingTask,
     batches = task.batches()
     # the loop times itself (OBSERVABILITY.md, plane "train"): one
     # loop/step span a step, its parts as children; what the children do
-    # not cover is the loop's own time
+    # not cover is the loop's own time. The late-step recorder opens and
+    # closes the step's span, and says why a step that ran over did
     span = functools.partial(task.tracer.span, "train")
     step_attributes = task.family.STEP_ATTRIBUTES
+    late = task.late_steps
     try:
+        late.start()
         while ((max_epochs is None or collab.local_epoch < max_epochs)
                and (max_steps is None or local_steps < max_steps)):
             profiler.tick(local_steps)
-            with span("loop/step", f"step:{local_steps + 1}") as step_row:
+            with late.step(local_steps + 1) as step_row:
                 with span("loop/batch_fetch"):
                     batch = next(batches)
                 with span("loop/grad_dispatch"):
@@ -278,6 +281,7 @@ def train_loop(task: TrainingTask,
             if ckpt is not None and params_are_finite(collab.state.params):
                 ckpt.save_backup(collab.state, collab.local_epoch)
     finally:
+        late.stop()
         # the trace from a crashed run is the artifact you want most
         profiler.close()
         if ckpt is not None:

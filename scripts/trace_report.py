@@ -21,6 +21,9 @@ Outputs (printed table + ``--out`` JSON):
   of the same trace separated by more than ``--gap-s`` of silence
   (span end -> next span start) — the signature of a stall the phase
   walls themselves don't show;
+- **late steps**: the trainer's ``loop/late_step`` events (plane
+  ``train``; ``dalle_tpu/obs/late.py``): which step ran over, by how
+  much, in which phase, and the cause its record supports;
 - **round table** (``--rounds``): one row per trace id with per-peer
   total span time, phase count, and errors.
 
@@ -42,6 +45,7 @@ from typing import Dict, List, Optional
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
+from dalle_tpu.obs.late import LATE_EVENT, late_step_line  # noqa: E402
 from dalle_tpu.obs.trace import load_jsonl, merge_rows  # noqa: E402
 
 
@@ -174,6 +178,17 @@ def setup_facts(rows: List[dict]) -> Dict[str, dict]:
     return facts
 
 
+def late_steps(rows: List[dict]) -> List[dict]:
+    """The trainer's late-step records (``loop/late_step`` events, one
+    after the ``loop/step`` row of every step that ran over): peer, step
+    and the record's attributes, with the line the trainer logged."""
+    return [dict(r.get("a", {}), peer=str(r.get("peer", "")),
+                 trace=r["trace"],
+                 line=late_step_line(r["trace"], r.get("a", {})))
+            for r in rows
+            if r["plane"] == "train" and r["phase"] == LATE_EVENT]
+
+
 def build_report(files: List[str], gap_s: float = 1.0,
                  rounds: bool = False) -> dict:
     per_peer = [load_jsonl(f) for f in files]
@@ -187,6 +202,7 @@ def build_report(files: List[str], gap_s: float = 1.0,
         "setup": setup_facts(rows),
         "stragglers": straggler_attribution(rows),
         "gaps": detect_gaps(rows, gap_s=gap_s),
+        "late_steps": late_steps(rows),
     }
     if rounds:
         report["rounds"] = round_table(rows)
@@ -236,6 +252,8 @@ def main(argv=None) -> int:
         print(f"  gap: {g['peer']} went silent {g['gap_s']}s inside "
               f"{g['trace']} ({g['after_phase']} -> "
               f"{g['before_phase']})")
+    for late in report["late_steps"]:
+        print(f"  late step: {late['peer']} {late['line']}")
     if args.out:
         with open(args.out, "w", encoding="utf-8") as fh:
             json.dump(report, fh, indent=1)

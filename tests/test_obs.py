@@ -299,6 +299,62 @@ class TestOverheadBudget:
         assert cost <= 20e-6, f"{cost * 1e6:.1f} us a span"
         assert tracer.open_spans() == []
 
+    def test_a_steps_two_edges_stay_under_300_us(self, caplog):
+        """What the late-step recorder adds to every step: the span it
+        opens and closes for the loop, the kernel's counters, the
+        threads' clocks, the compile counter, the device's statistics,
+        the step's rows read back from the ring and the median of 32.
+        Under 300 us a step (the shortest step of the benchmark is 1.24
+        s); best of three."""
+        from dalle_tpu.obs import compiles as C
+        from dalle_tpu.obs.late import LateSteps
+        tracer = Tracer(annotate=jax.profiler.TraceAnnotation)
+        device = jax.local_devices()[0]
+        allocs = iter(range(1, 10_000))
+
+        def device_memory():              # the call, and a count that moves
+            return dict(device.memory_stats() or {"bytes_in_use": 1},
+                        num_allocs=next(allocs))
+        rec = LateSteps(tracer, compiles=C.CompileCounter(tracer),
+                        device_memory=device_memory)
+        rec.start()
+        try:
+            def steps(n, first):
+                t0 = time.perf_counter()
+                for i in range(first, first + n):
+                    with rec.step(i) as row:
+                        for phase in ("loop/batch_fetch",
+                                      "loop/grad_dispatch", "loop/loss_wait",
+                                      "loop/hook", "collab/step"):
+                            with tracer.span("train", phase):
+                                pass
+                        row.set(moe_dense_calls=0.0)
+                return (time.perf_counter() - t0) / n
+            steps(40, 1)                      # fill the history
+            with_it = min(steps(300, 100 + 300 * k) for k in range(3))
+        finally:
+            import logging
+            with caplog.at_level(logging.INFO, logger="dalle_tpu.obs.late"):
+                rec.stop()
+
+        def bare(n):
+            t0 = time.perf_counter()
+            for i in range(n):
+                with tracer.span("train", "loop/step", f"step:{i}") as row:
+                    for phase in ("loop/batch_fetch", "loop/grad_dispatch",
+                                  "loop/loss_wait", "loop/hook",
+                                  "collab/step"):
+                        with tracer.span("train", phase):
+                            pass
+                    row.set(moe_dense_calls=0.0)
+            return (time.perf_counter() - t0) / n
+        without = min(bare(300) for _ in range(3))
+        added = with_it - without
+        assert added <= 300e-6, f"{added * 1e6:.0f} us a step"
+        said = [r.getMessage() for r in caplog.records]
+        assert any(" of 940 steps" in line for line in said), said
+        assert next(allocs) > 900            # every edge asked the device
+
     def test_per_span_cost_is_bounded(self):
         # generous absolute ceiling (~100x the typical few-us cost) so
         # the pin survives the 2-core box's scheduling noise
@@ -607,6 +663,43 @@ class TestTraceReport:
                    and g["gap_s"] > 1.0 for g in rep["gaps"])
         assert {r["trace"] for r in rep["rounds"]} == {
             f"run:{e}" for e in range(4)}
+        assert rep["late_steps"] == []
+
+    def test_late_steps_are_listed_under_the_gaps(self, tmp_path, capsys):
+        """The operator's reader of ``loop/late_step`` events: step,
+        excess, where, cause, and the line the trainer logged."""
+        from scripts import trace_report
+        attrs = {"step_s": 3.361, "usual_s": 1.794, "excess_s": 1.567,
+                 "where": "loop/loss_wait", "where_excess_s": 1.566,
+                 "pulse_missed_s": 1.55, "pulse_lock_waits": 1,
+                 "process_cpu_s": 0.01,
+                 "machine_ran_s": 3.36, "throttled_s": 0.0,
+                 "invol_switches": 0, "vol_switches": 2,
+                 "stacks": "peer.jsonl.stacks", "cause": "process_stopped"}
+        rows = [{"v": 1, "peer": "p0", "plane": "train",
+                 "phase": "loop/step", "trace": "step:812", "t0": 50.0,
+                 "dur_s": 3.361},
+                {"v": 1, "peer": "p0", "plane": "train",
+                 "phase": "loop/late_step", "trace": "step:812",
+                 "t0": 53.361, "dur_s": 0.0, "a": attrs},
+                # a swarm event of the same name is no late step
+                {"v": 1, "peer": "p0", "plane": "swarm",
+                 "phase": "loop/late_step", "trace": "run:0", "t0": 1.0,
+                 "dur_s": 0.0}]
+        path = tmp_path / "p0.jsonl"
+        path.write_text("".join(json.dumps(r) + "\n" for r in rows))
+        (late,) = trace_report.build_report([str(path)])["late_steps"]
+        assert (late["peer"], late["trace"], late["excess_s"],
+                late["where"], late["cause"]) == (
+            "p0", "step:812", 1.567, "loop/loss_wait", "process_stopped")
+        line = ("step:812 took 3.361 s where 1.794 is usual (+1.567 s in "
+                "loop/loss_wait): process_stopped: pulse missed 1.55 s, "
+                "process CPU 0.01 s, machine ran 3.36 s, throttled 0.00 s, "
+                "0 involuntary / 2 voluntary switches; stacks in "
+                "peer.jsonl.stacks")
+        assert late["line"] == line
+        assert trace_report.main([str(path)]) == 0
+        assert f"  late step: p0 {line}" in capsys.readouterr().out
 
 
 # -- fetch_metrics aggregation edges (satellite) --------------------------
@@ -785,3 +878,818 @@ class TestStateTransferSpans:
         assert serve, "server recorded no state_serve span"
         assert serve[-1]["trace"] == fetch[-1]["trace"]
         assert serve[-1]["trace"].startswith("xfer:xfer:")
+
+
+# -- the late-step recorder (obs/late.py) -----------------------------------
+
+class _World:
+    """A clock and the kernel's counters, moved by hand: what a step of
+    the loop sees at its two edges."""
+
+    def __init__(self):
+        self.now = 1000.0
+        self.host = {"process_cpu_s": 5.0, "vol_switches": 100,
+                     "invol_switches": 3, "major_faults": 0,
+                     "steal_s": 0.0, "machine_ran_s": 500.0,
+                     "throttled_s": 0.0}
+        self.threads = {"MainThread": 2.0, "wire": 0.5}
+        self.pulse = [0.0, 0]         # missed s, lock waits
+        self.compiled = [0, 0.0]
+        self.switches_a_wait = 1      # 0: a kernel that counts none
+
+    def clock(self):
+        return self.now
+
+    def read_host(self):
+        return dict(self.host, threads=dict(self.threads))
+
+    def read_pulse(self):
+        return tuple(self.pulse)
+
+    def cost(self):                       # the compile counter's reading
+        return tuple(self.compiled)
+
+    def pass_(self, seconds, machine=True, main_cpu=0.0):
+        self.now += seconds
+        if machine:
+            self.host["machine_ran_s"] += seconds
+        self.host["vol_switches"] += self.switches_a_wait
+        self.host["process_cpu_s"] += main_cpu
+        self.threads["MainThread"] += main_cpu
+
+
+def _recorder(world, **kw):
+    from dalle_tpu.obs.late import LateSteps
+    tracer = Tracer(peer="late", clock=world.clock)
+    return tracer, LateSteps(tracer, compiles=world, host=world.read_host,
+                             pulse=world.read_pulse, clock=world.clock,
+                             slow_attributes=("moe_dense_calls",), **kw)
+
+
+def _a_step(world, tracer, rec, n, extra=None, attrs=None):
+    """One step as the loop runs it; ``extra`` = (phase, what happens in
+    it beside its usual milliseconds)."""
+    phase, happens = extra or (None, None)
+
+    def spend(name, seconds):
+        with tracer.span("train", name):
+            world.pass_(seconds, main_cpu=0.001)
+            if name == phase:
+                happens()
+    if phase == "between_steps":           # before the step's span opens
+        happens()
+    with rec.step(n) as row:
+        spend("loop/batch_fetch", 0.001)
+        spend("loop/grad_dispatch", 0.002)
+        spend("loop/loss_wait", 1.0)
+        row.set(**(attrs or {"moe_dense_calls": 0.0,
+                             "moe_sum_spills": float(n % 3)}))
+        spend("loop/hook", 0.001)
+        with tracer.span("train", "collab/step"):
+            spend("collab/accumulate", 0.004)
+            spend("collab/global_step", 0.0)
+            world.pass_(0.002)
+
+
+def _late_events(tracer):
+    rows = tracer if isinstance(tracer, list) else tracer.dump()
+    return [r for r in rows if r["phase"] == "loop/late_step"]
+
+
+def _cases():
+    """name -> (the phase the excess is in, what happens there, the
+    attributes of the step, the cause and a few attributes of the record;
+    None = not late)."""
+    def stall(w, s, **kw):
+        return lambda: w.pass_(s, **kw)
+
+    def compiled(w):
+        w.pass_(2.0, main_cpu=1.9)
+        w.compiled[0] += 1
+        w.compiled[1] += 1.5
+
+    def collected(w, rec):
+        rec._on_gc("start", {})
+        w.pass_(0.4, main_cpu=0.4)
+        rec._on_gc("stop", {})
+
+    def stopped(w):
+        w.pass_(2.0)
+        w.pulse[0] += 1.95
+        w.pulse[1] += 1
+        w.host["throttled_s"] += 1.9
+
+    def paused(w):
+        w.pass_(2.0, machine=False)
+        w.pulse[0] += 1.98
+
+    def held_by_a_busy_thread(w):
+        w.pass_(2.0)
+        w.pulse[0] += 1.9
+        w.host["process_cpu_s"] += 1.9
+        w.threads["wire"] += 1.9
+
+    def held_by_a_native_call(w):
+        w.pass_(2.0)
+        w.pulse[0] += 1.9
+        w.pulse[1] += 380
+
+    def starved_by_native_threads(w):
+        w.pass_(0.3)
+        w.pulse[0] += 0.3
+        w.host["process_cpu_s"] += 2.4        # eight native threads
+
+    def main_thread_worked(w):
+        w.pass_(0.5, main_cpu=0.5)
+        w.pulse[0] += 0.4                     # it kept the lock meanwhile
+        w.pulse[1] += 90
+
+    def hook_called_native_code(w):
+        w.pass_(0.04)                         # the profiler's start
+        w.pulse[0] += 0.021
+        w.host["process_cpu_s"] += 0.03
+
+    return {
+        "compile": ("collab/accumulate", compiled, None, "compile",
+                    {"where": "collab/step", "compiles": 1,
+                     "hook_or_after": 1}),
+        "model": ("loop/loss_wait", lambda w: w.pass_(0.5),
+                  {"moe_dense_calls": 3.0, "moe_sum_spills": 1.0}, "model",
+                  {"moe_dense_calls": 3.0, "moe_dense_calls_usual": 0.0,
+                   "slower_lowering": "moe_dense_calls"}),
+        "gc": ("loop/hook", collected, None, "gc",
+               {"where": "loop/hook", "gc_n": 1, "gc_s": 0.4}),
+        "machine_stopped": ("loop/loss_wait", paused, None,
+                            "machine_stopped", {"pulse_missed_s": 1.98}),
+        "process_stopped": ("loop/loss_wait", stopped, None,
+                            "process_stopped",
+                            {"throttled_s": 1.9, "where": "loop/loss_wait",
+                             "pulse_lock_waits": 1, "hook_or_after": 0}),
+        "interpreter_held-a_thread_burned": (
+            "loop/loss_wait", held_by_a_busy_thread, None,
+            "interpreter_held",
+            {"busiest_thread": "wire", "busiest_thread_cpu_s": 1.9}),
+        "interpreter_held-a_native_call_kept_the_lock": (
+            "loop/loss_wait", held_by_a_native_call, None,
+            "interpreter_held", {"pulse_lock_waits": 380}),
+        "host-the_hook": ("loop/hook", lambda w: w.pass_(0.3), None, "host",
+                          {"where": "loop/hook", "where_excess_s": 0.3,
+                           "hook_or_after": 1}),
+        "host-the_main_thread_worked": (
+            "loop/grad_dispatch", main_thread_worked, None, "host",
+            {"busiest_thread": "MainThread", "where": "loop/grad_dispatch"}),
+        "host-a_native_call_of_the_hook_kept_the_lock": (
+            "loop/hook", hook_called_native_code, None, "host",
+            {"where": "loop/hook", "busiest_thread": "native threads"}),
+        "device_or_runtime": ("loop/loss_wait", lambda w: w.pass_(0.3), None,
+                              "device_or_runtime",
+                              {"where": "loop/loss_wait",
+                               "pulse_missed_s": 0.0}),
+        "device_or_runtime-traced_run_waits_in_dispatch": (
+            "loop/grad_dispatch", lambda w: w.pass_(0.3), None,
+            "device_or_runtime", {"where": "loop/grad_dispatch",
+                                  "hook_or_after": 0}),
+        "process_stopped-between_two_steps": (
+            "between_steps", stopped, None, "process_stopped",
+            {"where": "between_steps", "between_s": 2.0,
+             "where_excess_s": 2.0, "hook_or_after": 0}),
+        "host-between_two_steps": (
+            "between_steps", lambda w: w.pass_(0.3), None, "host",
+            {"where": "between_steps", "between_s": 0.3}),
+        "unknown": ("loop/loss_wait", starved_by_native_threads, None,
+                    "unknown", {"busiest_thread": "native threads"}),
+        "round_work_is_not_late": ("collab/global_step",
+                                   lambda w: w.pass_(3.0), None, None, {}),
+        "quiet_scatter_is_not_late": ("loop/loss_wait",
+                                      lambda w: w.pass_(0.004), None, None,
+                                      {}),
+    }
+
+
+class TestLateSteps:
+    @pytest.mark.parametrize("case", sorted(_cases()))
+    def test_cause_table(self, case):
+        """Every row of ``obs.late.CAUSES`` on an injected clock and
+        injected counters: six quiet steps, then one in which the case
+        happens."""
+        phase, happens, attrs, cause, shown = _cases()[case]
+        world = _World()
+        tracer, rec = _recorder(world)
+        for n in range(1, 7):
+            _a_step(world, tracer, rec, n)
+        assert _late_events(tracer) == []
+        import inspect
+        args = (world, rec)[:len(inspect.signature(happens).parameters)]
+        _a_step(world, tracer, rec, 7, (phase, lambda: happens(*args)),
+                attrs)
+        _a_step(world, tracer, rec, 8)
+        events = _late_events(tracer)
+        if cause is None:
+            assert events == []
+            return
+        (event,) = events
+        a = event["a"]
+        assert event["trace"] == "step:7" and event["dur_s"] == 0.0
+        assert a["cause"] == cause, a
+        assert a["usual_s"] == pytest.approx(1.01, abs=1e-6)
+        assert a["excess_s"] == pytest.approx(
+            a["step_s"] + a.get("between_s", 0.0) - 1.01, abs=1e-5)
+        for key, value in shown.items():
+            assert a[key] == (pytest.approx(value, abs=1e-6)
+                              if isinstance(value, float) else value), key
+        assert all(isinstance(v, (int, float, str)) for v in a.values())
+        # the event follows its step's row, on the ring's clock
+        rows = tracer.dump()
+        at = rows.index(event)
+        assert rows[at - 1]["phase"] == "loop/step"
+        assert rows[at - 1]["trace"] == "step:7"
+        assert event["t0"] == pytest.approx(
+            rows[at - 1]["t0"] + rows[at - 1]["dur_s"])
+
+    def test_the_table_is_the_nine_causes_in_order(self):
+        from dalle_tpu.obs import late
+        assert [name for name, _, _ in late.CAUSES] == [
+            "compile", "model", "gc", "machine_stopped", "process_stopped",
+            "interpreter_held", "host", "device_or_runtime", "unknown"]
+        doc = open(os.path.join(REPO, "OBSERVABILITY.md")).read()
+        for name, _, _ in late.CAUSES:
+            assert f"`{name}`" in doc, name
+
+    @pytest.mark.parametrize("late_at", [1, 2, 3, 4])
+    def test_the_first_four_steps_are_never_late(self, late_at):
+        world = _World()
+        tracer, rec = _recorder(world)
+        for n in range(1, 5):
+            _a_step(world, tracer, rec, n,
+                    ("loop/loss_wait", lambda: world.pass_(5.0))
+                    if n == late_at else None)
+        assert _late_events(tracer) == []
+        _a_step(world, tracer, rec, 5,
+                ("loop/loss_wait", lambda: world.pass_(5.0)))
+        assert [e["trace"] for e in _late_events(tracer)] == ["step:5"]
+
+    def test_sources_in_name_only_are_left_out(self, caplog):
+        """A sandbox's kernel (the chip machine's, PERF.md section 6, PR
+        35): ``/proc/stat`` does not tick, ``getrusage`` counts no switch
+        and no fault, the allocator no allocation. Judged once, over the
+        first four steps: their keys are left out from then on like those
+        of a file that is absent, the device is not asked again, no stall
+        is laid on a machine whose clock never moved, and the line says
+        what ``process_stopped`` then cannot tell."""
+        import logging
+        world = _World()
+        world.switches_a_wait = 0
+        asked = []
+
+        def device_memory():
+            asked.append(world.now)
+            return {"bytes_in_use": 7 << 30, "num_allocs": 1234}
+        tracer, rec = _recorder(world, device_memory=device_memory)
+        rec.start()
+        with caplog.at_level(logging.INFO, logger="dalle_tpu.obs.late"):
+            for n in range(1, 7):
+                _a_step(world, tracer, rec, n)
+                world.host["machine_ran_s"] = 500.0
+                if n == 3:
+                    world.host["vol_switches"] += 2   # a stray one, no count
+            assert len(asked) == 5                # the start, four steps
+            (judged,) = [r.getMessage() for r in caplog.records]
+            assert judged.endswith(
+                "left out of the records: invol_switches, machine_ran_s, "
+                "major_faults, mem_allocs_delta, mem_in_use_delta, "
+                "pulse_lock_waits, steal_s, vol_switches")
+            caplog.clear()
+
+            def frozen():
+                world.pass_(2.0, machine=False)
+                world.pulse[0] += 1.97
+                world.host["invol_switches"] += 1     # too late to count
+            _a_step(world, tracer, rec, 7, ("loop/loss_wait", frozen))
+        rec.stop()
+        (event,) = _late_events(tracer)
+        a = event["a"]
+        assert a["cause"] == "process_stopped", a
+        assert not {"machine_ran_s", "steal_s", "vol_switches",
+                    "invol_switches", "major_faults", "pulse_lock_waits",
+                    "mem_in_use_delta", "mem_allocs_delta"} & set(a)
+        (said,) = [r.getMessage() for r in caplog.records]
+        assert "machine ran" not in said and "switches" in said
+        assert "switches are not counted here (a native call that slept " \
+            "with the interpreter lock reads the same)" in said
+        assert "involuntary" not in said
+
+    def test_a_source_that_moved_in_the_first_steps_is_kept(self):
+        """Judged once: a counter that counted over the first four steps
+        and says 0 for a later one has said something."""
+        world = _World()
+        held = {"bytes_in_use": 1000, "num_allocs": 0}
+
+        def device_memory():
+            if world.now < 1004.5:
+                held["num_allocs"] += 3
+            return dict(held)
+        tracer, rec = _recorder(world, device_memory=device_memory)
+        rec.start()
+        for n in range(1, 7):
+            _a_step(world, tracer, rec, n)
+        world.switches_a_wait = 0
+        _a_step(world, tracer, rec, 7,
+                ("loop/loss_wait", lambda: world.pass_(0.3)))
+        rec.stop()
+        a = _late_events(tracer)[0]["a"]
+        assert (a["mem_allocs_delta"], a["mem_in_use_delta"],
+                a["vol_switches"], a["major_faults"]) == (0, 0, 0, 0)
+        assert a["machine_ran_s"] == pytest.approx(a["step_s"], abs=1e-3)
+
+    def test_a_beat_that_is_overdue_counts_as_far_as_it_is(self):
+        """A stall that ends with its step: the step's close reads the
+        pulse before the pulse has run again, and does not wait for it."""
+        from dalle_tpu.obs.late import BEAT_S, Pulse
+        now = [100.0]
+        pulse = Pulse(clock=lambda: now[0])            # never started
+        assert pulse.read() == (0.0, 0)
+        now[0] += BEAT_S + 0.001                       # ordinary scheduling
+        assert pulse.read()[0] == 0.0
+        now[0] = 100.0 + BEAT_S + 1.5
+        assert pulse.read()[0] == pytest.approx(1.5)
+
+    def test_stacks_are_taken_after_beats_that_came_on_time(self):
+        """Once a step, when it has been open 1.25 x the usual in beats
+        the pulse did not miss: not during a stall (this thread does not
+        run) and not at the first beat after it (every thread is back in
+        a wait, the dump would name nothing)."""
+        import io
+        from dalle_tpu.obs.late import Pulse
+        now = [100.0]
+        out = io.StringIO()
+        pulse = Pulse(clock=lambda: now[0], stacks=out)    # never started
+        pulse.opened("step:7", 0.5)
+        now[0] += 2.1                  # a stall of 2 s, and its first beat
+        pulse._missed_s += 2.0
+        assert pulse._stacks_due(now[0]) is None       # it beat for 0.1 s
+        now[0] += 0.45
+        step = pulse._stacks_due(now[0])
+        assert step is not None
+        frames = pulse._take_stacks(step[0], now[0] - step[1])
+        # this thread took them itself, as the pulse does in earnest
+        assert frames.startswith("MainThread: late.py:")
+        assert frames.split("; ")[0].endswith(" _take_stacks")
+        dump = out.getvalue()
+        assert " in test_stacks_are_taken_after_beats_that_came_on_" in dump
+        assert dump.startswith("Late step (step:7 open 2.550 s):\n"
+                               "Thread MainThread (most recent call first)")
+        assert "late-step-pulse" not in dump and "pytest" in dump
+        pulse._frames = frames
+        assert pulse._stacks_due(now[0] + 5.0) is None     # once a step
+        assert pulse.closed() == frames and pulse.closed() is None
+        pulse.opened("step:8", 0.5)
+        assert pulse._stacks_due(now[0] + 0.4) is None
+        assert pulse._stacks_due(now[0] + 0.6)[0] == "step:8"
+
+    def test_a_step_that_raised_is_not_compared(self, caplog):
+        import logging
+        world = _World()
+        tracer, rec = _recorder(world)
+        for n in range(1, 7):
+            _a_step(world, tracer, rec, n)
+
+        def dies():
+            world.pass_(4.0)
+            raise KeyError("the window is over")
+        with pytest.raises(KeyError):
+            _a_step(world, tracer, rec, 7, ("loop/hook", dies))
+        assert _late_events(tracer) == []
+        assert tracer.dump()[-1]["a"]["error"] == "KeyError"
+        with caplog.at_level(logging.INFO, logger="dalle_tpu.obs.late"):
+            rec.stop()
+        assert [r.getMessage() for r in caplog.records] == [
+            "late steps: 0 of 6 steps, +0.000 s in all"]
+
+    def test_one_warning_in_thirty_seconds_and_none_is_lost(self, caplog):
+        """The first late step logs its line at once; those of the next
+        30 s are held back and named by the next line, or by ``stop``."""
+        import logging
+        world = _World()
+        tracer, rec = _recorder(world)
+        slow = ("loop/loss_wait", lambda: world.pass_(0.3))
+        with caplog.at_level(logging.WARNING, logger="dalle_tpu.obs.late"):
+            for n in range(1, 7):
+                _a_step(world, tracer, rec, n)
+            for n in (7, 8, 9):
+                _a_step(world, tracer, rec, n, slow)
+            said = [r.getMessage() for r in caplog.records]
+            assert len(said) == 1 and said[0].startswith(
+                "step:7 took 1.310 s where 1.010 is usual (+0.300 s in "
+                "loop/loss_wait): device_or_runtime: pulse missed 0.00 s, "
+                "process CPU 0.01 s, machine ran 1.31 s, throttled 0.00 s")
+            assert "0 involuntary / 8 voluntary switches" in said[0]
+            for n in range(10, 40):
+                _a_step(world, tracer, rec, n)
+            _a_step(world, tracer, rec, 40, slow)
+            _a_step(world, tracer, rec, 41, slow)
+            rec.stop()
+        said = [r.getMessage() for r in caplog.records]
+        assert len(said) == 3
+        assert said[1].startswith("step:40 took") and said[1].endswith(
+            "(2 more held back since the last line: step:8 +0.300 s in "
+            "loop/loss_wait: device_or_runtime; step:9 +0.300 s in "
+            "loop/loss_wait: device_or_runtime)")
+        assert said[2] == ("1 more late steps since the last line: step:41 "
+                           "+0.300 s in loop/loss_wait: device_or_runtime")
+        assert len(_late_events(tracer)) == 5          # the ring has all
+
+    def test_stop_says_the_runs_sum(self, caplog):
+        """The operator's end-of-run line: the WARNINGs are one in 30 s,
+        this counts every late step since ``start``."""
+        import logging
+        world = _World()
+        tracer, rec = _recorder(world)
+        rec.start()
+        for n in range(1, 13):
+            _a_step(world, tracer, rec, n, {
+                7: ("loop/loss_wait", lambda: world.pass_(0.3)),
+                9: ("loop/hook", lambda: world.pass_(0.5)),
+                11: ("loop/loss_wait", lambda: world.pass_(0.2))}.get(n))
+        with caplog.at_level(logging.INFO, logger="dalle_tpu.obs.late"):
+            rec.stop()
+            rec.stop()                                 # says it once
+        assert [r.getMessage() for r in caplog.records
+                if r.levelno == logging.INFO] == [
+            "late steps: 3 of 12 steps, +1.000 s in all, "
+            "2 device_or_runtime, 1 host"]
+
+    # -- real threads, the real kernel ------------------------------------
+
+    @staticmethod
+    def _real(tmp_path=None, **kw):
+        from dalle_tpu.obs.late import LateSteps
+        tracer = Tracer(peer="late")
+        stacks = str(tmp_path / "peer.jsonl.stacks") if tmp_path else None
+        return tracer, LateSteps(tracer, stacks_path=stacks, **kw)
+
+    @staticmethod
+    def _run(tracer, rec, steps, during, quiet_s=0.06):
+        """``steps`` real steps of ``quiet_s``; ``during[n]`` = (phase,
+        what to do in it). Returns the late-step events of those steps:
+        on a loaded box a quiet step of 60 ms can come 5 ms late too."""
+        rec.start()
+        try:
+            for n in range(1, steps + 1):
+                phase, act = during.get(n, (None, None))
+                with rec.step(n):
+                    with tracer.span("train", "loop/loss_wait"):
+                        time.sleep(quiet_s)
+                        if phase == "loop/loss_wait":
+                            act()
+                    with tracer.span("train", "loop/hook"):
+                        if phase == "loop/hook":
+                            act()
+        finally:
+            rec.stop()
+        assert not [t for t in threading.enumerate()
+                    if t.name == "late-step-pulse"]
+        return [e for e in _late_events(tracer)
+                if int(e["trace"].split(":")[1]) in during]
+
+    def test_a_thread_that_keeps_the_interpreter_lock(self, tmp_path):
+        """A thread inside a native call that does not release the lock
+        (``ctypes.PyDLL``): the pulse misses the hold and wakes every 5
+        ms for the lock meanwhile. No stacks: the pulse cannot run during
+        the hold, after it the holder is gone (the C watchdog that could
+        catch it mid-call is not safe to arm, ``obs/late.py``)."""
+        import ctypes
+        libc = ctypes.PyDLL(None)
+        held = []
+
+        def keeps_the_lock():
+            t0 = time.perf_counter()
+            libc.usleep(400_000)
+            held.append(time.perf_counter() - t0)
+
+        def act():
+            thread = threading.Thread(target=keeps_the_lock, name="keeper")
+            thread.start()
+            thread.join()
+        tracer, rec = self._real(tmp_path)
+        (event,) = self._run(tracer, rec, 8, {7: ("loop/loss_wait", act)})
+        a = event["a"]
+        assert event["trace"] == "step:7" and a["where"] == "loop/loss_wait"
+        assert a["pulse_missed_s"] == pytest.approx(held[0], abs=0.05)
+        assert a["pulse_lock_waits"] >= 30          # ~80 at 5 ms each
+        assert a["process_cpu_over_s"] < 0.1        # nobody burned CPU
+        assert a["cause"] == "interpreter_held", a
+        assert a["excess_s"] == pytest.approx(0.4, abs=0.08)
+        assert "keeps_the_lock" not in a.get("stacks", "")
+
+    def test_a_hook_that_sleeps_while_the_pulse_beats(self, tmp_path):
+        tracer, rec = self._real(tmp_path)
+        (event,) = self._run(tracer, rec, 8,
+                             {7: ("loop/hook", lambda: time.sleep(0.3))})
+        a = event["a"]
+        assert a["where"] == "loop/hook" and a["cause"] == "host", a
+        assert a["where_excess_s"] == pytest.approx(0.3, abs=0.05)
+        assert a["pulse_missed_s"] < 0.1
+        stacks = tmp_path / "peer.jsonl.stacks"
+        assert a["stacks"].startswith(f"{stacks}: MainThread: test_obs.py:")
+        assert "<lambda>" in a["stacks"]             # the sleeping frame
+        dump = stacks.read_text()
+        assert dump.startswith("Late step (step:7 open 0.")
+        assert dump.count("Late step (") == 1        # once a step
+
+    def test_a_process_that_was_stopped(self, tmp_path):
+        """``SIGSTOP`` for half a second inside a step, from outside: no
+        thread of the process runs, the machine does."""
+        import signal
+        code = (
+            "import json, sys, time\n"
+            "sys.modules['jax'] = None\n"
+            "from dalle_tpu.obs.trace import Tracer\n"
+            "from dalle_tpu.obs.late import LateSteps\n"
+            "tracer = Tracer(peer='child')\n"
+            f"rec = LateSteps(tracer, stacks_path={str(tmp_path / 's')!r})\n"
+            "rec.start()\n"
+            "for n in range(1, 9):\n"
+            "    if n == 7:\n"
+            "        print('now', flush=True)\n"
+            "    with rec.step(n):\n"
+            "        with tracer.span('train', 'loop/loss_wait'):\n"
+            "            time.sleep(0.2)\n"
+            "rec.stop()\n"
+            "print(json.dumps([r for r in tracer.dump()\n"
+            "                  if r['phase'] == 'loop/late_step']))\n")
+        child = subprocess.Popen([sys.executable, "-c", code], cwd=REPO,
+                                 stdout=subprocess.PIPE, text=True)
+        try:
+            assert child.stdout.readline().strip() == "now"
+            time.sleep(0.05)
+            child.send_signal(signal.SIGSTOP)
+            time.sleep(0.5)
+            child.send_signal(signal.SIGCONT)
+            out, _ = child.communicate(timeout=60)
+        finally:
+            child.kill()
+        assert child.returncode == 0
+        (event,) = [e for e in json.loads(out.strip().splitlines()[-1])
+                    if e["trace"] == "step:7"]   # a loaded box may add one
+        a = event["a"]
+        assert a["cause"] == "process_stopped", a
+        # the step's sleep ran out while the process stood still: the
+        # excess is the stop less what was left of the sleep (0.15 s)
+        assert a["excess_s"] == pytest.approx(0.35, abs=0.1)
+        assert a["pulse_missed_s"] == pytest.approx(0.5, abs=0.1)
+        assert a["pulse_lock_waits"] <= 5 and a["process_cpu_s"] < 0.1
+        assert a["machine_ran_s"] == pytest.approx(a["step_s"], abs=0.06)
+
+    def test_every_source_missing_leaves_its_keys_out(self, tmp_path):
+        """No ``/proc/stat``, no ``/proc/pressure``, no ``cpu.stat``, a
+        backend without ``memory_stats``: a record all the same."""
+        from dalle_tpu.obs.late import HostCounters
+        nothing = str(tmp_path / "nothing")
+        tracer, rec = self._real(
+            host=HostCounters(proc_stat=nothing, pressure=nothing,
+                              cpu_stat=None),
+            device_memory=lambda: None)
+        (event,) = self._run(tracer, rec, 8,
+                             {7: ("loop/hook", lambda: time.sleep(0.3))})
+        a = event["a"]
+        assert a["cause"] == "host" and a["where"] == "loop/hook"
+        gone = {"machine_ran_s", "steal_s", "psi_cpu_s", "psi_io_s",
+                "psi_mem_s", "throttled_s", "mem_in_use_delta",
+                "mem_allocs_delta"}
+        assert not gone & set(a), gone & set(a)
+        assert {"process_cpu_s", "vol_switches", "invol_switches",
+                "major_faults", "pulse_missed_s", "gc_s", "gc_n"} <= set(a)
+
+    def test_what_the_device_holds_is_read_through_the_callable(self):
+        held = {"bytes_in_use": 1000, "num_allocs": 10,
+                "peak_bytes_in_use": 1000, "largest_alloc_size": 7}
+
+        def device_memory():
+            held["num_allocs"] += 1          # an allocator that counts
+            return dict(held)
+
+        def grows():
+            held["bytes_in_use"] = 5000
+            held["num_allocs"] += 4
+            time.sleep(0.3)
+        tracer, rec = self._real(device_memory=device_memory)
+        (event,) = self._run(tracer, rec, 8, {7: ("loop/hook", grows)})
+        a = event["a"]
+        assert (a["mem_in_use_delta"], a["mem_allocs_delta"]) == (4000, 5)
+        assert not [k for k in a if k.startswith("mem_peak")]
+        # no trace file: the stacks go to standard error
+        assert a["stacks"].startswith("standard error: MainThread: ")
+
+    def test_a_file_that_feeds_only_absent_keys_is_not_read_again(self):
+        from dalle_tpu.obs.late import HostCounters
+        read = HostCounters()
+        if "machine_ran_s" not in read():
+            pytest.skip("no /proc/stat here")
+        read.leave_out({"vol_switches"})
+        assert "machine_ran_s" in read()
+        read.leave_out({"machine_ran_s", "steal_s"})
+        assert not {"machine_ran_s", "steal_s"} & set(read())
+
+    def test_the_kernels_counters_as_they_are_here(self):
+        """What this machine has is read and only grows."""
+        from dalle_tpu.obs.late import HostCounters
+        read = HostCounters()
+        first = read()
+        sum(i * i for i in range(200_000))
+        second = read()
+        assert second["process_cpu_s"] > first["process_cpu_s"]
+        assert "MainThread" in second["threads"]
+        for key, value in first.items():
+            if key != "threads":
+                assert second[key] >= value, key
+        if os.path.exists("/proc/stat"):
+            assert second["machine_ran_s"] > 0
+
+    def test_the_recorder_imports_and_records_without_jax(self):
+        code = (
+            "import sys, time\n"
+            "sys.modules['jax'] = None\n"
+            "import dalle_tpu.obs as obs\n"
+            "from dalle_tpu.obs.late import LateSteps\n"
+            "t = obs.configure(peer='x')\n"
+            "rec = LateSteps(t)\n"
+            "rec.start()\n"
+            "for n in range(1, 9):\n"
+            "    with rec.step(n) as row:\n"
+            "        with t.span('train', 'loop/hook'):\n"
+            "            time.sleep(0.3 if n == 7 else 0.02)\n"
+            "rec.stop()\n"
+            "late = [r for r in t.dump() if r['phase'] == 'loop/late_step']\n"
+            "late = [r for r in late if r['trace'] == 'step:7']\n"
+            "assert len(late) == 1 and late[0]['a']['cause'] == 'host', late\n"
+            "assert 'jax' not in [m for m in sys.modules "
+            "if sys.modules[m] is not None]\n")
+        done = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                              capture_output=True, text=True, timeout=120)
+        assert done.returncode == 0, done.stderr[-2000:]
+        assert "step:7 took" in done.stderr          # the WARNING's line
+
+
+@pytest.fixture(scope="module")
+def late_loop(tmp_path_factory):
+    """A tiny-preset ``train_loop`` on the CPU with a trace file: its hook
+    sleeps once (step 7) and swaps the grad step for a new ``jax.jit`` of
+    it once (step 9, so that step 10 traces and compiles again). CPU steps
+    of milliseconds scatter by more than 5 ms on a loaded box, so the
+    floor is raised for the run."""
+    import logging
+
+    from dalle_tpu.obs import compiles, late
+    from dalle_tpu.training.loop import train_loop
+    from dalle_tpu.training.steps import make_grad_step
+    from tests.test_trainer_spans import _make_task
+    tmp = tmp_path_factory.mktemp("late")
+    trace_file = tmp / "peer.jsonl"
+    records = []
+
+    class Keep(logging.Handler):
+        def emit(self, record):
+            records.append(record)
+    keep = Keep(logging.INFO)
+    log = logging.getLogger("dalle_tpu.obs.late")
+    log.addHandler(keep)
+    level = log.level
+    log.setLevel(logging.INFO)
+    floor, late.LATE_FLOOR_S = late.LATE_FLOOR_S, 0.25
+
+    def on_step(n, loss):
+        if n == 7:
+            time.sleep(0.5)
+        if n == 9:
+            task.__dict__["grad_step"] = jax.jit(make_grad_step(task.model))
+    try:
+        with _make_task(tmp, trace_file=str(trace_file)) as task:
+            train_loop(task, max_steps=12, warmup_steps=1,
+                       publish_metrics_records=False, on_step=on_step)
+            task.tracer.flush()
+            threads = [t.name for t in threading.enumerate()]
+            yield {"rows": task.tracer.dump(), "said": [
+                r.getMessage() for r in records
+                if r.levelno == logging.WARNING], "threads": threads,
+                "told": [r.getMessage() for r in records
+                         if r.levelno == logging.INFO],
+                "trace_file": trace_file}
+    finally:
+        late.LATE_FLOOR_S = floor
+        log.removeHandler(keep)
+        log.setLevel(level)
+        compiles.install(None)
+
+
+class TestLateStepsInTheLoop:
+    def test_one_event_for_the_step_whose_hook_slept(self, late_loop):
+        events = _late_events(late_loop["rows"])
+        assert [e["trace"] for e in events] == ["step:7", "step:10"]
+        a = events[0]["a"]
+        assert a["where"] == "loop/hook" and a["cause"] == "host", a
+        # the hook's own median is microseconds; the step's moves with
+        # the load on the box
+        assert a["where_excess_s"] == pytest.approx(0.5, abs=0.1)
+        assert 0.25 < a["excess_s"] < 0.6
+        # taken once, when the step had been open 1.25 x the usual: in
+        # the hook's sleep, or on a loaded box still in the loss's wait
+        assert a["stacks"].startswith(
+            str(late_loop["trace_file"]) + ".stacks: MainThread: ")
+        assert a["hook_or_after"] == 1
+        total = sum(e["a"]["excess_s"] for e in events)
+        assert late_loop["told"][-1] == (
+            f"late steps: 2 of 12 steps, +{total:.3f} s in all, 1 host, "
+            "1 compile")
+
+    def test_a_forced_recompile_is_named(self, late_loop):
+        event = _late_events(late_loop["rows"])[1]
+        a = event["a"]
+        assert a["cause"] == "compile", a
+        assert a["compiles"] >= 1 and a["compile_s"] >= a["excess_s"] / 2
+
+    def test_one_warning_and_the_held_back_one_at_the_end(self, late_loop):
+        first, last = late_loop["said"]
+        assert first.startswith("step:7 took ") and ": host: " in first
+        assert "in loop/hook" in first and "stacks in " in first
+        assert last.startswith("1 more late steps since the last line: "
+                               "step:10 +")
+        assert last.endswith(": compile")
+
+    def test_the_event_is_in_the_trace_file_and_the_report(self, late_loop,
+                                                           capsys):
+        from scripts import trace_report
+        rows = load_jsonl(str(late_loop["trace_file"]))
+        assert [r["trace"] for r in _late_events(rows)] == ["step:7",
+                                                            "step:10"]
+        rep = trace_report.build_report([str(late_loop["trace_file"])])
+        assert [(s["trace"], s["where"], s["cause"])
+                for s in rep["late_steps"]] == [
+            ("step:7", "loop/hook", "host"),
+            ("step:10", rep["late_steps"][1]["where"], "compile")]
+        assert trace_report.main([str(late_loop["trace_file"])]) == 0
+        out = capsys.readouterr().out
+        assert "late step: " in out and " step:7 took " in out
+        assert ": host: " in out
+
+    def test_an_edge_asks_one_device_on_a_host_of_four(self, monkeypatch):
+        """``TrainingTask`` hands the recorder the fullest local device's
+        statistics: the device is chosen at the first reading, and every
+        later edge asks that one alone."""
+        import types
+
+        from dalle_tpu import task as task_module
+
+        class Device:
+            def __init__(self, held):
+                self.held, self.asked = held, 0
+
+            def memory_stats(self):
+                self.asked += 1
+                return {"bytes_in_use": self.held, "num_allocs": self.asked}
+        devices = [Device(held) for held in (3, 9, 5, 1)]
+        monkeypatch.setattr(task_module.jax, "local_devices",
+                            lambda: devices)
+        me = types.SimpleNamespace(
+            tracer=Tracer(peer="four"), compiles=None,
+            family=types.SimpleNamespace(SLOW_STEP_ATTRIBUTES=()),
+            collab_cfg=types.SimpleNamespace(trace_file=None))
+        rec = task_module.TrainingTask.late_steps.func(me)
+        for _ in range(5):
+            assert rec.device_memory()["bytes_in_use"] == 9
+        assert [d.asked for d in devices] == [1, 6, 1, 1]
+
+    def test_the_pulse_and_the_callback_are_gone_after_the_loop(self,
+                                                                late_loop):
+        import gc
+        assert "late-step-pulse" not in late_loop["threads"]
+        assert not [c for c in gc.callbacks
+                    if getattr(c, "__self__", None).__class__.__name__
+                    == "LateSteps"]
+
+    def test_they_are_gone_when_the_loop_raises(self, tmp_path, caplog):
+        import logging
+        from dalle_tpu.obs import compiles
+        from dalle_tpu.training.loop import train_loop
+        from tests.test_trainer_spans import _make_task
+
+        class Over(Exception):
+            pass
+
+        def on_step(n, loss):
+            if n == 6:
+                assert [t for t in threading.enumerate()
+                        if t.name == "late-step-pulse"]
+                raise Over()
+        try:
+            with _make_task(tmp_path) as task:
+                with pytest.raises(Over), caplog.at_level(
+                        logging.INFO, logger="dalle_tpu.obs.late"):
+                    train_loop(task, warmup_steps=1,
+                               publish_metrics_records=False,
+                               on_step=on_step)
+                assert [r.getMessage() for r in caplog.records
+                        if " of 5 steps" in r.getMessage()]
+        finally:
+            compiles.install(None)
+        assert not [t for t in threading.enumerate()
+                    if t.name == "late-step-pulse"]
