@@ -13,8 +13,9 @@ Phases, all but the last counted as set-up:
    ones included: minimum compile time 0);
 2. ``TrainingTask`` and its sharded ``train_state`` from ``--seed``
    (``init_s``);
-3. Mosaic census of the lowered grad step (a dispatcher that gave way to
-   XLA is ``correct: false``);
+3. Mosaic census of the lowered grad step, held to the roles the
+   configuration lists (``mosaic_census``: a layer that gave way to XLA is
+   ``correct: false``; how a role is split into kernels is the program's);
 4. reference check: the system's real grad step on a batch that tiles two
    seeded sequences, against the reference of the configuration's
    yardstick (``cell.yardstick``, ``benchmark/yardsticks/<name>.py``) on
@@ -77,6 +78,24 @@ def kernel_census(lowered_text: str) -> collections.Counter:
     (copy of ``chip_smoke.kernel_census``)."""
     return collections.Counter(
         re.findall(r'kernel_name = "([^"]+)"', lowered_text))
+
+
+def mosaic_census(lowered_text: str, roles: List[str]) -> Dict[str, Any]:
+    """A lowered program's Mosaic kernels held to a configuration's
+    ``mosaic_kernels``. Each entry names a **role** as a regular expression
+    that is ``re.fullmatch``ed against the kernel names (a plain name is its
+    own expression): ``missing`` are the roles no kernel fills, which fail
+    ``correct``; ``unlisted`` the kernels no role names, with their counts,
+    which are printed and fail nothing. The one place that matches: the
+    harness and the tests under ``tests/benchmark_tests/`` both call it."""
+    found = kernel_census(lowered_text)
+    fills = {role: [name for name in found if re.fullmatch(role, name)]
+             for role in roles}
+    named = {name for names in fills.values() for name in names}
+    return {"found": dict(found),
+            "missing": [role for role in roles if not fills[role]],
+            "unlisted": {name: n for name, n in found.items()
+                         if name not in named}}
 
 
 class CompileLog:
@@ -374,12 +393,28 @@ def census_check(task, cell: Cell, batch) -> Dict[str, Any]:
     lowered = task.grad_step.lower(task.train_state.params, batch)
     # a cache load: the loop's own call compiled the same module
     plan = lowered.compile().memory_analysis()
-    census = kernel_census(lowered.as_text())
-    return {"found": dict(census),
-            "missing": [k for k in cell.config["mosaic_kernels"]
-                        if not census.get(k)],
-            "grad_step_plan_bytes":
-                int(getattr(plan, "temp_size_in_bytes", 0) or 0)}
+    return dict(mosaic_census(lowered.as_text(),
+                              cell.config["mosaic_kernels"]),
+                grad_step_plan_bytes=int(
+                    getattr(plan, "temp_size_in_bytes", 0) or 0))
+
+
+def engagement_records() -> Dict[str, str]:
+    """What the program's mechanisms said of themselves when the step was
+    traced: every attribute of the ``setup/warmup`` ring row that is a
+    sentence (read as ``reducers/program_attr.py`` reads a row's; its
+    counts, such as ``steps``, are left out), whatever the program names
+    them, so that a mechanism a later PR adds is printed with no edit here.
+    Printed beside the census; no metric reads them. Empty where the
+    program keeps no ring."""
+    from benchmark.reducers import program_span
+    said: Dict[str, str] = {}
+    for row in program_span.ring_rows() or []:
+        if (row.get("plane"), row.get("phase")) == (program_span.PLANE,
+                                                    "setup/warmup"):
+            said.update({k: v for k, v in row.get("a", {}).items()
+                         if isinstance(v, str)})
+    return said
 
 
 def reference_check(task, cell: Cell, seed: int) -> Dict[str, Any]:
@@ -521,6 +556,12 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         del first_batch
         t = mark("census_s", t)
         correct = bool(ref["ok"] and not census["missing"])
+        # each number that decided it, beside its limit
+        compared = {
+            "loss_rel": [ref["loss_rel_err"], ref["tolerance"]["loss_rel"]],
+            "grad_rel_l2": [ref["grad_rel_l2_max"],
+                            ref["tolerance"]["grad_rel_l2"]],
+            "census_missing": [len(census["missing"]), 0]}
 
         # -- 5, 6: the production loop --------------------------------------
         trace_dir = out_dir / "trace" if trace else None
@@ -627,6 +668,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         device["busy_s"] = reduced.busy_s
         device["window_s"] = reduced.window_s
         result["breakdown"] = reduced.breakdown()
+    result["compared"] = compared          # last in the line
 
     # earlier lines, for the record: they decide nothing
     say(json.dumps({"setup": {k: round(v, 3) for k, v in setup.items()},
@@ -641,7 +683,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
                     "shutdown_s": round(t_end - last_stamps[-1], 3),
                     # reading and reducing the trace file, after the window
                     "trace_read_s": round(trace_read_s, 3)}))
-    say(json.dumps({"reference_check": ref, "census": census}))
+    say(json.dumps({"reference_check": ref, "census": census,
+                    "engagement": engagement_records()}))
     for i, r in enumerate(results):
         say(json.dumps({"window": i, **r}))
     slow = sorted(compile_by_program.items(), key=lambda kv: -kv[1])[:8]

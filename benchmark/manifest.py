@@ -1,4 +1,4 @@
-"""BENCHMARK.json and the data files it names.
+r"""BENCHMARK.json and the data files it names.
 
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file of its own, found by the name in
@@ -41,6 +41,20 @@ throw-away root besides):
   for (guide ``model-configs`` section 4: not all the chips the model
   needs); and ``deployment``, that deployment in words. An uncut one
   (``reduced`` empty) has neither ``published`` nor ``layer_shared_by``.
+- ``mosaic_kernels`` lists everything the configuration's step must run
+  on Mosaic. An entry names a **role**, as a regular expression that
+  ``harness.mosaic_census`` ``re.fullmatch``es against the ``kernel_name``s
+  of the lowered grad step: a plain name is its own expression
+  (``_bwd_kernel`` is not filled by ``_win_bwd_kernel``), and
+  ``_causal_(?!fwd_)\w+`` says "attention's backward, however many kernels
+  that is". A role no kernel fills is ``missing`` and the run is
+  ``correct: false``; a kernel no role names is ``unlisted``, printed with
+  its count in the run's ``census`` record and failing nothing: it is
+  where the next ``benchmark`` PR sees a list fall behind. So a PR that
+  fuses, splits or renames kernels inside a role edits no file, one that
+  adds a kernel runs and shows under ``unlisted``, and one whose layer
+  leaves Mosaic for the XLA lowering fails. Every entry compiles as an
+  expression.
 - ``flagship`` and ``xl`` are pinned besides to what they are:
   ``reduced`` ``[]`` in both, ``assumed`` ``[]`` and ``dim``, ``heads``,
   ``vocab_image``.

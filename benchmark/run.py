@@ -5,8 +5,13 @@
 The last line of standard output is one JSON object with ``correct``,
 ``attempted``, ``failed``, ``metrics`` and ``device`` (and ``breakdown`` in
 a traced run): the cell's end-to-end metrics with ``--trace 0``, its
-per-layer metrics with ``--trace 1``. Earlier lines itemise set-up, the
-reference check, and every window's step intervals; they decide nothing.
+per-layer metrics with ``--trace 1``. Its last key, ``compared``, holds each
+number that decided ``correct`` beside its limit; the same are the last
+lines of standard error, both by the benchmark's contract: of a run that is
+not correct the driver's record keeps the end of each stream and nothing
+else. Earlier lines itemise set-up, the reference check
+with the census and the program's engagement records, and every window's
+step intervals; they decide nothing.
 Without a TPU, or with another number of chips than the cell asks for, it
 prints no result and exits non-zero.
 
@@ -52,6 +57,10 @@ def main(argv=None) -> int:
     except (BenchFailure, intervals.TooFewIntervals) as e:
         print(f"benchmark: no result: {e}", file=sys.stderr, flush=True)
         return 3
+    for name, (value, limit) in result["compared"].items():
+        print(f"compared {name}: {value!r} (limit {limit!r})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

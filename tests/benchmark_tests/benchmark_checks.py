@@ -116,6 +116,12 @@ def configuration_file(man, config, presets):
         f"{who}: reduced is {on_file['reduced']} in the file and "
         f"{entry['reduced']} in BENCHMARK.json")
     assert on_file["source"] == entry["source"], f"{who}: source differs"
+    for role in on_file["mosaic_kernels"]:
+        try:
+            re.compile(role)
+        except re.error as e:
+            raise AssertionError(f"{who}: mosaic_kernels entry {role!r} is "
+                                 f"no regular expression: {e}") from e
     reduced, assumed = on_file["reduced"], on_file["assumed"]
     for listed, keys in (("reduced", reduced), ("assumed", assumed)):
         for key in keys:

@@ -53,13 +53,13 @@ def _rehearse(tmp_path, chips, trace, seconds):
          str(tmp_path)], cwd=ROOT, env=env, capture_output=True, text=True,
         timeout=900)
     assert done.returncode == 0, done.stderr[-3000:]
-    last = done.stdout.strip().splitlines()[-1]
+    *earlier, last = done.stdout.strip().splitlines()
     assert last.startswith("REHEARSAL")
-    return json.loads(last.split(":", 1)[1]), tmp_path / "run"
+    return json.loads(last.split(":", 1)[1]), tmp_path / "run", earlier
 
 
 def test_rehearsal_solo_end_to_end(tmp_path):
-    result, out = _rehearse(tmp_path, 1, 0, 4)
+    result, out, earlier = _rehearse(tmp_path, 1, 0, 4)
     assert result["correct"] is True and result["failed"] == 0
     assert set(result["metrics"]) == {"train_tokens_per_s", "setup_s"}
     assert result["device"]["platform"] == "cpu"   # a rehearsal, no result
@@ -75,10 +75,27 @@ def test_rehearsal_solo_end_to_end(tmp_path):
     assert result["metrics"]["train_tokens_per_s"]["value"] \
         == window["train_tokens_per_s"]
     assert set(window["host"]) >= {"process_cpu_s", "involuntary_switches"}
+    # each number that decided ``correct`` beside its limit, last in the line
+    assert list(result)[-1] == "compared"
+    assert set(result["compared"]) == {"loss_rel", "grad_rel_l2",
+                                       "census_missing"}
+    for value, limit in result["compared"].values():
+        assert value <= limit
+    # the line of the reference check carries the census and the program's
+    # engagement records: every sentence of the ``setup/warmup`` row, its
+    # counts left out; a DALL-E model has no ``moe_layout`` to give
+    line = next(json.loads(line) for line in earlier
+                if line.startswith('{"reference_check"'))
+    assert line["census"]["missing"] == []
+    assert line["census"]["unlisted"] == {} == line["census"]["found"]
+    said = line["engagement"]
+    assert set(said) >= {"attn_layout", "layer_loop", "grad_reduction"}
+    assert "moe_layout" not in said and "steps" not in said
+    assert all(isinstance(v, str) for v in said.values())
 
 
 def test_rehearsal_solo_traced(tmp_path):
-    result, out = _rehearse(tmp_path, 1, 1, 4)
+    result, out, _ = _rehearse(tmp_path, 1, 1, 4)
     assert result["correct"] is True
     got = result["metrics"]
     for name in ("step_interval_s", "stall_pct", "host_freeze_s",
@@ -100,6 +117,6 @@ def test_rehearsal_solo_traced(tmp_path):
 
 @pytest.mark.slow
 def test_rehearsal_dp4_on_four_virtual_devices(tmp_path):
-    result, _ = _rehearse(tmp_path, 4, 0, 4)
+    result, _, _ = _rehearse(tmp_path, 4, 0, 4)
     assert result["correct"] is True
     assert result["device"]["count"] == 4

@@ -268,6 +268,10 @@ def _entry_disagrees(data, on_file):
     data["configs"][-1]["reduced"] = ["layers"]
 
 
+def _role_that_does_not_compile(data, on_file):
+    on_file["mosaic_kernels"] = ["_fwd_kernel", "_causal_(?!fwd_"]
+
+
 def _cut_flagship(root):
     """``flagship`` given a cut that the general rule would admit: only
     its pin refuses it. (A throw-away copy: no PR may edit the real one.)"""
@@ -292,12 +296,16 @@ def _cut_flagship(root):
      r"'vocab_text'\] in the file and \['layers'\] in BENCHMARK.json"),
     ("cut_flagship", "flagship",
      r"configuration flagship: reduced is pinned to \[\], not \['depth'\]"),
+    ("role_that_does_not_compile", STANDIN,
+     r"configuration standin: mosaic_kernels entry '_causal_\(\?!fwd_' is no "
+     r"regular expression"),
 ])
 def test_a_configuration_that_breaks_the_rule_is_refused(
         tmp_path, presets, case, config, message):
     mutate = {"no_such_key": _no_such_key, "no_published": _no_published,
               "entry_disagrees": _entry_disagrees,
-              "cut_flagship": _cut_flagship(tmp_path)}[case]
+              "cut_flagship": _cut_flagship(tmp_path),
+              "role_that_does_not_compile": _role_that_does_not_compile}[case]
     man, _ = _standin_root(tmp_path, presets, mutate)
     with pytest.raises(AssertionError, match=message):
         checks.configuration_file(man, config, presets)

@@ -15,8 +15,7 @@ import pytest
 
 from benchmark import manifest as M
 from benchmark import trace as T
-from benchmark.harness import RunContext, peaks_for
-from benchmark.harness import kernel_census as harness_census
+from benchmark.harness import RunContext, mosaic_census, peaks_for
 from dalle_tpu.cli.run_trainer import MODEL_PRESETS
 
 ROOT = M.ROOT
@@ -295,9 +294,21 @@ def test_a_traced_rehearsal_of_the_preset_runs_through_the_harness(
     for name in (*OWN_METRICS, f"attn_roofline.{CELL}",
                  f"moe_router_share_pct.{CELL}"):
         assert name not in got
-    check = [json.loads(line) for line in done.stdout.splitlines()
-             if line.startswith('{"reference_check"')][0]["reference_check"]
+    line = [json.loads(line) for line in done.stdout.splitlines()
+            if line.startswith('{"reference_check"')][0]
+    check = line["reference_check"]
     assert check["loss_rel_err"] < 1e-5 and check["grad_rel_l2_max"] < 1e-4
+    # the same line carries the census and the mechanisms' own word on how
+    # they engaged (the ``setup/warmup`` row's attributes)
+    assert line["census"]["missing"] == [] == list(
+        line["census"]["unlisted"])         # interpreted: no Mosaic call
+    said = line["engagement"]
+    assert set(said) >= {"attn_layout", "moe_layout", "layer_loop",
+                         "grad_reduction"}
+    assert said["attn_layout"].startswith("blockwise ")
+    assert "experts held" in said["moe_layout"]
+    assert list(result)[-1] == "compared"
+    assert result["compared"]["grad_rel_l2"][0] == check["grad_rel_l2_max"]
 
 
 @pytest.mark.slow
@@ -347,9 +358,9 @@ def test_the_real_widths_compile_for_a_described_v5e_and_fit():
         compiled = lowered.compile()
     finally:
         jax.default_backend = default_backend
-    lowered_kernels = harness_census(lowered.as_text())
-    for kernel in cell.config["mosaic_kernels"]:
-        assert lowered_kernels[kernel], kernel
+    census = mosaic_census(lowered.as_text(), cell.config["mosaic_kernels"])
+    # ``unlisted`` is the program's to grow: the run's record prints it
+    assert census["missing"] == [], census
     text = compiled.as_text()
     assert not re.findall(r"(?:f32|bf16)\[[0-9,]*8192,8192[0-9,]*\]", text)
     plan = compiled.memory_analysis().temp_size_in_bytes
