@@ -121,8 +121,11 @@ from dalle_tpu.parallel.mesh import (LANES_SPEC, per_shard,
 ROWS_OVER_EXPECTED = 2.0
 
 # (kind, tokens, query lanes, key-value lanes) -> whether a traced call of
-# those local shapes took the blockwise kernel: what attn_layout reads
-_KERNEL_CHOICES: Dict[Tuple[str, int, int, int], bool] = {}
+# those local shapes took the blockwise kernel, and why its backward is the
+# dq and the dk/dv kernel (None where it is the one kernel a tile): what
+# attn_layout reads
+_KERNEL_CHOICES: Dict[Tuple[str, int, int, int],
+                      Tuple[bool, Optional[str]]] = {}
 
 
 # (tokens, lanes of the whole array, head_dim) -> why the last traced head
@@ -193,17 +196,25 @@ def dense_causal_attention(q, k, v, window: Optional[int],
     return out.reshape(q.shape).astype(q.dtype)
 
 
+def _backward_words(split_why: Optional[str]) -> str:
+    return (f"dq + dk/dv kernels ({split_why})" if split_why
+            else "one kernel a tile")
+
+
 def _attend_shard(q, k, v, *, kind: str, window: Optional[int],
                   head_dim: int):
     """One shard's attention: the blockwise kernel where it fits."""
     why_not = kernels.blockwise_fits(q.shape[2], k.shape[2], head_dim)
+    group = q.shape[2] // k.shape[2]
+    split_why = None if why_not else kernels.fused_backward_fits(
+        q.shape[1], group, q.dtype.itemsize)
     _KERNEL_CHOICES[kind, q.shape[1], q.shape[2], k.shape[2]] = \
-        why_not is None
+        (why_not is None, split_why)
     attn_mod.log_kernel_choice(
         f"{kind} attention", why_not is None,
         why_not or f"local q{tuple(q.shape)} over k{tuple(k.shape)}: "
-        f"blocks of {kernels.BLOCK}, "
-        f"{q.shape[2] // k.shape[2]} query heads a key-value tile")
+        f"blocks of {kernels.BLOCK}, {group} query heads a key-value tile, "
+        + _backward_words(split_why))
     if why_not is not None:
         return dense_causal_attention(q, k, v, window, head_dim)
     return kernels.causal_attention(q, k, v, window, kernels.BLOCK,
@@ -930,14 +941,24 @@ def init_params(model: SparseLM, rng: jax.Array, batch: int = 2):
 
 def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     """The ``setup/warmup`` row's attributes: which layers' traced calls
-    took the blockwise kernel (looked up in what the dispatcher did), how
-    the layers run, and what the expert layer holds."""
+    took the blockwise kernel and which backward those took (looked up in
+    what the dispatcher did), how the layers run, and what the expert layer
+    holds."""
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     widths = (cfg.num_heads * cfg.head_dim // tp,
               cfg.num_kv_heads * cfg.head_dim // tp)
     kinds = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
-    on = sum(_KERNEL_CHOICES.get((k, cfg.total_seq_len, *widths), False)
-             for k in kinds)
+    choices = [_KERNEL_CHOICES.get((k, cfg.total_seq_len, *widths),
+                                   (False, None)) for k in kinds]
+    on = sum(took for took, _ in choices)
+    # the backward of the layers that took the kernel: one length, group and
+    # dtype, so one answer
+    split_why = next((why for took, why in choices if took and why), None)
+    backward = ""
+    if on:
+        backward = ", backward: " + _backward_words(split_why)
+        if not split_why:
+            backward += f" ({on} of {len(kinds)} layers)"
     windows = sum(k == LAYER_WINDOW_ROPE for k in kinds)
     # every layer's head norms are the same two shapes: all took the pass
     # on the lanes, or the first refusal says why none did
@@ -971,7 +992,7 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
             f"blockwise {kernels.BLOCK}: {on} of {len(kinds)} layers, "
             f"{len(kinds) - windows} full no-rope + {windows} window "
             f"{cfg.window} rope, {cfg.num_heads // cfg.num_kv_heads} query "
-            f"heads a key-value head"
+            f"heads a key-value head{backward}"
             + (", normed queries and keys ("
                + (f"XLA: {norm_why_not}" if norm_why_not else
                   f"one pass on the lanes: {len(kinds)} of {len(kinds)} "

@@ -14,9 +14,23 @@ block) pairs hold an allowed pair (key <= query, and with ``window``
 query - key < window) is static, so the grid's last axis runs over a list
 of exactly those pairs, handed to the kernel as scalar-prefetch tables (a
 window layer of 4096 at T = 8192 visits 3/4 of a causal layer's tiles).
-The forward and the ``dq`` kernel walk the list query-block-major and keep
-a query block's accumulators in VMEM over its key blocks; the ``dk``/``dv``
-kernel walks it key-block-major.
+The forward walks the list query-block-major and keeps a query block's
+accumulators in VMEM over its key blocks (2 MXU products a tile and head:
+``q k^T``, ``p v``).
+
+**The backward computes a tile's probabilities once.** ``_causal_bwd_kernel``
+walks the same query-block-major list and makes, a tile and head, the
+probabilities and ``do v^T`` once and ``dq``, ``dk`` and ``dv`` from them:
+5 products (``q k^T``, ``do v^T``, ``ds k``, ``p^T do``, ``ds^T q``), 2 + 5
+= 7 with the forward's. ``dq`` is a query block's scratch, as the forward's
+accumulators are; ``dk`` and ``dv`` are summed in (T, 128) f32 accumulators
+that stay in VMEM for one key-value head's whole sequence (T KiB the pair).
+Those grow with T, so :func:`fused_backward_fits`, a pure function of the
+local shapes against the VMEM the call asks for, says where they no longer
+fit; past that the backward is the two kernels it was before, ``dq``
+query-block-major (3 products) and ``dk``/``dv`` key-block-major (4, the
+scores and ``do v^T`` a second time: 2 + 7 = 9). Every accumulator sums in
+the same order either way.
 
 **Layout.** As the zoo kernels since PR 28, the projections' own
 tokens-major arrays, one 128-wide head a lane tile (``head_dim`` is 128):
@@ -47,6 +61,9 @@ from jax.experimental.pallas import tpu as pltpu
 LANES = 128
 BLOCK = 512
 MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
+# what every call asks of the compiler, and what the one-kernel backward's
+# accumulators have to fit in (tests shrink it to reach the split kernels)
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _NT = (((1,), (1,)), ((), ()))       # a @ b.T
 
 
@@ -58,6 +75,28 @@ def blockwise_fits(q_width: int, kv_width: int, head_dim: int) -> Optional[str]:
         return f"{q_width} query lanes over {kv_width} key-value lanes"
     if q_width // kv_width > LANES:
         return "more query heads a group than statistics lanes"
+    return None
+
+
+def fused_backward_fits(tokens: int, group: int, itemsize: int,
+                        block: int = BLOCK) -> Optional[str]:
+    """None where the one-kernel backward holds a key-value head's whole
+    ``dk`` and ``dv`` in VMEM at these local shapes (``tokens`` a sample,
+    ``group`` query heads a key-value head, operands of ``itemsize``
+    bytes), else why not: then the backward is the ``dq`` and the
+    ``dk``/``dv`` kernel, which hold a block each."""
+    t = tokens + -tokens % block
+    tile = block * LANES
+    need = (2 * t * LANES * 4                   # dk, dv accumulators, f32
+            + 2 * 2 * t * LANES * itemsize      # their outputs, two buffers
+            + group * tile * 4                  # dq's accumulator
+            + 3 * 2 * group * tile * itemsize   # q, do, dq tiles
+            + 2 * 2 * tile * itemsize           # k, v tiles
+            + 2 * 2 * tile * 4                  # statistics, delta
+            + 4 * block * block * 4)            # scores, p, dp, ds
+    if need > VMEM_LIMIT_BYTES:
+        return (f"dk and dv of {t} tokens need {need / 2 ** 20:.1f} MiB of "
+                f"VMEM, over {VMEM_LIMIT_BYTES / 2 ** 20:g}")
     return None
 
 
@@ -93,11 +132,15 @@ def _head(h: int) -> slice:
     return slice(h * LANES, (h + 1) * LANES)
 
 
-def _probabilities(q, k, allowed, lse, scale):
-    """A tile's probabilities from the saved statistics (backward)."""
+def _tile_backward(q, do, k, v, allowed, lse, delta, scale):
+    """One head's (block, block) tile in the backward: its probabilities
+    from the saved statistics, and the scores' cotangent (times ``scale``,
+    so that it is q's and k's), both f32."""
     s = jax.lax.dot_general(q, k, _NT,
                             preferred_element_type=jnp.float32) * scale
-    return jnp.exp(jnp.where(allowed, s, MASK_VALUE) - lse)
+    prob = jnp.exp(jnp.where(allowed, s, MASK_VALUE) - lse)
+    dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
+    return prob, prob * (dp - delta) * scale
 
 
 def _causal_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
@@ -153,11 +196,9 @@ def _causal_dq_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
     k, v = k_ref[0], v_ref[0]
     stats, delta = stats_ref[0, 0], delta_ref[0, 0]
     for h in range(group):
-        prob = _probabilities(q_ref[0, :, _head(h)], k, allowed,
-                              stats[:, h:h + 1], scale)
-        dp = jax.lax.dot_general(do_ref[0, :, _head(h)], v, _NT,
-                                 preferred_element_type=jnp.float32)
-        ds = prob * (dp - delta[:, h:h + 1]) * scale
+        _, ds = _tile_backward(q_ref[0, :, _head(h)], do_ref[0, :, _head(h)],
+                               k, v, allowed, stats[:, h:h + 1],
+                               delta[:, h:h + 1], scale)
         dq_s[:, _head(h)] += jnp.dot(ds.astype(k.dtype), k,
                                      preferred_element_type=jnp.float32)
 
@@ -182,16 +223,58 @@ def _causal_dkv_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
     stats, delta = stats_ref[0, 0], delta_ref[0, 0]
     for h in range(group):
         q, do = q_ref[0, :, _head(h)], do_ref[0, :, _head(h)]
-        prob = _probabilities(q, k, allowed, stats[:, h:h + 1], scale)
-        dp = jax.lax.dot_general(do, v, _NT,
-                                 preferred_element_type=jnp.float32)
-        ds = prob * (dp - delta[:, h:h + 1]) * scale
+        prob, ds = _tile_backward(q, do, k, v, allowed, stats[:, h:h + 1],
+                                  delta[:, h:h + 1], scale)
         dv_s[...] += jnp.dot(prob.T.astype(do.dtype), do,
                              preferred_element_type=jnp.float32)
         dk_s[...] += jnp.dot(ds.T.astype(q.dtype), q,
                              preferred_element_type=jnp.float32)
 
     @pl.when(last_ref[p] == 1)
+    def _():
+        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _causal_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
+                       v_ref, do_ref, stats_ref, delta_ref, dq_ref, dk_ref,
+                       dv_ref, dq_s, dk_s, dv_s, *, scale: float, group: int,
+                       block: int, window: Optional[int]):
+    """``dq``, ``dk`` and ``dv`` from one pass over the band, query-block-
+    major: ``dq_s`` holds a query block over its key blocks, ``dk_s`` and
+    ``dv_s`` (T, 128) a key-value head's whole sequence over all pairs."""
+    p = pl.program_id(2)
+
+    @pl.when(p == 0)
+    def _():
+        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    @pl.when(first_ref[p] == 1)
+    def _():
+        dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
+
+    allowed = _allowed(qi_ref[p], ki_ref[p], block, window)
+    k, v = k_ref[0], v_ref[0]
+    stats, delta = stats_ref[0, 0], delta_ref[0, 0]
+    keys = pl.ds(pl.multiple_of(ki_ref[p] * block, block), block)
+    for h in range(group):
+        q, do = q_ref[0, :, _head(h)], do_ref[0, :, _head(h)]
+        prob, ds = _tile_backward(q, do, k, v, allowed, stats[:, h:h + 1],
+                                  delta[:, h:h + 1], scale)
+        # cast to the operands' dtype once, and transpose the narrow copy
+        ds = ds.astype(k.dtype)
+        dq_s[:, _head(h)] += jnp.dot(ds, k,
+                                     preferred_element_type=jnp.float32)
+        dv_s[keys, :] += jnp.dot(prob.astype(do.dtype).T, do,
+                                 preferred_element_type=jnp.float32)
+        dk_s[keys, :] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+
+    @pl.when(last_ref[p] == 1)
+    def _():
+        dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
+
+    @pl.when(p == pl.num_programs(2) - 1)
     def _():
         dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
@@ -206,7 +289,8 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
           interpret: bool):
     """``operands`` / ``outs``: (array or shape-dtype, kind) with kind "q"
     (a group's query lanes, by query block), "kv" (one key-value head, by
-    key block) or "stats" (by query block)."""
+    key block), "kv_all" (one key-value head's whole sequence) or "stats"
+    (by query block)."""
     b, g = operands[1][0].shape[0], operands[1][0].shape[2] // LANES
     table = band_pairs(t // block, block, window, key_major)
     specs = {
@@ -214,6 +298,8 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
                           lambda i, j, p, qi, ki, fi, la: (i, qi[p], j)),
         "kv": pl.BlockSpec((1, block, LANES),
                            lambda i, j, p, qi, ki, fi, la: (i, ki[p], j)),
+        "kv_all": pl.BlockSpec((1, t, LANES),
+                               lambda i, j, p, qi, ki, fi, la: (i, 0, j)),
         "stats": pl.BlockSpec((1, 1, block, LANES),
                               lambda i, j, p, qi, ki, fi, la:
                               (i, j, qi[p], 0)),
@@ -229,7 +315,7 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x, _ in outs],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=64 * 1024 * 1024),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*(jnp.asarray(table[:, c]) for c in range(4)),
       *(x for x, _ in operands))
@@ -299,12 +385,18 @@ def _vjp_bwd(window, block, interpret, res, dout):
                 (stats, "stats"), (delta, "stats")]
     kw = dict(t=q.shape[1], group=group, block=block, window=window,
               interpret=interpret)
-    (dq,) = _call(_causal_dq_kernel, operands, [(q, "q")],
-                  [pltpu.VMEM((block, group * LANES), jnp.float32)],
-                  key_major=False, **kw)
-    acc = pltpu.VMEM((block, LANES), jnp.float32)
-    dk, dv = _call(_causal_dkv_kernel, operands, [(k, "kv"), (v, "kv")],
-                   [acc, acc], key_major=True, **kw)
+    dq_s = pltpu.VMEM((block, group * LANES), jnp.float32)
+    if fused_backward_fits(t, group, q.dtype.itemsize, block) is None:
+        acc = pltpu.VMEM((q.shape[1], LANES), jnp.float32)
+        dq, dk, dv = _call(_causal_bwd_kernel, operands,
+                           [(q, "q"), (k, "kv_all"), (v, "kv_all")],
+                           [dq_s, acc, acc], key_major=False, **kw)
+    else:
+        (dq,) = _call(_causal_dq_kernel, operands, [(q, "q")], [dq_s],
+                      key_major=False, **kw)
+        acc = pltpu.VMEM((block, LANES), jnp.float32)
+        dk, dv = _call(_causal_dkv_kernel, operands, [(k, "kv"), (v, "kv")],
+                       [acc, acc], key_major=True, **kw)
     return dq[:, :t], dk[:, :t], dv[:, :t]
 
 

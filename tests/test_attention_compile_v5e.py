@@ -3,8 +3,10 @@ sandbox (no chip attached), at the benchmark cells' shapes: between the
 q/k/v projections and the out projection no array with a minor dimension
 of ``head_dim`` may exist — a 64-minor bf16 array is tiled half empty, and
 used to cost a heads-major transpose, six slices, six pads and three f32
-adds a layer beside. All in one file and behind fixtures, so that only the
-worker given this file loads the TPU compiler."""
+adds a layer beside; and the blockwise causal attention's backward at
+the cells' shapes and at the longest lengths its rule admits. All in one
+file and behind fixtures, so that only the worker given this file loads
+the TPU compiler."""
 import re
 
 import jax
@@ -147,9 +149,43 @@ def test_the_head_norms_of_queries_and_keys_stay_on_the_lanes(
         re.match(r"\s*(?:ROOT )?%?([\w\-]+?)[.\d]* =", line).group(1)
         for line in text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line)
-    assert names.count("qk_norm") == 4 and len(names) == 7, names
+    # beside them the blockwise attention's forward and its one backward
+    assert names.count("qk_norm") == 4 and len(names) == 6, names
     assert all("attn" in n for n in names if n != "qk_norm"), names
     for heads in (cfg.num_heads, cfg.num_kv_heads):
         assert f"{cfg.total_seq_len},{heads},{cfg.head_dim}]" not in text
     assert sparse_lm._HEAD_NORMS[
         cfg.total_seq_len, cfg.num_heads * cfg.head_dim, cfg.head_dim] is None
+
+
+@pytest.mark.parametrize("batch, tokens, heads, window, dtype, kernel", [
+    (2, 8192, 28, 4096, jnp.bfloat16, "_causal_bwd_kernel"),    # the cells'
+    (1, 8192, 32, None, jnp.bfloat16, "_causal_bwd_kernel"),
+    # the longest lengths ``fused_backward_fits`` admits: the chip's
+    # compiler has to take what the rule says fits
+    (1, 25600, 32, 2048, jnp.bfloat16, "_causal_bwd_kernel"),
+    (1, 14848, 32, None, jnp.float32, "_causal_bwd_kernel"),
+    (1, 26112, 32, 2048, jnp.bfloat16, "_causal_dkv_kernel"),   # one past
+])
+def test_the_blockwise_backward_the_rule_chooses_compiles(
+        batch, tokens, heads, window, dtype, kernel, one_chip,
+        no_persistent_cache):
+    """``causal_attention``'s gradient alone, 4 key-value heads: one
+    kernel a tile where a key-value head's ``dk`` and ``dv`` fit VMEM, the
+    ``dq`` and ``dk``/``dv`` kernels past that, and either compiles."""
+    from dalle_tpu.ops.pallas import causal_attention_kernels as K
+
+    fused = kernel == "_causal_bwd_kernel"
+    assert (K.fused_backward_fits(tokens, heads // 4,
+                                  jnp.dtype(dtype).itemsize) is None) == fused
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(K.causal_attention(
+            *a, window).astype(jnp.float32)), (0, 1, 2))(q, k, v)
+
+    q, kv = (jax.ShapeDtypeStruct((batch, tokens, n * K.LANES), dtype,
+                                  sharding=one_chip) for n in (heads, 4))
+    lowered = jax.jit(grads).lower(q, kv, kv)
+    assert (kernel in lowered.as_text()) and (
+        "_causal_dq_kernel" in lowered.as_text()) != fused
+    lowered.compile()

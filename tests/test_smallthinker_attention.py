@@ -1,8 +1,9 @@
 """The blockwise causal attention kernels (ops/pallas/
 causal_attention_kernels.py), interpreted on the CPU, against dense masked
 attention: full and windowed, 7 query heads and 1 to a key-value head, a
-length that is not a multiple of the block; and the list of tiles they
-visit."""
+length that is not a multiple of the block; the backward as one kernel a
+tile and as the ``dq`` + ``dk``/``dv`` pair it is where the accumulators
+do not fit; and the list of tiles they visit."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,13 +13,37 @@ from dalle_tpu.models.sparse_lm import dense_causal_attention
 from dalle_tpu.ops.pallas import causal_attention_kernels as K
 
 
-def _operands(t, group, kv_heads, seed=0):
+def _operands(t, group, kv_heads, seed=0, dtype=jnp.float32, batch=2):
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
     wide, narrow = kv_heads * group * K.LANES, kv_heads * K.LANES
-    return (jax.random.normal(keys[0], (2, t, wide)),
-            jax.random.normal(keys[1], (2, t, narrow)),
-            jax.random.normal(keys[2], (2, t, narrow)),
-            jax.random.normal(keys[3], (2, t, wide)))
+    return (jax.random.normal(keys[0], (batch, t, wide), dtype),
+            jax.random.normal(keys[1], (batch, t, narrow), dtype),
+            jax.random.normal(keys[2], (batch, t, narrow), dtype),
+            jax.random.normal(keys[3], (batch, t, wide), dtype))
+
+
+def _grads(q, k, v, w, window, block=128):
+    def weighed(q, k, v):
+        out = K.causal_attention(q, k, v, window, block, True)
+        return jnp.sum(out.astype(jnp.float32) * w)
+    return jax.grad(weighed, (0, 1, 2))(q, k, v)
+
+
+@pytest.fixture
+def kernels_called(monkeypatch):
+    """The names of the kernels ``_call`` was handed, in order."""
+    called, real = [], K._call
+
+    def spy(kernel, *args, **kw):
+        called.append(kernel.__name__)
+        return real(kernel, *args, **kw)
+    monkeypatch.setattr(K, "_call", spy)
+    return called
+
+
+def _rel(got, want):
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return float(np.abs(got - want).max() / np.abs(want).max())
 
 
 @pytest.mark.parametrize("t, group, kv_heads, window", [
@@ -26,8 +51,15 @@ def _operands(t, group, kv_heads, seed=0):
     (300, 7, 1, 160),       # a window that crosses block edges
     (384, 1, 2, 130),       # every head its own key-value head
     (256, 2, 2, None),
+    # five and six blocks: a key block is summed into from several query
+    # blocks' runs, and a window of 200 cuts through the blocks' edges
+    (640, 7, 1, 200),
+    (640, 8, 1, None),
+    (700, 8, 1, 200),       # ragged as well
+    (700, 7, 1, None),
 ])
-def test_blockwise_attention_matches_dense(t, group, kv_heads, window):
+def test_blockwise_attention_matches_dense(t, group, kv_heads, window,
+                                           kernels_called):
     q, k, v, w = _operands(t, group, kv_heads)
 
     def blockwise(q, k, v):
@@ -38,10 +70,63 @@ def test_blockwise_attention_matches_dense(t, group, kv_heads, window):
 
     np.testing.assert_allclose(blockwise(q, k, v), dense(q, k, v),
                                atol=5e-6)
-    got = jax.grad(lambda *a: jnp.sum(blockwise(*a) * w), (0, 1, 2))(q, k, v)
+    got = _grads(q, k, v, w, window)
     want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
     for g, r in zip(got, want):
-        assert float(jnp.abs(g - r).max() / jnp.abs(r).max()) < 1e-5
+        assert _rel(g, r) < 1e-5
+    # the backward was the one kernel
+    assert set(kernels_called) == {"_causal_fwd_kernel",
+                                   "_causal_bwd_kernel"}
+
+
+@pytest.mark.parametrize("t, group, window, dtype, limit", [
+    (640, 7, 200, jnp.float32, 1e-6),
+    (700, 8, None, jnp.float32, 1e-6),
+    (700, 7, 200, jnp.float32, 1e-6),
+    # bfloat16 operands, as the cells': one rounding of the widest value
+    (640, 8, 200, jnp.bfloat16, 2 ** -8),
+])
+def test_the_split_backward_agrees_with_the_fused(t, group, window, dtype,
+                                                  limit, kernels_called,
+                                                  monkeypatch):
+    """Past the length whose ``dk`` / ``dv`` fit VMEM the backward is the
+    ``dq`` and the ``dk``/``dv`` kernel: reached here by shrinking the
+    budget, and held to the one kernel's result, which sums in the same
+    order."""
+    q, k, v, w = _operands(t, group, 1, seed=3, dtype=dtype, batch=1)
+    fused = _grads(q, k, v, w, window)
+    assert kernels_called == ["_causal_fwd_kernel", "_causal_bwd_kernel"]
+    del kernels_called[:]
+    monkeypatch.setattr(K, "VMEM_LIMIT_BYTES", 512 * 1024)
+    assert K.fused_backward_fits(t, group, q.dtype.itemsize, 128)
+    split = _grads(q, k, v, w, window)
+    assert kernels_called == ["_causal_fwd_kernel", "_causal_dq_kernel",
+                              "_causal_dkv_kernel"]
+    for got, want in zip(split, fused):
+        assert got.dtype == want.dtype == dtype
+        assert _rel(got, want) <= limit
+
+
+@pytest.mark.parametrize("tokens, group, itemsize, fits", [
+    (8192, 7, 2, True),         # smallthinker21b's layers
+    (8192, 8, 2, True),         # trinitymini's
+    (8192, 8, 4, True),
+    (8000, 8, 2, True),         # judged at the padded length
+    (25600, 8, 2, True),        # the longest the compiler takes as well
+    (26112, 8, 2, False),
+    (32768, 7, 2, False),
+    (16384, 8, 4, False),
+])
+def test_where_the_fused_backward_fits(tokens, group, itemsize, fits):
+    """A pure function of the local shapes against the VMEM every call
+    asks for: (T, 128) f32 accumulators of ``dk`` and ``dv`` and their
+    outputs grow with T, the tiles do not."""
+    why_not = K.fused_backward_fits(tokens, group, itemsize)
+    assert (why_not is None) == fits
+    if not fits:
+        padded = tokens + -tokens % K.BLOCK
+        assert why_not.startswith(f"dk and dv of {padded} tokens need ")
+        assert why_not.endswith(" MiB of VMEM, over 64")
 
 
 def test_only_the_tiles_inside_the_band_are_visited():

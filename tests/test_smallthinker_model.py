@@ -74,9 +74,41 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(kernels,
         rel=1e-6)
     assert 0 < float(aux["moe_assignments_here_pct"]) < 100
     assert float(aux["moe_dropped"]) == 0.0
-    taken = sparse_lm._KERNEL_CHOICES.get(
-        ("window_rope", 28, 4 * 128, 2 * 128), False)
-    assert taken == kernels
+    taken, split_why = sparse_lm._KERNEL_CHOICES.get(
+        ("window_rope", 28, 4 * 128, 2 * 128), (False, None))
+    assert taken == kernels and split_why is None
+    layout = sparse_lm.engagement_records(cfg)["attn_layout"]
+    assert layout.startswith(f"blockwise 512: {4 * kernels} of 4 layers, ")
+    assert layout.endswith(
+        "2 query heads a key-value head"
+        + ", backward: one kernel a tile (4 of 4 layers)" * kernels)
+
+
+@pytest.mark.parametrize("interpret, budget, words", [
+    (True, None, ", backward: one kernel a tile (4 of 4 layers)"),
+    (True, 2 ** 20, ", backward: dq + dk/dv kernels (dk and dv of 512 "
+     "tokens need 11.0 MiB of VMEM, over 1)"),
+    (False, None, ""),          # no blockwise kernel, so no backward of it
+])
+def test_attn_layout_says_which_backward_the_layers_took(
+        interpret, budget, words, monkeypatch):
+    """Read from what the traced calls did, as the blockwise count is: the
+    one kernel where a key-value head's ``dk`` and ``dv`` fit VMEM, else
+    the ``dq`` and the ``dk``/``dv`` kernel and why."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", interpret)
+    monkeypatch.setattr(sparse_lm, "_KERNEL_CHOICES", {})
+    if budget:
+        monkeypatch.setattr(sparse_lm.kernels, "VMEM_LIMIT_BYTES", budget)
+    cfg = SparseLMConfig(**dict(TINY, head_dim=128))
+    text, image = _batch(cfg)
+    params = sparse_lm.init_params(sparse_lm.build(cfg),
+                                   jax.random.PRNGKey(1))
+    jax.eval_shape(lambda p: sparse_lm.build(cfg).apply(p, text, image),
+                   params)
+    layout = sparse_lm.engagement_records(cfg)["attn_layout"]
+    assert layout.startswith(f"blockwise 512: {4 * interpret} of 4 layers, "
+                             "1 full no-rope + 3 window 8 rope, ")
+    assert layout.endswith(f"2 query heads a key-value head{words}")
 
 
 def test_the_reference_at_the_sets_the_program_chose():

@@ -85,10 +85,18 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(kernels,
     for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
                             jax.tree.leaves(ref_grads)):
         assert rel_l2(g, r) < 2e-5, jax.tree_util.keystr(path)
-    # ... through the one-pass head norm, where the kernels run
+    # ... through the one-pass head norm, where the kernels run, and the
+    # blockwise attention's one-kernel backward
+    layout = sparse_lm.engagement_records(cfg)["attn_layout"]
     assert ("normed queries and keys (one pass on the lanes: 5 of 5 layers)"
             if kernels else "normed queries and keys (XLA: no Mosaic "
-            "backend)") in sparse_lm.engagement_records(cfg)["attn_layout"]
+            "backend)") in layout
+    assert layout.startswith(
+        "blockwise 512: 5 of 5 layers, 1 full no-rope + 4 window 8 rope, 2 "
+        "query heads a key-value head, backward: one kernel a tile (5 of 5 "
+        "layers), normed" if kernels else
+        "blockwise 512: 0 of 5 layers, 1 full no-rope + 4 window 8 rope, 2 "
+        "query heads a key-value head, normed")
     layer = params["params"]["layer_1"]
     assert set(layer) == {"attn", "attn_norm", "post_attn_norm", "ff",
                           "ff_norm", "post_ff_norm"}          # four norms
@@ -186,6 +194,26 @@ def test_attn_layout_says_which_lowering_the_head_norms_took(
     without = dataclasses.replace(cfg, qk_norm=False)
     assert "normed" not in sparse_lm.engagement_records(without)[
         "attn_layout"]
+
+
+def test_attn_layout_names_the_split_backward_between_the_other_words(
+        monkeypatch):
+    """Where ``dk`` and ``dv`` do not fit VMEM (the budget shrunk, as no
+    preset's length reaches) the word says so and why, after the heads and
+    before the head norms' words."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    monkeypatch.setattr(sparse_lm, "_KERNEL_CHOICES", {})
+    monkeypatch.setattr(sparse_lm.kernels, "VMEM_LIMIT_BYTES", 2 ** 20)
+    cfg = AfmoeLMConfig(**dict(SMALL, head_dim=128))
+    text, image = _batch(cfg)
+    jax.eval_shape(lambda p: sparse_lm.build(cfg).apply(p, text, image),
+                   _params(cfg))
+    layout = sparse_lm.engagement_records(cfg)["attn_layout"]
+    assert layout.startswith("blockwise 512: 2 of 2 layers, ")
+    assert ("2 query heads a key-value head, backward: dq + dk/dv kernels "
+            "(dk and dv of 512 tokens need 11.0 MiB of VMEM, over 1), normed "
+            "queries and keys (") in layout
+    assert layout.endswith(", gated output")
 
 
 def test_the_leading_dense_layer():
