@@ -181,7 +181,10 @@ class Tracer:
         # running profiler session (jax.profiler.TraceAnnotation); the
         # entry point injects it, so this module needs no JAX
         self._annotate = annotate
-        self._open = threading.local()   # .stack: this thread's open spans
+        # .stack: this thread's open spans. A threading.local: every
+        # thread reads and writes its own attribute, no other's
+        # graftlint: handoff=thread-local
+        self._open = threading.local()
         self._lock = threading.Lock()
         self._ring: deque = deque()      # (est_bytes, row)
         self._ring_used = 0

@@ -119,6 +119,14 @@ class _FollowerTracker:
         pass
 
 
+def accumulate_grads(acc, grads, scale):
+    """``acc + grads * scale`` in f32, leaf by leaf: the program of
+    ``collab/accumulate`` (named, so that the compile counter and the
+    memory account can say ``accumulate_grads``)."""
+    return jax.tree.map(lambda a, g: a + g.astype(jnp.float32) * scale,
+                        acc, grads)
+
+
 class CollaborativeOptimizer:
     """Owns the train state and drives swarm-synchronous updates.
 
@@ -140,7 +148,8 @@ class CollaborativeOptimizer:
                  matchmaking_min_group: int = 2,
                  authorizer=None,
                  role=None,
-                 tracer=None):
+                 tracer=None,
+                 memory=None):
         from dalle_tpu.parallel.multihost import SliceRole
         self.role = role or SliceRole()
         if self.role.swarm_enabled and dht is None:
@@ -166,6 +175,9 @@ class CollaborativeOptimizer:
         # each seam pays one `is None` test (transparency pinned by
         # tests/test_obs.py).
         self.tracer = tracer
+        # The trainer's memory account (obs/memory.py): told after every
+        # accumulate, which is where a step holds the most of the device
+        self.memory = memory
         if tracer is None and cfg.trace_file:
             from dalle_tpu.obs.trace import Tracer
             self.tracer = Tracer(
@@ -382,9 +394,14 @@ class CollaborativeOptimizer:
                 # resolved through the fetch plane before replay
                 fetcher=self._evidence)
         self._grad_acc = None
-        self._accumulate = jax.jit(
-            lambda acc, g, s: jax.tree.map(
-                lambda a, b: a + b.astype(jnp.float32) * s, acc, g))
+        # the accumulator is donated: the sum is written over it. Undonated,
+        # every step held the old accumulator, the step's gradients and the
+        # new accumulator at once, a third f32 tree of 4 bytes a parameter
+        # until the program had run (PERF.md section 6, PR 41: 1.88 GiB on
+        # trinitymini). Whoever takes the accumulator's leaves (a launched
+        # round, a global step) sets _grad_acc to None or is done with
+        # them before the next accumulate.
+        self._accumulate = jax.jit(accumulate_grads, donate_argnums=0)
         self._pending: Optional[_PendingRound] = None
         self._next_resync = 0.0
         self.last_timings: dict = {}
@@ -500,6 +517,8 @@ class CollaborativeOptimizer:
                                         device=g.sharding), grads)
             self._grad_acc = self._accumulate(
                 self._grad_acc, grads, float(batch_size))
+        if self.memory is not None:
+            self.memory.after_accumulate(self._grad_acc)
         self.local_samples += int(batch_size)
         with obs_span(tracer, "train", "collab/progress"):
             if self._pending is not None:

@@ -62,26 +62,52 @@ class TrainingTask:
         return self.tracer.span("train", f"setup/{what}", "setup")
 
     @functools.cached_property
+    def _read_device(self):
+        """The one device whose allocator the trainer reads at a step's
+        edges, not every chip of the host: the fullest when it is first
+        asked for (the train state is on the devices by then)."""
+        return max(jax.local_devices(), key=lambda d: (
+            d.memory_stats() or {}).get("bytes_in_use", 0))
+
+    def _bytes_on_read_device(self, tree, itemsize: Optional[int] = None):
+        """(bytes that ``tree``'s leaves keep on the read device, their
+        element count): a leaf placed there holds one shard of its
+        sharding's shard shape. With ``itemsize``, the bytes of leaves
+        that wide."""
+        device, total, count = self._read_device, 0, 0
+        for leaf in jax.tree.leaves(tree):
+            sharding = getattr(leaf, "sharding", None)
+            if sharding is None:
+                continue                     # a host value: no device holds it
+            count += leaf.size
+            if device in sharding.device_set:
+                total += (itemsize or leaf.dtype.itemsize) * int(np.prod(
+                    sharding.shard_shape(leaf.shape), dtype=np.int64))
+        return total, count
+
+    @functools.cached_property
+    def memory(self):
+        """The memory account (``obs/memory.py``; always on): what holds
+        the read device's memory, by owner, by phase of a step and at a
+        failed allocation. What needs JAX is handed in."""
+        from dalle_tpu.obs.memory import MemoryAccount
+        return MemoryAccount(
+            self.tracer, compiles=self.compiles,
+            device_memory=lambda: self._read_device.memory_stats(),
+            tree_bytes=self._bytes_on_read_device)
+
+    @functools.cached_property
     def late_steps(self):
         """The late-step recorder ``train_loop`` opens its steps through
         (``obs/late.py``; always on, like the ring it records into). What
-        needs JAX is handed in: the compile counter and the fullest local
-        device's allocator statistics (the device is chosen at the first
-        reading, the recorder's start: a step's edge reads one device,
-        not every chip of the host)."""
+        needs JAX is handed in: the compile counter and the read device's
+        allocator statistics, as the memory account read them at the
+        step's edge (one reading an edge, shared)."""
         from dalle_tpu.obs.late import LateSteps
-        fullest = []
-
-        def device_memory():
-            if not fullest:
-                fullest.append(max(
-                    jax.local_devices(), key=lambda d: (
-                        d.memory_stats() or {}).get("bytes_in_use", 0)))
-            return fullest[0].memory_stats()
-
         trace_file = self.collab_cfg.trace_file
         return LateSteps(
-            self.tracer, compiles=self.compiles, device_memory=device_memory,
+            self.tracer, compiles=self.compiles,
+            device_memory=lambda: self.memory.edge,
             slow_attributes=self.family.SLOW_STEP_ATTRIBUTES,
             stacks_path=f"{trace_file}.stacks" if trace_file else None)
 
@@ -186,7 +212,7 @@ class TrainingTask:
                 client_mode=self.peer_cfg.client_mode,
                 authorizer=self.authorizer if self.slice_role.swarm_enabled
                 else None,
-                role=self.slice_role, tracer=self.tracer)
+                role=self.slice_role, tracer=self.tracer, memory=self.memory)
 
     # -- mesh / compute ---------------------------------------------------
 
@@ -236,8 +262,11 @@ class TrainingTask:
             state = TrainState.create(params, self.tx)
             if self.opt_cfg.offload:
                 from dalle_tpu.training.offload import offload_train_state
-                return offload_train_state(self.mesh, state)
-            return shard_train_state(self.mesh, state)
+                state = offload_train_state(self.mesh, state)
+            else:
+                state = shard_train_state(self.mesh, state)
+        self.memory.state_built(state.params, state.opt_state)
+        return state
 
     @functools.cached_property
     def grad_step(self):

@@ -49,17 +49,24 @@ def warmup(task: TrainingTask, steps: int = 3) -> float:
     with task.tracer.span("train", "setup/warmup", "setup", steps=steps,
                           grad_reduction=grad_reduction_plan(task.mesh)
                           ) as span:
+        task.memory.read("setup/warmup opened")
         for i in range(steps):
             t0 = time.monotonic()
-            grads, metrics = task.grad_step(params, next(batches))
+            batch = next(batches)
+            grads, metrics = task.grad_step(params, batch)
             jax.block_until_ready(grads)
             loss = float(metrics["loss"])
             logger.info("warmup %d/%d: loss=%.4f (%.2fs)",
                         i + 1, steps, loss, time.monotonic() - t0)
         # the step is traced by now: the model says what its layers
-        # lowered to (attn_layout, layer_loop, ...)
+        # lowered to (attn_layout, layer_loop, ...), the memory account
+        # what the trainer's trees hold of the device
         span.set(**task.family.engagement_records(task.model_cfg,
                                                   task.mesh))
+        if steps:
+            span.set(memory_layout=task.memory.step_traced((grads, metrics),
+                                                           batch))
+        task.memory.read("setup/warmup ran")
     if not np.isfinite(loss):
         raise RuntimeError(f"warmup produced non-finite loss {loss}")
     # warmup gradients are discarded; the tracker timer starts fresh
@@ -129,9 +136,6 @@ def train_loop(task: TrainingTask,
         collab.local_epoch = multihost.broadcast_decision(
             collab.local_epoch)
         collab.tracker.reset_epoch(collab.local_epoch)
-    if warmup_steps:
-        warmup(task, warmup_steps)
-
     reports: List[EpochReport] = []
     loss_sum, mini_steps, local_steps = 0.0, 0, 0
     profiler = _StepProfiler(profile_dir, profile_steps)
@@ -142,8 +146,11 @@ def train_loop(task: TrainingTask,
     # closes the step's span, and says why a step that ran over did
     span = functools.partial(task.tracer.span, "train")
     step_attributes = task.family.STEP_ATTRIBUTES
-    late = task.late_steps
+    late, memory = task.late_steps, task.memory
     try:
+        if warmup_steps:
+            warmup(task, warmup_steps)
+        memory.start()
         late.start()
         while ((max_epochs is None or collab.local_epoch < max_epochs)
                and (max_steps is None or local_steps < max_steps)):
@@ -154,12 +161,14 @@ def train_loop(task: TrainingTask,
                 with span("loop/grad_dispatch"):
                     grads, metrics = task.grad_step(collab.state.params,
                                                     batch)
+                memory.after_grad((grads, metrics), batch)
                 with span("loop/loss_wait"):
                     loss = float(metrics["loss"])
                     # what the model counts a step (an expert layer's
                     # load), read with the loss from the step's aux
                     step_row.set(**{k: float(metrics[k])
                                     for k in step_attributes})
+                memory.settled()
                 loss_sum += loss
                 mini_steps += 1
                 local_steps += 1
@@ -257,6 +266,7 @@ def train_loop(task: TrainingTask,
                         if on_epoch is not None:
                             on_epoch(report)
                     loss_sum, mini_steps = 0.0, 0
+                memory.close_step(step_row)
         # an overlapped round (delay_optimizer_step) may still be in
         # flight when the loop exits: apply it rather than lose the
         # epoch's averaging (shutdown() would discard it) — EXCEPT when
@@ -280,6 +290,11 @@ def train_loop(task: TrainingTask,
                         collab.tracker.performance_ema.samples_per_second)))
             if ckpt is not None and params_are_finite(collab.state.params):
                 ckpt.save_backup(collab.state, collab.local_epoch)
+    except Exception as exc:
+        # a chip that is too small says what held it (one memory/exhausted
+        # record, one ERROR), and the runtime's own error goes on up
+        memory.exhausted(exc)
+        raise
     finally:
         late.stop()
         # the trace from a crashed run is the artifact you want most

@@ -24,6 +24,9 @@ Outputs (printed table + ``--out`` JSON):
 - **late steps**: the trainer's ``loop/late_step`` events (plane
   ``train``; ``dalle_tpu/obs/late.py``): which step ran over, by how
   much, in which phase, and the cause its record supports;
+- **memory**: the trainer's ``memory/*`` events (``dalle_tpu/obs/
+  memory.py``): what its trees hold of the device, where the process's
+  peak rose, and what held a device near its limit or past it;
 - **round table** (``--rounds``): one row per trace id with per-peer
   total span time, phase count, and errors.
 
@@ -45,6 +48,7 @@ from typing import Dict, List, Optional
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
+from dalle_tpu.obs import memory as memory_account  # noqa: E402
 from dalle_tpu.obs.late import LATE_EVENT, late_step_line  # noqa: E402
 from dalle_tpu.obs.trace import load_jsonl, merge_rows  # noqa: E402
 
@@ -189,6 +193,16 @@ def late_steps(rows: List[dict]) -> List[dict]:
             if r["plane"] == "train" and r["phase"] == LATE_EVENT]
 
 
+def memory_events(rows: List[dict]) -> List[dict]:
+    """The memory account's events (``memory/owners``, ``peak_rose``,
+    ``near_limit``, ``exhausted``), each with its line."""
+    return [dict(r.get("a", {}), peer=str(r.get("peer", "")),
+                 phase=r["phase"], trace=r["trace"],
+                 line=memory_account.event_line(r))
+            for r in rows
+            if r["plane"] == "train" and r["phase"].startswith("memory/")]
+
+
 def build_report(files: List[str], gap_s: float = 1.0,
                  rounds: bool = False) -> dict:
     per_peer = [load_jsonl(f) for f in files]
@@ -203,6 +217,7 @@ def build_report(files: List[str], gap_s: float = 1.0,
         "stragglers": straggler_attribution(rows),
         "gaps": detect_gaps(rows, gap_s=gap_s),
         "late_steps": late_steps(rows),
+        "memory": memory_events(rows),
     }
     if rounds:
         report["rounds"] = round_table(rows)
@@ -254,6 +269,8 @@ def main(argv=None) -> int:
               f"{g['before_phase']})")
     for late in report["late_steps"]:
         print(f"  late step: {late['peer']} {late['line']}")
+    for event in report["memory"]:
+        print(f"  {event['phase']}: {event['peer']} {event['line']}")
     if args.out:
         with open(args.out, "w", encoding="utf-8") as fh:
             json.dump(report, fh, indent=1)

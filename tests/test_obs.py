@@ -1632,9 +1632,9 @@ class TestLateStepsInTheLoop:
         assert ": host: " in out
 
     def test_an_edge_asks_one_device_on_a_host_of_four(self, monkeypatch):
-        """``TrainingTask`` hands the recorder the fullest local device's
-        statistics: the device is chosen at the first reading, and every
-        later edge asks that one alone."""
+        """``TrainingTask`` hands the memory account the fullest local
+        device, chosen once; the late-step recorder reads the account's
+        last edge and asks no device at all."""
         import types
 
         from dalle_tpu import task as task_module
@@ -1647,16 +1647,22 @@ class TestLateStepsInTheLoop:
                 self.asked += 1
                 return {"bytes_in_use": self.held, "num_allocs": self.asked}
         devices = [Device(held) for held in (3, 9, 5, 1)]
-        monkeypatch.setattr(task_module.jax, "local_devices",
-                            lambda: devices)
+        monkeypatch.setattr(task_module.jax, "local_devices", lambda: devices)
+        row = types.SimpleNamespace(set=lambda **a: None)
         me = types.SimpleNamespace(
             tracer=Tracer(peer="four"), compiles=None,
             family=types.SimpleNamespace(SLOW_STEP_ATTRIBUTES=()),
-            collab_cfg=types.SimpleNamespace(trace_file=None))
-        rec = task_module.TrainingTask.late_steps.func(me)
+            collab_cfg=types.SimpleNamespace(trace_file=None),
+            _bytes_on_read_device=lambda tree, itemsize=None: (0, 0))
+        me._read_device = task_module.TrainingTask._read_device.func(me)
+        me.memory = task_module.TrainingTask.memory.func(me)
+        late = task_module.TrainingTask.late_steps.func(me)
+        me.memory.start()
         for _ in range(5):
-            assert rec.device_memory()["bytes_in_use"] == 9
-        assert [d.asked for d in devices] == [1, 6, 1, 1]
+            assert late.device_memory()["bytes_in_use"] == 9
+        assert [d.asked for d in devices] == [1, 2, 1, 1]
+        me.memory.close_step(row)
+        assert late.device_memory()["num_allocs"] == 3
 
     def test_the_pulse_and_the_callback_are_gone_after_the_loop(self,
                                                                 late_loop):
