@@ -83,17 +83,23 @@ parameters.
 
 Device scopes (``jax.named_scope`` and module names; the benchmark's
 ``*_share_pct`` metrics read them): ``embed``, ``attn`` (projections,
-rotary, kernel), ``rms_norm``, ``ff/router``, ``ff/dispatch``,
+kernel), ``rms_norm``, ``ff/router``, ``ff/dispatch``,
 ``ff/experts``, ``ff/combine``, ``head``, ``ce``; where the configuration
 has them ``ff/shared`` (the shared expert), ``ff/dense`` (a dense layer's
 block), ``attn/gate`` (the ``W_g`` product, the sigmoid and the multiply),
-``attn/qk_norm``. The token-major kernel
+``attn/qk_norm``, ``attn/rotary``. The token-major kernel
 (``token_major_sum[mosaic]`` in a trace) runs under the scope of its sum,
 ``ff/combine`` or ``ff/dispatch``; the grouped products under
-``ff/experts``; the head norms' one pass on the lanes
-(``qk_norm[mosaic]``, ops/pallas/head_norm_kernels.py: no (B, T, H, d)
-array exists where it runs, :func:`head_norm_why_not` is the rule) under
-``attn/qk_norm``.
+``ff/experts``. What is done to each head of queries and keys between
+their projections and the attention (the head norm where the
+configuration has one, the rotary where the layer has positions) is one
+pass on the lanes (ops/pallas/head_norm_kernels.py: no (B, T, H, d) array
+exists where it runs, and the rotary reads one head's (T, d) tables;
+:func:`head_norm_why_not` is the rule, and where it refuses the layer
+runs ``rms_norm`` on the reshape and ``attention.apply_rotary_lanes``):
+``qk_norm[mosaic]`` under ``attn/qk_norm`` where there is a norm, the
+rotary of a window layer in it; ``rotary[mosaic]`` under ``attn/rotary``
+where there is none.
 """
 
 from __future__ import annotations
@@ -128,10 +134,11 @@ _KERNEL_CHOICES: Dict[Tuple[str, int, int, int],
                       Tuple[bool, Optional[str]]] = {}
 
 
-# (tokens, lanes of the whole array, head_dim) -> why the last traced head
-# norm of such an array did not take the one-pass kernel on its local
+# (tokens, lanes of the whole array, head_dim, normed, rotated) -> why the
+# last traced per-head work of that kind on such an array (the head norm,
+# the rotary, or both) did not take the one pass on the lanes on its local
 # shapes, None where it did: what attn_layout reads
-_HEAD_NORMS: Dict[Tuple[int, int, int], Optional[str]] = {}
+_HEAD_PASSES: Dict[Tuple[int, int, int, bool, bool], Optional[str]] = {}
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -143,31 +150,49 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
 
 def head_norm_why_not(tokens: int, width: int,
                       head_dim: int) -> Optional[str]:
-    """Why the RMS norm over each head's ``head_dim`` lanes of (tokens,
-    width) samples is the reshape to heads and :func:`rms_norm`, and not
-    one pass on the lanes (ops/pallas/head_norm_kernels.py); None where it
-    is the pass."""
+    """Why the work on each head's ``head_dim`` lanes of (tokens, width)
+    samples (the RMS norm, the rotary) is the reshape to heads and
+    :func:`rms_norm`, and ``attention.apply_rotary_lanes`` with tables as
+    wide as the array, and not one pass on the lanes
+    (ops/pallas/head_norm_kernels.py); None where it is the pass."""
     if not attn_mod._pallas_by_default():
         return "no Mosaic backend"
     return head_norm.fits(tokens, width, head_dim)
 
 
-def _norm_heads_shard(x, scale, *, eps: float, head_dim: int, lanes: int):
-    """One shard's norm of x (B, T, H*d) over each head's d lanes, one
-    scale vector for all heads: the one-pass kernel where it fits.
+def _per_head_shard(x, scale=None, *, eps: float, head_dim: int, lanes: int,
+                    theta: Optional[float]):
+    """One shard's work on each head of x (B, T, H*d) between a projection
+    and the attention: the RMS norm over the head's d lanes where there is
+    a ``scale`` (one vector for all heads), then the rotary of positions
+    0..T-1 where there is a ``theta``; one pass of the kernel that reads
+    one head's tables where it fits, else the two expressions it replaces.
     ``lanes``: the whole array's, of which a ``tp`` shard holds a part."""
     b, t, width = x.shape
+    norm, rotary = scale is not None, theta is not None
     why_not = head_norm_why_not(t, width, head_dim)
-    _HEAD_NORMS[t, lanes, head_dim] = why_not
+    _HEAD_PASSES[t, lanes, head_dim, norm, rotary] = why_not
     attn_mod.log_kernel_choice(
-        "head norm", why_not is None,
+        " + ".join(["head norm"] * norm + ["rotary"] * rotary),
+        why_not is None,
         why_not or f"local {tuple(x.shape)}: heads of {head_dim} lanes, "
         f"{head_norm.rows_tile(t, width)} rows a tile")
+    pos = jnp.arange(t)
     if why_not is not None:
-        return rms_norm(x.reshape(b, t, -1, head_dim), scale,
-                        eps).reshape(x.shape)
-    return head_norm.head_rms_norm(x, scale, eps, head_dim,
-                                   attn_mod._PALLAS_INTERPRET)
+        if norm:
+            x = rms_norm(x.reshape(b, t, -1, head_dim), scale,
+                         eps).reshape(x.shape)
+        if rotary:
+            x = attn_mod.apply_rotary_lanes(
+                x, *attn_mod.rotary_cos_sin(pos, head_dim, theta,
+                                            width // head_dim), head_dim)
+        return x
+    tables = None
+    if rotary:
+        tables = head_norm.rotary_tables(
+            *attn_mod.rotary_cos_sin(pos, head_dim, theta))
+    return head_norm.per_head(x, scale, tables, eps, head_dim,
+                              attn_mod._PALLAS_INTERPRET)
 
 
 # ---------------------------------------------------------------------------
@@ -235,28 +260,26 @@ class Attention(nn.Module):
         q = dense(cfg.num_heads * cfg.head_dim, name="q")(a)
         k = dense(cfg.num_kv_heads * cfg.head_dim, name="k")(a)
         v = dense(cfg.num_kv_heads * cfg.head_dim, name="v")(a)
-        if cfg.qk_norm:
-            def normed(x, name):
-                norm = functools.partial(
-                    _norm_heads_shard, eps=cfg.rms_eps,
-                    head_dim=cfg.head_dim, lanes=x.shape[2])
+        rope = self.kind == LAYER_WINDOW_ROPE
+        if cfg.qk_norm or rope:
+            def per_head(x, name):
+                work = functools.partial(
+                    _per_head_shard, eps=cfg.rms_eps, head_dim=cfg.head_dim,
+                    lanes=x.shape[2], theta=cfg.rope_theta if rope else None)
+                operands, specs = [x], [LANES_SPEC]
+                if cfg.qk_norm:
+                    operands.append(self.param(name, nn.initializers.ones,
+                                               (cfg.head_dim,), pdt))
+                    specs.append(P())
                 if attn_mod._pallas_by_default():
-                    norm = per_shard(norm, self.mesh, (LANES_SPEC, P()),
-                                     LANES_SPEC, scope="qk_norm")
-                return norm(x, self.param(name, nn.initializers.ones,
-                                          (cfg.head_dim,), pdt))
+                    work = per_shard(work, self.mesh, tuple(specs),
+                                     LANES_SPEC, scope=scope)
+                return work(*operands)
 
-            with jax.named_scope("qk_norm"):
-                q, k = normed(q, "q_norm"), normed(k, "k_norm")
-        window = None
-        if self.kind == LAYER_WINDOW_ROPE:
-            window = cfg.window
-            pos = jnp.arange(a.shape[1])
-            q, k = (attn_mod.apply_rotary_lanes(
-                x, *attn_mod.rotary_cos_sin(pos, cfg.head_dim,
-                                            cfg.rope_theta, heads),
-                cfg.head_dim)
-                for x, heads in ((q, cfg.num_heads), (k, cfg.num_kv_heads)))
+            scope = head_norm.scope(cfg.qk_norm)
+            with jax.named_scope(scope):
+                q, k = per_head(q, "q_norm"), per_head(k, "k_norm")
+        window = cfg.window if rope else None
         if attn_mod._pallas_by_default():
             attend = functools.partial(_attend_shard, kind=self.kind,
                                        window=window, head_dim=cfg.head_dim)
@@ -960,12 +983,31 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
         if not split_why:
             backward += f" ({on} of {len(kinds)} layers)"
     windows = sum(k == LAYER_WINDOW_ROPE for k in kinds)
-    # every layer's head norms are the same two shapes: all took the pass
-    # on the lanes, or the first refusal says why none did
-    norm_why_not = next(filter(None, (
-        _HEAD_NORMS.get((cfg.total_seq_len, h * cfg.head_dim, cfg.head_dim),
-                        "none traced")
-        for h in (cfg.num_heads, cfg.num_kv_heads))), None)
+
+    def pass_why_not(norm: bool, rotary: bool) -> Optional[str]:
+        """Queries' and keys' per-head work of one kind is the same two
+        shapes in every layer that has it: all took the pass on the
+        lanes, or the first refusal says why none did."""
+        return next(filter(None, (
+            _HEAD_PASSES.get((cfg.total_seq_len, h * cfg.head_dim,
+                              cfg.head_dim, norm, rotary), "none traced")
+            for h in (cfg.num_heads, cfg.num_kv_heads))), None)
+
+    def lowering(why_not: Optional[str], took: str) -> str:
+        return f"(XLA: {why_not})" if why_not else f"({took})"
+
+    words = ""
+    if cfg.qk_norm:
+        words += ", normed queries and keys " + lowering(
+            next(filter(None, (
+                pass_why_not(True, rotary)
+                for rotary in {k == LAYER_WINDOW_ROPE for k in kinds})), None),
+            f"one pass on the lanes: {len(kinds)} of {len(kinds)} layers")
+    if windows:
+        words += ", rotary " + lowering(
+            pass_why_not(cfg.qk_norm, True),
+            ("in the head pass" if cfg.qk_norm else "one pass on the lanes")
+            + f": {windows} of {windows} rope layers")
     first, last = cfg.expert_offset, cfg.expert_offset + cfg.experts_held - 1
     devices = mesh.size if mesh is not None else 1
     why_not, tile = _SUM_LOWERINGS.get(
@@ -993,10 +1035,7 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
             f"{len(kinds) - windows} full no-rope + {windows} window "
             f"{cfg.window} rope, {cfg.num_heads // cfg.num_kv_heads} query "
             f"heads a key-value head{backward}"
-            + (", normed queries and keys ("
-               + (f"XLA: {norm_why_not}" if norm_why_not else
-                  f"one pass on the lanes: {len(kinds)} of {len(kinds)} "
-                  "layers") + ")") * cfg.qk_norm
+            + words
             + ", gated output" * cfg.attention_gate),
         "layer_loop": (f"unrolled: {len(kinds)} layers, each "
                        "rematerialised but its attention"),

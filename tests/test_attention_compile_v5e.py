@@ -121,19 +121,25 @@ def test_no_head_dim_minor_array_around_the_kernels(
     assert not narrow, narrow
 
 
-def test_the_head_norms_of_queries_and_keys_stay_on_the_lanes(
-        one_chip, no_persistent_cache, monkeypatch):
-    """One attention layer of ``trinitymini`` (a window layer: norm, then
-    rotary) at the cell's shapes: the head norms are two Mosaic calls a
-    direction named ``qk_norm`` (not ``attn``: the attention roofline must
-    not count them), and no (B, T, heads, 128) array exists."""
-    from dalle_tpu.config import LAYER_WINDOW_ROPE, trinitymini_model_config
+@pytest.mark.parametrize("preset, micro, scope, calls", [
+    ("trinitymini", 1, "qk_norm", 4), ("smallthinker21b", 2, "rotary", 4)])
+def test_the_per_head_work_on_queries_and_keys_stays_on_the_lanes(
+        preset, micro, scope, calls, one_chip, no_persistent_cache,
+        monkeypatch):
+    """One window layer's attention (``trinitymini``: norm, then rotary;
+    ``smallthinker21b``: rotary) at its cell's shapes: the per-head work is
+    two Mosaic calls a direction named ``qk_norm`` or ``rotary`` (not
+    ``attn``: the attention roofline must not count them), no (B, T,
+    heads, 128) array exists, and XLA is left nothing of the rotary: no
+    cosine or sine of a table as wide as the array, no padded shifted
+    copy (``apply_rotary_lanes``' (..., H*d - 64) slices)."""
+    from dalle_tpu import config
     from dalle_tpu.models import attention, sparse_lm
 
     monkeypatch.setattr(attention, "_pallas_by_default", lambda: True)
-    cfg = trinitymini_model_config()
-    mod = sparse_lm.Attention(cfg, LAYER_WINDOW_ROPE, name="attn")
-    a = jax.ShapeDtypeStruct((1, cfg.total_seq_len, cfg.hidden_size),
+    cfg = getattr(config, f"{preset}_model_config")()
+    mod = sparse_lm.Attention(cfg, config.LAYER_WINDOW_ROPE, name="attn")
+    a = jax.ShapeDtypeStruct((micro, cfg.total_seq_len, cfg.hidden_size),
                              jnp.bfloat16, sharding=one_chip)
     params = jax.tree.map(
         lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
@@ -150,12 +156,48 @@ def test_the_head_norms_of_queries_and_keys_stay_on_the_lanes(
         for line in text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line)
     # beside them the blockwise attention's forward and its one backward
-    assert names.count("qk_norm") == 4 and len(names) == 6, names
-    assert all("attn" in n for n in names if n != "qk_norm"), names
+    assert names.count(scope) == calls and len(names) == calls + 2, names
+    assert all("attn" in n for n in names if n != scope), names
+    t, d = cfg.total_seq_len, cfg.head_dim
     for heads in (cfg.num_heads, cfg.num_kv_heads):
-        assert f"{cfg.total_seq_len},{heads},{cfg.head_dim}]" not in text
-    assert sparse_lm._HEAD_NORMS[
-        cfg.total_seq_len, cfg.num_heads * cfg.head_dim, cfg.head_dim] is None
+        assert f"{t},{heads},{d}]" not in text
+        assert f",{heads * d - d // 2}]" not in text
+        assert sparse_lm._HEAD_PASSES[t, heads * d, d, cfg.qk_norm,
+                                      True] is None
+    tables = [line.split("=")[1].split()[0] for line in text.splitlines()
+              if re.search(r" (cosine|sine)\(", line)]
+    assert tables and all(_minor(s.split("]")[0]) == d for s in tables), \
+        tables
+
+
+@pytest.mark.parametrize("batch, lanes, normed", [
+    (1, 4096, True), (1, 512, True),         # trinitymini's q and k
+    (2, 3584, False), (2, 512, False),       # smallthinker21b's
+    (1, 4096, False)])
+def test_the_per_head_pass_compiles_in_both_directions(
+        batch, lanes, normed, one_chip, no_persistent_cache):
+    """``head_norm_kernels.per_head`` and its gradient alone at the cells'
+    local shapes: the lane rotate by half a head lowers and the tiles fit
+    VMEM, with the norm and without."""
+    from dalle_tpu.ops.pallas import head_norm_kernels as K
+
+    tokens = 8192
+    assert K.fits(tokens, lanes, K.LANES) is None
+
+    def both(x, scale, tables, w):
+        y, vjp = jax.vjp(lambda x, scale: K.per_head(
+            x, scale if normed else None, tables, 1e-5, K.LANES), x, scale)
+        return y, vjp(w)
+
+    x = jax.ShapeDtypeStruct((batch, tokens, lanes), jnp.bfloat16,
+                             sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((K.LANES,), jnp.float32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((tokens, K.LANES), jnp.float32,
+                                 sharding=one_chip)
+    lowered = jax.jit(both).lower(x, scale, (table, table), x)
+    assert {"_head_norm_fwd_kernel", "_head_norm_bwd_kernel"} <= set(
+        re.findall(r'kernel_name = "([^"]+)"', lowered.as_text()))
+    lowered.compile()
 
 
 @pytest.mark.parametrize("batch, tokens, heads, window, dtype, kernel", [

@@ -2,7 +2,10 @@
 the CPU, against the expression it replaces (a reshape to heads and
 ``rms_norm``) and its ``jax.grad``: 32- and 4-head widths, bf16 and f32, a
 row count that takes more than one tile, two samples, heads of two lane
-tiles; per shard on the 8-device mesh; and the rule that chooses it."""
+tiles; per shard on the 8-device mesh; and the rule that chooses it. The
+same pass with the rotary in it, after the norm (``trinitymini``) or alone
+(``smallthinker21b``), against the two expressions it replaces: the
+norm's pass and ``apply_rotary_lanes`` with tables as wide as the array."""
 import functools
 
 import jax
@@ -91,27 +94,33 @@ def test_a_row_count_past_one_tile_takes_several():
     assert K.rows_tile(8 * 137, 4096) == 8          # 8 x a prime
 
 
+@pytest.mark.parametrize("variant", ["norm", "norm_rotary", "rotary"])
 @pytest.mark.parametrize("nested", [False, True],
                          ids=["whole_mesh", "inside_manual_dp"])
-def test_per_shard_whole_heads_and_dscale_of_one_device(nested, monkeypatch,
+def test_per_shard_whole_heads_and_dscale_of_one_device(nested, variant,
+                                                        monkeypatch,
                                                         inside_manual_dp):
     """dp 2 x fsdp 2 x tp 2: a shard holds a sample's rows of two of the
     four heads; the replicated scale's gradient is the one-device value
     with no sum written out (the ``shard_map``'s transpose sums it over
-    the axes it binds, the gradient accumulation's psum over ``dp``)."""
+    the axes it binds, the gradient accumulation's psum over ``dp``). One
+    head's tables are every head's: a shard needs no other."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
     mesh = make_mesh(dp=2, fsdp=2, tp=2)
     keys = jax.random.split(jax.random.PRNGKey(7), 3)
     x = jax.random.normal(keys[0], (4, 24, 512)) * 2.0
     w = jax.random.normal(keys[1], x.shape)
     scale = 1.0 + 0.1 * jax.random.normal(keys[2], (128,))
-    norm = functools.partial(sparse_lm._norm_heads_shard, eps=EPS,
-                             head_dim=128, lanes=512)
+    normed, rotated = "norm" in variant, "rotary" in variant
+    work = functools.partial(sparse_lm._per_head_shard, eps=EPS,
+                             head_dim=128, lanes=512,
+                             theta=1e4 if rotated else None)
 
     def value_and_grads(mesh_):
         def f(scale, x, w):
-            out = per_shard(norm, mesh_, (LANES_SPEC, P()), LANES_SPEC,
-                            scope="qk_norm")(x, scale)
+            out = per_shard(work, mesh_, (LANES_SPEC, P())[:1 + normed],
+                            LANES_SPEC, scope="qk_norm")(
+                                x, *[scale] * normed)
             return jnp.sum(out * w), out
         vg = jax.value_and_grad(f, argnums=(0, 1), has_aux=True)
         if nested and mesh_ is not None:
@@ -121,9 +130,10 @@ def test_per_shard_whole_heads_and_dscale_of_one_device(nested, monkeypatch,
     (_, out_m), g_m = value_and_grads(mesh)(scale, x, w)
     (_, out_1), g_1 = value_and_grads(None)(scale, x, w)
     assert len(out_m.sharding.device_set) == 8
-    assert sparse_lm._HEAD_NORMS[24, 512, 128] is None
+    assert sparse_lm._HEAD_PASSES[24, 512, 128, normed, rotated] is None
     np.testing.assert_allclose(out_m, out_1, rtol=1e-6, atol=1e-6)
-    for a, b in zip(g_m, g_1):
+    # (no gradient reaches a scale that is not there)
+    for a, b in list(zip(g_m, g_1))[1 - normed:]:
         assert rel_l2(a, b) < 1e-6
 
 
@@ -143,19 +153,31 @@ def test_head_norm_why_not(tokens, width, head_dim, interpret, why,
     assert sparse_lm.head_norm_why_not(tokens, width, head_dim) == why
 
 
+@pytest.mark.parametrize("variant", ["norm", "norm_rotary", "rotary"])
 @pytest.mark.parametrize("shape, head_dim", [((2, 32, 256), 64),
                                              ((1, 12, 256), 128)])
 def test_what_does_not_fit_is_todays_expression_bit_for_bit(shape, head_dim,
+                                                            variant,
                                                             monkeypatch):
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
     keys = jax.random.split(jax.random.PRNGKey(3), 3)
     x = jax.random.normal(keys[0], shape).astype(jnp.bfloat16)
     w = jax.random.normal(keys[1], shape).astype(jnp.bfloat16)
     scale = 1.0 + 0.1 * jax.random.normal(keys[2], (head_dim,))
+    normed, rotated = "norm" in variant, "rotary" in variant
 
-    today = functools.partial(by_reshape, head_dim=head_dim)
-    now = functools.partial(sparse_lm._norm_heads_shard, eps=EPS,
-                            head_dim=head_dim, lanes=shape[2])
+    def today(x, scale):
+        if normed:
+            x = by_reshape(x, scale, head_dim)
+        if rotated:
+            x = by_rotary_lanes(x, head_dim, 1e4)
+        return x
+
+    def now(x, scale):
+        return sparse_lm._per_head_shard(
+            x, *[scale] * normed, eps=EPS, head_dim=head_dim,
+            lanes=shape[2], theta=1e4 if rotated else None)
+
     assert sparse_lm.head_norm_why_not(shape[1], shape[2],
                                        head_dim) is not None
     for fn, ref in ((now, today),
@@ -164,3 +186,152 @@ def test_what_does_not_fit_is_todays_expression_bit_for_bit(shape, head_dim,
         for a, b in zip(jax.tree.leaves(jax.jit(fn)(x, scale)),
                         jax.tree.leaves(jax.jit(ref)(x, scale))):
             assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# The rotary in the pass
+# ---------------------------------------------------------------------------
+
+# what the pass does beside the rotary, and the configuration's theta
+VARIANTS = {"norm_rotary": (True, 1e4),      # trinitymini
+            "rotary": (False, 1.5e6)}        # smallthinker21b
+
+# heads, head_dim, (samples, rows), dtype: queries' and keys' widths of the
+# two configurations, each over more than one tile of rows
+ROTARY_CASES = {
+    "q_4096_lanes_bf16": (32, 128, (1, 512), "bfloat16"),
+    "q_3584_lanes_two_samples_bf16": (28, 128, (2, 512), "bfloat16"),
+    "k_512_lanes_bf16": (4, 128, (1, 4096), "bfloat16"),
+    "k_512_lanes_one_tile_f32": (4, 128, (2, 104), "float32"),
+    "2_heads_of_256_f32": (2, 256, (1, 64), "float32"),
+}
+
+
+def by_rotary_lanes(x, head_dim, theta):
+    """The expression the pass replaces: tables as wide as the array."""
+    return attention.apply_rotary_lanes(
+        x, *attention.rotary_cos_sin(jnp.arange(x.shape[1]), head_dim,
+                                     theta, x.shape[2] // head_dim),
+        head_dim)
+
+
+def as_stated(fn, *args):
+    """``fn(*args)`` compiled to round where the program says it does. Left
+    to itself the CPU's compiler, which runs an interpreted kernel too,
+    keeps a value that is cast to bf16 and back in f32
+    (``xla_allow_excess_precision``); the chip's kernel compiler rounds."""
+    return jax.jit(fn).lower(*args).compile(
+        compiler_options={"xla_allow_excess_precision": False})(*args)
+
+
+@functools.lru_cache(maxsize=None)
+def _rotated(variant, case):
+    """(out, dx, dscale) of the one pass, of the two passes it replaces
+    (the norm's kernel, then the XLA rotary), and of the plain expression
+    in f32 from the same input (``dx`` rounded to the input's dtype as a
+    gradient is); ``dscale`` None where there is no norm."""
+    normed, theta = VARIANTS[variant]
+    heads, head_dim, rows, dtype = ROTARY_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    shape = (*rows, heads * head_dim)
+    x = (jax.random.normal(keys[0], shape) * 2.0 + 0.3).astype(dtype)
+    w = jax.random.normal(keys[1], shape).astype(dtype)
+    scale = 1.0 + 0.2 * jax.random.normal(keys[2], (head_dim,))
+
+    # the tables of both sides from one compiled cosine: fused into its
+    # reader, the CPU's compiler emits another one in some fusions, a last
+    # digit apart at large angles
+    pos = jnp.arange(rows[1])
+    wide = attention.rotary_cos_sin(pos, head_dim, theta, heads)
+    one = attention.rotary_cos_sin(pos, head_dim, theta)
+    if dtype == "bfloat16":
+        # ... and of 8 significant bits beside a bf16 input's 8, so that
+        # both products are exact in f32 and a fused multiply-add, which
+        # the CPU's compiler makes of one product or the other as it likes
+        # (the v5e's vector unit has none), rounds as the two operations do
+        wide, one = (tuple(t.astype(dtype).astype(jnp.float32) for t in ts)
+                     for ts in (wide, one))
+    assert all(np.array_equal(a, b[:, -head_dim:]) for a, b in zip(one, wide))
+    tables = K.rotary_tables(*one)
+
+    def one_pass(x, scale):
+        return K.per_head(x, scale if normed else None, tables, EPS,
+                          head_dim, True)
+
+    def two_passes(x, scale):
+        if normed:
+            x = K.head_rms_norm(x, scale, EPS, head_dim, True)
+        return attention.apply_rotary_lanes(x, *wide, head_dim)
+
+    def plain_f32(x, scale):
+        x = x.astype(jnp.float32)
+        if normed:
+            x = by_reshape(x, scale, head_dim)
+        return attention.apply_rotary_lanes(x, *wide, head_dim)
+
+    def outputs(fn, w):
+        def run(x, scale, w):
+            y, vjp = jax.vjp(fn, x, scale)
+            dx, dscale = vjp(w)
+            return y, dx, dscale if normed else None
+        return as_stated(run, x, scale, w)
+
+    return (outputs(one_pass, w), outputs(two_passes, w),
+            outputs(plain_f32, w.astype(jnp.float32)))
+
+
+ROTATED = [(v, c) for v in VARIANTS for c in ROTARY_CASES]
+
+
+@pytest.mark.parametrize("variant, case", ROTATED)
+def test_forward_is_the_two_passes_bit_for_bit(variant, case):
+    """The same f32 arithmetic a head and the same roundings: the normed
+    value to ``x.dtype`` before the rotary, the result to ``x.dtype``. In
+    f32, where a product is not exact, to the contraction the CPU's
+    compiler chose on either side: a last digit of the larger product."""
+    (y, _, _), (ref, _, _), _ = _rotated(variant, case)
+    assert y.dtype == ref.dtype and y.shape == ref.shape
+    if y.dtype == jnp.bfloat16:
+        assert np.array_equal(y, ref)
+    else:
+        assert rel_l2(y, ref) < 1e-7
+
+
+@pytest.mark.parametrize("variant, case", ROTATED)
+def test_dx_is_the_plain_gradient_and_no_further_than_two_passes(variant,
+                                                                  case):
+    """One rounding, at ``dx``, where the two passes round the rotary's
+    cotangent to ``x.dtype`` on the way (three of them and their sum in
+    the program: PERF.md section 6, PR 42)."""
+    (_, dx, _), (_, two, _), (_, ref, _) = _rotated(variant, case)
+    assert dx.dtype == two.dtype == jnp.dtype(ROTARY_CASES[case][3])
+    f32 = ROTARY_CASES[case][3] == "float32"
+    assert rel_l2(dx, ref) < (1e-6 if f32 else 1e-4)
+    # in f32 both are at its rounding
+    assert rel_l2(dx, ref) <= rel_l2(two, ref) + 1e-7 * f32
+
+
+@pytest.mark.parametrize("case", ROTARY_CASES)
+def test_dscale_through_the_rotary(case):
+    (_, _, ds), (_, _, two), (_, _, ref) = _rotated("norm_rotary", case)
+    assert ds.shape == ref.shape == (ROTARY_CASES[case][1],)
+    assert ds.dtype == jnp.float32
+    assert rel_l2(ds, ref) < 5e-6
+    assert rel_l2(ds, ref) <= rel_l2(two, ref) + 1e-7
+
+
+def test_the_rotary_alone_saves_no_array_of_the_inputs_size():
+    """Its transpose needs the tables and the cotangent: residuals of size
+    (B, T, H * d) are the norm's ``x`` alone."""
+    x = jnp.ones((1, 64, 512), jnp.bfloat16)
+    scale = jnp.ones((128,))
+
+    tables = K.rotary_tables(*attention.rotary_cos_sin(jnp.arange(64), 128))
+
+    def residuals(scale):
+        _, vjp = jax.vjp(
+            lambda x: K.per_head(x, scale, tables, EPS, 128, True), x)
+        return [r.shape for r in jax.tree.leaves(vjp)
+                if getattr(r, "shape", ()) == x.shape]
+
+    assert residuals(None) == [] and residuals(scale) == [x.shape]

@@ -79,9 +79,12 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(kernels,
     assert taken == kernels and split_why is None
     layout = sparse_lm.engagement_records(cfg)["attn_layout"]
     assert layout.startswith(f"blockwise 512: {4 * kernels} of 4 layers, ")
+    # (28 tokens: the rotary's pass wants rows in eights, the test below)
     assert layout.endswith(
         "2 query heads a key-value head"
-        + ", backward: one kernel a tile (4 of 4 layers)" * kernels)
+        + (", backward: one kernel a tile (4 of 4 layers), rotary (XLA: 28 "
+           "rows are not whole sublane tiles of 8)" if kernels else
+           ", rotary (XLA: no Mosaic backend)"))
 
 
 @pytest.mark.parametrize("interpret, budget, words", [
@@ -108,7 +111,59 @@ def test_attn_layout_says_which_backward_the_layers_took(
     layout = sparse_lm.engagement_records(cfg)["attn_layout"]
     assert layout.startswith(f"blockwise 512: {4 * interpret} of 4 layers, "
                              "1 full no-rope + 3 window 8 rope, ")
-    assert layout.endswith(f"2 query heads a key-value head{words}")
+    assert f"2 query heads a key-value head{words}, rotary (" in layout
+
+
+@pytest.mark.parametrize("interpret, head_dim, text_len, words", [
+    (True, 128, 16, "(one pass on the lanes: 3 of 3 rope layers)"),
+    (True, 64, 16, "(XLA: head_dim 64 is not whole 128-lane tiles)"),
+    (True, 128, 12, "(XLA: 28 rows are not whole sublane tiles of 8)"),
+    (False, 128, 16, "(XLA: no Mosaic backend)"),
+    (None, 128, 16, "(XLA: none traced)"),
+])
+def test_attn_layout_says_which_lowering_the_rotary_took(
+        interpret, head_dim, text_len, words, monkeypatch):
+    """Read from what the traced calls did, as the blockwise count is; a
+    configuration with no head norms: the rotary is a pass of its own."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", bool(interpret))
+    monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
+    cfg = SparseLMConfig(**dict(TINY, head_dim=head_dim,
+                                text_seq_len=text_len))
+    if interpret is not None:
+        text, image = _batch(cfg)
+        params = sparse_lm.init_params(sparse_lm.build(cfg),
+                                       jax.random.PRNGKey(1))
+        jax.eval_shape(lambda p: sparse_lm.build(cfg).apply(p, text, image),
+                       params)
+    layout = sparse_lm.engagement_records(cfg)["attn_layout"]
+    assert layout.endswith(f", rotary {words}") and "normed" not in layout
+    none = dataclasses.replace(cfg, layer_kinds=("full_nope",) * 4)
+    assert "rotary" not in sparse_lm.engagement_records(none)["attn_layout"]
+
+
+def test_the_rotary_in_its_pass_is_the_xla_lowering(monkeypatch):
+    """Loss and every gradient leaf of a tiny model whose three rope
+    layers rotate queries and keys in the pass (interpreted; 32 tokens),
+    against the same model with the rotary as ``apply_rotary_lanes``: the
+    same f32 model to its rounding."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
+    cfg = SparseLMConfig(**dict(TINY, head_dim=128, text_seq_len=16))
+    params = sparse_lm.init_params(sparse_lm.build(cfg),
+                                   jax.random.PRNGKey(1))
+    text, image = _batch(cfg)
+    took = lambda: {why for (t, *_), why in sparse_lm._HEAD_PASSES.items()
+                    if t == cfg.total_seq_len}
+    (loss, _), grads = _system(cfg, params, text, image)
+    assert took() == {None}
+    monkeypatch.setattr(sparse_lm.head_norm, "fits",
+                        lambda *a: "the test says so")
+    (ref_loss, _), ref_grads = _system(cfg, params, text, image)
+    assert took() == {"the test says so"}
+    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree.leaves(ref_grads)):
+        assert rel_l2(g, r) < 1e-5, jax.tree_util.keystr(path)
 
 
 def test_the_reference_at_the_sets_the_program_chose():

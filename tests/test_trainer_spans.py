@@ -44,6 +44,9 @@ class TestTrainLoopRing:
     def rows(self, tmp_path_factory):
         from dalle_tpu.training.loop import train_loop
         seen = []
+        # another file's task of these shapes, earlier in this process,
+        # would leave the accumulate compiled and step 1 nothing to count
+        jax.clear_caches()
         with _make_task(tmp_path_factory.mktemp("ring")) as task:
             assert task.tracer is default_tracer()
             assert task.tracer.sink_path is None        # ring only

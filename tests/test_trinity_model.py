@@ -89,8 +89,10 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(kernels,
     # blockwise attention's one-kernel backward
     layout = sparse_lm.engagement_records(cfg)["attn_layout"]
     assert ("normed queries and keys (one pass on the lanes: 5 of 5 layers)"
+            ", rotary (in the head pass: 4 of 4 rope layers), gated output"
             if kernels else "normed queries and keys (XLA: no Mosaic "
-            "backend)") in layout
+            "backend), rotary (XLA: no Mosaic backend), gated output"
+            ) in layout
     assert layout.startswith(
         "blockwise 512: 5 of 5 layers, 1 full no-rope + 4 window 8 rope, 2 "
         "query heads a key-value head, backward: one kernel a tile (5 of 5 "
@@ -176,24 +178,60 @@ def test_a_mechanism_left_out_is_told(mechanism, with_everything):
         assert rel_l2(g, r) < 2e-5
 
 
-@pytest.mark.parametrize("interpret, head_dim, words", [
-    (True, 128, "(one pass on the lanes: 2 of 2 layers)"),
-    (True, 64, "(XLA: head_dim 64 is not whole 128-lane tiles)"),
-    (False, 128, "(XLA: no Mosaic backend)"),
+@pytest.mark.parametrize("interpret, head_dim, words, rotary", [
+    (True, 128, "(one pass on the lanes: 2 of 2 layers)",
+     "(in the head pass: 1 of 1 rope layers)"),
+    (True, 64, "(XLA: head_dim 64 is not whole 128-lane tiles)", None),
+    (False, 128, "(XLA: no Mosaic backend)", None),
+    (None, 128, "(XLA: none traced)", None),
 ])
 def test_attn_layout_says_which_lowering_the_head_norms_took(
-        interpret, head_dim, words, monkeypatch):
-    """Read from what the traced calls did, as the blockwise count is."""
-    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", interpret)
+        interpret, head_dim, words, rotary, monkeypatch):
+    """Read from what the traced calls did, as the blockwise count is: the
+    head norms of every layer, and the rotary of the rope layers, which
+    runs in the norm's pass or, for the norm's own reason, as XLA."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", bool(interpret))
+    monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
     cfg = AfmoeLMConfig(**dict(SMALL, head_dim=head_dim))
-    text, image = _batch(cfg)
-    jax.eval_shape(lambda p: sparse_lm.build(cfg).apply(p, text, image),
-                   _params(cfg))
+    if interpret is not None:
+        text, image = _batch(cfg)
+        jax.eval_shape(lambda p: sparse_lm.build(cfg).apply(p, text, image),
+                       _params(cfg))
     layout = sparse_lm.engagement_records(cfg)["attn_layout"]
-    assert layout.endswith(f"normed queries and keys {words}, gated output")
+    assert layout.endswith(f"normed queries and keys {words}, rotary "
+                           f"{rotary or words}, gated output")
     without = dataclasses.replace(cfg, qk_norm=False)
     assert "normed" not in sparse_lm.engagement_records(without)[
         "attn_layout"]
+    # ... whose rotary would be a pass of its own, and none was traced
+    assert ", rotary (XLA: none traced), gated" in sparse_lm.\
+        engagement_records(without)["attn_layout"]
+
+
+def test_norm_and_rotary_in_one_pass_are_the_xla_lowering(monkeypatch):
+    """Loss and every gradient leaf of the tiny model whose four rope
+    layers norm and rotate queries and keys in one pass and whose full
+    layer norms them in it (interpreted), against the same model with the
+    reshaped ``rms_norm`` and ``apply_rotary_lanes``: the same f32 model
+    to its rounding."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
+    cfg = AfmoeLMConfig(**dict(TINY, head_dim=128))
+    params = _params(cfg)
+    text, image = _batch(cfg)
+    took = lambda: {(norm, rotary, why) for (t, _, _, norm, rotary), why
+                    in sparse_lm._HEAD_PASSES.items()
+                    if t == cfg.total_seq_len}
+    (loss, _), grads = _system(cfg, params, text, image)
+    assert took() == {(True, True, None), (True, False, None)}
+    monkeypatch.setattr(sparse_lm.head_norm, "fits",
+                        lambda *a: "the test says so")
+    (ref_loss, _), ref_grads = _system(cfg, params, text, image)
+    assert {why for _, _, why in took()} == {"the test says so"}
+    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree.leaves(ref_grads)):
+        assert rel_l2(g, r) < 1e-5, jax.tree_util.keystr(path)
 
 
 def test_attn_layout_names_the_split_backward_between_the_other_words(
@@ -370,7 +408,8 @@ def test_the_preset_trains_through_the_peers_normal_path():
     assert warm["attn_layout"].startswith("blockwise 512: 0 of 5 layers, 1 "
                                           "full no-rope + 4 window 8 rope")
     assert warm["attn_layout"].endswith(
-        "normed queries and keys (XLA: no Mosaic backend), gated output")
+        "normed queries and keys (XLA: no Mosaic backend), rotary (XLA: no "
+        "Mosaic backend), gated output")
     steps = [r for r in rows if r["phase"] == "loop/step"][-3:]
     for row in (r["a"] for r in steps):
         assert 0 < row["moe_assignments_here_pct"] < 100
