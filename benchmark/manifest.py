@@ -15,6 +15,23 @@ per-layer metric is a file of its own, found by the name in
 so a later PR adds a cell, or a configuration of another architecture, by
 adding files and entries, and edits nothing.
 
+**How a later PR adds to ``per_layer``** (``benchmark_checks.every_check``
+holds the repo's manifest and every rehearsal's throw-away root to it):
+
+- a new metric is one file under ``layer_metrics/`` and one entry **at the
+  end of the list**. No test holds the list's tail; the four ``late_*``
+  entries are held to being adjacent and in order, wherever that run stands
+  (``benchmark_checks.late_metrics_are_a_run``), so nothing goes between them.
+- a cell that reads a metric another cell already reads, with the same
+  reducer and ``params``, **appends its name to that entry's ``workloads``**
+  and brings no file: ``kernel_roofline``'s ``least`` names a function that
+  each cell's own yardstick resolves, so one entry serves every architecture
+  (``attn_roofline`` lists every cell, the ``moe_*`` ones the sparse cells).
+  A metric with no ``workloads`` list is read by every cell already.
+- a copy under ``<metric>.<cell>``, with a file of its own, is for a cell
+  whose ``params`` differ, and the file's ``note`` says in which. No check
+  forbids a copy; eleven that differed by ``name`` alone went at PR 43.
+
 **What a configuration's file holds** (``tests/benchmark_tests/
 benchmark_checks.py:configuration_file`` holds every configuration of
 ``BENCHMARK.json`` to it, and a stand-in of another architecture in a

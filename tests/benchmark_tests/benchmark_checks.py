@@ -25,6 +25,12 @@ from benchmark.harness import peaks_for
 PINNED = {"flagship": ([], set()),
           "xl": ([], {"dim", "heads", "vocab_image"})}
 
+# PR 35's four late-step metrics, which the ledger and PERF.md read side
+# by side: held together as a run, in this order, wherever in the list the
+# run stands. What comes before or after it is not held.
+LATE_RUN = ("late_steps", "late_excess_s", "late_pulse_missed_s",
+            "late_unnamed_s")
+
 
 def as_run(model_cfg):
     """A preset's dataclass as a configuration file holds it: every field,
@@ -88,6 +94,28 @@ def names_units_and_whys(man):
         assert M.UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
         if m["name"].endswith("_roofline"):
             assert m["unit"] == "%"
+
+
+def late_metrics_are_a_run(man):
+    """The four ``late_*`` metrics are in ``per_layer`` once each, adjacent,
+    in ``LATE_RUN``'s order, and every cell reports them (no ``workloads``
+    list). A later PR's entries go at the end of the list, after the run
+    or after whatever already follows it; one put inside the run is named."""
+    names = [m["name"] for m in man.data["per_layer"]]
+    for name in LATE_RUN:
+        assert names.count(name) == 1, (
+            f"per_layer names {name} {names.count(name)} times, not once")
+    start = names.index(LATE_RUN[0])
+    stands = (names + [None] * len(LATE_RUN))[start:start + len(LATE_RUN)]
+    for want, got in zip(LATE_RUN, stands):
+        assert got == want, (
+            f"per_layer: {got!r} stands where {want!r} belongs: the four "
+            f"late_* metrics are one run, in the order {list(LATE_RUN)}, "
+            f"and a new entry goes at the end of the list")
+    for m in man.data["per_layer"][start:start + len(LATE_RUN)]:
+        assert "workloads" not in m, (
+            f"per_layer: {m['name']} lists workloads; every cell reports "
+            f"the late_* metrics")
 
 
 def metric_file_agrees(man, metric):
@@ -213,6 +241,7 @@ def every_check(man, presets):
     parametrised tests do one case at a time."""
     manifest_shape(man)
     names_units_and_whys(man)
+    late_metrics_are_a_run(man)
     for cell_name in sorted(man.cells):
         cell_resolves_its_files(man, cell_name)
     for metric in man.data["per_layer"]:

@@ -3,9 +3,8 @@
 
     python tests/benchmark_tests/memory_rehearse.py <trace 0|1> <out dir>
 
-The throw-away root is ``benchmark_rehearse.tiny_root``'s with the five
-entries appended to its ``BENCHMARK.json`` (``scripts/memory_metrics_run.
-with_entries``: the repo's own does not name them yet). The CPU backend
+The throw-away root is ``benchmark_rehearse.tiny_root``'s, whose
+``per_layer`` is the repo's own and so names the five. The CPU backend
 keeps no allocator statistics, so the task's account is handed a fake
 allocator: the owners' sum, 3 MiB of program code, and the accumulate's
 operand for as long as a real one holds it (until the next wait for the
@@ -23,8 +22,6 @@ T0 = time.perf_counter()
 from benchmark_rehearse import tiny_root  # noqa: E402
 
 from benchmark import harness  # noqa: E402
-from benchmark.manifest import Manifest  # noqa: E402
-from scripts.memory_metrics_run import with_entries  # noqa: E402
 
 MIB = 2 ** 20
 CODE, RESERVED, LIMIT = 3 * MIB, 5 * MIB, 1 << 40
@@ -70,11 +67,6 @@ def install():
 if __name__ == "__main__":
     trace, out = int(sys.argv[1]), Path(sys.argv[2])
     cell = tiny_root(out / "root")
-    manifest = out / "root" / "BENCHMARK.json"
-    manifest.write_text(json.dumps(with_entries(
-        json.loads(manifest.read_text()),
-        out / "root" / "benchmark" / "layer_metrics")))
-    cell = Manifest(out / "root").cell(cell.name)
     install()
     res = harness.run_cell(
         cell, seed=2**31 + 12345, seconds=float(os.environ.get("SECS", "4")),

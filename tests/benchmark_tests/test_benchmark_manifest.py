@@ -7,6 +7,7 @@ import dataclasses
 import json
 import shutil
 import types
+from pathlib import Path
 
 import benchmark_checks as checks
 import pytest
@@ -18,6 +19,17 @@ from dalle_tpu.cli.run_trainer import MODEL_PRESETS
 
 MAN = M.Manifest()
 CELLS = sorted(MAN.cells)
+# the benchmark's five cells at PR 43, and the metrics more than one of
+# them reads through one entry: a cell a later PR adds joins these lists
+# (or brings a copy with parameters of its own) and is not judged here
+SPARSE_CELLS = {"smallthinker21b-train-solo", "trinitymini-train-solo"}
+CELLS_AT_PR_43 = SPARSE_CELLS | {"flagship-train-solo", "xl-train-solo",
+                                 "flagship-train-dp4"}
+SHARED = dict({"attn_roofline": CELLS_AT_PR_43}, **dict.fromkeys((
+    "moe_experts_roofline", "moe_router_share_pct", "moe_dispatch_share_pct",
+    "moe_experts_share_pct", "moe_load_max_over_mean",
+    "moe_assignments_here_pct", "moe_dense_calls", "moe_dropped",
+    "moe_sum_spills"), SPARSE_CELLS))
 
 
 def test_manifest_shape():
@@ -39,6 +51,30 @@ def test_metric_file_agrees_with_the_manifest(metric):
     checks.metric_file_agrees(MAN, metric)
 
 
+@pytest.mark.parametrize("metric", sorted(SHARED))
+def test_a_metric_several_cells_read_is_one_entry_that_lists_them(metric):
+    """No copy under ``<metric>.<cell>`` (eleven went at PR 43): of the
+    five cells exactly those that run the mechanism are listed, every
+    listed cell is there and reads the one file through its own yardstick,
+    and a roofline's name ends in ``_roofline`` and so is held to ``%``."""
+    entry = next(m for m in MAN.data["per_layer"] if m["name"] == metric)
+    listed = entry["workloads"]
+    assert len(listed) == len(set(listed)) and set(listed) <= set(MAN.cells)
+    assert set(listed) & CELLS_AT_PR_43 == SHARED[metric]
+    assert not [m["name"] for m in MAN.data["per_layer"]
+                if m["name"].startswith(metric + ".")
+                and m["name"][len(metric) + 1:] in CELLS_AT_PR_43]
+    on_file = json.loads(MAN.metric_file(metric).read_text())
+    if on_file["reducer"] == "kernel_roofline":
+        assert metric.endswith("_roofline") and entry["unit"] == "%"
+    for name in listed:
+        cell = MAN.cell(name)
+        read = next(m for m in cell.per_layer if m["name"] == metric)
+        assert read["params"] == on_file["params"]
+        if "least" in read["params"]:
+            assert callable(getattr(cell.yardstick, read["params"]["least"]))
+
+
 @pytest.mark.parametrize("config", sorted(MAN.configs))
 def test_configuration_file_holds_the_preset_as_run(config):
     """The rule of ``benchmark/manifest.py``'s docstring; ``flagship`` and
@@ -50,6 +86,12 @@ def _copy_of_the_benchmark(tmp_path):
     shutil.copytree(MAN.dir, tmp_path / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__"))
     return json.loads((MAN.root / "BENCHMARK.json").read_text())
+
+
+def _entry(entries, name):
+    """The entry of that name in a list of ``BENCHMARK.json``, wherever it
+    stands."""
+    return next(e for e in entries if e["name"] == name)
 
 
 def _per_layer_entry(on_file, cells):
@@ -67,24 +109,37 @@ def test_a_cell_added_as_files_and_one_entry_is_found(tmp_path):
                               "config": "flagship",
                               "traffic": "solo-256x64", "chips": 1,
                               "why": "test"})
-    # a metric that names its cells comes to a new cell as an entry and a
-    # file of the cell's own that name the same reducer: no list of an
-    # entry that is there is edited, and no code is added
-    own = dict(json.loads(MAN.metric_file("attn_roofline").read_text()),
-               name="attn_roofline.a64")
+    # a metric that names its cells comes to a new cell by the cell's name
+    # appended to the entry's list; a copy under <metric>.<cell>, with a
+    # file of its own, only where the cell's parameters differ (here the
+    # kernels of one scope alone), and the file says which
+    _entry(data["per_layer"], "attn_roofline")["workloads"].append(
+        "flagship-train-solo-a64")
+    shared = json.loads(MAN.metric_file("attn_roofline").read_text())
+    own = dict(shared, name="attn_roofline.a64",
+               params=dict(shared["params"], scope="(^|/)axial_row(/|$)"),
+               note="differs from attn_roofline by params.scope")
     (tmp_path / "benchmark/layer_metrics/attn_roofline.a64.json").write_text(
         json.dumps(own))
     data["per_layer"].append(
         _per_layer_entry(own, ["flagship-train-solo-a64"]))
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(data))
-    cell = M.Manifest(tmp_path).cell("flagship-train-solo-a64")
+    man = M.Manifest(tmp_path)
+    checks.manifest_shape(man)
+    checks.names_units_and_whys(man)
+    checks.late_metrics_are_a_run(man)
+    checks.metric_file_agrees(man, "attn_roofline.a64")
+    cell = man.cell("flagship-train-solo-a64")
     assert cell.traffic["grad_accum_steps"] == 64
     assert cell.config["preset"] == "flagship"
     read = {m["name"]: m for m in cell.per_layer}
-    assert "attn_roofline" not in read
+    assert read["attn_roofline"]["params"] == shared["params"]
     assert read["attn_roofline.a64"]["reducer"] == "kernel_roofline"
     assert read["attn_roofline.a64"]["params"]["least"] \
         == "attention_min_seconds_per_sample"
+    for name in CELLS:                 # the cells that were there: unmoved
+        assert [m["name"] for m in man.cell(name).per_layer] \
+            == [m["name"] for m in MAN.cell(name).per_layer]
     with pytest.raises(KeyError):
         M.Manifest(tmp_path).cell("no-such-cell")
 
@@ -112,6 +167,18 @@ def router_min_seconds_per_sample(model, peaks):
     flops = 2.0 * tokens_per_sample(model) * model["hidden"] * model[
         "experts_published"] * model["layers"]
     return {"seconds": 3.0 * flops / peaks["bf16_flops_per_s"]}
+
+
+def attention_min_seconds_per_sample(model, peaks):
+    t = tokens_per_sample(model)
+    flops = 4.0 * model["hidden"] * model["layers"] * t * (t + 1) / 2
+    return {"seconds": 3.0 * flops / peaks["bf16_flops_per_s"]}
+
+
+def experts_min_seconds_per_sample(model, peaks):
+    return {"seconds": train_flops_per_sample(model) * model[
+        "experts_held"] / model["experts_published"]
+        / peaks["bf16_flops_per_s"]}
 '''
 
 
@@ -174,11 +241,16 @@ def _standin_root(tmp_path, presets, mutate=None):
                               "chips": 1, "why": "test"})
     data["per_layer"].append(
         _per_layer_entry(roofline, ["standin-train-solo"]))
+    # what the next sparse configuration does with the metrics the sparse
+    # cells share: its cell's name on each list, and no copy
+    for metric in SHARED:
+        _entry(data["per_layer"], metric)["workloads"].append(
+            "standin-train-solo")
     if mutate is not None:
         mutate(data, on_file)
     (d / f"configs/{STANDIN}.json").write_text(json.dumps(on_file))
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(data))
-    return M.Manifest(tmp_path), roofline
+    return M.Manifest(tmp_path)
 
 
 @pytest.fixture()
@@ -195,16 +267,19 @@ def test_a_configuration_added_as_files_and_entries_is_found(tmp_path,
     **cut to a chip's share**, of a dataclass that is not the program's
     ``ModelConfig``), its yardstick file, a roofline metric as one
     ``layer_metrics`` file naming the common reducer and the yardstick's
-    function, and the entries that name them. It then meets **every** check
-    the suite applies per configuration, cell, metric and yardstick — the
-    same functions the parametrised tests call — and ``check_model``. No
-    file the benchmark had is edited, and the new cell reads its kernel's
-    share with no reducer code."""
+    function, the entries that name them **at the ends of the real lists**,
+    and its cell's name on the list of each metric the sparse cells share.
+    It then meets **every** check the suite applies per configuration,
+    cell, metric and yardstick — the same functions the parametrised tests
+    call, the run of ``late_*`` among them — and ``check_model``. No file
+    the benchmark had is edited, and the new cell reads its kernels'
+    shares, the shared ones through its own yardstick, with no reducer
+    code."""
     _copy_of_the_benchmark(tmp_path / "before")
     before = {p.relative_to(tmp_path / "before"): p.read_bytes()
               for p in (tmp_path / "before/benchmark").rglob("*")
               if p.is_file()}
-    man, roofline = _standin_root(tmp_path / "root", presets)
+    man = _standin_root(tmp_path / "root", presets)
     d = man.dir
 
     checks.every_check(man, presets)
@@ -220,17 +295,25 @@ def test_a_configuration_added_as_files_and_entries_is_found(tmp_path,
     assert cell.yardstick.__file__ == str(d / "yardsticks/other.py")
     assert cell.yardstick.tokens_per_sample(model) == 2048
     read = {m["name"]: m for m in cell.per_layer}
-    assert "router_roofline" in read and "attn_roofline" not in read
+    assert set(read) >= {"router_roofline", *SHARED}
     assert "mfu_pct" in read          # a metric of every cell comes along
     # the cells that were there read what they read, by the same yardstick
-    old = man.cell("flagship-train-solo")
-    assert old.yardstick.__file__ == str(d / "yardsticks/dalle.py")
-    assert "router_roofline" not in {m["name"] for m in old.per_layer}
+    for name in CELLS:
+        old = man.cell(name)
+        assert old.yardstick.__file__ == str(
+            d / "yardsticks" / Path(MAN.cell(name).yardstick.__file__).name)
+        assert [m["name"] for m in old.per_layer] \
+            == [m["name"] for m in MAN.cell(name).per_layer]
+
+    timed = {(read[name]["params"]["pattern"],
+              read[name]["params"].get("scope"))
+             for name in ("router_roofline", "attn_roofline",
+                          "moe_experts_roofline")}
 
     class Trace:
         @staticmethod
         def seconds_matching(pattern, scope=None):
-            return 0.5 if pattern == roofline["params"]["pattern"] else 0.0
+            return 0.5 if (pattern, scope) in timed else 0.0
 
     peaks = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
     ctx = RunContext(model=model, yardstick=cell.yardstick, chips=1,
@@ -241,6 +324,14 @@ def test_a_configuration_added_as_files_and_entries_is_found(tmp_path,
     least = 3.0 * 2.0 * 2048 * 2560 * 64 * 4 / 1e12
     assert M.reducer(m["reducer"])(ctx, **m["params"]) == pytest.approx(
         100 * least * 3 * 8 / 0.5)
+    # the shared rooflines: one file, this cell's own yardstick's functions
+    for name, least in (
+            ("attn_roofline", 3.0 * 4.0 * 2560 * 4 * 2048 * 2049 / 2 / 1e12),
+            ("moe_experts_roofline",
+             6.0 * 4 * 6 * 3 * 2560 * 768 * 2048 * 16 / 64 / 1e12)):
+        m = read[name]
+        assert M.reducer(m["reducer"])(ctx, **m["params"]) == pytest.approx(
+            100 * least * 3 * 8 / 0.5), name
     flops_per_token = 6.0 * 4 * 6 * 3 * 2560 * 768
     assert M.reducer(read["mfu_pct"]["reducer"])(ctx) == pytest.approx(
         100 * flops_per_token * 5000.0 / 1e12)
@@ -254,10 +345,57 @@ def test_a_configuration_added_as_files_and_entries_is_found(tmp_path,
         "benchmark/yardsticks/other.py"]
 
 
+def _late_at(data, name):
+    return [m["name"] for m in data["per_layer"]].index(name)
+
+
+def _entry_inside_the_run(data, on_file):
+    """The fault PR 43 repaired, the other way round: the stand-in's
+    roofline entry not at the end of the list but between two of the four."""
+    data["per_layer"].insert(_late_at(data, "late_excess_s"),
+                             data["per_layer"].pop())
+
+
+def _run_out_of_order(data, on_file):
+    a, b = _late_at(data, "late_excess_s"), _late_at(data,
+                                                     "late_pulse_missed_s")
+    data["per_layer"][a], data["per_layer"][b] = (data["per_layer"][b],
+                                                  data["per_layer"][a])
+
+
+def _one_of_the_four_gone(data, on_file):
+    del data["per_layer"][_late_at(data, "late_unnamed_s")]
+
+
+def _one_of_the_four_lists_cells(data, on_file):
+    _entry(data["per_layer"], "late_steps")["workloads"] = [
+        "standin-train-solo"]
+
+
+@pytest.mark.parametrize("mutate, message", [
+    (_entry_inside_the_run,
+     r"per_layer: 'router_roofline' stands where 'late_excess_s' belongs"),
+    (_run_out_of_order,
+     r"per_layer: 'late_pulse_missed_s' stands where 'late_excess_s' "
+     r"belongs"),
+    (_one_of_the_four_gone, r"per_layer names late_unnamed_s 0 times"),
+    (_one_of_the_four_lists_cells, r"per_layer: late_steps lists workloads"),
+])
+def test_a_list_that_breaks_the_run_of_late_metrics_is_refused(
+        tmp_path, presets, mutate, message):
+    """``every_check`` holds a rehearsal's root to the run too, and the
+    message names the metric that stands in the wrong place."""
+    man = _standin_root(tmp_path, presets, mutate)
+    with pytest.raises(AssertionError, match=message):
+        checks.late_metrics_are_a_run(man)
+    with pytest.raises(AssertionError, match=message):
+        checks.every_check(man, presets)
+
+
 def _no_such_key(data, on_file):
     on_file["reduced"].append("no_such_key")
     on_file["published"]["no_such_key"] = 1
-    data["configs"][-1]["reduced"].append("no_such_key")
+    _entry(data["configs"], STANDIN)["reduced"].append("no_such_key")
 
 
 def _no_published(data, on_file):
@@ -265,7 +403,7 @@ def _no_published(data, on_file):
 
 
 def _entry_disagrees(data, on_file):
-    data["configs"][-1]["reduced"] = ["layers"]
+    _entry(data["configs"], STANDIN)["reduced"] = ["layers"]
 
 
 def _role_that_does_not_compile(data, on_file):
@@ -281,7 +419,7 @@ def _cut_flagship(root):
         flagship.update(reduced=["depth"], published={"depth": 128},
                         layer_shared_by=1)
         path.write_text(json.dumps(flagship))
-        data["configs"][0]["reduced"] = ["depth"]
+        _entry(data["configs"], "flagship")["reduced"] = ["depth"]
     return mutate
 
 
@@ -306,7 +444,7 @@ def test_a_configuration_that_breaks_the_rule_is_refused(
               "entry_disagrees": _entry_disagrees,
               "cut_flagship": _cut_flagship(tmp_path),
               "role_that_does_not_compile": _role_that_does_not_compile}[case]
-    man, _ = _standin_root(tmp_path, presets, mutate)
+    man = _standin_root(tmp_path, presets, mutate)
     with pytest.raises(AssertionError, match=message):
         checks.configuration_file(man, config, presets)
     # the other configurations of that root still pass
@@ -323,7 +461,7 @@ def test_the_two_pinned_configurations_keep_their_assumed(
         path = tmp_path / f"benchmark/configs/{config}.json"
         path.write_text(json.dumps(dict(json.loads(path.read_text()),
                                         **{key: value})))
-    man, _ = _standin_root(tmp_path, presets, mutate)
+    man = _standin_root(tmp_path, presets, mutate)
     with pytest.raises(AssertionError,
                        match=f"configuration {config}: {key} is pinned"):
         checks.configuration_file(man, config, presets)

@@ -1,9 +1,9 @@
-"""The memory account's five per-layer metrics (PR 41): their files under
-``benchmark/layer_metrics/`` held to the manifest's rules on a copy of
-``BENCHMARK.json`` that names them (the repo's own does not yet: see
-``scripts/memory_metrics_run.py``), read by ``reducers/program_attr.py``
-from hand-made ``loop/step`` rows with known answers, and all five on the
-line of a traced rehearsal behind a fake allocator."""
+"""The memory account's five per-layer metrics (PR 41; entries of
+``BENCHMARK.json`` since PR 43): their files under
+``benchmark/layer_metrics/`` held to the manifest's rules, read by
+``reducers/program_attr.py`` from hand-made ``loop/step`` rows with known
+answers, and all five on the line of a traced rehearsal behind a fake
+allocator."""
 import json
 import os
 import statistics
@@ -19,8 +19,7 @@ from benchmark.harness import RunContext
 from benchmark.reducers import program_attr
 from dalle_tpu.obs import memory as account
 from dalle_tpu.obs import trace as obs_trace
-from scripts.memory_metrics_run import (MEMORY_METRICS, root_with_entries,
-                                        with_entries)
+from scripts.memory_metrics_run import MEMORY_METRICS, with_entries
 
 ROOT = Path(__file__).resolve().parent.parent.parent
 #: metric -> (the ``loop/step`` attribute it reads, how it reduces it)
@@ -34,8 +33,8 @@ READS = {"loop_in_use_peak_gib": ("mem_step_max", max),
 
 
 @pytest.fixture(scope="module")
-def man(tmp_path_factory):
-    return M.Manifest(root_with_entries(tmp_path_factory.mktemp("root")))
+def man():
+    return M.Manifest()
 
 
 def test_the_manifest_with_the_entries_passes_every_check(man):
@@ -65,20 +64,16 @@ def test_the_metric_keeps_to_the_manifest(man, metric):
         assert metric in {m["name"] for m in man.cell(cell).per_layer}
 
 
-def test_the_entries_go_at_the_end_and_move_nothing():
-    """What a ``benchmark`` PR has to do: the five entries after the list
-    as it stands (whose tail ``test_benchmark_late_steps.py`` pins, which
-    is why this PR does not name them in ``BENCHMARK.json``)."""
+def test_the_five_are_in_the_list_each_once_as_their_files_have_them():
+    """Since PR 43 ``BENCHMARK.json`` names the five, each once and as its
+    file has it, so ``scripts/memory_metrics_run.with_entries``, which
+    appended them to a copy until then, finds nothing to add."""
     data = json.loads((ROOT / "BENCHMARK.json").read_text())
     names = [m["name"] for m in data["per_layer"]]
-    assert not set(names) & set(MEMORY_METRICS)
-    grown = with_entries(data, ROOT / "benchmark" / "layer_metrics")
-    assert grown["per_layer"][:len(names)] == data["per_layer"]
-    assert tuple(m["name"] for m in grown["per_layer"][len(names):]) \
-        == MEMORY_METRICS
-    assert {k: v for k, v in grown.items() if k != "per_layer"} \
-        == {k: v for k, v in data.items() if k != "per_layer"}
-    assert with_entries(grown, ROOT / "benchmark" / "layer_metrics") == grown
+    for metric in MEMORY_METRICS:
+        assert names.count(metric) == 1, metric
+        checks.metric_file_agrees(M.Manifest(), metric)
+    assert with_entries(data, ROOT / "benchmark" / "layer_metrics") == data
 
 
 @pytest.fixture()

@@ -21,7 +21,7 @@ from dalle_tpu.cli.run_trainer import MODEL_PRESETS
 ROOT = M.ROOT
 MAN = M.Manifest()
 CONFIG, CELL = "smallthinker21b", "smallthinker21b-train-solo"
-NEW_METRICS = ("attn_roofline." + CELL, "moe_experts_roofline",
+NEW_METRICS = ("attn_roofline", "moe_experts_roofline",
                "moe_router_share_pct", "moe_dispatch_share_pct",
                "moe_experts_share_pct", "moe_load_max_over_mean",
                "moe_assignments_here_pct", "moe_dense_calls", "moe_dropped")
@@ -47,11 +47,12 @@ def test_everything_the_pr_adds_passes_every_check():
             cell.traffic["grad_accum_steps"]) == (2, 4)
     read = {m["name"] for m in cell.per_layer}
     assert read >= set(NEW_METRICS)
-    assert "attn_roofline" not in read      # the DALL-E cells' own entry
+    # attn_roofline is one entry for every cell since PR 43 (the cell's
+    # own copy until then); the moe_* ones are the sparse cells' alone
     for other in ("flagship-train-solo", "xl-train-solo",
                   "flagship-train-dp4"):
-        assert not {m["name"] for m in MAN.cell(other).per_layer} \
-            & set(NEW_METRICS)
+        assert {m["name"] for m in MAN.cell(other).per_layer} \
+            & set(NEW_METRICS) == {"attn_roofline"}
 
 
 def test_the_file_holds_the_sources_config_under_the_sources_keys():
@@ -178,7 +179,7 @@ def test_every_trace_fed_metric_of_the_pr_reads_the_programs_scopes():
     assert read("moe_experts_share_pct") == pytest.approx(2 * share)
     y, model = cell.yardstick, cell.config["model"]
     attn = y.attention_min_seconds_per_sample(model, peaks)["seconds"]
-    assert read("attn_roofline." + CELL) == pytest.approx(
+    assert read("attn_roofline") == pytest.approx(
         100 * attn * 24 / 200e-9)
     experts = y.experts_min_seconds_per_sample(model, peaks)["seconds"]
     assert read("moe_experts_roofline") == pytest.approx(
@@ -197,7 +198,7 @@ def test_every_trace_fed_metric_of_the_pr_reads_the_programs_scopes():
         raw["planes"][1]])
     ctx.trace = T.Reduced(bare)
     assert read("moe_experts_roofline") is None
-    assert read("attn_roofline." + CELL) is None
+    assert read("attn_roofline") is None
 
 
 def test_the_program_attribute_reducer(monkeypatch):

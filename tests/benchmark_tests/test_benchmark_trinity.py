@@ -24,13 +24,12 @@ CONFIG, CELL = "trinitymini", "trinitymini-train-solo"
 SPARSE_CELL = "smallthinker21b-train-solo"
 OWN_METRICS = ("moe_shared_share_pct", "ff_dense_share_pct",
                "attn_gate_share_pct")
-# the cell's own copies of the sparse cell's metrics, by their stems
-COPIED = {"attn_roofline": "attn_roofline." + SPARSE_CELL,
-          **{stem: stem for stem in (
-              "moe_experts_roofline", "moe_router_share_pct",
-              "moe_dispatch_share_pct", "moe_experts_share_pct",
-              "moe_load_max_over_mean", "moe_assignments_here_pct",
-              "moe_dense_calls", "moe_dropped", "moe_sum_spills")}}
+# the metrics the cell reads through the entry the sparse cell reads (its
+# own copies under ``<metric>.<cell>`` until PR 43)
+SHARED = ("attn_roofline", "moe_experts_roofline", "moe_router_share_pct",
+          "moe_dispatch_share_pct", "moe_experts_share_pct",
+          "moe_load_max_over_mean", "moe_assignments_here_pct",
+          "moe_dense_calls", "moe_dropped", "moe_sum_spills")
 
 # config.json of arcee-ai/Trinity-Mini: its numbers
 PERIOD = ["sliding_attention"] * 3 + ["full_attention"]
@@ -62,19 +61,21 @@ def test_everything_the_pr_adds_passes_every_check():
     for key in set(other) - {"why", "per_device_batch", "grad_accum_steps"}:
         assert cell.traffic[key] == other[key], key
     read = {m["name"] for m in cell.per_layer}
-    mine = set(OWN_METRICS) | {f"{stem}.{CELL}" for stem in COPIED}
-    assert read >= mine
-    assert not read & set(COPIED.values())      # the sparse cell's own
+    assert read >= set(OWN_METRICS) | set(SHARED)
+    assert not [name for name in read if CELL in name]      # no copy
     for name in ("flagship-train-solo", "xl-train-solo",
                  "flagship-train-dp4", SPARSE_CELL):
-        assert not {m["name"] for m in MAN.cell(name).per_layer} & mine
-    # a copy names the reducer and the parameters of what it copies
+        assert not {m["name"] for m in MAN.cell(name).per_layer} \
+            & set(OWN_METRICS)
+    # one entry and one file for both sparse cells, each through the
+    # functions of its own yardstick
     files = {m["name"]: m for m in cell.per_layer}
     theirs = {m["name"]: m for m in MAN.cell(SPARSE_CELL).per_layer}
-    for stem, original in COPIED.items():
-        copy = files[f"{stem}.{CELL}"]
-        for key in ("reducer", "params", "unit", "better", "source"):
-            assert copy[key] == theirs[original][key], (stem, key)
+    for name in SHARED:
+        assert files[name] == theirs[name], name
+    least = files["moe_experts_roofline"]["params"]["least"]
+    assert getattr(cell.yardstick, least).__module__ \
+        != getattr(MAN.cell(SPARSE_CELL).yardstick, least).__module__
 
 
 def test_the_file_holds_the_sources_config_under_the_sources_keys():
@@ -231,15 +232,14 @@ def test_every_trace_fed_metric_of_the_pr_reads_the_programs_scopes():
     assert read("moe_shared_share_pct") == pytest.approx(2 * share)
     assert read("ff_dense_share_pct") == pytest.approx(3 * share)
     assert read("attn_gate_share_pct") == pytest.approx(3 * share)
-    own = lambda stem: read(f"{stem}.{CELL}")
-    assert own("moe_router_share_pct") == pytest.approx(share)
-    assert own("moe_dispatch_share_pct") == pytest.approx(2 * share)
-    assert own("moe_experts_share_pct") == pytest.approx(share)
+    assert read("moe_router_share_pct") == pytest.approx(share)
+    assert read("moe_dispatch_share_pct") == pytest.approx(2 * share)
+    assert read("moe_experts_share_pct") == pytest.approx(share)
     y, model = cell.yardstick, cell.config["model"]
     attn = y.attention_min_seconds_per_sample(model, peaks)["seconds"]
-    assert own("attn_roofline") == pytest.approx(100 * attn * 24 / 100e-9)
+    assert read("attn_roofline") == pytest.approx(100 * attn * 24 / 100e-9)
     experts = y.experts_min_seconds_per_sample(model, peaks)["seconds"]
-    assert own("moe_experts_roofline") == pytest.approx(
+    assert read("moe_experts_roofline") == pytest.approx(
         100 * experts * 24 / 100e-9)
     # the accepted shares keep their meaning: the new scopes lie under
     # their roots, a shared expert's gate is none of attention's
@@ -256,16 +256,16 @@ def test_every_trace_fed_metric_of_the_pr_reads_the_programs_scopes():
     ctx.trace = T.Reduced(bare)
     for name in OWN_METRICS:
         assert read(name) == 0.0
-    assert own("moe_experts_roofline") is None
-    assert own("attn_roofline") is None
+    assert read("moe_experts_roofline") is None
+    assert read("attn_roofline") is None
 
 
 def test_a_traced_rehearsal_of_the_preset_runs_through_the_harness(
         tmp_path):
     """The tiny preset, its yardstick and the new metric files through
     ``harness.run_cell`` on the CPU: the reference check passes, the
-    program-fed metrics of the cell are read under the cell's own names,
-    the trace-fed ones are left out (no device plane here)."""
+    program-fed metrics of the cell are read under the names both sparse
+    cells share, the trace-fed ones are left out (no device plane here)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", SECS="6",
                PYTHONPATH=str(ROOT))
     env.pop("XLA_FLAGS", None)
@@ -280,7 +280,7 @@ def test_a_traced_rehearsal_of_the_preset_runs_through_the_harness(
     result = json.loads(last.split(":", 1)[1])
     assert result["correct"] is True and result["failed"] == 0
     got = result["metrics"]
-    own = lambda stem: got[f"{stem}.{CELL}"]["value"]
+    own = lambda name: got[name]["value"]
     assert 0 < own("moe_assignments_here_pct") < 100
     assert own("moe_load_max_over_mean") >= 1.0
     # interpreted kernels: the sorted lowering takes every call of the two
@@ -291,8 +291,7 @@ def test_a_traced_rehearsal_of_the_preset_runs_through_the_harness(
                  "compiles_after_first_step", "grad_step_plan_gib"):
         assert name in got, name
     assert got["compiles_after_first_step"]["value"] == 0
-    for name in (*OWN_METRICS, f"attn_roofline.{CELL}",
-                 f"moe_router_share_pct.{CELL}"):
+    for name in (*OWN_METRICS, "attn_roofline", "moe_router_share_pct"):
         assert name not in got
     line = [json.loads(line) for line in done.stdout.splitlines()
             if line.startswith('{"reference_check"')][0]
