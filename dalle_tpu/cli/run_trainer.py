@@ -23,9 +23,10 @@ import logging
 import sys
 from typing import Optional, Sequence
 
-from dalle_tpu.config import (AfmoeLMConfig, CollabConfig, ModelConfig,
-                              OptimizerConfig, PeerConfig, SparseLMConfig,
-                              TrainerConfig, flagship_model_config,
+from dalle_tpu.config import (AfmoeLMConfig, CollabConfig, JoyAILMConfig,
+                              ModelConfig, OptimizerConfig, PeerConfig,
+                              SparseLMConfig, TrainerConfig,
+                              flagship_model_config, joyaiflash_model_config,
                               smallthinker21b_model_config,
                               tiny_model_config, trinitymini_model_config,
                               xl_model_config)
@@ -47,13 +48,17 @@ MODEL_PRESETS = {
     # Trinity-Mini cut to one of 16 chips' share of a layer (a subclass
     # that states its mechanisms as fields): trinitymini-train-solo
     "trinitymini": trinitymini_model_config,
+    # JoyAI-LLM-Flash cut to one of 32 chips' share of a layer (latent
+    # attention, a prediction module): joyaiflash-train-solo
+    "joyaiflash": joyaiflash_model_config,
 }
 
 CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
                   PeerConfig)
 # Every architecture's configuration class. A preset builds one of them;
 # a field two of them share (vocab_text, dtype, ...) is one flag.
-MODEL_CLASSES = (ModelConfig, SparseLMConfig, AfmoeLMConfig)
+MODEL_CLASSES = (ModelConfig, SparseLMConfig, AfmoeLMConfig,
+                 JoyAILMConfig)
 
 
 def maybe_wandb_run(project: Optional[str], name: str):

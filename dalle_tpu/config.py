@@ -693,8 +693,9 @@ class AuxConfig:
 
 LAYER_FULL_NOPE = "full_nope"      # causal over the whole sequence, no positions
 LAYER_WINDOW_ROPE = "window_rope"  # causal inside ``window``, rotary
+LAYER_FULL_ROPE = "full_rope"      # causal over the whole sequence, rotary
 
-VALID_LAYER_KINDS = (LAYER_FULL_NOPE, LAYER_WINDOW_ROPE)
+VALID_LAYER_KINDS = (LAYER_FULL_NOPE, LAYER_WINDOW_ROPE, LAYER_FULL_ROPE)
 
 
 @dataclass(frozen=True)
@@ -767,6 +768,17 @@ class SparseLMConfig:
     qk_norm: ClassVar[bool] = False
     sandwich_norms: ClassVar[bool] = False
     mup_enabled: ClassVar[bool] = False
+    # ... and fields of ``JoyAILMConfig``: latent attention (0: the three
+    # projections of one width above) and prediction modules after the
+    # last layer (0: one loss)
+    q_lora_rank: ClassVar[int] = 0
+    kv_lora_rank: ClassVar[int] = 0
+    qk_nope_head_dim: ClassVar[int] = 0
+    qk_rope_head_dim: ClassVar[int] = 0
+    v_head_dim: ClassVar[int] = 0
+    rope_interleave: ClassVar[bool] = False
+    num_nextn_predict_layers: ClassVar[int] = 0
+    mtp_loss_weight: ClassVar[float] = 0.0
     # fields a configuration's file states and no entry point's flag sets:
     # what the source fixes and models/sparse_lm.py is written for
     # (``validate`` holds each to its one value), and the one assumption
@@ -801,6 +813,10 @@ class SparseLMConfig:
         for kind in self.layer_kinds:
             if kind not in VALID_LAYER_KINDS:
                 raise ValueError(f"unknown layer kind {kind!r}")
+            if kind == LAYER_FULL_ROPE and not self.kv_lora_rank:
+                raise ValueError(
+                    f"layer kind {kind!r} is latent attention: a class "
+                    "that states its widths has it (JoyAILMConfig)")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
         if self.vocab_text + self.vocab_image != self.vocab_size:
@@ -934,6 +950,95 @@ def trinitymini_model_config(**overrides: Any) -> AfmoeLMConfig:
     return dataclasses.replace(AfmoeLMConfig(), **overrides)
 
 
+@dataclass(frozen=True)
+class JoyAILMConfig(AfmoeLMConfig):
+    """``AfmoeLMConfig``'s router, shared expert and leading dense layer
+    (no output gate, no head norms, two norms a layer, no embedding scale)
+    with the two mechanisms of ``model_type`` ``joyai_llm_flash`` as
+    fields: **latent attention** in every layer (queries through a normed
+    latent of ``q_lora_rank``; keys and values from a normed latent of
+    ``kv_lora_rank``; a head's query-key width is ``qk_nope_head_dim`` +
+    ``qk_rope_head_dim``, the rotary part of the key is ONE head that every
+    query head reads; values are ``v_head_dim`` wide; rotary on interleaved
+    pairs, causal over the whole sequence: ``full_rope``), and
+    ``num_nextn_predict_layers`` **prediction modules** after the last
+    layer (one more expert layer on ``[norm(E[t+1]) ; norm(z)] . W_eh``
+    through the same embedding and head, its loss weighted
+    ``mtp_loss_weight``). Defaults are JoyAI-LLM-Flash (jdopensource,
+    config.json) cut to the share one of the 32 chips of a layer holds:
+    the dense layer and four expert layers (published 0-4), experts 0-7 of
+    256, an eighth of the vocabulary, the prediction module; every width
+    as published. ``head_dim`` and ``num_kv_heads`` are the source's keys
+    (64, 32) and nothing here reads them; ``window`` is no layer's."""
+
+    num_hidden_layers: int = 5       # published 40: 1 dense + 39 expert
+    num_kv_heads: int = 32
+    head_dim: int = 64
+    expert_width: int = 768
+    num_experts: int = 256
+    vocab_size: int = 16160          # published 129280
+    window: int = 0
+    layer_kinds: Tuple[str, ...] = (LAYER_FULL_ROPE,)
+    rope_theta: float = 3.2e7
+    rms_eps: float = 1e-6
+    vocab_text: int = 8080
+    vocab_image: int = 8080
+    dense_width: int = 7168
+    route_scale: float = 2.5
+    attention_gate: bool = False
+    qk_norm: bool = False
+    sandwich_norms: bool = False
+    mup_enabled: bool = False
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_interleave: bool = True
+    num_nextn_predict_layers: int = 1
+    # assumed: config.json gives no weight; 0.3 is the DeepSeek-V3
+    # report's for most of its run (section 4.2)
+    mtp_loss_weight: float = 0.3
+
+    # the head's three widths are the source's and the only ones the
+    # blockwise kernels take
+    no_flag: ClassVar[Tuple[str, ...]] = AfmoeLMConfig.no_flag + (
+        "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+        "rope_interleave", "mtp_loss_weight")
+    decode_missing: ClassVar[Optional[str]] = (
+        "models/decode.py has no latent attention (no cache of the "
+        "compressed keys and values and the one rotary key, no absorbed "
+        "form), no dense gated block, no expert layer with a shared expert "
+        "and no use for a prediction module")
+
+    def validate(self) -> None:
+        super().validate()
+        if min(self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+               self.qk_rope_head_dim, self.v_head_dim) <= 0 \
+                or self.qk_rope_head_dim % 2:
+            raise ValueError(
+                "latent attention needs q_lora_rank, kv_lora_rank, "
+                "qk_nope_head_dim, v_head_dim and an even qk_rope_head_dim")
+        if not self.rope_interleave:
+            raise ValueError(
+                "models/sparse_lm.py rotates latent attention's rotary part "
+                "on interleaved pairs (rope_interleave)")
+        if self.attention_gate or self.qk_norm:
+            raise ValueError("latent attention has no output gate and no "
+                             "head norms")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError("models/sparse_lm.py has one prediction module "
+                             "or none")
+        if self.num_nextn_predict_layers and self.total_seq_len < 3:
+            raise ValueError("a prediction module needs three tokens")
+
+
+def joyaiflash_model_config(**overrides: Any) -> JoyAILMConfig:
+    """Preset ``joyaiflash``: the cell ``joyaiflash-train-solo``
+    (benchmark/configs/joyaiflash.json holds ``asdict`` of it)."""
+    return dataclasses.replace(JoyAILMConfig(), **overrides)
+
+
 def tiny_model_config(**overrides: Any) -> ModelConfig:
     """CPU-smoke configuration (preset ``tiny``): 4 full-attention layers
     of width 64."""
@@ -983,8 +1088,8 @@ def xl_model_config(**overrides: Any) -> ModelConfig:
     """
     # Blanket remat and the XLA LayerNorm (ln_fusion off): a pre-round
     # reading had the fused kernel slower at this width, where XLA fuses
-    # the norm into its neighbours (BENCHMARK.json's xl.why keeps that
-    # claim); not measured on today's stack (ROADMAP Design 5).
+    # the norm into its neighbours; not measured on today's stack
+    # (ROADMAP Design 5).
     base = dict(dim=1792, heads=28, head_dim=64,
                 vocab_image=16384, image_grid=32,
                 remat_skip_blocks=0, head_chunk=2048, scan_unroll=2)

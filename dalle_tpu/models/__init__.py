@@ -8,7 +8,7 @@ def family(cfg):
     (``init_params(model, rng)``), says its own engagement records
     (``engagement_records(cfg, mesh)``: attributes of the ``setup/warmup``
     row) and names the entries of the step's ``aux`` that go onto every
-    ``loop/step`` row (``STEP_ATTRIBUTES``; ``SLOW_STEP_ATTRIBUTES`` are
-    those that count a slower lowering)."""
+    ``loop/step`` row (``step_attributes(cfg)``; ``SLOW_STEP_ATTRIBUTES``
+    are those that count a slower lowering)."""
     import importlib
     return importlib.import_module(cfg.model_module)

@@ -281,9 +281,14 @@ def engagement_records(cfg: ModelConfig, mesh=None) -> dict:
             "attn_layout": attn_layout_record(cfg, mesh)}
 
 
-# entries of the step's aux that go onto every loop/step row, and those
-# of them that count a slower lowering (obs/late.py's cause "model"): none
-STEP_ATTRIBUTES = ()
+def step_attributes(cfg) -> tuple:
+    """Entries of the step's aux that go onto every ``loop/step`` row:
+    none."""
+    return ()
+
+
+# those of them that count a slower lowering (obs/late.py's cause
+# "model"): none
 SLOW_STEP_ATTRIBUTES = ()
 
 

@@ -40,6 +40,29 @@ What ``AfmoeLMConfig`` describes (its defaults: Trinity-Mini, arcee-ai,
 (:class:`GatedBlock` is the dense layer's block and the shared expert, which
 every token takes and every rank computes alike.)
 
+What ``JoyAILMConfig`` describes (its defaults: JoyAI-LLM-Flash,
+jdopensource, ``model_type`` ``joyai_llm_flash``): ``AfmoeLMConfig``'s dense
+and expert layers with two norms a layer, no gate, no head norms and no
+embedding scale, around **latent attention** (:class:`LatentAttention`,
+the layers of kind ``full_rope``: every one of the preset's):
+
+    c_q   = rmsnorm(a . W_qa)              ``q_lora_rank``
+    [q_nope ; q_rope] = c_q . W_qb         H x ``qk_nope_head_dim``, then
+                                           H x ``qk_rope_head_dim``
+    [c_kv ; k_rope] = a . W_kva            ``kv_lora_rank`` + ONE rotary key
+    [k_nope ; v] = rmsnorm(c_kv) . W_kvb   H x nope, then H x ``v_head_dim``
+    q_rope, k_rope <- rotary on interleaved pairs (x_2i, x_2i+1)
+    s_h   = (q_nope,h . k_nope,h + q_rope,h . k_rope) / sqrt(nope + rope)
+    h     = x + concat_h(softmax(s_h) v_h) . W_o       causal, whole sequence
+
+and, with ``num_nextn_predict_layers`` 1, a **prediction module**
+(:class:`PredictionModule`, parameters under ``mtp``) after the final norm:
+``h'_i = [rmsnorm(E[t_{i+1}]) ; rmsnorm(z_i)] . W_eh`` of the main model's
+normed last state ``z``, one more expert layer, a final norm of its own,
+the same embedding and the same head, cross-entropy against ``t_{i+2}``
+over the T - 2 positions that have one. The step's ``loss`` is ``loss_main
++ mtp_loss_weight * loss_mtp``; both ride beside it in the step's aux.
+
 **The expert layer is told which experts it holds** (``experts_held``
 consecutive ones from ``expert_offset``): it routes over all
 ``num_experts``, computes the part of the result its own experts give for
@@ -87,7 +110,12 @@ kernel), ``rms_norm``, ``ff/router``, ``ff/dispatch``,
 ``ff/experts``, ``ff/combine``, ``head``, ``ce``; where the configuration
 has them ``ff/shared`` (the shared expert), ``ff/dense`` (a dense layer's
 block), ``attn/gate`` (the ``W_g`` product, the sigmoid and the multiply),
-``attn/qk_norm``, ``attn/rotary``. The token-major kernel
+``attn/qk_norm``, ``attn/rotary``; latent attention's ``attn/q_a``,
+``attn/q_b``, ``attn/kv_a``, ``attn/kv_b``, ``attn/latent_norm``,
+``attn/rotary`` (XLA code on the 64-wide parts), ``attn/out`` and its
+kernels ``attn[mosaic]``; the prediction module's under a root ``mtp``
+(``mtp/embed``, ``mtp/norms``, ``mtp/proj``, ``mtp/block/attn...``,
+``mtp/block/ff...``, ``mtp/head``, ``mtp/ce``). The token-major kernel
 (``token_major_sum[mosaic]`` in a trace) runs under the scope of its sum,
 ``ff/combine`` or ``ff/dispatch``; the grouped products under
 ``ff/experts``. What is done to each head of queries and keys between
@@ -111,9 +139,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from dalle_tpu.config import LAYER_WINDOW_ROPE, SparseLMConfig
+from dalle_tpu.config import (LAYER_FULL_ROPE, LAYER_WINDOW_ROPE,
+                              SparseLMConfig)
 from dalle_tpu.models import attention as attn_mod
 from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
 from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
@@ -291,6 +321,127 @@ class Attention(nn.Module):
             g = dense(cfg.num_heads * cfg.head_dim, name="gate")(a)
             with jax.named_scope("gate"):
                 ctx = ctx * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dt)
+        return dense(cfg.hidden_size, name="out")(ctx)
+
+
+def rotary_interleaved_lanes(x: jax.Array, head_dim: int,
+                             theta: float) -> jax.Array:
+    """Rotary of positions 0..T-1 on x (B, T, n * head_dim), each head's
+    lanes as interleaved pairs: ``(x_2i, x_2i+1)`` turned by ``pos *
+    theta^(-2i / head_dim)``, in f32. The pair's other member is a shift by
+    one lane, up for the even lanes and down for the odd ones, so no array
+    with a minor dimension of 2 exists."""
+    t, width = x.shape[1], x.shape[2]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (width,), 0)
+    freqs = theta ** (-(lane % head_dim // 2 * 2).astype(jnp.float32)
+                      / head_dim)
+    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * freqs
+    up = jnp.pad(x[..., 1:], ((0, 0), (0, 0), (0, 1)))        # x[lane + 1]
+    down = jnp.pad(x[..., :-1], ((0, 0), (0, 0), (1, 0)))     # x[lane - 1]
+    rot = jnp.where(lane % 2 == 0, -up, down).astype(jnp.float32)
+    return (x.astype(jnp.float32) * jnp.cos(angles)
+            + rot * jnp.sin(angles)).astype(x.dtype)
+
+
+def dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v) -> jax.Array:
+    """The XLA lowering of latent attention (no Mosaic backend, or widths
+    the kernels refuse): dense masked scores from the two products.
+    q_nope, k_nope: (B, T, H*n); q_rope: (B, T, H*r); k_rope: (B, T, r);
+    v: (B, T, H*d). Returns (B, T, H*d)."""
+    b, t, _ = q_nope.shape
+    rope = k_rope.shape[2]
+    heads = q_rope.shape[2] // rope
+    split = lambda x: x.reshape(b, t, heads, -1)
+    qn, kn = split(q_nope), split(k_nope)
+    s = jnp.einsum("bqhd,bkhd->bhqk", qn, kn,
+                   preferred_element_type=jnp.float32) \
+        + jnp.einsum("bqhd,bkd->bhqk", split(q_rope), k_rope,
+                     preferred_element_type=jnp.float32)
+    s = s * (qn.shape[-1] + rope) ** -0.5
+    i = np.arange(t)
+    w = jax.nn.softmax(jnp.where(i[None, :] <= i[:, None], s,
+                                 attn_mod.NEG_INF), axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", w.astype(v.dtype), split(v),
+                     preferred_element_type=jnp.float32)
+    return out.reshape(v.shape).astype(v.dtype)
+
+
+# the one rotary key: a sample's on the shard that has the sample, whole
+ROPE_KEY_SPEC = P(LANES_SPEC[0], None, None)
+
+# (tokens, heads, nope, rope, value lanes) -> why the last traced latent
+# attention of those local shapes did not take the blockwise kernels, None
+# where it did: what attn_layout reads
+_LATENT_CHOICES: Dict[Tuple[int, int, int, int, int], Optional[str]] = {}
+
+
+def _latent_shard(q_nope, q_rope, k_nope, k_rope, v, *, nope: int,
+                  rope: int, value: int):
+    """One shard's latent attention: the blockwise kernels where they
+    fit."""
+    t, heads = q_nope.shape[1], q_rope.shape[2] // rope
+    why_not = kernels.latent_fits(t, heads, nope, rope, value,
+                                  q_nope.dtype.itemsize)
+    _LATENT_CHOICES[t, heads, nope, rope, value] = why_not
+    attn_mod.log_kernel_choice(
+        "latent attention", why_not is None,
+        why_not or f"local {heads} heads of {nope} + {rope} | {value} over "
+        f"one rotary key, {t} tokens: blocks of {kernels.BLOCK}, "
+        f"{kernels.LATENT_HEADS} heads a step, " + _backward_words(None))
+    if why_not is not None:
+        return dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v)
+    return kernels.latent_attention(q_nope, q_rope, k_nope, k_rope, v,
+                                    kernels.BLOCK, attn_mod._PALLAS_INTERPRET)
+
+
+class LatentAttention(nn.Module):
+    """Queries through a normed latent, keys and values from one, a head's
+    scores the sum of its own 128-wide product and a 64-wide one with the
+    one rotary key (module docstring). The projections' columns are
+    head-major part by part: ``q_b`` is every head's ``nope`` lanes, then
+    every head's ``rope`` lanes; ``kv_a`` the latent, then the rotary key;
+    ``kv_b`` every head's ``k_nope``, then every head's ``v``: each part is
+    a slice at a lane tile's edge and no (B, T, H, d) array exists."""
+    cfg: SparseLMConfig
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, a: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        dt, pdt = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=dt,
+                                  param_dtype=pdt)
+        heads, nope = cfg.num_heads, cfg.qk_nope_head_dim
+        rope, value = cfg.qk_rope_head_dim, cfg.v_head_dim
+
+        def latent_norm(name, x):
+            with jax.named_scope("latent_norm"):
+                return rms_norm(x, self.param(
+                    name, nn.initializers.ones, (x.shape[-1],), pdt),
+                    cfg.rms_eps)
+
+        q = dense(heads * (nope + rope), name="q_b")(latent_norm(
+            "q_a_norm", dense(cfg.q_lora_rank, name="q_a")(a)))
+        kv = dense(cfg.kv_lora_rank + rope, name="kv_a")(a)
+        k_rope = kv[..., cfg.kv_lora_rank:]
+        kv = dense(heads * (nope + value), name="kv_b")(latent_norm(
+            "kv_a_norm", kv[..., :cfg.kv_lora_rank]))
+        q_nope, q_rope = q[..., :heads * nope], q[..., heads * nope:]
+        k_nope, v = kv[..., :heads * nope], kv[..., heads * nope:]
+        with jax.named_scope("rotary"):
+            q_rope = rotary_interleaved_lanes(q_rope, rope, cfg.rope_theta)
+            k_rope = rotary_interleaved_lanes(k_rope, rope, cfg.rope_theta)
+        if attn_mod._pallas_by_default():
+            attend = functools.partial(_latent_shard, nope=nope, rope=rope,
+                                       value=value)
+            # tp splits the heads; the one rotary key is whole on each
+            ctx = per_shard(
+                attend, self.mesh,
+                (LANES_SPEC, LANES_SPEC, LANES_SPEC, ROPE_KEY_SPEC, LANES_SPEC),
+                LANES_SPEC, scope=self.name)(q_nope, q_rope, k_nope, k_rope,
+                                             v)
+        else:
+            ctx = dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v)
         return dense(cfg.hidden_size, name="out")(ctx)
 
 
@@ -729,6 +880,11 @@ class ExpertLayer(nn.Module):
                 preferred_element_type=jnp.float32)
             cfg = self.cfg
             if cfg.score_func == "softmax":
+                # the weights are top_k's own values, so a replay takes it
+                # again whatever is kept: these sets are not kept (kept
+                # beside the replay's top_k the step was 0.7% slower on the
+                # v5e, read back through a gather 4.3%:
+                # smallthinker21b-train-solo, PR 44)
                 top, idx = jax.lax.top_k(scores, cfg.experts_per_token)
                 return idx, jax.nn.softmax(top, axis=-1)
             scores = jax.nn.sigmoid(scores)
@@ -737,6 +893,7 @@ class ExpertLayer(nn.Module):
                 select = scores + jax.lax.stop_gradient(
                     self.router_bias.astype(jnp.float32))
             _, idx = jax.lax.top_k(select, cfg.experts_per_token)
+            idx = checkpoint_name(idx, "chosen")
             top = jnp.take_along_axis(scores, idx, axis=-1)
             if cfg.route_norm:
                 top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
@@ -801,7 +958,10 @@ class Layer(nn.Module):
         a = norm("attn_norm", x)
         if early:
             idx, p = ff.route(a)
-        y = Attention(cfg, self.kind, self.mesh, name="attn")(a)
+        if self.kind == LAYER_FULL_ROPE:
+            y = LatentAttention(cfg, self.mesh, name="attn")(a)
+        else:
+            y = Attention(cfg, self.kind, self.mesh, name="attn")(a)
         if cfg.sandwich_norms:
             y = norm("post_attn_norm", y)
         h = x + y
@@ -823,6 +983,19 @@ class Layer(nn.Module):
 # ---------------------------------------------------------------------------
 # The model
 # ---------------------------------------------------------------------------
+
+# What a rematerialised layer keeps besides its input: its attention's
+# output and row statistics (the backward pass replays the projections and
+# the experts, not the attention kernel) and, of a sigmoid router, the
+# experts its tokens chose, (B, T, k) int32. A compiler may fuse and round
+# the replay's scores otherwise than the forward pass's, and a top-k taken
+# again then flips near-ties, so that the backward pass differentiates
+# other experts than the forward pass ran: XLA's CPU backend does (the
+# routed leaves a few times further from the reference at the program's
+# own sets), the v5e's did not at the cell's size (PERF.md section 6, PR
+# 44). Kept, the sets a forward-and-backward program returns are the ones
+# it differentiates, whatever the compiler.
+KEPT_OF_A_LAYER = ("attn_out", "attn_stats", "chosen")
 
 def _streamed_nll(h, kernel, targets, weights, chunk: int):
     """Sums of ``weights`` x next-token negative log-likelihood over the
@@ -849,6 +1022,35 @@ def _streamed_nll(h, kernel, targets, weights, chunk: int):
     sums, _ = jax.lax.scan(body, jnp.zeros(weights.shape[1:], jnp.float32),
                            (split(h), split(targets), split(weights)))
     return sums
+
+
+class PredictionModule(nn.Module):
+    """One more expert layer that reads the main model's normed last state
+    ``z`` and the NEXT token's embedding (module docstring); returns its own
+    normed state, which the caller puts through the shared head, and the
+    block's counters."""
+    cfg: SparseLMConfig
+    layer_cls: Any
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, z: jax.Array, next_emb: jax.Array):
+        cfg = self.cfg
+        dt, pdt = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
+        scale = lambda name: self.param(name, nn.initializers.ones,
+                                        (cfg.hidden_size,), pdt)
+        with jax.named_scope("norms"):
+            both = jnp.concatenate(
+                [rms_norm(next_emb, scale("enorm"), cfg.rms_eps),
+                 rms_norm(z, scale("hnorm"), cfg.rms_eps)], axis=-1)
+        # W_eh makes the module's input of an embedding and a state: its
+        # product counts with the embedding's (``embed_share_pct``)
+        with jax.named_scope("embed"):
+            x = nn.Dense(cfg.hidden_size, use_bias=False, dtype=dt,
+                         param_dtype=pdt, name="proj")(both)
+        x, counters = self.layer_cls(cfg, LAYER_FULL_ROPE, self.mesh,
+                                     name="block")(x)
+        return rms_norm(x, scale("final_norm"), cfg.rms_eps), counters
 
 
 class SparseLM(nn.Module):
@@ -879,12 +1081,9 @@ class SparseLM(nn.Module):
                 x = x * cfg.hidden_size ** 0.5
             x = x.astype(dt)
 
-        # a layer keeps its input and its attention's output and row
-        # statistics: the backward pass replays the projections and the
-        # experts, not the attention kernel
         layer_cls = nn.remat(
             Layer, policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "attn_stats"))
+                *KEPT_OF_A_LAYER))
         counters = []
         for i in range(cfg.num_hidden_layers):
             x, c = layer_cls(cfg, cfg.kind_of_layer(i), self.mesh,
@@ -920,9 +1119,38 @@ class SparseLM(nn.Module):
         shards = sum_over_manual_data_axes(1)
         if loss_mask is not None:
             denoms = jnp.maximum(denoms, 1.0)
+        loss = jnp.sum(sums) / jnp.sum(denoms)
+        losses = {}
+        if cfg.num_nextn_predict_layers:
+            # position i reads z_i and token i + 1 and predicts token i + 2:
+            # the last two positions have nothing to predict (the rows run
+            # all the same, on a token 0: causality keeps them to
+            # themselves)
+            shift = lambda a, n: jnp.concatenate(
+                [a[:, n:], jnp.zeros((b, n), a.dtype)], axis=1)
+            with jax.named_scope("mtp"), jax.named_scope("embed"):
+                next_emb = jnp.take(table, shift(ids, 1), axis=0).astype(dt)
+            z, c = PredictionModule(cfg, layer_cls, self.mesh, name="mtp")(
+                x, next_emb)
+            counters.append(c)
+            weights = (jnp.arange(t) < t - 2).astype(jnp.float32)
+            weights = jnp.broadcast_to(weights[None, :, None], (b, t, 1))
+            if loss_mask is not None:
+                weights = weights * shift(loss_mask, 2)[..., None].astype(
+                    jnp.float32)
+            with jax.named_scope("mtp"):
+                mtp_sums = _streamed_nll(
+                    z.reshape(b * t, -1), head.astype(dt),
+                    shift(ids, 2).reshape(-1), weights.reshape(b * t, 1),
+                    min(cfg.head_chunk, b * t))
+            mtp_denom = sum_over_manual_data_axes(jnp.sum(weights))
+            if loss_mask is not None:
+                mtp_denom = jnp.maximum(mtp_denom, 1.0)
+            losses = {"loss_main": loss, "loss_mtp": mtp_sums[0] / mtp_denom}
+            loss = loss + cfg.mtp_loss_weight * losses["loss_mtp"]
         stack = lambda key: jnp.stack([c[key] for c in counters])
         aux = {
-            "loss": jnp.sum(sums) / jnp.sum(denoms),
+            "loss": loss, **losses,
             "loss_text": sums[0] / jnp.maximum(denoms[0], 1.0),
             "loss_img": sums[1] / jnp.maximum(denoms[1], 1.0),
             # the expert layers' counters: share of the tokens x k
@@ -960,6 +1188,27 @@ def init_params(model: SparseLM, rng: jax.Array, batch: int = 2):
         batch = -(-batch // shards) * shards
     tokens = jnp.zeros((batch, 8), jnp.int32)
     return jax.jit(model.init)(rng, tokens, tokens)
+
+
+def _latent_layout(cfg: SparseLMConfig, tp: int) -> str:
+    """``attn_layout`` of a configuration whose every layer is latent
+    attention, the prediction module's block among them: the widths, and
+    which lowering the traced calls took."""
+    layers = cfg.num_hidden_layers + cfg.num_nextn_predict_layers
+    why_not = "no Mosaic backend"
+    if attn_mod._pallas_by_default():
+        why_not = _LATENT_CHOICES.get(
+            (cfg.total_seq_len, cfg.num_heads // tp, cfg.qk_nope_head_dim,
+             cfg.qk_rope_head_dim, cfg.v_head_dim), "none traced")
+    took = (f"dense XLA lowering ({why_not})" if why_not else
+            f"blockwise {kernels.BLOCK}: {layers} of {layers} layers, "
+            f"{kernels.LATENT_HEADS} heads a step, backward: "
+            + _backward_words(None))
+    return (f"latent {cfg.q_lora_rank} / {cfg.kv_lora_rank} + one rotary "
+            f"key of {cfg.qk_rope_head_dim}, heads {cfg.num_heads} x "
+            f"({cfg.qk_nope_head_dim} + {cfg.qk_rope_head_dim} | "
+            f"{cfg.v_head_dim}), {took}, rotary (XLA: interleaved pairs on "
+            f"the {cfg.qk_rope_head_dim}-wide parts)")
 
 
 def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
@@ -1029,14 +1278,25 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     if cfg.num_dense_layers:
         beside += (f", layers 0-{cfg.num_dense_layers - 1} dense "
                    f"{cfg.dense_width}")
+    attn_layout = (
+        f"blockwise {kernels.BLOCK}: {on} of {len(kinds)} layers, "
+        f"{len(kinds) - windows} full no-rope + {windows} window "
+        f"{cfg.window} rope, {cfg.num_heads // cfg.num_kv_heads} query "
+        f"heads a key-value head{backward}"
+        + words
+        + ", gated output" * cfg.attention_gate)
+    said = {}
+    if LAYER_FULL_ROPE in kinds:
+        attn_layout = _latent_layout(cfg, tp)
+    if cfg.num_nextn_predict_layers:
+        said["mtp_layout"] = (
+            "one prediction module after the final norm: [norm(next "
+            "token's embedding) ; norm(last state)] . W_eh, one expert "
+            "layer, a final norm of its own; shares the embedding and the "
+            f"head; loss_mtp over T - 2 positions, weight "
+            f"{cfg.mtp_loss_weight:g}")
     return {
-        "attn_layout": (
-            f"blockwise {kernels.BLOCK}: {on} of {len(kinds)} layers, "
-            f"{len(kinds) - windows} full no-rope + {windows} window "
-            f"{cfg.window} rope, {cfg.num_heads // cfg.num_kv_heads} query "
-            f"heads a key-value head{backward}"
-            + words
-            + ", gated output" * cfg.attention_gate),
+        "attn_layout": attn_layout, **said,
         "layer_loop": (f"unrolled: {len(kinds)} layers, each "
                        "rematerialised but its attention"),
         "moe_layout": (
@@ -1048,8 +1308,15 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     }
 
 
-STEP_ATTRIBUTES = ("moe_assignments_here_pct", "moe_load_max_over_mean",
-                   "moe_dropped", "moe_dense_calls", "moe_sum_spills")
+def step_attributes(cfg: SparseLMConfig) -> Tuple[str, ...]:
+    """Entries of the step's aux that go onto every ``loop/step`` row: the
+    expert layers' counters and, of a configuration with a prediction
+    module, the two losses its loss is made of."""
+    losses = ("loss_main", "loss_mtp") if cfg.num_nextn_predict_layers else ()
+    return ("moe_assignments_here_pct", "moe_load_max_over_mean",
+            "moe_dropped", "moe_dense_calls", "moe_sum_spills") + losses
+
+
 # those of them that count a slower lowering, so that a step above its
 # median in one is late because of the model (obs/late.py's cause "model");
 # a spill costs its tile microseconds and is reported without being blamed

@@ -42,6 +42,26 @@ whole group, and ``dk``/``dv`` are summed over the group in VMEM. The row
 statistics are (B, G, T, 128) f32 with query head ``h`` of the group in
 lane ``h``.
 
+**Latent attention** (:func:`latent_attention`): a head's scores are the
+sum of two products, ``q_nope k_nope^T`` over 128 lanes and ``q_rope
+k_rope^T`` over 64, where ``k_rope`` is ONE (B, T, 64) head that every query
+head reads, and its values are 128 wide: one key-value head a query head.
+``_latent_fwd_kernel`` and ``_latent_bwd_kernel`` walk the same band with
+the same tile arithmetic and statistics layout, ``LATENT_HEADS`` = 2 heads a
+grid step, so that the two heads' 64-wide rotary parts are one 128-lane
+tile of ``q_rope`` (B, T, H*64). The shared key reaches the kernels as one
+(B, T, 256) placement ``[k_rope, 0 | 0, k_rope]``: the step's (block, 128)
+tile of ``q_rope`` times the first half's transpose is head 0's rotary
+scores, times the second's head 1's, with no slice inside a lane tile; it is
+written once a call, never once a head. ``dk_rope`` is summed over the key
+blocks AND the heads in one (T, 128) f32 accumulator that stays in VMEM
+over the whole of a sample's grid (the head axis is ``arbitrary`` there),
+head 0's part in the lower 64 lanes and head 1's in the upper, which the
+caller adds. The backward is the one kernel a tile: the same 5 products,
+the three on the score side as a 128-wide and a 64-wide part each (8 MXU
+calls; the rotary parts run 128 lanes deep for the 64 they need). Where
+its accumulators do not fit VMEM :func:`latent_fits` refuses the shapes.
+
 Scores, softmax and statistics are f32; the MXU operands are in the
 operands' dtype (bf16 in training).
 """
@@ -143,6 +163,32 @@ def _tile_backward(q, do, k, v, allowed, lse, delta, scale):
     return prob, prob * (dp - delta) * scale
 
 
+def _softmax_step(h: int, s, v, m_s, l_s, acc_s, block: int):
+    """Head ``h``'s masked (block, block) scores of one key block folded
+    into its running maximum, sum and accumulator."""
+    m_prev, l_prev = m_s[h], l_s[h]                  # (block, 128)
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
+    e = jnp.exp(s - jnp.tile(m_next, (1, block // LANES)))
+    alpha = jnp.exp(m_prev - m_next)
+    l_s[h] = alpha * l_prev + jnp.sum(e, axis=1)[:, None]
+    m_s[h] = m_next
+    acc_s[:, _head(h)] = alpha * acc_s[:, _head(h)] + jnp.dot(
+        e.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+
+def _write_forward(o_ref, stats_ref, m_s, l_s, acc_s, group: int,
+                   block: int):
+    """A query block's outputs and row statistics, head ``h`` in lane
+    ``h``, after its last key block."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block, LANES), 1)
+    stats = jnp.zeros((block, LANES), jnp.float32)
+    for h in range(group):
+        l = l_s[h]
+        o_ref[0, :, _head(h)] = (acc_s[:, _head(h)] / l).astype(o_ref.dtype)
+        stats = jnp.where(lane == h, m_s[h] + jnp.log(l), stats)
+    stats_ref[0, 0] = stats
+
+
 def _causal_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                        v_ref, o_ref, stats_ref, m_s, l_s, acc_s, *,
                        scale: float, group: int, block: int,
@@ -160,26 +206,12 @@ def _causal_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
     for h in range(group):
         s = jax.lax.dot_general(q_ref[0, :, _head(h)], k, _NT,
                                 preferred_element_type=jnp.float32) * scale
-        s = jnp.where(allowed, s, MASK_VALUE)
-        m_prev, l_prev = m_s[h], l_s[h]                  # (block, 128)
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
-        e = jnp.exp(s - jnp.tile(m_next, (1, block // LANES)))
-        alpha = jnp.exp(m_prev - m_next)
-        l_s[h] = alpha * l_prev + jnp.sum(e, axis=1)[:, None]
-        m_s[h] = m_next
-        acc_s[:, _head(h)] = alpha * acc_s[:, _head(h)] + jnp.dot(
-            e.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        _softmax_step(h, jnp.where(allowed, s, MASK_VALUE), v, m_s, l_s,
+                      acc_s, block)
 
     @pl.when(last_ref[p] == 1)
     def _():
-        lane = jax.lax.broadcasted_iota(jnp.int32, (block, LANES), 1)
-        stats = jnp.zeros((block, LANES), jnp.float32)
-        for h in range(group):
-            l = l_s[h]
-            o_ref[0, :, _head(h)] = (acc_s[:, _head(h)] / l).astype(
-                o_ref.dtype)
-            stats = jnp.where(lane == h, m_s[h] + jnp.log(l), stats)
-        stats_ref[0, 0] = stats
+        _write_forward(o_ref, stats_ref, m_s, l_s, acc_s, group, block)
 
 
 def _causal_dq_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
@@ -286,12 +318,20 @@ def _causal_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
 
 def _call(kernel, operands, outs, scratch, *, t: int, group: int,
           block: int, window: Optional[int], key_major: bool,
-          interpret: bool):
+          interpret: bool, scale: float = LANES ** -0.5,
+          steps: Optional[int] = None, heads_parallel: bool = True):
     """``operands`` / ``outs``: (array or shape-dtype, kind) with kind "q"
     (a group's query lanes, by query block), "kv" (one key-value head, by
     key block), "kv_all" (one key-value head's whole sequence) or "stats"
-    (by query block)."""
-    b, g = operands[1][0].shape[0], operands[1][0].shape[2] // LANES
+    (by query block); for latent attention, whose ``group`` heads have a
+    key-value head each, "kv_group" / "kv_group_all" (the group's key or
+    value lanes), "q_rope" (the group's rotary query lanes, one tile),
+    "k_rope" (the shared rotary key's placement, by key block) and
+    "k_rope_all" (its cotangent's whole sequence, the same block for every
+    head). ``steps``: the grid's head axis, where it is not the key-value
+    heads of the second operand."""
+    b = operands[1][0].shape[0]
+    g = steps or operands[1][0].shape[2] // LANES
     table = band_pairs(t // block, block, window, key_major)
     specs = {
         "q": pl.BlockSpec((1, block, group * LANES),
@@ -303,9 +343,21 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
         "stats": pl.BlockSpec((1, 1, block, LANES),
                               lambda i, j, p, qi, ki, fi, la:
                               (i, j, qi[p], 0)),
+        "kv_group": pl.BlockSpec((1, block, group * LANES),
+                                 lambda i, j, p, qi, ki, fi, la:
+                                 (i, ki[p], j)),
+        "kv_group_all": pl.BlockSpec((1, t, group * LANES),
+                                     lambda i, j, p, qi, ki, fi, la:
+                                     (i, 0, j)),
+        "q_rope": pl.BlockSpec((1, block, LANES),
+                               lambda i, j, p, qi, ki, fi, la: (i, qi[p], j)),
+        "k_rope": pl.BlockSpec((1, block, group * LANES),
+                               lambda i, j, p, qi, ki, fi, la: (i, ki[p], 0)),
+        "k_rope_all": pl.BlockSpec((1, t, LANES),
+                                   lambda i, j, p, qi, ki, fi, la: (i, 0, 0)),
     }
     return pl.pallas_call(
-        functools.partial(kernel, scale=LANES ** -0.5, group=group,
+        functools.partial(kernel, scale=scale, group=group,
                           block=block, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(b, g, len(table)),
@@ -314,7 +366,9 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
             scratch_shapes=list(scratch)),
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x, _ in outs],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=(
+                "parallel", "parallel" if heads_parallel else "arbitrary",
+                "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*(jnp.asarray(table[:, c]) for c in range(4)),
@@ -370,16 +424,21 @@ def _vjp_fwd(q, k, v, window, block, interpret):
     return out, (q, k, v, out, stats)
 
 
+def _delta(dout, out, group: int, block: int):
+    """rowsum(do * o), a head: (B, T, G, group) -> lanes of the statistics'
+    layout, the rows padded to whole blocks."""
+    b, t, _ = out.shape
+    delta = jnp.sum((dout.astype(jnp.float32) * out.astype(jnp.float32))
+                    .reshape(b, t, -1, group, LANES), axis=-1)
+    return jnp.pad(delta.swapaxes(1, 2),
+                   ((0, 0), (0, 0), (0, -t % block), (0, LANES - group)))
+
+
 def _vjp_bwd(window, block, interpret, res, dout):
     q, k, v, out, stats = res
     b, t, width = q.shape
     group = width // k.shape[2]
-    # delta = rowsum(do * o), a head: (B, T, G, group) -> lanes of the
-    # statistics' layout
-    delta = jnp.sum((dout.astype(jnp.float32) * out.astype(jnp.float32))
-                    .reshape(b, t, -1, group, LANES), axis=-1)
-    delta = jnp.pad(delta.swapaxes(1, 2),
-                    ((0, 0), (0, 0), (0, -t % block), (0, LANES - group)))
+    delta = _delta(dout, out, group, block)
     q, k, v, dout = (_padded(x, block) for x in (q, k, v, dout))
     operands = [(q, "q"), (k, "kv"), (v, "kv"), (dout, "q"),
                 (stats, "stats"), (delta, "stats")]
@@ -401,3 +460,228 @@ def _vjp_bwd(window, block, interpret, res, dout):
 
 
 causal_attention.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention: scores from two products, one shared rotary key
+# ---------------------------------------------------------------------------
+
+LATENT_HEADS = 2                  # heads a grid step: 2 x 64 rotary lanes
+ROPE_LANES = LANES // LATENT_HEADS
+LATENT_SCALE = (LANES + ROPE_LANES) ** -0.5
+
+
+def latent_fits(tokens: int, heads: int, nope: int, rope: int, value: int,
+                itemsize: int, block: int = BLOCK) -> Optional[str]:
+    """None where the latent kernels take these local shapes (``heads``
+    heads of ``nope`` + ``rope`` query-key lanes and ``value`` value lanes,
+    ``tokens`` a sample, operands of ``itemsize`` bytes), else why not."""
+    if (nope, rope, value) != (LANES, ROPE_LANES, LANES):
+        return (f"heads of {nope} + {rope} | {value} lanes are not "
+                f"{LANES} + {ROPE_LANES} | {LANES}")
+    if heads % LATENT_HEADS:
+        return f"{heads} heads are not pairs (two rotary parts a lane tile)"
+    t = tokens + -tokens % block
+    wide, tile = LATENT_HEADS * LANES, block * LANES
+    need = (2 * t * wide * 4                    # dk_nope, dv accumulators
+            + 2 * 2 * t * wide * itemsize       # their outputs, two buffers
+            + t * LANES * 4 * 3                 # dk_rope: one, and its output
+            + (LATENT_HEADS + 1) * tile * 4     # dq's accumulators
+            + 3 * 2 * (LATENT_HEADS + 1) * tile * itemsize   # q, do, dq
+            + 3 * 2 * LATENT_HEADS * tile * itemsize         # k, v, k_rope
+            + 2 * 2 * tile * 4                  # statistics, delta
+            + 4 * block * block * 4)            # scores, p, dp, ds
+    if need > VMEM_LIMIT_BYTES:
+        return (f"dk and dv of {t} tokens, two heads a step, need "
+                f"{need / 2 ** 20:.1f} MiB of VMEM, over "
+                f"{VMEM_LIMIT_BYTES / 2 ** 20:g}")
+    return None
+
+
+def _latent_scores(q_nope, q_rope, k_nope, k_rope, scale):
+    """(block, block) f32: one head's scores. ``q_rope``: the step's two
+    heads' rotary parts; ``k_rope``: the shared key placed in this head's
+    half of the lanes, zeros in the other's."""
+    return (jax.lax.dot_general(q_nope, k_nope, _NT,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(q_rope, k_rope, _NT,
+                                  preferred_element_type=jnp.float32)) * scale
+
+
+def _latent_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, qn_ref, qr_ref,
+                       kn_ref, kr_ref, v_ref, o_ref, stats_ref, m_s, l_s,
+                       acc_s, *, scale: float, group: int, block: int,
+                       window: Optional[int]):
+    p = pl.program_id(2)
+
+    @pl.when(first_ref[p] == 1)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, -jnp.inf, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    allowed = _allowed(qi_ref[p], ki_ref[p], block, window)
+    q_rope = qr_ref[0]
+    for h in range(group):
+        s = _latent_scores(qn_ref[0, :, _head(h)], q_rope,
+                           kn_ref[0, :, _head(h)], kr_ref[0, :, _head(h)],
+                           scale)
+        _softmax_step(h, jnp.where(allowed, s, MASK_VALUE),
+                      v_ref[0, :, _head(h)], m_s, l_s, acc_s, block)
+
+    @pl.when(last_ref[p] == 1)
+    def _():
+        _write_forward(o_ref, stats_ref, m_s, l_s, acc_s, group, block)
+
+
+def _latent_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, qn_ref, qr_ref,
+                       kn_ref, kr_ref, v_ref, do_ref, stats_ref, delta_ref,
+                       dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref, dqn_s,
+                       dqr_s, dkn_s, dkr_s, dv_s, *, scale: float, group: int,
+                       block: int, window: Optional[int]):
+    """Every cotangent from one pass over the band, query-block-major:
+    ``dqn_s`` / ``dqr_s`` hold a query block over its key blocks, ``dkn_s``
+    / ``dv_s`` (T, group * 128) the step's heads' whole sequence over all
+    pairs, ``dkr_s`` (T, 128) the shared key's over all pairs and all
+    heads of a sample."""
+    j, p = pl.program_id(1), pl.program_id(2)
+    start, end = p == 0, p == pl.num_programs(2) - 1
+
+    @pl.when(start)
+    def _():
+        dkn_s[...] = jnp.zeros(dkn_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    @pl.when(start & (j == 0))
+    def _():
+        dkr_s[...] = jnp.zeros(dkr_s.shape, jnp.float32)
+
+    @pl.when(first_ref[p] == 1)
+    def _():
+        dqn_s[...] = jnp.zeros(dqn_s.shape, jnp.float32)
+        dqr_s[...] = jnp.zeros(dqr_s.shape, jnp.float32)
+
+    allowed = _allowed(qi_ref[p], ki_ref[p], block, window)
+    stats, delta = stats_ref[0, 0], delta_ref[0, 0]
+    keys = pl.ds(pl.multiple_of(ki_ref[p] * block, block), block)
+    q_rope = qr_ref[0]
+    half = jax.lax.broadcasted_iota(jnp.int32, (block, LANES), 1) \
+        // ROPE_LANES
+    dk_rope = []
+    for h in range(group):
+        q_nope, do = qn_ref[0, :, _head(h)], do_ref[0, :, _head(h)]
+        k_nope, k_rope = kn_ref[0, :, _head(h)], kr_ref[0, :, _head(h)]
+        s = _latent_scores(q_nope, q_rope, k_nope, k_rope, scale)
+        prob = jnp.exp(jnp.where(allowed, s, MASK_VALUE) - stats[:, h:h + 1])
+        dp = jax.lax.dot_general(do, v_ref[0, :, _head(h)], _NT,
+                                 preferred_element_type=jnp.float32)
+        # cast to the operands' dtype once, and transpose the narrow copy
+        ds = (prob * (dp - delta[:, h:h + 1]) * scale).astype(k_nope.dtype)
+        dqn_s[:, _head(h)] += jnp.dot(ds, k_nope,
+                                      preferred_element_type=jnp.float32)
+        # the other head's half of k_rope is zeros: its lanes stay
+        dqr_s[...] += jnp.dot(ds, k_rope, preferred_element_type=jnp.float32)
+        dv_s[keys, _head(h)] += jnp.dot(prob.astype(do.dtype).T, do,
+                                        preferred_element_type=jnp.float32)
+        dkn_s[keys, _head(h)] += jnp.dot(ds.T, q_nope,
+                                         preferred_element_type=jnp.float32)
+        dk_rope.append(jnp.dot(ds.T, q_rope,
+                               preferred_element_type=jnp.float32))
+    # ds^T q_rope is a head's in its own half of the lanes only
+    dkr_s[keys, :] += jnp.where(half == 0, *dk_rope)
+
+    @pl.when(last_ref[p] == 1)
+    def _():
+        dqn_ref[0] = dqn_s[...].astype(dqn_ref.dtype)
+        dqr_ref[0] = dqr_s[...].astype(dqr_ref.dtype)
+
+    @pl.when(end)
+    def _():
+        dkn_ref[0] = dkn_s[...].astype(dkn_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+    @pl.when(end & (j == pl.num_programs(1) - 1))
+    def _():
+        dkr_ref[0] = dkr_s[...]
+
+
+def _placed(k_rope):
+    """(B, T, 256): ``[k_rope, 0 | 0, k_rope]``, the shared key in head 0's
+    half of one lane tile and in head 1's half of the next."""
+    zeros = jnp.zeros_like(k_rope)
+    return jnp.concatenate([k_rope, zeros, zeros, k_rope], axis=2)
+
+
+def _latent_fwd(q_nope, q_rope, k_nope, k_rope, v, block, interpret):
+    group = LATENT_HEADS
+    rows = pltpu.VMEM((group, block, LANES), jnp.float32)
+    return _call(
+        _latent_fwd_kernel,
+        [(q_nope, "q"), (q_rope, "q_rope"), (k_nope, "kv_group"),
+         (_placed(k_rope), "k_rope"), (v, "kv_group")],
+        [(v, "q"), (_stats_like(q_nope, group), "stats")],
+        [rows, rows, pltpu.VMEM((block, group * LANES), jnp.float32)],
+        t=q_nope.shape[1], group=group, block=block, window=None,
+        key_major=False, interpret=interpret, scale=LATENT_SCALE,
+        steps=q_nope.shape[2] // (group * LANES))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def latent_attention(q_nope, q_rope, k_nope, k_rope, v, block: int = BLOCK,
+                     interpret: bool = False):
+    """softmax((q_nope k_nope^T + q_rope k_rope^T) / sqrt(192), key <=
+    query) v, head ``h`` reading its own ``k_nope`` and ``v`` and the one
+    ``k_rope``.
+
+    q_nope, k_nope, v: (B, T, H*128); q_rope: (B, T, H*64), head-major;
+    k_rope: (B, T, 64). Returns (B, T, H*128). ``T`` is padded as
+    :func:`causal_attention` pads it.
+    """
+    t = q_nope.shape[1]
+    out, _ = _latent_fwd(
+        *(_padded(x, block) for x in (q_nope, q_rope, k_nope, k_rope, v)),
+        block, interpret)
+    return out[:, :t]
+
+
+def _latent_vjp_fwd(q_nope, q_rope, k_nope, k_rope, v, block, interpret):
+    t = q_nope.shape[1]
+    out, stats = _latent_fwd(
+        *(_padded(x, block) for x in (q_nope, q_rope, k_nope, k_rope, v)),
+        block, interpret)
+    out = checkpoint_name(out[:, :t], "attn_out")
+    stats = checkpoint_name(stats, "attn_stats")
+    return out, (q_nope, q_rope, k_nope, k_rope, v, out, stats)
+
+
+def _latent_vjp_bwd(block, interpret, res, dout):
+    q_nope, q_rope, k_nope, k_rope, v, out, stats = res
+    b, t, _ = q_nope.shape
+    group = LATENT_HEADS
+    delta = _delta(dout, out, group, block)
+    q_nope, q_rope, k_nope, k_rope, v, dout = (
+        _padded(x, block) for x in (q_nope, q_rope, k_nope, k_rope, v, dout))
+    padded = q_nope.shape[1]
+    dk_rope_like = jax.ShapeDtypeStruct((b, padded, LANES), jnp.float32)
+    wide = pltpu.VMEM((padded, group * LANES), jnp.float32)
+    dq_nope, dq_rope, dk_nope, dk_rope, dv = _call(
+        _latent_bwd_kernel,
+        [(q_nope, "q"), (q_rope, "q_rope"), (k_nope, "kv_group"),
+         (_placed(k_rope), "k_rope"), (v, "kv_group"), (dout, "q"),
+         (stats, "stats"), (delta, "stats")],
+        [(q_nope, "q"), (q_rope, "q_rope"), (k_nope, "kv_group_all"),
+         (dk_rope_like, "k_rope_all"), (v, "kv_group_all")],
+        [pltpu.VMEM((block, group * LANES), jnp.float32),
+         pltpu.VMEM((block, LANES), jnp.float32), wide,
+         pltpu.VMEM((padded, LANES), jnp.float32), wide],
+        t=padded, group=group, block=block, window=None, key_major=False,
+        interpret=interpret, scale=LATENT_SCALE,
+        steps=q_nope.shape[2] // (group * LANES), heads_parallel=False)
+    # the shared key's cotangent: the two heads' halves of every step's sum
+    dk_rope = (dk_rope[..., :ROPE_LANES]
+               + dk_rope[..., ROPE_LANES:]).astype(k_rope.dtype)
+    return (dq_nope[:, :t], dq_rope[:, :t], dk_nope[:, :t], dk_rope[:, :t],
+            dv[:, :t])
+
+
+latent_attention.defvjp(_latent_vjp_fwd, _latent_vjp_bwd)

@@ -145,7 +145,7 @@ def train_loop(task: TrainingTask,
     # not cover is the loop's own time. The late-step recorder opens and
     # closes the step's span, and says why a step that ran over did
     span = functools.partial(task.tracer.span, "train")
-    step_attributes = task.family.STEP_ATTRIBUTES
+    step_attributes = task.family.step_attributes(task.model_cfg)
     late, memory = task.late_steps, task.memory
     try:
         if warmup_steps:

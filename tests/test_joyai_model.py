@@ -1,0 +1,370 @@
+"""``JoyAILMConfig`` (preset ``joyaiflash``) through models/sparse_lm.py at
+a tiny size, seeded random weights, f32: loss and every gradient leaf
+against the plain reference of its yardstick under both lowerings; the
+latent kernels against the dense lowering of the same equations; the
+rotary's interleaved pairs against a complex multiply; the preset trains
+through the peer's normal path and the entry points that decode refuse it.
+(What it shares with ``trinitymini`` is parametrised in
+tests/test_trinity_model.py: a mechanism left out is told, the shares add
+up to the uncut layer.)"""
+import dataclasses
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.manifest import Manifest
+from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
+from dalle_tpu.config import (AfmoeLMConfig, JoyAILMConfig, SparseLMConfig,
+                              joyaiflash_model_config)
+from dalle_tpu.models import attention, family, sparse_lm
+from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
+
+Y = Manifest().yardstick("joyai")
+
+# a dense layer, two expert layers and the prediction module; a sequence
+# (40: no other test file's) of two fields, half of the router's experts
+TINY = dict(hidden_size=64, num_hidden_layers=3, num_heads=4, num_kv_heads=4,
+            expert_width=32, num_experts=8, experts_per_token=2,
+            experts_held=4, expert_offset=2, vocab_size=96, text_seq_len=24,
+            image_grid=4, vocab_text=48, vocab_image=48, dtype="float32",
+            head_chunk=16, dense_width=96, q_lora_rank=48, kv_lora_rank=32,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
+# the widths the kernels take (interpreted): 128 + 64 | 128
+KERNEL_WIDTHS = dict(qk_nope_head_dim=128, qk_rope_head_dim=64,
+                     v_head_dim=128, hidden_size=128, expert_width=128,
+                     dense_width=128)
+
+
+def as_file(cfg):
+    return json.loads(json.dumps(dataclasses.asdict(cfg)))
+
+
+def _batch(cfg, seed=0, n=2):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.integers(2, cfg.vocab_text,
+                                     (n, cfg.text_seq_len)), jnp.int32),
+            jnp.asarray(rng.integers(0, cfg.vocab_image,
+                                     (n, cfg.image_seq_len)), jnp.int32))
+
+
+def rel_l2(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b),
+                                                      1e-30))
+
+
+def _params(cfg, seed=1):
+    """Seeded weights with every vector leaf (norm scales, the router's
+    bias) moved off its initial ones and zeros, so that each counts."""
+    params = sparse_lm.init_params(sparse_lm.build(cfg),
+                                   jax.random.PRNGKey(seed))
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 100), len(leaves))
+    return jax.tree.unflatten(tree, [
+        a + 0.1 * jax.random.normal(k, a.shape) if a.ndim == 1 else a
+        for a, k in zip(leaves, keys)])
+
+
+@pytest.mark.parametrize("with_kernels", [False, True])
+def test_loss_and_every_gradient_leaf_against_the_yardstick(with_kernels,
+                                                            monkeypatch):
+    """The whole tiny model, the prediction module's loss in it; with
+    ``with_kernels`` the latent attention, the grouped products and the
+    token-major sums run their Pallas kernels, interpreted. Limits: f32 on
+    both sides, the reference at the highest matmul precision; the program
+    and the reference order their sums differently (blockwise softmax,
+    streamed head, sorted experts), which the parent's test of the other
+    configuration reads at the same 2e-6 / 2e-5."""
+    cfg = JoyAILMConfig(**dict(TINY, **(KERNEL_WIDTHS if with_kernels
+                                        else {})))
+    cfg.validate()
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", with_kernels)
+    params = _params(cfg)
+    text, image = _batch(cfg)
+    model = sparse_lm.build(cfg)
+    (loss, aux), grads = jax.jit(jax.value_and_grad(
+        lambda p: model.apply(p, text, image), has_aux=True))(params)
+    ref_loss, ref_grads = Y.loss_and_grads(params, text, image, as_file(cfg))
+    assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree.leaves(ref_grads)):
+        assert rel_l2(g, r) < 2e-5, jax.tree_util.keystr(path)
+    # the loss is the sum of its two parts, which ride beside it
+    (_, (main, mtp)) = jax.jit(lambda p: Y.loss_fn(
+        p, text, image, as_file(cfg)))(params)
+    assert float(aux["loss_main"]) == pytest.approx(float(main), rel=2e-6)
+    assert float(aux["loss_mtp"]) == pytest.approx(float(mtp), rel=2e-6)
+    assert float(loss) == pytest.approx(
+        float(main) + cfg.mtp_loss_weight * float(mtp), rel=1e-6)
+    tree = params["params"]
+    assert set(tree) == {"token_emb", "lm_head", "final_norm", "mtp",
+                         "layer_0", "layer_1", "layer_2"}
+    assert set(tree["layer_1"]) == {"attn", "attn_norm", "ff", "ff_norm"}
+    assert set(tree["layer_1"]["attn"]) == {"q_a", "q_a_norm", "q_b", "kv_a",
+                                            "kv_a_norm", "kv_b", "out"}
+    assert set(tree["mtp"]) == {"enorm", "hnorm", "proj", "block",
+                                "final_norm"}
+    assert set(tree["mtp"]["block"]["ff"]) == {"router", "router_bias",
+                                               "experts", "shared"}
+    d = cfg.hidden_size
+    assert tree["mtp"]["proj"]["kernel"].shape == (2 * d, d)
+    assert tree["layer_0"]["attn"]["kv_a"]["kernel"].shape == (
+        d, cfg.kv_lora_rank + cfg.qk_rope_head_dim)    # ONE rotary key
+    # counters of the three expert layers, the module's block among them
+    assert float(aux["moe_dropped"]) == 0.0
+    assert float(aux["moe_dense_calls"]) == (0.0 if with_kernels else 3.0)
+    said = sparse_lm.engagement_records(cfg)
+    widths = "128 + 64 | 128" if with_kernels else "16 + 8 | 16"
+    assert said["attn_layout"].startswith(
+        f"latent 48 / 32 + one rotary key of {cfg.qk_rope_head_dim}, heads "
+        f"4 x ({widths}), ")
+    assert ("blockwise 512: 4 of 4 layers, 2 heads a step, backward: one "
+            "kernel a tile" if with_kernels else
+            "dense XLA lowering (no Mosaic backend)") in said["attn_layout"]
+    assert "weight 0.3" in said["mtp_layout"]
+
+
+# bfloat16 activations, enough tokens (768 a sequence) and experts (64, 8
+# held) that a few dozen of a layer's top-8 sets are near-ties
+NEAR_TIES = dict(TINY, hidden_size=128, num_hidden_layers=4, expert_width=64,
+                 num_experts=64, experts_per_token=8, experts_held=8,
+                 expert_offset=0, vocab_size=512, text_seq_len=512,
+                 image_grid=16, vocab_text=256, vocab_image=256,
+                 dtype="bfloat16", head_chunk=256, dense_width=256)
+
+
+@pytest.mark.parametrize("kept", [True, False])
+def test_the_backward_pass_differentiates_the_experts_the_forward_ran(
+        kept, monkeypatch):
+    """A rematerialised layer keeps the sets its tokens chose
+    (``KEPT_OF_A_LAYER``): against the reference **at the program's sets**
+    the router's and the routed experts' gradients are then as close as
+    the shared expert's, which reads the same rows and chooses nothing.
+    ``kept`` False is the program before PR 44: XLA's CPU backend rounds
+    the replay's scores otherwise, the top-k taken again flips near-ties,
+    and the routed leaves read several times the shared expert's distance
+    (0.05 to 0.065 against 0.013 here; the v5e's replay took the same
+    sets at the cell's size, PERF.md section 6)."""
+    if not kept:
+        monkeypatch.setattr(sparse_lm, "KEPT_OF_A_LAYER",
+                            ("attn_out", "attn_stats"))
+    cfg = JoyAILMConfig(**NEAR_TIES)
+    cfg.validate()
+    params = sparse_lm.init_params(sparse_lm.build(cfg),
+                                   jax.random.PRNGKey(1))
+    text, image = _batch(cfg, n=1)
+    model = sparse_lm.build(cfg)
+
+    def loss_and_sets(p):
+        (loss, _), sown = model.apply(p, text, image,
+                                      mutable=["intermediates"])
+        return loss, sown["intermediates"]
+    (_, sown), grads = jax.jit(jax.value_and_grad(
+        loss_and_sets, has_aux=True))(params)
+    layers = [sown[f"layer_{i}"] for i in range(1, cfg.num_hidden_layers)]
+    chosen = np.stack([np.asarray(layer["chosen"][0])
+                       for layer in layers + [sown["mtp"]["block"]]])
+    _, at_sets = Y.loss_and_grads_at(chosen, params, text, image,
+                                     as_file(cfg))
+    routed, shared = [], []
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree.leaves(at_sets)):
+        name = jax.tree_util.keystr(path)
+        if "['router']" in name or "['experts']" in name:
+            routed.append(rel_l2(g, r))
+        elif "['shared']" in name:
+            shared.append(rel_l2(g, r))
+    assert len(routed) == 16 and len(shared) == 12
+    assert 0.005 < max(shared) < 0.025      # bfloat16's own distance
+    if kept:
+        assert max(routed) < 1.5 * max(shared)
+    else:
+        assert max(routed) > 3 * max(shared)
+
+
+@pytest.mark.parametrize("tokens, heads, block", [
+    (256, 4, 128),      # whole blocks
+    (200, 2, 128),      # an odd length that pads
+    (3 * 128 + 7, 2, 128),
+])
+def test_the_latent_kernels_are_the_dense_lowering(tokens, heads, block):
+    """Forward values and every cotangent, the shared key's (summed over
+    the heads inside the kernel) among them, interpreted; nothing of the
+    band is left out (no window: every tile at or under the diagonal)."""
+    keys = jax.random.split(jax.random.PRNGKey(tokens), 6)
+    shape = lambda lanes: (2, tokens, lanes)
+    q_nope, k_nope, v, w = (jax.random.normal(k, shape(heads * 128))
+                            for k in keys[:4])
+    q_rope = jax.random.normal(keys[4], shape(heads * 64))
+    k_rope = jax.random.normal(keys[5], shape(64))
+    operands = (q_nope, q_rope, k_nope, k_rope, v)
+    assert kernels.latent_fits(tokens, heads, 128, 64, 128, 4) is None
+    with jax.default_matmul_precision("highest"):
+        out, grads = jax.value_and_grad(lambda *a: jnp.sum(
+            kernels.latent_attention(*a, block, True) * w),
+            argnums=range(5))(*operands)
+        want, want_grads = jax.value_and_grad(lambda *a: jnp.sum(
+            sparse_lm.dense_latent_attention(*a) * w),
+            argnums=range(5))(*operands)
+        values = kernels.latent_attention(*operands, block, True)
+    np.testing.assert_allclose(
+        values, sparse_lm.dense_latent_attention(*operands), atol=2e-5)
+    assert float(out) == pytest.approx(float(want), rel=1e-5)
+    for name, g, r in zip(("q_nope", "q_rope", "k_nope", "k_rope", "v"),
+                          grads, want_grads):
+        assert g.shape == r.shape and rel_l2(g, r) < 2e-6, name
+    assert grads[3].shape == (2, tokens, 64)
+
+
+def test_latent_fits_says_why_not():
+    fits = kernels.latent_fits
+    assert fits(8192, 32, 128, 64, 128, 2) is None          # the cell's
+    assert "not 128 + 64 | 128" in fits(8192, 32, 64, 64, 128, 2)
+    assert "not 128 + 64 | 128" in fits(40, 4, 16, 8, 16, 4)
+    assert "are not pairs" in fits(8192, 3, 128, 64, 128, 2)
+    # the one-kernel backward's accumulators grow with the sequence
+    assert "MiB of VMEM, over 64" in fits(16384, 32, 128, 64, 128, 2)
+
+
+def test_the_rotary_turns_interleaved_pairs_as_complex_numbers():
+    """Lanes (2i, 2i + 1) of every head are one complex number, turned by
+    ``pos * theta^(-2i / d)``: written out with numpy's complex multiply;
+    the program's shift-by-a-lane form and the yardstick's agree with it."""
+    theta, d, heads, t = 3.2e7, 8, 3, 11
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(0),
+                                     (2, t, heads * d)), np.float64)
+    z = x.reshape(2, t, heads, d // 2, 2)
+    z = z[..., 0] + 1j * z[..., 1]
+    turn = np.exp(1j * np.arange(t)[:, None]
+                  * theta ** (-2.0 * np.arange(d // 2) / d))
+    z = z * turn[None, :, None, :]
+    want = np.stack([z.real, z.imag], -1).reshape(x.shape)
+    got = sparse_lm.rotary_interleaved_lanes(jnp.asarray(x, jnp.float32), d,
+                                             theta)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    ref = Y.rotary_pairs(jnp.asarray(x, jnp.float32).reshape(2, t, heads, d),
+                         theta)
+    np.testing.assert_allclose(ref.reshape(x.shape), want, atol=2e-5)
+    # position 0 is left as it is, and a pair keeps its length
+    np.testing.assert_allclose(got[:, 0], x[:, 0], atol=1e-6)
+    pairs = lambda a: np.asarray(a).reshape(2, t, -1, 2)
+    np.testing.assert_allclose(np.linalg.norm(pairs(got), axis=-1),
+                               np.linalg.norm(pairs(x), axis=-1), rtol=1e-4)
+
+
+TINY_FLAGS = [
+    "--hidden-size", "64", "--num-hidden-layers", "3", "--num-heads", "4",
+    "--num-kv-heads", "4", "--expert-width", "32", "--num-experts", "8",
+    "--experts-per-token", "2", "--experts-held", "4", "--expert-offset",
+    "2", "--vocab-size", "96", "--text-seq-len", "24", "--image-grid", "4",
+    "--vocab-text", "48", "--vocab-image", "48", "--dtype", "float32",
+    "--head-chunk", "16", "--dense-width", "96", "--q-lora-rank", "48",
+    "--kv-lora-rank", "32"]
+# no flag sets a head's three widths (``no_flag``): the preset's own
+AS_FLAGGED = {**TINY, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+              "v_head_dim": 128}
+
+
+def test_the_preset_trains_through_the_peers_normal_path():
+    """``run_trainer --preset joyaiflash`` (+ tiny field flags): the parser
+    builds the preset's own class, TrainingTask the model its configuration
+    names, and train_loop runs it with the swarm optimizer; the rows of the
+    trainer's ring carry the model's records and the two losses."""
+    from dalle_tpu.obs.trace import default_tracer
+    from dalle_tpu.task import TrainingTask
+    from dalle_tpu.training.loop import train_loop
+
+    args = run_trainer.build_parser().parse_args(
+        ["--preset", "joyaiflash", *TINY_FLAGS,
+         "--per-device-batch", "1", "--grad-accum-steps", "2",
+         "--target-batch-size", str(1 << 30), "--seed", "7"])
+    configs = run_trainer.configs_from_args(args)
+    assert configs[0] == JoyAILMConfig(**AS_FLAGGED)
+    task = TrainingTask(*configs)
+    assert family(task.model_cfg) is sparse_lm
+    assert isinstance(task.model, sparse_lm.SparseLM)
+    losses = []
+    with task:
+        train_loop(task, max_steps=3, warmup_steps=1,
+                   on_step=lambda n, loss: losses.append(loss))
+    assert len(losses) == 3 and all(np.isfinite(losses))
+    rows = [r for r in default_tracer().dump() if r.get("plane") == "train"]
+    warm = [r for r in rows if r["phase"] == "setup/warmup"][-1]["a"]
+    assert warm["moe_layout"].startswith(
+        "4 of 8 experts held (2-5), top 2 of 8, sigmoid, bias, norm, x2.5, "
+        "a shared expert of 32, layers 0-0 dense 96, no exchange")
+    assert warm["attn_layout"] == (
+        "latent 48 / 32 + one rotary key of 64, heads 4 x (128 + 64 | 128), "
+        "dense XLA lowering (no Mosaic backend), rotary (XLA: interleaved "
+        "pairs on the 64-wide parts)")
+    assert warm["mtp_layout"].startswith("one prediction module after the "
+                                         "final norm")
+    steps = [r for r in rows if r["phase"] == "loop/step"][-3:]
+    for row, loss in zip((r["a"] for r in steps), losses):
+        assert row["moe_dropped"] == 0.0
+        # the dense lowering in each of the three expert layers (the
+        # module's among them) of every shard
+        assert row["moe_dense_calls"] == 3.0 * task.mesh.size
+        assert row["loss_main"] + 0.3 * row["loss_mtp"] == pytest.approx(
+            loss, rel=1e-5)
+    assert task.model_cfg.optimizer_stacking()["stacked_experts"] == 4
+
+
+def test_the_preset_is_a_class_of_its_own_and_the_parents_keep_theirs():
+    """``benchmark/configs/{smallthinker21b,trinitymini}.json`` hold
+    ``asdict`` of the two parent classes: what the new class states as
+    fields are class attributes there, and no key of theirs is new."""
+    sparse = {f.name for f in dataclasses.fields(SparseLMConfig)}
+    afmoe = {f.name for f in dataclasses.fields(AfmoeLMConfig)}
+    joyai = {f.name for f in dataclasses.fields(JoyAILMConfig)}
+    assert len(sparse) == 27 and len(afmoe) == 39
+    added = joyai - afmoe
+    assert added == {"q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                     "qk_rope_head_dim", "v_head_dim", "rope_interleave",
+                     "num_nextn_predict_layers", "mtp_loss_weight"}
+    for parent in (SparseLMConfig(), AfmoeLMConfig()):
+        assert not set(dataclasses.asdict(parent)) & added
+        assert not any(getattr(parent, name) for name in added)   # off
+    cfg = joyaiflash_model_config()
+    assert type(cfg) is JoyAILMConfig and isinstance(cfg, AfmoeLMConfig)
+    cfg.validate()
+    assert (cfg.hidden_size, cfg.q_lora_rank, cfg.kv_lora_rank) == (
+        2048, 1536, 512)
+    assert (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+            cfg.num_heads) == (128, 64, 128, 32)
+    assert (cfg.dense_width, cfg.expert_width, cfg.num_experts,
+            cfg.experts_per_token, cfg.route_scale) == (7168, 768, 256, 8,
+                                                        2.5)
+    assert (cfg.rope_theta, cfg.rms_eps) == (3.2e7, 1e-6)
+    assert {cfg.kind_of_layer(i) for i in range(5)} == {"full_rope"}
+    flags = {a.dest for a in run_trainer.build_parser()._actions}
+    assert {"q_lora_rank", "kv_lora_rank", "num_nextn_predict_layers"} \
+        <= flags
+    stated = set(JoyAILMConfig.no_flag) - set(AfmoeLMConfig.no_flag)
+    assert stated == {"qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+                      "rope_interleave", "mtp_loss_weight"}
+    assert not flags & stated
+    # the kind decides, and needs a class that states latent widths
+    with pytest.raises(ValueError, match="full_rope"):
+        SparseLMConfig(layer_kinds=("full_rope",)).validate()
+    with pytest.raises(ValueError, match="interleaved pairs"):
+        dataclasses.replace(cfg, rope_interleave=False).validate()
+    with pytest.raises(ValueError, match="one prediction module"):
+        dataclasses.replace(cfg, num_nextn_predict_layers=2).validate()
+
+
+@pytest.mark.parametrize("cli, argv", [
+    (run_inference, ["--checkpoint-dir", "x", "--tokenizer-path", "y",
+                     "--query", "a cat"]),
+    (run_server, ["--random-init"]),
+    (run_aux_peer, []),
+])
+def test_entry_points_that_decode_refuse_the_preset_at_start(cli, argv):
+    with pytest.raises(SystemExit) as refused:
+        cli.main(["--preset", "joyaiflash", *argv])
+    message = str(refused.value)
+    assert "joyaiflash" in message and "models/decode.py" in message
+    assert "latent attention" in message and "prediction module" in message
+    assert message.count(".") <= 3 and "\n" not in message   # one sentence

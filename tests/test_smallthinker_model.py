@@ -500,7 +500,7 @@ def test_the_dalle_keeps_its_records_and_its_step_rows():
     from dalle_tpu.config import tiny_model_config
     from dalle_tpu.models import dalle
     cfg = tiny_model_config()
-    assert family(cfg) is dalle and dalle.STEP_ATTRIBUTES == ()
+    assert family(cfg) is dalle and dalle.step_attributes(cfg) == ()
     records = dalle.engagement_records(cfg)
     assert set(records) == {"layer_loop", "attn_layout"}
     assert cfg.optimizer_stacking() == {"stacked_reps": 0,

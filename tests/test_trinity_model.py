@@ -14,7 +14,7 @@ import pytest
 
 from benchmark.manifest import Manifest
 from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
-from dalle_tpu.config import (AfmoeLMConfig, SparseLMConfig,
+from dalle_tpu.config import (AfmoeLMConfig, JoyAILMConfig, SparseLMConfig,
                               trinitymini_model_config)
 from dalle_tpu.models import attention, family, sparse_lm
 
@@ -28,6 +28,16 @@ TINY = dict(hidden_size=64, num_hidden_layers=5, num_heads=4, num_kv_heads=2,
             experts_held=4, expert_offset=2, vocab_size=96, window=8,
             text_seq_len=16, image_grid=4, vocab_text=48, vocab_image=48,
             dtype="float32", head_chunk=16, dense_width=96)
+
+
+# ``JoyAILMConfig`` (preset ``joyaiflash``) at the same tiny size: its
+# yardstick, and the widths of its latent attention; its own tests are
+# tests/test_joyai_model.py, the cases below the ones both share
+YJ = Manifest().yardstick("joyai")
+JOYAI_TINY = dict(
+    {k: v for k, v in TINY.items() if k not in ("head_dim", "window")},
+    num_hidden_layers=2, num_kv_heads=4, q_lora_rank=48, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
 
 
 def as_file(cfg):
@@ -143,6 +153,45 @@ SMALL = dict(TINY, num_hidden_layers=2,
              layer_kinds=("window_rope", "full_nope"))
 
 
+# ``joyaiflash``'s mechanisms have no switch on either side (the program
+# and the reference are written for them): each is left out of the
+# REFERENCE by a patch of the yardstick's module, or of what it reads
+def _no_latent_norms(monkeypatch, model):
+    plain = YJ._rms_norm
+    latents = (model["q_lora_rank"], model["kv_lora_rank"])
+    monkeypatch.setattr(YJ, "_rms_norm", lambda x, g, eps: (
+        x if g.shape[0] in latents else plain(x, g, eps)))
+    return model
+
+
+def _no_shared_rotary_key(monkeypatch, model):
+    # the one key every head reads (B, T, rope) adds nothing to a score
+    plain = YJ.rotary_pairs
+    monkeypatch.setattr(YJ, "rotary_pairs", lambda x, theta: (
+        jnp.zeros_like(x) if x.ndim == 3 else plain(x, theta)))
+    return model
+
+
+def _scale_of_the_unrotated_part_alone(monkeypatch, model):
+    plain = YJ._attention
+    nope, rope = model["qk_nope_head_dim"], model["qk_rope_head_dim"]
+    up = ((nope + rope) / nope) ** 0.5        # 1 / sqrt(nope) in all
+    monkeypatch.setattr(YJ, "_attention", lambda qn, qr, *rest: plain(
+        qn * up, qr * up, *rest))
+    return model
+
+
+JOYAI_LEFT_OUT = {
+    "the norms of the two latents": _no_latent_norms,
+    "the shared rotary key": _no_shared_rotary_key,
+    "the scale 1 / sqrt(nope + rope)": _scale_of_the_unrotated_part_alone,
+    "the prediction module's loss":
+        lambda monkeypatch, model: dict(model, mtp_loss_weight=0.0),
+    "the prediction module":
+        lambda monkeypatch, model: dict(model, num_nextn_predict_layers=0),
+}
+
+
 @pytest.fixture(scope="module")
 def with_everything():
     cfg = AfmoeLMConfig(**SMALL)
@@ -152,11 +201,15 @@ def with_everything():
     return cfg, params, text, image, float(loss)
 
 
-@pytest.mark.parametrize("mechanism", list(LEFT_OUT))
-def test_a_mechanism_left_out_is_told(mechanism, with_everything):
+@pytest.mark.parametrize("mechanism", [*LEFT_OUT, *JOYAI_LEFT_OUT])
+def test_a_mechanism_left_out_is_told(mechanism, with_everything,
+                                      monkeypatch):
     """The system with every mechanism against the reference without this
     one: they disagree. The system without it against the reference
     without it: they agree, so both read the same key."""
+    if mechanism in JOYAI_LEFT_OUT:
+        return _a_joyaiflash_mechanism_left_out_is_told(mechanism,
+                                                        monkeypatch)
     cfg, params, text, image, loss = with_everything
     without = dataclasses.replace(cfg, **LEFT_OUT[mechanism])
     without.validate()
@@ -176,6 +229,37 @@ def test_a_mechanism_left_out_is_told(mechanism, with_everything):
     assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
     for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
         assert rel_l2(g, r) < 2e-5
+
+
+def _a_joyaiflash_mechanism_left_out_is_told(mechanism, monkeypatch):
+    """The system against the reference whole agrees; against the reference
+    without the mechanism it does not (ten times the distance at which
+    they agree). The module, which has a field, is also left out of both."""
+    cfg = JoyAILMConfig(**JOYAI_TINY)
+    cfg.validate()
+    params = _params(cfg)
+    text, image = _batch(cfg)
+    (loss, aux), _ = _system(cfg, params, text, image)
+    whole, _ = jax.jit(lambda p: YJ.loss_fn(p, text, image,
+                                            as_file(cfg)))(params)
+    assert float(loss) == pytest.approx(float(whole), rel=2e-6)
+    assert float(aux["loss_main"] + cfg.mtp_loss_weight * aux["loss_mtp"]) \
+        == pytest.approx(float(loss), rel=1e-6)
+    without = JOYAI_LEFT_OUT[mechanism](monkeypatch, as_file(cfg))
+    lacking, _ = jax.jit(lambda p: YJ.loss_fn(p, text, image,
+                                              without))(params)
+    assert abs(float(lacking) - float(loss)) > 2e-5 * float(loss)
+    if mechanism == "the prediction module":
+        cfg = dataclasses.replace(cfg, num_nextn_predict_layers=0)
+        params = _params(cfg)
+        assert "mtp" not in params["params"]
+        (loss, aux), grads = _system(cfg, params, text, image)
+        assert "loss_mtp" not in aux and "loss_main" not in aux
+        ref_loss, ref_grads = YJ.loss_and_grads(params, text, image,
+                                                as_file(cfg))
+        assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
+        for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
+            assert rel_l2(g, r) < 2e-5
 
 
 @pytest.mark.parametrize("interpret, head_dim, words, rotary", [
@@ -323,38 +407,50 @@ def test_a_bias_changes_the_chosen_set_and_not_the_weights():
     assert (idx2 == 5).any(-1).all()
 
 
-@pytest.mark.parametrize("kernels", [False, True])
+@pytest.mark.parametrize("config, kernels", [
+    ("trinitymini", False), ("trinitymini", True),
+    ("joyaiflash", False), ("joyaiflash", True)])
 def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
-        kernels, monkeypatch):
-    """8 experts over 4 shares of 2 (``expert_offset`` 0, 2, 4, 6): every
-    share's layer returns its routed part plus the shared expert, which
-    all compute alike; the routed parts summed plus the shared expert
-    counted once equal the reference's uncut layer."""
+        config, kernels, monkeypatch):
+    """``trinitymini``: 8 experts over 4 shares of 2 (``expert_offset`` 0,
+    2, 4, 6); ``joyaiflash``: its own 32 shares of 8 consecutive experts
+    (``8r .. 8r + 7``), 256 in all, top 8, at a small width (8 shares of
+    64 where the kernels run interpreted). Every share's
+    layer returns its routed part plus the shared expert, which all compute
+    alike; the routed parts summed plus the shared expert counted once
+    equal the reference's uncut layer."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", kernels)
-    base = AfmoeLMConfig(**dict(TINY, experts_held=2))
+    if config == "trinitymini":
+        base, y = AfmoeLMConfig(**dict(TINY, experts_held=2)), Y
+    else:       # interpreted, 8 shares of 64 experts: a share costs 3 s
+        base, y = JoyAILMConfig(**dict(
+            JOYAI_TINY, num_experts=64 if kernels else 256, experts_held=8,
+            expert_offset=0, experts_per_token=8)), YJ
+    n, held = base.num_experts, base.experts_held
+    shares = n // held
     rng = jax.random.split(jax.random.PRNGKey(3), 9)
     d, f = base.hidden_size, base.expert_width
     m = jax.random.normal(rng[0], (2, 28, d))
     kernel = lambda key, shape: {"kernel": jax.random.normal(key, shape)
                                  * 0.2}
-    whole = {"router": jax.random.normal(rng[1], (d, 8)),
-             "router_bias": 0.05 * jax.random.normal(rng[2], (8,)),
-             "experts": {"gate": jax.random.normal(rng[3], (8, d, f)) * 0.2,
-                         "up": jax.random.normal(rng[4], (8, d, f)) * 0.2,
-                         "down": jax.random.normal(rng[5], (8, f, d)) * 0.2},
+    whole = {"router": jax.random.normal(rng[1], (d, n)),
+             "router_bias": 0.05 * jax.random.normal(rng[2], (n,)),
+             "experts": {"gate": jax.random.normal(rng[3], (n, d, f)) * 0.2,
+                         "up": jax.random.normal(rng[4], (n, d, f)) * 0.2,
+                         "down": jax.random.normal(rng[5], (n, f, d)) * 0.2},
              "shared": {"gate": kernel(rng[6], (d, f)),
                         "up": kernel(rng[7], (d, f)),
                         "down": kernel(rng[8], (f, d))}}
-    want = Y.whole_layer_experts(m, whole, as_file(base))
-    shared = Y.gated_block(m, whole["shared"])
+    want = y.whole_layer_experts(m, whole, as_file(base))
+    shared = y.gated_block(m, whole["shared"])
     assert float(jnp.abs(shared).max()) > 0.01
 
     routed, here = jnp.zeros_like(m), 0.0
-    for share in range(4):
-        cfg = dataclasses.replace(base, expert_offset=2 * share)
+    for share in range(shares):
+        cfg = dataclasses.replace(base, expert_offset=held * share)
         layer = sparse_lm.ExpertLayer(cfg)
         mine = {"params": dict(whole, experts={
-            k: w[2 * share: 2 * share + 2]
+            k: w[held * share: held * (share + 1)]
             for k, w in whole["experts"].items()})}
         idx, p = layer.apply(mine, m, method="route")    # alike on all
         y, counters = layer.apply(mine, m, idx, p)
@@ -363,8 +459,8 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
     np.testing.assert_allclose(routed + shared, want, atol=5e-5)
     assert here == pytest.approx(1.0)     # every assignment, by one share
     # summing the shares' results as they come counts the shared expert
-    # four times: that is not the layer
-    assert float(jnp.abs(routed + 4 * shared - want).max()) > 0.01
+    # once a share: that is not the layer
+    assert float(jnp.abs(routed + shares * shared - want).max()) > 0.01
 
 
 TINY_FLAGS = [
