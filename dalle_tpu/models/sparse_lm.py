@@ -112,8 +112,9 @@ has them ``ff/shared`` (the shared expert), ``ff/dense`` (a dense layer's
 block), ``attn/gate`` (the ``W_g`` product, the sigmoid and the multiply),
 ``attn/qk_norm``, ``attn/rotary``; latent attention's ``attn/q_a``,
 ``attn/q_b``, ``attn/kv_a``, ``attn/kv_b``, ``attn/latent_norm``,
-``attn/rotary`` (XLA code on the 64-wide parts), ``attn/out`` and its
-kernels ``attn[mosaic]``; the prediction module's under a root ``mtp``
+``attn/rotary`` (``rotary[mosaic]``: the 64-wide parts' interleaved
+pairs in one pass on the lanes, below), ``attn/out`` and its kernels
+``attn[mosaic]``; the prediction module's under a root ``mtp``
 (``mtp/embed``, ``mtp/norms``, ``mtp/proj``, ``mtp/block/attn...``,
 ``mtp/block/ff...``, ``mtp/head``, ``mtp/ce``). The token-major kernel
 (``token_major_sum[mosaic]`` in a trace) runs under the scope of its sum,
@@ -127,7 +128,13 @@ exists where it runs, and the rotary reads one head's (T, d) tables;
 runs ``rms_norm`` on the reshape and ``attention.apply_rotary_lanes``):
 ``qk_norm[mosaic]`` under ``attn/qk_norm`` where there is a norm, the
 rotary of a window layer in it; ``rotary[mosaic]`` under ``attn/rotary``
-where there is none.
+where there is none. Latent attention's rotary of interleaved pairs is
+such a pass of its own (``head_norm_kernels.pair_rotary``, the same name
+in a trace): the queries' rotary columns read where ``q_b`` wrote them,
+the one key where ``kv_a`` wrote it in a second small call, one lane
+tile's (T, 128) tables for two heads side by side; :func:`pair_rotary_why_not` is the rule, and where
+it refuses :func:`rotary_interleaved_lanes` runs with tables as wide as
+the array.
 """
 
 from __future__ import annotations
@@ -324,23 +331,74 @@ class Attention(nn.Module):
         return dense(cfg.hidden_size, name="out")(ctx)
 
 
+def _pair_angles(tokens: int, lanes: int, head_dim: int,
+                 theta: float) -> jax.Array:
+    """(tokens, lanes) f32: ``pos * theta^(-2i / head_dim)`` on lanes 2i
+    and 2i + 1 of every head of ``head_dim`` lanes."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (lanes,), 0)
+    freqs = theta ** (-(lane % head_dim // 2 * 2).astype(jnp.float32)
+                      / head_dim)
+    return jnp.arange(tokens, dtype=jnp.float32)[:, None] * freqs
+
+
 def rotary_interleaved_lanes(x: jax.Array, head_dim: int,
                              theta: float) -> jax.Array:
     """Rotary of positions 0..T-1 on x (B, T, n * head_dim), each head's
     lanes as interleaved pairs: ``(x_2i, x_2i+1)`` turned by ``pos *
     theta^(-2i / head_dim)``, in f32. The pair's other member is a shift by
     one lane, up for the even lanes and down for the odd ones, so no array
-    with a minor dimension of 2 exists."""
-    t, width = x.shape[1], x.shape[2]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (width,), 0)
-    freqs = theta ** (-(lane % head_dim // 2 * 2).astype(jnp.float32)
-                      / head_dim)
-    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * freqs
+    with a minor dimension of 2 exists. The XLA lowering, with tables as
+    wide as the array: what :func:`_pair_rotary_shard` runs where the one
+    pass on the lanes refuses."""
+    angles = _pair_angles(x.shape[1], x.shape[2], head_dim, theta)
+    return turn_pairs_lanes(x, jnp.cos(angles), jnp.sin(angles))
+
+
+def turn_pairs_lanes(x: jax.Array, cos: jax.Array,
+                     sin: jax.Array) -> jax.Array:
+    """x (B, T, W)'s interleaved pairs turned by (T, W) f32 tables in which
+    a pair's two lanes hold the same angle; in f32, cast to ``x.dtype``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (x.shape[2],), 0)
     up = jnp.pad(x[..., 1:], ((0, 0), (0, 0), (0, 1)))        # x[lane + 1]
     down = jnp.pad(x[..., :-1], ((0, 0), (0, 0), (1, 0)))     # x[lane - 1]
     rot = jnp.where(lane % 2 == 0, -up, down).astype(jnp.float32)
-    return (x.astype(jnp.float32) * jnp.cos(angles)
-            + rot * jnp.sin(angles)).astype(x.dtype)
+    return (x.astype(jnp.float32) * cos + rot * sin).astype(x.dtype)
+
+
+def pair_rotary_why_not(tokens: int, width: int,
+                        head_dim: int) -> Optional[str]:
+    """Why the rotary of interleaved pairs on (tokens, width) samples is
+    :func:`rotary_interleaved_lanes` and not one pass on the lanes
+    (``head_norm_kernels.pair_rotary``); None where it is the pass."""
+    if not attn_mod._pallas_by_default():
+        return "no Mosaic backend"
+    return head_norm.pairs_fit(tokens, width, head_dim)
+
+
+def _pair_rotary_shard(x, *, start: int, head_dim: int, theta: float,
+                       lanes: int):
+    """One shard's rotary of interleaved pairs on ``x[..., start:]`` (B,
+    T, n * head_dim): one pass of the kernel that reads one lane tile's
+    tables, and ``x`` where a projection wrote it (where the lanes before
+    ``start`` are whole blocks of the pass), else the expression it
+    replaces on the slice. ``lanes``: the rotated lanes of the whole
+    array, of which a ``tp`` shard holds a part."""
+    t, width = x.shape[1], x.shape[2] - start
+    why_not = pair_rotary_why_not(t, width, head_dim)
+    _HEAD_PASSES[t, lanes, head_dim, False, True] = why_not
+    attn_mod.log_kernel_choice(
+        "rotary", why_not is None,
+        why_not or f"local {tuple(x.shape)} from lane {start}: pairs in "
+        f"heads of {head_dim} lanes, "
+        f"{head_norm.pair_rows_tile(t, width)} rows a tile")
+    if why_not is not None or start % head_norm.pair_block(width):
+        x, start = x[..., start:], 0
+    if why_not is not None:
+        return rotary_interleaved_lanes(x, head_dim, theta)
+    angles = _pair_angles(t, head_norm.LANES, head_dim, theta)
+    return head_norm.pair_rotary(
+        x, head_norm.pair_tables(jnp.cos(angles), jnp.sin(angles)),
+        start, attn_mod._PALLAS_INTERPRET)
 
 
 def dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v) -> jax.Array:
@@ -422,15 +480,30 @@ class LatentAttention(nn.Module):
 
         q = dense(heads * (nope + rope), name="q_b")(latent_norm(
             "q_a_norm", dense(cfg.q_lora_rank, name="q_a")(a)))
-        kv = dense(cfg.kv_lora_rank + rope, name="kv_a")(a)
-        k_rope = kv[..., cfg.kv_lora_rank:]
+        kv_a = dense(cfg.kv_lora_rank + rope, name="kv_a")(a)
         kv = dense(heads * (nope + value), name="kv_b")(latent_norm(
-            "kv_a_norm", kv[..., :cfg.kv_lora_rank]))
-        q_nope, q_rope = q[..., :heads * nope], q[..., heads * nope:]
+            "kv_a_norm", kv_a[..., :cfg.kv_lora_rank]))
+        q_nope = q[..., :heads * nope]
         k_nope, v = kv[..., :heads * nope], kv[..., heads * nope:]
-        with jax.named_scope("rotary"):
-            q_rope = rotary_interleaved_lanes(q_rope, rope, cfg.rope_theta)
-            k_rope = rotary_interleaved_lanes(k_rope, rope, cfg.rope_theta)
+
+        def rotary(x, start, spec):
+            """``x[..., start:]`` rotated, read where the projection wrote
+            it; but a mesh axis that splits the lanes (``tp``) splits the
+            heads of the rotary part, not the lanes of ``x``."""
+            if spec[2] and self.mesh is not None \
+                    and self.mesh.shape.get(spec[2], 1) > 1:
+                x, start = x[..., start:], 0
+            work = functools.partial(
+                _pair_rotary_shard, start=start, head_dim=rope,
+                theta=cfg.rope_theta, lanes=x.shape[2] - start)
+            if attn_mod._pallas_by_default():
+                work = per_shard(work, self.mesh, (spec,), spec,
+                                 scope=head_norm.ROTARY_SCOPE)
+            return work(x)
+
+        with jax.named_scope(head_norm.ROTARY_SCOPE):
+            q_rope = rotary(q, heads * nope, LANES_SPEC)
+            k_rope = rotary(kv_a, cfg.kv_lora_rank, ROPE_KEY_SPEC)
         if attn_mod._pallas_by_default():
             attend = functools.partial(_latent_shard, nope=nope, rope=rope,
                                        value=value)
@@ -1195,20 +1268,29 @@ def _latent_layout(cfg: SparseLMConfig, tp: int) -> str:
     attention, the prediction module's block among them: the widths, and
     which lowering the traced calls took."""
     layers = cfg.num_hidden_layers + cfg.num_nextn_predict_layers
-    why_not = "no Mosaic backend"
+    rope = cfg.qk_rope_head_dim
+    why_not = not_the_pass = "no Mosaic backend"
     if attn_mod._pallas_by_default():
         why_not = _LATENT_CHOICES.get(
             (cfg.total_seq_len, cfg.num_heads // tp, cfg.qk_nope_head_dim,
-             cfg.qk_rope_head_dim, cfg.v_head_dim), "none traced")
+             rope, cfg.v_head_dim), "none traced")
+        # the queries' rotary parts and the one key: the same two shapes
+        # in every layer, so all took the one pass on the lanes or the
+        # first refusal says why none did
+        not_the_pass = next(filter(None, (
+            _HEAD_PASSES.get((cfg.total_seq_len, lanes, rope, False, True),
+                             "none traced")
+            for lanes in (cfg.num_heads * rope, rope))), None)
     took = (f"dense XLA lowering ({why_not})" if why_not else
             f"blockwise {kernels.BLOCK}: {layers} of {layers} layers, "
             f"{kernels.LATENT_HEADS} heads a step, backward: "
             + _backward_words(None))
+    rotary = (f"XLA: {not_the_pass}" if not_the_pass else
+              f"one pass on the lanes: {layers} of {layers} layers")
     return (f"latent {cfg.q_lora_rank} / {cfg.kv_lora_rank} + one rotary "
-            f"key of {cfg.qk_rope_head_dim}, heads {cfg.num_heads} x "
-            f"({cfg.qk_nope_head_dim} + {cfg.qk_rope_head_dim} | "
-            f"{cfg.v_head_dim}), {took}, rotary (XLA: interleaved pairs on "
-            f"the {cfg.qk_rope_head_dim}-wide parts)")
+            f"key of {rope}, heads {cfg.num_heads} x "
+            f"({cfg.qk_nope_head_dim} + {rope} | "
+            f"{cfg.v_head_dim}), {took}, rotary ({rotary})")
 
 
 def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:

@@ -1,5 +1,6 @@
 """One pass over each head's lanes, on the lanes layout: the RMS norm of
-queries and keys, their rotary, or both.
+queries and keys, their rotary (``rotate_half``, or interleaved pairs),
+or both.
 
 ``x`` is (B, T, H * head_dim): what a projection writes and the attention
 kernels read, a head a run of ``head_dim`` lanes. The norm of queries and
@@ -43,6 +44,27 @@ is theirs bit for bit. The backward stays in f32 from ``dout`` to ``dx``
 (the two passes rounded the cotangent between them) and recomputes the
 inverse RMS from the tile it has loaded: residuals are {x, scale} of a
 norm and the tables of a rotary.
+
+Latent attention's rotary is of interleaved pairs ``(x_2i, x_2i+1)`` on
+heads of 64 lanes, two a lane tile (``joyaiflash``): :func:`pair_rotary`,
+a kernel of its own (the three configurations' programs do not move with
+each other's) of the same shape: one lane tile's (T, 128) f32 tables, a
+tile's rows a grid step with the sample the inner axis, the pair's other
+member ``x[lane + 1]`` on the even lanes and ``x[lane - 1]`` on the odd
+ones: one select of two lane rotates, by 127 and by 1, so the lane a
+rotate wraps around is never the one chosen, and the pair's sign rides in
+the sine (:func:`pair_tables`). The select is its own inverse, so one
+kernel serves both directions: ``out = x cos + swap(x) sin``, transposed
+``dx = dout cos + swap(dout sin)``. It reads its lanes where a wider
+array holds them (the last 2 048 of ``q_b``'s (B, T, 6 144) output as
+column block 2; the one key as the first half of the last lane tile of
+``kv_a``'s (B, T, 576), a block that ends past the array: no slice is
+traced, so XLA copies none in front of the kernel) and hands back one
+``dx``; residuals are the tables. Numerics are
+``models/sparse_lm.rotary_interleaved_lanes``': f32 from f32 tables made
+by the same expression, the result cast to ``x.dtype``; on the v5e the
+forward is that expression's bit for bit at the cell's two shapes
+(``scripts/pair_rotary_probe.py``; PERF.md section 6, PR 45).
 """
 
 from __future__ import annotations
@@ -76,6 +98,10 @@ def fits(tokens: int, width: int, head_dim: int) -> Optional[str]:
         return f"head_dim {head_dim} is not whole {LANES}-lane tiles"
     if width % head_dim:
         return f"{width} lanes are not whole heads of {head_dim}"
+    return _rows_fit(tokens, width)
+
+
+def _rows_fit(tokens: int, width: int) -> Optional[str]:
     if tokens % 8:
         return f"{tokens} rows are not whole sublane tiles of 8"
     if 8 * width > TILE:
@@ -253,3 +279,118 @@ def rotary_tables(cos, sin):
     own inverse."""
     half = cos.shape[-1] // 2
     return cos, jnp.where(jnp.arange(2 * half) < half, -sin, sin)
+
+
+# ---------------------------------------------------------------------------
+# The rotary of interleaved pairs (latent attention's 64-wide parts)
+# ---------------------------------------------------------------------------
+
+def pairs_fit(tokens: int, width: int, head_dim: int) -> Optional[str]:
+    """None where :func:`pair_rotary` takes samples' (tokens, width) lanes
+    of heads of ``head_dim`` lanes, else why not: whole heads side by side
+    in a lane tile share one tile's tables, and an array narrower than a
+    tile is one head (latent attention's shared key)."""
+    if head_dim % 2 or LANES % head_dim:
+        return (f"heads of {head_dim} lanes are not whole heads of pairs a "
+                f"{LANES}-lane tile")
+    if width % LANES and width != head_dim:
+        return (f"{width} lanes are neither whole {LANES}-lane tiles nor "
+                f"one head of {head_dim}")
+    return _rows_fit(tokens, width)
+
+
+def pair_tables(cos, sin):
+    """What :func:`pair_rotary` reads of one lane tile's (T, 128) ``cos``
+    and ``sin``, a pair's two lanes holding the same angle: ``sin`` with
+    the pair's sign in it, minus on the even lanes (``out_2i = x_2i cos -
+    x_2i+1 sin``), so that the kernel's other member of the pair is one
+    select of two lane rotates, which is its own inverse."""
+    return cos, jnp.where(jnp.arange(cos.shape[-1]) % 2 == 0, -sin, sin)
+
+
+def pair_block(width: int) -> int:
+    """Lanes of ``x`` a grid step of :func:`pair_rotary` reads for
+    ``width`` rotated ones: the lanes before them in ``x`` have to be whole
+    such blocks for the pass to read them where they lie."""
+    return width if width % LANES == 0 else LANES
+
+
+def pair_rows_tile(tokens: int, width: int) -> int:
+    """Rows a grid step of :func:`pair_rotary`: an array narrower than a
+    lane tile fills whole ones in VMEM."""
+    return rows_tile(tokens, max(width, LANES))
+
+
+def _pair_rotary_kernel(x_ref, cos_ref, sin_ref, out_ref, *, transpose):
+    """``out = x cos + swap(x) sin`` a lane tile of the block, ``swap`` the
+    pair's other member: ``x[lane + 1]`` on the even lanes, ``x[lane - 1]``
+    on the odd ones, so the lane a rotate wraps around is never chosen.
+    ``transpose``: ``dx = dout cos + swap(dout sin)``. An ``out`` narrower
+    than a lane tile (the one key) is the first lanes of ``x``'s block and
+    of the tables."""
+    lanes = min(out_ref.shape[1], LANES)
+    cos, sin = cos_ref[:, :lanes], sin_ref[:, :lanes]
+    even = jax.lax.broadcasted_iota(jnp.int32, cos.shape, 1) % 2 == 0
+
+    def swap(v):
+        return jnp.where(even, pltpu.roll(v, lanes - 1, 1),
+                         pltpu.roll(v, 1, 1))
+
+    for lo in range(0, out_ref.shape[1], lanes):
+        y = x_ref[:, lo:lo + lanes].astype(jnp.float32)
+        y = y * cos + (swap(y * sin) if transpose else swap(y) * sin)
+        out_ref[:, lo:lo + lanes] = y.astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("before", "transpose", "interpret"))
+def _pair_call(x, cos, sin, *, before, transpose, interpret):
+    """``x``'s lanes from ``before`` on, rotated (or their cotangent): (B,
+    T, width). A tile's rows of the tables a grid step with the sample the
+    inner axis, as :func:`_specs` has them."""
+    b, t, total = x.shape
+    width = total - before
+    block = pair_block(width) if before else width
+    assert before % block == 0, (before, block)
+    bm = pair_rows_tile(t, width)
+    table = pl.BlockSpec((bm, LANES), lambda i, n: (i, 0))
+    with jax.named_scope(ROTARY_SCOPE):
+        return pl.pallas_call(
+            functools.partial(_pair_rotary_kernel, transpose=transpose),
+            grid=(t // bm, b),
+            in_specs=[pl.BlockSpec((None, bm, block),
+                                   lambda i, n: (n, i, before // block)),
+                      table, table],
+            out_specs=pl.BlockSpec((None, bm, width), lambda i, n: (n, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((b, t, width), x.dtype),
+            compiler_params=_PARAMS,
+            interpret=interpret,
+        )(x, cos, sin)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def pair_rotary(x, tables, before: int = 0, interpret: bool = False):
+    """The rotary of interleaved pairs ``(x_2i, x_2i+1)`` on the lanes of
+    ``x`` (B, T, W) from ``before`` on, read where they lie (no slice is
+    traced): where :func:`pairs_fit` takes them and ``before`` is whole
+    :func:`pair_block` s. ``tables``: what :func:`pair_tables` gives for
+    the T positions and one lane tile. Returns those lanes, (B, T, W -
+    before), in ``x.dtype``. Gradient residuals: the tables."""
+    return _pair_call(x, *tables, before=before, transpose=False,
+                      interpret=interpret)
+
+
+def _pair_vjp_fwd(x, tables, before, interpret):
+    return _pair_call(x, *tables, before=before, transpose=False,
+                      interpret=interpret), tables
+
+
+def _pair_vjp_bwd(before, interpret, tables, dout):
+    dx = _pair_call(dout, *tables, before=0, transpose=True,
+                    interpret=interpret)
+    # the lanes before are not this pass's: one pad of noughts, which XLA
+    # fuses into the sum with what their own readers hand back
+    return jnp.pad(dx, ((0, 0), (0, 0), (before, 0))), None
+
+
+pair_rotary.defvjp(_pair_vjp_fwd, _pair_vjp_bwd)

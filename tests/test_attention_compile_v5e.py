@@ -200,6 +200,94 @@ def test_the_per_head_pass_compiles_in_both_directions(
     lowered.compile()
 
 
+def test_latent_attentions_rotary_is_one_pass_where_q_b_wrote_it(
+        one_chip, no_persistent_cache, monkeypatch):
+    """One latent layer's gradient at ``joyaiflash``'s cell's shapes: the
+    rotary of the queries' 2 048 lanes and of the one 64-wide key is two
+    Mosaic calls a direction named ``rotary`` (not ``attn``: the attention
+    roofline reads the latent kernels alone), the queries' read as column
+    block 2 of ``q_b``'s (1, 8 192, 6 144) output and the key as the first
+    half of lane tile 4 of ``kv_a``'s (1, 8 192, 576), and XLA is left the
+    tables of one lane tile: no cosine or sine as wide as the rotated
+    array, no padded shifted copy of it (``rotary_interleaved_lanes``'
+    (..., 2 047) slices), no copy of the columns in front of a kernel."""
+    from dalle_tpu import config
+    from dalle_tpu.models import attention, sparse_lm
+
+    monkeypatch.setattr(attention, "_pallas_by_default", lambda: True)
+    cfg = config.joyaiflash_model_config()
+    mod = sparse_lm.LatentAttention(cfg, name="attn")
+    t, heads, rope = cfg.total_seq_len, cfg.num_heads, cfg.qk_rope_head_dim
+    a = jax.ShapeDtypeStruct((1, t, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: mod.init(jax.random.PRNGKey(0),
+                                        jnp.zeros(a.shape, a.dtype))))
+
+    def loss(p, a):
+        return jnp.sum(mod.apply(p, a).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, a).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = sorted(re.match(r"\s*(?:ROOT )?%?([\w\-]+?)[.\d]* =", line).group(1)
+                   for line in calls)
+    # beside them the latent kernels' forward and their one backward
+    assert names.count("rotary") == 4 and len(names) == 6, names
+    assert all("attn" in n for n in names if n != "rotary"), names
+    for lanes in (heads * rope, rope):
+        assert sparse_lm._HEAD_PASSES[t, lanes, rope, False, True] is None
+    # each forward call reads its projection's whole output where it lies
+    for whole, part in ((heads * (cfg.qk_nope_head_dim + rope), heads * rope),
+                        (cfg.kv_lora_rank + rope, rope)):
+        reads = [c for c in calls
+                 if f"bf16[1,{t},{whole}]" in c.split("custom-call(")[1]]
+        assert len(reads) == 1 and f"bf16[1,{t},{part}]" in \
+            reads[0].split("custom-call(")[0], reads
+    assert f",{heads * rope - 1}]" not in text and f",{rope - 1}]" not in text
+    tables = [line.split("=")[1].split()[0] for line in text.splitlines()
+              if re.search(r" (cosine|sine)\(", line)]
+    assert tables and all(_minor(s.split("]")[0]) <= 128 for s in tables), \
+        tables
+    # the rotary parts exist as the pass wrote them and as nothing else:
+    # no slice or copy of q_b's last columns, nor of kv_a's
+    moved = [m.group(0)[:160] for m in map(_PRODUCED.match, text.splitlines())
+             if m and any(f"bf16[1,{t},{lanes}]" in m.group(1)
+                          for lanes in (heads * rope, rope))]
+    assert not moved, moved
+
+
+@pytest.mark.parametrize("batch, lanes, before", [
+    (1, 6144, 4096), (1, 576, 512),          # joyaiflash's q_b and kv_a
+    (1, 64, 0), (2, 1024, 0)])               # a key alone; half the heads
+def test_the_pair_pass_compiles_in_both_directions(
+        batch, lanes, before, one_chip, no_persistent_cache):
+    """``head_norm_kernels.pair_rotary`` and its gradient alone at the
+    cell's local shapes: the two lane rotates lower at 128 lanes and at
+    the key's 64, a block of ``kv_a``'s last lane tile ends past the array,
+    and the tiles fit VMEM."""
+    from dalle_tpu.ops.pallas import head_norm_kernels as K
+
+    tokens = 8192
+    assert K.pairs_fit(tokens, lanes - before, 64) is None
+
+    def both(x, tables, w):
+        y, vjp = jax.vjp(lambda x: K.pair_rotary(x, tables, before), x)
+        return y, vjp(w)
+
+    x, w = (jax.ShapeDtypeStruct((batch, tokens, n), jnp.bfloat16,
+                                 sharding=one_chip)
+            for n in (lanes, lanes - before))
+    table = jax.ShapeDtypeStruct((tokens, K.LANES), jnp.float32,
+                                 sharding=one_chip)
+    lowered = jax.jit(both).lower(x, (table, table), w)
+    assert "_pair_rotary_kernel" in re.findall(r'kernel_name = "([^"]+)"',
+                                               lowered.as_text())
+    lowered.compile()
+
+
 @pytest.mark.parametrize("batch, tokens, heads, window, dtype, kernel", [
     (2, 8192, 28, 4096, jnp.bfloat16, "_causal_bwd_kernel"),    # the cells'
     (1, 8192, 32, None, jnp.bfloat16, "_causal_bwd_kernel"),

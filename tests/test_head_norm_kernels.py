@@ -5,7 +5,10 @@ row count that takes more than one tile, two samples, heads of two lane
 tiles; per shard on the 8-device mesh; and the rule that chooses it. The
 same pass with the rotary in it, after the norm (``trinitymini``) or alone
 (``smallthinker21b``), against the two expressions it replaces: the
-norm's pass and ``apply_rotary_lanes`` with tables as wide as the array."""
+norm's pass and ``apply_rotary_lanes`` with tables as wide as the array.
+And the pass of interleaved pairs (``joyaiflash``'s latent attention:
+``pair_rotary``) against ``rotary_interleaved_lanes``: the queries' rotary
+columns read out of ``q_b``'s wider array, the one 64-wide key."""
 import functools
 
 import jax
@@ -17,6 +20,7 @@ from jax.sharding import PartitionSpec as P
 from dalle_tpu.models import attention, sparse_lm
 from dalle_tpu.ops.pallas import head_norm_kernels as K
 from dalle_tpu.parallel.mesh import LANES_SPEC, make_mesh, per_shard
+
 
 EPS = 1e-5
 
@@ -335,3 +339,240 @@ def test_the_rotary_alone_saves_no_array_of_the_inputs_size():
                 if getattr(r, "shape", ()) == x.shape]
 
     assert residuals(None) == [] and residuals(scale) == [x.shape]
+
+
+# ---------------------------------------------------------------------------
+# The rotary of interleaved pairs
+# ---------------------------------------------------------------------------
+
+PAIR_THETA, PAIR_HEAD = 3.2e7, 64           # joyaiflash's
+
+# (samples, rows, lanes of the array), the lane the rotated columns start
+# at, dtype: the cell's two arrays at fewer rows (q_b's 6 144 lanes of
+# which the last 2 048 turn, kv_a's 576 of which the last 64 are the key:
+# half of a lane tile that ends past the array), each over two row tiles,
+# small ones in f32, and arrays that are rotated whole
+PAIR_CASES = {
+    "q_2048_of_6144_two_samples_bf16": ((2, 1024, 6144), 4096, "bfloat16"),
+    "k_64_of_576_two_tiles_bf16": ((1, 16384, 576), 512, "bfloat16"),
+    "q_256_of_768_f32": ((1, 64, 768), 512, "float32"),
+    "k_64_of_192_two_samples_f32": ((2, 104, 192), 128, "float32"),
+    "k_64_the_whole_array_bf16": ((2, 512, 64), 0, "bfloat16"),
+    "q_128_the_whole_array_bf16": ((2, 512, 128), 0, "bfloat16"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _paired(case):
+    """(out, dx) of the one pass, of the expression it replaces on the
+    slice (tables as wide as the rotated columns), and of that expression
+    in f32 from the same input (``dx`` rounded to the input's dtype)."""
+    shape, start, dtype = PAIR_CASES[case]
+    width = shape[2] - start
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 2)
+    x = (jax.random.normal(keys[0], shape) * 2.0 + 0.3).astype(dtype)
+    w = jax.random.normal(keys[1], (*shape[:2], width)).astype(dtype)
+
+    # both sides' tables from one compiled cosine, of 8 significant bits
+    # beside a bf16 input's 8 (the reasons are _rotated's)
+    def tables(lanes):
+        angles = sparse_lm._pair_angles(shape[1], lanes, PAIR_HEAD,
+                                        PAIR_THETA)
+        ts = jnp.cos(angles), jnp.sin(angles)
+        if dtype == "bfloat16":
+            ts = tuple(t.astype(dtype).astype(jnp.float32) for t in ts)
+        return ts
+    wide, one = tables(width), tables(K.LANES)
+    # one lane tile's table is every tile's, and a narrower array's
+    assert all(np.array_equal(jnp.tile(a, (1, -(-width // K.LANES)))
+                              [:, :width], b) for a, b in zip(one, wide))
+    assert start % K.pair_block(width) == 0
+
+    def one_pass(x):
+        return K.pair_rotary(x, K.pair_tables(*one), start, True)
+
+    def today(x):
+        return sparse_lm.turn_pairs_lanes(x[..., start:], *wide)
+
+    def outputs(fn, x, w):
+        def run(x, w):
+            y, vjp = jax.vjp(fn, x)
+            return y, vjp(w)[0]
+        return as_stated(run, x, w)
+
+    return (outputs(one_pass, x, w), outputs(today, x, w),
+            outputs(lambda x: today(x.astype(jnp.float32)), x,
+                    w.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("case", PAIR_CASES)
+def test_the_pair_pass_forward_is_todays_expression_bit_for_bit(case):
+    """The same f32 arithmetic a lane and one rounding, to ``x.dtype``; the
+    sign rides in the sine where today's negates the shifted copy. In f32,
+    where a product is not exact, to the contraction the CPU's compiler
+    chose on either side."""
+    (y, _), (ref, _), _ = _paired(case)
+    shape, start, dtype = PAIR_CASES[case]
+    assert y.dtype == ref.dtype == jnp.dtype(dtype)
+    assert y.shape == ref.shape == (*shape[:2], shape[2] - start)
+    if dtype == "bfloat16":
+        assert np.array_equal(y, ref)
+    else:
+        assert rel_l2(y, ref) < 1e-7
+
+
+@pytest.mark.parametrize("case", PAIR_CASES)
+def test_the_pair_pass_dx_is_the_plain_gradient_and_no_further_than_todays(
+        case):
+    """One ``dx`` of the whole array's shape, nought in the columns the
+    pass does not turn; f32 from ``dout`` to it, one rounding."""
+    (_, dx), (_, xla), (_, ref) = _paired(case)
+    shape, start, dtype = PAIR_CASES[case]
+    assert dx.shape == shape and dx.dtype == xla.dtype == jnp.dtype(dtype)
+    assert not np.any(np.asarray(dx[..., :start], np.float32))
+    f32 = dtype == "float32"
+    assert rel_l2(dx, ref) < (1e-6 if f32 else 1e-4)
+    assert rel_l2(dx, ref) <= rel_l2(xla, ref) + 1e-7 * f32
+
+
+# ... and one whose lanes before the rotated ones are no whole block of the
+# pass: sliced first, then the pass
+DISPATCHED = {**PAIR_CASES,
+              "q_128_after_64_sliced_first_f32": ((1, 64, 192), 64,
+                                                  "float32")}
+
+
+@pytest.mark.parametrize("case", DISPATCHED)
+def test_the_dispatcher_takes_the_pair_pass_with_its_own_tables(
+        case, monkeypatch):
+    """``_pair_rotary_shard`` from the array and the lane its rotary part
+    starts at: the tables it makes for one lane tile, the block it names
+    and the record ``attn_layout`` reads."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    shape, start, dtype = DISPATCHED[case]
+    width = shape[2] - start
+    x = jax.random.normal(jax.random.PRNGKey(5), shape).astype(dtype)
+    sparse_lm._HEAD_PASSES.pop((shape[1], width, PAIR_HEAD, False, True),
+                               None)
+    y = as_stated(functools.partial(
+        sparse_lm._pair_rotary_shard, start=start, head_dim=PAIR_HEAD,
+        theta=PAIR_THETA, lanes=width), x)
+    ref = as_stated(lambda x: sparse_lm.rotary_interleaved_lanes(
+        x[..., start:], PAIR_HEAD, PAIR_THETA), x)
+    assert sparse_lm._HEAD_PASSES[shape[1], width, PAIR_HEAD, False,
+                                  True] is None
+    assert y.dtype == ref.dtype and y.shape == ref.shape
+    # each side's cosine compiled into its own fusion: a last digit apart
+    # at large angles, so a result may round to the other neighbour
+    assert rel_l2(y, ref) < (1e-6 if dtype == "float32" else 1e-4)
+
+
+@pytest.mark.parametrize("tokens, width, head_dim, interpret, why", [
+    (8192, 2048, 64, True, None),           # the cell's two
+    (8192, 64, 64, True, None),
+    (64, 256, 128, True, None),             # one head a lane tile
+    (64, 128, 32, True, None),              # four
+    (64, 192, 64, True, "192 lanes are neither whole 128-lane tiles nor one "
+                        "head of 64"),
+    (64, 32, 16, True, "32 lanes are neither whole 128-lane tiles nor one "
+                       "head of 16"),
+    (64, 384, 48, True, "heads of 48 lanes are not whole heads of pairs a "
+                        "128-lane tile"),
+    (64, 256, 256, True, "heads of 256 lanes are not whole heads of pairs a "
+                         "128-lane tile"),
+    (60, 256, 64, True, "60 rows are not whole sublane tiles of 8"),
+    (64, 1 << 18, 64, True, "8 rows of 262144 lanes pass a tile of 1048576 "
+                            "numbers"),
+    (8192, 2048, 64, False, "no Mosaic backend"),
+])
+def test_pair_rotary_why_not(tokens, width, head_dim, interpret, why,
+                             monkeypatch):
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", interpret)
+    assert sparse_lm.pair_rotary_why_not(tokens, width, head_dim) == why
+
+
+@pytest.mark.parametrize("shape, start, head_dim, interpret", [
+    ((2, 32, 576), 384, 64, True),          # 192 lanes: a tile and a half
+    ((2, 32, 704), 512, 64, True),          # ... and so is 192 after 512
+    ((1, 12, 384), 256, 64, True),          # 12 rows
+    ((2, 32, 96), 0, 48, True),             # heads of 48
+    ((2, 32, 384), 256, 64, False),         # no Mosaic backend
+])
+def test_what_the_pair_rule_refuses_is_todays_expression_bit_for_bit(
+        shape, start, head_dim, interpret, monkeypatch):
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", interpret)
+    keys = jax.random.split(jax.random.PRNGKey(3), 2)
+    x = jax.random.normal(keys[0], shape).astype(jnp.bfloat16)
+    width = shape[2] - start
+    w = jax.random.normal(keys[1], (*shape[:2], width)).astype(jnp.bfloat16)
+
+    def today(x):
+        return sparse_lm.rotary_interleaved_lanes(x[..., start:], head_dim,
+                                                  PAIR_THETA)
+
+    def now(x):
+        return sparse_lm._pair_rotary_shard(
+            x, start=start, head_dim=head_dim, theta=PAIR_THETA, lanes=width)
+
+    assert sparse_lm.pair_rotary_why_not(shape[1], width,
+                                         head_dim) is not None
+    for fn, ref in ((now, today),
+                    (jax.grad(lambda x: jnp.sum(now(x) * w)),
+                     jax.grad(lambda x: jnp.sum(today(x) * w)))):
+        a, b = jax.jit(fn)(x), jax.jit(ref)(x)
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert sparse_lm._HEAD_PASSES[shape[1], width, head_dim, False,
+                                  True] is not None
+
+
+@pytest.mark.parametrize("part", ["queries", "key"])
+@pytest.mark.parametrize("nested", [False, True],
+                         ids=["whole_mesh", "inside_manual_dp"])
+def test_the_pair_pass_per_shard_whole_pairs_of_heads(nested, part,
+                                                      monkeypatch,
+                                                      inside_manual_dp):
+    """dp 2 x fsdp 2 x tp 2: a shard holds a sample's rows of one pair of
+    the four heads' rotary lanes (one lane tile: its tables are every
+    tile's), and the one key whole."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    mesh = make_mesh(dp=2, fsdp=2, tp=2)
+    lanes, spec = ((256, LANES_SPEC) if part == "queries"
+                   else (64, sparse_lm.ROPE_KEY_SPEC))
+    keys = jax.random.split(jax.random.PRNGKey(7), 2)
+    x = jax.random.normal(keys[0], (4, 24, lanes)) * 2.0
+    w = jax.random.normal(keys[1], x.shape)
+    work = functools.partial(sparse_lm._pair_rotary_shard, start=0,
+                             head_dim=PAIR_HEAD, theta=PAIR_THETA,
+                             lanes=lanes)
+
+    def value_and_grads(mesh_):
+        def f(x, w):
+            out = per_shard(work, mesh_, (spec,), spec, scope="rotary")(x)
+            return jnp.sum(out * w), out
+        vg = jax.value_and_grad(f, argnums=(0,), has_aux=True)
+        if nested and mesh_ is not None:
+            vg = inside_manual_dp(vg, mesh_, (True, True), (0,))
+        return jax.jit(vg)
+
+    sparse_lm._HEAD_PASSES.pop((24, lanes, PAIR_HEAD, False, True), None)
+    (_, out_m), g_m = value_and_grads(mesh)(x, w)
+    assert sparse_lm._HEAD_PASSES[24, lanes, PAIR_HEAD, False, True] is None
+    (_, out_1), g_1 = value_and_grads(None)(x, w)
+    assert len(out_m.sharding.device_set) == 8
+    np.testing.assert_allclose(out_m, out_1, rtol=1e-6, atol=1e-6)
+    assert rel_l2(g_m[0], g_1[0]) < 1e-6
+    ref = sparse_lm.rotary_interleaved_lanes(x, PAIR_HEAD, PAIR_THETA)
+    np.testing.assert_allclose(out_m, ref, rtol=1e-5, atol=1e-5)
+
+
+def test_the_pair_pass_saves_no_array_of_the_inputs_size():
+    """Its transpose needs the tables and the cotangent: no residual has
+    the shape of ``q_b``'s output, nor of the columns turned."""
+    x = jnp.ones((1, 64, 768), jnp.bfloat16)
+    angles = sparse_lm._pair_angles(64, K.LANES, PAIR_HEAD, PAIR_THETA)
+    tables = K.pair_tables(jnp.cos(angles), jnp.sin(angles))
+    out, vjp = jax.vjp(lambda x: K.pair_rotary(x, tables, 512, True), x)
+    assert out.shape == (1, 64, 256)
+    kept = [r.shape for r in jax.tree.leaves(vjp) if hasattr(r, "shape")]
+    assert kept and x.shape not in kept and out.shape not in kept
+    assert set(kept) <= {(64, K.LANES)}

@@ -120,10 +120,106 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(with_kernels,
     assert said["attn_layout"].startswith(
         f"latent 48 / 32 + one rotary key of {cfg.qk_rope_head_dim}, heads "
         f"4 x ({widths}), ")
-    assert ("blockwise 512: 4 of 4 layers, 2 heads a step, backward: one "
-            "kernel a tile" if with_kernels else
-            "dense XLA lowering (no Mosaic backend)") in said["attn_layout"]
+    assert said["attn_layout"].endswith(
+        "blockwise 512: 4 of 4 layers, 2 heads a step, backward: one "
+        "kernel a tile, rotary (one pass on the lanes: 4 of 4 layers)"
+        if with_kernels else
+        "dense XLA lowering (no Mosaic backend), rotary (XLA: no Mosaic "
+        "backend)")
     assert "weight 0.3" in said["mtp_layout"]
+
+
+@pytest.mark.parametrize("state, text_seq_len, rotary", [
+    ("taken", 24, "one pass on the lanes: 4 of 4 layers"),
+    ("refused", 20, "XLA: 36 rows are not whole sublane tiles of 8"),
+    ("no_backend", 24, "XLA: no Mosaic backend"),
+])
+def test_attn_layout_says_which_lowering_the_rotary_took(
+        state, text_seq_len, rotary, monkeypatch):
+    """The record's last words, from what the traced calls did: the pass
+    on every latent layer (the prediction module's among them), the rule's
+    refusal of the local shapes, or a backend with no Mosaic kernels."""
+    cfg = JoyAILMConfig(**dict(TINY, **KERNEL_WIDTHS,
+                               text_seq_len=text_seq_len))
+    cfg.validate()
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET",
+                        state != "no_backend")
+    monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
+    model = sparse_lm.build(cfg)
+    assert sparse_lm.engagement_records(cfg)["attn_layout"].endswith(
+        ", rotary (XLA: no Mosaic backend)" if state == "no_backend"
+        else ", rotary (XLA: none traced)")
+    loss, _ = jax.jit(model.apply)(_params(cfg), *_batch(cfg))
+    assert np.isfinite(float(loss))
+    said = sparse_lm.engagement_records(cfg)["attn_layout"]
+    assert said.endswith(f", rotary ({rotary})"), said
+    assert "interleaved pairs" not in said
+    # the queries' 4 x 64 rotary lanes and the one key
+    assert sorted(k[1:] for k in sparse_lm._HEAD_PASSES
+                  if k[0] == cfg.total_seq_len) == [
+        (64, 64, False, True), (256, 64, False, True)]
+
+
+@pytest.mark.parametrize("axes", [dict(dp=2, fsdp=2, tp=2),
+                                  dict(dp=4, fsdp=2, tp=1)],
+                         ids=["tp_splits_the_heads", "samples_only"])
+def test_latent_attention_on_a_mesh_is_the_one_device_layer(axes,
+                                                            monkeypatch):
+    """One layer, kernels interpreted, value and every gradient: where
+    ``tp`` splits the heads the rotary pass gets the queries' rotary part
+    as an array of its own (a shard's whole pairs of heads), else it reads
+    ``q_b``'s output where it lies; the key always ``kv_a``'s."""
+    from dalle_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    cfg = JoyAILMConfig(**dict(TINY, **KERNEL_WIDTHS))
+    a = jax.random.normal(jax.random.PRNGKey(0),
+                          (8, cfg.total_seq_len, cfg.hidden_size))
+
+    def value_and_grads(mesh):
+        mod = sparse_lm.LatentAttention(cfg, mesh=mesh, name="attn")
+        params = mod.init(jax.random.PRNGKey(1), a)
+        return jax.jit(jax.value_and_grad(
+            lambda p, a: jnp.sum(mod.apply(p, a) ** 2), (0, 1)))(params, a)
+
+    monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
+    value, grads = value_and_grads(make_mesh(**axes))
+    assert set(sparse_lm._HEAD_PASSES.values()) == {None}
+    want, want_grads = value_and_grads(None)
+    assert float(value) == pytest.approx(float(want), rel=5e-6)
+    for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        assert rel_l2(g, r) < 5e-6
+
+
+def test_the_pair_pass_in_the_model_is_its_xla_lowering(monkeypatch):
+    """Loss and every gradient leaf of the whole tiny model with the rotary
+    as the one pass on the lanes (interpreted) against the same model with
+    ``rotary_interleaved_lanes``, every other kernel running on both
+    sides: within the limits the yardstick's comparison has."""
+    cfg = JoyAILMConfig(**dict(TINY, **KERNEL_WIDTHS))
+    cfg.validate()
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    params = _params(cfg)
+    text, image = _batch(cfg)
+    model = sparse_lm.build(cfg)
+
+    def loss_and_grads():
+        # a new function a lowering: nothing traced before is reused
+        return jax.jit(jax.value_and_grad(
+            lambda p: model.apply(p, text, image)[0]))(params)
+
+    loss, grads = loss_and_grads()
+    assert "one pass on the lanes" in sparse_lm.engagement_records(cfg)[
+        "attn_layout"]
+    monkeypatch.setattr(sparse_lm, "pair_rotary_why_not",
+                        lambda *shape: "refused here")
+    ref_loss, ref_grads = loss_and_grads()
+    assert sparse_lm.engagement_records(cfg)["attn_layout"].endswith(
+        "rotary (XLA: refused here)")
+    assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree.leaves(ref_grads)):
+        assert rel_l2(g, r) < 2e-5, jax.tree_util.keystr(path)
 
 
 # bfloat16 activations, enough tokens (768 a sequence) and experts (64, 8
@@ -297,8 +393,8 @@ def test_the_preset_trains_through_the_peers_normal_path():
         "a shared expert of 32, layers 0-0 dense 96, no exchange")
     assert warm["attn_layout"] == (
         "latent 48 / 32 + one rotary key of 64, heads 4 x (128 + 64 | 128), "
-        "dense XLA lowering (no Mosaic backend), rotary (XLA: interleaved "
-        "pairs on the 64-wide parts)")
+        "dense XLA lowering (no Mosaic backend), rotary (XLA: no Mosaic "
+        "backend)")
     assert warm["mtp_layout"].startswith("one prediction module after the "
                                          "final norm")
     steps = [r for r in rows if r["phase"] == "loop/step"][-3:]
