@@ -134,7 +134,10 @@ in a trace): the queries' rotary columns read where ``q_b`` wrote them,
 the one key where ``kv_a`` wrote it in a second small call, one lane
 tile's (T, 128) tables for two heads side by side; :func:`pair_rotary_why_not` is the rule, and where
 it refuses :func:`rotary_interleaved_lanes` runs with tables as wide as
-the array.
+the array. The latent kernels likewise read ``q_nope``, ``k_nope`` and
+``v`` as column blocks of ``q_b``'s and ``kv_b``'s outputs and make their
+backward's ``delta`` themselves (``attn_operands`` on the ``setup/warmup``
+row says so, or "sliced: <why>": :class:`LatentAttention`).
 """
 
 from __future__ import annotations
@@ -431,25 +434,49 @@ ROPE_KEY_SPEC = P(LANES_SPEC[0], None, None)
 # attention of those local shapes did not take the blockwise kernels, None
 # where it did: what attn_layout reads
 _LATENT_CHOICES: Dict[Tuple[int, int, int, int, int], Optional[str]] = {}
+# the same key -> why that call's q_nope, k_nope and v were slices and not
+# q_b's and kv_b's outputs read where they lie, None where they were read
+# there: what attn_operands reads
+_LATENT_OPERANDS: Dict[Tuple[int, int, int, int, int], Optional[str]] = {}
+IN_PLACE = ("q_nope, k_nope, v read where q_b and kv_b wrote them, delta in "
+            "the backward kernel")
 
 
-def _latent_shard(q_nope, q_rope, k_nope, k_rope, v, *, nope: int,
-                  rope: int, value: int):
+def _latent_shard(q, q_rope, kv, k_rope, *, nope: int, rope: int,
+                  value: int):
     """One shard's latent attention: the blockwise kernels where they
-    fit."""
-    t, heads = q_nope.shape[1], q_rope.shape[2] // rope
+    fit. ``q`` and ``kv`` as ``kernels.latent_attention`` takes them: the
+    projections' whole outputs, or ``q_nope`` and the pair ``(k_nope, v)``
+    where a mesh axis splits the heads."""
+    t, heads = q.shape[1], q_rope.shape[2] // rope
     why_not = kernels.latent_fits(t, heads, nope, rope, value,
-                                  q_nope.dtype.itemsize)
-    _LATENT_CHOICES[t, heads, nope, rope, value] = why_not
+                                  q.dtype.itemsize)
+    sliced = why_not
+    if isinstance(kv, tuple):
+        sliced = sliced or ("a mesh axis splits the heads of each part, "
+                            "not the lanes of q_b's and kv_b's outputs")
+    key = t, heads, nope, rope, value
+    _LATENT_CHOICES[key], _LATENT_OPERANDS[key] = why_not, sliced
     attn_mod.log_kernel_choice(
         "latent attention", why_not is None,
         why_not or f"local {heads} heads of {nope} + {rope} | {value} over "
         f"one rotary key, {t} tokens: blocks of {kernels.BLOCK}, "
-        f"{kernels.LATENT_HEADS} heads a step, " + _backward_words(None))
+        f"{kernels.LATENT_HEADS} heads a step, " + _backward_words(None)
+        + ", " + (f"operands sliced: {sliced}" if sliced else IN_PLACE))
     if why_not is not None:
+        q_nope, (k_nope, v) = _latent_parts(q, kv, heads * nope)
         return dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v)
-    return kernels.latent_attention(q_nope, q_rope, k_nope, k_rope, v,
-                                    kernels.BLOCK, attn_mod._PALLAS_INTERPRET)
+    return kernels.latent_attention(q, q_rope, kv, k_rope, kernels.BLOCK,
+                                    attn_mod._PALLAS_INTERPRET)
+
+
+def _latent_parts(q, kv, lanes: int):
+    """``q_nope`` and the pair ``(k_nope, v)`` as arrays of their own: the
+    first ``lanes`` lanes of ``q_b``'s output, the first ``lanes`` lanes of
+    ``kv_b``'s and the rest; a pair is handed back as it is."""
+    if not isinstance(kv, tuple):
+        kv = kv[..., :lanes], kv[..., lanes:]
+    return q[..., :lanes], kv
 
 
 class LatentAttention(nn.Module):
@@ -458,8 +485,14 @@ class LatentAttention(nn.Module):
     one rotary key (module docstring). The projections' columns are
     head-major part by part: ``q_b`` is every head's ``nope`` lanes, then
     every head's ``rope`` lanes; ``kv_a`` the latent, then the rotary key;
-    ``kv_b`` every head's ``k_nope``, then every head's ``v``: each part is
-    a slice at a lane tile's edge and no (B, T, H, d) array exists."""
+    ``kv_b`` every head's ``k_nope``, then every head's ``v``: each part
+    starts at a lane tile's edge and no (B, T, H, d) array exists. The
+    rotary pass and the latent kernels read their parts of ``q_b``'s,
+    ``kv_a``'s and ``kv_b``'s outputs where they lie, as column blocks (no
+    slice is traced); where a mesh axis splits the lanes (``tp`` > 1) it
+    splits the heads of each part, so the parts are sliced first and the
+    same entries get them at offset 0, as the dense lowering does (no Mosaic
+    backend, or ``latent_fits`` refuses): ``attn_operands`` says which."""
     cfg: SparseLMConfig
     mesh: Any = None
 
@@ -483,15 +516,16 @@ class LatentAttention(nn.Module):
         kv_a = dense(cfg.kv_lora_rank + rope, name="kv_a")(a)
         kv = dense(heads * (nope + value), name="kv_b")(latent_norm(
             "kv_a_norm", kv_a[..., :cfg.kv_lora_rank]))
-        q_nope = q[..., :heads * nope]
-        k_nope, v = kv[..., :heads * nope], kv[..., heads * nope:]
+
+        def split(axis) -> bool:
+            return bool(axis) and self.mesh is not None \
+                and self.mesh.shape.get(axis, 1) > 1
 
         def rotary(x, start, spec):
             """``x[..., start:]`` rotated, read where the projection wrote
             it; but a mesh axis that splits the lanes (``tp``) splits the
             heads of the rotary part, not the lanes of ``x``."""
-            if spec[2] and self.mesh is not None \
-                    and self.mesh.shape.get(spec[2], 1) > 1:
+            if split(spec[2]):
                 x, start = x[..., start:], 0
             work = functools.partial(
                 _pair_rotary_shard, start=start, head_dim=rope,
@@ -505,15 +539,19 @@ class LatentAttention(nn.Module):
             q_rope = rotary(q, heads * nope, LANES_SPEC)
             k_rope = rotary(kv_a, cfg.kv_lora_rank, ROPE_KEY_SPEC)
         if attn_mod._pallas_by_default():
+            # the kernels read q_b's and kv_b's outputs where they lie; but
+            # tp splits the heads (the one rotary key is whole on each)
+            if split(LANES_SPEC[2]):
+                q, kv = _latent_parts(q, kv, heads * nope)
             attend = functools.partial(_latent_shard, nope=nope, rope=rope,
                                        value=value)
-            # tp splits the heads; the one rotary key is whole on each
             ctx = per_shard(
                 attend, self.mesh,
-                (LANES_SPEC, LANES_SPEC, LANES_SPEC, ROPE_KEY_SPEC, LANES_SPEC),
-                LANES_SPEC, scope=self.name)(q_nope, q_rope, k_nope, k_rope,
-                                             v)
+                (LANES_SPEC, LANES_SPEC,
+                 jax.tree.map(lambda _: LANES_SPEC, kv), ROPE_KEY_SPEC),
+                LANES_SPEC, scope=self.name)(q, q_rope, kv, k_rope)
         else:
+            q_nope, (k_nope, v) = _latent_parts(q, kv, heads * nope)
             ctx = dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v)
         return dense(cfg.hidden_size, name="out")(ctx)
 
@@ -1293,6 +1331,21 @@ def _latent_layout(cfg: SparseLMConfig, tp: int) -> str:
             f"{cfg.v_head_dim}), {took}, rotary ({rotary})")
 
 
+def _latent_operands(cfg: SparseLMConfig, tp: int) -> str:
+    """``attn_operands`` of such a configuration: whether the traced calls'
+    kernels read ``q_nope``, ``k_nope`` and ``v`` where ``q_b`` and
+    ``kv_b`` wrote them (every layer has the one shape, so all did or the
+    refusal says why none did)."""
+    layers = cfg.num_hidden_layers + cfg.num_nextn_predict_layers
+    sliced = "no Mosaic backend"
+    if attn_mod._pallas_by_default():
+        sliced = _LATENT_OPERANDS.get(
+            (cfg.total_seq_len, cfg.num_heads // tp, cfg.qk_nope_head_dim,
+             cfg.qk_rope_head_dim, cfg.v_head_dim), "none traced")
+    return (f"sliced: {sliced}" if sliced else
+            f"latent: {IN_PLACE}: {layers} of {layers} layers")
+
+
 def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     """The ``setup/warmup`` row's attributes: which layers' traced calls
     took the blockwise kernel and which backward those took (looked up in
@@ -1370,6 +1423,7 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     said = {}
     if LAYER_FULL_ROPE in kinds:
         attn_layout = _latent_layout(cfg, tp)
+        said["attn_operands"] = _latent_operands(cfg, tp)
     if cfg.num_nextn_predict_layers:
         said["mtp_layout"] = (
             "one prediction module after the final norm: [norm(next "

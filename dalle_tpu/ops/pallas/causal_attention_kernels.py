@@ -49,7 +49,19 @@ head reads, and its values are 128 wide: one key-value head a query head.
 ``_latent_fwd_kernel`` and ``_latent_bwd_kernel`` walk the same band with
 the same tile arithmetic and statistics layout, ``LATENT_HEADS`` = 2 heads a
 grid step, so that the two heads' 64-wide rotary parts are one 128-lane
-tile of ``q_rope`` (B, T, H*64). The shared key reaches the kernels as one
+tile of ``q_rope`` (B, T, H*64). **The kernels read ``q_nope``, ``k_nope``
+and ``v`` where the projections wrote them:** ``q`` is ``q_b``'s whole
+(B, T, H*192) output, every head's ``nope`` lanes first, and a step's two
+heads' tile is its column block ``j`` of 256 lanes; ``kv`` is ``kv_b``'s
+whole (B, T, H*256) output, every head's ``k_nope`` and then every head's
+``v``: ``k_nope`` is column block ``j`` and ``v`` column block ``H/2 + j``
+(kind "v_group", ``_call``'s ``v_block``), forward and backward alike, so no
+slice stands between a projection and a kernel. Handed a pair ``(k_nope,
+v)`` instead (a ``tp`` axis splits the heads of each part, not the lanes of
+``kv``), both are read at block ``j``. The cotangents go back in the form
+the operands came in: ``dq_nope`` padded with noughts to ``q``'s width,
+``dk_nope`` and ``dv`` side by side as ``kv``'s, or the pair. The shared key
+reaches the kernels as one
 (B, T, 256) placement ``[k_rope, 0 | 0, k_rope]``: the step's (block, 128)
 tile of ``q_rope`` times the first half's transpose is head 0's rotary
 scores, times the second's head 1's, with no slice inside a lane tile; it is
@@ -59,8 +71,14 @@ over the whole of a sample's grid (the head axis is ``arbitrary`` there),
 head 0's part in the lower 64 lanes and head 1's in the upper, which the
 caller adds. The backward is the one kernel a tile: the same 5 products,
 the three on the score side as a 128-wide and a 64-wide part each (8 MXU
-calls; the rotary parts run 128 lanes deep for the 64 they need). Where
-its accumulators do not fit VMEM :func:`latent_fits` refuses the shapes.
+calls; the rotary parts run 128 lanes deep for the 64 they need). **It
+makes its own ``delta``:** the forward's output is one more operand, and at
+a query block's first pair each head's rowsum(``do`` * ``o``) goes, in f32,
+into a VMEM scratch the block's other pairs read (``do`` and ``o`` keep
+their block over the run, so they are fetched once): no XLA reduce and no
+(B, H/2, T, 128) array of it (:func:`_delta` stays ``causal_attention``'s).
+Where its accumulators do not fit VMEM :func:`latent_fits` refuses the
+shapes.
 
 Scores, softmax and statistics are f32; the MXU operands are in the
 operands' dtype (bf16 in training).
@@ -319,7 +337,8 @@ def _causal_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
 def _call(kernel, operands, outs, scratch, *, t: int, group: int,
           block: int, window: Optional[int], key_major: bool,
           interpret: bool, scale: float = LANES ** -0.5,
-          steps: Optional[int] = None, heads_parallel: bool = True):
+          steps: Optional[int] = None, heads_parallel: bool = True,
+          v_block: int = 0):
     """``operands`` / ``outs``: (array or shape-dtype, kind) with kind "q"
     (a group's query lanes, by query block), "kv" (one key-value head, by
     key block), "kv_all" (one key-value head's whole sequence) or "stats"
@@ -328,8 +347,9 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
     value lanes), "q_rope" (the group's rotary query lanes, one tile),
     "k_rope" (the shared rotary key's placement, by key block) and
     "k_rope_all" (its cotangent's whole sequence, the same block for every
-    head). ``steps``: the grid's head axis, where it is not the key-value
-    heads of the second operand."""
+    head) and "v_group" (the group's value lanes, ``v_block`` column blocks
+    into an array that holds the keys' lanes first). ``steps``: the grid's
+    head axis, where it is not the key-value heads of the second operand."""
     b = operands[1][0].shape[0]
     g = steps or operands[1][0].shape[2] // LANES
     table = band_pairs(t // block, block, window, key_major)
@@ -355,6 +375,9 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
                                lambda i, j, p, qi, ki, fi, la: (i, ki[p], 0)),
         "k_rope_all": pl.BlockSpec((1, t, LANES),
                                    lambda i, j, p, qi, ki, fi, la: (i, 0, 0)),
+        "v_group": pl.BlockSpec((1, block, group * LANES),
+                                lambda i, j, p, qi, ki, fi, la:
+                                (i, ki[p], v_block + j)),
     }
     return pl.pallas_call(
         functools.partial(kernel, scale=scale, group=group,
@@ -488,8 +511,8 @@ def latent_fits(tokens: int, heads: int, nope: int, rope: int, value: int,
             + t * LANES * 4 * 3                 # dk_rope: one, and its output
             + (LATENT_HEADS + 1) * tile * 4     # dq's accumulators
             + 3 * 2 * (LATENT_HEADS + 1) * tile * itemsize   # q, do, dq
-            + 3 * 2 * LATENT_HEADS * tile * itemsize         # k, v, k_rope
-            + 2 * 2 * tile * 4                  # statistics, delta
+            + 4 * 2 * LATENT_HEADS * tile * itemsize         # k, v, k_rope, o
+            + (2 + LATENT_HEADS) * tile * 4     # statistics, the deltas
             + 4 * block * block * 4)            # scores, p, dp, ds
     if need > VMEM_LIMIT_BYTES:
         return (f"dk and dv of {t} tokens, two heads a step, need "
@@ -535,15 +558,16 @@ def _latent_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, qn_ref, qr_ref,
 
 
 def _latent_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, qn_ref, qr_ref,
-                       kn_ref, kr_ref, v_ref, do_ref, stats_ref, delta_ref,
+                       kn_ref, kr_ref, v_ref, do_ref, o_ref, stats_ref,
                        dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref, dqn_s,
-                       dqr_s, dkn_s, dkr_s, dv_s, *, scale: float, group: int,
-                       block: int, window: Optional[int]):
+                       dqr_s, dkn_s, dkr_s, dv_s, delta_s, *, scale: float,
+                       group: int, block: int, window: Optional[int]):
     """Every cotangent from one pass over the band, query-block-major:
-    ``dqn_s`` / ``dqr_s`` hold a query block over its key blocks, ``dkn_s``
-    / ``dv_s`` (T, group * 128) the step's heads' whole sequence over all
-    pairs, ``dkr_s`` (T, 128) the shared key's over all pairs and all
-    heads of a sample."""
+    ``dqn_s`` / ``dqr_s`` hold a query block over its key blocks, as
+    ``delta_s`` holds each head's rowsum(do * o) of it, on every lane;
+    ``dkn_s`` / ``dv_s`` (T, group * 128) the step's heads' whole sequence
+    over all pairs, ``dkr_s`` (T, 128) the shared key's over all pairs and
+    all heads of a sample."""
     j, p = pl.program_id(1), pl.program_id(2)
     start, end = p == 0, p == pl.num_programs(2) - 1
 
@@ -560,9 +584,14 @@ def _latent_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, qn_ref, qr_ref,
     def _():
         dqn_s[...] = jnp.zeros(dqn_s.shape, jnp.float32)
         dqr_s[...] = jnp.zeros(dqr_s.shape, jnp.float32)
+        for h in range(group):
+            delta_s[h] = jnp.broadcast_to(jnp.sum(
+                do_ref[0, :, _head(h)].astype(jnp.float32)
+                * o_ref[0, :, _head(h)].astype(jnp.float32),
+                axis=1, keepdims=True), (block, LANES))
 
     allowed = _allowed(qi_ref[p], ki_ref[p], block, window)
-    stats, delta = stats_ref[0, 0], delta_ref[0, 0]
+    stats = stats_ref[0, 0]
     keys = pl.ds(pl.multiple_of(ki_ref[p] * block, block), block)
     q_rope = qr_ref[0]
     half = jax.lax.broadcasted_iota(jnp.int32, (block, LANES), 1) \
@@ -576,7 +605,7 @@ def _latent_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, qn_ref, qr_ref,
         dp = jax.lax.dot_general(do, v_ref[0, :, _head(h)], _NT,
                                  preferred_element_type=jnp.float32)
         # cast to the operands' dtype once, and transpose the narrow copy
-        ds = (prob * (dp - delta[:, h:h + 1]) * scale).astype(k_nope.dtype)
+        ds = (prob * (dp - delta_s[h][:, :1]) * scale).astype(k_nope.dtype)
         dqn_s[:, _head(h)] += jnp.dot(ds, k_nope,
                                       preferred_element_type=jnp.float32)
         # the other head's half of k_rope is zeros: its lanes stay
@@ -612,76 +641,105 @@ def _placed(k_rope):
     return jnp.concatenate([k_rope, zeros, zeros, k_rope], axis=2)
 
 
-def _latent_fwd(q_nope, q_rope, k_nope, k_rope, v, block, interpret):
+def _keys_and_values(kv):
+    """The kernels' key and value operands, and the column block of
+    ``LATENT_HEADS`` heads at which the values start in theirs: ``kv`` is
+    one (B, T, H*256) array, every head's ``k_nope`` and then every head's
+    ``v``, read twice where it lies, or the pair ``(k_nope, v)``."""
+    if isinstance(kv, tuple):
+        return (*kv, 0)
+    return kv, kv, kv.shape[2] // (2 * LATENT_HEADS * LANES)
+
+
+def _pad_all(operands, block: int):
+    return jax.tree.map(lambda x: _padded(x, block), operands)
+
+
+def _latent_fwd(q, q_rope, kv, k_rope, block, interpret):
     group = LATENT_HEADS
+    k, v, v_block = _keys_and_values(kv)
+    (b, t, _), steps = q.shape, q_rope.shape[2] // LANES
+    out = jax.ShapeDtypeStruct((b, t, steps * group * LANES), v.dtype)
     rows = pltpu.VMEM((group, block, LANES), jnp.float32)
     return _call(
         _latent_fwd_kernel,
-        [(q_nope, "q"), (q_rope, "q_rope"), (k_nope, "kv_group"),
-         (_placed(k_rope), "k_rope"), (v, "kv_group")],
-        [(v, "q"), (_stats_like(q_nope, group), "stats")],
+        [(q, "q"), (q_rope, "q_rope"), (k, "kv_group"),
+         (_placed(k_rope), "k_rope"), (v, "v_group")],
+        [(out, "q"), (_stats_like(out, group), "stats")],
         [rows, rows, pltpu.VMEM((block, group * LANES), jnp.float32)],
-        t=q_nope.shape[1], group=group, block=block, window=None,
-        key_major=False, interpret=interpret, scale=LATENT_SCALE,
-        steps=q_nope.shape[2] // (group * LANES))
+        t=t, group=group, block=block, window=None, key_major=False,
+        interpret=interpret, scale=LATENT_SCALE, steps=steps,
+        v_block=v_block)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def latent_attention(q_nope, q_rope, k_nope, k_rope, v, block: int = BLOCK,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def latent_attention(q, q_rope, kv, k_rope, block: int = BLOCK,
                      interpret: bool = False):
     """softmax((q_nope k_nope^T + q_rope k_rope^T) / sqrt(192), key <=
     query) v, head ``h`` reading its own ``k_nope`` and ``v`` and the one
     ``k_rope``.
 
-    q_nope, k_nope, v: (B, T, H*128); q_rope: (B, T, H*64), head-major;
-    k_rope: (B, T, 64). Returns (B, T, H*128). ``T`` is padded as
+    q: (B, T, H*128) or wider: every head's ``nope`` lanes first, whatever
+    follows them (``q_b``'s output, the unrotated ``rope`` lanes last) is
+    not read and gets a cotangent of noughts; q_rope: (B, T, H*64), rotated,
+    head-major; kv: (B, T, H*256), every head's ``k_nope`` and then every
+    head's ``v``, or the pair ``(k_nope, v)`` of (B, T, H*128) each;
+    k_rope: (B, T, 64), rotated. Returns (B, T, H*128). ``T`` is padded as
     :func:`causal_attention` pads it.
     """
-    t = q_nope.shape[1]
-    out, _ = _latent_fwd(
-        *(_padded(x, block) for x in (q_nope, q_rope, k_nope, k_rope, v)),
-        block, interpret)
+    t = q.shape[1]
+    out, _ = _latent_fwd(*_pad_all((q, q_rope, kv, k_rope), block), block,
+                         interpret)
     return out[:, :t]
 
 
-def _latent_vjp_fwd(q_nope, q_rope, k_nope, k_rope, v, block, interpret):
-    t = q_nope.shape[1]
-    out, stats = _latent_fwd(
-        *(_padded(x, block) for x in (q_nope, q_rope, k_nope, k_rope, v)),
-        block, interpret)
+def _latent_vjp_fwd(q, q_rope, kv, k_rope, block, interpret):
+    t = q.shape[1]
+    out, stats = _latent_fwd(*_pad_all((q, q_rope, kv, k_rope), block),
+                             block, interpret)
     out = checkpoint_name(out[:, :t], "attn_out")
     stats = checkpoint_name(stats, "attn_stats")
-    return out, (q_nope, q_rope, k_nope, k_rope, v, out, stats)
+    return out, (q, q_rope, kv, k_rope, out, stats)
 
 
 def _latent_vjp_bwd(block, interpret, res, dout):
-    q_nope, q_rope, k_nope, k_rope, v, out, stats = res
-    b, t, _ = q_nope.shape
-    group = LATENT_HEADS
-    delta = _delta(dout, out, group, block)
-    q_nope, q_rope, k_nope, k_rope, v, dout = (
-        _padded(x, block) for x in (q_nope, q_rope, k_nope, k_rope, v, dout))
-    padded = q_nope.shape[1]
+    q, q_rope, kv, k_rope, out, stats = res
+    (_, t, width), group = q.shape, LATENT_HEADS
+    q, q_rope, kv, k_rope, out, dout = _pad_all(
+        (q, q_rope, kv, k_rope, out, dout), block)
+    k, v, v_block = _keys_and_values(kv)
+    b, padded, _ = out.shape
+    dq_like = jax.ShapeDtypeStruct(out.shape, q.dtype)
+    dkv_like = jax.ShapeDtypeStruct(out.shape, k.dtype)
     dk_rope_like = jax.ShapeDtypeStruct((b, padded, LANES), jnp.float32)
     wide = pltpu.VMEM((padded, group * LANES), jnp.float32)
     dq_nope, dq_rope, dk_nope, dk_rope, dv = _call(
         _latent_bwd_kernel,
-        [(q_nope, "q"), (q_rope, "q_rope"), (k_nope, "kv_group"),
-         (_placed(k_rope), "k_rope"), (v, "kv_group"), (dout, "q"),
-         (stats, "stats"), (delta, "stats")],
-        [(q_nope, "q"), (q_rope, "q_rope"), (k_nope, "kv_group_all"),
-         (dk_rope_like, "k_rope_all"), (v, "kv_group_all")],
+        [(q, "q"), (q_rope, "q_rope"), (k, "kv_group"),
+         (_placed(k_rope), "k_rope"), (v, "v_group"), (dout, "q"),
+         (out, "q"), (stats, "stats")],
+        [(dq_like, "q"), (q_rope, "q_rope"), (dkv_like, "kv_group_all"),
+         (dk_rope_like, "k_rope_all"), (dkv_like, "kv_group_all")],
         [pltpu.VMEM((block, group * LANES), jnp.float32),
          pltpu.VMEM((block, LANES), jnp.float32), wide,
-         pltpu.VMEM((padded, LANES), jnp.float32), wide],
+         pltpu.VMEM((padded, LANES), jnp.float32), wide,
+         pltpu.VMEM((group, block, LANES), jnp.float32)],
         t=padded, group=group, block=block, window=None, key_major=False,
         interpret=interpret, scale=LATENT_SCALE,
-        steps=q_nope.shape[2] // (group * LANES), heads_parallel=False)
+        steps=q_rope.shape[2] // LANES, heads_parallel=False,
+        v_block=v_block)
+    # the lanes of q after the heads' nope parts are not this call's: one
+    # pad of noughts, which XLA fuses into the sum with the rotary's
+    dq = dq_nope[:, :t]
+    if width > dq.shape[2]:
+        dq = jnp.pad(dq, ((0, 0), (0, 0), (0, width - dq.shape[2])))
+    dkv = dk_nope[:, :t], dv[:, :t]
+    if not isinstance(kv, tuple):
+        dkv = jnp.concatenate(dkv, axis=2)
     # the shared key's cotangent: the two heads' halves of every step's sum
     dk_rope = (dk_rope[..., :ROPE_LANES]
                + dk_rope[..., ROPE_LANES:]).astype(k_rope.dtype)
-    return (dq_nope[:, :t], dq_rope[:, :t], dk_nope[:, :t], dk_rope[:, :t],
-            dv[:, :t])
+    return dq, dq_rope[:, :t], dkv, dk_rope[:, :t]
 
 
 latent_attention.defvjp(_latent_vjp_fwd, _latent_vjp_bwd)

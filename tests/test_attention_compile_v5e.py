@@ -200,8 +200,53 @@ def test_the_per_head_pass_compiles_in_both_directions(
     lowered.compile()
 
 
+@pytest.fixture(scope="module")
+def latent_layer(one_chip):
+    """One latent layer's gradient at ``joyaiflash``'s cell's shapes,
+    compiled once for the two tests below (about 20 s): the configuration
+    and the optimised HLO."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from dalle_tpu import config
+    from dalle_tpu.models import attention, sparse_lm
+
+    cfg = config.joyaiflash_model_config()
+    mod = sparse_lm.LatentAttention(cfg, name="attn")
+    a = jax.ShapeDtypeStruct((1, cfg.total_seq_len, cfg.hidden_size),
+                             jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: mod.init(jax.random.PRNGKey(0),
+                                        jnp.zeros(a.shape, a.dtype))))
+
+    def loss(p, a):
+        return jnp.sum(mod.apply(p, a).astype(jnp.float32) ** 2)
+
+    # as ``monkeypatch`` and ``no_persistent_cache`` do for one test
+    by_default = attention._pallas_by_default
+    cached = jax.config.jax_enable_compilation_cache
+    attention._pallas_by_default = lambda: True
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, a).compile().as_text()
+    finally:
+        attention._pallas_by_default = by_default
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    return cfg, text
+
+
+def _custom_calls(text):
+    """The optimised HLO's Mosaic calls: (name without its number, line)."""
+    return [(re.match(r"\s*(?:ROOT )?%?([\w\-]+?)[.\d]* =", line).group(1),
+             line) for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
 def test_latent_attentions_rotary_is_one_pass_where_q_b_wrote_it(
-        one_chip, no_persistent_cache, monkeypatch):
+        latent_layer):
     """One latent layer's gradient at ``joyaiflash``'s cell's shapes: the
     rotary of the queries' 2 048 lanes and of the one 64-wide key is two
     Mosaic calls a direction named ``rotary`` (not ``attn``: the attention
@@ -211,29 +256,12 @@ def test_latent_attentions_rotary_is_one_pass_where_q_b_wrote_it(
     tables of one lane tile: no cosine or sine as wide as the rotated
     array, no padded shifted copy of it (``rotary_interleaved_lanes``'
     (..., 2 047) slices), no copy of the columns in front of a kernel."""
-    from dalle_tpu import config
-    from dalle_tpu.models import attention, sparse_lm
+    from dalle_tpu.models import sparse_lm
 
-    monkeypatch.setattr(attention, "_pallas_by_default", lambda: True)
-    cfg = config.joyaiflash_model_config()
-    mod = sparse_lm.LatentAttention(cfg, name="attn")
+    cfg, text = latent_layer
     t, heads, rope = cfg.total_seq_len, cfg.num_heads, cfg.qk_rope_head_dim
-    a = jax.ShapeDtypeStruct((1, t, cfg.hidden_size), jnp.bfloat16,
-                             sharding=one_chip)
-    params = jax.tree.map(
-        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
-        jax.eval_shape(lambda: mod.init(jax.random.PRNGKey(0),
-                                        jnp.zeros(a.shape, a.dtype))))
-
-    def loss(p, a):
-        return jnp.sum(mod.apply(p, a).astype(jnp.float32) ** 2)
-
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, a).compile().as_text()
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    names = sorted(re.match(r"\s*(?:ROOT )?%?([\w\-]+?)[.\d]* =", line).group(1)
-                   for line in calls)
+    names = sorted(name for name, _ in _custom_calls(text))
+    calls = [line for name, line in _custom_calls(text) if name == "rotary"]
     # beside them the latent kernels' forward and their one backward
     assert names.count("rotary") == 4 and len(names) == 6, names
     assert all("attn" in n for n in names if n != "rotary"), names
@@ -257,6 +285,59 @@ def test_latent_attentions_rotary_is_one_pass_where_q_b_wrote_it(
              if m and any(f"bf16[1,{t},{lanes}]" in m.group(1)
                           for lanes in (heads * rope, rope))]
     assert not moved, moved
+
+
+def test_latent_attentions_kernels_read_what_q_b_and_kv_b_wrote(
+        latent_layer):
+    """The same compile: the forward and the backward kernel take ``q_b``'s
+    (1, 8 192, 6 144) and ``kv_b``'s (1, 8 192, 8 192) outputs themselves,
+    the second twice (``k_nope`` at column block ``j``, ``v`` at ``16 +
+    j``), so XLA cuts no ``q_nope``, ``k_nope`` or ``v`` out of them (no
+    ``slice`` or ``copy`` of either, in any fusion); the backward takes the
+    forward's output where ``delta`` = rowsum(do * o) stood, and nothing
+    holds that in the statistics' (1, 16, 8 192, 128) layout but the
+    statistics; ``dk_nope`` and ``dv`` go side by side into ``kv_b``'s
+    two backward products as the kernel wrote them (no concatenate is a
+    row of its own)."""
+    from dalle_tpu.models import sparse_lm
+
+    cfg, text = latent_layer
+    t, heads = cfg.total_seq_len, cfg.num_heads
+    q_b = f"bf16[1,{t},{heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)}]"
+    kv_b = f"bf16[1,{t},{heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)}]"
+    out = f"bf16[1,{t},{heads * cfg.v_head_dim}]"
+    fwd, bwd = (line for name, line in _custom_calls(text)
+                if name != "rotary")
+    for call in (fwd, bwd):
+        operands = call.split("operand_layout_constraints={")[1]
+        assert operands.count(q_b + "{") == 1, operands
+        assert operands.count(kv_b + "{") == 2, operands
+    # q_nope, k_nope, v (and dout, out in the backward) as arrays of
+    # their own would be operands of v's width: the forward has none
+    assert out not in fwd.split("operand_layout_constraints={")[1]
+    assert bwd.split("operand_layout_constraints={")[1].count(out) == 2
+    # whatever the entry computation holds of either width is read by
+    # Mosaic calls and by nothing else: no slice, copy or fusion
+    entry = text.split("ENTRY")[1].splitlines()
+    wrote = [m.group(1) for m in (re.match(
+        r"\s*(?:ROOT )?(%[\w.\-]+) = (\S+?)\{", line) for line in entry)
+        if m and m.group(2) in (q_b, kv_b)]
+    assert len(wrote) == 2, wrote
+    readers = {line.strip() for line in entry for name in wrote
+               if re.search(re.escape(name) + r"[,)]",
+                            line.split(" = ", 1)[-1])}
+    # the queries' rotary pass and the two kernels
+    assert len(readers) == 3 and all(
+        'custom_call_target="tpu_custom_call"' in line for line in readers), \
+        [line[:160] for line in readers]
+    stats = f"f32[1,{heads // 2},{t},128]"
+    holds = [line.strip()[:120] for line in text.splitlines()
+             if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = " + re.escape(stats), line)]
+    assert len(holds) == 1 and "get-tuple-element" in holds[0], holds
+    assert " concatenate(" not in text.split("ENTRY")[1]
+    key = (t, heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+           cfg.v_head_dim)
+    assert sparse_lm._LATENT_OPERANDS[key] is None
 
 
 @pytest.mark.parametrize("batch, lanes, before", [

@@ -127,6 +127,10 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(with_kernels,
         "dense XLA lowering (no Mosaic backend), rotary (XLA: no Mosaic "
         "backend)")
     assert "weight 0.3" in said["mtp_layout"]
+    assert said["attn_operands"] == (
+        "latent: q_nope, k_nope, v read where q_b and kv_b wrote them, "
+        "delta in the backward kernel: 4 of 4 layers" if with_kernels else
+        "sliced: no Mosaic backend")
 
 
 @pytest.mark.parametrize("state, text_seq_len, rotary", [
@@ -183,8 +187,18 @@ def test_latent_attention_on_a_mesh_is_the_one_device_layer(axes,
             lambda p, a: jnp.sum(mod.apply(p, a) ** 2), (0, 1)))(params, a)
 
     monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
-    value, grads = value_and_grads(make_mesh(**axes))
+    monkeypatch.setattr(sparse_lm, "_LATENT_OPERANDS", {})
+    mesh = make_mesh(**axes)
+    value, grads = value_and_grads(mesh)
     assert set(sparse_lm._HEAD_PASSES.values()) == {None}
+    # the kernels too: a shard's heads of each part as arrays of their own
+    # where tp splits them, else q_b's and kv_b's outputs where they lie
+    said = sparse_lm.engagement_records(cfg, mesh)["attn_operands"]
+    assert said == (
+        "sliced: a mesh axis splits the heads of each part, not the lanes "
+        "of q_b's and kv_b's outputs" if axes["tp"] > 1 else
+        "latent: q_nope, k_nope, v read where q_b and kv_b wrote them, "
+        "delta in the backward kernel: 4 of 4 layers"), said
     want, want_grads = value_and_grads(None)
     assert float(value) == pytest.approx(float(want), rel=5e-6)
     for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
@@ -297,14 +311,19 @@ def test_the_latent_kernels_are_the_dense_lowering(tokens, heads, block):
     k_rope = jax.random.normal(keys[5], shape(64))
     operands = (q_nope, q_rope, k_nope, k_rope, v)
     assert kernels.latent_fits(tokens, heads, 128, 64, 128, 4) is None
+
+    def latent(q_nope, q_rope, k_nope, k_rope, v):
+        # the parts as arrays of their own, each read at offset 0
+        return kernels.latent_attention(q_nope, q_rope, (k_nope, v), k_rope,
+                                        block, True)
+
     with jax.default_matmul_precision("highest"):
-        out, grads = jax.value_and_grad(lambda *a: jnp.sum(
-            kernels.latent_attention(*a, block, True) * w),
-            argnums=range(5))(*operands)
+        out, grads = jax.value_and_grad(lambda *a: jnp.sum(latent(*a) * w),
+                                        argnums=range(5))(*operands)
         want, want_grads = jax.value_and_grad(lambda *a: jnp.sum(
             sparse_lm.dense_latent_attention(*a) * w),
             argnums=range(5))(*operands)
-        values = kernels.latent_attention(*operands, block, True)
+        values = latent(*operands)
     np.testing.assert_allclose(
         values, sparse_lm.dense_latent_attention(*operands), atol=2e-5)
     assert float(out) == pytest.approx(float(want), rel=1e-5)
@@ -312,6 +331,127 @@ def test_the_latent_kernels_are_the_dense_lowering(tokens, heads, block):
                           grads, want_grads):
         assert g.shape == r.shape and rel_l2(g, r) < 2e-6, name
     assert grads[3].shape == (2, tokens, 64)
+
+
+def _whole_operands(tokens, heads, dtype, batch=2):
+    """``q_b``'s and ``kv_b``'s outputs as the layer lays them out (every
+    head's nope lanes, then the unrotated rope lanes; every head's k_nope,
+    then every head's v), the rotated parts, and a cotangent."""
+    keys = jax.random.split(jax.random.PRNGKey(tokens + heads), 5)
+    widths = (heads * 192, heads * 64, heads * 256, 64, heads * 128)
+    return [jax.random.normal(k, (batch, tokens, w)).astype(dtype)
+            for k, w in zip(keys, widths)]
+
+
+@pytest.mark.parametrize("tokens, heads, block, dtype", [
+    (256, 4, 128, jnp.float32),             # two pairs of heads
+    (200, 6, 128, jnp.float32),             # three, a length that pads
+    (3 * 128 + 7, 4, 128, jnp.bfloat16),    # the cell's dtype
+])
+def test_the_latent_kernels_read_the_projections_outputs_where_they_lie(
+        tokens, heads, block, dtype):
+    """``q`` and ``kv`` whole (``q_nope`` column block ``j``, ``k_nope``
+    column block ``j`` and ``v`` column block ``H/2 + j`` of 256 lanes)
+    against the same entry handed the three slices at offset 0: the same
+    tiles reach the same kernel bodies, so the values and every cotangent
+    agree bit for bit; the cotangents come back in the operands' form, and
+    ``q``'s unrotated rope lanes, which this call does not read, get
+    exactly nought."""
+    q, q_rope, kv, k_rope, w = _whole_operands(tokens, heads, dtype)
+    lanes = heads * 128
+
+    def whole(q, q_rope, kv, k_rope):
+        return kernels.latent_attention(q, q_rope, kv, k_rope, block, True)
+
+    def sliced(q, q_rope, kv, k_rope):
+        return kernels.latent_attention(
+            q[..., :lanes], q_rope, (kv[..., :lanes], kv[..., lanes:]),
+            k_rope, block, True)
+
+    both = lambda f: jax.value_and_grad(lambda *a: jnp.sum(
+        (f(*a) * w).astype(jnp.float32)), argnums=range(4))(
+            q, q_rope, kv, k_rope)
+    np.testing.assert_array_equal(whole(q, q_rope, kv, k_rope),
+                                  sliced(q, q_rope, kv, k_rope))
+    (value, grads), (want, want_grads) = both(whole), both(sliced)
+    assert float(value) == float(want)
+    for name, g, r in zip(("q", "q_rope", "kv", "k_rope"), grads,
+                          want_grads):
+        assert g.shape == r.shape and g.dtype == dtype, name
+        np.testing.assert_array_equal(g, r, err_msg=name)
+    assert grads[0].shape == q.shape and grads[2].shape == kv.shape
+    assert not np.any(np.asarray(grads[0][..., lanes:], np.float32))
+    assert np.any(np.asarray(grads[2][..., lanes:], np.float32))
+
+
+@pytest.mark.parametrize("tokens, heads, block", [(256, 4, 128),
+                                                  (200, 6, 128)])
+def test_the_backward_kernels_own_delta_gives_the_dense_gradient(
+        tokens, heads, block):
+    """The whole operands' cotangents against the plain gradient of the
+    dense lowering in f32: ``delta`` = rowsum(do * o) is summed inside the
+    backward kernel, a head and query block, and differs from any other
+    order of the 128 products by f32 rounding (the same 2e-6 the kernels
+    with ``delta`` as XLA code were held to)."""
+    q, q_rope, kv, k_rope, w = _whole_operands(tokens, heads, jnp.float32)
+    lanes = heads * 128
+
+    def dense(q, q_rope, kv, k_rope):
+        return sparse_lm.dense_latent_attention(
+            q[..., :lanes], q_rope, kv[..., :lanes], k_rope, kv[..., lanes:])
+
+    with jax.default_matmul_precision("highest"):
+        grads, want_grads = (jax.grad(lambda *a: jnp.sum(f(*a) * w),
+                                      argnums=range(4))(q, q_rope, kv, k_rope)
+                             for f in (lambda *a: kernels.latent_attention(
+                                 *a, block, True), dense))
+    for name, g, r in zip(("q", "q_rope", "kv", "k_rope"), grads,
+                          want_grads):
+        assert g.shape == r.shape and rel_l2(g, r) < 2e-6, name
+
+
+def test_the_grad_step_slices_neither_q_b_nor_kv_b(monkeypatch):
+    """The tiny model's gradient lowered for a TPU from here (the Mosaic
+    dispatch forced; nothing runs): no ``stablehlo.slice`` of an array as
+    wide as ``q_b``'s or ``kv_b``'s output is traced, where the program
+    before PR 46 cut ``q_nope`` and ``k_nope`` / ``v`` out of them once a
+    layer and direction, and the record says every layer's kernels read
+    them where they lie."""
+    import re
+    cfg = JoyAILMConfig(**dict(TINY, **KERNEL_WIDTHS))
+    cfg.validate()
+    monkeypatch.setattr(attention, "_pallas_by_default", lambda: True)
+    monkeypatch.setattr(sparse_lm, "_LATENT_OPERANDS", {})
+    model = sparse_lm.build(cfg)
+    params = jax.eval_shape(
+        lambda: sparse_lm.init_params(model, jax.random.PRNGKey(0)))
+    text, image = _batch(cfg)
+    lowered = jax.jit(jax.grad(
+        lambda p: model.apply(p, text, image)[0])).trace(params).lower(
+            lowering_platforms=("tpu",)).as_text()
+    kernels_in = re.findall(r'kernel_name = "(_latent_\w+)"', lowered)
+    assert kernels_in.count("_latent_fwd_kernel") == 4 \
+        and kernels_in.count("_latent_bwd_kernel") == 4, kernels_in
+    for width in (cfg.num_heads * 192, cfg.num_heads * 256):
+        assert f"x{width}xf32>" in lowered
+        cut = re.findall(r"stablehlo\.slice[^\n]*: \(tensor<(?:\d+x)*%dx\w+>\)"
+                         % width, lowered)
+        assert not cut, cut
+    assert sparse_lm.engagement_records(cfg)["attn_operands"] == (
+        "latent: q_nope, k_nope, v read where q_b and kv_b wrote them, "
+        "delta in the backward kernel: 4 of 4 layers")
+    # and with the parts sliced first, as a tp axis has them, it shows
+    def cut_first(q, q_rope, kv, k_rope, **widths):
+        q_nope, (k_nope, v) = sparse_lm._latent_parts(q, kv, 512)
+        return sparse_lm.dense_latent_attention(q_nope, q_rope, k_nope,
+                                                k_rope, v)
+
+    monkeypatch.setattr(sparse_lm, "_latent_shard", cut_first)
+    sliced = jax.jit(jax.grad(
+        lambda p: model.apply(p, text, image)[0])).trace(params).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert re.findall(r"stablehlo\.slice[^\n]*: \(tensor<(?:\d+x)*1024x\w+>\)",
+                      sliced)
 
 
 def test_latent_fits_says_why_not():
