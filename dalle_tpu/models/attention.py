@@ -30,8 +30,7 @@ runs in float32, with activations in bfloat16 for the MXU.
 from __future__ import annotations
 
 import functools
-import logging
-from typing import Dict, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,37 +44,19 @@ from dalle_tpu.config import (
     ATTN_FULL,
     SP_ULYSSES,
 )
-from dalle_tpu.parallel.mesh import LANES_SPEC, per_shard
-
-logger = logging.getLogger(__name__)
+from dalle_tpu.ops.pallas import lowering
+from dalle_tpu.parallel.mesh import LANES_SPEC
 
 NEG_INF = -1e9  # softmax mask fill; safe in fp32 accumulation
 
 # Tests set this True to route the model through the fused Pallas kernels
 # in interpret mode on CPU (the dispatchers otherwise pick the kernels
-# only on a real TPU backend).
+# only on a real TPU backend). Read by ops/pallas/lowering.py alone.
 _PALLAS_INTERPRET = False
 
 
-def _pallas_by_default() -> bool:
-    return jax.default_backend() == "tpu" or _PALLAS_INTERPRET
-
-
-@functools.lru_cache(maxsize=None)
-def log_kernel_choice(site: str, kernel: bool, why: str) -> None:
-    """Say which lowering a shape predicate picked — once per distinct
-    (site, choice, reason), at trace time — so a run that quietly gave a
-    fused kernel up for the XLA lowering shows it in its log."""
-    logger.info("%s: %s (%s)", site,
-                "Pallas kernel" if kernel else "XLA lowering", why)
-
-
-# (attn_type, head_dim, local width, tokens, text_len) -> whether the
-# dispatcher gave a traced call of those local shapes the Pallas kernel.
-# Keyed by everything the choice is made from, so that another model or
-# shape traced in the same process (eval, a second config) neither
-# vouches for this one nor taints it: what attn_layout_record reads.
-_KERNEL_CHOICES: Dict[Tuple[str, int, int, int, int], bool] = {}
+def _zoo_site(attn_type: str) -> str:
+    return f"{attn_type} attention"
 
 
 def attn_layout_record(cfg, mesh=None) -> str:
@@ -95,8 +76,8 @@ def attn_layout_record(cfg, mesh=None) -> str:
             shards *= mesh.shape.get("sp", 1)
     width = cfg.heads * cfg.head_dim // shards
     sched = cfg.layer_schedule()
-    on = sum(_KERNEL_CHOICES.get(
-        (t, cfg.head_dim, width, cfg.total_seq_len, cfg.text_seq_len), False)
+    on = sum(lowering.why_not(_zoo_site(t), (
+        cfg.head_dim, width, cfg.total_seq_len, cfg.text_seq_len)) is None
         for _, t in sched)
     return f"lane-dense {LANES}: {on} of {len(sched)} layers"
 
@@ -276,13 +257,14 @@ def _as_lanes(fn, q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
 
 
 def _fused_lanes(q: jax.Array, k: jax.Array, v: jax.Array, head_dim: int,
-                 attn_type: str, text_len: int, grid: int, conv_kernel: int,
-                 interpret: bool) -> jax.Array:
+                 attn_type: str, text_len: int, grid: int,
+                 conv_kernel: int) -> jax.Array:
     """A zoo layer's Pallas kernel on (B, T, H*d) operands: the line
     kernel for the axial types, the window kernel for conv_like / full."""
     from dalle_tpu.ops.pallas.attention_kernels import (line_attention,
                                                         window_attention)
 
+    interpret = lowering.interpret()
     if attn_type in (ATTN_AXIAL_ROW, ATTN_AXIAL_COL):
         return line_attention(q, k, v, head_dim, text_len, grid,
                               attn_type == ATTN_AXIAL_COL, interpret)
@@ -290,38 +272,15 @@ def _fused_lanes(q: jax.Array, k: jax.Array, v: jax.Array, head_dim: int,
     return window_attention(q, k, v, head_dim, text_len, grid, hw, interpret)
 
 
-def axial_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
-                          attn_type: str, text_len: int, grid: int,
-                          interpret: bool = False) -> jax.Array:
-    """Pallas fused axial attention: scores and probabilities live in VMEM
-    only (flash-attention style, with a custom backward); the XLA lowering
-    of the same math materialized them in HBM at ~31% of the train step.
-
-    Operands are (B, T, H, d) here for the tests' and the XLA lowering's
-    sake; the kernel reads them as the (B, T, H*d) array they are in
-    memory (ops/pallas/attention_kernels.py: no heads-major copy, text and
-    image rows in one call). ``interpret=True`` runs the kernel on CPU
-    for tests."""
-    return _as_lanes(
-        lambda *qkv: _fused_lanes(*qkv, q.shape[-1], attn_type, text_len,
-                                  grid, 0, interpret), q, k, v)
-
-
 def axial_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    attn_type: str, text_len: int, grid: int,
-                    use_pallas: Optional[bool] = None) -> jax.Array:
-    """Axial row/col attention over [text || image] sequence.
+                    attn_type: str, text_len: int, grid: int) -> jax.Array:
+    """Axial row/col attention over [text || image] sequence, the XLA
+    lowering.
 
     q/k/v: (B, T, H, d) with T = text_len + grid*grid. The image block is
     viewed as a (grid, grid) raster; rows (axial_row) or columns (axial_col)
     become a batch dimension so XLA sees large, regular batched matmuls.
-    ``use_pallas=None`` auto-selects the fused VMEM kernel on TPU.
     """
-    if use_pallas is None:
-        use_pallas = _pallas_by_default()
-    if use_pallas:
-        return axial_attention_fused(q, k, v, attn_type, text_len, grid,
-                                     interpret=_PALLAS_INTERPRET)
     b, t, h, d = q.shape
     q_t, k_t, v_t = (x[:, :text_len] for x in (q, k, v))
     out_t = _text_causal(q_t, k_t, v_t)
@@ -343,20 +302,6 @@ def axial_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # named for the save policies (see dense_zoo_attention)
     return checkpoint_name(jnp.concatenate([out_t, out_i], axis=1),
                            "attn_ctx")
-
-
-def window_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
-                           attn_type: str, text_len: int, grid: int,
-                           conv_kernel: int = 11,
-                           interpret: bool = False) -> jax.Array:
-    """Pallas fused conv_like/full attention (see axial_attention_fused for
-    the layout): image queries attend to the text prefix plus the exact
-    conv window (or, for 'full', every earlier token) with scores in VMEM
-    only — the dense lowering materialized (B, H, T, T) f32 scores in HBM
-    for the flagship's final 'w_conv' layer (reference task.py:63-65)."""
-    return _as_lanes(
-        lambda *qkv: _fused_lanes(*qkv, q.shape[-1], attn_type, text_len,
-                                  grid, conv_kernel, interpret), q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -383,13 +328,32 @@ def zoo_attention_lanes(q: jax.Array, k: jax.Array, v: jax.Array, *,
     arrays: fast paths where available. With a ``mesh`` of more than one
     device the fused kernels run per shard (batch over dp/fsdp, lanes —
     whole heads — over tp; parallel/mesh.per_shard), under the caller's
-    ``scope`` so that they keep its name there."""
+    ``scope`` so that they keep its name there: the lane-dense kernel
+    where ``attention_kernels.lane_dense_fits`` on a shard's local shapes,
+    else the XLA lowering."""
     kw = dict(head_dim=head_dim, attn_type=attn_type, text_len=text_len,
               grid=grid, conv_kernel=conv_kernel)
-    if not _pallas_by_default():
-        return _xla_on_lanes(q, k, v, **kw)
-    return per_shard(functools.partial(_fused_zoo_attention, **kw), mesh,
-                     (LANES_SPEC,) * 3, LANES_SPEC, scope=scope)(q, k, v)
+    return lowering.site(
+        _zoo_site(attn_type), functools.partial(_lane_dense, **kw),
+        functools.partial(_fused_lanes, **kw),
+        functools.partial(_xla_on_lanes, **kw),
+        mesh, (LANES_SPEC,) * 3, LANES_SPEC, scope)(q, k, v)
+
+
+def _lane_dense(q: jax.Array, k: jax.Array, v: jax.Array, *, head_dim: int,
+                attn_type: str, text_len: int, grid: int,
+                conv_kernel: int) -> bool:
+    """Whether one shard's (local) shapes take the lane-dense kernel."""
+    from dalle_tpu.ops.pallas.attention_kernels import (LANES,
+                                                        lane_dense_fits)
+
+    _, t, width = q.shape
+    why_not = lane_dense_fits(width, head_dim, t, text_len,
+                              q.dtype.itemsize)
+    return lowering.chose(
+        _zoo_site(attn_type), (head_dim, width, t, text_len), why_not,
+        why_not or f"local q{tuple(q.shape)}: {LANES // head_dim} heads to "
+        f"a {LANES}-lane tile, text {text_len} + grid {grid} in one call")
 
 
 def _xla_on_lanes(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -400,34 +364,8 @@ def _xla_on_lanes(q: jax.Array, k: jax.Array, v: jax.Array, *,
     q, k, v = (x.reshape(b, t, width // head_dim, head_dim)
                for x in (q, k, v))
     if attn_type in (ATTN_AXIAL_ROW, ATTN_AXIAL_COL):
-        out = axial_attention(q, k, v, attn_type, text_len, grid,
-                              use_pallas=False)
+        out = axial_attention(q, k, v, attn_type, text_len, grid)
     else:
         out = dense_zoo_attention(q, k, v, attn_type, text_len, grid,
                                   conv_kernel)
     return out.reshape(b, t, width)
-
-
-def _fused_zoo_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                         head_dim: int, attn_type: str, text_len: int,
-                         grid: int, conv_kernel: int) -> jax.Array:
-    """The kernel dispatch on one shard's (local) shapes: the lane-dense
-    kernel where ``attention_kernels.lane_dense_fits``, else the XLA
-    lowering."""
-    from dalle_tpu.ops.pallas.attention_kernels import (LANES,
-                                                        lane_dense_fits)
-
-    _, t, width = q.shape
-    why_not = lane_dense_fits(width, head_dim, t, text_len,
-                              q.dtype.itemsize)
-    _KERNEL_CHOICES[attn_type, head_dim, width, t, text_len] = why_not is None
-    log_kernel_choice(
-        f"{attn_type} attention", why_not is None,
-        why_not or f"local q{tuple(q.shape)}: {LANES // head_dim} heads to "
-        f"a {LANES}-lane tile, text {text_len} + grid {grid} in one call")
-    if why_not is not None:
-        return _xla_on_lanes(q, k, v, head_dim=head_dim, attn_type=attn_type,
-                             text_len=text_len, grid=grid,
-                             conv_kernel=conv_kernel)
-    return _fused_lanes(q, k, v, head_dim, attn_type, text_len, grid,
-                        conv_kernel, _PALLAS_INTERPRET)

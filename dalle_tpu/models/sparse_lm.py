@@ -124,20 +124,28 @@ their projections and the attention (the head norm where the
 configuration has one, the rotary where the layer has positions) is one
 pass on the lanes (ops/pallas/head_norm_kernels.py: no (B, T, H, d) array
 exists where it runs, and the rotary reads one head's (T, d) tables;
-:func:`head_norm_why_not` is the rule, and where it refuses the layer
-runs ``rms_norm`` on the reshape and ``attention.apply_rotary_lanes``):
+``head_norm_kernels.fits`` is the rule, and where it refuses the layer
+runs ``rms_norm`` on the reshape and ``attention.apply_rotary_lanes``:
+:func:`head_pass`):
 ``qk_norm[mosaic]`` under ``attn/qk_norm`` where there is a norm, the
 rotary of a window layer in it; ``rotary[mosaic]`` under ``attn/rotary``
 where there is none. Latent attention's rotary of interleaved pairs is
 such a pass of its own (``head_norm_kernels.pair_rotary``, the same name
 in a trace): the queries' rotary columns read where ``q_b`` wrote them,
 the one key where ``kv_a`` wrote it in a second small call, one lane
-tile's (T, 128) tables for two heads side by side; :func:`pair_rotary_why_not` is the rule, and where
-it refuses :func:`rotary_interleaved_lanes` runs with tables as wide as
-the array. The latent kernels likewise read ``q_nope``, ``k_nope`` and
+tile's (T, 128) tables for two heads side by side;
+``head_norm_kernels.pairs_fit`` is the rule, and where it refuses
+:func:`rotary_interleaved_lanes` runs with tables as wide as the array
+(:func:`pair_rotary`). The latent kernels likewise read ``q_nope``, ``k_nope`` and
 ``v`` as column blocks of ``q_b``'s and ``kv_b``'s outputs and make their
 backward's ``delta`` themselves (``attn_operands`` on the ``setup/warmup``
 row says so, or "sliced: <why>": :class:`LatentAttention`).
+
+Every one of those choices between a kernel and its XLA lowering is made,
+remembered and said through ops/pallas/lowering.py: a site here is a
+predicate, a kernel, a lowering and their specs, and
+:func:`engagement_records` asks that module's record what the traced calls
+took, by the keys the sites' own ``_*_key`` functions make.
 """
 
 from __future__ import annotations
@@ -158,27 +166,13 @@ from dalle_tpu.models import attention as attn_mod
 from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
 from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
 from dalle_tpu.ops.pallas import head_norm_kernels as head_norm
+from dalle_tpu.ops.pallas import lowering
 from dalle_tpu.ops.pallas import token_sum_kernels as token_sum
-from dalle_tpu.parallel.mesh import (LANES_SPEC, per_shard,
-                                     sum_over_manual_data_axes)
+from dalle_tpu.parallel.mesh import LANES_SPEC, sum_over_manual_data_axes
 
 # rows of the dispatch buffer over what a uniform router sends to the
 # held experts (tests shrink it to reach the dense lowering)
 ROWS_OVER_EXPECTED = 2.0
-
-# (kind, tokens, query lanes, key-value lanes) -> whether a traced call of
-# those local shapes took the blockwise kernel, and why its backward is the
-# dq and the dk/dv kernel (None where it is the one kernel a tile): what
-# attn_layout reads
-_KERNEL_CHOICES: Dict[Tuple[str, int, int, int],
-                      Tuple[bool, Optional[str]]] = {}
-
-
-# (tokens, lanes of the whole array, head_dim, normed, rotated) -> why the
-# last traced per-head work of that kind on such an array (the head norm,
-# the rotary, or both) did not take the one pass on the lanes on its local
-# shapes, None where it did: what attn_layout reads
-_HEAD_PASSES: Dict[Tuple[int, int, int, bool, bool], Optional[str]] = {}
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -188,51 +182,59 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
         return (y * scale).astype(x.dtype)
 
 
-def head_norm_why_not(tokens: int, width: int,
-                      head_dim: int) -> Optional[str]:
-    """Why the work on each head's ``head_dim`` lanes of (tokens, width)
-    samples (the RMS norm, the rotary) is the reshape to heads and
-    :func:`rms_norm`, and ``attention.apply_rotary_lanes`` with tables as
-    wide as the array, and not one pass on the lanes
-    (ops/pallas/head_norm_kernels.py); None where it is the pass."""
-    if not attn_mod._pallas_by_default():
-        return "no Mosaic backend"
-    return head_norm.fits(tokens, width, head_dim)
+def _head_pass_site(norm: bool, rotary: bool) -> str:
+    return " + ".join(["head norm"] * norm + ["rotary"] * rotary)
 
 
-def _per_head_shard(x, scale=None, *, eps: float, head_dim: int, lanes: int,
-                    theta: Optional[float]):
-    """One shard's work on each head of x (B, T, H*d) between a projection
-    and the attention: the RMS norm over the head's d lanes where there is
-    a ``scale`` (one vector for all heads), then the rotary of positions
-    0..T-1 where there is a ``theta``; one pass of the kernel that reads
-    one head's tables where it fits, else the two expressions it replaces.
-    ``lanes``: the whole array's, of which a ``tp`` shard holds a part."""
-    b, t, width = x.shape
+def _head_pass_key(tokens: int, lanes: int, head_dim: int, tp: int = 1):
+    """What the record knows a per-head pass by: a sample's tokens and a
+    ``tp`` shard's lanes of the array's, in heads of ``head_dim``."""
+    return tokens, lanes // tp, head_dim
+
+
+def head_pass(x, scale=None, *, mesh, eps: float, head_dim: int,
+              theta: Optional[float]):
+    """The work on each head of x (B, T, H*d) between a projection and the
+    attention: the RMS norm over the head's d lanes where there is a
+    ``scale`` (one vector for all heads), then the rotary of positions
+    0..T-1 where there is a ``theta``. A shard's is one pass of the kernel
+    that reads one head's tables where it fits
+    (ops/pallas/head_norm_kernels.py), else the two expressions it
+    replaces: the reshape to heads and :func:`rms_norm`, and
+    ``attention.apply_rotary_lanes`` with tables as wide as the array."""
     norm, rotary = scale is not None, theta is not None
-    why_not = head_norm_why_not(t, width, head_dim)
-    _HEAD_PASSES[t, lanes, head_dim, norm, rotary] = why_not
-    attn_mod.log_kernel_choice(
-        " + ".join(["head norm"] * norm + ["rotary"] * rotary),
-        why_not is None,
-        why_not or f"local {tuple(x.shape)}: heads of {head_dim} lanes, "
-        f"{head_norm.rows_tile(t, width)} rows a tile")
-    pos = jnp.arange(t)
-    if why_not is not None:
+    name = _head_pass_site(norm, rotary)
+
+    def fits(x, scale=None) -> bool:
+        _, t, width = x.shape
+        why_not = head_norm.fits(t, width, head_dim)
+        return lowering.chose(
+            name, _head_pass_key(t, width, head_dim), why_not,
+            why_not or f"local {tuple(x.shape)}: heads of {head_dim} lanes, "
+            f"{head_norm.rows_tile(t, width)} rows a tile")
+
+    def kernel(x, scale=None):
+        tables = None
+        if rotary:
+            tables = head_norm.rotary_tables(*attn_mod.rotary_cos_sin(
+                jnp.arange(x.shape[1]), head_dim, theta))
+        return head_norm.per_head(x, scale, tables, eps, head_dim,
+                                  lowering.interpret())
+
+    def xla(x, scale=None):
+        b, t, width = x.shape
         if norm:
             x = rms_norm(x.reshape(b, t, -1, head_dim), scale,
                          eps).reshape(x.shape)
         if rotary:
             x = attn_mod.apply_rotary_lanes(
-                x, *attn_mod.rotary_cos_sin(pos, head_dim, theta,
+                x, *attn_mod.rotary_cos_sin(jnp.arange(t), head_dim, theta,
                                             width // head_dim), head_dim)
         return x
-    tables = None
-    if rotary:
-        tables = head_norm.rotary_tables(
-            *attn_mod.rotary_cos_sin(pos, head_dim, theta))
-    return head_norm.per_head(x, scale, tables, eps, head_dim,
-                              attn_mod._PALLAS_INTERPRET)
+
+    return lowering.site(
+        name, fits, kernel, xla, mesh, (LANES_SPEC, P())[:1 + norm],
+        LANES_SPEC, head_norm.scope(norm))(x, *[scale] * norm)
 
 
 # ---------------------------------------------------------------------------
@@ -266,24 +268,41 @@ def _backward_words(split_why: Optional[str]) -> str:
             else "one kernel a tile")
 
 
-def _attend_shard(q, k, v, *, kind: str, window: Optional[int],
-                  head_dim: int):
-    """One shard's attention: the blockwise kernel where it fits."""
-    why_not = kernels.blockwise_fits(q.shape[2], k.shape[2], head_dim)
-    group = q.shape[2] // k.shape[2]
-    split_why = None if why_not else kernels.fused_backward_fits(
-        q.shape[1], group, q.dtype.itemsize)
-    _KERNEL_CHOICES[kind, q.shape[1], q.shape[2], k.shape[2]] = \
-        (why_not is None, split_why)
-    attn_mod.log_kernel_choice(
-        f"{kind} attention", why_not is None,
-        why_not or f"local q{tuple(q.shape)} over k{tuple(k.shape)}: "
-        f"blocks of {kernels.BLOCK}, {group} query heads a key-value tile, "
-        + _backward_words(split_why))
-    if why_not is not None:
-        return dense_causal_attention(q, k, v, window, head_dim)
-    return kernels.causal_attention(q, k, v, window, kernels.BLOCK,
-                                    attn_mod._PALLAS_INTERPRET)
+def _blockwise_site(kind: str) -> str:
+    return f"{kind} attention"
+
+
+def _blockwise_key(tokens: int, q_lanes: int, kv_lanes: int, tp: int = 1):
+    """What the record knows a blockwise attention by: a sample's tokens
+    and a ``tp`` shard's lanes of the queries and of the keys."""
+    return tokens, q_lanes // tp, kv_lanes // tp
+
+
+def attend(q, k, v, *, mesh, kind: str, window: Optional[int],
+           head_dim: int, scope: Optional[str] = None):
+    """Causal attention of q (B, T, H*d) over k, v (B, T, G*d): a shard's
+    is the blockwise kernel where it fits, else
+    :func:`dense_causal_attention`."""
+    name = _blockwise_site(kind)
+
+    def fits(q, k, v) -> bool:
+        why_not = kernels.blockwise_fits(q.shape[2], k.shape[2], head_dim)
+        group = q.shape[2] // k.shape[2]
+        split_why = None if why_not else kernels.fused_backward_fits(
+            q.shape[1], group, q.dtype.itemsize)
+        return lowering.chose(
+            name, _blockwise_key(q.shape[1], q.shape[2], k.shape[2]),
+            why_not,
+            why_not or f"local q{tuple(q.shape)} over k{tuple(k.shape)}: "
+            f"blocks of {kernels.BLOCK}, {group} query heads a key-value "
+            "tile, " + _backward_words(split_why), split_backward=split_why)
+
+    return lowering.site(
+        name, fits,
+        lambda q, k, v: kernels.causal_attention(
+            q, k, v, window, kernels.BLOCK, lowering.interpret()),
+        lambda q, k, v: dense_causal_attention(q, k, v, window, head_dim),
+        mesh, (LANES_SPEC,) * 3, LANES_SPEC, scope)(q, k, v)
 
 
 class Attention(nn.Module):
@@ -303,30 +322,18 @@ class Attention(nn.Module):
         rope = self.kind == LAYER_WINDOW_ROPE
         if cfg.qk_norm or rope:
             def per_head(x, name):
-                work = functools.partial(
-                    _per_head_shard, eps=cfg.rms_eps, head_dim=cfg.head_dim,
-                    lanes=x.shape[2], theta=cfg.rope_theta if rope else None)
-                operands, specs = [x], [LANES_SPEC]
-                if cfg.qk_norm:
-                    operands.append(self.param(name, nn.initializers.ones,
-                                               (cfg.head_dim,), pdt))
-                    specs.append(P())
-                if attn_mod._pallas_by_default():
-                    work = per_shard(work, self.mesh, tuple(specs),
-                                     LANES_SPEC, scope=scope)
-                return work(*operands)
+                scale = self.param(name, nn.initializers.ones,
+                                   (cfg.head_dim,), pdt) \
+                    if cfg.qk_norm else None
+                return head_pass(x, scale, mesh=self.mesh, eps=cfg.rms_eps,
+                                 head_dim=cfg.head_dim,
+                                 theta=cfg.rope_theta if rope else None)
 
-            scope = head_norm.scope(cfg.qk_norm)
-            with jax.named_scope(scope):
+            with jax.named_scope(head_norm.scope(cfg.qk_norm)):
                 q, k = per_head(q, "q_norm"), per_head(k, "k_norm")
-        window = cfg.window if rope else None
-        if attn_mod._pallas_by_default():
-            attend = functools.partial(_attend_shard, kind=self.kind,
-                                       window=window, head_dim=cfg.head_dim)
-            ctx = per_shard(attend, self.mesh, (LANES_SPEC,) * 3,
-                            LANES_SPEC, scope=self.name)(q, k, v)
-        else:
-            ctx = dense_causal_attention(q, k, v, window, cfg.head_dim)
+        ctx = attend(q, k, v, mesh=self.mesh, kind=self.kind,
+                     window=cfg.window if rope else None,
+                     head_dim=cfg.head_dim, scope=self.name)
         if cfg.attention_gate:
             g = dense(cfg.num_heads * cfg.head_dim, name="gate")(a)
             with jax.named_scope("gate"):
@@ -351,8 +358,8 @@ def rotary_interleaved_lanes(x: jax.Array, head_dim: int,
     theta^(-2i / head_dim)``, in f32. The pair's other member is a shift by
     one lane, up for the even lanes and down for the odd ones, so no array
     with a minor dimension of 2 exists. The XLA lowering, with tables as
-    wide as the array: what :func:`_pair_rotary_shard` runs where the one
-    pass on the lanes refuses."""
+    wide as the array: what :func:`pair_rotary` runs where the one pass on
+    the lanes refuses."""
     angles = _pair_angles(x.shape[1], x.shape[2], head_dim, theta)
     return turn_pairs_lanes(x, jnp.cos(angles), jnp.sin(angles))
 
@@ -368,40 +375,55 @@ def turn_pairs_lanes(x: jax.Array, cos: jax.Array,
     return (x.astype(jnp.float32) * cos + rot * sin).astype(x.dtype)
 
 
-def pair_rotary_why_not(tokens: int, width: int,
-                        head_dim: int) -> Optional[str]:
-    """Why the rotary of interleaved pairs on (tokens, width) samples is
-    :func:`rotary_interleaved_lanes` and not one pass on the lanes
-    (``head_norm_kernels.pair_rotary``); None where it is the pass."""
-    if not attn_mod._pallas_by_default():
-        return "no Mosaic backend"
-    return head_norm.pairs_fit(tokens, width, head_dim)
+# the log's word for it, as for a head pass with no norm
+PAIR_ROTARY_SITE = "rotary"
 
 
-def _pair_rotary_shard(x, *, start: int, head_dim: int, theta: float,
-                       lanes: int):
-    """One shard's rotary of interleaved pairs on ``x[..., start:]`` (B,
-    T, n * head_dim): one pass of the kernel that reads one lane tile's
-    tables, and ``x`` where a projection wrote it (where the lanes before
-    ``start`` are whole blocks of the pass), else the expression it
-    replaces on the slice. ``lanes``: the rotated lanes of the whole
-    array, of which a ``tp`` shard holds a part."""
-    t, width = x.shape[1], x.shape[2] - start
-    why_not = pair_rotary_why_not(t, width, head_dim)
-    _HEAD_PASSES[t, lanes, head_dim, False, True] = why_not
-    attn_mod.log_kernel_choice(
-        "rotary", why_not is None,
-        why_not or f"local {tuple(x.shape)} from lane {start}: pairs in "
-        f"heads of {head_dim} lanes, "
-        f"{head_norm.pair_rows_tile(t, width)} rows a tile")
-    if why_not is not None or start % head_norm.pair_block(width):
+def _pair_key(tokens: int, lanes: int, head_dim: int, tp: int = 1):
+    """What the record knows a rotary of interleaved pairs by: a sample's
+    tokens and a ``tp`` shard's lanes of the rotated ones."""
+    return tokens, lanes // tp, head_dim, "pairs"
+
+
+def _splits(mesh, axis: Optional[str]) -> bool:
+    """Whether ``mesh`` splits what a spec puts on ``axis``."""
+    return bool(axis) and mesh is not None and mesh.shape.get(axis, 1) > 1
+
+
+def pair_rotary(x, start: int, *, mesh, spec, head_dim: int, theta: float):
+    """The rotary of interleaved pairs on ``x[..., start:]`` (B, T, n *
+    head_dim), ``x`` split by ``spec``: a shard's is one pass of the
+    kernel that reads one lane tile's tables
+    (``head_norm_kernels.pair_rotary``), and ``x`` where a projection
+    wrote it (where the lanes before ``start`` are whole blocks of the
+    pass), else :func:`rotary_interleaved_lanes` on the slice. But a mesh
+    axis that splits the lanes (``tp``) splits the heads of the rotary
+    part, not the lanes of ``x``: the part is sliced first."""
+    if _splits(mesh, spec[2]):
         x, start = x[..., start:], 0
-    if why_not is not None:
-        return rotary_interleaved_lanes(x, head_dim, theta)
-    angles = _pair_angles(t, head_norm.LANES, head_dim, theta)
-    return head_norm.pair_rotary(
-        x, head_norm.pair_tables(jnp.cos(angles), jnp.sin(angles)),
-        start, attn_mod._PALLAS_INTERPRET)
+
+    def fits(x) -> bool:
+        t, width = x.shape[1], x.shape[2] - start
+        why_not = head_norm.pairs_fit(t, width, head_dim)
+        return lowering.chose(
+            PAIR_ROTARY_SITE, _pair_key(t, width, head_dim), why_not,
+            why_not or f"local {tuple(x.shape)} from lane {start}: pairs in "
+            f"heads of {head_dim} lanes, "
+            f"{head_norm.pair_rows_tile(t, width)} rows a tile")
+
+    def kernel(x):
+        first = start
+        if start % head_norm.pair_block(x.shape[2] - start):
+            x, first = x[..., start:], 0
+        angles = _pair_angles(x.shape[1], head_norm.LANES, head_dim, theta)
+        return head_norm.pair_rotary(
+            x, head_norm.pair_tables(jnp.cos(angles), jnp.sin(angles)),
+            first, lowering.interpret())
+
+    return lowering.site(
+        PAIR_ROTARY_SITE, fits, kernel,
+        lambda x: rotary_interleaved_lanes(x[..., start:], head_dim, theta),
+        mesh, (spec,), spec, head_norm.ROTARY_SCOPE)(x)
 
 
 def dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v) -> jax.Array:
@@ -430,44 +452,60 @@ def dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v) -> jax.Array:
 # the one rotary key: a sample's on the shard that has the sample, whole
 ROPE_KEY_SPEC = P(LANES_SPEC[0], None, None)
 
-# (tokens, heads, nope, rope, value lanes) -> why the last traced latent
-# attention of those local shapes did not take the blockwise kernels, None
-# where it did: what attn_layout reads
-_LATENT_CHOICES: Dict[Tuple[int, int, int, int, int], Optional[str]] = {}
-# the same key -> why that call's q_nope, k_nope and v were slices and not
-# q_b's and kv_b's outputs read where they lie, None where they were read
-# there: what attn_operands reads
-_LATENT_OPERANDS: Dict[Tuple[int, int, int, int, int], Optional[str]] = {}
 IN_PLACE = ("q_nope, k_nope, v read where q_b and kv_b wrote them, delta in "
             "the backward kernel")
+LATENT_SITE = "latent attention"
 
 
-def _latent_shard(q, q_rope, kv, k_rope, *, nope: int, rope: int,
-                  value: int):
-    """One shard's latent attention: the blockwise kernels where they
-    fit. ``q`` and ``kv`` as ``kernels.latent_attention`` takes them: the
-    projections' whole outputs, or ``q_nope`` and the pair ``(k_nope, v)``
-    where a mesh axis splits the heads."""
-    t, heads = q.shape[1], q_rope.shape[2] // rope
-    why_not = kernels.latent_fits(t, heads, nope, rope, value,
-                                  q.dtype.itemsize)
-    sliced = why_not
-    if isinstance(kv, tuple):
-        sliced = sliced or ("a mesh axis splits the heads of each part, "
-                            "not the lanes of q_b's and kv_b's outputs")
-    key = t, heads, nope, rope, value
-    _LATENT_CHOICES[key], _LATENT_OPERANDS[key] = why_not, sliced
-    attn_mod.log_kernel_choice(
-        "latent attention", why_not is None,
-        why_not or f"local {heads} heads of {nope} + {rope} | {value} over "
-        f"one rotary key, {t} tokens: blocks of {kernels.BLOCK}, "
-        f"{kernels.LATENT_HEADS} heads a step, " + _backward_words(None)
-        + ", " + (f"operands sliced: {sliced}" if sliced else IN_PLACE))
-    if why_not is not None:
-        q_nope, (k_nope, v) = _latent_parts(q, kv, heads * nope)
+def _latent_key(tokens: int, heads: int, nope: int, rope: int, value: int,
+                tp: int = 1):
+    """What the record knows a latent attention by: a sample's tokens, a
+    ``tp`` shard's heads and a head's three widths."""
+    return tokens, heads // tp, nope, rope, value
+
+
+def latent_attend(q, q_rope, kv, k_rope, *, mesh, nope: int, rope: int,
+                  value: int, scope: Optional[str] = None):
+    """Latent attention: a shard's is the blockwise kernels where they
+    fit, else :func:`dense_latent_attention`. ``q`` and ``kv`` as
+    ``kernels.latent_attention`` takes them: the projections' whole
+    outputs, or ``q_nope`` and the pair ``(k_nope, v)`` where a mesh axis
+    splits the heads (``tp``; the one rotary key is whole on each shard):
+    the projections' whole outputs come in, and are sliced first there.
+    The record's ``sliced``: why the call's q_nope, k_nope and v were
+    slices and not q_b's and kv_b's outputs read where they lie, None where
+    they were read there."""
+    if _splits(mesh, LANES_SPEC[2]):
+        q, kv = _latent_parts(q, kv, q_rope.shape[2] // rope * nope)
+
+    def fits(q, q_rope, kv, k_rope) -> bool:
+        t, heads = q.shape[1], q_rope.shape[2] // rope
+        why_not = kernels.latent_fits(t, heads, nope, rope, value,
+                                      q.dtype.itemsize)
+        sliced = why_not
+        if isinstance(kv, tuple):
+            sliced = sliced or ("a mesh axis splits the heads of each part, "
+                                "not the lanes of q_b's and kv_b's outputs")
+        return lowering.chose(
+            LATENT_SITE, _latent_key(t, heads, nope, rope, value), why_not,
+            why_not or f"local {heads} heads of {nope} + {rope} | {value} "
+            f"over one rotary key, {t} tokens: blocks of {kernels.BLOCK}, "
+            f"{kernels.LATENT_HEADS} heads a step, " + _backward_words(None)
+            + ", " + (f"operands sliced: {sliced}" if sliced else IN_PLACE),
+            sliced=sliced)
+
+    def xla(q, q_rope, kv, k_rope):
+        q_nope, (k_nope, v) = _latent_parts(
+            q, kv, q_rope.shape[2] // rope * nope)
         return dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v)
-    return kernels.latent_attention(q, q_rope, kv, k_rope, kernels.BLOCK,
-                                    attn_mod._PALLAS_INTERPRET)
+
+    return lowering.site(
+        LATENT_SITE, fits,
+        lambda *operands: kernels.latent_attention(
+            *operands, kernels.BLOCK, lowering.interpret()),
+        xla, mesh,
+        (LANES_SPEC, LANES_SPEC, jax.tree.map(lambda _: LANES_SPEC, kv),
+         ROPE_KEY_SPEC), LANES_SPEC, scope)(q, q_rope, kv, k_rope)
 
 
 def _latent_parts(q, kv, lanes: int):
@@ -517,42 +555,13 @@ class LatentAttention(nn.Module):
         kv = dense(heads * (nope + value), name="kv_b")(latent_norm(
             "kv_a_norm", kv_a[..., :cfg.kv_lora_rank]))
 
-        def split(axis) -> bool:
-            return bool(axis) and self.mesh is not None \
-                and self.mesh.shape.get(axis, 1) > 1
-
-        def rotary(x, start, spec):
-            """``x[..., start:]`` rotated, read where the projection wrote
-            it; but a mesh axis that splits the lanes (``tp``) splits the
-            heads of the rotary part, not the lanes of ``x``."""
-            if split(spec[2]):
-                x, start = x[..., start:], 0
-            work = functools.partial(
-                _pair_rotary_shard, start=start, head_dim=rope,
-                theta=cfg.rope_theta, lanes=x.shape[2] - start)
-            if attn_mod._pallas_by_default():
-                work = per_shard(work, self.mesh, (spec,), spec,
-                                 scope=head_norm.ROTARY_SCOPE)
-            return work(x)
-
+        rotary = functools.partial(pair_rotary, mesh=self.mesh, head_dim=rope,
+                                   theta=cfg.rope_theta)
         with jax.named_scope(head_norm.ROTARY_SCOPE):
-            q_rope = rotary(q, heads * nope, LANES_SPEC)
-            k_rope = rotary(kv_a, cfg.kv_lora_rank, ROPE_KEY_SPEC)
-        if attn_mod._pallas_by_default():
-            # the kernels read q_b's and kv_b's outputs where they lie; but
-            # tp splits the heads (the one rotary key is whole on each)
-            if split(LANES_SPEC[2]):
-                q, kv = _latent_parts(q, kv, heads * nope)
-            attend = functools.partial(_latent_shard, nope=nope, rope=rope,
-                                       value=value)
-            ctx = per_shard(
-                attend, self.mesh,
-                (LANES_SPEC, LANES_SPEC,
-                 jax.tree.map(lambda _: LANES_SPEC, kv), ROPE_KEY_SPEC),
-                LANES_SPEC, scope=self.name)(q, q_rope, kv, k_rope)
-        else:
-            q_nope, (k_nope, v) = _latent_parts(q, kv, heads * nope)
-            ctx = dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v)
+            q_rope = rotary(q, heads * nope, spec=LANES_SPEC)
+            k_rope = rotary(kv_a, cfg.kv_lora_rank, spec=ROPE_KEY_SPEC)
+        ctx = latent_attend(q, q_rope, kv, k_rope, mesh=self.mesh, nope=nope,
+                            rope=rope, value=value, scope=self.name)
         return dense(cfg.hidden_size, name="out")(ctx)
 
 
@@ -581,10 +590,11 @@ def dispatch_rows(tokens: int, cfg: SparseLMConfig) -> int:
 SLOT_NS_A_TOKEN = 49.0
 WINDOW_NS = 1200.0
 
-# (slots, held, width, dtype) -> (why not the kernel, tokens a tile) of the
-# last traced token-major sum of those shapes: what moe_layout reads
-_SUM_LOWERINGS: Dict[Tuple[int, int, int, str],
-                     Tuple[Optional[str], int]] = {}
+SUM_SITE = "token-major sum"
+
+
+def _sum_key(slots: int, held: int, width: int, dtype):
+    return slots, held, width, jnp.dtype(dtype).name
 
 
 def runs_why_not(tokens: int, slots: int, held: int, dim: int,
@@ -710,8 +720,11 @@ def _sum_to_tokens(rows_of, plan: _Plan, weight=None, dtype=jnp.float32):
     reads its run of rows in every held expert's group, each row once."""
     (n, k), held = plan.row.shape, plan.row_of.shape[1]
     why_not = runs_why_not(n, k, held, rows_of.shape[1], rows_of.dtype)
-    _SUM_LOWERINGS[k, held, rows_of.shape[1], rows_of.dtype.name] = (
-        why_not, token_sum.tokens_tile(n))
+    # a cost rule and no gate: the sum is traced only under the grouped
+    # products, which have one. Remembered, not said
+    lowering.record(SUM_SITE,
+                    _sum_key(k, held, rows_of.shape[1], rows_of.dtype),
+                    why_not, tile=token_sum.tokens_tile(n))
     if why_not is not None:
         return _sum_over_slots(rows_of, plan, weight).astype(dtype)
     if weight is not None:
@@ -720,7 +733,7 @@ def _sum_to_tokens(rows_of, plan: _Plan, weight=None, dtype=jnp.float32):
             0.0), axis=1)
     return token_sum.token_major_sum(
         rows_of, plan.row_of, plan.start, plan.written, weight,
-        out_dtype=dtype, interpret=attn_mod._PALLAS_INTERPRET)
+        out_dtype=dtype, interpret=lowering.interpret())
 
 
 def _rows_of(source, plan: _Plan):
@@ -731,10 +744,8 @@ def _rows_of(source, plan: _Plan):
 
 def grouped_kernels_why_not(dim: int, width: int) -> Optional[str]:
     """Why the grouped Pallas products cannot take experts of ``dim`` x
-    ``width`` here; None where they can (interpreted, any size)."""
-    if not attn_mod._pallas_by_default():
-        return "no Mosaic backend"
-    if (dim % 128 or width % 128) and not attn_mod._PALLAS_INTERPRET:
+    ``width``; None where they can (interpreted, any size)."""
+    if (dim % 128 or width % 128) and not lowering.interpret():
         return f"{dim} x {width} are not lane tiles"
     return None
 
@@ -743,7 +754,7 @@ def _grouped_dots(plan: _Plan):
     """``dot(x, w)``: row tile t of x times its expert's weights, and
     ``grads(x, w, dy) -> (dx, dw)``."""
     kw = dict(tiles=plan.tiles, tile=grouped.TILE,
-              interpret=attn_mod._PALLAS_INTERPRET)
+              interpret=lowering.interpret())
     return (functools.partial(grouped.grouped_matmul, **kw),
             functools.partial(grouped.grouped_matmul_grads, **kw))
 
@@ -888,6 +899,9 @@ def _held_experts_bwd(offset, rows, act, res, cotangent):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+PRODUCTS_SITE = "expert products"
+
+
 def held_experts(m, idx, p, gate, up, down, *, offset: int, rows: int,
                  act: str = "relu"):
     """The held experts' part of the layer: the sorted lowering where the
@@ -895,15 +909,18 @@ def held_experts(m, idx, p, gate, up, down, *, offset: int, rows: int,
     they do not, chosen on the device by the count; the dense one alone
     where the grouped kernels cannot run. Returns ((N, D) f32, the
     assignments computed)."""
-    why_not = grouped_kernels_why_not(m.shape[1], gate.shape[2])
-    attn_mod.log_kernel_choice(
-        "expert products", why_not is None,
-        why_not or f"{rows} rows in tiles of {grouped.TILE}, "
-        f"{gate.shape[0]} experts of {m.shape[1]} x {gate.shape[2]}")
-    if why_not is not None:
-        return _every_expert(m, idx, p, gate, up, down, offset=offset,
-                             act=act)
-    return _held_experts(offset, rows, act, m, idx, p, gate, up, down)
+    def fits(m, idx, p, gate, up, down) -> bool:
+        why_not = grouped_kernels_why_not(m.shape[1], gate.shape[2])
+        return lowering.chose(
+            PRODUCTS_SITE, (rows, *gate.shape), why_not,
+            why_not or f"{rows} rows in tiles of {grouped.TILE}, "
+            f"{gate.shape[0]} experts of {m.shape[1]} x {gate.shape[2]}")
+
+    return lowering.site(
+        PRODUCTS_SITE, fits, functools.partial(_held_experts, offset, rows,
+                                               act),
+        functools.partial(_every_expert, offset=offset, act=act))(
+            m, idx, p, gate, up, down)
 
 
 class ExpertWeights(nn.Module):
@@ -1024,7 +1041,10 @@ class ExpertLayer(nn.Module):
             counts = group_sizes(key, cfg.experts_held)
             sizes = counts.astype(jnp.float32)
             here = jnp.sum(sizes)
-            sorted_ = grouped_kernels_why_not(d, cfg.expert_width) is None
+            # what held_experts just chose for this call's rows and
+            # its (held, D, F) experts
+            sorted_ = lowering.why_not(PRODUCTS_SITE, (
+                rows, cfg.experts_held, d, cfg.expert_width)) is None
             dense = (here > rows) | (not sorted_)
             # runs of the sorted lowering's token-major kernel that pass
             # their first window (none where the sums are gathered or
@@ -1301,49 +1321,40 @@ def init_params(model: SparseLM, rng: jax.Array, batch: int = 2):
     return jax.jit(model.init)(rng, tokens, tokens)
 
 
-def _latent_layout(cfg: SparseLMConfig, tp: int) -> str:
-    """``attn_layout`` of a configuration whose every layer is latent
-    attention, the prediction module's block among them: the widths, and
-    which lowering the traced calls took."""
+def _latent_records(cfg: SparseLMConfig, tp: int) -> Dict[str, str]:
+    """``attn_layout`` and ``attn_operands`` of a configuration whose every
+    layer is latent attention, the prediction module's block among them:
+    the widths, which lowering the traced calls took, and whether their
+    kernels read ``q_nope``, ``k_nope`` and ``v`` where ``q_b`` and
+    ``kv_b`` wrote them. Every layer has the one shape, and its rotary the
+    same two (the queries' rotary parts and the one key): all took the
+    kernel, or the first refusal says why none did."""
     layers = cfg.num_hidden_layers + cfg.num_nextn_predict_layers
-    rope = cfg.qk_rope_head_dim
-    why_not = not_the_pass = "no Mosaic backend"
-    if attn_mod._pallas_by_default():
-        why_not = _LATENT_CHOICES.get(
-            (cfg.total_seq_len, cfg.num_heads // tp, cfg.qk_nope_head_dim,
-             rope, cfg.v_head_dim), "none traced")
-        # the queries' rotary parts and the one key: the same two shapes
-        # in every layer, so all took the one pass on the lanes or the
-        # first refusal says why none did
-        not_the_pass = next(filter(None, (
-            _HEAD_PASSES.get((cfg.total_seq_len, lanes, rope, False, True),
-                             "none traced")
-            for lanes in (cfg.num_heads * rope, rope))), None)
+    tokens, rope = cfg.total_seq_len, cfg.qk_rope_head_dim
+    call = LATENT_SITE, _latent_key(
+        tokens, cfg.num_heads, cfg.qk_nope_head_dim, rope, cfg.v_head_dim,
+        tp)
+    why_not = lowering.why_not(*call)
+    not_the_pass = lowering.first_refusal(
+        (PAIR_ROTARY_SITE, key) for key in (
+            _pair_key(tokens, cfg.num_heads * rope, rope, tp),
+            _pair_key(tokens, rope, rope)))
+    sliced = why_not or lowering.recorded(*call)["sliced"]
     took = (f"dense XLA lowering ({why_not})" if why_not else
             f"blockwise {kernels.BLOCK}: {layers} of {layers} layers, "
             f"{kernels.LATENT_HEADS} heads a step, backward: "
             + _backward_words(None))
     rotary = (f"XLA: {not_the_pass}" if not_the_pass else
               f"one pass on the lanes: {layers} of {layers} layers")
-    return (f"latent {cfg.q_lora_rank} / {cfg.kv_lora_rank} + one rotary "
+    return {
+        "attn_layout": (
+            f"latent {cfg.q_lora_rank} / {cfg.kv_lora_rank} + one rotary "
             f"key of {rope}, heads {cfg.num_heads} x "
             f"({cfg.qk_nope_head_dim} + {rope} | "
-            f"{cfg.v_head_dim}), {took}, rotary ({rotary})")
-
-
-def _latent_operands(cfg: SparseLMConfig, tp: int) -> str:
-    """``attn_operands`` of such a configuration: whether the traced calls'
-    kernels read ``q_nope``, ``k_nope`` and ``v`` where ``q_b`` and
-    ``kv_b`` wrote them (every layer has the one shape, so all did or the
-    refusal says why none did)."""
-    layers = cfg.num_hidden_layers + cfg.num_nextn_predict_layers
-    sliced = "no Mosaic backend"
-    if attn_mod._pallas_by_default():
-        sliced = _LATENT_OPERANDS.get(
-            (cfg.total_seq_len, cfg.num_heads // tp, cfg.qk_nope_head_dim,
-             cfg.qk_rope_head_dim, cfg.v_head_dim), "none traced")
-    return (f"sliced: {sliced}" if sliced else
-            f"latent: {IN_PLACE}: {layers} of {layers} layers")
+            f"{cfg.v_head_dim}), {took}, rotary ({rotary})"),
+        "attn_operands": (
+            f"sliced: {sliced}" if sliced else
+            f"latent: {IN_PLACE}: {layers} of {layers} layers")}
 
 
 def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
@@ -1352,15 +1363,17 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     what the dispatcher did), how the layers run, and what the expert layer
     holds."""
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-    widths = (cfg.num_heads * cfg.head_dim // tp,
-              cfg.num_kv_heads * cfg.head_dim // tp)
+    tokens = cfg.total_seq_len
     kinds = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
-    choices = [_KERNEL_CHOICES.get((k, cfg.total_seq_len, *widths),
-                                   (False, None)) for k in kinds]
-    on = sum(took for took, _ in choices)
+    calls = [(_blockwise_site(k), _blockwise_key(
+        tokens, cfg.num_heads * cfg.head_dim,
+        cfg.num_kv_heads * cfg.head_dim, tp)) for k in kinds]
+    took = [c for c in calls if lowering.why_not(*c) is None]
+    on = len(took)
     # the backward of the layers that took the kernel: one length, group and
     # dtype, so one answer
-    split_why = next((why for took, why in choices if took and why), None)
+    split_why = next(filter(None, (
+        lowering.recorded(*c)["split_backward"] for c in took)), None)
     backward = ""
     if on:
         backward = ", backward: " + _backward_words(split_why)
@@ -1368,39 +1381,36 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
             backward += f" ({on} of {len(kinds)} layers)"
     windows = sum(k == LAYER_WINDOW_ROPE for k in kinds)
 
-    def pass_why_not(norm: bool, rotary: bool) -> Optional[str]:
-        """Queries' and keys' per-head work of one kind is the same two
-        shapes in every layer that has it: all took the pass on the
-        lanes, or the first refusal says why none did."""
-        return next(filter(None, (
-            _HEAD_PASSES.get((cfg.total_seq_len, h * cfg.head_dim,
-                              cfg.head_dim, norm, rotary), "none traced")
-            for h in (cfg.num_heads, cfg.num_kv_heads))), None)
+    def passes(norm: bool, rotary: bool):
+        """Queries' and keys' per-head work of one kind: the same two
+        shapes in every layer that has it."""
+        return [(_head_pass_site(norm, rotary), _head_pass_key(
+            tokens, h * cfg.head_dim, cfg.head_dim, tp))
+            for h in (cfg.num_heads, cfg.num_kv_heads)]
 
-    def lowering(why_not: Optional[str], took: str) -> str:
+    def lowering_of(asked, took: str) -> str:
+        why_not = lowering.first_refusal(asked)
         return f"(XLA: {why_not})" if why_not else f"({took})"
 
     words = ""
     if cfg.qk_norm:
-        words += ", normed queries and keys " + lowering(
-            next(filter(None, (
-                pass_why_not(True, rotary)
-                for rotary in {k == LAYER_WINDOW_ROPE for k in kinds})), None),
+        words += ", normed queries and keys " + lowering_of(
+            [c for rotary in {k == LAYER_WINDOW_ROPE for k in kinds}
+             for c in passes(True, rotary)],
             f"one pass on the lanes: {len(kinds)} of {len(kinds)} layers")
     if windows:
-        words += ", rotary " + lowering(
-            pass_why_not(cfg.qk_norm, True),
+        words += ", rotary " + lowering_of(
+            passes(cfg.qk_norm, True),
             ("in the head pass" if cfg.qk_norm else "one pass on the lanes")
             + f": {windows} of {windows} rope layers")
     first, last = cfg.expert_offset, cfg.expert_offset + cfg.experts_held - 1
     devices = mesh.size if mesh is not None else 1
-    why_not, tile = _SUM_LOWERINGS.get(
-        (cfg.experts_per_token, cfg.experts_held, cfg.hidden_size,
-         jnp.dtype(cfg.dtype).name), ("", 0))
-    sums = ("none traced (the dense lowering)" if not tile else
-            f"one gather a slot ({why_not})" if why_not else
-            f"runs of rows, {tile} tokens a tile, windows of "
-            f"{token_sum.WINDOW} rows")
+    summed = lowering.recorded(SUM_SITE, _sum_key(
+        cfg.experts_per_token, cfg.experts_held, cfg.hidden_size, cfg.dtype))
+    sums = ("none traced (the dense lowering)" if summed is None else
+            f"one gather a slot ({summed['why_not']})" if summed["why_not"]
+            else f"runs of rows, {summed['tile']} tokens a tile, windows "
+            f"of {token_sum.WINDOW} rows")
     # the router's kind, and what stands beside the routed experts
     router = "softmax over the chosen"
     if cfg.score_func == "sigmoid":
@@ -1420,10 +1430,9 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
         f"heads a key-value head{backward}"
         + words
         + ", gated output" * cfg.attention_gate)
-    said = {}
+    said = {"attn_layout": attn_layout}
     if LAYER_FULL_ROPE in kinds:
-        attn_layout = _latent_layout(cfg, tp)
-        said["attn_operands"] = _latent_operands(cfg, tp)
+        said = _latent_records(cfg, tp)
     if cfg.num_nextn_predict_layers:
         said["mtp_layout"] = (
             "one prediction module after the final norm: [norm(next "
@@ -1432,7 +1441,7 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
             f"head; loss_mtp over T - 2 positions, weight "
             f"{cfg.mtp_loss_weight:g}")
     return {
-        "attn_layout": attn_layout, **said,
+        **said,
         "layer_loop": (f"unrolled: {len(kinds)} layers, each "
                        "rematerialised but its attention"),
         "moe_layout": (

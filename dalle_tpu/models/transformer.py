@@ -41,6 +41,7 @@ from dalle_tpu.models.attention import (
     rotary_cos_sin,
     zoo_attention_lanes,
 )
+from dalle_tpu.ops.pallas import lowering
 from dalle_tpu.parallel.mesh import TOKENS_SPEC, per_shard
 
 logger = logging.getLogger(__name__)
@@ -144,7 +145,6 @@ class FusedLayerNorm(nn.Module):
                            _param_dtype(cfg))
         bias = self.param("bias", nn.initializers.zeros_init(), (d,),
                           _param_dtype(cfg))
-        from dalle_tpu.models import attention as attn_mod
         # one numerical contract (flax's): statistics are formed in f32
         # from the ORIGINAL input. The kernel reads activation-dtype
         # tiles, so it is used only when the input is ALREADY in
@@ -152,13 +152,13 @@ class FusedLayerNorm(nn.Module):
         # then a no-op); a wider input (f32 into a bf16 model) takes the
         # inline fallback, whose f32 stats match nn.LayerNorm exactly
         # (ADVICE r4: the two paths previously diverged on such inputs)
-        if (attn_mod._pallas_by_default() and x.ndim == 3
-                and x.dtype == jnp.dtype(_dtype(cfg))):
-            return per_shard(
-                functools.partial(_layer_norm_shard, out_dtype=_dtype(cfg)),
+        xla = functools.partial(_layer_norm_xla, out_dtype=_dtype(cfg))
+        if x.ndim == 3 and x.dtype == jnp.dtype(_dtype(cfg)):
+            return lowering.site(
+                "LayerNorm", _layer_norm_tiles, _layer_norm_kernel, xla,
                 self.mesh, (TOKENS_SPEC, P(), P()), TOKENS_SPEC,
-                scope=self.name)(x, scale, bias)
-        return _layer_norm_xla(x, scale, bias, _dtype(cfg))
+                self.name)(x, scale, bias)
+        return xla(x, scale, bias)
 
 
 def _layer_norm_xla(x, scale, bias, out_dtype):
@@ -170,18 +170,19 @@ def _layer_norm_xla(x, scale, bias, out_dtype):
     return y.astype(out_dtype)
 
 
-def _layer_norm_shard(x, scale, bias, *, out_dtype):
+def _layer_norm_tiles(x, scale, bias) -> bool:
     """One shard's LayerNorm: the kernel where its LOCAL rows tile."""
-    from dalle_tpu.models import attention as attn_mod
-    from dalle_tpu.ops.pallas.ln_kernels import layer_norm, ln_supported
+    from dalle_tpu.ops.pallas.ln_kernels import ln_supported
     m, d = x.shape[0] * x.shape[1], x.shape[-1]
     ok = ln_supported(m, d)
-    attn_mod.log_kernel_choice(
-        "LayerNorm", ok, f"ln_supported({m} local rows, {d}) is {ok}")
-    if not ok:
-        return _layer_norm_xla(x, scale, bias, out_dtype)
-    y = layer_norm(x.reshape(m, d), scale, bias, 1e-6, 256,
-                   attn_mod._PALLAS_INTERPRET)
+    words = f"ln_supported({m} local rows, {d}) is {ok}"
+    return lowering.chose("LayerNorm", (m, d), None if ok else words, words)
+
+
+def _layer_norm_kernel(x, scale, bias):
+    from dalle_tpu.ops.pallas.ln_kernels import layer_norm
+    y = layer_norm(x.reshape(-1, x.shape[-1]), scale, bias, 1e-6, 256,
+                   lowering.interpret())
     return y.reshape(x.shape)
 
 
@@ -245,10 +246,7 @@ class GEGLUFeedForward(nn.Module):
         wi, wg, wo = wi.astype(cd), wg.astype(cd), wo.astype(cd)
         bi, bg, bo = bi.astype(cd), bg.astype(cd), bo.astype(cd)
         x = x.astype(cd)
-        # same kernel gating as the attention zoo: real TPU backend, or
-        # interpret mode when tests opt in (models/attention.py)
-        from dalle_tpu.models import attention as attn_mod
-        if self.fuse and attn_mod._pallas_by_default():
+        if self.fuse and lowering.mosaic():
             tp = self.mesh.shape["tp"] if self.mesh is not None else 1
             return per_shard(
                 functools.partial(_geglu_shard, tp=tp), self.mesh,
@@ -272,21 +270,18 @@ def _geglu_shard(x, wi, wg, wo, bi, bg, bo, tp_index, *, tp: int):
     (``tp_index``: (1,), the shard's slice of ``arange(tp)``; see
     parallel/mesh.shard_map_unbound) and the partials are summed over
     ``tp``."""
-    from dalle_tpu.models import attention as attn_mod
     from dalle_tpu.ops.pallas.geglu_kernels import geglu_ff, geglu_supported
     b, t, d = x.shape
     inner = wi.shape[1]
     if tp > 1:
         bo = jnp.where(tp_index[0] == 0, bo, jnp.zeros_like(bo))
     ok = geglu_supported(b * t, d, inner, x.dtype)
-    attn_mod.log_kernel_choice(
-        "GEGLU feed-forward", ok,
-        f"geglu_supported({b * t} local rows, {d}, {inner}, {x.dtype}) "
-        f"is {ok}")
-    if ok:
+    words = (f"geglu_supported({b * t} local rows, {d}, {inner}, {x.dtype}) "
+             f"is {ok}")
+    if lowering.chose("GEGLU feed-forward", (b * t, d, inner, x.dtype.name),
+                      None if ok else words, words):
         out = geglu_ff(x.reshape(b * t, d), wi, wg, wo, bi, bg, bo,
-                       256, 512, attn_mod._PALLAS_INTERPRET
-                       ).reshape(b, t, d)
+                       256, 512, lowering.interpret()).reshape(b, t, d)
     else:
         out = _geglu_xla(x, wi, wg, wo, bi, bg, bo)
     return jax.lax.psum(out, "tp") if tp > 1 else out

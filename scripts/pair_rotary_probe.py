@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """The rotary of interleaved pairs at ``joyaiflash``'s cell's two shapes, on
 the chip: the one pass on the lanes (``head_norm_kernels.pair_rotary``
-through ``sparse_lm._pair_rotary_shard``, reading ``q_b``'s and ``kv_a``'s
+through ``sparse_lm.pair_rotary``, reading ``q_b``'s and ``kv_a``'s
 outputs where they lie) against the XLA expression it replaces
 (``sparse_lm.rotary_interleaved_lanes`` on the slice).
 
@@ -36,6 +36,8 @@ def main(argv=None) -> None:
 
     from dalle_tpu.config import joyaiflash_model_config
     from dalle_tpu.models import sparse_lm
+    from dalle_tpu.ops.pallas import lowering
+    from dalle_tpu.parallel.mesh import LANES_SPEC
 
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -56,9 +58,9 @@ def main(argv=None) -> None:
         keys = jax.random.split(jax.random.PRNGKey(args.seed + n), 2)
         x = (jax.random.normal(keys[0], shape) * 2.0 + 0.3).astype(dtype)
         w = jax.random.normal(keys[1], (*shape[:2], width)).astype(dtype)
-        now = functools.partial(sparse_lm._pair_rotary_shard, start=start,
-                                head_dim=rope, theta=cfg.rope_theta,
-                                lanes=width)
+        now = functools.partial(sparse_lm.pair_rotary, start=start,
+                                mesh=None, spec=LANES_SPEC, head_dim=rope,
+                                theta=cfg.rope_theta)
         xla = lambda x: sparse_lm.rotary_interleaved_lanes(
             x[..., start:], rope, cfg.rope_theta)
         plain = lambda x: xla(f32(x))
@@ -68,7 +70,8 @@ def main(argv=None) -> None:
                 lambda x: jnp.sum(f32(fn(x)) * f32(w))))(x)
 
         y, ref = jax.jit(now)(x), jax.jit(xla)(x)
-        why_not = sparse_lm._HEAD_PASSES[t, width, rope, False, True]
+        why_not = lowering.why_not(sparse_lm.PAIR_ROTARY_SITE,
+                                   sparse_lm._pair_key(t, width, rope))
         dx, dx_xla, dx_plain = grad(now), grad(xla), grad(plain)
         out["shapes"][name] = {
             "array": list(shape), "rotated_from_lane": start,

@@ -27,6 +27,19 @@ import pytest  # noqa: E402
 
 
 @pytest.fixture
+def lowering_record(monkeypatch):
+    """``ops/pallas/lowering.py`` with an empty record of which lowering
+    the traced calls took and a log that says every choice again: what a
+    test asks (``why_not``, ``recorded``) is what it traced itself. The
+    process's own record comes back after the test."""
+    from dalle_tpu.ops.pallas import lowering
+
+    monkeypatch.setattr(lowering, "_RECORD", {})
+    lowering._say.cache_clear()
+    return lowering
+
+
+@pytest.fixture
 def inside_manual_dp():
     """``wrap(vg, mesh, batched, argnums)``: the value-and-grad function
     ``vg(*args) -> ((loss, out), grads)`` run as the gradient accumulation

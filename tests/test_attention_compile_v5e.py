@@ -55,12 +55,12 @@ def _layer_gradient_hlo(cfg, attn_type, one_chip, monkeypatch):
     """d(sum(attn(x)^2))/d(params, x) for one ``ZooAttention`` layer,
     compiled for one v5e: the Mosaic kernels' names in the lowered module
     (where the benchmark's census reads them) and the optimised HLO."""
-    from dalle_tpu.models import attention
     from dalle_tpu.models.transformer import ZooAttention, _make_rot
+    from dalle_tpu.ops.pallas import lowering
 
     # the dispatcher asks the backend whether Mosaic is there: here it is
     # the described chip's compiler, whatever the process runs on
-    monkeypatch.setattr(attention, "_pallas_by_default", lambda: True)
+    monkeypatch.setattr(lowering, "mosaic", lambda: True)
     mod = ZooAttention(cfg, attn_type, name="attn")
     t = cfg.total_seq_len
     params = jax.eval_shape(lambda: mod.init(
@@ -134,9 +134,10 @@ def test_the_per_head_work_on_queries_and_keys_stays_on_the_lanes(
     cosine or sine of a table as wide as the array, no padded shifted
     copy (``apply_rotary_lanes``' (..., H*d - 64) slices)."""
     from dalle_tpu import config
-    from dalle_tpu.models import attention, sparse_lm
+    from dalle_tpu.models import sparse_lm
+    from dalle_tpu.ops.pallas import lowering
 
-    monkeypatch.setattr(attention, "_pallas_by_default", lambda: True)
+    monkeypatch.setattr(lowering, "mosaic", lambda: True)
     cfg = getattr(config, f"{preset}_model_config")()
     mod = sparse_lm.Attention(cfg, config.LAYER_WINDOW_ROPE, name="attn")
     a = jax.ShapeDtypeStruct((micro, cfg.total_seq_len, cfg.hidden_size),
@@ -162,8 +163,9 @@ def test_the_per_head_work_on_queries_and_keys_stays_on_the_lanes(
     for heads in (cfg.num_heads, cfg.num_kv_heads):
         assert f"{t},{heads},{d}]" not in text
         assert f",{heads * d - d // 2}]" not in text
-        assert sparse_lm._HEAD_PASSES[t, heads * d, d, cfg.qk_norm,
-                                      True] is None
+        assert lowering.why_not(
+            "head norm + rotary" if cfg.qk_norm else "rotary",
+            (t, heads * d, d)) is None
     tables = [line.split("=")[1].split()[0] for line in text.splitlines()
               if re.search(r" (cosine|sine)\(", line)]
     assert tables and all(_minor(s.split("]")[0]) == d for s in tables), \
@@ -208,7 +210,8 @@ def latent_layer(one_chip):
     from jax.experimental.compilation_cache import compilation_cache
 
     from dalle_tpu import config
-    from dalle_tpu.models import attention, sparse_lm
+    from dalle_tpu.models import sparse_lm
+    from dalle_tpu.ops.pallas import lowering
 
     cfg = config.joyaiflash_model_config()
     mod = sparse_lm.LatentAttention(cfg, name="attn")
@@ -223,16 +226,16 @@ def latent_layer(one_chip):
         return jnp.sum(mod.apply(p, a).astype(jnp.float32) ** 2)
 
     # as ``monkeypatch`` and ``no_persistent_cache`` do for one test
-    by_default = attention._pallas_by_default
+    mosaic = lowering.mosaic
     cached = jax.config.jax_enable_compilation_cache
-    attention._pallas_by_default = lambda: True
+    lowering.mosaic = lambda: True
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
         text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
             params, a).compile().as_text()
     finally:
-        attention._pallas_by_default = by_default
+        lowering.mosaic = mosaic
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()
     return cfg, text
@@ -257,6 +260,7 @@ def test_latent_attentions_rotary_is_one_pass_where_q_b_wrote_it(
     array, no padded shifted copy of it (``rotary_interleaved_lanes``'
     (..., 2 047) slices), no copy of the columns in front of a kernel."""
     from dalle_tpu.models import sparse_lm
+    from dalle_tpu.ops.pallas import lowering
 
     cfg, text = latent_layer
     t, heads, rope = cfg.total_seq_len, cfg.num_heads, cfg.qk_rope_head_dim
@@ -265,8 +269,10 @@ def test_latent_attentions_rotary_is_one_pass_where_q_b_wrote_it(
     # beside them the latent kernels' forward and their one backward
     assert names.count("rotary") == 4 and len(names) == 6, names
     assert all("attn" in n for n in names if n != "rotary"), names
+    # (asked of the record as the fixture's trace left it: no gate here)
     for lanes in (heads * rope, rope):
-        assert sparse_lm._HEAD_PASSES[t, lanes, rope, False, True] is None
+        assert lowering.recorded("rotary", sparse_lm._pair_key(
+            t, lanes, rope))["why_not"] is None
     # each forward call reads its projection's whole output where it lies
     for whole, part in ((heads * (cfg.qk_nope_head_dim + rope), heads * rope),
                         (cfg.kv_lora_rank + rope, rope)):
@@ -300,6 +306,7 @@ def test_latent_attentions_kernels_read_what_q_b_and_kv_b_wrote(
     two backward products as the kernel wrote them (no concatenate is a
     row of its own)."""
     from dalle_tpu.models import sparse_lm
+    from dalle_tpu.ops.pallas import lowering
 
     cfg, text = latent_layer
     t, heads = cfg.total_seq_len, cfg.num_heads
@@ -335,9 +342,10 @@ def test_latent_attentions_kernels_read_what_q_b_and_kv_b_wrote(
              if re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = " + re.escape(stats), line)]
     assert len(holds) == 1 and "get-tuple-element" in holds[0], holds
     assert " concatenate(" not in text.split("ENTRY")[1]
-    key = (t, heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-           cfg.v_head_dim)
-    assert sparse_lm._LATENT_OPERANDS[key] is None
+    assert lowering.recorded("latent attention", sparse_lm._latent_key(
+        t, heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+        cfg.v_head_dim)) == {
+            "why_not": None, "sliced": None}
 
 
 @pytest.mark.parametrize("batch, lanes, before", [

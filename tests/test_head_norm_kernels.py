@@ -15,11 +15,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
 
 from dalle_tpu.models import attention, sparse_lm
 from dalle_tpu.ops.pallas import head_norm_kernels as K
-from dalle_tpu.parallel.mesh import LANES_SPEC, make_mesh, per_shard
+from dalle_tpu.parallel.mesh import LANES_SPEC, make_mesh
 
 
 EPS = 1e-5
@@ -103,7 +102,8 @@ def test_a_row_count_past_one_tile_takes_several():
                          ids=["whole_mesh", "inside_manual_dp"])
 def test_per_shard_whole_heads_and_dscale_of_one_device(nested, variant,
                                                         monkeypatch,
-                                                        inside_manual_dp):
+                                                        inside_manual_dp,
+                                                        lowering_record):
     """dp 2 x fsdp 2 x tp 2: a shard holds a sample's rows of two of the
     four heads; the replicated scale's gradient is the one-device value
     with no sum written out (the ``shard_map``'s transpose sums it over
@@ -116,15 +116,12 @@ def test_per_shard_whole_heads_and_dscale_of_one_device(nested, variant,
     w = jax.random.normal(keys[1], x.shape)
     scale = 1.0 + 0.1 * jax.random.normal(keys[2], (128,))
     normed, rotated = "norm" in variant, "rotary" in variant
-    work = functools.partial(sparse_lm._per_head_shard, eps=EPS,
-                             head_dim=128, lanes=512,
-                             theta=1e4 if rotated else None)
 
     def value_and_grads(mesh_):
         def f(scale, x, w):
-            out = per_shard(work, mesh_, (LANES_SPEC, P())[:1 + normed],
-                            LANES_SPEC, scope="qk_norm")(
-                                x, *[scale] * normed)
+            out = sparse_lm.head_pass(
+                x, scale if normed else None, mesh=mesh_, eps=EPS,
+                head_dim=128, theta=1e4 if rotated else None)
             return jnp.sum(out * w), out
         vg = jax.value_and_grad(f, argnums=(0, 1), has_aux=True)
         if nested and mesh_ is not None:
@@ -134,7 +131,10 @@ def test_per_shard_whole_heads_and_dscale_of_one_device(nested, variant,
     (_, out_m), g_m = value_and_grads(mesh)(scale, x, w)
     (_, out_1), g_1 = value_and_grads(None)(scale, x, w)
     assert len(out_m.sharding.device_set) == 8
-    assert sparse_lm._HEAD_PASSES[24, 512, 128, normed, rotated] is None
+    # a tp shard's two heads of the four took the pass
+    assert lowering_record.why_not(
+        " + ".join(["head norm"] * normed + ["rotary"] * rotated),
+        (24, 256, 128)) is None
     np.testing.assert_allclose(out_m, out_1, rtol=1e-6, atol=1e-6)
     # (no gradient reaches a scale that is not there)
     for a, b in list(zip(g_m, g_1))[1 - normed:]:
@@ -152,9 +152,14 @@ def test_per_shard_whole_heads_and_dscale_of_one_device(nested, variant,
     (64, 512, 128, False, "no Mosaic backend"),
 ])
 def test_head_norm_why_not(tokens, width, head_dim, interpret, why,
-                           monkeypatch):
+                           monkeypatch, lowering_record):
+    """The rule's answer on the local shapes; with no Mosaic backend the
+    record answers for it, whatever was traced."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", interpret)
-    assert sparse_lm.head_norm_why_not(tokens, width, head_dim) == why
+    assert lowering_record.why_not("rotary", (tokens, width, head_dim)) == (
+        "none traced" if interpret else why)
+    if interpret:
+        assert K.fits(tokens, width, head_dim) == why
 
 
 @pytest.mark.parametrize("variant", ["norm", "norm_rotary", "rotary"])
@@ -178,12 +183,11 @@ def test_what_does_not_fit_is_todays_expression_bit_for_bit(shape, head_dim,
         return x
 
     def now(x, scale):
-        return sparse_lm._per_head_shard(
-            x, *[scale] * normed, eps=EPS, head_dim=head_dim,
-            lanes=shape[2], theta=1e4 if rotated else None)
+        return sparse_lm.head_pass(
+            x, scale if normed else None, mesh=None, eps=EPS,
+            head_dim=head_dim, theta=1e4 if rotated else None)
 
-    assert sparse_lm.head_norm_why_not(shape[1], shape[2],
-                                       head_dim) is not None
+    assert K.fits(shape[1], shape[2], head_dim) is not None
     for fn, ref in ((now, today),
                     (jax.grad(lambda *a: jnp.sum(now(*a) * w), (0, 1)),
                      jax.grad(lambda *a: jnp.sum(today(*a) * w), (0, 1)))):
@@ -444,23 +448,21 @@ DISPATCHED = {**PAIR_CASES,
 
 @pytest.mark.parametrize("case", DISPATCHED)
 def test_the_dispatcher_takes_the_pair_pass_with_its_own_tables(
-        case, monkeypatch):
-    """``_pair_rotary_shard`` from the array and the lane its rotary part
-    starts at: the tables it makes for one lane tile, the block it names
-    and the record ``attn_layout`` reads."""
+        case, monkeypatch, lowering_record):
+    """``pair_rotary`` from the array and the lane its rotary part starts
+    at: the tables it makes for one lane tile, the block it names and the
+    record ``attn_layout`` reads."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
     shape, start, dtype = DISPATCHED[case]
     width = shape[2] - start
     x = jax.random.normal(jax.random.PRNGKey(5), shape).astype(dtype)
-    sparse_lm._HEAD_PASSES.pop((shape[1], width, PAIR_HEAD, False, True),
-                               None)
     y = as_stated(functools.partial(
-        sparse_lm._pair_rotary_shard, start=start, head_dim=PAIR_HEAD,
-        theta=PAIR_THETA, lanes=width), x)
+        sparse_lm.pair_rotary, start=start, mesh=None, spec=LANES_SPEC,
+        head_dim=PAIR_HEAD, theta=PAIR_THETA), x)
     ref = as_stated(lambda x: sparse_lm.rotary_interleaved_lanes(
         x[..., start:], PAIR_HEAD, PAIR_THETA), x)
-    assert sparse_lm._HEAD_PASSES[shape[1], width, PAIR_HEAD, False,
-                                  True] is None
+    assert lowering_record.why_not("rotary", sparse_lm._pair_key(
+        shape[1], width, PAIR_HEAD)) is None
     assert y.dtype == ref.dtype and y.shape == ref.shape
     # each side's cosine compiled into its own fusion: a last digit apart
     # at large angles, so a result may round to the other neighbour
@@ -486,9 +488,14 @@ def test_the_dispatcher_takes_the_pair_pass_with_its_own_tables(
     (8192, 2048, 64, False, "no Mosaic backend"),
 ])
 def test_pair_rotary_why_not(tokens, width, head_dim, interpret, why,
-                             monkeypatch):
+                             monkeypatch, lowering_record):
+    """The rule's answer on the local shapes; with no Mosaic backend the
+    record answers for it, whatever was traced."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", interpret)
-    assert sparse_lm.pair_rotary_why_not(tokens, width, head_dim) == why
+    assert lowering_record.why_not("rotary", sparse_lm._pair_key(
+        tokens, width, head_dim)) == ("none traced" if interpret else why)
+    if interpret:
+        assert K.pairs_fit(tokens, width, head_dim) == why
 
 
 @pytest.mark.parametrize("shape, start, head_dim, interpret", [
@@ -499,7 +506,7 @@ def test_pair_rotary_why_not(tokens, width, head_dim, interpret, why,
     ((2, 32, 384), 256, 64, False),         # no Mosaic backend
 ])
 def test_what_the_pair_rule_refuses_is_todays_expression_bit_for_bit(
-        shape, start, head_dim, interpret, monkeypatch):
+        shape, start, head_dim, interpret, monkeypatch, lowering_record):
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", interpret)
     keys = jax.random.split(jax.random.PRNGKey(3), 2)
     x = jax.random.normal(keys[0], shape).astype(jnp.bfloat16)
@@ -511,18 +518,17 @@ def test_what_the_pair_rule_refuses_is_todays_expression_bit_for_bit(
                                                   PAIR_THETA)
 
     def now(x):
-        return sparse_lm._pair_rotary_shard(
-            x, start=start, head_dim=head_dim, theta=PAIR_THETA, lanes=width)
+        return sparse_lm.pair_rotary(
+            x, start, mesh=None, spec=LANES_SPEC, head_dim=head_dim,
+            theta=PAIR_THETA)
 
-    assert sparse_lm.pair_rotary_why_not(shape[1], width,
-                                         head_dim) is not None
     for fn, ref in ((now, today),
                     (jax.grad(lambda x: jnp.sum(now(x) * w)),
                      jax.grad(lambda x: jnp.sum(today(x) * w)))):
         a, b = jax.jit(fn)(x), jax.jit(ref)(x)
         assert a.dtype == b.dtype and np.array_equal(a, b)
-    assert sparse_lm._HEAD_PASSES[shape[1], width, head_dim, False,
-                                  True] is not None
+    assert lowering_record.why_not("rotary", sparse_lm._pair_key(
+        shape[1], width, head_dim)) not in (None, "none traced")
 
 
 @pytest.mark.parametrize("part", ["queries", "key"])
@@ -530,7 +536,8 @@ def test_what_the_pair_rule_refuses_is_todays_expression_bit_for_bit(
                          ids=["whole_mesh", "inside_manual_dp"])
 def test_the_pair_pass_per_shard_whole_pairs_of_heads(nested, part,
                                                       monkeypatch,
-                                                      inside_manual_dp):
+                                                      inside_manual_dp,
+                                                      lowering_record):
     """dp 2 x fsdp 2 x tp 2: a shard holds a sample's rows of one pair of
     the four heads' rotary lanes (one lane tile: its tables are every
     tile's), and the one key whole."""
@@ -541,22 +548,21 @@ def test_the_pair_pass_per_shard_whole_pairs_of_heads(nested, part,
     keys = jax.random.split(jax.random.PRNGKey(7), 2)
     x = jax.random.normal(keys[0], (4, 24, lanes)) * 2.0
     w = jax.random.normal(keys[1], x.shape)
-    work = functools.partial(sparse_lm._pair_rotary_shard, start=0,
-                             head_dim=PAIR_HEAD, theta=PAIR_THETA,
-                             lanes=lanes)
 
     def value_and_grads(mesh_):
         def f(x, w):
-            out = per_shard(work, mesh_, (spec,), spec, scope="rotary")(x)
+            out = sparse_lm.pair_rotary(x, 0, mesh=mesh_, spec=spec,
+                                        head_dim=PAIR_HEAD, theta=PAIR_THETA)
             return jnp.sum(out * w), out
         vg = jax.value_and_grad(f, argnums=(0,), has_aux=True)
         if nested and mesh_ is not None:
             vg = inside_manual_dp(vg, mesh_, (True, True), (0,))
         return jax.jit(vg)
 
-    sparse_lm._HEAD_PASSES.pop((24, lanes, PAIR_HEAD, False, True), None)
     (_, out_m), g_m = value_and_grads(mesh)(x, w)
-    assert sparse_lm._HEAD_PASSES[24, lanes, PAIR_HEAD, False, True] is None
+    # a tp shard's pair of the four heads, or the one key whole
+    assert lowering_record.why_not("rotary", sparse_lm._pair_key(
+        24, lanes, PAIR_HEAD, 2 if part == "queries" else 1)) is None
     (_, out_1), g_1 = value_and_grads(None)(x, w)
     assert len(out_m.sharding.device_set) == 8
     np.testing.assert_allclose(out_m, out_1, rtol=1e-6, atol=1e-6)

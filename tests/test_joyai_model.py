@@ -68,8 +68,8 @@ def _params(cfg, seed=1):
 
 
 @pytest.mark.parametrize("with_kernels", [False, True])
-def test_loss_and_every_gradient_leaf_against_the_yardstick(with_kernels,
-                                                            monkeypatch):
+def test_loss_and_every_gradient_leaf_against_the_yardstick(
+        with_kernels, monkeypatch, lowering_record):
     """The whole tiny model, the prediction module's loss in it; with
     ``with_kernels`` the latent attention, the grouped products and the
     token-major sums run their Pallas kernels, interpreted. Limits: f32 on
@@ -115,18 +115,32 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(with_kernels,
     # counters of the three expert layers, the module's block among them
     assert float(aux["moe_dropped"]) == 0.0
     assert float(aux["moe_dense_calls"]) == (0.0 if with_kernels else 3.0)
+    # which lowering the four latent layers took, asked of the record
+    shut = None if with_kernels else "no Mosaic backend"
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    latent = "latent attention", (40, 4, nope, rope, cfg.v_head_dim)
+    assert lowering_record.why_not(*latent) == shut
+    assert lowering_record.first_refusal(
+        ("rotary", sparse_lm._pair_key(40, lanes, rope))
+        for lanes in (4 * rope, rope)) == shut
+    if with_kernels:
+        assert lowering_record.recorded(*latent) == {
+            "why_not": None, "sliced": None}
+    # the sentences, whole, as the operator reads them
     said = sparse_lm.engagement_records(cfg)
     widths = "128 + 64 | 128" if with_kernels else "16 + 8 | 16"
-    assert said["attn_layout"].startswith(
-        f"latent 48 / 32 + one rotary key of {cfg.qk_rope_head_dim}, heads "
-        f"4 x ({widths}), ")
-    assert said["attn_layout"].endswith(
-        "blockwise 512: 4 of 4 layers, 2 heads a step, backward: one "
-        "kernel a tile, rotary (one pass on the lanes: 4 of 4 layers)"
-        if with_kernels else
-        "dense XLA lowering (no Mosaic backend), rotary (XLA: no Mosaic "
-        "backend)")
-    assert "weight 0.3" in said["mtp_layout"]
+    assert said["attn_layout"] == (
+        f"latent 48 / 32 + one rotary key of {rope}, heads 4 x ({widths}), "
+        + ("blockwise 512: 4 of 4 layers, 2 heads a step, backward: one "
+           "kernel a tile, rotary (one pass on the lanes: 4 of 4 layers)"
+           if with_kernels else
+           "dense XLA lowering (no Mosaic backend), rotary (XLA: no Mosaic "
+           "backend)"))
+    assert said["mtp_layout"] == (
+        "one prediction module after the final norm: [norm(next token's "
+        "embedding) ; norm(last state)] . W_eh, one expert layer, a final "
+        "norm of its own; shares the embedding and the head; loss_mtp over "
+        "T - 2 positions, weight 0.3")
     assert said["attn_operands"] == (
         "latent: q_nope, k_nope, v read where q_b and kv_b wrote them, "
         "delta in the backward kernel: 4 of 4 layers" if with_kernels else
@@ -139,7 +153,7 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(with_kernels,
     ("no_backend", 24, "XLA: no Mosaic backend"),
 ])
 def test_attn_layout_says_which_lowering_the_rotary_took(
-        state, text_seq_len, rotary, monkeypatch):
+        state, text_seq_len, rotary, monkeypatch, lowering_record):
     """The record's last words, from what the traced calls did: the pass
     on every latent layer (the prediction module's among them), the rule's
     refusal of the local shapes, or a backend with no Mosaic kernels."""
@@ -148,7 +162,6 @@ def test_attn_layout_says_which_lowering_the_rotary_took(
     cfg.validate()
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET",
                         state != "no_backend")
-    monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
     model = sparse_lm.build(cfg)
     assert sparse_lm.engagement_records(cfg)["attn_layout"].endswith(
         ", rotary (XLA: no Mosaic backend)" if state == "no_backend"
@@ -158,17 +171,20 @@ def test_attn_layout_says_which_lowering_the_rotary_took(
     said = sparse_lm.engagement_records(cfg)["attn_layout"]
     assert said.endswith(f", rotary ({rotary})"), said
     assert "interleaved pairs" not in said
-    # the queries' 4 x 64 rotary lanes and the one key
-    assert sorted(k[1:] for k in sparse_lm._HEAD_PASSES
-                  if k[0] == cfg.total_seq_len) == [
-        (64, 64, False, True), (256, 64, False, True)]
+    # the queries' 4 x 64 rotary lanes and the one key: each was traced
+    # (with no backend the gate answers for both)
+    for lanes in (256, 64):
+        assert lowering_record.why_not("rotary", sparse_lm._pair_key(
+            cfg.total_seq_len, lanes, 64)) == {
+                "taken": None, "no_backend": "no Mosaic backend"}.get(
+                    state, rotary[len("XLA: "):])
 
 
 @pytest.mark.parametrize("axes", [dict(dp=2, fsdp=2, tp=2),
                                   dict(dp=4, fsdp=2, tp=1)],
                          ids=["tp_splits_the_heads", "samples_only"])
-def test_latent_attention_on_a_mesh_is_the_one_device_layer(axes,
-                                                            monkeypatch):
+def test_latent_attention_on_a_mesh_is_the_one_device_layer(
+        axes, monkeypatch, lowering_record):
     """One layer, kernels interpreted, value and every gradient: where
     ``tp`` splits the heads the rotary pass gets the queries' rotary part
     as an array of its own (a shard's whole pairs of heads), else it reads
@@ -186,11 +202,18 @@ def test_latent_attention_on_a_mesh_is_the_one_device_layer(axes,
         return jax.jit(jax.value_and_grad(
             lambda p, a: jnp.sum(mod.apply(p, a) ** 2), (0, 1)))(params, a)
 
-    monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
-    monkeypatch.setattr(sparse_lm, "_LATENT_OPERANDS", {})
     mesh = make_mesh(**axes)
     value, grads = value_and_grads(mesh)
-    assert set(sparse_lm._HEAD_PASSES.values()) == {None}
+    # a shard's rotary lanes of the queries, and the one key, took the pass
+    tokens, tp = cfg.total_seq_len, axes["tp"]
+    assert lowering_record.first_refusal(
+        ("rotary", key) for key in (
+            sparse_lm._pair_key(tokens, 4 * 64, 64, tp),
+            sparse_lm._pair_key(tokens, 64, 64))) is None
+    assert lowering_record.recorded("latent attention", sparse_lm._latent_key(
+        tokens, 4, 128, 64, 128, tp))["sliced"] == (
+            "a mesh axis splits the heads of each part, not the lanes of "
+            "q_b's and kv_b's outputs" if tp > 1 else None)
     # the kernels too: a shard's heads of each part as arrays of their own
     # where tp splits them, else q_b's and kv_b's outputs where they lie
     said = sparse_lm.engagement_records(cfg, mesh)["attn_operands"]
@@ -205,7 +228,8 @@ def test_latent_attention_on_a_mesh_is_the_one_device_layer(axes,
         assert rel_l2(g, r) < 5e-6
 
 
-def test_the_pair_pass_in_the_model_is_its_xla_lowering(monkeypatch):
+def test_the_pair_pass_in_the_model_is_its_xla_lowering(monkeypatch,
+                                                        lowering_record):
     """Loss and every gradient leaf of the whole tiny model with the rotary
     as the one pass on the lanes (interpreted) against the same model with
     ``rotary_interleaved_lanes``, every other kernel running on both
@@ -223,13 +247,13 @@ def test_the_pair_pass_in_the_model_is_its_xla_lowering(monkeypatch):
             lambda p: model.apply(p, text, image)[0]))(params)
 
     loss, grads = loss_and_grads()
-    assert "one pass on the lanes" in sparse_lm.engagement_records(cfg)[
-        "attn_layout"]
-    monkeypatch.setattr(sparse_lm, "pair_rotary_why_not",
+    rotaries = [("rotary", sparse_lm._pair_key(cfg.total_seq_len, lanes, 64))
+                for lanes in (4 * 64, 64)]
+    assert lowering_record.first_refusal(rotaries) is None
+    monkeypatch.setattr(sparse_lm.head_norm, "pairs_fit",
                         lambda *shape: "refused here")
     ref_loss, ref_grads = loss_and_grads()
-    assert sparse_lm.engagement_records(cfg)["attn_layout"].endswith(
-        "rotary (XLA: refused here)")
+    assert lowering_record.first_refusal(rotaries) == "refused here"
     assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
     for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
                             jax.tree.leaves(ref_grads)):
@@ -410,7 +434,8 @@ def test_the_backward_kernels_own_delta_gives_the_dense_gradient(
         assert g.shape == r.shape and rel_l2(g, r) < 2e-6, name
 
 
-def test_the_grad_step_slices_neither_q_b_nor_kv_b(monkeypatch):
+def test_the_grad_step_slices_neither_q_b_nor_kv_b(monkeypatch,
+                                                   lowering_record):
     """The tiny model's gradient lowered for a TPU from here (the Mosaic
     dispatch forced; nothing runs): no ``stablehlo.slice`` of an array as
     wide as ``q_b``'s or ``kv_b``'s output is traced, where the program
@@ -420,8 +445,7 @@ def test_the_grad_step_slices_neither_q_b_nor_kv_b(monkeypatch):
     import re
     cfg = JoyAILMConfig(**dict(TINY, **KERNEL_WIDTHS))
     cfg.validate()
-    monkeypatch.setattr(attention, "_pallas_by_default", lambda: True)
-    monkeypatch.setattr(sparse_lm, "_LATENT_OPERANDS", {})
+    monkeypatch.setattr(lowering_record, "mosaic", lambda: True)
     model = sparse_lm.build(cfg)
     params = jax.eval_shape(
         lambda: sparse_lm.init_params(model, jax.random.PRNGKey(0)))
@@ -440,13 +464,10 @@ def test_the_grad_step_slices_neither_q_b_nor_kv_b(monkeypatch):
     assert sparse_lm.engagement_records(cfg)["attn_operands"] == (
         "latent: q_nope, k_nope, v read where q_b and kv_b wrote them, "
         "delta in the backward kernel: 4 of 4 layers")
-    # and with the parts sliced first, as a tp axis has them, it shows
-    def cut_first(q, q_rope, kv, k_rope, **widths):
-        q_nope, (k_nope, v) = sparse_lm._latent_parts(q, kv, 512)
-        return sparse_lm.dense_latent_attention(q_nope, q_rope, k_nope,
-                                                k_rope, v)
-
-    monkeypatch.setattr(sparse_lm, "_latent_shard", cut_first)
+    # and with the parts sliced first, as the dense lowering has them (and
+    # a tp axis), it shows
+    monkeypatch.setattr(sparse_lm.kernels, "latent_fits",
+                        lambda *shape: "the test says so")
     sliced = jax.jit(jax.grad(
         lambda p: model.apply(p, text, image)[0])).trace(params).lower(
             lowering_platforms=("tpu",)).as_text()
@@ -503,7 +524,7 @@ AS_FLAGGED = {**TINY, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
               "v_head_dim": 128}
 
 
-def test_the_preset_trains_through_the_peers_normal_path():
+def test_the_preset_trains_through_the_peers_normal_path(lowering_record):
     """``run_trainer --preset joyaiflash`` (+ tiny field flags): the parser
     builds the preset's own class, TrainingTask the model its configuration
     names, and train_loop runs it with the swarm optimizer; the rows of the
@@ -528,9 +549,14 @@ def test_the_preset_trains_through_the_peers_normal_path():
     assert len(losses) == 3 and all(np.isfinite(losses))
     rows = [r for r in default_tracer().dump() if r.get("plane") == "train"]
     warm = [r for r in rows if r["phase"] == "setup/warmup"][-1]["a"]
-    assert warm["moe_layout"].startswith(
+    # the sentences, whole, as the operator reads them (from an empty
+    # record: the token-major sum has no gate, and another test's sum of
+    # these shapes in this process would be this model's too)
+    assert warm["moe_layout"] == (
         "4 of 8 experts held (2-5), top 2 of 8, sigmoid, bias, norm, x2.5, "
-        "a shared expert of 32, layers 0-0 dense 96, no exchange")
+        "a shared expert of 32, layers 0-0 dense 96, no exchange: 8 devices, "
+        "data parallel; token-major sums: none traced (the dense lowering)")
+    assert warm["attn_operands"] == "sliced: no Mosaic backend"
     assert warm["attn_layout"] == (
         "latent 48 / 32 + one rotary key of 64, heads 4 x (128 + 64 | 128), "
         "dense XLA lowering (no Mosaic backend), rotary (XLA: no Mosaic "

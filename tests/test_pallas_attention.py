@@ -11,13 +11,24 @@ import numpy as np
 import pytest
 
 from dalle_tpu.config import ATTN_AXIAL_COL, ATTN_AXIAL_ROW
-from dalle_tpu.models.attention import (axial_attention,
-                                        axial_attention_fused,
-                                        dense_zoo_attention,
-                                        window_attention_fused)
+from dalle_tpu.models.attention import (_as_lanes, _fused_lanes,
+                                        axial_attention, dense_zoo_attention)
 
 # 4 heads of 32: one 128-lane tile, the narrowest width the kernels take
 TEXT, GRID, H, D = 16, 4, 4, 32
+
+
+@pytest.fixture(autouse=True)
+def interpreted(monkeypatch):
+    """The kernels run on the CPU, interpreted."""
+    from dalle_tpu.models import attention
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+
+
+def fused_on_lanes(q, k, v, attn_type, text_len, grid, conv_kernel=11):
+    """A zoo layer's kernel on the lanes view of (B, T, H, d) operands."""
+    return _as_lanes(lambda *qkv: _fused_lanes(
+        *qkv, q.shape[-1], attn_type, text_len, grid, conv_kernel), q, k, v)
 
 
 def _qkv(key, b=2, t=TEXT + GRID * GRID):
@@ -30,8 +41,7 @@ def _qkv(key, b=2, t=TEXT + GRID * GRID):
 class TestFusedAxial:
     def test_forward_matches_dense_oracle(self, attn_type):
         q, k, v = _qkv(jax.random.PRNGKey(0))
-        got = axial_attention_fused(q, k, v, attn_type, TEXT, GRID,
-                                    interpret=True)
+        got = fused_on_lanes(q, k, v, attn_type, TEXT, GRID)
         want = dense_zoo_attention(q, k, v, attn_type, TEXT, GRID)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-5)
@@ -41,13 +51,11 @@ class TestFusedAxial:
         w = jax.random.normal(jax.random.PRNGKey(2), q.shape)
 
         def loss_fused(q, k, v):
-            out = axial_attention_fused(q, k, v, attn_type, TEXT, GRID,
-                                        interpret=True)
+            out = fused_on_lanes(q, k, v, attn_type, TEXT, GRID)
             return jnp.sum(out * w)
 
         def loss_ref(q, k, v):
-            out = axial_attention(q, k, v, attn_type, TEXT, GRID,
-                                  use_pallas=False)
+            out = axial_attention(q, k, v, attn_type, TEXT, GRID)
             return jnp.sum(out * w)
 
         g_fused = jax.grad(loss_fused, argnums=(0, 1, 2))(q, k, v)
@@ -62,8 +70,8 @@ class TestFusedAxial:
         grid = 6
         t = TEXT + grid * grid
         q, k, v = _qkv(jax.random.PRNGKey(3), t=t)
-        got = jax.jit(lambda q, k, v: axial_attention_fused(
-            q, k, v, attn_type, TEXT, grid, interpret=True))(q, k, v)
+        got = jax.jit(lambda q, k, v: fused_on_lanes(
+            q, k, v, attn_type, TEXT, grid))(q, k, v)
         want = dense_zoo_attention(q, k, v, attn_type, TEXT, grid)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-5)
@@ -75,8 +83,7 @@ class TestFusedWindow:
 
     def test_forward_matches_dense_oracle(self, attn_type):
         q, k, v = _qkv(jax.random.PRNGKey(4))
-        got = window_attention_fused(q, k, v, attn_type, TEXT, GRID,
-                                     conv_kernel=3, interpret=True)
+        got = fused_on_lanes(q, k, v, attn_type, TEXT, GRID, conv_kernel=3)
         want = dense_zoo_attention(q, k, v, attn_type, TEXT, GRID,
                                    conv_kernel=3)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -87,8 +94,8 @@ class TestFusedWindow:
         w = jax.random.normal(jax.random.PRNGKey(6), q.shape)
 
         def loss_fused(q, k, v):
-            out = window_attention_fused(q, k, v, attn_type, TEXT, GRID,
-                                         conv_kernel=3, interpret=True)
+            out = fused_on_lanes(q, k, v, attn_type, TEXT, GRID,
+                                 conv_kernel=3)
             return jnp.sum(out * w)
 
         def loss_ref(q, k, v):
@@ -115,8 +122,8 @@ class TestFusedWindow:
                 return jnp.sum(fn(q, k, v) * w)
             return inner
 
-        fused = lambda q, k, v: window_attention_fused(  # noqa: E731
-            q, k, v, attn_type, TEXT, grid, conv_kernel=5, interpret=True)
+        fused = lambda q, k, v: fused_on_lanes(  # noqa: E731
+            q, k, v, attn_type, TEXT, grid, conv_kernel=5)
         dense = lambda q, k, v: dense_zoo_attention(  # noqa: E731
             q, k, v, attn_type, TEXT, grid, conv_kernel=5)
         np.testing.assert_allclose(np.asarray(fused(q, k, v)),
@@ -180,7 +187,8 @@ class TestRematPolicyPinsKernelReplay:
 @pytest.mark.parametrize("attn_type", [ATTN_AXIAL_COL, "conv_like"])
 def test_per_shard_kernels_match_single_device(attn_type, nested,
                                                monkeypatch,
-                                               inside_manual_dp):
+                                               inside_manual_dp,
+                                               lowering_record):
     """GSPMD cannot partition a Mosaic kernel, so on a mesh the dispatcher
     runs the fused kernels per shard (batch over dp x fsdp, whole heads'
     lanes over tp): values and gradients must equal the unwrapped
@@ -208,11 +216,10 @@ def test_per_shard_kernels_match_single_device(attn_type, nested,
             vg = inside_manual_dp(vg, mesh_, (True,) * 4, (0, 1, 2))
         return jax.jit(vg)
 
-    monkeypatch.setattr(attention, "_KERNEL_CHOICES", {})
     (_, out_m), g_m = loss(mesh, nested)(q, k, v, w)
     # the kernel, not the XLA lowering, on the shards' local shapes
-    assert attention._KERNEL_CHOICES == {
-        (attn_type, 32, 128, TEXT + GRID * GRID, TEXT): True}
+    assert lowering_record.why_not(
+        f"{attn_type} attention", (32, 128, TEXT + GRID * GRID, TEXT)) is None
     (_, out_1), g_1 = loss(None)(q, k, v, w)
     assert len(out_m.sharding.device_set) == 8
     np.testing.assert_allclose(np.asarray(out_m), np.asarray(out_1),
@@ -226,11 +233,7 @@ ZOO = [ATTN_AXIAL_ROW, ATTN_AXIAL_COL, "conv_like", "full"]
 
 
 def _fused(q, k, v, attn_type, grid=GRID):
-    if attn_type in (ATTN_AXIAL_ROW, ATTN_AXIAL_COL):
-        return axial_attention_fused(q, k, v, attn_type, TEXT, grid,
-                                     interpret=True)
-    return window_attention_fused(q, k, v, attn_type, TEXT, grid,
-                                  conv_kernel=3, interpret=True)
+    return fused_on_lanes(q, k, v, attn_type, TEXT, grid, conv_kernel=3)
 
 
 @pytest.mark.parametrize("head_dim", [32, 64, 128])
@@ -306,7 +309,7 @@ def test_text_rows_dk_dv_are_the_two_parts_summed(attn_type):
 
 @pytest.mark.parametrize("attn_type", [ATTN_AXIAL_ROW, "conv_like"])
 def test_odd_head_count_takes_the_xla_lowering_and_says_so(
-        attn_type, monkeypatch, caplog):
+        attn_type, monkeypatch, caplog, lowering_record):
     """Three heads of 64 do not fill 128-lane tiles: the dispatcher takes
     the XLA lowering of the same attention and logs the choice once."""
     import logging
@@ -314,8 +317,6 @@ def test_odd_head_count_takes_the_xla_lowering_and_says_so(
     from dalle_tpu.models import attention
 
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
-    monkeypatch.setattr(attention, "_KERNEL_CHOICES", {})
-    attention.log_kernel_choice.cache_clear()     # it says a thing once
     ks = jax.random.split(jax.random.PRNGKey(31), 3)
     q, k, v = (jax.random.normal(kk, (2, TEXT + GRID * GRID, 3, 64),
                                  jnp.float32) for kk in ks)
@@ -324,7 +325,8 @@ def test_odd_head_count_takes_the_xla_lowering_and_says_so(
         return attention.zoo_attention(q, k, v, attn_type=attn_type,
                                        text_len=TEXT, grid=GRID,
                                        conv_kernel=3)
-    with caplog.at_level(logging.INFO, logger=attention.logger.name):
+    # (it says a thing once: the fixture made it forget what it had said)
+    with caplog.at_level(logging.INFO, logger=lowering_record.logger.name):
         jaxpr = jax.make_jaxpr(run)(q, k, v)
         got = run(q, k, v)
     assert "pallas_call" not in str(jaxpr)
@@ -332,8 +334,9 @@ def test_odd_head_count_takes_the_xla_lowering_and_says_so(
             if r.getMessage().startswith(f"{attn_type} attention")]
     assert said == [f"{attn_type} attention: XLA lowering (3 heads of 64 "
                     f"do not fill 128-lane tiles)"]
-    assert attention._KERNEL_CHOICES == {
-        (attn_type, 64, 192, TEXT + GRID * GRID, TEXT): False}
+    assert lowering_record.why_not(
+        f"{attn_type} attention", (64, 192, TEXT + GRID * GRID, TEXT)) == (
+            "3 heads of 64 do not fill 128-lane tiles")
     want = dense_zoo_attention(q, k, v, attn_type, TEXT, GRID, conv_kernel=3)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-5)
@@ -342,7 +345,7 @@ def test_odd_head_count_takes_the_xla_lowering_and_says_so(
 @pytest.mark.parametrize("heads,on", [(4, 9), (3, 0)],
                          ids=["fills_tiles", "falls_back"])
 def test_attn_layout_record_counts_the_layers_that_took_the_kernel(
-        heads, on, monkeypatch):
+        heads, on, monkeypatch, lowering_record):
     """The ``attn_layout`` attribute of the ``setup/warmup`` row: looked
     up in what the dispatcher did while the step was traced, at this
     model's own shapes — another model traced in the same process
@@ -352,7 +355,6 @@ def test_attn_layout_record_counts_the_layers_that_took_the_kernel(
     from dalle_tpu.models.dalle import DALLE, init_params
 
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
-    monkeypatch.setattr(attention, "_KERNEL_CHOICES", {})
 
     def trace(cfg):
         model = DALLE(cfg)

@@ -50,8 +50,8 @@ def _system(cfg, params, text, image):
 
 
 @pytest.mark.parametrize("kernels", [False, True])
-def test_loss_and_every_gradient_leaf_against_the_yardstick(kernels,
-                                                            monkeypatch):
+def test_loss_and_every_gradient_leaf_against_the_yardstick(
+        kernels, monkeypatch, lowering_record):
     """Whole tiny model, both layer kinds, a sequence (28) longer than the
     window (8), half of the router's experts held; with ``kernels`` the
     attention runs the blockwise Pallas kernels, interpreted."""
@@ -74,14 +74,23 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(kernels,
         rel=1e-6)
     assert 0 < float(aux["moe_assignments_here_pct"]) < 100
     assert float(aux["moe_dropped"]) == 0.0
-    taken, split_why = sparse_lm._KERNEL_CHOICES.get(
-        ("window_rope", 28, 4 * 128, 2 * 128), (False, None))
-    assert taken == kernels and split_why is None
-    layout = sparse_lm.engagement_records(cfg)["attn_layout"]
-    assert layout.startswith(f"blockwise 512: {4 * kernels} of 4 layers, ")
+    # which lowering every layer took, and the one backward kernel a tile
+    for kind in ("full_nope", "window_rope"):
+        call = f"{kind} attention", (28, 4 * cfg.head_dim, 2 * cfg.head_dim)
+        assert lowering_record.why_not(*call) == (
+            None if kernels else "no Mosaic backend")
+        if kernels:
+            assert lowering_record.recorded(*call) == {
+                "why_not": None, "split_backward": None}
     # (28 tokens: the rotary's pass wants rows in eights, the test below)
-    assert layout.endswith(
-        "2 query heads a key-value head"
+    assert lowering_record.first_refusal(
+        ("rotary", (28, heads * cfg.head_dim, cfg.head_dim))
+        for heads in (4, 2)) == (
+            "28 rows are not whole sublane tiles of 8" if kernels else
+            "no Mosaic backend")
+    assert sparse_lm.engagement_records(cfg)["attn_layout"] == (
+        f"blockwise 512: {4 * kernels} of 4 layers, 1 full no-rope + 3 "
+        "window 8 rope, 2 query heads a key-value head"
         + (", backward: one kernel a tile (4 of 4 layers), rotary (XLA: 28 "
            "rows are not whole sublane tiles of 8)" if kernels else
            ", rotary (XLA: no Mosaic backend)"))
@@ -94,12 +103,11 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(kernels,
     (False, None, ""),          # no blockwise kernel, so no backward of it
 ])
 def test_attn_layout_says_which_backward_the_layers_took(
-        interpret, budget, words, monkeypatch):
+        interpret, budget, words, monkeypatch, lowering_record):
     """Read from what the traced calls did, as the blockwise count is: the
     one kernel where a key-value head's ``dk`` and ``dv`` fit VMEM, else
     the ``dq`` and the ``dk``/``dv`` kernel and why."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", interpret)
-    monkeypatch.setattr(sparse_lm, "_KERNEL_CHOICES", {})
     if budget:
         monkeypatch.setattr(sparse_lm.kernels, "VMEM_LIMIT_BYTES", budget)
     cfg = SparseLMConfig(**dict(TINY, head_dim=128))
@@ -122,11 +130,11 @@ def test_attn_layout_says_which_backward_the_layers_took(
     (None, 128, 16, "(XLA: none traced)"),
 ])
 def test_attn_layout_says_which_lowering_the_rotary_took(
-        interpret, head_dim, text_len, words, monkeypatch):
+        interpret, head_dim, text_len, words, monkeypatch, lowering_record):
     """Read from what the traced calls did, as the blockwise count is; a
-    configuration with no head norms: the rotary is a pass of its own."""
-    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", bool(interpret))
-    monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
+    configuration with no head norms: the rotary is a pass of its own.
+    (``None``: kernels there are, and nothing was traced.)"""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", interpret is not False)
     cfg = SparseLMConfig(**dict(TINY, head_dim=head_dim,
                                 text_seq_len=text_len))
     if interpret is not None:
@@ -141,19 +149,20 @@ def test_attn_layout_says_which_lowering_the_rotary_took(
     assert "rotary" not in sparse_lm.engagement_records(none)["attn_layout"]
 
 
-def test_the_rotary_in_its_pass_is_the_xla_lowering(monkeypatch):
+def test_the_rotary_in_its_pass_is_the_xla_lowering(monkeypatch,
+                                                    lowering_record):
     """Loss and every gradient leaf of a tiny model whose three rope
     layers rotate queries and keys in the pass (interpreted; 32 tokens),
     against the same model with the rotary as ``apply_rotary_lanes``: the
     same f32 model to its rounding."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
-    monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
     cfg = SparseLMConfig(**dict(TINY, head_dim=128, text_seq_len=16))
     params = sparse_lm.init_params(sparse_lm.build(cfg),
                                    jax.random.PRNGKey(1))
     text, image = _batch(cfg)
-    took = lambda: {why for (t, *_), why in sparse_lm._HEAD_PASSES.items()
-                    if t == cfg.total_seq_len}
+    # the queries' 4 heads and the keys' 2
+    took = lambda: {lowering_record.why_not(
+        "rotary", (cfg.total_seq_len, heads * 128, 128)) for heads in (4, 2)}
     (loss, _), grads = _system(cfg, params, text, image)
     assert took() == {None}
     monkeypatch.setattr(sparse_lm.head_norm, "fits",
@@ -386,7 +395,7 @@ def test_a_weights_pieces_add_up_to_it_exactly():
 
 
 def test_which_lowering_a_token_major_sum_takes_is_read_off_its_shapes(
-        monkeypatch):
+        monkeypatch, lowering_record):
     """The kernel over runs while the held experts stay under about ten a
     slot and their windows fit VMEM; one gather a slot otherwise. The
     cell's layout takes the kernel; the crossing lies past what fits."""
@@ -421,7 +430,6 @@ def test_which_lowering_a_token_major_sum_takes_is_read_off_its_shapes(
     sparse_lm._sum_to_tokens(rows, plan)
     assert layout().endswith("token-major sums: one gather a slot (4 windows "
                              "cost more than 2 gathers of 56 rows)")
-    sparse_lm._SUM_LOWERINGS.pop((2, 4, 192, "bfloat16"))
 
 TINY_FLAGS = [
     "--hidden-size", "64", "--num-hidden-layers", "4", "--num-heads", "4",
@@ -432,7 +440,7 @@ TINY_FLAGS = [
     "--vocab-image", "48", "--dtype", "float32", "--head-chunk", "16"]
 
 
-def test_the_preset_trains_through_the_peers_normal_path():
+def test_the_preset_trains_through_the_peers_normal_path(lowering_record):
     """``run_trainer --preset smallthinker21b`` (+ tiny field flags):
     the parser builds the preset's own class, TrainingTask builds the
     model its configuration names, and train_loop runs it with the swarm
@@ -460,8 +468,16 @@ def test_the_preset_trains_through_the_peers_normal_path():
     assert len(losses) == 3 and all(np.isfinite(losses))
     rows = [r for r in default_tracer().dump() if r.get("plane") == "train"]
     warm = [r for r in rows if r["phase"] == "setup/warmup"][-1]["a"]
-    assert warm["moe_layout"].startswith("4 of 8 experts held (2-5), top 2")
-    assert warm["attn_layout"].startswith("blockwise 512: 0 of 4 layers")
+    # the sentences, whole, as the operator reads them (from an empty
+    # record: the token-major sum has no gate, and another test's sum of
+    # these shapes in this process would be this model's too)
+    assert warm["moe_layout"] == (
+        "4 of 8 experts held (2-5), top 2 of 8, softmax over the chosen, no "
+        "exchange: 8 devices, data parallel; token-major sums: none traced "
+        "(the dense lowering)")
+    assert warm["attn_layout"] == (
+        "blockwise 512: 0 of 4 layers, 1 full no-rope + 3 window 8 rope, 2 "
+        "query heads a key-value head, rotary (XLA: no Mosaic backend)")
     assert warm["layer_loop"] == ("unrolled: 4 layers, each rematerialised "
                                   "but its attention")
     steps = [r for r in rows if r["phase"] == "loop/step"][-3:]

@@ -76,8 +76,8 @@ def _system(cfg, params, text, image):
 
 
 @pytest.mark.parametrize("kernels", [False, True])
-def test_loss_and_every_gradient_leaf_against_the_yardstick(kernels,
-                                                            monkeypatch):
+def test_loss_and_every_gradient_leaf_against_the_yardstick(
+        kernels, monkeypatch, lowering_record):
     """The whole tiny model with every mechanism on; with ``kernels`` the
     attention, the grouped products and the token-major sums run their
     Pallas kernels, interpreted."""
@@ -97,18 +97,27 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(kernels,
         assert rel_l2(g, r) < 2e-5, jax.tree_util.keystr(path)
     # ... through the one-pass head norm, where the kernels run, and the
     # blockwise attention's one-kernel backward
-    layout = sparse_lm.engagement_records(cfg)["attn_layout"]
-    assert ("normed queries and keys (one pass on the lanes: 5 of 5 layers)"
-            ", rotary (in the head pass: 4 of 4 rope layers), gated output"
-            if kernels else "normed queries and keys (XLA: no Mosaic "
-            "backend), rotary (XLA: no Mosaic backend), gated output"
-            ) in layout
-    assert layout.startswith(
+    shut = None if kernels else "no Mosaic backend"
+    for kind, rotary in (("window_rope", True), ("full_nope", False)):
+        call = f"{kind} attention", (32, 4 * cfg.head_dim, 2 * cfg.head_dim)
+        assert lowering_record.why_not(*call) == shut
+        if kernels:
+            assert lowering_record.recorded(*call) == {
+                "why_not": None, "split_backward": None}
+        assert lowering_record.first_refusal(
+            ("head norm" + " + rotary" * rotary,
+             (32, heads * cfg.head_dim, cfg.head_dim))
+            for heads in (4, 2)) == shut
+    # the whole sentence, as the operator reads it
+    assert sparse_lm.engagement_records(cfg)["attn_layout"] == (
         "blockwise 512: 5 of 5 layers, 1 full no-rope + 4 window 8 rope, 2 "
         "query heads a key-value head, backward: one kernel a tile (5 of 5 "
-        "layers), normed" if kernels else
+        "layers), normed queries and keys (one pass on the lanes: 5 of 5 "
+        "layers), rotary (in the head pass: 4 of 4 rope layers), gated "
+        "output" if kernels else
         "blockwise 512: 0 of 5 layers, 1 full no-rope + 4 window 8 rope, 2 "
-        "query heads a key-value head, normed")
+        "query heads a key-value head, normed queries and keys (XLA: no "
+        "Mosaic backend), rotary (XLA: no Mosaic backend), gated output")
     layer = params["params"]["layer_1"]
     assert set(layer) == {"attn", "attn_norm", "post_attn_norm", "ff",
                           "ff_norm", "post_ff_norm"}          # four norms
@@ -270,12 +279,12 @@ def _a_joyaiflash_mechanism_left_out_is_told(mechanism, monkeypatch):
     (None, 128, "(XLA: none traced)", None),
 ])
 def test_attn_layout_says_which_lowering_the_head_norms_took(
-        interpret, head_dim, words, rotary, monkeypatch):
+        interpret, head_dim, words, rotary, monkeypatch, lowering_record):
     """Read from what the traced calls did, as the blockwise count is: the
     head norms of every layer, and the rotary of the rope layers, which
-    runs in the norm's pass or, for the norm's own reason, as XLA."""
-    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", bool(interpret))
-    monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
+    runs in the norm's pass or, for the norm's own reason, as XLA.
+    (``None``: kernels there are, and nothing was traced.)"""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", interpret is not False)
     cfg = AfmoeLMConfig(**dict(SMALL, head_dim=head_dim))
     if interpret is not None:
         text, image = _batch(cfg)
@@ -288,30 +297,33 @@ def test_attn_layout_says_which_lowering_the_head_norms_took(
     assert "normed" not in sparse_lm.engagement_records(without)[
         "attn_layout"]
     # ... whose rotary would be a pass of its own, and none was traced
-    assert ", rotary (XLA: none traced), gated" in sparse_lm.\
+    assert (", rotary (XLA: none traced), gated" if interpret is not False
+            else ", rotary (XLA: no Mosaic backend), gated") in sparse_lm.\
         engagement_records(without)["attn_layout"]
 
 
-def test_norm_and_rotary_in_one_pass_are_the_xla_lowering(monkeypatch):
+def test_norm_and_rotary_in_one_pass_are_the_xla_lowering(monkeypatch,
+                                                          lowering_record):
     """Loss and every gradient leaf of the tiny model whose four rope
     layers norm and rotate queries and keys in one pass and whose full
     layer norms them in it (interpreted), against the same model with the
     reshaped ``rms_norm`` and ``apply_rotary_lanes``: the same f32 model
     to its rounding."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
-    monkeypatch.setattr(sparse_lm, "_HEAD_PASSES", {})
     cfg = AfmoeLMConfig(**dict(TINY, head_dim=128))
     params = _params(cfg)
     text, image = _batch(cfg)
-    took = lambda: {(norm, rotary, why) for (t, _, _, norm, rotary), why
-                    in sparse_lm._HEAD_PASSES.items()
-                    if t == cfg.total_seq_len}
+    # the queries' 4 heads and the keys' 2, with the rotary and without
+    took = lambda: {(rotary, lowering_record.why_not(
+        "head norm" + " + rotary" * rotary,
+        (cfg.total_seq_len, heads * 128, 128)))
+        for rotary in (True, False) for heads in (4, 2)}
     (loss, _), grads = _system(cfg, params, text, image)
-    assert took() == {(True, True, None), (True, False, None)}
+    assert took() == {(True, None), (False, None)}
     monkeypatch.setattr(sparse_lm.head_norm, "fits",
                         lambda *a: "the test says so")
     (ref_loss, _), ref_grads = _system(cfg, params, text, image)
-    assert {why for _, _, why in took()} == {"the test says so"}
+    assert {why for _, why in took()} == {"the test says so"}
     assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
     for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
                             jax.tree.leaves(ref_grads)):
@@ -319,12 +331,11 @@ def test_norm_and_rotary_in_one_pass_are_the_xla_lowering(monkeypatch):
 
 
 def test_attn_layout_names_the_split_backward_between_the_other_words(
-        monkeypatch):
+        monkeypatch, lowering_record):
     """Where ``dk`` and ``dv`` do not fit VMEM (the budget shrunk, as no
     preset's length reaches) the word says so and why, after the heads and
     before the head norms' words."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
-    monkeypatch.setattr(sparse_lm, "_KERNEL_CHOICES", {})
     monkeypatch.setattr(sparse_lm.kernels, "VMEM_LIMIT_BYTES", 2 ** 20)
     cfg = AfmoeLMConfig(**dict(SMALL, head_dim=128))
     text, image = _batch(cfg)
@@ -473,7 +484,7 @@ TINY_FLAGS = [
     "--dense-width", "96"]
 
 
-def test_the_preset_trains_through_the_peers_normal_path():
+def test_the_preset_trains_through_the_peers_normal_path(lowering_record):
     """``run_trainer --preset trinitymini`` (+ tiny field flags): the
     parser builds the preset's own class, TrainingTask the model its
     configuration names, and train_loop runs it with the swarm optimizer;
@@ -498,14 +509,18 @@ def test_the_preset_trains_through_the_peers_normal_path():
     assert len(losses) == 3 and all(np.isfinite(losses))
     rows = [r for r in default_tracer().dump() if r.get("plane") == "train"]
     warm = [r for r in rows if r["phase"] == "setup/warmup"][-1]["a"]
-    assert warm["moe_layout"].startswith(
+    # the sentences, whole, as the operator reads them (from an empty
+    # record: the token-major sum has no gate, and another test's sum of
+    # these shapes in this process would be this model's too)
+    assert warm["moe_layout"] == (
         "4 of 8 experts held (2-5), top 2 of 8, sigmoid, bias, norm, "
-        "x2.826, a shared expert of 32, layers 0-0 dense 96, no exchange")
-    assert warm["attn_layout"].startswith("blockwise 512: 0 of 5 layers, 1 "
-                                          "full no-rope + 4 window 8 rope")
-    assert warm["attn_layout"].endswith(
-        "normed queries and keys (XLA: no Mosaic backend), rotary (XLA: no "
-        "Mosaic backend), gated output")
+        "x2.826, a shared expert of 32, layers 0-0 dense 96, no exchange: "
+        "8 devices, data parallel; token-major sums: none traced (the dense "
+        "lowering)")
+    assert warm["attn_layout"] == (
+        "blockwise 512: 0 of 5 layers, 1 full no-rope + 4 window 8 rope, 2 "
+        "query heads a key-value head, normed queries and keys (XLA: no "
+        "Mosaic backend), rotary (XLA: no Mosaic backend), gated output")
     steps = [r for r in rows if r["phase"] == "loop/step"][-3:]
     for row in (r["a"] for r in steps):
         assert 0 < row["moe_assignments_here_pct"] < 100
