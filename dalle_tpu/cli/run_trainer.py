@@ -24,9 +24,10 @@ import sys
 from typing import Optional, Sequence
 
 from dalle_tpu.config import (AfmoeLMConfig, CollabConfig, JoyAILMConfig,
-                              ModelConfig, OptimizerConfig, PeerConfig,
-                              SparseLMConfig, TrainerConfig,
+                              Lfm2MoeLMConfig, ModelConfig, OptimizerConfig,
+                              PeerConfig, SparseLMConfig, TrainerConfig,
                               flagship_model_config, joyaiflash_model_config,
+                              lfm2moe_model_config,
                               smallthinker21b_model_config,
                               tiny_model_config, trinitymini_model_config,
                               xl_model_config)
@@ -51,6 +52,10 @@ MODEL_PRESETS = {
     # JoyAI-LLM-Flash cut to one of 32 chips' share of a layer (latent
     # attention, a prediction module): joyaiflash-train-solo
     "joyaiflash": joyaiflash_model_config,
+    # LFM2-8B-A1B cut to one of 4 chips' share of a layer (a gated short
+    # convolution in four layers of five, 64-wide heads, a tied head):
+    # lfm2moe-train-solo
+    "lfm2moe": lfm2moe_model_config,
 }
 
 CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
@@ -58,7 +63,7 @@ CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
 # Every architecture's configuration class. A preset builds one of them;
 # a field two of them share (vocab_text, dtype, ...) is one flag.
 MODEL_CLASSES = (ModelConfig, SparseLMConfig, AfmoeLMConfig,
-                 JoyAILMConfig)
+                 JoyAILMConfig, Lfm2MoeLMConfig)
 
 
 def maybe_wandb_run(project: Optional[str], name: str):

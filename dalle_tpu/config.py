@@ -693,16 +693,24 @@ class AuxConfig:
 
 LAYER_FULL_NOPE = "full_nope"      # causal over the whole sequence, no positions
 LAYER_WINDOW_ROPE = "window_rope"  # causal inside ``window``, rotary
-LAYER_FULL_ROPE = "full_rope"      # causal over the whole sequence, rotary
+# causal over the whole sequence, rotary: which attention runs it is read
+# from the configuration's fields (``kv_lora_rank``: latent attention; else
+# grouped key-value heads, as the two kinds above)
+LAYER_FULL_ROPE = "full_rope"
+# no attention: a gated short convolution (``models/sparse_lm.ShortConv``)
+LAYER_SHORT_CONV = "short_conv"
 
-VALID_LAYER_KINDS = (LAYER_FULL_NOPE, LAYER_WINDOW_ROPE, LAYER_FULL_ROPE)
+VALID_LAYER_KINDS = (LAYER_FULL_NOPE, LAYER_WINDOW_ROPE, LAYER_FULL_ROPE,
+                     LAYER_SHORT_CONV)
 
 
 @dataclass(frozen=True)
 class SparseLMConfig:
     """A decoder-only language model whose every layer is a mixture of
-    experts: RMSNorm, grouped key-value heads, per-layer attention kind
-    (``layer_kinds``, cycled over the depth), a router that reads the
+    experts: RMSNorm, grouped key-value heads, per-layer operator kind
+    (``layer_kinds``, cycled over the depth: three kinds of softmax
+    attention and, for a class that states its length, a gated short
+    convolution), a router that reads the
     normed layer input, gated-ReLU experts, an untied head
     (``models/sparse_lm.py``). Defaults are SmallThinker-21BA3B-Instruct
     (PowerInfer, config.json) cut to the share one of the 8 chips of a
@@ -779,6 +787,10 @@ class SparseLMConfig:
     rope_interleave: ClassVar[bool] = False
     num_nextn_predict_layers: ClassVar[int] = 0
     mtp_loss_weight: ClassVar[float] = 0.0
+    # ... and of ``Lfm2MoeLMConfig``: the taps of the layers of kind
+    # ``short_conv`` (0: no such layer)
+    conv_kernel: ClassVar[int] = 0
+    conv_bias: ClassVar[bool] = False
     # fields a configuration's file states and no entry point's flag sets:
     # what the source fixes and models/sparse_lm.py is written for
     # (``validate`` holds each to its one value), and the one assumption
@@ -810,13 +822,16 @@ class SparseLMConfig:
         return layer < self.num_dense_layers
 
     def validate_shapes(self) -> None:
+        # ``full_rope`` is any class's: grouped key-value heads with rotary
+        # over the whole sequence, or latent attention where the class
+        # states ``kv_lora_rank``
         for kind in self.layer_kinds:
             if kind not in VALID_LAYER_KINDS:
                 raise ValueError(f"unknown layer kind {kind!r}")
-            if kind == LAYER_FULL_ROPE and not self.kv_lora_rank:
+            if kind == LAYER_SHORT_CONV and self.conv_kernel < 1:
                 raise ValueError(
-                    f"layer kind {kind!r} is latent attention: a class "
-                    "that states its widths has it (JoyAILMConfig)")
+                    f"layer kind {kind!r} is a short convolution: a class "
+                    "that states its length has it (Lfm2MoeLMConfig)")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
         if self.vocab_text + self.vocab_image != self.vocab_size:
@@ -839,8 +854,8 @@ class SparseLMConfig:
         if (self.tied_embeddings or self.attention_bias
                 or not self.router_softmax_over_chosen):
             raise ValueError(
-                "models/sparse_lm.py has an untied head, no attention bias "
-                "and a softmax over the chosen experts only")
+                "this class has an untied head, no attention bias and a "
+                "softmax over the chosen experts only")
 
 
 def smallthinker21b_model_config(**overrides: Any) -> SparseLMConfig:
@@ -915,6 +930,12 @@ class AfmoeLMConfig(SparseLMConfig):
 
     def validate(self) -> None:
         self.validate_shapes()
+        if self.tied_embeddings or self.attention_bias:
+            raise ValueError(
+                "this class has an untied head and no attention bias")
+        self.validate_blocks_and_router()
+
+    def validate_blocks_and_router(self) -> None:
         if not 0 <= self.num_dense_layers < self.num_hidden_layers:
             raise ValueError(
                 "num_dense_layers must leave an expert layer (the step's "
@@ -927,10 +948,6 @@ class AfmoeLMConfig(SparseLMConfig):
             raise ValueError(
                 f"router_input {self.router_input!r}: the router reads the "
                 "norm the experts read ('post_attention_norm')")
-        if self.tied_embeddings or self.attention_bias:
-            raise ValueError(
-                "models/sparse_lm.py has an untied head and no attention "
-                "bias")
         softmax = self.score_func == "softmax"
         if self.score_func not in ("softmax", "sigmoid") \
                 or softmax != self.router_softmax_over_chosen:
@@ -1037,6 +1054,71 @@ def joyaiflash_model_config(**overrides: Any) -> JoyAILMConfig:
     """Preset ``joyaiflash``: the cell ``joyaiflash-train-solo``
     (benchmark/configs/joyaiflash.json holds ``asdict`` of it)."""
     return dataclasses.replace(JoyAILMConfig(), **overrides)
+
+
+@dataclass(frozen=True)
+class Lfm2MoeLMConfig(AfmoeLMConfig):
+    """``AfmoeLMConfig``'s sigmoid router with a selection bias, leading
+    dense layer and head norms (no shared expert, no output gate, two norms
+    a layer, no embedding scale) with the mechanisms of ``model_type``
+    ``lfm2_moe`` as fields: in the layers of kind ``short_conv`` the
+    operator is no attention but a **gated short convolution** (``[B ; C ;
+    u] = a . W_in``, a causal depthwise convolution of ``conv_kernel`` taps
+    over ``B * u``, ``(C * z) . W_out``; ``conv_bias``: none), the layers of
+    kind ``full_rope`` are grouped-query attention with rotary over the
+    whole sequence on normed 64-wide heads (two a lane tile), and the head
+    is the embedding's table (``tied_embeddings``). Defaults are
+    LFM2-8B-A1B (LiquidAI, config.json) cut to the share one of the 4 chips
+    of a layer holds: the first dense layer and one period of expert layers
+    (``layer_kinds`` names all five: published layers 0 and 2-5), experts
+    0-7 of 32, a quarter of the vocabulary; every width as published.
+    ``window`` is no layer's."""
+
+    num_kv_heads: int = 8
+    head_dim: int = 64
+    expert_width: int = 1792
+    num_experts: int = 32
+    experts_per_token: int = 4
+    vocab_size: int = 16384          # published 65536
+    window: int = 0
+    layer_kinds: Tuple[str, ...] = (
+        LAYER_SHORT_CONV, LAYER_FULL_ROPE, LAYER_SHORT_CONV,
+        LAYER_SHORT_CONV, LAYER_SHORT_CONV)
+    rope_theta: float = 1e6
+    tied_embeddings: bool = True
+    vocab_text: int = 8192
+    vocab_image: int = 8192
+    num_dense_layers: int = 1        # published 2
+    dense_width: int = 7168
+    num_shared_experts: int = 0
+    route_scale: float = 1.0
+    attention_gate: bool = False
+    sandwich_norms: bool = False
+    mup_enabled: bool = False
+    conv_kernel: int = 3             # the source's conv_L_cache
+    conv_bias: bool = False
+
+    no_flag: ClassVar[Tuple[str, ...]] = AfmoeLMConfig.no_flag + (
+        "conv_bias",)
+    decode_missing: ClassVar[Optional[str]] = (
+        "models/decode.py has no short convolution (no state of its last "
+        "conv_kernel - 1 tokens beside the cache), no grouped key-value "
+        "heads with head norms and rotary, no dense gated block and no "
+        "expert layer")
+
+    def validate(self) -> None:
+        self.validate_shapes()
+        self.validate_blocks_and_router()
+        if self.attention_bias or self.conv_bias:
+            raise ValueError(
+                "models/sparse_lm.py has no attention bias and no bias in "
+                "the short convolution or its projections")
+
+
+def lfm2moe_model_config(**overrides: Any) -> Lfm2MoeLMConfig:
+    """Preset ``lfm2moe``: the cell ``lfm2moe-train-solo``
+    (benchmark/configs/lfm2moe.json holds ``asdict`` of it)."""
+    return dataclasses.replace(Lfm2MoeLMConfig(), **overrides)
 
 
 def tiny_model_config(**overrides: Any) -> ModelConfig:

@@ -1,6 +1,6 @@
 """A sparse decoder-only language model on the trainer's normal path.
 
-Two configurations' equations, each mechanism read from a field of the
+Four configurations' equations, each mechanism read from a field of the
 configuration and none from a preset's name. What ``SparseLMConfig``
 describes (its defaults: SmallThinker-21BA3B-Instruct, PowerInfer): every
 layer is
@@ -63,6 +63,28 @@ the same embedding and the same head, cross-entropy against ``t_{i+2}``
 over the T - 2 positions that have one. The step's ``loss`` is ``loss_main
 + mtp_loss_weight * loss_mtp``; both ride beside it in the step's aux.
 
+What ``Lfm2MoeLMConfig`` describes (its defaults: LFM2-8B-A1B, LiquidAI,
+``model_type`` ``lfm2_moe``): ``AfmoeLMConfig``'s dense and expert layers
+(no shared expert, ``route_scale`` 1) with two norms a layer, no gate and no
+embedding scale, around an operator that ``cfg.layer_kinds`` names a layer:
+
+    short_conv (:class:`ShortConv`, parameters under ``conv``):
+      [B ; C ; u] = a . W_in             hidden -> 3 x hidden, no bias
+      z_t = sum_{j<K} taps[j] * (B * u)_{t-(K-1)+j}   depthwise, causal
+                                         (noughts before t = 0), K =
+                                         ``conv_kernel``; * is elementwise
+      h   = x + (C * z) . W_out
+    full_rope (:class:`Attention`; no ``kv_lora_rank``):
+      q,k = rmsnorm(q), rmsnorm(k)       ``qk_norm``, over a head's 64 lanes
+      q,k <- rotary (rotate-half, all of ``head_dim``, position = index)
+      h   = x + attention(q, k, v) . W_o  causal over the whole sequence,
+                                         grouped key-value heads
+
+and the head is the embedding's table (``tied_embeddings``: one leaf, which
+gets the sum of both uses' gradients; :func:`_streamed_nll` contracts it
+where it lies). ``full_rope`` is thus any class's kind: which attention runs
+it is read from ``cfg.kv_lora_rank``.
+
 **The expert layer is told which experts it holds** (``experts_held``
 consecutive ones from ``expert_offset``): it routes over all
 ``num_experts``, computes the part of the result its own experts give for
@@ -116,7 +138,12 @@ block), ``attn/gate`` (the ``W_g`` product, the sigmoid and the multiply),
 pairs in one pass on the lanes, below), ``attn/out`` and its kernels
 ``attn[mosaic]``; the prediction module's under a root ``mtp``
 (``mtp/embed``, ``mtp/norms``, ``mtp/proj``, ``mtp/block/attn...``,
-``mtp/block/ff...``, ``mtp/head``, ``mtp/ce``). The token-major kernel
+``mtp/block/ff...``, ``mtp/head``, ``mtp/ce``); the short convolution's
+``conv/in_proj``, ``conv/mix`` (the two gates and the taps, forward and
+backward: XLA code, :func:`short_conv_mix`, which reads B, C and u as column
+blocks of ``in_proj``'s output and shifts along the tokens; ``conv_layout``
+on the ``setup/warmup`` row says so) and ``conv/out_proj`` (never under
+``attn``: the attention shares keep meaning attention). The token-major kernel
 (``token_major_sum[mosaic]`` in a trace) runs under the scope of its sum,
 ``ff/combine`` or ``ff/dispatch``; the grouped products under
 ``ff/experts``. What is done to each head of queries and keys between
@@ -136,7 +163,12 @@ the one key where ``kv_a`` wrote it in a second small call, one lane
 tile's (T, 128) tables for two heads side by side;
 ``head_norm_kernels.pairs_fit`` is the rule, and where it refuses
 :func:`rotary_interleaved_lanes` runs with tables as wide as the array
-(:func:`pair_rotary`). The latent kernels likewise read ``q_nope``, ``k_nope`` and
+(:func:`pair_rotary`). Heads of 64 lanes (``full_rope`` of
+``Lfm2MoeLMConfig``) take the blockwise kernels' second form, two heads a
+lane tile (``causal_attention_kernels._halves_*_kernel``: ``attn_layout``
+says "2 heads of 64 a lane tile"), while their head norm and rotary stay on
+:func:`head_pass`'s XLA lowering, for ``head_norm_kernels.fits``'s reason,
+which the record keeps. The latent kernels likewise read ``q_nope``, ``k_nope`` and
 ``v`` as column blocks of ``q_b``'s and ``kv_b``'s outputs and make their
 backward's ``delta`` themselves (``attn_operands`` on the ``setup/warmup``
 row says so, or "sliced: <why>": :class:`LatentAttention`).
@@ -160,8 +192,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from dalle_tpu.config import (LAYER_FULL_ROPE, LAYER_WINDOW_ROPE,
-                              SparseLMConfig)
+from dalle_tpu.config import (LAYER_FULL_ROPE, LAYER_SHORT_CONV,
+                              LAYER_WINDOW_ROPE, SparseLMConfig)
 from dalle_tpu.models import attention as attn_mod
 from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
 from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
@@ -268,6 +300,12 @@ def _backward_words(split_why: Optional[str]) -> str:
             else "one kernel a tile")
 
 
+def _heads_a_tile(head_dim: int) -> str:
+    """The kernels' second form, in words; nothing for any other head."""
+    return (f"2 heads of {head_dim} a lane tile, "
+            if head_dim == kernels.HALF else "")
+
+
 def _blockwise_site(kind: str) -> str:
     return f"{kind} attention"
 
@@ -295,14 +333,20 @@ def attend(q, k, v, *, mesh, kind: str, window: Optional[int],
             why_not,
             why_not or f"local q{tuple(q.shape)} over k{tuple(k.shape)}: "
             f"blocks of {kernels.BLOCK}, {group} query heads a key-value "
-            "tile, " + _backward_words(split_why), split_backward=split_why)
+            "tile, " + _heads_a_tile(head_dim) + _backward_words(split_why),
+            split_backward=split_why)
 
     return lowering.site(
         name, fits,
         lambda q, k, v: kernels.causal_attention(
-            q, k, v, window, kernels.BLOCK, lowering.interpret()),
+            q, k, v, window, kernels.BLOCK, lowering.interpret(), head_dim),
         lambda q, k, v: dense_causal_attention(q, k, v, window, head_dim),
         mesh, (LANES_SPEC,) * 3, LANES_SPEC, scope)(q, k, v)
+
+
+# the kinds whose queries and keys are rotated (``full_rope`` here: grouped
+# key-value heads; a class with ``kv_lora_rank`` runs LatentAttention)
+ROPE_KINDS = (LAYER_WINDOW_ROPE, LAYER_FULL_ROPE)
 
 
 class Attention(nn.Module):
@@ -319,7 +363,7 @@ class Attention(nn.Module):
         q = dense(cfg.num_heads * cfg.head_dim, name="q")(a)
         k = dense(cfg.num_kv_heads * cfg.head_dim, name="k")(a)
         v = dense(cfg.num_kv_heads * cfg.head_dim, name="v")(a)
-        rope = self.kind == LAYER_WINDOW_ROPE
+        rope = self.kind in ROPE_KINDS
         if cfg.qk_norm or rope:
             def per_head(x, name):
                 scale = self.param(name, nn.initializers.ones,
@@ -332,7 +376,8 @@ class Attention(nn.Module):
             with jax.named_scope(head_norm.scope(cfg.qk_norm)):
                 q, k = per_head(q, "q_norm"), per_head(k, "k_norm")
         ctx = attend(q, k, v, mesh=self.mesh, kind=self.kind,
-                     window=cfg.window if rope else None,
+                     window=cfg.window if self.kind == LAYER_WINDOW_ROPE
+                     else None,
                      head_dim=cfg.head_dim, scope=self.name)
         if cfg.attention_gate:
             g = dense(cfg.num_heads * cfg.head_dim, name="gate")(a)
@@ -563,6 +608,66 @@ class LatentAttention(nn.Module):
         ctx = latent_attend(q, q_rope, kv, k_rope, mesh=self.mesh, nope=nope,
                             rope=rope, value=value, scope=self.name)
         return dense(cfg.hidden_size, name="out")(ctx)
+
+
+# ---------------------------------------------------------------------------
+# The gated short convolution (layers of kind ``short_conv``)
+# ---------------------------------------------------------------------------
+
+def short_conv_mix(bcu: jax.Array, taps: jax.Array) -> jax.Array:
+    """``C * conv(B * u)`` of ``in_proj``'s output bcu (B, T, 3 D) = ``[B ;
+    C ; u]``, read as three column blocks where the projection wrote them:
+    the depthwise causal convolution ``z_t = sum_j taps[j] (B u)_{t - (K -
+    1) + j}`` (noughts before t = 0) as K shifts along the tokens, each an
+    f32 product with an f32 tap, and no (B, T, K, D) array; the two gates
+    in ``bcu``'s dtype. taps: (K, D) f32."""
+    k, d = taps.shape
+    gate_in, gate_out, u = (bcu[..., i * d:(i + 1) * d] for i in range(3))
+    bu = gate_in * u
+    taps = taps.astype(jnp.float32)
+    z = taps[k - 1] * bu.astype(jnp.float32)
+    for back in range(1, min(k, bu.shape[1])):
+        earlier = jnp.pad(bu[:, :-back], ((0, 0), (back, 0), (0, 0)))
+        z = z + taps[k - 1 - back] * earlier.astype(jnp.float32)
+    return gate_out * z.astype(bcu.dtype)
+
+
+class ShortConv(nn.Module):
+    """``(C * conv(B * u)) . W_out`` with ``[B ; C ; u] = a . W_in`` (module
+    docstring): the operator of a ``short_conv`` layer, under the name
+    ``conv``. Scopes ``conv/in_proj``, ``conv/mix`` (the two gates and the
+    taps, XLA code) and ``conv/out_proj``. Leaves ``in_proj/kernel`` (D, 3
+    D), ``taps`` (K, D) (the source's depthwise weight (D, 1, K), a tap a
+    row: tap K - 1 weighs the token itself) and ``out_proj/kernel``."""
+    cfg: SparseLMConfig
+
+    @nn.compact
+    def __call__(self, a: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        pdt = jnp.dtype(cfg.param_dtype)
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=jnp.dtype(cfg.dtype), param_dtype=pdt)
+        bcu = dense(3 * cfg.hidden_size, name="in_proj")(a)
+        taps = self.param(
+            "taps", nn.initializers.variance_scaling(
+                1.0, "fan_in", "truncated_normal", in_axis=0, out_axis=1),
+            (cfg.conv_kernel, cfg.hidden_size), pdt)
+        with jax.named_scope("mix"):
+            y = short_conv_mix(bcu, taps)
+        return dense(cfg.hidden_size, name="out_proj")(y)
+
+
+def conv_layout(cfg: SparseLMConfig) -> str:
+    """The ``setup/warmup`` row's ``conv_layout``: a function of the
+    configuration (the mix is XLA code on every backend and mesh)."""
+    kinds = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
+    convs = kinds.count(LAYER_SHORT_CONV)
+    return (f"gated short convolution: {convs} of {len(kinds)} layers, "
+            f"{cfg.conv_kernel} taps, causal, depthwise over "
+            f"{cfg.hidden_size} lanes; conv/mix is XLA code: B, C and u read "
+            f"as column blocks of in_proj's (B, T, {3 * cfg.hidden_size}) "
+            f"output in place, the taps as {cfg.conv_kernel - 1} shifts "
+            "along the tokens in f32 (no Mosaic kernel)")
 
 
 # ---------------------------------------------------------------------------
@@ -1069,8 +1174,10 @@ class ExpertLayer(nn.Module):
 
 
 class Layer(nn.Module):
-    """One layer; ``dense``: its feed-forward is the dense gated block
-    (no router, no counters). With ``cfg.sandwich_norms`` the attention's
+    """One layer: the operator its ``kind`` names (a softmax attention
+    under ``attn``, or the gated short convolution under ``conv``) and a
+    feed-forward; ``dense``: that is the dense gated block (no router, no
+    counters). With ``cfg.sandwich_norms`` the attention's
     and the feed-forward's results are normed before they join the
     residual: four norms a layer."""
     cfg: SparseLMConfig
@@ -1089,7 +1196,9 @@ class Layer(nn.Module):
         a = norm("attn_norm", x)
         if early:
             idx, p = ff.route(a)
-        if self.kind == LAYER_FULL_ROPE:
+        if self.kind == LAYER_SHORT_CONV:
+            y = ShortConv(cfg, name="conv")(a)
+        elif self.kind == LAYER_FULL_ROPE and cfg.kv_lora_rank:
             y = LatentAttention(cfg, self.mesh, name="attn")(a)
         else:
             y = Attention(cfg, self.kind, self.mesh, name="attn")(a)
@@ -1128,10 +1237,14 @@ class Layer(nn.Module):
 # it differentiates, whatever the compiler.
 KEPT_OF_A_LAYER = ("attn_out", "attn_stats", "chosen")
 
-def _streamed_nll(h, kernel, targets, weights, chunk: int):
+def _streamed_nll(h, kernel, targets, weights, chunk: int,
+                  tied: bool = False):
     """Sums of ``weights`` x next-token negative log-likelihood over the
     rows of ``h`` (N, D), ``chunk`` rows of the (N, V) logits alive at a
-    time and none kept for the backward pass. weights: (N, n_sums)."""
+    time and none kept for the backward pass. weights: (N, n_sums).
+    kernel: the head (D, V), or with ``tied`` the embedding's table (V, D),
+    contracted over its second axis where it lies: no transposed copy
+    stands beside it over the scan."""
     n = h.shape[0]
     pad = -n % chunk
     if pad:
@@ -1143,7 +1256,9 @@ def _streamed_nll(h, kernel, targets, weights, chunk: int):
     def body(sums, xs):
         hc, tc, wc = xs
         with jax.named_scope("head"):
-            logits = jnp.dot(hc, kernel, preferred_element_type=jnp.float32)
+            logits = jax.lax.dot_general(
+                hc, kernel, (((1,), (1 if tied else 0,)), ((), ())),
+                preferred_element_type=jnp.float32)
         with jax.named_scope("ce"):
             nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
                 logits, tc[:, None], axis=-1)[:, 0]
@@ -1223,8 +1338,12 @@ class SparseLM(nn.Module):
                 counters.append(c)
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
                                    (cfg.hidden_size,), pdt), cfg.rms_eps)
-        head = self.param("lm_head", nn.initializers.normal(stddev=0.02),
-                          (cfg.hidden_size, cfg.vocab_size), pdt)
+        # the head: a leaf of its own, or the embedding's table, which then
+        # gets the sum of both uses' gradients and is one leaf to LAMB
+        tied = cfg.tied_embeddings
+        head = table if tied else self.param(
+            "lm_head", nn.initializers.normal(stddev=0.02),
+            (cfg.hidden_size, cfg.vocab_size), pdt)
 
         # position t predicts token t + 1; the last has nothing to predict
         b, t = ids.shape
@@ -1242,7 +1361,7 @@ class SparseLM(nn.Module):
             weights = weights * shifted[..., None].astype(jnp.float32)
         sums = _streamed_nll(
             x.reshape(b * t, -1), head.astype(dt), targets.reshape(-1),
-            weights.reshape(b * t, 2), min(cfg.head_chunk, b * t))
+            weights.reshape(b * t, 2), min(cfg.head_chunk, b * t), tied)
         # normalised over the WHOLE (micro)batch: under the accumulation's
         # shard_map over dp the denominators are summed over the shards
         # and this shard returns its share (as DALLE)
@@ -1273,7 +1392,7 @@ class SparseLM(nn.Module):
                 mtp_sums = _streamed_nll(
                     z.reshape(b * t, -1), head.astype(dt),
                     shift(ids, 2).reshape(-1), weights.reshape(b * t, 1),
-                    min(cfg.head_chunk, b * t))
+                    min(cfg.head_chunk, b * t), tied)
             mtp_denom = sum_over_manual_data_axes(jnp.sum(weights))
             if loss_mask is not None:
                 mtp_denom = jnp.maximum(mtp_denom, 1.0)
@@ -1364,7 +1483,9 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     holds."""
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     tokens = cfg.total_seq_len
-    kinds = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
+    layers = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
+    # the attention layers (a short convolution has ``conv_layout``)
+    kinds = [k for k in layers if k != LAYER_SHORT_CONV]
     calls = [(_blockwise_site(k), _blockwise_key(
         tokens, cfg.num_heads * cfg.head_dim,
         cfg.num_kv_heads * cfg.head_dim, tp)) for k in kinds]
@@ -1380,6 +1501,8 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
         if not split_why:
             backward += f" ({on} of {len(kinds)} layers)"
     windows = sum(k == LAYER_WINDOW_ROPE for k in kinds)
+    whole = sum(k == LAYER_FULL_ROPE for k in kinds)
+    ropes = windows + whole
 
     def passes(norm: bool, rotary: bool):
         """Queries' and keys' per-head work of one kind: the same two
@@ -1395,14 +1518,14 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     words = ""
     if cfg.qk_norm:
         words += ", normed queries and keys " + lowering_of(
-            [c for rotary in {k == LAYER_WINDOW_ROPE for k in kinds}
+            [c for rotary in {k in ROPE_KINDS for k in kinds}
              for c in passes(True, rotary)],
             f"one pass on the lanes: {len(kinds)} of {len(kinds)} layers")
-    if windows:
+    if ropes:
         words += ", rotary " + lowering_of(
             passes(cfg.qk_norm, True),
             ("in the head pass" if cfg.qk_norm else "one pass on the lanes")
-            + f": {windows} of {windows} rope layers")
+            + f": {ropes} of {ropes} rope layers")
     first, last = cfg.expert_offset, cfg.expert_offset + cfg.experts_held - 1
     devices = mesh.size if mesh is not None else 1
     summed = lowering.recorded(SUM_SITE, _sum_key(
@@ -1423,16 +1546,31 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     if cfg.num_dense_layers:
         beside += (f", layers 0-{cfg.num_dense_layers - 1} dense "
                    f"{cfg.dense_width}")
+    of_kind = [(len(kinds) - ropes, "full no-rope"),
+               (windows, f"window {cfg.window} rope"), (whole, "full rope")]
+    # the first two always (the accepted cells' words); with a ``full_rope``
+    # layer only the kinds the configuration has
+    of_kind = " + ".join(f"{n} {what}" for n, what in of_kind
+                         if n or not (whole or what == "full rope"))
     attn_layout = (
-        f"blockwise {kernels.BLOCK}: {on} of {len(kinds)} layers, "
-        f"{len(kinds) - windows} full no-rope + {windows} window "
-        f"{cfg.window} rope, {cfg.num_heads // cfg.num_kv_heads} query "
+        f"blockwise {kernels.BLOCK}: {on} of {len(kinds)} "
+        + "attention " * (len(kinds) < len(layers)) + f"layers, {of_kind}, "
+        + _heads_a_tile(cfg.head_dim)
+        + f"{cfg.num_heads // cfg.num_kv_heads} query "
         f"heads a key-value head{backward}"
         + words
         + ", gated output" * cfg.attention_gate)
     said = {"attn_layout": attn_layout}
-    if LAYER_FULL_ROPE in kinds:
+    if cfg.kv_lora_rank:
         said = _latent_records(cfg, tp)
+    if len(kinds) < len(layers):
+        said["conv_layout"] = conv_layout(cfg)
+    if cfg.tied_embeddings:
+        said["head_layout"] = (
+            f"tied: the head is the embedding's table ({cfg.vocab_size} x "
+            f"{cfg.hidden_size}), contracted where it lies in the streamed "
+            "cross-entropy; one leaf, the sum of both uses' gradients, one "
+            "LAMB trust ratio")
     if cfg.num_nextn_predict_layers:
         said["mtp_layout"] = (
             "one prediction module after the final norm: [norm(next "
@@ -1442,7 +1580,7 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
             f"{cfg.mtp_loss_weight:g}")
     return {
         **said,
-        "layer_loop": (f"unrolled: {len(kinds)} layers, each "
+        "layer_loop": (f"unrolled: {len(layers)} layers, each "
                        "rematerialised but its attention"),
         "moe_layout": (
             f"{cfg.experts_held} of {cfg.num_experts} experts held "

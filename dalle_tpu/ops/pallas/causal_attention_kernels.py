@@ -33,7 +33,8 @@ scores and ``do v^T`` a second time: 2 + 7 = 9). Every accumulator sums in
 the same order either way.
 
 **Layout.** As the zoo kernels since PR 28, the projections' own
-tokens-major arrays, one 128-wide head a lane tile (``head_dim`` is 128):
+tokens-major arrays, one 128-wide head a lane tile (``head_dim`` 128; 64,
+two a tile, further down):
 ``q`` (B, T, H*128), ``k``/``v`` (B, T, G*128) for G key-value heads. One
 grid step takes a key-value head's (block, 128) tiles of ``k`` and ``v``
 once and the ``H / G`` query heads that read them, side by side in the
@@ -80,6 +81,25 @@ their block over the run, so they are fetched once): no XLA reduce and no
 Where its accumulators do not fit VMEM :func:`latent_fits` refuses the
 shapes.
 
+**Two 64-wide heads a lane tile** (``head_dim`` 64, :data:`HALF`): the same
+arrays, grid, band walk, statistics layout and ``_call``; a grid step is a
+key-value *tile*, two key-value heads side by side, and the ``H / G`` query
+tiles (2 ``H / G`` query heads, local head ``h`` in statistics lane ``h``)
+that read them. No array is 64 lanes wide: a head is told from its
+neighbour by where the other operand of a product is nought. Once a grid
+step the key and the value tile are *placed* (:func:`_placed_halves`: the
+key-value head of local query head ``h``, half ``h // (H / G)`` of the
+tile, moved by one lane rotate where it has to be into the half ``h % 2``
+that the query head has in its own tile, noughts in the other half), so
+``q_tile k_placed^T`` is one head's scores over 128 lanes of which 64 are
+nought, ``p v_placed`` lands in the head's own half of the accumulator, and
+``ds k_placed`` in its half of ``dq``. ``dk`` and ``dv`` are ``ds^T q`` and
+``p^T do`` with the *other* head of the query tile noughted, summed apart
+for the heads that sit in their key-value head's half and for those that
+sit in the other, which one rotate a grid step brings home
+(``_halves_*_kernel``; the MXU runs 128 deep and 128 wide for the 64 a head
+needs, as in a 128-wide head's products at half the useful work).
+
 Scores, softmax and statistics are f32; the MXU operands are in the
 operands' dtype (bf16 in training).
 """
@@ -97,6 +117,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
+HALF = LANES // 2                 # a 64-wide head: two a lane tile
 BLOCK = 512
 MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 # what every call asks of the compiler, and what the one-kernel backward's
@@ -107,11 +128,12 @@ _NT = (((1,), (1,)), ((), ()))       # a @ b.T
 
 def blockwise_fits(q_width: int, kv_width: int, head_dim: int) -> Optional[str]:
     """None where the kernels take these local shapes, else why not."""
-    if head_dim != LANES:
-        return f"head_dim {head_dim} is not one {LANES}-lane tile"
+    if head_dim not in (LANES, HALF):
+        return (f"head_dim {head_dim} is neither one {LANES}-lane tile nor "
+                "half of one")
     if q_width % kv_width or kv_width % LANES:
         return f"{q_width} query lanes over {kv_width} key-value lanes"
-    if q_width // kv_width > LANES:
+    if q_width // kv_width * (LANES // head_dim) > LANES:
         return "more query heads a group than statistics lanes"
     return None
 
@@ -122,7 +144,9 @@ def fused_backward_fits(tokens: int, group: int, itemsize: int,
     ``dk`` and ``dv`` in VMEM at these local shapes (``tokens`` a sample,
     ``group`` query heads a key-value head, operands of ``itemsize``
     bytes), else why not: then the backward is the ``dq`` and the
-    ``dk``/``dv`` kernel, which hold a block each."""
+    ``dk``/``dv`` kernel, which hold a block each. With two 64-wide heads a
+    lane tile the same sizes are a key-value tile's and its ``group`` query
+    tiles'."""
     t = tokens + -tokens % block
     tile = block * LANES
     need = (2 * t * LANES * 4                   # dk, dv accumulators, f32
@@ -181,16 +205,21 @@ def _tile_backward(q, do, k, v, allowed, lse, delta, scale):
     return prob, prob * (dp - delta) * scale
 
 
-def _softmax_step(h: int, s, v, m_s, l_s, acc_s, block: int):
+def _softmax_step(h: int, s, v, m_s, l_s, acc_s, block: int, mine=None):
     """Head ``h``'s masked (block, block) scores of one key block folded
-    into its running maximum, sum and accumulator."""
+    into its running maximum, sum and accumulator. ``mine``: with two heads
+    a lane tile, (block, 128) bool, the head's half of its tile ``h // 2``
+    (``v`` is nought in the other half, whose accumulator stays)."""
     m_prev, l_prev = m_s[h], l_s[h]                  # (block, 128)
     m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
     e = jnp.exp(s - jnp.tile(m_next, (1, block // LANES)))
     alpha = jnp.exp(m_prev - m_next)
     l_s[h] = alpha * l_prev + jnp.sum(e, axis=1)[:, None]
     m_s[h] = m_next
-    acc_s[:, _head(h)] = alpha * acc_s[:, _head(h)] + jnp.dot(
+    lanes = _head(h)
+    if mine is not None:
+        lanes, alpha = _head(h // 2), jnp.where(mine, alpha, 1.0)
+    acc_s[:, lanes] = alpha * acc_s[:, lanes] + jnp.dot(
         e.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
 
@@ -331,6 +360,211 @@ def _causal_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
 
 
 # ---------------------------------------------------------------------------
+# Two 64-wide heads a lane tile
+# ---------------------------------------------------------------------------
+
+def _lane_half(block: int):
+    """(block, 128) int32: 0 on a tile's first 64 lanes, 1 on the rest."""
+    return jax.lax.broadcasted_iota(jnp.int32, (block, LANES), 1) // HALF
+
+
+def _placed_halves(x, half, group: int):
+    """A key-value tile's two heads where a step's query heads need them:
+    ``{(b, a): (block, 128)}``, head ``b`` of ``x`` in half ``a`` and
+    noughts in the other, for the ``(h // group, h % 2)`` of the step's
+    2 ``group`` query heads. One lane rotate at most, and a select each."""
+    swapped = None
+    placed = {}
+    for h in range(2 * group):
+        b, a = h // group, h % 2
+        if (b, a) in placed:
+            continue
+        source = x
+        if a != b:
+            if swapped is None:
+                # Mosaic rotates 32-bit lanes only: widened and back, exact
+                swapped = pltpu.roll(x.astype(jnp.float32), HALF,
+                                     1).astype(x.dtype)
+            source = swapped
+        placed[b, a] = jnp.where(half == a, source, jnp.zeros_like(x))
+    return placed
+
+
+def _halves_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
+                       v_ref, o_ref, stats_ref, m_s, l_s, acc_s, *,
+                       scale: float, group: int, block: int,
+                       window: Optional[int]):
+    p = pl.program_id(2)
+
+    @pl.when(first_ref[p] == 1)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, -jnp.inf, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    allowed = _allowed(qi_ref[p], ki_ref[p], block, window)
+    half = _lane_half(block)
+    ks = _placed_halves(k_ref[0], half, group)
+    vs = _placed_halves(v_ref[0], half, group)
+    for h in range(2 * group):
+        at = h // group, h % 2
+        s = jax.lax.dot_general(q_ref[0, :, _head(h // 2)], ks[at], _NT,
+                                preferred_element_type=jnp.float32) * scale
+        _softmax_step(h, jnp.where(allowed, s, MASK_VALUE), vs[at], m_s, l_s,
+                      acc_s, block, mine=half == h % 2)
+
+    @pl.when(last_ref[p] == 1)
+    def _():
+        lane = jax.lax.broadcasted_iota(jnp.int32, (block, LANES), 1)
+        stats = jnp.zeros((block, LANES), jnp.float32)
+        for h in range(2 * group):
+            stats = jnp.where(lane == h, m_s[h] + jnp.log(l_s[h]), stats)
+        stats_ref[0, 0] = stats
+        for c in range(group):
+            l = jnp.where(half == 0, l_s[2 * c], l_s[2 * c + 1])
+            o_ref[0, :, _head(c)] = (acc_s[:, _head(c)] / l).astype(
+                o_ref.dtype)
+
+
+def _halves_backward(h: int, q_ref, do_ref, ks, vs, allowed, half, stats,
+                     delta, scale, group: int, own: bool):
+    """Local query head ``h``'s tile in the backward: its placed key, its
+    probabilities and scores' cotangent (``_tile_backward``) and its query
+    and output cotangent, the tile's other head noughted where ``own`` (the
+    products that sum over the queries, ``dk`` and ``dv``, read them)."""
+    at, lanes = (h // group, h % 2), _head(h // 2)
+    q, do = q_ref[0, :, lanes], do_ref[0, :, lanes]
+    if own:
+        mine = half == h % 2
+        q = jnp.where(mine, q, jnp.zeros_like(q))
+        do = jnp.where(mine, do, jnp.zeros_like(do))
+    prob, ds = _tile_backward(q, do, ks[at], vs[at], allowed,
+                              stats[:, h:h + 1], delta[:, h:h + 1], scale)
+    return q, do, ks[at], prob, ds.astype(q.dtype)
+
+
+def _home(parts):
+    """``dk`` or ``dv`` of a key-value tile from its query heads' (block,
+    128) parts, each in the half its query head has: those of a head that
+    sits in its key-value head's half as they are, the others through one
+    lane rotate. parts: [(crossed, part)]."""
+    total = sum(part for crossed, part in parts if not crossed)
+    crossed = [part for crossed, part in parts if crossed]
+    return total + pltpu.roll(sum(crossed), HALF, 1) if crossed else total
+
+
+def _halves_dq_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
+                      v_ref, do_ref, stats_ref, delta_ref, dq_ref, dq_s, *,
+                      scale: float, group: int, block: int,
+                      window: Optional[int]):
+    p = pl.program_id(2)
+
+    @pl.when(first_ref[p] == 1)
+    def _():
+        dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
+
+    allowed = _allowed(qi_ref[p], ki_ref[p], block, window)
+    half = _lane_half(block)
+    ks = _placed_halves(k_ref[0], half, group)
+    vs = _placed_halves(v_ref[0], half, group)
+    stats, delta = stats_ref[0, 0], delta_ref[0, 0]
+    for h in range(2 * group):
+        _, _, k, _, ds = _halves_backward(
+            h, q_ref, do_ref, ks, vs, allowed, half, stats, delta, scale,
+            group, own=False)
+        dq_s[:, _head(h // 2)] += jnp.dot(
+            ds, k, preferred_element_type=jnp.float32)
+
+    @pl.when(last_ref[p] == 1)
+    def _():
+        dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
+
+
+def _halves_dkv_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
+                       v_ref, do_ref, stats_ref, delta_ref, dk_ref, dv_ref,
+                       dk_s, dv_s, *, scale: float, group: int, block: int,
+                       window: Optional[int]):
+    p = pl.program_id(2)
+
+    @pl.when(first_ref[p] == 1)
+    def _():
+        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    allowed = _allowed(qi_ref[p], ki_ref[p], block, window)
+    half = _lane_half(block)
+    ks = _placed_halves(k_ref[0], half, group)
+    vs = _placed_halves(v_ref[0], half, group)
+    stats, delta = stats_ref[0, 0], delta_ref[0, 0]
+    dk, dv = [], []
+    for h in range(2 * group):
+        q, do, _, prob, ds = _halves_backward(
+            h, q_ref, do_ref, ks, vs, allowed, half, stats, delta, scale,
+            group, own=True)
+        crossed = h // group != h % 2
+        dv.append((crossed, jnp.dot(prob.astype(do.dtype).T, do,
+                                    preferred_element_type=jnp.float32)))
+        dk.append((crossed, jnp.dot(ds.T, q,
+                                    preferred_element_type=jnp.float32)))
+    dk_s[...] += _home(dk)
+    dv_s[...] += _home(dv)
+
+    @pl.when(last_ref[p] == 1)
+    def _():
+        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _halves_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
+                       v_ref, do_ref, stats_ref, delta_ref, dq_ref, dk_ref,
+                       dv_ref, dq_s, dk_s, dv_s, *, scale: float, group: int,
+                       block: int, window: Optional[int]):
+    """``_causal_bwd_kernel`` for a key-value tile of two heads: one pass
+    over the band, ``dk_s`` and ``dv_s`` (T, 128) the tile's whole
+    sequence."""
+    p = pl.program_id(2)
+
+    @pl.when(p == 0)
+    def _():
+        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    @pl.when(first_ref[p] == 1)
+    def _():
+        dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
+
+    allowed = _allowed(qi_ref[p], ki_ref[p], block, window)
+    half = _lane_half(block)
+    ks = _placed_halves(k_ref[0], half, group)
+    vs = _placed_halves(v_ref[0], half, group)
+    stats, delta = stats_ref[0, 0], delta_ref[0, 0]
+    keys = pl.ds(pl.multiple_of(ki_ref[p] * block, block), block)
+    dk, dv = [], []
+    for h in range(2 * group):
+        q, do, k, prob, ds = _halves_backward(
+            h, q_ref, do_ref, ks, vs, allowed, half, stats, delta, scale,
+            group, own=True)
+        crossed = h // group != h % 2
+        dq_s[:, _head(h // 2)] += jnp.dot(
+            ds, k, preferred_element_type=jnp.float32)
+        dv.append((crossed, jnp.dot(prob.astype(do.dtype).T, do,
+                                    preferred_element_type=jnp.float32)))
+        dk.append((crossed, jnp.dot(ds.T, q,
+                                    preferred_element_type=jnp.float32)))
+    dk_s[keys, :] += _home(dk)
+    dv_s[keys, :] += _home(dv)
+
+    @pl.when(last_ref[p] == 1)
+    def _():
+        dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
+
+    @pl.when(p == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+# ---------------------------------------------------------------------------
 # pallas_call: grid (B, key-value heads, pairs of the band)
 # ---------------------------------------------------------------------------
 
@@ -404,15 +638,17 @@ def _stats_like(q, group: int):
                                 jnp.float32)
 
 
-def _fwd(q, k, v, window, block, interpret):
+def _fwd(q, k, v, window, block, interpret, head_dim):
     group = q.shape[2] // k.shape[2]
-    rows = pltpu.VMEM((group, block, LANES), jnp.float32)
+    per = LANES // head_dim                 # heads a lane tile
+    rows = pltpu.VMEM((per * group, block, LANES), jnp.float32)
     return _call(
-        _causal_fwd_kernel, [(q, "q"), (k, "kv"), (v, "kv")],
+        _causal_fwd_kernel if per == 1 else _halves_fwd_kernel,
+        [(q, "q"), (k, "kv"), (v, "kv")],
         [(q, "q"), (_stats_like(q, group), "stats")],
         [rows, rows, pltpu.VMEM((block, group * LANES), jnp.float32)],
         t=q.shape[1], group=group, block=block, window=window,
-        key_major=False, interpret=interpret)
+        key_major=False, interpret=interpret, scale=head_dim ** -0.5)
 
 
 def _padded(x, block: int):
@@ -420,26 +656,28 @@ def _padded(x, block: int):
     return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def causal_attention(q, k, v, window: Optional[int] = None,
-                     block: int = BLOCK, interpret: bool = False):
-    """softmax(q k^T / sqrt(128), key <= query [and query - key < window])
-    v, query head ``h`` reading key-value head ``h // (H / G)``.
+                     block: int = BLOCK, interpret: bool = False,
+                     head_dim: int = LANES):
+    """softmax(q k^T / sqrt(d), key <= query [and query - key < window])
+    v, query head ``h`` reading key-value head ``h // (H / G)``, heads of
+    ``head_dim`` d = 128 (one a lane tile) or 64 (two).
 
-    q: (B, T, H*128); k, v: (B, T, G*128). Returns (B, T, H*128). ``T``
+    q: (B, T, H*d); k, v: (B, T, G*d). Returns (B, T, H*d). ``T``
     need not be a multiple of ``block``: the rows are padded at the end,
     where causality keeps them out of every real row's sum.
     """
     t = q.shape[1]
     out, _ = _fwd(*(_padded(x, block) for x in (q, k, v)), window, block,
-                  interpret)
+                  interpret, head_dim)
     return out[:, :t]
 
 
-def _vjp_fwd(q, k, v, window, block, interpret):
+def _vjp_fwd(q, k, v, window, block, interpret, head_dim):
     t = q.shape[1]
     out, stats = _fwd(*(_padded(x, block) for x in (q, k, v)), window,
-                      block, interpret)
+                      block, interpret, head_dim)
     # named on the residuals themselves, so that a remat policy can keep
     # them and the backward pass does not run the forward kernel again
     out = checkpoint_name(out[:, :t], "attn_out")
@@ -447,37 +685,42 @@ def _vjp_fwd(q, k, v, window, block, interpret):
     return out, (q, k, v, out, stats)
 
 
-def _delta(dout, out, group: int, block: int):
-    """rowsum(do * o), a head: (B, T, G, group) -> lanes of the statistics'
-    layout, the rows padded to whole blocks."""
+def _delta(dout, out, heads: int, block: int, head_dim: int = LANES):
+    """rowsum(do * o), a head: (B, T, grid steps, ``heads`` a step) -> lanes
+    of the statistics' layout, the rows padded to whole blocks."""
     b, t, _ = out.shape
     delta = jnp.sum((dout.astype(jnp.float32) * out.astype(jnp.float32))
-                    .reshape(b, t, -1, group, LANES), axis=-1)
+                    .reshape(b, t, -1, heads, head_dim), axis=-1)
     return jnp.pad(delta.swapaxes(1, 2),
-                   ((0, 0), (0, 0), (0, -t % block), (0, LANES - group)))
+                   ((0, 0), (0, 0), (0, -t % block), (0, LANES - heads)))
 
 
-def _vjp_bwd(window, block, interpret, res, dout):
+def _vjp_bwd(window, block, interpret, head_dim, res, dout):
     q, k, v, out, stats = res
     b, t, width = q.shape
     group = width // k.shape[2]
-    delta = _delta(dout, out, group, block)
+    per = LANES // head_dim
+    delta = _delta(dout, out, per * group, block, head_dim)
     q, k, v, dout = (_padded(x, block) for x in (q, k, v, dout))
     operands = [(q, "q"), (k, "kv"), (v, "kv"), (dout, "q"),
                 (stats, "stats"), (delta, "stats")]
     kw = dict(t=q.shape[1], group=group, block=block, window=window,
-              interpret=interpret)
+              interpret=interpret, scale=head_dim ** -0.5)
+    fused, dq_only, dkv_only = (
+        (_causal_bwd_kernel, _causal_dq_kernel, _causal_dkv_kernel)
+        if per == 1 else
+        (_halves_bwd_kernel, _halves_dq_kernel, _halves_dkv_kernel))
     dq_s = pltpu.VMEM((block, group * LANES), jnp.float32)
     if fused_backward_fits(t, group, q.dtype.itemsize, block) is None:
         acc = pltpu.VMEM((q.shape[1], LANES), jnp.float32)
-        dq, dk, dv = _call(_causal_bwd_kernel, operands,
+        dq, dk, dv = _call(fused, operands,
                            [(q, "q"), (k, "kv_all"), (v, "kv_all")],
                            [dq_s, acc, acc], key_major=False, **kw)
     else:
-        (dq,) = _call(_causal_dq_kernel, operands, [(q, "q")], [dq_s],
+        (dq,) = _call(dq_only, operands, [(q, "q")], [dq_s],
                       key_major=False, **kw)
         acc = pltpu.VMEM((block, LANES), jnp.float32)
-        dk, dv = _call(_causal_dkv_kernel, operands, [(k, "kv"), (v, "kv")],
+        dk, dv = _call(dkv_only, operands, [(k, "kv"), (v, "kv")],
                        [acc, acc], key_major=True, **kw)
     return dq[:, :t], dk[:, :t], dv[:, :t]
 

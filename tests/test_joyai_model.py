@@ -608,9 +608,12 @@ def test_the_preset_is_a_class_of_its_own_and_the_parents_keep_theirs():
     assert stated == {"qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
                       "rope_interleave", "mtp_loss_weight"}
     assert not flags & stated
-    # the kind decides, and needs a class that states latent widths
-    with pytest.raises(ValueError, match="full_rope"):
-        SparseLMConfig(layer_kinds=("full_rope",)).validate()
+    # the kind and the class's widths decide: ``full_rope`` without
+    # ``kv_lora_rank`` is grouped key-value heads with rotary over the whole
+    # sequence (tests/test_lfm2_model.py), any class's
+    SparseLMConfig(layer_kinds=("full_rope",)).validate()
+    with pytest.raises(ValueError, match="unknown layer kind"):
+        SparseLMConfig(layer_kinds=("latent",)).validate()
     with pytest.raises(ValueError, match="interleaved pairs"):
         dataclasses.replace(cfg, rope_interleave=False).validate()
     with pytest.raises(ValueError, match="one prediction module"):

@@ -145,7 +145,10 @@ def test_the_form_of_a_site(interpret, monkeypatch, caplog, lowering_record):
     with caplog.at_level(logging.INFO, logger=lowering_record.logger.name):
         for lanes in (8, 8, 6):
             np.testing.assert_array_equal(run(lanes), 0.5)
-    said = [r.getMessage() for r in caplog.records]
+    # the seam's own words: a trainer run earlier in the process leaves
+    # the compile counter saying what compiles after its last step
+    said = [r.getMessage() for r in caplog.records
+            if r.name == lowering_record.logger.name]
     if not interpret:
         assert said == ["halving: XLA lowering (no Mosaic backend)"]
         assert not lowering_record._RECORD

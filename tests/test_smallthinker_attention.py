@@ -152,7 +152,7 @@ def test_only_the_tiles_inside_the_band_are_visited():
 
 
 @pytest.mark.parametrize("q_width, kv_width, head_dim, fits", [
-    (28 * 128, 4 * 128, 128, True), (4 * 64, 2 * 64, 64, False),
+    (28 * 128, 4 * 128, 128, True), (4 * 32, 4 * 32, 32, False),
     (3 * 128, 2 * 128, 128, False)])
 def test_shapes_the_kernels_take(q_width, kv_width, head_dim, fits):
     assert (K.blockwise_fits(q_width, kv_width, head_dim) is None) == fits
