@@ -102,21 +102,30 @@ the count (:func:`held_experts`). Where the grouped kernels cannot run (no
 Mosaic backend, sizes that are not lane tiles) the dense lowering is the
 layer.
 
-**The sorted lowering moves rows four times**: tokens to rows and the
-output's cotangent to rows (one gather of the buffer's rows each,
-:func:`_rows_of`), and two token-major sums, combine's forward ``y[n] =
-sum_j p[n, j] ys[row[n, j]]`` and dispatch's backward ``dm[n] = sum_j
-dxs[row[n, j]]`` (:func:`_sum_to_tokens`). The plan sorts the flattened
-(token, slot) assignments by held expert with a *stable* sort and
-``top_k`` names an expert once a token, so **a group's rows ascend by
-token and a tile of consecutive tokens owns one contiguous run of rows in
-every group**: a sum is, a token tile, one copy of a window of rows a held
-expert and a placement on the MXU (ops/pallas/token_sum_kernels.py: only
+**The sorted lowering moves rows four times, all four by runs**: tokens
+to rows and the output's cotangent to rows (:func:`_to_rows`), and two
+token-major sums, combine's forward ``y[n] = sum_j p[n, j] ys[row[n, j]]``
+and dispatch's backward ``dm[n] = sum_j dxs[row[n, j]]``
+(:func:`_sum_to_tokens`). The plan sorts the flattened (token, slot)
+assignments by held expert with a *stable* sort and ``top_k`` names an
+expert once a token, so **a group's rows ascend by token and a tile of
+consecutive tokens owns one contiguous run of rows in every group**: a sum
+is, a token tile, one copy of a window of rows a held expert and a
+placement on the MXU, and the way there is its transpose, a token tile read
+once and placed into a window of every group that is written out whole
+(ops/pallas/token_sum_kernels.py, ``token_major_sum`` and ``rows_of``: only
 rows that hold an assignment are read, each once, nothing is rounded).
-Where that kernel's cost, which grows with the experts held, passes the
-cost of one gather of all N rows a slot, or its windows do not fit VMEM,
-the sums are those gathers (a slot with no row here reads a row of its
-own and is masked): :func:`runs_why_not` is the rule, read off shapes.
+**The cotangent moves in the dtype the caller rounds the result to**
+(``held_experts(..., rounded_to=)``, a fact ``ExpertLayer`` states of its
+own last line: ``dy`` then holds that dtype's numbers widened, and is
+widened again on the rows). Each movement has a cost rule read off its
+shapes and no flag. The sums: where the kernel's cost, which grows with the
+experts held, passes the cost of one gather of all N rows a slot, or its
+windows do not fit VMEM, they are those gathers (a slot with no row here
+reads a row of its own and is masked): :func:`runs_why_not`. Tokens to
+rows: the kernel costs a window a held expert and token tile whatever the
+rows, one XLA gather (:func:`_rows_of`) the bytes it writes, so a buffer of
+few rows keeps the gather: :func:`rows_why_not`.
 
 The model takes the trainer's batches as they are: ``text`` and ``image``
 are the two halves of one token sequence, image ids offset by
@@ -145,7 +154,9 @@ blocks of ``in_proj``'s output and shifts along the tokens; ``conv_layout``
 on the ``setup/warmup`` row says so) and ``conv/out_proj`` (never under
 ``attn``: the attention shares keep meaning attention). The token-major kernel
 (``token_major_sum[mosaic]`` in a trace) runs under the scope of its sum,
-``ff/combine`` or ``ff/dispatch``; the grouped products under
+``ff/combine`` or ``ff/dispatch``, and its transpose (``rows_of[mosaic]``)
+under ``ff/dispatch`` (tokens) or ``ff/combine`` (the cotangent); the
+grouped products under
 ``ff/experts``. What is done to each head of queries and keys between
 their projections and the attention (the head norm where the
 configuration has one, the rotary where the layer has positions) is one
@@ -722,6 +733,53 @@ def runs_why_not(tokens: int, slots: int, held: int, dim: int,
     return why_not
 
 
+# What the two lowerings of tokens -> rows cost on the v5e (my chip runs,
+# PR 49: scripts/rows_of_probe.py, seed 490001, 8 held experts, the four
+# sparse cells' shapes; beside them the traced steps of
+# lfm2moe-train-solo, seed 2147490011, and smallthinker21b-train-solo, seed
+# 2147490022). One XLA gather with its mask, a KiB it writes: 4.68 ns at a
+# width of 2 560 in bf16 and 4.68-4.70 in f32 alone, 4.7-4.8 in the step for
+# the f32 cotangent (alone, a narrow bf16 one reads 3.0; in the step the
+# forward's mask is a pass of its own and the two read 6.5 together: the
+# rule takes the cheaper reading). The kernel, a KiB of the windows it
+# writes (token tiles x held experts x token_sum.WINDOW rows, whatever the
+# buffer's rows): 2.83-2.97 ns in bf16 (760 ns a window of 64 x 2 048, 907
+# of 64 x 2 560; 566-758 and 904 in the step), 5.08 in f32 (the product at
+# HIGHEST).
+GATHER_NS_A_KIB = 4.7
+ROWS_NS_A_KIB = {2: 2.9, 4: 5.1}
+
+ROWS_SITE = "row gather"
+
+
+def _rows_key(held: int, width: int, dtype, what: str):
+    """``what``: "tokens" (the forward's and the replay's ``xs``) or
+    "cotangent" (the backward's ``dy``)."""
+    return held, width, jnp.dtype(dtype).name, what
+
+
+def rows_why_not(tokens: int, rows: int, held: int, dim: int,
+                 dtype) -> Optional[str]:
+    """Why tokens of these shapes go to the buffer's rows by one XLA gather
+    and not by the kernel over runs (``token_sum_kernels.rows_of``); None
+    where they take the kernel. The kernel places and writes a window for
+    every held expert and token tile, whatever the rows; the gather pays
+    for every row it writes: the kernel wins where the buffer has more
+    than about 0.6 rows (1.1 in f32) a row of windows, if the windows fit
+    VMEM. With 8 experts held the 18 432 and 26 624 rows of 8 192 and
+    16 384 tokens take it, 10 240 rows of 8 192 tokens by a hair, 6 144
+    keep the gather."""
+    dtype = jnp.dtype(dtype)
+    why_not = token_sum.fits(held, dim, dtype)
+    if why_not is None:
+        windows = -(-tokens // token_sum.tokens_tile(tokens)) * held
+        if windows * token_sum.WINDOW * ROWS_NS_A_KIB[dtype.itemsize] \
+                > rows * GATHER_NS_A_KIB:
+            why_not = (f"{windows} windows cost more than a gather of "
+                       f"{rows} rows")
+    return why_not
+
+
 def held_key(idx: jax.Array, offset: int, held: int) -> jax.Array:
     """(tokens * k,) the held expert's local index of every assignment,
     ``held`` for an assignment to an expert that lives elsewhere."""
@@ -771,16 +829,19 @@ def dispatch_plan(idx: jax.Array, offset: int, held: int,
     rank = jnp.argsort(order)                 # the inverse permutation
     first_sorted = jnp.cumsum(sizes) - sizes
     first_row, tiles = grouped.tile_plan(sizes, n_tiles, tile)
-    # assignment -> row
+    # assignment -> row. A table of ``held`` entries is read by a select a
+    # held expert, and a row tile's expert is the same for its ``tile``
+    # rows: an XLA gather of scalars costs the v5e 10-20 ns an element
     e = jnp.minimum(key, held - 1)
-    row = (first_row[e] + rank - first_sorted[e]).reshape(n, k)
+    shift = jnp.sum(jnp.where(e[:, None] == jnp.arange(held),
+                              (first_row - first_sorted)[None, :], 0), axis=1)
+    row = (shift + rank).reshape(n, k)
     here = (key.reshape(n, k) < held) & (row < n_tiles * tile)
     # row -> assignment
-    r = jnp.arange(n_tiles * tile)
-    e = tiles.expert[r // tile]
-    within = r - first_row[e]
-    valid = (tiles.active[r // tile] == 1) & (within < sizes[e])
-    a = order[jnp.clip(first_sorted[e] + within, 0, n * k - 1)]
+    of_tile = lambda table: jnp.repeat(table[tiles.expert], tile)
+    within = jnp.arange(n_tiles * tile) - of_tile(first_row)
+    valid = (jnp.repeat(tiles.active, tile) == 1) & (within < of_tile(sizes))
+    a = order[jnp.clip(of_tile(first_sorted) + within, 0, n * k - 1)]
     # a slot with no row here is gathered all the same and masked after:
     # it reads a row of its own (one row for all of them is a hot spot:
     # 0.73 against 0.59 ms a gather on the v5e)
@@ -847,6 +908,25 @@ def _rows_of(source, plan: _Plan):
     return jnp.where(plan.valid[:, None], source[plan.token], 0)
 
 
+def _to_rows(source, plan: _Plan, what: str, **facts):
+    """(rows, D) in ``source``'s dtype: :func:`_rows_of` by the lowering its
+    shapes choose (:func:`rows_why_not`): where the kernel over runs, a
+    tile of tokens is read once and writes its run of rows in every held
+    expert's group. The kernel leaves the rows of inactive row tiles
+    (``plan.written`` on) unwritten: the grouped products skip them, the
+    token-major kernel zeroes them and ``score`` masks them."""
+    (n, held), rows = plan.row_of.shape, plan.token.shape[0]
+    why_not = rows_why_not(n, rows, held, source.shape[1], source.dtype)
+    # a cost rule and no gate, as the sums'
+    lowering.record(ROWS_SITE,
+                    _rows_key(held, source.shape[1], source.dtype, what),
+                    why_not, **facts)
+    if why_not is not None:
+        return _rows_of(source, plan)
+    return token_sum.rows_of(source, plan.row_of, plan.start, plan.written,
+                             rows=rows, interpret=lowering.interpret())
+
+
 def grouped_kernels_why_not(dim: int, width: int) -> Optional[str]:
     """Why the grouped Pallas products cannot take experts of ``dim`` x
     ``width``; None where they can (interpreted, any size)."""
@@ -897,7 +977,7 @@ def _sorted_forward(m, idx, p, gate, up, down, *, offset: int, rows: int,
     (E_h, F, D). Returns (((N, D) f32, assignments computed), _Kept)."""
     with jax.named_scope("dispatch"):
         plan = dispatch_plan(idx, offset, gate.shape[0], rows)
-        xs = _rows_of(m, plan)
+        xs = _to_rows(m, plan, "tokens")
     with jax.named_scope("experts"):
         dot, _ = _grouped_dots(plan)
         g, u = dot(xs, gate), dot(xs, up)
@@ -908,13 +988,17 @@ def _sorted_forward(m, idx, p, gate, up, down, *, offset: int, rows: int,
             _Kept(plan, xs, g, u, ys))
 
 
-def _sorted_backward(kept: _Kept, p, gate, up, down, dy, act: str):
+def _sorted_backward(kept: _Kept, p, gate, up, down, dy, act: str,
+                     stated: bool):
     """Cotangents of (m, p, gate, up, down) from what the forward kept:
-    no product and no gather of the forward pass is run again."""
+    no product and no gather of the forward pass is run again. ``dy`` moves
+    to the rows in the dtype it comes in and is widened there (``stated``:
+    the caller said its numbers are that dtype's, for the record)."""
     plan, xs, g, u, ys = kept
     with jax.named_scope("combine"):
         weight = jnp.where(plan.valid, p[plan.token, plan.slot], 0.0)
-        dy_rows = _rows_of(dy, plan)
+        dy_rows = _to_rows(dy, plan, "cotangent",
+                           stated=stated).astype(jnp.float32)
         dys = (dy_rows * weight[:, None]).astype(ys.dtype)
         # a routing weight's cotangent is its row's <dy, ys>: taken on the
         # rows, then one scalar a slot (not one row a slot)
@@ -955,13 +1039,14 @@ def _every_expert(m, idx, p, gate, up, down, *, offset: int, act: str):
     return y, here
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _held_experts(offset, rows, act, m, idx, p, gate, up, down):
-    return _held_experts_fwd(offset, rows, act, m, idx, p, gate, up,
-                             down)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _held_experts(offset, rows, act, rounded_to, m, idx, p, gate, up, down):
+    return _held_experts_fwd(offset, rows, act, rounded_to, m, idx, p, gate,
+                             up, down)[0]
 
 
-def _held_experts_fwd(offset, rows, act, m, idx, p, gate, up, down):
+def _held_experts_fwd(offset, rows, act, rounded_to, m, idx, p, gate, up,
+                      down):
     # One conditional a direction, written out: lax.cond's own derivative
     # would return both lowerings' residuals from the forward conditional
     # and run neither backward without them. Here the sorted lowering
@@ -983,18 +1068,23 @@ def _held_experts_fwd(offset, rows, act, m, idx, p, gate, up, down):
     return out, (fits, kept, operands)
 
 
-def _held_experts_bwd(offset, rows, act, res, cotangent):
+def _held_experts_bwd(offset, rows, act, rounded_to, res, cotangent):
     fits, kept, (m, idx, p, gate, up, down) = res
     dy = cotangent[0]
+    if rounded_to is not None:
+        # the caller rounds the result to this dtype before anything reads
+        # it, so what comes back are its numbers, widened: exact
+        dy = dy.astype(rounded_to)
 
     def dense(kept, m, p, gate, up, down, dy):
         _, vjp = jax.vjp(lambda m, p, *w: _every_expert(
             m, idx, p, *w, offset=offset, act=act)[0],
             m, p, gate, up, down)
-        return vjp(dy)
+        return vjp(dy.astype(jnp.float32))
 
     def sorted_(kept, m, p, gate, up, down, dy):
-        return _sorted_backward(kept, p, gate, up, down, dy, act)
+        return _sorted_backward(kept, p, gate, up, down, dy, act,
+                                stated=rounded_to is not None)
 
     dm, dp, *dw = jax.lax.cond(fits, sorted_, dense, kept, m, p, gate, up,
                                down, dy)
@@ -1008,12 +1098,20 @@ PRODUCTS_SITE = "expert products"
 
 
 def held_experts(m, idx, p, gate, up, down, *, offset: int, rows: int,
-                 act: str = "relu"):
+                 act: str = "relu", rounded_to=None):
     """The held experts' part of the layer: the sorted lowering where the
     step's assignments to held experts fit ``rows``, the dense one where
     they do not, chosen on the device by the count; the dense one alone
     where the grouped kernels cannot run. Returns ((N, D) f32, the
-    assignments computed)."""
+    assignments computed).
+
+    ``rounded_to``: a fact the caller states, not a request: the dtype it
+    rounds the (N, D) result to (after any sums in f32) before anything
+    else reads it. The result's cotangent then holds numbers of that dtype,
+    widened, and the sorted lowering moves it to the rows in that dtype
+    (half the bytes for a 16-bit one) and widens it there: the same
+    cotangents, bit for bit. A caller that states nothing (None) keeps the
+    f32 movement. The forward's arithmetic is the same either way."""
     def fits(m, idx, p, gate, up, down) -> bool:
         why_not = grouped_kernels_why_not(m.shape[1], gate.shape[2])
         return lowering.chose(
@@ -1022,8 +1120,9 @@ def held_experts(m, idx, p, gate, up, down, *, offset: int, rows: int,
             f"{gate.shape[0]} experts of {m.shape[1]} x {gate.shape[2]}")
 
     return lowering.site(
-        PRODUCTS_SITE, fits, functools.partial(_held_experts, offset, rows,
-                                               act),
+        PRODUCTS_SITE, fits, functools.partial(
+            _held_experts, offset, rows, act,
+            None if rounded_to is None else jnp.dtype(rounded_to)),
         functools.partial(_every_expert, offset=offset, act=act))(
             m, idx, p, gate, up, down)
 
@@ -1139,7 +1238,8 @@ class ExpertLayer(nn.Module):
         y, computed = held_experts(
             m.reshape(b * t, d), idx.reshape(b * t, -1),
             p.reshape(b * t, -1), *self.experts(),
-            offset=cfg.expert_offset, rows=rows, act=cfg.hidden_act)
+            offset=cfg.expert_offset, rows=rows, act=cfg.hidden_act,
+            rounded_to=m.dtype)     # this call's last line
         with jax.named_scope("router"):
             key = held_key(idx.reshape(b * t, -1), cfg.expert_offset,
                            cfg.experts_held)
@@ -1534,6 +1634,22 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
             f"one gather a slot ({summed['why_not']})" if summed["why_not"]
             else f"runs of rows, {summed['tile']} tokens a tile, windows "
             f"of {token_sum.WINDOW} rows")
+    # tokens -> rows: the forward's and the replay's one record, and the
+    # cotangent's in whichever dtype it moved
+    key = functools.partial(_rows_key, cfg.experts_held, cfg.hidden_size)
+    how = lambda said: ("kernel over runs" if said["why_not"] is None
+                        else f"one gather ({said['why_not']})")
+    to_rows = lowering.recorded(ROWS_SITE, key(cfg.dtype, "tokens"))
+    if to_rows is not None:
+        sums += f"; rows from tokens: {how(to_rows)}"
+    for dtype in dict.fromkeys((cfg.dtype, "float32")):
+        moved = lowering.recorded(ROWS_SITE, key(dtype, "cotangent"))
+        if moved is not None:
+            sums += (f"; cotangent gathered as {jnp.dtype(dtype).name} ("
+                     + ("the caller rounds the result to it" if moved["stated"]
+                        else "the caller states no rounding")
+                     + f"), {how(moved)}")
+            break
     # the router's kind, and what stands beside the routed experts
     router = "softmax over the chosen"
     if cfg.score_func == "sigmoid":

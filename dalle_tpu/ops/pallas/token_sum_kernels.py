@@ -1,6 +1,6 @@
 """Token-major sums over rows sorted by expert: every token's sum of
 [weight x] the rows that computed its assignments, reading only rows that
-hold one.
+hold one; and their transpose, every token to the rows of its assignments.
 
 ``rows`` is (R, D), laid out by ``grouped_matmul_kernels.tile_plan``: a
 held expert's rows are contiguous. **Within an expert's group the rows
@@ -34,6 +34,18 @@ past the last group) are zeroed in VMEM before the product, since 0 x NaN
 is NaN: ``written`` is the first such row. Rows below it that hold no
 assignment are finite (an active tile's product of zero rows) and meet a
 zero column of ``S``.
+
+**The transpose, tokens to rows** (:func:`rows_of`), walks the same runs
+the other way: a step holds its tile of tokens in VMEM (read once, an
+ordinary block) and places them into a window of every group with the same
+0/1 matrices transposed, ``window = S.T @ tile``, then copies the windows
+out (VMEM -> HBM). Runs of neighbouring tiles abut at rows that are no
+multiple of ``ALIGN``, so a group's window is *carried* from step to step
+in VMEM: it holds what its ``WINDOW`` rows of the buffer hold so far, moves
+on by whole ``ALIGN`` rows as the runs advance, and is written again,
+fuller, by every step. The last token tile's runs go on to their groups'
+ends, so the rows of an active row tile that hold no assignment come out
+zero; rows of inactive row tiles are not written.
 """
 
 from __future__ import annotations
@@ -277,3 +289,131 @@ def token_major_sum(rows, row_of, start, written, weight=None, *,
         interpret=interpret,
     )(start.reshape(-1).astype(jnp.int32),
       jnp.reshape(written, (1,)).astype(jnp.int32), *operands, rows)
+
+
+# ---------------------------------------------------------------------------
+# The transpose: tokens to rows
+# ---------------------------------------------------------------------------
+
+def _rows_of_kernel(start_ref, written_ref, row_ref, src_ref, out_ref, stage,
+                    base, sem, *, held: int, tokens: int):
+    """One token tile: its run of rows in every group, as ``S.T @ tile``
+    added to the group's window of the buffer and written out whole.
+
+    ``stage[e]`` is what rows ``base[e] .. base[e] + WINDOW`` of the buffer
+    hold so far (rows no token has reached yet: zero), kept from step to
+    step: runs of neighbouring tiles abut at rows that are no multiple of
+    ``ALIGN``, and an aligned copy has to bring the neighbour's rows with
+    it. A window moves on by whole ``ALIGN`` rows (the stage shifts), never
+    past its group's last ``WINDOW`` rows, and every round writes every
+    group's window again, so a copy per group is in flight while the next
+    tile is placed and none overlaps another group's."""
+    i, tiles = pl.program_id(0), pl.num_programs(0)
+    tn, dt = src_ref.shape[0], stage.dtype
+    rows = out_ref.shape[0]
+    precision = (jax.lax.Precision.HIGHEST if dt == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    first = lambda e: start_ref[e]
+    # a group ends where the next starts (whole row tiles), the last at
+    # ``written``; its last window, inside the buffer
+    ends = [first(e + 1) for e in range(held - 1)] + [written_ref[0]]
+    last = [jnp.minimum(end, rows) - WINDOW for end in ends]
+    lo = [start_ref[i * held + e] for e in range(held)]
+    # the last tile's runs go on to their groups' ends: rows of active row
+    # tiles that hold no assignment are zero
+    hi = [jnp.where(i + 1 == tiles, ends[e], start_ref[(i + 1) * held + e])
+          for e in range(held)]
+
+    @pl.when(i == 0)
+    def _():
+        stage[...] = jnp.zeros(stage.shape, dt)
+        for e in range(held):
+            base[e] = first(e)
+
+    def copies():
+        return [pltpu.make_async_copy(
+            stage.at[e, pl.ds(0, WINDOW), :],
+            out_ref.at[pl.ds(pl.multiple_of(base[e], ALIGN), WINDOW), :],
+            sem.at[e]) for e in range(held)]
+
+    tile = src_ref[...]
+    if tokens % tn:        # the last tile's rows past the tokens: anything
+        n = jax.lax.broadcasted_iota(jnp.int32, (tn, 1), 0) + i * tn
+        tile = jnp.where(n < tokens, tile, jnp.zeros_like(tile))
+    r = jax.lax.broadcasted_iota(jnp.int32, (WINDOW, tn), 0)
+
+    def one_round(q, carry):
+        # the first row this round places, and where the window then lies
+        cur = [jnp.where(q == 0, lo[e], (lo[e] & -ALIGN) + q * WINDOW)
+               for e in range(held)]
+        at = [jnp.where(cur[e] < hi[e],
+                        jnp.minimum(cur[e] & -ALIGN, last[e]), base[e])
+              for e in range(held)]
+        s_t = jnp.concatenate([
+            (jnp.where(row_ref[e:e + 1, :] >= cur[e],
+                       row_ref[e:e + 1, :] - at[e], -1) == r).astype(dt)
+            for e in range(held)], axis=0)
+        placed = jax.lax.dot_general(
+            s_t, tile, (((1,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+
+        @pl.when((i > 0) | (q > 0))
+        def _():
+            for copy in copies():
+                copy.wait()
+
+        for e in range(held):
+            shift = pl.multiple_of(at[e] - base[e], ALIGN)
+            kept = stage[e, pl.ds(shift, WINDOW), :].astype(jnp.float32)
+            stage[e, pl.ds(0, WINDOW), :] = (
+                kept + placed[e * WINDOW:(e + 1) * WINDOW]).astype(dt)
+            base[e] = at[e]
+        for copy in copies():
+            copy.start()
+        return carry
+
+    rounds = functools.reduce(jnp.maximum, [
+        jnp.where(h > l, jax.lax.div(h - (l & -ALIGN) + (WINDOW - 1), WINDOW),
+                  1) for l, h in zip(lo, hi)])
+    jax.lax.fori_loop(0, rounds, one_round, 0)
+
+    @pl.when(i + 1 == tiles)
+    def _():
+        for copy in copies():
+            copy.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
+def rows_of(source, row_of, start, written, *, rows: int,
+            interpret: bool = False):
+    """(rows, D) in ``source``'s dtype, the transpose of
+    :func:`token_major_sum` without weights: row ``row_of[n, e]`` is
+    ``source[n]``, every other row below ``written`` is zero (a negative
+    zero of ``source`` comes out positive: the one bit a selection by a
+    product changes), and rows from ``written`` on are not written: they
+    may hold anything.
+
+    source: (N, D). row_of, start, written: as :func:`token_major_sum`
+    takes them; ``rows`` a multiple of ``WINDOW``."""
+    n, held = row_of.shape
+    d = source.shape[1]
+    tn = tokens_tile(n)
+    tiles = -(-n // tn)
+    row_t = jnp.pad(row_of.T, ((0, 0), (0, tiles * tn - n)),
+                    constant_values=-1)
+    return pl.pallas_call(
+        functools.partial(_rows_of_kernel, held=held, tokens=n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(tiles,),
+            in_specs=[pl.BlockSpec((held, tn), lambda i, *_: (0, i)),
+                      pl.BlockSpec((tn, d), lambda i, *_: (i, 0))],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((held, 2 * WINDOW, d), source.dtype),
+                            pltpu.SMEM((held,), jnp.int32),
+                            pltpu.SemaphoreType.DMA((held,))]),
+        out_shape=jax.ShapeDtypeStruct((rows, d), source.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM),
+        interpret=interpret,
+    )(start.reshape(-1).astype(jnp.int32),
+      jnp.reshape(written, (1,)).astype(jnp.int32), row_t, source)

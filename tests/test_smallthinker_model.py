@@ -377,6 +377,129 @@ def test_the_kernel_over_runs_against_one_gather_a_slot(case, weighted,
     np.testing.assert_array_equal(low, got.astype(jnp.bfloat16))
 
 
+TO_ROWS = {
+    # name: (tokens, router)
+    "uniform": (1024, _uniform),
+    "everything_on_one_expert": (512, _one_expert_a_tile),
+    "an_expert_and_a_tile_with_none": (768, _a_tile_and_an_expert_with_none),
+    "tokens_no_multiple_of_the_tile": (1000, _uniform),
+    # sixteen token tiles: the runs' boundaries in the held experts' groups
+    # fall on every residue of ALIGN
+    "boundaries_at_every_residue": (4096, _uniform),
+    "fewer_tokens_than_a_tile": (56, _uniform),
+}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("case", list(TO_ROWS))
+def test_tokens_go_to_rows_by_runs_as_by_one_gather(case, dtype,
+                                                    monkeypatch):
+    """Tokens to the dispatch buffer's rows, on one plan by both lowerings:
+    the Pallas kernel over runs (``token_sum_kernels.rows_of``: the
+    transpose of the token-major sum, interpreted) and the XLA gather
+    ``_rows_of``, bit for bit in every row of an active row tile (a
+    token's row where one holds an assignment, zero elsewhere). Rows of
+    inactive tiles are not written (NaN, interpreted): every reader masks
+    them."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    n, router = TO_ROWS[case]
+    k, experts, held, offset, d = 2, 8, 4, 2, 64
+    rng = np.random.default_rng(11)
+    idx = jnp.asarray(router(rng, n, k, experts), jnp.int32)
+    plan = sparse_lm.dispatch_plan(idx, offset, held, n * k)
+    source = jnp.asarray(rng.normal(size=(n, d)), dtype)
+    assert sparse_lm.rows_why_not(n, plan.token.shape[0], held, d,
+                                  dtype) is None
+    want = sparse_lm._rows_of(source, plan)
+    got = jax.jit(lambda s, plan: sparse_lm._to_rows(s, plan, "tokens"))(
+        source, plan)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    written = int(plan.written)
+    assert 0 < written < want.shape[0]       # some tile holds no group
+    np.testing.assert_array_equal(got[:written].astype(jnp.float32),
+                                  want[:written].astype(jnp.float32))
+    assert float(jnp.max(jnp.abs(want[written:].astype(jnp.float32)))) == 0
+    # and the inverse movement gives every token back once an assignment
+    back = sparse_lm._sum_to_tokens(got, plan)
+    np.testing.assert_array_equal(
+        back, source.astype(jnp.float32)
+        * jnp.sum(plan.here, axis=1, keepdims=True))
+    # what the cases are for
+    start = np.asarray(plan.start)
+    runs, spills = np.diff(start, axis=0), int(token_sum.spills(plan.start))
+    if case == "everything_on_one_expert":
+        assert (runs[:, 1] == 256).all() and spills >= 2
+    if case == "an_expert_and_a_tile_with_none":
+        assert (runs[:, 2] == 0).all() and (runs[1] == 0).all()
+        # the expert with no row owns one row tile, all of it zero
+        first = int(start[0, 2])
+        assert first + 256 <= written
+        assert float(jnp.max(jnp.abs(got[first:first + 256]))) == 0.0
+    if case == "tokens_no_multiple_of_the_tile":
+        assert plan.start.shape == (5, held)
+    if case == "boundaries_at_every_residue":
+        assert set((start % token_sum.ALIGN).reshape(-1)) == set(
+            range(token_sum.ALIGN))
+    if case == "fewer_tokens_than_a_tile":
+        assert plan.start.shape == (2, held)
+
+
+def _held_experts_cotangents(m, idx, p, experts, rows, rounded_to):
+    """Value and the five cotangents of a loss that rounds the layer's
+    result to ``m``'s dtype first, as ``ExpertLayer`` does."""
+    def loss(m, p, experts):
+        y, _ = sparse_lm.held_experts(
+            m, idx, p, experts["gate"], experts["up"], experts["down"],
+            offset=2, rows=rows, act="silu", rounded_to=rounded_to)
+        y = y.astype(m.dtype).astype(jnp.float32)
+        return jnp.sum(y * jnp.cos(y))
+    return jax.jit(jax.value_and_grad(loss, (0, 1, 2)))(m, p, experts)
+
+
+@pytest.mark.parametrize("router", [_uniform, _one_expert_a_tile],
+                         ids=["uniform", "spills"])
+def test_the_cotangent_moves_in_the_dtype_the_caller_rounds_to(
+        router, monkeypatch, lowering_record):
+    """``held_experts``' five cotangents three ways, equal to the last bit:
+    the kernel over runs with the cotangent moved as bfloat16 (the caller
+    states what it rounds the result to), the kernel with the cotangent in
+    f32 (a caller that states nothing), and one XLA gather each. Which
+    movement ran is read from the lowering record."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    n, k, held, d, f = 512, 2, 4, 64, 32
+    rng = np.random.default_rng(5)
+    idx = jnp.asarray(router(rng, n, k, 8), jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    m = jax.random.normal(keys[0], (n, d)).astype(jnp.bfloat16)
+    p = jax.nn.softmax(jax.random.normal(keys[1], (n, k)), -1)
+    experts = {name: (jax.random.normal(key, shape) * 0.2
+                      ).astype(jnp.bfloat16)
+               for name, key, shape in (("gate", keys[2], (held, d, f)),
+                                        ("up", keys[3], (held, d, f)),
+                                        ("down", keys[4], (held, f, d)))}
+    key = lambda dtype: sparse_lm._rows_key(held, d, dtype, "cotangent")
+    said = lambda dtype: lowering_record.recorded(sparse_lm.ROWS_SITE,
+                                                  key(dtype))
+    stated = _held_experts_cotangents(m, idx, p, experts, n * k, m.dtype)
+    assert said("bfloat16") == {"why_not": None, "stated": True}
+    assert said("float32") is None           # no f32 movement was traced
+    silent = _held_experts_cotangents(m, idx, p, experts, n * k, None)
+    assert said("float32") == {"why_not": None, "stated": False}
+    monkeypatch.setattr(sparse_lm, "ROWS_NS_A_KIB", {2: 1e9, 4: 1e9})
+    gathered = _held_experts_cotangents(m, idx, p, experts, n * k, m.dtype)
+    assert "cost more than a gather" in said("bfloat16")["why_not"]
+    assert "cost more than a gather" in lowering_record.recorded(
+        sparse_lm.ROWS_SITE,
+        sparse_lm._rows_key(held, d, "bfloat16", "tokens"))["why_not"]
+    leaves = lambda out: [np.asarray(a.astype(jnp.float32))
+                          for a in jax.tree.leaves(out)]
+    assert len(leaves(stated)) == 6          # the value, dm, dp, three dw
+    for a, b, c in zip(*map(leaves, (stated, silent, gathered))):
+        assert np.isfinite(a).all() and np.abs(a).max() > 0
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a, c)
+
+
 def test_a_weights_pieces_add_up_to_it_exactly():
     """Three bf16 numbers for an f32 routing weight (under jit, where a
     rounding that is converted back may be dropped); the weight itself for
@@ -430,6 +553,53 @@ def test_which_lowering_a_token_major_sum_takes_is_read_off_its_shapes(
     sparse_lm._sum_to_tokens(rows, plan)
     assert layout().endswith("token-major sums: one gather a slot (4 windows "
                              "cost more than 2 gathers of 56 rows)")
+
+def test_which_lowering_takes_tokens_to_rows_is_read_off_its_shapes(
+        monkeypatch, lowering_record):
+    """The kernel over runs where the buffer has more rows than about 0.6
+    of the windows' (1.1 in f32) and the windows fit VMEM; one gather
+    otherwise. Of the four cells' layouts three take the kernel, the one
+    with the fewest rows keeps its gather; the ``setup/warmup`` row says
+    which, and in which dtype the cotangent moved."""
+    why_not = sparse_lm.rows_why_not
+    assert why_not(16384, 26624, 8, 2560, "bfloat16") is None  # smallthinker
+    assert why_not(8192, 18432, 8, 2048, "bfloat16") is None   # lfm2moe
+    assert why_not(8192, 10240, 8, 2048, "bfloat16") is None   # trinitymini
+    assert why_not(8192, 6144, 8, 2048, "bfloat16") == (       # joyaiflash
+        "256 windows cost more than a gather of 6144 rows")
+    assert why_not(8192, 18432, 8, 2048, "float32") is None
+    assert why_not(16384, 26624, 8, 2560, "float32") == (
+        "512 windows cost more than a gather of 26624 rows")
+    assert "VMEM" in why_not(16384, 26624, 32, 2560, "bfloat16")
+    assert "lane tiles" in why_not(16384, 26624, 7, 2560, "bfloat16")
+    assert why_not(16384, 26624, 8, 2560, "float16") == "rows in float16"
+    cfg = SparseLMConfig(**dict(TINY, hidden_size=192, dtype="bfloat16"))
+    layout = lambda: sparse_lm.engagement_records(cfg)["moe_layout"]
+    assert layout().endswith("token-major sums: none traced (the dense "
+                             "lowering)")
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    idx = jnp.asarray(np.argsort(np.random.default_rng(0).normal(
+        size=(56, 8)), axis=1)[:, :2], jnp.int32)
+    plan = sparse_lm.dispatch_plan(idx, 2, 4, 112)
+    tokens = jnp.ones((56, 192), jnp.bfloat16)
+    sparse_lm._sum_to_tokens(sparse_lm._to_rows(tokens, plan, "tokens"),
+                             plan)
+    assert layout().endswith(
+        "windows of 64 rows; rows from tokens: kernel over runs")
+    sparse_lm._to_rows(tokens.astype(jnp.float32), plan, "cotangent",
+                       stated=False)
+    assert layout().endswith(
+        "rows from tokens: kernel over runs; cotangent gathered as float32 "
+        "(the caller states no rounding), kernel over runs")
+    monkeypatch.setattr(sparse_lm, "ROWS_NS_A_KIB", {2: 100.0, 4: 100.0})
+    sparse_lm._to_rows(tokens, plan, "tokens")
+    sparse_lm._to_rows(tokens, plan, "cotangent", stated=True)
+    assert layout().endswith(
+        "rows from tokens: one gather (4 windows cost more than a gather "
+        "of 1280 rows); cotangent gathered as bfloat16 (the caller rounds "
+        "the result to it), one gather (4 windows cost more than a gather "
+        "of 1280 rows)")
+
 
 TINY_FLAGS = [
     "--hidden-size", "64", "--num-hidden-layers", "4", "--num-heads", "4",
