@@ -102,6 +102,19 @@ the count (:func:`held_experts`). Where the grouped kernels cannot run (no
 Mosaic backend, sizes that are not lane tiles) the dense lowering is the
 layer.
 
+**Between dispatch and combine the unit of work is a row tile that holds
+rows, and what is done to such a tile is done while it is in VMEM.** The
+buffer's tail holds none (about half its tiles under a router that favours
+no expert): the grouped kernels move nothing for them. Where an expert's
+two weight blocks and the tiles fit VMEM (``grouped.block_why_not``, asked
+at the site "expert products" of the local ``(dim, width, dtype)``; every
+published width so far) gate, up and the activation are one kernel, the
+backward's ``dys . W_down^T`` kernel makes the two cotangents and ``act(g) *
+u`` on the tile, and one kernel sums ``dg . W_gate^T + du . W_up^T`` into
+one ``dxs`` buffer, in f32, rounded once; elsewhere the three products a
+direction run with XLA code between them (``moe_layout`` says which, and
+why not). Every other rounding is where the three products put it.
+
 **The sorted lowering moves rows four times, all four by runs**: tokens
 to rows and the output's cotangent to rows (:func:`_to_rows`), and two
 token-major sums, combine's forward ``y[n] = sum_j p[n, j] ys[row[n, j]]``
@@ -156,8 +169,10 @@ on the ``setup/warmup`` row says so) and ``conv/out_proj`` (never under
 (``token_major_sum[mosaic]`` in a trace) runs under the scope of its sum,
 ``ff/combine`` or ``ff/dispatch``, and its transpose (``rows_of[mosaic]``)
 under ``ff/dispatch`` (tokens) or ``ff/combine`` (the cotangent); the
-grouped products under
-``ff/experts``. What is done to each head of queries and keys between
+grouped products under ``ff/experts`` (``experts[mosaic]``; with the expert
+block on the tile nothing else runs there but the weights' casts, and the
+sum of the two ``dxs`` counts there and no longer under ``ff/dispatch``).
+What is done to each head of queries and keys between
 their projections and the attention (the head norm where the
 configuration has one, the rotary where the layer has positions) is one
 pass on the lanes (ops/pallas/head_norm_kernels.py: no (B, T, H, d) array
@@ -935,30 +950,32 @@ def grouped_kernels_why_not(dim: int, width: int) -> Optional[str]:
     return None
 
 
-def _grouped_dots(plan: _Plan):
-    """``dot(x, w)``: row tile t of x times its expert's weights, and
-    ``grads(x, w, dy) -> (dx, dw)``."""
-    kw = dict(tiles=plan.tiles, tile=grouped.TILE,
-              interpret=lowering.interpret())
-    return (functools.partial(grouped.grouped_matmul, **kw),
-            functools.partial(grouped.grouped_matmul_grads, **kw))
+def _block_key(dim: int, width: int, dtype):
+    """What the record knows the expert block's form by: what
+    ``grouped.block_why_not`` chose it from."""
+    return dim, width, jnp.dtype(dtype).name
 
 
-def _act(name: str, g: jax.Array) -> jax.Array:
-    """The gate's activation (``cfg.hidden_act``), in g's dtype."""
-    if name == "relu":
-        return jax.nn.relu(g)
-    return jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype)
+def _three_products(gate: jax.Array) -> Optional[str]:
+    """Why these (held, D, F) experts' block runs as three products a
+    direction with XLA code between them; None where its tile work is in
+    the kernels."""
+    return grouped.block_why_not(*_block_key(*gate.shape[1:], gate.dtype))
 
 
-def _gate_cotangent(name: str, g: jax.Array, u: jax.Array,
-                    dhidden: jax.Array) -> jax.Array:
-    """The cotangent of ``g`` in ``hidden = act(g) * u``."""
-    if name == "relu":
-        return jnp.where(g > 0, dhidden * u, 0)
-    g32 = g.astype(jnp.float32)
-    sig = jax.nn.sigmoid(g32)
-    return (dhidden * u * (sig * (1.0 + g32 * (1.0 - sig)))).astype(g.dtype)
+BLOCK_ON_THE_TILE = ("gate, up and activation one kernel; cotangents on the "
+                     "tile; one dxs; inactive tiles unmoved")
+
+
+def _block_words(why_not: Optional[str]) -> str:
+    return (f"three products a direction ({why_not})" if why_not
+            else BLOCK_ON_THE_TILE)
+
+
+def _grouped(plan: _Plan):
+    """How every grouped kernel of a call is run: the plan's tiles."""
+    return dict(tiles=plan.tiles, tile=grouped.TILE,
+                interpret=lowering.interpret())
 
 
 class _Kept(NamedTuple):
@@ -979,9 +996,14 @@ def _sorted_forward(m, idx, p, gate, up, down, *, offset: int, rows: int,
         plan = dispatch_plan(idx, offset, gate.shape[0], rows)
         xs = _to_rows(m, plan, "tokens")
     with jax.named_scope("experts"):
-        dot, _ = _grouped_dots(plan)
-        g, u = dot(xs, gate), dot(xs, up)
-        ys = dot(_act(act, g) * u, down)
+        how = _grouped(plan)
+        if _three_products(gate):
+            g = grouped.grouped_matmul(xs, gate, **how)
+            u = grouped.grouped_matmul(xs, up, **how)
+            hidden = grouped.act(act, g) * u
+        else:
+            g, u, hidden = grouped.gated_hidden(xs, gate, up, name=act, **how)
+        ys = grouped.grouped_matmul(hidden, down, **how)
     with jax.named_scope("combine"):
         y = _sum_to_tokens(ys, plan, p)
     return ((y, jnp.sum(plan.valid, dtype=jnp.float32)),
@@ -1006,15 +1028,28 @@ def _sorted_backward(kept: _Kept, p, gate, up, down, dy, act: str,
                                   dy_rows * ys.astype(jnp.float32), 0.0),
                         axis=-1)
         dp = jnp.where(plan.here, score[plan.row], 0.0)
-    with jax.named_scope("experts"):
-        _, grads = _grouped_dots(plan)
-        hidden = _act(act, g)
-        dhidden, ddown = grads(hidden * u, down, dys)
-        dxs_gate, dgate = grads(xs, gate,
-                                _gate_cotangent(act, g, u, dhidden))
-        dxs_up, dup = grads(xs, up, dhidden * hidden)
+    how = _grouped(plan)
+    if _three_products(gate):
+        with jax.named_scope("experts"):
+            grads = functools.partial(grouped.grouped_matmul_grads, **how)
+            hidden = grouped.act(act, g)
+            dhidden, ddown = grads(hidden * u, down, dys)
+            dxs_gate, dgate = grads(
+                xs, gate, grouped.gate_cotangent(act, g, u, dhidden))
+            dxs_up, dup = grads(xs, up, dhidden * hidden)
+        with jax.named_scope("dispatch"):
+            dxs = dxs_gate + dxs_up
+    else:
+        with jax.named_scope("experts"):
+            dg, du, hidden = grouped.gated_hidden_grads(dys, down, g, u,
+                                                        name=act, **how)
+            dgate, dup, ddown = (
+                grouped.weights_grad(x, dy, like=w, **how)
+                for x, dy, w in ((xs, dg, gate), (xs, du, up),
+                                 (hidden, dys, down)))
+            dxs = grouped.rows_grad(dg, du, gate, up, **how)
     with jax.named_scope("dispatch"):
-        dm = _sum_to_tokens(dxs_gate + dxs_up, plan, dtype=xs.dtype)
+        dm = _sum_to_tokens(dxs, plan, dtype=xs.dtype)
     return dm, dp, dgate, dup, ddown
 
 
@@ -1028,7 +1063,7 @@ def _every_expert(m, idx, p, gate, up, down, *, offset: int, act: str):
         with jax.named_scope("combine"):
             weight = jnp.sum(jnp.where(idx == e + offset, p, 0.0), axis=1)
         with jax.named_scope("experts"):
-            out = jnp.dot(_act(act, jnp.dot(m, w_gate))
+            out = jnp.dot(grouped.act(act, jnp.dot(m, w_gate))
                           * jnp.dot(m, w_up), w_down)
         with jax.named_scope("combine"):
             return y + out.astype(jnp.float32) * weight[:, None], None
@@ -1114,10 +1149,18 @@ def held_experts(m, idx, p, gate, up, down, *, offset: int, rows: int,
     f32 movement. The forward's arithmetic is the same either way."""
     def fits(m, idx, p, gate, up, down) -> bool:
         why_not = grouped_kernels_why_not(m.shape[1], gate.shape[2])
-        return lowering.chose(
-            PRODUCTS_SITE, (rows, *gate.shape), why_not,
-            why_not or f"{rows} rows in tiles of {grouped.TILE}, "
-            f"{gate.shape[0]} experts of {m.shape[1]} x {gate.shape[2]}")
+        words = why_not
+        if why_not is None:
+            # the block's form, which the sorted lowering asks again of the
+            # same shapes: remembered for ``moe_layout``
+            three = _three_products(gate)
+            lowering.record(PRODUCTS_SITE,
+                            _block_key(*gate.shape[1:], gate.dtype), three)
+            words = (f"{rows} rows in tiles of {grouped.TILE}, "
+                     f"{gate.shape[0]} experts of {m.shape[1]} x "
+                     f"{gate.shape[2]}, " + _block_words(three))
+        return lowering.chose(PRODUCTS_SITE, (rows, *gate.shape), why_not,
+                              words)
 
     return lowering.site(
         PRODUCTS_SITE, fits, functools.partial(
@@ -1159,7 +1202,8 @@ class GatedBlock(nn.Module):
         dense = functools.partial(
             nn.Dense, use_bias=False, dtype=jnp.dtype(cfg.dtype),
             param_dtype=jnp.dtype(cfg.param_dtype))
-        hidden = _act(cfg.hidden_act, dense(self.width, name="gate")(m)) \
+        hidden = grouped.act(cfg.hidden_act,
+                             dense(self.width, name="gate")(m)) \
             * dense(self.width, name="up")(m)
         return dense(cfg.hidden_size, name="down")(hidden)
 
@@ -1251,23 +1295,29 @@ class ExpertLayer(nn.Module):
             sorted_ = lowering.why_not(PRODUCTS_SITE, (
                 rows, cfg.experts_held, d, cfg.expert_width)) is None
             dense = (here > rows) | (not sorted_)
-            # runs of the sorted lowering's token-major kernel that pass
-            # their first window (none where the sums are gathered or
-            # the call is dense)
-            spills = 0.0
-            if sorted_ and runs_why_not(b * t, cfg.experts_per_token,
-                                        cfg.experts_held, d,
-                                        m.dtype) is None:
-                first_row, _ = grouped.tile_plan(
+            # of the sorted lowering: the share of its grid's row tiles
+            # that hold rows (the grouped kernels move nothing for the
+            # rest), and the runs of its token-major kernel that pass
+            # their first window (none where the sums are gathered); a
+            # dense call has neither
+            active, spills = 0.0, 0.0
+            if sorted_:
+                first_row, tiles = grouped.tile_plan(
                     counts, _buffer_tiles(rows, cfg.experts_held))
-                spills = jnp.where(dense, 0, token_sum.spills(_run_starts(
-                    key.reshape(b * t, -1), first_row))).astype(jnp.float32)
+                active = jnp.where(dense, 0, jnp.mean(
+                    tiles.active.astype(jnp.float32)))
+                if runs_why_not(b * t, cfg.experts_per_token,
+                                cfg.experts_held, d, m.dtype) is None:
+                    spills = jnp.where(dense, 0, token_sum.spills(
+                        _run_starts(key.reshape(b * t, -1), first_row))
+                    ).astype(jnp.float32)
             counters = {
                 "here": here / (b * t * cfg.experts_per_token),
                 "load": jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9),
                 "dense": dense.astype(jnp.float32),
                 "dropped": here - computed,
-                "spills": spills}
+                "spills": spills,
+                "tiles_active": active}
         if cfg.num_shared_experts:
             y = y + self.shared(m).reshape(b * t, d)
         return y.reshape(b, t, d).astype(m.dtype), counters
@@ -1518,6 +1568,10 @@ class SparseLM(nn.Module):
             # passed the token-major kernel's first window and cost their
             # tile a further round, both sums of a layer alike
             "moe_sum_spills": jnp.sum(stack("spills")),
+            # row tiles of the grouped kernels' grid that hold rows (the
+            # layers' median): what the kernels move nothing for is the rest
+            "moe_tiles_active_pct":
+                100.0 * jnp.median(stack("tiles_active")) / shards,
         }
         return aux["loss"], aux
 
@@ -1650,6 +1704,11 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
                         else "the caller states no rounding")
                      + f"), {how(moved)}")
             break
+    # the products between them: a traced call's form of the expert block
+    block = lowering.recorded(PRODUCTS_SITE, _block_key(
+        cfg.hidden_size, cfg.expert_width, cfg.dtype))
+    if block is not None:
+        sums += "; expert block: " + _block_words(block["why_not"])
     # the router's kind, and what stands beside the routed experts
     router = "softmax over the chosen"
     if cfg.score_func == "sigmoid":
@@ -1713,7 +1772,8 @@ def step_attributes(cfg: SparseLMConfig) -> Tuple[str, ...]:
     module, the two losses its loss is made of."""
     losses = ("loss_main", "loss_mtp") if cfg.num_nextn_predict_layers else ()
     return ("moe_assignments_here_pct", "moe_load_max_over_mean",
-            "moe_dropped", "moe_dense_calls", "moe_sum_spills") + losses
+            "moe_dropped", "moe_dense_calls", "moe_sum_spills",
+            "moe_tiles_active_pct") + losses
 
 
 # those of them that count a slower lowering, so that a step above its

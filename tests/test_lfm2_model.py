@@ -20,6 +20,7 @@ from dalle_tpu.config import (AfmoeLMConfig, JoyAILMConfig, Lfm2MoeLMConfig,
                               SparseLMConfig, lfm2moe_model_config)
 from dalle_tpu.models import attention, family, sparse_lm
 from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
+from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
 
 Y = Manifest().yardstick("lfm2")
 
@@ -403,6 +404,39 @@ def test_the_four_shares_add_up_to_the_uncut_layer(with_kernels,
     np.testing.assert_allclose(total, want, atol=5e-5)
     assert here == pytest.approx(1.0)     # every assignment, by one share
     np.testing.assert_allclose(jnp.sum(p, -1), 1.0, atol=1e-6)  # x 1.0
+
+
+def test_the_expert_block_on_the_tile_is_the_same_model_to_the_last_bit(
+        monkeypatch, lowering_record):
+    """The preset at the widths its kernels take, f32, interpreted: loss,
+    counters and every gradient leaf with the expert block's tile work in
+    its kernels equal the three products a direction with XLA code between
+    them (the predicate's refusal: the limit shrunk), and the step's
+    ``moe_tiles_active_pct`` is the plan's: 88 tokens a call, top 2 of 8
+    with 4 held, send each held expert under a tile of rows, so 4 of the
+    buffer's 1 + 4 tiles hold rows in both expert layers."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    cfg = Lfm2MoeLMConfig(**dict(TINY, **KERNEL_WIDTHS))
+    params, (text, image) = _params(cfg), _batch(cfg)
+    said = lambda: lowering_record.recorded(
+        sparse_lm.PRODUCTS_SITE, sparse_lm._block_key(
+            cfg.hidden_size, cfg.expert_width, cfg.dtype))["why_not"]
+    on_the_tile = _system(cfg, params, text, image)
+    assert said() is None
+    assert sparse_lm.engagement_records(cfg)["moe_layout"].endswith(
+        "; expert block: gate, up and activation one kernel; cotangents on "
+        "the tile; one dxs; inactive tiles unmoved")
+    monkeypatch.setattr(grouped, "_VMEM", 256 * 1024)
+    three = _system(cfg, params, text, image)
+    assert said() == ("two blocks of 128 x 128 and the tiles need 2.1 MiB "
+                      "of VMEM, over 0.25")
+    (loss, aux), grads = on_the_tile
+    assert float(aux["moe_dense_calls"]) == 0.0
+    assert float(aux["moe_tiles_active_pct"]) == pytest.approx(80.0)
+    assert "moe_tiles_active_pct" in sparse_lm.step_attributes(cfg)
+    for a, b in zip(jax.tree.leaves(on_the_tile), jax.tree.leaves(three),
+                    strict=True):
+        np.testing.assert_array_equal(a, b)
 
 
 TINY_FLAGS = [

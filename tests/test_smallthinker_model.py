@@ -15,6 +15,7 @@ from benchmark.manifest import Manifest
 from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
 from dalle_tpu.config import ModelConfig, SparseLMConfig
 from dalle_tpu.models import attention, family, sparse_lm
+from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
 from dalle_tpu.ops.pallas import token_sum_kernels as token_sum
 
 Y = Manifest().yardstick("smallthinker")
@@ -444,16 +445,30 @@ def test_tokens_go_to_rows_by_runs_as_by_one_gather(case, dtype,
         assert plan.start.shape == (2, held)
 
 
-def _held_experts_cotangents(m, idx, p, experts, rows, rounded_to):
+def _held_experts_cotangents(m, idx, p, experts, rows, rounded_to,
+                             act="silu"):
     """Value and the five cotangents of a loss that rounds the layer's
     result to ``m``'s dtype first, as ``ExpertLayer`` does."""
     def loss(m, p, experts):
         y, _ = sparse_lm.held_experts(
             m, idx, p, experts["gate"], experts["up"], experts["down"],
-            offset=2, rows=rows, act="silu", rounded_to=rounded_to)
+            offset=2, rows=rows, act=act, rounded_to=rounded_to)
         y = y.astype(m.dtype).astype(jnp.float32)
         return jnp.sum(y * jnp.cos(y))
     return jax.jit(jax.value_and_grad(loss, (0, 1, 2)))(m, p, experts)
+
+
+def _experts_operands(router, dtype, n=512, k=2, held=4, d=64, f=32):
+    rng = np.random.default_rng(5)
+    idx = jnp.asarray(router(rng, n, k, 8), jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    m = jax.random.normal(keys[0], (n, d)).astype(dtype)
+    p = jax.nn.softmax(jax.random.normal(keys[1], (n, k)), -1)
+    experts = {name: (jax.random.normal(key, shape) * 0.2).astype(dtype)
+               for name, key, shape in (("gate", keys[2], (held, d, f)),
+                                        ("up", keys[3], (held, d, f)),
+                                        ("down", keys[4], (held, f, d)))}
+    return m, idx, p, experts
 
 
 @pytest.mark.parametrize("router", [_uniform, _one_expert_a_tile],
@@ -466,17 +481,8 @@ def test_the_cotangent_moves_in_the_dtype_the_caller_rounds_to(
     f32 (a caller that states nothing), and one XLA gather each. Which
     movement ran is read from the lowering record."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
-    n, k, held, d, f = 512, 2, 4, 64, 32
-    rng = np.random.default_rng(5)
-    idx = jnp.asarray(router(rng, n, k, 8), jnp.int32)
-    keys = jax.random.split(jax.random.PRNGKey(5), 5)
-    m = jax.random.normal(keys[0], (n, d)).astype(jnp.bfloat16)
-    p = jax.nn.softmax(jax.random.normal(keys[1], (n, k)), -1)
-    experts = {name: (jax.random.normal(key, shape) * 0.2
-                      ).astype(jnp.bfloat16)
-               for name, key, shape in (("gate", keys[2], (held, d, f)),
-                                        ("up", keys[3], (held, d, f)),
-                                        ("down", keys[4], (held, f, d)))}
+    m, idx, p, experts = _experts_operands(router, jnp.bfloat16)
+    (n, k), (held, d, _) = idx.shape, experts["gate"].shape
     key = lambda dtype: sparse_lm._rows_key(held, d, dtype, "cotangent")
     said = lambda dtype: lowering_record.recorded(sparse_lm.ROWS_SITE,
                                                   key(dtype))
@@ -498,6 +504,107 @@ def test_the_cotangent_moves_in_the_dtype_the_caller_rounds_to(
         assert np.isfinite(a).all() and np.abs(a).max() > 0
         np.testing.assert_array_equal(a, b)
         np.testing.assert_array_equal(a, c)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("act", ["relu", "silu"])
+@pytest.mark.parametrize("router", [_uniform,
+                                    _a_tile_and_an_expert_with_none],
+                         ids=["uniform", "an_expert_with_none"])
+def test_the_expert_block_on_the_tile_is_the_three_products_a_direction(
+        router, act, dtype, monkeypatch, lowering_record):
+    """``held_experts``' value and five cotangents by both forms of the
+    expert block, interpreted: gate, up and the activation one kernel, the
+    cotangents on the tile and one ``dxs`` (where two weight blocks and the
+    tiles fit VMEM), and the three products a direction with XLA code
+    between them (where they do not: the limit shrunk). Equal to the last
+    bit, but for the one rounding the block removes: ``dm`` in a 16-bit
+    dtype, whose two ``dxs`` are summed in f32 and rounded once."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    m, idx, p, experts = _experts_operands(router, dtype)
+    (n, k), (_, d, f) = idx.shape, experts["gate"].shape
+    said = lambda: lowering_record.recorded(
+        sparse_lm.PRODUCTS_SITE, sparse_lm._block_key(d, f, dtype))
+    on_the_tile = _held_experts_cotangents(m, idx, p, experts, n * k,
+                                           m.dtype, act)
+    assert said() == {"why_not": None}
+    monkeypatch.setattr(grouped, "_VMEM", 64 * 1024)
+    three = _held_experts_cotangents(m, idx, p, experts, n * k, m.dtype, act)
+    assert said()["why_not"].startswith(
+        f"two blocks of {d} x {f} and the tiles need ")
+    leaves = lambda out: [np.asarray(a.astype(jnp.float32))
+                          for a in jax.tree.leaves(out)]
+    names = ["value", "dm", "dp", "ddown", "dgate", "dup"]
+    for name, a, b in zip(names, leaves(on_the_tile), leaves(three),
+                          strict=True):
+        assert np.isfinite(a).all() and np.abs(a).max() > 0, name
+        if name == "dm" and dtype == "bfloat16":
+            # a rounding removed: within the roundings of two bf16
+            # addends of the largest size, and not equal everywhere
+            np.testing.assert_allclose(a, b, rtol=0,
+                                       atol=2.0 ** -7 * np.abs(b).max())
+            assert (a != b).any()
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def test_moe_layout_says_which_form_the_expert_block_took_and_why_not(
+        monkeypatch, lowering_record):
+    """The ``setup/warmup`` row's ``moe_layout`` ends with the form the
+    last traced call of the configuration's experts took; the log line of
+    the site says it too."""
+    cfg = SparseLMConfig(**dict(TINY, dtype="bfloat16"))
+    layout = lambda: sparse_lm.engagement_records(cfg)["moe_layout"]
+    assert "expert block" not in layout()
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    m, idx, p, experts = _experts_operands(_uniform, "bfloat16", n=56)
+    run = lambda: sparse_lm.held_experts(
+        m, idx, p, experts["gate"], experts["up"], experts["down"],
+        offset=2, rows=112)
+    run()
+    assert layout().endswith(
+        "; expert block: gate, up and activation one kernel; cotangents on "
+        "the tile; one dxs; inactive tiles unmoved")
+    monkeypatch.setattr(grouped, "_VMEM", 64 * 1024)
+    run()
+    assert layout().endswith(
+        "; expert block: three products a direction (two blocks of 64 x 32 "
+        "and the tiles need 0.4 MiB of VMEM, over 0.0625)")
+    # experts of another size said nothing of this configuration's
+    other = SparseLMConfig(**dict(TINY, dtype="bfloat16", expert_width=64))
+    assert "expert block" not in sparse_lm.engagement_records(
+        other)["moe_layout"]
+
+
+def test_the_share_of_row_tiles_that_hold_rows_on_a_known_plan(
+        monkeypatch, lowering_record):
+    """``moe_tiles_active_pct`` from the layer's counter: 1 024 tokens, top
+    2 of 8 with experts 2..5 held and a router that sends every token to
+    expert 3 first: its 1 024 rows are 4 tiles, the other three held
+    experts' 150-odd rows a tile each, of a grid of 2 048 / 256 + 4 = 12:
+    7 of 12. A call whose assignments pass the buffer is dense: 0."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    cfg = SparseLMConfig(**TINY)
+    layer = sparse_lm.ExpertLayer(cfg)
+    rng = np.random.default_rng(11)
+    m = jnp.asarray(rng.normal(size=(1, 1024, cfg.hidden_size)), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(0), m, jnp.zeros(
+        (1, 1024, 2), jnp.int32), jnp.ones((1, 1024, 2)) / 2)
+    p = jnp.ones((1, 1024, 2)) / 2
+    idx = jnp.asarray(_one_expert_a_tile(rng, 1024, 2, 8), jnp.int32)[None]
+    _, counters = layer.apply(params, m, idx, p)
+    assert sparse_lm.dispatch_rows(1024, cfg) == 2048
+    assert float(counters["dense"]) == 0.0
+    assert float(counters["tiles_active"]) == pytest.approx(7 / 12)
+    # every token on two held experts: 4 + 4 tiles and the two empty
+    # experts' one each
+    idx = jnp.tile(jnp.asarray([[3, 4]], jnp.int32), (1, 1024, 1))
+    _, counters = layer.apply(params, m, idx, p)
+    assert float(counters["tiles_active"]) == pytest.approx(10 / 12)
+    monkeypatch.setattr(sparse_lm, "ROWS_OVER_EXPECTED", 0.5)
+    _, counters = layer.apply(params, m, idx, p)
+    assert float(counters["dense"]) == 1.0
+    assert float(counters["tiles_active"]) == 0.0
 
 
 def test_a_weights_pieces_add_up_to_it_exactly():
@@ -659,6 +766,7 @@ def test_the_preset_trains_through_the_peers_normal_path(lowering_record):
         # every shard, and said so
         assert row["moe_dense_calls"] == 4.0 * task.mesh.size
         assert row["moe_sum_spills"] == 0.0      # a dense call has no runs
+        assert row["moe_tiles_active_pct"] == 0.0    # and no grid of tiles
     # the optimizer was told the expert axis by the configuration
     assert task.model_cfg.optimizer_stacking()["stacked_experts"] == 4
 

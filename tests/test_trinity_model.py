@@ -17,6 +17,7 @@ from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
 from dalle_tpu.config import (AfmoeLMConfig, JoyAILMConfig, SparseLMConfig,
                               trinitymini_model_config)
 from dalle_tpu.models import attention, family, sparse_lm
+from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
 
 Y = Manifest().yardstick("trinity")
 
@@ -472,6 +473,40 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
     # summing the shares' results as they come counts the shared expert
     # once a share: that is not the layer
     assert float(jnp.abs(routed + shares * shared - want).max()) > 0.01
+
+
+@pytest.mark.parametrize("config", ["trinitymini", "joyaiflash"])
+def test_the_expert_block_on_the_tile_is_the_same_model_to_the_last_bit(
+        config, monkeypatch, lowering_record):
+    """Gated-SiLU experts beside a shared expert under a sigmoid router,
+    f32, the grouped kernels interpreted: loss, counters and every gradient
+    leaf with the expert block's tile work in its kernels (gate, up and
+    activation one kernel, the cotangents on the tile, one ``dxs``) equal
+    the three products a direction with XLA code between them, which the
+    model takes where two weight blocks do not fit VMEM, and says why."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    cfg = (AfmoeLMConfig(**TINY) if config == "trinitymini"
+           else JoyAILMConfig(**JOYAI_TINY))
+    params, (text, image) = _params(cfg), _batch(cfg)
+    said = lambda: lowering_record.recorded(
+        sparse_lm.PRODUCTS_SITE, sparse_lm._block_key(
+            cfg.hidden_size, cfg.expert_width, cfg.dtype))["why_not"]
+    layout = lambda: sparse_lm.engagement_records(cfg)["moe_layout"]
+    on_the_tile = _system(cfg, params, text, image)
+    assert said() is None
+    assert layout().endswith("; expert block: " + sparse_lm.BLOCK_ON_THE_TILE)
+    monkeypatch.setattr(grouped, "_VMEM", 64 * 1024)
+    three = _system(cfg, params, text, image)
+    assert "need 0.6 MiB of VMEM, over 0.0625" in said()
+    assert layout().endswith(f"; expert block: three products a direction "
+                             f"({said()})")
+    (loss, aux), grads = on_the_tile
+    assert float(aux["moe_dense_calls"]) == 0.0
+    assert 0.0 < float(aux["moe_tiles_active_pct"]) <= 100.0
+    assert np.isfinite(float(loss))
+    for a, b in zip(jax.tree.leaves(on_the_tile), jax.tree.leaves(three),
+                    strict=True):
+        np.testing.assert_array_equal(a, b)
 
 
 TINY_FLAGS = [
