@@ -24,10 +24,11 @@ import sys
 from typing import Optional, Sequence
 
 from dalle_tpu.config import (AfmoeLMConfig, CollabConfig, JoyAILMConfig,
-                              Lfm2MoeLMConfig, ModelConfig, OptimizerConfig,
-                              PeerConfig, SparseLMConfig, TrainerConfig,
-                              flagship_model_config, joyaiflash_model_config,
-                              lfm2moe_model_config,
+                              KeyeLMConfig, Lfm2MoeLMConfig, ModelConfig,
+                              OptimizerConfig, PeerConfig, SparseLMConfig,
+                              TrainerConfig, flagship_model_config,
+                              joyaiflash_model_config,
+                              keyevl2_model_config, lfm2moe_model_config,
                               smallthinker21b_model_config,
                               tiny_model_config, trinitymini_model_config,
                               xl_model_config)
@@ -56,6 +57,10 @@ MODEL_PRESETS = {
     # convolution in four layers of five, 64-wide heads, a tied head):
     # lfm2moe-train-solo
     "lfm2moe": lfm2moe_model_config,
+    # Keye-VL-2.0-30B-A3B's language model cut to one of 16 chips' share
+    # of a layer (an indexer chooses 2 048 keys a query, attention over
+    # the chosen keys, three position rows): keyevl2-train-solo
+    "keyevl2": keyevl2_model_config,
 }
 
 CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
@@ -63,7 +68,7 @@ CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
 # Every architecture's configuration class. A preset builds one of them;
 # a field two of them share (vocab_text, dtype, ...) is one flag.
 MODEL_CLASSES = (ModelConfig, SparseLMConfig, AfmoeLMConfig,
-                 JoyAILMConfig, Lfm2MoeLMConfig)
+                 JoyAILMConfig, Lfm2MoeLMConfig, KeyeLMConfig)
 
 
 def maybe_wandb_run(project: Optional[str], name: str):

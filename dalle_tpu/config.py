@@ -699,9 +699,13 @@ LAYER_WINDOW_ROPE = "window_rope"  # causal inside ``window``, rotary
 LAYER_FULL_ROPE = "full_rope"
 # no attention: a gated short convolution (``models/sparse_lm.ShortConv``)
 LAYER_SHORT_CONV = "short_conv"
+# grouped key-value heads with rotary over the whole sequence, each query
+# over the keys its layer's indexer chose (``index_topk`` of those before
+# it): a class that states an indexer has it (``KeyeLMConfig``)
+LAYER_SELECTED_ROPE = "selected_rope"
 
 VALID_LAYER_KINDS = (LAYER_FULL_NOPE, LAYER_WINDOW_ROPE, LAYER_FULL_ROPE,
-                     LAYER_SHORT_CONV)
+                     LAYER_SHORT_CONV, LAYER_SELECTED_ROPE)
 
 
 @dataclass(frozen=True)
@@ -791,6 +795,17 @@ class SparseLMConfig:
     # ``short_conv`` (0: no such layer)
     conv_kernel: ClassVar[int] = 0
     conv_bias: ClassVar[bool] = False
+    # ... and of ``KeyeLMConfig``: the indexer of the layers of kind
+    # ``selected_rope`` (0 keys a query: no such layer), its loss's weight,
+    # and the frequency pairs that read each of three position rows (none:
+    # one row, position = index)
+    index_topk: ClassVar[int] = 0
+    index_heads: ClassVar[int] = 0
+    index_head_dim: ClassVar[int] = 0
+    index_chunk: ClassVar[int] = 0
+    indexer_rotary: ClassVar[bool] = False
+    indexer_loss_weight: ClassVar[float] = 0.0
+    mrope_section: ClassVar[Tuple[int, ...]] = ()
     # fields a configuration's file states and no entry point's flag sets:
     # what the source fixes and models/sparse_lm.py is written for
     # (``validate`` holds each to its one value), and the one assumption
@@ -832,6 +847,10 @@ class SparseLMConfig:
                 raise ValueError(
                     f"layer kind {kind!r} is a short convolution: a class "
                     "that states its length has it (Lfm2MoeLMConfig)")
+            if kind == LAYER_SELECTED_ROPE and self.index_topk < 1:
+                raise ValueError(
+                    f"layer kind {kind!r} attends over the keys an indexer "
+                    "chose: a class that states one has it (KeyeLMConfig)")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
         if self.vocab_text + self.vocab_image != self.vocab_size:
@@ -1119,6 +1138,90 @@ def lfm2moe_model_config(**overrides: Any) -> Lfm2MoeLMConfig:
     """Preset ``lfm2moe``: the cell ``lfm2moe-train-solo``
     (benchmark/configs/lfm2moe.json holds ``asdict`` of it)."""
     return dataclasses.replace(Lfm2MoeLMConfig(), **overrides)
+
+
+@dataclass(frozen=True)
+class KeyeLMConfig(AfmoeLMConfig):
+    """``AfmoeLMConfig``'s gated-SiLU experts behind a softmax router over
+    the chosen that reads the post-attention norm (no dense layer, no
+    shared expert, no output gate, two norms a layer, no embedding scale,
+    head norms) with the mechanisms of ``model_type`` ``KeyeVL2``'s language
+    model as fields: every layer is of kind ``selected_rope``, grouped-query
+    attention in which **a query attends to the keys its layer's indexer
+    chose** (``index_heads`` heads of ``index_head_dim`` over ONE key head
+    score every earlier key, ``relu`` a head, weighted a query and head;
+    the ``index_topk`` largest are the query's set; the indexer reads the
+    layer's normed input with the gradient stopped and learns from a loss
+    of its own, the KL from the heads' mean attention over the set to the
+    softmax of its scores there, weight ``indexer_loss_weight``), and the
+    rotary reads **three position rows** (``mrope_section``: of a head's
+    64 frequency pairs the first 16 read row 0, the next 24 row 1, the
+    last 24 row 2; the indexer's own rotary reads row 0). Defaults are
+    Keye-VL-2.0-30B-A3B (Kwai-Keye, config.json; the vision tower is not
+    this model's) cut to the share one of the 16 chips of a layer holds:
+    7 of 48 alike layers, experts 0-7 of 128, an eighth of the vocabulary;
+    every width as published. ``window`` is no layer's; ``index_chunk`` is
+    the rows of scores the selection and the loss hold at a time (the
+    source's ``q_chunk_size``: it changes no number)."""
+
+    num_hidden_layers: int = 7       # published 48, all alike
+    expert_width: int = 768
+    vocab_size: int = 18992          # published 151936
+    window: int = 0
+    layer_kinds: Tuple[str, ...] = (LAYER_SELECTED_ROPE,)
+    rope_theta: float = 1e7
+    rms_eps: float = 1e-6
+    router_softmax_over_chosen: bool = True
+    vocab_text: int = 9496
+    vocab_image: int = 9496
+    num_dense_layers: int = 0
+    dense_width: int = 0
+    num_shared_experts: int = 0
+    score_func: str = "softmax"
+    selection_bias: bool = False
+    route_norm: bool = False
+    route_scale: float = 1.0
+    attention_gate: bool = False
+    sandwich_norms: bool = False
+    mup_enabled: bool = False
+    index_topk: int = 2048
+    index_heads: int = 16
+    index_head_dim: int = 64
+    index_chunk: int = 512
+    # assumed, each with its reason in benchmark/configs/keyevl2.json
+    indexer_rotary: bool = True
+    indexer_loss_weight: float = 1.0
+    mrope_section: Tuple[int, ...] = (16, 24, 24)
+
+    no_flag: ClassVar[Tuple[str, ...]] = AfmoeLMConfig.no_flag + (
+        "indexer_rotary", "indexer_loss_weight", "mrope_section")
+    decode_missing: ClassVar[Optional[str]] = (
+        "models/decode.py has no indexer (no cache of its one key head, no "
+        "selection of a query's keys at decode time), no grouped key-value "
+        "heads with head norms and three-row rotary and no expert layer")
+
+    def validate(self) -> None:
+        super().validate()
+        if self.num_dense_layers:
+            raise ValueError("every layer of this class is an expert layer")
+        if min(self.index_heads, self.index_head_dim, self.index_chunk) < 1:
+            raise ValueError("the indexer needs index_heads, index_head_dim "
+                             "and index_chunk")
+        if self.index_head_dim % 2 or not self.indexer_rotary:
+            raise ValueError(
+                "models/sparse_lm.py rotates the indexer's queries and key "
+                "(rotate-half over an even index_head_dim)")
+        if sum(self.mrope_section) * 2 != self.head_dim \
+                or len(self.mrope_section) != 3:
+            raise ValueError(
+                "mrope_section names the frequency pairs of three position "
+                "rows: its sum is head_dim / 2")
+
+
+def keyevl2_model_config(**overrides: Any) -> KeyeLMConfig:
+    """Preset ``keyevl2``: the cell ``keyevl2-train-solo``
+    (benchmark/configs/keyevl2.json holds ``asdict`` of it)."""
+    return dataclasses.replace(KeyeLMConfig(), **overrides)
 
 
 def tiny_model_config(**overrides: Any) -> ModelConfig:

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dalle_tpu.config import ModelConfig
+from dalle_tpu.config import LAYER_SELECTED_ROPE, ModelConfig
 from dalle_tpu.models.attention import (NEG_INF, apply_rotary_lanes,
                                         rotary_cos_sin, zoo_attention_mask)
 
@@ -110,6 +110,18 @@ def n_cache_slots(cfg: ModelConfig) -> int:
     return len(cfg.layer_schedule())
 
 
+def refuse_selected_layers(cfg) -> None:
+    """A configuration with a layer of kind ``selected_rope`` (attention
+    over the keys an indexer chose, models/sparse_lm.py) cannot be decoded
+    here, and is told so by the kind's name in one sentence."""
+    kind = LAYER_SELECTED_ROPE
+    if kind in getattr(cfg, "layer_kinds", ()):
+        raise NotImplementedError(
+            f"models/decode.py cannot decode a layer of kind {kind!r}: it "
+            "keeps no cache of the indexer's one key head and has no way to "
+            "choose a query's keys at decode time")
+
+
 def init_cache(cfg: ModelConfig, batch: int, dtype=None):
     """Static-shape KV cache, one k/v pair per layer application (weight
     sharing shares parameters, not activations).
@@ -121,6 +133,7 @@ def init_cache(cfg: ModelConfig, batch: int, dtype=None):
     cycle-structured decode also splits the scanned body from the w_conv
     slot so the scan carries its cache without slicing a big array.
     """
+    refuse_selected_layers(cfg)
     dtype = dtype or jnp.dtype(cfg.dtype)
     hd = cfg.heads * cfg.head_dim
     reps = _cycle_reps(cfg)

@@ -1,6 +1,6 @@
 """A sparse decoder-only language model on the trainer's normal path.
 
-Four configurations' equations, each mechanism read from a field of the
+Five configurations' equations, each mechanism read from a field of the
 configuration and none from a preset's name. What ``SparseLMConfig``
 describes (its defaults: SmallThinker-21BA3B-Instruct, PowerInfer): every
 layer is
@@ -84,6 +84,59 @@ and the head is the embedding's table (``tied_embeddings``: one leaf, which
 gets the sum of both uses' gradients; :func:`_streamed_nll` contracts it
 where it lies). ``full_rope`` is thus any class's kind: which attention runs
 it is read from ``cfg.kv_lora_rank``.
+
+What ``KeyeLMConfig`` describes (its defaults: the language model of
+Keye-VL-2.0-30B-A3B, Kwai-Keye, ``model_type`` ``KeyeVL2``):
+``AfmoeLMConfig``'s gated-SiLU experts behind a softmax router over the chosen that reads the
+post-attention norm (no dense layer, no shared expert, two norms a layer)
+around grouped-query attention **over the keys an indexer chose**, in
+every layer (kind ``selected_rope``; ``sg`` = stop_gradient):
+
+    q,k   = rmsnorm(q), rmsnorm(k)       ``qk_norm``, over a head's 128 lanes
+    q,k  <- rotary (rotate-half), frequency pair i of 64 reading position
+            row 0 for i < 16, row 1 for 16 <= i < 40, row 2 after
+            (``mrope_section`` [16, 24, 24]; :func:`position_tables`)
+    indexer (:class:`Indexer`, parameters under ``attn/indexer``):
+      qI  = sg(a) . W_qI (``index_heads`` J x ``index_head_dim`` e)
+      kI  = layernorm(sg(a) . W_kI)      ONE head of e
+      w   = sg(a) . W_w                  J
+      qI, kI <- rotary (rotate-half over e, position row 0)
+      I[t,s] = (J e)^-1/2 sum_j w[t,j] relu(qI[t,j] . kI[s])        f32
+      S_t = the ``index_topk`` largest I[t,s] over s <= t (every s <= t
+            where t < ``index_topk``; ties to the lower s)
+    o_h[t] = sum_{s in S_t} softmax_{s in S_t}(q_h[t] . k_g(h)[s] / sqrt(d))
+             v_g(h)[s];   h = x + concat_h(o_h) . W_o
+    L_I  += mean_t KL(sg(1/H sum_h P_h[t, .]) || softmax_{s in S_t} I[t, s])
+
+The step's ``loss`` is ``loss_main + indexer_loss_weight * loss_indexer``
+(``L_I`` summed over the layers); both ride beside it in the step's aux
+with ``sparse_selected_pct`` (chosen pairs over causal pairs, the layers'
+median). By construction ``loss_main``'s gradient on the indexer's leaves
+is nought, and ``loss_indexer``'s on every other leaf. The three position
+rows are an input of the layer stack (:func:`field_positions` makes them
+from the two fields' lengths: a text token's three are its index, an image
+token's the text's length, + its row, + its column; nothing else knows the
+rule). **How a layer runs it** (:func:`selected_attend`; the ``setup/warmup``
+row's ``sparse_layout`` says it): a kernel writes the scores of the causal
+band's tiles, one (T, T) f32 array a sequence
+(ops/pallas/indexer_kernels.py); a query's threshold is found by counting,
+four bits a pass, in ``index_chunk``-row chunks of XLA code
+(:func:`select_keys`: no sort; XLA's ``top_k`` of 2 048 among 8 192 takes
+8 times as long on the v5e); the selection is ONE (T, T) f32 array ``sel``
+that holds a chosen pair's score and the kernels' mask value elsewhere, which
+the blockwise kernels read a tile of beside ``q``, ``k`` and ``v``
+(``causal_attention_kernels.selected_*``: the band's every tile is
+visited, so the step's time does not depend on the selection); the heads'
+mean probability is a second (T, T) f32 array written by a kernel from the
+forward's statistics, the loss's row sums are XLA code over both, and its
+gradient to ``qI``, ``kI`` and ``w`` is one kernel that makes the scores'
+cotangent tile by tile. All of it is one derivative rule
+(:func:`_selected_kernels`) that makes the backward's arrays in the
+backward pass between two barriers, so that one layer's are alive at a
+time. No (T, T) array exists per head. Scopes ``attn/indexer/proj``,
+``attn/indexer/scores`` (``scores[mosaic]``, forward and gradient),
+``attn/indexer/select``, ``attn/indexer/align`` (``align[mosaic]`` and the
+row sums), ``attn/qk_norm``, ``attn[mosaic]``.
 
 **The expert layer is told which experts it holds** (``experts_held``
 consecutive ones from ``expert_offset``): it routes over all
@@ -218,12 +271,14 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from dalle_tpu.config import (LAYER_FULL_ROPE, LAYER_SHORT_CONV,
-                              LAYER_WINDOW_ROPE, SparseLMConfig)
+from dalle_tpu.config import (LAYER_FULL_ROPE, LAYER_SELECTED_ROPE,
+                              LAYER_SHORT_CONV, LAYER_WINDOW_ROPE,
+                              SparseLMConfig)
 from dalle_tpu.models import attention as attn_mod
 from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
 from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
 from dalle_tpu.ops.pallas import head_norm_kernels as head_norm
+from dalle_tpu.ops.pallas import indexer_kernels as index_kernels
 from dalle_tpu.ops.pallas import lowering
 from dalle_tpu.ops.pallas import token_sum_kernels as token_sum
 from dalle_tpu.parallel.mesh import LANES_SPEC, sum_over_manual_data_axes
@@ -250,18 +305,47 @@ def _head_pass_key(tokens: int, lanes: int, head_dim: int, tp: int = 1):
     return tokens, lanes // tp, head_dim
 
 
+def position_tables(rows: jax.Array, sections: Tuple[int, ...],
+                    head_dim: int, theta: float, heads: int = 1):
+    """cos/sin (T, heads * head_dim) of a rotate-half rotary whose frequency
+    pair i reads the position row its section names: of ``rows`` (R, T)
+    the first ``sections[0]`` pairs read row 0, the next ``sections[1]``
+    row 1, and so on (both lanes of a pair, in every head). With equal rows
+    these are ``attention.rotary_cos_sin``'s tables of that row, bit for
+    bit: the same f32 product a lane."""
+    half = head_dim // 2
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    row_of_lane = np.tile(np.repeat(np.arange(len(sections)), sections),
+                          2 * heads)
+    rows = rows.astype(jnp.float32)
+    pos = rows[0][:, None]
+    for r in range(1, len(sections)):
+        pos = jnp.where(row_of_lane == r, rows[r][:, None], pos)
+    angles = pos * jnp.tile(freqs, 2 * heads)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
 def head_pass(x, scale=None, *, mesh, eps: float, head_dim: int,
-              theta: Optional[float]):
+              theta: Optional[float], positions=None,
+              sections: Tuple[int, ...] = ()):
     """The work on each head of x (B, T, H*d) between a projection and the
     attention: the RMS norm over the head's d lanes where there is a
     ``scale`` (one vector for all heads), then the rotary of positions
-    0..T-1 where there is a ``theta``. A shard's is one pass of the kernel
+    0..T-1 where there is a ``theta`` (with ``positions`` (R, T) and
+    ``sections``, of the rows its frequency pairs read:
+    :func:`position_tables`). A shard's is one pass of the kernel
     that reads one head's tables where it fits
     (ops/pallas/head_norm_kernels.py), else the two expressions it
     replaces: the reshape to heads and :func:`rms_norm`, and
     ``attention.apply_rotary_lanes`` with tables as wide as the array."""
     norm, rotary = scale is not None, theta is not None
     name = _head_pass_site(norm, rotary)
+
+    def cos_sin(tokens: int, heads: int = 1):
+        if positions is None:
+            return attn_mod.rotary_cos_sin(jnp.arange(tokens), head_dim,
+                                           theta, heads)
+        return position_tables(positions, sections, head_dim, theta, heads)
 
     def fits(x, scale=None) -> bool:
         _, t, width = x.shape
@@ -274,8 +358,7 @@ def head_pass(x, scale=None, *, mesh, eps: float, head_dim: int,
     def kernel(x, scale=None):
         tables = None
         if rotary:
-            tables = head_norm.rotary_tables(*attn_mod.rotary_cos_sin(
-                jnp.arange(x.shape[1]), head_dim, theta))
+            tables = head_norm.rotary_tables(*cos_sin(x.shape[1]))
         return head_norm.per_head(x, scale, tables, eps, head_dim,
                                   lowering.interpret())
 
@@ -286,8 +369,7 @@ def head_pass(x, scale=None, *, mesh, eps: float, head_dim: int,
                          eps).reshape(x.shape)
         if rotary:
             x = attn_mod.apply_rotary_lanes(
-                x, *attn_mod.rotary_cos_sin(jnp.arange(t), head_dim, theta,
-                                            width // head_dim), head_dim)
+                x, *cos_sin(t, width // head_dim), head_dim)
         return x
 
     return lowering.site(
@@ -371,8 +453,390 @@ def attend(q, k, v, *, mesh, kind: str, window: Optional[int],
 
 
 # the kinds whose queries and keys are rotated (``full_rope`` here: grouped
-# key-value heads; a class with ``kv_lora_rank`` runs LatentAttention)
-ROPE_KINDS = (LAYER_WINDOW_ROPE, LAYER_FULL_ROPE)
+# key-value heads; a class with ``kv_lora_rank`` runs LatentAttention;
+# ``selected_rope``: over the keys an indexer chose)
+ROPE_KINDS = (LAYER_WINDOW_ROPE, LAYER_FULL_ROPE, LAYER_SELECTED_ROPE)
+
+
+# ---------------------------------------------------------------------------
+# Attention over the keys an indexer chose (layers of kind ``selected_rope``)
+# ---------------------------------------------------------------------------
+
+# what ``sel`` holds off a query's set (the kernels' own mask value)
+OFF = kernels.MASK_VALUE
+
+
+def dense_index_scores(qi, ki, w, scale: float) -> jax.Array:
+    """(B, T, T) f32: ``scale * sum_j w[t, j] relu(qi[t, j] . ki[s])``, every
+    pair. qi: (B, T, J*d); ki: (B, T, d); w: (B, T, J) f32. The XLA lowering
+    of ``indexer_kernels.index_scores``, and differentiable."""
+    b, t, d = ki.shape
+    z = jnp.einsum("bqjd,bkd->bqjk", qi.reshape(b, t, -1, d), ki,
+                   preferred_element_type=jnp.float32)
+    return scale * jnp.einsum("bqjk,bqj->bqk", jax.nn.relu(z),
+                              w.astype(jnp.float32))
+
+
+def _sortable(x: jax.Array) -> jax.Array:
+    """f32 -> uint32 whose order is the numbers' (-0.0 counted as 0.0)."""
+    bits = jax.lax.bitcast_convert_type(x + 0.0, jnp.int32)
+    flipped = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    return jax.lax.bitcast_convert_type(flipped, jnp.uint32) \
+        ^ jnp.uint32(0x80000000)
+
+
+# bits of a key settled a pass of the threshold's search: 2^4 - 1 = 15
+# candidates counted in one reading of the scores, 8 readings in all
+SEARCH_BITS = 4
+
+
+def _kth_largest(keys: jax.Array, k: jax.Array) -> jax.Array:
+    """(..., 1) uint32: each row's ``k``-th largest of ``keys`` (..., N),
+    the largest u with ``count(keys >= u) >= k``: found from the top bits
+    down, ``SEARCH_BITS`` a pass, by counting. No sort."""
+    digits = jnp.arange(1, 2 ** SEARCH_BITS, dtype=jnp.uint32)
+
+    def settle(i, found):
+        shift = (32 - SEARCH_BITS * (i + 1)).astype(jnp.uint32)
+        candidates = found | (digits << shift)              # (..., 15)
+        counts = jnp.sum(keys[..., None, :] >= candidates[..., None],
+                         axis=-1, dtype=jnp.int32)
+        # counts fall as the digit grows: the largest digit that keeps k
+        digit = jnp.sum(counts >= k, axis=-1, keepdims=True,
+                        dtype=jnp.int32).astype(jnp.uint32)
+        return found | (digit << shift)
+
+    return jax.lax.fori_loop(0, 32 // SEARCH_BITS, settle,
+                             jnp.zeros(keys.shape[:-1] + (1,), jnp.uint32))
+
+
+# Row chunks whose keys end at different columns are grouped, and a group's
+# chunks run as one loop over the group's widest: the compiled step holds a
+# copy of the loop's body a group, a layer and a direction (unrolled, one a
+# chunk, the seven layers' selections were 57 of the step's 167 MB of
+# program, which no compile cache of the chip's kept: PERF.md section 6, PR
+# 52), for about a sixth more keys counted than each chunk's own width.
+SELECT_GROUPS = 3
+ALIGN_GROUPS = 4
+
+
+def _chunk_groups(chunks: int, first: int, groups: int):
+    """``[(first chunk, chunk after the last)]``: the chunks ``first`` ..
+    ``chunks`` - 1 in at most ``groups`` runs of near-equal length."""
+    count = chunks - first
+    groups = max(1, min(groups, count))
+    edges = [first + count * g // groups for g in range(groups + 1)]
+    return [(a, b) for a, b in zip(edges, edges[1:]) if b > a]
+
+
+def _by_chunks(body, operands, first_row: int, chunk: int):
+    """``body(row indices (chunk,), *chunk's rows of each operand)`` over
+    the ``chunk``-row slices of ``operands`` (B, R, W), R a whole number of
+    chunks that starts at row ``first_row``, as one loop; each result
+    (B, chunk, ...) comes back as (B, R, ...)."""
+    b, r = operands[0].shape[:2]
+    split = lambda x: x.reshape(b, r // chunk, chunk, *x.shape[2:]).swapaxes(
+        0, 1)
+    starts = first_row + chunk * jnp.arange(r // chunk)
+    out = jax.lax.map(
+        lambda xs: body(xs[0] + jnp.arange(chunk), *xs[1:]),
+        (starts, *(split(x) for x in operands)))
+    join = lambda y: y.swapaxes(0, 1).reshape(b, r, *y.shape[3:])
+    return jax.tree.map(join, out)
+
+
+def _choose(rows, x, topk: int):
+    """(B, chunk, W) bool: of the keys 0 .. W - 1, those of each query's
+    set, for queries ``rows`` (chunk,) with scores ``x``."""
+    rows = rows[:, None]
+    causal = jnp.arange(x.shape[-1])[None, :] <= rows
+    keys = jnp.where(causal, _sortable(x), jnp.uint32(0))
+    k = jnp.minimum(rows + 1, topk)
+    kth = _kth_largest(keys, k)
+    above, equal = keys > kth, keys == kth
+    want = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    tied = jnp.sum(equal, axis=-1, keepdims=True, dtype=jnp.int32)
+
+    def lower_first(equal):
+        before = jnp.cumsum(equal, axis=-1, dtype=jnp.int32) - equal
+        return equal & (before < want)
+
+    return causal & (above | jax.lax.cond(
+        jnp.any(tied > want), lower_first, lambda e: e, equal))
+
+
+# jitted so that the layers' calls, forward and backward, share one trace
+# and one lowering
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def select_keys(scores: jax.Array, topk: int, chunk: int) -> jax.Array:
+    """(B, T, T) f32 ``sel``: ``scores[t, s]`` where s is one of the
+    ``topk`` largest of row t over s <= t (every s <= t where t < ``topk``;
+    ties to the lower s, as ``lax.top_k``), :data:`OFF` elsewhere. Reads
+    nothing above the diagonal's ``chunk``-row tiles (the kernel leaves
+    them unwritten). ``chunk`` rows at a time, each over the keys up to the
+    last row of its group of chunks (:func:`_chunk_groups`); a row's
+    threshold is found by counting (:func:`_kth_largest`), and the tie rule
+    costs a running count along the keys only in a chunk where a tie
+    straddles some row's threshold. The rows before ``topk`` choose every
+    key before them: no search."""
+    t = scores.shape[1]
+    pad = -t % chunk
+    if pad:
+        scores = jnp.pad(scores, ((0, 0), (0, pad), (0, pad)))
+    chunks, plain = (t + pad) // chunk, min(topk, t) // chunk
+    out = []
+    if plain:
+        last = plain * chunk
+        i = jnp.arange(last)
+        out.append((jnp.where(i[None, :] <= i[:, None],
+                              scores[:, :last, :last], OFF), last))
+    for first, after in _chunk_groups(chunks, plain, SELECT_GROUPS):
+        r0, last = first * chunk, after * chunk
+        x = scores[:, r0:last, :last]
+        chosen = _by_chunks(lambda rows, x: _choose(rows, x, topk), (x,),
+                            r0, chunk)
+        out.append((jnp.where(chosen, x, OFF), last))
+    whole = jnp.concatenate([
+        jnp.pad(part, ((0, 0), (0, 0), (0, t + pad - last)),
+                constant_values=OFF) for part, last in out], axis=1)
+    return whole[:, :t, :t]
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _align_rows(sel: jax.Array, pbar: jax.Array, chunk: int):
+    """Each row's KL(pbar || softmax of ``sel`` over its set), its
+    log-sum-exp of ``sel`` there and the keys in its set: (B, T) f32 each.
+    ``pbar`` is read on the sets only (and nowhere above the diagonal's
+    tiles). ``chunk`` rows at a time, over the keys up to the last row of
+    the chunk's group."""
+    t = sel.shape[1]
+    pad = -t % chunk
+    if pad:
+        sel = jnp.pad(sel, ((0, 0), (0, pad), (0, pad)), constant_values=OFF)
+        pbar = jnp.pad(pbar, ((0, 0), (0, pad), (0, pad)))
+
+    def rows_of(rows, x, target):
+        on = x > OFF
+        lse = jax.nn.logsumexp(x, axis=-1)
+        target = jnp.where(on, target, 0.0)
+        kl = jnp.sum(jax.scipy.special.xlogy(target, target)
+                     - target * jnp.where(on, x - lse[..., None], 0.0),
+                     axis=-1)
+        return kl, lse, jnp.sum(on, axis=-1, dtype=jnp.float32)
+
+    out = []
+    for first, after in _chunk_groups((t + pad) // chunk, 0, ALIGN_GROUPS):
+        r0, last = first * chunk, after * chunk
+        out.append(_by_chunks(rows_of, (sel[:, r0:last, :last],
+                                        pbar[:, r0:last, :last]), r0, chunk))
+    return tuple(jnp.concatenate(parts, axis=1)[:, :t]
+                 for parts in zip(*out))
+
+
+def _chosen_keys(qi, ki, w, topk: int, chunk: int, scale: float):
+    """``sel`` (B, T, T) f32 of the indexer's three operands: the scores'
+    kernel and the selection."""
+    with jax.named_scope("indexer"):
+        with jax.named_scope("scores"):
+            scores = index_kernels.index_scores(
+                qi, ki, w, scale, kernels.BLOCK, lowering.interpret())
+        with jax.named_scope("select"):
+            t = qi.shape[1]
+            return select_keys(scores[:, :t, :t], topk, chunk)
+
+
+def _mean_and_rows(q, k, stats, sel, chunk: int):
+    """The heads' mean probability and the loss's row sums
+    (:func:`_align_rows`) under the scope ``indexer/align``."""
+    with jax.named_scope("indexer"), jax.named_scope("align"):
+        pbar = kernels.selected_mean_probs(q, k, stats, sel, kernels.BLOCK,
+                                           lowering.interpret())
+        return pbar, _align_rows(sel, pbar, chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _selected_kernels(q, k, v, qi, ki, w, topk: int, chunk: int,
+                      scale: float):
+    """Attention over the chosen keys and the indexer's loss, on Mosaic but
+    for the selection and the loss's row sums: returns the context, each
+    sample's KL summed over its rows, and its chosen pairs.
+
+    One derivative rule for all of it, so that what the backward pass needs
+    of the three (T, T) arrays (the selection, the heads' mean probability;
+    the scores on the way) is made *in* the backward pass, behind a barrier
+    with the output's cotangent on one side and all six cotangents on the
+    other. Left to a rematerialised layer's replay and to plain
+    differentiation, the compiler's schedule holds every layer's arrays at
+    once: 0.75 GiB a layer at 8 192 tokens (PERF.md section 6, PR 52). The
+    rule keeps the six operands, the context and the statistics, as
+    ``causal_attention`` does."""
+    return _selected_kernels_fwd(q, k, v, qi, ki, w, topk, chunk, scale)[0]
+
+
+def _selected_kernels_fwd(q, k, v, qi, ki, w, topk, chunk, scale):
+    sel = _chosen_keys(qi, ki, w, topk, chunk, scale)
+    ctx, stats = kernels.selected_forward(q, k, v, sel, kernels.BLOCK,
+                                          lowering.interpret())
+    ctx = checkpoint_name(ctx, "attn_out")
+    stats = checkpoint_name(stats, "attn_stats")
+    _, (kl, _, count) = _mean_and_rows(q, k, stats, sel, chunk)
+    # ... and the context is not out before the loss's sums are (see the
+    # backward rule): the selection is held for one layer at a time
+    out = jax.lax.optimization_barrier(
+        (ctx, jnp.sum(kl, axis=1), jnp.sum(count, axis=1)))
+    return out, (q, k, v, qi, ki, w, ctx, stats)
+
+
+def _selected_kernels_bwd(topk, chunk, scale, res, cotangents):
+    dctx, dkl, _ = cotangents                # a count carries no gradient
+    # nothing below starts before the context's cotangent is there
+    dctx, res = jax.lax.optimization_barrier((dctx, res))
+    q, k, v, qi, ki, w, ctx, stats = res
+    sel = _chosen_keys(qi, ki, w, topk, chunk, scale)
+    dq, dk, dv = kernels.selected_backward(
+        q, k, v, sel, ctx, stats, dctx, kernels.BLOCK, lowering.interpret())
+    pbar, (_, lse, _) = _mean_and_rows(q, k, stats, sel, chunk)
+    # the scores' backward, with the KL's cotangent made on each tile
+    with jax.named_scope("indexer"), jax.named_scope("scores"):
+        dqi, dki, dw = index_kernels.index_grads(
+            qi, ki, w, lse, jnp.broadcast_to(dkl[:, None], lse.shape), sel,
+            pbar, scale, kernels.BLOCK, lowering.interpret())
+    # ... and no cotangent leaves before all have: the indexer's feed
+    # nothing but its own leaves' gradients, and a compiler free to put
+    # them off does, to the end of the backward pass, with every layer's
+    # (T, T) arrays held until then
+    return jax.lax.optimization_barrier((dq, dk, dv, dqi, dki, dw))
+
+
+_selected_kernels.defvjp(_selected_kernels_fwd, _selected_kernels_bwd)
+
+
+def dense_selected_attention(q, k, v, qi, ki, w, *, topk: int, chunk: int,
+                             scale: float, head_dim: int):
+    """The XLA lowering of :func:`_selected_kernels` (no Mosaic backend, or
+    widths the kernels refuse): dense scores and masks, the same selection,
+    plain differentiation."""
+    b, t, _ = q.shape
+    with jax.named_scope("indexer"):
+        with jax.named_scope("scores"):
+            scores = dense_index_scores(qi, ki, w, scale)
+        with jax.named_scope("select"):
+            sel = select_keys(jax.lax.stop_gradient(scores), topk, chunk)
+    on = sel > OFF
+    g = k.shape[2] // head_dim
+    qh = q.reshape(b, t, g, -1, head_dim)
+    kh, vh = k.reshape(b, t, g, head_dim), v.reshape(b, t, g, head_dim)
+    s = jnp.einsum("bqgnd,bkgd->bgnqk", qh, kh,
+                   preferred_element_type=jnp.float32) * head_dim ** -0.5
+    prob = jax.nn.softmax(jnp.where(on[:, None, None], s, attn_mod.NEG_INF),
+                          axis=-1)
+    ctx = jnp.einsum("bgnqk,bkgd->bqgnd", prob.astype(v.dtype), vh,
+                     preferred_element_type=jnp.float32)
+    with jax.named_scope("indexer"), jax.named_scope("align"):
+        pbar = jax.lax.stop_gradient(jnp.mean(prob, axis=(1, 2)))
+        log_sigma = jax.nn.log_softmax(jnp.where(on, scores, OFF), axis=-1)
+        kl = jnp.sum(jnp.where(on, jax.scipy.special.xlogy(pbar, pbar)
+                               - pbar * log_sigma, 0.0), axis=(1, 2))
+        chosen = jnp.sum(on, axis=(1, 2), dtype=jnp.float32)
+    return ctx.reshape(q.shape).astype(q.dtype), kl, chosen
+
+
+SELECTED_SITE = "selected attention"
+SAMPLES_SPEC = P(LANES_SPEC[0])
+
+
+def _selected_key(tokens: int, q_lanes: int, kv_lanes: int, heads: int,
+                  topk: int):
+    """What the record knows an attention over chosen keys by: a sample's
+    tokens, the queries' and keys' lanes, the indexer's heads and the size
+    of a set."""
+    return tokens, q_lanes, kv_lanes, heads, topk
+
+
+def selected_attend(q, k, v, qi, ki, w, *, mesh, cfg: SparseLMConfig,
+                    scope: Optional[str] = None):
+    """Attention of q (B, T, H*d) over the keys of k, v (B, T, G*d) that
+    the indexer's qi (B, T, J*e), ki (B, T, e) and w (B, T, J) choose for
+    each query, and the indexer's loss: (context, (B,) KL summed over a
+    sample's rows, (B,) chosen pairs). A shard's is the kernels where they
+    fit, else :func:`dense_selected_attention`. No mesh axis may split the
+    heads: the loss's target is their mean."""
+    if _splits(mesh, LANES_SPEC[2]):
+        raise NotImplementedError(
+            "attention over chosen keys averages the heads' probabilities "
+            "for the indexer's loss: a mesh axis that splits the heads "
+            "(tp) would need that mean summed over it")
+    topk, chunk = cfg.index_topk, cfg.index_chunk
+    scale = (cfg.index_heads * cfg.index_head_dim) ** -0.5
+
+    def fits(q, k, v, qi, ki, w) -> bool:
+        t, item = q.shape[1], q.dtype.itemsize
+        why_not = kernels.selected_fits(t, q.shape[2], k.shape[2],
+                                        cfg.head_dim, item) \
+            or index_kernels.fits(t, cfg.index_heads, cfg.index_head_dim,
+                                  item)
+        return lowering.chose(
+            SELECTED_SITE,
+            _selected_key(t, q.shape[2], k.shape[2], cfg.index_heads, topk),
+            why_not,
+            why_not or f"local q{tuple(q.shape)} over k{tuple(k.shape)}, "
+            f"{topk} keys a query chosen by {cfg.index_heads} heads of "
+            f"{cfg.index_head_dim}: " + sparse_words(chunk))
+
+    lanes, samples = P(*LANES_SPEC[:2], None), SAMPLES_SPEC
+    return lowering.site(
+        SELECTED_SITE, fits,
+        lambda *operands: _selected_kernels(*operands, topk, chunk, scale),
+        functools.partial(dense_selected_attention, topk=topk, chunk=chunk,
+                          scale=scale, head_dim=cfg.head_dim),
+        mesh, (lanes,) * 6, (lanes, samples, samples), scope)(
+            q, k, v, qi, ki, w)
+
+
+def sparse_words(chunk: int) -> str:
+    """What the Mosaic lowering of a ``selected_rope`` layer is handed, in
+    words (the ``setup/warmup`` row's ``sparse_layout``)."""
+    return (
+        f"scores (T, T) f32 a sequence by a kernel over the causal band's "
+        f"tiles of {kernels.BLOCK}; a query's threshold by counting, "
+        f"{SEARCH_BITS} bits a pass, in {chunk}-row chunks of XLA code (no "
+        "sort), ties to the lower key; the kernels are handed one (T, T) "
+        "f32 array that holds a chosen pair's score and the mask value "
+        "elsewhere, and visit every tile of the causal band (none is "
+        "skipped: the walk does not depend on the data); the heads' mean "
+        "probability (T, T) f32 by a kernel from the forward's statistics; "
+        "the loss's row sums XLA code, its gradient to the indexer one "
+        "kernel with no (T, T) cotangent")
+
+
+class Indexer(nn.Module):
+    """The indexer's three operands of a layer (module docstring): queries
+    of ``index_heads`` heads, ONE normed key head, a weight a query and
+    head; queries and key rotated (rotate-half over ``index_head_dim``,
+    position row 0). It reads the layer's normed input with the gradient
+    stopped. Leaves ``q/kernel``, ``k/kernel``, ``k_norm/{scale,bias}``,
+    ``weights/kernel``."""
+    cfg: SparseLMConfig
+
+    @nn.compact
+    def __call__(self, a: jax.Array, positions: jax.Array):
+        cfg = self.cfg
+        dt, pdt = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=dt,
+                                  param_dtype=pdt)
+        heads, dim = cfg.index_heads, cfg.index_head_dim
+        a = jax.lax.stop_gradient(a)
+        with jax.named_scope("proj"):
+            qi = dense(heads * dim, name="q")(a)
+            ki = nn.LayerNorm(epsilon=cfg.rms_eps, dtype=dt, param_dtype=pdt,
+                              name="k_norm")(dense(dim, name="k")(a))
+            w = nn.Dense(heads, use_bias=False, dtype=dt, param_dtype=pdt,
+                         name="weights")(a).astype(jnp.float32)
+            rotate = lambda x: attn_mod.apply_rotary_lanes(
+                x, *attn_mod.rotary_cos_sin(positions[0], dim,
+                                            cfg.rope_theta,
+                                            x.shape[-1] // dim), dim)
+            return rotate(qi), rotate(ki), w
 
 
 class Attention(nn.Module):
@@ -381,7 +845,10 @@ class Attention(nn.Module):
     mesh: Any = None
 
     @nn.compact
-    def __call__(self, a: jax.Array) -> jax.Array:
+    def __call__(self, a: jax.Array, positions=None):
+        """``positions`` (3, T): the rows a configuration with
+        ``mrope_section`` rotates by. A ``selected_rope`` layer returns the
+        indexer's counters beside its output."""
         cfg = self.cfg
         dt, pdt = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
         dense = functools.partial(nn.Dense, use_bias=False, dtype=dt,
@@ -391,16 +858,26 @@ class Attention(nn.Module):
         v = dense(cfg.num_kv_heads * cfg.head_dim, name="v")(a)
         rope = self.kind in ROPE_KINDS
         if cfg.qk_norm or rope:
+            rows = dict(positions=positions, sections=cfg.mrope_section) \
+                if cfg.mrope_section else {}
+
             def per_head(x, name):
                 scale = self.param(name, nn.initializers.ones,
                                    (cfg.head_dim,), pdt) \
                     if cfg.qk_norm else None
                 return head_pass(x, scale, mesh=self.mesh, eps=cfg.rms_eps,
                                  head_dim=cfg.head_dim,
-                                 theta=cfg.rope_theta if rope else None)
+                                 theta=cfg.rope_theta if rope else None,
+                                 **rows)
 
             with jax.named_scope(head_norm.scope(cfg.qk_norm)):
                 q, k = per_head(q, "q_norm"), per_head(k, "k_norm")
+        if self.kind == LAYER_SELECTED_ROPE:
+            ctx, kl, chosen = selected_attend(
+                q, k, v, *Indexer(cfg, name="indexer")(a, positions),
+                mesh=self.mesh, cfg=cfg, scope=self.name)
+            return dense(cfg.hidden_size, name="out")(ctx), {
+                "index_kl": kl, "index_chosen": chosen}
         ctx = attend(q, k, v, mesh=self.mesh, kind=self.kind,
                      window=cfg.window if self.kind == LAYER_WINDOW_ROPE
                      else None,
@@ -1336,7 +1813,7 @@ class Layer(nn.Module):
     dense: bool = False
 
     @nn.compact
-    def __call__(self, x: jax.Array):
+    def __call__(self, x: jax.Array, positions=None):
         cfg = self.cfg
         norm = lambda name, v: rms_norm(
             v, self.param(name, nn.initializers.ones, (cfg.hidden_size,),
@@ -1350,8 +1827,12 @@ class Layer(nn.Module):
             y = ShortConv(cfg, name="conv")(a)
         elif self.kind == LAYER_FULL_ROPE and cfg.kv_lora_rank:
             y = LatentAttention(cfg, self.mesh, name="attn")(a)
+        elif self.kind == LAYER_SELECTED_ROPE:
+            y, indexer = Attention(cfg, self.kind, self.mesh, name="attn")(
+                a, positions)
         else:
-            y = Attention(cfg, self.kind, self.mesh, name="attn")(a)
+            y = Attention(cfg, self.kind, self.mesh, name="attn")(
+                a, *[positions] * bool(cfg.mrope_section))
         if cfg.sandwich_norms:
             y = norm("post_attn_norm", y)
         h = x + y
@@ -1365,6 +1846,8 @@ class Layer(nn.Module):
             # the experts every token chose; nothing is kept otherwise
             self.sow("intermediates", "chosen", idx)
             y, counters = ff(m, idx, p)
+            if self.kind == LAYER_SELECTED_ROPE:
+                counters = {**counters, **indexer}
         if cfg.sandwich_norms:
             y = norm("post_ff_norm", y)
         return h + y, counters
@@ -1480,10 +1963,17 @@ class SparseLM(nn.Module):
         layer_cls = nn.remat(
             Layer, policy=jax.checkpoint_policies.save_only_these_names(
                 *KEPT_OF_A_LAYER))
+        # the rows a configuration with ``mrope_section`` rotates by: an
+        # input of the layer stack, made here from the two fields' lengths
+        rows = ()
+        if cfg.mrope_section:
+            rows = (field_positions(text_tokens.shape[1],
+                                    image_tokens.shape[1], cfg.image_grid),)
         counters = []
         for i in range(cfg.num_hidden_layers):
             x, c = layer_cls(cfg, cfg.kind_of_layer(i), self.mesh,
-                             cfg.layer_is_dense(i), name=f"layer_{i}")(x)
+                             cfg.layer_is_dense(i), name=f"layer_{i}")(
+                                 x, *rows)
             if c is not None:
                 counters.append(c)
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
@@ -1549,6 +2039,17 @@ class SparseLM(nn.Module):
             losses = {"loss_main": loss, "loss_mtp": mtp_sums[0] / mtp_denom}
             loss = loss + cfg.mtp_loss_weight * losses["loss_mtp"]
         stack = lambda key: jnp.stack([c[key] for c in counters])
+        if cfg.index_topk:
+            # the indexer's loss: the layers' sum of the mean over the
+            # (micro)batch's tokens of a row's KL; and the chosen pairs over
+            # the causal pairs, the layers' median
+            rows_all = sum_over_manual_data_axes(float(b * t))
+            causal = sum_over_manual_data_axes(b * t * (t + 1) / 2.0)
+            losses = {"loss_main": loss,
+                      "loss_indexer": jnp.sum(stack("index_kl")) / rows_all,
+                      "sparse_selected_pct": 100.0 * jnp.median(jnp.sum(
+                          stack("index_chosen"), axis=1)) / causal}
+            loss = loss + cfg.indexer_loss_weight * losses["loss_indexer"]
         aux = {
             "loss": loss, **losses,
             "loss_text": sums[0] / jnp.maximum(denoms[0], 1.0),
@@ -1579,6 +2080,20 @@ class SparseLM(nn.Module):
 # ---------------------------------------------------------------------------
 # What task.py and training/loop.py ask of a model's module
 # ---------------------------------------------------------------------------
+
+def field_positions(text_len: int, image_len: int, grid: int) -> jax.Array:
+    """(3, T) int32, the three position rows of a ``text`` field of
+    ``text_len`` tokens followed by an ``image`` field of ``image_len``
+    tokens, rows of ``grid``: text token i has (i, i, i); the image token
+    at (r, c) has (text_len, text_len + r, text_len + c). The one place
+    that knows the rule: the layers take the rows."""
+    i = np.arange(text_len)
+    r, c = np.divmod(np.arange(image_len), grid)
+    start = np.full(image_len, text_len)
+    return jnp.asarray(np.stack([
+        np.concatenate([i, start]), np.concatenate([i, start + r]),
+        np.concatenate([i, start + c])]).astype(np.int32))
+
 
 def build(cfg: SparseLMConfig, mesh=None) -> SparseLM:
     return SparseLM(cfg, mesh=mesh)
@@ -1656,7 +2171,7 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
             backward += f" ({on} of {len(kinds)} layers)"
     windows = sum(k == LAYER_WINDOW_ROPE for k in kinds)
     whole = sum(k == LAYER_FULL_ROPE for k in kinds)
-    ropes = windows + whole
+    ropes = sum(k in ROPE_KINDS for k in kinds)
 
     def passes(norm: bool, rotary: bool):
         """Queries' and keys' per-head work of one kind: the same two
@@ -1738,6 +2253,24 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     said = {"attn_layout": attn_layout}
     if cfg.kv_lora_rank:
         said = _latent_records(cfg, tp)
+    if cfg.index_topk:
+        # every layer is of the one kind and shape: one call says it
+        why_not = lowering.why_not(SELECTED_SITE, _selected_key(
+            tokens, cfg.num_heads * cfg.head_dim,
+            cfg.num_kv_heads * cfg.head_dim, cfg.index_heads,
+            cfg.index_topk))
+        took = (f"dense XLA lowering ({why_not})" if why_not else
+                f"blockwise {kernels.BLOCK}: {len(kinds)} of {len(kinds)} "
+                f"layers, {cfg.num_heads // cfg.num_kv_heads} query heads a "
+                "key-value head, backward: " + _backward_words(None))
+        said = {
+            "attn_layout": (
+                f"over {cfg.index_topk} keys a query, chosen by an indexer "
+                f"of {cfg.index_heads} heads of {cfg.index_head_dim} over "
+                f"one key head, {took}{words}, positions: three rows by "
+                f"sections {list(cfg.mrope_section)}"),
+            "sparse_layout": (f"dense masks in XLA code ({why_not})"
+                              if why_not else sparse_words(cfg.index_chunk))}
     if len(kinds) < len(layers):
         said["conv_layout"] = conv_layout(cfg)
     if cfg.tied_embeddings:
@@ -1769,8 +2302,11 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
 def step_attributes(cfg: SparseLMConfig) -> Tuple[str, ...]:
     """Entries of the step's aux that go onto every ``loop/step`` row: the
     expert layers' counters and, of a configuration with a prediction
-    module, the two losses its loss is made of."""
+    module or an indexer, the two losses its loss is made of (and an
+    indexer's chosen pairs over the causal pairs)."""
     losses = ("loss_main", "loss_mtp") if cfg.num_nextn_predict_layers else ()
+    if cfg.index_topk:
+        losses = ("loss_main", "loss_indexer", "sparse_selected_pct")
     return ("moe_assignments_here_pct", "moe_load_max_over_mean",
             "moe_dropped", "moe_dense_calls", "moe_sum_spills",
             "moe_tiles_active_pct") + losses

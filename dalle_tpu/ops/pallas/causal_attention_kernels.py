@@ -139,14 +139,16 @@ def blockwise_fits(q_width: int, kv_width: int, head_dim: int) -> Optional[str]:
 
 
 def fused_backward_fits(tokens: int, group: int, itemsize: int,
-                        block: int = BLOCK) -> Optional[str]:
+                        block: int = BLOCK,
+                        selected: bool = False) -> Optional[str]:
     """None where the one-kernel backward holds a key-value head's whole
     ``dk`` and ``dv`` in VMEM at these local shapes (``tokens`` a sample,
     ``group`` query heads a key-value head, operands of ``itemsize``
     bytes), else why not: then the backward is the ``dq`` and the
     ``dk``/``dv`` kernel, which hold a block each. With two 64-wide heads a
     lane tile the same sizes are a key-value tile's and its ``group`` query
-    tiles'."""
+    tiles'. ``selected``: the allowed pairs are data
+    (:func:`selected_attention`), one more (block, block) f32 tile a step."""
     t = tokens + -tokens % block
     tile = block * LANES
     need = (2 * t * LANES * 4                   # dk, dv accumulators, f32
@@ -155,7 +157,8 @@ def fused_backward_fits(tokens: int, group: int, itemsize: int,
             + 3 * 2 * group * tile * itemsize   # q, do, dq tiles
             + 2 * 2 * tile * itemsize           # k, v tiles
             + 2 * 2 * tile * 4                  # statistics, delta
-            + 4 * block * block * 4)            # scores, p, dp, ds
+            + 4 * block * block * 4             # scores, p, dp, ds
+            + selected * 2 * block * block * 4)  # the selection's tile
     if need > VMEM_LIMIT_BYTES:
         return (f"dk and dv of {t} tokens need {need / 2 ** 20:.1f} MiB of "
                 f"VMEM, over {VMEM_LIMIT_BYTES / 2 ** 20:g}")
@@ -572,7 +575,7 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
           block: int, window: Optional[int], key_major: bool,
           interpret: bool, scale: float = LANES ** -0.5,
           steps: Optional[int] = None, heads_parallel: bool = True,
-          v_block: int = 0):
+          v_block: int = 0, more_specs=None):
     """``operands`` / ``outs``: (array or shape-dtype, kind) with kind "q"
     (a group's query lanes, by query block), "kv" (one key-value head, by
     key block), "kv_all" (one key-value head's whole sequence) or "stats"
@@ -582,8 +585,10 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
     "k_rope" (the shared rotary key's placement, by key block) and
     "k_rope_all" (its cotangent's whole sequence, the same block for every
     head) and "v_group" (the group's value lanes, ``v_block`` column blocks
-    into an array that holds the keys' lanes first). ``steps``: the grid's
-    head axis, where it is not the key-value heads of the second operand."""
+    into an array that holds the keys' lanes first); for a selection read
+    from the data, "sel" (a (B, T, T) array's tile of the pair). ``steps``:
+    the grid's head axis, where it is not the key-value heads of the second
+    operand. ``more_specs``: a caller's own kinds, {kind: BlockSpec}."""
     b = operands[1][0].shape[0]
     g = steps or operands[1][0].shape[2] // LANES
     table = band_pairs(t // block, block, window, key_major)
@@ -612,6 +617,10 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
         "v_group": pl.BlockSpec((1, block, group * LANES),
                                 lambda i, j, p, qi, ki, fi, la:
                                 (i, ki[p], v_block + j)),
+        "sel": pl.BlockSpec((1, block, block),
+                            lambda i, j, p, qi, ki, fi, la:
+                            (i, qi[p], ki[p])),
+        **(more_specs or {}),
     }
     return pl.pallas_call(
         functools.partial(kernel, scale=scale, group=group,
@@ -986,3 +995,248 @@ def _latent_vjp_bwd(block, interpret, res, dout):
 
 
 latent_attention.defvjp(_latent_vjp_fwd, _latent_vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Attention over a selected set: the allowed pairs are data
+# ---------------------------------------------------------------------------
+#
+# ``sel`` (B, T, T) f32 holds, for query t and key s, a score where s is in
+# t's set and ``MASK_VALUE`` where it is not (every s > t among those): what
+# an indexer's selection writes (models/sparse_lm.py). The kernels walk the
+# causal band's tiles as ``causal_attention`` does, every one of them (a
+# set of thousands of keys scattered over the keys before a query leaves no
+# tile of 512 x 512 empty, and a walk that depended on the data would make
+# the step's time depend on it), and read a tile's allowed pairs from
+# ``sel``'s tile instead of computing them from the indices: one more
+# (block, block) f32 operand a grid step. Everything else is
+# ``_causal_fwd_kernel``'s and ``_causal_bwd_kernel``'s: the tile
+# arithmetic, ``_softmax_step``, the statistics' layout, ``_tile_backward``,
+# ``_call``. A tile that holds no chosen pair folds nothing in: its masked
+# scores are ``MASK_VALUE``, which the first chosen key's score wipes
+# (``alpha`` = exp(MASK_VALUE - m) = 0) and which adds exp(MASK_VALUE - m)
+# = 0 after it. Every real query has a chosen key (a set is never empty).
+
+def selected_fits(tokens: int, q_width: int, kv_width: int, head_dim: int,
+                  itemsize: int, block: int = BLOCK) -> Optional[str]:
+    """None where the selected-set kernels take these local shapes, else
+    why not: one 128-wide head a lane tile, the one-kernel backward (no
+    split form is written for a selection), and the mean over the heads
+    with every head of a sample in one grid step."""
+    if head_dim != LANES:
+        return f"head_dim {head_dim} is not one {LANES}-lane tile"
+    why_not = blockwise_fits(q_width, kv_width, head_dim)
+    if why_not is None:
+        why_not = fused_backward_fits(tokens, q_width // kv_width, itemsize,
+                                      block, selected=True)
+    if why_not is None:
+        tile = block * block * 4
+        need = (2 * block * (q_width + kv_width) * itemsize   # q, k tiles
+                + 2 * (kv_width // LANES) * block * LANES * 4  # statistics
+                + 4 * tile + 4 * tile)          # sel, the mean; s, p, sums
+        if need > VMEM_LIMIT_BYTES:
+            why_not = (f"every head's tile of the mean needs "
+                       f"{need / 2 ** 20:.1f} MiB of VMEM, over "
+                       f"{VMEM_LIMIT_BYTES / 2 ** 20:g}")
+    return why_not
+
+
+def _chosen(sel_ref):
+    """(block, block) bool: the tile's pairs that the selection allows."""
+    return sel_ref[0] > MASK_VALUE
+
+
+def _selected_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
+                         v_ref, sel_ref, o_ref, stats_ref, m_s, l_s, acc_s,
+                         *, scale: float, group: int, block: int,
+                         window: Optional[int]):
+    p = pl.program_id(2)
+
+    @pl.when(first_ref[p] == 1)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, -jnp.inf, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    allowed = _chosen(sel_ref)
+    k, v = k_ref[0], v_ref[0]
+    for h in range(group):
+        s = jax.lax.dot_general(q_ref[0, :, _head(h)], k, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        _softmax_step(h, jnp.where(allowed, s, MASK_VALUE), v, m_s, l_s,
+                      acc_s, block)
+
+    @pl.when(last_ref[p] == 1)
+    def _():
+        _write_forward(o_ref, stats_ref, m_s, l_s, acc_s, group, block)
+
+
+def _selected_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
+                         v_ref, sel_ref, do_ref, stats_ref, delta_ref,
+                         dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s, *,
+                         scale: float, group: int, block: int,
+                         window: Optional[int]):
+    """``_causal_bwd_kernel`` with the tile's allowed pairs read from
+    ``sel``: one pass over the band, ``dk_s`` and ``dv_s`` (T, 128) a
+    key-value head's whole sequence."""
+    p = pl.program_id(2)
+
+    @pl.when(p == 0)
+    def _():
+        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    @pl.when(first_ref[p] == 1)
+    def _():
+        dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
+
+    allowed = _chosen(sel_ref)
+    k, v = k_ref[0], v_ref[0]
+    stats, delta = stats_ref[0, 0], delta_ref[0, 0]
+    keys = pl.ds(pl.multiple_of(ki_ref[p] * block, block), block)
+    for h in range(group):
+        q, do = q_ref[0, :, _head(h)], do_ref[0, :, _head(h)]
+        prob, ds = _tile_backward(q, do, k, v, allowed, stats[:, h:h + 1],
+                                  delta[:, h:h + 1], scale)
+        ds = ds.astype(k.dtype)
+        dq_s[:, _head(h)] += jnp.dot(ds, k,
+                                     preferred_element_type=jnp.float32)
+        dv_s[keys, :] += jnp.dot(prob.astype(do.dtype).T, do,
+                                 preferred_element_type=jnp.float32)
+        dk_s[keys, :] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+
+    @pl.when(last_ref[p] == 1)
+    def _():
+        dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
+
+    @pl.when(p == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _selected_mean_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
+                          stats_ref, sel_ref, o_ref, *, scale: float,
+                          group: int, block: int, window: Optional[int]):
+    """A tile of the heads' mean probability: every key-value head of a
+    sample and its ``group`` query heads in one grid step, each head's
+    probabilities from the forward's statistics, none of them written."""
+    allowed = _chosen(sel_ref)
+    heads = k_ref.shape[2] // LANES
+    total = jnp.zeros((block, block), jnp.float32)
+    for g in range(heads):
+        k, stats = k_ref[0, :, _head(g)], stats_ref[0, g]
+        for h in range(group):
+            s = jax.lax.dot_general(
+                q_ref[0, :, _head(g * group + h)], k, _NT,
+                preferred_element_type=jnp.float32) * scale
+            total += jnp.exp(jnp.where(allowed, s, MASK_VALUE)
+                             - stats[:, h:h + 1])
+    o_ref[0] = total * (1.0 / (heads * group))
+
+
+def _padded_sel(sel, block: int):
+    pad = -sel.shape[1] % block
+    return jnp.pad(sel, ((0, 0), (0, pad), (0, pad)),
+                   constant_values=MASK_VALUE) if pad else sel
+
+
+def selected_forward(q, k, v, sel, block: int = BLOCK,
+                     interpret: bool = False):
+    """:func:`selected_attention`'s two results with no derivative rule: for
+    a caller that keeps them and calls :func:`selected_backward` itself."""
+    t = q.shape[1]
+    q, k, v = (_padded(x, block) for x in (q, k, v))
+    group = q.shape[2] // k.shape[2]
+    rows = pltpu.VMEM((group, block, LANES), jnp.float32)
+    out, stats = _call(
+        _selected_fwd_kernel,
+        [(q, "q"), (k, "kv"), (v, "kv"), (_padded_sel(sel, block), "sel")],
+        [(q, "q"), (_stats_like(q, group), "stats")],
+        [rows, rows, pltpu.VMEM((block, group * LANES), jnp.float32)],
+        t=q.shape[1], group=group, block=block, window=None,
+        key_major=False, interpret=interpret)
+    return out[:, :t], stats
+
+
+def selected_backward(q, k, v, sel, out, stats, dout, block: int = BLOCK,
+                      interpret: bool = False):
+    """Cotangents of (q, k, v) from :func:`selected_forward`'s results and
+    the output's cotangent: one kernel a tile."""
+    t, group = q.shape[1], q.shape[2] // k.shape[2]
+    delta = _delta(dout, out, group, block)
+    q, k, v, dout = (_padded(x, block) for x in (q, k, v, dout))
+    acc = pltpu.VMEM((q.shape[1], LANES), jnp.float32)
+    dq, dk, dv = _call(
+        _selected_bwd_kernel,
+        [(q, "q"), (k, "kv"), (v, "kv"), (_padded_sel(sel, block), "sel"),
+         (dout, "q"), (stats, "stats"), (delta, "stats")],
+        [(q, "q"), (k, "kv_all"), (v, "kv_all")],
+        [pltpu.VMEM((block, group * LANES), jnp.float32), acc, acc],
+        t=q.shape[1], group=group, block=block, window=None,
+        key_major=False, interpret=interpret)
+    return dq[:, :t], dk[:, :t], dv[:, :t]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def selected_attention(q, k, v, sel, block: int = BLOCK,
+                       interpret: bool = False):
+    """softmax(q k^T / sqrt(128), over the keys of the query's set) v, query
+    head ``h`` reading key-value head ``h // (H / G)``, and the row
+    statistics ``L = m + log(sum exp(s - m))`` that
+    :func:`selected_mean_probs` reads.
+
+    q: (B, T, H*128); k, v: (B, T, G*128); sel: (B, T, T) f32, a score where
+    the key is in the query's set and ``MASK_VALUE`` where it is not (no
+    gradient passes to it). Returns ((B, T, H*128), (B, G, T', 128) f32),
+    T' = T padded to whole blocks."""
+    return selected_forward(q, k, v, sel, block, interpret)
+
+
+def _selected_vjp_fwd(q, k, v, sel, block, interpret):
+    out, stats = selected_forward(q, k, v, sel, block, interpret)
+    out = checkpoint_name(out, "attn_out")
+    stats = checkpoint_name(stats, "attn_stats")
+    return (out, stats), (q, k, v, sel, out, stats)
+
+
+def _selected_vjp_bwd(block, interpret, res, cotangents):
+    q, k, v, sel, out, stats = res
+    dout, _ = cotangents                 # the statistics carry no gradient
+    # the caller hands ``sel`` in with its gradient stopped: its noughts
+    # are read by nothing
+    return (*selected_backward(q, k, v, sel, out, stats, dout, block,
+                               interpret), jnp.zeros_like(sel))
+
+
+selected_attention.defvjp(_selected_vjp_fwd, _selected_vjp_bwd)
+
+
+def selected_mean_probs(q, k, stats, sel, block: int = BLOCK,
+                        interpret: bool = False):
+    """(B, T, T) f32: the mean over the H query heads of each head's
+    attention probabilities, from :func:`selected_attention`'s statistics;
+    noughts where the key is not in the query's set, and *unwritten* in the
+    tiles above the causal band (mask by ``sel``). No gradient: the caller
+    stops it."""
+    t = q.shape[1]
+    group = q.shape[2] // k.shape[2]
+    q, k, sel = _padded(q, block), _padded(k, block), _padded_sel(sel, block)
+    heads = k.shape[2] // LANES
+    spec = lambda shape, at: pl.BlockSpec(
+        shape, lambda i, j, p, qi, ki, fi, la: at(i, qi[p], ki[p]))
+    (mean,) = _call(
+        _selected_mean_kernel,
+        [(q, "q_every"), (k, "kv_every"), (stats, "stats_every"),
+         (sel, "sel")],
+        [(jax.ShapeDtypeStruct(sel.shape, jnp.float32), "sel")], [],
+        t=q.shape[1], group=group, block=block, window=None,
+        key_major=False, interpret=interpret, steps=1,
+        more_specs={
+            "q_every": spec((1, block, q.shape[2]),
+                            lambda i, qb, kb: (i, qb, 0)),
+            "kv_every": spec((1, block, k.shape[2]),
+                             lambda i, qb, kb: (i, kb, 0)),
+            "stats_every": spec((1, heads, block, LANES),
+                                lambda i, qb, kb: (i, 0, qb, 0))})
+    return mean[:, :t, :t]

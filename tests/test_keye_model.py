@@ -1,0 +1,494 @@
+"""``KeyeLMConfig`` (preset ``keyevl2``) through models/sparse_lm.py at a
+tiny size, seeded random weights, f32, ``index_topk`` under the sequence's
+length so that the selection bites and three unequal position rows: loss
+and every gradient leaf against the plain reference of its yardstick under
+both lowerings; the selected sets are ``lax.top_k`` of the reference's
+scores, ties to the lower key; neither loss's gradient reaches the other's
+leaves; equal rows are the one-row rotary bit for bit; the shares add up to
+the uncut layer; the Mosaic kernels interpreted against the dense-mask
+lowering, an empty tile among the cases; ``models/decode.py`` refuses the
+kind; the preset trains through the peer's normal path."""
+import dataclasses
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.manifest import Manifest
+from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
+from dalle_tpu.config import (AfmoeLMConfig, JoyAILMConfig, KeyeLMConfig,
+                              SparseLMConfig, keyevl2_model_config)
+from dalle_tpu.models import attention, decode, family, sparse_lm
+from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
+from dalle_tpu.ops.pallas import indexer_kernels
+
+Y = Manifest().yardstick("keye")
+
+# two layers; a sequence of 56 text tokens and a 4 x 4 image field (72: no
+# other test file's), so that the three position rows differ; 20 keys a
+# query of up to 72; half of the router's experts held. The widths are the
+# kernels' (interpreted): 128-wide heads, 64-wide indexer heads, lane tiles
+TINY = dict(hidden_size=128, num_hidden_layers=2, num_heads=4,
+            num_kv_heads=2, expert_width=128, num_experts=8,
+            experts_per_token=2, experts_held=4, expert_offset=2,
+            vocab_size=96, text_seq_len=56, image_grid=4, vocab_text=48,
+            vocab_image=48, dtype="float32", head_chunk=16, index_topk=20,
+            index_heads=2, index_chunk=32)
+TINY_FLAGS = [str(x) for key, value in TINY.items()
+              for x in ("--" + key.replace("_", "-"), value)]
+
+
+def as_file(cfg):
+    return json.loads(json.dumps(dataclasses.asdict(cfg)))
+
+
+def _batch(cfg, seed=0, n=2):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.integers(2, cfg.vocab_text,
+                                     (n, cfg.text_seq_len)), jnp.int32),
+            jnp.asarray(rng.integers(0, cfg.vocab_image,
+                                     (n, cfg.image_seq_len)), jnp.int32))
+
+
+def rel_l2(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b),
+                                                      1e-30))
+
+
+def _params(cfg, seed=1):
+    """Seeded weights with every vector leaf (norm scales, the indexer's
+    key norm's bias) moved off its initial ones and zeros."""
+    params = sparse_lm.init_params(sparse_lm.build(cfg),
+                                   jax.random.PRNGKey(seed))
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 100), len(leaves))
+    return jax.tree.unflatten(tree, [
+        a + 0.1 * jax.random.normal(k, a.shape) if a.ndim == 1 else a
+        for a, k in zip(leaves, keys)])
+
+
+def _leaves(tree):
+    return {jax.tree_util.keystr(k): v for k, v in
+            jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+@pytest.mark.parametrize("with_kernels", [False, True])
+def test_loss_and_every_gradient_leaf_against_the_yardstick(
+        with_kernels, monkeypatch):
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", with_kernels)
+    cfg = KeyeLMConfig(**TINY)
+    cfg.validate()
+    params, (text, image) = _params(cfg), _batch(cfg)
+    model = sparse_lm.build(cfg)
+    with jax.default_matmul_precision("highest"):
+        (loss, aux), grads = jax.jit(jax.value_and_grad(
+            lambda p: model.apply(p, text, image), has_aux=True))(params)
+        want, want_grads = Y.loss_and_grads(params, text, image,
+                                            as_file(cfg))
+        _, (main, align) = Y.loss_fn(params, text, image, as_file(cfg))
+    assert float(loss) == pytest.approx(float(want), rel=2e-6)
+    assert float(aux["loss_main"]) == pytest.approx(float(main), rel=2e-6)
+    assert float(aux["loss_indexer"]) == pytest.approx(float(align),
+                                                       rel=2e-5)
+    assert float(align) > 0.01                  # the second loss is there
+    assert float(aux["loss"]) == pytest.approx(
+        float(aux["loss_main"]) + cfg.indexer_loss_weight
+        * float(aux["loss_indexer"]), rel=1e-6)
+    t, k = cfg.total_seq_len, cfg.index_topk
+    pairs = sum(min(i + 1, k) for i in range(t))
+    assert float(aux["sparse_selected_pct"]) == pytest.approx(
+        100.0 * pairs / (t * (t + 1) / 2), rel=1e-6)
+    ours, theirs = _leaves(grads), _leaves(want_grads)
+    assert ours.keys() == theirs.keys()
+    assert sum("['indexer']" in name for name in ours) == 5 * 2
+    for name in ours:
+        assert rel_l2(ours[name], theirs[name]) < 2e-5, name
+        assert float(jnp.abs(theirs[name]).max()) > 0, name
+
+
+def test_the_selected_sets_are_top_k_of_the_references_scores():
+    """The program's selection on its own dense scores against the
+    yardstick's sets, layer by layer, rows with fewer than ``index_topk``
+    keys before them included (they choose every one)."""
+    cfg = KeyeLMConfig(**TINY)
+    params, (text, image) = _params(cfg), _batch(cfg)
+    model = sparse_lm.build(cfg)
+    with jax.default_matmul_precision("highest"):
+        theirs = np.asarray(Y.chosen_keys(params, text, image, as_file(cfg)))
+        _, kept = model.apply(
+            params, text, image, mutable=["intermediates"],
+            capture_intermediates=lambda m, _: isinstance(
+                m, sparse_lm.Indexer))
+        scale = (cfg.index_heads * cfg.index_head_dim) ** -0.5
+        for i in range(cfg.num_hidden_layers):
+            qi, ki, w = kept["intermediates"][f"layer_{i}"]["attn"][
+                "indexer"]["__call__"][0]
+            sel = sparse_lm.select_keys(
+                sparse_lm.dense_index_scores(qi, ki, w, scale),
+                cfg.index_topk, cfg.index_chunk)
+            ours = np.asarray(sel > sparse_lm.OFF)
+            assert (ours == theirs[i]).all(), i
+            count = ours.sum(-1)
+            assert (count == np.minimum(np.arange(cfg.total_seq_len) + 1,
+                                        cfg.index_topk)).all()
+            assert not np.triu(ours[0], 1).any()         # never a later key
+
+
+@pytest.mark.parametrize("topk, chunk", [(5, 8), (16, 16), (7, 64), (40, 8)])
+def test_select_keys_is_lax_top_k_ties_to_the_lower_key(topk, chunk):
+    """Scores drawn from few values, so that ties straddle most rows'
+    thresholds, and a -0.0 beside a 0.0: the chosen keys are those of
+    ``lax.top_k`` over the keys up to the query (stable: the lower index
+    first), and the array holds their scores and ``OFF`` elsewhere."""
+    t = 40
+    rng = np.random.default_rng(topk)
+    scores = rng.integers(-2, 3, (2, t, t)).astype(np.float32) * 0.5
+    scores[0, :, 3] = -0.0
+    sel = np.asarray(sparse_lm.select_keys(jnp.asarray(scores), topk, chunk))
+    for b in range(2):
+        for q in range(t):
+            k = min(topk, q + 1)
+            _, idx = jax.lax.top_k(jnp.asarray(scores[b, q, :q + 1]), k)
+            want = np.zeros(t, bool)
+            want[np.asarray(idx)] = True
+            assert ((sel[b, q] > sparse_lm.OFF) == want).all(), (b, q)
+            assert (sel[b, q][want] == scores[b, q][want]).all()
+            assert (sel[b, q][~want] == sparse_lm.OFF).all()
+
+
+@pytest.mark.parametrize("with_kernels", [False, True])
+def test_neither_losss_gradient_reaches_the_others_leaves(with_kernels,
+                                                          monkeypatch):
+    """``d loss_main / d indexer`` and ``d loss_indexer / d (every other
+    leaf)`` are nought exactly: the indexer reads its input with the
+    gradient stopped and chooses, which no gradient passes; its loss's
+    target is the heads' mean with the gradient stopped."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", with_kernels)
+    cfg = KeyeLMConfig(**TINY)
+    params, (text, image) = _params(cfg), _batch(cfg)
+    model = sparse_lm.build(cfg)
+    of = lambda name: jax.jit(jax.grad(
+        lambda p: model.apply(p, text, image)[1][name]))(params)
+    main, align = _leaves(of("loss_main")), _leaves(of("loss_indexer"))
+    for name in main:
+        if "['indexer']" in name:
+            assert not np.asarray(main[name]).any(), name
+            assert np.asarray(align[name]).any(), name
+        else:
+            assert not np.asarray(align[name]).any(), name
+            assert np.asarray(main[name]).any(), name
+
+
+def test_equal_rows_are_the_one_row_rotary_bit_for_bit():
+    """Three equal position rows give ``rotary_cos_sin``'s tables and
+    ``head_pass``'s one-row result, to the last bit; unequal rows do not,
+    and each frequency pair reads the row its section names."""
+    t, d, theta, sections = 24, 128, 1e7, (16, 24, 24)
+    rows = jnp.broadcast_to(jnp.arange(t), (3, t))
+    for heads in (1, 3):
+        got = sparse_lm.position_tables(rows, sections, d, theta, heads)
+        want = attention.rotary_cos_sin(jnp.arange(t), d, theta, heads)
+        for a, b in zip(got, want):
+            assert (np.asarray(a) == np.asarray(b)).all()
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, t, 2 * d))
+    scale = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(1), (d,))
+    how = dict(mesh=None, eps=1e-6, head_dim=d, theta=theta)
+    one = sparse_lm.head_pass(x, scale, **how)
+    same = sparse_lm.head_pass(x, scale, positions=rows, sections=sections,
+                               **how)
+    assert (np.asarray(one) == np.asarray(same)).all()
+    apart = sparse_lm.field_positions(16, 8, 4)
+    assert apart.shape == (3, t)
+    assert apart[:, :16].tolist() == [list(range(16))] * 3
+    assert apart[0, 16:].tolist() == [16] * 8
+    assert apart[1, 16:].tolist() == [16] * 4 + [17] * 4
+    assert apart[2, 16:].tolist() == [16, 17, 18, 19] * 2
+    other = sparse_lm.head_pass(x, scale, positions=apart,
+                                sections=sections, **how)
+    assert not (np.asarray(one)[:, 16:] == np.asarray(other)[:, 16:]).all()
+    assert (np.asarray(one)[:, :16] == np.asarray(other)[:, :16]).all()
+    # pair i of 64 reads row 0 for i < 16, row 1 to 40, row 2 after
+    cos, _ = sparse_lm.position_tables(apart, sections, d, theta)
+    freqs = 1.0 / theta ** (np.arange(64) / 64)
+    for pair, row in ((0, 0), (15, 0), (16, 1), (39, 1), (40, 2), (63, 2)):
+        want = np.cos(np.asarray(apart[row], np.float32) * np.float32(
+            freqs[pair]))
+        np.testing.assert_allclose(cos[:, pair], want, atol=1e-6)
+        np.testing.assert_allclose(cos[:, 64 + pair], want, atol=1e-6)
+
+
+def test_the_sixteen_shares_add_up_to_the_uncut_layer(monkeypatch):
+    """The preset's deployment at a small width: 128 experts, top 8, over
+    16 shares of 8 consecutive experts. Every share's layer returns its
+    routed part and nothing else: the sixteen summed as they come equal the
+    reference's uncut layer, and every assignment is computed by one; two
+    of the shares through the grouped kernels (interpreted) are the dense
+    lowering's."""
+    base = KeyeLMConfig(**dict(TINY, num_experts=128, experts_per_token=8,
+                               experts_held=8, expert_offset=0))
+    n, held = base.num_experts, base.experts_held
+    assert n // held == 16
+    rng = jax.random.split(jax.random.PRNGKey(3), 5)
+    d, f = base.hidden_size, base.expert_width
+    m = jax.random.normal(rng[0], (2, 36, d))
+    whole = {"router": jax.random.normal(rng[1], (d, n)),
+             "experts": {"gate": jax.random.normal(rng[2], (n, d, f)) * 0.2,
+                         "up": jax.random.normal(rng[3], (n, d, f)) * 0.2,
+                         "down": jax.random.normal(rng[4], (n, f, d)) * 0.2}}
+
+    def share(i):
+        cfg = dataclasses.replace(base, expert_offset=held * i)
+        layer = sparse_lm.ExpertLayer(cfg)
+        mine = {"params": dict(whole, experts={
+            k: w[held * i: held * (i + 1)]
+            for k, w in whole["experts"].items()})}
+        idx, p = layer.apply(mine, m, method="route")   # alike on all
+        y, counters = layer.apply(mine, m, idx, p)
+        return y, float(counters["here"]), p
+
+    with jax.default_matmul_precision("highest"):
+        want = Y.whole_layer_experts(m, whole, as_file(base))
+        parts = [share(i) for i in range(16)]
+        total = sum(y for y, _, _ in parts)
+        np.testing.assert_allclose(total, want, atol=1e-4)
+        # every assignment, by one share
+        assert sum(here for _, here, _ in parts) == pytest.approx(1.0)
+        np.testing.assert_allclose(jnp.sum(parts[0][2], -1), 1.0, atol=1e-6)
+        monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+        for i in (0, 9):
+            np.testing.assert_allclose(share(i)[0], parts[i][0], atol=1e-4)
+
+
+# -- the kernels, interpreted, against the dense-mask lowering ---------------
+
+def _operands(t, heads, kv_heads, index_heads, seed):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    normal = lambda k, width: jax.random.normal(k, (2, t, width))
+    return (normal(keys[0], heads * 128), normal(keys[1], kv_heads * 128),
+            normal(keys[2], kv_heads * 128), normal(keys[3],
+                                                    index_heads * 64),
+            normal(keys[4], 64), normal(keys[5], index_heads))
+
+
+def _dense_attention(q, k, v, sel):
+    b, t, _ = q.shape
+    g = k.shape[2] // 128
+    s = jnp.einsum("bqgnd,bkgd->bgnqk", q.reshape(b, t, g, -1, 128),
+                   k.reshape(b, t, g, 128)) * 128 ** -0.5
+    prob = jax.nn.softmax(jnp.where((sel > sparse_lm.OFF)[:, None, None], s,
+                                    -1e30), -1)
+    out = jnp.einsum("bgnqk,bkgd->bqgnd", prob, v.reshape(b, t, g, 128))
+    return out.reshape(q.shape), jnp.mean(prob, axis=(1, 2))
+
+
+def _an_empty_tile(sel):
+    """Queries 128.. choose among the keys 128.. only, themselves at least
+    (a set is never empty): the tile (query block 1, key block 0) holds no
+    chosen pair."""
+    sel = np.array(sel)
+    sel[:, 128:, :128] = sparse_lm.OFF
+    late = np.arange(128, sel.shape[1])
+    sel[:, late, late] = 0.25
+    return jnp.asarray(sel)
+
+
+@pytest.mark.parametrize("t, heads, kv_heads, topk, empty", [
+    (256, 4, 2, 40, False), (256, 2, 2, 40, True), (200, 4, 1, 64, False)])
+def test_the_selected_kernels_are_the_dense_mask_lowering(
+        t, heads, kv_heads, topk, empty):
+    """Forward, statistics' use (the heads' mean) and backward of the three
+    attention kernels at blocks of 128, T a whole number of blocks and not,
+    a tile with no chosen key among the cases."""
+    q, k, v, qi, ki, w = _operands(t, heads, kv_heads, 2, seed=t + topk)
+    with jax.default_matmul_precision("highest"):
+        sel = sparse_lm.select_keys(
+            sparse_lm.dense_index_scores(qi, ki, w, 0.1), topk, 64)
+        if empty:
+            sel = _an_empty_tile(sel)
+            assert not (np.asarray(sel)[:, 128:, :128]
+                        > sparse_lm.OFF).any()
+        want, want_mean = _dense_attention(q, k, v, sel)
+        (out, stats), back = jax.vjp(
+            lambda *a: kernels.selected_attention(*a, sel, 128, True),
+            q, k, v)
+        np.testing.assert_allclose(out, want, atol=2e-5)
+        mean = kernels.selected_mean_probs(q, k, stats, sel, 128, True)
+        on = np.asarray(sel > sparse_lm.OFF)
+        np.testing.assert_allclose(np.where(on, mean, 0),
+                                   np.where(on, want_mean, 0), atol=2e-6)
+        dout = jax.random.normal(jax.random.PRNGKey(9), out.shape)
+        got = back((dout, jnp.zeros_like(stats)))
+        _, dense_back = jax.vjp(
+            lambda *a: _dense_attention(*a, sel)[0], q, k, v)
+        for ours, theirs in zip(got, dense_back(dout)):
+            assert rel_l2(ours, theirs) < 2e-5
+
+
+@pytest.mark.parametrize("t, index_heads", [(256, 2), (200, 4), (384, 16)])
+def test_the_indexers_kernels_are_the_dense_scores_and_their_gradient(
+        t, index_heads):
+    """``index_scores`` on the causal band's tiles, and ``index_grads``
+    against plain differentiation of the KL through the dense scores."""
+    q, k, _, qi, ki, w = _operands(t, 2, 1, index_heads, seed=t)
+    scale = (index_heads * 64) ** -0.5
+    with jax.default_matmul_precision("highest"):
+        dense = sparse_lm.dense_index_scores(qi, ki, w, scale)
+        scores = indexer_kernels.index_scores(qi, ki, w, scale, 128, True)
+        band = np.tril(np.ones((t, t), bool))
+        np.testing.assert_allclose(
+            np.where(band, scores[:, :t, :t], 0), np.where(band, dense, 0),
+            atol=2e-5)
+        sel = sparse_lm.select_keys(dense, 48, 64)
+        on = sel > sparse_lm.OFF
+        pbar = jax.nn.softmax(jnp.where(on, jax.random.normal(
+            jax.random.PRNGKey(5), sel.shape), -1e30), -1)
+        coef = jax.random.uniform(jax.random.PRNGKey(6), (2, t))
+
+        def loss(qi, ki, w):
+            log_sigma = jax.nn.log_softmax(jnp.where(
+                on, sparse_lm.dense_index_scores(qi, ki, w, scale),
+                sparse_lm.OFF), -1)
+            return jnp.sum(coef * jnp.sum(
+                jnp.where(on, -pbar * log_sigma, 0.0), -1))
+        want = jax.grad(loss, argnums=(0, 1, 2))(qi, ki, w)
+        _, lse, _ = sparse_lm._align_rows(sel, pbar, 64)
+        got = indexer_kernels.index_grads(qi, ki, w, lse, coef, sel, pbar,
+                                          scale, 128, True)
+    for ours, theirs in zip(got, want):
+        assert ours.shape == theirs.shape
+        assert rel_l2(ours, theirs) < 2e-5
+
+
+def test_the_predicates_say_why_not():
+    assert kernels.selected_fits(8192, 4096, 512, 128, 2) is None
+    assert "head_dim 64" in kernels.selected_fits(8192, 2048, 512, 64, 2)
+    assert "MiB of VMEM" in kernels.selected_fits(65536, 4096, 512, 128, 2)
+    assert indexer_kernels.fits(8192, 16, 64, 2) is None
+    assert "two a lane tile" in indexer_kernels.fits(8192, 16, 128, 2)
+    assert "pairs" in indexer_kernels.fits(8192, 3, 64, 2)
+    # the one-kernel backward with the selection's tile: 2 MiB more
+    assert kernels.fused_backward_fits(8192, 8, 2) is None
+    assert kernels.fused_backward_fits(8192, 8, 2, selected=True) is None
+
+
+# -- the configuration, the entry points -------------------------------------
+
+def test_the_preset_is_a_class_of_its_own_and_the_parents_keep_theirs():
+    fields = lambda cls: {f.name for f in dataclasses.fields(cls)}
+    added = fields(KeyeLMConfig) - fields(AfmoeLMConfig)
+    assert added == {"index_topk", "index_heads", "index_head_dim",
+                     "index_chunk", "indexer_rotary", "indexer_loss_weight",
+                     "mrope_section"}
+    for parent in (SparseLMConfig(), AfmoeLMConfig(), JoyAILMConfig()):
+        assert not set(dataclasses.asdict(parent)) & added
+        assert not any(getattr(parent, name) for name in added)   # off
+    cfg = keyevl2_model_config()
+    assert type(cfg) is KeyeLMConfig and isinstance(cfg, AfmoeLMConfig)
+    cfg.validate()
+    assert (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+            cfg.expert_width, cfg.num_experts, cfg.experts_per_token) == (
+                2048, 32, 4, 128, 768, 128, 8)
+    assert (cfg.index_topk, cfg.index_heads, cfg.index_head_dim,
+            cfg.index_chunk, cfg.mrope_section, cfg.rope_theta) == (
+                2048, 16, 64, 512, (16, 24, 24), 1e7)
+    assert (cfg.num_hidden_layers, cfg.experts_held, cfg.vocab_size) == (
+        7, 8, 18992)
+    assert {cfg.kind_of_layer(i) for i in range(7)} == {"selected_rope"}
+    assert not any(cfg.layer_is_dense(i) for i in range(7))
+    assert (cfg.score_func, cfg.hidden_act, cfg.qk_norm) == (
+        "softmax", "silu", True)
+    assert not (cfg.attention_gate or cfg.sandwich_norms or cfg.mup_enabled
+                or cfg.num_shared_experts or cfg.kv_lora_rank
+                or cfg.tied_embeddings or cfg.selection_bias)
+    flags = {a.dest for a in run_trainer.build_parser()._actions}
+    assert "index_topk" in flags and "mrope_section" not in flags
+    # the kind needs a class that states an indexer
+    with pytest.raises(ValueError, match="selected_rope"):
+        SparseLMConfig(layer_kinds=("selected_rope",)).validate()
+    with pytest.raises(ValueError, match="mrope_section"):
+        dataclasses.replace(cfg, mrope_section=(16, 24, 20)).validate()
+    with pytest.raises(ValueError, match="rotates the indexer"):
+        dataclasses.replace(cfg, indexer_rotary=False).validate()
+    # what init_params counts: PERF.md section 4
+    shapes = jax.eval_shape(lambda: sparse_lm.init_params(
+        sparse_lm.build(cfg), jax.random.PRNGKey(0)))
+    assert sum(a.size for a in jax.tree.leaves(shapes)) == 491_848_320
+    layer = shapes["params"]["layer_0"]
+    count = lambda tree: sum(a.size for a in jax.tree.leaves(tree))
+    assert count(layer["attn"]["indexer"]) == 2_260_992 + 128
+    assert count(layer["ff"]["experts"]) == 37_748_736
+
+
+def test_decode_refuses_the_kind_by_name():
+    with pytest.raises(NotImplementedError) as refused:
+        decode.init_cache(keyevl2_model_config(), batch=1)
+    message = str(refused.value)
+    assert "'selected_rope'" in message and "indexer" in message
+    assert message.count(".") == 1 and "\n" not in message  # decode.py only
+    decode.refuse_selected_layers(SparseLMConfig())         # no such layer
+
+
+@pytest.mark.parametrize("cli, argv", [
+    (run_inference, ["--checkpoint-dir", "x", "--tokenizer-path", "y",
+                     "--query", "a cat"]),
+    (run_server, ["--random-init"]),
+    (run_aux_peer, []),
+])
+def test_entry_points_that_decode_refuse_the_preset_at_start(cli, argv):
+    with pytest.raises(SystemExit) as refused:
+        cli.main(["--preset", "keyevl2", *argv])
+    message = str(refused.value)
+    assert "keyevl2" in message and "models/decode.py" in message
+    assert "indexer" in message
+    assert message.count(".") <= 3 and "\n" not in message   # one sentence
+
+
+def test_the_preset_trains_through_the_peers_normal_path(lowering_record):
+    """``run_trainer --preset keyevl2`` (+ tiny field flags): the parser
+    builds the preset's own class, TrainingTask the model its configuration
+    names, and train_loop runs it with the swarm optimizer; the rows of the
+    trainer's ring carry the two losses, the selected share and the
+    model's records, ``sparse_layout`` among them."""
+    from dalle_tpu.obs.trace import default_tracer
+    from dalle_tpu.task import TrainingTask
+    from dalle_tpu.training.loop import train_loop
+
+    args = run_trainer.build_parser().parse_args(
+        ["--preset", "keyevl2", *TINY_FLAGS,
+         "--per-device-batch", "1", "--grad-accum-steps", "2",
+         "--target-batch-size", str(1 << 30), "--seed", "7"])
+    configs = run_trainer.configs_from_args(args)
+    assert configs[0] == KeyeLMConfig(**TINY)
+    task = TrainingTask(*configs)
+    assert family(task.model_cfg) is sparse_lm
+    losses = []
+    with task:
+        train_loop(task, max_steps=3, warmup_steps=1,
+                   on_step=lambda n, loss: losses.append(loss))
+        names = [jax.tree_util.keystr(path) for path, _ in
+                 jax.tree_util.tree_flatten_with_path(
+                     task.collab_optimizer.state.params)[0]]
+    assert len(losses) == 3 and all(np.isfinite(losses))
+    assert sum("['indexer']" in name for name in names) == 5 * 2
+    rows = [r for r in default_tracer().dump() if r.get("plane") == "train"]
+    warm = [r for r in rows if r["phase"] == "setup/warmup"][-1]["a"]
+    assert warm["attn_layout"].startswith(
+        "over 20 keys a query, chosen by an indexer of 2 heads of 64 over "
+        "one key head, dense XLA lowering (no Mosaic backend)")
+    assert "three rows by sections [16, 24, 24]" in warm["attn_layout"]
+    assert warm["sparse_layout"] == (
+        "dense masks in XLA code (no Mosaic backend)")
+    assert "softmax over the chosen" in warm["moe_layout"]
+    steps = [r for r in rows if r["phase"] == "loop/step"][-3:]
+    t, k = 72, 20
+    share = 100.0 * sum(min(i + 1, k) for i in range(t)) / (t * (t + 1) / 2)
+    for row in (r["a"] for r in steps):
+        assert row["loss_indexer"] > 0 and row["loss_main"] > 0
+        assert row["sparse_selected_pct"] == pytest.approx(share, rel=1e-5)
+        assert row["moe_dropped"] == 0.0
+    assert sparse_lm.step_attributes(task.model_cfg)[-3:] == (
+        "loss_main", "loss_indexer", "sparse_selected_pct")
+    assert sparse_lm.step_attributes(SparseLMConfig())[-1] == \
+        "moe_tiles_active_pct"
