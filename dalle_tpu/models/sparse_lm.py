@@ -119,10 +119,13 @@ token's the text's length, + its row, + its column; nothing else knows the
 rule). **How a layer runs it** (:func:`selected_attend`; the ``setup/warmup``
 row's ``sparse_layout`` says it): a kernel writes the scores of the causal
 band's tiles, one (T, T) f32 array a sequence
-(ops/pallas/indexer_kernels.py); a query's threshold is found by counting,
-four bits a pass, in ``index_chunk``-row chunks of XLA code
-(:func:`select_keys`: no sort; XLA's ``top_k`` of 2 048 among 8 192 takes
-8 times as long on the v5e); the selection is ONE (T, T) f32 array ``sel``
+(ops/pallas/indexer_kernels.py); a query's threshold is found by counting, a
+bit a pass, in a second kernel there that keeps a block of queries' scores
+in VMEM over its passes (``index_select``: no sort; :func:`select_keys`,
+the same sets by four bits a pass in ``index_chunk``-row chunks of XLA
+code, is the dense lowering's selection and 6 times as slow on the v5e,
+XLA's ``top_k`` of 2 048 among 8 192 50 times); the selection is ONE (T, T)
+f32 array ``sel``
 that holds a chosen pair's score and the kernels' mask value elsewhere, which
 the blockwise kernels read a tile of beside ``q``, ``k`` and ``v``
 (``causal_attention_kernels.selected_*``: the band's every tile is
@@ -135,8 +138,8 @@ cotangent tile by tile. All of it is one derivative rule
 backward pass between two barriers, so that one layer's are alive at a
 time. No (T, T) array exists per head. Scopes ``attn/indexer/proj``,
 ``attn/indexer/scores`` (``scores[mosaic]``, forward and gradient),
-``attn/indexer/select``, ``attn/indexer/align`` (``align[mosaic]`` and the
-row sums), ``attn/qk_norm``, ``attn[mosaic]``.
+``attn/indexer/select`` (``select[mosaic]``), ``attn/indexer/align``
+(``align[mosaic]`` and the row sums), ``attn/qk_norm``, ``attn[mosaic]``.
 
 **The expert layer is told which experts it holds** (``experts_held``
 consecutive ones from ``expert_offset``): it routes over all
@@ -633,16 +636,17 @@ def _align_rows(sel: jax.Array, pbar: jax.Array, chunk: int):
                  for parts in zip(*out))
 
 
-def _chosen_keys(qi, ki, w, topk: int, chunk: int, scale: float):
+def _chosen_keys(qi, ki, w, topk: int, scale: float):
     """``sel`` (B, T, T) f32 of the indexer's three operands: the scores'
-    kernel and the selection."""
+    kernel and the selection's (:func:`select_keys`' array, bit for bit)."""
     with jax.named_scope("indexer"):
         with jax.named_scope("scores"):
             scores = index_kernels.index_scores(
                 qi, ki, w, scale, kernels.BLOCK, lowering.interpret())
         with jax.named_scope("select"):
             t = qi.shape[1]
-            return select_keys(scores[:, :t, :t], topk, chunk)
+            return index_kernels.index_select(
+                scores, topk, kernels.BLOCK, lowering.interpret())[:, :t, :t]
 
 
 def _mean_and_rows(q, k, stats, sel, chunk: int):
@@ -658,7 +662,7 @@ def _mean_and_rows(q, k, stats, sel, chunk: int):
 def _selected_kernels(q, k, v, qi, ki, w, topk: int, chunk: int,
                       scale: float):
     """Attention over the chosen keys and the indexer's loss, on Mosaic but
-    for the selection and the loss's row sums: returns the context, each
+    for the loss's row sums: returns the context, each
     sample's KL summed over its rows, and its chosen pairs.
 
     One derivative rule for all of it, so that what the backward pass needs
@@ -674,7 +678,7 @@ def _selected_kernels(q, k, v, qi, ki, w, topk: int, chunk: int,
 
 
 def _selected_kernels_fwd(q, k, v, qi, ki, w, topk, chunk, scale):
-    sel = _chosen_keys(qi, ki, w, topk, chunk, scale)
+    sel = _chosen_keys(qi, ki, w, topk, scale)
     ctx, stats = kernels.selected_forward(q, k, v, sel, kernels.BLOCK,
                                           lowering.interpret())
     ctx = checkpoint_name(ctx, "attn_out")
@@ -692,7 +696,7 @@ def _selected_kernels_bwd(topk, chunk, scale, res, cotangents):
     # nothing below starts before the context's cotangent is there
     dctx, res = jax.lax.optimization_barrier((dctx, res))
     q, k, v, qi, ki, w, ctx, stats = res
-    sel = _chosen_keys(qi, ki, w, topk, chunk, scale)
+    sel = _chosen_keys(qi, ki, w, topk, scale)
     dq, dk, dv = kernels.selected_backward(
         q, k, v, sel, ctx, stats, dctx, kernels.BLOCK, lowering.interpret())
     pbar, (_, lse, _) = _mean_and_rows(q, k, stats, sel, chunk)
@@ -798,15 +802,16 @@ def sparse_words(chunk: int) -> str:
     words (the ``setup/warmup`` row's ``sparse_layout``)."""
     return (
         f"scores (T, T) f32 a sequence by a kernel over the causal band's "
-        f"tiles of {kernels.BLOCK}; a query's threshold by counting, "
-        f"{SEARCH_BITS} bits a pass, in {chunk}-row chunks of XLA code (no "
-        "sort), ties to the lower key; the kernels are handed one (T, T) "
+        f"tiles of {kernels.BLOCK}; a query's threshold by counting, a bit "
+        f"a pass over the bit planes of {index_kernels.SELECT_ROWS} queries' "
+        "scores held in VMEM, one kernel (no sort), ties to the lower key; "
+        "the kernels are handed one (T, T) "
         "f32 array that holds a chosen pair's score and the mask value "
         "elsewhere, and visit every tile of the causal band (none is "
         "skipped: the walk does not depend on the data); the heads' mean "
         "probability (T, T) f32 by a kernel from the forward's statistics; "
-        "the loss's row sums XLA code, its gradient to the indexer one "
-        "kernel with no (T, T) cotangent")
+        f"the loss's row sums {chunk}-row chunks of XLA code, its gradient "
+        "to the indexer one kernel with no (T, T) cotangent")
 
 
 class Indexer(nn.Module):

@@ -6,8 +6,10 @@ both lowerings; the selected sets are ``lax.top_k`` of the reference's
 scores, ties to the lower key; neither loss's gradient reaches the other's
 leaves; equal rows are the one-row rotary bit for bit; the shares add up to
 the uncut layer; the Mosaic kernels interpreted against the dense-mask
-lowering, an empty tile among the cases; ``models/decode.py`` refuses the
-kind; the preset trains through the peer's normal path."""
+lowering, an empty tile among the cases; the selection's kernel against
+``select_keys`` and ``lax.top_k``, and the lowered step selects by it alone;
+``models/decode.py`` refuses the kind; the preset trains through the peer's
+normal path."""
 import dataclasses
 import json
 
@@ -361,6 +363,171 @@ def test_the_indexers_kernels_are_the_dense_scores_and_their_gradient(
         assert rel_l2(ours, theirs) < 2e-5
 
 
+# -- the selection's kernel, interpreted --------------------------------------
+
+BIG = float(np.finfo(np.float32).max)
+
+
+def _few(seed, b, t):
+    """Scores of five values, so that ties straddle most rows' thresholds."""
+    return np.random.default_rng(seed).integers(-2, 3, (b, t, t)).astype(
+        np.float32) * 0.5
+
+
+def _normal(seed, b, t):
+    return np.random.default_rng(seed).standard_normal((b, t, t)).astype(
+        np.float32)
+
+
+def _zeros(seed, b, t):
+    """Runs of exact zeros under a row's threshold, a -0.0 beside a 0.0."""
+    x = np.maximum(_normal(seed, b, t), 0.0)
+    x[:, :, 3::7] = -0.0
+    return x
+
+
+def _extremes(seed, b, t):
+    """Negative scores all, the largest and the least finite f32 among
+    them, twice each (a tie at either end)."""
+    x = -np.abs(_normal(seed, b, t)) - 1.0
+    x[:, :, 5] = x[:, :, 90] = BIG
+    x[:, :, 6] = x[:, :, 70] = -BIG
+    return x
+
+
+def _tied_rows(seed, b, t):
+    """Distinct scores but in the last row block and in the first that
+    searches (rows 100-130 at 100 keys a query), where a row's threshold is
+    tied across more keys than the row may take."""
+    x = _normal(seed, b, t)
+    for rows in (slice(100, 131), slice(t - 20, t)):
+        x[:, rows] = np.round(x[:, rows])
+    return x
+
+
+def _top_k_sets(x, topk):
+    """(B, T, T) bool: ``lax.top_k``'s sets over the keys up to the query."""
+    b, t, _ = x.shape
+    causal = np.tril(np.ones((t, t), bool))
+    _, idx = jax.lax.top_k(jnp.where(causal, x, -jnp.inf), min(topk, t))
+    keep = np.arange(idx.shape[-1])[None, :] < np.minimum(
+        np.arange(t) + 1, topk)[:, None]
+    on = np.zeros((b, t, t), bool)
+    for i in range(b):
+        rows = np.broadcast_to(np.arange(t)[:, None], keep.shape)[keep]
+        on[i, rows, np.asarray(idx[i])[keep]] = True
+    return on
+
+
+@pytest.mark.parametrize("scores, b, t, topk, block", [
+    (_normal, 1, 256, 200, 128),      # rows before index_topk: a block, and
+                                      # a block that holds some
+    (_normal, 2, 200, 64, 128),       # T no whole number of row blocks; B 2
+    (_few, 1, 200, 64, 128),          # ... and ties everywhere
+    (_tied_rows, 1, 384, 100, 128),   # ties in the first searching block
+                                      # and in the last
+    (_zeros, 1, 256, 40, 128),
+    (_extremes, 1, 256, 40, 128),
+    (_few, 2, 256, 300, 128),         # index_topk >= T: no search
+    (_normal, 1, 700, 130, 512),      # T no whole number of BLOCK; row
+                                      # blocks of 128 in a block of 512
+    (_few, 1, 130, 7, 128),           # few keys a query, most rows tied
+    (_normal, 1, 4200, 4150, 512),    # keys past one group of bit planes
+])
+def test_the_selection_kernel_is_select_keys_and_lax_top_k(scores, b, t,
+                                                           topk, block):
+    """``index_select`` on an array whose tiles above the causal band hold
+    NaN (``index_scores`` leaves them unwritten): ``select_keys``' array bit
+    for bit, the sets those of ``lax.top_k`` over the keys up to the query
+    (ties to the lower key), the score on a set and ``OFF`` off it."""
+    x = scores(t + topk, b, t)
+    pad = -t % block
+    tile = np.arange(t + pad) // block
+    padded = np.where(tile[None, :] > tile[:, None], np.nan,
+                      np.pad(x, ((0, 0), (0, pad), (0, pad))))
+    sel = np.asarray(indexer_kernels.index_select(
+        jnp.asarray(padded, jnp.float32), topk, block, True))
+    assert sel.shape == padded.shape
+    assert (sel[:, :, t:] == sparse_lm.OFF).all()    # every column written
+    sel = sel[:, :t, :t]
+    want = np.asarray(sparse_lm.select_keys(jnp.asarray(x), topk, 64))
+    assert np.array_equal(sel, want)
+    on = _top_k_sets(jnp.asarray(x), topk)
+    # (the least finite f32 lies under OFF: a set is told by equality)
+    assert np.array_equal(sel[on].view(np.int32), x[on].view(np.int32))
+    assert (sel[~on] == sparse_lm.OFF).all()
+    assert (on.sum(-1) == np.minimum(np.arange(t) + 1, topk)).all()
+
+
+def test_the_bit_planes_are_the_words_transposed():
+    words = np.random.default_rng(0).integers(
+        -2 ** 31, 2 ** 31, (32, 8, 128)).astype(np.int32)
+    planes = np.asarray(indexer_kernels._planes(jnp.asarray(words)))
+    for bit in range(32):
+        for j in (0, 1, 13, 30, 31):
+            assert np.array_equal((planes[31 - bit] >> (31 - j)) & 1,
+                                  (words[j] >> bit) & 1)
+
+
+def test_the_derivative_rule_with_the_selection_kernel_is_the_dense_lowering(
+        monkeypatch):
+    """``_selected_kernels`` (scores, selection, attention, the heads' mean,
+    the loss's gradient: every kernel interpreted) against
+    ``dense_selected_attention`` differentiated plainly: the three results
+    and all six cotangents."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    operands = _operands(200, 4, 2, 2, seed=53)
+    topk, chunk, scale = 48, 64, 0.1
+    with jax.default_matmul_precision("highest"):
+        ours, back = jax.vjp(lambda *a: sparse_lm._selected_kernels(
+            *a, topk, chunk, scale), *operands)
+        theirs, dense_back = jax.vjp(
+            lambda *a: sparse_lm.dense_selected_attention(
+                *a, topk=topk, chunk=chunk, scale=scale, head_dim=128),
+            *operands)
+        np.testing.assert_allclose(ours[0], theirs[0], atol=2e-5)
+        np.testing.assert_allclose(ours[1], theirs[1], rtol=2e-5)
+        assert np.array_equal(ours[2], theirs[2])            # chosen pairs
+        keys = jax.random.split(jax.random.PRNGKey(8), 2)
+        cotangents = (jax.random.normal(keys[0], ours[0].shape),
+                      jax.random.uniform(keys[1], ours[1].shape),
+                      jnp.zeros_like(ours[2]))
+        for name, mine, plain in zip("q k v qi ki w".split(),
+                                     back(cotangents),
+                                     dense_back(cotangents)):
+            assert rel_l2(mine, plain) < 2e-5, name
+
+
+def test_the_lowered_step_selects_by_the_kernel_alone(monkeypatch):
+    """A small grad step lowered for a TPU with the Mosaic lowering:
+    ``_index_select_kernel`` wherever the scores are made (a layer's
+    forward, its replay, its backward rule), and nothing under the scope
+    ``attn/indexer/select`` but the kernel and the cut to T: no loop of
+    ``select_keys``."""
+    import re
+
+    from benchmark.harness import kernel_census
+    from dalle_tpu.training.steps import make_grad_step
+
+    cfg = KeyeLMConfig(**TINY)
+    model = sparse_lm.build(cfg)
+    shapes = jax.eval_shape(
+        lambda: sparse_lm.init_params(model, jax.random.PRNGKey(0)))
+    tokens = lambda n: jax.ShapeDtypeStruct((2, n), jnp.int32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = jax.jit(make_grad_step(model, accum_steps=2)).trace(
+        shapes, {"text": tokens(cfg.text_seq_len),
+                 "image": tokens(cfg.image_seq_len)}).lower(
+                     lowering_platforms=("tpu",)).as_text(debug_info=True)
+    found = kernel_census(text)
+    assert found["_index_select_kernel"] == found["_index_scores_kernel"] \
+        == 3 * cfg.num_hidden_layers
+    assert found["_index_grads_kernel"] == cfg.num_hidden_layers
+    under = set(re.findall(r'attn/indexer/select/([^"/]*)', text))
+    assert under == {"pallas_call", "slice"}
+    assert re.search(r'attn/indexer/align/[^"]*_align_rows', text)
+
+
 def test_the_predicates_say_why_not():
     assert kernels.selected_fits(8192, 4096, 512, 128, 2) is None
     assert "head_dim 64" in kernels.selected_fits(8192, 2048, 512, 64, 2)
@@ -368,6 +535,11 @@ def test_the_predicates_say_why_not():
     assert indexer_kernels.fits(8192, 16, 64, 2) is None
     assert "two a lane tile" in indexer_kernels.fits(8192, 16, 128, 2)
     assert "pairs" in indexer_kernels.fits(8192, 3, 64, 2)
+    # the selection's row block: its scores, its selection, their bit planes
+    assert indexer_kernels.fits(24576, 16, 64, 2) is None
+    refused = indexer_kernels.fits(28672, 16, 64, 2)
+    assert "a row block of 128 queries' scores over 28672 keys" in refused
+    assert "MiB of VMEM" in refused and refused.count(".") == 1
     # the one-kernel backward with the selection's tile: 2 MiB more
     assert kernels.fused_backward_fits(8192, 8, 2) is None
     assert kernels.fused_backward_fits(8192, 8, 2, selected=True) is None
