@@ -330,17 +330,15 @@ def run_smoke(out_dir: Path, *, preset: str = "flagship",
                  if str(r.msg).startswith("warmup %d/%d")]
         print(f"warmup grad steps (first compiles) {steps} s; "
               f"whole run {run_s:.1f} s")
-        # the trainer's own compile counter (dalle_tpu/obs/compiles.py),
-        # counting since run_trainer built its task
-        from dalle_tpu.obs import compiles
-        counted = compiles.installed().snapshot()
-        for name, row in sorted(counted["by_program"].items(),
-                                key=lambda kv: -kv[1]["compile_s"]):
-            if row["compile_s"] >= 1.0:
-                print(f"compile {name}: {row['compile_s']:.1f} s")
-        print("persistent compile cache hits: "
-              f"{counted['total']['cache_hits']}; compiles after the first "
-              f"step: {[c[0] for c in counted['after_first_step']]}")
+        # the trainer's own account of its set-up, made when its first
+        # step closed (dalle_tpu/obs/compiles.py), and what its compile
+        # counter saw after that
+        from dalle_tpu.obs import compiles, default_tracer
+        for row in default_tracer().dump():
+            if row["phase"] == compiles.ACCOUNT_EVENT:
+                print(compiles.account_line(row["a"]))
+        print("compiles after the first step: "
+              f"{[c[0] for c in compiles.installed().after_first_step]}")
         peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
                  for d in devices]
         print(f"peak_bytes_in_use per device: {peaks}")

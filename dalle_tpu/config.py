@@ -502,8 +502,12 @@ class CollabConfig:
     # round paths stay byte-identical to the uninstrumented protocol.
     trace_file: Optional[str] = None
     # Byte cap on the in-memory flight ring behind the tracer (the
-    # last-N-rounds dump a failure artifact wants).
-    trace_ring_kb: int = 256
+    # last-N-rounds dump a failure artifact wants). A trainer's ring also
+    # keeps its set-up: the spans, a compile event a program (800 on a
+    # host of four with an eager init) and a row a traced call of a Mosaic
+    # call site are read after the run (the benchmark's per-layer set-up
+    # metrics), 225 KB of rows on four chips, so the cap is twice that.
+    trace_ring_kb: int = 512
 
 
 @dataclass(frozen=True)

@@ -636,15 +636,24 @@ def _align_rows(sel: jax.Array, pbar: jax.Array, chunk: int):
                  for parts in zip(*out))
 
 
+# the indexer's three kernels inside the selected attention's rules: no
+# choice of their own (``selected_attend`` made it), a row of the record
+# each for what their tracing costs, at every call of a rule
+SCORES_SITE, SELECTION_SITE, ALIGNMENT_SITE = (
+    "indexer scores", "indexer selection", "indexer alignment")
+
+
 def _chosen_keys(qi, ki, w, topk: int, scale: float):
     """``sel`` (B, T, T) f32 of the indexer's three operands: the scores'
     kernel and the selection's (:func:`select_keys`' array, bit for bit)."""
+    t = qi.shape[1]
     with jax.named_scope("indexer"):
-        with jax.named_scope("scores"):
+        with jax.named_scope("scores"), lowering.traced(
+                SCORES_SITE, (t, qi.shape[2], ki.shape[2])):
             scores = index_kernels.index_scores(
                 qi, ki, w, scale, kernels.BLOCK, lowering.interpret())
-        with jax.named_scope("select"):
-            t = qi.shape[1]
+        with jax.named_scope("select"), lowering.traced(
+                SELECTION_SITE, (t, topk)):
             return index_kernels.index_select(
                 scores, topk, kernels.BLOCK, lowering.interpret())[:, :t, :t]
 
@@ -652,7 +661,9 @@ def _chosen_keys(qi, ki, w, topk: int, scale: float):
 def _mean_and_rows(q, k, stats, sel, chunk: int):
     """The heads' mean probability and the loss's row sums
     (:func:`_align_rows`) under the scope ``indexer/align``."""
-    with jax.named_scope("indexer"), jax.named_scope("align"):
+    with jax.named_scope("indexer"), jax.named_scope("align"), \
+            lowering.traced(ALIGNMENT_SITE,
+                            (q.shape[1], q.shape[2], k.shape[2], chunk)):
         pbar = kernels.selected_mean_probs(q, k, stats, sel, kernels.BLOCK,
                                            lowering.interpret())
         return pbar, _align_rows(sel, pbar, chunk)
@@ -1385,18 +1396,18 @@ def _sum_to_tokens(rows_of, plan: _Plan, weight=None, dtype=jnp.float32):
     why_not = runs_why_not(n, k, held, rows_of.shape[1], rows_of.dtype)
     # a cost rule and no gate: the sum is traced only under the grouped
     # products, which have one. Remembered, not said
-    lowering.record(SUM_SITE,
-                    _sum_key(k, held, rows_of.shape[1], rows_of.dtype),
-                    why_not, tile=token_sum.tokens_tile(n))
-    if why_not is not None:
-        return _sum_over_slots(rows_of, plan, weight).astype(dtype)
-    if weight is not None:
-        weight = jnp.sum(jnp.where(
-            plan.key[:, :, None] == jnp.arange(held), weight[:, :, None],
-            0.0), axis=1)
-    return token_sum.token_major_sum(
-        rows_of, plan.row_of, plan.start, plan.written, weight,
-        out_dtype=dtype, interpret=lowering.interpret())
+    key = _sum_key(k, held, rows_of.shape[1], rows_of.dtype)
+    lowering.record(SUM_SITE, key, why_not, tile=token_sum.tokens_tile(n))
+    with lowering.traced(SUM_SITE, key):
+        if why_not is not None:
+            return _sum_over_slots(rows_of, plan, weight).astype(dtype)
+        if weight is not None:
+            weight = jnp.sum(jnp.where(
+                plan.key[:, :, None] == jnp.arange(held),
+                weight[:, :, None], 0.0), axis=1)
+        return token_sum.token_major_sum(
+            rows_of, plan.row_of, plan.start, plan.written, weight,
+            out_dtype=dtype, interpret=lowering.interpret())
 
 
 def _rows_of(source, plan: _Plan):
@@ -1415,13 +1426,14 @@ def _to_rows(source, plan: _Plan, what: str, **facts):
     (n, held), rows = plan.row_of.shape, plan.token.shape[0]
     why_not = rows_why_not(n, rows, held, source.shape[1], source.dtype)
     # a cost rule and no gate, as the sums'
-    lowering.record(ROWS_SITE,
-                    _rows_key(held, source.shape[1], source.dtype, what),
-                    why_not, **facts)
-    if why_not is not None:
-        return _rows_of(source, plan)
-    return token_sum.rows_of(source, plan.row_of, plan.start, plan.written,
-                             rows=rows, interpret=lowering.interpret())
+    key = _rows_key(held, source.shape[1], source.dtype, what)
+    lowering.record(ROWS_SITE, key, why_not, **facts)
+    with lowering.traced(ROWS_SITE, key):
+        if why_not is not None:
+            return _rows_of(source, plan)
+        return token_sum.rows_of(
+            source, plan.row_of, plan.start, plan.written, rows=rows,
+            interpret=lowering.interpret())
 
 
 def grouped_kernels_why_not(dim: int, width: int) -> Optional[str]:

@@ -278,12 +278,14 @@ def _geglu_shard(x, wi, wg, wo, bi, bg, bo, tp_index, *, tp: int):
     ok = geglu_supported(b * t, d, inner, x.dtype)
     words = (f"geglu_supported({b * t} local rows, {d}, {inner}, {x.dtype}) "
              f"is {ok}")
-    if lowering.chose("GEGLU feed-forward", (b * t, d, inner, x.dtype.name),
-                      None if ok else words, words):
-        out = geglu_ff(x.reshape(b * t, d), wi, wg, wo, bi, bg, bo,
-                       256, 512, lowering.interpret()).reshape(b, t, d)
-    else:
-        out = _geglu_xla(x, wi, wg, wo, bi, bg, bo)
+    site, key = "GEGLU feed-forward", (b * t, d, inner, x.dtype.name)
+    took = lowering.chose(site, key, None if ok else words, words)
+    with lowering.traced(site, key):
+        if took:
+            out = geglu_ff(x.reshape(b * t, d), wi, wg, wo, bi, bg, bo,
+                           256, 512, lowering.interpret()).reshape(b, t, d)
+        else:
+            out = _geglu_xla(x, wi, wg, wo, bi, bg, bo)
     return jax.lax.psum(out, "tp") if tp > 1 else out
 
 

@@ -506,8 +506,7 @@ class CollaborativeOptimizer:
         in_round = ({"round": self._round_trace(self._pending.epoch)}
                     if tracer is not None and self._pending is not None
                     else {})
-        with obs_span(tracer, "train", "collab/accumulate",
-                      samples=int(batch_size), **in_round):
+        with obs_span(tracer, "train", "collab/accumulate", **in_round):
             if self._grad_acc is None:
                 # placed like the gradients: the accumulate then sees the
                 # operands of every later call and compiles once (the
@@ -542,7 +541,7 @@ class CollaborativeOptimizer:
 
         decision = self._CONTINUE
         min_epoch = 0
-        with obs_span(tracer, "train", "collab/decide") as deciding:
+        with obs_span(tracer, "train", "collab/decide"):
             if self.role.swarm_enabled:
                 progress = self.tracker.global_progress()
                 if progress.epoch > self.local_epoch:
@@ -557,7 +556,6 @@ class CollaborativeOptimizer:
                 elif progress.ready_to_update:
                     decision = self._GLOBAL_STEP
             decision = broadcast_decision(decision)
-            deciding.set(decision=decision)
 
         if decision == self._RESYNC:
             if self.role.swarm_enabled:

@@ -267,6 +267,10 @@ def train_loop(task: TrainingTask,
                             on_epoch(report)
                     loss_sum, mini_steps = 0.0, 0
                 memory.close_step(step_row)
+            if local_steps == 1:
+                # set-up ends where the first step closes: where its
+                # seconds went, once (obs/compiles.py)
+                task.compiles.account_setup()
         # an overlapped round (delay_optimizer_step) may still be in
         # flight when the loop exits: apply it rather than lose the
         # epoch's averaging (shutdown() would discard it) — EXCEPT when

@@ -12,8 +12,11 @@ outputs are byte-equal run the same program::
         --preset smallthinker21b --micro 2 --accum 4 --out <file>
 
 What a PR that edits ``models/sparse_lm.py`` shows for the presets it
-must not move (PERF.md section 6, PR 33). It holds libtpu's lock while it
-runs: one at a time.
+must not move (PERF.md section 6, PR 33). After the byte count it prints
+what the trace cost by Mosaic call site (calls, keys, seconds: the compile
+counter's ``by_site``), so a PR that adds sites sees what they cost tracing
+before it spends a chip-minute. It holds libtpu's lock while it runs: one
+at a time.
 """
 
 from __future__ import annotations
@@ -80,6 +83,21 @@ def lowered_text(preset: str, micro: int, accum: int) -> str:
     return BODY.sub(without_locations, text)
 
 
+def site_account(snap) -> str:
+    """What this one trace cost, on this box's CPU (proportions and
+    counts, not the chip host's speeds): the step's tracing and lowering,
+    and every Mosaic call site's calls, keys and seconds."""
+    step = snap["by_program"].get("grad_step", {})
+    lines = [f"grad_step traced in {step.get('trace_s', 0.0):.1f} s, "
+             f"lowered in {step.get('lower_s', 0.0):.1f} s; by call site:",
+             f"  {'site':28s} calls keys  trace_s again_s"]
+    for site, at in sorted(snap["by_site"].items(),
+                           key=lambda kv: -kv[1]["trace_s"]):
+        lines.append(f"  {site:28s} {at['calls']:5d} {at['keys']:4d} "
+                     f"{at['trace_s']:8.2f} {at['again_s']:7.2f}")
+    return "\n".join(lines)
+
+
 def main(argv=None) -> None:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     parser.add_argument("--preset", default="smallthinker21b")
@@ -87,11 +105,14 @@ def main(argv=None) -> None:
     parser.add_argument("--accum", type=int, default=4)
     parser.add_argument("--out", required=True)
     args = parser.parse_args(argv)
+    from dalle_tpu.obs import compiles
+    counter = compiles.install(None)     # no task: the counter alone
     text = lowered_text(args.preset, args.micro, args.accum)
     with open(args.out, "w") as f:
         f.write(text)
     print(len(text), "bytes, sha256",
           hashlib.sha256(text.encode()).hexdigest())
+    print(site_account(counter.snapshot()))
 
 
 if __name__ == "__main__":

@@ -161,3 +161,198 @@ def test_the_form_of_a_site(interpret, monkeypatch, caplog, lowering_record):
     assert lowering_record.recorded("halving", (4, 4)) == {"why_not": None, "tile": 8}
     assert lowering_record.why_not("halving", (4, 3)) == "odd lanes"
     assert lowering_record.why_not("halving", (4, 8)) == "none traced"
+
+
+# -- what a site's tracing costs (PR 54) -------------------------------------
+
+@pytest.fixture
+def counter():
+    """The process's compile counter recording into a tracer of the test's:
+    the sites' brackets time themselves where there is one."""
+    from dalle_tpu.obs import compiles
+    from dalle_tpu.obs.trace import Tracer
+    tracer = Tracer(peer="sites")
+    yield compiles.install(tracer)
+    compiles.install(None)
+
+
+def _halving(record, kernel=lambda x: x * 0.5):
+    def choose(x):
+        return record.chose("halving", x.shape[1:], None, f"local {x.shape}")
+    return record.site("halving", choose, kernel, lambda x: x / 2.0)
+
+
+def test_a_traced_call_times_itself_in_the_record_and_the_ring(
+        monkeypatch, lowering_record, counter):
+    """Every traced call of a site is one bracket: ``traced_n``, ``trace_s``
+    and ``again_s`` in the record's row, and one span of plane ``train``,
+    ``trace/site`` the first time a ``(site, key)`` is traced and
+    ``trace/site_again`` after that, the child of the span that was open.
+    A jitted caller's second call is served from the cache: no bracket."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    tracer = counter.tracer
+    wide, narrow = jnp.ones((2, 4, 8)), jnp.ones((2, 4, 6))
+    with tracer.span("train", "setup/warmup", "setup"):
+        for x in (wide, wide, narrow):
+            _halving(lowering_record)(x)
+    step = jax.jit(_halving(lowering_record))
+    step(wide), step(wide)                   # one trace, outside any span
+    rows = [r for r in tracer.dump() if r["phase"].startswith("trace/")]
+    assert [(r["phase"], r["a"]["nth"], r.get("parent")) for r in rows] == [
+        ("trace/site", 1, "setup/warmup"),
+        ("trace/site_again", 2, "setup/warmup"),
+        ("trace/site", 1, "setup/warmup"),
+        ("trace/site_again", 3, None)]
+    assert {r["a"]["site"] for r in rows} == {"halving"}
+    digest = lowering_record.key_digest
+    assert [r["a"]["key"] for r in rows] == [
+        digest((4, 8)), digest((4, 8)), digest((4, 6)), digest((4, 8))]
+    assert len(digest((4, 8))) == 8 and digest((4, 8)) != digest((4, 6))
+    assert all(r["plane"] == "train" and r["dur_s"] > 0 for r in rows)
+    timed = lowering_record.timing("halving", (4, 8))
+    assert timed["traced_n"] == 3
+    assert timed["trace_s"] == pytest.approx(
+        sum(r["dur_s"] for r in rows if r["a"]["key"] == digest((4, 8))),
+        rel=0.2)
+    assert 0 < timed["again_s"] < timed["trace_s"]
+    assert lowering_record.timing("halving", (4, 6)) == {
+        "traced_n": 1, "trace_s": pytest.approx(rows[2]["dur_s"], rel=0.2),
+        "again_s": 0.0}
+    # the facts are the site's own, as before: a choice said again keeps
+    # what the row's tracing cost
+    assert lowering_record.recorded("halving", (4, 8)) == {"why_not": None}
+    by_site = counter.snapshot()["by_site"]
+    assert by_site == lowering_record.by_site()
+    assert by_site["halving"] == {
+        "calls": 4, "keys": 2, "again_n": 2,
+        "trace_s": pytest.approx(sum(r["dur_s"] for r in rows), rel=0.2),
+        "again_s": pytest.approx(timed["again_s"])}
+
+
+def test_sites_nest_and_the_inner_rows_parent_says_so(
+        monkeypatch, lowering_record, counter):
+    """A site traced inside another site's call is a row of its own, whose
+    ``parent`` is the outer's phase: a reader takes the union."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+
+    def outer_kernel(x):
+        with lowering_record.traced("inner", x.shape):
+            return x * 0.5
+    _halving(lowering_record, outer_kernel)(jnp.ones((2, 4, 8)))
+    inner, outer = [r for r in counter.tracer.dump()
+                    if r["phase"].startswith("trace/")]
+    assert (inner["a"]["site"], inner["parent"]) == ("inner", "trace/site")
+    assert outer["a"]["site"] == "halving" and "parent" not in outer
+    assert outer["t0"] <= inner["t0"]
+    assert inner["t0"] + inner["dur_s"] <= outer["t0"] + outer["dur_s"] + 2e-6
+    # a site with no choice of its own gets a row that took its kernel
+    assert lowering_record.recorded("inner", (2, 4, 8)) == {"why_not": None}
+
+
+def test_with_no_counter_a_site_runs_as_it_did(monkeypatch, lowering_record):
+    """The tools that lower for a described chip from the sandbox have no
+    task, no tracer and no counter: no clock is read, nothing is kept."""
+    from dalle_tpu.obs import compiles
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    monkeypatch.setattr(compiles, "_installed", None)
+    np.testing.assert_array_equal(
+        _halving(lowering_record)(jnp.ones((2, 4, 8))), 0.5)
+    assert lowering_record._RECORD == {("halving", (4, 8)): {"why_not": None}}
+    assert lowering_record.timing("halving", (4, 8)) == {
+        "traced_n": 0, "trace_s": 0, "again_s": 0}
+    assert lowering_record.by_site() == {}
+    # and a counter with no tracer (scripts/lowered_step.py) keeps the
+    # record's half alone
+    counter = compiles.CompileCounter(None)
+    monkeypatch.setattr(compiles, "_installed", counter)
+    _halving(lowering_record)(jnp.ones((2, 4, 8)))
+    assert lowering_record.timing("halving", (4, 8))["traced_n"] == 1
+    assert counter.snapshot()["by_site"]["halving"]["calls"] == 1
+
+
+def _lowered_step(preset, lowering_record, monkeypatch, **overrides):
+    """A preset's grad step lowered for a TPU without one, as
+    ``tests/benchmark_tests/test_benchmark_census.py`` lowers the cells':
+    (kernel names -> count, the sites' account of that one trace)."""
+    from benchmark.harness import kernel_census
+    from dalle_tpu.cli.run_trainer import MODEL_PRESETS
+    from dalle_tpu.models import family
+    from dalle_tpu.parallel.mesh import make_mesh
+    from dalle_tpu.training.steps import make_grad_step
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = MODEL_PRESETS[preset](**overrides)
+    fam = family(cfg)
+    model = fam.build(cfg, make_mesh(devices=jax.devices()[:1]))
+    params = jax.eval_shape(
+        lambda: fam.init_params(model, jax.random.PRNGKey(0)))
+    lowering_record._RECORD.clear()      # the step's calls alone
+    batch = {"text": jax.ShapeDtypeStruct((2, cfg.text_seq_len), jnp.int32),
+             "image": jax.ShapeDtypeStruct((2, cfg.image_seq_len),
+                                           jnp.int32)}
+    text = jax.jit(make_grad_step(model, accum_steps=1)).trace(
+        params, batch).lower(lowering_platforms=("tpu",)).as_text()
+    return kernel_census(text), lowering_record.by_site()
+
+
+def _every_row_was_timed(record, but=()):
+    for (site, key), row in record._RECORD.items():
+        if (site, key) not in but:
+            assert record.timing(site, key)["traced_n"] >= 1, (site, key)
+            assert row["trace_s"] >= row["again_s"] >= 0
+
+
+def test_every_site_of_a_tiny_sparse_step_counts_its_traced_calls(
+        monkeypatch, lowering_record, counter):
+    """After one traced step every ``(site, key)`` the record knows was
+    timed. An attention site's calls are its forward kernels in the
+    lowered step, one a layer (its output is saved: no replay). Elsewhere
+    the two counts differ, and say how: a rematerialised layer's replay
+    lowers a site's forward kernel again without calling the site's
+    Python, and a jitted entry (the token-major sum, the rows) is called
+    at every site and lowered once a shape."""
+    from dalle_tpu.models import sparse_lm
+    census, sites = _lowered_step(
+        "smallthinker21b", lowering_record, monkeypatch, hidden_size=256,
+        num_hidden_layers=2, layer_kinds=("full_nope", "window_rope"),
+        num_heads=2, num_kv_heads=1, head_dim=128, expert_width=128,
+        num_experts=8, experts_per_token=2, experts_held=4, expert_offset=2,
+        vocab_size=512, window=512, text_seq_len=256, image_grid=16,
+        vocab_text=256, vocab_image=256)
+    # the expert block's form is a fact of the products' call, written
+    # under a key of its own with no call of its own
+    _every_row_was_timed(lowering_record, but={
+        (sparse_lm.PRODUCTS_SITE, sparse_lm._block_key(256, 128,
+                                                       "bfloat16"))})
+    attention_calls = sum(at["calls"] for site, at in sites.items()
+                          if site.endswith(" attention"))
+    assert attention_calls == census["_causal_fwd_kernel"] == 2
+    assert sites["rotary"]["calls"] == census["_head_norm_bwd_kernel"] == 2
+    assert census["_head_norm_fwd_kernel"] == 4        # each replayed
+    assert sites[sparse_lm.PRODUCTS_SITE]["calls"] == 2
+    assert census["_gated_hidden_kernel"] == 4         # each replayed
+    for site, kernel in ((sparse_lm.SUM_SITE, "_token_sum_kernel"),
+                         (sparse_lm.ROWS_SITE, "_rows_of_kernel")):
+        assert sites[site]["calls"] > census[kernel] >= 1
+        assert sites[site]["again_n"] == sites[site]["calls"] \
+            - sites[site]["keys"]
+    assert all(at["trace_s"] >= at["again_s"] for at in sites.values())
+
+
+def test_every_site_of_a_small_dalle_step_counts_its_traced_calls(
+        monkeypatch, lowering_record, counter):
+    """The zoo's sites at the flagship's widths, six layers: every row was
+    timed, and each site's calls are its forward kernels in the lowered
+    step (attention's output and the feed-forward's are saved; LayerNorm
+    is replayed in the rematerialised blocks, where it is lowered again
+    without a call)."""
+    census, sites = _lowered_step("flagship", lowering_record, monkeypatch,
+                                  depth=6)
+    _every_row_was_timed(lowering_record)
+    attention_calls = sum(at["calls"] for site, at in sites.items()
+                          if site.endswith(" attention"))
+    assert attention_calls == census["_fwd_kernel"] \
+        + census["_win_fwd_kernel"]
+    assert sites["GEGLU feed-forward"]["calls"] == census["_ff_fwd_kernel"]
+    assert 1 <= sites["LayerNorm"]["calls"] <= census["_ln_fwd_kernel"]
+    assert sites["LayerNorm"]["keys"] == 1
+    assert sites["LayerNorm"]["again_n"] == sites["LayerNorm"]["calls"] - 1

@@ -1530,6 +1530,128 @@ class TestLateSteps:
         assert "step:7 took" in done.stderr          # the WARNING's line
 
 
+class TestSelfSeconds:
+    """The compile counter's seconds two ways (``obs/compiles.py``): JAX's
+    own, in which a jitted function traced inside another is in the outer's
+    seconds too, and the self form, whose parts sum."""
+
+    INNER_S, OUTER_S, ASIDE_S = 0.12, 0.08, 0.35
+
+    @pytest.fixture()
+    def counted(self):
+        """``outer`` = OUTER_S of Python + two calls of jitted ``inner``
+        (INNER_S), traced once inside ``setup/train_state``; meanwhile a
+        second thread, started from inside ``outer``'s trace and waited
+        for there, traces ``aside`` (ASIDE_S)."""
+        import jax.numpy as jnp
+
+        from dalle_tpu.obs import compiles
+        tracer = Tracer(peer="self")
+        counter = compiles.install(tracer)
+        installed_at = time.perf_counter()
+
+        @jax.jit
+        def self_inner(x):
+            time.sleep(self.INNER_S)
+            return x + 1
+
+        @jax.jit
+        def self_aside(x):
+            time.sleep(self.ASIDE_S)
+            return x - 1
+
+        @jax.jit
+        def self_outer(x):
+            other = threading.Thread(
+                target=lambda: self_aside.lower(jnp.ones(3)))
+            other.start()
+            time.sleep(self.OUTER_S)
+            y = self_inner(self_inner(x))
+            other.join()
+            return y * 2
+
+        try:
+            with tracer.span("train", "setup/train_state", "setup"):
+                self_outer(jnp.ones(3)).block_until_ready()
+            first = counter.snapshot()
+            self_outer(jnp.ones(3)).block_until_ready()     # cached
+            (span_row,) = [r for r in tracer.dump()
+                           if r["phase"] == "setup/train_state"]
+            yield {"first": first, "second": counter.snapshot(),
+                   "span_s": span_row["dur_s"], "cost": counter.cost(),
+                   "since_install": time.perf_counter() - installed_at}
+        finally:
+            compiles.install(None)
+
+    def test_the_inclusive_keys_read_as_before(self, counted):
+        by = counted["first"]["by_program"]
+        inner, outer = by["self_inner"], by["self_outer"]
+        # the second call of ``inner`` inside the one trace costs nothing
+        assert inner["trace_n"] == 2
+        assert inner["trace_s"] == pytest.approx(self.INNER_S, rel=0.25)
+        assert outer["trace_n"] == 1 and outer["compile_n"] == 1
+        # ``outer``'s seconds hold ``inner``'s: their sum is over the wall
+        assert outer["trace_s"] >= inner["trace_s"] + self.OUTER_S
+        for row in by.values():
+            assert set(row) == {
+                "trace_n", "trace_s", "trace_self_s", "lower_n", "lower_s",
+                "lower_self_s", "compile_n", "compile_s", "cache_hits",
+                "cache_misses"}
+
+    def test_self_seconds_sum_to_the_wall(self, counted):
+        by = counted["first"]["by_program"]
+        inner, outer = by["self_inner"], by["self_outer"]
+        assert inner["trace_self_s"] == pytest.approx(inner["trace_s"],
+                                                      rel=0.05)
+        # the parts of the one trace sum to its wall, within 5%
+        assert inner["trace_self_s"] + outer["trace_self_s"] == \
+            pytest.approx(outer["trace_s"], rel=0.05)
+        assert outer["trace_self_s"] == pytest.approx(
+            outer["trace_s"] - inner["trace_s"], rel=0.05)
+
+    def test_another_threads_trace_is_not_taken_out(self, counted):
+        """``aside`` was traced on a second thread while ``outer``'s trace
+        waited for it: its seconds ended inside ``outer``'s interval and
+        stay in ``outer``'s self seconds."""
+        by = counted["first"]["by_program"]
+        aside, outer, inner = (by[f"self_{name}"]
+                               for name in ("aside", "outer", "inner"))
+        assert aside["trace_self_s"] == pytest.approx(self.ASIDE_S, rel=0.25)
+        # ``outer``'s trace lasted as long as the thread it waited for
+        assert outer["trace_s"] >= self.ASIDE_S
+        assert outer["trace_self_s"] >= self.ASIDE_S - 1.25 * self.INNER_S
+        # a thread has no open span of the first's: the span's tally holds
+        # the parts of the one trace, which sum to its wall, and no more
+        span = counted["first"]["by_span"]["setup/train_state"]
+        assert span["trace_self_s"] == pytest.approx(outer["trace_s"],
+                                                     rel=0.05)
+
+    def test_no_span_and_no_process_holds_more_self_seconds_than_wall(
+            self, counted):
+        from dalle_tpu.obs import compiles
+        first = counted["first"]
+        span = first["by_span"]["setup/train_state"]
+        assert span["trace_self_s"] <= counted["span_s"]
+        assert compiles.self_seconds(span) <= counted["span_s"]
+        # inclusive seconds make no such promise: the same span, summed
+        assert span["trace_s"] > span["trace_self_s"]
+        # a process's threads trace side by side: each thread's self
+        # seconds stay under the wall, here the first's
+        total = counted["second"]["total"]
+        aside = counted["second"]["by_program"]["self_aside"]
+        assert compiles.self_seconds(total) - compiles.self_seconds(aside) \
+            <= counted["since_install"]
+        # what the late-step recorder reads at a step's edge is the self form
+        compiled, seconds = counted["cost"]
+        assert compiled == total["compile_n"]
+        assert seconds == pytest.approx(compiles.self_seconds(total))
+
+    def test_a_cached_second_call_costs_nothing(self, counted):
+        first, second = counted["first"], counted["second"]
+        for name in ("self_outer", "self_inner", "self_aside"):
+            assert second["by_program"][name] == first["by_program"][name]
+
+
 @pytest.fixture(scope="module")
 def late_loop(tmp_path_factory):
     """A tiny-preset ``train_loop`` on the CPU with a trace file: its hook

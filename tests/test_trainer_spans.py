@@ -47,16 +47,23 @@ class TestTrainLoopRing:
         # another file's task of these shapes, earlier in this process,
         # would leave the accumulate compiled and step 1 nothing to count
         jax.clear_caches()
-        with _make_task(tmp_path_factory.mktemp("ring")) as task:
-            assert task.tracer is default_tracer()
-            assert task.tracer.sink_path is None        # ring only
-            assert task.collab_optimizer.tracer is task.tracer
-            train_loop(task, max_steps=3, warmup_steps=1,
-                       publish_metrics_records=False,
-                       on_step=lambda n, loss: seen.append(n))
-            assert task.tracer.ring_evictions == 0
-            assert task.compiles is compiles.installed()
-            yield task.tracer.dump(), task.compiles.snapshot()
+        # ... and the call sites it traced would be in the sites' record,
+        # which is the process's: this task's own, from an empty one
+        from dalle_tpu.ops.pallas import lowering
+        record, lowering._RECORD = lowering._RECORD, {}
+        try:
+            with _make_task(tmp_path_factory.mktemp("ring")) as task:
+                assert task.tracer is default_tracer()
+                assert task.tracer.sink_path is None        # ring only
+                assert task.collab_optimizer.tracer is task.tracer
+                train_loop(task, max_steps=3, warmup_steps=1,
+                           publish_metrics_records=False,
+                           on_step=lambda n, loss: seen.append(n))
+                assert task.tracer.ring_evictions == 0
+                assert task.compiles is compiles.installed()
+                yield task.tracer.dump(), task.compiles.snapshot()
+        finally:
+            lowering._RECORD = record
         compiles.install(None)
 
     def test_ring_only_tracer_records_the_solo_path(self, rows):
@@ -144,6 +151,127 @@ class TestTrainLoopRing:
         first_step = [r for r in events if r["trace"] == "step:1"]
         assert first_step and all(r["parent"] == "collab/accumulate"
                                   for r in first_step)
+
+
+    def test_set_up_is_accounted_once_when_the_first_step_closes(self, rows):
+        """One ``setup/account`` event, written between the first step's
+        close and the second's start: the wall from the first set-up
+        span's start, the spans' walls, what of it was JAX's machinery
+        (parts that sum, so under the wall) and what no span names."""
+        ring, _ = rows
+        (event,) = [r for r in ring if r["phase"] == compiles.ACCOUNT_EVENT]
+        assert event["dur_s"] == 0 and event["trace"] == "setup"
+        assert "parent" not in event
+        first, second = [r for r in ring if r["phase"] == "loop/step"][:2]
+        closed = first["t0"] + first["dur_s"]
+        assert closed <= event["t0"] <= second["t0"]
+        a = event["a"]
+        start = min(r["t0"] for r in ring if r["phase"].startswith("setup/")
+                    and r["dur_s"] > 0)
+        assert a["wall_s"] == pytest.approx(closed - start, abs=2e-3)
+        walls = {r["phase"]: r["dur_s"] for r in ring if r["dur_s"] > 0}
+        assert set(a["spans"]) == {"dht", "collab_optimizer", "train_state",
+                                   "warmup"}
+        for what, seconds in a["spans"].items():
+            assert seconds == pytest.approx(walls[f"setup/{what}"], abs=2e-3)
+        assert a["first_step_s"] == pytest.approx(first["dur_s"], abs=2e-3)
+        # the optimizer builds the node and the state inside its own span
+        # and the loop follows at once: the spans and the step cover it
+        assert 0 <= a["unnamed_s"] < 0.05 * a["wall_s"]
+        jit = a["trace_self_s"] + a["lower_self_s"] + a["compile_s"]
+        assert 0 < jit <= a["wall_s"]
+        assert a["grad_step_trace_s"] > 0 and a["grad_step_lower_s"] > 0
+        assert a["sites"] == []         # a CPU mesh: no site took a kernel
+        line = compiles.account_line(a)
+        assert line.startswith("set-up to the first step's close took ")
+        assert "train_state" in line and "none traced" in line
+
+    def test_a_steady_step_writes_the_rows_it_wrote_before(self, rows):
+        """What PR 54 added runs while JAX traces or once at the first
+        step's close: a step after the first writes nine rows, as on the
+        parent (its span, four children with the hook's, ``collab/step``
+        and its three parts)."""
+        ring, _ = rows
+        by_step = {n: [r["phase"] for r in ring if r["trace"] == f"step:{n}"]
+                   for n in (2, 3)}
+        assert len(by_step[2]) == len(by_step[3]) == 9, by_step
+        assert sorted(by_step[2]) == sorted(by_step[3])
+        assert not [r for r in ring if r["phase"].startswith("trace/")
+                    and r["trace"].startswith("step:")]
+
+    def test_the_benchmarks_readers_find_the_set_up_metrics(self, rows):
+        """The five per-layer metrics of PR 54 on the ring and the counter
+        of a real (tiny) run, read as ``harness.run_cell`` reads them: the
+        counter's two are numbers, each under the span or the wall it is
+        part of; no site took a kernel on the CPU mesh, so the sites' spans
+        give nothing to read and their count is 0."""
+        from benchmark.harness import RunContext
+        from benchmark.manifest import Manifest, reducer
+        ring, _ = rows
+        ctx = RunContext(values={}, traced_steps=0)
+        files = {m["name"]: m for m in Manifest().cell(
+            "flagship-train-solo").per_layer}
+        read = lambda name: reducer(files[name]["reducer"])(
+            ctx, **files[name]["params"])
+        (event,) = [r for r in ring if r["phase"] == compiles.ACCOUNT_EVENT]
+        assert 0 < read("state_init_jit_self_s") <= read("task_state_init_s")
+        assert read("state_init_jit_self_s") <= read("state_init_jit_s")
+        assert 0 < read("setup_jit_self_s") <= event["a"]["wall_s"]
+        assert read("kernel_sites_trace_s") is None
+        assert read("kernel_sites_again_trace_s") is None
+        assert read("kernel_site_again_calls") == 0.0
+
+
+def test_the_account_is_one_event_and_one_line_a_counter(caplog):
+    """On a hand-made ring: nested set-up spans count once, a harness's
+    own seconds between the task and the loop are ``unnamed_s``, the five
+    sites whose tracing took longest are named, and a second call says
+    nothing."""
+    tracer = Tracer(peer="acct")
+    counter = compiles.CompileCounter(tracer)
+    assert counter.account_setup() is None       # no step has closed
+    counter.reset(tracer)
+    tracer.add("train", "setup/dht", "setup", 10.5, 0.5,
+               parent="setup/collab_optimizer")
+    tracer.add("train", "setup/train_state", "setup", 11.0, 6.0,
+               parent="setup/collab_optimizer")
+    tracer.add("train", "setup/collab_optimizer", "setup", 10.0, 8.0)
+    tracer.add("train", "setup/warmup", "setup", 30.0, 3.0)
+    counter.on_duration("/jax/core/compile/jaxpr_trace_duration", 4.0,
+                        fun_name="grad_step")
+    counter.on_duration("/jax/core/compile/backend_compile_duration", 2.0,
+                        fun_name="jit(grad_step)")
+    tracer.add("train", "loop/step", "step:1", 33.5, 1.5)
+    by_site = {f"site {i}": {"calls": 3, "keys": 1, "trace_s": float(i),
+                             "again_n": 2, "again_s": i / 2.0}
+               for i in range(7)}
+    snapshot = counter.snapshot
+    counter.snapshot = lambda: dict(snapshot(), by_site=by_site)
+    with caplog.at_level(logging.INFO, logger="dalle_tpu.obs.compiles"):
+        account = counter.account_setup()
+        assert counter.account_setup() is None
+    assert account["wall_s"] == 25.0
+    assert account["spans"] == {"collab_optimizer": 8.0, "dht": 0.5,
+                                "train_state": 6.0, "warmup": 3.0}
+    assert account["first_step_s"] == 1.5
+    assert account["unnamed_s"] == pytest.approx(25.0 - 8.0 - 3.0 - 1.5)
+    assert account["trace_self_s"] == 4.0 and account["compile_s"] == 2.0
+    assert account["grad_step_trace_s"] == 4.0
+    assert [s[0] for s in account["sites"]] == [f"site {i}"
+                                                for i in (6, 5, 4, 3, 2)]
+    assert account["sites"][0] == ["site 6", 6.0, 3, 1, 3.0]
+    (event,) = [r for r in tracer.dump()
+                if r["phase"] == compiles.ACCOUNT_EVENT]
+    assert event["a"] == account and event["trace"] == "setup"
+    (line,) = [r.getMessage() for r in caplog.records
+               if r.name == "dalle_tpu.obs.compiles"]
+    assert line == compiles.account_line(account)
+    assert "took 25.0 s" in line and "unnamed 12.5" in line
+    assert "site 6 6.0 s in 3 calls on 1 keys (3.0 s of it over again)" \
+        in line
+    # a task after this one counts anew and is accounted again
+    counter.reset(tracer)
+    assert counter.account_setup() is not None
 
 
 def test_compile_counter_names_a_retraced_function_and_warns(caplog):
