@@ -250,7 +250,10 @@ tile's (T, 128) tables for two heads side by side;
 lane tile (``causal_attention_kernels._halves_*_kernel``: ``attn_layout``
 says "2 heads of 64 a lane tile"), while their head norm and rotary stay on
 :func:`head_pass`'s XLA lowering, for ``head_norm_kernels.fits``'s reason,
-which the record keeps. The latent kernels likewise read ``q_nope``, ``k_nope`` and
+which the record keeps. The 128-wide kernels multiply an edge tile of the
+band by its sub-tiles that hold an allowed pair (``attn_band`` on the
+``setup/warmup`` row has each layer kind's tiles and visited over allowed
+pairs, whole tiles' and the sub-tiles'). The latent kernels likewise read ``q_nope``, ``k_nope`` and
 ``v`` as column blocks of ``q_b``'s and ``kv_b``'s outputs and make their
 backward's ``delta`` themselves (``attn_operands`` on the ``setup/warmup``
 row says so, or "sliced: <why>": :class:`LatentAttention`).
@@ -411,6 +414,19 @@ def _backward_words(split_why: Optional[str]) -> str:
             else "one kernel a tile")
 
 
+def _band_words(band: Dict[str, int]) -> str:
+    """A call's ``causal_attention_kernels.band_of`` in words: the
+    tiles it visits, how the edge tiles among them are multiplied, and
+    visited over allowed pairs, whole tiles' first where sub-tiles cut it."""
+    over = lambda pairs: f"{pairs / band['allowed']:.4f}"
+    cut = band["visited"] < band["whole"]
+    return (f"{band['tiles']} tile{'s' * (band['tiles'] != 1)}, "
+            f"{band['edge_tiles']} at an edge "
+            + (f"by sub-tiles of {band['sub']}" if cut else "whole")
+            + ", visited over allowed pairs "
+            + (over(band["whole"]) + " -> ") * cut + over(band["visited"]))
+
+
 def _heads_a_tile(head_dim: int) -> str:
     """The kernels' second form, in words; nothing for any other head."""
     return (f"2 heads of {head_dim} a lane tile, "
@@ -439,13 +455,16 @@ def attend(q, k, v, *, mesh, kind: str, window: Optional[int],
         group = q.shape[2] // k.shape[2]
         split_why = None if why_not else kernels.fused_backward_fits(
             q.shape[1], group, q.dtype.itemsize)
+        band = None if why_not else kernels.band_of(q.shape[1], window,
+                                                    head_dim)
         return lowering.chose(
             name, _blockwise_key(q.shape[1], q.shape[2], k.shape[2]),
             why_not,
             why_not or f"local q{tuple(q.shape)} over k{tuple(k.shape)}: "
-            f"blocks of {kernels.BLOCK}, {group} query heads a key-value "
-            "tile, " + _heads_a_tile(head_dim) + _backward_words(split_why),
-            split_backward=split_why)
+            f"blocks of {kernels.BLOCK}, {_band_words(band)}, {group} query "
+            "heads a key-value tile, " + _heads_a_tile(head_dim)
+            + _backward_words(split_why),
+            split_backward=split_why, band=band)
 
     return lowering.site(
         name, fits,
@@ -2268,6 +2287,14 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
         + words
         + ", gated output" * cfg.attention_gate)
     said = {"attn_layout": attn_layout}
+    # the band of each kind of layer that took the kernel, as its call read
+    # it off the function the kernels cut their edge tiles by
+    bands = {kind: lowering.recorded(*call)["band"]
+             for kind, call in zip(kinds, calls) if call in took}
+    if bands:
+        said["attn_band"] = "; ".join(
+            f"{kinds.count(kind)} {kind}: {_band_words(band)}"
+            for kind, band in bands.items())
     if cfg.kv_lora_rank:
         said = _latent_records(cfg, tp)
     if cfg.index_topk:

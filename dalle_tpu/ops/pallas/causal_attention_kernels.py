@@ -18,6 +18,36 @@ The forward walks the list query-block-major and keeps a query block's
 accumulators in VMEM over its key blocks (2 MXU products a tile and head:
 ``q k^T``, ``p v``).
 
+**An edge tile is multiplied by its sub-tiles that hold an allowed pair.**
+A tile on the diagonal holds allowed pairs in its lower triangle only, one
+on a window's far side in its upper triangle only: with whole tiles of 512
+the band visits 1.11 to 1.18 times the allowed pairs of the cells' layers.
+Which tiles are such *edge* tiles, and which (``SUB_BLOCK`` x ``SUB_BLOCK``)
+sub-tiles of one hold no allowed pair, depends on ``d`` = query block - key
+block, ``block``, ``window`` and the sub-tile's size alone
+(:func:`edge_tiles`, :func:`band_pairs`' test applied to a sub-tile; a
+window that is no multiple of the block has two kinds of edge tile on its
+far side). The 128-wide kernels (``_causal_fwd_kernel``,
+``_causal_bwd_kernel``, ``_causal_dq_kernel``, ``_causal_dkv_kernel``) tell a
+tile's kind from the prefetched ``qi - ki`` (:func:`_by_kind`): an *interior*
+tile runs its products whole and is not masked (every pair of it is
+allowed); an edge tile runs them once a query sub-block, its rows against
+the key rows of the sub-tiles it keeps, which lie side by side (3 of a
+diagonal tile's 4 sub-tiles of 256: 256 x 256 and 256 x 512), masked, with
+``dk`` / ``dv`` summed at those key rows. All slices are static and on row
+boundaries. A masked pair's probability was an exact nought, so outputs and
+``dq`` are the whole tile's bit for bit, and ``dk`` / ``dv`` (whose products
+contract over the query rows, now in two parts) to f32 rounding.
+:func:`band_account` counts what this leaves (1.05 and 1.09 times the
+allowed pairs). :func:`sub_block` alone says who cuts and at what size:
+:func:`causal_attention` hands its value to the kernels it picks, and
+:func:`band_of` is the same call's account for the site's record. **The
+other bodies still multiply whole tiles and mask them**: two 64-wide heads
+a lane tile (``_halves_*_kernel``, whose ``sub`` is the tile), latent
+attention (``_latent_*_kernel``), a selection read from data
+(``_selected_*_kernel``) and the indexer's kernels (``indexer_kernels.py``),
+which share ``band_pairs`` and ``_call`` and not ``_by_kind``.
+
 **The backward computes a tile's probabilities once.** ``_causal_bwd_kernel``
 walks the same query-block-major list and makes, a tile and head, the
 probabilities and ``do v^T`` once and ``dq``, ``dk`` and ``dv`` from them:
@@ -107,7 +137,7 @@ operands' dtype (bf16 in training).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +149,8 @@ from jax.experimental.pallas import tpu as pltpu
 LANES = 128
 HALF = LANES // 2                 # a 64-wide head: two a lane tile
 BLOCK = 512
+# the sub-tile the 128-wide kernels cut an edge tile into (:func:`edge_tiles`)
+SUB_BLOCK = 256
 MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 # what every call asks of the compiler, and what the one-kernel backward's
 # accumulators have to fit in (tests shrink it to reach the split kernels)
@@ -183,14 +215,91 @@ def band_pairs(n_blocks: int, block: int, window: Optional[int],
     return np.asarray(rows, np.int32)
 
 
+def sub_block(block: int, head_dim: int = LANES) -> int:
+    """The sub-tile :func:`causal_attention` cuts an edge tile of ``block``
+    into: :data:`SUB_BLOCK` for one 128-wide head a lane tile, where it
+    divides the tile; else the tile itself (whole tiles)."""
+    return (SUB_BLOCK if head_dim == LANES and block % SUB_BLOCK == 0
+            else block)
+
+
+def edge_tiles(block: int, window: Optional[int],
+               sub: int) -> Dict[int, np.ndarray]:
+    """The band's *edge* tiles, by ``d`` = query block - key block: those
+    that hold an allowed pair and a pair that is not. ``{d: kept}``, ``kept``
+    a (block / sub, block / sub) bool array: which (query sub-block, key
+    sub-block) of the tile holds an allowed pair (:func:`band_pairs`' test,
+    applied to a sub-tile). A tile of the band whose ``d`` is not listed is
+    *interior*: every pair of it is allowed. ``d`` = 0, the diagonal, is
+    always an edge; a window adds one or two at its far side."""
+    at = np.arange(block // sub)
+    edges = {}
+    for d in range(1 if window is None else (window + block - 2) // block + 1):
+        # the least and the largest query - key of each sub-tile
+        least = d * block + (at[:, None] - at[None, :]) * sub - (sub - 1)
+        largest = least + 2 * (sub - 1)
+        kept, whole = largest >= 0, least >= 0
+        if window is not None:
+            kept, whole = kept & (least < window), whole & (largest < window)
+        if not whole.all():
+            edges[d] = kept
+    return edges
+
+
+def _strips(kept: np.ndarray, sub: int):
+    """An edge tile's work by query sub-block: ``(rows, keys)`` slices of
+    the tile, a sub-block's query rows and the key rows of the sub-tiles it
+    keeps, which lie side by side (query - key falls along a row)."""
+    for a, row in enumerate(kept):
+        cols = np.flatnonzero(row)
+        if len(cols):
+            assert cols[-1] - cols[0] + 1 == len(cols), kept
+            yield (slice(a * sub, (a + 1) * sub),
+                   slice(cols[0] * sub, (cols[-1] + 1) * sub))
+
+
+def band_account(n_blocks: int, block: int, window: Optional[int],
+                 sub: int) -> Dict[str, int]:
+    """What a call's band costs, in (query, key) pairs a head: its
+    ``tiles`` and the ``edge_tiles`` among them, the pairs ``allowed``, the
+    pairs of the whole tiles (``whole``) and the pairs ``visited`` with edge
+    tiles cut into sub-tiles of ``sub`` (``sub`` = ``block``: ``whole``)."""
+    table = band_pairs(n_blocks, block, window, key_major=False)
+    edges = edge_tiles(block, window, sub)
+    d = table[:, 0] - table[:, 1]
+    dropped = sum(int((~edges[e]).sum()) * int((d == e).sum())
+                  for e in edges) * sub * sub
+    t, whole = n_blocks * block, len(table) * block * block
+    reach = np.minimum(np.arange(t) + 1, window or t)
+    return {"tiles": len(table),
+            "edge_tiles": int(np.isin(d, list(edges)).sum()), "sub": sub,
+            "allowed": int(reach.sum()), "whole": whole,
+            "visited": whole - dropped}
+
+
+def band_of(tokens: int, window: Optional[int], head_dim: int = LANES,
+            block: int = BLOCK) -> Dict[str, int]:
+    """:func:`band_account` of the call :func:`causal_attention` makes for
+    ``tokens`` a sample: the padded length's blocks, and the sub-tile its
+    kernels are handed."""
+    return band_account(-(-tokens // block), block, window,
+                        sub_block(block, head_dim))
+
+
+def _within(first, shape, window: Optional[int]):
+    """``shape`` (rows, keys) bool: which (query row, key column) may
+    attend, ``first`` the query - key of the first row and column."""
+    rel = first \
+        + jax.lax.broadcasted_iota(jnp.int32, shape, 0) \
+        - jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    ok = rel >= 0
+    return ok if window is None else ok & (rel < window)
+
+
 def _allowed(qi, ki, block: int, window: Optional[int]):
     """(block, block) bool: which (query row, key column) of this tile may
     attend. One mask a grid step, shared by the group's heads."""
-    rel = (qi - ki) * block \
-        + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0) \
-        - jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-    ok = rel >= 0
-    return ok if window is None else ok & (rel < window)
+    return _within((qi - ki) * block, (block, block), window)
 
 
 def _head(h: int) -> slice:
@@ -200,29 +309,34 @@ def _head(h: int) -> slice:
 def _tile_backward(q, do, k, v, allowed, lse, delta, scale):
     """One head's (block, block) tile in the backward: its probabilities
     from the saved statistics, and the scores' cotangent (times ``scale``,
-    so that it is q's and k's), both f32."""
+    so that it is q's and k's), both f32. ``allowed``: None where every
+    pair is."""
     s = jax.lax.dot_general(q, k, _NT,
                             preferred_element_type=jnp.float32) * scale
-    prob = jnp.exp(jnp.where(allowed, s, MASK_VALUE) - lse)
+    if allowed is not None:
+        s = jnp.where(allowed, s, MASK_VALUE)
+    prob = jnp.exp(s - lse)
     dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
     return prob, prob * (dp - delta) * scale
 
 
-def _softmax_step(h: int, s, v, m_s, l_s, acc_s, block: int, mine=None):
+def _softmax_step(h: int, s, v, m_s, l_s, acc_s, block: int, mine=None,
+                  rows: slice = slice(None)):
     """Head ``h``'s masked (block, block) scores of one key block folded
     into its running maximum, sum and accumulator. ``mine``: with two heads
     a lane tile, (block, 128) bool, the head's half of its tile ``h // 2``
-    (``v`` is nought in the other half, whose accumulator stays)."""
-    m_prev, l_prev = m_s[h], l_s[h]                  # (block, 128)
+    (``v`` is nought in the other half, whose accumulator stays). ``rows``:
+    the query rows of the tile that ``s`` holds, over ``block`` keys."""
+    m_prev, l_prev = m_s[h, rows], l_s[h, rows]      # (rows, 128)
     m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
     e = jnp.exp(s - jnp.tile(m_next, (1, block // LANES)))
     alpha = jnp.exp(m_prev - m_next)
-    l_s[h] = alpha * l_prev + jnp.sum(e, axis=1)[:, None]
-    m_s[h] = m_next
+    l_s[h, rows] = alpha * l_prev + jnp.sum(e, axis=1)[:, None]
+    m_s[h, rows] = m_next
     lanes = _head(h)
     if mine is not None:
         lanes, alpha = _head(h // 2), jnp.where(mine, alpha, 1.0)
-    acc_s[:, lanes] = alpha * acc_s[:, lanes] + jnp.dot(
+    acc_s[rows, lanes] = alpha * acc_s[rows, lanes] + jnp.dot(
         e.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
 
@@ -239,10 +353,32 @@ def _write_forward(o_ref, stats_ref, m_s, l_s, acc_s, group: int,
     stats_ref[0, 0] = stats
 
 
+def _by_kind(d, block: int, window: Optional[int], sub: int, work) -> None:
+    """Run ``work(rows, keys, allowed)`` over the tile whose query block -
+    key block is ``d``: once over an interior tile, whole and with no mask
+    (``allowed`` None); over an edge tile once a query sub-block, its rows
+    against the key rows :func:`edge_tiles` keeps for it and their mask.
+    ``rows`` / ``keys``: static slices of the tile."""
+    edges = edge_tiles(block, window, sub)
+    whole = slice(0, block)
+
+    @pl.when(functools.reduce(jnp.logical_and, [d != e for e in edges]))
+    def _():
+        work(whole, whole, None)
+
+    for e, kept in edges.items():
+        @pl.when(d == e)
+        def _():
+            for rows, keys in _strips(kept, sub):
+                work(rows, keys, _within(
+                    e * block + rows.start - keys.start,
+                    (rows.stop - rows.start, keys.stop - keys.start), window))
+
+
 def _causal_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                        v_ref, o_ref, stats_ref, m_s, l_s, acc_s, *,
                        scale: float, group: int, block: int,
-                       window: Optional[int]):
+                       window: Optional[int], sub: int):
     p = pl.program_id(2)
 
     @pl.when(first_ref[p] == 1)
@@ -251,38 +387,55 @@ def _causal_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    allowed = _allowed(qi_ref[p], ki_ref[p], block, window)
-    k, v = k_ref[0], v_ref[0]
-    for h in range(group):
-        s = jax.lax.dot_general(q_ref[0, :, _head(h)], k, _NT,
-                                preferred_element_type=jnp.float32) * scale
-        _softmax_step(h, jnp.where(allowed, s, MASK_VALUE), v, m_s, l_s,
-                      acc_s, block)
+    def fold(rows, keys, allowed):
+        k, v = k_ref[0, keys], v_ref[0, keys]
+        for h in range(group):
+            s = jax.lax.dot_general(q_ref[0, rows, _head(h)], k, _NT,
+                                    preferred_element_type=jnp.float32) * scale
+            if allowed is not None:
+                s = jnp.where(allowed, s, MASK_VALUE)
+            _softmax_step(h, s, v, m_s, l_s, acc_s, k.shape[0], rows=rows)
+
+    _by_kind(qi_ref[p] - ki_ref[p], block, window, sub, fold)
 
     @pl.when(last_ref[p] == 1)
     def _():
         _write_forward(o_ref, stats_ref, m_s, l_s, acc_s, group, block)
 
 
+def _strip_backward(h: int, rows, keys, allowed, q_ref, k_ref, v_ref, do_ref,
+                    stats_ref, delta_ref, scale):
+    """:func:`_tile_backward` of head ``h`` over the tile's ``rows`` and
+    ``keys``, with the operands it read: (q, do, k, prob, ds), ``ds`` in the
+    operands' dtype."""
+    q, do = q_ref[0, rows, _head(h)], do_ref[0, rows, _head(h)]
+    k = k_ref[0, keys]
+    prob, ds = _tile_backward(q, do, k, v_ref[0, keys], allowed,
+                              stats_ref[0, 0, rows, h:h + 1],
+                              delta_ref[0, 0, rows, h:h + 1], scale)
+    # cast to the operands' dtype once, and transpose the narrow copy
+    return q, do, k, prob, ds.astype(k.dtype)
+
+
 def _causal_dq_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                       v_ref, do_ref, stats_ref, delta_ref, dq_ref, dq_s, *,
                       scale: float, group: int, block: int,
-                      window: Optional[int]):
+                      window: Optional[int], sub: int):
     p = pl.program_id(2)
 
     @pl.when(first_ref[p] == 1)
     def _():
         dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
 
-    allowed = _allowed(qi_ref[p], ki_ref[p], block, window)
-    k, v = k_ref[0], v_ref[0]
-    stats, delta = stats_ref[0, 0], delta_ref[0, 0]
-    for h in range(group):
-        _, ds = _tile_backward(q_ref[0, :, _head(h)], do_ref[0, :, _head(h)],
-                               k, v, allowed, stats[:, h:h + 1],
-                               delta[:, h:h + 1], scale)
-        dq_s[:, _head(h)] += jnp.dot(ds.astype(k.dtype), k,
-                                     preferred_element_type=jnp.float32)
+    def fold(rows, keys, allowed):
+        for h in range(group):
+            _, _, k, _, ds = _strip_backward(
+                h, rows, keys, allowed, q_ref, k_ref, v_ref, do_ref,
+                stats_ref, delta_ref, scale)
+            dq_s[rows, _head(h)] += jnp.dot(
+                ds, k, preferred_element_type=jnp.float32)
+
+    _by_kind(qi_ref[p] - ki_ref[p], block, window, sub, fold)
 
     @pl.when(last_ref[p] == 1)
     def _():
@@ -292,7 +445,7 @@ def _causal_dq_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
 def _causal_dkv_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                        v_ref, do_ref, stats_ref, delta_ref, dk_ref, dv_ref,
                        dk_s, dv_s, *, scale: float, group: int, block: int,
-                       window: Optional[int]):
+                       window: Optional[int], sub: int):
     p = pl.program_id(2)
 
     @pl.when(first_ref[p] == 1)
@@ -300,17 +453,17 @@ def _causal_dkv_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
         dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
         dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
 
-    allowed = _allowed(qi_ref[p], ki_ref[p], block, window)
-    k, v = k_ref[0], v_ref[0]
-    stats, delta = stats_ref[0, 0], delta_ref[0, 0]
-    for h in range(group):
-        q, do = q_ref[0, :, _head(h)], do_ref[0, :, _head(h)]
-        prob, ds = _tile_backward(q, do, k, v, allowed, stats[:, h:h + 1],
-                                  delta[:, h:h + 1], scale)
-        dv_s[...] += jnp.dot(prob.T.astype(do.dtype), do,
-                             preferred_element_type=jnp.float32)
-        dk_s[...] += jnp.dot(ds.T.astype(q.dtype), q,
-                             preferred_element_type=jnp.float32)
+    def fold(rows, keys, allowed):
+        for h in range(group):
+            q, do, _, prob, ds = _strip_backward(
+                h, rows, keys, allowed, q_ref, k_ref, v_ref, do_ref,
+                stats_ref, delta_ref, scale)
+            dv_s[keys, :] += jnp.dot(prob.astype(do.dtype).T, do,
+                                     preferred_element_type=jnp.float32)
+            dk_s[keys, :] += jnp.dot(ds.T, q,
+                                     preferred_element_type=jnp.float32)
+
+    _by_kind(qi_ref[p] - ki_ref[p], block, window, sub, fold)
 
     @pl.when(last_ref[p] == 1)
     def _():
@@ -321,7 +474,7 @@ def _causal_dkv_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
 def _causal_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                        v_ref, do_ref, stats_ref, delta_ref, dq_ref, dk_ref,
                        dv_ref, dq_s, dk_s, dv_s, *, scale: float, group: int,
-                       block: int, window: Optional[int]):
+                       block: int, window: Optional[int], sub: int):
     """``dq``, ``dk`` and ``dv`` from one pass over the band, query-block-
     major: ``dq_s`` holds a query block over its key blocks, ``dk_s`` and
     ``dv_s`` (T, 128) a key-value head's whole sequence over all pairs."""
@@ -336,21 +489,21 @@ def _causal_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
     def _():
         dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
 
-    allowed = _allowed(qi_ref[p], ki_ref[p], block, window)
-    k, v = k_ref[0], v_ref[0]
-    stats, delta = stats_ref[0, 0], delta_ref[0, 0]
-    keys = pl.ds(pl.multiple_of(ki_ref[p] * block, block), block)
-    for h in range(group):
-        q, do = q_ref[0, :, _head(h)], do_ref[0, :, _head(h)]
-        prob, ds = _tile_backward(q, do, k, v, allowed, stats[:, h:h + 1],
-                                  delta[:, h:h + 1], scale)
-        # cast to the operands' dtype once, and transpose the narrow copy
-        ds = ds.astype(k.dtype)
-        dq_s[:, _head(h)] += jnp.dot(ds, k,
-                                     preferred_element_type=jnp.float32)
-        dv_s[keys, :] += jnp.dot(prob.astype(do.dtype).T, do,
-                                 preferred_element_type=jnp.float32)
-        dk_s[keys, :] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+    def fold(rows, keys, allowed):
+        at = pl.ds(pl.multiple_of(ki_ref[p] * block + keys.start, sub),
+                   keys.stop - keys.start)
+        for h in range(group):
+            q, do, k, prob, ds = _strip_backward(
+                h, rows, keys, allowed, q_ref, k_ref, v_ref, do_ref,
+                stats_ref, delta_ref, scale)
+            dq_s[rows, _head(h)] += jnp.dot(
+                ds, k, preferred_element_type=jnp.float32)
+            dv_s[at, :] += jnp.dot(prob.astype(do.dtype).T, do,
+                                   preferred_element_type=jnp.float32)
+            dk_s[at, :] += jnp.dot(ds.T, q,
+                                   preferred_element_type=jnp.float32)
+
+    _by_kind(qi_ref[p] - ki_ref[p], block, window, sub, fold)
 
     @pl.when(last_ref[p] == 1)
     def _():
@@ -363,7 +516,8 @@ def _causal_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
 
 
 # ---------------------------------------------------------------------------
-# Two 64-wide heads a lane tile
+# Two 64-wide heads a lane tile (whole tiles: their ``sub`` is the tile,
+# :func:`sub_block`)
 # ---------------------------------------------------------------------------
 
 def _lane_half(block: int):
@@ -396,7 +550,7 @@ def _placed_halves(x, half, group: int):
 def _halves_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                        v_ref, o_ref, stats_ref, m_s, l_s, acc_s, *,
                        scale: float, group: int, block: int,
-                       window: Optional[int]):
+                       window: Optional[int], sub: int):
     p = pl.program_id(2)
 
     @pl.when(first_ref[p] == 1)
@@ -459,7 +613,7 @@ def _home(parts):
 def _halves_dq_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                       v_ref, do_ref, stats_ref, delta_ref, dq_ref, dq_s, *,
                       scale: float, group: int, block: int,
-                      window: Optional[int]):
+                      window: Optional[int], sub: int):
     p = pl.program_id(2)
 
     @pl.when(first_ref[p] == 1)
@@ -486,7 +640,7 @@ def _halves_dq_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
 def _halves_dkv_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                        v_ref, do_ref, stats_ref, delta_ref, dk_ref, dv_ref,
                        dk_s, dv_s, *, scale: float, group: int, block: int,
-                       window: Optional[int]):
+                       window: Optional[int], sub: int):
     p = pl.program_id(2)
 
     @pl.when(first_ref[p] == 1)
@@ -521,7 +675,7 @@ def _halves_dkv_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
 def _halves_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                        v_ref, do_ref, stats_ref, delta_ref, dq_ref, dk_ref,
                        dv_ref, dq_s, dk_s, dv_s, *, scale: float, group: int,
-                       block: int, window: Optional[int]):
+                       block: int, window: Optional[int], sub: int):
     """``_causal_bwd_kernel`` for a key-value tile of two heads: one pass
     over the band, ``dk_s`` and ``dv_s`` (T, 128) the tile's whole
     sequence."""
@@ -575,7 +729,7 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
           block: int, window: Optional[int], key_major: bool,
           interpret: bool, scale: float = LANES ** -0.5,
           steps: Optional[int] = None, heads_parallel: bool = True,
-          v_block: int = 0, more_specs=None):
+          v_block: int = 0, more_specs=None, **static):
     """``operands`` / ``outs``: (array or shape-dtype, kind) with kind "q"
     (a group's query lanes, by query block), "kv" (one key-value head, by
     key block), "kv_all" (one key-value head's whole sequence) or "stats"
@@ -588,7 +742,9 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
     into an array that holds the keys' lanes first); for a selection read
     from the data, "sel" (a (B, T, T) array's tile of the pair). ``steps``:
     the grid's head axis, where it is not the key-value heads of the second
-    operand. ``more_specs``: a caller's own kinds, {kind: BlockSpec}."""
+    operand. ``more_specs``: a caller's own kinds, {kind: BlockSpec}.
+    ``static``: the kernel's further static keywords (``sub`` of the
+    kernels :func:`causal_attention` picks)."""
     b = operands[1][0].shape[0]
     g = steps or operands[1][0].shape[2] // LANES
     table = band_pairs(t // block, block, window, key_major)
@@ -623,8 +779,8 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
         **(more_specs or {}),
     }
     return pl.pallas_call(
-        functools.partial(kernel, scale=scale, group=group,
-                          block=block, window=window),
+        functools.partial(kernel, scale=scale, group=group, block=block,
+                          window=window, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(b, g, len(table)),
             in_specs=[specs[kind] for _, kind in operands],
@@ -657,7 +813,8 @@ def _fwd(q, k, v, window, block, interpret, head_dim):
         [(q, "q"), (_stats_like(q, group), "stats")],
         [rows, rows, pltpu.VMEM((block, group * LANES), jnp.float32)],
         t=q.shape[1], group=group, block=block, window=window,
-        key_major=False, interpret=interpret, scale=head_dim ** -0.5)
+        key_major=False, interpret=interpret, scale=head_dim ** -0.5,
+        sub=sub_block(block, head_dim))
 
 
 def _padded(x, block: int):
@@ -714,7 +871,8 @@ def _vjp_bwd(window, block, interpret, head_dim, res, dout):
     operands = [(q, "q"), (k, "kv"), (v, "kv"), (dout, "q"),
                 (stats, "stats"), (delta, "stats")]
     kw = dict(t=q.shape[1], group=group, block=block, window=window,
-              interpret=interpret, scale=head_dim ** -0.5)
+              interpret=interpret, scale=head_dim ** -0.5,
+              sub=sub_block(block, head_dim))
     fused, dq_only, dkv_only = (
         (_causal_bwd_kernel, _causal_dq_kernel, _causal_dkv_kernel)
         if per == 1 else

@@ -127,8 +127,10 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(
     call = "full_rope attention", (44, 4 * cfg.head_dim, 2 * cfg.head_dim)
     assert lowering_record.why_not(*call) == shut
     if with_kernels:
+        # two 64-wide heads a lane tile: whole tiles (``sub`` the tile)
         assert lowering_record.recorded(*call) == {
-            "why_not": None, "split_backward": None}
+            "why_not": None, "split_backward": None,
+            "band": kernels.band_account(1, 512, None, 512)}
     # the head pass of 64-wide heads stays XLA code, for the rule's reason
     assert lowering_record.first_refusal(
         ("head norm + rotary", (44, heads * cfg.head_dim, cfg.head_dim))
@@ -146,6 +148,9 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(
         "blockwise 512: 0 of 1 attention layers, 1 full rope, 2 query heads "
         "a key-value head, normed queries and keys (XLA: no Mosaic backend), "
         "rotary (XLA: no Mosaic backend)")
+    assert said.get("attn_band") == (
+        "1 full_rope: 1 tile, 1 at an edge whole, visited over allowed "
+        "pairs 1.9961" if with_kernels else None)
     assert said["conv_layout"] == (
         f"gated short convolution: 2 of 3 layers, 3 taps, causal, depthwise "
         f"over {d} lanes; conv/mix is XLA code: B, C and u read as column "

@@ -76,13 +76,15 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(
     assert 0 < float(aux["moe_assignments_here_pct"]) < 100
     assert float(aux["moe_dropped"]) == 0.0
     # which lowering every layer took, and the one backward kernel a tile
-    for kind in ("full_nope", "window_rope"):
+    for kind, window in (("full_nope", None), ("window_rope", 8)):
         call = f"{kind} attention", (28, 4 * cfg.head_dim, 2 * cfg.head_dim)
         assert lowering_record.why_not(*call) == (
             None if kernels else "no Mosaic backend")
         if kernels:
+            # ... and the band of its one padded tile, an edge in sub-tiles
             assert lowering_record.recorded(*call) == {
-                "why_not": None, "split_backward": None}
+                "why_not": None, "split_backward": None,
+                "band": sparse_lm.kernels.band_account(1, 512, window, 256)}
     # (28 tokens: the rotary's pass wants rows in eights, the test below)
     assert lowering_record.first_refusal(
         ("rotary", (28, heads * cfg.head_dim, cfg.head_dim))
@@ -95,6 +97,12 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(
         + (", backward: one kernel a tile (4 of 4 layers), rotary (XLA: 28 "
            "rows are not whole sublane tiles of 8)" if kernels else
            ", rotary (XLA: no Mosaic backend)"))
+    # the band's account, a layer kind, from the function the kernels use
+    assert sparse_lm.engagement_records(cfg).get("attn_band") == (
+        "1 full_nope: 1 tile, 1 at an edge by sub-tiles of 256, visited "
+        "over allowed pairs 1.9961 -> 1.4971; 3 window_rope: 1 tile, 1 at "
+        "an edge by sub-tiles of 256, visited over allowed pairs 64.4405 -> "
+        "48.3304" if kernels else None)
 
 
 @pytest.mark.parametrize("interpret, budget, words", [

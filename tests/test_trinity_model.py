@@ -104,7 +104,9 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(
         assert lowering_record.why_not(*call) == shut
         if kernels:
             assert lowering_record.recorded(*call) == {
-                "why_not": None, "split_backward": None}
+                "why_not": None, "split_backward": None,
+                "band": sparse_lm.kernels.band_account(
+                    1, 512, 8 if rotary else None, 256)}
         assert lowering_record.first_refusal(
             ("head norm" + " + rotary" * rotary,
              (32, heads * cfg.head_dim, cfg.head_dim))
