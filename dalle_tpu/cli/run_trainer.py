@@ -25,13 +25,13 @@ from typing import Optional, Sequence
 
 from dalle_tpu.config import (AfmoeLMConfig, CollabConfig, JoyAILMConfig,
                               KeyeLMConfig, Lfm2MoeLMConfig, ModelConfig,
-                              OptimizerConfig, PeerConfig, SparseLMConfig,
+                              NemotronHLMConfig, OptimizerConfig, PeerConfig, SparseLMConfig,
                               TrainerConfig, flagship_model_config,
                               joyaiflash_model_config,
                               keyevl2_model_config, lfm2moe_model_config,
                               smallthinker21b_model_config,
                               tiny_model_config, trinitymini_model_config,
-                              xl_model_config)
+                              twotower30b_model_config, xl_model_config)
 from dalle_tpu.cli._args import (add_dataclass_args, check_no_collisions,
                                  dataclass_from_args)
 
@@ -61,6 +61,11 @@ MODEL_PRESETS = {
     # of a layer (an indexer chooses 2 048 keys a query, attention over
     # the chosen keys, three position rows): keyevl2-train-solo
     "keyevl2": keyevl2_model_config,
+    # the 52-layer stack of Nemotron-Labs-TwoTower-30B-A3B-Base-BF16 cut
+    # to one of 16 chips' share of a layer (Mamba-2 mixers and their
+    # chunked scan, layers of one part, two-product relu^2 experts):
+    # twotower30b-train-solo
+    "twotower30b": twotower30b_model_config,
 }
 
 CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
@@ -68,7 +73,8 @@ CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
 # Every architecture's configuration class. A preset builds one of them;
 # a field two of them share (vocab_text, dtype, ...) is one flag.
 MODEL_CLASSES = (ModelConfig, SparseLMConfig, AfmoeLMConfig,
-                 JoyAILMConfig, Lfm2MoeLMConfig, KeyeLMConfig)
+                 JoyAILMConfig, Lfm2MoeLMConfig, KeyeLMConfig,
+                 NemotronHLMConfig)
 
 
 def maybe_wandb_run(project: Optional[str], name: str):

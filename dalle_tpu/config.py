@@ -707,9 +707,17 @@ LAYER_SHORT_CONV = "short_conv"
 # over the keys its layer's indexer chose (``index_topk`` of those before
 # it): a class that states an indexer has it (``KeyeLMConfig``)
 LAYER_SELECTED_ROPE = "selected_rope"
+# no attention: a Mamba-2 state-space mixer (``models/sparse_lm.Mamba2Mixer``:
+# a scalar decay a head over a fixed-size state, trained by a chunked scan);
+# a class that states its sizes has it (``NemotronHLMConfig``)
+LAYER_MAMBA2 = "mamba2"
+# no operator at all: the layer is its expert feed-forward alone. Only where
+# a class's layers are one part each (``one_part_layers``)
+LAYER_EXPERTS = "experts"
 
 VALID_LAYER_KINDS = (LAYER_FULL_NOPE, LAYER_WINDOW_ROPE, LAYER_FULL_ROPE,
-                     LAYER_SHORT_CONV, LAYER_SELECTED_ROPE)
+                     LAYER_SHORT_CONV, LAYER_SELECTED_ROPE, LAYER_MAMBA2,
+                     LAYER_EXPERTS)
 
 
 @dataclass(frozen=True)
@@ -810,6 +818,20 @@ class SparseLMConfig:
     indexer_rotary: ClassVar[bool] = False
     indexer_loss_weight: ClassVar[float] = 0.0
     mrope_section: ClassVar[Tuple[int, ...]] = ()
+    # ... and of ``NemotronHLMConfig``: layers that are ONE part behind one
+    # norm (``layer_kinds`` then names a layer's part: a mixer or
+    # ``experts``), experts of two products (no gate), a shared expert's
+    # width of its own (0: ``num_shared_experts`` x ``expert_width``), and
+    # the sizes of the layers of kind ``mamba2`` (0 heads: no such layer)
+    one_part_layers: ClassVar[bool] = False
+    expert_gated: ClassVar[bool] = True
+    shared_expert_width: ClassVar[int] = 0
+    mamba_num_heads: ClassVar[int] = 0
+    mamba_head_dim: ClassVar[int] = 0
+    ssm_groups: ClassVar[int] = 0
+    ssm_state_size: ClassVar[int] = 0
+    ssm_chunk: ClassVar[int] = 0
+    residual_rescale_layers: ClassVar[int] = 0
     # fields a configuration's file states and no entry point's flag sets:
     # what the source fixes and models/sparse_lm.py is written for
     # (``validate`` holds each to its one value), and the one assumption
@@ -840,6 +862,12 @@ class SparseLMConfig:
         not the expert layer (the leading ``num_dense_layers``)."""
         return layer < self.num_dense_layers
 
+    @property
+    def shared_width(self) -> int:
+        """The shared expert's width (0: none)."""
+        return self.shared_expert_width \
+            or self.num_shared_experts * self.expert_width
+
     def validate_shapes(self) -> None:
         # ``full_rope`` is any class's: grouped key-value heads with rotary
         # over the whole sequence, or latent attention where the class
@@ -855,6 +883,15 @@ class SparseLMConfig:
                 raise ValueError(
                     f"layer kind {kind!r} attends over the keys an indexer "
                     "chose: a class that states one has it (KeyeLMConfig)")
+            if kind == LAYER_MAMBA2 and self.mamba_num_heads < 1:
+                raise ValueError(
+                    f"layer kind {kind!r} is a state-space mixer: a class "
+                    "that states its sizes has it (NemotronHLMConfig)")
+            if kind == LAYER_EXPERTS and not self.one_part_layers:
+                raise ValueError(
+                    f"layer kind {kind!r} is a feed-forward with no "
+                    "operator: a class whose layers are one part each has "
+                    "it (NemotronHLMConfig)")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
         if self.vocab_text + self.vocab_image != self.vocab_size:
@@ -965,7 +1002,10 @@ class AfmoeLMConfig(SparseLMConfig):
                 "counters are the expert layers')")
         if self.num_dense_layers and self.dense_width <= 0:
             raise ValueError("dense layers need a dense_width")
-        if self.hidden_act not in ("relu", "silu"):
+        # a gated block's activation, or the square of ReLU between an
+        # ungated one's two products
+        acts = ("relu", "silu") if self.expert_gated else ("relu2",)
+        if self.hidden_act not in acts:
             raise ValueError(f"unknown hidden_act {self.hidden_act!r}")
         if self.router_input != "post_attention_norm":
             raise ValueError(
@@ -1226,6 +1266,134 @@ def keyevl2_model_config(**overrides: Any) -> KeyeLMConfig:
     """Preset ``keyevl2``: the cell ``keyevl2-train-solo``
     (benchmark/configs/keyevl2.json holds ``asdict`` of it)."""
     return dataclasses.replace(KeyeLMConfig(), **overrides)
+
+
+@dataclass(frozen=True)
+class NemotronHLMConfig(AfmoeLMConfig):
+    """``AfmoeLMConfig``'s sigmoid router with a selection bias, normalised
+    weights, a scale and a shared expert beside the routed ones (no dense
+    layer, no output gate, no head norms, no embedding scale, no positions
+    anywhere) with the mechanisms of ``model_type`` ``nemotron_h`` as
+    fields: **every layer is one part behind one norm**
+    (``one_part_layers``: ``h + part(rmsnorm(h))``; ``layer_kinds`` names
+    each layer's part, cycled over the depth), the parts being a **Mamba-2
+    state-space mixer** (kind ``mamba2``: ``mamba_num_heads`` heads of
+    ``mamba_head_dim``, ``ssm_groups`` groups of B and C of
+    ``ssm_state_size``, ``conv_kernel`` causal depthwise taps with a bias
+    and a SiLU, a scalar decay a head, a gated RMS norm over each group's
+    lanes; every part's output projection drawn as the source's
+    ``rescale_prenorm_residual`` has it, ``residual_rescale_layers``;
+    trained by a chunked scan of ``ssm_chunk`` tokens), softmax
+    attention over the whole sequence with no positions (``full_nope``), and
+    the expert feed-forward alone (``experts``) whose experts are **two
+    products, not gated** (``expert_gated`` false: ``W_down relu(W_up
+    m)^2``, ``hidden_act`` ``relu2``), the shared expert of a width of its
+    own (``shared_expert_width``). Defaults are the 52-layer stack of
+    Nemotron-Labs-TwoTower-30B-A3B-Base-BF16 (nvidia, config.json; the
+    denoiser tower its description speaks of has no key there and is not
+    this model's) cut to the share one of the 16 chips of a layer holds:
+    published layers 0-6 (``MEMEM*E``, one turn of the pattern's 7-layer
+    cycle), experts 0-7 of 128, an eighth of the vocabulary; every width as
+    published. ``window`` and ``rope_theta`` are no layer's."""
+
+    hidden_size: int = 2688
+    num_hidden_layers: int = 7       # published 52; 3 M, 3 E, 1 *
+    num_heads: int = 32
+    num_kv_heads: int = 2
+    head_dim: int = 128
+    expert_width: int = 1856
+    num_experts: int = 128
+    experts_per_token: int = 6
+    vocab_size: int = 16384          # published 131072
+    window: int = 0
+    layer_kinds: Tuple[str, ...] = (
+        LAYER_MAMBA2, LAYER_EXPERTS, LAYER_MAMBA2, LAYER_EXPERTS,
+        LAYER_MAMBA2, LAYER_FULL_NOPE, LAYER_EXPERTS)
+    rope_theta: float = 1e4          # the source's key; nothing reads it
+    rms_eps: float = 1e-5
+    vocab_text: int = 8192
+    vocab_image: int = 8192
+    num_dense_layers: int = 0
+    dense_width: int = 0
+    num_shared_experts: int = 1
+    hidden_act: str = "relu2"
+    route_scale: float = 2.5
+    attention_gate: bool = False
+    qk_norm: bool = False
+    sandwich_norms: bool = False
+    mup_enabled: bool = False
+    conv_kernel: int = 4
+    conv_bias: bool = True
+    one_part_layers: bool = True
+    expert_gated: bool = False
+    shared_expert_width: int = 3712
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    ssm_groups: int = 8              # the source's n_groups
+    ssm_state_size: int = 128
+    ssm_chunk: int = 128             # the source's chunk_size
+    # the source's rescale_prenorm_residual: at init every layer's one
+    # output projection (a mixer's, attention's, the experts' and the shared
+    # expert's ``down``) is divided by the root of the PUBLISHED depth,
+    # which a cut of the layers does not change (0: no rescale)
+    residual_rescale_layers: int = 52
+
+    no_flag: ClassVar[Tuple[str, ...]] = AfmoeLMConfig.no_flag + (
+        "conv_bias", "one_part_layers", "expert_gated",
+        "residual_rescale_layers")
+    decode_missing: ClassVar[Optional[str]] = (
+        "models/decode.py has no state-space mixer (kind 'mamba2': no cache "
+        "of the recurrence's state and of the convolution's last conv_kernel "
+        "- 1 tokens), no layer that is one part alone, no grouped key-value "
+        "heads and no expert layer with a shared expert")
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_lanes(self) -> int:
+        """x, B and C side by side: what the depthwise taps run over."""
+        return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state_size
+
+    def validate(self) -> None:
+        self.validate_shapes()
+        self.validate_blocks_and_router()
+        if self.attention_bias or self.tied_embeddings:
+            raise ValueError(
+                "this class has an untied head and no attention bias")
+        if not self.one_part_layers or self.expert_gated \
+                or self.hidden_act != "relu2":
+            raise ValueError(
+                "this class's layers are one part each and its experts two "
+                "products with relu^2 between (hidden_act 'relu2')")
+        if self.num_dense_layers or self.sandwich_norms \
+                or self.attention_gate or self.qk_norm or self.mup_enabled:
+            raise ValueError(
+                "a one-part layer has one norm, and this class no dense "
+                "layer, output gate, head norm or embedding scale")
+        if LAYER_EXPERTS not in [
+                self.kind_of_layer(i) for i in range(self.num_hidden_layers)]:
+            raise ValueError("layer_kinds must leave an expert layer (the "
+                             "step's counters are the expert layers')")
+        if any(k not in (LAYER_MAMBA2, LAYER_FULL_NOPE, LAYER_EXPERTS)
+               for k in self.layer_kinds):
+            raise ValueError(
+                "a one-part layer is 'mamba2', 'full_nope' or 'experts' "
+                "(the family's attention has no positions)")
+        if min(self.mamba_num_heads, self.mamba_head_dim, self.ssm_groups,
+               self.ssm_state_size, self.ssm_chunk, self.conv_kernel) < 1 \
+                or self.mamba_num_heads % self.ssm_groups:
+            raise ValueError(
+                "the state-space mixer needs mamba_num_heads (a multiple of "
+                "ssm_groups), mamba_head_dim, ssm_state_size, ssm_chunk and "
+                "conv_kernel")
+
+
+def twotower30b_model_config(**overrides: Any) -> NemotronHLMConfig:
+    """Preset ``twotower30b``: the cell ``twotower30b-train-solo``
+    (benchmark/configs/twotower30b.json holds ``asdict`` of it)."""
+    return dataclasses.replace(NemotronHLMConfig(), **overrides)
 
 
 def tiny_model_config(**overrides: Any) -> ModelConfig:

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dalle_tpu.config import LAYER_SELECTED_ROPE, ModelConfig
+from dalle_tpu.config import LAYER_MAMBA2, LAYER_SELECTED_ROPE, ModelConfig
 from dalle_tpu.models.attention import (NEG_INF, apply_rotary_lanes,
                                         rotary_cos_sin, zoo_attention_mask)
 
@@ -122,6 +122,19 @@ def refuse_selected_layers(cfg) -> None:
             "choose a query's keys at decode time")
 
 
+def refuse_recurrent_layers(cfg) -> None:
+    """A configuration with a layer of kind ``mamba2`` (a state-space
+    mixer, models/sparse_lm.py) cannot be decoded here either: the cache
+    below holds keys and values, not a recurrence's state."""
+    kind = LAYER_MAMBA2
+    if kind in getattr(cfg, "layer_kinds", ()):
+        raise NotImplementedError(
+            f"models/decode.py cannot decode a layer of kind {kind!r}: it "
+            "keeps no cache of the recurrence's state a head and of the "
+            "convolution's last taps' tokens, and has no single-token step "
+            "of the scan")
+
+
 def init_cache(cfg: ModelConfig, batch: int, dtype=None):
     """Static-shape KV cache, one k/v pair per layer application (weight
     sharing shares parameters, not activations).
@@ -134,6 +147,7 @@ def init_cache(cfg: ModelConfig, batch: int, dtype=None):
     slot so the scan carries its cache without slicing a big array.
     """
     refuse_selected_layers(cfg)
+    refuse_recurrent_layers(cfg)
     dtype = dtype or jnp.dtype(cfg.dtype)
     hd = cfg.heads * cfg.head_dim
     reps = _cycle_reps(cfg)

@@ -1,6 +1,6 @@
 """A sparse decoder-only language model on the trainer's normal path.
 
-Five configurations' equations, each mechanism read from a field of the
+Six configurations' equations, each mechanism read from a field of the
 configuration and none from a preset's name. What ``SparseLMConfig``
 describes (its defaults: SmallThinker-21BA3B-Instruct, PowerInfer): every
 layer is
@@ -141,6 +141,47 @@ time. No (T, T) array exists per head. Scopes ``attn/indexer/proj``,
 ``attn/indexer/select`` (``select[mosaic]``), ``attn/indexer/align``
 (``align[mosaic]`` and the row sums), ``attn/qk_norm``, ``attn[mosaic]``.
 
+What ``NemotronHLMConfig`` describes (its defaults: the 52-layer stack of
+Nemotron-Labs-TwoTower-30B-A3B-Base-BF16, nvidia, ``model_type``
+``nemotron_h``): **every layer is one part behind one norm**
+(:class:`OnePartLayer`), ``x' = x + part(rmsnorm(x))``, the part named by
+``cfg.layer_kinds``; no positions anywhere, no bias but the convolution's:
+
+    mamba2 (:class:`Mamba2Mixer`, parameters under ``ssm``; H heads of P,
+    G groups of state N, K taps):
+      [z ; xBC ; dt] = a . W_in          hidden -> H P + (H P + 2 G N) + H
+      xBC <- silu(sum_{j<K} taps[j] * xBC_{t-(K-1)+j} + bias)   depthwise,
+                                         causal, noughts before t = 0
+      x (T, H, P), B (T, G, N), C (T, G, N) = split(xBC); head h reads
+                                         group h // (H / G)
+      D_t,h = softplus(dt_t,h + dt_bias_h);  A_h = -exp(A_log_h)
+      S_t = exp(D_t A) S_{t-1} + D_t x_t B_t^T;  y_t = S_t C_t + D_h x_t
+                                         f32, S (P, N) a head, S_{-1} = 0
+      y <- rmsnorm over each group's H P / G lanes of (y * silu(z))
+      part = y . W_out                   H P -> hidden
+    full_nope (:class:`Attention`): grouped key-value heads, causal over
+      the whole sequence, no rotary
+    experts (:class:`ExpertLayer`): the sigmoid router of ``AfmoeLMConfig``
+      (bias, norm, scale) on the layer's one normed input m, and
+      f = shared(m) + sum_{e in S, e held here} p_e . W_down,e relu(W_up,e m)^2
+      two products an expert, NOT gated (``expert_gated`` false,
+      ``hidden_act`` ``relu2``; :class:`UngatedBlock` is the shared expert)
+
+**How the recurrence runs** (:func:`chunked_scan`, the site "ssm scan";
+``ssm_layout`` on the ``setup/warmup`` row says it): in chunks of
+``ssm_chunk`` tokens. Inside a chunk the masked (chunk x chunk) form a
+head, ``y_i += sum_{j<=i} exp(cs_i - cs_j) (C_i . B_j) D_j x_j`` with ``cs``
+the running sum of ``D A`` inside the chunk; across chunks the carried (P,
+N) state, ``S_c = exp(cs_last) S_{c-1} + sum_j exp(cs_last - cs_j) D_j x_j
+B_j^T``, and ``y_i += exp(cs_i) C_i . S_{c-1}``. ``D``, the decays, their
+running sums and the states are f32; the products' operands are in
+``cfg.dtype`` with f32 accumulation. Nothing of (T, T) and no state a token
+exists, forward, replay or backward (plain differentiation of the chunked
+form under the layer's rematerialisation). All of it XLA code: scopes
+``ssm/in_proj``, ``ssm/conv`` (taps, bias, SiLU), ``ssm/scan`` (``D``, the
+decays, both forms, ``D x``), ``ssm/gate_norm``, ``ssm/out_proj`` (never
+under ``attn`` or ``conv``).
+
 **The expert layer is told which experts it holds** (``experts_held``
 consecutive ones from ``expert_offset``): it routes over all
 ``num_experts``, computes the part of the result its own experts give for
@@ -277,9 +318,9 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from dalle_tpu.config import (LAYER_FULL_ROPE, LAYER_SELECTED_ROPE,
-                              LAYER_SHORT_CONV, LAYER_WINDOW_ROPE,
-                              SparseLMConfig)
+from dalle_tpu.config import (LAYER_EXPERTS, LAYER_FULL_ROPE, LAYER_MAMBA2,
+                              LAYER_SELECTED_ROPE, LAYER_SHORT_CONV,
+                              LAYER_WINDOW_ROPE, SparseLMConfig)
 from dalle_tpu.models import attention as attn_mod
 from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
 from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
@@ -381,6 +422,22 @@ def head_pass(x, scale=None, *, mesh, eps: float, head_dim: int,
     return lowering.site(
         name, fits, kernel, xla, mesh, (LANES_SPEC, P())[:1 + norm],
         LANES_SPEC, head_norm.scope(norm))(x, *[scale] * norm)
+
+
+def out_proj_init(cfg: SparseLMConfig, **axes) -> Dict[str, Any]:
+    """``kernel_init`` of a residual branch's output projection where the
+    configuration states the source's ``rescale_prenorm_residual``
+    (``cfg.residual_rescale_layers``, the model's PUBLISHED depth): U(+-1 /
+    sqrt(fan_in)) over the root of that depth, so that no branch's output
+    (a mixer's slowly varying state, the constant part of an expert's
+    ``relu(u)^2``) outweighs the token in the residual stream an untrained
+    router reads (PERF.md section 6, PR 57). Nothing where it states none:
+    the module's default."""
+    depth = cfg.residual_rescale_layers
+    if not depth:
+        return {}
+    return {"kernel_init": nn.initializers.variance_scaling(
+        1.0 / (3 * depth), "fan_in", "uniform", **axes)}
 
 
 # ---------------------------------------------------------------------------
@@ -921,7 +978,7 @@ class Attention(nn.Module):
             g = dense(cfg.num_heads * cfg.head_dim, name="gate")(a)
             with jax.named_scope("gate"):
                 ctx = ctx * jax.nn.sigmoid(g.astype(jnp.float32)).astype(dt)
-        return dense(cfg.hidden_size, name="out")(ctx)
+        return dense(cfg.hidden_size, name="out", **out_proj_init(cfg))(ctx)
 
 
 def _pair_angles(tokens: int, lanes: int, head_dim: int,
@@ -1209,6 +1266,206 @@ def conv_layout(cfg: SparseLMConfig) -> str:
 
 
 # ---------------------------------------------------------------------------
+# The Mamba-2 state-space mixer (layers of kind ``mamba2``)
+# ---------------------------------------------------------------------------
+
+def causal_taps_silu(xbc: jax.Array, taps: jax.Array,
+                     bias: jax.Array) -> jax.Array:
+    """``silu(sum_j taps[j] * xbc_{t - (K - 1) + j} + bias)``: the depthwise
+    causal convolution over all of xbc's (B, T, W) lanes (noughts before
+    t = 0) as K shifts along the tokens, each an f32 product with an f32
+    tap (:func:`short_conv_mix`'s way), the bias and the SiLU in f32. taps:
+    (K, W); bias: (W,)."""
+    k = taps.shape[0]
+    taps = taps.astype(jnp.float32)
+    z = taps[k - 1] * xbc.astype(jnp.float32) + bias.astype(jnp.float32)
+    for back in range(1, min(k, xbc.shape[1])):
+        earlier = jnp.pad(xbc[:, :-back], ((0, 0), (back, 0), (0, 0)))
+        z = z + taps[k - 1 - back] * earlier.astype(jnp.float32)
+    return jax.nn.silu(z).astype(xbc.dtype)
+
+
+def chunked_scan(x, bm, cm, dt, a, d, *, heads: int, groups: int,
+                 chunk: int) -> jax.Array:
+    """``y_t = S_t C_t + d x_t`` of ``S_t = exp(dt_t a) S_{t-1} + dt_t x_t
+    B_t^T`` (``S_{-1}`` = 0), in chunks of ``chunk`` tokens (module
+    docstring). x: (B, T, H*P); bm, cm: (B, T, G*N), head h reading group h
+    // (H / G); dt: (B, T, H) f32, after the softplus; a (negative), d:
+    (H,) f32. Returns (B, T, H*P) in x's dtype. A sequence that is no whole
+    number of chunks is padded behind with tokens whose ``dt`` is 0: they
+    change no state and nothing reads them."""
+    b, t, _ = x.shape
+    q = min(chunk, t)
+    pad = -t % q
+    if pad:
+        x, bm, cm, dt = (jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+                         for v in (x, bm, cm, dt))
+    c, r = (t + pad) // q, heads // groups
+    f32 = jnp.float32
+    xc = x.reshape(b, c, q, groups, r, -1)            # (b, c, q, g, r, P)
+    bc = bm.reshape(b, c, q, groups, -1)              # (b, c, q, g, N)
+    cc = cm.reshape(b, c, q, groups, -1)
+    dtc = dt.reshape(b, c, q, heads).transpose(0, 1, 3, 2)   # (b, c, h, q)
+    cs = jnp.cumsum(dtc * a[:, None], axis=-1)        # running log decay
+    # inside a chunk: the masked (q x q) form a head
+    i = np.arange(q)
+    seg = jnp.where(i[None, :] <= i[:, None],
+                    cs[..., :, None] - cs[..., None, :], -jnp.inf)
+    cb = jnp.einsum("bcign,bcjgn->bcgij", cc, bc, preferred_element_type=f32)
+    m = (jnp.exp(seg) * dtc[..., None, :]).reshape(b, c, groups, r, q, q) \
+        * cb[:, :, :, None]
+    y = jnp.einsum("bcgrij,bcjgrp->bcigrp", m.astype(x.dtype), xc,
+                   preferred_element_type=f32)
+    # a chunk's own state, and the state every chunk starts from
+    to_end = (jnp.exp(cs[..., -1:] - cs) * dtc).transpose(0, 1, 3, 2)
+    xw = (xc.astype(f32) * to_end.reshape(b, c, q, groups, r, 1)).astype(
+        x.dtype)
+    local = jnp.einsum("bcjgrp,bcjgn->cbgrpn", xw, bc,
+                       preferred_element_type=f32)
+    total = jnp.exp(cs[..., -1]).reshape(b, c, groups, r).transpose(
+        1, 0, 2, 3)                                   # (c, b, g, r)
+
+    def carry(state, chunk_of):
+        decay, own = chunk_of
+        return decay[..., None, None] * state + own, state
+
+    _, before = jax.lax.scan(carry, jnp.zeros(local.shape[1:], f32),
+                             (total, local))          # (c, b, g, r, P, N)
+    y = y + jnp.einsum("bcign,cbgrpn->bcigrp", cc, before.astype(x.dtype),
+                       preferred_element_type=f32) \
+        * jnp.exp(cs).transpose(0, 1, 3, 2).reshape(b, c, q, groups, r, 1)
+    y = y + xc.astype(f32) * d.reshape(groups, r, 1)
+    return y.reshape(b, t + pad, -1)[:, :t].astype(x.dtype)
+
+
+def gated_group_norm(y, z, scale, groups: int, eps: float) -> jax.Array:
+    """RMS norm over each of ``groups`` runs of lanes of ``y * silu(z)``
+    (gate first, norm after), one scale vector for all lanes; in f32."""
+    gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    parts = gated.reshape(*gated.shape[:-1], groups, -1)
+    normed = parts * jax.lax.rsqrt(
+        jnp.mean(parts * parts, -1, keepdims=True) + eps)
+    return (normed.reshape(gated.shape) * scale).astype(y.dtype)
+
+
+SCAN_SITE = "ssm scan"
+# the site's one answer so far: which lowering the scan should be is the
+# trace's to say (PERF.md section 5), and the record keeps the question
+NO_SCAN_KERNEL = "no Mosaic kernel of the chunked scan yet"
+
+
+def _scan_key(tokens: int, cfg: SparseLMConfig):
+    """What the record knows a chunked scan by: a sample's tokens and the
+    mixer's sizes."""
+    return (tokens, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_groups,
+            cfg.ssm_state_size, cfg.ssm_chunk)
+
+
+def ssm_scan(x, bm, cm, dt, a, d, *, mesh, cfg: SparseLMConfig,
+             scope: Optional[str] = None):
+    """:func:`chunked_scan` as a call site: a shard's samples, every head
+    (no mesh axis splits the mixer's lanes)."""
+    def fits(x, bm, cm, dt, a, d) -> bool:
+        return lowering.chose(SCAN_SITE, _scan_key(x.shape[1], cfg),
+                              NO_SCAN_KERNEL, NO_SCAN_KERNEL)
+
+    # no kernel yet: the site's two lowerings are the one XLA code
+    xla = functools.partial(chunked_scan, heads=cfg.mamba_num_heads,
+                            groups=cfg.ssm_groups, chunk=cfg.ssm_chunk)
+    lanes = P(*LANES_SPEC[:2], None)
+    return lowering.site(SCAN_SITE, fits, xla, xla, mesh,
+                         (lanes,) * 4 + (P(), P()), lanes, scope)(
+                             x, bm, cm, dt, a, d)
+
+
+# what the source's keys time_step_min, time_step_max and time_step_floor
+# are for: ``dt_bias`` at init is the inverse softplus of a step drawn
+# log-uniformly between the first two and held above the third. The
+# decays' ``A`` is drawn from U(1, 16), the family's default range (no key)
+DT_MIN, DT_MAX, DT_FLOOR = 1e-3, 1e-1, 1e-4
+A_RANGE = (1.0, 16.0)
+
+
+def _dt_bias_init(key, shape, dtype):
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                 * (np.log(DT_MAX) - np.log(DT_MIN)) + np.log(DT_MIN))
+    dt = jnp.maximum(dt, DT_FLOOR)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _a_log_init(key, shape, dtype):
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, *A_RANGE)
+                   ).astype(dtype)
+
+
+class Mamba2Mixer(nn.Module):
+    """The state-space mixer of a ``mamba2`` layer (module docstring),
+    under the name ``ssm``. Leaves ``in_proj/kernel`` (D, 2 H P + 2 G N +
+    H), ``taps`` (K, H P + 2 G N) (the source's depthwise weight, a tap a
+    row: tap K - 1 weighs the token itself), ``conv_bias``, ``dt_bias``,
+    ``A_log``, ``D`` (H each), ``norm`` (H P) and ``out_proj/kernel``."""
+    cfg: SparseLMConfig
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, a: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        pdt = jnp.dtype(cfg.param_dtype)
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=jnp.dtype(cfg.dtype), param_dtype=pdt)
+        heads, inner = cfg.mamba_num_heads, cfg.mamba_inner
+        lanes = cfg.mamba_conv_lanes
+        state = cfg.ssm_groups * cfg.ssm_state_size
+        zxbcdt = dense(inner + lanes + heads, name="in_proj")(a)
+        taps = self.param(
+            "taps", nn.initializers.variance_scaling(
+                1.0, "fan_in", "truncated_normal", in_axis=0, out_axis=1),
+            (cfg.conv_kernel, lanes), pdt)
+        bias = self.param("conv_bias", nn.initializers.zeros, (lanes,), pdt)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (heads,), pdt)
+        a_log = self.param("A_log", _a_log_init, (heads,), pdt)
+        skip = self.param("D", nn.initializers.ones, (heads,), pdt)
+        scale = self.param("norm", nn.initializers.ones, (inner,), pdt)
+        with jax.named_scope("conv"):
+            xbc = causal_taps_silu(zxbcdt[..., inner:inner + lanes], taps,
+                                   bias)
+        with jax.named_scope("scan"):
+            f32 = jnp.float32
+            dt = jax.nn.softplus(zxbcdt[..., inner + lanes:].astype(f32)
+                                 + dt_bias.astype(f32))
+            y = ssm_scan(xbc[..., :inner], xbc[..., inner:inner + state],
+                         xbc[..., inner + state:], dt,
+                         -jnp.exp(a_log.astype(f32)), skip.astype(f32),
+                         mesh=self.mesh, cfg=cfg, scope="scan")
+        with jax.named_scope("gate_norm"):
+            y = gated_group_norm(y, zxbcdt[..., :inner], scale,
+                                 cfg.ssm_groups, cfg.rms_eps)
+        return dense(cfg.hidden_size, name="out_proj", **out_proj_init(cfg))(y)
+
+
+def ssm_layout(cfg: SparseLMConfig) -> str:
+    """The ``setup/warmup`` row's ``ssm_layout``: the configuration's
+    sizes, and of the scan's site what its traced calls said."""
+    kinds = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
+    tokens, chunk = cfg.total_seq_len, cfg.ssm_chunk
+    said = lowering.recorded(SCAN_SITE, _scan_key(tokens, cfg))
+    how = "none traced" if said is None else said["why_not"]
+    return (
+        f"Mamba-2 mixer: {kinds.count(LAYER_MAMBA2)} of {len(kinds)} layers, "
+        f"{cfg.mamba_num_heads} heads x {cfg.mamba_head_dim}, "
+        f"{cfg.ssm_groups} groups of B and C, state {cfg.ssm_state_size}, "
+        f"{cfg.conv_kernel} taps with a bias over {cfg.mamba_conv_lanes} "
+        f"lanes; chunked scan: chunks of {chunk}, {-(-tokens // chunk)} a "
+        f"sequence of {tokens}: inside a chunk the masked ({chunk} x "
+        f"{chunk}) form a head, across chunks the carried "
+        f"({cfg.mamba_head_dim} x {cfg.ssm_state_size}) state, the decays, "
+        "their sums and the states in f32; no (T, T) array and no state a "
+        f"token; ssm/conv, ssm/scan and ssm/gate_norm are XLA code ({how}); "
+        "the replay keeps nothing of the mixer but the layer's input, the "
+        "backward is plain differentiation of the chunked form")
+
+
+# ---------------------------------------------------------------------------
 # The expert layer
 # ---------------------------------------------------------------------------
 
@@ -1458,29 +1715,39 @@ def _to_rows(source, plan: _Plan, what: str, **facts):
 def grouped_kernels_why_not(dim: int, width: int) -> Optional[str]:
     """Why the grouped Pallas products cannot take experts of ``dim`` x
     ``width``; None where they can (interpreted, any size)."""
-    if (dim % 128 or width % 128) and not lowering.interpret():
+    # a width may end in half a lane tile (1 856 = 14.5 x 128): every block
+    # of the grouped kernels spans its array's whole minor dimension, and
+    # Mosaic masks the last tile's upper lanes. No leaf is padded
+    if (dim % 128 or width % 128 not in (0, 64)) \
+            and not lowering.interpret():
         return f"{dim} x {width} are not lane tiles"
     return None
 
 
-def _block_key(dim: int, width: int, dtype):
+def _block_key(dim: int, width: int, dtype, gated: bool = True):
     """What the record knows the expert block's form by: what
     ``grouped.block_why_not`` chose it from."""
-    return dim, width, jnp.dtype(dtype).name
+    return (dim, width, jnp.dtype(dtype).name) + ("ungated",) * (not gated)
 
 
-def _three_products(gate: jax.Array) -> Optional[str]:
-    """Why these (held, D, F) experts' block runs as three products a
-    direction with XLA code between them; None where its tile work is in
-    the kernels."""
-    return grouped.block_why_not(*_block_key(*gate.shape[1:], gate.dtype))
+def _three_products(first: jax.Array, gated: bool = True) -> Optional[str]:
+    """Why these (held, D, F) experts' block runs as its products (three a
+    direction, two where not ``gated``) with XLA code between them; None
+    where its tile work is in the kernels."""
+    return grouped.block_why_not(*first.shape[1:], first.dtype, gated)
 
 
 BLOCK_ON_THE_TILE = ("gate, up and activation one kernel; cotangents on the "
                      "tile; one dxs; inactive tiles unmoved")
+UNGATED_ON_THE_TILE = ("two products an expert, not gated: up and the "
+                       "activation one kernel; the cotangent on the tile; "
+                       "inactive tiles unmoved")
 
 
-def _block_words(why_not: Optional[str]) -> str:
+def _block_words(why_not: Optional[str], gated: bool = True) -> str:
+    if not gated:
+        return (f"two products a direction, not gated ({why_not})"
+                if why_not else UNGATED_ON_THE_TILE)
     return (f"three products a direction ({why_not})" if why_not
             else BLOCK_ON_THE_TILE)
 
@@ -1495,22 +1762,30 @@ class _Kept(NamedTuple):
     """What the sorted lowering keeps for its backward pass."""
     plan: _Plan
     xs: jax.Array         # (rows, D) the rows' tokens
-    gate: jax.Array       # (rows, F) xs . W_gate
+    gate: Any             # (rows, F) xs . W_gate; () of ungated experts
     up: jax.Array         # (rows, F) xs . W_up
     ys: jax.Array         # (rows, D) the experts' outputs
 
 
-def _sorted_forward(m, idx, p, gate, up, down, *, offset: int, rows: int,
-                    act: str):
+def _sorted_forward(m, idx, p, *weights, offset: int, rows: int, act: str):
     """The held experts' part of the layer for assignments sorted by
-    expert. m: (N, D); idx, p: (N, k); gate, up: (E_h, D, F); down:
-    (E_h, F, D). Returns (((N, D) f32, assignments computed), _Kept)."""
+    expert. m: (N, D); idx, p: (N, k); weights: gate, up (E_h, D, F) and
+    down (E_h, F, D), or up and down alone of experts that are not gated.
+    Returns (((N, D) f32, assignments computed), _Kept)."""
+    *gate, up, down = weights
     with jax.named_scope("dispatch"):
-        plan = dispatch_plan(idx, offset, gate.shape[0], rows)
+        plan = dispatch_plan(idx, offset, up.shape[0], rows)
         xs = _to_rows(m, plan, "tokens")
     with jax.named_scope("experts"):
         how = _grouped(plan)
-        if _three_products(gate):
+        if not gate:
+            g = ()
+            if _three_products(up, gated=False):
+                u = grouped.grouped_matmul(xs, up, **how)
+                hidden = grouped.act(act, u)
+            else:
+                u, hidden = grouped.hidden(xs, up, name=act, **how)
+        elif _three_products(gate := gate[0]):
             g = grouped.grouped_matmul(xs, gate, **how)
             u = grouped.grouped_matmul(xs, up, **how)
             hidden = grouped.act(act, g) * u
@@ -1523,13 +1798,13 @@ def _sorted_forward(m, idx, p, gate, up, down, *, offset: int, rows: int,
             _Kept(plan, xs, g, u, ys))
 
 
-def _sorted_backward(kept: _Kept, p, gate, up, down, dy, act: str,
-                     stated: bool):
-    """Cotangents of (m, p, gate, up, down) from what the forward kept:
+def _sorted_backward(kept: _Kept, p, weights, dy, act: str, stated: bool):
+    """Cotangents of (m, p, *weights) from what the forward kept:
     no product and no gather of the forward pass is run again. ``dy`` moves
     to the rows in the dtype it comes in and is widened there (``stated``:
     the caller said its numbers are that dtype's, for the record)."""
     plan, xs, g, u, ys = kept
+    *gate, up, down = weights
     with jax.named_scope("combine"):
         weight = jnp.where(plan.valid, p[plan.token, plan.slot], 0.0)
         dy_rows = _to_rows(dy, plan, "cotangent",
@@ -1542,7 +1817,22 @@ def _sorted_backward(kept: _Kept, p, gate, up, down, dy, act: str,
                         axis=-1)
         dp = jnp.where(plan.here, score[plan.row], 0.0)
     how = _grouped(plan)
-    if _three_products(gate):
+    if not gate:
+        with jax.named_scope("experts"):
+            if _three_products(up, gated=False):
+                hidden = grouped.act(act, u)
+                dhidden, ddown = grouped.grouped_matmul_grads(
+                    hidden, down, dys, **how)
+                du = grouped.act_cotangent(act, u, dhidden)
+            else:
+                du, hidden = grouped.hidden_grads(dys, down, u, name=act,
+                                                  **how)
+                ddown = grouped.weights_grad(hidden, dys, like=down, **how)
+            dup = grouped.weights_grad(xs, du, like=up, **how)
+            dxs = grouped.grouped_matmul(du, up, transpose_w=True, **how)
+        with jax.named_scope("dispatch"):
+            return _sum_to_tokens(dxs, plan, dtype=xs.dtype), dp, dup, ddown
+    if _three_products(gate := gate[0]):
         with jax.named_scope("experts"):
             grads = functools.partial(grouped.grouped_matmul_grads, **how)
             hidden = grouped.act(act, g)
@@ -1566,42 +1856,46 @@ def _sorted_backward(kept: _Kept, p, gate, up, down, dy, act: str,
     return dm, dp, dgate, dup, ddown
 
 
-def _every_expert(m, idx, p, gate, up, down, *, offset: int, act: str):
+def _every_expert(m, idx, p, *weights, offset: int, act: str):
     """The same sum with no dispatch: every held expert on every token,
     times its routing weight (0 for a token not routed to it)."""
-    held = gate.shape[0]
+    held = weights[0].shape[0]
 
     def one(y, xs):
-        e, w_gate, w_up, w_down = xs
+        e, *w_gate, w_up, w_down = xs
         with jax.named_scope("combine"):
             weight = jnp.sum(jnp.where(idx == e + offset, p, 0.0), axis=1)
         with jax.named_scope("experts"):
-            out = jnp.dot(grouped.act(act, jnp.dot(m, w_gate))
-                          * jnp.dot(m, w_up), w_down)
+            if w_gate:
+                hidden = grouped.act(act, jnp.dot(m, w_gate[0])) \
+                    * jnp.dot(m, w_up)
+            else:
+                hidden = grouped.act(act, jnp.dot(m, w_up))
+            out = jnp.dot(hidden, w_down)
         with jax.named_scope("combine"):
             return y + out.astype(jnp.float32) * weight[:, None], None
 
     y, _ = jax.lax.scan(one, jnp.zeros(m.shape, jnp.float32),
-                        (jnp.arange(held), gate, up, down))
+                        (jnp.arange(held), *weights))
     here = jnp.sum(held_key(idx, offset, held) < held, dtype=jnp.float32)
     return y, here
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _held_experts(offset, rows, act, rounded_to, m, idx, p, gate, up, down):
-    return _held_experts_fwd(offset, rows, act, rounded_to, m, idx, p, gate,
-                             up, down)[0]
+def _held_experts(offset, rows, act, rounded_to, m, idx, p, weights):
+    return _held_experts_fwd(offset, rows, act, rounded_to, m, idx, p,
+                             weights)[0]
 
 
-def _held_experts_fwd(offset, rows, act, rounded_to, m, idx, p, gate, up,
-                      down):
+def _held_experts_fwd(offset, rows, act, rounded_to, m, idx, p, weights):
     # One conditional a direction, written out: lax.cond's own derivative
     # would return both lowerings' residuals from the forward conditional
     # and run neither backward without them. Here the sorted lowering
     # returns what its backward reads (_Kept); the dense one, taken when a
     # step's assignments do not fit, hands back zeros of those shapes and
     # is computed again in the backward pass.
-    operands = (m, idx, p, gate, up, down)
+    operands = (m, idx, p, *weights)
+    held = weights[0].shape[0]
     sorted_ = functools.partial(_sorted_forward, offset=offset, rows=rows,
                                 act=act)
     like = jax.eval_shape(sorted_, *operands)[1]
@@ -1610,33 +1904,33 @@ def _held_experts_fwd(offset, rows, act, rounded_to, m, idx, p, gate, up,
         return (_every_expert(*operands, offset=offset, act=act),
                 jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), like))
 
-    fits = jnp.sum(held_key(idx, offset, gate.shape[0])
-                   < gate.shape[0]) <= rows
+    fits = jnp.sum(held_key(idx, offset, held) < held) <= rows
     out, kept = jax.lax.cond(fits, sorted_, dense, *operands)
     return out, (fits, kept, operands)
 
 
 def _held_experts_bwd(offset, rows, act, rounded_to, res, cotangent):
-    fits, kept, (m, idx, p, gate, up, down) = res
+    fits, kept, (m, idx, p, *weights) = res
     dy = cotangent[0]
     if rounded_to is not None:
         # the caller rounds the result to this dtype before anything reads
         # it, so what comes back are its numbers, widened: exact
         dy = dy.astype(rounded_to)
 
-    def dense(kept, m, p, gate, up, down, dy):
+    def dense(kept, m, p, *rest):
+        *w, dy = rest
         _, vjp = jax.vjp(lambda m, p, *w: _every_expert(
-            m, idx, p, *w, offset=offset, act=act)[0],
-            m, p, gate, up, down)
+            m, idx, p, *w, offset=offset, act=act)[0], m, p, *w)
         return vjp(dy.astype(jnp.float32))
 
-    def sorted_(kept, m, p, gate, up, down, dy):
-        return _sorted_backward(kept, p, gate, up, down, dy, act,
+    def sorted_(kept, m, p, *rest):
+        *w, dy = rest
+        return _sorted_backward(kept, p, w, dy, act,
                                 stated=rounded_to is not None)
 
-    dm, dp, *dw = jax.lax.cond(fits, sorted_, dense, kept, m, p, gate, up,
-                               down, dy)
-    return (dm, np.zeros(idx.shape, jax.dtypes.float0), dp, *dw)
+    dm, dp, *dw = jax.lax.cond(fits, sorted_, dense, kept, m, p, *weights,
+                               dy)
+    return (dm, np.zeros(idx.shape, jax.dtypes.float0), dp, tuple(dw))
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -1645,13 +1939,15 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 PRODUCTS_SITE = "expert products"
 
 
-def held_experts(m, idx, p, gate, up, down, *, offset: int, rows: int,
+def held_experts(m, idx, p, *weights, offset: int, rows: int,
                  act: str = "relu", rounded_to=None):
     """The held experts' part of the layer: the sorted lowering where the
     step's assignments to held experts fit ``rows``, the dense one where
     they do not, chosen on the device by the count; the dense one alone
     where the grouped kernels cannot run. Returns ((N, D) f32, the
-    assignments computed).
+    assignments computed). ``weights``: gate, up and down, or up and down
+    alone of experts that are not gated (``act`` then stands between the
+    two products).
 
     ``rounded_to``: a fact the caller states, not a request: the dtype it
     rounds the (N, D) result to (after any sums in f32) before anything
@@ -1660,32 +1956,38 @@ def held_experts(m, idx, p, gate, up, down, *, offset: int, rows: int,
     (half the bytes for a 16-bit one) and widens it there: the same
     cotangents, bit for bit. A caller that states nothing (None) keeps the
     f32 movement. The forward's arithmetic is the same either way."""
-    def fits(m, idx, p, gate, up, down) -> bool:
-        why_not = grouped_kernels_why_not(m.shape[1], gate.shape[2])
+    gated = len(weights) == 3
+
+    def fits(m, idx, p, first, *others) -> bool:
+        why_not = grouped_kernels_why_not(m.shape[1], first.shape[2])
         words = why_not
         if why_not is None:
             # the block's form, which the sorted lowering asks again of the
             # same shapes: remembered for ``moe_layout``
-            three = _three_products(gate)
-            lowering.record(PRODUCTS_SITE,
-                            _block_key(*gate.shape[1:], gate.dtype), three)
+            three = _three_products(first, gated)
+            lowering.record(
+                PRODUCTS_SITE,
+                _block_key(*first.shape[1:], first.dtype, gated), three)
             words = (f"{rows} rows in tiles of {grouped.TILE}, "
-                     f"{gate.shape[0]} experts of {m.shape[1]} x "
-                     f"{gate.shape[2]}, " + _block_words(three))
-        return lowering.chose(PRODUCTS_SITE, (rows, *gate.shape), why_not,
+                     f"{first.shape[0]} experts of {m.shape[1]} x "
+                     f"{first.shape[2]}, " + _block_words(three, gated))
+        return lowering.chose(PRODUCTS_SITE, (rows, *first.shape), why_not,
                               words)
 
+    kernels_ = functools.partial(
+        _held_experts, offset, rows, act,
+        None if rounded_to is None else jnp.dtype(rounded_to))
     return lowering.site(
-        PRODUCTS_SITE, fits, functools.partial(
-            _held_experts, offset, rows, act,
-            None if rounded_to is None else jnp.dtype(rounded_to)),
+        PRODUCTS_SITE, fits,
+        lambda m, idx, p, *weights: kernels_(m, idx, p, weights),
         functools.partial(_every_expert, offset=offset, act=act))(
-            m, idx, p, gate, up, down)
+            m, idx, p, *weights)
 
 
 class ExpertWeights(nn.Module):
     """The held experts' weights, stacked on a leading axis: leaves
-    ``.../experts/{gate,up,down}`` (LAMB: one trust ratio an expert)."""
+    ``.../experts/{gate,up,down}`` (LAMB: one trust ratio an expert), ``up``
+    and ``down`` alone where the experts are not gated."""
     cfg: SparseLMConfig
 
     @nn.compact
@@ -1696,10 +1998,13 @@ class ExpertWeights(nn.Module):
             batch_axis=(0,))
         pdt, dt = jnp.dtype(cfg.param_dtype), jnp.dtype(cfg.dtype)
         d, f, e = cfg.hidden_size, cfg.expert_width, cfg.experts_held
-        return tuple(self.param(name, init, shape, pdt).astype(dt)
-                     for name, shape in (("gate", (e, d, f)),
-                                         ("up", (e, d, f)),
-                                         ("down", (e, f, d))))
+        leaves = (("gate", (e, d, f)), ("up", (e, d, f)),
+                  ("down", (e, f, d)))[not cfg.expert_gated:]
+        out = out_proj_init(cfg, in_axis=-2, out_axis=-1, batch_axis=(0,))
+        return tuple(
+            self.param(name, out.get("kernel_init", init)
+                       if name == "down" else init, shape, pdt).astype(dt)
+            for name, shape in leaves)
 
 
 class GatedBlock(nn.Module):
@@ -1719,6 +2024,24 @@ class GatedBlock(nn.Module):
                              dense(self.width, name="gate")(m)) \
             * dense(self.width, name="up")(m)
         return dense(cfg.hidden_size, name="down")(hidden)
+
+
+class UngatedBlock(nn.Module):
+    """``W_down act(W_up m)`` of one width on every token (``act``: the
+    square of ReLU): the shared expert of experts that are not gated.
+    Ordinary leaves ``.../{up,down}/kernel``."""
+    cfg: SparseLMConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, m: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=jnp.dtype(cfg.dtype),
+            param_dtype=jnp.dtype(cfg.param_dtype))
+        hidden = grouped.act(cfg.hidden_act, dense(self.width, name="up")(m))
+        return dense(cfg.hidden_size, name="down", **out_proj_init(cfg))(
+            hidden)
 
 
 class DenseFF(nn.Module):
@@ -1748,8 +2071,8 @@ class ExpertLayer(nn.Module):
                 pdt)
         self.experts = ExpertWeights(cfg)
         if cfg.num_shared_experts:
-            self.shared = GatedBlock(
-                cfg, cfg.num_shared_experts * cfg.expert_width)
+            block = GatedBlock if cfg.expert_gated else UngatedBlock
+            self.shared = block(cfg, cfg.shared_width)
 
     def route(self, a: jax.Array):
         """Top-k of the router's f32 scores of its normed input (the
@@ -1889,6 +2212,34 @@ class Layer(nn.Module):
         return h + y, counters
 
 
+class OnePartLayer(nn.Module):
+    """One layer of a configuration with ``one_part_layers``: ``x +
+    part(rmsnorm(x))``, the part its ``kind`` names and nothing else: a
+    state-space mixer under ``ssm``, a softmax attention under ``attn``, or
+    the expert feed-forward under ``ff`` (``experts``: the only kind with a
+    router and counters). One norm, ``norm``."""
+    cfg: SparseLMConfig
+    kind: str
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        cfg = self.cfg
+        a = rms_norm(x, self.param("norm", nn.initializers.ones,
+                                   (cfg.hidden_size,),
+                                   jnp.dtype(cfg.param_dtype)), cfg.rms_eps)
+        if self.kind == LAYER_EXPERTS:
+            ff = ExpertLayer(cfg, name="ff")
+            idx, p = ff.route(a)
+            # as ``Layer``: the experts every token chose, for whoever asks
+            self.sow("intermediates", "chosen", idx)
+            y, counters = ff(a, idx, p)
+            return x + y, counters
+        if self.kind == LAYER_MAMBA2:
+            return x + Mamba2Mixer(cfg, self.mesh, name="ssm")(a), None
+        return x + Attention(cfg, self.kind, self.mesh, name="attn")(a), None
+
+
 # ---------------------------------------------------------------------------
 # The model
 # ---------------------------------------------------------------------------
@@ -1997,7 +2348,8 @@ class SparseLM(nn.Module):
             x = x.astype(dt)
 
         layer_cls = nn.remat(
-            Layer, policy=jax.checkpoint_policies.save_only_these_names(
+            OnePartLayer if cfg.one_part_layers else Layer,
+            policy=jax.checkpoint_policies.save_only_these_names(
                 *KEPT_OF_A_LAYER))
         # the rows a configuration with ``mrope_section`` rotates by: an
         # input of the layer stack, made here from the two fields' lengths
@@ -2007,9 +2359,10 @@ class SparseLM(nn.Module):
                                     image_tokens.shape[1], cfg.image_grid),)
         counters = []
         for i in range(cfg.num_hidden_layers):
-            x, c = layer_cls(cfg, cfg.kind_of_layer(i), self.mesh,
-                             cfg.layer_is_dense(i), name=f"layer_{i}")(
-                                 x, *rows)
+            # a layer of two parts is told whether its second is dense
+            two = () if cfg.one_part_layers else (cfg.layer_is_dense(i),)
+            x, c = layer_cls(cfg, cfg.kind_of_layer(i), self.mesh, *two,
+                             name=f"layer_{i}")(x, *rows)
             if c is not None:
                 counters.append(c)
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
@@ -2189,8 +2542,10 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     tokens = cfg.total_seq_len
     layers = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
-    # the attention layers (a short convolution has ``conv_layout``)
-    kinds = [k for k in layers if k != LAYER_SHORT_CONV]
+    # the attention layers (a short convolution has ``conv_layout``, a
+    # state-space mixer ``ssm_layout``, a layer of experts alone neither)
+    kinds = [k for k in layers
+             if k not in (LAYER_SHORT_CONV, LAYER_MAMBA2, LAYER_EXPERTS)]
     calls = [(_blockwise_site(k), _blockwise_key(
         tokens, cfg.num_heads * cfg.head_dim,
         cfg.num_kv_heads * cfg.head_dim, tp)) for k in kinds]
@@ -2257,9 +2612,10 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
             break
     # the products between them: a traced call's form of the expert block
     block = lowering.recorded(PRODUCTS_SITE, _block_key(
-        cfg.hidden_size, cfg.expert_width, cfg.dtype))
+        cfg.hidden_size, cfg.expert_width, cfg.dtype, cfg.expert_gated))
     if block is not None:
-        sums += "; expert block: " + _block_words(block["why_not"])
+        sums += "; expert block: " + _block_words(block["why_not"],
+                                                  cfg.expert_gated)
     # the router's kind, and what stands beside the routed experts
     router = "softmax over the chosen"
     if cfg.score_func == "sigmoid":
@@ -2267,8 +2623,7 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
                   + ", norm" * cfg.route_norm + f", x{cfg.route_scale:g}")
     beside = ""
     if cfg.num_shared_experts:
-        beside += (f", a shared expert of "
-                   f"{cfg.num_shared_experts * cfg.expert_width}")
+        beside += f", a shared expert of {cfg.shared_width}"
     if cfg.num_dense_layers:
         beside += (f", layers 0-{cfg.num_dense_layers - 1} dense "
                    f"{cfg.dense_width}")
@@ -2315,8 +2670,10 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
                 f"sections {list(cfg.mrope_section)}"),
             "sparse_layout": (f"dense masks in XLA code ({why_not})"
                               if why_not else sparse_words(cfg.index_chunk))}
-    if len(kinds) < len(layers):
+    if LAYER_SHORT_CONV in layers:
         said["conv_layout"] = conv_layout(cfg)
+    if LAYER_MAMBA2 in layers:
+        said["ssm_layout"] = ssm_layout(cfg)
     if cfg.tied_embeddings:
         said["head_layout"] = (
             f"tied: the head is the embedding's table ({cfg.vocab_size} x "
@@ -2333,7 +2690,9 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     return {
         **said,
         "layer_loop": (f"unrolled: {len(layers)} layers, each "
-                       "rematerialised but its attention"),
+                       "rematerialised but its attention"
+                       + (", one part a layer behind one norm: "
+                          + " ".join(layers)) * cfg.one_part_layers),
         "moe_layout": (
             f"{cfg.experts_held} of {cfg.num_experts} experts held "
             f"({first}-{last}), top {cfg.experts_per_token} of "

@@ -37,6 +37,16 @@ gradient contracts; :func:`rows_grad` sums ``dg W_gate.T + du W_up.T`` in
 f32 and rounds once (two buffers, each rounded, then added and rounded
 again, before). :func:`weights_grad` is the third product alone.
 
+**An expert that is not gated** (``hidden = act(x W_up)``, ``act`` the
+square of ReLU: two products a direction and one weight block a kernel) has
+the same two tile kernels with one operand fewer, :func:`hidden` and
+:func:`hidden_grads` (``du = 2 relu(u) (dy W_down.T)``); its rows' gradient
+is :func:`grouped_matmul` with ``transpose_w``, nothing to sum. **A width
+that is no whole number of lane tiles** (1 856 = 14.5 x 128) goes through
+every kernel here as it is: each block spans its array's whole minor
+dimension, which Mosaic lays out in whole tiles with the last one's upper
+lanes masked, so no leaf and no buffer is padded by this code.
+
 What each form costs on the v5e (my chip run, PR 50:
 ``scripts/grouped_probe.py``, seed 500001, 8 held experts, bf16, a router
 that favours none; microseconds a call, in brackets the active tiles'
@@ -113,10 +123,24 @@ def tile_plan(sizes: jax.Array, n_tiles: int, tile: int = TILE):
 
 
 def act(name: str, g: jax.Array) -> jax.Array:
-    """The gate's activation (``cfg.hidden_act``), in g's dtype."""
+    """The gate's activation (``cfg.hidden_act``), in g's dtype; ``relu2``
+    is the square of ReLU, what stands between the two products of an
+    expert that is not gated."""
     if name == "relu":
         return jax.nn.relu(g)
+    if name == "relu2":
+        r = jax.nn.relu(g)
+        return r * r
     return jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype)
+
+
+def act_cotangent(name: str, u: jax.Array, dhidden: jax.Array) -> jax.Array:
+    """The cotangent of ``u`` in ``hidden = act(u)``, for the activations
+    an ungated expert has (``relu2``: ``2 relu(u) dhidden``)."""
+    if name != "relu2":
+        raise ValueError(f"no ungated expert has the activation {name!r}")
+    r = jax.nn.relu(u)
+    return dhidden * (r + r)
 
 
 def gate_cotangent(name: str, g: jax.Array, u: jax.Array,
@@ -130,13 +154,27 @@ def gate_cotangent(name: str, g: jax.Array, u: jax.Array,
     return (dhidden * u * (sig * (1.0 + g32 * (1.0 - sig)))).astype(g.dtype)
 
 
-def block_why_not(dim: int, width: int, dtype) -> Optional[str]:
+def block_why_not(dim: int, width: int, dtype,
+                  gated: bool = True) -> Optional[str]:
     """Why the expert block's kernels cannot take experts of ``dim`` x
     ``width`` in ``dtype``; None where an expert's two weight blocks (two
     buffers each), a grid step's tiles (two buffers each) and its f32
-    intermediates fit VMEM. The largest of the three kernels decides."""
+    intermediates fit VMEM. The largest of the three kernels decides. An
+    expert that is not ``gated`` has one weight block a kernel
+    (:func:`hidden`, :func:`hidden_grads`)."""
     size = jnp.dtype(dtype).itemsize
     weights, rows, hidden = dim * width * size, TILE * dim, TILE * width
+    if not gated:
+        need = max(
+            # hidden: x; u, act(u); the product in f32 and a temporary
+            2 * weights + 2 * size * (rows + 2 * hidden) + 2 * 4 * hidden,
+            # hidden_grads: dy, u; du, act(u); dhidden and a temporary
+            2 * weights + 2 * size * (rows + 3 * hidden) + 3 * 4 * hidden)
+        if need > _VMEM:
+            return (f"a block of {dim} x {width} and the tiles need "
+                    f"{need / 2 ** 20:.1f} MiB of VMEM, over "
+                    f"{_VMEM / 2 ** 20:g}")
+        return None
     need = max(
         # gated_hidden: x; g, u, act(g) * u; both products in f32 and a
         # temporary of the activation's
@@ -212,6 +250,26 @@ def _gated_hidden_grads_kernel(expert_ref, active_ref, first_ref, last_ref,
         dg_ref[...] = gate_cotangent(name, g, u, dhidden)
         du_ref[...] = dhidden * hidden
         hidden_ref[...] = hidden * u
+
+
+def _hidden_kernel(expert_ref, active_ref, first_ref, last_ref, block_ref,
+                   x_ref, up_ref, u_ref, hidden_ref, *, name: str):
+    @pl.when(active_ref[pl.program_id(0)] == 1)
+    def _():
+        u = _dot(x_ref[...], up_ref[0]).astype(u_ref.dtype)
+        u_ref[...] = u
+        hidden_ref[...] = act(name, u)
+
+
+def _hidden_grads_kernel(expert_ref, active_ref, first_ref, last_ref,
+                         block_ref, dy_ref, down_ref, u_ref, du_ref,
+                         hidden_ref, *, name: str):
+    @pl.when(active_ref[pl.program_id(0)] == 1)
+    def _():
+        u = u_ref[...]
+        dhidden = _dot(dy_ref[...], down_ref[0], True).astype(u.dtype)
+        du_ref[...] = act_cotangent(name, u, dhidden)
+        hidden_ref[...] = act(name, u)
 
 
 def _rows_grad_kernel(expert_ref, active_ref, first_ref, last_ref,
@@ -301,6 +359,28 @@ def gated_hidden_grads(dy, down, g, u, tiles: Tiles, name: str,
     return _over_tiles(
         functools.partial(_gated_hidden_grads_kernel, name=name), tiles,
         (dy, down, g, u), (like,) * 3, tile=tile, interpret=interpret)
+
+
+def hidden(x, up, tiles: Tiles, name: str, tile: int = TILE,
+           interpret: bool = False):
+    """``(u, act(u))``, each (rows, F), of an expert that is not gated:
+    ``u`` the grouped product of ``x`` with ``up``, rounded to ``x``'s dtype
+    before the activation ``name`` reads it."""
+    like = jax.ShapeDtypeStruct((x.shape[0], up.shape[2]), x.dtype)
+    return _over_tiles(
+        functools.partial(_hidden_kernel, name=name), tiles, (x, up),
+        (like,) * 2, tile=tile, interpret=interpret)
+
+
+def hidden_grads(dy, down, u, tiles: Tiles, name: str, tile: int = TILE,
+                 interpret: bool = False):
+    """``(du, act(u))`` for the cotangent ``dy`` (rows, D) of ``act(u) @
+    down``: the rows' gradient ``dy @ down.T`` is rounded to ``u``'s dtype
+    and goes no further than the tile."""
+    like = jax.ShapeDtypeStruct(u.shape, u.dtype)
+    return _over_tiles(
+        functools.partial(_hidden_grads_kernel, name=name), tiles,
+        (dy, down, u), (like,) * 2, tile=tile, interpret=interpret)
 
 
 def rows_grad(dg, du, gate, up, tiles: Tiles, tile: int = TILE,

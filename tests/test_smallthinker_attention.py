@@ -92,6 +92,8 @@ def _rel(got, want):
     # the sizes the kernels ship with, tiles of 512 in sub-tiles of 256: a
     # window off both, ragged
     (1100, 1, 1, 700, K.BLOCK, K.SUB_BLOCK, jnp.float32),
+    # 16 query heads a key-value tile (32 over 2, ``twotower30b``'s), ragged
+    (300, 16, 2, None, 128, 128, jnp.float32),
 ])
 def test_blockwise_attention_matches_dense(t, group, kv_heads, window, block,
                                            sub, dtype, kernels_called,
@@ -142,6 +144,8 @@ def test_blockwise_attention_matches_dense(t, group, kv_heads, window, block,
     (700, 1, 100, 256, 128, jnp.float32, 1e-6),
     # the sizes the kernels ship with
     (1100, 1, 700, K.BLOCK, K.SUB_BLOCK, jnp.float32, 1e-6),
+    # 16 query heads a key-value tile
+    (300, 16, None, 128, 128, jnp.float32, 1e-6),
 ])
 def test_the_split_backward_agrees_with_the_fused(t, group, window, block,
                                                   sub, dtype, limit,
@@ -171,6 +175,7 @@ def test_the_split_backward_agrees_with_the_fused(t, group, window, block,
     (8192, 7, 2, True),         # smallthinker21b's layers
     (8192, 8, 2, True),         # trinitymini's
     (8192, 8, 4, True),
+    (8192, 16, 2, True),        # twotower30b's: 16 query heads a tile
     (8000, 8, 2, True),         # judged at the padded length
     (25600, 8, 2, True),        # the longest the compiler takes as well
     (26112, 8, 2, False),

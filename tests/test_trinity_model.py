@@ -14,7 +14,8 @@ import pytest
 
 from benchmark.manifest import Manifest
 from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
-from dalle_tpu.config import (AfmoeLMConfig, JoyAILMConfig, SparseLMConfig,
+from dalle_tpu.config import (AfmoeLMConfig, JoyAILMConfig,
+                              NemotronHLMConfig, SparseLMConfig,
                               trinitymini_model_config)
 from dalle_tpu.models import attention, family, sparse_lm
 from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
@@ -35,6 +36,7 @@ TINY = dict(hidden_size=64, num_hidden_layers=5, num_heads=4, num_kv_heads=2,
 # yardstick, and the widths of its latent attention; its own tests are
 # tests/test_joyai_model.py, the cases below the ones both share
 YJ = Manifest().yardstick("joyai")
+YN = Manifest().yardstick("nemotronh")
 JOYAI_TINY = dict(
     {k: v for k, v in TINY.items() if k not in ("head_dim", "window")},
     num_hidden_layers=2, num_kv_heads=4, q_lora_rank=48, kv_lora_rank=32,
@@ -423,19 +425,33 @@ def test_a_bias_changes_the_chosen_set_and_not_the_weights():
 
 @pytest.mark.parametrize("config, kernels", [
     ("trinitymini", False), ("trinitymini", True),
-    ("joyaiflash", False), ("joyaiflash", True)])
+    ("joyaiflash", False), ("joyaiflash", True),
+    ("twotower30b", False), ("twotower30b", True)])
 def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
         config, kernels, monkeypatch):
     """``trinitymini``: 8 experts over 4 shares of 2 (``expert_offset`` 0,
     2, 4, 6); ``joyaiflash``: its own 32 shares of 8 consecutive experts
     (``8r .. 8r + 7``), 256 in all, top 8, at a small width (8 shares of
-    64 where the kernels run interpreted). Every share's
+    64 where the kernels run interpreted); ``twotower30b``: its own 16
+    shares of 8 consecutive experts, 128 in all, top 6, two-product experts
+    (no gate) beside a shared expert of a width of its own (2 shares of 16,
+    of a width that ends in half a lane tile, where the kernels run
+    interpreted). Every share's
     layer returns its routed part plus the shared expert, which all compute
     alike; the routed parts summed plus the shared expert counted once
     equal the reference's uncut layer."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", kernels)
     if config == "trinitymini":
         base, y = AfmoeLMConfig(**dict(TINY, experts_held=2)), Y
+    elif config == "twotower30b":
+        small = {k: TINY[k] for k in (
+            "vocab_size", "text_seq_len", "image_grid", "vocab_text",
+            "vocab_image", "dtype", "head_chunk")}
+        base, y = NemotronHLMConfig(**dict(
+            small, hidden_size=128 if kernels else 64,
+            expert_width=192 if kernels else 32, shared_expert_width=96,
+            num_experts=16 if kernels else 128, experts_held=8,
+            expert_offset=0, experts_per_token=6)), YN
     else:       # interpreted, 8 shares of 64 experts: a share costs 3 s
         base, y = JoyAILMConfig(**dict(
             JOYAI_TINY, num_experts=64 if kernels else 256, experts_held=8,
@@ -443,7 +459,7 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
     n, held = base.num_experts, base.experts_held
     shares = n // held
     rng = jax.random.split(jax.random.PRNGKey(3), 9)
-    d, f = base.hidden_size, base.expert_width
+    d, f, fs = base.hidden_size, base.expert_width, base.shared_width
     m = jax.random.normal(rng[0], (2, 28, d))
     kernel = lambda key, shape: {"kernel": jax.random.normal(key, shape)
                                  * 0.2}
@@ -452,11 +468,14 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
              "experts": {"gate": jax.random.normal(rng[3], (n, d, f)) * 0.2,
                          "up": jax.random.normal(rng[4], (n, d, f)) * 0.2,
                          "down": jax.random.normal(rng[5], (n, f, d)) * 0.2},
-             "shared": {"gate": kernel(rng[6], (d, f)),
-                        "up": kernel(rng[7], (d, f)),
-                        "down": kernel(rng[8], (f, d))}}
+             "shared": {"gate": kernel(rng[6], (d, fs)),
+                        "up": kernel(rng[7], (d, fs)),
+                        "down": kernel(rng[8], (fs, d))}}
+    block = y.gated_block if base.expert_gated else y.ungated_block
+    if not base.expert_gated:       # two leaves an expert, two a block
+        del whole["experts"]["gate"], whole["shared"]["gate"]
     want = y.whole_layer_experts(m, whole, as_file(base))
-    shared = y.gated_block(m, whole["shared"])
+    shared = block(m, whole["shared"])
     assert float(jnp.abs(shared).max()) > 0.01
 
     routed, here = jnp.zeros_like(m), 0.0
