@@ -167,20 +167,28 @@ Nemotron-Labs-TwoTower-30B-A3B-Base-BF16, nvidia, ``model_type``
       two products an expert, NOT gated (``expert_gated`` false,
       ``hidden_act`` ``relu2``; :class:`UngatedBlock` is the shared expert)
 
-**How the recurrence runs** (:func:`chunked_scan`, the site "ssm scan";
-``ssm_layout`` on the ``setup/warmup`` row says it): in chunks of
-``ssm_chunk`` tokens. Inside a chunk the masked (chunk x chunk) form a
-head, ``y_i += sum_{j<=i} exp(cs_i - cs_j) (C_i . B_j) D_j x_j`` with ``cs``
-the running sum of ``D A`` inside the chunk; across chunks the carried (P,
-N) state, ``S_c = exp(cs_last) S_{c-1} + sum_j exp(cs_last - cs_j) D_j x_j
-B_j^T``, and ``y_i += exp(cs_i) C_i . S_{c-1}``. ``D``, the decays, their
-running sums and the states are f32; the products' operands are in
-``cfg.dtype`` with f32 accumulation. Nothing of (T, T) and no state a token
-exists, forward, replay or backward (plain differentiation of the chunked
-form under the layer's rematerialisation). All of it XLA code: scopes
-``ssm/in_proj``, ``ssm/conv`` (taps, bias, SiLU), ``ssm/scan`` (``D``, the
-decays, both forms, ``D x``), ``ssm/gate_norm``, ``ssm/out_proj`` (never
-under ``attn`` or ``conv``).
+**How the recurrence runs** (the site "ssm scan", :func:`ssm_scan`;
+``ssm_layout`` on the ``setup/warmup`` row says which lowering ran): in
+chunks of ``ssm_chunk`` tokens. Inside a chunk the masked (chunk x chunk)
+form a head, ``y_i += sum_{j<=i} exp(cs_i - cs_j) (C_i . B_j) D_j x_j`` with
+``cs`` the running sum of ``D A`` inside the chunk; across chunks the
+carried (P, N) state, ``S_c = exp(cs_last) S_{c-1} + sum_j exp(cs_last -
+cs_j) D_j x_j B_j^T``, and ``y_i += exp(cs_i) C_i . S_{c-1}``. ``D``, the
+decays, their running sums and the states are f32; the products' operands
+are in ``cfg.dtype`` with f32 accumulation. Nothing of (T, T) and no state a
+token exists, forward, replay or backward. Where the local shapes are lane
+tiles (whole chunks of whole tiles, a state of whole tiles, a group's heads
+whole tiles: ``ssm_scan_kernels.fits``) it runs as a forward and a backward
+Mosaic kernel (ops/pallas/ssm_scan_kernels.py: a chunk's form is made, used
+and dropped in VMEM and the states are carried there; the layer's replay is
+the forward call that keeps the state each chunk starts from, which the
+backward kernel walks in reverse); anywhere else (no Mosaic backend, a
+ragged tail, the tests' tiny widths) as XLA code, :func:`chunked_scan`,
+whose backward is plain differentiation of the chunked form. Scopes
+``ssm/in_proj``, ``ssm/conv`` (taps, bias, SiLU: XLA code), ``ssm/scan``
+(``D``; then the decays, both forms and ``D x``: ``scan[mosaic]``, or XLA
+code), ``ssm/gate_norm`` (XLA code), ``ssm/out_proj`` (never under ``attn``
+or ``conv``).
 
 **The expert layer is told which experts it holds** (``experts_held``
 consecutive ones from ``expert_offset``): it routes over all
@@ -327,6 +335,7 @@ from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
 from dalle_tpu.ops.pallas import head_norm_kernels as head_norm
 from dalle_tpu.ops.pallas import indexer_kernels as index_kernels
 from dalle_tpu.ops.pallas import lowering
+from dalle_tpu.ops.pallas import ssm_scan_kernels
 from dalle_tpu.ops.pallas import token_sum_kernels as token_sum
 from dalle_tpu.parallel.mesh import LANES_SPEC, sum_over_manual_data_axes
 
@@ -1349,9 +1358,9 @@ def gated_group_norm(y, z, scale, groups: int, eps: float) -> jax.Array:
 
 
 SCAN_SITE = "ssm scan"
-# the site's one answer so far: which lowering the scan should be is the
-# trace's to say (PERF.md section 5), and the record keeps the question
-NO_SCAN_KERNEL = "no Mosaic kernel of the chunked scan yet"
+# the backward's kind, a fact of the site's record
+SCAN_BACKWARD = ("one kernel, the chunks in reverse from the states the "
+                 "forward kept")
 
 
 def _scan_key(tokens: int, cfg: SparseLMConfig):
@@ -1363,17 +1372,34 @@ def _scan_key(tokens: int, cfg: SparseLMConfig):
 
 def ssm_scan(x, bm, cm, dt, a, d, *, mesh, cfg: SparseLMConfig,
              scope: Optional[str] = None):
-    """:func:`chunked_scan` as a call site: a shard's samples, every head
-    (no mesh axis splits the mixer's lanes)."""
-    def fits(x, bm, cm, dt, a, d) -> bool:
-        return lowering.chose(SCAN_SITE, _scan_key(x.shape[1], cfg),
-                              NO_SCAN_KERNEL, NO_SCAN_KERNEL)
+    """The chunked scan as a call site: a shard's samples, every head (no
+    mesh axis splits the mixer's lanes). The kernels of
+    ops/pallas/ssm_scan_kernels.py where their predicate takes the local
+    shapes, else :func:`chunked_scan`."""
+    sizes = dict(heads=cfg.mamba_num_heads, groups=cfg.ssm_groups,
+                 chunk=cfg.ssm_chunk)
 
-    # no kernel yet: the site's two lowerings are the one XLA code
-    xla = functools.partial(chunked_scan, heads=cfg.mamba_num_heads,
-                            groups=cfg.ssm_groups, chunk=cfg.ssm_chunk)
+    def fits(x, bm, cm, dt, a, d) -> bool:
+        tokens = x.shape[1]
+        key = _scan_key(tokens, cfg)
+        why_not = ssm_scan_kernels.fits(*key, x.dtype.itemsize)
+        if why_not is not None:
+            return lowering.chose(SCAN_SITE, key, why_not, why_not)
+        r = cfg.mamba_num_heads // cfg.ssm_groups
+        k = ssm_scan_kernels.chunks_a_step(
+            tokens // cfg.ssm_chunk, cfg.ssm_chunk, r, cfg.mamba_head_dim,
+            cfg.ssm_state_size, x.dtype.itemsize)
+        return lowering.chose(
+            SCAN_SITE, key, None,
+            f"local x{x.shape} in chunks of {cfg.ssm_chunk}, {k} a grid "
+            f"step, {r} heads a group",
+            chunks_a_step=k, backward=SCAN_BACKWARD)
+
+    kernel = functools.partial(ssm_scan_kernels.scan, **sizes,
+                               interpret=lowering.interpret())
+    xla = functools.partial(chunked_scan, **sizes)
     lanes = P(*LANES_SPEC[:2], None)
-    return lowering.site(SCAN_SITE, fits, xla, xla, mesh,
+    return lowering.site(SCAN_SITE, fits, kernel, xla, mesh,
                          (lanes,) * 4 + (P(), P()), lanes, scope)(
                              x, bm, cm, dt, a, d)
 
@@ -1449,7 +1475,18 @@ def ssm_layout(cfg: SparseLMConfig) -> str:
     kinds = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
     tokens, chunk = cfg.total_seq_len, cfg.ssm_chunk
     said = lowering.recorded(SCAN_SITE, _scan_key(tokens, cfg))
-    how = "none traced" if said is None else said["why_not"]
+    if not lowering.mosaic():
+        said = {"why_not": lowering.NO_BACKEND}
+    if said is None or said["why_not"] is not None:
+        why = lowering.NONE_TRACED if said is None else said["why_not"]
+        scan = (f"ssm/scan is XLA code ({why}), its backward plain "
+                "differentiation of the chunked form")
+    else:
+        scan = (f"ssm/scan is a pair of Pallas kernels "
+                f"({said['chunks_a_step']} chunks a grid step, a chunk's "
+                "form and the carried states in VMEM; backward: "
+                f"{said['backward']}), the decays' running sums in the "
+                "kernels, the step sizes around them XLA code")
     return (
         f"Mamba-2 mixer: {kinds.count(LAYER_MAMBA2)} of {len(kinds)} layers, "
         f"{cfg.mamba_num_heads} heads x {cfg.mamba_head_dim}, "
@@ -1460,9 +1497,8 @@ def ssm_layout(cfg: SparseLMConfig) -> str:
         f"{chunk}) form a head, across chunks the carried "
         f"({cfg.mamba_head_dim} x {cfg.ssm_state_size}) state, the decays, "
         "their sums and the states in f32; no (T, T) array and no state a "
-        f"token; ssm/conv, ssm/scan and ssm/gate_norm are XLA code ({how}); "
-        "the replay keeps nothing of the mixer but the layer's input, the "
-        "backward is plain differentiation of the chunked form")
+        f"token; {scan}; ssm/conv and ssm/gate_norm are XLA code; the "
+        "replay keeps nothing of the mixer but the layer's input")
 
 
 # ---------------------------------------------------------------------------

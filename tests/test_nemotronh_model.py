@@ -147,14 +147,18 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(
     assert lowering_record.why_not(*call) == shut
     scan = sparse_lm.SCAN_SITE, sparse_lm._scan_key(43, cfg)
     assert scan[1] == (43, 4, 8, 2, 16, 8)
+    # the tiny mixer's shapes are none the scan's kernels take
+    refusal = "43 tokens are not whole chunks of 8"
     assert lowering_record.why_not(*scan) == (
-        sparse_lm.NO_SCAN_KERNEL if with_kernels else shut)
+        refusal if with_kernels else shut)
     said = sparse_lm.engagement_records(cfg)
     assert said["ssm_layout"].startswith(
         "Mamba-2 mixer: 1 of 4 layers, 4 heads x 8, 2 groups of B and C, "
         "state 16, 4 taps with a bias over 96 lanes; chunked scan: chunks "
         "of 8, 6 a sequence of 43")
     assert "no (T, T) array and no state a token" in said["ssm_layout"]
+    assert f"ssm/scan is XLA code ({refusal if with_kernels else shut})" \
+        in said["ssm_layout"]
     assert "conv_layout" not in said
     assert said["layer_loop"].endswith(
         "one part a layer behind one norm: mamba2 experts full_nope experts")
@@ -168,6 +172,46 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(
             "expert block: " + sparse_lm.UNGATED_ON_THE_TILE)
         assert sparse_lm.UNGATED_ON_THE_TILE.startswith(
             "two products an expert, not gated")
+
+
+# a mixer the scan's kernels take: heads of half a lane tile, a state and a
+# chunk of one, a sequence of two chunks
+SCAN_WIDTHS = dict(mamba_num_heads=4, mamba_head_dim=64, ssm_groups=2,
+                   ssm_state_size=128, ssm_chunk=128, text_seq_len=240,
+                   num_hidden_layers=3,
+                   layer_kinds=("mamba2", "experts", "mamba2"))
+
+
+def test_a_mixer_of_lane_tiles_takes_the_scans_kernels(monkeypatch,
+                                                       lowering_record):
+    """Two mixer layers (an expert layer between) whose scan runs the
+    kernel pair, interpreted, under the layers' rematerialisation: loss and
+    every gradient leaf against the yardstick at the limits of the XLA
+    lowering, and ``ssm_layout`` says which lowering ran."""
+    cfg = NemotronHLMConfig(**dict(TINY, **SCAN_WIDTHS))
+    cfg.validate()
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    params = _params(cfg)
+    text, image = _batch(cfg)
+    (loss, _), grads = _system(cfg, params, text, image)
+    ref_loss, ref_grads = Y.loss_and_grads(params, text, image, as_file(cfg))
+    assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree.leaves(ref_grads)):
+        assert rel_l2(g, r) < 2e-5, jax.tree_util.keystr(path)
+    key = sparse_lm._scan_key(256, cfg)
+    assert lowering_record.recorded(sparse_lm.SCAN_SITE, key) == {
+        "why_not": None, "chunks_a_step": 2,
+        "backward": sparse_lm.SCAN_BACKWARD}
+    layout = sparse_lm.engagement_records(cfg)["ssm_layout"]
+    assert layout.startswith(
+        "Mamba-2 mixer: 2 of 3 layers, 4 heads x 64, 2 groups of B and C, "
+        "state 128, 4 taps with a bias over 768 lanes; chunked scan: chunks "
+        "of 128, 2 a sequence of 256")
+    assert "ssm/scan is a pair of Pallas kernels (2 chunks a grid step" \
+        in layout
+    assert "backward: one kernel, the chunks in reverse" in layout
+    assert "ssm/conv and ssm/gate_norm are XLA code" in layout
 
 
 @pytest.mark.parametrize("tokens, chunk", [
