@@ -184,10 +184,29 @@ and dropped in VMEM and the states are carried there; the layer's replay is
 the forward call that keeps the state each chunk starts from, which the
 backward kernel walks in reverse); anywhere else (no Mosaic backend, a
 ragged tail, the tests' tiny widths) as XLA code, :func:`chunked_scan`,
-whose backward is plain differentiation of the chunked form. Scopes
-``ssm/in_proj``, ``ssm/conv`` (taps, bias, SiLU: XLA code), ``ssm/scan``
-(``D``; then the decays, both forms and ``D x``: ``scan[mosaic]``, or XLA
-code), ``ssm/gate_norm`` (XLA code), ``ssm/out_proj`` (never under ``attn``
+whose backward is plain differentiation of the chunked form.
+
+**The way into and out of the scan is a pass a direction** (the sites "ssm
+taps" and "ssm gate norm", :func:`ssm_taps` and :func:`ssm_gate_norm`;
+ops/pallas/ssm_pass_kernels.py): between ``in_proj`` and ``out_proj`` nothing
+is sliced out of ``in_proj``'s (B, T, 2 H P + 2 G N + H) output and no (T, W)
+f32 array reaches HBM. The taps pass reads the ``xBC`` columns where the
+projection wrote them, with the K - 1 tokens before a tile as a small second
+block, and writes ``x``, ``B`` and ``C`` as three arrays, the scan's
+operands; its backward makes the pre-activation again from the raw columns
+and sums ``d taps`` and ``d bias`` in f32. The gate-and-norm pass reads the
+scan's ``y`` and the ``z`` columns in place; a group's sum over its lanes is
+a product with ones. Each pass's cotangent of ``in_proj``'s output is its own
+columns padded with noughts, which XLA adds up inside the projection's
+backward products. Where a predicate refuses (``ssm_pass_kernels.taps_fit`` /
+``gate_norm_fit``: parts or groups of no whole lane tiles, a part that is no
+column block of ``in_proj``'s output, tokens of no whole sublane tiles; no
+Mosaic backend) the stage is XLA code, :func:`causal_taps_silu` /
+:func:`gated_group_norm` on slices. Scopes ``ssm/in_proj``, ``ssm/conv``
+(``taps[mosaic]``, or XLA code), ``ssm/scan`` (``D``; then the decays, both
+forms and ``D x``: ``scan[mosaic]``, or XLA code; the softplus of the step
+sizes and the rows' transposes are XLA code either way), ``ssm/gate_norm``
+(``gate_norm[mosaic]``, or XLA code), ``ssm/out_proj`` (never under ``attn``
 or ``conv``).
 
 **The expert layer is told which experts it holds** (``experts_held``
@@ -335,6 +354,7 @@ from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
 from dalle_tpu.ops.pallas import head_norm_kernels as head_norm
 from dalle_tpu.ops.pallas import indexer_kernels as index_kernels
 from dalle_tpu.ops.pallas import lowering
+from dalle_tpu.ops.pallas import ssm_pass_kernels
 from dalle_tpu.ops.pallas import ssm_scan_kernels
 from dalle_tpu.ops.pallas import token_sum_kernels as token_sum
 from dalle_tpu.parallel.mesh import LANES_SPEC, sum_over_manual_data_axes
@@ -1404,6 +1424,94 @@ def ssm_scan(x, bm, cm, dt, a, d, *, mesh, cfg: SparseLMConfig,
                              x, bm, cm, dt, a, d)
 
 
+TAPS_SITE = "ssm taps"
+GATE_NORM_SITE = "ssm gate norm"
+
+
+def _taps_key(tokens: int, cfg: SparseLMConfig):
+    """What the record knows a taps pass by: a sample's tokens, where
+    ``xBC`` lies in ``in_proj``'s output, its parts' widths, the taps and
+    the operands' bytes a number."""
+    state = cfg.ssm_groups * cfg.ssm_state_size
+    return (tokens, cfg.mamba_inner, (cfg.mamba_inner, state, state),
+            cfg.conv_kernel, jnp.dtype(cfg.dtype).itemsize)
+
+
+def _gate_norm_key(tokens: int, cfg: SparseLMConfig):
+    return (tokens, cfg.mamba_inner, cfg.ssm_groups,
+            jnp.dtype(cfg.dtype).itemsize)
+
+
+def ssm_taps(zxbcdt, taps, bias, *, mesh, cfg: SparseLMConfig,
+             scope: Optional[str] = None):
+    """The taps, the bias and the SiLU as a call site: ``x``, ``B`` and
+    ``C`` of ``in_proj``'s whole output (B, T, .). The pass of
+    ops/pallas/ssm_pass_kernels.py, which reads ``xBC``'s columns where
+    they lie and writes the three apart, where its predicate takes the local
+    shapes; else :func:`causal_taps_silu` on a slice, and three slices of
+    its result."""
+    inner = cfg.mamba_inner
+    state = cfg.ssm_groups * cfg.ssm_state_size
+
+    def fits(zxbcdt, taps, bias) -> bool:
+        key = _taps_key(zxbcdt.shape[1], cfg)
+        why_not = ssm_pass_kernels.taps_fit(*key)
+        return lowering.chose(
+            TAPS_SITE, key, why_not, why_not or (
+                f"local xBC{zxbcdt.shape[:2] + (cfg.mamba_conv_lanes,)} "
+                f"read at lane {inner} of {zxbcdt.shape[2]}, "
+                f"{ssm_pass_kernels.rows_tile(key[0], key[-1])} tokens a "
+                "grid step"))
+
+    def kernel(zxbcdt, taps, bias):
+        f32 = jnp.float32
+        return ssm_pass_kernels.taps_silu(
+            zxbcdt, taps.astype(f32), bias.astype(f32), inner,
+            (inner, state, state), lowering.interpret())
+
+    def xla(zxbcdt, taps, bias):
+        xbc = causal_taps_silu(
+            zxbcdt[..., inner:inner + cfg.mamba_conv_lanes], taps, bias)
+        return (xbc[..., :inner], xbc[..., inner:inner + state],
+                xbc[..., inner + state:])
+
+    lanes = P(*LANES_SPEC[:2], None)
+    return lowering.site(TAPS_SITE, fits, kernel, xla, mesh,
+                         (lanes, P(), P()), (lanes,) * 3, scope)(
+                             zxbcdt, taps, bias)
+
+
+def ssm_gate_norm(y, zxbcdt, scale, *, mesh, cfg: SparseLMConfig,
+                  scope: Optional[str] = None):
+    """The gate and the group norm as a call site: of the scan's ``y`` and
+    ``in_proj``'s whole output, whose first lanes are ``z``. The pass of
+    ops/pallas/ssm_pass_kernels.py where its predicate takes the local
+    shapes, else :func:`gated_group_norm` on a slice."""
+    def fits(y, zxbcdt, scale) -> bool:
+        key = _gate_norm_key(y.shape[1], cfg)
+        why_not = ssm_pass_kernels.gate_norm_fit(*key)
+        return lowering.chose(
+            GATE_NORM_SITE, key, why_not, why_not or (
+                f"local y{y.shape} and z read at lane 0 of "
+                f"{zxbcdt.shape[2]}, {cfg.ssm_groups} groups of "
+                f"{y.shape[2] // cfg.ssm_groups} lanes, "
+                f"{ssm_pass_kernels.rows_tile(key[0], key[-1])} tokens a "
+                "grid step"))
+
+    def kernel(y, zxbcdt, scale):
+        return ssm_pass_kernels.gate_norm(
+            y, zxbcdt, scale.astype(jnp.float32), cfg.ssm_groups,
+            cfg.rms_eps, lowering.interpret())
+
+    def xla(y, zxbcdt, scale):
+        return gated_group_norm(y, zxbcdt[..., :y.shape[2]], scale,
+                                cfg.ssm_groups, cfg.rms_eps)
+
+    lanes = P(*LANES_SPEC[:2], None)
+    return lowering.site(GATE_NORM_SITE, fits, kernel, xla, mesh,
+                         (lanes, lanes, P()), lanes, scope)(y, zxbcdt, scale)
+
+
 # what the source's keys time_step_min, time_step_max and time_step_floor
 # are for: ``dt_bias`` at init is the inverse softplus of a step drawn
 # log-uniformly between the first two and held above the third. The
@@ -1441,7 +1549,6 @@ class Mamba2Mixer(nn.Module):
                                   dtype=jnp.dtype(cfg.dtype), param_dtype=pdt)
         heads, inner = cfg.mamba_num_heads, cfg.mamba_inner
         lanes = cfg.mamba_conv_lanes
-        state = cfg.ssm_groups * cfg.ssm_state_size
         zxbcdt = dense(inner + lanes + heads, name="in_proj")(a)
         taps = self.param(
             "taps", nn.initializers.variance_scaling(
@@ -1453,40 +1560,47 @@ class Mamba2Mixer(nn.Module):
         skip = self.param("D", nn.initializers.ones, (heads,), pdt)
         scale = self.param("norm", nn.initializers.ones, (inner,), pdt)
         with jax.named_scope("conv"):
-            xbc = causal_taps_silu(zxbcdt[..., inner:inner + lanes], taps,
-                                   bias)
+            x, bm, cm = ssm_taps(zxbcdt, taps, bias, mesh=self.mesh, cfg=cfg,
+                                 scope="conv")
         with jax.named_scope("scan"):
             f32 = jnp.float32
             dt = jax.nn.softplus(zxbcdt[..., inner + lanes:].astype(f32)
                                  + dt_bias.astype(f32))
-            y = ssm_scan(xbc[..., :inner], xbc[..., inner:inner + state],
-                         xbc[..., inner + state:], dt,
-                         -jnp.exp(a_log.astype(f32)), skip.astype(f32),
-                         mesh=self.mesh, cfg=cfg, scope="scan")
+            y = ssm_scan(x, bm, cm, dt, -jnp.exp(a_log.astype(f32)),
+                         skip.astype(f32), mesh=self.mesh, cfg=cfg,
+                         scope="scan")
         with jax.named_scope("gate_norm"):
-            y = gated_group_norm(y, zxbcdt[..., :inner], scale,
-                                 cfg.ssm_groups, cfg.rms_eps)
+            y = ssm_gate_norm(y, zxbcdt, scale, mesh=self.mesh, cfg=cfg,
+                              scope="gate_norm")
         return dense(cfg.hidden_size, name="out_proj", **out_proj_init(cfg))(y)
 
 
 def ssm_layout(cfg: SparseLMConfig) -> str:
     """The ``setup/warmup`` row's ``ssm_layout``: the configuration's
-    sizes, and of the scan's site what its traced calls said."""
+    sizes, and of the mixer's three sites what their traced calls said."""
     kinds = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
     tokens, chunk = cfg.total_seq_len, cfg.ssm_chunk
-    said = lowering.recorded(SCAN_SITE, _scan_key(tokens, cfg))
-    if not lowering.mosaic():
-        said = {"why_not": lowering.NO_BACKEND}
-    if said is None or said["why_not"] is not None:
-        why = lowering.NONE_TRACED if said is None else said["why_not"]
+    scan_key = _scan_key(tokens, cfg)
+    why = lowering.why_not(SCAN_SITE, scan_key)
+    if why is not None:
         scan = (f"ssm/scan is XLA code ({why}), its backward plain "
                 "differentiation of the chunked form")
     else:
+        said = lowering.recorded(SCAN_SITE, scan_key)
         scan = (f"ssm/scan is a pair of Pallas kernels "
                 f"({said['chunks_a_step']} chunks a grid step, a chunk's "
                 "form and the carried states in VMEM; backward: "
                 f"{said['backward']}), the decays' running sums in the "
                 "kernels, the step sizes around them XLA code")
+
+    def stage(site, key, one_pass):
+        why = lowering.why_not(site, key)
+        return one_pass if why is None else f"XLA code ({why})"
+
+    taps = stage(TAPS_SITE, _taps_key(tokens, cfg),
+                 "one pass a direction, x, B and C written apart")
+    gate_norm = stage(GATE_NORM_SITE, _gate_norm_key(tokens, cfg),
+                      "one pass a direction")
     return (
         f"Mamba-2 mixer: {kinds.count(LAYER_MAMBA2)} of {len(kinds)} layers, "
         f"{cfg.mamba_num_heads} heads x {cfg.mamba_head_dim}, "
@@ -1497,8 +1611,9 @@ def ssm_layout(cfg: SparseLMConfig) -> str:
         f"{chunk}) form a head, across chunks the carried "
         f"({cfg.mamba_head_dim} x {cfg.ssm_state_size}) state, the decays, "
         "their sums and the states in f32; no (T, T) array and no state a "
-        f"token; {scan}; ssm/conv and ssm/gate_norm are XLA code; the "
-        "replay keeps nothing of the mixer but the layer's input")
+        f"token; {scan}; taps, bias and SiLU: {taps}; gate and group norm: "
+        f"{gate_norm}; the replay keeps nothing of the mixer but the "
+        "layer's input")
 
 
 # ---------------------------------------------------------------------------

@@ -159,6 +159,13 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(
     assert "no (T, T) array and no state a token" in said["ssm_layout"]
     assert f"ssm/scan is XLA code ({refusal if with_kernels else shut})" \
         in said["ssm_layout"]
+    # nor any the two passes take: parts and groups of no whole lane tile
+    taps_refusal = "a part of 32 lanes is not whole 128-lane tiles"
+    norm_refusal = "a group of 16 lanes is not whole 128-lane tiles"
+    assert (f"taps, bias and SiLU: XLA code "
+            f"({taps_refusal if with_kernels else shut}); gate and group "
+            f"norm: XLA code ({norm_refusal if with_kernels else shut}); "
+            ) in said["ssm_layout"]
     assert "conv_layout" not in said
     assert said["layer_loop"].endswith(
         "one part a layer behind one norm: mamba2 experts full_nope experts")
@@ -211,7 +218,13 @@ def test_a_mixer_of_lane_tiles_takes_the_scans_kernels(monkeypatch,
     assert "ssm/scan is a pair of Pallas kernels (2 chunks a grid step" \
         in layout
     assert "backward: one kernel, the chunks in reverse" in layout
-    assert "ssm/conv and ssm/gate_norm are XLA code" in layout
+    assert ("taps, bias and SiLU: one pass a direction, x, B and C written "
+            "apart; gate and group norm: one pass a direction; the replay "
+            "keeps nothing of the mixer but the layer's input") in layout
+    for site, key in ((sparse_lm.TAPS_SITE, sparse_lm._taps_key(256, cfg)),
+                      (sparse_lm.GATE_NORM_SITE,
+                       sparse_lm._gate_norm_key(256, cfg))):
+        assert lowering_record.recorded(site, key) == {"why_not": None}
 
 
 @pytest.mark.parametrize("tokens, chunk", [
@@ -486,7 +499,10 @@ def test_the_preset_trains_through_the_peers_normal_path(lowering_record):
     warm = [r for r in rows if r["phase"] == "setup/warmup"][-1]["a"]
     assert warm["ssm_layout"].startswith(
         "Mamba-2 mixer: 1 of 4 layers, 4 heads x 8")
-    assert "XLA code" in warm["ssm_layout"]
+    assert ("ssm/scan is XLA code (no Mosaic backend), its backward plain "
+            "differentiation of the chunked form; taps, bias and SiLU: XLA "
+            "code (no Mosaic backend); gate and group norm: XLA code (no "
+            "Mosaic backend); the replay keeps") in warm["ssm_layout"]
     assert warm["layer_loop"] == (
         "unrolled: 4 layers, each rematerialised but its attention, one "
         "part a layer behind one norm: mamba2 experts full_nope experts")
