@@ -270,7 +270,12 @@ are the two halves of one token sequence, image ids offset by
 T - 1 predicted positions; ``loss_text`` / ``loss_img`` are its means over
 the targets of the two fields. Router product and softmax, attention
 softmax and cross-entropy are f32; the rest runs in ``cfg.dtype`` from f32
-parameters.
+parameters. The head is streamed (:func:`_streamed_nll`: ``cfg.head_chunk``
+rows of the logits alive at a time) and, differentiated, makes ``dx`` and
+``dW`` in the scan that makes the loss, so a micro-step multiplies the
+logits once; nothing of the head is computed again in the backward pass,
+and ``head_layout`` on the ``setup/warmup`` row says which calls traced
+that rule.
 
 Device scopes (``jax.named_scope`` and module names; the benchmark's
 ``*_share_pct`` metrics read them): ``embed``, ``attn`` (projections,
@@ -343,6 +348,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
+from jax.custom_derivatives import SymbolicZero
 from jax.sharding import PartitionSpec as P
 
 from dalle_tpu.config import (LAYER_EXPERTS, LAYER_FULL_ROPE, LAYER_MAMBA2,
@@ -2408,37 +2414,140 @@ class OnePartLayer(nn.Module):
 # it differentiates, whatever the compiler.
 KEPT_OF_A_LAYER = ("attn_out", "attn_stats", "chosen")
 
+HEAD_SITE = "streamed head"
+
+
+def _head_key(hidden: int, vocab: int, n_sums: int, tied: bool, dtype):
+    """What the record knows a call of the streamed head by: the widths, its
+    columns of weights (two of the main loss, one of the prediction
+    module's) and the operands' form; the rows are a fact of the call."""
+    return hidden, vocab, n_sums, tied, jnp.dtype(dtype).name
+
+
+def head_layout(cfg: SparseLMConfig) -> str:
+    """The ``setup/warmup`` row's ``head_layout``: which of the
+    configuration's calls of the streamed head (the main loss's, a
+    prediction module's) traced the rule that makes the gradients with the
+    loss, in what chunks, and what carried ``dW``'s sum."""
+    calls = {"main": 2, "mtp": 1} if cfg.num_nextn_predict_layers else {
+        "main": 2}
+    made = {name: said for name, n_sums in calls.items()
+            if (said := lowering.recorded(HEAD_SITE, _head_key(
+                cfg.hidden_size, cfg.vocab_size, n_sums,
+                cfg.tied_embeddings, cfg.dtype)))}
+    words = f"gradients made with the loss: {len(made)} of {len(calls)} calls"
+    if not made:
+        return words
+    # the calls share their rows and the chunk: one says it
+    said = next(iter(made.values()))
+    return words + (f" ({', '.join(made)}), {said['chunks']} chunks of "
+                    f"{said['rows']} rows, dW added in float32 and carried "
+                    f"in {said['carried']}")
+
+
 def _streamed_nll(h, kernel, targets, weights, chunk: int,
                   tied: bool = False):
-    """Sums of ``weights`` x next-token negative log-likelihood over the
-    rows of ``h`` (N, D), ``chunk`` rows of the (N, V) logits alive at a
-    time and none kept for the backward pass. weights: (N, n_sums).
-    kernel: the head (D, V), or with ``tied`` the embedding's table (V, D),
-    contracted over its second axis where it lies: no transposed copy
-    stands beside it over the scan."""
-    n = h.shape[0]
-    pad = -n % chunk
-    if pad:
-        h, targets, weights = (jnp.pad(x, ((0, pad),) + ((0, 0),)
-                                       * (x.ndim - 1))
-                               for x in (h, targets, weights))
+    """``(total, sums)``: the sums of ``weights`` x next-token negative
+    log-likelihood over the rows of ``h`` (N, D), a sum a column of
+    ``weights`` (N, n_sums), and their total, ``chunk`` rows of the (N, V)
+    logits alive at a time and none kept. kernel: the head (D, V), or with
+    ``tied`` the embedding's table (V, D), contracted over its second axis
+    where it lies: no transposed copy stands beside it over the scan.
 
-    @jax.checkpoint
-    def body(sums, xs):
-        hc, tc, wc = xs
+    ``total`` is what a caller differentiates; ``sums`` are reported
+    (``loss_text`` / ``loss_img``), and a derivative that reaches them is
+    refused. Differentiated, the one scan makes the gradients with the
+    loss: a chunk's ``dlogits`` of a unit cotangent from the logits it has
+    in hand, its rows of ``dx`` and its term of ``dW``, so the logits are
+    multiplied once and the backward pass scales two arrays by the
+    cotangent that arrives and multiplies nothing. ``dW``'s sum over the
+    chunks is carried in the head's dtype, as the transposed scan carried
+    it, and a chunk's term is added to it in f32 (an f32 carry's bytes are
+    not hidden behind the product: 0.5 ms a chunk at 2 560 x 18 992). The
+    products are autodiff's: f32 ``dlogits`` against the operands as they
+    lie, which a TPU's default precision rounds to their dtype in the unit,
+    as it rounded the transposed scan's."""
+    n, dims = h.shape[0], (((1,), (1 if tied else 0,)), ((), ()))
+    split = lambda x: jnp.pad(
+        x, ((0, -n % chunk),) + ((0, 0),) * (x.ndim - 1)).reshape(
+            -1, chunk, *x.shape[1:])
+
+    def nll_of(hc, kernel, tc):
         with jax.named_scope("head"):
             logits = jax.lax.dot_general(
-                hc, kernel, (((1,), (1 if tied else 0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                hc, kernel, dims, preferred_element_type=jnp.float32)
         with jax.named_scope("ce"):
-            nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            return logits, lse, lse - jnp.take_along_axis(
                 logits, tc[:, None], axis=-1)[:, 0]
-            return sums + jnp.sum(nll[:, None] * wc, axis=0), None
 
-    split = lambda x: x.reshape(-1, chunk, *x.shape[1:])
-    sums, _ = jax.lax.scan(body, jnp.zeros(weights.shape[1:], jnp.float32),
-                           (split(h), split(targets), split(weights)))
-    return sums
+    def add(sums, nll, wc):
+        with jax.named_scope("ce"):
+            return sums + jnp.sum(nll[:, None] * wc, axis=0)
+
+    zeros = lambda: jnp.zeros(weights.shape[1:], jnp.float32)
+
+    @jax.custom_vjp
+    def scan(h, kernel, targets, weights):
+        def body(sums, xs):
+            hc, tc, wc = xs
+            return add(sums, nll_of(hc, kernel, tc)[2], wc), None
+
+        sums, _ = jax.lax.scan(body, zeros(),
+                               (split(h), split(targets), split(weights)))
+        return jnp.sum(sums), sums
+
+    def forward(h, kernel, targets, weights):
+        h, kernel, targets, weights = (
+            x.value for x in (h, kernel, targets, weights))
+
+        def body(carry, xs):
+            (sums, dw), (hc, tc, wc) = carry, xs
+            logits, lse, nll = nll_of(hc, kernel, tc)
+            with jax.named_scope("ce"):
+                hot = tc[:, None] == jax.lax.broadcasted_iota(
+                    tc.dtype, logits.shape, 1)
+                dlogits = (jnp.exp(logits - lse[:, None]) - hot) * jnp.sum(
+                    wc, axis=1, keepdims=True)
+                # written once for both products: left to the compiler,
+                # each product's fusion makes its own from the logits
+                # (0.2 ms a product more at 2 048 x 18 992)
+                dlogits = jax.lax.optimization_barrier(dlogits)
+            with jax.named_scope("head"):
+                dx = jax.lax.dot_general(
+                    dlogits, kernel, (((1,), (0 if tied else 1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dw = (dw + jax.lax.dot_general(
+                    *((dlogits, hc) if tied else (hc, dlogits)),
+                    (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)).astype(dw.dtype)
+            return (add(sums, nll, wc), dw), dx
+
+        (sums, dw), dx = jax.lax.scan(
+            body, (zeros(), jnp.zeros_like(kernel)),
+            (split(h), split(targets), split(weights)))
+        lowering.record(
+            HEAD_SITE, _head_key(h.shape[1], kernel.shape[0 if tied else 1],
+                                 weights.shape[1], tied, h.dtype),
+            None, chunks=dx.shape[0], rows=chunk, carried=dw.dtype.name)
+        return (jnp.sum(sums), sums), (dx.reshape(-1, h.shape[1])[:n], dw)
+
+    def backward(made, cotangents):
+        c, of_sums = cotangents
+        if not isinstance(of_sums, SymbolicZero):
+            raise TypeError(
+                "the streamed head differentiates its total, one cotangent "
+                "for every column of weights; a derivative reached its "
+                "sums, which are reported and not differentiated")
+        if isinstance(c, SymbolicZero):
+            return None, None, None, None
+        dx, dw = made
+        # the scalar before the one rounding to the operands' dtype
+        return ((dx * c).astype(h.dtype), (dw * c).astype(kernel.dtype),
+                None, None)
+
+    scan.defvjp(forward, backward, symbolic_zeros=True)
+    return scan(h, kernel, targets, weights)
 
 
 class PredictionModule(nn.Module):
@@ -2539,7 +2648,7 @@ class SparseLM(nn.Module):
             shifted = jnp.concatenate(
                 [loss_mask[:, 1:], jnp.zeros((b, 1), loss_mask.dtype)], 1)
             weights = weights * shifted[..., None].astype(jnp.float32)
-        sums = _streamed_nll(
+        total, sums = _streamed_nll(
             x.reshape(b * t, -1), head.astype(dt), targets.reshape(-1),
             weights.reshape(b * t, 2), min(cfg.head_chunk, b * t), tied)
         # normalised over the WHOLE (micro)batch: under the accumulation's
@@ -2549,7 +2658,7 @@ class SparseLM(nn.Module):
         shards = sum_over_manual_data_axes(1)
         if loss_mask is not None:
             denoms = jnp.maximum(denoms, 1.0)
-        loss = jnp.sum(sums) / jnp.sum(denoms)
+        loss = total / jnp.sum(denoms)
         losses = {}
         if cfg.num_nextn_predict_layers:
             # position i reads z_i and token i + 1 and predicts token i + 2:
@@ -2569,14 +2678,14 @@ class SparseLM(nn.Module):
                 weights = weights * shift(loss_mask, 2)[..., None].astype(
                     jnp.float32)
             with jax.named_scope("mtp"):
-                mtp_sums = _streamed_nll(
+                mtp_total, _ = _streamed_nll(
                     z.reshape(b * t, -1), head.astype(dt),
                     shift(ids, 2).reshape(-1), weights.reshape(b * t, 1),
                     min(cfg.head_chunk, b * t), tied)
             mtp_denom = sum_over_manual_data_axes(jnp.sum(weights))
             if loss_mask is not None:
                 mtp_denom = jnp.maximum(mtp_denom, 1.0)
-            losses = {"loss_main": loss, "loss_mtp": mtp_sums[0] / mtp_denom}
+            losses = {"loss_main": loss, "loss_mtp": mtp_total / mtp_denom}
             loss = loss + cfg.mtp_loss_weight * losses["loss_mtp"]
         stack = lambda key: jnp.stack([c[key] for c in counters])
         if cfg.index_topk:
@@ -2825,12 +2934,11 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
         said["conv_layout"] = conv_layout(cfg)
     if LAYER_MAMBA2 in layers:
         said["ssm_layout"] = ssm_layout(cfg)
-    if cfg.tied_embeddings:
-        said["head_layout"] = (
-            f"tied: the head is the embedding's table ({cfg.vocab_size} x "
-            f"{cfg.hidden_size}), contracted where it lies in the streamed "
-            "cross-entropy; one leaf, the sum of both uses' gradients, one "
-            "LAMB trust ratio")
+    said["head_layout"] = (
+        f"tied: the head is the embedding's table ({cfg.vocab_size} x "
+        f"{cfg.hidden_size}), contracted where it lies in the streamed "
+        "cross-entropy; one leaf, the sum of both uses' gradients, one "
+        "LAMB trust ratio; ") * cfg.tied_embeddings + head_layout(cfg)
     if cfg.num_nextn_predict_layers:
         said["mtp_layout"] = (
             "one prediction module after the final norm: [norm(next "

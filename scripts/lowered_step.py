@@ -41,7 +41,8 @@ def kernel_text(body_b64: str) -> str:
         return module.operation.get_asm(enable_debug_info=False)
 
 
-def lowered_text(preset: str, micro: int, accum: int) -> str:
+def lowered_text(preset: str, micro: int, accum: int,
+                 devices: int = 1) -> str:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     import jax.numpy as jnp
@@ -56,7 +57,7 @@ def lowered_text(preset: str, micro: int, accum: int) -> str:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     cfg = MODEL_PRESETS[preset]()
-    mesh = make_mesh(devices=topo.devices[:1])
+    mesh = make_mesh(devices=topo.devices[:devices])
     module = family(cfg)
     model = module.build(cfg, mesh)
     shapes = jax.eval_shape(
@@ -103,11 +104,14 @@ def main(argv=None) -> None:
     parser.add_argument("--preset", default="smallthinker21b")
     parser.add_argument("--micro", type=int, default=2)
     parser.add_argument("--accum", type=int, default=4)
+    parser.add_argument("--devices", type=int, default=1,
+                        help="of the described host's four, all on dp; "
+                        "--micro is then the devices' micro-batches together")
     parser.add_argument("--out", required=True)
     args = parser.parse_args(argv)
     from dalle_tpu.obs import compiles
     counter = compiles.install(None)     # no task: the counter alone
-    text = lowered_text(args.preset, args.micro, args.accum)
+    text = lowered_text(args.preset, args.micro, args.accum, args.devices)
     with open(args.out, "w") as f:
         f.write(text)
     print(len(text), "bytes, sha256",

@@ -145,6 +145,11 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(
         "latent: q_nope, k_nope, v read where q_b and kv_b wrote them, "
         "delta in the backward kernel: 4 of 4 layers" if with_kernels else
         "sliced: no Mosaic backend")
+    # both uses of the head, the main loss's and the prediction module's,
+    # traced the rule that makes the gradients in the loss's pass
+    assert said["head_layout"] == (
+        "gradients made with the loss: 2 of 2 calls (main, mtp), 5 chunks "
+        "of 16 rows, dW added in float32 and carried in float32")
 
 
 @pytest.mark.parametrize("state, text_seq_len, rotary", [

@@ -158,6 +158,10 @@ def test_loss_and_every_gradient_leaf_against_the_yardstick(
         "shifts along the tokens in f32 (no Mosaic kernel)")
     assert said["head_layout"].startswith(
         f"tied: the head is the embedding's table (96 x {d})")
+    assert said["head_layout"].endswith(
+        "one LAMB trust ratio; gradients made with the loss: 1 of 1 calls "
+        "(main), 6 chunks of 16 rows, dW added in float32 and carried in "
+        "float32")
     assert said["moe_layout"].startswith(
         "4 of 8 experts held (2-5), top 2 of 8, sigmoid, bias, norm, x1, "
         "layers 0-0 dense ")

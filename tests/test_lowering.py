@@ -319,10 +319,14 @@ def test_every_site_of_a_tiny_sparse_step_counts_its_traced_calls(
         vocab_size=512, window=512, text_seq_len=256, image_grid=16,
         vocab_text=256, vocab_image=256)
     # the expert block's form is a fact of the products' call, written
-    # under a key of its own with no call of its own
+    # under a key of its own with no call of its own; the streamed head's
+    # row is a fact its derivative rule writes (no kernel, no bracket)
+    head = sparse_lm.HEAD_SITE, sparse_lm._head_key(256, 512, 2, False,
+                                                    "bfloat16")
     _every_row_was_timed(lowering_record, but={
         (sparse_lm.PRODUCTS_SITE, sparse_lm._block_key(256, 128,
-                                                       "bfloat16"))})
+                                                       "bfloat16")), head})
+    assert lowering_record.recorded(*head)["carried"] == "bfloat16"
     attention_calls = sum(at["calls"] for site, at in sites.items()
                           if site.endswith(" attention"))
     assert attention_calls == census["_causal_fwd_kernel"] == 2
