@@ -194,7 +194,8 @@ class TestLeaderChoice:
 class TestAssistantLoop:
     def test_grad_flat_elements_matches_param_count(self):
         from dalle_tpu.config import tiny_model_config
-        from dalle_tpu.models.dalle import DALLE, init_params
+        from dalle_init import init_params
+        from dalle_tpu.models.dalle import DALLE
         import jax
 
         cfg = tiny_model_config()

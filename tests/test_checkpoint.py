@@ -7,9 +7,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dalle_init import init_params
 from dalle_tpu.config import (CollabConfig, OptimizerConfig, PeerConfig,
                               TrainerConfig, tiny_model_config)
-from dalle_tpu.models.dalle import DALLE, init_params
+from dalle_tpu.models.dalle import DALLE
 from dalle_tpu.optim import make_optimizer
 from dalle_tpu.training.checkpoint import (CheckpointManager,
                                            params_are_finite)

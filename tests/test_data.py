@@ -135,7 +135,8 @@ class TestCodesDataset:
         import jax
 
         from dalle_tpu.config import OptimizerConfig
-        from dalle_tpu.models.dalle import DALLE, init_params
+        from dalle_init import init_params
+        from dalle_tpu.models.dalle import DALLE
         from dalle_tpu.optim import make_optimizer
         from dalle_tpu.training.steps import TrainState, make_train_step
 

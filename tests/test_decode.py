@@ -6,8 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dalle_init import init_params
 from dalle_tpu.config import tiny_model_config
-from dalle_tpu.models.dalle import DALLE, init_params
+from dalle_tpu.models.dalle import DALLE
 from dalle_tpu.models.decode import (SamplingConfig, decode_step,
                                      generate_images, init_cache,
                                      layer_params, resolve_buckets,

@@ -5,12 +5,15 @@ repetition out of the stacked leaves reproduces the unrolled layers
 (which is also how models/decode.py::layer_params reads the scanned tree).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dalle_init import init_params
 from dalle_tpu.config import flagship_model_config
-from dalle_tpu.models.dalle import DALLE, init_params
+from dalle_tpu.models.dalle import DALLE
 
 
 def _cfg(dense_scan, depth=9):
@@ -21,6 +24,21 @@ def _cfg(dense_scan, depth=9):
         # f32 so scanned-vs-unrolled parity is EXACT (measured 0.0 diff);
         # under bf16 the two reduction orders drift like any reordering
         dense_scan=dense_scan, dtype="float32")
+
+
+def _init(cfg):
+    return init_params(DALLE(cfg), jax.random.PRNGKey(0))
+
+
+@functools.cache
+def _loss_and_grads(cfg, batch):
+    """One jitted program a configuration and batch, shared by the cases
+    that ask for the same model's loss and gradients."""
+    model = DALLE(cfg)
+    text = jnp.zeros((batch, cfg.text_seq_len), jnp.int32)
+    image = jnp.ones((batch, cfg.image_seq_len), jnp.int32)
+    return jax.jit(jax.value_and_grad(
+        lambda p: model.apply(p, text, image)[0]))
 
 
 def _unrolled_from_scanned(params, cfg):
@@ -34,7 +52,7 @@ def _unrolled_from_scanned(params, cfg):
     for uid in range(body):
         rep, sub = divmod(uid, group)
         out_tr[f"block_{uid}"] = jax.tree.map(
-            lambda a: a[rep], tr["cycle"][f"block_{sub}"])
+            lambda a: np.asarray(a)[rep], tr["cycle"][f"block_{sub}"])
     out = copy.copy(params)
     out["params"] = dict(params["params"], transformer=out_tr)
     return out
@@ -43,7 +61,7 @@ def _unrolled_from_scanned(params, cfg):
 class TestDenseScan:
     def test_scanned_tree_shape(self):
         cfg = _cfg(True)
-        params = init_params(DALLE(cfg), jax.random.PRNGKey(0))
+        params = _init(cfg)
         tr = params["params"]["transformer"]
         assert "cycle" in tr and "block_wconv" in tr
         # 8 body layers / group 4 = 2 reps, stacked leading axis
@@ -55,18 +73,14 @@ class TestDenseScan:
 
     def test_scanned_matches_unrolled_forward_and_grads(self):
         cfg_s, cfg_u = _cfg(True), _cfg(False)
-        model_s, model_u = DALLE(cfg_s), DALLE(cfg_u)
-        params_s = init_params(model_s, jax.random.PRNGKey(0))
+        params_s = _init(cfg_s)
         params_u = _unrolled_from_scanned(params_s, cfg_s)
-        text = jnp.zeros((2, cfg_s.text_seq_len), jnp.int32)
-        image = jnp.ones((2, cfg_s.image_seq_len), jnp.int32)
 
-        l_s = float(model_s.apply(params_s, text, image)[0])
-        l_u = float(model_u.apply(params_u, text, image)[0])
+        l_s, g_s = _loss_and_grads(cfg_s, 2)(params_s)
+        l_u, g_u = _loss_and_grads(cfg_u, 2)(params_u)
+        l_s, l_u = float(l_s), float(l_u)
         assert abs(l_s - l_u) / abs(l_u) < 1e-6, (l_s, l_u)
 
-        g_s = jax.grad(lambda p: model_s.apply(p, text, image)[0])(params_s)
-        g_u = jax.grad(lambda p: model_u.apply(p, text, image)[0])(params_u)
         # compare per-layer: slice the scanned grads like the params
         g_su = _unrolled_from_scanned(g_s, cfg_s)
         flat_u, _ = jax.tree_util.tree_flatten_with_path(g_u["params"])
@@ -92,11 +106,7 @@ class TestDenseScan:
         # applications of rep 2 must not change the loss, and their param
         # slices must get ZERO grads
         cfg = _cfg(True, depth=10)
-        model = DALLE(cfg)
-        params = init_params(model, jax.random.PRNGKey(0))
-        text = jnp.zeros((1, cfg.text_seq_len), jnp.int32)
-        image = jnp.ones((1, cfg.image_seq_len), jnp.int32)
-        g = jax.grad(lambda p: model.apply(p, text, image)[0])(params)
+        _, g = _loss_and_grads(cfg, 1)(_init(cfg))
         tr = g["params"]["transformer"]["cycle"]
         # rep 2 exists for block_1..block_3 only as overhang
         for sub in (1, 2, 3):
@@ -113,7 +123,7 @@ class TestDenseScan:
         from dalle_tpu.models.decode import layer_params
         cfg = _cfg(True, depth=4)
         assert cfg.dense_scan_reps() == 0
-        params = init_params(DALLE(cfg), jax.random.PRNGKey(0))
+        params = _init(cfg)
         tr = params["params"]["transformer"]
         assert "cycle" not in tr and "block_0" in tr
         layers = layer_params(params, cfg)
@@ -128,7 +138,7 @@ class TestDenseScan:
 
         from dalle_tpu.parallel.sharding import param_specs
         cfg = _cfg(True)
-        params = init_params(DALLE(cfg), jax.random.PRNGKey(0))
+        params = _init(cfg)
         specs = param_specs(params)
         tr = specs["params"]["transformer"]
         assert tr["cycle"]["block_0"]["attn"]["q"]["kernel"] == P(
@@ -146,18 +156,16 @@ class TestDenseScan:
         from dalle_tpu.optim import make_optimizer
 
         cfg_s, cfg_u = _cfg(True), _cfg(False)
-        model_s, model_u = DALLE(cfg_s), DALLE(cfg_u)
-        params_s = init_params(model_s, jax.random.PRNGKey(0))
+        params_s = _init(cfg_s)
         params_u = _unrolled_from_scanned(params_s, cfg_s)
-        text = jnp.zeros((2, cfg_s.text_seq_len), jnp.int32)
-        image = jnp.ones((2, cfg_s.image_seq_len), jnp.int32)
-        g_s = jax.grad(lambda p: model_s.apply(p, text, image)[0])(params_s)
-        g_u = jax.grad(lambda p: model_u.apply(p, text, image)[0])(params_u)
+        _, g_s = _loss_and_grads(cfg_s, 2)(params_s)
+        _, g_u = _loss_and_grads(cfg_u, 2)(params_u)
 
         tx = make_optimizer(OptimizerConfig(state_bits=32, warmup_steps=2,
                                             total_steps=100))
-        upd_s, _ = tx.update(g_s, tx.init(params_s), params_s)
-        upd_u, _ = tx.update(g_u, tx.init(params_u), params_u)
+        first_update = jax.jit(lambda g, p: tx.update(g, tx.init(p), p)[0])
+        upd_s = first_update(g_s, params_s)
+        upd_u = first_update(g_u, params_u)
         upd_su = _unrolled_from_scanned(upd_s, cfg_s)
         flat_u = jax.tree_util.tree_flatten_with_path(upd_u["params"])[0]
         flat_s = dict(jax.tree_util.tree_flatten_with_path(
@@ -171,7 +179,7 @@ class TestDenseScan:
     def test_decode_layer_params_slices_scanned_tree(self):
         from dalle_tpu.models.decode import layer_params
         cfg = _cfg(True)
-        params = init_params(DALLE(cfg), jax.random.PRNGKey(0))
+        params = _init(cfg)
         layers = layer_params(params, cfg)
         assert len(layers) == cfg.depth
         group = len(cfg.attn_types)

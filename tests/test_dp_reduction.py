@@ -9,9 +9,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dalle_init import init_params
 from dalle_tpu.config import OptimizerConfig, tiny_model_config
 from dalle_tpu.data.synthetic import SyntheticCodes
-from dalle_tpu.models.dalle import DALLE, init_params
+from dalle_tpu.models.dalle import DALLE
 from dalle_tpu.optim import make_optimizer
 from dalle_tpu.parallel.mesh import batch_sharding, make_mesh
 from dalle_tpu.training.steps import (

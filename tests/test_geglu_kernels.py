@@ -77,7 +77,8 @@ class TestModelIntegration:
     @staticmethod
     def _model(ff_fusion, skip):
         from dalle_tpu.config import flagship_model_config
-        from dalle_tpu.models.dalle import DALLE, init_params
+        from dalle_init import init_params
+        from dalle_tpu.models.dalle import DALLE
 
         cfg = flagship_model_config(
             depth=9, dim=128, heads=2, head_dim=64, text_seq_len=16,
@@ -102,12 +103,13 @@ class TestModelIntegration:
         def loss(m):
             return lambda p: m.apply(p, text, image)[0]
 
-        l_u = float(loss(model)(params))
-        l_f = float(loss(model_f)(params))
+        # one jitted program a model: loss and gradients in one trace
+        # (four eager passes through the interpreted kernels took 150-190 s)
+        l_u, g_u = jax.jit(jax.value_and_grad(loss(model)))(params)
+        l_f, g_f = jax.jit(jax.value_and_grad(loss(model_f)))(params)
+        l_u, l_f = float(l_u), float(l_f)
         assert abs(l_u - l_f) / abs(l_u) < 1e-3, (l_u, l_f)
 
-        g_u = jax.grad(loss(model))(params)
-        g_f = jax.grad(loss(model_f))(params)
         flat_u, _ = jax.tree_util.tree_flatten(g_u)
         flat_f, _ = jax.tree_util.tree_flatten(g_f)
         for a, b in zip(flat_u, flat_f):

@@ -19,6 +19,7 @@ import pytest
 from dalle_tpu.models import attention, sparse_lm
 from dalle_tpu.ops.pallas import head_norm_kernels as K
 from dalle_tpu.parallel.mesh import LANES_SPEC, make_mesh
+from sparse_family import rel_l2
 
 
 EPS = 1e-5
@@ -37,11 +38,6 @@ CASES = {
 def by_reshape(x, scale, head_dim):
     return sparse_lm.rms_norm(x.reshape(*x.shape[:2], -1, head_dim), scale,
                               EPS).reshape(x.shape)
-
-
-def rel_l2(a, b):
-    a, b = (np.asarray(v, np.float32) for v in (a, b))
-    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
 
 
 @functools.lru_cache(maxsize=None)

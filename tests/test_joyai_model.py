@@ -1,26 +1,23 @@
 """``JoyAILMConfig`` (preset ``joyaiflash``) through models/sparse_lm.py at
-a tiny size, seeded random weights, f32: loss and every gradient leaf
-against the plain reference of its yardstick under both lowerings; the
-latent kernels against the dense lowering of the same equations; the
-rotary's interleaved pairs against a complex multiply; the preset trains
-through the peer's normal path and the entry points that decode refuse it.
-(What it shares with ``trinitymini`` is parametrised in
-tests/test_trinity_model.py: a mechanism left out is told, the shares add
-up to the uncut layer.)"""
+a tiny size, seeded random weights, f32: the family's cases over its row
+(tests/sparse_family.py), and what only it has: the latent kernels against
+the dense lowering of the same equations; the rotary's interleaved pairs
+against a complex multiply; the backward pass differentiates the experts the
+forward ran."""
 import dataclasses
-import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import sparse_family as fam
 from benchmark.manifest import Manifest
-from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
 from dalle_tpu.config import (AfmoeLMConfig, JoyAILMConfig, SparseLMConfig,
                               joyaiflash_model_config)
-from dalle_tpu.models import attention, family, sparse_lm
+from dalle_tpu.models import attention, sparse_lm
 from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
+from sparse_family import as_file, batch, rel_l2
 
 Y = Manifest().yardstick("joyai")
 
@@ -36,120 +33,187 @@ TINY = dict(hidden_size=64, num_hidden_layers=3, num_heads=4, num_kv_heads=4,
 KERNEL_WIDTHS = dict(qk_nope_head_dim=128, qk_rope_head_dim=64,
                      v_head_dim=128, hidden_size=128, expert_width=128,
                      dense_width=128)
+# a dense layer and an expert layer with the prediction module: every
+# mechanism in two layers, a sequence of 32
+SMALL = dict(TINY, num_hidden_layers=2, text_seq_len=16)
 
 
-def as_file(cfg):
-    return json.loads(json.dumps(dataclasses.asdict(cfg)))
+# the mechanisms have no switch on either side (the program and the
+# reference are written for them): each is left out of the REFERENCE by a
+# patch of the yardstick's module, or of what it reads
+def _no_latent_norms(monkeypatch, model):
+    plain = Y._rms_norm
+    latents = (model["q_lora_rank"], model["kv_lora_rank"])
+    monkeypatch.setattr(Y, "_rms_norm", lambda x, g, eps: (
+        x if g.shape[0] in latents else plain(x, g, eps)))
+    return model
 
 
-def _batch(cfg, seed=0, n=2):
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.integers(2, cfg.vocab_text,
-                                     (n, cfg.text_seq_len)), jnp.int32),
-            jnp.asarray(rng.integers(0, cfg.vocab_image,
-                                     (n, cfg.image_seq_len)), jnp.int32))
+def _no_shared_rotary_key(monkeypatch, model):
+    # the one key every head reads (B, T, rope) adds nothing to a score
+    plain = Y.rotary_pairs
+    monkeypatch.setattr(Y, "rotary_pairs", lambda x, theta: (
+        jnp.zeros_like(x) if x.ndim == 3 else plain(x, theta)))
+    return model
 
 
-def rel_l2(a, b):
-    return float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b),
-                                                      1e-30))
+def _scale_of_the_unrotated_part_alone(monkeypatch, model):
+    plain = Y._attention
+    nope, rope = model["qk_nope_head_dim"], model["qk_rope_head_dim"]
+    up = ((nope + rope) / nope) ** 0.5        # 1 / sqrt(nope) in all
+    monkeypatch.setattr(Y, "_attention", lambda qn, qr, *rest: plain(
+        qn * up, qr * up, *rest))
+    return model
 
 
-def _params(cfg, seed=1):
-    """Seeded weights with every vector leaf (norm scales, the router's
-    bias) moved off its initial ones and zeros, so that each counts."""
-    params = sparse_lm.init_params(sparse_lm.build(cfg),
-                                   jax.random.PRNGKey(seed))
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed + 100), len(leaves))
-    return jax.tree.unflatten(tree, [
-        a + 0.1 * jax.random.normal(k, a.shape) if a.ndim == 1 else a
-        for a, k in zip(leaves, keys)])
+LEFT_OUT = {
+    "the norms of the two latents": _no_latent_norms,
+    "the shared rotary key": _no_shared_rotary_key,
+    "the scale 1 / sqrt(nope + rope)": _scale_of_the_unrotated_part_alone,
+    "the prediction module's loss": dict(mtp_loss_weight=0.0),
+    "the prediction module": dict(num_nextn_predict_layers=0),
+}
 
 
-@pytest.mark.parametrize("with_kernels", [False, True])
-def test_loss_and_every_gradient_leaf_against_the_yardstick(
-        with_kernels, monkeypatch, lowering_record):
-    """The whole tiny model, the prediction module's loss in it; with
-    ``with_kernels`` the latent attention, the grouped products and the
-    token-major sums run their Pallas kernels, interpreted. Limits: f32 on
-    both sides, the reference at the highest matmul precision; the program
-    and the reference order their sums differently (blockwise softmax,
-    streamed head, sorted experts), which the parent's test of the other
-    configuration reads at the same 2e-6 / 2e-5."""
-    cfg = JoyAILMConfig(**dict(TINY, **(KERNEL_WIDTHS if with_kernels
-                                        else {})))
-    cfg.validate()
-    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", with_kernels)
-    params = _params(cfg)
-    text, image = _batch(cfg)
-    model = sparse_lm.build(cfg)
-    (loss, aux), grads = jax.jit(jax.value_and_grad(
-        lambda p: model.apply(p, text, image), has_aux=True))(params)
-    ref_loss, ref_grads = Y.loss_and_grads(params, text, image, as_file(cfg))
-    assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
-    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
-                            jax.tree.leaves(ref_grads)):
-        assert rel_l2(g, r) < 2e-5, jax.tree_util.keystr(path)
-    # the loss is the sum of its two parts, which ride beside it
-    (_, (main, mtp)) = jax.jit(lambda p: Y.loss_fn(
-        p, text, image, as_file(cfg)))(params)
-    assert float(aux["loss_main"]) == pytest.approx(float(main), rel=2e-6)
-    assert float(aux["loss_mtp"]) == pytest.approx(float(mtp), rel=2e-6)
-    assert float(loss) == pytest.approx(
-        float(main) + cfg.mtp_loss_weight * float(mtp), rel=1e-6)
-    tree = params["params"]
-    assert set(tree) == {"token_emb", "lm_head", "final_norm", "mtp",
-                         "layer_0", "layer_1", "layer_2"}
-    assert set(tree["layer_1"]) == {"attn", "attn_norm", "ff", "ff_norm"}
-    assert set(tree["layer_1"]["attn"]) == {"q_a", "q_a_norm", "q_b", "kv_a",
-                                            "kv_a_norm", "kv_b", "out"}
-    assert set(tree["mtp"]) == {"enorm", "hnorm", "proj", "block",
-                                "final_norm"}
-    assert set(tree["mtp"]["block"]["ff"]) == {"router", "router_bias",
-                                               "experts", "shared"}
-    d = cfg.hidden_size
-    assert tree["mtp"]["proj"]["kernel"].shape == (2 * d, d)
-    assert tree["layer_0"]["attn"]["kv_a"]["kernel"].shape == (
-        d, cfg.kv_lora_rank + cfg.qk_rope_head_dim)    # ONE rotary key
-    # counters of the three expert layers, the module's block among them
-    assert float(aux["moe_dropped"]) == 0.0
-    assert float(aux["moe_dense_calls"]) == (0.0 if with_kernels else 3.0)
-    # which lowering the four latent layers took, asked of the record
-    shut = None if with_kernels else "no Mosaic backend"
-    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    latent = "latent attention", (40, 4, nope, rope, cfg.v_head_dim)
-    assert lowering_record.why_not(*latent) == shut
-    assert lowering_record.first_refusal(
-        ("rotary", sparse_lm._pair_key(40, lanes, rope))
-        for lanes in (4 * rope, rope)) == shut
-    if with_kernels:
-        assert lowering_record.recorded(*latent) == {
-            "why_not": None, "sliced": None}
-    # the sentences, whole, as the operator reads them
-    said = sparse_lm.engagement_records(cfg)
-    widths = "128 + 64 | 128" if with_kernels else "16 + 8 | 16"
-    assert said["attn_layout"] == (
-        f"latent 48 / 32 + one rotary key of {rope}, heads 4 x ({widths}), "
-        + ("blockwise 512: 4 of 4 layers, 2 heads a step, backward: one "
-           "kernel a tile, rotary (one pass on the lanes: 4 of 4 layers)"
-           if with_kernels else
-           "dense XLA lowering (no Mosaic backend), rotary (XLA: no Mosaic "
-           "backend)"))
-    assert said["mtp_layout"] == (
-        "one prediction module after the final norm: [norm(next token's "
-        "embedding) ; norm(last state)] . W_eh, one expert layer, a final "
-        "norm of its own; shares the embedding and the head; loss_mtp over "
-        "T - 2 positions, weight 0.3")
-    assert said["attn_operands"] == (
-        "latent: q_nope, k_nope, v read where q_b and kv_b wrote them, "
-        "delta in the backward kernel: 4 of 4 layers" if with_kernels else
-        "sliced: no Mosaic backend")
-    # both uses of the head, the main loss's and the prediction module's,
-    # traced the rule that makes the gradients in the loss's pass
-    assert said["head_layout"] == (
-        "gradients made with the loss: 2 of 2 calls (main, mtp), 5 chunks "
-        "of 16 rows, dW added in float32 and carried in float32")
+class TestJoyaiflash(fam.Family, fam.MechanismsLeftOut, fam.SharesAddUp,
+                     fam.BlockOnTheTile):
+    config, preset = JoyAILMConfig, "joyaiflash"
+    preset_config, Y = staticmethod(joyaiflash_model_config), Y
+    # the three expert layers, the prediction module's block among them
+    TINY, KERNEL_WIDTHS, EXPERT_LAYERS = TINY, KERNEL_WIDTHS, 3
+    LEFT_OUT, EVERYTHING = LEFT_OUT, SMALL
+    # its own 32 shares of 8 consecutive experts (``8r .. 8r + 7``), 256 in
+    # all, top 8, at a small width; 8 shares of 64 where the kernels run
+    # interpreted: a share costs 3 s
+    SHARES = {interpreted: (8 if interpreted else 32, dict(
+        SMALL, num_experts=64 if interpreted else 256, experts_held=8,
+        expert_offset=0, experts_per_token=8))
+        for interpreted in (False, True)}
+    # gated-SiLU experts beside a shared expert under a sigmoid router
+    BLOCK = dict(fields=SMALL, vmem=64 * 1024,
+                 refusal="need 0.6 MiB of VMEM, over 0.0625")
+    ADDED = {"q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+             "qk_rope_head_dim", "v_head_dim", "rope_interleave",
+             "num_nextn_predict_layers", "mtp_loss_weight"}
+    PUBLISHED = dict(
+        hidden_size=2048, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        num_heads=32, dense_width=7168, expert_width=768, num_experts=256,
+        experts_per_token=8, route_scale=2.5, rope_theta=3.2e7, rms_eps=1e-6)
+    REFUSAL = ("latent attention", "prediction module")
+
+    def the_yardstick_also(self, *, cfg, tree, shut, said, loss, aux,
+                           with_kernels, lowering_record, weights, text,
+                           image, **_):
+        """The prediction module's loss in it; with the kernels the latent
+        attention, the grouped products and the token-major sums run
+        interpreted."""
+        # the loss is the sum of its two parts, which ride beside it
+        (_, (main, mtp)) = jax.jit(lambda p: Y.loss_fn(
+            p, text, image, as_file(cfg)))(weights)
+        assert float(aux["loss_main"]) == pytest.approx(float(main), rel=2e-6)
+        assert float(aux["loss_mtp"]) == pytest.approx(float(mtp), rel=2e-6)
+        assert float(loss) == pytest.approx(
+            float(main) + cfg.mtp_loss_weight * float(mtp), rel=1e-6)
+        assert set(tree) == {"token_emb", "lm_head", "final_norm", "mtp",
+                             "layer_0", "layer_1", "layer_2"}
+        assert set(tree["layer_1"]) == {"attn", "attn_norm", "ff", "ff_norm"}
+        assert set(tree["layer_1"]["attn"]) == {
+            "q_a", "q_a_norm", "q_b", "kv_a", "kv_a_norm", "kv_b", "out"}
+        assert set(tree["mtp"]) == {"enorm", "hnorm", "proj", "block",
+                                    "final_norm"}
+        assert set(tree["mtp"]["block"]["ff"]) == {"router", "router_bias",
+                                                   "experts", "shared"}
+        d = cfg.hidden_size
+        assert tree["mtp"]["proj"]["kernel"].shape == (2 * d, d)
+        assert tree["layer_0"]["attn"]["kv_a"]["kernel"].shape == (
+            d, cfg.kv_lora_rank + cfg.qk_rope_head_dim)    # ONE rotary key
+        # which lowering the four latent layers took, asked of the record
+        nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        latent = "latent attention", (40, 4, nope, rope, cfg.v_head_dim)
+        assert lowering_record.why_not(*latent) == shut
+        assert lowering_record.first_refusal(
+            ("rotary", sparse_lm._pair_key(40, lanes, rope))
+            for lanes in (4 * rope, rope)) == shut
+        if with_kernels:
+            assert lowering_record.recorded(*latent) == {
+                "why_not": None, "sliced": None}
+        # the sentences, whole, as the operator reads them
+        widths = "128 + 64 | 128" if with_kernels else "16 + 8 | 16"
+        assert said["attn_layout"] == (
+            f"latent 48 / 32 + one rotary key of {rope}, heads 4 x "
+            f"({widths}), "
+            + ("blockwise 512: 4 of 4 layers, 2 heads a step, backward: one "
+               "kernel a tile, rotary (one pass on the lanes: 4 of 4 layers)"
+               if with_kernels else
+               "dense XLA lowering (no Mosaic backend), rotary (XLA: no "
+               "Mosaic backend)"))
+        assert said["mtp_layout"] == (
+            "one prediction module after the final norm: [norm(next token's "
+            "embedding) ; norm(last state)] . W_eh, one expert layer, a final "
+            "norm of its own; shares the embedding and the head; loss_mtp "
+            "over T - 2 positions, weight 0.3")
+        assert said["attn_operands"] == (
+            "latent: q_nope, k_nope, v read where q_b and kv_b wrote them, "
+            "delta in the backward kernel: 4 of 4 layers" if with_kernels else
+            "sliced: no Mosaic backend")
+        # both uses of the head, the main loss's and the prediction module's,
+        # traced the rule that makes the gradients in the loss's pass
+        assert said["head_layout"] == (
+            "gradients made with the loss: 2 of 2 calls (main, mtp), 5 chunks "
+            "of 16 rows, dW added in float32 and carried in float32")
+
+    def left_out_also(self, mechanism, cfg, loss, aux):
+        """The module, which has a field, is also left out of both."""
+        assert float(aux["loss_main"] + cfg.mtp_loss_weight
+                     * aux["loss_mtp"]) == pytest.approx(loss, rel=1e-6)
+        if mechanism == "the prediction module":
+            cfg = dataclasses.replace(cfg, num_nextn_predict_layers=0)
+            params, (text, image) = fam.params(cfg), batch(cfg)
+            assert "mtp" not in params["params"]
+            (loss, aux), grads = fam.system(cfg, params, text, image)
+            assert "loss_mtp" not in aux and "loss_main" not in aux
+            ref_loss, ref_grads = Y.loss_and_grads(params, text, image,
+                                                   as_file(cfg))
+            assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
+            fam.leaves_within(grads, ref_grads, 2e-5)
+
+    def the_normal_path_also(self, *, warm, steps, losses, **_):
+        """The rows carry the model's records and the two losses."""
+        assert warm["moe_layout"] == (
+            "4 of 8 experts held (2-5), top 2 of 8, sigmoid, bias, norm, "
+            "x2.5, a shared expert of 32, layers 0-0 dense 96, no exchange: 8 "
+            "devices, data parallel; token-major sums: none traced (the dense "
+            "lowering)")
+        assert warm["attn_operands"] == "sliced: no Mosaic backend"
+        assert warm["attn_layout"] == (
+            "latent 48 / 32 + one rotary key of 64, heads 4 x (128 + 64 | "
+            "128), dense XLA lowering (no Mosaic backend), rotary (XLA: no "
+            "Mosaic backend)")
+        assert warm["mtp_layout"].startswith("one prediction module after the "
+                                             "final norm")
+        for row, loss in zip(steps, losses):
+            assert row["loss_main"] + 0.3 * row["loss_mtp"] == pytest.approx(
+                loss, rel=1e-5)
+
+    def the_class_also(self, cfg, flags):
+        assert {cfg.kind_of_layer(i) for i in range(5)} == {"full_rope"}
+        assert {"q_lora_rank", "kv_lora_rank", "num_nextn_predict_layers"} \
+            <= flags
+        stated = set(JoyAILMConfig.no_flag) - set(AfmoeLMConfig.no_flag)
+        assert stated == {"qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+                          "rope_interleave", "mtp_loss_weight"}
+        assert not flags & stated
+        # the kind and the class's widths decide: ``full_rope`` without
+        # ``kv_lora_rank`` is grouped key-value heads with rotary over the
+        # whole sequence (tests/test_lfm2_model.py), any class's
+        SparseLMConfig(layer_kinds=("full_rope",)).validate()
+        with pytest.raises(ValueError, match="unknown layer kind"):
+            SparseLMConfig(layer_kinds=("latent",)).validate()
+        with pytest.raises(ValueError, match="interleaved pairs"):
+            dataclasses.replace(cfg, rope_interleave=False).validate()
+        with pytest.raises(ValueError, match="one prediction module"):
+            dataclasses.replace(cfg, num_nextn_predict_layers=2).validate()
 
 
 @pytest.mark.parametrize("state, text_seq_len, rotary", [
@@ -171,7 +235,7 @@ def test_attn_layout_says_which_lowering_the_rotary_took(
     assert sparse_lm.engagement_records(cfg)["attn_layout"].endswith(
         ", rotary (XLA: no Mosaic backend)" if state == "no_backend"
         else ", rotary (XLA: none traced)")
-    loss, _ = jax.jit(model.apply)(_params(cfg), *_batch(cfg))
+    loss, _ = jax.jit(model.apply)(fam.params(cfg), *batch(cfg))
     assert np.isfinite(float(loss))
     said = sparse_lm.engagement_records(cfg)["attn_layout"]
     assert said.endswith(f", rotary ({rotary})"), said
@@ -203,7 +267,7 @@ def test_latent_attention_on_a_mesh_is_the_one_device_layer(
 
     def value_and_grads(mesh):
         mod = sparse_lm.LatentAttention(cfg, mesh=mesh, name="attn")
-        params = mod.init(jax.random.PRNGKey(1), a)
+        params = jax.jit(mod.init)(jax.random.PRNGKey(1), a)
         return jax.jit(jax.value_and_grad(
             lambda p, a: jnp.sum(mod.apply(p, a) ** 2), (0, 1)))(params, a)
 
@@ -235,34 +299,19 @@ def test_latent_attention_on_a_mesh_is_the_one_device_layer(
 
 def test_the_pair_pass_in_the_model_is_its_xla_lowering(monkeypatch,
                                                         lowering_record):
-    """Loss and every gradient leaf of the whole tiny model with the rotary
-    as the one pass on the lanes (interpreted) against the same model with
-    ``rotary_interleaved_lanes``, every other kernel running on both
-    sides: within the limits the yardstick's comparison has."""
+    """The whole tiny model with the rotary as the one pass on the lanes
+    against the same model with ``rotary_interleaved_lanes``: within the
+    limits the yardstick's comparison has."""
     cfg = JoyAILMConfig(**dict(TINY, **KERNEL_WIDTHS))
     cfg.validate()
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
-    params = _params(cfg)
-    text, image = _batch(cfg)
-    model = sparse_lm.build(cfg)
-
-    def loss_and_grads():
-        # a new function a lowering: nothing traced before is reused
-        return jax.jit(jax.value_and_grad(
-            lambda p: model.apply(p, text, image)[0]))(params)
-
-    loss, grads = loss_and_grads()
     rotaries = [("rotary", sparse_lm._pair_key(cfg.total_seq_len, lanes, 64))
                 for lanes in (4 * 64, 64)]
-    assert lowering_record.first_refusal(rotaries) is None
-    monkeypatch.setattr(sparse_lm.head_norm, "pairs_fit",
-                        lambda *shape: "refused here")
-    ref_loss, ref_grads = loss_and_grads()
-    assert lowering_record.first_refusal(rotaries) == "refused here"
-    assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
-    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
-                            jax.tree.leaves(ref_grads)):
-        assert rel_l2(g, r) < 2e-5, jax.tree_util.keystr(path)
+    assert fam.a_pass_is_its_xla_lowering(
+        cfg, lambda: lowering_record.first_refusal(rotaries),
+        lambda: monkeypatch.setattr(sparse_lm.head_norm, "pairs_fit",
+                                    lambda *shape: "refused here"),
+        within=(2e-6, 2e-5)) == (None, "refused here")
 
 
 # bfloat16 activations, enough tokens (768 a sequence) and experts (64, 8
@@ -291,9 +340,7 @@ def test_the_backward_pass_differentiates_the_experts_the_forward_ran(
                             ("attn_out", "attn_stats"))
     cfg = JoyAILMConfig(**NEAR_TIES)
     cfg.validate()
-    params = sparse_lm.init_params(sparse_lm.build(cfg),
-                                   jax.random.PRNGKey(1))
-    text, image = _batch(cfg, n=1)
+    params, (text, image) = fam.params(cfg, moved=False), batch(cfg, n=1)
     model = sparse_lm.build(cfg)
 
     def loss_and_sets(p):
@@ -347,14 +394,15 @@ def test_the_latent_kernels_are_the_dense_lowering(tokens, heads, block):
                                         block, True)
 
     with jax.default_matmul_precision("highest"):
-        out, grads = jax.value_and_grad(lambda *a: jnp.sum(latent(*a) * w),
-                                        argnums=range(5))(*operands)
-        want, want_grads = jax.value_and_grad(lambda *a: jnp.sum(
+        # jitted: eagerly every operation of the four is a compile
+        out, grads = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(latent(*a) * w), argnums=range(5)))(*operands)
+        want, want_grads = jax.jit(jax.value_and_grad(lambda *a: jnp.sum(
             sparse_lm.dense_latent_attention(*a) * w),
-            argnums=range(5))(*operands)
-        values = latent(*operands)
-    np.testing.assert_allclose(
-        values, sparse_lm.dense_latent_attention(*operands), atol=2e-5)
+            argnums=range(5)))(*operands)
+        values = jax.jit(latent)(*operands)
+        dense_values = jax.jit(sparse_lm.dense_latent_attention)(*operands)
+    np.testing.assert_allclose(values, dense_values, atol=2e-5)
     assert float(out) == pytest.approx(float(want), rel=1e-5)
     for name, g, r in zip(("q_nope", "q_rope", "k_nope", "k_rope", "v"),
                           grads, want_grads):
@@ -397,8 +445,8 @@ def test_the_latent_kernels_read_the_projections_outputs_where_they_lie(
             q[..., :lanes], q_rope, (kv[..., :lanes], kv[..., lanes:]),
             k_rope, block, True)
 
-    both = lambda f: jax.value_and_grad(lambda *a: jnp.sum(
-        (f(*a) * w).astype(jnp.float32)), argnums=range(4))(
+    both = lambda f: jax.jit(jax.value_and_grad(lambda *a: jnp.sum(
+        (f(*a) * w).astype(jnp.float32)), argnums=range(4)))(
             q, q_rope, kv, k_rope)
     np.testing.assert_array_equal(whole(q, q_rope, kv, k_rope),
                                   sliced(q, q_rope, kv, k_rope))
@@ -430,10 +478,11 @@ def test_the_backward_kernels_own_delta_gives_the_dense_gradient(
             q[..., :lanes], q_rope, kv[..., :lanes], k_rope, kv[..., lanes:])
 
     with jax.default_matmul_precision("highest"):
-        grads, want_grads = (jax.grad(lambda *a: jnp.sum(f(*a) * w),
-                                      argnums=range(4))(q, q_rope, kv, k_rope)
-                             for f in (lambda *a: kernels.latent_attention(
-                                 *a, block, True), dense))
+        grads, want_grads = (
+            jax.jit(jax.grad(lambda *a: jnp.sum(f(*a) * w),
+                             argnums=range(4)))(q, q_rope, kv, k_rope)
+            for f in (lambda *a: kernels.latent_attention(*a, block, True),
+                      dense))
     for name, g, r in zip(("q", "q_rope", "kv", "k_rope"), grads,
                           want_grads):
         assert g.shape == r.shape and rel_l2(g, r) < 2e-6, name
@@ -454,7 +503,7 @@ def test_the_grad_step_slices_neither_q_b_nor_kv_b(monkeypatch,
     model = sparse_lm.build(cfg)
     params = jax.eval_shape(
         lambda: sparse_lm.init_params(model, jax.random.PRNGKey(0)))
-    text, image = _batch(cfg)
+    text, image = batch(cfg)
     lowered = jax.jit(jax.grad(
         lambda p: model.apply(p, text, image)[0])).trace(params).lower(
             lowering_platforms=("tpu",)).as_text()
@@ -515,126 +564,3 @@ def test_the_rotary_turns_interleaved_pairs_as_complex_numbers():
     np.testing.assert_allclose(np.linalg.norm(pairs(got), axis=-1),
                                np.linalg.norm(pairs(x), axis=-1), rtol=1e-4)
 
-
-TINY_FLAGS = [
-    "--hidden-size", "64", "--num-hidden-layers", "3", "--num-heads", "4",
-    "--num-kv-heads", "4", "--expert-width", "32", "--num-experts", "8",
-    "--experts-per-token", "2", "--experts-held", "4", "--expert-offset",
-    "2", "--vocab-size", "96", "--text-seq-len", "24", "--image-grid", "4",
-    "--vocab-text", "48", "--vocab-image", "48", "--dtype", "float32",
-    "--head-chunk", "16", "--dense-width", "96", "--q-lora-rank", "48",
-    "--kv-lora-rank", "32"]
-# no flag sets a head's three widths (``no_flag``): the preset's own
-AS_FLAGGED = {**TINY, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
-              "v_head_dim": 128}
-
-
-def test_the_preset_trains_through_the_peers_normal_path(lowering_record):
-    """``run_trainer --preset joyaiflash`` (+ tiny field flags): the parser
-    builds the preset's own class, TrainingTask the model its configuration
-    names, and train_loop runs it with the swarm optimizer; the rows of the
-    trainer's ring carry the model's records and the two losses."""
-    from dalle_tpu.obs.trace import default_tracer
-    from dalle_tpu.task import TrainingTask
-    from dalle_tpu.training.loop import train_loop
-
-    args = run_trainer.build_parser().parse_args(
-        ["--preset", "joyaiflash", *TINY_FLAGS,
-         "--per-device-batch", "1", "--grad-accum-steps", "2",
-         "--target-batch-size", str(1 << 30), "--seed", "7"])
-    configs = run_trainer.configs_from_args(args)
-    assert configs[0] == JoyAILMConfig(**AS_FLAGGED)
-    task = TrainingTask(*configs)
-    assert family(task.model_cfg) is sparse_lm
-    assert isinstance(task.model, sparse_lm.SparseLM)
-    losses = []
-    with task:
-        train_loop(task, max_steps=3, warmup_steps=1,
-                   on_step=lambda n, loss: losses.append(loss))
-    assert len(losses) == 3 and all(np.isfinite(losses))
-    rows = [r for r in default_tracer().dump() if r.get("plane") == "train"]
-    warm = [r for r in rows if r["phase"] == "setup/warmup"][-1]["a"]
-    # the sentences, whole, as the operator reads them (from an empty
-    # record: the token-major sum has no gate, and another test's sum of
-    # these shapes in this process would be this model's too)
-    assert warm["moe_layout"] == (
-        "4 of 8 experts held (2-5), top 2 of 8, sigmoid, bias, norm, x2.5, "
-        "a shared expert of 32, layers 0-0 dense 96, no exchange: 8 devices, "
-        "data parallel; token-major sums: none traced (the dense lowering)")
-    assert warm["attn_operands"] == "sliced: no Mosaic backend"
-    assert warm["attn_layout"] == (
-        "latent 48 / 32 + one rotary key of 64, heads 4 x (128 + 64 | 128), "
-        "dense XLA lowering (no Mosaic backend), rotary (XLA: no Mosaic "
-        "backend)")
-    assert warm["mtp_layout"].startswith("one prediction module after the "
-                                         "final norm")
-    steps = [r for r in rows if r["phase"] == "loop/step"][-3:]
-    for row, loss in zip((r["a"] for r in steps), losses):
-        assert row["moe_dropped"] == 0.0
-        # the dense lowering in each of the three expert layers (the
-        # module's among them) of every shard
-        assert row["moe_dense_calls"] == 3.0 * task.mesh.size
-        assert row["loss_main"] + 0.3 * row["loss_mtp"] == pytest.approx(
-            loss, rel=1e-5)
-    assert task.model_cfg.optimizer_stacking()["stacked_experts"] == 4
-
-
-def test_the_preset_is_a_class_of_its_own_and_the_parents_keep_theirs():
-    """``benchmark/configs/{smallthinker21b,trinitymini}.json`` hold
-    ``asdict`` of the two parent classes: what the new class states as
-    fields are class attributes there, and no key of theirs is new."""
-    sparse = {f.name for f in dataclasses.fields(SparseLMConfig)}
-    afmoe = {f.name for f in dataclasses.fields(AfmoeLMConfig)}
-    joyai = {f.name for f in dataclasses.fields(JoyAILMConfig)}
-    assert len(sparse) == 27 and len(afmoe) == 39
-    added = joyai - afmoe
-    assert added == {"q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-                     "qk_rope_head_dim", "v_head_dim", "rope_interleave",
-                     "num_nextn_predict_layers", "mtp_loss_weight"}
-    for parent in (SparseLMConfig(), AfmoeLMConfig()):
-        assert not set(dataclasses.asdict(parent)) & added
-        assert not any(getattr(parent, name) for name in added)   # off
-    cfg = joyaiflash_model_config()
-    assert type(cfg) is JoyAILMConfig and isinstance(cfg, AfmoeLMConfig)
-    cfg.validate()
-    assert (cfg.hidden_size, cfg.q_lora_rank, cfg.kv_lora_rank) == (
-        2048, 1536, 512)
-    assert (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
-            cfg.num_heads) == (128, 64, 128, 32)
-    assert (cfg.dense_width, cfg.expert_width, cfg.num_experts,
-            cfg.experts_per_token, cfg.route_scale) == (7168, 768, 256, 8,
-                                                        2.5)
-    assert (cfg.rope_theta, cfg.rms_eps) == (3.2e7, 1e-6)
-    assert {cfg.kind_of_layer(i) for i in range(5)} == {"full_rope"}
-    flags = {a.dest for a in run_trainer.build_parser()._actions}
-    assert {"q_lora_rank", "kv_lora_rank", "num_nextn_predict_layers"} \
-        <= flags
-    stated = set(JoyAILMConfig.no_flag) - set(AfmoeLMConfig.no_flag)
-    assert stated == {"qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
-                      "rope_interleave", "mtp_loss_weight"}
-    assert not flags & stated
-    # the kind and the class's widths decide: ``full_rope`` without
-    # ``kv_lora_rank`` is grouped key-value heads with rotary over the whole
-    # sequence (tests/test_lfm2_model.py), any class's
-    SparseLMConfig(layer_kinds=("full_rope",)).validate()
-    with pytest.raises(ValueError, match="unknown layer kind"):
-        SparseLMConfig(layer_kinds=("latent",)).validate()
-    with pytest.raises(ValueError, match="interleaved pairs"):
-        dataclasses.replace(cfg, rope_interleave=False).validate()
-    with pytest.raises(ValueError, match="one prediction module"):
-        dataclasses.replace(cfg, num_nextn_predict_layers=2).validate()
-
-
-@pytest.mark.parametrize("cli, argv", [
-    (run_inference, ["--checkpoint-dir", "x", "--tokenizer-path", "y",
-                     "--query", "a cat"]),
-    (run_server, ["--random-init"]),
-    (run_aux_peer, []),
-])
-def test_entry_points_that_decode_refuse_the_preset_at_start(cli, argv):
-    with pytest.raises(SystemExit) as refused:
-        cli.main(["--preset", "joyaiflash", *argv])
-    message = str(refused.value)
-    assert "joyaiflash" in message and "models/decode.py" in message
-    assert "latent attention" in message and "prediction module" in message
-    assert message.count(".") <= 3 and "\n" not in message   # one sentence

@@ -1,30 +1,29 @@
 """``KeyeLMConfig`` (preset ``keyevl2``) through models/sparse_lm.py at a
 tiny size, seeded random weights, f32, ``index_topk`` under the sequence's
-length so that the selection bites and three unequal position rows: loss
-and every gradient leaf against the plain reference of its yardstick under
-both lowerings; the selected sets are ``lax.top_k`` of the reference's
-scores, ties to the lower key; neither loss's gradient reaches the other's
-leaves; equal rows are the one-row rotary bit for bit; the shares add up to
-the uncut layer; the Mosaic kernels interpreted against the dense-mask
-lowering, an empty tile among the cases; the selection's kernel against
-``select_keys`` and ``lax.top_k``, and the lowered step selects by it alone;
-``models/decode.py`` refuses the kind; the preset trains through the peer's
-normal path."""
+length so that the selection bites and three unequal position rows: the
+family's cases over its row (tests/sparse_family.py), and what only it has:
+the selected sets are ``lax.top_k`` of the reference's scores, ties to the
+lower key; neither loss's gradient reaches the other's leaves; equal rows
+are the one-row rotary bit for bit; the sixteen shares add up to the uncut
+layer; the Mosaic kernels interpreted against the dense-mask lowering, an
+empty tile among the cases; the selection's kernel against ``select_keys``
+and ``lax.top_k``, and the lowered step selects by it alone;
+``models/decode.py`` refuses the kind."""
 import dataclasses
-import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import sparse_family as fam
 from benchmark.manifest import Manifest
-from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
-from dalle_tpu.config import (AfmoeLMConfig, JoyAILMConfig, KeyeLMConfig,
-                              SparseLMConfig, keyevl2_model_config)
-from dalle_tpu.models import attention, decode, family, sparse_lm
+from dalle_tpu.config import (KeyeLMConfig, SparseLMConfig,
+                              keyevl2_model_config)
+from dalle_tpu.models import attention, decode, sparse_lm
 from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
 from dalle_tpu.ops.pallas import indexer_kernels
+from sparse_family import as_file, batch, rel_l2
 
 Y = Manifest().yardstick("keye")
 
@@ -38,76 +37,88 @@ TINY = dict(hidden_size=128, num_hidden_layers=2, num_heads=4,
             vocab_size=96, text_seq_len=56, image_grid=4, vocab_text=48,
             vocab_image=48, dtype="float32", head_chunk=16, index_topk=20,
             index_heads=2, index_chunk=32)
-TINY_FLAGS = [str(x) for key, value in TINY.items()
-              for x in ("--" + key.replace("_", "-"), value)]
 
 
-def as_file(cfg):
-    return json.loads(json.dumps(dataclasses.asdict(cfg)))
+class TestKeyevl2(fam.Family):
+    config, preset = KeyeLMConfig, "keyevl2"
+    preset_config, Y = staticmethod(keyevl2_model_config), Y
+    # (no ``KERNEL_WIDTHS``: the tiny model's are the kernels')
+    TINY, EXPERT_LAYERS, PRECISION = TINY, 2, "highest"
+    ADDED = {"index_topk", "index_heads", "index_head_dim", "index_chunk",
+             "indexer_rotary", "indexer_loss_weight", "mrope_section"}
+    PUBLISHED = dict(
+        hidden_size=2048, num_heads=32, num_kv_heads=4, head_dim=128,
+        expert_width=768, num_experts=128, experts_per_token=8,
+        index_topk=2048, index_heads=16, index_head_dim=64, index_chunk=512,
+        mrope_section=(16, 24, 24), rope_theta=1e7, num_hidden_layers=7,
+        experts_held=8, vocab_size=18992, score_func="softmax",
+        hidden_act="silu", qk_norm=True)
+    REFUSAL = ("indexer",)
 
+    def the_yardstick_also(self, *, cfg, aux, grads, ref_grads, weights,
+                           text, image, **_):
+        with jax.default_matmul_precision("highest"):
+            _, (main, align) = Y.loss_fn(weights, text, image, as_file(cfg))
+        assert float(aux["loss_main"]) == pytest.approx(float(main), rel=2e-6)
+        assert float(aux["loss_indexer"]) == pytest.approx(float(align),
+                                                           rel=2e-5)
+        assert float(align) > 0.01                  # the second loss is there
+        assert float(aux["loss"]) == pytest.approx(
+            float(aux["loss_main"]) + cfg.indexer_loss_weight
+            * float(aux["loss_indexer"]), rel=1e-6)
+        t, k = cfg.total_seq_len, cfg.index_topk
+        pairs = sum(min(i + 1, k) for i in range(t))
+        assert float(aux["sparse_selected_pct"]) == pytest.approx(
+            100.0 * pairs / (t * (t + 1) / 2), rel=1e-6)
+        ours, theirs = fam.leaves(grads), fam.leaves(ref_grads)
+        assert sum("['indexer']" in name for name in ours) == 5 * 2
+        for name in ours:       # every leaf counts, the indexer's too
+            assert float(jnp.abs(theirs[name]).max()) > 0, name
 
-def _batch(cfg, seed=0, n=2):
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.integers(2, cfg.vocab_text,
-                                     (n, cfg.text_seq_len)), jnp.int32),
-            jnp.asarray(rng.integers(0, cfg.vocab_image,
-                                     (n, cfg.image_seq_len)), jnp.int32))
+    def the_normal_path_also(self, *, task, names, warm, steps, **_):
+        """The rows carry the two losses, the selected share and the
+        model's records, ``sparse_layout`` among them."""
+        assert sum("['indexer']" in name for name in names) == 5 * 2
+        assert warm["attn_layout"].startswith(
+            "over 20 keys a query, chosen by an indexer of 2 heads of 64 over "
+            "one key head, dense XLA lowering (no Mosaic backend)")
+        assert "three rows by sections [16, 24, 24]" in warm["attn_layout"]
+        assert warm["sparse_layout"] == (
+            "dense masks in XLA code (no Mosaic backend)")
+        assert "softmax over the chosen" in warm["moe_layout"]
+        t, k = 72, 20
+        share = 100.0 * sum(min(i + 1, k) for i in range(t)) / (
+            t * (t + 1) / 2)
+        for row in steps:
+            assert row["loss_indexer"] > 0 and row["loss_main"] > 0
+            assert row["sparse_selected_pct"] == pytest.approx(share, rel=1e-5)
+        assert sparse_lm.step_attributes(task.model_cfg)[-3:] == (
+            "loss_main", "loss_indexer", "sparse_selected_pct")
+        assert sparse_lm.step_attributes(SparseLMConfig())[-1] == \
+            "moe_tiles_active_pct"
 
-
-def rel_l2(a, b):
-    return float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b),
-                                                      1e-30))
-
-
-def _params(cfg, seed=1):
-    """Seeded weights with every vector leaf (norm scales, the indexer's
-    key norm's bias) moved off its initial ones and zeros."""
-    params = sparse_lm.init_params(sparse_lm.build(cfg),
-                                   jax.random.PRNGKey(seed))
-    leaves, tree = jax.tree.flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed + 100), len(leaves))
-    return jax.tree.unflatten(tree, [
-        a + 0.1 * jax.random.normal(k, a.shape) if a.ndim == 1 else a
-        for a, k in zip(leaves, keys)])
-
-
-def _leaves(tree):
-    return {jax.tree_util.keystr(k): v for k, v in
-            jax.tree_util.tree_flatten_with_path(tree)[0]}
-
-
-@pytest.mark.parametrize("with_kernels", [False, True])
-def test_loss_and_every_gradient_leaf_against_the_yardstick(
-        with_kernels, monkeypatch):
-    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", with_kernels)
-    cfg = KeyeLMConfig(**TINY)
-    cfg.validate()
-    params, (text, image) = _params(cfg), _batch(cfg)
-    model = sparse_lm.build(cfg)
-    with jax.default_matmul_precision("highest"):
-        (loss, aux), grads = jax.jit(jax.value_and_grad(
-            lambda p: model.apply(p, text, image), has_aux=True))(params)
-        want, want_grads = Y.loss_and_grads(params, text, image,
-                                            as_file(cfg))
-        _, (main, align) = Y.loss_fn(params, text, image, as_file(cfg))
-    assert float(loss) == pytest.approx(float(want), rel=2e-6)
-    assert float(aux["loss_main"]) == pytest.approx(float(main), rel=2e-6)
-    assert float(aux["loss_indexer"]) == pytest.approx(float(align),
-                                                       rel=2e-5)
-    assert float(align) > 0.01                  # the second loss is there
-    assert float(aux["loss"]) == pytest.approx(
-        float(aux["loss_main"]) + cfg.indexer_loss_weight
-        * float(aux["loss_indexer"]), rel=1e-6)
-    t, k = cfg.total_seq_len, cfg.index_topk
-    pairs = sum(min(i + 1, k) for i in range(t))
-    assert float(aux["sparse_selected_pct"]) == pytest.approx(
-        100.0 * pairs / (t * (t + 1) / 2), rel=1e-6)
-    ours, theirs = _leaves(grads), _leaves(want_grads)
-    assert ours.keys() == theirs.keys()
-    assert sum("['indexer']" in name for name in ours) == 5 * 2
-    for name in ours:
-        assert rel_l2(ours[name], theirs[name]) < 2e-5, name
-        assert float(jnp.abs(theirs[name]).max()) > 0, name
+    def the_class_also(self, cfg, flags):
+        assert {cfg.kind_of_layer(i) for i in range(7)} == {"selected_rope"}
+        assert not any(cfg.layer_is_dense(i) for i in range(7))
+        assert not (cfg.attention_gate or cfg.sandwich_norms or cfg.mup_enabled
+                    or cfg.num_shared_experts or cfg.kv_lora_rank
+                    or cfg.tied_embeddings or cfg.selection_bias)
+        assert "index_topk" in flags and "mrope_section" not in flags
+        # the kind needs a class that states an indexer
+        with pytest.raises(ValueError, match="selected_rope"):
+            SparseLMConfig(layer_kinds=("selected_rope",)).validate()
+        with pytest.raises(ValueError, match="mrope_section"):
+            dataclasses.replace(cfg, mrope_section=(16, 24, 20)).validate()
+        with pytest.raises(ValueError, match="rotates the indexer"):
+            dataclasses.replace(cfg, indexer_rotary=False).validate()
+        # what init_params counts: PERF.md section 4
+        shapes = jax.eval_shape(lambda: sparse_lm.init_params(
+            sparse_lm.build(cfg), jax.random.PRNGKey(0)))
+        assert sum(a.size for a in jax.tree.leaves(shapes)) == 491_848_320
+        layer = shapes["params"]["layer_0"]
+        count = lambda tree: sum(a.size for a in jax.tree.leaves(tree))
+        assert count(layer["attn"]["indexer"]) == 2_260_992 + 128
+        assert count(layer["ff"]["experts"]) == 37_748_736
 
 
 def test_the_selected_sets_are_top_k_of_the_references_scores():
@@ -115,14 +126,14 @@ def test_the_selected_sets_are_top_k_of_the_references_scores():
     yardstick's sets, layer by layer, rows with fewer than ``index_topk``
     keys before them included (they choose every one)."""
     cfg = KeyeLMConfig(**TINY)
-    params, (text, image) = _params(cfg), _batch(cfg)
+    params, (text, image) = fam.params(cfg), batch(cfg)
     model = sparse_lm.build(cfg)
     with jax.default_matmul_precision("highest"):
         theirs = np.asarray(Y.chosen_keys(params, text, image, as_file(cfg)))
-        _, kept = model.apply(
-            params, text, image, mutable=["intermediates"],
+        _, kept = jax.jit(lambda *a: model.apply(
+            *a, mutable=["intermediates"],
             capture_intermediates=lambda m, _: isinstance(
-                m, sparse_lm.Indexer))
+                m, sparse_lm.Indexer)))(params, text, image)
         scale = (cfg.index_heads * cfg.index_head_dim) ** -0.5
         for i in range(cfg.num_hidden_layers):
             qi, ki, w = kept["intermediates"][f"layer_{i}"]["attn"][
@@ -169,11 +180,12 @@ def test_neither_losss_gradient_reaches_the_others_leaves(with_kernels,
     target is the heads' mean with the gradient stopped."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", with_kernels)
     cfg = KeyeLMConfig(**TINY)
-    params, (text, image) = _params(cfg), _batch(cfg)
+    params, (text, image) = fam.params(cfg), batch(cfg)
     model = sparse_lm.build(cfg)
     of = lambda name: jax.jit(jax.grad(
         lambda p: model.apply(p, text, image)[1][name]))(params)
-    main, align = _leaves(of("loss_main")), _leaves(of("loss_indexer"))
+    main, align = (fam.leaves(of(name))
+                   for name in ("loss_main", "loss_indexer"))
     for name in main:
         if "['indexer']" in name:
             assert not np.asarray(main[name]).any(), name
@@ -354,7 +366,7 @@ def test_the_indexers_kernels_are_the_dense_scores_and_their_gradient(
                 sparse_lm.OFF), -1)
             return jnp.sum(coef * jnp.sum(
                 jnp.where(on, -pbar * log_sigma, 0.0), -1))
-        want = jax.grad(loss, argnums=(0, 1, 2))(qi, ki, w)
+        want = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(qi, ki, w)
         _, lse, _ = sparse_lm._align_rows(sel, pbar, 64)
         got = indexer_kernels.index_grads(qi, ki, w, lse, coef, sel, pbar,
                                           scale, 128, True)
@@ -545,53 +557,6 @@ def test_the_predicates_say_why_not():
     assert kernels.fused_backward_fits(8192, 8, 2, selected=True) is None
 
 
-# -- the configuration, the entry points -------------------------------------
-
-def test_the_preset_is_a_class_of_its_own_and_the_parents_keep_theirs():
-    fields = lambda cls: {f.name for f in dataclasses.fields(cls)}
-    added = fields(KeyeLMConfig) - fields(AfmoeLMConfig)
-    assert added == {"index_topk", "index_heads", "index_head_dim",
-                     "index_chunk", "indexer_rotary", "indexer_loss_weight",
-                     "mrope_section"}
-    for parent in (SparseLMConfig(), AfmoeLMConfig(), JoyAILMConfig()):
-        assert not set(dataclasses.asdict(parent)) & added
-        assert not any(getattr(parent, name) for name in added)   # off
-    cfg = keyevl2_model_config()
-    assert type(cfg) is KeyeLMConfig and isinstance(cfg, AfmoeLMConfig)
-    cfg.validate()
-    assert (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-            cfg.expert_width, cfg.num_experts, cfg.experts_per_token) == (
-                2048, 32, 4, 128, 768, 128, 8)
-    assert (cfg.index_topk, cfg.index_heads, cfg.index_head_dim,
-            cfg.index_chunk, cfg.mrope_section, cfg.rope_theta) == (
-                2048, 16, 64, 512, (16, 24, 24), 1e7)
-    assert (cfg.num_hidden_layers, cfg.experts_held, cfg.vocab_size) == (
-        7, 8, 18992)
-    assert {cfg.kind_of_layer(i) for i in range(7)} == {"selected_rope"}
-    assert not any(cfg.layer_is_dense(i) for i in range(7))
-    assert (cfg.score_func, cfg.hidden_act, cfg.qk_norm) == (
-        "softmax", "silu", True)
-    assert not (cfg.attention_gate or cfg.sandwich_norms or cfg.mup_enabled
-                or cfg.num_shared_experts or cfg.kv_lora_rank
-                or cfg.tied_embeddings or cfg.selection_bias)
-    flags = {a.dest for a in run_trainer.build_parser()._actions}
-    assert "index_topk" in flags and "mrope_section" not in flags
-    # the kind needs a class that states an indexer
-    with pytest.raises(ValueError, match="selected_rope"):
-        SparseLMConfig(layer_kinds=("selected_rope",)).validate()
-    with pytest.raises(ValueError, match="mrope_section"):
-        dataclasses.replace(cfg, mrope_section=(16, 24, 20)).validate()
-    with pytest.raises(ValueError, match="rotates the indexer"):
-        dataclasses.replace(cfg, indexer_rotary=False).validate()
-    # what init_params counts: PERF.md section 4
-    shapes = jax.eval_shape(lambda: sparse_lm.init_params(
-        sparse_lm.build(cfg), jax.random.PRNGKey(0)))
-    assert sum(a.size for a in jax.tree.leaves(shapes)) == 491_848_320
-    layer = shapes["params"]["layer_0"]
-    count = lambda tree: sum(a.size for a in jax.tree.leaves(tree))
-    assert count(layer["attn"]["indexer"]) == 2_260_992 + 128
-    assert count(layer["ff"]["experts"]) == 37_748_736
-
 
 def test_decode_refuses_the_kind_by_name():
     with pytest.raises(NotImplementedError) as refused:
@@ -600,67 +565,3 @@ def test_decode_refuses_the_kind_by_name():
     assert "'selected_rope'" in message and "indexer" in message
     assert message.count(".") == 1 and "\n" not in message  # decode.py only
     decode.refuse_selected_layers(SparseLMConfig())         # no such layer
-
-
-@pytest.mark.parametrize("cli, argv", [
-    (run_inference, ["--checkpoint-dir", "x", "--tokenizer-path", "y",
-                     "--query", "a cat"]),
-    (run_server, ["--random-init"]),
-    (run_aux_peer, []),
-])
-def test_entry_points_that_decode_refuse_the_preset_at_start(cli, argv):
-    with pytest.raises(SystemExit) as refused:
-        cli.main(["--preset", "keyevl2", *argv])
-    message = str(refused.value)
-    assert "keyevl2" in message and "models/decode.py" in message
-    assert "indexer" in message
-    assert message.count(".") <= 3 and "\n" not in message   # one sentence
-
-
-def test_the_preset_trains_through_the_peers_normal_path(lowering_record):
-    """``run_trainer --preset keyevl2`` (+ tiny field flags): the parser
-    builds the preset's own class, TrainingTask the model its configuration
-    names, and train_loop runs it with the swarm optimizer; the rows of the
-    trainer's ring carry the two losses, the selected share and the
-    model's records, ``sparse_layout`` among them."""
-    from dalle_tpu.obs.trace import default_tracer
-    from dalle_tpu.task import TrainingTask
-    from dalle_tpu.training.loop import train_loop
-
-    args = run_trainer.build_parser().parse_args(
-        ["--preset", "keyevl2", *TINY_FLAGS,
-         "--per-device-batch", "1", "--grad-accum-steps", "2",
-         "--target-batch-size", str(1 << 30), "--seed", "7"])
-    configs = run_trainer.configs_from_args(args)
-    assert configs[0] == KeyeLMConfig(**TINY)
-    task = TrainingTask(*configs)
-    assert family(task.model_cfg) is sparse_lm
-    losses = []
-    with task:
-        train_loop(task, max_steps=3, warmup_steps=1,
-                   on_step=lambda n, loss: losses.append(loss))
-        names = [jax.tree_util.keystr(path) for path, _ in
-                 jax.tree_util.tree_flatten_with_path(
-                     task.collab_optimizer.state.params)[0]]
-    assert len(losses) == 3 and all(np.isfinite(losses))
-    assert sum("['indexer']" in name for name in names) == 5 * 2
-    rows = [r for r in default_tracer().dump() if r.get("plane") == "train"]
-    warm = [r for r in rows if r["phase"] == "setup/warmup"][-1]["a"]
-    assert warm["attn_layout"].startswith(
-        "over 20 keys a query, chosen by an indexer of 2 heads of 64 over "
-        "one key head, dense XLA lowering (no Mosaic backend)")
-    assert "three rows by sections [16, 24, 24]" in warm["attn_layout"]
-    assert warm["sparse_layout"] == (
-        "dense masks in XLA code (no Mosaic backend)")
-    assert "softmax over the chosen" in warm["moe_layout"]
-    steps = [r for r in rows if r["phase"] == "loop/step"][-3:]
-    t, k = 72, 20
-    share = 100.0 * sum(min(i + 1, k) for i in range(t)) / (t * (t + 1) / 2)
-    for row in (r["a"] for r in steps):
-        assert row["loss_indexer"] > 0 and row["loss_main"] > 0
-        assert row["sparse_selected_pct"] == pytest.approx(share, rel=1e-5)
-        assert row["moe_dropped"] == 0.0
-    assert sparse_lm.step_attributes(task.model_cfg)[-3:] == (
-        "loss_main", "loss_indexer", "sparse_selected_pct")
-    assert sparse_lm.step_attributes(SparseLMConfig())[-1] == \
-        "moe_tiles_active_pct"

@@ -90,7 +90,8 @@ class TestModelIntegration:
     @staticmethod
     def _model(ln_fusion):
         from dalle_tpu.config import flagship_model_config
-        from dalle_tpu.models.dalle import DALLE, init_params
+        from dalle_init import init_params
+        from dalle_tpu.models.dalle import DALLE
 
         # dim 128 so ln_supported passes; head_chunk off for tiny vocab
         cfg = flagship_model_config(
@@ -115,8 +116,11 @@ class TestModelIntegration:
         def loss(m):
             return lambda p: m.apply(p, text, image)[0]
 
-        l_u = float(loss(model)(params))
-        l_f = float(loss(model_f)(params))
+        # one jitted program a model: loss and gradients in one trace
+        # (four eager passes through the interpreted kernels took 150-190 s)
+        l_u, g_u = jax.jit(jax.value_and_grad(loss(model)))(params)
+        l_f, g_f = jax.jit(jax.value_and_grad(loss(model_f)))(params)
+        l_u, l_f = float(l_u), float(l_f)
         assert abs(l_u - l_f) / abs(l_u) < 1e-3, (l_u, l_f)
 
         # Forward parity is exact (loss diff 0.0 measured in f32); the
@@ -124,8 +128,6 @@ class TestModelIntegration:
         # fast-variance chain — algebraically equal, differently rounded,
         # and the per-layer ulps compound through 9 layers of backprop to
         # rel ~1e-3 (largest at the embeddings). Tolerance sized to that.
-        g_u = jax.grad(loss(model))(params)
-        g_f = jax.grad(loss(model_f))(params)
         for a, b in zip(jax.tree_util.tree_flatten(g_u)[0],
                         jax.tree_util.tree_flatten(g_f)[0]):
             np.testing.assert_allclose(

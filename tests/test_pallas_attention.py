@@ -58,8 +58,8 @@ class TestFusedAxial:
             out = axial_attention(q, k, v, attn_type, TEXT, GRID)
             return jnp.sum(out * w)
 
-        g_fused = jax.grad(loss_fused, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+        g_fused = jax.jit(jax.grad(loss_fused, argnums=(0, 1, 2)))(q, k, v)
+        g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g_fused, g_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=5e-4, atol=5e-5)
@@ -103,8 +103,8 @@ class TestFusedWindow:
                                       conv_kernel=3)
             return jnp.sum(out * w)
 
-        g_fused = jax.grad(loss_fused, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+        g_fused = jax.jit(jax.grad(loss_fused, argnums=(0, 1, 2)))(q, k, v)
+        g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g_fused, g_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=5e-4, atol=5e-5)
@@ -129,8 +129,8 @@ class TestFusedWindow:
         np.testing.assert_allclose(np.asarray(fused(q, k, v)),
                                    np.asarray(dense(q, k, v)),
                                    rtol=2e-4, atol=2e-5)
-        g_fused = jax.grad(loss(fused), argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+        g_fused = jax.jit(jax.grad(loss(fused), argnums=(0, 1, 2)))(q, k, v)
+        g_ref = jax.jit(jax.grad(loss(dense), argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g_fused, g_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=5e-4, atol=5e-5)
@@ -149,7 +149,8 @@ class TestRematPolicyPinsKernelReplay:
     def _pallas_count(policy, monkeypatch):
         from dalle_tpu.config import flagship_model_config
         from dalle_tpu.models import attention
-        from dalle_tpu.models.dalle import DALLE, init_params
+        from dalle_init import init_params
+        from dalle_tpu.models.dalle import DALLE
 
         monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
 
@@ -258,13 +259,13 @@ class TestLanePacking:
 
     def test_backward_matches_xla_autodiff(self, attn_type, head_dim):
         q, k, v, w = self._qkv(head_dim, 12)
-        g_fused = jax.grad(
+        g_fused = jax.jit(jax.grad(
             lambda *qkv: jnp.sum(_fused(*qkv, attn_type) * w),
-            argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(
+            argnums=(0, 1, 2)))(q, k, v)
+        g_ref = jax.jit(jax.grad(
             lambda *qkv: jnp.sum(dense_zoo_attention(
                 *qkv, attn_type, TEXT, GRID, conv_kernel=3) * w),
-            argnums=(0, 1, 2))(q, k, v)
+            argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g_fused, g_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=5e-4, atol=5e-5)
@@ -284,8 +285,8 @@ def test_text_rows_dk_dv_are_the_two_parts_summed(attn_type):
     is_text = (jnp.arange(shape[1]) < TEXT)[None, :, None, None]
 
     def grads(fn, weight):
-        return jax.grad(lambda k, v: jnp.sum(fn(q, k, v) * weight),
-                        argnums=(0, 1))(k, v)
+        return jax.jit(jax.grad(lambda k, v: jnp.sum(fn(q, k, v) * weight),
+                                argnums=(0, 1)))(k, v)
 
     fused = lambda q, k, v: _fused(q, k, v, attn_type)  # noqa: E731
     dense = lambda q, k, v: dense_zoo_attention(  # noqa: E731

@@ -37,7 +37,7 @@ def test_vqgan_decodes_codes_to_pixels():
     codes = jnp.asarray(
         np.random.RandomState(0).randint(0, cfg.n_embed,
                                          (2, cfg.code_grid ** 2)), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), codes)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), codes)
     imgs = decode_codes(params, cfg, codes)
     assert imgs.shape == (2, cfg.resolution, cfg.resolution, 3)
     assert imgs.dtype == jnp.uint8
@@ -100,7 +100,7 @@ def test_taming_checkpoint_mapping_roundtrip():
     cfg = tiny_vqgan_config()
     model = VQGANDecoder(cfg)
     codes = jnp.zeros((1, cfg.code_grid ** 2), jnp.int32)
-    params = model.init(jax.random.PRNGKey(1), codes)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), codes)
     sd = _fake_taming_state_dict(cfg, params)
     mapped = map_taming_state_dict(sd, cfg)
 
@@ -128,7 +128,7 @@ def test_clip_scores_shapes_and_selfconsistency():
                          jnp.float32)
     tokens = jnp.asarray(rng.randint(1, cfg.vocab_size, (2, cfg.context_length)),
                          jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), images, tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), images, tokens)
     scores = clip_scores(params, cfg, images, tokens)
     assert scores.shape == (3, 2)
     assert np.all(np.abs(np.asarray(scores)) <= 1.0 + 1e-5)  # cosine range
@@ -215,7 +215,7 @@ def test_openai_checkpoint_mapping_preserves_scores():
                          jnp.float32)
     tokens = jnp.asarray(rng.randint(1, cfg.vocab_size,
                                      (2, cfg.context_length)), jnp.int32)
-    params = model.init(jax.random.PRNGKey(2), images, tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(2), images, tokens)
     sd = _fake_openai_state_dict(cfg, params)
     mapped = jax.tree.map(jnp.asarray, map_openai_state_dict(sd, cfg))
     want = np.asarray(clip_scores(params, cfg, images, tokens))

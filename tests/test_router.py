@@ -17,8 +17,9 @@ import jax
 import numpy as np
 import pytest
 
+from dalle_init import init_params
 from dalle_tpu.config import ServingConfig, tiny_model_config
-from dalle_tpu.models.dalle import DALLE, init_params
+from dalle_tpu.models.dalle import DALLE
 from dalle_tpu.models.decode import (SamplingConfig, generate_images,
                                      resolve_buckets)
 from dalle_tpu.serving.engine import DecodeEngine
@@ -264,11 +265,22 @@ class TestFailover:
         503); the router retries on the surviving engine and the client
         gets the exact solo codes. Nothing is orphaned on the dead
         engine. The admit-stall chaos seam holds the request in the
-        dying engine long enough to make the race deterministic."""
+        dying engine until the engine is told to stop (the stall's end
+        is the test's to give, not 0.6 s of the box's clock), so the
+        order of the race is fixed whatever the load."""
         from dalle_tpu.serving.chaos import ServeChaos, ServeFaultPlan
         cfg, params = flat_setup
         text = _text(cfg)
-        chaos = ServeChaos(ServeFaultPlan.from_dict(
+        stalled = threading.Event()
+
+        class HeldAtAdmit(ServeChaos):
+            def _stall(self, rule, roll):
+                self._count("stall")
+                stalled.set()
+                with dying._cv:         # stop() notifies under it
+                    dying._cv.wait_for(lambda: dying._stopping, timeout=60)
+
+        chaos = HeldAtAdmit(ServeFaultPlan.from_dict(
             {"seed": 0, "rules": [{"ops": ["admit"],
                                    "stall_s": [0.6, 0.6]}]}))
         dying = DecodeEngine(params, cfg,
@@ -299,7 +311,8 @@ class TestFailover:
 
             t = threading.Thread(target=client, daemon=True)
             t.start()
-            time.sleep(0.3)           # inside the admit stall window
+            assert stalled.wait(60)   # inside the admit stall: counted
+            assert chaos.injected == {"stall": 1}
             table["b-backup"]["queue_depth"] = 0   # backup now best
             dying.stop(drain=False)   # the engine dies mid-request
             t.join(timeout=90)

@@ -13,10 +13,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dalle_init import init_params
 from dalle_tpu.config import (ATTN_AXIAL_COL, ATTN_AXIAL_ROW, ATTN_CONV_LIKE,
                               ATTN_FULL, tiny_model_config)
 from dalle_tpu.models.attention import dense_zoo_attention
-from dalle_tpu.models.dalle import DALLE, init_params
+from dalle_tpu.models.dalle import DALLE
 from dalle_tpu.parallel.mesh import make_mesh
 from dalle_tpu.parallel.sequence import sp_zoo_attention
 
@@ -32,12 +33,22 @@ def _qkv(rng_seed: int = 0):
     return q, k, v
 
 
+def dense(q, k, v, *stated, **kw):
+    """The dense layer as one program (eagerly, a compile an operation)."""
+    return jax.jit(lambda q, k, v: dense_zoo_attention(
+        q, k, v, *stated, **kw))(q, k, v)
+
+
+def sp(q, k, v, **kw):
+    return jax.jit(lambda q, k, v: sp_zoo_attention(q, k, v, **kw))(q, k, v)
+
+
 def test_ring_matches_dense_full():
     mesh = make_mesh(dp=2, fsdp=1, tp=1, sp=4)
     q, k, v = _qkv()
-    want = dense_zoo_attention(q, k, v, ATTN_FULL, TEXT, GRID)
-    got = sp_zoo_attention(q, k, v, mesh=mesh, mode="ring",
-                           attn_type=ATTN_FULL, text_len=TEXT, grid=GRID)
+    want = dense(q, k, v, ATTN_FULL, TEXT, GRID)
+    got = sp(q, k, v, mesh=mesh, mode="ring", attn_type=ATTN_FULL,
+             text_len=TEXT, grid=GRID)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -45,9 +56,9 @@ def test_ring_matches_dense_full():
 def test_ring_with_tp_axis():
     mesh = make_mesh(dp=1, fsdp=2, tp=2, sp=2)
     q, k, v = _qkv(1)
-    want = dense_zoo_attention(q, k, v, ATTN_FULL, TEXT, GRID)
-    got = sp_zoo_attention(q, k, v, mesh=mesh, mode="ring",
-                           attn_type=ATTN_FULL, text_len=TEXT, grid=GRID)
+    want = dense(q, k, v, ATTN_FULL, TEXT, GRID)
+    got = sp(q, k, v, mesh=mesh, mode="ring", attn_type=ATTN_FULL,
+             text_len=TEXT, grid=GRID)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -57,10 +68,9 @@ def test_ring_with_tp_axis():
 def test_ulysses_matches_dense(attn_type):
     mesh = make_mesh(dp=2, fsdp=1, tp=2, sp=2)
     q, k, v = _qkv(2)
-    want = dense_zoo_attention(q, k, v, attn_type, TEXT, GRID, conv_kernel=3)
-    got = sp_zoo_attention(q, k, v, mesh=mesh, mode="ulysses",
-                           attn_type=attn_type, text_len=TEXT, grid=GRID,
-                           conv_kernel=3)
+    want = dense(q, k, v, attn_type, TEXT, GRID, conv_kernel=3)
+    got = sp(q, k, v, mesh=mesh, mode="ulysses", attn_type=attn_type,
+             text_len=TEXT, grid=GRID, conv_kernel=3)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 

@@ -23,8 +23,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dalle_init import init_params
 from dalle_tpu.config import ServingConfig, tiny_model_config
-from dalle_tpu.models.dalle import DALLE, init_params
+from dalle_tpu.models.dalle import DALLE
 from dalle_tpu.models.decode import (SamplingConfig, bucket_bounds,
                                      generate_images, init_cache,
                                      resolve_buckets)

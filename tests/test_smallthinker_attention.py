@@ -27,7 +27,7 @@ def _grads(q, k, v, w, window, block=128):
     def weighed(q, k, v):
         out = K.causal_attention(q, k, v, window, block, True)
         return jnp.sum(out.astype(jnp.float32) * w)
-    return jax.grad(weighed, (0, 1, 2))(q, k, v)
+    return jax.jit(jax.grad(weighed, (0, 1, 2)))(q, k, v)
 
 
 @pytest.fixture
@@ -116,8 +116,8 @@ def test_blockwise_attention_matches_dense(t, group, kv_heads, window, block,
         def weighed(q, k, v):
             out = attention(q, k, v).astype(jnp.float32)
             return jnp.sum(out * w), out
-        (_, out), grads = jax.value_and_grad(weighed, (0, 1, 2),
-                                             has_aux=True)(q, k, v)
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            weighed, (0, 1, 2), has_aux=True))(q, k, v)
         return out, grads
 
     (out, got), (ref, want) = out_and_grads(blockwise), out_and_grads(dense)

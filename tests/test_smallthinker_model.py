@@ -1,108 +1,100 @@
 """The sparse language model (models/sparse_lm.py) at a tiny size, seeded
-random weights, f32: against the plain reference of its yardstick; the
-shares of the expert layer add up to the uncut layer; no token is dropped
-whatever the router does; and the model trains through the peer's normal
-path (run_trainer's parser, TrainingTask, train_loop)."""
+random weights, f32, as ``SparseLMConfig`` (preset ``smallthinker21b``), the
+root of the family's classes: the family's cases over its row
+(tests/sparse_family.py), and what only it has: the shares of the expert
+layer add up to the uncut layer; no token is dropped whatever the router
+does; the token-major sums and the expert block by both lowerings."""
 import dataclasses
-import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import sparse_family as fam
 from benchmark.manifest import Manifest
-from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
+from dalle_tpu.cli import run_trainer
 from dalle_tpu.config import ModelConfig, SparseLMConfig
+from dalle_tpu.config import smallthinker21b_model_config
 from dalle_tpu.models import attention, family, sparse_lm
 from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
 from dalle_tpu.ops.pallas import token_sum_kernels as token_sum
+from sparse_family import as_file, batch, rel_l2
 
 Y = Manifest().yardstick("smallthinker")
 
+# both layer kinds, a sequence (28) longer than the window (8), half of the
+# router's experts held
 TINY = dict(hidden_size=64, num_hidden_layers=4, num_heads=4, num_kv_heads=2,
             head_dim=16, expert_width=32, num_experts=8, experts_per_token=2,
             experts_held=4, expert_offset=2, vocab_size=96, window=8,
             text_seq_len=12, image_grid=4, vocab_text=48, vocab_image=48,
             dtype="float32", head_chunk=16)
+# the attention's blockwise Pallas kernels, interpreted
+KERNEL_WIDTHS = dict(head_dim=128)
 
 
-def as_file(cfg):
-    return json.loads(json.dumps(dataclasses.asdict(cfg)))
+class TestSmallthinker21b(fam.Family):
+    config, preset = SparseLMConfig, "smallthinker21b"
+    preset_config, Y = staticmethod(smallthinker21b_model_config), Y
+    TINY, KERNEL_WIDTHS, EXPERT_LAYERS = TINY, KERNEL_WIDTHS, 4
+    MOVED = False       # the weights as ``init_params`` draws them
+    # ... and the band of its one padded tile, an edge in sub-tiles
+    BLOCKWISE = {"full_nope": (None, 256), "window_rope": (8, 256)}
+    ADDED = set()       # the root: the family's own fields
+    # every width is the source's (``reduced``: depth, experts held, vocab)
+    PUBLISHED = dict(hidden_size=2560, num_heads=28, num_kv_heads=4,
+                     head_dim=128, expert_width=768, num_experts=64,
+                     experts_per_token=6, window=4096, rope_theta=1.5e6,
+                     rms_eps=1e-6)
+    REFUSAL = ("grouped key-value heads", "expert layer")
 
+    def the_yardstick_also(self, *, cfg, aux, with_kernels, lowering_record,
+                           said, **_):
+        assert {cfg.kind_of_layer(i) for i in range(4)} == {"full_nope",
+                                                           "window_rope"}
+        # the two fields' means weigh back to the loss: 11 and 16 targets
+        assert float(aux["loss"]) == pytest.approx(
+            (11 * float(aux["loss_text"]) + 16 * float(aux["loss_img"])) / 27,
+            rel=1e-6)
+        # (28 tokens: the rotary's pass wants rows in eights, the test below)
+        assert lowering_record.first_refusal(
+            ("rotary", (28, heads * cfg.head_dim, cfg.head_dim))
+            for heads in (4, 2)) == (
+                "28 rows are not whole sublane tiles of 8" if with_kernels else
+                "no Mosaic backend")
+        assert said["attn_layout"] == (
+            f"blockwise 512: {4 * with_kernels} of 4 layers, 1 full no-rope + "
+            "3 window 8 rope, 2 query heads a key-value head"
+            + (", backward: one kernel a tile (4 of 4 layers), rotary (XLA: "
+               "28 rows are not whole sublane tiles of 8)" if with_kernels else
+               ", rotary (XLA: no Mosaic backend)"))
+        # the band's account, a layer kind, from the function the kernels use
+        assert said.get("attn_band") == (
+            "1 full_nope: 1 tile, 1 at an edge by sub-tiles of 256, visited "
+            "over allowed pairs 1.9961 -> 1.4971; 3 window_rope: 1 tile, 1 at "
+            "an edge by sub-tiles of 256, visited over allowed pairs 64.4405 "
+            "-> 48.3304" if with_kernels else None)
 
-def _batch(cfg, seed=0, n=2):
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.integers(2, cfg.vocab_text,
-                                     (n, cfg.text_seq_len)), jnp.int32),
-            jnp.asarray(rng.integers(0, cfg.vocab_image,
-                                     (n, cfg.image_seq_len)), jnp.int32))
-
-
-def rel_l2(a, b):
-    return float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b),
-                                                      1e-30))
-
-
-def _system(cfg, params, text, image):
-    model = sparse_lm.build(cfg)
-    return jax.jit(jax.value_and_grad(
-        lambda p: model.apply(p, text, image), has_aux=True))(params)
-
-
-@pytest.mark.parametrize("kernels", [False, True])
-def test_loss_and_every_gradient_leaf_against_the_yardstick(
-        kernels, monkeypatch, lowering_record):
-    """Whole tiny model, both layer kinds, a sequence (28) longer than the
-    window (8), half of the router's experts held; with ``kernels`` the
-    attention runs the blockwise Pallas kernels, interpreted."""
-    cfg = SparseLMConfig(**dict(TINY, head_dim=128 if kernels else 16))
-    assert {cfg.kind_of_layer(i) for i in range(4)} == {"full_nope",
-                                                       "window_rope"}
-    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", kernels)
-    params = sparse_lm.init_params(sparse_lm.build(cfg),
-                                   jax.random.PRNGKey(1))
-    text, image = _batch(cfg)
-    (loss, aux), grads = _system(cfg, params, text, image)
-    ref_loss, ref_grads = Y.loss_and_grads(params, text, image, as_file(cfg))
-    assert float(loss) == pytest.approx(float(ref_loss), rel=2e-6)
-    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
-                            jax.tree.leaves(ref_grads)):
-        assert rel_l2(g, r) < 2e-5, jax.tree_util.keystr(path)
-    # the two fields' means weigh back to the loss: 11 and 16 targets
-    assert float(aux["loss"]) == pytest.approx(
-        (11 * float(aux["loss_text"]) + 16 * float(aux["loss_img"])) / 27,
-        rel=1e-6)
-    assert 0 < float(aux["moe_assignments_here_pct"]) < 100
-    assert float(aux["moe_dropped"]) == 0.0
-    # which lowering every layer took, and the one backward kernel a tile
-    for kind, window in (("full_nope", None), ("window_rope", 8)):
-        call = f"{kind} attention", (28, 4 * cfg.head_dim, 2 * cfg.head_dim)
-        assert lowering_record.why_not(*call) == (
-            None if kernels else "no Mosaic backend")
-        if kernels:
-            # ... and the band of its one padded tile, an edge in sub-tiles
-            assert lowering_record.recorded(*call) == {
-                "why_not": None, "split_backward": None,
-                "band": sparse_lm.kernels.band_account(1, 512, window, 256)}
-    # (28 tokens: the rotary's pass wants rows in eights, the test below)
-    assert lowering_record.first_refusal(
-        ("rotary", (28, heads * cfg.head_dim, cfg.head_dim))
-        for heads in (4, 2)) == (
-            "28 rows are not whole sublane tiles of 8" if kernels else
-            "no Mosaic backend")
-    assert sparse_lm.engagement_records(cfg)["attn_layout"] == (
-        f"blockwise 512: {4 * kernels} of 4 layers, 1 full no-rope + 3 "
-        "window 8 rope, 2 query heads a key-value head"
-        + (", backward: one kernel a tile (4 of 4 layers), rotary (XLA: 28 "
-           "rows are not whole sublane tiles of 8)" if kernels else
-           ", rotary (XLA: no Mosaic backend)"))
-    # the band's account, a layer kind, from the function the kernels use
-    assert sparse_lm.engagement_records(cfg).get("attn_band") == (
-        "1 full_nope: 1 tile, 1 at an edge by sub-tiles of 256, visited "
-        "over allowed pairs 1.9961 -> 1.4971; 3 window_rope: 1 tile, 1 at "
-        "an edge by sub-tiles of 256, visited over allowed pairs 64.4405 -> "
-        "48.3304" if kernels else None)
+    def the_normal_path_also(self, *, warm, steps, **_):
+        """The sentences, whole, as the operator reads them."""
+        assert type(run_trainer.configs_from_args(
+            run_trainer.build_parser().parse_args(["--preset", "tiny"]))[0]) \
+            is ModelConfig
+        assert warm["moe_layout"] == (
+            "4 of 8 experts held (2-5), top 2 of 8, softmax over the chosen, "
+            "no exchange: 8 devices, data parallel; token-major sums: none "
+            "traced (the dense lowering)")
+        assert warm["attn_layout"] == (
+            "blockwise 512: 0 of 4 layers, 1 full no-rope + 3 window 8 rope, "
+            "2 query heads a key-value head, rotary (XLA: no Mosaic backend)")
+        assert warm["layer_loop"] == (
+            "unrolled: 4 layers, each rematerialised but its attention")
+        for row in steps:
+            assert 0 < row["moe_assignments_here_pct"] < 100
+            assert row["moe_load_max_over_mean"] >= 1.0
+            assert row["moe_sum_spills"] == 0.0      # a dense call has no runs
+            assert row["moe_tiles_active_pct"] == 0.0    # and no grid of tiles
 
 
 @pytest.mark.parametrize("interpret, budget, words", [
@@ -120,11 +112,7 @@ def test_attn_layout_says_which_backward_the_layers_took(
     if budget:
         monkeypatch.setattr(sparse_lm.kernels, "VMEM_LIMIT_BYTES", budget)
     cfg = SparseLMConfig(**dict(TINY, head_dim=128))
-    text, image = _batch(cfg)
-    params = sparse_lm.init_params(sparse_lm.build(cfg),
-                                   jax.random.PRNGKey(1))
-    jax.eval_shape(lambda p: sparse_lm.build(cfg).apply(p, text, image),
-                   params)
+    fam.trace(cfg)
     layout = sparse_lm.engagement_records(cfg)["attn_layout"]
     assert layout.startswith(f"blockwise 512: {4 * interpret} of 4 layers, "
                              "1 full no-rope + 3 window 8 rope, ")
@@ -147,11 +135,7 @@ def test_attn_layout_says_which_lowering_the_rotary_took(
     cfg = SparseLMConfig(**dict(TINY, head_dim=head_dim,
                                 text_seq_len=text_len))
     if interpret is not None:
-        text, image = _batch(cfg)
-        params = sparse_lm.init_params(sparse_lm.build(cfg),
-                                       jax.random.PRNGKey(1))
-        jax.eval_shape(lambda p: sparse_lm.build(cfg).apply(p, text, image),
-                       params)
+        fam.trace(cfg)
     layout = sparse_lm.engagement_records(cfg)["attn_layout"]
     assert layout.endswith(f", rotary {words}") and "normed" not in layout
     none = dataclasses.replace(cfg, layer_kinds=("full_nope",) * 4)
@@ -166,22 +150,13 @@ def test_the_rotary_in_its_pass_is_the_xla_lowering(monkeypatch,
     same f32 model to its rounding."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
     cfg = SparseLMConfig(**dict(TINY, head_dim=128, text_seq_len=16))
-    params = sparse_lm.init_params(sparse_lm.build(cfg),
-                                   jax.random.PRNGKey(1))
-    text, image = _batch(cfg)
     # the queries' 4 heads and the keys' 2
     took = lambda: {lowering_record.why_not(
         "rotary", (cfg.total_seq_len, heads * 128, 128)) for heads in (4, 2)}
-    (loss, _), grads = _system(cfg, params, text, image)
-    assert took() == {None}
-    monkeypatch.setattr(sparse_lm.head_norm, "fits",
-                        lambda *a: "the test says so")
-    (ref_loss, _), ref_grads = _system(cfg, params, text, image)
-    assert took() == {"the test says so"}
-    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
-    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
-                            jax.tree.leaves(ref_grads)):
-        assert rel_l2(g, r) < 1e-5, jax.tree_util.keystr(path)
+    assert fam.a_pass_is_its_xla_lowering(
+        cfg, took, lambda: monkeypatch.setattr(
+            sparse_lm.head_norm, "fits", lambda *a: "the test says so"),
+        moved=False) == ({None}, {"the test says so"})
 
 
 def test_the_reference_at_the_sets_the_program_chose():
@@ -191,9 +166,9 @@ def test_the_reference_at_the_sets_the_program_chose():
     routing weights are the softmax of its own scores at those experts."""
     cfg = SparseLMConfig(**TINY)
     model = sparse_lm.build(cfg)
-    params = sparse_lm.init_params(model, jax.random.PRNGKey(2))
-    text, image = _batch(cfg)
-    _, kept = model.apply(params, text, image, mutable=["intermediates"])
+    params, (text, image) = fam.params(cfg, 2, moved=False), batch(cfg)
+    _, kept = jax.jit(lambda *a: model.apply(
+        *a, mutable=["intermediates"]))(params, text, image)
     ours = np.stack([np.asarray(kept["intermediates"][f"layer_{i}"]
                                 ["chosen"][0]) for i in range(4)])
     assert ours.shape == (4, 2, 28, cfg.experts_per_token)
@@ -354,7 +329,8 @@ def test_the_kernel_over_runs_against_one_gather_a_slot(case, weighted,
     idx = jnp.asarray(router(rng, n, k, experts), jnp.int32)
     assert all(len(set(row)) == k for row in np.asarray(idx))
     p = jax.nn.softmax(jnp.asarray(rng.normal(size=(n, k)), jnp.float32), -1)
-    plan = sparse_lm.dispatch_plan(idx, offset, held, n * k)
+    plan = jax.jit(sparse_lm.dispatch_plan, static_argnums=(1, 2, 3))(
+        idx, offset, held, n * k)
     rows = jnp.asarray(rng.normal(size=(plan.token.shape[0], d)), dtype)
     written = int(plan.written)
     assert written < rows.shape[0]       # some tile holds no group
@@ -415,7 +391,8 @@ def test_tokens_go_to_rows_by_runs_as_by_one_gather(case, dtype,
     k, experts, held, offset, d = 2, 8, 4, 2, 64
     rng = np.random.default_rng(11)
     idx = jnp.asarray(router(rng, n, k, experts), jnp.int32)
-    plan = sparse_lm.dispatch_plan(idx, offset, held, n * k)
+    plan = jax.jit(sparse_lm.dispatch_plan, static_argnums=(1, 2, 3))(
+        idx, offset, held, n * k)
     source = jnp.asarray(rng.normal(size=(n, d)), dtype)
     assert sparse_lm.rows_why_not(n, plan.token.shape[0], held, d,
                                   dtype) is None
@@ -716,68 +693,6 @@ def test_which_lowering_takes_tokens_to_rows_is_read_off_its_shapes(
         "of 1280 rows)")
 
 
-TINY_FLAGS = [
-    "--hidden-size", "64", "--num-hidden-layers", "4", "--num-heads", "4",
-    "--num-kv-heads", "2", "--head-dim", "16", "--expert-width", "32",
-    "--num-experts", "8", "--experts-per-token", "2", "--experts-held", "4",
-    "--expert-offset", "2", "--vocab-size", "96", "--window", "8",
-    "--text-seq-len", "12", "--image-grid", "4", "--vocab-text", "48",
-    "--vocab-image", "48", "--dtype", "float32", "--head-chunk", "16"]
-
-
-def test_the_preset_trains_through_the_peers_normal_path(lowering_record):
-    """``run_trainer --preset smallthinker21b`` (+ tiny field flags):
-    the parser builds the preset's own class, TrainingTask builds the
-    model its configuration names, and train_loop runs it with the swarm
-    optimizer; the rows of the trainer's ring carry the model's records."""
-    from dalle_tpu.obs.trace import default_tracer
-    from dalle_tpu.task import TrainingTask
-    from dalle_tpu.training.loop import train_loop
-
-    args = run_trainer.build_parser().parse_args(
-        ["--preset", "smallthinker21b", *TINY_FLAGS,
-         "--per-device-batch", "1", "--grad-accum-steps", "2",
-         "--target-batch-size", str(1 << 30), "--seed", "7"])
-    configs = run_trainer.configs_from_args(args)
-    assert configs[0] == SparseLMConfig(**TINY)
-    assert type(run_trainer.configs_from_args(
-        run_trainer.build_parser().parse_args(["--preset", "tiny"]))[0]) \
-        is ModelConfig
-    task = TrainingTask(*configs)
-    assert family(task.model_cfg) is sparse_lm
-    assert isinstance(task.model, sparse_lm.SparseLM)
-    losses = []
-    with task:
-        train_loop(task, max_steps=3, warmup_steps=1,
-                   on_step=lambda n, loss: losses.append(loss))
-    assert len(losses) == 3 and all(np.isfinite(losses))
-    rows = [r for r in default_tracer().dump() if r.get("plane") == "train"]
-    warm = [r for r in rows if r["phase"] == "setup/warmup"][-1]["a"]
-    # the sentences, whole, as the operator reads them (from an empty
-    # record: the token-major sum has no gate, and another test's sum of
-    # these shapes in this process would be this model's too)
-    assert warm["moe_layout"] == (
-        "4 of 8 experts held (2-5), top 2 of 8, softmax over the chosen, no "
-        "exchange: 8 devices, data parallel; token-major sums: none traced "
-        "(the dense lowering)")
-    assert warm["attn_layout"] == (
-        "blockwise 512: 0 of 4 layers, 1 full no-rope + 3 window 8 rope, 2 "
-        "query heads a key-value head, rotary (XLA: no Mosaic backend)")
-    assert warm["layer_loop"] == ("unrolled: 4 layers, each rematerialised "
-                                  "but its attention")
-    steps = [r for r in rows if r["phase"] == "loop/step"][-3:]
-    for row in (r["a"] for r in steps):
-        assert 0 < row["moe_assignments_here_pct"] < 100
-        assert row["moe_load_max_over_mean"] >= 1.0
-        assert row["moe_dropped"] == 0.0
-        # no Mosaic backend here: the dense lowering in every layer of
-        # every shard, and said so
-        assert row["moe_dense_calls"] == 4.0 * task.mesh.size
-        assert row["moe_sum_spills"] == 0.0      # a dense call has no runs
-        assert row["moe_tiles_active_pct"] == 0.0    # and no grid of tiles
-    # the optimizer was told the expert axis by the configuration
-    assert task.model_cfg.optimizer_stacking()["stacked_experts"] == 4
-
 
 def test_what_a_configuration_states_is_a_field_and_not_a_flag():
     """The four facts of the source that ``validate`` holds to one value,
@@ -808,18 +723,3 @@ def test_the_dalle_keeps_its_records_and_its_step_rows():
     assert cfg.optimizer_stacking() == {"stacked_reps": 0,
                                         "stacked_experts": 0}
     assert len(dataclasses.fields(ModelConfig)) == 29
-
-
-@pytest.mark.parametrize("cli, argv", [
-    (run_inference, ["--checkpoint-dir", "x", "--tokenizer-path", "y",
-                     "--query", "a cat"]),
-    (run_server, ["--random-init"]),
-    (run_aux_peer, []),
-])
-def test_entry_points_that_decode_refuse_the_preset_at_start(cli, argv):
-    with pytest.raises(SystemExit) as refused:
-        cli.main(["--preset", "smallthinker21b", *argv])
-    message = str(refused.value)
-    assert "smallthinker21b" in message and "models/decode.py" in message
-    assert "grouped key-value heads" in message and "expert layer" in message
-    assert message.count(".") <= 3 and "\n" not in message   # one sentence

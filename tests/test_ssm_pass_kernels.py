@@ -18,6 +18,7 @@ from dalle_tpu.config import NemotronHLMConfig
 from dalle_tpu.models import attention, sparse_lm
 from dalle_tpu.ops.pallas import ssm_pass_kernels as K
 from dalle_tpu.parallel.mesh import make_mesh
+from sparse_family import rel_l2
 
 # samples, tokens, H P, G N, heads (the lanes of dt), taps, groups,
 # tokens a grid step where not the kernels' own
@@ -31,11 +32,6 @@ BF16_SHAPES = {
     "one_tile": (1, 32, 128, 128, 2, 4, 1, K.ROWS),
 }
 EPS = 1e-5
-
-
-def rel_l2(a, b):
-    a, b = (np.asarray(v, np.float32) for v in (a, b))
-    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
 
 
 def _operands(shape, dtype):

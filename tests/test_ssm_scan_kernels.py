@@ -18,6 +18,7 @@ from dalle_tpu.config import NemotronHLMConfig
 from dalle_tpu.models import attention, sparse_lm
 from dalle_tpu.ops.pallas import ssm_scan_kernels as K
 from dalle_tpu.parallel.mesh import make_mesh
+from sparse_family import rel_l2
 
 Y = Manifest().yardstick("nemotronh")
 OPERANDS = ("x", "B", "C", "dt", "a", "d")
@@ -31,11 +32,6 @@ SHAPES = {
 # tokens a grid step where not the kernels' own: the state, and its
 # cotangent, carried from one grid step to the next
 STEP_TOKENS = {"two_steps_two_heads_a_group": 256}
-
-
-def rel_l2(a, b):
-    a, b = (np.asarray(v, np.float32) for v in (a, b))
-    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
 
 
 def _operands(shape, dtype, seed=0):

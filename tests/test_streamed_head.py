@@ -15,6 +15,7 @@ import pytest
 
 from dalle_tpu.config import JoyAILMConfig
 from dalle_tpu.models import sparse_lm
+from sparse_family import rel_l2
 
 # eight chunks, and arrays large enough that the ratio of two bf16 errors
 # is its expectation to a few per cent
@@ -70,11 +71,6 @@ def operands(rows, n_sums, tied, dtype, seed=0):
             jnp.asarray(0.3 * kernel, dtype),
             jnp.asarray(rng.integers(0, VOCAB, rows), jnp.int32),
             jnp.asarray(weights, jnp.float32))
-
-
-def rel_l2(a, b):
-    a, b = (jnp.asarray(x, jnp.float32) for x in (a, b))
-    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
 
 def with_total(sums):

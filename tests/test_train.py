@@ -7,9 +7,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dalle_init import init_params
 from dalle_tpu.config import OptimizerConfig, tiny_model_config
 from dalle_tpu.data.synthetic import SyntheticCodes
-from dalle_tpu.models.dalle import DALLE, init_params
+from dalle_tpu.models.dalle import DALLE
 from dalle_tpu.optim import global_norm, lamb, make_lr_schedule, make_optimizer
 from dalle_tpu.parallel.mesh import batch_sharding, make_mesh
 from dalle_tpu.parallel.sharding import param_shardings
@@ -127,7 +128,8 @@ class TestTrainStep:
         asked for before accepting the narrower scan carry)."""
         from dalle_tpu.config import tiny_model_config
         from dalle_tpu.data.synthetic import SyntheticCodes
-        from dalle_tpu.models.dalle import DALLE, init_params
+        from dalle_init import init_params
+        from dalle_tpu.models.dalle import DALLE
 
         kw = dict(depth=9, dtype="bfloat16", shared_block_cycle=2,
                   final_conv_block=True)
