@@ -130,16 +130,21 @@ that holds a chosen pair's score and the kernels' mask value elsewhere, which
 the blockwise kernels read a tile of beside ``q``, ``k`` and ``v``
 (``causal_attention_kernels.selected_*``: the band's every tile is
 visited, so the step's time does not depend on the selection); the heads'
-mean probability is a second (T, T) f32 array written by a kernel from the
-forward's statistics, the loss's row sums are XLA code over both, and its
-gradient to ``qI``, ``kI`` and ``w`` is one kernel that makes the scores'
-cotangent tile by tile. All of it is one derivative rule
+mean probability is made a tile at a time by a kernel from the forward's
+statistics, which in the forward pass sums each tile into the loss's rows
+where it is made (a row's KL, log-sum-exp and count: no (T, T) array of
+the mean there) and in the backward pass writes the mean alone, a second
+(T, T) f32 array; the rows' log-sum-exp is kept a layer with the
+statistics; the loss's gradient to ``qI``, ``kI`` and ``w`` is one kernel
+that makes the scores' cotangent tile by tile. All of it is one derivative
+rule
 (:func:`_selected_kernels`) that makes the backward's arrays in the
 backward pass between two barriers, so that one layer's are alive at a
 time. No (T, T) array exists per head. Scopes ``attn/indexer/proj``,
 ``attn/indexer/scores`` (``scores[mosaic]``, forward and gradient),
 ``attn/indexer/select`` (``select[mosaic]``), ``attn/indexer/align``
-(``align[mosaic]`` and the row sums), ``attn/qk_norm``, ``attn[mosaic]``.
+(``align[mosaic]``, both forms, and nothing else), ``attn/qk_norm``,
+``attn[mosaic]``.
 
 What ``NemotronHLMConfig`` describes (its defaults: the 52-layer stack of
 Nemotron-Labs-TwoTower-30B-A3B-Base-BF16, nvidia, ``model_type``
@@ -631,7 +636,6 @@ def _kth_largest(keys: jax.Array, k: jax.Array) -> jax.Array:
 # program, which no compile cache of the chip's kept: PERF.md section 6, PR
 # 52), for about a sixth more keys counted than each chunk's own width.
 SELECT_GROUPS = 3
-ALIGN_GROUPS = 4
 
 
 def _chunk_groups(chunks: int, first: int, groups: int):
@@ -716,37 +720,6 @@ def select_keys(scores: jax.Array, topk: int, chunk: int) -> jax.Array:
     return whole[:, :t, :t]
 
 
-@functools.partial(jax.jit, static_argnums=2)
-def _align_rows(sel: jax.Array, pbar: jax.Array, chunk: int):
-    """Each row's KL(pbar || softmax of ``sel`` over its set), its
-    log-sum-exp of ``sel`` there and the keys in its set: (B, T) f32 each.
-    ``pbar`` is read on the sets only (and nowhere above the diagonal's
-    tiles). ``chunk`` rows at a time, over the keys up to the last row of
-    the chunk's group."""
-    t = sel.shape[1]
-    pad = -t % chunk
-    if pad:
-        sel = jnp.pad(sel, ((0, 0), (0, pad), (0, pad)), constant_values=OFF)
-        pbar = jnp.pad(pbar, ((0, 0), (0, pad), (0, pad)))
-
-    def rows_of(rows, x, target):
-        on = x > OFF
-        lse = jax.nn.logsumexp(x, axis=-1)
-        target = jnp.where(on, target, 0.0)
-        kl = jnp.sum(jax.scipy.special.xlogy(target, target)
-                     - target * jnp.where(on, x - lse[..., None], 0.0),
-                     axis=-1)
-        return kl, lse, jnp.sum(on, axis=-1, dtype=jnp.float32)
-
-    out = []
-    for first, after in _chunk_groups((t + pad) // chunk, 0, ALIGN_GROUPS):
-        r0, last = first * chunk, after * chunk
-        out.append(_by_chunks(rows_of, (sel[:, r0:last, :last],
-                                        pbar[:, r0:last, :last]), r0, chunk))
-    return tuple(jnp.concatenate(parts, axis=1)[:, :t]
-                 for parts in zip(*out))
-
-
 # the indexer's three kernels inside the selected attention's rules: no
 # choice of their own (``selected_attend`` made it), a row of the record
 # each for what their tracing costs, at every call of a rule
@@ -769,23 +742,24 @@ def _chosen_keys(qi, ki, w, topk: int, scale: float):
                 scores, topk, kernels.BLOCK, lowering.interpret())[:, :t, :t]
 
 
-def _mean_and_rows(q, k, stats, sel, chunk: int):
-    """The heads' mean probability and the loss's row sums
-    (:func:`_align_rows`) under the scope ``indexer/align``."""
+def _aligned(form, q, k, stats, sel):
+    """One form of the heads' mean's kernel (``selected_loss_rows``,
+    ``selected_mean_probs``) under the scope ``indexer/align``: the
+    kernel and nothing else (the caller cuts its rows to T). One key of the
+    record for both: one site."""
     with jax.named_scope("indexer"), jax.named_scope("align"), \
             lowering.traced(ALIGNMENT_SITE,
-                            (q.shape[1], q.shape[2], k.shape[2], chunk)):
-        pbar = kernels.selected_mean_probs(q, k, stats, sel, kernels.BLOCK,
-                                           lowering.interpret())
-        return pbar, _align_rows(sel, pbar, chunk)
+                            (q.shape[1], q.shape[2], k.shape[2])):
+        return form(q, k, stats, sel, kernels.BLOCK, lowering.interpret())
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
 def _selected_kernels(q, k, v, qi, ki, w, topk: int, chunk: int,
                       scale: float):
-    """Attention over the chosen keys and the indexer's loss, on Mosaic but
-    for the loss's row sums: returns the context, each
-    sample's KL summed over its rows, and its chosen pairs.
+    """Attention over the chosen keys and the indexer's loss, on Mosaic:
+    returns the context, each sample's KL summed over its rows, and its
+    chosen pairs. ``chunk`` is the dense lowering's (its selection's row
+    chunks), taken so that the two lowerings share their statics.
 
     One derivative rule for all of it, so that what the backward pass needs
     of the three (T, T) arrays (the selection, the heads' mean probability;
@@ -795,33 +769,44 @@ def _selected_kernels(q, k, v, qi, ki, w, topk: int, chunk: int,
     differentiation, the compiler's schedule holds every layer's arrays at
     once: 0.75 GiB a layer at 8 192 tokens (PERF.md section 6, PR 52). The
     rule keeps the six operands, the context and the statistics, as
-    ``causal_attention`` does."""
+    ``causal_attention`` does, and each row's log-sum-exp over its set
+    ((B, T) f32), which the loss's gradient reads."""
     return _selected_kernels_fwd(q, k, v, qi, ki, w, topk, chunk, scale)[0]
 
 
 def _selected_kernels_fwd(q, k, v, qi, ki, w, topk, chunk, scale):
+    t = q.shape[1]
     sel = _chosen_keys(qi, ki, w, topk, scale)
     ctx, stats = kernels.selected_forward(q, k, v, sel, kernels.BLOCK,
                                           lowering.interpret())
     ctx = checkpoint_name(ctx, "attn_out")
     stats = checkpoint_name(stats, "attn_stats")
-    _, (kl, _, count) = _mean_and_rows(q, k, stats, sel, chunk)
+    # the loss's rows, summed where the mean's tiles are made: no (T, T)
+    # array of the mean in the forward pass
+    rows = _aligned(kernels.selected_loss_rows, q, k, stats, sel)[:, :t]
+    # kept with the statistics: a rematerialised layer's replay does not
+    # run the rows' kernel again, nor the backward rule for its sums
+    lse = checkpoint_name(rows[..., kernels.LSE_LANE], "attn_stats")
     # ... and the context is not out before the loss's sums are (see the
     # backward rule): the selection is held for one layer at a time
     out = jax.lax.optimization_barrier(
-        (ctx, jnp.sum(kl, axis=1), jnp.sum(count, axis=1)))
-    return out, (q, k, v, qi, ki, w, ctx, stats)
+        (ctx, jnp.sum(rows[..., kernels.KL_LANE], axis=1),
+         jnp.sum(rows[..., kernels.COUNT_LANE], axis=1)))
+    return out, (q, k, v, qi, ki, w, ctx, stats, lse)
 
 
 def _selected_kernels_bwd(topk, chunk, scale, res, cotangents):
     dctx, dkl, _ = cotangents                # a count carries no gradient
     # nothing below starts before the context's cotangent is there
     dctx, res = jax.lax.optimization_barrier((dctx, res))
-    q, k, v, qi, ki, w, ctx, stats = res
+    q, k, v, qi, ki, w, ctx, stats, lse = res
     sel = _chosen_keys(qi, ki, w, topk, scale)
     dq, dk, dv = kernels.selected_backward(
         q, k, v, sel, ctx, stats, dctx, kernels.BLOCK, lowering.interpret())
-    pbar, (_, lse, _) = _mean_and_rows(q, k, stats, sel, chunk)
+    # the mean alone: ``index_grads`` reads it a tile at a time, and the
+    # rows' log-sum-exp is the forward's
+    t = q.shape[1]
+    pbar = _aligned(kernels.selected_mean_probs, q, k, stats, sel)[:, :t, :t]
     # the scores' backward, with the KL's cotangent made on each tile
     with jax.named_scope("indexer"), jax.named_scope("scores"):
         dqi, dki, dw = index_kernels.index_grads(
@@ -907,7 +892,7 @@ def selected_attend(q, k, v, qi, ki, w, *, mesh, cfg: SparseLMConfig,
             why_not,
             why_not or f"local q{tuple(q.shape)} over k{tuple(k.shape)}, "
             f"{topk} keys a query chosen by {cfg.index_heads} heads of "
-            f"{cfg.index_head_dim}: " + sparse_words(chunk))
+            f"{cfg.index_head_dim}: " + sparse_words())
 
     lanes, samples = P(*LANES_SPEC[:2], None), SAMPLES_SPEC
     return lowering.site(
@@ -919,7 +904,7 @@ def selected_attend(q, k, v, qi, ki, w, *, mesh, cfg: SparseLMConfig,
             q, k, v, qi, ki, w)
 
 
-def sparse_words(chunk: int) -> str:
+def sparse_words() -> str:
     """What the Mosaic lowering of a ``selected_rope`` layer is handed, in
     words (the ``setup/warmup`` row's ``sparse_layout``)."""
     return (
@@ -931,8 +916,9 @@ def sparse_words(chunk: int) -> str:
         "f32 array that holds a chosen pair's score and the mask value "
         "elsewhere, and visit every tile of the causal band (none is "
         "skipped: the walk does not depend on the data); the heads' mean "
-        "probability (T, T) f32 by a kernel from the forward's statistics; "
-        f"the loss's row sums {chunk}-row chunks of XLA code, its gradient "
+        "probability by a kernel from the forward's statistics: the loss's "
+        "row sums in the mean's kernel, forward; the mean alone (T, T) f32, "
+        "backward; the rows' log-sum-exp kept a layer; the loss's gradient "
         "to the indexer one kernel with no (T, T) cotangent")
 
 
@@ -2929,7 +2915,7 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
                 f"one key head, {took}{words}, positions: three rows by "
                 f"sections {list(cfg.mrope_section)}"),
             "sparse_layout": (f"dense masks in XLA code ({why_not})"
-                              if why_not else sparse_words(cfg.index_chunk))}
+                              if why_not else sparse_words())}
     if LAYER_SHORT_CONV in layers:
         said["conv_layout"] = conv_layout(cfg)
     if LAYER_MAMBA2 in layers:

@@ -1180,7 +1180,9 @@ def selected_fits(tokens: int, q_width: int, kv_width: int, head_dim: int,
     """None where the selected-set kernels take these local shapes, else
     why not: one 128-wide head a lane tile, the one-kernel backward (no
     split form is written for a selection), and the mean over the heads
-    with every head of a sample in one grid step."""
+    with every head of a sample in one grid step, in the larger of its two
+    forms: the one that writes the mean's tiles (the other keeps five
+    (block, 128) sums and writes one such block of rows)."""
     if head_dim != LANES:
         return f"head_dim {head_dim} is not one {LANES}-lane tile"
     why_not = blockwise_fits(q_width, kv_width, head_dim)
@@ -1191,7 +1193,9 @@ def selected_fits(tokens: int, q_width: int, kv_width: int, head_dim: int,
         tile = block * block * 4
         need = (2 * block * (q_width + kv_width) * itemsize   # q, k tiles
                 + 2 * (kv_width // LANES) * block * LANES * 4  # statistics
-                + 4 * tile + 4 * tile)          # sel, the mean; s, p, sums
+                + 2 * tile                                     # sel
+                + max(2 * tile, 7 * block * LANES * 4)  # the mean | the rows
+                + 4 * tile)                     # s, p, the heads' sum
         if need > VMEM_LIMIT_BYTES:
             why_not = (f"every head's tile of the mean needs "
                        f"{need / 2 ** 20:.1f} MiB of VMEM, over "
@@ -1273,13 +1277,37 @@ def _selected_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
 
+# lanes of the loss's rows (:func:`selected_loss_rows`)
+KL_LANE, LSE_LANE, COUNT_LANE = 0, 1, 2
+_TINY = float(np.finfo(np.float32).tiny)
+
+
+def _slabs(x):
+    """A (block, W) tile's 128-lane column slabs: whole registers."""
+    return [x[:, _head(c)] for c in range(x.shape[1] // LANES)]
+
+
 def _selected_mean_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
-                          stats_ref, sel_ref, o_ref, *, scale: float,
-                          group: int, block: int, window: Optional[int]):
+                          stats_ref, sel_ref, o_ref, *sums, scale: float,
+                          group: int, block: int, window: Optional[int],
+                          rows: bool = False):
     """A tile of the heads' mean probability: every key-value head of a
     sample and its ``group`` query heads in one grid step, each head's
-    probabilities from the forward's statistics, none of them written."""
-    allowed = _chosen(sel_ref)
+    probabilities from the forward's statistics, none of them written.
+
+    ``rows``: the tile is not written either, but summed into the indexer's
+    loss a row. ``sums`` (m, l, kl, p, n: (block, 128) f32 each) hold a row
+    block over its tiles, lane j the tile columns j mod 128: the running
+    maximum of ``sel`` and sum of ``exp(sel - m)`` (``_softmax_step``'s
+    ``m`` and ``l``, a lane; a lane's masked scores are wiped as a tile's
+    are there), the sums of ``tot (log tot - sel)`` and of ``tot``, the
+    heads' sum ``tot`` = H ``pbar``, and the count of the set. At the row
+    block's last tile the lanes are joined and ``o_ref`` (1, block, 128)
+    gets, a row, KL(pbar || softmax of ``sel`` over the set) = sum pbar
+    (log pbar - sel) + lse sum pbar in :data:`KL_LANE`, the log-sum-exp in
+    :data:`LSE_LANE`, the count in :data:`COUNT_LANE`."""
+    sel = sel_ref[0]                      # (the rows form reads it too)
+    allowed = sel > MASK_VALUE
     heads = k_ref.shape[2] // LANES
     total = jnp.zeros((block, block), jnp.float32)
     for g in range(heads):
@@ -1290,7 +1318,59 @@ def _selected_mean_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                 preferred_element_type=jnp.float32) * scale
             total += jnp.exp(jnp.where(allowed, s, MASK_VALUE)
                              - stats[:, h:h + 1])
-    o_ref[0] = total * (1.0 / (heads * group))
+    if not rows:
+        o_ref[0] = total * (1.0 / (heads * group))
+        return
+
+    # A tile's sums are kept a lane, lane j the tile's columns j mod 128:
+    # its column slabs added register to register. (A lane reduction of
+    # such a tile by the XLU costs five products of its size and a sum as a
+    # product with ones one, PERF.md section 6, PR 58, and the MXU and the
+    # vector unit are this kernel's busiest.) The lanes are joined once a
+    # row block, and the heads' sum is divided there.
+    p = pl.program_id(2)
+    m_s, l_s, kl_s, p_s, n_s = sums
+
+    @pl.when(first_ref[p] == 1)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, -jnp.inf, jnp.float32)
+        for ref in (l_s, kl_s, p_s, n_s):
+            ref[...] = jnp.zeros(ref.shape, jnp.float32)
+
+    added = lambda xs: functools.reduce(jnp.add, xs)
+    x, tot = _slabs(sel), _slabs(total)
+    m_prev = m_s[...]
+    m_next = functools.reduce(jnp.maximum, x, m_prev)
+    l_s[...] = jnp.exp(m_prev - m_next) * l_s[...] + added(
+        [jnp.exp(xc - m_next) for xc in x])
+    m_s[...] = m_next
+    # xlogy(0, 0) = 0, and off the set 0 * (a finite number)
+    kl_s[...] += added([tc * (jnp.log(jnp.maximum(tc, _TINY)) - xc)
+                        for tc, xc in zip(tot, x)])
+    p_s[...] += added(tot)
+    # (compared again a slab: 0.02 ms a call faster than slabs of the mask)
+    n_s[...] += added([jnp.where(xc > MASK_VALUE, 1.0, 0.0) for xc in x])
+
+    @pl.when(last_ref[p] == 1)
+    def _():
+        # a row's sum over the lanes, on every lane: a product with ones
+        ones = jnp.ones((LANES, LANES), jnp.float32)
+        summed = lambda x: jnp.dot(x, ones,
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=jnp.float32)
+        m = jnp.max(m_s[...], axis=1, keepdims=True)
+        lse = m + jnp.log(summed(l_s[...] * jnp.exp(m_s[...] - m)))
+        n, each = summed(n_s[...]), 1.0 / (heads * group)
+        # pbar = the heads' sum * each: sum pbar (log pbar - sel)
+        #   = each * (sum tot (log tot - sel) + log(each) sum tot)
+        pbar = summed(p_s[...]) * each
+        kl = each * summed(kl_s[...]) + (np.log(each) + lse) * pbar
+        lane = jax.lax.broadcasted_iota(jnp.int32, (block, LANES), 1)
+        # (a padded row's statistics are its masked scores': its "mean" is
+        # no nought, and its sums are dropped here)
+        o_ref[0] = jnp.where(
+            lane == KL_LANE, jnp.where(n > 0.0, kl, 0.0), jnp.where(
+                lane == LSE_LANE, lse, jnp.where(lane == COUNT_LANE, n, 0.0)))
 
 
 def _padded_sel(sel, block: int):
@@ -1370,31 +1450,52 @@ def _selected_vjp_bwd(block, interpret, res, cotangents):
 selected_attention.defvjp(_selected_vjp_fwd, _selected_vjp_bwd)
 
 
-def selected_mean_probs(q, k, stats, sel, block: int = BLOCK,
-                        interpret: bool = False):
-    """(B, T, T) f32: the mean over the H query heads of each head's
-    attention probabilities, from :func:`selected_attention`'s statistics;
-    noughts where the key is not in the query's set, and *unwritten* in the
-    tiles above the causal band (mask by ``sel``). No gradient: the caller
-    stops it."""
-    t = q.shape[1]
+def _mean_call(q, k, stats, sel, block: int, interpret: bool, rows: bool):
+    """:func:`_selected_mean_kernel` over the band, in either form."""
     group = q.shape[2] // k.shape[2]
     q, k, sel = _padded(q, block), _padded(k, block), _padded_sel(sel, block)
+    b, t, _ = q.shape
     heads = k.shape[2] // LANES
     spec = lambda shape, at: pl.BlockSpec(
         shape, lambda i, j, p, qi, ki, fi, la: at(i, qi[p], ki[p]))
-    (mean,) = _call(
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    like = (f32(b, t, LANES), "rows") if rows else (f32(*sel.shape), "sel")
+    (out,) = _call(
         _selected_mean_kernel,
         [(q, "q_every"), (k, "kv_every"), (stats, "stats_every"),
          (sel, "sel")],
-        [(jax.ShapeDtypeStruct(sel.shape, jnp.float32), "sel")], [],
-        t=q.shape[1], group=group, block=block, window=None,
-        key_major=False, interpret=interpret, steps=1,
+        [like], [pltpu.VMEM((block, LANES), jnp.float32)] * 5 if rows else [],
+        t=t, group=group, block=block, window=None,
+        key_major=False, interpret=interpret, steps=1, rows=rows,
         more_specs={
             "q_every": spec((1, block, q.shape[2]),
                             lambda i, qb, kb: (i, qb, 0)),
             "kv_every": spec((1, block, k.shape[2]),
                              lambda i, qb, kb: (i, kb, 0)),
             "stats_every": spec((1, heads, block, LANES),
-                                lambda i, qb, kb: (i, 0, qb, 0))})
-    return mean[:, :t, :t]
+                                lambda i, qb, kb: (i, 0, qb, 0)),
+            "rows": spec((1, block, LANES), lambda i, qb, kb: (i, qb, 0))})
+    return out
+
+
+def selected_mean_probs(q, k, stats, sel, block: int = BLOCK,
+                        interpret: bool = False):
+    """(B, T', T') f32, T' = T padded to whole blocks as the statistics
+    are: the mean over the H query heads of each head's attention
+    probabilities, from :func:`selected_attention`'s statistics; noughts
+    where the key is not in the query's set, and *unwritten* in the tiles
+    above the causal band (mask by ``sel``). No gradient: the caller stops
+    it."""
+    return _mean_call(q, k, stats, sel, block, interpret, False)
+
+
+def selected_loss_rows(q, k, stats, sel, block: int = BLOCK,
+                       interpret: bool = False):
+    """(B, T', 128) f32, T' = T padded to whole blocks as the statistics
+    are; a row: KL(pbar || softmax of ``sel`` over the row's set) in
+    :data:`KL_LANE`, ``pbar`` :func:`selected_mean_probs`' mean; the
+    log-sum-exp of ``sel`` over the set in :data:`LSE_LANE`; the keys in the
+    set in :data:`COUNT_LANE` (a padded row: 0, its masked scores', 0). The
+    mean's kernel with the tiles summed where they are made: no (T, T) array
+    is written. No gradient."""
+    return _mean_call(q, k, stats, sel, block, interpret, True)

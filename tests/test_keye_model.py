@@ -328,7 +328,8 @@ def test_the_selected_kernels_are_the_dense_mask_lowering(
             lambda *a: kernels.selected_attention(*a, sel, 128, True),
             q, k, v)
         np.testing.assert_allclose(out, want, atol=2e-5)
-        mean = kernels.selected_mean_probs(q, k, stats, sel, 128, True)
+        mean = kernels.selected_mean_probs(q, k, stats, sel, 128,
+                                           True)[:, :t, :t]
         on = np.asarray(sel > sparse_lm.OFF)
         np.testing.assert_allclose(np.where(on, mean, 0),
                                    np.where(on, want_mean, 0), atol=2e-6)
@@ -338,6 +339,62 @@ def test_the_selected_kernels_are_the_dense_mask_lowering(
             lambda *a: _dense_attention(*a, sel)[0], q, k, v)
         for ours, theirs in zip(got, dense_back(dout)):
             assert rel_l2(ours, theirs) < 2e-5
+
+
+def _every_key_before(sel):
+    """Every row chooses every key up to itself (a sequence's rows before
+    ``index_topk``): scores of its own, the largest finite f32 among them."""
+    t = sel.shape[1]
+    x = np.random.default_rng(t).standard_normal(sel.shape).astype(
+        np.float32) * 4.0
+    x[:, :, 7] = 30.0
+    return jnp.asarray(np.where(np.tril(np.ones((t, t), bool)), x,
+                                sparse_lm.OFF))
+
+
+@pytest.mark.parametrize("t, heads, kv_heads, topk, planted", [
+    (256, 4, 2, 40, None), (256, 2, 2, 40, _an_empty_tile),
+    (200, 4, 1, 64, None), (384, 4, 2, 300, None),
+    (200, 2, 1, 200, _every_key_before)])
+def test_the_loss_rows_are_log_softmax_and_the_dense_kl(t, heads, kv_heads,
+                                                        topk, planted):
+    """The rows form of the heads' mean's kernel at blocks of 128, T a whole
+    number of blocks and not, a tile with no chosen key, rows before
+    ``index_topk`` and sets that are every key before the query: a row's KL
+    against the dense one over the mean form's array, its log-sum-exp
+    against ``logsumexp`` over the set, its count; f32 all."""
+    q, k, v, qi, ki, w = _operands(t, heads, kv_heads, 2, seed=t + topk)
+    with jax.default_matmul_precision("highest"):
+        sel = sparse_lm.select_keys(
+            sparse_lm.dense_index_scores(qi, ki, w, 0.1), topk, 64)
+        if planted is not None:
+            sel = planted(sel)
+        on = sel > sparse_lm.OFF
+        _, stats = kernels.selected_forward(q, k, v, sel, 128, True)
+        rows = kernels.selected_loss_rows(q, k, stats, sel, 128, True)
+        assert rows.shape == (2, t + -t % 128, 128) and rows.dtype == \
+            jnp.float32
+        pbar = jnp.where(on, kernels.selected_mean_probs(
+            q, k, stats, sel, 128, True)[:, :t, :t], 0.0)
+        log_sigma = jax.nn.log_softmax(jnp.where(on, sel, sparse_lm.OFF), -1)
+        want = jnp.sum(jnp.where(on, jax.scipy.special.xlogy(pbar, pbar)
+                                 - pbar * log_sigma, 0.0), -1)
+        lse = jax.nn.logsumexp(jnp.where(on, sel, -jnp.inf), axis=-1)
+    rows = np.asarray(rows)
+    kl = rows[:, :t, kernels.KL_LANE]
+    np.testing.assert_allclose(kl, want, atol=1e-5, rtol=1e-5)
+    assert kl.min() > -1e-5 and kl.max() > 0.1      # (f32 sums: no clamp)
+    np.testing.assert_allclose(rows[:, :t, kernels.LSE_LANE], lse,
+                               atol=4e-6, rtol=1e-6)
+    count = np.minimum(np.arange(t) + 1, topk)
+    assert np.array_equal(np.asarray(on).sum(-1), np.broadcast_to(
+        count, (2, t))) or planted is _an_empty_tile
+    assert np.array_equal(rows[:, :t, kernels.COUNT_LANE],
+                          np.asarray(on).sum(-1))
+    # a padded row: no key, no loss, a finite log-sum-exp
+    assert not rows[:, t:, kernels.KL_LANE].any()
+    assert not rows[:, t:, kernels.COUNT_LANE].any()
+    assert np.isfinite(rows).all()
 
 
 @pytest.mark.parametrize("t, index_heads", [(256, 2), (200, 4), (384, 16)])
@@ -367,7 +424,7 @@ def test_the_indexers_kernels_are_the_dense_scores_and_their_gradient(
             return jnp.sum(coef * jnp.sum(
                 jnp.where(on, -pbar * log_sigma, 0.0), -1))
         want = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(qi, ki, w)
-        _, lse, _ = sparse_lm._align_rows(sel, pbar, 64)
+        lse = jax.nn.logsumexp(jnp.where(on, sel, -jnp.inf), axis=-1)
         got = indexer_kernels.index_grads(qi, ki, w, lse, coef, sel, pbar,
                                           scale, 128, True)
     for ours, theirs in zip(got, want):
@@ -510,6 +567,52 @@ def test_the_derivative_rule_with_the_selection_kernel_is_the_dense_lowering(
             assert rel_l2(mine, plain) < 2e-5, name
 
 
+@pytest.mark.parametrize("kept", [True, False])
+def test_a_rematerialised_layer_keeps_the_rows_log_sum_exp(monkeypatch, kept):
+    """The compiled grad step, every kernel interpreted, runs the rows form
+    of the heads' mean's kernel once a layer (the forward pass) and the mean
+    form once (the backward rule): the layer's replay does not make the
+    rows again, because their log-sum-exp is kept under the statistics'
+    name. Each form's result passes through a host callback that counts,
+    which the compiler drops with a result nothing reads. ``kept`` False:
+    the name left off the (B, T) residual, and the replay runs the rows
+    form a second time (what the name is for, and that the count tells)."""
+    import collections
+
+    from dalle_tpu.training.steps import make_grad_step
+
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    ran = collections.Counter()
+
+    def counted(form):
+        def seen(x):
+            ran[form.__name__] += 1
+            return x
+
+        def through(*operands):
+            out = form(*operands)
+            return jax.pure_callback(
+                seen, jax.ShapeDtypeStruct(out.shape, out.dtype), out)
+        through.__name__ = form.__name__
+        return through
+
+    for form in (kernels.selected_loss_rows, kernels.selected_mean_probs):
+        monkeypatch.setattr(kernels, form.__name__, counted(form))
+    if not kept:
+        named = sparse_lm.checkpoint_name
+        monkeypatch.setattr(
+            sparse_lm, "checkpoint_name",
+            lambda x, name: x if x.ndim == 2 else named(x, name))
+    cfg = KeyeLMConfig(**TINY)
+    (text, image) = batch(cfg)
+    jax.block_until_ready(jax.jit(make_grad_step(
+        sparse_lm.build(cfg), accum_steps=1))(
+            fam.params(cfg), {"text": text, "image": image}))
+    layers = cfg.num_hidden_layers
+    assert ran == {"selected_loss_rows": layers * (1 if kept else 2),
+                   "selected_mean_probs": layers}
+
+
 def test_the_lowered_step_selects_by_the_kernel_alone(monkeypatch):
     """A small grad step lowered for a TPU with the Mosaic lowering:
     ``_index_select_kernel`` wherever the scores are made (a layer's
@@ -537,7 +640,13 @@ def test_the_lowered_step_selects_by_the_kernel_alone(monkeypatch):
     assert found["_index_grads_kernel"] == cfg.num_hidden_layers
     under = set(re.findall(r'attn/indexer/select/([^"/]*)', text))
     assert under == {"pallas_call", "slice"}
-    assert re.search(r'attn/indexer/align/[^"]*_align_rows', text)
+    # the loss's rows are summed in the mean's kernel: no XLA reduction,
+    # slice or loop under the alignment's scope, and its two forms where
+    # the scores are made
+    assert found["_selected_mean_kernel"] == 3 * cfg.num_hidden_layers
+    under = set(re.findall(r'attn/indexer/align/([^"/]*)', text))
+    # (the tiny T padded to whole blocks: ``jnp.pad``; not at the cell's)
+    assert under - {"jit", "jit(_pad)", "pad"} == {"pallas_call"}
 
 
 def test_the_predicates_say_why_not():
