@@ -25,10 +25,12 @@ from typing import Optional, Sequence
 
 from dalle_tpu.config import (AfmoeLMConfig, CollabConfig, JoyAILMConfig,
                               KeyeLMConfig, Lfm2MoeLMConfig, ModelConfig,
-                              NemotronHLMConfig, OptimizerConfig, PeerConfig, SparseLMConfig,
+                              NemotronHLMConfig, OptimizerConfig, PeerConfig,
+                              Qwen3NextLMConfig, SparseLMConfig,
                               TrainerConfig, flagship_model_config,
                               joyaiflash_model_config,
                               keyevl2_model_config, lfm2moe_model_config,
+                              qwen3next80b_model_config,
                               smallthinker21b_model_config,
                               tiny_model_config, trinitymini_model_config,
                               twotower30b_model_config, xl_model_config)
@@ -66,6 +68,11 @@ MODEL_PRESETS = {
     # chunked scan, layers of one part, two-product relu^2 experts):
     # twotower30b-train-solo
     "twotower30b": twotower30b_model_config,
+    # Qwen3-Next-80B-A3B-Instruct cut to one of 32 chips' share of a layer
+    # (gated-delta-rule mixers in three layers of four, gated attention on
+    # 256-wide heads with a quarter of each rotated, 16 of 512 experts at
+    # ten a token beside a gated shared expert): qwen3next80b-train-solo
+    "qwen3next80b": qwen3next80b_model_config,
 }
 
 CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
@@ -74,7 +81,7 @@ CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
 # a field two of them share (vocab_text, dtype, ...) is one flag.
 MODEL_CLASSES = (ModelConfig, SparseLMConfig, AfmoeLMConfig,
                  JoyAILMConfig, Lfm2MoeLMConfig, KeyeLMConfig,
-                 NemotronHLMConfig)
+                 NemotronHLMConfig, Qwen3NextLMConfig)
 
 
 def maybe_wandb_run(project: Optional[str], name: str):

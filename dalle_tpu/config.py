@@ -714,10 +714,16 @@ LAYER_MAMBA2 = "mamba2"
 # no operator at all: the layer is its expert feed-forward alone. Only where
 # a class's layers are one part each (``one_part_layers``)
 LAYER_EXPERTS = "experts"
+# no attention: a gated-delta-rule mixer (``models/sparse_lm.GatedDeltaMixer``:
+# a linear attention whose (key x value) state a head is read back before it
+# is written, ``v_t - S^T k_t``, under a scalar decay a head and token,
+# trained by a chunked form); a class that states its sizes has it
+# (``Qwen3NextLMConfig``)
+LAYER_GATED_DELTA = "gated_delta"
 
 VALID_LAYER_KINDS = (LAYER_FULL_NOPE, LAYER_WINDOW_ROPE, LAYER_FULL_ROPE,
                      LAYER_SHORT_CONV, LAYER_SELECTED_ROPE, LAYER_MAMBA2,
-                     LAYER_EXPERTS)
+                     LAYER_EXPERTS, LAYER_GATED_DELTA)
 
 
 @dataclass(frozen=True)
@@ -832,6 +838,18 @@ class SparseLMConfig:
     ssm_state_size: ClassVar[int] = 0
     ssm_chunk: ClassVar[int] = 0
     residual_rescale_layers: ClassVar[int] = 0
+    # ... and of ``Qwen3NextLMConfig``: the share of a head's lanes that
+    # the rotary turns (1: all of them), a sigmoid gate on the shared
+    # expert's output, and the sizes of the layers of kind ``gated_delta``
+    # (0 heads: no such layer)
+    partial_rotary_factor: ClassVar[float] = 1.0
+    shared_expert_gate: ClassVar[bool] = False
+    linear_num_key_heads: ClassVar[int] = 0
+    linear_num_value_heads: ClassVar[int] = 0
+    linear_key_head_dim: ClassVar[int] = 0
+    linear_value_head_dim: ClassVar[int] = 0
+    linear_conv_kernel_dim: ClassVar[int] = 0
+    delta_chunk: ClassVar[int] = 0
     # fields a configuration's file states and no entry point's flag sets:
     # what the source fixes and models/sparse_lm.py is written for
     # (``validate`` holds each to its one value), and the one assumption
@@ -863,6 +881,12 @@ class SparseLMConfig:
         return layer < self.num_dense_layers
 
     @property
+    def rotary_dim(self) -> int:
+        """The lanes of a head that the rotary turns: its first
+        ``partial_rotary_factor`` of ``head_dim``."""
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
     def shared_width(self) -> int:
         """The shared expert's width (0: none)."""
         return self.shared_expert_width \
@@ -887,6 +911,10 @@ class SparseLMConfig:
                 raise ValueError(
                     f"layer kind {kind!r} is a state-space mixer: a class "
                     "that states its sizes has it (NemotronHLMConfig)")
+            if kind == LAYER_GATED_DELTA and self.linear_num_value_heads < 1:
+                raise ValueError(
+                    f"layer kind {kind!r} is a gated-delta-rule mixer: a "
+                    "class that states its sizes has it (Qwen3NextLMConfig)")
             if kind == LAYER_EXPERTS and not self.one_part_layers:
                 raise ValueError(
                     f"layer kind {kind!r} is a feed-forward with no "
@@ -1394,6 +1422,132 @@ def twotower30b_model_config(**overrides: Any) -> NemotronHLMConfig:
     """Preset ``twotower30b``: the cell ``twotower30b-train-solo``
     (benchmark/configs/twotower30b.json holds ``asdict`` of it)."""
     return dataclasses.replace(NemotronHLMConfig(), **overrides)
+
+@dataclass(frozen=True)
+class Qwen3NextLMConfig(AfmoeLMConfig):
+    """``AfmoeLMConfig``'s gated-SiLU experts behind a softmax router over
+    the chosen that reads the post-attention norm, with a shared expert
+    beside the routed ones and a sigmoid gate on the attention's output over
+    RMS-normed queries and keys (no dense layer, no selection bias, two
+    norms a layer, no embedding scale) with the mechanisms of ``model_type``
+    ``qwen3_next`` as fields: in the layers of kind ``gated_delta`` the
+    operator is no attention but a **gated-delta-rule mixer**
+    (``linear_num_key_heads`` query/key heads of ``linear_key_head_dim``,
+    each serving ``linear_num_value_heads / linear_num_key_heads`` value
+    heads of ``linear_value_head_dim``; ``[q ; k ; v]`` through a depthwise
+    causal convolution of ``linear_conv_kernel_dim`` taps with no bias and a
+    SiLU; L2-normalised queries and keys; a (key x value) state a value head
+    that a scalar decay ``exp(g_t)`` shrinks and the delta rule ``S +=
+    k beta (v - S^T k)^T`` writes; an RMS norm over each head's lanes
+    BEFORE the ``silu(z)`` gate; trained in chunks of ``delta_chunk``
+    tokens), the layers of kind ``full_rope`` are grouped-query attention on
+    heads of 256 lanes, two lane tiles, of which the rotary turns the first
+    ``partial_rotary_factor`` (64 lanes, halves of 32) and leaves the rest,
+    and the shared expert's output is times ``sigmoid(w_g . m)``
+    (``shared_expert_gate``). Defaults are Qwen3-Next-80B-A3B-Instruct
+    (Qwen, config.json; the prediction module its description speaks of has
+    no key there and is not this model's) cut to the share one of the 32
+    chips of a layer holds: published layers 0-3 (three ``gated_delta`` and
+    one ``full_rope``: ``full_attention_interval`` 4), experts 0-15 of 512,
+    an eighth of the vocabulary; every width as published. ``window`` is no
+    layer's."""
+
+    num_hidden_layers: int = 4       # published 48: 12 periods of layer_kinds
+    num_heads: int = 16
+    num_kv_heads: int = 2
+    head_dim: int = 256
+    expert_width: int = 512
+    num_experts: int = 512
+    experts_per_token: int = 10
+    experts_held: int = 16
+    vocab_size: int = 18992          # published 151936
+    window: int = 0
+    layer_kinds: Tuple[str, ...] = (
+        LAYER_GATED_DELTA, LAYER_GATED_DELTA, LAYER_GATED_DELTA,
+        LAYER_FULL_ROPE)
+    rope_theta: float = 1e7
+    rms_eps: float = 1e-6
+    router_softmax_over_chosen: bool = True
+    vocab_text: int = 9496
+    vocab_image: int = 9496
+    num_dense_layers: int = 0
+    dense_width: int = 0
+    num_shared_experts: int = 1      # of shared_expert_intermediate_size 512
+    score_func: str = "softmax"
+    selection_bias: bool = False
+    route_norm: bool = False
+    route_scale: float = 1.0
+    sandwich_norms: bool = False
+    mup_enabled: bool = False
+    partial_rotary_factor: float = 0.25
+    shared_expert_gate: bool = True
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    # how the recurrence is computed, not a change of it
+    delta_chunk: int = 64
+
+    no_flag: ClassVar[Tuple[str, ...]] = AfmoeLMConfig.no_flag + (
+        "shared_expert_gate",)
+    decode_missing: ClassVar[Optional[str]] = (
+        "models/decode.py has no gated-delta-rule mixer (kind 'gated_delta': "
+        "no cache of the (key x value) state a head and of the convolution's "
+        "last taps' tokens), no grouped key-value heads of 256 lanes with a "
+        "partial rotary, head norms and an output gate, and no expert layer "
+        "with a gated shared expert")
+
+    @property
+    def linear_key_lanes(self) -> int:
+        return self.linear_num_key_heads * self.linear_key_head_dim
+
+    @property
+    def linear_value_lanes(self) -> int:
+        return self.linear_num_value_heads * self.linear_value_head_dim
+
+    @property
+    def linear_conv_lanes(self) -> int:
+        """q, k and v side by side: what the depthwise taps run over."""
+        return 2 * self.linear_key_lanes + self.linear_value_lanes
+
+    def validate(self) -> None:
+        super().validate()
+        if self.num_dense_layers or self.sandwich_norms or self.mup_enabled \
+                or not (self.attention_gate and self.qk_norm):
+            raise ValueError(
+                "this class has no dense layer, two norms a layer, no "
+                "embedding scale, and a gated attention over normed queries "
+                "and keys")
+        if any(k not in (LAYER_GATED_DELTA, LAYER_FULL_ROPE)
+               for k in self.layer_kinds):
+            raise ValueError(
+                "a layer of this class is 'gated_delta' or 'full_rope'")
+        if not self.num_shared_experts or not self.shared_expert_gate:
+            raise ValueError("this class has a gated shared expert")
+        rotary = self.head_dim * self.partial_rotary_factor
+        if rotary != int(rotary) or int(rotary) % 2 or not 0 < rotary \
+                <= self.head_dim:
+            raise ValueError(
+                "partial_rotary_factor x head_dim is the even number of a "
+                "head's lanes that the rotary turns")
+        chunk = self.delta_chunk
+        if min(self.linear_num_key_heads, self.linear_num_value_heads,
+               self.linear_key_head_dim, self.linear_value_head_dim,
+               self.linear_conv_kernel_dim, chunk) < 1 \
+                or self.linear_num_value_heads % self.linear_num_key_heads \
+                or chunk & (chunk - 1):
+            raise ValueError(
+                "the gated-delta-rule mixer needs linear_num_value_heads (a "
+                "multiple of linear_num_key_heads), linear_key_head_dim, "
+                "linear_value_head_dim, linear_conv_kernel_dim and a "
+                "delta_chunk that is a power of two")
+
+
+def qwen3next80b_model_config(**overrides: Any) -> Qwen3NextLMConfig:
+    """Preset ``qwen3next80b``: the cell ``qwen3next80b-train-solo``
+    (benchmark/configs/qwen3next80b.json holds ``asdict`` of it)."""
+    return dataclasses.replace(Qwen3NextLMConfig(), **overrides)
 
 
 def tiny_model_config(**overrides: Any) -> ModelConfig:

@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dalle_tpu.config import LAYER_MAMBA2, LAYER_SELECTED_ROPE, ModelConfig
+from dalle_tpu.config import (LAYER_GATED_DELTA, LAYER_MAMBA2,
+                              LAYER_SELECTED_ROPE, ModelConfig)
 from dalle_tpu.models.attention import (NEG_INF, apply_rotary_lanes,
                                         rotary_cos_sin, zoo_attention_mask)
 
@@ -124,15 +125,23 @@ def refuse_selected_layers(cfg) -> None:
 
 def refuse_recurrent_layers(cfg) -> None:
     """A configuration with a layer of kind ``mamba2`` (a state-space
-    mixer, models/sparse_lm.py) cannot be decoded here either: the cache
-    below holds keys and values, not a recurrence's state."""
-    kind = LAYER_MAMBA2
-    if kind in getattr(cfg, "layer_kinds", ()):
+    mixer, models/sparse_lm.py) or ``gated_delta`` (a gated-delta-rule
+    mixer) cannot be decoded here either: the cache below holds keys and
+    values, not a recurrence's state."""
+    kinds = getattr(cfg, "layer_kinds", ())
+    if LAYER_MAMBA2 in kinds:
         raise NotImplementedError(
-            f"models/decode.py cannot decode a layer of kind {kind!r}: it "
+            f"models/decode.py cannot decode a layer of kind "
+            f"{LAYER_MAMBA2!r}: it "
             "keeps no cache of the recurrence's state a head and of the "
             "convolution's last taps' tokens, and has no single-token step "
             "of the scan")
+    if LAYER_GATED_DELTA in kinds:
+        raise NotImplementedError(
+            f"models/decode.py cannot decode a layer of kind "
+            f"{LAYER_GATED_DELTA!r}: it keeps no cache of the (key x value) "
+            "state a head and of the convolution's last taps' tokens, and "
+            "has no single-token step of the delta rule")
 
 
 def init_cache(cfg: ModelConfig, batch: int, dtype=None):

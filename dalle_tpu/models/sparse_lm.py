@@ -1,6 +1,6 @@
 """A sparse decoder-only language model on the trainer's normal path.
 
-Six configurations' equations, each mechanism read from a field of the
+Seven configurations' equations, each mechanism read from a field of the
 configuration and none from a preset's name. What ``SparseLMConfig``
 describes (its defaults: SmallThinker-21BA3B-Instruct, PowerInfer): every
 layer is
@@ -214,6 +214,67 @@ sizes and the rows' transposes are XLA code either way), ``ssm/gate_norm``
 (``gate_norm[mosaic]``, or XLA code), ``ssm/out_proj`` (never under ``attn``
 or ``conv``).
 
+What ``Qwen3NextLMConfig`` describes (its defaults:
+Qwen3-Next-80B-A3B-Instruct, Qwen, ``model_type`` ``qwen3_next``):
+``AfmoeLMConfig``'s two-part layer (two norms, no dense layer, a softmax
+router over the chosen that reads the post-attention norm, gated-SiLU
+experts) whose operator ``cfg.layer_kinds`` names a layer, no bias anywhere:
+
+    gated_delta (:class:`GatedDeltaMixer`, parameters under ``gdn``; G
+    query/key heads of dk, H value heads of dv, value head h reading
+    query/key head h // (H / G); K taps):
+      [q ; k ; v], z, [b ; a] = a . W_qkv, a . W_z, a . W_ba
+                                         hidden -> 2 G dk + H dv, H dv, 2 H
+      [q ; k ; v] <- silu(sum_{j<K} taps[j] * [q ; k ; v]_{t-(K-1)+j})
+                                         depthwise, causal, no bias
+      q^ = q / sqrt(sum q^2 + 1e-6) / sqrt(dk); k^ = k / sqrt(sum k^2 + 1e-6)
+      beta_t = sigmoid(b_t);  g_t = -exp(A_log) softplus(a_t + dt_bias)
+      S_t = e^{g_t} S_{t-1} + k^_t (beta_t (v_t - (e^{g_t} S_{t-1})^T k^_t))^T
+      o_t = S_t^T q^_t                   f32, S (dk, dv) a value head
+      y = rmsnorm over each head's dv lanes of o, THEN times silu(z)
+      h = x + y . W_out
+    full_rope (:class:`Attention`): heads of 256 lanes, two lane tiles;
+      q, k = rmsnorm(q), rmsnorm(k) over a head's lanes (``qk_norm``); the
+      rotary turns a head's first ``partial_rotary_factor`` x ``head_dim``
+      = 64 lanes (rotate-half, halves of 32, a head of 64's frequencies) and
+      leaves the other 192; the context times sigmoid(a . W_gate)
+      (``attention_gate``)
+    the expert block: ``f = sigmoid(m . w_g) shared(m) + sum_{e in S, e held
+      here} p_e . expert_e(m)`` (``shared_expert_gate``: the leaf
+      ``ff/shared_gate``, a vector; under the scope ``ff/shared``)
+
+**How the rule runs** (the site "delta rule", :func:`delta_rule`;
+``gdn_layout`` on the ``setup/warmup`` row says which lowering ran): in
+chunks of ``delta_chunk`` tokens (:func:`chunked_delta_rule`). The delta
+rule reads the state back before it writes, so what a chunk writes with an
+empty state is the solution of a unit lower-triangular system a head: with
+``cs`` the running sum of ``g`` inside the chunk and ``A_ij = beta_i e^{cs_i
+- cs_j} k^_i . k^_j`` (j < i), ``u = (I + A)^-1 (beta v)`` and ``w = (I +
+A)^-1 (beta e^{cs} k^)``; a chunk that starts from the state ``S`` writes ``U
+= u - w S``, reads out ``o = e^{cs} q^ S + (e^{cs_i - cs_j} q^_i . k^_j)_{j
+<= i} U`` and hands on ``e^{cs_last} S + (e^{cs_last - cs} k^)^T U``. The
+inverse is made with no loop (:func:`unit_lower_inverse`: diagonal blocks of
+8 by a finite product, doubled three times by block substitution; its
+derivative two products with the inverse it made), in f32 from three
+bfloat16 pieces an operand; the decays, their sums and the carried states are
+f32; the
+products' operands are in ``cfg.dtype`` with f32 accumulation (the inverse
+too, once made). The chunks are a ``lax.scan`` that carries the state, in
+blocks of ``RULE_BLOCK`` chunks whose in-chunk tables are made at once and
+which are replayed a block under ``jax.checkpoint`` (the states kept are one
+a block, a block's tables alive at a time): nothing of (T, T) and no state a
+token exists, forward, replay or backward. No Mosaic kernel
+is written for the rule yet: the site's XLA lowering is its one lowering,
+and ``gdn/rule`` is where a kernel's time will be read. The taps take the
+Mamba-2 mixer's pass (``ssm_pass_kernels.taps_silu`` with a bias of
+noughts, ``q``, ``k`` and ``v`` written apart) where its predicate takes
+the shapes, the heads' norm :func:`head_pass`'s kernel; the L2 norms, the
+gate and ``beta`` / ``g`` are XLA code. Scopes ``gdn/in_proj``,
+``gdn/conv`` (``taps[mosaic]``, or XLA code), ``gdn/rule`` (the L2 norms,
+``beta``, ``g`` and the chunked form), ``gdn/gate_norm``
+(``qk_norm[mosaic]``: the head pass's name; then the gate) and
+``gdn/out_proj`` (never under ``attn``, ``conv`` or ``ssm``).
+
 **The expert layer is told which experts it holds** (``experts_held``
 consecutive ones from ``expert_offset``): it routes over all
 ``num_experts``, computes the part of the result its own experts give for
@@ -323,7 +384,13 @@ the one key where ``kv_a`` wrote it in a second small call, one lane
 tile's (T, 128) tables for two heads side by side;
 ``head_norm_kernels.pairs_fit`` is the rule, and where it refuses
 :func:`rotary_interleaved_lanes` runs with tables as wide as the array
-(:func:`pair_rotary`). Heads of 64 lanes (``full_rope`` of
+(:func:`pair_rotary`). Heads of 256 lanes (``full_rope`` of
+``Qwen3NextLMConfig``) take the blockwise kernels' third form, one head
+over two lane tiles (the 128-wide kernels with ``width`` 256: the scores'
+product 256 deep, the context 256 wide, the statistics a lane a head as
+before; ``attn_layout`` says "one head of 256 over 2 lane tiles"), and the
+head pass with one lane tile's tables for the 64 lanes it turns. Heads of
+64 lanes (``full_rope`` of
 ``Lfm2MoeLMConfig``) take the blockwise kernels' second form, two heads a
 lane tile (``causal_attention_kernels._halves_*_kernel``: ``attn_layout``
 says "2 heads of 64 a lane tile"), while their head norm and rotary stay on
@@ -356,7 +423,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.custom_derivatives import SymbolicZero
 from jax.sharding import PartitionSpec as P
 
-from dalle_tpu.config import (LAYER_EXPERTS, LAYER_FULL_ROPE, LAYER_MAMBA2,
+from dalle_tpu.config import (LAYER_EXPERTS, LAYER_FULL_ROPE,
+                              LAYER_GATED_DELTA, LAYER_MAMBA2,
                               LAYER_SELECTED_ROPE, LAYER_SHORT_CONV,
                               LAYER_WINDOW_ROPE, SparseLMConfig)
 from dalle_tpu.models import attention as attn_mod
@@ -412,9 +480,26 @@ def position_tables(rows: jax.Array, sections: Tuple[int, ...],
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def partial_rotary_lanes(x: jax.Array, cos: jax.Array, sin: jax.Array,
+                         head_dim: int) -> jax.Array:
+    """The rotary (rotate-half) of the first R lanes of every head of x
+    (B, T, H * head_dim), the head's other lanes as they are; cos, sin: (T,
+    R) of one head (``attention.rotary_cos_sin`` of R). The XLA lowering of
+    the head pass's ``turned`` form: on a reshape to heads, in f32."""
+    b, t, _ = x.shape
+    turned = cos.shape[-1]
+    heads = x.reshape(b, t, -1, head_dim)
+    first = heads[..., :turned].astype(jnp.float32)
+    x1, x2 = first[..., :turned // 2], first[..., turned // 2:]
+    first = first * cos[:, None, :] \
+        + jnp.concatenate([-x2, x1], axis=-1) * sin[:, None, :]
+    return jnp.concatenate([first.astype(x.dtype), heads[..., turned:]],
+                           axis=-1).reshape(x.shape)
+
+
 def head_pass(x, scale=None, *, mesh, eps: float, head_dim: int,
               theta: Optional[float], positions=None,
-              sections: Tuple[int, ...] = ()):
+              sections: Tuple[int, ...] = (), turned: int = 0):
     """The work on each head of x (B, T, H*d) between a projection and the
     attention: the RMS norm over the head's d lanes where there is a
     ``scale`` (one vector for all heads), then the rotary of positions
@@ -424,14 +509,20 @@ def head_pass(x, scale=None, *, mesh, eps: float, head_dim: int,
     that reads one head's tables where it fits
     (ops/pallas/head_norm_kernels.py), else the two expressions it
     replaces: the reshape to heads and :func:`rms_norm`, and
-    ``attention.apply_rotary_lanes`` with tables as wide as the array."""
+    ``attention.apply_rotary_lanes`` with tables as wide as the array.
+    ``turned``: the lanes of a head the rotary turns, its first, where
+    they are not all of them (``partial_rotary_factor``): frequencies of a
+    head of ``turned`` lanes, the rest of the head as it is; the same pass,
+    with one lane tile's tables (:func:`partial_rotary_lanes` is its XLA
+    lowering)."""
     norm, rotary = scale is not None, theta is not None
     name = _head_pass_site(norm, rotary)
+    turned = turned if rotary and turned != head_dim else 0
 
     def cos_sin(tokens: int, heads: int = 1):
         if positions is None:
-            return attn_mod.rotary_cos_sin(jnp.arange(tokens), head_dim,
-                                           theta, heads)
+            return attn_mod.rotary_cos_sin(jnp.arange(tokens),
+                                           turned or head_dim, theta, heads)
         return position_tables(positions, sections, head_dim, theta, heads)
 
     def fits(x, scale=None) -> bool:
@@ -440,21 +531,25 @@ def head_pass(x, scale=None, *, mesh, eps: float, head_dim: int,
         return lowering.chose(
             name, _head_pass_key(t, width, head_dim), why_not,
             why_not or f"local {tuple(x.shape)}: heads of {head_dim} lanes, "
-            f"{head_norm.rows_tile(t, width)} rows a tile")
+            + f"the first {turned} of each rotated, " * bool(turned)
+            + f"{head_norm.rows_tile(t, width)} rows a tile")
 
     def kernel(x, scale=None):
         tables = None
         if rotary:
-            tables = head_norm.rotary_tables(*cos_sin(x.shape[1]))
+            tables = (head_norm.partial_rotary_tables if turned
+                      else head_norm.rotary_tables)(*cos_sin(x.shape[1]))
         return head_norm.per_head(x, scale, tables, eps, head_dim,
-                                  lowering.interpret())
+                                  lowering.interpret(), turned)
 
     def xla(x, scale=None):
         b, t, width = x.shape
         if norm:
             x = rms_norm(x.reshape(b, t, -1, head_dim), scale,
                          eps).reshape(x.shape)
-        if rotary:
+        if turned:
+            x = partial_rotary_lanes(x, *cos_sin(t), head_dim)
+        elif rotary:
             x = attn_mod.apply_rotary_lanes(
                 x, *cos_sin(t, width // head_dim), head_dim)
         return x
@@ -525,9 +620,14 @@ def _band_words(band: Dict[str, int]) -> str:
 
 
 def _heads_a_tile(head_dim: int) -> str:
-    """The kernels' second form, in words; nothing for any other head."""
-    return (f"2 heads of {head_dim} a lane tile, "
-            if head_dim == kernels.HALF else "")
+    """The kernels' second and third forms, in words; nothing for a head
+    of one lane tile."""
+    if head_dim == kernels.HALF:
+        return f"2 heads of {head_dim} a lane tile, "
+    if head_dim > kernels.LANES:
+        return (f"one head of {head_dim} over {head_dim // kernels.LANES} "
+                "lane tiles, ")
+    return ""
 
 
 def _blockwise_site(kind: str) -> str:
@@ -551,7 +651,8 @@ def attend(q, k, v, *, mesh, kind: str, window: Optional[int],
         why_not = kernels.blockwise_fits(q.shape[2], k.shape[2], head_dim)
         group = q.shape[2] // k.shape[2]
         split_why = None if why_not else kernels.fused_backward_fits(
-            q.shape[1], group, q.dtype.itemsize)
+            q.shape[1], group, q.dtype.itemsize,
+            lanes=max(kernels.LANES, head_dim))
         band = None if why_not else kernels.band_of(q.shape[1], window,
                                                     head_dim)
         return lowering.chose(
@@ -981,7 +1082,7 @@ class Attention(nn.Module):
                 return head_pass(x, scale, mesh=self.mesh, eps=cfg.rms_eps,
                                  head_dim=cfg.head_dim,
                                  theta=cfg.rope_theta if rope else None,
-                                 **rows)
+                                 turned=cfg.rotary_dim, **rows)
 
             with jax.named_scope(head_norm.scope(cfg.qk_norm)):
                 q, k = per_head(q, "q_norm"), per_head(k, "k_norm")
@@ -1434,43 +1535,54 @@ def _gate_norm_key(tokens: int, cfg: SparseLMConfig):
             jnp.dtype(cfg.dtype).itemsize)
 
 
-def ssm_taps(zxbcdt, taps, bias, *, mesh, cfg: SparseLMConfig,
-             scope: Optional[str] = None):
-    """The taps, the bias and the SiLU as a call site: ``x``, ``B`` and
-    ``C`` of ``in_proj``'s whole output (B, T, .). The pass of
-    ops/pallas/ssm_pass_kernels.py, which reads ``xBC``'s columns where
-    they lie and writes the three apart, where its predicate takes the local
-    shapes; else :func:`causal_taps_silu` on a slice, and three slices of
-    its result."""
-    inner = cfg.mamba_inner
-    state = cfg.ssm_groups * cfg.ssm_state_size
+def _taps_site(site: str, key, words, operand, taps, bias, *, mesh,
+               scope: Optional[str]):
+    """The taps, a bias and the SiLU as a call site of either mixer: the
+    parts of ``operand`` (B, T, .) that ``key`` names (tokens, the lane they
+    start at, their widths, the taps, bytes a number), each written as an
+    array of its own. The pass of ops/pallas/ssm_pass_kernels.py, which
+    reads the parts' columns where they lie, where its predicate takes the
+    local shapes; else :func:`causal_taps_silu` on a slice, and slices of its
+    result. ``words(operand, key)``: what the log says the kernel was
+    given."""
+    _, before, widths, _, _ = key
+    ends = np.cumsum(widths)
 
-    def fits(zxbcdt, taps, bias) -> bool:
-        key = _taps_key(zxbcdt.shape[1], cfg)
-        why_not = ssm_pass_kernels.taps_fit(*key)
-        return lowering.chose(
-            TAPS_SITE, key, why_not, why_not or (
-                f"local xBC{zxbcdt.shape[:2] + (cfg.mamba_conv_lanes,)} "
-                f"read at lane {inner} of {zxbcdt.shape[2]}, "
-                f"{ssm_pass_kernels.rows_tile(key[0], key[-1])} tokens a "
-                "grid step"))
+    def fits(operand, taps, bias) -> bool:
+        local = (operand.shape[1], *key[1:])
+        why_not = ssm_pass_kernels.taps_fit(*local)
+        return lowering.chose(site, local, why_not,
+                              why_not or words(operand, local))
 
-    def kernel(zxbcdt, taps, bias):
+    def kernel(operand, taps, bias):
         f32 = jnp.float32
         return ssm_pass_kernels.taps_silu(
-            zxbcdt, taps.astype(f32), bias.astype(f32), inner,
-            (inner, state, state), lowering.interpret())
+            operand, taps.astype(f32), bias.astype(f32), before, widths,
+            lowering.interpret())
 
-    def xla(zxbcdt, taps, bias):
-        xbc = causal_taps_silu(
-            zxbcdt[..., inner:inner + cfg.mamba_conv_lanes], taps, bias)
-        return (xbc[..., :inner], xbc[..., inner:inner + state],
-                xbc[..., inner + state:])
+    def xla(operand, taps, bias):
+        out = causal_taps_silu(operand[..., before:before + ends[-1]], taps,
+                               bias)
+        return tuple(out[..., end - width:end]
+                     for end, width in zip(ends, widths))
 
     lanes = P(*LANES_SPEC[:2], None)
-    return lowering.site(TAPS_SITE, fits, kernel, xla, mesh,
-                         (lanes, P(), P()), (lanes,) * 3, scope)(
-                             zxbcdt, taps, bias)
+    return lowering.site(site, fits, kernel, xla, mesh, (lanes, P(), P()),
+                         (lanes,) * len(widths), scope)(operand, taps, bias)
+
+
+def ssm_taps(zxbcdt, taps, bias, *, mesh, cfg: SparseLMConfig,
+             scope: Optional[str] = None):
+    """The Mamba-2 mixer's taps, bias and SiLU (:func:`_taps_site`): ``x``,
+    ``B`` and ``C`` of ``in_proj``'s whole output (B, T, .)."""
+    def words(zxbcdt, key):
+        return (f"local xBC{zxbcdt.shape[:2] + (cfg.mamba_conv_lanes,)} "
+                f"read at lane {cfg.mamba_inner} of {zxbcdt.shape[2]}, "
+                f"{ssm_pass_kernels.rows_tile(key[0], key[-1])} tokens a "
+                "grid step")
+
+    return _taps_site(TAPS_SITE, _taps_key(zxbcdt.shape[1], cfg), words,
+                      zxbcdt, taps, bias, mesh=mesh, scope=scope)
 
 
 def ssm_gate_norm(y, zxbcdt, scale, *, mesh, cfg: SparseLMConfig,
@@ -1606,6 +1718,362 @@ def ssm_layout(cfg: SparseLMConfig) -> str:
         f"token; {scan}; taps, bias and SiLU: {taps}; gate and group norm: "
         f"{gate_norm}; the replay keeps nothing of the mixer but the "
         "layer's input")
+
+
+# ---------------------------------------------------------------------------
+# The gated-delta-rule mixer (layers of kind ``gated_delta``)
+# ---------------------------------------------------------------------------
+
+# the diagonal blocks of a chunk's unit lower-triangular matrix that are
+# inverted by products; the blocks between them by block substitution
+INVERSE_BASE = 8
+# the inverse's products: three bfloat16 pieces an f32 operand (about 2^-17
+# a product, amplified by at most a few tens inside a block of 8 and not at
+# all by the substitution), under the one rounding to ``cfg.dtype`` that its
+# result gets; the six pieces of HIGHEST cost the step 0.08 s more on the v5e
+# (1.760 -> 1.678 s) and the reference check's worst and median leaf read
+# inside their ranges either way (PERF.md section 6, PR 64)
+_INVERSE_PRECISION = jax.lax.Precision.HIGH
+
+
+@jax.custom_vjp
+def unit_lower_inverse(a: jax.Array) -> jax.Array:
+    """``(I + a)^-1`` of a strictly lower-triangular ``a`` (..., C, C) f32,
+    C a power of two, with no loop and no division. The diagonal blocks of
+    ``INVERSE_BASE`` = 8 by the finite product ``(I + a)^-1 = prod_{i<3} (I
+    + (-a)^(2^i))`` (a block's 8th power is nought), then block sizes
+    doubled: ``[[L11, 0], [L21, L22]]^-1 = [[L11^-1, 0], [-L22^-1 L21
+    L11^-1, L22^-1]]``, which is substitution by blocks. The whole chunk by
+    the product alone sums powers up to the 63rd, whose entries reach
+    binomial sizes before they cancel: in f32 that loses the answer where
+    keys resemble each other (tests/test_qwen3next_model.py holds both to
+    the recurrence). Every product in f32 at ``_INVERSE_PRECISION``.
+    Differentiated, it is two products with the inverse it made, ``da = -
+    T^T dT T^T`` (the blocks' products are not walked back: at 8 192 tokens
+    and 32 heads they were a tenth of the step, PERF.md section 6, PR 64);
+    the cotangent is ``a``'s as a whole matrix, which the caller's mask
+    cuts to the strictly lower part."""
+    c = a.shape[-1]
+    base = min(INVERSE_BASE, c)
+    mm = functools.partial(jnp.matmul, precision=_INVERSE_PRECISION)
+
+    def diagonal(size):
+        """(..., C / size, size, size): ``a``'s diagonal blocks of ``size``,
+        as static slices (a reshape and ``jnp.diagonal`` here trips a check
+        of XLA's CPU compiler)."""
+        return jnp.stack([a[..., lo:lo + size, lo:lo + size]
+                          for lo in range(0, c, size)], axis=-3)
+
+    eye = jnp.eye(base, dtype=a.dtype)
+    power = -diagonal(base)
+    inv = eye + power
+    for _ in range(max(base.bit_length() - 2, 0)):
+        power = mm(power, power)
+        inv = mm(inv, eye + power)
+    size = base
+    while size < c:
+        # the pairs of neighbouring diagonal blocks, and the block under
+        # the first of each pair
+        first, second = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+        under = diagonal(2 * size)[..., size:, :size]
+        low = -mm(second, mm(under, first))
+        inv = jnp.concatenate([
+            jnp.concatenate([first, jnp.zeros_like(first)], axis=-1),
+            jnp.concatenate([low, second], axis=-1)], axis=-2)
+        size *= 2
+    return inv[..., 0, :, :]
+
+
+def _unit_lower_inverse_fwd(a):
+    inverse = unit_lower_inverse(a)
+    return inverse, inverse
+
+
+def _unit_lower_inverse_bwd(inverse, cotangent):
+    mm = functools.partial(jnp.matmul, precision=_INVERSE_PRECISION)
+    turned = jnp.swapaxes(inverse, -1, -2)
+    return (-mm(turned, mm(cotangent, turned)),)
+
+
+unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+# chunks whose in-chunk work (the inverse, the products with it, the
+# decays' tables) is made at once and held together: a block of the
+# sequence. A block is replayed whole in the backward pass, so the tables of
+# one block are alive at a time, not the sequence's (at 8 192 tokens and 32
+# heads those are 5 GiB a layer: PERF.md section 6, PR 64)
+RULE_BLOCK = 16
+
+
+def _rule_block(state, operands, *, dtype):
+    """One block of chunks from the carried ``state`` (B, G, R, dk, dv)
+    f32: ``(state after, o)``. operands: q, k (B, N, C, G, dk), v (B, N, C,
+    G, R, dv) and g, beta (B, N, G, R, C) f32 of the block's N chunks of C
+    tokens."""
+    qc, kc, vc, gc, bc = operands
+    c = qc.shape[2]
+    f32, dt = jnp.float32, dtype
+    cs = jnp.cumsum(gc, axis=-1)                          # running log decay
+    since_start = jnp.exp(cs)       # what is left of the chunk's first state
+    i = np.arange(c)
+    decay = jnp.exp(jnp.where(i[None, :] <= i[:, None],
+                              cs[..., :, None] - cs[..., None, :], -jnp.inf))
+    kk = jnp.einsum("bnigd,bnjgd->bngij", kc, kc, preferred_element_type=f32)
+    qk = jnp.einsum("bnigd,bnjgd->bngij", qc, kc, preferred_element_type=f32)
+    # (I + A) u = beta (v - exp(cs) k S): A strictly lower
+    a = jnp.where(i[None, :] < i[:, None],
+                  bc[..., :, None] * decay * kk[:, :, :, None], 0.0)
+    inverse = unit_lower_inverse(a).astype(dt)            # (b, n, G, R, c, c)
+    per_row = lambda x: x.transpose(0, 1, 4, 2, 3)[..., None]  # (b,n,c,G,R,1)
+    kr = kc[:, :, :, :, None, :].astype(f32)              # (b, n, c, G, 1, dk)
+    qr = qc[:, :, :, :, None, :].astype(f32)
+    v_in = (vc.astype(f32) * per_row(bc)).astype(dt)
+    k_in = (kr * per_row(bc * since_start)).astype(dt)
+    # what the rule writes with an empty state, and what a state takes off
+    u_own = jnp.einsum("bngrij,bnjgrp->nbgrip", inverse, v_in,
+                       preferred_element_type=f32)
+    w = jnp.einsum("bngrij,bnjgrd->nbgrid", inverse, k_in,
+                   preferred_element_type=f32).astype(dt)
+    attn = (decay * qk[:, :, :, None]).astype(dt).transpose(1, 0, 2, 3, 4, 5)
+    q_in = (qr * per_row(since_start)).astype(dt).transpose(1, 0, 3, 4, 2, 5)
+    k_out = (kr * per_row(jnp.exp(cs[..., -1:] - cs))).astype(dt).transpose(
+        1, 0, 3, 4, 2, 5)                                 # (n, b, G, R, c, dk)
+    total = since_start[..., -1].transpose(1, 0, 2, 3)    # (n, b, G, R)
+
+    def carry(state, chunk_of):
+        u_own, w, attn, q_in, k_out, total = chunk_of
+        s = state.astype(dt)
+        u = (u_own - jnp.einsum("bgrid,bgrdp->bgrip", w, s,
+                                preferred_element_type=f32)).astype(dt)
+        o = jnp.einsum("bgrid,bgrdp->bgrip", q_in, s,
+                       preferred_element_type=f32) \
+            + jnp.einsum("bgrij,bgrjp->bgrip", attn, u,
+                         preferred_element_type=f32)
+        state = total[..., None, None] * state + jnp.einsum(
+            "bgrid,bgrip->bgrdp", k_out, u, preferred_element_type=f32)
+        return state, o.astype(dt)
+
+    return jax.lax.scan(carry, state, (u_own, w, attn, q_in, k_out, total))
+
+
+def chunked_delta_rule(q, k, v, g, beta, *, key_heads: int,
+                       chunk: int) -> jax.Array:
+    """``o_t = S_t^T q_t`` of ``S_t = exp(g_t) S_{t-1} + k_t (beta_t (v_t -
+    (exp(g_t) S_{t-1})^T k_t))^T`` (``S_{-1}`` = 0), in chunks of ``chunk``
+    tokens (module docstring), the chunks in blocks of ``RULE_BLOCK`` that
+    carry the state from one to the next and are replayed whole in the
+    backward pass. q, k: (B, T, G*dk), L2-normalised, value head h reading
+    query/key head h // (H / G); v: (B, T, H*dv); g (the log of the decay,
+    <= 0), beta: (B, T, H) f32. Returns (B, T, H*dv) in v's dtype. A
+    sequence that is no whole number of chunks is padded behind with tokens
+    whose ``k``, ``beta`` and ``g`` are 0: they change no state and nothing
+    reads them."""
+    b, t, _ = v.shape
+    heads = g.shape[-1]
+    c = min(chunk, 1 << max(t - 1, 0).bit_length())
+    pad = -t % c
+    if pad:
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+                            for x in (q, k, v, g, beta))
+    n, r = (t + pad) // c, heads // key_heads
+    per = max(m for m in range(1, min(n, RULE_BLOCK) + 1) if n % m == 0)
+    # (blocks, b, chunks a block, ...)
+    blocks = lambda x: jnp.moveaxis(
+        x.reshape(b, n // per, per, *x.shape[2:]), 1, 0)
+    by_head = lambda x: x.reshape(b, n, c, key_heads, r).transpose(
+        0, 1, 3, 4, 2)                                    # (b, n, G, R, c)
+    operands = tuple(map(blocks, (
+        q.reshape(b, n, c, key_heads, -1), k.reshape(b, n, c, key_heads, -1),
+        v.reshape(b, n, c, key_heads, r, -1), by_head(g), by_head(beta))))
+    start = jnp.zeros((b, key_heads, r, q.shape[-1] // key_heads,
+                       v.shape[-1] // heads), jnp.float32)
+    _, o = jax.lax.scan(
+        jax.checkpoint(functools.partial(_rule_block, dtype=v.dtype)),
+        start, operands)
+    # (blocks, chunks a block, b, G, R, c, dv) -> (b, T, H * dv)
+    return o.transpose(2, 0, 1, 5, 3, 4, 6).reshape(b, t + pad, -1)[:, :t]
+
+
+DELTA_SITE = "delta rule"
+NO_RULE_KERNEL = ("no Mosaic kernel is written for the rule: a scan over "
+                  "chunks in XLA code")
+
+
+def _delta_key(tokens: int, cfg: SparseLMConfig):
+    """What the record knows a delta rule by: a sample's tokens and the
+    mixer's sizes."""
+    return (tokens, cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+            cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+            cfg.delta_chunk)
+
+
+def delta_rule(q, k, v, g, beta, *, mesh, cfg: SparseLMConfig,
+               scope: Optional[str] = None):
+    """The rule as a call site: a shard's samples, every head (no mesh axis
+    splits the mixer's lanes). Its one lowering today is
+    :func:`chunked_delta_rule`; the site is where a kernel's predicate
+    will stand."""
+    xla = functools.partial(chunked_delta_rule,
+                            key_heads=cfg.linear_num_key_heads,
+                            chunk=cfg.delta_chunk)
+
+    def fits(q, k, v, g, beta) -> bool:
+        return lowering.chose(DELTA_SITE, _delta_key(q.shape[1], cfg),
+                              NO_RULE_KERNEL, NO_RULE_KERNEL)
+
+    lanes = P(*LANES_SPEC[:2], None)
+    # ``fits`` refuses every shape: the kernel's place holds the lowering
+    return lowering.site(DELTA_SITE, fits, xla, xla, mesh, (lanes,) * 5,
+                         lanes, scope)(q, k, v, g, beta)
+
+
+GDN_TAPS_SITE = "gdn taps"
+
+
+def _gdn_taps_key(tokens: int, cfg: SparseLMConfig):
+    """As :func:`_taps_key`: ``[q ; k ; v]`` lie first in ``in_proj``'s
+    output."""
+    return (tokens, 0, (cfg.linear_key_lanes, cfg.linear_key_lanes,
+                        cfg.linear_value_lanes),
+            cfg.linear_conv_kernel_dim, jnp.dtype(cfg.dtype).itemsize)
+
+
+def gdn_taps(qkv, taps, *, mesh, cfg: SparseLMConfig,
+             scope: Optional[str] = None):
+    """The gated-delta mixer's taps and SiLU (:func:`_taps_site`, with a
+    bias of noughts: the source has none): ``q``, ``k`` and ``v`` of
+    ``in_proj``'s ``[q ; k ; v]`` (B, T, .), written apart."""
+    def words(qkv, key):
+        return (f"local qkv{tuple(qkv.shape)} read at lane 0 of "
+                f"{qkv.shape[2]}, "
+                f"{ssm_pass_kernels.rows_tile(key[0], key[-1])} tokens a "
+                "grid step")
+
+    return _taps_site(GDN_TAPS_SITE, _gdn_taps_key(qkv.shape[1], cfg), words,
+                      qkv, taps, jnp.zeros(taps.shape[1:], jnp.float32),
+                      mesh=mesh, scope=scope)
+
+
+def l2_normed(x: jax.Array, head_dim: int, scale: float = 1.0) -> jax.Array:
+    """Each head of x (B, T, H * head_dim) over the root of its squares' sum
+    + 1e-6, times ``scale``; in f32, rounded to x's dtype."""
+    heads = x.astype(jnp.float32).reshape(*x.shape[:2], -1, head_dim)
+    heads = heads * (scale * jax.lax.rsqrt(
+        jnp.sum(heads * heads, -1, keepdims=True) + 1e-6))
+    return heads.reshape(x.shape).astype(x.dtype)
+
+
+# ``A_log`` at init: the log of U(0, 16), as the source's modeling code
+# draws it; ``dt_bias`` ones
+GDN_A_RANGE = (0.0, 16.0)
+
+
+def _gdn_a_log_init(key, shape, dtype):
+    return jnp.log(jax.random.uniform(
+        key, shape, jnp.float32, *GDN_A_RANGE,
+    ).clip(min=jnp.finfo(jnp.float32).tiny)).astype(dtype)
+
+
+class GatedDeltaInProj(nn.Module):
+    """The mixer's way in, under the name ``in_proj``: ``[q ; k ; v]``, ``z``
+    and ``[b ; a]`` of the layer's normed input as three products, leaves
+    ``qkv/kernel`` (D, 2 G dk + H dv), ``z/kernel`` (D, H dv) and
+    ``ba/kernel`` (D, 2 H). The source's ``in_proj_qkvz`` and
+    ``in_proj_ba`` hold the same columns, interleaved by key head (a
+    layout). Three arrays and not one with column blocks: each reader's
+    cotangent is then its own product's operand, where one array's is the
+    readers' three padded to its width and summed in f32, 4.8 ms a layer
+    and micro-step at the cell's size (PERF.md section 6, PR 64)."""
+    cfg: SparseLMConfig
+
+    @nn.compact
+    def __call__(self, a: jax.Array):
+        cfg = self.cfg
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=jnp.dtype(cfg.dtype),
+            param_dtype=jnp.dtype(cfg.param_dtype))
+        return (dense(cfg.linear_conv_lanes, name="qkv")(a),
+                dense(cfg.linear_value_lanes, name="z")(a),
+                dense(2 * cfg.linear_num_value_heads, name="ba")(a))
+
+
+class GatedDeltaMixer(nn.Module):
+    """The gated-delta-rule mixer of a ``gated_delta`` layer (module
+    docstring), under the name ``gdn``. Leaves ``in_proj/{qkv,z,ba}/kernel``
+    (:class:`GatedDeltaInProj`), ``taps`` (K, 2 G dk + H dv) (a tap a row: tap K - 1
+    weighs the token itself), ``dt_bias``, ``A_log`` (H each), ``norm`` (dv:
+    one scale vector for every head) and ``out_proj/kernel``."""
+    cfg: SparseLMConfig
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, a: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        dt, pdt = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=dt,
+                                  param_dtype=pdt)
+        heads, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                         cfg.linear_value_head_dim)
+        lanes = cfg.linear_conv_lanes
+        qkv, z, ba = GatedDeltaInProj(cfg, name="in_proj")(a)
+        taps = self.param(
+            "taps", nn.initializers.variance_scaling(
+                1.0, "fan_in", "truncated_normal", in_axis=0, out_axis=1),
+            (cfg.linear_conv_kernel_dim, lanes), pdt)
+        dt_bias = self.param("dt_bias", nn.initializers.ones, (heads,), pdt)
+        a_log = self.param("A_log", _gdn_a_log_init, (heads,), pdt)
+        scale = self.param("norm", nn.initializers.ones, (dv,), pdt)
+        with jax.named_scope("conv"):
+            q, k, v = gdn_taps(qkv, taps, mesh=self.mesh, cfg=cfg,
+                               scope="conv")
+        with jax.named_scope("rule"):
+            f32 = jnp.float32
+            ba = ba.astype(f32)
+            beta = jax.nn.sigmoid(ba[..., :heads])
+            g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+                ba[..., heads:] + dt_bias.astype(f32))
+            o = delta_rule(l2_normed(q, dk, dk ** -0.5), l2_normed(k, dk), v,
+                           g, beta, mesh=self.mesh, cfg=cfg, scope="rule")
+        with jax.named_scope("gate_norm"):
+            # the norm BEFORE the gate, over each head's lanes
+            o = head_pass(o, scale, mesh=self.mesh, eps=cfg.rms_eps,
+                          head_dim=dv, theta=None)
+            o = (o.astype(f32) * jax.nn.silu(z.astype(f32))).astype(dt)
+        return dense(cfg.hidden_size, name="out_proj")(o)
+
+
+def gdn_layout(cfg: SparseLMConfig, tp: int = 1) -> str:
+    """The ``setup/warmup`` row's ``gdn_layout``: the configuration's sizes,
+    and of the mixer's sites what their traced calls said."""
+    kinds = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
+    tokens, chunk = cfg.total_seq_len, cfg.delta_chunk
+    why = lowering.why_not(DELTA_SITE, _delta_key(tokens, cfg))
+    rule = "a Pallas kernel" if why is None else f"XLA chunks ({why})"
+    why = lowering.why_not(GDN_TAPS_SITE, _gdn_taps_key(tokens, cfg))
+    taps = ("the Mamba-2 mixer's pass, one a direction, q, k and v written "
+            "apart, a bias of noughts" if why is None
+            else f"XLA code ({why})")
+    why = lowering.first_refusal([(
+        _head_pass_site(True, False), _head_pass_key(
+            tokens, cfg.linear_value_lanes, cfg.linear_value_head_dim, tp))])
+    norm = "one pass on the lanes" if why is None else f"XLA code ({why})"
+    return (
+        f"gated-delta-rule mixer: {kinds.count(LAYER_GATED_DELTA)} of "
+        f"{len(kinds)} layers, {cfg.linear_num_key_heads} query/key heads x "
+        f"{cfg.linear_key_head_dim} serving {cfg.linear_num_value_heads} "
+        f"value heads x {cfg.linear_value_head_dim}, "
+        f"{cfg.linear_conv_kernel_dim} taps with no bias over "
+        f"{cfg.linear_conv_lanes} lanes; the rule in chunks of {chunk}, "
+        f"{-(-tokens // chunk)} a sequence of {tokens}: inside a chunk the "
+        f"inverse of a unit lower-triangular ({chunk} x {chunk}) matrix a "
+        f"head (blocks of {min(INVERSE_BASE, chunk)} by products, then "
+        "doubled by substitution), across chunks the carried "
+        f"({cfg.linear_key_head_dim} x {cfg.linear_value_head_dim}) state, "
+        "the decays, their sums, the inverse and the states in f32; no (T, "
+        f"T) array and no state a token; gdn/rule: {rule}, replayed a block "
+        f"of {RULE_BLOCK} chunks in the backward pass; taps and SiLU: {taps}; the heads' norm "
+        f"before the gate: {norm}, the gate XLA code")
 
 
 # ---------------------------------------------------------------------------
@@ -2216,6 +2684,10 @@ class ExpertLayer(nn.Module):
         if cfg.num_shared_experts:
             block = GatedBlock if cfg.expert_gated else UngatedBlock
             self.shared = block(cfg, cfg.shared_width)
+        if cfg.shared_expert_gate:
+            self.shared_gate = self.param(
+                "shared_gate", nn.initializers.normal(
+                    stddev=cfg.hidden_size ** -0.5), (cfg.hidden_size,), pdt)
 
     def route(self, a: jax.Array):
         """Top-k of the router's f32 scores of its normed input (the
@@ -2298,7 +2770,15 @@ class ExpertLayer(nn.Module):
                 "spills": spills,
                 "tiles_active": active}
         if cfg.num_shared_experts:
-            y = y + self.shared(m).reshape(b * t, d)
+            shared = self.shared(m).reshape(b * t, d)
+            if cfg.shared_expert_gate:
+                # sigmoid(w_g . m) a token, on the shared expert alone
+                with jax.named_scope("shared"):
+                    gate = jax.nn.sigmoid(jnp.dot(
+                        m.reshape(b * t, d), self.shared_gate.astype(m.dtype),
+                        preferred_element_type=jnp.float32))
+                    shared = gate[:, None] * shared
+            y = y + shared
         return y.reshape(b, t, d).astype(m.dtype), counters
 
 
@@ -2327,6 +2807,8 @@ class Layer(nn.Module):
             idx, p = ff.route(a)
         if self.kind == LAYER_SHORT_CONV:
             y = ShortConv(cfg, name="conv")(a)
+        elif self.kind == LAYER_GATED_DELTA:
+            y = GatedDeltaMixer(cfg, self.mesh, name="gdn")(a)
         elif self.kind == LAYER_FULL_ROPE and cfg.kv_lora_rank:
             y = LatentAttention(cfg, self.mesh, name="attn")(a)
         elif self.kind == LAYER_SELECTED_ROPE:
@@ -2789,9 +3271,11 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
     tokens = cfg.total_seq_len
     layers = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
     # the attention layers (a short convolution has ``conv_layout``, a
-    # state-space mixer ``ssm_layout``, a layer of experts alone neither)
+    # state-space mixer ``ssm_layout``, a gated-delta-rule mixer
+    # ``gdn_layout``, a layer of experts alone none)
     kinds = [k for k in layers
-             if k not in (LAYER_SHORT_CONV, LAYER_MAMBA2, LAYER_EXPERTS)]
+             if k not in (LAYER_SHORT_CONV, LAYER_MAMBA2, LAYER_EXPERTS,
+                          LAYER_GATED_DELTA)]
     calls = [(_blockwise_site(k), _blockwise_key(
         tokens, cfg.num_heads * cfg.head_dim,
         cfg.num_kv_heads * cfg.head_dim, tp)) for k in kinds]
@@ -2828,7 +3312,9 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
              for c in passes(True, rotary)],
             f"one pass on the lanes: {len(kinds)} of {len(kinds)} layers")
     if ropes:
-        words += ", rotary " + lowering_of(
+        words += ", rotary " + (
+            f"of a head's first {cfg.rotary_dim} lanes "
+            * (cfg.rotary_dim != cfg.head_dim)) + lowering_of(
             passes(cfg.qk_norm, True),
             ("in the head pass" if cfg.qk_norm else "one pass on the lanes")
             + f": {ropes} of {ropes} rope layers")
@@ -2869,7 +3355,8 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
                   + ", norm" * cfg.route_norm + f", x{cfg.route_scale:g}")
     beside = ""
     if cfg.num_shared_experts:
-        beside += f", a shared expert of {cfg.shared_width}"
+        beside += f", a shared expert of {cfg.shared_width}" \
+            + " under a sigmoid gate a token" * cfg.shared_expert_gate
     if cfg.num_dense_layers:
         beside += (f", layers 0-{cfg.num_dense_layers - 1} dense "
                    f"{cfg.dense_width}")
@@ -2920,6 +3407,8 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
         said["conv_layout"] = conv_layout(cfg)
     if LAYER_MAMBA2 in layers:
         said["ssm_layout"] = ssm_layout(cfg)
+    if LAYER_GATED_DELTA in layers:
+        said["gdn_layout"] = gdn_layout(cfg, tp)
     said["head_layout"] = (
         f"tied: the head is the embedding's table ({cfg.vocab_size} x "
         f"{cfg.hidden_size}), contracted where it lies in the streamed "

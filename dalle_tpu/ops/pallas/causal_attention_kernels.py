@@ -148,6 +148,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 HALF = LANES // 2                 # a 64-wide head: two a lane tile
+WIDE = 2 * LANES                  # a 256-wide head: one over two lane tiles
 BLOCK = 512
 # the sub-tile the 128-wide kernels cut an edge tile into (:func:`edge_tiles`)
 SUB_BLOCK = 256
@@ -160,19 +161,19 @@ _NT = (((1,), (1,)), ((), ()))       # a @ b.T
 
 def blockwise_fits(q_width: int, kv_width: int, head_dim: int) -> Optional[str]:
     """None where the kernels take these local shapes, else why not."""
-    if head_dim not in (LANES, HALF):
+    if head_dim not in (LANES, HALF, WIDE):
         return (f"head_dim {head_dim} is neither one {LANES}-lane tile nor "
                 "half of one")
-    if q_width % kv_width or kv_width % LANES:
+    if q_width % kv_width or kv_width % max(LANES, head_dim):
         return f"{q_width} query lanes over {kv_width} key-value lanes"
-    if q_width // kv_width * (LANES // head_dim) > LANES:
+    if q_width // kv_width * max(1, LANES // head_dim) > LANES:
         return "more query heads a group than statistics lanes"
     return None
 
 
 def fused_backward_fits(tokens: int, group: int, itemsize: int,
-                        block: int = BLOCK,
-                        selected: bool = False) -> Optional[str]:
+                        block: int = BLOCK, selected: bool = False,
+                        lanes: int = LANES) -> Optional[str]:
     """None where the one-kernel backward holds a key-value head's whole
     ``dk`` and ``dv`` in VMEM at these local shapes (``tokens`` a sample,
     ``group`` query heads a key-value head, operands of ``itemsize``
@@ -180,15 +181,16 @@ def fused_backward_fits(tokens: int, group: int, itemsize: int,
     ``dk``/``dv`` kernel, which hold a block each. With two 64-wide heads a
     lane tile the same sizes are a key-value tile's and its ``group`` query
     tiles'. ``selected``: the allowed pairs are data
-    (:func:`selected_attention`), one more (block, block) f32 tile a step."""
+    (:func:`selected_attention`), one more (block, block) f32 tile a step.
+    ``lanes``: a head's, where it is wider than one lane tile."""
     t = tokens + -tokens % block
-    tile = block * LANES
-    need = (2 * t * LANES * 4                   # dk, dv accumulators, f32
-            + 2 * 2 * t * LANES * itemsize      # their outputs, two buffers
+    tile = block * lanes
+    need = (2 * t * lanes * 4                   # dk, dv accumulators, f32
+            + 2 * 2 * t * lanes * itemsize      # their outputs, two buffers
             + group * tile * 4                  # dq's accumulator
             + 3 * 2 * group * tile * itemsize   # q, do, dq tiles
             + 2 * 2 * tile * itemsize           # k, v tiles
-            + 2 * 2 * tile * 4                  # statistics, delta
+            + 2 * 2 * block * LANES * 4         # statistics, delta
             + 4 * block * block * 4             # scores, p, dp, ds
             + selected * 2 * block * block * 4)  # the selection's tile
     if need > VMEM_LIMIT_BYTES:
@@ -217,9 +219,9 @@ def band_pairs(n_blocks: int, block: int, window: Optional[int],
 
 def sub_block(block: int, head_dim: int = LANES) -> int:
     """The sub-tile :func:`causal_attention` cuts an edge tile of ``block``
-    into: :data:`SUB_BLOCK` for one 128-wide head a lane tile, where it
-    divides the tile; else the tile itself (whole tiles)."""
-    return (SUB_BLOCK if head_dim == LANES and block % SUB_BLOCK == 0
+    into: :data:`SUB_BLOCK` for one head over whole lane tiles (128 wide, or
+    256), where it divides the tile; else the tile itself (whole tiles)."""
+    return (SUB_BLOCK if head_dim % LANES == 0 and block % SUB_BLOCK == 0
             else block)
 
 
@@ -302,8 +304,9 @@ def _allowed(qi, ki, block: int, window: Optional[int]):
     return _within((qi - ki) * block, (block, block), window)
 
 
-def _head(h: int) -> slice:
-    return slice(h * LANES, (h + 1) * LANES)
+def _head(h: int, width: int = LANES) -> slice:
+    """Head ``h``'s lanes of a group's tile, heads of ``width`` lanes."""
+    return slice(h * width, (h + 1) * width)
 
 
 def _tile_backward(q, do, k, v, allowed, lse, delta, scale):
@@ -321,7 +324,7 @@ def _tile_backward(q, do, k, v, allowed, lse, delta, scale):
 
 
 def _softmax_step(h: int, s, v, m_s, l_s, acc_s, block: int, mine=None,
-                  rows: slice = slice(None)):
+                  rows: slice = slice(None), width: int = LANES):
     """Head ``h``'s masked (block, block) scores of one key block folded
     into its running maximum, sum and accumulator. ``mine``: with two heads
     a lane tile, (block, 128) bool, the head's half of its tile ``h // 2``
@@ -333,22 +336,26 @@ def _softmax_step(h: int, s, v, m_s, l_s, acc_s, block: int, mine=None,
     alpha = jnp.exp(m_prev - m_next)
     l_s[h, rows] = alpha * l_prev + jnp.sum(e, axis=1)[:, None]
     m_s[h, rows] = m_next
-    lanes = _head(h)
+    lanes = _head(h, width)
     if mine is not None:
         lanes, alpha = _head(h // 2), jnp.where(mine, alpha, 1.0)
+    if width > LANES:       # a head over several lane tiles: alpha on each
+        alpha = jnp.tile(alpha, (1, width // LANES))
     acc_s[rows, lanes] = alpha * acc_s[rows, lanes] + jnp.dot(
         e.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
 
 def _write_forward(o_ref, stats_ref, m_s, l_s, acc_s, group: int,
-                   block: int):
+                   block: int, width: int = LANES):
     """A query block's outputs and row statistics, head ``h`` in lane
     ``h``, after its last key block."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (block, LANES), 1)
     stats = jnp.zeros((block, LANES), jnp.float32)
     for h in range(group):
         l = l_s[h]
-        o_ref[0, :, _head(h)] = (acc_s[:, _head(h)] / l).astype(o_ref.dtype)
+        sums = l if width == LANES else jnp.tile(l, (1, width // LANES))
+        o_ref[0, :, _head(h, width)] = (
+            acc_s[:, _head(h, width)] / sums).astype(o_ref.dtype)
         stats = jnp.where(lane == h, m_s[h] + jnp.log(l), stats)
     stats_ref[0, 0] = stats
 
@@ -378,7 +385,7 @@ def _by_kind(d, block: int, window: Optional[int], sub: int, work) -> None:
 def _causal_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                        v_ref, o_ref, stats_ref, m_s, l_s, acc_s, *,
                        scale: float, group: int, block: int,
-                       window: Optional[int], sub: int):
+                       window: Optional[int], sub: int, width: int = LANES):
     p = pl.program_id(2)
 
     @pl.when(first_ref[p] == 1)
@@ -390,25 +397,28 @@ def _causal_fwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
     def fold(rows, keys, allowed):
         k, v = k_ref[0, keys], v_ref[0, keys]
         for h in range(group):
-            s = jax.lax.dot_general(q_ref[0, rows, _head(h)], k, _NT,
+            s = jax.lax.dot_general(q_ref[0, rows, _head(h, width)], k, _NT,
                                     preferred_element_type=jnp.float32) * scale
             if allowed is not None:
                 s = jnp.where(allowed, s, MASK_VALUE)
-            _softmax_step(h, s, v, m_s, l_s, acc_s, k.shape[0], rows=rows)
+            _softmax_step(h, s, v, m_s, l_s, acc_s, k.shape[0], rows=rows,
+                          width=width)
 
     _by_kind(qi_ref[p] - ki_ref[p], block, window, sub, fold)
 
     @pl.when(last_ref[p] == 1)
     def _():
-        _write_forward(o_ref, stats_ref, m_s, l_s, acc_s, group, block)
+        _write_forward(o_ref, stats_ref, m_s, l_s, acc_s, group, block,
+                       width)
 
 
 def _strip_backward(h: int, rows, keys, allowed, q_ref, k_ref, v_ref, do_ref,
-                    stats_ref, delta_ref, scale):
+                    stats_ref, delta_ref, scale, width: int = LANES):
     """:func:`_tile_backward` of head ``h`` over the tile's ``rows`` and
     ``keys``, with the operands it read: (q, do, k, prob, ds), ``ds`` in the
     operands' dtype."""
-    q, do = q_ref[0, rows, _head(h)], do_ref[0, rows, _head(h)]
+    lanes = _head(h, width)
+    q, do = q_ref[0, rows, lanes], do_ref[0, rows, lanes]
     k = k_ref[0, keys]
     prob, ds = _tile_backward(q, do, k, v_ref[0, keys], allowed,
                               stats_ref[0, 0, rows, h:h + 1],
@@ -420,7 +430,7 @@ def _strip_backward(h: int, rows, keys, allowed, q_ref, k_ref, v_ref, do_ref,
 def _causal_dq_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                       v_ref, do_ref, stats_ref, delta_ref, dq_ref, dq_s, *,
                       scale: float, group: int, block: int,
-                      window: Optional[int], sub: int):
+                      window: Optional[int], sub: int, width: int = LANES):
     p = pl.program_id(2)
 
     @pl.when(first_ref[p] == 1)
@@ -431,8 +441,8 @@ def _causal_dq_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
         for h in range(group):
             _, _, k, _, ds = _strip_backward(
                 h, rows, keys, allowed, q_ref, k_ref, v_ref, do_ref,
-                stats_ref, delta_ref, scale)
-            dq_s[rows, _head(h)] += jnp.dot(
+                stats_ref, delta_ref, scale, width)
+            dq_s[rows, _head(h, width)] += jnp.dot(
                 ds, k, preferred_element_type=jnp.float32)
 
     _by_kind(qi_ref[p] - ki_ref[p], block, window, sub, fold)
@@ -445,7 +455,7 @@ def _causal_dq_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
 def _causal_dkv_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                        v_ref, do_ref, stats_ref, delta_ref, dk_ref, dv_ref,
                        dk_s, dv_s, *, scale: float, group: int, block: int,
-                       window: Optional[int], sub: int):
+                       window: Optional[int], sub: int, width: int = LANES):
     p = pl.program_id(2)
 
     @pl.when(first_ref[p] == 1)
@@ -457,7 +467,7 @@ def _causal_dkv_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
         for h in range(group):
             q, do, _, prob, ds = _strip_backward(
                 h, rows, keys, allowed, q_ref, k_ref, v_ref, do_ref,
-                stats_ref, delta_ref, scale)
+                stats_ref, delta_ref, scale, width)
             dv_s[keys, :] += jnp.dot(prob.astype(do.dtype).T, do,
                                      preferred_element_type=jnp.float32)
             dk_s[keys, :] += jnp.dot(ds.T, q,
@@ -474,7 +484,8 @@ def _causal_dkv_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
 def _causal_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
                        v_ref, do_ref, stats_ref, delta_ref, dq_ref, dk_ref,
                        dv_ref, dq_s, dk_s, dv_s, *, scale: float, group: int,
-                       block: int, window: Optional[int], sub: int):
+                       block: int, window: Optional[int], sub: int,
+                       width: int = LANES):
     """``dq``, ``dk`` and ``dv`` from one pass over the band, query-block-
     major: ``dq_s`` holds a query block over its key blocks, ``dk_s`` and
     ``dv_s`` (T, 128) a key-value head's whole sequence over all pairs."""
@@ -495,8 +506,8 @@ def _causal_bwd_kernel(qi_ref, ki_ref, first_ref, last_ref, q_ref, k_ref,
         for h in range(group):
             q, do, k, prob, ds = _strip_backward(
                 h, rows, keys, allowed, q_ref, k_ref, v_ref, do_ref,
-                stats_ref, delta_ref, scale)
-            dq_s[rows, _head(h)] += jnp.dot(
+                stats_ref, delta_ref, scale, width)
+            dq_s[rows, _head(h, width)] += jnp.dot(
                 ds, k, preferred_element_type=jnp.float32)
             dv_s[at, :] += jnp.dot(prob.astype(do.dtype).T, do,
                                    preferred_element_type=jnp.float32)
@@ -729,7 +740,7 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
           block: int, window: Optional[int], key_major: bool,
           interpret: bool, scale: float = LANES ** -0.5,
           steps: Optional[int] = None, heads_parallel: bool = True,
-          v_block: int = 0, more_specs=None, **static):
+          v_block: int = 0, more_specs=None, lanes: int = LANES, **static):
     """``operands`` / ``outs``: (array or shape-dtype, kind) with kind "q"
     (a group's query lanes, by query block), "kv" (one key-value head, by
     key block), "kv_all" (one key-value head's whole sequence) or "stats"
@@ -744,16 +755,18 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
     the grid's head axis, where it is not the key-value heads of the second
     operand. ``more_specs``: a caller's own kinds, {kind: BlockSpec}.
     ``static``: the kernel's further static keywords (``sub`` of the
-    kernels :func:`causal_attention` picks)."""
+    kernels :func:`causal_attention` picks). ``lanes``: a head's lanes in
+    the kinds "q", "kv" and "kv_all", where it is over several lane tiles
+    (the statistics keep one lane a head)."""
     b = operands[1][0].shape[0]
-    g = steps or operands[1][0].shape[2] // LANES
+    g = steps or operands[1][0].shape[2] // lanes
     table = band_pairs(t // block, block, window, key_major)
     specs = {
-        "q": pl.BlockSpec((1, block, group * LANES),
+        "q": pl.BlockSpec((1, block, group * lanes),
                           lambda i, j, p, qi, ki, fi, la: (i, qi[p], j)),
-        "kv": pl.BlockSpec((1, block, LANES),
+        "kv": pl.BlockSpec((1, block, lanes),
                            lambda i, j, p, qi, ki, fi, la: (i, ki[p], j)),
-        "kv_all": pl.BlockSpec((1, t, LANES),
+        "kv_all": pl.BlockSpec((1, t, lanes),
                                lambda i, j, p, qi, ki, fi, la: (i, 0, j)),
         "stats": pl.BlockSpec((1, 1, block, LANES),
                               lambda i, j, p, qi, ki, fi, la:
@@ -797,24 +810,32 @@ def _call(kernel, operands, outs, scratch, *, t: int, group: int,
       *(x for x, _ in operands))
 
 
-def _stats_like(q, group: int):
+def _stats_like(q, group: int, lanes: int = LANES):
     b, t, width = q.shape
-    return jax.ShapeDtypeStruct((b, width // (group * LANES), t, LANES),
+    return jax.ShapeDtypeStruct((b, width // (group * lanes), t, LANES),
                                 jnp.float32)
+
+
+def _wide(head_dim: int) -> dict:
+    """What a call for heads over several lane tiles adds to ``_call``'s
+    keywords: the tiles' lanes and the kernels' ``width``; nothing for a
+    head of one lane tile or half of one (their calls as they were)."""
+    return dict(lanes=head_dim, width=head_dim) if head_dim > LANES else {}
 
 
 def _fwd(q, k, v, window, block, interpret, head_dim):
     group = q.shape[2] // k.shape[2]
-    per = LANES // head_dim                 # heads a lane tile
+    per = max(1, LANES // head_dim)         # heads a lane tile
+    lanes = max(LANES, head_dim)            # a head's, or two heads', lanes
     rows = pltpu.VMEM((per * group, block, LANES), jnp.float32)
     return _call(
         _causal_fwd_kernel if per == 1 else _halves_fwd_kernel,
         [(q, "q"), (k, "kv"), (v, "kv")],
-        [(q, "q"), (_stats_like(q, group), "stats")],
-        [rows, rows, pltpu.VMEM((block, group * LANES), jnp.float32)],
+        [(q, "q"), (_stats_like(q, group, lanes), "stats")],
+        [rows, rows, pltpu.VMEM((block, group * lanes), jnp.float32)],
         t=q.shape[1], group=group, block=block, window=window,
         key_major=False, interpret=interpret, scale=head_dim ** -0.5,
-        sub=sub_block(block, head_dim))
+        sub=sub_block(block, head_dim), **_wide(head_dim))
 
 
 def _padded(x, block: int):
@@ -828,7 +849,8 @@ def causal_attention(q, k, v, window: Optional[int] = None,
                      head_dim: int = LANES):
     """softmax(q k^T / sqrt(d), key <= query [and query - key < window])
     v, query head ``h`` reading key-value head ``h // (H / G)``, heads of
-    ``head_dim`` d = 128 (one a lane tile) or 64 (two).
+    ``head_dim`` d = 128 (one a lane tile), 64 (two) or 256 (one over two
+    lane tiles: the scores' product 256 deep, the context 256 wide).
 
     q: (B, T, H*d); k, v: (B, T, G*d). Returns (B, T, H*d). ``T``
     need not be a multiple of ``block``: the rows are padded at the end,
@@ -865,28 +887,30 @@ def _vjp_bwd(window, block, interpret, head_dim, res, dout):
     q, k, v, out, stats = res
     b, t, width = q.shape
     group = width // k.shape[2]
-    per = LANES // head_dim
+    per = max(1, LANES // head_dim)
+    lanes = max(LANES, head_dim)
     delta = _delta(dout, out, per * group, block, head_dim)
     q, k, v, dout = (_padded(x, block) for x in (q, k, v, dout))
     operands = [(q, "q"), (k, "kv"), (v, "kv"), (dout, "q"),
                 (stats, "stats"), (delta, "stats")]
     kw = dict(t=q.shape[1], group=group, block=block, window=window,
               interpret=interpret, scale=head_dim ** -0.5,
-              sub=sub_block(block, head_dim))
+              sub=sub_block(block, head_dim), **_wide(head_dim))
     fused, dq_only, dkv_only = (
         (_causal_bwd_kernel, _causal_dq_kernel, _causal_dkv_kernel)
         if per == 1 else
         (_halves_bwd_kernel, _halves_dq_kernel, _halves_dkv_kernel))
-    dq_s = pltpu.VMEM((block, group * LANES), jnp.float32)
-    if fused_backward_fits(t, group, q.dtype.itemsize, block) is None:
-        acc = pltpu.VMEM((q.shape[1], LANES), jnp.float32)
+    dq_s = pltpu.VMEM((block, group * lanes), jnp.float32)
+    if fused_backward_fits(t, group, q.dtype.itemsize, block,
+                           lanes=lanes) is None:
+        acc = pltpu.VMEM((q.shape[1], lanes), jnp.float32)
         dq, dk, dv = _call(fused, operands,
                            [(q, "q"), (k, "kv_all"), (v, "kv_all")],
                            [dq_s, acc, acc], key_major=False, **kw)
     else:
         (dq,) = _call(dq_only, operands, [(q, "q")], [dq_s],
                       key_major=False, **kw)
-        acc = pltpu.VMEM((block, LANES), jnp.float32)
+        acc = pltpu.VMEM((block, lanes), jnp.float32)
         dk, dv = _call(dkv_only, operands, [(k, "kv"), (v, "kv")],
                        [acc, acc], key_major=True, **kw)
     return dq[:, :t], dk[:, :t], dv[:, :t]

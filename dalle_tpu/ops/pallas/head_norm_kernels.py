@@ -119,12 +119,30 @@ def _inv_rms(x, eps):
     return jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
 
 
-def _head_norm_fwd_kernel(*refs, eps, head_dim, norm, rotary):
-    """refs: x, [scale where ``norm``], [cos, sin where ``rotary``], out."""
+def _turn(y, tables, half: int, transpose: bool = False):
+    """The rotary of a head's FIRST ``2 * half`` lanes alone, on the lane
+    tiles ``y`` that hold them (:func:`partial_rotary_tables`): a lane of
+    the first half takes its partner from ``half`` lanes on, one of the
+    second from ``half`` lanes back, two lane rotates, each times a sine
+    that is nought where the other's partner stands; ``transpose``: the
+    cotangent's way back."""
+    cos, up, down = tables
+    back = y.shape[1] - half
+    if transpose:
+        return y * cos + pltpu.roll(y * up, back, 1) \
+            + pltpu.roll(y * down, half, 1)
+    return y * cos + pltpu.roll(y, half, 1) * up \
+        + pltpu.roll(y, back, 1) * down
+
+
+def _head_norm_fwd_kernel(*refs, eps, head_dim, norm, rotary, turned=0):
+    """refs: x, [scale where ``norm``], [cos, sin where ``rotary``; cos,
+    sin up, sin down where ``turned`` lanes of a head alone are], out."""
     refs = iter(refs)
     x_ref = next(refs)
     scale = next(refs)[...].astype(jnp.float32) if norm else None
     cos, sin = (next(refs)[...], next(refs)[...]) if rotary else (None, None)
+    tables = (cos, sin, next(refs)[...]) if turned else None
     out_ref = next(refs)
     for lo in range(0, x_ref.shape[1], head_dim):
         y = x_ref[:, lo:lo + head_dim].astype(jnp.float32)
@@ -134,24 +152,39 @@ def _head_norm_fwd_kernel(*refs, eps, head_dim, norm, rotary):
             # the rotary reads the normed value as the norm's own pass
             # would have written it
             y = y.astype(out_ref.dtype).astype(jnp.float32)
+        if turned:
+            # the lane tiles that hold the turned lanes; the rest as it is
+            wide = cos.shape[1]
+            if wide < head_dim:
+                out_ref[:, lo + wide:lo + head_dim] = y[:, wide:].astype(
+                    out_ref.dtype)
+            out_ref[:, lo:lo + wide] = _turn(
+                y[:, :wide], tables, turned // 2).astype(out_ref.dtype)
+            continue
         if rotary:
             y = y * cos + pltpu.roll(y, head_dim // 2, 1) * sin
         out_ref[:, lo:lo + head_dim] = y.astype(out_ref.dtype)
 
 
-def _head_norm_bwd_kernel(*refs, eps, head_dim, norm, rotary):
-    """refs: [x, scale where ``norm``], dout, [cos, sin where ``rotary``],
-    dx, [ds where ``norm``]."""
+def _head_norm_bwd_kernel(*refs, eps, head_dim, norm, rotary, turned=0):
+    """refs: [x, scale where ``norm``], dout, [cos, sin where ``rotary``;
+    three tables where ``turned``], dx, [ds where ``norm``]."""
     refs = iter(refs)
     x_ref = next(refs) if norm else None
     scale = next(refs)[...].astype(jnp.float32) if norm else None
     dout_ref = next(refs)
     cos, sin = (next(refs)[...], next(refs)[...]) if rotary else (None, None)
+    tables = (cos, sin, next(refs)[...]) if turned else None
     dx_ref = next(refs)
     ds_ref = next(refs) if norm else None
     for lo in range(0, dout_ref.shape[1], head_dim):
         dy = dout_ref[:, lo:lo + head_dim].astype(jnp.float32)
-        if rotary:
+        if turned:
+            wide = cos.shape[1]
+            first = _turn(dy[:, :wide], tables, turned // 2, transpose=True)
+            dy = first if wide == head_dim else jnp.concatenate(
+                [first, dy[:, wide:]], axis=1)
+        elif rotary:
             dy = dy * cos + pltpu.roll(dy * sin, head_dim // 2, 1)
         if norm:
             x = x_ref[:, lo:lo + head_dim].astype(jnp.float32)
@@ -172,17 +205,18 @@ _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel"),
                                vmem_limit_bytes=64 * 1024 * 1024)
 
 
-def _specs(shape, head_dim):
+def _specs(shape, head_dim, table: int = 0):
     """(grid, a tile of ``x``, the scale, a tile's rows of a table, the
     ``dscale`` slab a tile writes). The sample is the inner grid axis: a
     table's block is then the same from one step to the next and is
-    fetched once a row tile."""
+    fetched once a row tile. ``table``: a table's lanes, where they are
+    not a head's."""
     b, t, width = shape
     bm = rows_tile(t, width)
     return ((t // bm, b),
             pl.BlockSpec((None, bm, width), lambda i, n: (n, i, 0)),
             pl.BlockSpec((1, head_dim), lambda i, n: (0, 0)),
-            pl.BlockSpec((bm, head_dim), lambda i, n: (i, 0)),
+            pl.BlockSpec((bm, table or head_dim), lambda i, n: (i, 0)),
             pl.BlockSpec((None, 8, width), lambda i, n: (n, i, 0)))
 
 
@@ -191,17 +225,28 @@ def scope(norm: bool) -> str:
     return SCOPE if norm else ROTARY_SCOPE
 
 
+def _turned(tables, turned: int):
+    """The kernels' further keyword and a table's lanes where ``turned``
+    lanes of a head alone are rotated (three tables); nothing else."""
+    if not turned:
+        return {}, 0
+    return {"turned": turned}, tables[0].shape[1]
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("eps", "head_dim", "interpret"))
-def _fwd_call(x, scale, tables, *, eps, head_dim, interpret):
+                   static_argnames=("eps", "head_dim", "interpret", "turned"))
+def _fwd_call(x, scale, tables, *, eps, head_dim, interpret, turned=0):
     norm, rotary = scale is not None, tables is not None
-    grid, tile, scale_spec, table_spec, _ = _specs(x.shape, head_dim)
+    more, wide = _turned(tables, turned)
+    grid, tile, scale_spec, table_spec, _ = _specs(x.shape, head_dim, wide)
     with jax.named_scope(scope(norm)):
         return pl.pallas_call(
             functools.partial(_head_norm_fwd_kernel, eps=eps,
-                              head_dim=head_dim, norm=norm, rotary=rotary),
+                              head_dim=head_dim, norm=norm, rotary=rotary,
+                              **more),
             grid=grid,
-            in_specs=[tile] + [scale_spec] * norm + [table_spec] * 2 * rotary,
+            in_specs=[tile] + [scale_spec] * norm
+            + [table_spec] * len(tables or ()),
             out_specs=tile,
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
             compiler_params=_PARAMS,
@@ -210,22 +255,25 @@ def _fwd_call(x, scale, tables, *, eps, head_dim, interpret):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("eps", "head_dim", "interpret"))
-def _bwd_call(x, scale, tables, dout, *, eps, head_dim, interpret):
+                   static_argnames=("eps", "head_dim", "interpret", "turned"))
+def _bwd_call(x, scale, tables, dout, *, eps, head_dim, interpret, turned=0):
     """(dx, dscale); ``x`` and ``scale`` None where there is no norm, and
     ``dscale`` then too."""
     norm, rotary = scale is not None, tables is not None
-    grid, tile, scale_spec, table_spec, slab = _specs(dout.shape, head_dim)
+    more, wide = _turned(tables, turned)
+    grid, tile, scale_spec, table_spec, slab = _specs(dout.shape, head_dim,
+                                                      wide)
     dx = jax.ShapeDtypeStruct(dout.shape, dout.dtype)
     ds = jax.ShapeDtypeStruct(
         (dout.shape[0], grid[0] * 8, dout.shape[2]), jnp.float32)
     with jax.named_scope(scope(norm)):
         out = pl.pallas_call(
             functools.partial(_head_norm_bwd_kernel, eps=eps,
-                              head_dim=head_dim, norm=norm, rotary=rotary),
+                              head_dim=head_dim, norm=norm, rotary=rotary,
+                              **more),
             grid=grid,
             in_specs=([tile, scale_spec] * norm + [tile]
-                      + [table_spec] * 2 * rotary),
+                      + [table_spec] * len(tables or ())),
             out_specs=[tile] + [slab] * norm,
             out_shape=[dx] + [ds] * norm,
             compiler_params=_PARAMS,
@@ -237,28 +285,31 @@ def _bwd_call(x, scale, tables, dout, *, eps, head_dim, interpret):
                                axis=0).astype(scale.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def per_head(x, scale, tables, eps: float, head_dim: int,
-             interpret: bool = False):
+             interpret: bool = False, turned: int = 0):
     """``x`` (B, T, H * head_dim) in one pass, in ``x.dtype``; where
     :func:`fits`. Each head's ``head_dim`` lanes normed, times ``scale``
     (head_dim,), where that is not None; then rotated by ``tables``, what
     :func:`rotary_tables` gives for the T positions, where those are not
-    None. Gradient residuals: {x, scale} of a norm, and the tables."""
+    None; with ``turned``, a head's first ``turned`` lanes alone, by what
+    :func:`partial_rotary_tables` gives. Gradient residuals: {x, scale} of
+    a norm, and the tables."""
     return _fwd_call(x, scale, tables, eps=eps, head_dim=head_dim,
-                     interpret=interpret)
+                     interpret=interpret, turned=turned)
 
 
-def _vjp_fwd(x, scale, tables, eps, head_dim, interpret):
+def _vjp_fwd(x, scale, tables, eps, head_dim, interpret, turned):
     out = _fwd_call(x, scale, tables, eps=eps, head_dim=head_dim,
-                    interpret=interpret)
+                    interpret=interpret, turned=turned)
     return out, (x if scale is not None else None, scale, tables)
 
 
-def _vjp_bwd(eps, head_dim, interpret, res, dout):
+def _vjp_bwd(eps, head_dim, interpret, turned, res, dout):
     x, scale, tables = res
     dx, dscale = _bwd_call(x, scale, tables, dout, eps=eps,
-                           head_dim=head_dim, interpret=interpret)
+                           head_dim=head_dim, interpret=interpret,
+                           turned=turned)
     return dx, dscale, None
 
 
@@ -279,6 +330,22 @@ def rotary_tables(cos, sin):
     own inverse."""
     half = cos.shape[-1] // 2
     return cos, jnp.where(jnp.arange(2 * half) < half, -sin, sin)
+
+
+def partial_rotary_tables(cos, sin):
+    """What the pass reads where the rotary turns a head's first R lanes
+    alone, of (T, R) ``cos`` and ``sin`` (``models/attention.rotary_cos_sin``
+    of R): (T, W) ``cos``, ``sin up`` and ``sin down``, W the whole lane
+    tiles that hold the R lanes; ``cos`` is 1 past them (those lanes pass
+    as they are), ``sin up`` is the sine on the second half (its partner is
+    R / 2 lanes back) and ``sin down`` minus the sine on the first (its
+    partner R / 2 lanes on), noughts elsewhere (:func:`_turn`)."""
+    turned = cos.shape[-1]
+    pad = ((0, 0), (0, -turned % LANES))
+    first = jnp.arange(turned) < turned // 2
+    return (jnp.pad(cos, pad, constant_values=1.0),
+            jnp.pad(jnp.where(first, 0.0, sin), pad),
+            jnp.pad(jnp.where(first, -sin, 0.0), pad))
 
 
 # ---------------------------------------------------------------------------

@@ -56,7 +56,7 @@ import pytest
 from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
 from dalle_tpu.config import (AfmoeLMConfig, JoyAILMConfig, KeyeLMConfig,
                               Lfm2MoeLMConfig, NemotronHLMConfig,
-                              SparseLMConfig)
+                              Qwen3NextLMConfig, SparseLMConfig)
 from dalle_tpu.models import attention, family, sparse_lm
 from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
 
@@ -67,7 +67,8 @@ CHAIN = {SparseLMConfig: (None, 27), AfmoeLMConfig: (SparseLMConfig, 12),
          JoyAILMConfig: (AfmoeLMConfig, 8),
          Lfm2MoeLMConfig: (AfmoeLMConfig, 2),
          KeyeLMConfig: (AfmoeLMConfig, 7),
-         NemotronHLMConfig: (AfmoeLMConfig, 11)}
+         NemotronHLMConfig: (AfmoeLMConfig, 11),
+         Qwen3NextLMConfig: (AfmoeLMConfig, 8)}
 
 
 def as_file(cfg):
@@ -375,11 +376,17 @@ class MechanismsLeftOut:
     def with_everything(cls):
         cfg = cls.config(**cls.EVERYTHING)
         cfg.validate()
-        weights, (text, image) = params(cfg), batch(cfg)
+        weights, (text, image) = cls.weights_with_everything(cfg), batch(cfg)
         (loss, aux), _ = system(cfg, weights, text, image)
         theirs = cls.for_the_reference(cfg, weights)
         whole = reference_loss(cls.Y, theirs, text, image, as_file(cfg))
         return cfg, theirs, text, image, float(loss), aux, whole
+
+    @staticmethod
+    def weights_with_everything(cfg):
+        """The weights both sides run on: a subclass moves what an init
+        leaves where its mechanisms do not count."""
+        return params(cfg)
 
     @staticmethod
     def for_the_reference(cfg, weights):
@@ -439,11 +446,19 @@ class SharesAddUp:
         if not base.expert_gated:       # two leaves an expert, two a block
             for block in ("experts", "shared"):
                 whole.get(block, {}).pop("gate", None)
+        if base.shared_expert_gate:     # a number a token on the shared one
+            whole["shared_gate"] = jax.random.normal(
+                jax.random.PRNGKey(4), (d,)) * 0.2
         shared = jnp.zeros_like(m)
         with jax.default_matmul_precision("highest"):
-            if fs:
+            if base.shared_expert_gate:
+                shared = y.shared_part(m, whole)
+                assert float(jnp.abs(shared - y.gated_block(
+                    m, whole["shared"])).max()) > 0.01
+            elif fs:
                 shared = (y.gated_block if base.expert_gated
                           else y.ungated_block)(m, whole["shared"])
+            if fs:
                 assert float(jnp.abs(shared).max()) > 0.01
             want = y.whole_layer_experts(m, whole, as_file(base))
             routed, here = jnp.zeros_like(m), 0.0

@@ -408,3 +408,63 @@ def test_the_blockwise_backward_the_rule_chooses_compiles(
     assert (kernel in lowered.as_text()) and (
         "_causal_dq_kernel" in lowered.as_text()) != fused
     lowered.compile()
+
+
+# -- heads of 256 lanes and a rotary of their first lanes (qwen3next80b) ------
+
+@pytest.mark.parametrize("tokens, kernel", [
+    # past what the one kernel holds (the cell's own length takes the one
+    # kernel: its compile is the real step's, on the chip and in
+    # test_benchmark_qwen3next.py's slow case)
+    (16384, "_causal_dkv_kernel"),
+])
+def test_the_256_wide_backward_the_rule_chooses_compiles_for_a_v5e(
+        tokens, kernel, one_chip, no_persistent_cache):
+    """``causal_attention``'s gradient alone at 16 query over 2 key-value
+    heads of 256 lanes, bfloat16: the chip's compiler takes what
+    ``fused_backward_fits(lanes=256)`` says fits VMEM, and the two kernels
+    past it."""
+    from dalle_tpu.ops.pallas import causal_attention_kernels as K
+
+    hd = K.WIDE
+    fused = kernel == "_causal_bwd_kernel"
+    assert (K.fused_backward_fits(tokens, 8, 2, lanes=hd) is None) == fused
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(K.causal_attention(
+            *a, None, K.BLOCK, False, hd).astype(jnp.float32)),
+            (0, 1, 2))(q, k, v)
+
+    q, kv = (jax.ShapeDtypeStruct((1, tokens, n * hd), jnp.bfloat16,
+                                  sharding=one_chip) for n in (16, 2))
+    lowered = jax.jit(grads).lower(q, kv, kv)
+    assert (kernel in lowered.as_text()) and (
+        "_causal_dq_kernel" in lowered.as_text()) != fused
+    lowered.compile()
+
+
+@pytest.mark.parametrize("lanes", [4096, 512])      # the cell's q and k
+def test_the_turned_head_pass_compiles_in_both_directions_for_a_v5e(
+        lanes, one_chip, no_persistent_cache):
+    """``head_norm_kernels.per_head`` with the norm and the rotary of a
+    256-wide head's first 64 lanes, and its gradient, at the cell's local
+    shapes: the two lane rotates of one lane tile lower and the tiles fit
+    VMEM."""
+    from dalle_tpu.ops.pallas import head_norm_kernels as K
+    tokens, hd, turned = 8192, 256, 64
+    assert K.fits(tokens, lanes, hd) is None
+
+    def both(x, scale, tables, w):
+        y, vjp = jax.vjp(lambda x, scale: K.per_head(
+            x, scale, tables, 1e-6, hd, False, turned), x, scale)
+        return y, vjp(w)
+
+    x = jax.ShapeDtypeStruct((1, tokens, lanes), jnp.bfloat16,
+                             sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((hd,), jnp.float32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((tokens, K.LANES), jnp.float32,
+                                 sharding=one_chip)
+    lowered = jax.jit(both).lower(x, scale, (table,) * 3, x)
+    assert {"_head_norm_fwd_kernel", "_head_norm_bwd_kernel"} <= set(
+        re.findall(r'kernel_name = "([^"]+)"', lowered.as_text()))
+    lowered.compile()

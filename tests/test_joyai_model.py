@@ -299,10 +299,15 @@ def test_latent_attention_on_a_mesh_is_the_one_device_layer(
 
 def test_the_pair_pass_in_the_model_is_its_xla_lowering(monkeypatch,
                                                         lowering_record):
-    """The whole tiny model with the rotary as the one pass on the lanes
-    against the same model with ``rotary_interleaved_lanes``: within the
-    limits the yardstick's comparison has."""
-    cfg = JoyAILMConfig(**dict(TINY, **KERNEL_WIDTHS))
+    """A tiny model of one latent-attention layer (the pass's two shapes of
+    call, the queries' rotary lanes and the one key: it is held to a
+    layer's gradients, not to a stack of the same layer and a prediction
+    module) with the rotary as the one pass on the lanes against the same
+    model with ``rotary_interleaved_lanes``: within the limits the
+    yardstick's comparison has."""
+    cfg = JoyAILMConfig(**dict(TINY, **KERNEL_WIDTHS, num_hidden_layers=1,
+                               num_dense_layers=0,
+                               num_nextn_predict_layers=0))
     cfg.validate()
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
     rotaries = [("rotary", sparse_lm._pair_key(cfg.total_seq_len, lanes, 64))

@@ -144,12 +144,15 @@ def test_attn_layout_says_which_lowering_the_rotary_took(
 
 def test_the_rotary_in_its_pass_is_the_xla_lowering(monkeypatch,
                                                     lowering_record):
-    """Loss and every gradient leaf of a tiny model whose three rope
-    layers rotate queries and keys in the pass (interpreted; 32 tokens),
-    against the same model with the rotary as ``apply_rotary_lanes``: the
-    same f32 model to its rounding."""
+    """Loss and every gradient leaf of a tiny model of one rope layer (the
+    kind's one shape of call: the pass is held to a layer's gradients, not
+    to a stack of the same layer) that rotates queries and keys in the pass
+    (interpreted; 32 tokens), against the same model with the rotary as
+    ``apply_rotary_lanes``: the same f32 model to its rounding."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
-    cfg = SparseLMConfig(**dict(TINY, head_dim=128, text_seq_len=16))
+    cfg = SparseLMConfig(**dict(TINY, head_dim=128, text_seq_len=16,
+                                num_hidden_layers=1,
+                                layer_kinds=("window_rope",)))
     # the queries' 4 heads and the keys' 2
     took = lambda: {lowering_record.why_not(
         "rotary", (cfg.total_seq_len, heads * 128, 128)) for heads in (4, 2)}

@@ -201,13 +201,16 @@ def test_attn_layout_says_which_lowering_the_head_norms_took(
 
 def test_norm_and_rotary_in_one_pass_are_the_xla_lowering(monkeypatch,
                                                           lowering_record):
-    """Loss and every gradient leaf of the tiny model whose four rope
-    layers norm and rotate queries and keys in one pass and whose full
-    layer norms them in it (interpreted), against the same model with the
-    reshaped ``rms_norm`` and ``apply_rotary_lanes``: the same f32 model
-    to its rounding."""
+    """Loss and every gradient leaf of a tiny model of one layer of each
+    kind (the pass's two shapes of call: it is held to a layer's gradients,
+    not to a stack of the same layer) whose rope layer norms and rotates
+    queries and keys in one pass and whose full layer norms them in it
+    (interpreted), against the same model with the reshaped ``rms_norm``
+    and ``apply_rotary_lanes``: the same f32 model to its rounding."""
     monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
-    cfg = AfmoeLMConfig(**dict(TINY, head_dim=128))
+    cfg = AfmoeLMConfig(**dict(TINY, head_dim=128, num_hidden_layers=2,
+                               num_dense_layers=0,
+                               layer_kinds=("window_rope", "full_nope")))
     # the queries' 4 heads and the keys' 2, with the rotary and without
     took = lambda: {(rotary, lowering_record.why_not(
         "head norm" + " + rotary" * rotary,
