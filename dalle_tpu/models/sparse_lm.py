@@ -259,19 +259,29 @@ derivative two products with the inverse it made), in f32 from three
 bfloat16 pieces an operand; the decays, their sums and the carried states are
 f32; the
 products' operands are in ``cfg.dtype`` with f32 accumulation (the inverse
-too, once made). The chunks are a ``lax.scan`` that carries the state, in
-blocks of ``RULE_BLOCK`` chunks whose in-chunk tables are made at once and
-which are replayed a block under ``jax.checkpoint`` (the states kept are one
-a block, a block's tables alive at a time): nothing of (T, T) and no state a
-token exists, forward, replay or backward. No Mosaic kernel
-is written for the rule yet: the site's XLA lowering is its one lowering,
-and ``gdn/rule`` is where a kernel's time will be read. The taps take the
+too, once made). Nothing of (T, T) and no state a token exists, forward,
+replay or backward. Where the local shapes are lane tiles (whole chunks of a
+power of two of 8 to 128 tokens, ``dk`` and ``dv`` whole tiles, value heads
+a whole multiple of key heads: ``delta_rule_kernels.fits``) it runs as a
+forward and a backward Mosaic kernel (ops/pallas/delta_rule_kernels.py: a
+chunk's tables and its inverse are made, used and dropped in VMEM, two
+heads' (64 x 64) tables as one block-diagonal (128 x 128) operand, and the
+states are carried there; the layer's replay is the forward call that keeps
+the state each grid step starts from and every chunk's inverse, and the
+backward kernel walks a grid step's chunks forward again from that state,
+then in reverse); anywhere else (no Mosaic backend, a ragged tail, the
+tests' tiny widths) as XLA code, :func:`chunked_delta_rule`: a ``lax.scan``
+that carries the state, in blocks of ``RULE_BLOCK`` chunks whose in-chunk
+tables are made at once and which are replayed a block under
+``jax.checkpoint`` (the states kept are one a block, a block's tables alive
+at a time). The taps take the
 Mamba-2 mixer's pass (``ssm_pass_kernels.taps_silu`` with a bias of
 noughts, ``q``, ``k`` and ``v`` written apart) where its predicate takes
 the shapes, the heads' norm :func:`head_pass`'s kernel; the L2 norms, the
 gate and ``beta`` / ``g`` are XLA code. Scopes ``gdn/in_proj``,
 ``gdn/conv`` (``taps[mosaic]``, or XLA code), ``gdn/rule`` (the L2 norms,
-``beta``, ``g`` and the chunked form), ``gdn/gate_norm``
+``beta``, ``g`` and the rows' transposes, XLA code either way; then the
+chunked form: ``rule[mosaic]``, or XLA code), ``gdn/gate_norm``
 (``qk_norm[mosaic]``: the head pass's name; then the gate) and
 ``gdn/out_proj`` (never under ``attn``, ``conv`` or ``ssm``).
 
@@ -429,6 +439,7 @@ from dalle_tpu.config import (LAYER_EXPERTS, LAYER_FULL_ROPE,
                               LAYER_WINDOW_ROPE, SparseLMConfig)
 from dalle_tpu.models import attention as attn_mod
 from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
+from dalle_tpu.ops.pallas import delta_rule_kernels
 from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
 from dalle_tpu.ops.pallas import head_norm_kernels as head_norm
 from dalle_tpu.ops.pallas import indexer_kernels as index_kernels
@@ -1896,8 +1907,6 @@ def chunked_delta_rule(q, k, v, g, beta, *, key_heads: int,
 
 
 DELTA_SITE = "delta rule"
-NO_RULE_KERNEL = ("no Mosaic kernel is written for the rule: a scan over "
-                  "chunks in XLA code")
 
 
 def _delta_key(tokens: int, cfg: SparseLMConfig):
@@ -1911,20 +1920,36 @@ def _delta_key(tokens: int, cfg: SparseLMConfig):
 def delta_rule(q, k, v, g, beta, *, mesh, cfg: SparseLMConfig,
                scope: Optional[str] = None):
     """The rule as a call site: a shard's samples, every head (no mesh axis
-    splits the mixer's lanes). Its one lowering today is
-    :func:`chunked_delta_rule`; the site is where a kernel's predicate
-    will stand."""
-    xla = functools.partial(chunked_delta_rule,
-                            key_heads=cfg.linear_num_key_heads,
-                            chunk=cfg.delta_chunk)
+    splits the mixer's lanes). The kernels of
+    ops/pallas/delta_rule_kernels.py where their predicate takes the local
+    shapes, else :func:`chunked_delta_rule`."""
+    sizes = dict(key_heads=cfg.linear_num_key_heads, chunk=cfg.delta_chunk)
 
     def fits(q, k, v, g, beta) -> bool:
-        return lowering.chose(DELTA_SITE, _delta_key(q.shape[1], cfg),
-                              NO_RULE_KERNEL, NO_RULE_KERNEL)
+        tokens = q.shape[1]
+        key = _delta_key(tokens, cfg)
+        why_not = delta_rule_kernels.fits(*key, v.dtype.itemsize)
+        if why_not is not None:
+            return lowering.chose(DELTA_SITE, key, why_not, why_not)
+        r = cfg.linear_num_value_heads // cfg.linear_num_key_heads
+        keys = delta_rule_kernels.keys_a_step(cfg.linear_num_key_heads, r,
+                                              cfg.delta_chunk)
+        n = delta_rule_kernels.chunks_a_step(
+            tokens // cfg.delta_chunk, cfg.delta_chunk, r,
+            cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+            v.dtype.itemsize, keys)
+        return lowering.chose(
+            DELTA_SITE, key, None,
+            f"local v{v.shape} in chunks of {cfg.delta_chunk}, {n} chunks "
+            f"of {keys} key heads a grid step, {r} value heads a key head",
+            chunks_a_step=n, keys_a_step=keys,
+            backward=delta_rule_kernels.BACKWARD)
 
+    kernel = functools.partial(delta_rule_kernels.rule, **sizes,
+                               interpret=lowering.interpret())
+    xla = functools.partial(chunked_delta_rule, **sizes)
     lanes = P(*LANES_SPEC[:2], None)
-    # ``fits`` refuses every shape: the kernel's place holds the lowering
-    return lowering.site(DELTA_SITE, fits, xla, xla, mesh, (lanes,) * 5,
+    return lowering.site(DELTA_SITE, fits, kernel, xla, mesh, (lanes,) * 5,
                          lanes, scope)(q, k, v, g, beta)
 
 
@@ -2048,8 +2073,17 @@ def gdn_layout(cfg: SparseLMConfig, tp: int = 1) -> str:
     and of the mixer's sites what their traced calls said."""
     kinds = [cfg.kind_of_layer(i) for i in range(cfg.num_hidden_layers)]
     tokens, chunk = cfg.total_seq_len, cfg.delta_chunk
-    why = lowering.why_not(DELTA_SITE, _delta_key(tokens, cfg))
-    rule = "a Pallas kernel" if why is None else f"XLA chunks ({why})"
+    delta_key = _delta_key(tokens, cfg)
+    why = lowering.why_not(DELTA_SITE, delta_key)
+    if why is not None:
+        rule = (f"XLA chunks ({why}), replayed a block of {RULE_BLOCK} "
+                "chunks in the backward pass")
+    else:
+        said = lowering.recorded(DELTA_SITE, delta_key)
+        rule = (f"a Pallas kernel a direction ({said['chunks_a_step']} "
+                f"chunks of {said['keys_a_step']} key heads a grid step, a "
+                "chunk's tables, its inverse and the "
+                f"carried states in VMEM; backward: {said['backward']})")
     why = lowering.why_not(GDN_TAPS_SITE, _gdn_taps_key(tokens, cfg))
     taps = ("the Mamba-2 mixer's pass, one a direction, q, k and v written "
             "apart, a bias of noughts" if why is None
@@ -2071,9 +2105,9 @@ def gdn_layout(cfg: SparseLMConfig, tp: int = 1) -> str:
         "doubled by substitution), across chunks the carried "
         f"({cfg.linear_key_head_dim} x {cfg.linear_value_head_dim}) state, "
         "the decays, their sums, the inverse and the states in f32; no (T, "
-        f"T) array and no state a token; gdn/rule: {rule}, replayed a block "
-        f"of {RULE_BLOCK} chunks in the backward pass; taps and SiLU: {taps}; the heads' norm "
-        f"before the gate: {norm}, the gate XLA code")
+        f"T) array and no state a token; gdn/rule: {rule}; taps and SiLU: "
+        f"{taps}; the heads' norm before the gate: {norm}, the gate XLA "
+        "code")
 
 
 # ---------------------------------------------------------------------------

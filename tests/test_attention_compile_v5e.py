@@ -468,3 +468,34 @@ def test_the_turned_head_pass_compiles_in_both_directions_for_a_v5e(
     assert {"_head_norm_fwd_kernel", "_head_norm_bwd_kernel"} <= set(
         re.findall(r'kernel_name = "([^"]+)"', lowered.as_text()))
     lowered.compile()
+
+
+# -- the gated delta rule's kernel pair (qwen3next80b) ------------------------
+
+@pytest.mark.parametrize("tokens", [8192])          # the cell's local sample
+def test_the_delta_rules_kernels_compile_in_both_directions_for_a_v5e(
+        tokens, one_chip, no_persistent_cache):
+    """``delta_rule_kernels.rule`` and its gradient at the cell's local
+    shape (1 x 8 192 tokens, 16 query/key heads x 128 serving 32 value
+    heads x 128, chunks of 64, bfloat16): the forward that keeps a state a
+    grid step and the backward lower, and the chip's compiler takes the
+    (64 x 64) tables, the products with noughts and ones and the blocks
+    that ``vmem_bytes`` counts."""
+    from dalle_tpu.ops.pallas import delta_rule_kernels as K
+    key_heads, heads, dk, dv, chunk = 16, 32, 128, 128, 64
+    assert K.fits(tokens, key_heads, heads, dk, dv, chunk, 2) is None
+
+    def both(q, k, v, g, beta, w):
+        o, vjp = jax.vjp(lambda *a: K.rule(*a, key_heads=key_heads,
+                                           chunk=chunk), q, k, v, g, beta)
+        return o, vjp(w)
+
+    narrow, wide = (jax.ShapeDtypeStruct((1, tokens, n * K.LANES),
+                                         jnp.bfloat16, sharding=one_chip)
+                    for n in (key_heads, heads))
+    row = jax.ShapeDtypeStruct((1, tokens, heads), jnp.float32,
+                               sharding=one_chip)
+    lowered = jax.jit(both).lower(narrow, narrow, wide, row, row, wide)
+    assert {"_delta_rule_fwd_kernel", "_delta_rule_bwd_kernel"} <= set(
+        re.findall(r'kernel_name = "([^"]+)"', lowered.as_text()))
+    lowered.compile()

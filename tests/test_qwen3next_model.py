@@ -18,6 +18,7 @@ from dalle_tpu.config import (AfmoeLMConfig, Qwen3NextLMConfig,
                               SparseLMConfig, qwen3next80b_model_config)
 from dalle_tpu.models import attention, decode, sparse_lm
 from dalle_tpu.ops.pallas import causal_attention_kernels as kernels
+from dalle_tpu.ops.pallas import delta_rule_kernels
 from sparse_family import rel_l2
 
 Y = Manifest().yardstick("qwen3next")
@@ -184,11 +185,11 @@ class TestQwen3next80b(fam.Family, fam.MechanismsLeftOut, fam.SharesAddUp):
             assert float(jnp.abs(jax.tree.leaves(leaf)[0]).max()) > 0, name
         assert float(jnp.abs(
             grads["params"][last]["ff"]["shared_gate"]).max()) > 0
-        # the rule has one lowering, whatever the backend, and says so
+        # a mixer of lane tiles takes the rule's kernels, and says so
         tokens = cfg.total_seq_len
         rule = sparse_lm.DELTA_SITE, sparse_lm._delta_key(tokens, cfg)
         assert lowering_record.why_not(*rule) == (
-            sparse_lm.NO_RULE_KERNEL if with_kernels else shut)
+            None if with_kernels else shut)
         layout = said["gdn_layout"]
         assert layout.startswith(
             f"gated-delta-rule mixer: {mixers} of {layers} layers, "
@@ -200,8 +201,15 @@ class TestQwen3next80b(fam.Family, fam.MechanismsLeftOut, fam.SharesAddUp):
         assert "no (T, T) array and no state a token" in layout
         taps = sparse_lm.GDN_TAPS_SITE, sparse_lm._gdn_taps_key(tokens, cfg)
         if with_kernels:
-            assert "gdn/rule: XLA chunks (no Mosaic kernel is written" \
-                in layout
+            assert lowering_record.recorded(*rule) == {
+                "why_not": None, "chunks_a_step": 4, "keys_a_step": 1,
+                "backward": delta_rule_kernels.BACKWARD}
+            assert ("gdn/rule: a Pallas kernel a direction (4 chunks of 1 "
+                    "key heads a grid step, a chunk's tables, its inverse "
+                    "and the carried "
+                    "states in VMEM; backward: one kernel, a grid step's "
+                    "chunks forward again from the state the forward kept a "
+                    "step, then in reverse); taps and SiLU") in layout
             # the Mamba-2 mixer's taps pass and the head pass take the
             # mixer's shapes; the attention's heads the 256-wide form
             assert lowering_record.recorded(*taps) == {"why_not": None}
@@ -216,6 +224,8 @@ class TestQwen3next80b(fam.Family, fam.MechanismsLeftOut, fam.SharesAddUp):
                 "rotary of a head's first 64 lanes (in the head pass: 1 of 1 "
                 "rope layers), gated output")
         else:
+            assert (f"gdn/rule: XLA chunks ({shut}), replayed a block of 16 "
+                    "chunks in the backward pass; taps") in layout
             assert f"taps and SiLU: XLA code ({shut})" in layout
             assert "rotary of a head's first 4 lanes" in said["attn_layout"]
         assert "ssm_layout" not in said and "conv_layout" not in said
@@ -281,6 +291,30 @@ class TestQwen3next80b(fam.Family, fam.MechanismsLeftOut, fam.SharesAddUp):
             dataclasses.replace(cfg, partial_rotary_factor=0.3).validate()
         with pytest.raises(ValueError, match="gated shared expert"):
             dataclasses.replace(cfg, num_shared_experts=0).validate()
+
+
+def test_the_tiny_mixer_says_its_refusal_by_shape(monkeypatch,
+                                                  lowering_record):
+    """With a Mosaic backend the tiny model's rule (43 tokens in chunks of
+    16, heads of 8 lanes) is the XLA lowering, refused by what the site
+    observed of the shapes; ``gdn_layout`` says which."""
+    monkeypatch.setattr(attention, "_PALLAS_INTERPRET", True)
+    cfg = Qwen3NextLMConfig(**TINY)
+    (q, k, v, g, beta), _ = _rule_operands(cfg.total_seq_len)
+    got = sparse_lm.delta_rule(q, k, v, g, beta, mesh=None, cfg=cfg)
+    np.testing.assert_array_equal(got, sparse_lm.chunked_delta_rule(
+        q, k, v, g, beta, key_heads=2, chunk=16))
+    why = "43 tokens are not whole chunks of 16"
+    assert lowering_record.why_not(
+        sparse_lm.DELTA_SITE, sparse_lm._delta_key(43, cfg)) == why
+    assert (f"gdn/rule: XLA chunks ({why}), replayed a block of 16 chunks "
+            "in the backward pass") in sparse_lm.gdn_layout(cfg)
+    # whole chunks of it: the heads' lanes
+    sparse_lm.delta_rule(*(x[:, :32] for x in (q, k, v, g, beta)),
+                         mesh=None, cfg=cfg)
+    assert lowering_record.why_not(
+        sparse_lm.DELTA_SITE, sparse_lm._delta_key(32, cfg)) == (
+            "heads of 8 and 8 lanes are not whole 128-lane tiles")
 
 
 def _rule_operands(tokens, g_heads=2, r=2, dk=8, dv=8):
