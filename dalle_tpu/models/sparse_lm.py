@@ -266,15 +266,21 @@ a whole multiple of key heads: ``delta_rule_kernels.fits``) it runs as a
 forward and a backward Mosaic kernel (ops/pallas/delta_rule_kernels.py: a
 chunk's tables and its inverse are made, used and dropped in VMEM, two
 heads' (64 x 64) tables as one block-diagonal (128 x 128) operand, and the
-states are carried there; the layer's replay is the forward call that keeps
-the state each grid step starts from and every chunk's inverse, and the
-backward kernel walks a grid step's chunks forward again from that state,
-then in reverse); anywhere else (no Mosaic backend, a ragged tail, the
-tests' tiny widths) as XLA code, :func:`chunked_delta_rule`: a ``lax.scan``
+states are carried there; the forward call of a gradient keeps the state
+each grid step starts from and every chunk's inverse, and the backward
+kernel walks a grid step's chunks forward again from that state, then in
+reverse; a rematerialised layer keeps by name, ``KEPT_OF_A_LAYER``, ``o``,
+those states and those inverses, and the normalised ``q`` and ``k`` and the
+``g`` and ``beta`` rows that only this scope makes: 258 MiB a sample and
+layer at ``qwen3next80b``'s sizes, so its replay runs neither the forward
+kernel nor the scope's XLA code a second time); anywhere else (no Mosaic
+backend, a ragged tail, the tests' tiny widths) as XLA code,
+:func:`chunked_delta_rule`: a ``lax.scan``
 that carries the state, in blocks of ``RULE_BLOCK`` chunks whose in-chunk
 tables are made at once and which are replayed a block under
 ``jax.checkpoint`` (the states kept are one a block, a block's tables alive
-at a time). The taps take the
+at a time; a layer's rematerialisation keeps nothing of it and replays it
+whole). The taps take the
 Mamba-2 mixer's pass (``ssm_pass_kernels.taps_silu`` with a bias of
 noughts, ``q``, ``k`` and ``v`` written apart) where its predicate takes
 the shapes, the heads' norm :func:`head_pass`'s kernel; the L2 norms, the
@@ -2080,10 +2086,16 @@ def gdn_layout(cfg: SparseLMConfig, tp: int = 1) -> str:
                 "chunks in the backward pass")
     else:
         said = lowering.recorded(DELTA_SITE, delta_key)
+        kept = delta_rule_kernels.kept_bytes(
+            *delta_key, jnp.dtype(cfg.dtype).itemsize) / 2 ** 20
         rule = (f"a Pallas kernel a direction ({said['chunks_a_step']} "
                 f"chunks of {said['keys_a_step']} key heads a grid step, a "
                 "chunk's tables, its inverse and the "
-                f"carried states in VMEM; backward: {said['backward']})")
+                f"carried states in VMEM; backward: {said['backward']}; a "
+                "rematerialised layer keeps o, the state a grid step, the "
+                "inverses, the normalised q and k and the g and beta rows, "
+                f"{kept:g} MiB a sample and layer: its replay runs neither "
+                "the forward kernel nor the norms and rows again)")
     why = lowering.why_not(GDN_TAPS_SITE, _gdn_taps_key(tokens, cfg))
     taps = ("the Mamba-2 mixer's pass, one a direction, q, k and v written "
             "apart, a bias of noughts" if why is None
@@ -2905,8 +2917,14 @@ class OnePartLayer(nn.Module):
 
 # What a rematerialised layer keeps besides its input: its attention's
 # output and row statistics (the backward pass replays the projections and
-# the experts, not the attention kernel) and, of a sigmoid router, the
-# experts its tokens chose, (B, T, k) int32. A compiler may fuse and round
+# the experts, not the attention kernel), of a gated-delta mixer what its
+# rule's kernel made and read (``o``, the state a grid step, every chunk's
+# inverse, the normalised ``q`` and ``k`` and the two rows:
+# ``delta_rule_kernels.kept_bytes``, 258 MiB a sample and layer at
+# ``qwen3next80b``'s sizes, where the replay of the forward kernel was 2.07
+# ms a layer and micro-step and of the XLA code around it 0.6; PERF.md
+# section 6, PR 66) and, of a sigmoid router, the experts its tokens chose,
+# (B, T, k) int32. A compiler may fuse and round
 # the replay's scores otherwise than the forward pass's, and a top-k taken
 # again then flips near-ties, so that the backward pass differentiates
 # other experts than the forward pass ran: XLA's CPU backend does (the
@@ -2914,7 +2932,8 @@ class OnePartLayer(nn.Module):
 # own sets), the v5e's did not at the cell's size (PERF.md section 6, PR
 # 44). Kept, the sets a forward-and-backward program returns are the ones
 # it differentiates, whatever the compiler.
-KEPT_OF_A_LAYER = ("attn_out", "attn_stats", "chosen")
+KEPT_OF_A_LAYER = ("attn_out", "attn_stats", "chosen",
+                   *delta_rule_kernels.KEPT)
 
 HEAD_SITE = "streamed head"
 

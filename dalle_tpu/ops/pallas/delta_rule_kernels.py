@@ -58,13 +58,28 @@ thirds of the forward call (1.4 of 2.1 ms), at what the MXU gives thirty
 (128 x 128 x 128) products a chunk.
 
 The forward writes ``o`` and, where a gradient will ask (:func:`_core`'s
-``custom_vjp`` rule, which under a layer's rematerialisation is the replay),
-**the state each grid step starts from**, (B, G / keys, steps, dk, keys r dv)
-f32, and **every chunk's inverses in f32**, the heads' (Q x Q) blocks side by
-side: 64 MiB each a layer at the cell's sizes (8 key heads and 4 chunks a
-step), alive for that layer's backward, where a state a chunk would be 256
-MiB; the inverse kept is what the XLA lowering's ``custom_vjp`` keeps too,
-and making it a third time cost 1.3 ms a call. The backward's grid step
+``custom_vjp`` rule), **the state each grid step starts from**, (B, G /
+keys, steps, dk, keys r dv) f32, and **every chunk's inverses in f32**, the
+heads' (Q x Q) blocks side by side: 64 MiB each a layer at the cell's sizes
+(8 key heads and 4 chunks a step), where a state a chunk would be 256 MiB;
+the inverse kept is what the XLA lowering's ``custom_vjp`` keeps too, and
+making it a third time cost 1.3 ms a call. **Across a rematerialised
+layer's replay** the rule keeps what it made and what only its scope makes:
+the derivative's forward names, with
+``jax.ad_checkpoint.checkpoint_name`` and on the residuals themselves as the
+attention kernels name their context and statistics, ``o``, the states and
+the inverses (``KEPT_MADE``), and the normalised ``q`` and ``k`` and the
+``g`` and ``beta`` rows (``KEPT_READ``), and
+``sparse_lm.KEPT_OF_A_LAYER`` holds the names: :func:`kept_bytes`, 258 MiB a
+sample and layer at the cell's sizes (64 + 64 + 64, 32 + 32, 1 + 1), alive
+from the layer's forward to its backward, and the backward pass runs no
+forward kernel and none of the L2 norms' and the rows' XLA code a second
+time. A policy without the names replays both (the forward call 2.07 ms a
+layer and micro-step at the cell's shape, which JAX differentiates through
+the same ``keep`` call in the first forward too; the XLA code 0.6 ms:
+PERF.md section 6, PR 66). The XLA lowering
+(``sparse_lm.chunked_delta_rule``) is not part of this: it keeps
+its own ``jax.checkpoint`` a block. The backward's grid step
 makes its chunks' tables from the inverses, walks the chunks forward from
 the step's state (keeping in VMEM the state each chunk starts from and
 ``U``), then in reverse with the states' cotangent in scratch (keeping it a
@@ -114,6 +129,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -146,6 +162,12 @@ _VMEM = 64 * 1024 * 1024
 _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"),
     vmem_limit_bytes=_VMEM)
+# the names (``jax.ad_checkpoint.checkpoint_name``) of what a
+# rematerialisation policy may keep of a derivative's forward: what the
+# kernel made (``o``, the state each grid step starts from, every chunk's
+# inverses) and what it read that only the caller's scope makes (the
+# normalised ``q`` and ``k``, the two rows)
+KEPT = KEPT_MADE, KEPT_READ = "rule_made", "rule_read"
 # how the backward gets its states, a fact of the site's record
 BACKWARD = ("one kernel, a grid step's chunks forward again from the state "
             "the forward kept a step, then in reverse")
@@ -192,6 +214,21 @@ def chunks_a_step(chunks: int, chunk: int, heads_a_key: int, dk: int,
     return max(k for k in range(1, max(1, STEP_TOKENS // chunk) + 1)
                if chunks % k == 0 and (k == 1 or vmem_bytes(
                    chunk, heads_a_key, dk, dv, itemsize, k, keys) <= _VMEM))
+
+
+def kept_bytes(tokens: int, key_heads: int, heads: int, dk: int, dv: int,
+               chunk: int, itemsize: int) -> int:
+    """Bytes a sample of what the names ``KEPT`` keep of one call: ``o``,
+    the state each grid step starts from and every chunk's inverses (f32),
+    ``q`` and ``k``, and the ``g`` and ``beta`` rows (f32, :func:`rule`)."""
+    r = heads // key_heads
+    keys, pack = keys_a_step(key_heads, r, chunk), pack_of(r, chunk)
+    chunks = tokens // chunk
+    steps = chunks // chunks_a_step(chunks, chunk, r, dk, dv, itemsize, keys)
+    rows = key_heads // keys * chunks * _rows(keys * r // pack) * pack * chunk
+    return (tokens * heads * dv * itemsize + steps * heads * dk * dv * 4
+            + tokens * heads * chunk * 4
+            + 2 * tokens * key_heads * dk * itemsize + 2 * rows * 4)
 
 
 def fits(tokens: int, key_heads: int, heads: int, dk: int, dv: int,
@@ -906,6 +943,12 @@ def _core(q, k, v, g, beta, r: int, chunk: int, keys: int, interpret: bool):
 def _core_fwd(q, k, v, g, beta, r, chunk, keys, interpret):
     o, *kept = _fwd_call(q, k, v, g, beta, r=r, chunk=chunk, keys=keys,
                          keep=True, interpret=interpret)
+    # named on the residuals themselves (and on ``o``, which the layer's
+    # norm reads again), so that a remat policy can keep them and the
+    # backward pass runs neither the forward kernel nor the caller's code
+    # for ``q``, ``k`` and the rows again (``v`` is the taps' to keep)
+    o, *kept = (checkpoint_name(x, KEPT_MADE) for x in (o, *kept))
+    q, k, g, beta = (checkpoint_name(x, KEPT_READ) for x in (q, k, g, beta))
     return o, (q, k, v, g, beta, *kept)
 
 
@@ -924,7 +967,9 @@ def rule(q, k, v, g, beta, *, key_heads: int, chunk: int,
     f32. The rows the kernels read (a pack of heads a sublane, their
     tokens of a chunk one head after another on the lanes) are XLA code
     here, and their gradient is JAX's differentiation of it. Gradient
-    residuals: the operands and the state each grid step starts from."""
+    residuals: the operands, the state each grid step starts from and every
+    chunk's inverses; all but ``v``, and ``o``, carry the names a layer's
+    rematerialisation keeps them by (``KEPT``)."""
     b, t, _ = v.shape
     r = g.shape[-1] // key_heads
     pack = pack_of(r, chunk)

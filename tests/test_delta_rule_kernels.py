@@ -8,7 +8,11 @@ that resemble each other (the inverse's hard case), a state remembered
 across chunks (``A`` = 0.05) and one forgotten within a token (``A`` as the
 source draws it), keys wider than values, chunks of 16, two samples, f32 and
 bfloat16; the site's predicate, each refusal with its recorded
-reason and the XLA lowering's result; per shard on the 8-device mesh."""
+reason and the XLA lowering's result; per shard on the 8-device mesh; a
+rematerialised layer of the model keeps what the rule made (one forward
+kernel in its gradient's program, two without the rule's names in the
+policy) and computes the same numbers either way."""
+import collections
 import functools
 
 import jax
@@ -21,6 +25,7 @@ from dalle_tpu.config import Qwen3NextLMConfig
 from dalle_tpu.models import attention, sparse_lm
 from dalle_tpu.ops.pallas import delta_rule_kernels as K
 from dalle_tpu.parallel.mesh import make_mesh
+import sparse_family as fam
 from sparse_family import rel_l2
 
 Y = Manifest().yardstick("qwen3next")
@@ -281,3 +286,92 @@ def test_per_shard_a_shard_holds_samples_and_every_head(
     np.testing.assert_allclose(y_m, y_1, rtol=1e-6, atol=1e-6)
     for name, got, want in zip(OPERANDS, g_m, g_1):
         assert rel_l2(got, want) < 1e-6, name
+
+
+# one gated-delta layer of the model (mixer and expert block) at the widths
+# the kernels take, 128 tokens in two fields
+LAYER = dict(TINY, **FITS, text_seq_len=64, image_grid=8)
+_OTHERS = tuple(name for name in sparse_lm.KEPT_OF_A_LAYER
+                if name not in K.KEPT)
+# what the policy of a rematerialised layer names, by case, and what the
+# gradient's program then holds: forward kernels, and ``by_row`` transposes
+# of the ``g`` and ``beta`` rows (one each in a forward pass)
+POLICIES = {
+    "without_the_rules_names": (_OTHERS, 2, 4),
+    "what_the_kernel_made_alone": (_OTHERS + (K.KEPT_MADE,), 1, 4),
+    "as_shipped": (sparse_lm.KEPT_OF_A_LAYER, 1, 2),
+}
+ROWS_PERMUTATION = (0, 3, 1, 4, 5, 2)            # ``K.rule``'s ``by_row``
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [
+                    value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+@functools.cache
+def _layer(policy):
+    """(the jaxpr of the model's gradient, its loss and gradients) with
+    ``POLICIES[policy]``'s names as ``KEPT_OF_A_LAYER``: one program a
+    policy."""
+    cfg = Qwen3NextLMConfig(**LAYER)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attention, "_PALLAS_INTERPRET", True)
+        patch.setattr(sparse_lm, "KEPT_OF_A_LAYER", POLICIES[policy][0])
+        weights, operands = fam.params(cfg), fam.batch(cfg, n=1)
+        step = fam.program(cfg)
+        return (step.trace(weights, *operands).jaxpr,
+                jax.device_get(step(weights, *operands)))
+
+
+@pytest.mark.parametrize("policy", list(POLICIES))
+def test_a_rematerialised_layer_runs_the_forward_kernel_once(policy):
+    """The gradient of the model's one ``gated_delta`` layer under
+    ``nn.remat``: with the rule's names in ``KEPT_OF_A_LAYER`` the replay
+    reads ``o``, the states and the inverses the first forward wrote, and
+    with the name of what the kernel read it makes no row of ``g`` and
+    ``beta`` again; with the names taken out it runs the forward kernel a
+    second time (so: the names reach the policy)."""
+    assert K.fits(128, 1, 2, 128, 128, 64, 4) is None
+    assert set(K.KEPT) <= set(sparse_lm.KEPT_OF_A_LAYER)
+    _, forwards, rows = POLICIES[policy]
+    equations = list(_equations(_layer(policy)[0]))
+    calls = collections.Counter(
+        eqn.params["jaxpr"].debug_info.func_name for eqn in equations
+        if eqn.primitive.name == "pallas_call")
+    assert calls["_delta_rule_fwd_kernel"] == forwards
+    assert calls["_delta_rule_bwd_kernel"] == 1
+    assert sum(eqn.primitive.name == "transpose"
+               and tuple(eqn.params["permutation"]) == ROWS_PERMUTATION
+               for eqn in equations) == rows
+
+
+@pytest.mark.parametrize("policy", list(POLICIES)[1:])
+def test_what_the_layer_keeps_changes_no_number(policy):
+    """Loss, aux and every gradient leaf under a policy with the rule's
+    names are those under the policy without them, bit for bit: the replay
+    made the same values from the same operands with the same program."""
+    (loss, aux), grads = _layer(policy)[1]
+    (loss_, aux_), grads_ = _layer("without_the_rules_names")[1]
+    assert loss == loss_
+    theirs = fam.leaves((aux_, grads_))
+    for name, got in fam.leaves((aux, grads)).items():
+        np.testing.assert_array_equal(got, theirs[name], err_msg=name)
+
+
+def test_what_the_names_keep_a_sample_and_layer():
+    """``kept_bytes``: ``o``, the states a grid step, the inverses, ``q``,
+    ``k`` and the two rows; 258 MiB at the cell's sizes."""
+    assert K.kept_bytes(8192, 16, 32, 128, 128, 64, 2) == (
+        64 + 64 + 64 + 32 + 32 + 1 + 1) << 20
+    # one grid step of two chunks, one pack of two heads
+    assert K.kept_bytes(128, 1, 2, 128, 128, 64, 4) == (
+        128 * 256 * 4 + 128 * 256 * 4 + 128 * 2 * 64 * 4
+        + 2 * 128 * 128 * 4 + 2 * 2 * 8 * 128 * 4)

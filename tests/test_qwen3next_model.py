@@ -209,7 +209,11 @@ class TestQwen3next80b(fam.Family, fam.MechanismsLeftOut, fam.SharesAddUp):
                     "and the carried "
                     "states in VMEM; backward: one kernel, a grid step's "
                     "chunks forward again from the state the forward kept a "
-                    "step, then in reverse); taps and SiLU") in layout
+                    "step, then in reverse; a rematerialised layer keeps o, "
+                    "the state a grid step, the inverses, the normalised q "
+                    "and k and the g and beta rows, 0.265625 MiB a sample "
+                    "and layer: its replay runs neither the forward kernel "
+                    "nor the norms and rows again); taps and SiLU") in layout
             # the Mamba-2 mixer's taps pass and the head pass take the
             # mixer's shapes; the attention's heads the 256-wide form
             assert lowering_record.recorded(*taps) == {"why_not": None}
