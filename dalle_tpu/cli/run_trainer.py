@@ -25,12 +25,12 @@ from typing import Optional, Sequence
 
 from dalle_tpu.config import (AfmoeLMConfig, CollabConfig, JoyAILMConfig,
                               KeyeLMConfig, Lfm2MoeLMConfig, ModelConfig,
-                              NemotronHLMConfig, OptimizerConfig, PeerConfig,
-                              Qwen3NextLMConfig, SparseLMConfig,
+                              NemotronHLMConfig, OptimizerConfig, OuroLMConfig,
+                              PeerConfig, Qwen3NextLMConfig, SparseLMConfig,
                               TrainerConfig, flagship_model_config,
                               joyaiflash_model_config,
                               keyevl2_model_config, lfm2moe_model_config,
-                              qwen3next80b_model_config,
+                              ouro2b6_model_config, qwen3next80b_model_config,
                               smallthinker21b_model_config,
                               tiny_model_config, trinitymini_model_config,
                               twotower30b_model_config, xl_model_config)
@@ -73,6 +73,10 @@ MODEL_PRESETS = {
     # 256-wide heads with a quarter of each rotated, 16 of 512 experts at
     # ten a token beside a gated shared expert): qwen3next80b-train-solo
     "qwen3next80b": qwen3next80b_model_config,
+    # Ouro-2.6B cut to 6 of its 48 layers and half its vocabulary (a dense
+    # stack of four-norm layers run four times on one set of parameters, an
+    # exit gate and the head after every pass): ouro2b6-train-solo
+    "ouro2b6": ouro2b6_model_config,
 }
 
 CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
@@ -81,7 +85,7 @@ CONFIG_CLASSES = (ModelConfig, OptimizerConfig, TrainerConfig, CollabConfig,
 # a field two of them share (vocab_text, dtype, ...) is one flag.
 MODEL_CLASSES = (ModelConfig, SparseLMConfig, AfmoeLMConfig,
                  JoyAILMConfig, Lfm2MoeLMConfig, KeyeLMConfig,
-                 NemotronHLMConfig, Qwen3NextLMConfig)
+                 NemotronHLMConfig, Qwen3NextLMConfig, OuroLMConfig)
 
 
 def maybe_wandb_run(project: Optional[str], name: str):

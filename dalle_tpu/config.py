@@ -850,6 +850,16 @@ class SparseLMConfig:
     linear_value_head_dim: ClassVar[int] = 0
     linear_conv_kernel_dim: ClassVar[int] = 0
     delta_chunk: ClassVar[int] = 0
+    # ... and of ``OuroLMConfig``: the times the one stack of layers is run
+    # on the one set of parameters (1: once, one exit), and the weight of
+    # the exit distribution's entropy in the loss
+    total_ut_steps: ClassVar[int] = 1
+    exit_entropy_weight: ClassVar[float] = 0.0
+    pass_input: ClassVar[str] = ""
+    exit_gate_input: ClassVar[str] = ""
+    exit_gate_bias: ClassVar[bool] = False
+    exit_gate_init_std: ClassVar[float] = 0.0
+    exit_loss: ClassVar[str] = ""
     # fields a configuration's file states and no entry point's flag sets:
     # what the source fixes and models/sparse_lm.py is written for
     # (``validate`` holds each to its one value), and the one assumption
@@ -874,6 +884,13 @@ class SparseLMConfig:
 
     def optimizer_stacking(self) -> Dict[str, int]:
         return {"stacked_reps": 0, "stacked_experts": self.experts_held}
+
+    @property
+    def has_expert_layers(self) -> bool:
+        """Whether any layer routes: a class that states no experts
+        (``num_experts`` 0, every layer dense: ``OuroLMConfig``) has no
+        router, no counters and no ``moe_*`` entry anywhere."""
+        return self.num_experts > 0
 
     def layer_is_dense(self, layer: int) -> bool:
         """Whether ``layer``'s feed-forward is the dense gated block and
@@ -1548,6 +1565,117 @@ def qwen3next80b_model_config(**overrides: Any) -> Qwen3NextLMConfig:
     """Preset ``qwen3next80b``: the cell ``qwen3next80b-train-solo``
     (benchmark/configs/qwen3next80b.json holds ``asdict`` of it)."""
     return dataclasses.replace(Qwen3NextLMConfig(), **overrides)
+
+
+@dataclass(frozen=True)
+class OuroLMConfig(AfmoeLMConfig):
+    """``AfmoeLMConfig``'s four-norm layer with a dense gated-SiLU
+    feed-forward in EVERY layer (``num_dense_layers`` is the depth:
+    **no expert layer**, so ``num_experts``, ``experts_held``,
+    ``experts_per_token`` and ``expert_width`` are 0 and the router's fields
+    are read by nothing; no output gate, no head norms, no embedding scale)
+    around multi-head attention of kind ``full_rope`` (16 / 16 heads of 128,
+    rotary over all of a head's lanes), with the mechanism of ``model_type``
+    ``ouro`` as fields: **the one stack of layers is run ``total_ut_steps``
+    times on one set of parameters**. The final norm closes every pass and
+    its output is both the next pass's input (``pass_input``) and that
+    pass's exit: an exit gate ``lam_t = sigmoid(z_t . w_g + b_g)``
+    (``exit_gate_input``, ``exit_gate_bias``) makes a row's exit
+    distribution ``p_t = lam_t prod_{j<t} (1 - lam_j)``, the last pass
+    taking what is left, the head is read after every pass, and the loss is
+    the rows' mean of ``sum_t p_t nll_t - exit_entropy_weight H(p)``
+    (``exit_loss``). Defaults are Ouro-2.6B (ByteDance, config.json; the
+    objective from the family's description, arXiv:2510.25741) cut for one
+    chip: 6 of the 48 layers (the period is one layer; 8 do not fit beside
+    the loop's carried state: PERF.md section 6, PR 67), half of the
+    vocabulary; every width and the four passes as published. ``window`` is
+    no layer's."""
+
+    hidden_size: int = 2048
+    num_hidden_layers: int = 6       # published 48, every one alike
+    num_heads: int = 16
+    num_kv_heads: int = 16
+    head_dim: int = 128
+    expert_width: int = 0
+    num_experts: int = 0
+    experts_per_token: int = 0
+    experts_held: int = 0
+    vocab_size: int = 24576          # published 49152
+    window: int = 0
+    layer_kinds: Tuple[str, ...] = (LAYER_FULL_ROPE,)
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-6
+    vocab_text: int = 12288
+    vocab_image: int = 12288
+    num_dense_layers: int = 6        # every layer: the source has no experts
+    dense_width: int = 5632          # the source's intermediate_size
+    num_shared_experts: int = 0
+    selection_bias: bool = False
+    route_norm: bool = False
+    route_scale: float = 1.0
+    attention_gate: bool = False
+    qk_norm: bool = False
+    mup_enabled: bool = False
+    total_ut_steps: int = 4
+    # assumed, each held to its one value by ``validate`` (config.json has
+    # no key for them; the configuration's file gives the reasons)
+    pass_input: str = "final_norm"
+    exit_gate_input: str = "final_norm"
+    exit_gate_bias: bool = True
+    exit_gate_init_std: float = 0.02
+    exit_loss: str = "expected_nll_minus_entropy"
+    exit_entropy_weight: float = 0.1
+
+    no_flag: ClassVar[Tuple[str, ...]] = AfmoeLMConfig.no_flag + (
+        "pass_input", "exit_gate_input", "exit_gate_bias",
+        "exit_gate_init_std", "exit_loss")
+    decode_missing: ClassVar[Optional[str]] = (
+        "models/decode.py has no looped stack (its cache is one a layer, "
+        "where layers run total_ut_steps times on one set of parameters "
+        "need one a pass and layer), no exit gate, no four-norm layer and "
+        "no rotary on 128-wide heads")
+
+    def validate(self) -> None:
+        self.validate_shapes()
+        if self.tied_embeddings or self.attention_bias:
+            raise ValueError(
+                "this class has an untied head and no attention bias")
+        if self.num_experts or self.experts_held or self.experts_per_token \
+                or self.expert_width or self.num_shared_experts \
+                or self.num_dense_layers != self.num_hidden_layers \
+                or self.dense_width <= 0:
+            raise ValueError(
+                "this class has no expert layer: num_dense_layers is the "
+                "depth, dense_width the feed-forward's width, and the "
+                "experts' counts and width are 0")
+        if self.hidden_act != "silu" or not self.sandwich_norms \
+                or self.attention_gate or self.qk_norm or self.mup_enabled \
+                or self.layer_kinds != (LAYER_FULL_ROPE,):
+            raise ValueError(
+                "a layer of this class is 'full_rope' attention and a "
+                "gated-SiLU block behind four norms, with no output gate, "
+                "no head norms and no embedding scale")
+        if self.total_ut_steps < 2:
+            raise ValueError(
+                "total_ut_steps counts the passes of the looped stack: 2 "
+                "or more (a stack run once is the parent class's)")
+        if (self.pass_input, self.exit_gate_input, self.exit_gate_bias,
+                self.exit_loss) != ("final_norm", "final_norm", True,
+                                    "expected_nll_minus_entropy"):
+            raise ValueError(
+                "models/sparse_lm.py runs the next pass on the final "
+                "norm's output, reads the exit gate (with its bias) from "
+                "it, and trains on the expected loss over the exits less "
+                "the exit distribution's weighted entropy")
+        if self.exit_entropy_weight < 0 or self.exit_gate_init_std <= 0:
+            raise ValueError("exit_entropy_weight >= 0 and a gate drawn "
+                             "with exit_gate_init_std > 0")
+
+
+def ouro2b6_model_config(**overrides: Any) -> OuroLMConfig:
+    """Preset ``ouro2b6``: the cell ``ouro2b6-train-solo``
+    (benchmark/configs/ouro2b6.json holds ``asdict`` of it)."""
+    return dataclasses.replace(OuroLMConfig(), **overrides)
 
 
 def tiny_model_config(**overrides: Any) -> ModelConfig:

@@ -144,6 +144,19 @@ def refuse_recurrent_layers(cfg) -> None:
             "has no single-token step of the delta rule")
 
 
+def refuse_looped_stack(cfg) -> None:
+    """A configuration whose stack of layers is run ``total_ut_steps``
+    times on one set of parameters (models/sparse_lm.py) cannot be decoded
+    here: the cache below is one a layer."""
+    passes = getattr(cfg, "total_ut_steps", 1)
+    if passes > 1:
+        raise NotImplementedError(
+            f"models/decode.py cannot decode a stack of layers that is run "
+            f"{passes} times on one set of parameters: its cache is one a "
+            "layer where such a stack needs one a pass and layer, and it "
+            "has no exit gate to stop a token's passes by")
+
+
 def init_cache(cfg: ModelConfig, batch: int, dtype=None):
     """Static-shape KV cache, one k/v pair per layer application (weight
     sharing shares parameters, not activations).
@@ -157,6 +170,7 @@ def init_cache(cfg: ModelConfig, batch: int, dtype=None):
     """
     refuse_selected_layers(cfg)
     refuse_recurrent_layers(cfg)
+    refuse_looped_stack(cfg)
     dtype = dtype or jnp.dtype(cfg.dtype)
     hd = cfg.heads * cfg.head_dim
     reps = _cycle_reps(cfg)

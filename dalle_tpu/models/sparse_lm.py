@@ -1,6 +1,6 @@
 """A sparse decoder-only language model on the trainer's normal path.
 
-Seven configurations' equations, each mechanism read from a field of the
+Eight configurations' equations, each mechanism read from a field of the
 configuration and none from a preset's name. What ``SparseLMConfig``
 describes (its defaults: SmallThinker-21BA3B-Instruct, PowerInfer): every
 layer is
@@ -290,6 +290,53 @@ gate and ``beta`` / ``g`` are XLA code. Scopes ``gdn/in_proj``,
 chunked form: ``rule[mosaic]``, or XLA code), ``gdn/gate_norm``
 (``qk_norm[mosaic]``: the head pass's name; then the gate) and
 ``gdn/out_proj`` (never under ``attn``, ``conv`` or ``ssm``).
+
+What ``OuroLMConfig`` describes (its defaults: Ouro-2.6B, ByteDance,
+``model_type`` ``ouro``; the objective from the family's description,
+arXiv:2510.25741): ``AfmoeLMConfig``'s four-norm layer with the dense
+gated-SiLU block in EVERY layer (``has_expert_layers`` false: no router, no
+counters, no ``moe_*`` entry anywhere) around multi-head ``full_rope``
+attention (16 / 16 heads of 128, all lanes rotated), and **the one stack run
+``total_ut_steps`` = R times on one set of leaves**:
+
+    x_0 = E[ids]
+    z_t = rmsnorm_f(stack(x_{t-1}));  x_t = z_t                  t = 1..R
+    lam_t = sigmoid(z_t . w_g + b_g)            a row's exit gate, f32
+    p_t = lam_t prod_{j<t} (1 - lam_j)  (t < R);  p_R = prod_{j<R} (1 - lam_j)
+    nll_t = next-token cross-entropy of z_t . W_head, a row
+    loss = mean over the T - 1 predicted rows of
+           [ sum_t p_t nll_t - beta H(p) ],  H(p) = - sum_t p_t ln p_t
+
+(``beta``: ``exit_entropy_weight``; leaves ``passes/layer_<i>/...``,
+``passes/final_norm``, ``exit_gate`` (hidden,), ``exit_gate_bias`` (1,),
+``lm_head``). **How it runs** (:class:`LoopedStack`, :func:`run_passes`,
+:func:`_looped_loss`; ``loop_layout`` on the ``setup/warmup`` row says which
+form a trace took): the passes are ONE traced body, a ``lax.scan`` whose
+constants are the leaves (the matrices cast to the activations' dtype once,
+outside the loop, so the loop's sum of a matrix's gradient over the passes is
+carried in that dtype), so set-up traces, lowers and compiles one pass's
+layers, not R of them; each layer is rematerialised as any stack's, so the
+loop stores a layer's input, its attention's output and ONE lane of its
+statistics once a layer AND pass (a head a key-value head leaves 127 of a
+tile's 128 lanes empty: ``causal_attention_kernels._vjp_fwd`` keeps the
+lane), the final norm's input and the exit's state; a layer reads its leaves
+through :func:`_leaves_with_state`, a barrier in the backward pass without
+which the compiler moves every layer's weight gradients to the end of the
+loop's body and keeps what they read alive until then. The R exits' rows go
+through the streamed head as ONE call under their exit weights (``p_t``
+depends on the gates, not on the logits, so a chunk's ``dx`` and ``dW`` are
+still made in the scan that makes the loss and the head's leaf gets the
+exits' sum from one carried array); the weights are differentiated and every
+row's loss comes back as a value (``_streamed_nll(..., rows=True)``: the
+gate's gradient is ``nll . dp``). ``loss_text`` / ``loss_img`` are the last
+pass's; the step's aux also carries ``loss_main`` (the expectation),
+``loss_entropy`` (``- beta H``), ``loss_exit_1`` .. ``loss_exit_R`` (each
+pass's own mean: what tells the passes apart, since one traced body has one
+name in a trace), ``exit_expected_pass`` and ``exit_entropy``. Scopes
+``passes`` (the loop and the one cast; the layers' ``layer_<i>/.../attn``,
+``ff/dense``, ``rms_norm`` under it), ``exit_gate`` (the gate's sum over the
+lanes, the sigmoids in logarithms, the survival sums, the entropy and the
+expected pass, forward and backward), ``head`` and ``ce`` as any stack's.
 
 **The expert layer is told which experts it holds** (``experts_held``
 consecutive ones from ``expert_offset``): it routes over all
@@ -2952,6 +2999,10 @@ def head_layout(cfg: SparseLMConfig) -> str:
     loss, in what chunks, and what carried ``dW``'s sum."""
     calls = {"main": 2, "mtp": 1} if cfg.num_nextn_predict_layers else {
         "main": 2}
+    exits = cfg.total_ut_steps
+    if exits > 1:       # one column of weights: the exit distribution
+        calls = {f"main: the {exits} exits' rows in one call under their "
+                 "exit weights, which are differentiated": 1}
     made = {name: said for name, n_sums in calls.items()
             if (said := lowering.recorded(HEAD_SITE, _head_key(
                 cfg.hidden_size, cfg.vocab_size, n_sums,
@@ -2967,7 +3018,7 @@ def head_layout(cfg: SparseLMConfig) -> str:
 
 
 def _streamed_nll(h, kernel, targets, weights, chunk: int,
-                  tied: bool = False):
+                  tied: bool = False, rows: bool = False):
     """``(total, sums)``: the sums of ``weights`` x next-token negative
     log-likelihood over the rows of ``h`` (N, D), a sum a column of
     ``weights`` (N, n_sums), and their total, ``chunk`` rows of the (N, V)
@@ -2987,7 +3038,15 @@ def _streamed_nll(h, kernel, targets, weights, chunk: int,
     not hidden behind the product: 0.5 ms a chunk at 2 560 x 18 992). The
     products are autodiff's: f32 ``dlogits`` against the operands as they
     lie, which a TPU's default precision rounds to their dtype in the unit,
-    as it rounded the transposed scan's."""
+    as it rounded the transposed scan's.
+
+    ``rows``: ``(total, sums, nll)`` with every row's negative
+    log-likelihood (N,) as a value, and ``weights`` differentiated: a
+    caller whose weights depend on parameters (a looped stack's exit
+    distribution, known before the logits are) gets ``d total / d weights =
+    nll`` from the rows the scan wrote, and still nothing of (rows,
+    vocabulary) outlives its chunk. The rows themselves are reported, as
+    ``sums`` are."""
     n, dims = h.shape[0], (((1,), (1 if tied else 0,)), ((), ()))
     split = lambda x: jnp.pad(
         x, ((0, -n % chunk),) + ((0, 0),) * (x.ndim - 1)).reshape(
@@ -3012,11 +3071,13 @@ def _streamed_nll(h, kernel, targets, weights, chunk: int,
     def scan(h, kernel, targets, weights):
         def body(sums, xs):
             hc, tc, wc = xs
-            return add(sums, nll_of(hc, kernel, tc)[2], wc), None
+            nll = nll_of(hc, kernel, tc)[2]
+            return add(sums, nll, wc), (nll if rows else None)
 
-        sums, _ = jax.lax.scan(body, zeros(),
-                               (split(h), split(targets), split(weights)))
-        return jnp.sum(sums), sums
+        sums, nll = jax.lax.scan(body, zeros(),
+                                 (split(h), split(targets), split(weights)))
+        out = jnp.sum(sums), sums
+        return (*out, nll.reshape(-1)[:n]) if rows else out
 
     def forward(h, kernel, targets, weights):
         h, kernel, targets, weights = (
@@ -3042,30 +3103,34 @@ def _streamed_nll(h, kernel, targets, weights, chunk: int,
                     *((dlogits, hc) if tied else (hc, dlogits)),
                     (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)).astype(dw.dtype)
-            return (add(sums, nll, wc), dw), dx
+            return (add(sums, nll, wc), dw), ((dx, nll) if rows else dx)
 
-        (sums, dw), dx = jax.lax.scan(
+        (sums, dw), made = jax.lax.scan(
             body, (zeros(), jnp.zeros_like(kernel)),
             (split(h), split(targets), split(weights)))
+        dx, nll = (made[0], (made[1].reshape(-1)[:n],)) if rows else (
+            made, ())
         lowering.record(
             HEAD_SITE, _head_key(h.shape[1], kernel.shape[0 if tied else 1],
                                  weights.shape[1], tied, h.dtype),
             None, chunks=dx.shape[0], rows=chunk, carried=dw.dtype.name)
-        return (jnp.sum(sums), sums), (dx.reshape(-1, h.shape[1])[:n], dw)
+        return (jnp.sum(sums), sums, *nll), (
+            dx.reshape(-1, h.shape[1])[:n], dw, *nll)
 
     def backward(made, cotangents):
-        c, of_sums = cotangents
-        if not isinstance(of_sums, SymbolicZero):
+        c, *reported = cotangents
+        if not all(isinstance(of, SymbolicZero) for of in reported):
             raise TypeError(
                 "the streamed head differentiates its total, one cotangent "
                 "for every column of weights; a derivative reached its "
                 "sums, which are reported and not differentiated")
         if isinstance(c, SymbolicZero):
             return None, None, None, None
-        dx, dw = made
+        dx, dw, *nll = made
         # the scalar before the one rounding to the operands' dtype
         return ((dx * c).astype(h.dtype), (dw * c).astype(kernel.dtype),
-                None, None)
+                None, jnp.broadcast_to((nll[0] * c)[:, None], weights.shape)
+                if rows else None)
 
     scan.defvjp(forward, backward, symbolic_zeros=True)
     return scan(h, kernel, targets, weights)
@@ -3100,6 +3165,203 @@ class PredictionModule(nn.Module):
         return rms_norm(x, scale("final_norm"), cfg.rms_eps), counters
 
 
+def remat_layer(cfg: SparseLMConfig):
+    """The configuration's layer class, rematerialised but for
+    ``KEPT_OF_A_LAYER``."""
+    return nn.remat(
+        OnePartLayer if cfg.one_part_layers else Layer,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *KEPT_OF_A_LAYER))
+
+
+@jax.custom_vjp
+def _leaves_with_state(x, leaves):
+    """``(x, leaves)`` as they are. Backward, the state's cotangent and the
+    leaves' leave together or not at all."""
+    return x, leaves
+
+
+_leaves_with_state.defvjp(
+    lambda x, leaves: ((x, leaves), None),
+    lambda _, cotangents: jax.lax.optimization_barrier(cotangents))
+
+
+class LoopedStack:
+    """The stack of a configuration with ``total_ut_steps`` > 1 as plain
+    functions of its leaves (``layer_<i>/...``, ``final_norm``: the tree
+    :class:`SparseLM` holds under ``passes``): :meth:`init` draws them,
+    :meth:`one_pass` is the body every pass runs. Every pass runs THESE
+    leaves, so a leaf's gradient is the sum over the passes."""
+
+    def __init__(self, cfg: SparseLMConfig, mesh=None):
+        self.cfg = cfg
+        layer_cls = remat_layer(cfg)
+        self.layers = {f"layer_{i}": layer_cls(
+            cfg, cfg.kind_of_layer(i), mesh, cfg.layer_is_dense(i))
+            for i in range(cfg.num_hidden_layers)}
+
+    def init(self, rng: jax.Array, x: jax.Array):
+        keys = jax.random.split(rng, len(self.layers))
+        leaves = {name: layer.init(key, x)["params"] for key, (name, layer)
+                  in zip(keys, self.layers.items())}
+        leaves["final_norm"] = jnp.ones((self.cfg.hidden_size,),
+                                        jnp.dtype(self.cfg.param_dtype))
+        return leaves
+
+    def as_run(self, leaves):
+        """The leaves as the passes read them: the matrices in the
+        activations' dtype, cast ONCE, outside the loop (a layer's products
+        cast them to it anyway), the vectors as they are. The loop's sum of
+        a matrix's gradient over the passes is then carried in that dtype,
+        half the bytes of the leaf's own, and widened once (PERF.md section
+        6, PR 67: the carried sums and a body's terms beside them are what
+        the chip has no room for in float32)."""
+        dt = jnp.dtype(self.cfg.dtype)
+        return jax.tree.map(lambda a: a.astype(dt) if a.ndim > 1 else a,
+                            leaves)
+
+    def one_pass(self, leaves, x: jax.Array):
+        """``(the normed state, the same again as this pass's exit)``. A
+        layer reads its leaves through :func:`_leaves_with_state`: backward,
+        the state's cotangent goes on to the layer before only with this
+        layer's weight gradients made. Nothing else orders them inside a
+        loop's body: the compiler moved every layer's to the body's end and
+        kept what they read (a layer's replayed activations) alive until
+        then, 6.7 GiB at ``ouro2b6``'s sizes where one layer's are 0.8."""
+        for name, layer in self.layers.items():
+            x, mine = _leaves_with_state(x, leaves[name])
+            with jax.named_scope(name):
+                x = layer.apply({"params": mine}, x)[0]
+        # the final norm closes the pass; its input is kept, not an f32 copy
+        z = jax.checkpoint(rms_norm, static_argnums=(2,))(
+            x, leaves["final_norm"], self.cfg.rms_eps)
+        return z, z
+
+
+LOOP_SITE = "pass loop"
+
+
+def _loop_key(cfg: SparseLMConfig):
+    return cfg.num_hidden_layers, cfg.total_ut_steps, cfg.hidden_size
+
+
+def run_passes(stack: LoopedStack, x: jax.Array, leaves):
+    """``stack.one_pass`` ``cfg.total_ut_steps`` times from the state ``x``:
+    the passes' exits stacked, (R, B, T, D). ONE traced body: a
+    ``lax.scan`` over the passes, so that tracing, lowering and compiling
+    cost what one pass's layers cost. The loop stores, a pass, what a
+    rematerialised layer keeps (its input and ``KEPT_OF_A_LAYER``), the
+    final norm's input and the exit's state. (The tests compare it with a
+    Python loop over the same body, tests/ouro_unrolled.py; the program has
+    this form alone.)"""
+    cfg = stack.cfg
+    lowering.record(LOOP_SITE, _loop_key(cfg), None, form="one traced pass")
+    leaves = stack.as_run(leaves)
+    _, exits = jax.lax.scan(lambda x, _: stack.one_pass(leaves, x), x, None,
+                            length=cfg.total_ut_steps)
+    return exits
+
+
+def loop_layout(cfg: SparseLMConfig) -> str:
+    """The ``setup/warmup`` row's ``loop_layout``: the looped stack's sizes
+    and the form the traced call gave the loop over the passes."""
+    layers, passes = cfg.num_hidden_layers, cfg.total_ut_steps
+    said = lowering.recorded(LOOP_SITE, _loop_key(cfg))
+    return (f"{layers} layers x {passes} passes: {layers * passes} "
+            f"applications of {layers} parameter sets, "
+            + (said["form"] if said else "none traced"))
+
+
+def exit_log_probs(logit: jax.Array) -> jax.Array:
+    """``ln p_t`` (R, ...) of the exit gates' logits (R, ...), f32: ``p_t =
+    lam_t prod_{j<t} (1 - lam_j)`` with ``lam = sigmoid(logit)``, the last
+    pass taking what is left, ``p_R = prod_{j<R} (1 - lam_j)`` (its own gate
+    is read by nothing). In logarithms: no product of small numbers, and
+    ``p ln p`` is finite wherever a gate saturates."""
+    stop = jax.nn.log_sigmoid(logit)
+    go = jax.nn.log_sigmoid(-logit)
+    before = jnp.cumsum(go, axis=0) - go        # sum_{j<t} ln (1 - lam_j)
+    return jnp.concatenate([(before + stop)[:-1], before[-1:]], axis=0)
+
+
+def _looped_loss(module, x, ids, table, text_len: int, loss_mask):
+    """What :class:`SparseLM` does after the embedding where the stack is
+    looped (module docstring, ``OuroLMConfig``): the passes, the exit
+    distribution, ONE call of the streamed head over the R exits' rows with
+    the exit weights, and the loss with its parts. ``module``: the
+    ``SparseLM`` whose compact call this is (the leaves are its)."""
+    cfg = module.cfg
+    dt, pdt = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
+    passes = cfg.total_ut_steps
+    stack = LoopedStack(cfg, module.mesh)
+    leaves = module.param("passes", stack.init, x)
+    with jax.named_scope("passes"):
+        exits = run_passes(stack, x, leaves)
+    gate = module.param(
+        "exit_gate", nn.initializers.normal(stddev=cfg.exit_gate_init_std),
+        (cfg.hidden_size,), pdt)
+    bias = module.param("exit_gate_bias", nn.initializers.zeros, (1,), pdt)
+    tied = cfg.tied_embeddings
+    head = table if tied else module.param(
+        "lm_head", nn.initializers.normal(stddev=0.02),
+        (cfg.hidden_size, cfg.vocab_size), pdt)
+
+    # position t predicts token t + 1; the last has nothing to predict
+    b, t = ids.shape
+    targets = jnp.concatenate(
+        [ids[:, 1:], jnp.zeros((b, 1), ids.dtype)], axis=1)
+    scored = jnp.broadcast_to(
+        (jnp.arange(t) < t - 1).astype(jnp.float32), (b, t))
+    if loss_mask is not None:
+        scored = scored * jnp.concatenate(
+            [loss_mask[:, 1:], jnp.zeros((b, 1), loss_mask.dtype)],
+            1).astype(jnp.float32)
+    in_text = (jnp.arange(t) + 1 < text_len).astype(jnp.float32)
+    with jax.named_scope("exit_gate"):
+        # a number a row and pass, in f32: a sum over the lanes, no product
+        # on the unit
+        logit = jnp.sum(exits.astype(jnp.float32) * gate.astype(jnp.float32),
+                        axis=-1) + bias.astype(jnp.float32)
+        log_p = exit_log_probs(logit)                        # (R, B, T)
+        p = jnp.exp(log_p)
+    # the exits' rows through the head as one call: the weights are known
+    # before the logits, so a chunk's dx and dW are made with its loss, and
+    # the head's leaf gets the four exits' sum from one carried array
+    rows = passes * b * t
+    total, _, nll = _streamed_nll(
+        exits.reshape(rows, -1), head.astype(dt),
+        jnp.tile(targets.reshape(-1), passes),
+        (p * scored).reshape(rows, 1), min(cfg.head_chunk, b * t), tied,
+        rows=True)
+    nll = nll.reshape(passes, b, t)
+    # normalised over the WHOLE (micro)batch, as the unlooped loss is
+    denom = sum_over_manual_data_axes(jnp.sum(scored))
+    fields = sum_over_manual_data_axes(jnp.stack(
+        [jnp.sum(scored * in_text), jnp.sum(scored * (1.0 - in_text))]))
+    if loss_mask is not None:
+        denom = jnp.maximum(denom, 1.0)
+    with jax.named_scope("exit_gate"):
+        entropy = jnp.sum(-jnp.sum(p * log_p, axis=0) * scored) / denom
+        order = jnp.arange(1, passes + 1, dtype=jnp.float32)
+        expected = jnp.sum(jnp.einsum("r,rbt->bt", order, p) * scored) / denom
+    loss_main = total / denom
+    loss_entropy = -cfg.exit_entropy_weight * entropy
+    aux = {
+        "loss": loss_main + loss_entropy,
+        # the expectation over the exits, and the entropy's term
+        "loss_main": loss_main, "loss_entropy": loss_entropy,
+        # the last pass's, as a model run once reports them
+        "loss_text": jnp.sum(nll[-1] * scored * in_text)
+        / jnp.maximum(fields[0], 1.0),
+        "loss_img": jnp.sum(nll[-1] * scored * (1.0 - in_text))
+        / jnp.maximum(fields[1], 1.0),
+        # each pass's own mean loss: what tells the passes apart
+        **{f"loss_exit_{i + 1}": jnp.sum(nll[i] * scored) / denom
+           for i in range(passes)},
+        "exit_expected_pass": expected, "exit_entropy": entropy}
+    return aux["loss"], aux
+
+
 class SparseLM(nn.Module):
     cfg: SparseLMConfig
     # as DALLE.mesh: the Mosaic kernels run per shard of it
@@ -3127,11 +3389,11 @@ class SparseLM(nn.Module):
             if cfg.mup_enabled:
                 x = x * cfg.hidden_size ** 0.5
             x = x.astype(dt)
+        if cfg.total_ut_steps > 1:
+            return _looped_loss(self, x, ids, table, text_tokens.shape[1],
+                                loss_mask)
 
-        layer_cls = nn.remat(
-            OnePartLayer if cfg.one_part_layers else Layer,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                *KEPT_OF_A_LAYER))
+        layer_cls = remat_layer(cfg)
         # the rows a configuration with ``mrope_section`` rotates by: an
         # input of the layer stack, made here from the two fields' lengths
         rows = ()
@@ -3474,12 +3736,17 @@ def engagement_records(cfg: SparseLMConfig, mesh=None) -> Dict[str, str]:
             "layer, a final norm of its own; shares the embedding and the "
             f"head; loss_mtp over T - 2 positions, weight "
             f"{cfg.mtp_loss_weight:g}")
+    said["layer_loop"] = (
+        ("a pass " if cfg.total_ut_steps > 1 else "")
+        + f"unrolled: {len(layers)} layers, each rematerialised but its "
+        "attention" + (", one part a layer behind one norm: "
+                       + " ".join(layers)) * cfg.one_part_layers)
+    if cfg.total_ut_steps > 1:
+        said["loop_layout"] = loop_layout(cfg)
+    if not cfg.has_expert_layers:
+        return said
     return {
         **said,
-        "layer_loop": (f"unrolled: {len(layers)} layers, each "
-                       "rematerialised but its attention"
-                       + (", one part a layer behind one norm: "
-                          + " ".join(layers)) * cfg.one_part_layers),
         "moe_layout": (
             f"{cfg.experts_held} of {cfg.num_experts} experts held "
             f"({first}-{last}), top {cfg.experts_per_token} of "
@@ -3497,6 +3764,14 @@ def step_attributes(cfg: SparseLMConfig) -> Tuple[str, ...]:
     losses = ("loss_main", "loss_mtp") if cfg.num_nextn_predict_layers else ()
     if cfg.index_topk:
         losses = ("loss_main", "loss_indexer", "sparse_selected_pct")
+    if cfg.total_ut_steps > 1:
+        # a looped stack's: the loss's two terms, each pass's own loss (what
+        # tells the passes apart: a trace cannot), and the exit distribution
+        losses = ("loss_main", "loss_entropy", *(
+            f"loss_exit_{i + 1}" for i in range(cfg.total_ut_steps)),
+            "exit_expected_pass", "exit_entropy")
+    if not cfg.has_expert_layers:
+        return losses
     return ("moe_assignments_here_pct", "moe_load_max_over_mean",
             "moe_dropped", "moe_dense_calls", "moe_sum_spills",
             "moe_tiles_active_pct") + losses

@@ -869,7 +869,15 @@ def _vjp_fwd(q, k, v, window, block, interpret, head_dim):
     # named on the residuals themselves, so that a remat policy can keep
     # them and the backward pass does not run the forward kernel again
     out = checkpoint_name(out[:, :t], "attn_out")
-    stats = checkpoint_name(stats, "attn_stats")
+    if max(1, LANES // head_dim) * (q.shape[2] // k.shape[2]) == 1:
+        # one query head a key-value head: of a tile's 128 lanes one holds
+        # a number. That lane is what a policy keeps (1 / 128 of the array:
+        # 64 MiB a layer at 16 heads and 8 192 tokens, which a stack run
+        # several times keeps once a pass) and the array is made of it again
+        lane = checkpoint_name(stats[..., 0], "attn_stats")
+        stats = jnp.pad(lane[..., None], ((0, 0),) * 3 + ((0, LANES - 1),))
+    else:
+        stats = checkpoint_name(stats, "attn_stats")
     return out, (q, k, v, out, stats)
 
 

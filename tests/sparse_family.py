@@ -15,6 +15,7 @@ whose attributes are its **row**, and the tests of what only it has.
            config, preset, preset_config, Y, TINY, KERNEL_WIDTHS
            MOVED, PRECISION, LOSS_WITHIN, LEAF_WITHIN   (the defaults do)
            EXPERT_LAYERS   the tiny model's, the prediction module's too
+                           (0: a dense stack, which carries no moe_* entry)
            BLOCKWISE       its kinds of blockwise attention layer
            ADDED           the fields the class states beyond its parent's
            PUBLISHED       the source's widths, as the preset must state them
@@ -56,7 +57,7 @@ import pytest
 from dalle_tpu.cli import run_aux_peer, run_inference, run_server, run_trainer
 from dalle_tpu.config import (AfmoeLMConfig, JoyAILMConfig, KeyeLMConfig,
                               Lfm2MoeLMConfig, NemotronHLMConfig,
-                              Qwen3NextLMConfig, SparseLMConfig)
+                              OuroLMConfig, Qwen3NextLMConfig, SparseLMConfig)
 from dalle_tpu.models import attention, family, sparse_lm
 from dalle_tpu.ops.pallas import grouped_matmul_kernels as grouped
 
@@ -68,7 +69,8 @@ CHAIN = {SparseLMConfig: (None, 27), AfmoeLMConfig: (SparseLMConfig, 12),
          Lfm2MoeLMConfig: (AfmoeLMConfig, 2),
          KeyeLMConfig: (AfmoeLMConfig, 7),
          NemotronHLMConfig: (AfmoeLMConfig, 11),
-         Qwen3NextLMConfig: (AfmoeLMConfig, 8)}
+         Qwen3NextLMConfig: (AfmoeLMConfig, 8),
+         OuroLMConfig: (AfmoeLMConfig, 7)}
 
 
 def as_file(cfg):
@@ -230,11 +232,15 @@ class Family:
         assert float(loss) == pytest.approx(float(ref_loss),
                                             rel=self.LOSS_WITHIN)
         leaves_within(grads, ref_grads, self.LEAF_WITHIN)
-        # the counters are the expert layers' only
-        assert float(aux["moe_dropped"]) == 0.0
-        assert float(aux["moe_dense_calls"]) == (
-            0.0 if kernels else self.EXPERT_LAYERS)
-        assert 0 < float(aux["moe_assignments_here_pct"]) < 100
+        # the counters are the expert layers' only: a stack with none (a
+        # row that states ``EXPERT_LAYERS`` 0) carries no ``moe_*`` entry
+        if not self.EXPERT_LAYERS:
+            assert not [name for name in aux if name.startswith("moe_")]
+        else:
+            assert float(aux["moe_dropped"]) == 0.0
+            assert float(aux["moe_dense_calls"]) == (
+                0.0 if kernels else self.EXPERT_LAYERS)
+            assert 0 < float(aux["moe_assignments_here_pct"]) < 100
         # no gradient reaches a router's bias, on either side: exact zeros
         for side in (grads, ref_grads):
             for name, bias in leaves(side).items():
@@ -301,6 +307,9 @@ class Family:
         warm = [r for r in rows if r["phase"] == "setup/warmup"][-1]["a"]
         steps = [r["a"] for r in rows if r["phase"] == "loop/step"][-3:]
         for row in steps:
+            if not self.EXPERT_LAYERS:
+                assert not [name for name in row if name.startswith("moe_")]
+                continue
             assert row["moe_dropped"] == 0.0
             # no Mosaic backend here: the dense lowering in every expert
             # layer of every shard
@@ -308,7 +317,7 @@ class Family:
                                               * task.mesh.size)
         # the optimizer was told the expert axis by the configuration
         assert task.model_cfg.optimizer_stacking()["stacked_experts"] == \
-            self.TINY["experts_held"]
+            self.TINY.get("experts_held", 0)
         self.the_normal_path_also(task=task, names=names, warm=warm,
                                   steps=steps, losses=losses)
 
